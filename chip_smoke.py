@@ -1,0 +1,462 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — does the searched train path still start on the chip?
+
+One process, normal entry points only (``FFConfig`` -> ``FFModel`` ->
+``models.nlp.build_*`` -> ``compile`` with the search ON -> ``fit``), on
+whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
+
+  Leg A  trainer, the paper's model: BERT-large at published width,
+         Adam, a few steps on one fixed batch drawn from a seed.
+  Leg B  the kernels on the same path: GPT-2 at published width and its
+         own context length, so attention resolves to the compiled flash
+         kernel by itself; KV-cache generation against the re-forward
+         path; the fused Adam kernel against ``AdamOptimizer.update`` on
+         the model's own leaves; then a second compile with
+         ``opt_update:fused`` in the step.
+
+It claims no speed. The times it prints are set-up facts of one run.
+It exits non-zero, before building anything, unless JAX reports a TPU;
+there is no option that lets it pass without one. Every later PR is
+checked with it: ``python3 chip_smoke.py`` from the repository root.
+
+The legs are plain functions, so ``tests/test_chip_smoke.py`` drives them
+at tiny widths on the CPU mesh, where the chip-only checks do not apply.
+"""
+from __future__ import annotations
+
+import json
+import sys
+import time
+
+import numpy as np
+
+SEED = 0
+SEARCH_BUDGET = 8
+TRAIN_STEPS = 5           # after the step that compiles
+PROMPT_LEN, NEW_TOKENS = 128, 16
+# Per-chip batches for f32 weights, gradients and Adam moments, no
+# rematerialization. BERT-large: XLA's memory analysis of the compiled
+# data-parallel step puts 8 samples at 10.2 GiB of a v5e's 16 (12 would
+# be 13.4, 16 does not fit); 8 leaves a searched plan's different
+# program its margin (PERF.md, section 5). GPT-2: 4 samples, 4.7 GiB.
+BERT_PER_CHIP_BATCH = 8
+GPT_PER_CHIP_BATCH = 4
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+
+
+class SmokeFailure(AssertionError):
+    """A check of the smoke did not hold."""
+
+
+def check(ok, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+# ----------------------------------------------------------------------
+# shared pieces
+# ----------------------------------------------------------------------
+def _config(batch: int, kernel_impls: str = "auto"):
+    from flexflow_tpu import FFConfig
+    cfg = FFConfig()
+    cfg.batch_size = batch
+    cfg.seed = SEED
+    cfg.search_budget = SEARCH_BUDGET   # the search runs on one chip too
+    cfg.kernel_impls = kernel_impls
+    cfg.trace = "true"                  # spans and counters are read below
+    return cfg
+
+
+def _compile(ff, out, optimizer, label: str) -> None:
+    """``FFModel.compile`` with the search on, then a report of what
+    each guarded compile phase did. On an accelerator a phase that is on
+    by default there must have run or left a typed reason."""
+    import jax
+
+    from flexflow_tpu.obs import events as obs
+    obs.clear()
+    t0 = time.perf_counter()
+    ff.compile(optimizer, "sparse_categorical_crossentropy", [],
+               output_tensor=out)
+    wall = time.perf_counter() - t0
+    spans: dict = {}
+    for ev in obs.events():
+        if ev["kind"] == "span":
+            spans[ev["name"]] = spans.get(ev["name"], 0.0) + ev["dur"]
+    ctr = obs.counters()
+    cm = getattr(ff, "_search_cost_model", None)
+    guard = getattr(ff, "_floor_guard_record", None)
+    say(f"{label}: compile {wall:.1f}s = search "
+        f"{ff._compile_phases.get('search_s', 0.0):.1f}s (calibrate"
+        f"+collective fit {spans.get('search.calibrate', 0.0):.1f}s, "
+        f"on-device op measurement "
+        f"{spans.get('costmodel.measure', 0.0):.1f}s, unity "
+        f"{spans.get('search.unity', 0.0):.1f}s, floor guard incl. its "
+        f"two XLA compiles {spans.get('search.floor_guard', 0.0):.1f}s)"
+        f" + verify {ff._compile_phases.get('verify_s', 0.0):.2f}s"
+        f" + init {ff._compile_phases.get('init_s', 0.0):.1f}s")
+    measure = {k.split(".", 1)[1]: int(v) for k, v in ctr.items()
+               if k.startswith("costmodel.measure_")}
+    say(f"{label}: mesh {dict(ff.dmesh.axis_sizes)}, strategy "
+        f"{_strategy_kind(ff)}, floor guard {guard}, op measurement "
+        f"{measure}, mxu_eff {getattr(cm, 'mxu_eff', None)}, collective "
+        f"fit (bw, lat) {getattr(cm, 'coll_bw', None)}, "
+        f"{getattr(cm, 'coll_lat', None)}")
+    if cm is not None and cm.measure_failures:
+        say(f"{label}: ops priced analytically after a failed "
+            f"microbenchmark: {cm.measure_failures}")
+    if ff._compile_skips:
+        say(f"{label}: skipped compile phases: {ff._compile_skips}")
+    check(int(np.prod(list(ff.dmesh.axis_sizes.values())))
+          == len(jax.devices()),
+          f"{label}: mesh {dict(ff.dmesh.axis_sizes)} does not cover "
+          f"{len(jax.devices())} devices")
+    check(guard is not None and ("adopted" in guard or guard["skipped"]),
+          f"{label}: the floor guard left no record")
+    if jax.devices()[0].platform == "cpu":
+        return
+    check(cm is not None and cm.measure_on_device,
+          f"{label}: on-device op measurement was not switched on")
+    priced = measure.get("measure_cache_hits", 0) \
+        + measure.get("measure_cache_misses", 0) \
+        - measure.get("measure_failures", 0) \
+        - measure.get("measure_over_budget", 0)
+    check(priced > 0,
+          f"{label}: no op was priced by an on-device measurement "
+          f"({measure}; failures {cm.measure_failures})")
+    check(len(jax.devices()) == 1 or cm.coll_bw is not None,
+          f"{label}: calibrate_collectives left no fit on "
+          f"{len(jax.devices())} devices")
+
+
+def _strategy_kind(ff) -> str:
+    from flexflow_tpu.parallel.strategy import ShardingStrategy
+    st = ff.strategy
+    rewritten = (f"graph {len(ff.layers)} -> "
+                 f"{len(ff.executor.program.layers)} layers")
+    if ff.dmesh.num_devices == 1:
+        return f"single device ({rewritten})"
+    if st.pipeline is not None:
+        return "pipeline"
+    # (the search may have rewritten the graph: compare on ITS layers)
+    dp = ShardingStrategy.data_parallel(ff.executor.program.layers,
+                                        ff.graph_inputs, ff.dmesh)
+
+    def axes(spec):       # P(("x0",), None) and P("x0") are one layout
+        return [tuple(e) if isinstance(e, (tuple, list)) else (e,)
+                for e in (spec or ()) if e is not None]
+
+    off = [n for n, s in st.ops.items()
+           if n not in dp.ops
+           or [axes(o) for o in s.outputs]
+           != [axes(o) for o in dp.ops[n].outputs]
+           or any(axes(w) for w in s.weights.values())]
+    if not off and not st.banks and not st.place_groups:
+        return "data_parallel"
+    return (f"searched ({len(off)}/{len(st.ops)} ops off data-parallel, "
+            f"{len(st.banks)} banks, {rewritten})")
+
+
+def _fit(ff, x, y, label: str, dropout: bool = False):
+    """``fit`` for 1 + TRAIN_STEPS epochs of the one fixed batch: every
+    epoch is one step on the same data. The loss must be finite at every
+    step and must have fallen. With dropout the training loss is a noisy
+    witness (a new mask every step, a handful of samples), so the fall
+    is then judged on the same batch in eval mode, before and after."""
+    before = float(ff.eval(x=x, y=y)["loss"]) if dropout else None
+    hist = ff.fit(x=x, y=y, epochs=1 + TRAIN_STEPS, verbose=False)
+    losses = [float(h["loss"]) for h in hist]
+    say(f"{label}: first step (XLA compile unless the floor guard "
+        f"already built it) {hist[0]['epoch_time_s']:.1f}s, then "
+        f"{TRAIN_STEPS} steps in "
+        f"{sum(h['epoch_time_s'] for h in hist[1:]):.2f}s; losses "
+        + " ".join(f"{v:.4f}" for v in losses))
+    check(all(np.isfinite(losses)), f"{label}: non-finite loss {losses}")
+    if dropout:
+        after = float(ff.eval(x=x, y=y)["loss"])
+        say(f"{label}: eval-mode loss on the fixed batch {before:.4f} -> "
+            f"{after:.4f}")
+        losses = [before, after]
+    check(losses[-1] < losses[0],
+          f"{label}: loss on the fixed batch did not fall: {losses}")
+
+
+def _step_hlo(ff, x, y) -> str:
+    """Text of the compiled train step (a compile-cache hit by now)."""
+    import jax.numpy as jnp
+    loader = ff._combined_loader(x, y, shuffle=False)
+    batch = next(iter(loader))
+    _check_placement("batch", batch)
+    step = ff.executor.make_train_step()
+    return step.lower(ff.params, ff.opt_state, ff.state, jnp.int32(0),
+                      batch).compile().as_text()
+
+
+def _check_placement(what: str, tree) -> None:
+    """Every leaf must sit on all the devices, sharded or replicated."""
+    import jax
+    n = len(jax.devices())
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        devs = {s.device for s in leaf.addressable_shards}
+        check(len(devs) == n,
+              f"{what}{jax.tree_util.keystr(path)} sits on {len(devs)} "
+              f"of {n} devices")
+
+
+def _check_step_program(ff, x, y, label: str,
+                        want_custom_call: bool) -> int:
+    import jax
+    _check_placement("params", ff.params)
+    txt = _step_hlo(ff, x, y)
+    # (the call target, not the bare word: op metadata repeats the word)
+    n_cc = txt.count('custom_call_target="tpu_custom_call"')
+    colls = [c for c in COLLECTIVES if c + "(" in txt or c + "-start" in txt]
+    say(f"{label}: compiled step has {n_cc} tpu_custom_call(s), "
+        f"collectives {colls or 'none'}")
+    if want_custom_call:
+        check(n_cc > 0, f"{label}: no Mosaic kernel in the compiled step")
+    if len(jax.devices()) > 1:
+        check(colls, f"{label}: no collective in the compiled step on "
+                     f"{len(jax.devices())} devices")
+    return n_cc
+
+
+def _peak_bytes() -> str:
+    import jax
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if not all(stats):
+        return "not reported by this backend"
+    worst = max(stats, key=lambda s: s["peak_bytes_in_use"])
+    return (f"{worst['peak_bytes_in_use'] / 2**30:.2f} GiB of "
+            f"bytes_limit {worst.get('bytes_limit', 0) / 2**30:.2f} GiB")
+
+
+# ----------------------------------------------------------------------
+# Leg A — trainer, the paper's model
+# ----------------------------------------------------------------------
+def leg_bert_train(bert_cfg, seq: int, per_chip_batch: int,
+                   alpha: float = 1e-6) -> None:
+    import jax
+
+    from flexflow_tpu import AdamOptimizer, FFModel
+    from flexflow_tpu.models.nlp import build_bert
+    batch = per_chip_batch * len(jax.devices())
+    ff = FFModel(_config(batch))
+    out = build_bert(ff, batch, seq, bert_cfg)
+    # (alpha: a post-LN stack from random weights with no warm-up —
+    # Adam's first steps move every weight by alpha whatever its
+    # gradient, and at 1e-5 the eval loss of BERT-large on the chip
+    # rose, 0.79 -> 0.91; PERF.md)
+    _compile(ff, out, AdamOptimizer(alpha=alpha), "A/bert")
+    rng = np.random.default_rng(SEED)
+    x = [rng.integers(0, bert_cfg.vocab_size, (batch, seq)).astype(np.int32),
+         np.tile(np.arange(seq, dtype=np.int32), (batch, 1))]
+    y = rng.integers(0, bert_cfg.num_labels, (batch, 1)).astype(np.int32)
+    _fit(ff, x, y, "A/bert", dropout=bert_cfg.dropout > 0)
+    _check_step_program(ff, x, y, "A/bert", want_custom_call=False)
+    say(f"A/bert: per-chip batch {per_chip_batch} (global {batch}), "
+        f"seq {seq}, {bert_cfg.num_layers} layers x "
+        f"{bert_cfg.hidden_size}, peak_bytes_in_use {_peak_bytes()}")
+
+
+# ----------------------------------------------------------------------
+# Leg B — the kernels on the same path
+# ----------------------------------------------------------------------
+def _gpt2(gpt_cfg, seq: int, per_chip_batch: int, kernel_impls: str):
+    """(model, output, x, y, ids): GPT-2 and its one fixed batch."""
+    import jax
+
+    from flexflow_tpu import FFModel
+    from flexflow_tpu.models.nlp import build_gpt2
+    batch = per_chip_batch * len(jax.devices())
+    rng = np.random.default_rng(SEED)
+    ids = rng.integers(0, gpt_cfg.vocab_size, (batch, seq)).astype(np.int32)
+    x = [ids, np.tile(np.arange(seq, dtype=np.int32), (batch, 1))]
+    y = np.roll(ids, -1, axis=1)[..., None]        # next token
+    ff = FFModel(_config(batch, kernel_impls))
+    return ff, build_gpt2(ff, batch, seq, gpt_cfg), x, y, ids
+
+
+def leg_gpt2_kernels(gpt_cfg, seq: int, per_chip_batch: int) -> int:
+    """B1: nothing forced — the executor resolves attention by itself.
+    Returns the number of Mosaic calls in the compiled train step."""
+    import jax
+
+    from flexflow_tpu import AdamOptimizer
+    chip = jax.devices()[0].platform != "cpu"
+    ff, out, x, y, ids = _gpt2(gpt_cfg, seq, per_chip_batch, "auto")
+    _compile(ff, out, AdamOptimizer(alpha=3e-5), "B/gpt2")
+    _fit(ff, x, y, "B/gpt2")
+    impls = sorted(set(ff.executor.resolved_attention_impls.values()))
+    say(f"B/gpt2: resolved attention impl {impls}, kernel plan "
+        f"{ff.strategy.kernel_impls or 'none'}")
+    if chip:
+        check(impls == ["flash"],
+              f"B/gpt2: attention resolved to {impls}, not flash, at "
+              f"seq {seq}")
+    n_flash = _check_step_program(ff, x, y, "B/gpt2",
+                                  want_custom_call=chip)
+    _check_generate(ff, ids)
+    _check_fused_adam(ff)
+    say(f"B/gpt2: per-chip batch {per_chip_batch} (global "
+        f"{ids.shape[0]}), seq {seq}, peak_bytes_in_use {_peak_bytes()}")
+    return n_flash
+
+
+def leg_gpt2_fused_step(gpt_cfg, seq: int, per_chip_batch: int,
+                        n_flash: int) -> None:
+    """B2: the same model with the fused optimizer kernel in the step."""
+    import jax
+
+    from flexflow_tpu import AdamOptimizer
+    chip = jax.devices()[0].platform != "cpu"
+    ff, out, x, y, _ = _gpt2(gpt_cfg, seq, per_chip_batch,
+                             "opt_update:fused")
+    _compile(ff, out, AdamOptimizer(alpha=3e-5), "B/gpt2+fused")
+    check(ff.executor._kernel_impls.get("opt_update") == "fused",
+          "B/gpt2+fused: the executor did not adopt opt_update:fused")
+    _fit(ff, x, y, "B/gpt2+fused")
+    n_fused = _check_step_program(ff, x, y, "B/gpt2+fused",
+                                  want_custom_call=chip)
+    if chip:
+        check(n_fused > n_flash,
+              f"B/gpt2+fused: {n_fused} Mosaic kernels in the step, no "
+              f"more than the {n_flash} without the fused update — the "
+              f"optimizer kernel is not in the compiled step")
+
+
+def _check_generate(ff, ids) -> None:
+    """KV-cache decode against the re-forward path on one prompt.
+
+    The two paths run different attention programs (flash prefill plus
+    cached decode steps against a full flash forward per token), so
+    their logits agree to rounding, not to the bit. With random weights
+    the best two of 50k logits can be closer than that; a token may
+    therefore differ only where the reference's own scores call the two
+    candidates a tie."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.obs.metrics_registry import REGISTRY
+    L = ids.shape[1]
+    prompt = np.array(ids)
+    prompt[:, PROMPT_LEN:] = 0
+    fallbacks = REGISTRY.counter("ff_kv_fallback_total")
+    before = fallbacks.value(model="<unnamed>")
+    t0 = time.perf_counter()
+    kv = np.asarray(ff.generate(prompt, PROMPT_LEN, NEW_TOKENS,
+                                kv_cache=True))
+    t1 = time.perf_counter()
+    ref = np.asarray(ff.generate(prompt, PROMPT_LEN, NEW_TOKENS,
+                                 kv_cache=False))
+    t2 = time.perf_counter()
+    check(fallbacks.value(model="<unnamed>") == before,
+          "B/generate: the KV path fell back to re-forward")
+    end = PROMPT_LEN + NEW_TOKENS
+    check((kv[:, :PROMPT_LEN] == prompt[:, :PROMPT_LEN]).all()
+          and (ref[:, :PROMPT_LEN] == prompt[:, :PROMPT_LEN]).all(),
+          "B/generate: the prompt was not preserved")
+    rows = np.flatnonzero((kv[:, :end] != ref[:, :end]).any(axis=1))
+    ties = 0
+    if rows.size:
+        pos = jnp.tile(jnp.arange(L, dtype=jnp.int32)[None],
+                       (ids.shape[0], 1))
+        scores = np.asarray(jax.jit(ff.executor.scored_forward)(
+            ff.params, ff.state,
+            {"input_ids": jnp.asarray(ref), "position_ids": pos}),
+            np.float32)
+        for r in rows:
+            p = int(np.flatnonzero(kv[r, :end] != ref[r, :end])[0])
+            row = scores[r, p - 1]       # both paths share tokens < p
+            gap = float(row[ref[r, p]] - row[kv[r, p]])
+            check(abs(gap) <= 2e-2 * float(row.std()),
+                  f"B/generate: row {r} token {p}: KV path chose "
+                  f"{kv[r, p]}, re-forward {ref[r, p]}, and the scores "
+                  f"differ by {gap:.4g} (std {row.std():.4g}) — not a "
+                  f"tie")
+            ties += 1
+    say(f"B/generate: {NEW_TOKENS} tokens after a {PROMPT_LEN}-token "
+        f"prompt, batch {ids.shape[0]}: KV path {t1 - t0:.1f}s, "
+        f"re-forward {t2 - t1:.1f}s (both include their compiles); "
+        f"{ids.shape[0] - rows.size}/{ids.shape[0]} rows identical, "
+        f"{ties} diverged at a numerical tie")
+
+
+def _check_fused_adam(ff) -> None:
+    """The fused Adam kernel against ``AdamOptimizer.update`` on the
+    model's own parameter leaves and shardings, mid-training moments."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu import AdamOptimizer
+    from flexflow_tpu.runtime.optimizers import fused_adam_tree_update
+    opt = AdamOptimizer(alpha=1e-3, weight_decay=0.01)
+    ex = ff.executor
+    params = ff.params
+    grads = jax.tree.map(lambda w: jnp.sin(w * 37.0) * 1e-2, params)
+    state = {"m": jax.tree.map(lambda g: 0.3 * g, grads),
+             "v": jax.tree.map(lambda g: 0.5 * g * g + 1e-9, grads)}
+    step = jnp.int32(7)
+    want = jax.jit(opt.update)(params, grads, state, step)
+    got = jax.jit(lambda p, g, s, t: fused_adam_tree_update(
+        opt, p, g, s, t, mesh=ex.dmesh.mesh,
+        param_specs=ex._param_specs))(params, grads, state, step)
+    worst = 0.0
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        err = float(np.max(np.abs(a - b) / (np.abs(b) + 1e-6)))
+        worst = max(worst, err)
+        check(err <= 1e-5,
+              f"B/fused-adam: {jax.tree_util.keystr(path)} differs from "
+              f"AdamOptimizer.update by {err:.3g} (relative)")
+    say(f"B/fused-adam: {len(jax.tree.leaves(params))} leaves match "
+        f"AdamOptimizer.update, worst relative difference {worst:.2g}")
+
+
+# ----------------------------------------------------------------------
+def main() -> int:
+    import jax
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        print(f"chip_smoke: JAX found no TPU (platform "
+              f"{devs[0].platform!r}); refusing to run", file=sys.stderr)
+        return 2
+    t0 = time.perf_counter()
+    from flexflow_tpu import MachineSpec, native
+    from flexflow_tpu.models.nlp import BertConfig, GPTConfig
+    from flexflow_tpu.utils.compilation_cache import (
+        cache_entries, enable_compilation_cache)
+    cache = enable_compilation_cache()
+    before = cache_entries(cache)
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    say(f"device {device}, generation "
+        f"{MachineSpec.detect().generation}, native runtime "
+        f"{native.available()}, jax {jax.__version__}")
+    say(f"compile cache {cache}: {len(before)} entries before")
+    try:
+        leg_bert_train(BertConfig(), 512, BERT_PER_CHIP_BATCH)
+        # (B1's model is gone by now: one training state at a time)
+        n_flash = leg_gpt2_kernels(GPTConfig(), 1024, GPT_PER_CHIP_BATCH)
+        leg_gpt2_fused_step(GPTConfig(), 1024, GPT_PER_CHIP_BATCH, n_flash)
+    except SmokeFailure as e:
+        print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
+        return 1
+    added = sorted(cache_entries(cache) - before)
+    say(f"compile cache {cache}: {len(before) + len(added)} entries "
+        f"after ({len(added)} added"
+        + (f": {[n[:48] for n in added]}" if 0 < len(added) <= 8 else "")
+        + ")")
+    say(f"all legs passed in {time.perf_counter() - t0:.0f}s")
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

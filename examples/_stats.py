@@ -2,8 +2,8 @@
 
 Deliberately imports nothing beyond the stdlib: the sweep parents
 (osdi22ae/run_all.py, tpu_fidelity.py) isolate framework/jax failures in
-per-model subprocesses, so the parent must stay importable even when the
-framework (or the ambient TPU plugin) is broken.
+per-model subprocesses, and a chip belongs to one process at a time, so
+the parent imports neither the framework nor JAX.
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@
 The CI tier runs the kernels in Pallas interpret mode on CPU
 (`tests/test_kernels.py`); this script is the compiled-on-TPU
 counterpart: Mosaic lowering, MXU-precision numerics, and the
-counter-based in-kernel dropout running compiled. (The round-4 run of
+counter-based in-kernel dropout running compiled. (An earlier run of
 this script caught two TPU-only bugs CPU CI cannot see: Mosaic's
 two-word PRNG seed limit, and a per-tile-seeded mask the
 differently-blocked backward could not regenerate.)
@@ -42,7 +42,7 @@ def rel_err(a, b):
 
 def main():
     from flexflow_tpu.utils.compilation_cache import enable_compilation_cache
-    enable_compilation_cache()
+    enable_compilation_cache()   # no FFModel.compile here to do it
     backend = jax.default_backend()
     print(f"backend={backend} devices={jax.devices()}", flush=True)
     if backend != "tpu":
@@ -70,7 +70,7 @@ def main():
 
     # -- 1/2: numerics + grads ------------------------------------------
     # f32 covers the padded-seq case too; bf16 covers block-aligned only
-    # (each (dtype, causal, seq) combo is ~2 remote compiles — keep it lean)
+    # (each (dtype, causal, seq) combo is ~2 compiles — keep it lean)
     for dtype, tol_f, tol_g, seqs in (
             (jnp.float32, 1e-2, 2e-2, (512, 393)),
             (jnp.bfloat16, 2e-2, 4e-2, (512,))):

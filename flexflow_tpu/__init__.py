@@ -14,14 +14,6 @@ Quick start::
                ["accuracy"])
     ff.fit(x=images, y=labels, epochs=2)
 """
-from .utils.jax_compat import enable_partitionable_rng
-
-# sharding-invariant random bits BEFORE any model code traces an rng
-# consumer: with the flag off, GSPMD generates different dropout masks
-# for different shardings of the same op (the tp-vs-dp numerics split
-# pinned by tests/test_tp_flag.py::test_tp_flag_matches_dp_numerics)
-enable_partitionable_rng()
-
 from .ffconst import (ActiMode, AggrMode, CompMode, DataType, InitializerType,
                       LossType, MetricsType, OperatorType, ParameterSyncType,
                       PoolType, RegularizerMode)

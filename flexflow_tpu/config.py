@@ -241,9 +241,6 @@ class FFConfig:
     # dispatched ahead of consumption; 0 disables, 1 is the old
     # single-slot double-buffer
     prefetch_batches: int = 2
-    # persistent XLA compilation cache dir; "" = off unless
-    # JAX_COMPILATION_CACHE_DIR is set (see utils/compilation_cache.py)
-    compilation_cache_dir: str = ""
     # DEPRECATED tri-state (kept as a shim over the kernel tier): "true"
     # forces attention:flash, "false" forces attention:xla, "auto" defers
     # to the searched kernel_impls dimension (kernels/registry.py emits a
@@ -530,8 +527,6 @@ class FFConfig:
                 cfg.serving_strategy_file = take()
             elif a == "--serving-floor-guard":
                 cfg.serving_floor_guard = take()
-            elif a == "--compilation-cache-dir":
-                cfg.compilation_cache_dir = take()
             elif a == "--seed":
                 cfg.seed = int(take())
             # unknown flags: skip (reference forwards to Legion)

@@ -41,7 +41,7 @@ from .runtime import losses as losses_mod
 from .runtime import metrics as metrics_mod
 from .runtime.initializers import initialize, initialize_host  # noqa: F401
 from .runtime.optimizers import Optimizer
-from .utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _npdt(dtype) -> "np.dtype":
@@ -172,6 +172,8 @@ class GraphProgram:
             op = get_op_def(layer.op_type)
             ins = [env[t.guid] for t in layer.inputs]
             w = params.get(layer.name, {})
+            ctx.op_sharding = strategy.ops.get(layer.name) \
+                if strategy is not None else None
             outs = op.emit(layer.params, ins, w, ctx, layer.name)
             if len(outs) != len(layer.outputs):
                 raise RuntimeError(
@@ -437,6 +439,11 @@ class Executor:
         # dispatches fused/unfused. Empty = default impls everywhere.
         self._kernel_impls: Dict[str, str] = dict(
             getattr(strategy, "kernel_impls", None) or {})
+        # layer name -> "xla" | "flash" | "ring", written while a step
+        # is traced: the implementation each attention op really emitted
+        self.resolved_attention_impls: Dict[str, str] = {}
+        # PartitionSpec per parameter leaf, set when they materialize
+        self._param_specs = None
         # pipeline region (parallel/pipeline_lowering): pre/post layer
         # split + GPipe lowering of the repeated-block region
         self.pipe = getattr(strategy, "pipeline", None)
@@ -483,6 +490,18 @@ class Executor:
                 self._logits_tensor = prod.inputs[0]
 
     # ------------------------------------------------------------------
+    def set_kernel_impls(self, plan: Dict[str, str]) -> None:
+        """Adopt a kernel plan. An executor the floor guard built has
+        already traced (and run) its steps under the plan it was built
+        with — jit would replay those for the same arguments, and a plan
+        adopted afterwards would silently never run — so a change drops
+        every cached step."""
+        if dict(plan) == self._kernel_impls:
+            return
+        self._kernel_impls = dict(plan)
+        self._train_step = self._eval_step = self._forward_fn = None
+        self.__dict__.pop("_decode_cache", None)
+
     def attach_qsync(self) -> None:
         """(Re)resolve the strategy's quantized-sync plan into an
         executable schedule. FFModel.compile calls this again after
@@ -520,6 +539,8 @@ class Executor:
         psh: Dict[str, Dict[str, Any]] = {}
         ssh: Dict[str, Dict[str, Any]] = {}
         params, state = self._build_params_and_state(seed, psh, ssh)
+        # the fused optimizer kernel runs under shard_map with these
+        self._param_specs = jax.tree.map(lambda sh: sh.spec, psh)
         # placement via the reshard planner's host→device step: sharded
         # leaves hand each device only its own slice instead of staging
         # a full per-device replica (parallel/reshard.place_host)
@@ -1023,6 +1044,7 @@ class Executor:
             ctx.kernel_impls = self._kernel_impls
         ctx.mesh = self.dmesh.mesh
         ctx.seq_axis = self.dmesh.seq_axis
+        ctx.resolved_impls = self.resolved_attention_impls
 
     def _forward(self, params, state, batch, training: bool, step,
                  strategy="__use_own__", shard_index=None):
@@ -1232,8 +1254,12 @@ class Executor:
                 # AdamOptimizer.update, adopted only when the registry
                 # predicate held (TPU backend, adam) at plan time
                 from .runtime.optimizers import fused_adam_tree_update
+                zero = self.opt_state_constraints
                 new_params, new_opt_state = fused_adam_tree_update(
-                    self.optimizer, params, grads, opt_state, step + 1)
+                    self.optimizer, params, grads, opt_state, step + 1,
+                    mesh=self.dmesh.mesh, param_specs=self._param_specs,
+                    state_specs=None if zero is None else jax.tree.map(
+                        lambda sh: sh.spec, zero["m"]))
                 if self.opt_state_constraints is not None:
                     new_opt_state = jax.tree.map(
                         jax.lax.with_sharding_constraint,

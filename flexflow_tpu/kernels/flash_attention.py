@@ -32,14 +32,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._interpret import pallas_interpret
+
 NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def _pad_to(x, mult, axis):
@@ -385,7 +380,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     dropout_seed=None,
                     block_q: int = 512, block_k: int = 512,
                     bwd_block_q: int = 128, bwd_block_k: int = 128,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    mesh=None, spec=None):
     """Tiled flash attention. q: (b, h, sq, d); k, v: (b, h, sk, d).
 
     Pads seq dims to block multiples and head_dim to a multiple of 64
@@ -397,11 +393,23 @@ def flash_attention(q, k, v, *, causal: bool = False,
     Block defaults are measured on v5e (head_dim 64): the forward wants
     large tiles (512x512 — k/v are re-streamed once per q block, so
     bigger q blocks cut HBM traffic); the backward wants small ones
-    (128x128 — its dq/dkv scratch accumulators serialize the grid)."""
+    (128x128 — its dq/dkv scratch accumulators serialize the grid).
+
+    ``mesh`` / ``spec``: inside a multi-device ``jit`` GSPMD cannot
+    partition a Mosaic kernel, so with a mesh of more than one device
+    the call runs under ``shard_map``. ``spec`` is the (b, h, s, d)
+    PartitionSpec of the operands; only its batch and head entries are
+    used — sequence and head_dim stay whole on every device."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = pallas_interpret()
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("dropout_rate > 0 requires dropout_seed")
+    if mesh is not None and mesh.size > 1:
+        return _flash_sharded(
+            q, k, v, mesh, spec, causal=causal, sm_scale=sm_scale,
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            block_q=block_q, block_k=block_k, bwd_block_q=bwd_block_q,
+            bwd_block_k=bwd_block_k, interpret=interpret)
     b, h, sq, d = q.shape
     sk = k.shape[2]
     if causal and sq != sk:
@@ -441,6 +449,29 @@ def flash_attention(q, k, v, *, causal: bool = False,
                seed, sk, sm_scale, causal, block_q, block_k,
                bwd_block_q, bwd_block_k, float(dropout_rate), interpret)
     return o.reshape(b, h, sq_p, d_p)[:, :, :sq, :d]
+
+
+def _flash_sharded(q, k, v, mesh, spec, *, dropout_rate, dropout_seed,
+                   **kw):
+    """:func:`flash_attention` on each device's (batch, head) shard."""
+    from jax.sharding import PartitionSpec as P
+    spec = P(*(tuple(spec or ()) + (None, None))[:2], None, None)
+    axes = tuple(a for e in spec if e is not None
+                 for a in (e if isinstance(e, tuple) else (e,)))
+    seed = jnp.zeros((), jnp.int32) if dropout_seed is None \
+        else jnp.asarray(dropout_seed, jnp.int32).reshape(())
+
+    def local(q_, k_, v_, seed_):
+        if dropout_rate > 0.0 and axes:
+            # the keep mask hashes the LOCAL batch-head index: give each
+            # shard its own seed or every shard drops the same entries
+            seed_ = seed_ + jax.lax.axis_index(axes) * jnp.int32(40503)
+        return flash_attention(q_, k_, v_, dropout_rate=dropout_rate,
+                               dropout_seed=seed_, **kw)
+
+    # check_vma off: pallas_call outputs carry no varying-axes info
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 3 + (P(),),
+                         out_specs=spec, check_vma=False)(q, k, v, seed)
 
 
 def mha_reference(q, k, v, *, causal: bool = False,

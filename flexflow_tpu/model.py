@@ -506,11 +506,9 @@ class FFModel:
         from .obs import events as obs_events
         obs_events.configure(self.config)
         _compile_t0 = time.perf_counter()
-        if self.config.compilation_cache_dir \
-                or os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-            from .utils.compilation_cache import enable_compilation_cache
-            enable_compilation_cache(
-                self.config.compilation_cache_dir or None)
+        # phase -> typed reason, for every compile phase that was
+        # allowed to fail and did (search/optimizer.py note_skip)
+        self._compile_skips: Dict[str, str] = {}
         if optimizer is not None:
             self.optimizer = optimizer
         if self.optimizer is None:
@@ -563,6 +561,10 @@ class FFModel:
                 c.clock_sync("compile")
             except Exception:  # noqa: BLE001 — alignment is best-effort
                 pass
+        # (after the rendezvous: asking for the platform starts the
+        # backend, which jax.distributed must precede)
+        from .utils.compilation_cache import enable_compilation_cache
+        enable_compilation_cache()
         if machine_spec is not None:
             spec = machine_spec
         elif self.config.machine_model_file:
@@ -1051,7 +1053,7 @@ class FFModel:
         if getattr(strat, "kernel_impls", None):
             # imported with the strategy: honor verbatim — the plan
             # verifier re-checks every predicate on this mesh/shapes
-            self.executor._kernel_impls = dict(strat.kernel_impls)
+            self.executor.set_kernel_impls(strat.kernel_impls)
             return
         policy = str(getattr(cfg, "kernel_impls", "auto") or "auto").lower()
         if policy in ("off", "none"):
@@ -1093,8 +1095,9 @@ class FFModel:
                 try:
                     cost_model.attach_calibration(
                         calibrate_mesh(self.dmesh))
-                except Exception:  # noqa: BLE001 — best-effort
-                    pass
+                except Exception as e:  # noqa: BLE001 — analytic terms
+                    from .search.optimizer import note_skip
+                    note_skip(self, "calibration_v2", e)
         searchable = cost_model.calib is not None
         if searchable:
             try:
@@ -1103,8 +1106,9 @@ class FFModel:
                 from .search.calibration import calibrate_kernel_impls
                 calibrate_kernel_impls(self.dmesh,
                                        cost_model.calib.table)
-            except Exception:  # noqa: BLE001 — priced analytically
-                pass
+            except Exception as e:  # noqa: BLE001 — priced analytically
+                from .search.optimizer import note_skip
+                note_skip(self, "kernel_impl_rows", e)
         tier = None
         if self.dmesh.seq_axis:
             tier = self.dmesh.axis_tiers.get(self.dmesh.seq_axis)
@@ -1246,7 +1250,7 @@ class FFModel:
         strat.kernel_impls = plan
         # the executor snapshotted (the then-empty) strategy.kernel_impls
         # at construction — refresh so the jitted step traces the plan
-        self.executor._kernel_impls = dict(plan)
+        self.executor.set_kernel_impls(plan)
         n_nondefault = sum(
             1 for e in audit_ops
             if e["impl"] != kreg.DEFAULT_IMPLS[e["op"]])
@@ -1308,18 +1312,8 @@ class FFModel:
                 is_label = (t is self.label_tensor
                             or t.guid not in gi_guids)
                 arrays["label" if is_label else t.name] = arr
-        shardings = {}
-        for t in graph_inputs:
-            if t.name in arrays:
-                shardings[t.name] = self.strategy.input_sharding(t.name)
-        out_sh = self.strategy.output_sharding(
-            self._output_tensor.owner_layer.name)
-        if out_sh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            ospec = self.strategy.ops[self._output_tensor.owner_layer.name]\
-                .outputs[self._output_tensor.owner_idx]
-            batch_axes = ospec[0] if ospec and len(ospec) > 0 else None
-            shardings["label"] = NamedSharding(self.dmesh.mesh, P(batch_axes))
+        shardings = self.strategy.batch_shardings(graph_inputs,
+                                                  self._output_tensor)
         return SingleDataLoader(arrays, bs, shardings, shuffle=shuffle,
                                 seed=self.config.seed,
                                 prefetch=self.config.prefetch_batches)
@@ -1555,10 +1549,12 @@ class FFModel:
                 return self._generate_kv(ids0, prompt_len, max_new_tokens,
                                          temperature, seed, eos_token_id,
                                          top_k, top_p)
-            except Exception:
+            except Exception as e:
                 if kv_cache is True:
                     raise
                 kv_failed_shapes.add((b, L))
+                self.__dict__.setdefault("_kv_fallback_reasons", {})[
+                    (b, L)] = f"{type(e).__name__}: {e}"[:500]
                 # the fallback is exact but O(L)-per-token — a serving
                 # deployment quietly riding it is a perf regression, so
                 # it is observable (Prometheus + /healthz), not just a

@@ -4,14 +4,18 @@ The reference implements its runtime core in C++ (simulator, dataloader,
 graph machinery — SURVEY.md §2.1/§2.3); this package is the TPU rebuild's
 native layer: ``flexflow_tpu/native/src/ffruntime.cc`` compiled to ``libffruntime.so``.
 
-``ensure_built()`` compiles the library on first use (g++, no external
-deps); every entry point has a pure-Python fallback so the framework works
-even without a toolchain, and the tests assert C++ == Python semantics.
+``ensure_built()`` compiles the library on first use, and again whenever
+the source is newer than the binary (g++, no external deps). The binary
+is never committed, so a checkout always runs what its own source says.
+Every entry point has a pure-Python fallback for hosts WITHOUT a
+toolchain, and the tests assert C++ == Python semantics; with a compiler
+present a failed build is an error, not a reason to fall back.
 """
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional, Sequence, Tuple
@@ -26,28 +30,41 @@ _SRC = os.path.join(_HERE, "src", "ffruntime.cc")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_build_failed = False
+
+
+def _stale() -> bool:
+    return not os.path.exists(_SO) or (
+        os.path.exists(_SRC)
+        and os.path.getmtime(_SRC) > os.path.getmtime(_SO))
 
 
 def ensure_built(force: bool = False) -> bool:
-    """Compile libffruntime.so if missing. Returns True if available."""
-    global _build_failed
-    if os.path.exists(_SO) and not force:
+    """Compile libffruntime.so if it is missing or older than its
+    source. Returns False when this host cannot build it (no g++, or no
+    source and no binary); raises when g++ is there and the build
+    fails."""
+    if not (force or _stale()):
         return True
-    if _build_failed and not force:
-        return False
     if not os.path.exists(_SRC):
-        _build_failed = True
+        return os.path.exists(_SO)
+    cxx = shutil.which("g++")
+    if cxx is None:
         return False
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-pthread",
-             "-shared", "-o", _SO, _SRC],
-            check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        _build_failed = True
-        return False
+            [cxx, "-O3", "-std=c++17", "-fPIC", "-Wall", "-pthread",
+             "-shared", "-o", tmp, _SRC],
+            check=True, capture_output=True, text=True, timeout=120)
+        os.replace(tmp, _SO)   # atomic: a concurrent loader sees old or new
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building {_SO} failed (g++ exit {e.returncode}):\n"
+            f"{e.stderr[-2000:]}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return True
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -62,10 +79,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return _lib
         if not ensure_built():
             return None
-        try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
-            return None
+        lib = ctypes.CDLL(_SO)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
         f64p = ctypes.POINTER(ctypes.c_double)

@@ -445,11 +445,10 @@ class MultiHeadAttentionOp(OpDef):
             return None
         return plan.get(name, plan.get("attention"))
 
-    # Measured on v5e (BERT-base, head_dim=64, tuned 512x512-fwd /
-    # 128x128-bwd blocks, unpadded d=64): XLA's fused attention still
-    # wins the train step below ~1024 tokens; at 1024 the Pallas kernel
-    # pulls ahead (f+b 124 vs 130 ms) and at 2048 it wins decisively
-    # (166 vs 226 ms) while never materializing the s^2 logits.
+    # "auto" without a kernel plan: the compiled kernel from this
+    # context length up, XLA's fused attention below it. The threshold
+    # is a builder's estimate that has not been timed on the chip under
+    # this JAX (PERF.md, open questions).
     FLASH_AUTO_MIN_SEQ = 1024
 
     @classmethod
@@ -459,9 +458,46 @@ class MultiHeadAttentionOp(OpDef):
             return False
         if mode == "true":
             return True
-        import jax as _jax
-        return _jax.default_backend() == "tpu" \
+        from ..kernels._interpret import pallas_interpret
+        return not pallas_interpret() \
             and seq_len >= cls.FLASH_AUTO_MIN_SEQ
+
+    @staticmethod
+    def _note_impl(ctx, name: str, impl: str) -> None:
+        """Record which implementation this trace emitted for the full
+        (non-KV) forward: ``Executor.resolved_attention_impls`` is what
+        the step really runs, whatever the plan or the switches say."""
+        rec = getattr(ctx, "resolved_impls", None)
+        if rec is not None and ctx.kv_mode is None:
+            rec[name] = impl
+
+    @staticmethod
+    def _kernel_shard_spec(ctx, batch: int, heads: int):
+        """``(mesh, spec)`` for a compiled kernel on (b, h, s, d)
+        operands inside the executor's multi-device jit, read from the
+        op's adopted sharding: the batch axes of output 0 and the head
+        axes of ``wq``. ``(None, None)`` means call the kernel directly
+        — one device, or emission already inside a manual region
+        (pipeline stages, the quantized-sync shard_map)."""
+        mesh = getattr(ctx, "mesh", None)
+        if mesh is None or mesh.size == 1 \
+                or getattr(ctx, "local_shape", False):
+            return None, None
+        from jax.sharding import PartitionSpec as P
+        sh = getattr(ctx, "op_sharding", None)
+        out = sh.outputs[0] if sh is not None and sh.outputs else None
+        wq = sh.weights.get("wq") if sh is not None else None
+
+        def entry(spec, i, dim):
+            e = spec[i] if spec is not None and len(spec) > i else None
+            if e is None:
+                return None
+            deg = 1
+            for a in (e if isinstance(e, tuple) else (e,)):
+                deg *= mesh.shape[a]
+            return e if dim % deg == 0 else None
+
+        return mesh, P(entry(out, 0, batch), entry(wq, 1, heads))
 
     def emit(self, params, inputs, weights, ctx, name):
         q, k, v = inputs
@@ -550,6 +586,7 @@ class MultiHeadAttentionOp(OpDef):
                     f"{name}: kernel impl 'ring' has no in-kernel "
                     f"dropout (the registry predicate rejects it; a "
                     f"forced plan must not bypass the verifier)")
+            self._note_impl(ctx, name, "ring")
             return self._emit_ring(weights, ctx, name, qh, kh, vh, mdt,
                                    cdt, causal)
         # a planned impl overrides the legacy tri-state: "flash" forces
@@ -570,7 +607,6 @@ class MultiHeadAttentionOp(OpDef):
             # In "auto" mode the dropout>0 case stays on XLA (the in-kernel
             # dropout path is opt-in via use_flash_attention="true").
             from ..kernels import flash_attention
-            on_tpu = jax.default_backend() == "tpu"
             if rate > 0.0 and flash_mode != "true":
                 pass  # fall through to the XLA path below
             else:
@@ -578,13 +614,16 @@ class MultiHeadAttentionOp(OpDef):
                 if rate > 0.0:
                     seed = jax.random.randint(ctx.rng_for(name), (),
                                               0, 2 ** 31 - 1, jnp.int32)
+                self._note_impl(ctx, name, "flash")
+                mesh, spec = self._kernel_shard_spec(
+                    ctx, qh.shape[0], qh.shape[2])
                 o = flash_attention(
                     jnp.swapaxes(qh, 1, 2).astype(mdt),
                     jnp.swapaxes(kh, 1, 2).astype(mdt),
                     jnp.swapaxes(vh, 1, 2).astype(mdt),
                     causal=causal,
                     dropout_rate=rate, dropout_seed=seed,
-                    interpret=None if on_tpu else True)
+                    mesh=mesh, spec=spec)
                 ctxv = jnp.swapaxes(o, 1, 2).astype(jnp.float32)
                 out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(mdt),
                                  weights["wo"].astype(mdt),
@@ -593,6 +632,7 @@ class MultiHeadAttentionOp(OpDef):
                     out = out + weights["bo"].astype(jnp.float32)
                 return [out.astype(cdt)]
 
+        self._note_impl(ctx, name, "xla")
         scale = 1.0 / math.sqrt(qh.shape[-1])
         logits = jnp.einsum("bqhd,bkhd->bhqk", qh.astype(mdt),
                             kh.astype(mdt),
@@ -645,7 +685,7 @@ class MultiHeadAttentionOp(OpDef):
         residency drops by the seq degree — the 1/deg envelope the
         plan verifier accounts (docs/kernels.md)."""
         from ..kernels import ring_attention
-        from ..utils.jax_compat import shard_map
+        from jax import shard_map
         mesh = getattr(ctx, "mesh", None)
         ax = getattr(ctx, "seq_axis", None)
         if mesh is None or ax is None:

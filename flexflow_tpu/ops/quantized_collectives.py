@@ -716,7 +716,7 @@ def sharded_grads(executor, params, state, batch, step, residual):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from ..runtime import metrics as metrics_mod
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
     sched: QsyncSchedule = executor._qsync
     axes = sched.axes
     sizes = sched.sizes

@@ -62,6 +62,13 @@ class EmitCtx:
         self.kernel_impls: Optional[Dict[str, str]] = None
         self.mesh = None                  # jax.sharding.Mesh
         self.seq_axis: Optional[str] = None
+        # the adopted OpSharding of the op being emitted (set per layer
+        # by GraphProgram.emit_layers): a compiled Pallas kernel must
+        # run under shard_map with these specs, because GSPMD cannot
+        # partition a Mosaic call
+        self.op_sharding = None
+        # layer name -> emitted attention impl, shared with the executor
+        self.resolved_impls: Optional[Dict[str, str]] = None
 
     def rng_for(self, name: str):
         return self.rngs.get(name)

@@ -72,17 +72,12 @@ def _enable_cpu_collectives() -> None:
     aren't implemented on the CPU backend". Must run before the CPU
     client is created — maybe_initialize calls it right before
     ``jax.distributed.initialize`` (which has the same constraint).
-    TPU/GPU backends ignore the option; jax versions without the flag
-    (or with gloo compiled out) just proceed."""
+    TPU/GPU backends ignore the option."""
     import jax
     impl = os.environ.get("FF_CPU_COLLECTIVES", "gloo")
     if not impl or impl == "none":
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-    except Exception:  # pragma: no cover - old jax or no gloo build
-        log.warning("distributed: could not enable CPU collectives "
-                    "(%s); multi-process CPU worlds will not work", impl)
+    jax.config.update("jax_cpu_collectives_implementation", impl)
 
 
 def maybe_initialize(config=None) -> bool:

@@ -184,17 +184,16 @@ class MachineSpec:
                 break
         if devices[0].platform == "cpu":
             gen = "cpu-sim"
-        log = logging.getLogger("flexflow_tpu")
         if gen is None:
-            gen = "v5e"
-            log.warning(
-                "MachineSpec.detect: unknown device kind %r (platform %r); "
-                "defaulting cost-model constants to %s — pass an explicit "
-                "MachineSpec or a machine-model file if this is wrong",
-                devices[0].device_kind, devices[0].platform, gen)
-        else:
-            log.info("MachineSpec.detect: %d x %s (device_kind=%r)",
-                     len(devices), gen, devices[0].device_kind)
+            raise ValueError(
+                f"MachineSpec.detect: device kind "
+                f"{devices[0].device_kind!r} (platform "
+                f"{devices[0].platform!r}) is not in TPU_GENERATIONS "
+                f"{sorted(TPU_GENERATIONS)}; its peaks are unknown, so "
+                f"pass an explicit MachineSpec or --machine-model-file")
+        logging.getLogger("flexflow_tpu").info(
+            "MachineSpec.detect: %d x %s (device_kind=%r)",
+            len(devices), gen, devices[0].device_kind)
         # each controller process hosts one DCN island (a slice, or a
         # CPU-sim process); ICI never spans jax processes in this model
         n_proc = jax.process_count()

@@ -28,7 +28,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _squeeze_stage(params):

@@ -646,7 +646,7 @@ class ReshardPlanner:
         transposes under shard_map)."""
         import jax
         from jax.sharding import NamedSharding
-        from ..utils.jax_compat import shard_map
+        from jax import shard_map
         mesh = self.dmesh.mesh
         dst_P = _to_partition_spec(plan.dst)
         nbytes = float(getattr(x, "size", 0) or 0) * \

@@ -134,6 +134,25 @@ class ShardingStrategy:
     def replicated(self) -> NamedSharding:
         return NamedSharding(self.dmesh.mesh, P())
 
+    def batch_shardings(self, graph_inputs, output_tensor
+                        ) -> Dict[str, NamedSharding]:
+        """Where one fed batch lives: every graph input on its planned
+        sharding, and ``"label"`` on the batch axes of the final op's
+        output — or replicated over the whole mesh when the plan leaves
+        that op unsharded (never on device 0 alone, for the step to
+        broadcast each time). The dataloader and the floor guard both
+        place batches with this, so a step compiled by one is the step
+        the other runs."""
+        out = {t.name: self.input_sharding(t.name) for t in graph_inputs}
+        out["label"] = self.replicated()
+        owner = output_tensor.owner_layer
+        if owner is not None and self.output_sharding(
+                owner.name, output_tensor.owner_idx) is not None:
+            ospec = self.ops[owner.name].outputs[output_tensor.owner_idx]
+            out["label"] = NamedSharding(
+                self.dmesh.mesh, P(ospec[0] if len(ospec) > 0 else None))
+        return out
+
     # ------------------------------------------------------------------
     @classmethod
     def data_parallel(cls, layers, input_tensors, dmesh: DeviceMesh

@@ -3,7 +3,7 @@
 Launched by :class:`.router.FleetRouter.spawn` (or by hand)::
 
     python -m flexflow_tpu.serving.fleet.replica --port 8101 \
-        --model gpt2-tiny --compile-cache /tmp/ffcache
+        --model gpt2-tiny
 
 Builds a model repository, starts the threaded HTTP front
 (``serve_http(block=False)``), and then watches **stdin** for the
@@ -19,8 +19,8 @@ Two model kinds:
   device step) — scheduler/router policy measurement decoupled from
   XLA compile noise; the bench harness's replicas.
 * ``gpt2-tiny``: a real tiny GPT-2 compiled through the persistent
-  XLA compile cache when ``--compile-cache`` is set (``allow_cpu=True``:
-  replicas share one host, where CPU cache reuse is safe), so a
+  XLA compile cache (``FFModel.compile`` enables it; whoever launches
+  the fleet places it with ``JAX_COMPILATION_CACHE_DIR``), so a
   replacement replica comes up warm. ``ff_model_compiles_total`` stays
   the honest witness: a warm start still *counts* its program builds,
   but the cache turns each build into a disk hit — asserted by the
@@ -62,9 +62,6 @@ def _build_repo(args):
                       instances=args.instances)
         return repo
     # gpt2-tiny: a real autoregressive model on the CPU sim mesh
-    if args.compile_cache:
-        from ...utils.compilation_cache import enable_compilation_cache
-        enable_compilation_cache(args.compile_cache, allow_cpu=True)
     from ... import FFConfig, FFModel, SGDOptimizer
     from ...models.nlp import GPTConfig, build_gpt2
     cfg = FFConfig()
@@ -99,9 +96,6 @@ def main(argv=None) -> int:
     p.add_argument("--bucket", type=int, default=4)
     p.add_argument("--seq-len", type=int, default=32)
     p.add_argument("--decode-segment", type=int, default=4)
-    p.add_argument("--compile-cache", default=None,
-                   help="persistent XLA compile-cache dir (shared "
-                        "across replicas: replacements start warm)")
     p.add_argument("--max-batch", type=int, default=4)
     p.add_argument("--max-delay-ms", type=float, default=2.0)
     p.add_argument("--max-queue", type=int, default=256)

@@ -446,7 +446,10 @@ def build_serving_plan_session(serving_strategy_file: str, build,
                 records[b] = {"searched_s": t_s, "baseline_s": t_b,
                               "adopted": adopted}
         except Exception as e:  # noqa: BLE001 — guard never kills a load
-            records = {"skipped": repr(e)[:200]}
+            records = {"skipped": f"{type(e).__name__}: {e}"[:500]}
+            import logging
+            logging.getLogger("flexflow_tpu").warning(
+                "serving floor guard skipped: %s", records["skipped"])
         obs_events.record_span(
             "serving.floor_guard", t0, time.perf_counter() - t0,
             buckets=len(bks))
@@ -619,14 +622,12 @@ class ModelRepository:
                 # clear any import the caller's config carried, or the
                 # instance would silently adopt that strategy instead
                 cfg.import_strategy_file = ""
-            # warm start: every repository load opts into the
-            # persistent compilation cache, so a fresh serving process
-            # re-loading the same model hits disk instead of re-paying
-            # XLA (the helper's own guard skips bare-CPU backends,
-            # where AOT reload risks SIGILL). Recompiles stay visible
+            # warm start: every repository load compiles through the
+            # persistent cache (placed by utils/compilation_cache.py),
+            # so a fresh serving process re-loading the same model hits
+            # disk instead of re-paying XLA. Recompiles stay visible
             # through ff_model_compiles_total{model=...}.
-            enable_compilation_cache(
-                getattr(cfg, "compilation_cache_dir", "") or None)
+            enable_compilation_cache()
             ff = FFModel(cfg)
             ff._model_name = name   # labels compile/fallback counters
             out = graph_build(ff)

@@ -1,3 +1,2 @@
-from .jax_compat import shard_map
 from .logger import RecursiveLogger
 from .profiling import Profiler, profile_region

@@ -1,10 +1,10 @@
-"""bench.py orchestration: the TPU re-probe-after-fallback path.
+"""bench.py orchestration: no accelerator, no headline.
 
-Round-2 postmortem: one failed 240s probe committed the whole round to
-CPU numbers while the chip recovered mid-day. These tests drive
-``bench.main()`` with a scripted ``_run_stage`` to prove the bench
-returns to the real platform before the searched A/B (stage 4.5), and
-stays on CPU when the re-probe also fails.
+The per-chip metric is a device number. These tests drive
+``bench.main()`` with a scripted ``_run_stage`` to prove that the bench
+reports it only from a run on an accelerator, exits non-zero when it
+finds none or a chip stage fails, and still carries the stages that say
+they are the virtual CPU mesh.
 """
 import json
 
@@ -25,9 +25,6 @@ def _scripted(default_probe_results):
         calls.append((tuple(args), "cpu" if on_cpu else "default"))
         stage = args[1]
         if stage == "probe":
-            if on_cpu:
-                return {"platform": "cpu", "n": 1,
-                        "device_kind": "cpu"}, None
             n_def = sum(1 for a, e in calls
                         if a[1] == "probe" and e == "default")
             res = default_probe_results[min(n_def - 1,
@@ -37,10 +34,6 @@ def _scripted(default_probe_results):
             return {"smoke_s": 0.1}, None
         if stage == "bert":
             searched = "--searched" in args
-            if on_cpu:
-                return {"sps": 1.8 if searched else 2.0, "mfu": 0.01,
-                        "flops_per_step": 1.0, "n_chips": 1,
-                        "search_time_s": 1.0, "generation": "cpu"}, None
             return {"sps": 950.0 if searched else 900.0, "mfu": 0.31,
                     "flops_per_step": 1.0, "n_chips": 1,
                     "search_time_s": 30.0, "generation": "v5e"}, None
@@ -180,56 +173,82 @@ def _scripted(default_probe_results):
     return fake_run_stage, calls
 
 
-def _run_main(monkeypatch, capsys, probe_results):
-    fake, calls = _scripted(probe_results)
-    monkeypatch.setattr(bench, "_run_stage", fake)
+def _run_main(monkeypatch, capsys, probe_results, fake=None):
+    scripted, calls = _scripted(probe_results)
+    monkeypatch.setattr(bench, "_run_stage", fake or scripted)
     monkeypatch.setattr(bench.subprocess, "Popen", _popen_raises)
     monkeypatch.setenv("BENCH_DEADLINE_S", "1200")
-    bench.main()
+    rc = 0
+    try:
+        bench.main()
+    except SystemExit as e:
+        rc = e.code
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    return out, calls
+    return out, calls, rc
 
 
-def test_reprobe_recovers_tpu(monkeypatch, capsys):
-    # probe 1 wedges -> CPU fallback; re-probe before stage 5 finds the
-    # chip back -> DP leg re-measured there, A/B runs there
-    tpu = {"platform": "tpu", "n": 1, "device_kind": "v5e"}
-    out, calls = _run_main(monkeypatch, capsys, [None, tpu])
+TPU = {"platform": "tpu", "n": 1, "device_kind": "TPU v5 lite"}
+_PER_CHIP = ("metric", "value", "unit", "vs_baseline", "dp_sps",
+             "searched_sps", "mfu")
+
+
+def test_headline_comes_from_the_chip(monkeypatch, capsys):
+    out, calls, rc = _run_main(monkeypatch, capsys, [TPU])
+    # (the only scripted failure is the disabled northstar subprocess)
+    assert rc == 0 and out["error"].startswith("northstar:")
     assert out["platform"] == "tpu"
-    assert out["reprobe"] == "recovered"
+    assert out["metric"] == bench.METRIC
     assert out["dp_sps"] == 900.0
     assert out["searched_sps"] == 950.0
     assert out["value"] == 950.0
     assert out["vs_baseline"] == round(950.0 / 900.0, 4)
-    # the searched leg ran on the default platform, not the cpu env
-    searched_calls = [e for a, e in calls if "--searched" in a]
-    assert searched_calls == ["default"]
+    # one probe, and no chip stage was ever sent to the cpu platform
+    assert len([a for a, _ in calls if a[1] == "probe"]) == 1
+    assert all(e == "default" for a, e in calls
+               if a[1] in ("probe", "smoke", "bert"))
 
 
-def test_reprobe_failure_stays_on_cpu(monkeypatch, capsys):
-    out, _ = _run_main(monkeypatch, capsys, [None, None])
+def test_failed_probe_exits_nonzero_without_per_chip_value(monkeypatch,
+                                                           capsys):
+    out, calls, rc = _run_main(monkeypatch, capsys, [None])
+    assert rc not in (0, None)
+    assert out["error"].startswith("probe: ")
+    assert not any(k in out for k in _PER_CHIP)
+    assert not any(a[1] in ("smoke", "bert") for a, _ in calls)
+
+
+def test_cpu_only_machine_exits_nonzero_without_per_chip_value(
+        monkeypatch, capsys):
+    cpu = {"platform": "cpu", "n": 1, "device_kind": "cpu"}
+    out, calls, rc = _run_main(monkeypatch, capsys, [cpu])
+    assert rc not in (0, None)
     assert out["platform"] == "cpu"
-    assert "reprobe" not in out
-    assert out["dp_sps"] == 2.0
-    assert out["searched_sps"] == 1.8
-    assert "reprobe" in out.get("error", "")
+    assert "no accelerator" in out["error"]
+    assert not any(k in out for k in _PER_CHIP)
+    # nothing was timed on the CPU under the flagship's name
+    assert not any(a[1] in ("smoke", "bert") for a, _ in calls)
 
 
-def test_tpu_first_try_skips_reprobe(monkeypatch, capsys):
-    tpu = {"platform": "tpu", "n": 1, "device_kind": "v5e"}
-    out, calls = _run_main(monkeypatch, capsys, [tpu])
-    assert out["platform"] == "tpu"
-    assert "reprobe" not in out
-    probes = [a for a, _ in calls if a[1] == "probe"]
-    assert len(probes) == 1
+def test_failed_chip_stage_exits_nonzero(monkeypatch, capsys):
+    scripted, _ = _scripted([TPU])
+
+    def fake(args, timeout, env=None):
+        if args[1] == "bert" and "--searched" in args:
+            return None, "rc=1: RESOURCE_EXHAUSTED"
+        return scripted(args, timeout, env)
+
+    out, _, rc = _run_main(monkeypatch, capsys, [TPU], fake=fake)
+    assert rc not in (0, None)
+    assert "bert(searched)" in out["error"]
+    assert "value" not in out and "metric" not in out
+    assert out["dp_sps"] == 900.0      # measured on the chip: kept
 
 
 def test_virtual_leg_fields_always_present(monkeypatch, capsys):
     """The 8-virtual-device searched-vs-DP + fidelity leg runs whatever
     the headline platform is, and its fields reach the driver JSON."""
-    for probes in ([{"platform": "tpu", "n": 1, "device_kind": "v5e"}],
-                   [None, None]):
-        out, calls = _run_main(monkeypatch, capsys, probes)
+    for probes in ([TPU], [None]):
+        out, calls, _ = _run_main(monkeypatch, capsys, probes)
         assert out["virtual_searched_vs_dp"] == 2.5
         assert out["virtual_fidelity_spearman"] == 0.7
         assert out["virtual_fidelity_rows"] == 8
@@ -293,8 +312,7 @@ def test_long_context_row_folds_into_fidelity(monkeypatch, capsys):
     """When the virtual leg carries scored rows, the long-context
     kernel-choice row joins them and the spearman is recomputed over
     the combined set (concordant ranks here -> stays 1.0 at 4 rows)."""
-    tpu = {"platform": "tpu", "n": 1, "device_kind": "v5e"}
-    fake, calls = _scripted([tpu])
+    fake, calls = _scripted([TPU])
     rows = [{"workload": "mlp", "ranker": "tasksim",
              "predicted": 1.2, "measured": 1.1},
             {"workload": "dlrm", "ranker": "tasksim",
@@ -309,10 +327,6 @@ def test_long_context_row_folds_into_fidelity(monkeypatch, capsys):
                     "rows": rows}, None
         return fake(args, timeout, env)
 
-    monkeypatch.setattr(bench, "_run_stage", fake2)
-    monkeypatch.setattr(bench.subprocess, "Popen", _popen_raises)
-    monkeypatch.setenv("BENCH_DEADLINE_S", "1200")
-    bench.main()
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    out, _, _ = _run_main(monkeypatch, capsys, [TPU], fake=fake2)
     assert out["virtual_fidelity_rows"] == 4
     assert out["virtual_fidelity_spearman"] == 1.0

@@ -1,0 +1,77 @@
+"""chip_smoke.py's legs at tiny widths on the CPU mesh.
+
+The smoke itself only means something on a TPU, and chip time is too
+scarce to debug its plumbing there: these tests run the same functions
+(compile with the search on, fit, the placement and program checks,
+KV-cache generation against re-forward, fused Adam against the plain
+update) on the 8-virtual-device CPU mesh, where the chip-only checks are
+off and the Pallas kernels run in interpret mode.
+"""
+import dataclasses
+import os
+import subprocess
+import sys
+
+import pytest
+
+import chip_smoke
+from flexflow_tpu.models.nlp import BertConfig, GPTConfig
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _small_run(monkeypatch):
+    monkeypatch.setattr(chip_smoke, "SEARCH_BUDGET", 2)
+    monkeypatch.setattr(chip_smoke, "TRAIN_STEPS", 2)
+    monkeypatch.setattr(chip_smoke, "PROMPT_LEN", 8)
+    monkeypatch.setattr(chip_smoke, "NEW_TOKENS", 4)
+
+
+def _one_layer(cfg):
+    """Tier-1 pays for compiles, not for depth."""
+    return dataclasses.replace(cfg, num_layers=1)
+
+
+def test_leg_a_bert_tiny_on_the_cpu_mesh(capsys):
+    # (BERT-large's step size moves a 64-wide model nowhere in 3 steps)
+    chip_smoke.leg_bert_train(_one_layer(BertConfig.tiny()), seq=16,
+                              per_chip_batch=1, alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "A/bert: mesh {'x0': 2, 'x1': 2, 'x2': 2}" in out
+    assert "floor guard {'skipped': " in out       # cpu: says so
+    assert "collectives ['all-reduce'" in out
+
+
+def test_leg_b_gpt2_tiny_on_the_cpu_mesh(capsys):
+    """(Its second half, the fused update inside the step, is TPU-only
+    by predicate; tests/test_kernel_tier.py drives that path on the CPU
+    mesh through the executor.)"""
+    n = chip_smoke.leg_gpt2_kernels(_one_layer(GPTConfig.tiny()), seq=16,
+                                    per_chip_batch=1)
+    out = capsys.readouterr().out
+    assert n == 0                                   # cpu: interpret mode
+    assert "B/gpt2: resolved attention impl ['xla']" in out   # cpu: auto
+    assert "B/generate: 4 tokens after a 8-token prompt" in out
+    assert "B/fused-adam: " in out and "leaves match" in out
+
+
+def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):
+    class Stuck:
+        def fit(self, **kw):
+            return [{"loss": 0.7, "epoch_time_s": 0.0}] * 3
+
+    with pytest.raises(chip_smoke.SmokeFailure, match="did not fall"):
+        chip_smoke._fit(Stuck(), None, None, "t")
+
+
+def test_main_refuses_to_run_without_a_tpu():
+    """On the CPU platform it exits non-zero before building a model and
+    prints no result line."""
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode not in (0, None)
+    assert "no TPU" in r.stderr
+    assert r.stdout.strip() == ""

@@ -77,6 +77,22 @@ def test_measured_matches_analytic_ordering(tmp_path):
     assert measured[0] < measured[-1], (analytic, measured)
 
 
+def test_sharded_attention_is_measurable(tmp_path):
+    """Found on four chips, once measurement failures stopped being
+    swallowed: a weight-sharded attention op was benchmarked with its
+    head_dim halved instead of its heads, and every such microbenchmark
+    raised (``Size of label 'd' ... (32) does not match ... (64)``)."""
+    attn = [l for l in _layers_by_cost()
+            if l.op_type == OperatorType.OP_MULTIHEAD_ATTENTION][0]
+    model = OpCostModel(MachineSpec.detect(), cache_dir=str(tmp_path))
+    for wdeg, degrees in ((2, {}), (1, {2: 2})):
+        cm = model.measure(attn, degrees, weight_shard_degree=wdeg,
+                           warmup=1, repeats=1)
+        assert cm is not None, model.measure_failures
+        assert cm.forward_time > 0
+    assert not model.measure_failures
+
+
 def test_disk_cache_roundtrip(tmp_path):
     spec = MachineSpec.detect()
     layers = _layers_by_cost()

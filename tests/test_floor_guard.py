@@ -71,10 +71,94 @@ def test_guard_adopts_searched_when_it_wins(monkeypatch):
     assert ff._floor_guard_record["adopted"] == "searched"
 
 
+def test_margin_inside_the_noise_keeps_the_floor(monkeypatch):
+    """Searched 'faster' by less than the timing noise is no measured
+    win: data parallel stays, reproducibly."""
+    sides = iter([[0.4989, 0.4999, 0.5009] * 4,    # searched: 0.1 ms less
+                  [0.4990, 0.5000, 0.5010] * 4])   # data parallel
+
+    def fake_time(ff, strategy, info):
+        times = next(sides)         # 12 steps each: nothing left to extend
+        return sum(times) / len(times), None, times, None
+
+    monkeypatch.setattr(opt_mod, "_time_strategy", fake_time)
+    ff = _searched_model(floor_guard="true")
+    rec = ff._floor_guard_record
+    assert rec["adopted"] == "dp" and rec["unresolved"]
+
+
 def test_guard_off_by_default_on_cpu():
-    """auto mode: CPU simulator runs skip the double-compile."""
+    """auto mode: CPU simulator runs skip the double-compile, and the
+    record says that the guard did not run and why."""
     ff = _searched_model(floor_guard="auto")
-    assert not hasattr(ff, "_floor_guard_record")
+    assert "cpu" in ff._floor_guard_record["skipped"]
+
+
+def test_guard_failure_is_recorded_not_swallowed(monkeypatch):
+    """A guard that cannot even time data parallel never kills the
+    compile, but the model says that the searched plan was adopted
+    unguarded, with the typed cause."""
+    def boom(ff, strategy, info):
+        raise MemoryError("RESOURCE_EXHAUSTED: out of HBM")
+
+    monkeypatch.setattr(opt_mod, "_time_strategy", boom)
+    ff = _searched_model(floor_guard="true")
+    assert ff._floor_guard_record["skipped"].startswith("MemoryError:")
+    assert "floor_guard" in ff._compile_skips
+
+
+def test_searched_plan_that_does_not_run_loses_to_the_floor(monkeypatch):
+    """A searched program that raises on its first steps (does not fit,
+    does not compile) is not adopted unguarded: data parallel is."""
+    real = opt_mod._time_strategy
+
+    def searched_ooms(ff, strategy, info):
+        if info is not None or strategy.ops != \
+                opt_mod.ShardingStrategy.data_parallel(
+                    ff.layers, ff.graph_inputs, ff.dmesh).ops:
+            raise MemoryError("RESOURCE_EXHAUSTED: out of HBM")
+        return real(ff, strategy, info)
+
+    monkeypatch.setattr(opt_mod, "_time_strategy", searched_ooms)
+    ff = _searched_model(floor_guard="true")
+    rec = ff._floor_guard_record
+    assert rec["adopted"] == "dp" and rec["dp_s_per_step"] > 0
+    assert rec["searched_error"].startswith("MemoryError:")
+    assert "floor_guard.searched" in ff._compile_skips
+    assert not ff.strategy.validate()
+
+
+def test_guard_holds_one_training_state_at_a_time(monkeypatch):
+    """The guard times searched then DP; neither side's params/moments
+    may outlive its timing run (two resident copies of a model that
+    half fills a chip do not fit)."""
+    import gc
+    import weakref
+
+    import jax
+    refs = []
+    real = opt_mod._GuardRun.time_steps
+
+    def spy(self, n):
+        gc.collect()
+        assert not any(r() is not None for r in refs), \
+            "a previous guard run's state is still resident"
+        init = self.executor.init_params_and_state
+
+        def tracked():
+            p, s = init()
+            refs.extend(weakref.ref(a) for a in jax.tree.leaves(p))
+            return p, s
+        self.executor.init_params_and_state = tracked
+        try:
+            real(self, n)
+        finally:
+            self.executor.init_params_and_state = init
+
+    monkeypatch.setattr(opt_mod._GuardRun, "time_steps", spy)
+    ff = _searched_model(floor_guard="true", budget=2)
+    assert ff._floor_guard_record["adopted"] in ("searched", "dp")
+    assert len(refs) > 0
 
 
 def test_guard_real_timing_path():
@@ -85,6 +169,21 @@ def test_guard_real_timing_path():
     assert rec["searched_s_per_step"] > 0
     assert rec["dp_s_per_step"] > 0
     assert rec["adopted"] in ("searched", "dp")
+
+
+def test_fit_runs_the_step_the_guard_compiled():
+    """The guard feeds its batch the way fit() does, so the winning
+    side's executable is the one training runs — not a near-copy that
+    differs in input placement and compiles all over again (on the chip
+    that second compile of BERT-large cost 106 s)."""
+    ff = _searched_model(floor_guard="true", budget=2)
+    step = ff.executor.make_train_step().__wrapped__
+    assert step._cache_size() == 1
+    rng = np.random.default_rng(0)
+    ff.fit(x=rng.normal(size=(8, 64)).astype(np.float32),
+           y=rng.integers(0, 10, size=(8, 1)).astype(np.int32),
+           epochs=2, verbose=False)
+    assert step._cache_size() == 1
 
 
 def test_guard_export_annotation(tmp_path, monkeypatch):

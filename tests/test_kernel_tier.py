@@ -183,6 +183,44 @@ def test_fused_adam_update_matches_unfused_bitwise():
                                    rtol=1e-6, atol=1e-7)
 
 
+def test_forced_fused_update_runs_even_when_the_guard_built_the_step(
+        monkeypatch):
+    """The floor guard compiles and runs the train step before the
+    kernel plan exists. Found on the chip (where the guard is on by
+    default): the adopted executor kept replaying that unfused trace,
+    so a forced ``opt_update:fused`` never ran."""
+    import dataclasses
+
+    from flexflow_tpu import AdamOptimizer
+    from flexflow_tpu.kernels import registry as kreg
+    from flexflow_tpu.runtime import optimizers as opt_mod
+    monkeypatch.setitem(
+        kreg.REGISTRY[kreg.OPT_UPDATE], "fused", dataclasses.replace(
+            kreg.REGISTRY[kreg.OPT_UPDATE]["fused"],
+            predicate=lambda ctx: None))   # let the CPU interpret it
+    calls = []
+    real = opt_mod.fused_adam_tree_update
+    monkeypatch.setattr(
+        opt_mod, "fused_adam_tree_update",
+        lambda *a, **k: calls.append(1) or real(*a, **k))
+    cfg = FFConfig()
+    cfg.batch_size = 8
+    cfg.search_budget = 2
+    cfg.search_floor_guard = "true"
+    cfg.kernel_impls = "opt_update:fused"
+    ff = FFModel(cfg)
+    x = ff.create_tensor((8, 64), name="x")
+    out = ff.dense(ff.dense(x, 64), 10)
+    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
+               output_tensor=out)
+    assert "adopted" in ff._floor_guard_record and not calls
+    rng = np.random.default_rng(0)
+    ff.fit(x=rng.normal(size=(8, 64)).astype(np.float32),
+           y=rng.integers(0, 10, size=(8, 1)).astype(np.int32),
+           epochs=1, verbose=False)
+    assert calls, "the step that trained never traced the fused update"
+
+
 # ---------------------------------------------------------------------------
 # cost model: the per-op impl dimension
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from flexflow_tpu.kernels import (flash_attention, mha_reference,
                                   ring_attention, ulysses_attention)
-from flexflow_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _rand_qkv(b=2, h=4, s=256, d=64, dtype=jnp.float32, seed=0):

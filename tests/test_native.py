@@ -15,6 +15,34 @@ def lib():
     return native.get_lib()
 
 
+def test_ensure_built_rebuilds_when_source_is_newer(lib):
+    """A binary older than its source is stale: it must not silently
+    outrank ``src/ffruntime.cc`` (it is git-ignored, so nothing else
+    would ever refresh it)."""
+    import os
+    so_before = os.path.getmtime(native._SO)
+    src_stat = os.stat(native._SRC)
+    try:
+        os.utime(native._SRC, (so_before + 10, so_before + 10))
+        assert native._stale()
+        assert native.ensure_built()
+        assert os.path.getmtime(native._SO) > so_before
+    finally:
+        os.utime(native._SRC, (src_stat.st_atime, src_stat.st_mtime))
+    assert not native._stale()
+
+
+def test_failed_build_raises_with_a_compiler_present(lib, monkeypatch,
+                                                     tmp_path):
+    bad = tmp_path / "broken.cc"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_SRC", str(bad))
+    monkeypatch.setattr(native, "_SO", str(tmp_path / "libbroken.so"))
+    with pytest.raises(RuntimeError, match="g\\+\\+ exit"):
+        native.ensure_built()
+    assert list(tmp_path.iterdir()) == [bad]   # no half-written binary
+
+
 def _random_dag(rng, n, extra_edges):
     """Random DAG: edges only from lower to higher ids."""
     edges = [(i, i + 1) for i in range(n - 1) if rng.random() < 0.7]

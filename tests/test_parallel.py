@@ -113,3 +113,21 @@ def test_strategy_validate_catches_axis_reuse():
     st.set_op("bad", [P(("x0", "x0"))], {})
     errs = st.validate()
     assert errs and "axis reused" in errs[0]
+
+
+def test_detect_raises_on_a_device_kind_without_known_peaks():
+    """The generations table is the peaks table: a device that is not in
+    it is an error, never a silent v5e (CPU stays ``cpu-sim``, for tests)."""
+    import types
+
+    import pytest
+
+    def dev(kind, platform="tpu"):
+        return types.SimpleNamespace(device_kind=kind, platform=platform)
+
+    assert MachineSpec.detect([dev("TPU v5 lite")] * 4).generation == "v5e"
+    assert MachineSpec.detect([dev("cpu", "cpu")]).generation == "cpu-sim"
+    with pytest.raises(ValueError, match="TPU v9x"):
+        MachineSpec.detect([dev("TPU v9x")])
+    with pytest.raises(ValueError, match="not in TPU_GENERATIONS"):
+        MachineSpec.detect([dev("NVIDIA H100", "gpu")])

@@ -90,7 +90,7 @@ def test_quantized_all_reduce_matches_psum_and_residual_mass():
     from jax.sharding import PartitionSpec as P
     from flexflow_tpu.ops.quantized_collectives import (
         quantized_all_reduce)
-    from flexflow_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     mesh, sizes = _mesh_and_sizes()
     rng = np.random.default_rng(0)
     x = rng.normal(size=(8, 300)).astype(np.float32)
@@ -117,7 +117,7 @@ def test_phased_sync_staged_dcn_leg():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from flexflow_tpu.ops.quantized_collectives import phased_sync
-    from flexflow_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     mesh, sizes = _mesh_and_sizes()
     rng = np.random.default_rng(2)
     x = rng.normal(size=(8, 257)).astype(np.float32)
@@ -153,7 +153,7 @@ def test_phased_sync_full_precision_passthrough():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from flexflow_tpu.ops.quantized_collectives import phased_sync
-    from flexflow_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     mesh, sizes = _mesh_and_sizes()
     x = np.arange(8 * 16, dtype=np.float32).reshape(8, 16)
 

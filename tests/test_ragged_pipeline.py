@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.parallel.pipeline import gpipe_ragged
-from flexflow_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 S = 4           # stages
 COUNTS = (2, 2, 1, 1)   # ragged: 6 blocks over 4 stages

@@ -469,14 +469,14 @@ def test_scheduler_hot_swap_drains_then_restarts():
 # compile-cache warm start
 # ---------------------------------------------------------------------------
 
-def test_repository_load_wires_compilation_cache(tmp_path, monkeypatch):
-    """Every repository load path opts into the persistent compile
-    cache; on bare CPU the helper's own SIGILL guard declines, so the
+def test_repository_load_wires_compilation_cache(monkeypatch):
+    """Every repository load path compiles through the persistent
+    cache; on the CPU test platform the helper sets nothing, so the
     wiring is witnessed through a recording stub."""
     import flexflow_tpu.utils.compilation_cache as cc
     calls = []
     monkeypatch.setattr(cc, "enable_compilation_cache",
-                        lambda path=None, **kw: calls.append(path))
+                        lambda: calls.append("enable"))
 
     from flexflow_tpu.serving.session import ModelRepository
     repo = ModelRepository()
@@ -485,27 +485,60 @@ def test_repository_load_wires_compilation_cache(tmp_path, monkeypatch):
         t = ff.create_tensor((4, 8), name="in0")
         return ff.dense(t, 4)
 
-    cfg = FFConfig()
-    cfg.compilation_cache_dir = str(tmp_path / "cache")
     session = repo._load_with_builder(
-        "dense", graph_build, batch_buckets=(4,), config=cfg,
+        "dense", graph_build, batch_buckets=(4,), config=FFConfig(),
         strategy_file=None, instances=1)
     assert repo.get("dense") is session
-    # called from the repository load AND again inside compile() —
-    # both opt-ins point at the configured directory
-    assert calls and set(calls) == {str(tmp_path / "cache")}
+    # called from the repository load AND again inside compile()
+    assert len(calls) >= 2
 
 
-def test_enable_compilation_cache_cpu_guard(tmp_path):
-    """On the bare-CPU test backend the helper must decline (reloading
-    foreign-host XLA:CPU AOT artifacts risks SIGILL)."""
+def _recorded_cache_config(monkeypatch, backend, env_dir):
+    """Run enable_compilation_cache with a scripted platform and
+    environment; returns (its answer, the jax.config updates it made)."""
     import jax
 
-    from flexflow_tpu.utils.compilation_cache import \
-        enable_compilation_cache
-    if jax.default_backend() != "cpu":
-        pytest.skip("cacheable backend: guard does not apply")
-    assert enable_compilation_cache(str(tmp_path / "c")) is None
+    import flexflow_tpu.utils.compilation_cache as cc
+    updates = {}
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    return cc.enable_compilation_cache(), updates
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_cache_dir_from_environment_is_never_overridden(
+        monkeypatch, tmp_path, backend):
+    """JAX_COMPILATION_CACHE_DIR set: the directory is JAX's own
+    business — no code path names another one."""
+    where = str(tmp_path / "placed_from_outside")
+    got, updates = _recorded_cache_config(monkeypatch, backend, where)
+    assert got == where
+    assert "jax_compilation_cache_dir" not in updates
+
+
+def test_cache_dir_defaults_to_the_checkout_on_an_accelerator(monkeypatch):
+    import flexflow_tpu
+    import flexflow_tpu.utils.compilation_cache as cc
+    got, updates = _recorded_cache_config(monkeypatch, "tpu", None)
+    root = os.path.dirname(os.path.dirname(
+        os.path.abspath(flexflow_tpu.__file__)))
+    assert got == cc.CHECKOUT_CACHE_DIR == os.path.join(root, ".jax_cache")
+    assert updates["jax_compilation_cache_dir"] == got
+    # (kernel bytes, hence cache keys, must not depend on the call chain)
+    assert updates["jax_include_full_tracebacks_in_locations"] is False
+
+
+def test_no_cache_on_the_cpu_platform_unless_placed(monkeypatch):
+    """CPU compiles are tests at toy sizes and an XLA:CPU executable is
+    tied to the build host's instruction set: nothing is cached unless
+    the environment says where."""
+    got, updates = _recorded_cache_config(monkeypatch, "cpu", None)
+    assert got is None and updates == {}
 
 
 def test_model_compile_counter_labels_decode_compiles():

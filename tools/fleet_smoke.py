@@ -29,7 +29,6 @@ import os
 import re
 import subprocess
 import sys
-import tempfile
 import threading
 import time
 import urllib.error
@@ -82,14 +81,22 @@ def main() -> int:
                                             AutoscalerConfig,
                                             FleetRouter, serve_fleet)
 
-    cache_dir = tempfile.mkdtemp(prefix="ff_fleet_cache_")
+    # the launcher places the replicas' compile cache, from outside
+    # them: the caller's directory if there is one, else the checkout's
+    from flexflow_tpu.utils.compilation_cache import CHECKOUT_CACHE_DIR
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or CHECKOUT_CACHE_DIR
     spawn_argv = [
         sys.executable, "-m", "flexflow_tpu.serving.fleet.replica",
         "--port", "{port}", "--name", "{name}", "--model", MODEL,
-        "--decode-segment", "4", "--compile-cache", cache_dir]
+        "--decode-segment", "4"]
     spawn_env = {"JAX_PLATFORMS": "cpu",
                  "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
                  "PYTHONPATH": REPO,
+                 "JAX_COMPILATION_CACHE_DIR": cache_dir,
+                 # the tiny model's programs compile in under JAX's
+                 # default one-second caching threshold
+                 "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
                  # replicas must NOT inherit a fault plan from the CI
                  # environment; the victim gets its own below
                  "FF_FAULT_PLAN": ""}

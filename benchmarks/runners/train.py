@@ -1,0 +1,359 @@
+"""Runner for traffic of ``kind: "train"``: a training cell.
+
+The model is built through the program's normal entry points
+(``FFConfig`` -> ``FFModel`` -> the configuration's builder -> ``compile``
+-> ``fit``). Data is a pool of ``steps_per_group`` batches drawn on the
+host from the seed; the program receives only the arrays. One *group* is
+one epoch of ``fit`` over the pool: the program's own loop, its async
+dispatch window intact, ending in the loop's single device fetch. The
+window is ONE ``fit`` call whose epochs are the groups, timed by this
+file's own clock at each ``on_epoch_end`` and ended through
+``stop_requested``. The first ``warmup_groups`` epochs of that call are
+the warm-up and end the set-up; with ``--trace 1`` two more groups run
+after the window under the profiler.
+
+Traffic file: ``kind``, ``per_chip_batch``, ``seq``, ``optimizer``
+(``{"class": "flexflow_tpu:AdamOptimizer", "args": {...}}``),
+``steps_per_group``, ``warmup_groups``, ``ffconfig`` (fields of
+``FFConfig`` to set), ``why``.
+Configuration file: ``builder``, ``config_class`` (dotted names in the
+program), the class's fields at the top level, ``task`` (``seq_cls`` |
+``causal_lm``), ``reference`` (``module:function`` under
+``reference/``), ``initial_loss_band``, ``reference_rel_tol``.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+import os
+import shutil
+import statistics
+import time
+import types
+
+import numpy as np
+
+from benchmarks.harness import cells, peaks, trace_reduce
+
+TRACED_GROUPS = 2
+PROGRAM_SEED = 0
+MARK = "bench.group"
+
+
+class GroupClock:
+    """The benchmark's own ``fit`` callback: the clock of the window."""
+
+    def __init__(self, seconds: float, warmup_groups: int, trace_dir,
+                 cache_dir):
+        self.seconds = seconds
+        self.warmup = warmup_groups
+        self.trace_dir = trace_dir
+        self.cache_dir = cache_dir
+        self.stop_requested = False
+        self.groups: list = []         # seconds of each window group
+        self.losses: list = []         # every group's mean loss
+        self.setup_end = None
+        self.cache_before = self.cache_after = None
+        self.traced = 0
+        self._mark = None
+        self._last = None
+        self._window_done = False
+
+    def _cache(self):
+        from flexflow_tpu.utils.compilation_cache import cache_entries
+        return cache_entries(self.cache_dir)
+
+    def _open_mark(self):
+        import jax
+        self._mark = jax.profiler.TraceAnnotation(MARK)
+        self._mark.__enter__()
+
+    def _close_mark(self):
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+            self._mark = None
+
+    def on_epoch_end(self, epoch, report, model):
+        import jax
+        now = time.perf_counter()
+        self.losses.append(float(report.get("loss", float("nan"))))
+        if epoch + 1 < self.warmup:
+            return
+        if epoch + 1 == self.warmup:          # the set-up ends here
+            self.cache_before = self._cache()
+            self.setup_end = self._last = time.perf_counter()
+            return
+        if not self._window_done:
+            self.groups.append(now - self._last)
+            self._last = now
+            if now - self.setup_end < self.seconds:
+                return
+            self._window_done = True
+            self.cache_after = self._cache()
+            if self.trace_dir is None:
+                self.stop_requested = True
+                return
+            shutil.rmtree(self.trace_dir, ignore_errors=True)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0      # device ops and marks only
+            opts.host_tracer_level = 2
+            jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
+            self._open_mark()
+            return
+        self._close_mark()
+        self.traced += 1
+        if self.traced < TRACED_GROUPS:
+            self._open_mark()
+            return
+        jax.profiler.stop_trace()
+        self.stop_requested = True
+
+
+def _pool(task: str, sizes: dict, n: int, seq: int, seed: int):
+    """``n`` sequences and their labels from the seed, on the host."""
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, sizes["vocab_size"], (n, seq)).astype(np.int32)
+    pos = np.tile(np.arange(seq, dtype=np.int32), (n, 1))
+    if task == "seq_cls":
+        y = rng.integers(0, sizes["num_labels"], (n, 1)).astype(np.int32)
+        classes = sizes["num_labels"]
+    elif task == "causal_lm":
+        y = np.roll(ids, -1, axis=1)[..., None]        # next token
+        classes = sizes["vocab_size"]
+    else:
+        raise cells.BenchmarkError(f"unknown task {task!r}")
+    return [ids, pos], y, classes
+
+
+def _device_info():
+    import jax
+    devs = jax.devices()
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    peak = max((s or {}).get("peak_bytes_in_use", 0) for s in stats)
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs), "memory_peak_bytes": int(peak)}
+
+
+def _reference_error(cell, ff, probs, x, n_ref: int):
+    """The program's eval-mode log-probabilities for the first ``n_ref``
+    sequences against the plain reference: ``(plain, centered)`` =
+    ``|sys - ref|_2`` over ``|ref|_2`` and over ``|ref - mean(ref)|_2``.
+    The first is the one held to the tolerance; the second, relative to
+    the spread of the reference's scores, is sharper where there are
+    many scores (a language model) and swings with the seed where there
+    are sixteen (a two-class head), so it is printed and not judged."""
+    import jax
+    import jax.numpy as jnp
+    mod_name, _, fn_name = cell.config["reference"].partition(":")
+    mod = cells.load_module(cell.bench_dir, "reference", mod_name)
+    fn = getattr(mod, fn_name)
+    # in the order the model was built (a searched plan may reorder the
+    # program's layers); a parameter the built graph does not know goes
+    # last, where the reference refuses it
+    order = [l.name for l in ff.layers if l.name in ff.params]
+    order += [n for n in sorted(ff.params) if n not in order]
+
+    def err(weights, got, ids, pos):
+        ref = fn(list(zip(order, weights)), cell.config, ids, pos)
+        sys_lp = jnp.log(jnp.clip(got.astype(jnp.float32), 1e-30))
+        num = jnp.sqrt(jnp.sum((sys_lp - ref) ** 2))
+        return (num / jnp.sqrt(jnp.sum(ref ** 2)),
+                num / jnp.sqrt(jnp.sum((ref - ref.mean()) ** 2)))
+
+    plain, centered = jax.jit(err)(
+        [ff.params[n] for n in order], probs[:n_ref], x[0][:n_ref],
+        x[1][:n_ref])
+    return float(plain), float(centered)
+
+
+def run(cell, seed: int, seconds: float, trace: bool, say, t_start: float):
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.obs import events as obs
+    from flexflow_tpu.utils.compilation_cache import enable_compilation_cache
+
+    cache_dir = enable_compilation_cache()
+    conf, traffic = cell.config, cell.traffic
+    checks: dict = {}
+
+    def check(name: str, ok: bool, what: str):
+        checks[name] = bool(ok)
+        say(f"check {name}: {'ok' if ok else 'FAILED'} - {what}")
+
+    dev = _device_info()
+    try:
+        peak = peaks.lookup(dev["kind"])
+    except peaks.UnknownDevice as e:
+        peak = None
+        say(str(e))
+    check("device", dev["platform"] == "tpu" and peak is not None
+          and dev["count"] == cell.chips,
+          f"{dev['count']} x {dev['kind']} ({dev['platform']}); the cell "
+          f"asks for {cell.chips} TPU chip(s)")
+
+    # -- the model, through the normal entry points --------------------
+    n_dev = dev["count"]
+    batch = int(traffic["per_chip_batch"]) * n_dev
+    seq = int(traffic["seq"])
+    spg = int(traffic["steps_per_group"])
+    cfg_cls = cells.load_attr(conf["config_class"])
+    model_cfg = cfg_cls(**{f.name: conf[f.name]
+                           for f in dataclasses.fields(cfg_cls)
+                           if f.name in conf})
+    cfg = FFConfig()
+    cfg.batch_size = batch
+    # The program folds its seed into the compiled step as a constant
+    # (the dropout key), so a new seed would be a new executable and a
+    # compile-cache miss in every run. Its seed is therefore held fixed
+    # and the weights are drawn again from --seed after compile().
+    cfg.seed = PROGRAM_SEED
+    if trace:
+        cfg.trace = "true"             # spans and counters are read below
+    for key, value in traffic.get("ffconfig", {}).items():
+        if not hasattr(cfg, key):
+            raise cells.BenchmarkError(
+                f"traffic {cell.traffic_name}: FFConfig has no {key!r}")
+        setattr(cfg, key, value)
+    ff = FFModel(cfg)
+    out = cells.load_attr(conf["builder"])(ff, batch, seq, model_cfg)
+    obs.clear()
+    t0 = time.perf_counter()
+    opt = traffic["optimizer"]
+    ff.compile(cells.load_attr(opt["class"])(**opt["args"]),
+               "sparse_categorical_crossentropy", [], output_tensor=out)
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    ff.params, ff.state = ff.executor.init_params_and_state(
+        jax.random.key(seed))
+    reinit_s = time.perf_counter() - t0
+    say(f"compile {compile_s:.1f}s (+ weights from the seed "
+        f"{reinit_s:.1f}s), phases {ff._compile_phases}, mesh "
+        f"{dict(ff.dmesh.axis_sizes)}, floor guard "
+        f"{getattr(ff, '_floor_guard_record', None)}, skipped phases "
+        f"{getattr(ff, '_compile_skips', {})}")
+
+    # -- data: a pool of steps_per_group batches from the seed ----------
+    x, y, classes = _pool(conf["task"], conf, spg * batch, seq, seed)
+    x0, y0 = [a[:batch] for a in x], y[:batch]
+    batch0 = next(iter(ff._combined_loader(x0, y0, shuffle=False)))
+
+    # -- the compiled step, as the window will run it --------------------
+    t0 = time.perf_counter()
+    compiled = ff.executor.make_train_step().lower(
+        ff.params, ff.opt_state, ff.state, jnp.int32(0), batch0).compile()
+    step_text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    step_bytes = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    say(f"train step compiled or loaded in {time.perf_counter() - t0:.1f}s:"
+        f" arguments {ma.argument_size_in_bytes / 2**30:.3f} + outputs "
+        f"{ma.output_size_in_bytes / 2**30:.3f} + temporaries "
+        f"{ma.temp_size_in_bytes / 2**30:.3f} - aliased "
+        f"{ma.alias_size_in_bytes / 2**30:.3f} GiB per device; attention "
+        f"{sorted(set(ff.executor.resolved_attention_impls.values()))}")
+    del compiled
+
+    # -- before: eval-mode loss on the pool's first batch, the reference -
+    eval_step = ff.executor.make_eval_step()
+    probs, bm = eval_step(ff.params, ff.state, batch0)
+    loss_before = float(bm["loss"])
+    lo, hi = conf["initial_loss_band"]
+    check("initial_loss", lo <= loss_before <= hi,
+          f"eval-mode loss before training {loss_before:.4f}, band "
+          f"[{lo}, {hi}] around ln({classes}) = {math.log(classes):.4f}")
+    if conf.get("reference"):
+        try:
+            n_ref = int(conf.get("reference_sequences", 2))
+            rel, centered = _reference_error(cell, ff, probs, x, n_ref)
+            what = (f"log-probabilities of {n_ref} sequences against "
+                    f"{conf['reference']}: relative error {rel:.3e}, "
+                    f"tolerance {conf['reference_rel_tol']} (relative "
+                    f"to the scores' spread {centered:.3e})")
+            ok = rel <= conf["reference_rel_tol"]
+        except Exception as e:  # noqa: BLE001 — a mismatch is a result
+            ok, what = False, f"{type(e).__name__}: {e}"
+        check("reference", ok, what)
+    del probs
+
+    # -- warm-up and window: one fit call, its epochs the groups ---------
+    trace_dir = os.path.join(cell.root, ".bench_trace", cell.name) \
+        if trace else None
+    clock = GroupClock(seconds, int(traffic.get("warmup_groups", 2)),
+                       trace_dir, cache_dir)
+    error = None
+    try:
+        ff.fit(x=x, y=y, epochs=10 ** 6, callbacks=[clock], verbose=False)
+    except Exception as e:  # noqa: BLE001 — a failed group is counted
+        error = f"{type(e).__name__}: {e}"
+        say(f"fit raised {error}")
+    setup_s = (clock.setup_end or time.perf_counter()) - t_start
+    window_losses = clock.losses[clock.warmup:]
+    failed = sum(1 for v in window_losses if not math.isfinite(v)) \
+        + (1 if error else 0)
+    attempted = len(window_losses) + (1 if error else 0)
+    check("finite_losses", error is None
+          and all(math.isfinite(v) for v in clock.losses),
+          f"{len(clock.losses)} groups, losses "
+          + " ".join(f"{v:.4f}" for v in clock.losses[:12]))
+
+    _, bm = eval_step(ff.params, ff.state, batch0)
+    loss_after = float(bm["loss"])
+    check("loss_fell", loss_after < loss_before,
+          f"eval-mode loss on the pool's first batch {loss_before:.4f} -> "
+          f"{loss_after:.4f}")
+    added = sorted((clock.cache_after or set()) - (clock.cache_before
+                                                   or set()))
+    check("no_compile_in_window", not added,
+          f"{len(added)} compile-cache entries added inside the window"
+          + (f": {[n[:48] for n in added[:4]]}" if added else ""))
+
+    # -- arithmetic -------------------------------------------------------
+    steps = spg * len(clock.groups)
+    wall = sum(clock.groups)
+    tokens_per_step = batch * seq
+    tokens_per_s = steps * tokens_per_step / wall if wall else None
+    flops = cells.load_module(cell.bench_dir, "flops",
+                              conf.get("flops", cell.config_name))
+    say(f"window: {len(clock.groups)} groups of {spg} steps in {wall:.3f}s"
+        f" (median group {statistics.median(clock.groups):.4f}s)"
+        if clock.groups else "window: no group completed")
+
+    reduced = None
+    dev = _device_info()
+    if trace and clock.traced == TRACED_GROUPS:
+        t0 = time.perf_counter()
+        kernel_names = [line.split("=")[0].strip().split()[-1]
+                        for line in step_text.splitlines()
+                        if 'custom_call_target="tpu_custom_call"' in line]
+        events = trace_reduce.extract(trace_reduce.find_xplane(trace_dir),
+                                      mark_prefix=MARK)
+        reduced = trace_reduce.reduce_trace(events, kernel_names)
+        scalars = {k: v for k, v in reduced.items()
+                   if not isinstance(v, list)}
+        say(f"trace reduced in {time.perf_counter() - t0:.1f}s: {scalars}")
+        if reduced:
+            dev["busy_s"] = reduced["busy_s"]
+            dev["window_s"] = reduced["window_s"]
+
+    spans: dict = {}
+    for ev in obs.events():
+        if ev["kind"] == "span":
+            spans[ev["name"]] = spans.get(ev["name"], 0.0) + ev["dur"]
+    ctx = types.SimpleNamespace(
+        cell=cell, model=ff, spans=spans, counters=dict(obs.counters()),
+        step_text=step_text, trace=reduced, groups=list(clock.groups),
+        steps_per_group=spg, tokens_per_step=tokens_per_step,
+        tokens_per_s=tokens_per_s, chips=n_dev, peak=peak,
+        train_flops_per_token=flops.train_flops_per_token(conf, seq),
+        compile_s=compile_s, in_window_compiles=len(added))
+    return types.SimpleNamespace(
+        correct=all(checks.values()) and failed == 0 and attempted > 0,
+        attempted=attempted, failed=failed,
+        end_to_end={"train_tokens_per_s": tokens_per_s,
+                    "step_hbm_gib": step_bytes / 2 ** 30,
+                    "setup_s": setup_s},
+        ctx=ctx, device=dev,
+        breakdown={"device_ops": reduced["device_ops"],
+                   "idle_gaps": reduced["idle_gaps"]} if reduced else None)

@@ -1,0 +1,9 @@
+"""``flash_bwd_dq_roofline``: the least time the chip could take for the
+traced calls of the kernel named ``flash_attention_bwd_dq``
+(``flops/flash_attention.py`` over the table of peaks) over the device
+time they took, in percent."""
+from benchmarks.harness import span_reduce
+
+
+def read(ctx):
+    return span_reduce.kernel_roofline(ctx, "flash_attention_bwd_dq")

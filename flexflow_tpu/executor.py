@@ -98,6 +98,15 @@ def _instrument_step(fn, name: str):
     return wrapped
 
 
+def _emit_scoped(op, layer: Layer, ins, w, ctx):
+    """``op.emit`` under ``jax.named_scope(layer.name)``: every device
+    op the layer lowers to carries, in its ``op_name`` metadata, the
+    name the strategy audit and the cost model use for that layer.
+    Trace-time metadata only: no computation changes."""
+    with jax.named_scope(layer.name):
+        return op.emit(layer.params, ins, w, ctx, layer.name)
+
+
 def _needs_rng(layer: Layer) -> bool:
     if layer.op_type == OperatorType.OP_DROPOUT:
         return True
@@ -159,8 +168,11 @@ class GraphProgram:
             if layer.name in grouped:
                 if layer.name not in bank_out:
                     grp, emit_fn = grouped[layer.name]
-                    emit_fn(grp, layers, env, params, ctx, strategy,
-                            bank_out)
+                    # several layers emitted as one: the scope is the
+                    # first member's name
+                    with jax.named_scope(grp.members[0]):
+                        emit_fn(grp, layers, env, params, ctx, strategy,
+                                bank_out)
                 o = bank_out[layer.name]
                 if bf16_act and hasattr(o, "dtype") \
                         and o.dtype == jnp.float32:
@@ -174,7 +186,7 @@ class GraphProgram:
             w = params.get(layer.name, {})
             ctx.op_sharding = strategy.ops.get(layer.name) \
                 if strategy is not None else None
-            outs = op.emit(layer.params, ins, w, ctx, layer.name)
+            outs = _emit_scoped(op, layer, ins, w, ctx)
             if len(outs) != len(layer.outputs):
                 raise RuntimeError(
                     f"op {layer.name} emitted {len(outs)} outputs, "
@@ -763,7 +775,7 @@ class Executor:
                 op = get_op_def(layer.op_type)
                 ins = [env[tt.guid] for tt in layer.inputs]
                 w = p.get(pipe.param_name(layer), {})
-                outs = op.emit(layer.params, ins, w, ctx, layer.name)
+                outs = _emit_scoped(op, layer, ins, w, ctx)
                 for o, tt in zip(outs, layer.outputs):
                     if bf16_act and hasattr(o, "dtype") \
                             and o.dtype == jnp.float32:
@@ -790,7 +802,7 @@ class Executor:
                 op = get_op_def(layer.op_type)
                 ins = [env[tt.guid] for tt in layer.inputs]
                 w = p.get(layer.name, {})
-                outs = op.emit(layer.params, ins, w, ctx, layer.name)
+                outs = _emit_scoped(op, layer, ins, w, ctx)
                 for o, tt in zip(outs, layer.outputs):
                     if bf16_act and hasattr(o, "dtype") \
                             and o.dtype == jnp.float32:
@@ -947,13 +959,13 @@ class Executor:
                     # then the bias applied exactly once
                     w = dict(w)
                     bias = w.pop("bo" if role == "attn" else "bias", None)
-                    outs = op.emit(layer.params, ins, w, ctx, layer.name)
+                    outs = _emit_scoped(op, layer, ins, w, ctx)
                     y = jax.lax.psum(outs[0], tp_ax)
                     if bias is not None:
                         y = (y + bias).astype(outs[0].dtype)
                     outs = [y]
                 else:
-                    outs = op.emit(layer.params, ins, w, ctx, layer.name)
+                    outs = _emit_scoped(op, layer, ins, w, ctx)
                 for o, tt in zip(outs, layer.outputs):
                     if bf16_act and hasattr(o, "dtype") \
                             and o.dtype == jnp.float32:
@@ -1148,6 +1160,49 @@ class Executor:
         bm["loss"] = loss
         return loss, bm
 
+    def _apply_update(self, params, grads, opt_state, step):
+        """The optimizer phase of the train step (``step`` is 1-based):
+        overlapped, fused-kernel or plain update, by the adopted plan."""
+        if self._overlap_schedule is not None:
+            # overlap path (runtime/overlap.py): per-bucket updates
+            # chained in backward-completion order — identity math
+            # (bit-exact with the serial branch below), but the
+            # barrier chain hands XLA dependency cuts so bucket k's
+            # grad sync + update (+ ZeRO gather) interleave with
+            # the backward of buckets k+1..
+            from .runtime import overlap as overlap_mod
+            new_params, new_opt_state = overlap_mod.overlapped_update(
+                self.optimizer, params, grads, opt_state, step,
+                self._overlap_schedule, self.opt_state_constraints)
+        elif self._kernel_impls.get("opt_update") == "fused":
+            # searched kernel tier: one-HBM-pass Pallas Adam update
+            # (kernels/opt_update.py) — bit-equal math to
+            # AdamOptimizer.update, adopted only when the registry
+            # predicate held (TPU backend, adam) at plan time
+            from .runtime.optimizers import fused_adam_tree_update
+            zero = self.opt_state_constraints
+            new_params, new_opt_state = fused_adam_tree_update(
+                self.optimizer, params, grads, opt_state, step,
+                mesh=self.dmesh.mesh, param_specs=self._param_specs,
+                state_specs=None if zero is None else jax.tree.map(
+                    lambda sh: sh.spec, zero["m"]))
+            if self.opt_state_constraints is not None:
+                new_opt_state = jax.tree.map(
+                    jax.lax.with_sharding_constraint,
+                    new_opt_state, self.opt_state_constraints)
+        else:
+            new_params, new_opt_state = self.optimizer.update(
+                params, grads, opt_state, step)
+            if self.opt_state_constraints is not None:
+                # ZeRO-1 pin: keep the updated moments on their
+                # sharded placement (GSPMD lowers the update to
+                # reduce-scatter + sharded math instead of
+                # replicating the state back)
+                new_opt_state = jax.tree.map(
+                    jax.lax.with_sharding_constraint,
+                    new_opt_state, self.opt_state_constraints)
+        return new_params, new_opt_state
+
     # ------------------------------------------------------------------
     def make_train_step(self):
         """Build the donated, jitted train step (fwd+bwd+update fused into
@@ -1163,11 +1218,15 @@ class Executor:
                 f"--gradient-accumulation-steps {accum} must divide "
                 f"the batch size {self.config.batch_size}")
 
+        # phase scopes: the backward ops carry JAX's own
+        # ``transpose(jvp(ff.forward))`` / ``transpose(jvp(ff.loss))``
         def loss_fn(p, st, mb, sub_step):
-            outs, new_state, aux, capture = self._forward(
-                p, st, mb, True, sub_step)
-            loss, bm = self._loss_and_metrics(outs, capture, mb["label"],
-                                              aux)
+            with jax.named_scope("ff.forward"):
+                outs, new_state, aux, capture = self._forward(
+                    p, st, mb, True, sub_step)
+            with jax.named_scope("ff.loss"):
+                loss, bm = self._loss_and_metrics(outs, capture,
+                                                  mb["label"], aux)
             return loss, (new_state, bm)
 
         def step_fn(params, opt_state, state, step, batch):
@@ -1237,44 +1296,9 @@ class Executor:
             # only the loss, and an auxiliary metric overflowing float32
             # on its own must not trigger a supervisor rollback
             bm["all_finite"] = jnp.all(jnp.isfinite(bm["loss"]))
-            if self._overlap_schedule is not None:
-                # overlap path (runtime/overlap.py): per-bucket updates
-                # chained in backward-completion order — identity math
-                # (bit-exact with the serial branch below), but the
-                # barrier chain hands XLA dependency cuts so bucket k's
-                # grad sync + update (+ ZeRO gather) interleave with
-                # the backward of buckets k+1..
-                from .runtime import overlap as overlap_mod
-                new_params, new_opt_state = overlap_mod.overlapped_update(
-                    self.optimizer, params, grads, opt_state, step + 1,
-                    self._overlap_schedule, self.opt_state_constraints)
-            elif self._kernel_impls.get("opt_update") == "fused":
-                # searched kernel tier: one-HBM-pass Pallas Adam update
-                # (kernels/opt_update.py) — bit-equal math to
-                # AdamOptimizer.update, adopted only when the registry
-                # predicate held (TPU backend, adam) at plan time
-                from .runtime.optimizers import fused_adam_tree_update
-                zero = self.opt_state_constraints
-                new_params, new_opt_state = fused_adam_tree_update(
-                    self.optimizer, params, grads, opt_state, step + 1,
-                    mesh=self.dmesh.mesh, param_specs=self._param_specs,
-                    state_specs=None if zero is None else jax.tree.map(
-                        lambda sh: sh.spec, zero["m"]))
-                if self.opt_state_constraints is not None:
-                    new_opt_state = jax.tree.map(
-                        jax.lax.with_sharding_constraint,
-                        new_opt_state, self.opt_state_constraints)
-            else:
-                new_params, new_opt_state = self.optimizer.update(
+            with jax.named_scope("ff.optimizer"):
+                new_params, new_opt_state = self._apply_update(
                     params, grads, opt_state, step + 1)
-                if self.opt_state_constraints is not None:
-                    # ZeRO-1 pin: keep the updated moments on their
-                    # sharded placement (GSPMD lowers the update to
-                    # reduce-scatter + sharded math instead of
-                    # replicating the state back)
-                    new_opt_state = jax.tree.map(
-                        jax.lax.with_sharding_constraint,
-                        new_opt_state, self.opt_state_constraints)
             if new_residual is not None:
                 from .ops.quantized_collectives import RESIDUAL_SLOT
                 new_opt_state = dict(new_opt_state)

@@ -298,6 +298,7 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(seed, q, k, v)
     return o, lse[:, :, 0]
 
@@ -340,6 +341,7 @@ def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(seed, q, k, v, do, lse_b, delta_b)
 
     # dkv grid: (bh, nk, nq) — index maps swap the roles of grid axes 1/2
@@ -364,6 +366,7 @@ def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(seed, q, k, v, do, lse_b, delta_b)
     return dq, dk, dv, np.zeros(seed.shape, dtype=jax.dtypes.float0)
 

@@ -34,6 +34,7 @@ from .executor import Executor, GraphProgram
 from .ffconst import (ActiMode, AggrMode, CompMode, DataType, InitializerType,
                       LossType, MetricsType, OperatorType, ParameterSyncType,
                       PoolType)
+from .obs import events as obs_events
 from .ops import get_op_def
 from .parallel.machine import DeviceMesh, MachineSpec
 from .parallel.strategy import ShardingStrategy
@@ -1341,73 +1342,88 @@ class FFModel:
         # buffer would block save_checkpoint of later clean params
         try:
             for epoch in range(epochs):
-                # re-fetch per epoch: callbacks (e.g.
-                # LearningRateScheduler) may invalidate the jitted step
-                # to apply new hyperparams
-                step_fn = self.executor.make_train_step()
-                pm = PerfMetrics()
-                buf = MetricsBuffer.for_config(self.config, pm=pm)
-                self._metrics_buffer = buf
-                t0 = time.perf_counter()
-                nb = 0
-                for batch in loader:
-                    bm = self._run_train_step(step_fn, batch)
-                    bsz = next(iter(batch.values())).shape[0]
-                    buf.push(self._step - 1, bm, bsz)
-                    nb += 1
-                    # dynamic recompilation hook (reference model.cc:2422)
-                    rs = getattr(self, "_recompile_state", None)
-                    if rs is not None and rs.step(self):
-                        step_fn = self.executor.make_train_step()
-                    pf = self.config.print_freq
-                    if pf > 0 and nb % pf == 0:
-                        # flush REGARDLESS of verbosity: print_freq is
-                        # the metric-fetch cadence, not just the print
-                        # cadence (pending device scalars must not pile
-                        # up for a whole quiet epoch)
-                        buf.flush()
-                        if verbose:
-                            rep = pm.report()
-                            msg = " ".join(f"{k}={v:.4f}"
-                                           for k, v in rep.items())
-                            print(f"epoch {epoch} iter "
-                                  f"{nb}/{loader.num_batches} {msg}")
-                buf.flush()
-                dt = time.perf_counter() - t0
-                rep = pm.report()
-                rep["epoch_time_s"] = dt
-                rep["samples_per_sec"] = pm.train_all / dt if dt > 0 \
-                    else 0.0
-                from .obs import events as obs_events
-                from .obs.metrics_registry import REGISTRY
-                obs_events.record_span("fit.epoch", t0, dt, epoch=epoch,
-                                       batches=nb)
-                REGISTRY.gauge(
-                    "ff_train_samples_per_sec",
-                    "Training throughput of the last completed epoch"
-                ).set(rep["samples_per_sec"])
-                history.append(rep)
-                if verbose:
-                    msg = " ".join(f"{k}={v:.4f}" for k, v in rep.items())
-                    print(f"epoch {epoch} done: {msg}")
-                if callbacks:
-                    stop = False
-                    for cb in callbacks:
-                        cb.on_epoch_end(epoch, rep, self)
-                        stop = stop or getattr(cb, "stop_requested",
-                                               False)
-                    if stop:
+                with obs_events.span("fit.epoch", epoch=epoch) as ep_span:
+                    if self._fit_epoch(epoch, loader, callbacks, verbose,
+                                       history, ep_span):
                         break
         finally:
             self._metrics_buffer = None
         self._current_metrics = history[-1] if history else {}
         if self.config.trace_export_file:
-            from .obs import events as obs_events
             from .obs.trace_export import export_chrome_trace
             if obs_events.enabled():
                 export_chrome_trace(self.config.trace_export_file)
         self._end_of_training_telemetry()
         return history
+
+    def _fit_epoch(self, epoch: int, loader, callbacks, verbose: bool,
+                   history: list, epoch_span) -> bool:
+        """One epoch of :meth:`fit`, inside its ``fit.epoch`` span;
+        returns whether a callback asked to stop. The loop's layer
+        boundaries each get a span, and nothing else does:
+        ``fit.loader_next`` (the loader), ``executor.train_step`` (the
+        dispatch, ``executor.py::_instrument_step``),
+        ``metrics_buffer.window_wait`` and ``metrics_buffer.flush`` (the
+        device fetches, ``runtime/metrics_buffer.py``) and
+        ``fit.callbacks`` (user code)."""
+        # re-fetch per epoch: callbacks (e.g. LearningRateScheduler) may
+        # invalidate the jitted step to apply new hyperparams
+        step_fn = self.executor.make_train_step()
+        pm = PerfMetrics()
+        buf = MetricsBuffer.for_config(self.config, pm=pm)
+        self._metrics_buffer = buf
+        t0 = time.perf_counter()
+        nb = 0
+        batches = iter(loader)
+        while True:
+            # iterated by hand: a ``for`` would hide the wait for the
+            # batch (the loader's reset, host slicing, the H2D put)
+            with obs_events.span("fit.loader_next"):
+                batch = next(batches, None)
+            if batch is None:
+                break
+            bm = self._run_train_step(step_fn, batch)
+            bsz = next(iter(batch.values())).shape[0]
+            buf.push(self._step - 1, bm, bsz)
+            nb += 1
+            # dynamic recompilation hook (reference model.cc:2422)
+            rs = getattr(self, "_recompile_state", None)
+            if rs is not None and rs.step(self):
+                step_fn = self.executor.make_train_step()
+            pf = self.config.print_freq
+            if pf > 0 and nb % pf == 0:
+                # flush REGARDLESS of verbosity: print_freq is the
+                # metric-fetch cadence, not just the print cadence
+                # (pending device scalars must not pile up for a whole
+                # quiet epoch)
+                buf.flush()
+                if verbose:
+                    rep = pm.report()
+                    msg = " ".join(f"{k}={v:.4f}" for k, v in rep.items())
+                    print(f"epoch {epoch} iter "
+                          f"{nb}/{loader.num_batches} {msg}")
+        buf.flush()
+        dt = time.perf_counter() - t0
+        rep = pm.report()
+        rep["epoch_time_s"] = dt
+        rep["samples_per_sec"] = pm.train_all / dt if dt > 0 else 0.0
+        epoch_span.set(batches=nb)
+        from .obs.metrics_registry import REGISTRY
+        REGISTRY.gauge(
+            "ff_train_samples_per_sec",
+            "Training throughput of the last completed epoch"
+        ).set(rep["samples_per_sec"])
+        history.append(rep)
+        if verbose:
+            msg = " ".join(f"{k}={v:.4f}" for k, v in rep.items())
+            print(f"epoch {epoch} done: {msg}")
+        stop = False
+        if callbacks:
+            with obs_events.span("fit.callbacks"):
+                for cb in callbacks:
+                    cb.on_epoch_end(epoch, rep, self)
+                    stop = stop or getattr(cb, "stop_requested", False)
+        return stop
 
     def _end_of_training_telemetry(self) -> None:
         """End-of-training observability hooks shared by :meth:`fit`

@@ -13,8 +13,7 @@ the predicted entries, so :mod:`.drift` can diff them row by row.
 
 Two measurement modes:
 
-  - **spans** (the CPU-sim fallback, and the default everywhere the
-    XPlane toolchain is absent): the executor's program is re-run as
+  - **spans** (the default): the executor's program is re-run as
     instrumented sub-steps — one jitted ``fwd+bwd`` per op (with the
     strategy's sharding constraints applied, so collectives execute),
     one timed gradient-sync collective per weighted op, one timed
@@ -26,9 +25,6 @@ Two measurement modes:
     executable is faster than the sub-step decomposition (XLA fuses
     across ops; each sub-step pays its own dispatch), and both numbers
     matter: per-op ratios for drift, the fused wall for throughput.
-  - **xplane** (real accelerators): run the steps under
-    ``jax.profiler.trace`` and parse the XPlane protobuf when the
-    profiler toolchain is importable; falls back to **spans** otherwise.
   - **coarse** (pipelined regions): the per-op decomposition cannot
     thread a GPipe region's stacked params, so only the compiled-step
     wall is measured and the per-op entries are marked unmeasured.
@@ -483,56 +479,6 @@ def _measure_coarse(ff, steps: int, predicted: List[Dict[str, Any]]
 
 
 # ----------------------------------------------------------------------
-# XPlane mode (real accelerators; falls back when unparseable)
-# ----------------------------------------------------------------------
-
-def _measure_xplane(ff, steps: int, predicted: List[Dict[str, Any]]
-                    ) -> Optional[Dict[str, Any]]:
-    """Profile K compiled steps under ``jax.profiler.trace`` and parse
-    the XPlane output. Returns None whenever the backend is the CPU sim
-    (its XPlane has no device lanes worth attributing) or the profiler
-    protobuf toolchain is not importable — the caller falls back to the
-    instrumented spans mode, which works everywhere."""
-    import jax
-    if jax.default_backend() == "cpu":
-        return None
-    try:  # the parse toolchain is optional by design
-        from tensorflow.core.profiler.protobuf import (  # noqa: F401
-            xplane_pb2)
-    except Exception:  # noqa: BLE001
-        return None
-    import glob
-    import tempfile
-    import jax.numpy as jnp
-    from ..search.optimizer import _synth_batch
-    try:
-        step = ff.executor.make_train_step()
-        cp = jax.tree.map(jnp.array, (ff.params, ff.opt_state, ff.state))
-        p, o, s = cp
-        batch = _synth_batch(ff)
-        p, o, s, bm = step(p, o, s, jnp.int32(0), batch)
-        _sync(bm["loss"])
-        tmp = tempfile.mkdtemp(prefix="ff_attrib_xplane_")
-        with jax.profiler.trace(tmp):
-            for i in range(steps):
-                p, o, s, bm = step(p, o, s, jnp.int32(i + 1), batch)
-                _sync(bm["loss"])
-        pbs = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
-                        recursive=True)
-        if not pbs:
-            return None
-        # per-op lane attribution from XPlane requires the full
-        # tensorboard profiler converter; until a real-pod run wires it
-        # (ROADMAP: real-pod validation), record the artifact path and
-        # let the spans mode supply the per-op side
-        side = _measure_spans(ff, steps, predicted)
-        side["xplane_path"] = pbs[0]
-        return side
-    except Exception:  # noqa: BLE001
-        return None
-
-
-# ----------------------------------------------------------------------
 # measured exposed-comm entry (overlap prediction coverage)
 # ----------------------------------------------------------------------
 
@@ -611,19 +557,16 @@ def run_attribution(ff, steps: Optional[int] = None
     steps = steps if steps is not None else attribution_steps(ff.config)
     t0 = time.perf_counter()
     try:
-        side = _measure_xplane(ff, steps, predicted)
-        if side is None:
-            # pipelined regions and device-subset groups stack member
-            # weights under group keys the per-layer decomposition
-            # cannot address — coarse (compiled-step-wall-only) mode
-            grouped = (ff.executor.pipe is not None
-                       or bool(getattr(ff.strategy, "banks", None))
-                       or bool(getattr(ff.strategy, "place_groups",
-                                       None)))
-            if grouped:
-                side = _measure_coarse(ff, steps, predicted)
-            else:
-                side = _measure_spans(ff, steps, predicted)
+        # pipelined regions and device-subset groups stack member
+        # weights under group keys the per-layer decomposition cannot
+        # address — coarse (compiled-step-wall-only) mode
+        grouped = (ff.executor.pipe is not None
+                   or bool(getattr(ff.strategy, "banks", None))
+                   or bool(getattr(ff.strategy, "place_groups", None)))
+        if grouped:
+            side = _measure_coarse(ff, steps, predicted)
+        else:
+            side = _measure_spans(ff, steps, predicted)
         side["jit_step_wall_s"] = _time_compiled_step(ff, steps)
     except Exception as e:  # noqa: BLE001 — must never kill training
         log.warning("attribution harness failed: %r", e)

@@ -2,11 +2,10 @@
 
 The output loads in ``chrome://tracing``, Perfetto (ui.perfetto.dev),
 and TensorBoard's trace viewer — the same viewers that read the XPlane
-traces ``utils/profiling.profile_region`` produces via ``jax.profiler``,
-so a host-side span trace and a device-side XLA trace of the same run
-can be inspected side by side (they cannot be merged into one file —
-XPlane is a different container — but the shared wall-clock makes the
-phases line up).
+traces ``jax.profiler`` writes. Under a running profiler trace the
+spans are in that XPlane already, as ``ff:<name>`` host events on the
+device lines' clock (``obs/events.py``); this export is the host-only
+view, for runs with no profiler.
 
 Format: the "JSON Array Format" of the Trace Event spec — one complete
 ('X') event per span, one instant ('i') event per point event,

@@ -34,8 +34,9 @@ old loop's semantics (errors and NaNs surface at the step that caused
 them), but still converting each metric exactly once.
 
 Observability: host-blocked milliseconds (window blocks + flush
-fetches) accumulate into the ``ff_host_blocked_ms_total`` gauge, and
-each flush records a ``metrics_buffer.flush`` span when tracing is on.
+fetches) accumulate into the ``ff_host_blocked_ms_total`` gauge; when
+tracing is on each window block is a ``metrics_buffer.window_wait``
+span and each flush's fetch a ``metrics_buffer.flush`` span.
 """
 from __future__ import annotations
 
@@ -148,7 +149,8 @@ class MetricsBuffer:
                 # hot path: accumulate blocked time locally; the
                 # registry gauge is only touched at flush time
                 t0 = time.perf_counter()
-                v.block_until_ready()
+                with obs_events.span("metrics_buffer.window_wait"):
+                    v.block_until_ready()
                 self.blocked_ms += (time.perf_counter() - t0) * 1000.0
 
     def flush(self) -> int:
@@ -160,8 +162,11 @@ class MetricsBuffer:
         entries = list(self._pending)
         self._pending.clear()
         t0 = time.perf_counter()
-        fetched = jax.device_get([bm for _, bm, _ in entries])
-        blocked = time.perf_counter() - t0
+        with obs_events.span("metrics_buffer.flush", steps=len(entries),
+                             window=self.window) as flush_span:
+            fetched = jax.device_get([bm for _, bm, _ in entries])
+            blocked = time.perf_counter() - t0
+            flush_span.set(blocked_ms=round(blocked * 1000.0, 3))
         for (step_idx, _, bsz), vals in zip(entries, fetched):
             vals = dict(vals)
             ok = vals.pop(ALL_FINITE_KEY, None)
@@ -187,8 +192,4 @@ class MetricsBuffer:
             "(metric flushes + in-flight window bounds)"
         ).inc(self.blocked_ms - self._gauge_reported_ms)
         self._gauge_reported_ms = self.blocked_ms
-        obs_events.record_span(
-            "metrics_buffer.flush", t0, blocked,
-            steps=len(entries), window=self.window,
-            blocked_ms=round(blocked * 1000.0, 3))
         return len(entries)

@@ -1,2 +1,2 @@
 from .logger import RecursiveLogger
-from .profiling import Profiler, profile_region
+from .profiling import Profiler

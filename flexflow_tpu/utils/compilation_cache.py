@@ -25,6 +25,7 @@ serving repository's loads, ``chip_smoke.py`` and ``bench.py``'s stages.
 from __future__ import annotations
 
 import os
+import re
 
 CHECKOUT_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), ".jax_cache")
@@ -55,8 +56,38 @@ def enable_compilation_cache() -> str | None:
         # cache key but cannot see inside that blob, so every program
         # holding a kernel missed the cache on the second start. One
         # frame per location is the same whoever calls.
-        jax.config.update("jax_include_full_tracebacks_in_locations", False)
+        one_frame_locations()
+        # With the metadata stripped from the key, a program that differs
+        # from a cached one only in its names (scopes, layer names) loads
+        # the OTHER program's executable, names and all: its
+        # ``as_text()`` and every profiler trace of it then tell of
+        # scopes this program never had, or of none (seen on the chip:
+        # PR 25's parent loaded the step PR 25 had compiled and "had"
+        # its phase scopes). The names are what a trace is read by, so
+        # they are part of the key; with one frame a location they are
+        # the same on every start.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        # ... but not where the checkout lies on disk: a location's file
+        # is named from the checkout's root (unless the deployment has
+        # set a canonicalization of its own)
+        if not jax.config.jax_hlo_source_file_canonicalization_regex:
+            jax.config.update(
+                "jax_hlo_source_file_canonicalization_regex",
+                "^" + re.escape(os.path.dirname(CHECKOUT_CACHE_DIR) + os.sep))
     return path
+
+
+def one_frame_locations() -> None:
+    """Cut every MLIR location to the one frame that made the op. NOT
+    ``jax_include_full_tracebacks_in_locations = False``, which gives
+    the same one frame but loses the name stack on the way to the
+    compiled step: every ``op_name`` came out as the bare primitive
+    (``dot_general``) and every Pallas call as ``tpu_custom_call.N``,
+    so a profiler trace could not tell a layer, a phase or a kernel
+    from another (PERF.md, PR 25)."""
+    import jax
+    jax.config.update("jax_traceback_in_locations_limit", 1)
 
 
 def cache_entries(path: str | None) -> set[str]:

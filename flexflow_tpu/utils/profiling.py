@@ -6,8 +6,9 @@ Reference analogs (SURVEY.md §5):
     a device-to-host fetch — dispatch alone returns before the device
     finishes);
   - ``-lg:prof`` Legion/Realm profiles    → ``jax.profiler`` traces
-    (XPlane, viewable in TensorBoard/Perfetto) via :func:`profile_region`
-    or ``Profiler(trace_dir=...)``;
+    (XPlane, viewable in TensorBoard/Perfetto) via
+    ``Profiler(trace_dir=...)``; the program's ``obs.events.span`` s land
+    in the same trace as ``ff:<name>``;
   - Legion iteration tracing              → jit caching (automatic); the
     profiler records compile (first-call) time separately from steady-state.
 """
@@ -27,19 +28,6 @@ def sync(value: Any) -> None:
     leaves = jax.tree.leaves(value)
     if leaves:
         np.asarray(leaves[-1])
-
-
-@contextlib.contextmanager
-def profile_region(name: str, trace_dir: Optional[str] = None):
-    """jax.profiler trace around a region (reference -lg:prof analog)."""
-    import jax
-    if trace_dir:
-        with jax.profiler.trace(trace_dir):
-            with jax.profiler.TraceAnnotation(name):
-                yield
-    else:
-        with jax.profiler.TraceAnnotation(name):
-            yield
 
 
 class Profiler:

@@ -460,3 +460,170 @@ def test_recursive_logger_thread_safety(capsys):
     # per-thread depth: every inner line is exactly one level deep —
     # never stacked by a sibling thread's concurrent enter()
     assert set(lines) == {"[obs_t] o", "[obs_t]   i"}
+
+
+# ----------------------------------------------------------------------
+# one clock: spans in the profiler's trace, scopes in the train step
+# ----------------------------------------------------------------------
+
+class _FakeAnnotation:
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+
+
+def test_enabled_span_is_a_trace_annotation_named_ff(traced, monkeypatch):
+    import jax
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    with events.span("outer", k=1):
+        with events.span("inner"):
+            pass
+    assert _FakeAnnotation.log == [
+        ("enter", "ff:outer"), ("enter", "ff:inner"),
+        ("exit", "ff:inner"), ("exit", "ff:outer")]
+    assert [e["name"] for e in events.events()] == ["inner", "outer"]
+    # after the fact there is nothing to enter: no annotation
+    events.record_span("late", 0.0, 1.0)
+    assert len(_FakeAnnotation.log) == 4
+
+
+def test_disabled_span_neither_imports_nor_calls_the_profiler(monkeypatch):
+    import sys
+    was_enabled = events.enabled()
+    events.disable()
+    try:
+        # a None entry makes any ``import jax.profiler`` raise
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)
+        with events.span("never") as s:
+            s.set(k=1)
+        with pytest.raises(ImportError):
+            from jax.profiler import TraceAnnotation  # noqa: F401
+    finally:
+        if was_enabled:
+            events.enable()
+
+
+def test_fit_loop_spans_nest_in_fit_epoch_on_one_thread(traced):
+    from flexflow_tpu import FFConfig, FFModel, SGDOptimizer
+    from flexflow_tpu.models import build_mlp
+    cfg = FFConfig()
+    cfg.batch_size = 16
+    cfg.only_data_parallel = True
+    cfg.async_dispatch_steps = 1      # so that the window has to wait
+    ff = FFModel(cfg)
+    out = build_mlp(ff, 16, in_dim=32, hidden=(64,), num_classes=8)
+    ff.compile(SGDOptimizer(0.01), "sparse_categorical_crossentropy", [],
+               output_tensor=out)
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(48, 32)).astype(np.float32)
+    y = rng.integers(0, 8, size=(48, 1)).astype(np.int32)
+
+    class Seen:
+        epochs = []
+
+        def on_epoch_end(self, epoch, report, model):
+            self.epochs.append(epoch)
+
+    events.clear()
+    ff.fit(x=x, y=y, epochs=1, callbacks=[Seen()], verbose=False)
+    assert Seen.epochs == [0]
+    evs = events.events()
+    epoch, = [e for e in evs if e["name"] == "fit.epoch"]
+    assert epoch["attrs"] == {"epoch": 0, "batches": 3}
+    inner = [e for e in evs if e is not epoch and e["name"] != "obs.attribution"]
+    count = {}
+    for e in inner:
+        count[e["name"]] = count.get(e["name"], 0) + 1
+        assert e["tid"] == epoch["tid"], e
+        assert e["ts"] >= epoch["ts"] and \
+            e["ts"] + e["dur"] <= epoch["ts"] + epoch["dur"] + 1e-9, e
+    # three batches: four fetches (the last one ends the epoch), three
+    # dispatches, two waits on the step leaving a window of one
+    assert count == {"fit.loader_next": 4, "executor.train_step": 3,
+                     "metrics_buffer.window_wait": 2,
+                     "metrics_buffer.flush": 1, "fit.callbacks": 1}
+    flush, = [e for e in inner if e["name"] == "metrics_buffer.flush"]
+    assert flush["attrs"]["steps"] == 3 and flush["attrs"]["window"] == 1
+    assert flush["attrs"]["blocked_ms"] >= 0
+    order = [e["name"] for e in sorted(inner, key=lambda e: e["ts"])]
+    assert order[0] == "fit.loader_next" and order[-1] == "fit.callbacks"
+    assert order[-2] == "metrics_buffer.flush"
+
+
+def tiny_gpt2_step_text() -> str:
+    """The lowered train step of a two-layer GPT-2 with the flash kernel
+    forced (interpret mode here), debug locations and all: what carries
+    the scopes and kernel names."""
+    import jax.numpy as jnp
+    from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+    from flexflow_tpu.models.nlp import GPTConfig, build_gpt2
+    cfg = FFConfig()
+    cfg.batch_size = 2
+    cfg.only_data_parallel = True
+    cfg.kernel_impls = "attention:flash"
+    ff = FFModel(cfg)
+    out = build_gpt2(ff, 2, 128, GPTConfig.tiny())
+    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
+               output_tensor=out)
+    ids = np.zeros((2, 128), np.int32)
+    pos = np.tile(np.arange(128, dtype=np.int32), (2, 1))
+    batch = next(iter(ff._combined_loader(
+        [ids, pos], np.zeros((2, 128, 1), np.int32), shuffle=False)))
+    lowered = ff.executor.make_train_step().lower(
+        ff.params, ff.opt_state, ff.state, jnp.int32(0), batch)
+    return lowered.as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def step_text():
+    return tiny_gpt2_step_text()
+
+
+@pytest.mark.parametrize("pattern", [
+    r"jit\(step_fn\)/jvp\(ff\.forward\)/",
+    r"transpose\(jvp\(ff\.forward\)\)/",
+    r"jit\(step_fn\)/jvp\(ff\.loss\)/",
+    r"jit\(step_fn\)/ff\.optimizer/",
+    r"jvp\(ff\.forward\)/lm_head/dot_general",
+    r"transpose\(jvp\(ff\.forward\)\)/lm_head/dot_general",
+    # (a layer without a name of its own is numbered by the process)
+    r"/jvp\(ff\.forward\)/op_multihead_attention_\d+/",
+    r"/transpose\(jvp\(ff\.forward\)\)/op_multihead_attention_\d+/",
+    # (on this 8-device mesh the kernels sit in a shard_map, whose body
+    # starts a name stack of its own: the kernel's name is all it has)
+    r"flash_attention_fwd/pallas_call",
+    r"flash_attention_bwd_dq/pallas_call",
+    r"flash_attention_bwd_dkv/pallas_call"])
+def test_train_step_carries_phase_layer_and_kernel_names(pattern,
+                                                         step_text):
+    import re
+    assert re.search(pattern, step_text)
+
+
+def test_step_text_is_the_same_in_two_fresh_processes():
+    """The compile cache's guard: a scope or kernel name that differed
+    from one process to the next (a counter, an ``id()``) would make
+    every warm start compile the step again."""
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = ("import hashlib, sys; sys.path[:0] = [%r, %r]; import conftest;"
+            " import test_obs; print(hashlib.sha256(test_obs."
+            "tiny_gpt2_step_text().encode()).hexdigest())"
+            % (os.path.dirname(here), here))
+    procs = [subprocess.Popen([sys.executable, "-c", code],
+                              stdout=subprocess.PIPE, text=True)
+             for _ in range(2)]
+    digests = [p.communicate(timeout=300)[0].strip().splitlines()[-1]
+               for p in procs]
+    assert all(p.returncode == 0 for p in procs)
+    assert len(digests[0]) == 64 and digests[0] == digests[1]

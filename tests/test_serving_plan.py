@@ -7,6 +7,7 @@ strategies only and serves whatever falls out."""
 import copy
 import json
 import os
+import re
 import tempfile
 
 import numpy as np
@@ -529,8 +530,16 @@ def test_cache_dir_defaults_to_the_checkout_on_an_accelerator(monkeypatch):
         os.path.abspath(flexflow_tpu.__file__)))
     assert got == cc.CHECKOUT_CACHE_DIR == os.path.join(root, ".jax_cache")
     assert updates["jax_compilation_cache_dir"] == got
-    # (kernel bytes, hence cache keys, must not depend on the call chain)
-    assert updates["jax_include_full_tracebacks_in_locations"] is False
+    # (kernel bytes, hence cache keys, must not depend on the call
+    # chain: one frame a location — and NOT by switching full tracebacks
+    # off, which strips every scope name from the compiled step)
+    assert updates["jax_traceback_in_locations_limit"] == 1
+    # (and an executable is never loaded under another program's names)
+    assert updates["jax_compilation_cache_include_metadata_in_key"] is True
+    # (but not under the place of the checkout on disk)
+    assert re.sub(updates["jax_hlo_source_file_canonicalization_regex"], "",
+                  cc.__file__) == "flexflow_tpu/utils/compilation_cache.py"
+    assert "jax_include_full_tracebacks_in_locations" not in updates
 
 
 def test_no_cache_on_the_cpu_platform_unless_placed(monkeypatch):

@@ -226,6 +226,27 @@ def _check_step_program(ff, x, y, label: str,
     return n_cc
 
 
+def _check_flash_grids(label: str, want: bool) -> None:
+    """The grids the flash kernels were emitted with (``flash.grid``
+    instants, one per traced call): tiles, steps, live and fetched."""
+    from flexflow_tpu.obs import events
+    grids = [dict(g) for g in sorted(
+        {tuple(sorted(e["attrs"].items()))
+         for e in events.events() if e["name"] == "flash.grid"})]
+    for g in grids:
+        say(f"{label}: {g['kernel']} tiles {g['block_q']}x{g['block_k']}, "
+            f"{g['steps']} steps, {g['live_steps']} live, "
+            f"{g['fetched_steps']} fetched")
+        check(g["fetched_steps"] == g["live_steps"] <= g["steps"],
+              f"{label}: {g['kernel']} fetches {g['fetched_steps']} blocks "
+              f"for {g['live_steps']} live steps")
+    if want:
+        check({g["kernel"] for g in grids} >= {
+            "flash_attention_fwd", "flash_attention_bwd_dq",
+            "flash_attention_bwd_dkv"},
+            f"{label}: no flash.grid event for one of the three kernels")
+
+
 def _peak_bytes() -> str:
     import jax
     stats = [d.memory_stats() for d in jax.local_devices()]
@@ -301,6 +322,7 @@ def leg_gpt2_kernels(gpt_cfg, seq: int, per_chip_batch: int) -> int:
               f"seq {seq}")
     n_flash = _check_step_program(ff, x, y, "B/gpt2",
                                   want_custom_call=chip)
+    _check_flash_grids("B/gpt2", want=chip)
     _check_generate(ff, ids)
     _check_fused_adam(ff)
     say(f"B/gpt2: per-chip batch {per_chip_batch} (global "

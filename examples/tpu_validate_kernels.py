@@ -11,7 +11,10 @@ differently-blocked backward could not regenerate.)
 Checks (each prints PASS/FAIL, exit code 1 on any failure):
   1. fwd numerics vs the plain-XLA golden, f32 + bf16, causal on/off,
      unpadded (512) and padded (393) sequence lengths;
-  2. full vjp (dq/dk/dv) vs jax.grad of the golden;
+  2. full vjp (dq/dk/dv) vs jax.grad of the golden, the backward
+     kernels at the tiles derived from the shapes (printed), and once at
+     the benchmark's cell-2 shapes (bh 144, s 1024, d 64, bf16, causal)
+     against the golden at ``highest`` precision;
   3. dropout>0: deterministic under one seed, decorrelated across seeds,
      empirical keep-rate ≈ 1-rate, and vjp matches jax.grad of an
      explicit-masked golden built from the kernel's own keep-mask.
@@ -24,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.kernels import flash_attention, mha_reference
+from flexflow_tpu.obs import events
 
 FAILED = []
 
@@ -32,6 +36,16 @@ def check(name, ok, detail=""):
     print(f"{'PASS' if ok else 'FAIL'} {name} {detail}", flush=True)
     if not ok:
         FAILED.append(name)
+
+
+def tiles():
+    """The tiles of the backward kernels emitted last (``flash.grid``)."""
+    last = {e["attrs"]["kernel"]: e["attrs"] for e in events.events()
+            if e["name"] == "flash.grid"}
+    return " ".join(
+        f"{name} {last[kernel]['block_q']}x{last[kernel]['block_k']}"
+        for name, kernel in (("dq", "flash_attention_bwd_dq"),
+                             ("dkv", "flash_attention_bwd_dkv")))
 
 
 def rel_err(a, b):
@@ -43,6 +57,7 @@ def rel_err(a, b):
 def main():
     from flexflow_tpu.utils.compilation_cache import enable_compilation_cache
     enable_compilation_cache()   # no FFModel.compile here to do it
+    events.enable()              # flash.grid: the tiles of each call
     backend = jax.default_backend()
     print(f"backend={backend} devices={jax.devices()}", flush=True)
     if backend != "tpu":
@@ -96,10 +111,35 @@ def main():
                 g_ref = jax.grad(lambda *x: loss(mha_reference, *x),
                                  argnums=(0, 1, 2))(q, k, v)
                 worst = max(rel_err(a, b_) for a, b_ in zip(g, g_ref))
-                check(f"bwd {tag}", worst < tol_g, f"rel={worst:.2e}")
+                check(f"bwd {tag}", worst < tol_g,
+                      f"rel={worst:.2e} tiles {tiles()}")
+
+    # the benchmark's cell 2: 12 x 12 heads of 1024 x 64, bf16, causal,
+    # against the golden at HIGHEST precision, bf16 tolerances as above
+    q, k, v = (jnp.asarray(rng.normal(size=(12, 12, 1024, 64)), jnp.bfloat16)
+               for _ in range(3))
+
+    def loss2(f, a, b_, c, **kw):
+        return jnp.sum(f(a, b_, c, causal=True, **kw).astype(jnp.float32)
+                       ** 2)
+
+    hi = dict(precision=jax.lax.Precision.HIGHEST)
+    o = flash_attention(q, k, v, causal=True)
+    rel = rel_err(o, mha_reference(q, k, v, causal=True, **hi))
+    check("fwd cell2 vs HIGHEST", rel < 2e-2, f"rel={rel:.2e}")
+    g = jax.grad(lambda *x: loss2(flash_attention, *x),
+                 argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *x: loss2(mha_reference, *x, **hi),
+                     argnums=(0, 1, 2))(q, k, v)
+    rels = [rel_err(a, b_) for a, b_ in zip(g, g_ref)]
+    check("bwd cell2 vs HIGHEST", max(rels) < 4e-2,
+          "rel dq={:.2e} dk={:.2e} dv={:.2e} tiles ".format(*rels)
+          + tiles())
 
     # -- 3: in-kernel dropout (TPU-only path) ---------------------------
-    b, h, seq, d = 2, 4, 256, 64
+    # seq 1024: a (512, 512) forward under backward tiles of another
+    # shape, which have to regenerate the same mask
+    b, h, seq, d = 2, 4, 1024, 64
     rate = 0.2
     q = jnp.asarray(rng.normal(size=(b, h, seq, d)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, h, seq, d)), jnp.float32)
@@ -161,7 +201,7 @@ def main():
     g_g = jax.grad(loss_g, argnums=(0, 1, 2))(q, k, v)
     worst = max(rel_err(a, b_) for a, b_ in zip(g_k, g_g))
     check("dropout vjp vs explicit-mask golden", worst < 2e-2,
-          f"rel={worst:.2e}")
+          f"rel={worst:.2e} tiles {tiles()}")
 
     print(f"\n{len(FAILED)} failures" if FAILED else "\nALL PASS")
     return 1 if FAILED else 0

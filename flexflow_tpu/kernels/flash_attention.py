@@ -23,6 +23,7 @@ kernel is unit-testable on CPU.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from typing import Optional
 
@@ -32,6 +33,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import events
 from ._interpret import pallas_interpret
 
 NEG_INF = -1e30
@@ -63,10 +65,11 @@ def _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k, rate):
 
     keep[i, j] is a pure hash of (seed, batch-head, ABSOLUTE query
     position, ABSOLUTE key position) — independent of the tiling — so
-    the forward (512x512 blocks) and backward (128x128 blocks) kernels
-    regenerate bit-identical masks. Found compiling on a real v5e: a
-    pltpu-PRNG mask seeded per (b, iq, ik) tile cannot be reproduced by
-    a differently-blocked backward pass, which silently corrupted dq
+    the forward (512x512 blocks) and the backward kernels (their own
+    tiles, :func:`bwd_tiles`) regenerate bit-identical masks. Found
+    compiling on a real v5e: a pltpu-PRNG mask seeded per (b, iq, ik)
+    tile cannot be reproduced by a differently-blocked backward pass,
+    which silently corrupted dq
     (and Mosaic's prng_set_seed_32 takes at most two seed words anyway).
     A position hash also lowers in interpret mode, so CPU CI now covers
     the dropout path. Mix: odd-constant multiplies folded by xor, then
@@ -105,7 +108,8 @@ def _position_keep(seed, bh, q_pos, k_pos, rate):
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_sc, m_sc, l_sc, *, sm_scale, causal,
                 kv_len, block_q, block_k, dropout_rate):
-    iq = pl.program_id(1)
+    b = pl.program_id(0)     # read out here: interpret mode has no
+    iq = pl.program_id(1)    # program_id inside pl.when's cond
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -137,7 +141,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # numerator (matches dropout-on-probs semantics)
         l_new = l_sc[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
         if dropout_rate > 0.0:
-            keep = _tile_keep_mask(seed_ref, pl.program_id(0), iq, ik,
+            keep = _tile_keep_mask(seed_ref, b, iq, ik,
                                    block_q, block_k, dropout_rate)
             p_eff = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
         else:
@@ -164,7 +168,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_sc, *, sm_scale, causal, kv_len, block_q,
                    block_k, dropout_rate):
-    iq = pl.program_id(1)
+    b = pl.program_id(0)     # read out here: interpret mode has no
+    iq = pl.program_id(1)    # program_id inside pl.when's cond
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -189,7 +194,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if dropout_rate > 0.0:
-            keep = _tile_keep_mask(seed_ref, pl.program_id(0), iq, ik,
+            keep = _tile_keep_mask(seed_ref, b, iq, ik,
                                    block_q, block_k, dropout_rate)
             dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
         ds = p * (dp - delta) * sm_scale
@@ -205,6 +210,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, sm_scale,
                     causal, kv_len, block_q, block_k, dropout_rate):
+    b = pl.program_id(0)
     ik = pl.program_id(1)
     iq = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -230,7 +236,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         p = jnp.exp(s - lse)                             # (bq, bk)
         if dropout_rate > 0.0:
             # same (seed, b, iq, ik) tuple as forward → identical mask
-            keep = _tile_keep_mask(seed_ref, pl.program_id(0), iq, ik,
+            keep = _tile_keep_mask(seed_ref, b, iq, ik,
                                    block_q, block_k, dropout_rate)
             p_eff = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
         else:
@@ -262,16 +268,82 @@ _SEED_SPEC = pl.BlockSpec((1, 1), lambda b, i, j: (0, 0),
                           memory_space=pltpu.SMEM)
 
 
+def _last_live_k(iq, block_q, block_k):
+    """Last k block the causal ``live`` test of q block ``iq`` passes."""
+    return ((iq + 1) * block_q - 1) // block_k
+
+
+def _first_live_q(ik, block_q, block_k):
+    """First q block the causal ``live`` test of k block ``ik`` passes."""
+    return (ik * block_k) // block_q
+
+
 def _q_spec(block_q, d):
     return pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
 
 
-def _k_spec(block_k, d):
-    return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+def _k_spec(block_q, block_k, d, causal):
+    """k/v blocks of the (bh, nq, nk) grids. A step above the causal
+    diagonal, whose arithmetic ``pl.when(live)`` skips, names the row's
+    last live block again, so the pipeline issues no copy for it."""
+    if not causal:
+        return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    return pl.BlockSpec(
+        (1, block_k, d),
+        lambda b, i, j: (b, jnp.minimum(j, _last_live_k(i, block_q, block_k)),
+                         0))
 
 
 def _row_spec(block_q):
     return pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
+
+
+def _dkv_specs(block_q, block_k, d, causal):
+    """(q/do, k/v, row statistics) specs of the (bh, nk, nq) dkv grid:
+    the index maps swap the roles of grid axes 1 and 2, and a dead causal
+    step names the column's first live q block (see _k_spec)."""
+    if causal:
+        def iq(j, i):
+            return jnp.maximum(i, _first_live_q(j, block_q, block_k))
+    else:
+        def iq(j, i):
+            return i
+    return (pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, iq(j, i), 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_q, 128),
+                         lambda b, j, i: (b, iq(j, i), 0)))
+
+
+def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
+    """What the grid of one call costs. ``steps``: all of them, each paid
+    for; ``live_steps``: those whose arithmetic runs; ``fetched_steps``:
+    those that name another streamed block (k/v for ``fwd`` and
+    ``bwd_dq``, q/do/row statistics for ``bwd_dkv``) than the step before
+    in their row of the grid, so that the pipeline copies one. A row's
+    first step counts as a fetch (Mosaic skips that one too where the
+    row before ended on the same block)."""
+    nq, nk = sq // block_q, sk // block_k
+    live = fetched = 0
+    if kernel == "bwd_dkv":            # rows are k blocks, q blocks stream
+        for j in range(nk):
+            first = _first_live_q(j, block_q, block_k) if causal else 0
+            live += nq - first
+            fetched += len({max(i, first) for i in range(nq)})
+    else:                              # rows are q blocks, k blocks stream
+        for i in range(nq):
+            last = _last_live_k(i, block_q, block_k) if causal else nk - 1
+            live += min(nk, last + 1)
+            fetched += len({min(j, last) for j in range(nk)})
+    return {"kernel": "flash_attention_" + kernel, "block_q": block_q,
+            "block_k": block_k, "steps": bh * nq * nk,
+            "live_steps": bh * live, "fetched_steps": bh * fetched}
+
+
+def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal):
+    """One ``flash.grid`` instant per emitted call, at trace time."""
+    if events.enabled():
+        events.instant("flash.grid", **grid_steps(
+            kernel, bh, sq, sk, block_q, block_k, causal))
 
 
 def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
@@ -282,11 +354,12 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         kv_len=kv_len, block_q=block_q, block_k=block_k,
         dropout_rate=dropout_rate)
+    ks = _k_spec(block_q, block_k, d, causal)
+    _note_grid("fwd", bh, sq, sk, block_q, block_k, causal)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, sq // block_q, sk // block_k),
-        in_specs=[_SEED_SPEC, _q_spec(block_q, d), _k_spec(block_k, d),
-                  _k_spec(block_k, d)],
+        in_specs=[_SEED_SPEC, _q_spec(block_q, d), ks, ks],
         out_specs=[_q_spec(block_q, d), _row_spec(block_q)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
@@ -303,35 +376,14 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
     return o, lse[:, :, 0]
 
 
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
-def _flash(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
-           bwd_block_q, bwd_block_k, dropout_rate, interpret):
-    o, _ = _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                     block_k, dropout_rate, interpret)
-    return o
-
-
-def _flash_fwd_rule(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                    block_k, bwd_block_q, bwd_block_k, dropout_rate,
-                    interpret):
-    o, lse = _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                       block_k, dropout_rate, interpret)
-    return o, (q, k, v, seed, o, lse)
-
-
-def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
-                    block_q, block_k, dropout_rate, interpret, res, do):
-    q, k, v, seed, o, lse = res
+def _bwd_dq_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
+                 causal, block_q, block_k, dropout_rate, interpret):
     bh, sq, d = q.shape
     sk = k.shape[1]
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    lse_b = jnp.broadcast_to(lse[:, :, None], (bh, sq, 128))
-    delta_b = jnp.broadcast_to(delta[:, :, None], (bh, sq, 128))
     row = _row_spec(block_q)
-    qs, ks = _q_spec(block_q, d), _k_spec(block_k, d)
-
-    dq = pl.pallas_call(
+    qs, ks = _q_spec(block_q, d), _k_spec(block_q, block_k, d, causal)
+    _note_grid("bwd_dq", bh, sq, sk, block_q, block_k, causal)
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
                           dropout_rate=dropout_rate),
@@ -344,18 +396,19 @@ def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
         name="flash_attention_bwd_dq",
     )(seed, q, k, v, do, lse_b, delta_b)
 
-    # dkv grid: (bh, nk, nq) — index maps swap the roles of grid axes 1/2
-    seed2 = pl.BlockSpec((1, 1), lambda b, j, i: (0, 0),
-                         memory_space=pltpu.SMEM)
-    qs2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    ks2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    row2 = pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
+
+def _bwd_dkv_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
+                  causal, block_q, block_k, dropout_rate, interpret):
+    bh, sq, d = q.shape
+    sk = k.shape[1]
+    qs2, ks2, row2 = _dkv_specs(block_q, block_k, d, causal)
+    _note_grid("bwd_dkv", bh, sq, sk, block_q, block_k, causal)
+    return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
                           dropout_rate=dropout_rate),
         grid=(bh, sk // block_k, sq // block_q),
-        in_specs=[seed2, qs2, ks2, ks2, qs2, row2, row2],
+        in_specs=[_SEED_SPEC, qs2, ks2, ks2, qs2, row2, row2],
         out_specs=[ks2, ks2],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
@@ -368,10 +421,110 @@ def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
         interpret=interpret,
         name="flash_attention_bwd_dkv",
     )(seed, q, k, v, do, lse_b, delta_b)
+
+
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
+def _flash(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
+           dq_blocks, dkv_blocks, dropout_rate, interpret):
+    o, _ = _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
+                     block_k, dropout_rate, interpret)
+    return o
+
+
+def _flash_fwd_rule(q, k, v, seed, kv_len, sm_scale, causal, block_q,
+                    block_k, dq_blocks, dkv_blocks, dropout_rate,
+                    interpret):
+    o, lse = _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
+                       block_k, dropout_rate, interpret)
+    return o, (q, k, v, seed, o, lse)
+
+
+def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
+                    dq_blocks, dkv_blocks, dropout_rate, interpret, res,
+                    do):
+    q, k, v, seed, o, lse = res
+    bh, sq, _ = q.shape
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    lse_b = jnp.broadcast_to(lse[:, :, None], (bh, sq, 128))
+    delta_b = jnp.broadcast_to(delta[:, :, None], (bh, sq, 128))
+    operands = (seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale, causal)
+    dq = _bwd_dq_call(*operands, *dq_blocks, dropout_rate, interpret)
+    dk, dv = _bwd_dkv_call(*operands, *dkv_blocks, dropout_rate, interpret)
     return dq, dk, dv, np.zeros(seed.shape, dtype=jax.dtypes.float0)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
+# backward tiles
+# ---------------------------------------------------------------------------
+#: What the working set of one backward grid step may take, as
+#: :func:`_bwd_vmem_bytes` counts it: Mosaic compiles a v5e kernel under a
+#: scoped-VMEM limit of 16 MiB, and of 480 compiles for a described v5e
+#: (both kernels, bf16 / f32, d 64 / 128 / 256, dropout, causal, ten tiles
+#: of 256 to 2048 a side) every one it refused counts 16.0 MiB or more.
+BWD_VMEM_BUDGET = 15 * 1024 * 1024
+#: the widest tile timed on the chip (PERF.md section 6, PR 28)
+MAX_BWD_TILE = 1024
+
+
+def _bwd_vmem_bytes(kernel, block_q, block_k, d, itemsize, dropout):
+    """Working set of one grid step of ``bwd_dq`` / ``bwd_dkv``: the
+    double-buffered operand and output blocks, the f32 scratch
+    accumulators, a staging copy of the q-side and k-side blocks at twice
+    their width around the matmuls, and the (block_q, block_k)
+    intermediates: of ``s``, ``p``, ``dp``, ``ds``, the masks and the
+    casts Mosaic streams through registers and keeps about one f32 tile,
+    one more for the dropout hash and keep mask."""
+    lanes = -(-d // 128) * 128               # a (rows, 64) block fills 128
+    q_side = 2 * block_q * lanes * itemsize + 2 * block_q * 128 * 4
+    k_side = 2 * block_k * lanes * itemsize  # k, v
+    if kernel == "bwd_dq":
+        out, scratch = block_q * lanes * itemsize, block_q * lanes * 4
+    else:
+        out, scratch = 2 * block_k * lanes * itemsize, 2 * block_k * lanes * 4
+    stage = (block_q + block_k) * lanes * 2 * itemsize
+    tiles = (2 if dropout else 1) * block_q * block_k * 4
+    return 2 * (q_side + k_side + out) + scratch + stage + tiles
+
+
+def _tile_sizes(padded):
+    """Multiples of 128 that divide ``padded``; a sequence with none
+    (shorter than 128, or not a multiple of it) is one tile."""
+    sizes = [t for t in range(128, min(padded, MAX_BWD_TILE) + 1, 128)
+             if padded % t == 0]
+    return sizes or [padded]
+
+
+def bwd_tiles(sq, sk, d, dtype, dropout):
+    """``((block_q, block_k) of bwd_dq, (block_q, block_k) of bwd_dkv)``
+    for padded sequence lengths ``sq``, ``sk`` and padded head dim ``d``:
+    per kernel the tile of the most pairs whose working set fits
+    ``BWD_VMEM_BUDGET``, and of two such the one with the wider resident
+    side (dq holds a q block while k blocks stream, dkv a k block). On a
+    v5e a grid step costs 0.3-0.4 us whatever it does and a live tile 4-6
+    ns per 1,024 pairs, so fewer and larger steps won at every shape
+    timed, causal or not: one 1,024-wide causal tile computes twice the
+    pairs it needs and still beats three 512-wide ones (PERF.md section
+    6, PR 28)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    out = []
+    for kernel, resident in (("bwd_dq", 0), ("bwd_dkv", 1)):
+        tiles = list(itertools.product(_tile_sizes(sq), _tile_sizes(sk)))
+        fits = [t for t in tiles
+                if _bwd_vmem_bytes(kernel, *t, d, itemsize, dropout)
+                <= BWD_VMEM_BUDGET] or tiles[:1]      # the smallest there is
+        out.append(max(fits, key=lambda t: (t[0] * t[1], t[resident])))
+    return tuple(out)
+
+
+def _explicit_block(bwd_block, fwd_block):
+    """A caller's backward block, made to tile the sequence the forward's
+    block padded: no larger than that block, and a divisor of it."""
+    bwd_block = min(bwd_block, fwd_block)
+    return fwd_block if fwd_block % bwd_block else bwd_block
 
 
 # ---------------------------------------------------------------------------
@@ -382,7 +535,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     dropout_rate: float = 0.0,
                     dropout_seed=None,
                     block_q: int = 512, block_k: int = 512,
-                    bwd_block_q: int = 128, bwd_block_k: int = 128,
+                    bwd_block_q: Optional[int] = None,
+                    bwd_block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     mesh=None, spec=None):
     """Tiled flash attention. q: (b, h, sq, d); k, v: (b, h, sk, d).
@@ -393,10 +547,17 @@ def flash_attention(q, k, v, *, causal: bool = False,
     applies in-kernel counter-based dropout to the attention
     probabilities (requires ``dropout_seed``, an int32 scalar).
 
-    Block defaults are measured on v5e (head_dim 64): the forward wants
-    large tiles (512x512 — k/v are re-streamed once per q block, so
-    bigger q blocks cut HBM traffic); the backward wants small ones
-    (128x128 — its dq/dkv scratch accumulators serialize the grid).
+    The forward's block default is measured on v5e (head_dim 64): large
+    tiles (512x512 — k/v are re-streamed once per q block, so bigger q
+    blocks cut HBM traffic). ``bwd_block_q`` / ``bwd_block_k`` = None
+    take each backward kernel's tile from the shapes (:func:`bwd_tiles`);
+    a value given is used by both. Timed on a v5e over 128 to 1024 a
+    side (PERF.md section 6, PR 28): at bh 144, s 1024, d 64, causal the
+    dq kernel takes 3.09 ms a call at 128x128 and 0.66 at 1024x1024, dkv
+    3.58 and 0.91; the largest tile that compiles won, or came within 3%
+    of the winner, at every shape. Under ``causal`` a grid step above
+    the diagonal is skipped and names its neighbour's k/v (q, in dkv)
+    block again, so nothing is copied for it.
 
     ``mesh`` / ``spec``: inside a multi-device ``jit`` GSPMD cannot
     partition a Mosaic kernel, so with a mesh of more than one device
@@ -423,13 +584,6 @@ def flash_attention(q, k, v, *, causal: bool = False,
     # lane mult of 128
     block_q = min(block_q, -(-sq // 8) * 8)
     block_k = min(block_k, -(-sk // 128) * 128)
-    # bwd blocks must tile the (block_q/block_k-padded) seq dims exactly
-    bwd_block_q = min(bwd_block_q, block_q)
-    bwd_block_k = min(bwd_block_k, block_k)
-    if block_q % bwd_block_q:
-        bwd_block_q = block_q
-    if block_k % bwd_block_k:
-        bwd_block_k = block_k
 
     # head_dim: pad only to a multiple of 64. d=64 (BERT/GPT-class) stays
     # unpadded — padding to the full 128 lane width doubled k/v HBM
@@ -441,6 +595,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
     vp = _pad_to(_pad_to(v, block_k, 2), 64, 3)
     sq_p, d_p = qp.shape[2], qp.shape[3]
     sk_p = kp.shape[2]
+    dq_blocks, dkv_blocks = bwd_tiles(sq_p, sk_p, d_p, q.dtype,
+                                      dropout_rate > 0.0)
+    # an explicit backward block wins, for both kernels
+    if bwd_block_q is not None:
+        bq = _explicit_block(bwd_block_q, block_q)
+        dq_blocks, dkv_blocks = (bq, dq_blocks[1]), (bq, dkv_blocks[1])
+    if bwd_block_k is not None:
+        bk = _explicit_block(bwd_block_k, block_k)
+        dq_blocks, dkv_blocks = (dq_blocks[0], bk), (dkv_blocks[0], bk)
 
     if dropout_seed is None:
         seed = jnp.zeros((1, 1), jnp.int32)
@@ -450,7 +613,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
                kp.reshape(b * h, sk_p, d_p),
                vp.reshape(b * h, sk_p, d_p),
                seed, sk, sm_scale, causal, block_q, block_k,
-               bwd_block_q, bwd_block_k, float(dropout_rate), interpret)
+               dq_blocks, dkv_blocks, float(dropout_rate), interpret)
     return o.reshape(b, h, sq_p, d_p)[:, :, :sq, :d]
 
 

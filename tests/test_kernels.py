@@ -1,6 +1,8 @@
 """Numerics tests for the Pallas kernels (interpret mode on CPU) and the
 sequence-parallel attention schemes (shard_map over virtual devices)."""
 import functools
+import importlib
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +12,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from flexflow_tpu.kernels import (flash_attention, mha_reference,
                                   ring_attention, ulysses_attention)
+from flexflow_tpu.obs import events
 from jax import shard_map
+
+# (the package binds the name ``flash_attention`` to the function)
+fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
 
 
 def _rand_qkv(b=2, h=4, s=256, d=64, dtype=jnp.float32, seed=0):
@@ -326,3 +332,247 @@ def test_dropout_keep_mask_matches_kernel():
                         jnp.where(keep, p / (1 - rate), 0.0), v)
     np.testing.assert_allclose(np.asarray(o), np.asarray(golden),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# backward tiles derived from the shapes, and no fetch for a dead causal
+# block (the compiled kernels: tests/test_tpu_aot_compile.py, chip_smoke.py)
+# ---------------------------------------------------------------------------
+def _grids_of(fn, *args):
+    """The ``flash.grid`` instants that tracing ``fn(*args)`` records."""
+    was_on = events.enabled()
+    events.enable()
+    events.clear()
+    try:
+        jax.eval_shape(fn, *args)
+        return {e["attrs"]["kernel"]: e["attrs"] for e in events.events()
+                if e["name"] == "flash.grid"}
+    finally:
+        events.clear()
+        if not was_on:
+            events.disable()
+
+
+def _qkv(sq, sk, d, h=1, seed=0):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((1, h, s, d)), jnp.float32)
+                 for s in (sq, sk, sk))
+
+
+DERIVED_CASES = {
+    # cell 2's shapes: (512, 512) forward, two blocks a side
+    "s1024_d64_causal": dict(sq=1024, sk=1024, d=64, causal=True),
+    "s512": dict(sq=512, sk=512, d=64, causal=False),
+    "s380_padded_causal": dict(sq=380, sk=380, d=64, causal=True),
+    "ragged_256_640": dict(sq=256, sk=640, d=64, causal=False),
+    "d128_causal": dict(sq=256, sk=256, d=128, causal=True),
+    "short_100": dict(sq=100, sk=100, d=32, causal=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DERIVED_CASES))
+def test_flash_gradients_at_derived_tiles(case):
+    c = DERIVED_CASES[case]
+    q, k, v = _qkv(c["sq"], c["sk"], c["d"])
+
+    def loss(f, **kw):
+        return lambda q, k, v: jnp.sum(f(q, k, v, causal=c["causal"],
+                                         **kw) ** 2)
+
+    grad_flash = jax.grad(loss(flash_attention, interpret=True),
+                          argnums=(0, 1, 2))
+    assert sorted(_grids_of(grad_flash, q, k, v)) == [
+        "flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+        "flash_attention_fwd"]
+    g = grad_flash(q, k, v)
+    g_ref = jax.grad(loss(mha_reference), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   atol=1e-3, rtol=1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_gqa_gradients_at_derived_tiles(causal):
+    q, k, v = _gqa_qkv(s=256)
+    loss = lambda f, **kw: (lambda q, k, v: jnp.sum(  # noqa: E731
+        f(q, k, v, causal=causal, **kw) ** 2))
+    g = jax.grad(loss(flash_attention, interpret=True, block_q=128,
+                      block_k=128), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(mha_reference), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   atol=5e-4, rtol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_dropout_gradients_with_bwd_tiles_unlike_fwd(causal):
+    """Dropout 0.1, forward at 128-wide blocks, backward at the derived
+    ones (256): the position hash has to give both the same mask. Golden:
+    the explicit mask in plain XLA."""
+    import math
+    from flexflow_tpu.kernels import dropout_keep_mask
+    b, h, s, d, rate, seed = 1, 2, 256, 64, 0.1, 5
+    q, k, v = _rand_qkv(b, h, s, d)
+    kw = dict(causal=causal, dropout_rate=rate, dropout_seed=seed,
+              interpret=True, block_q=128, block_k=128)
+    grids = _grids_of(jax.grad(lambda *x: jnp.sum(
+        flash_attention(*x, **kw))), q, k, v)
+    assert (grids["flash_attention_fwd"]["block_q"],
+            grids["flash_attention_bwd_dq"]["block_q"],
+            grids["flash_attention_bwd_dkv"]["block_k"]) == (128, 256, 256)
+
+    def golden(q, k, v):
+        s_ = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(d)
+        if causal:
+            s_ = jnp.where(np.tril(np.ones((s, s), bool)), s_, -1e30)
+        p = jax.nn.softmax(s_, -1)
+        keep = dropout_keep_mask(b, h, s, s, rate, seed)
+        return jnp.einsum("bhqk,bhkd->bhqd",
+                          jnp.where(keep, p / (1 - rate), 0.0), v)
+
+    g = jax.grad(lambda *x: jnp.sum(flash_attention(*x, **kw) ** 2),
+                 argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *x: jnp.sum(golden(*x) ** 2),
+                     argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   atol=1e-3, rtol=1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("s,block", [(1024, 512), (512, 128), (384, 128)])
+def test_flash_forward_bit_identical_without_fetch_elision(s, block,
+                                                           monkeypatch):
+    """The index maps decide which block a dead step names, never what a
+    live step computes: output and log-sum-exp to the last bit."""
+    rng = np.random.default_rng(1)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, s, 64)), jnp.float32)
+               for _ in range(3))
+    seed = jnp.zeros((1, 1), jnp.int32)
+    args = (q, k, v, seed, s, 0.125, True, block, block, 0.0, True)
+    o, lse = fa._fwd_call(*args)
+    plain = fa._k_spec
+    monkeypatch.setattr(
+        fa, "_k_spec", lambda bq, bk, d, causal: plain(bq, bk, d, False))
+    o0, lse0 = fa._fwd_call(*args)
+    assert jnp.array_equal(o, o0) and jnp.array_equal(lse, lse0)
+
+
+TILE_RULE_CASES = [(sq, sk, d, dt, drop)
+                   for sq, sk in ((1024, 1024), (512, 512), (384, 384),
+                                  (256, 1024), (2048, 2048), (4096, 4096),
+                                  (1536, 1536), (104, 128), (400, 512))
+                   for d in (64, 128, 256)
+                   for dt in ("bfloat16", "float32")
+                   for drop in (False, True)]
+
+
+@pytest.mark.parametrize("sq,sk,d,dtype,dropout", TILE_RULE_CASES)
+def test_bwd_tile_rule(sq, sk, d, dtype, dropout):
+    tiles = fa.bwd_tiles(sq, sk, d, jnp.dtype(dtype), dropout)
+    assert len(tiles) == 2
+    for kernel, (bq, bk) in zip(("bwd_dq", "bwd_dkv"), tiles):
+        assert sq % bq == 0 and sk % bk == 0
+        assert bq % 128 == 0 or bq == sq
+        assert bk % 128 == 0 or bk == sk
+        assert max(bq, bk) <= max(fa.MAX_BWD_TILE, min(sq, sk))
+        assert fa._bwd_vmem_bytes(
+            kernel, bq, bk, d, jnp.dtype(dtype).itemsize,
+            dropout) <= fa.BWD_VMEM_BUDGET
+        # the largest that fits: no admissible tile holds more pairs
+        for t in itertools.product(fa._tile_sizes(sq), fa._tile_sizes(sk)):
+            if t[0] * t[1] > bq * bk:
+                assert fa._bwd_vmem_bytes(
+                    kernel, *t, d, jnp.dtype(dtype).itemsize,
+                    dropout) > fa.BWD_VMEM_BUDGET
+
+
+def test_bwd_tile_rule_at_the_shapes_timed_on_the_chip():
+    """PERF.md section 6, PR 28: the tile the rule picks is the fastest
+    or within 5% of it at each (padded s, d) of the table, bf16."""
+    big = ((1024, 1024), (1024, 1024))
+    assert fa.bwd_tiles(1024, 1024, 64, jnp.bfloat16, False) == big
+    assert fa.bwd_tiles(2048, 2048, 64, jnp.bfloat16, False) == big
+    assert fa.bwd_tiles(1024, 1024, 128, jnp.bfloat16, False) == big
+    assert fa.bwd_tiles(512, 512, 64, jnp.bfloat16, False) == (
+        (512, 512), (512, 512))
+    # equal pairs: dq takes the taller tile, dkv the wider
+    assert fa.bwd_tiles(2048, 2048, 256, jnp.bfloat16, True) == (
+        (1024, 512), (512, 1024))
+
+
+@pytest.mark.parametrize("explicit,want", [
+    (dict(), None),
+    (dict(bwd_block_q=128, bwd_block_k=128), (128, 128)),
+    (dict(bwd_block_q=256), (256, None)),
+    (dict(bwd_block_k=128), (None, 128)),
+    # one that does not divide the forward's block falls to it, as before
+    (dict(block_q=256, block_k=256, bwd_block_q=96, bwd_block_k=384),
+     (256, 256)),
+])
+def test_explicit_bwd_blocks_win(explicit, want):
+    q, k, v = _qkv(512, 512, 64)
+    grids = _grids_of(jax.grad(lambda *x: jnp.sum(flash_attention(
+        *x, causal=True, interpret=True, **explicit))), q, k, v)
+    derived = fa.bwd_tiles(512, 512, 64, q.dtype, False)
+    for name, rule in zip(("bwd_dq", "bwd_dkv"), derived):
+        g = grids["flash_attention_" + name]
+        exp = tuple(r if w is None else w
+                    for r, w in zip(rule, want or (None, None)))
+        assert (g["block_q"], g["block_k"]) == exp
+
+
+GRIDS = [(1024, 128, 128), (1024, 512, 512), (1024, 256, 128),
+         (1024, 128, 512), (2048, 512, 256)]
+
+
+@pytest.mark.parametrize("s,bq,bk", GRIDS)
+def test_causal_index_maps_name_the_nearest_live_block(s, bq, bk):
+    nq, nk = s // bq, s // bk
+    k_map = fa._k_spec(bq, bk, 64, True).index_map
+    q_spec, k_spec2, row_spec = fa._dkv_specs(bq, bk, 64, True)
+    fetched_k = fetched_q = live = 0
+    for i in range(nq):
+        seen = set()
+        for j in range(nk):
+            is_live = j * bk <= (i + 1) * bq - 1        # the kernels' test
+            live += is_live
+            b, blk, z = (int(x) for x in k_map(3, i, j))
+            assert (b, z) == (3, 0)
+            last_live = max(jj for jj in range(nk)
+                            if jj * bk <= (i + 1) * bq - 1)
+            assert blk == (j if is_live else last_live)
+            seen.add(blk)
+        fetched_k += len(seen)
+    for j in range(nk):
+        seen = set()
+        for i in range(nq):
+            is_live = (i + 1) * bq - 1 >= j * bk
+            first_live = min(ii for ii in range(nq)
+                             if (ii + 1) * bq - 1 >= j * bk)
+            for spec in (q_spec, row_spec):
+                b, blk, z = (int(x) for x in spec.index_map(3, j, i))
+                assert (b, z) == (3, 0)
+                assert blk == (i if is_live else first_live)
+            assert tuple(int(x) for x in k_spec2.index_map(3, j, i)) \
+                == (3, j, 0)
+            seen.add(blk)
+        fetched_q += len(seen)
+    assert fetched_k == fetched_q == live < nq * nk
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        g = fa.grid_steps(kernel, 5, s, s, bq, bk, True)
+        assert g["steps"] == 5 * nq * nk
+        assert g["fetched_steps"] == g["live_steps"] == 5 * live
+        g = fa.grid_steps(kernel, 5, s, s, bq, bk, False)
+        assert g["fetched_steps"] == g["live_steps"] == g["steps"]
+
+
+def test_grid_steps_of_cell_2_before_and_after():
+    """ISSUE 28's arithmetic: 9,216 steps a call at 128 x 128, 5,184 of
+    them live, every one fetched; the same call at the derived tiles."""
+    old = fa.grid_steps("bwd_dq", 144, 1024, 1024, 128, 128, True)
+    assert (old["steps"], old["live_steps"]) == (9216, 5184)
+    for kernel, (bq, bk) in zip(("bwd_dq", "bwd_dkv"), fa.bwd_tiles(
+            1024, 1024, 64, jnp.bfloat16, False)):
+        new = fa.grid_steps(kernel, 144, 1024, 1024, bq, bk, True)
+        assert new["steps"] <= 9216 // 4
+        assert new["fetched_steps"] == new["live_steps"]

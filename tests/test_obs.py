@@ -559,10 +559,10 @@ def test_fit_loop_spans_nest_in_fit_epoch_on_one_thread(traced):
     assert order[-2] == "metrics_buffer.flush"
 
 
-def tiny_gpt2_step_text() -> str:
+def tiny_gpt2_lowered_step(seq: int = 128):
     """The lowered train step of a two-layer GPT-2 with the flash kernel
-    forced (interpret mode here), debug locations and all: what carries
-    the scopes and kernel names."""
+    forced (interpret mode here)."""
+    import dataclasses
     import jax.numpy as jnp
     from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
     from flexflow_tpu.models.nlp import GPTConfig, build_gpt2
@@ -571,16 +571,22 @@ def tiny_gpt2_step_text() -> str:
     cfg.only_data_parallel = True
     cfg.kernel_impls = "attention:flash"
     ff = FFModel(cfg)
-    out = build_gpt2(ff, 2, 128, GPTConfig.tiny())
+    out = build_gpt2(ff, 2, seq, dataclasses.replace(
+        GPTConfig.tiny(), max_position=seq))
     ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
                output_tensor=out)
-    ids = np.zeros((2, 128), np.int32)
-    pos = np.tile(np.arange(128, dtype=np.int32), (2, 1))
+    ids = np.zeros((2, seq), np.int32)
+    pos = np.tile(np.arange(seq, dtype=np.int32), (2, 1))
     batch = next(iter(ff._combined_loader(
-        [ids, pos], np.zeros((2, 128, 1), np.int32), shuffle=False)))
-    lowered = ff.executor.make_train_step().lower(
+        [ids, pos], np.zeros((2, seq, 1), np.int32), shuffle=False)))
+    return ff.executor.make_train_step().lower(
         ff.params, ff.opt_state, ff.state, jnp.int32(0), batch)
-    return lowered.as_text(debug_info=True)
+
+
+def tiny_gpt2_step_text() -> str:
+    """Debug locations and all: what carries the scopes and kernel
+    names."""
+    return tiny_gpt2_lowered_step().as_text(debug_info=True)
 
 
 @pytest.fixture(scope="module")
@@ -627,3 +633,55 @@ def test_step_text_is_the_same_in_two_fresh_processes():
                for p in procs]
     assert all(p.returncode == 0 for p in procs)
     assert len(digests[0]) == 64 and digests[0] == digests[1]
+
+
+# ----------------------------------------------------------------------
+# flash.grid: what the flash kernels' grids cost, at trace time
+# ----------------------------------------------------------------------
+
+def _flash_grid_events():
+    return [e for e in events.events() if e["name"] == "flash.grid"]
+
+
+def test_traced_train_step_records_one_flash_grid_instant_per_kernel_call(
+        traced):
+    """Two causal attention layers at seq 2048: a (512, 512) forward and
+    the derived backward tiles, each on a grid of several blocks a side,
+    part of them above the diagonal."""
+    tiny_gpt2_lowered_step(seq=2048)
+    by_kernel = {}
+    for e in _flash_grid_events():
+        by_kernel.setdefault(e["attrs"]["kernel"], []).append(e["attrs"])
+    assert sorted(by_kernel) == ["flash_attention_bwd_dkv",
+                                 "flash_attention_bwd_dq",
+                                 "flash_attention_fwd"]
+    for seen in by_kernel.values():    # two layers a trace of the step
+        assert len(seen) == len(by_kernel["flash_attention_fwd"])
+        assert len(seen) % 2 == 0
+        for g in seen:
+            assert g["fetched_steps"] == g["live_steps"] < g["steps"], g
+            assert 2048 % g["block_q"] == 0 and 2048 % g["block_k"] == 0
+    fwd = by_kernel["flash_attention_fwd"][0]
+    assert (fwd["block_q"], fwd["block_k"]) == (512, 512)
+    assert fwd["live_steps"] * 16 == fwd["steps"] * 10
+
+
+def test_no_flash_grid_event_and_no_profiler_with_events_off(monkeypatch):
+    import sys
+    import jax
+    import jax.numpy as jnp
+    from flexflow_tpu.kernels import flash_attention
+    was_enabled = events.enabled()
+    events.disable()
+    events.clear()
+    try:
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)
+        q = jnp.zeros((1, 2, 256, 64), jnp.float32)
+        jax.eval_shape(jax.grad(lambda q: jnp.sum(flash_attention(
+            q, q, q, causal=True, interpret=True))), q)
+        assert _flash_grid_events() == []
+        with pytest.raises(ImportError):
+            from jax.profiler import TraceAnnotation  # noqa: F401
+    finally:
+        if was_enabled:
+            events.enable()

@@ -10,6 +10,7 @@ It proves compilation only. Whether the kernels compute the right values
 on the chip is ``chip_smoke.py``'s job.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,14 +75,59 @@ def _adam_operands(mesh, shape, pspec, sspec=None):
     return {"w": w}, {"w": w}, {"m": {"w": s}, "v": {"w": s}}
 
 
-def test_flash_fwd_bwd_compiles_on_one_device(v5e_devices):
+def _kernel_names(txt):
+    """The Mosaic calls' instruction names; outside any ``named_scope``
+    XLA wraps them as ``jvp_<name>_`` / ``transpose_jvp_<name>__``."""
+    names = (l.split(" = ")[0].split("%")[-1].rsplit(".", 1)[0]
+             for l in txt.splitlines() if MOSAIC_CALL in l)
+    return sorted(re.sub(r"^(transpose_)?(jvp_)?|_+$", "", n)
+                  for n in names)
+
+
+FLASH_NAMES = ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+               "flash_attention_fwd"]
+
+
+# forward + backward at the backward tiles derived from the shapes
+# (flash_attention.bwd_tiles): cell 2 of the benchmark, a longer
+# sequence, a wider head
+@pytest.mark.parametrize("shape", [(2, 12, 1024, 64), (1, 12, 2048, 64),
+                                   (1, 8, 1024, 128)])
+def test_flash_fwd_bwd_compiles_on_one_device(v5e_devices, chip_locations,
+                                              shape):
     mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
-    qkv = jax.ShapeDtypeStruct((2, 12, 1024, 64), jnp.bfloat16,
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                                sharding=NamedSharding(mesh, P()))
     txt = _compile_text(
         jax.grad(functools.partial(_flash_loss, None, None),
                  argnums=(0, 1, 2)), qkv, qkv, qkv)
-    assert txt.count(MOSAIC_CALL) == 3  # fwd, dq, dkv
+    assert _kernel_names(txt) == FLASH_NAMES
+
+
+@pytest.mark.parametrize("dropout", [False, True])
+@pytest.mark.parametrize("dtype,d", [("bfloat16", 256), ("float32", 64),
+                                     ("float32", 128), ("float32", 256)])
+def test_flash_bwd_compiles_where_the_tile_rule_has_to_step_down(
+        v5e_devices, chip_locations, dtype, d, dropout):
+    """Mosaic refuses 1024 x 1024 backward tiles for wide heads, f32
+    operands and dropout (over its 16 MiB of scoped VMEM): what the rule
+    hands it instead has to compile."""
+    from flexflow_tpu.kernels.flash_attention import bwd_tiles
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    qkv = jax.ShapeDtypeStruct((1, 2, 2048, d), jnp.dtype(dtype),
+                               sharding=NamedSharding(mesh, P()))
+    if dropout or d == 256:
+        assert bwd_tiles(2048, 2048, d, qkv.dtype, dropout) != (
+            (1024, 1024), (1024, 1024))
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, interpret=False,
+                            dropout_rate=0.1 if dropout else 0.0,
+                            dropout_seed=3)
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert _kernel_names(txt) == FLASH_NAMES
 
 
 def test_flash_kernels_are_named_in_the_compiled_step(v5e_devices,
@@ -103,11 +149,8 @@ def test_flash_kernels_are_named_in_the_compiled_step(v5e_devices,
 
     txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
     calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
-    names = sorted(l.split(" = ")[0].split("%")[-1].rsplit(".", 1)[0]
-                   for l in calls)
-    assert names == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
-                     "flash_attention_fwd"]
-    by_name = {n: l for n in names for l in calls if f"%{n}." in l}
+    assert _kernel_names(txt) == FLASH_NAMES
+    by_name = {n: l for n in FLASH_NAMES for l in calls if f"%{n}." in l}
     assert 'jvp(ff.forward)/attn_3/flash_attention_fwd/pallas_call"' \
         in by_name["flash_attention_fwd"]
     for n in ("flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
@@ -139,15 +182,18 @@ def test_kernel_bytes_do_not_depend_on_who_traced_first(v5e_devices,
     assert lowered() == from_deeper(3)
 
 
-def test_flash_under_shard_map_compiles_on_2x2(v5e_devices):
+@pytest.mark.parametrize("shape", [(4, 12, 1024, 64), (2, 12, 2048, 64),
+                                   (2, 8, 1024, 128)])
+def test_flash_under_shard_map_compiles_on_2x2(v5e_devices, chip_locations,
+                                               shape):
     mesh = Mesh(np.array(v5e_devices).reshape(2, 2), ("x0", "x1"))
     spec = P("x0", "x1")          # batch over x0, heads over x1
-    qkv = jax.ShapeDtypeStruct((4, 12, 1024, 64), jnp.bfloat16,
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                                sharding=NamedSharding(mesh, spec))
     txt = _compile_text(
         jax.grad(functools.partial(_flash_loss, mesh, spec),
                  argnums=(0, 1, 2)), qkv, qkv, qkv)
-    assert MOSAIC_CALL in txt
+    assert _kernel_names(txt) == FLASH_NAMES
     assert "all-gather" not in txt    # operands stay where they are
 
 

@@ -1,0 +1,10 @@
+"""``mtp_time_share.train``: device self time of the ops of the
+multi-token-prediction module's layers (its norms, ``W_eh``, its decoder
+layer, its loss; not its half of the shared head's product, which is one
+op with the trunk's), both phases, over device busy time in the traced
+groups, in percent."""
+from benchmarks.harness import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.share_of_layers(ctx, scope_reduce.in_mtp_module)

@@ -13,6 +13,15 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          path; the fused Adam kernel against ``AdamOptimizer.update`` on
          the model's own leaves; then a second compile with
          ``opt_update:fused`` in the step.
+  Leg C  latent attention, routed experts and a multi-token-prediction
+         module (``build_latent_moe``): a small model, then one chip's
+         share of JoyAI-LLM-Flash at published widths, 1 x 4096 tokens a
+         chip with the expert layers rematerialised. Data parallel, no
+         search (the expert dimension is not a searched axis yet). The
+         ``moe.route`` and ``flash.grid`` instants it checks are printed.
+         It looks at no gradient and not at the MTP head's output, and
+         neither does the benchmark's ``correct``: after a change to
+         either op run ``examples/tpu_validate_latent_moe.py`` as well.
 
 It claims no speed. The times it prints are set-up facts of one run.
 It exits non-zero, before building anything, unless JAX reports a TPU;
@@ -352,6 +361,105 @@ def leg_gpt2_fused_step(gpt_cfg, seq: int, per_chip_batch: int,
               f"optimizer kernel is not in the compiled step")
 
 
+# ----------------------------------------------------------------------
+# Leg C — latent attention, routed experts, multi-token prediction
+# ----------------------------------------------------------------------
+VALIDATION = "examples/tpu_validate_latent_moe.py"
+
+
+def leg_latent_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
+                   alpha: float = 1e-5) -> None:
+    """``build_latent_moe`` through compile and fit with ``remat =
+    "blocks"``: the loss falls, every attention layer resolved to the
+    flash kernel (on a chip), every expert layer announced its routing
+    and dropped nothing, and the step fits the chip. A falling loss says
+    little about the gradients (Adam divides their scale away: PR 29's
+    unwritten rows trained and passed): ``VALIDATION`` holds them, and
+    the MTP head, to the reference, and this leg names it."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu import AdamOptimizer, FFModel
+    from flexflow_tpu.models.nlp import build_latent_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    batch = per_chip_batch * len(jax.devices())
+    rng = np.random.default_rng(SEED)
+    ids = rng.integers(0, model_cfg.vocab_size,
+                       (batch, seq)).astype(np.int32)
+    x = [ids, np.tile(np.arange(seq, dtype=np.int32), (batch, 1))]
+    y = np.roll(ids, -1, axis=1)[..., None]
+    cfg = _config(batch)
+    cfg.search_budget = 0
+    cfg.only_data_parallel = True
+    cfg.remat = "blocks"
+    ff = FFModel(cfg)
+    out = build_latent_moe(ff, batch, seq, model_cfg)
+    events.clear()
+    t0 = time.perf_counter()
+    ff.compile(AdamOptimizer(alpha=alpha),
+               "sparse_categorical_crossentropy", [], output_tensor=out)
+    n_params = sum(int(np.prod(w.shape)) for l in ff.params.values()
+                   for w in l.values())
+    say(f"{label}: compile {time.perf_counter() - t0:.1f}s, "
+        f"{len(ff.layers)} graph nodes, {n_params:,} parameters, mesh "
+        f"{dict(ff.dmesh.axis_sizes)}, rematerialised run "
+        f"{ff.executor._remat and ff.executor._remat[:3]}")
+    check(ff.executor._remat is not None,
+          f"{label}: remat = blocks found no repeated run of expert layers")
+    _fit(ff, x, y, label)
+    impls = ff.executor.resolved_attention_impls
+    n_attn = model_cfg.num_hidden_layers + model_cfg.num_nextn_predict_layers
+    say(f"{label}: resolved attention impls "
+        f"{sorted(set(impls.values()))} in {len(impls)} layers")
+    check(len(impls) == n_attn, f"{label}: {len(impls)} attention layers "
+                                f"resolved, the model has {n_attn}")
+    if chip:
+        check(set(impls.values()) == {"flash"},
+              f"{label}: attention resolved to {impls} at seq {seq}")
+    routes = {e["attrs"]["layer"]: e["attrs"] for e in events.events()
+              if e["name"] == "moe.route"}
+    for name, r in sorted(routes.items()):
+        say(f"{label}: moe.route {name}: {r['experts_held']} of "
+            f"{r['experts_published']} experts held from {r['first_held']}"
+            f", top {r['top_k']}, {r['tokens']} tokens, "
+            f"{r['rows_multiplied']} rows in the sorted buffer")
+        check(r["experts_published"] == (
+            model_cfg.n_routed_experts_published
+            or model_cfg.n_routed_experts)
+            and r["experts_held"] == model_cfg.n_routed_experts
+            and r["rows_multiplied"] == r["tokens"] * r["top_k"],
+            f"{label}: {name} routes as {r}")
+    n_expert = n_attn - model_cfg.first_k_dense_replace
+    check(len(routes) == n_expert, f"{label}: {len(routes)} expert layers "
+                                   f"announced, the model has {n_expert}")
+    ctr = events.counters()
+    say(f"{label}: counters " + ", ".join(
+        f"{k} {ctr.get(k)}" for k in ("moe.local_assignments",
+                                      "moe.dropped", "moe.load_max",
+                                      "moe.load_mean")))
+    check(ctr.get("moe.dropped") == 0 and ctr.get(
+        "moe.local_assignments", 0) > 0,
+        f"{label}: the experts' counters read {ctr}")
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: gradients and the MTP head's "
+        f"log-probabilities against the reference: python3 {VALIDATION}")
+    step = ff.executor.make_train_step()
+    batch0 = next(iter(ff._combined_loader(x, y, shuffle=False)))
+    compiled = step.lower(ff.params, ff.opt_state, ff.state, jnp.int32(0),
+                          batch0).compile()
+    ma = compiled.memory_analysis()
+    gib = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+           + ma.temp_size_in_bytes - ma.alias_size_in_bytes) / 2 ** 30
+    n_cc = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    say(f"{label}: compiled step {gib:.2f} GiB a device, {n_cc} "
+        f"tpu_custom_call(s), peak_bytes_in_use {_peak_bytes()}")
+    if chip:
+        check(gib <= 15.0, f"{label}: the step takes {gib:.2f} GiB")
+        check(n_cc >= 3 * n_attn, f"{label}: {n_cc} Mosaic calls for "
+                                  f"{n_attn} attention layers")
+
+
 def _check_generate(ff, ids) -> None:
     """KV-cache decode against the re-forward path on one prompt.
 
@@ -451,7 +559,9 @@ def main() -> int:
         return 2
     t0 = time.perf_counter()
     from flexflow_tpu import MachineSpec, native
-    from flexflow_tpu.models.nlp import BertConfig, GPTConfig
+    from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
+                                         JoyAIFlashRankConfig,
+                                         LatentMoEConfig)
     from flexflow_tpu.utils.compilation_cache import (
         cache_entries, enable_compilation_cache)
     cache = enable_compilation_cache()
@@ -467,6 +577,11 @@ def main() -> int:
         # (B1's model is gone by now: one training state at a time)
         n_flash = leg_gpt2_kernels(GPTConfig(), 1024, GPT_PER_CHIP_BATCH)
         leg_gpt2_fused_step(GPTConfig(), 1024, GPT_PER_CHIP_BATCH, n_flash)
+        # a small model at a length where attention resolves to flash,
+        # then one chip's share of the published model
+        leg_latent_moe(LatentMoEConfig.tiny(), 1024, 1, "C/small",
+                       alpha=1e-3)
+        leg_latent_moe(JoyAIFlashRankConfig(), 4096, 1, "C/joyai")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

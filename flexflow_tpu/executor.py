@@ -98,6 +98,11 @@ def _instrument_step(fn, name: str):
     return wrapped
 
 
+#: where ``_forward`` leaves the step's device counters
+#: (``EmitCtx.counters``) among the captured values
+COUNTERS_KEY = "step_counters"
+
+
 def _emit_scoped(op, layer: Layer, ins, w, ctx):
     """``op.emit`` under ``jax.named_scope(layer.name)``: every device
     op the layer lowers to carries, in its ``op_name`` metadata, the
@@ -368,13 +373,16 @@ class GraphProgram:
 
 def _find_remat_blocks(layers):
     """Block boundaries for ``--remat``: the maximal repeated-block run,
-    each block single-input/single-output, containing no stateful or
-    aux-loss-emitting ops (their side-channel writes cannot cross a
-    ``jax.checkpoint`` boundary). Returns
+    each block single-input/single-output (beside the graph's own inputs
+    and constants, which every block may read: positions, masks),
+    containing no stateful or aux-loss-emitting ops (their side-channel
+    writes cannot cross a ``jax.checkpoint`` boundary). Returns
     ``(start, unit, reps, entry_guids, exit_guids)`` or None."""
     from .parallel.pipeline_lowering import (_has_state, chunk_boundaries,
                                              find_repeated_run)
-    run = find_repeated_run(list(layers), 1)
+    graph_inputs = frozenset(t.guid for l in layers for t in l.inputs
+                             if t.owner_layer is None)
+    run = find_repeated_run(list(layers), 1, graph_inputs)
     if run is None:
         return None
     total, start, unit = run
@@ -1103,6 +1111,10 @@ class Executor:
         new_state = dict(state)
         for k, v in ctx.new_state.items():
             new_state[k] = v
+        # the ops' device counters ride with the captured values: the
+        # loss's metrics read them from there (see _loss_and_metrics)
+        if ctx.counters:
+            capture[COUNTERS_KEY] = ctx.counters
         return outs, new_state, ctx.aux_losses, capture
 
     def _emit_remat(self, params, batch, ctx, capture,
@@ -1117,13 +1129,17 @@ class Executor:
         self.program.emit_layers(layers[:start], env, params, ctx,
                                  st, capture)
         x = env[entries[0]]
+        # what a block may read beside its entry: the graph's inputs
+        inputs_env = {t.guid: env[t.guid]
+                      for l in layers[start:start + reps * unit]
+                      for t in l.inputs if t.owner_layer is None}
         for b in range(reps):
             block = layers[start + b * unit:start + (b + 1) * unit]
             entry_g, exit_g = entries[b], exits[b]
 
             def block_fn(x_, p_, _block=block, _entry=entry_g,
                          _exit=exit_g):
-                benv = {_entry: x_}
+                benv = {**inputs_env, _entry: x_}
                 bctx = EmitCtx(training=ctx.training, rngs=ctx.rngs,
                                state=ctx.state, config=self.config,
                                seq_length=ctx.seq_length)
@@ -1134,11 +1150,15 @@ class Executor:
                 if bctx.new_state or bctx.aux_losses:
                     raise RuntimeError(
                         "stateful/aux op inside a rematted block")
-                return benv[_exit]
+                # the block's device counters leave it as outputs: a
+                # side channel cannot cross jax.checkpoint
+                return benv[_exit], bctx.counters
 
             bp = {l.name: params[l.name] for l in block
                   if l.name in params}
-            x = jax.checkpoint(block_fn)(x, bp)
+            x, counted = jax.checkpoint(block_fn)(x, bp)
+            for key, v in counted.items():
+                ctx.count(key, v)
             env[exit_g] = x
             capture[exit_g] = x
         self.program.emit_layers(layers[start + reps * unit:], env,
@@ -1158,6 +1178,8 @@ class Executor:
         bm = metrics_mod.compute_batch_metrics(self.metrics, pred, label,
                                                self.loss_type)
         bm["loss"] = loss
+        for key, v in capture.get(COUNTERS_KEY, {}).items():
+            bm[metrics_mod.COUNTER_PREFIX + key] = jax.lax.stop_gradient(v)
         return loss, bm
 
     def _apply_update(self, params, grads, opt_state, step):
@@ -1282,7 +1304,7 @@ class Executor:
                 # average the squares and sqrt once (ownership of the
                 # distinction lives with the metrics module)
                 def reduce_metric(k, v):
-                    if k in metrics_mod.COUNT_KEYS:
+                    if metrics_mod.is_count(k):
                         return jnp.sum(v, axis=0)
                     if k in metrics_mod.RMS_KEYS:
                         return jnp.sqrt(jnp.mean(v * v, axis=0))

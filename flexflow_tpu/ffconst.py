@@ -217,6 +217,12 @@ class OperatorType(enum.IntEnum):
     # (nmt/lstm.cu) outside the op registry; here it is a first-class op
     OP_LSTM = enum.auto()
     OP_INVALID = enum.auto()
+    # appended after OP_INVALID so that every earlier value stays what it
+    # was: latent (low-rank q / kv) attention, one sparse dropless
+    # routed-experts layer, and a next-token loss on a second head
+    OP_LATENT_ATTENTION = enum.auto()
+    OP_ROUTED_EXPERTS = enum.auto()
+    OP_NEXT_TOKEN_LOSS = enum.auto()
 
 
 # Ops that are pure elementwise-unary (single input, same shape out).

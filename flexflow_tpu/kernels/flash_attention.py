@@ -349,24 +349,25 @@ def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal):
 def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
               dropout_rate, interpret):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]      # q.k over d, p.v over dv
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         kv_len=kv_len, block_q=block_q, block_k=block_k,
         dropout_rate=dropout_rate)
-    ks = _k_spec(block_q, block_k, d, causal)
     _note_grid("fwd", bh, sq, sk, block_q, block_k, causal)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, sq // block_q, sk // block_k),
-        in_specs=[_SEED_SPEC, _q_spec(block_q, d), ks, ks],
-        out_specs=[_q_spec(block_q, d), _row_spec(block_q)],
+        in_specs=[_SEED_SPEC, _q_spec(block_q, d),
+                  _k_spec(block_q, block_k, d, causal),
+                  _k_spec(block_q, block_k, dv, causal)],
+        out_specs=[_q_spec(block_q, dv), _row_spec(block_q)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -379,16 +380,17 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
 def _bwd_dq_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
                  causal, block_q, block_k, dropout_rate, interpret):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     row = _row_spec(block_q)
     qs, ks = _q_spec(block_q, d), _k_spec(block_q, block_k, d, causal)
+    dos, vs = _q_spec(block_q, dv), _k_spec(block_q, block_k, dv, causal)
     _note_grid("bwd_dq", bh, sq, sk, block_q, block_k, causal)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
                           dropout_rate=dropout_rate),
         grid=(bh, sq // block_q, sk // block_k),
-        in_specs=[_SEED_SPEC, qs, ks, ks, qs, row, row],
+        in_specs=[_SEED_SPEC, qs, ks, vs, dos, row, row],
         out_specs=qs,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -400,23 +402,24 @@ def _bwd_dq_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
 def _bwd_dkv_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
                   causal, block_q, block_k, dropout_rate, interpret):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     qs2, ks2, row2 = _dkv_specs(block_q, block_k, d, causal)
+    dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal)
     _note_grid("bwd_dkv", bh, sq, sk, block_q, block_k, causal)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
                           dropout_rate=dropout_rate),
         grid=(bh, sk // block_k, sq // block_q),
-        in_specs=[_SEED_SPEC, qs2, ks2, ks2, qs2, row2, row2],
-        out_specs=[ks2, ks2],
+        in_specs=[_SEED_SPEC, qs2, ks2, vs2, dos2, row2, row2],
+        out_specs=[ks2, vs2],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
@@ -470,22 +473,26 @@ BWD_VMEM_BUDGET = 15 * 1024 * 1024
 MAX_BWD_TILE = 1024
 
 
-def _bwd_vmem_bytes(kernel, block_q, block_k, d, itemsize, dropout):
+def _bwd_vmem_bytes(kernel, block_q, block_k, d, itemsize, dropout,
+                    dv=None):
     """Working set of one grid step of ``bwd_dq`` / ``bwd_dkv``: the
     double-buffered operand and output blocks, the f32 scratch
     accumulators, a staging copy of the q-side and k-side blocks at twice
     their width around the matmuls, and the (block_q, block_k)
     intermediates: of ``s``, ``p``, ``dp``, ``ds``, the masks and the
     casts Mosaic streams through registers and keeps about one f32 tile,
-    one more for the dropout hash and keep mask."""
-    lanes = -(-d // 128) * 128               # a (rows, 64) block fills 128
-    q_side = 2 * block_q * lanes * itemsize + 2 * block_q * 128 * 4
-    k_side = 2 * block_k * lanes * itemsize  # k, v
+    one more for the dropout hash and keep mask. ``d`` is the head size
+    of q and k (and dq, dk), ``dv`` that of v and do (and dv); None means
+    the same."""
+    qk = -(-d // 128) * 128                  # a (rows, 64) block fills 128
+    both = qk + -(-(d if dv is None else dv) // 128) * 128
+    q_side = block_q * both * itemsize + 2 * block_q * 128 * 4   # q, do
+    k_side = block_k * both * itemsize                           # k, v
     if kernel == "bwd_dq":
-        out, scratch = block_q * lanes * itemsize, block_q * lanes * 4
+        out, scratch = block_q * qk * itemsize, block_q * qk * 4
     else:
-        out, scratch = 2 * block_k * lanes * itemsize, 2 * block_k * lanes * 4
-    stage = (block_q + block_k) * lanes * 2 * itemsize
+        out, scratch = block_k * both * itemsize, block_k * both * 4
+    stage = (block_q + block_k) * both * itemsize
     tiles = (2 if dropout else 1) * block_q * block_k * 4
     return 2 * (q_side + k_side + out) + scratch + stage + tiles
 
@@ -498,9 +505,10 @@ def _tile_sizes(padded):
     return sizes or [padded]
 
 
-def bwd_tiles(sq, sk, d, dtype, dropout):
+def bwd_tiles(sq, sk, d, dtype, dropout, dv=None):
     """``((block_q, block_k) of bwd_dq, (block_q, block_k) of bwd_dkv)``
-    for padded sequence lengths ``sq``, ``sk`` and padded head dim ``d``:
+    for padded sequence lengths ``sq``, ``sk`` and padded head dims ``d``
+    (q, k) and ``dv`` (v, the output; None: the same as ``d``):
     per kernel the tile of the most pairs whose working set fits
     ``BWD_VMEM_BUDGET``, and of two such the one with the wider resident
     side (dq holds a q block while k blocks stream, dkv a k block). On a
@@ -514,7 +522,7 @@ def bwd_tiles(sq, sk, d, dtype, dropout):
     for kernel, resident in (("bwd_dq", 0), ("bwd_dkv", 1)):
         tiles = list(itertools.product(_tile_sizes(sq), _tile_sizes(sk)))
         fits = [t for t in tiles
-                if _bwd_vmem_bytes(kernel, *t, d, itemsize, dropout)
+                if _bwd_vmem_bytes(kernel, *t, d, itemsize, dropout, dv)
                 <= BWD_VMEM_BUDGET] or tiles[:1]      # the smallest there is
         out.append(max(fits, key=lambda t: (t[0] * t[1], t[resident])))
     return tuple(out)
@@ -539,10 +547,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     bwd_block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     mesh=None, spec=None):
-    """Tiled flash attention. q: (b, h, sq, d); k, v: (b, h, sk, d).
+    """Tiled flash attention. q: (b, h, sq, d); k: (b, h, sk, d); v:
+    (b, h, sk, dv), and the output (b, h, sq, dv). ``dv`` may differ from
+    ``d`` (latent attention: q.k over 192, p.v over 128); the score scale
+    defaults to ``1 / sqrt(d)``.
 
-    Pads seq dims to block multiples and head_dim to a multiple of 64
-    (padded keys masked, padded head dims sliced off), runs the Pallas
+    Pads seq dims to block multiples and each head dim to a multiple of
+    64 (padded keys masked, padded head dims sliced off), runs the Pallas
     kernels, and is differentiable via the custom VJP. ``dropout_rate`` > 0
     applies in-kernel counter-based dropout to the attention
     probabilities (requires ``dropout_seed``, an int32 scalar).
@@ -575,7 +586,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
             block_q=block_q, block_k=block_k, bwd_block_q=bwd_block_q,
             bwd_block_k=bwd_block_k, interpret=interpret)
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     if causal and sq != sk:
         raise NotImplementedError("causal flash requires sq == sk")
     if sm_scale is None:
@@ -594,9 +605,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
     kp = _pad_to(_pad_to(k, block_k, 2), 64, 3)
     vp = _pad_to(_pad_to(v, block_k, 2), 64, 3)
     sq_p, d_p = qp.shape[2], qp.shape[3]
-    sk_p = kp.shape[2]
+    sk_p, dv_p = kp.shape[2], vp.shape[3]
     dq_blocks, dkv_blocks = bwd_tiles(sq_p, sk_p, d_p, q.dtype,
-                                      dropout_rate > 0.0)
+                                      dropout_rate > 0.0, dv_p)
     # an explicit backward block wins, for both kernels
     if bwd_block_q is not None:
         bq = _explicit_block(bwd_block_q, block_q)
@@ -611,10 +622,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
         seed = jnp.asarray(dropout_seed, jnp.int32).reshape(1, 1)
     o = _flash(qp.reshape(b * h, sq_p, d_p),
                kp.reshape(b * h, sk_p, d_p),
-               vp.reshape(b * h, sk_p, d_p),
+               vp.reshape(b * h, sk_p, dv_p),
                seed, sk, sm_scale, causal, block_q, block_k,
                dq_blocks, dkv_blocks, float(dropout_rate), interpret)
-    return o.reshape(b, h, sq_p, d_p)[:, :, :sq, :d]
+    return o.reshape(b, h, sq_p, dv_p)[:, :, :sq, :dv]
 
 
 def _flash_sharded(q, k, v, mesh, spec, *, dropout_rate, dropout_seed,

@@ -245,6 +245,62 @@ class FFModel:
         return self._add_layer(OperatorType.OP_MULTIHEAD_ATTENTION,
                                [query, key, value], params, name).outputs[0]
 
+    def latent_attention(self, input: Tensor, positions: Tensor,
+                         num_heads: int, q_rank: int,
+                         kv_rank: int, nope_dim: int, rope_dim: int,
+                         v_dim: int, rope_theta: float = 10000.0,
+                         eps: float = 1e-6,
+                         name: Optional[str] = None) -> Tensor:
+        """Causal multi-head latent attention (``ops.nn_ops.
+        LatentAttentionOp``): low-rank q (``q_rank``) and kv
+        (``kv_rank``) with a norm on each latent, q/k heads of
+        ``nope_dim + rope_dim`` (rotary on the last ``rope_dim``, one
+        rotary key shared by the heads), v heads of ``v_dim``.
+        ``positions``: (batch, seq) int32, what the rotary embedding
+        turns by."""
+        return self._add_layer(
+            OperatorType.OP_LATENT_ATTENTION, [input, positions],
+            dict(num_heads=num_heads, q_rank=q_rank, kv_rank=kv_rank,
+                 nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+                 rope_theta=float(rope_theta), eps=eps), name).outputs[0]
+
+    def routed_experts(self, input: Tensor, num_experts: int, top_k: int,
+                       expert_dim: int, shared_dim: int = 0,
+                       experts_held: Optional[int] = None,
+                       first_held: int = 0, scale: float = 1.0,
+                       bias_std: float = 0.0,
+                       name: Optional[str] = None) -> Tensor:
+        """One sparse, dropless mixture-of-experts feed-forward layer
+        (``ops.moe_ops.RoutedExpertsOp``): sigmoid scores over
+        ``num_experts``, bias-corrected top-``top_k``, SwiGLU experts of
+        width ``expert_dim`` and a shared one of ``shared_dim`` (0: none).
+        ``experts_held`` (default: all) and ``first_held`` say which
+        experts' weights live here: the layer routes over all of them
+        and computes the part of the result that its own give."""
+        held = num_experts if experts_held is None else experts_held
+        if not 0 <= first_held <= first_held + held <= num_experts:
+            raise ValueError(
+                f"experts {first_held}..{first_held + held} are not "
+                f"among the {num_experts} the router scores")
+        if top_k > num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        return self._unary(OperatorType.OP_ROUTED_EXPERTS, input, name,
+                           num_experts=num_experts, top_k=top_k,
+                           expert_dim=expert_dim, shared_dim=shared_dim,
+                           experts_held=held, first_held=first_held,
+                           scale=float(scale), bias_std=float(bias_std))
+
+    def next_token_loss(self, logits: Tensor, ids: Tensor, offset: int,
+                        weight: float,
+                        name: Optional[str] = None) -> Tensor:
+        """Add ``weight`` x the mean cross-entropy of ``logits[:, t]``
+        against ``ids[:, t + offset]`` to the training loss
+        (``ops.nn_ops.NextTokenLossOp``)."""
+        return self._add_layer(OperatorType.OP_NEXT_TOKEN_LOSS,
+                               [logits, ids],
+                               {"offset": int(offset),
+                                "weight": float(weight)}, name).outputs[0]
+
     def batch_norm(self, input: Tensor, relu: bool = True,
                    eps: float = 1e-5, momentum: float = 0.1,
                    name: Optional[str] = None) -> Tensor:

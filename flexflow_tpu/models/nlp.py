@@ -664,3 +664,150 @@ def mixtral_load_hf_state_dict(state_dict, cfg: MixtralConfig):
         raise ValueError(f"unmapped checkpoint tensors "
                          f"{sorted(leftover)[:8]} — config mismatch")
     return params
+
+
+@dataclasses.dataclass
+class LatentMoEConfig:
+    """DeepSeek-V3-shaped decoder: latent attention in every layer,
+    ``first_k_dense_replace`` leading SwiGLU layers and then sparse
+    routed experts with a shared one, ``num_nextn_predict_layers``
+    (0 or 1) multi-token-prediction modules. The fields carry the names
+    of the published ``config.json`` keys; the defaults are
+    JoyAI-LLM-Flash's (``model_type: joyai_llm_flash``).
+
+    ``n_routed_experts`` counts the experts whose weights are HELD here,
+    ``first_held_expert`` onwards; the router, the top-k and the gates'
+    normalisation run over ``n_routed_experts_published`` (None: the
+    same, every expert is held). A device of an expert-parallel layer
+    holds its share and computes its own experts' part of the result."""
+    vocab_size: int = 129280
+    hidden_size: int = 2048
+    num_hidden_layers: int = 40
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 32000000.0
+    rms_norm_eps: float = 1e-6
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 768
+    n_routed_experts: int = 256
+    n_routed_experts_published: int | None = None
+    first_held_expert: int = 0
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    routed_scaling_factor: float = 2.5
+    num_nextn_predict_layers: int = 1
+    # not in config.json: DeepSeek-V3's weight of the MTP loss late in
+    # training (arXiv:2412.19437 section 4.2), and the spread of the
+    # routers' correction bias, which is drawn once and never trained
+    mtp_loss_weight: float = 0.3
+    router_bias_std: float = 0.02
+
+    @classmethod
+    def tiny(cls):
+        """3 + 1 layers, 4 heads of 24 / 16, 16 experts top-4: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_hidden_layers=3,
+                   num_attention_heads=4, q_lora_rank=48, kv_lora_rank=32,
+                   qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                   rope_theta=10000.0, intermediate_size=160,
+                   moe_intermediate_size=32, n_routed_experts=16,
+                   num_experts_per_tok=4, router_bias_std=0.05)
+
+
+@dataclasses.dataclass
+class JoyAIFlashRankConfig(LatentMoEConfig):
+    """What ONE chip holds of JoyAI-LLM-Flash where 16 chips share each
+    layer (the benchmark's ``joyai_llm_flash``): experts 0 to 15 of the
+    256, one of eight slices of the vocabulary, and the leading dense
+    layer with four of the 39 expert layers (the rest lie on further
+    chips as pipeline stages); every width as published."""
+    vocab_size: int = 16160
+    num_hidden_layers: int = 5
+    n_routed_experts: int = 16
+    n_routed_experts_published: int | None = 256
+
+
+def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
+                     cfg: LatentMoEConfig | None = None):
+    """Causal LM of :class:`LatentMoEConfig`: inputs ``[ids, pos]``,
+    output the softmax over the head (the executor's CE-on-logits path),
+    as :func:`build_gpt2`; ``pos`` is what every layer's rotary
+    embedding turns by.
+
+    The multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
+    section 2.2) predicts token ``t + 2`` from the trunk's last hidden
+    state at ``t`` and the embedding of token ``t + 1`` through one more
+    decoder layer, and adds ``mtp_loss_weight`` times its cross-entropy
+    to the loss. It shares the trunk's embedding and output head: the
+    graph has no weight shared by two layers, so the module reads the
+    trunk's embedding output shifted by one position, and its final
+    hidden state goes through the ONE head layer beside the trunk's
+    (joined along the sequence, split again after)."""
+    cfg = cfg or LatentMoEConfig()
+    if cfg.num_nextn_predict_layers not in (0, 1):
+        raise ValueError("0 or 1 multi-token-prediction module")
+    b, s, hid = batch_size, seq_len, cfg.hidden_size
+    published = cfg.n_routed_experts_published or cfg.n_routed_experts
+    ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
+    pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids")
+    emb = ff.embedding(ids, cfg.vocab_size, hid, name="embed_tokens")
+
+    def norm(x, name):
+        return ff.rms_norm(x, eps=cfg.rms_norm_eps, name=name)
+
+    def decoder_layer(h, tag, experts: bool):
+        attn = ff.latent_attention(
+            norm(h, f"input_norm_{tag}"), pos, cfg.num_attention_heads,
+            cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+            cfg.qk_rope_head_dim, cfg.v_head_dim,
+            rope_theta=cfg.rope_theta, eps=cfg.rms_norm_eps,
+            name=f"attn_{tag}")
+        h = ff.add(h, attn, name=f"attn_res_{tag}")
+        x = norm(h, f"post_norm_{tag}")
+        if experts:
+            y = ff.routed_experts(
+                x, published, cfg.num_experts_per_tok,
+                cfg.moe_intermediate_size,
+                shared_dim=cfg.n_shared_experts * cfg.moe_intermediate_size,
+                experts_held=cfg.n_routed_experts,
+                first_held=cfg.first_held_expert,
+                scale=cfg.routed_scaling_factor,
+                bias_std=cfg.router_bias_std, name=f"experts_{tag}")
+        else:
+            gate = ff.dense(x, cfg.intermediate_size, use_bias=False,
+                            name=f"gate_proj_{tag}")
+            up = ff.dense(x, cfg.intermediate_size, use_bias=False,
+                          name=f"up_proj_{tag}")
+            silu = ff.multiply(gate, ff.sigmoid(gate), name=f"silu_{tag}")
+            y = ff.dense(ff.multiply(silu, up), hid, use_bias=False,
+                         name=f"down_proj_{tag}")
+        return ff.add(h, y, name=f"mlp_res_{tag}")
+
+    h = emb
+    for i in range(cfg.num_hidden_layers):
+        h = decoder_layer(h, str(i), i >= cfg.first_k_dense_replace)
+    out = norm(h, "final_norm")
+    if not cfg.num_nextn_predict_layers:
+        return ff.softmax(ff.dense(out, cfg.vocab_size, use_bias=False,
+                                   name="lm_head"))
+
+    # Emb(x_{t+1}): the trunk's embeddings one position on; the last
+    # position has no next token (zeros there, and no target either)
+    nxt = ff.concat([ff.slice_tensor(emb, [1], [s], [1]),
+                     ff.create_constant((b, 1, hid), 0.0)], axis=1,
+                    name="mtp_next_emb")
+    joined = ff.concat([norm(nxt, "mtp_enorm"), norm(h, "mtp_hnorm")],
+                       axis=-1, name="mtp_concat")
+    hm = ff.dense(joined, hid, use_bias=False, name="mtp_eh_proj")
+    hm = norm(decoder_layer(hm, "mtp", True), "mtp_final_norm")
+    logits = ff.dense(ff.concat([out, hm], axis=1, name="head_in"),
+                      cfg.vocab_size, use_bias=False, name="lm_head")
+    ff.next_token_loss(ff.slice_tensor(logits, [s], [2 * s], [1]), ids,
+                       offset=2, weight=cfg.mtp_loss_weight,
+                       name="mtp_loss")
+    return ff.softmax(ff.slice_tensor(logits, [0], [s], [1],
+                                      name="lm_logits"))

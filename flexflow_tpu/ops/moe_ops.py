@@ -24,8 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ffconst import DataType, OperatorType
-from .registry import EmitCtx, OpDef, register, compute_dtype
+from ..core.tensor import WeightSpec
+from ..ffconst import DataType, InitializerType, OperatorType
+from ..obs import events
+from .registry import EmitCtx, OpDef, compute_dtype, matmul, register
 
 
 def _capacity(params, batch: int, k: int) -> int:
@@ -153,3 +155,198 @@ class CacheOp(OpDef):
             if use_cached and not ctx.training:
                 return [st["cached"]]
         return [x]
+
+
+# ---------------------------------------------------------------------------
+# sparse, dropless routed experts (one op a layer)
+# ---------------------------------------------------------------------------
+@jax.custom_vjp
+def _rows_for(x, order, inverse):
+    """Row ``order[r] // k`` of ``x`` for every sorted assignment ``r``
+    (``k = len(order) // len(x)`` assignments a token). Its transpose
+    gathers too: the ``k`` rows of a token sit at ``inverse`` and are
+    summed, so no scatter is emitted in either direction."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _rows_for_fwd(x, order, inverse):
+    return _rows_for(x, order, inverse), (inverse, x.shape[0])
+
+
+def _rows_for_bwd(res, g):
+    inverse, t = res
+    summed = g[inverse].astype(jnp.float32).reshape(
+        t, -1, g.shape[-1]).sum(axis=1)
+    return summed.astype(g.dtype), None, None
+
+
+_rows_for.defvjp(_rows_for_fwd, _rows_for_bwd)
+
+
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    """``x[perm]`` for a permutation whose inverse is known: the
+    transpose is ``g[inverse]``, a gather, where autodiff would scatter."""
+    return x[perm]
+
+
+_permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
+                lambda inverse, g: (g[inverse], None, None))
+
+
+def route(scores, bias, top_k: int, scale: float):
+    """Bias-corrected top-k (DeepSeek-V3's ``noaux_tc`` with one group):
+    the choice is over ``scores + bias``, the gates are the chosen
+    experts' own scores, normalised over all ``top_k`` chosen and scaled.
+    ``scores``: (tokens, experts) float32. Returns ``(idx, gates)``, both
+    (tokens, top_k)."""
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = scale * chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
+    return idx, gates
+
+
+@register
+class RoutedExpertsOp(OpDef):
+    """One mixture-of-experts feed-forward layer, sparse and dropless,
+    for a device that holds ``experts_held`` of the model's
+    ``num_experts`` routed experts (``first_held`` onwards) and a shared
+    expert beside them.
+
+      s = sigmoid(x wg)                      float32, over ALL experts
+      S = top-k of (s + bias);  g_i = scale * s_i / sum_{j in S} s_j
+      y = sum_{i in S, i held} g_i E_i(x)  +  E_shared(x)
+
+    Every expert is a SwiGLU, ``w_down(silu(w_gate x) * w_up x)``. The
+    router, the choice and the gates' normalisation run over the
+    published expert count whatever is held: what the absent experts
+    would have added is left out, as on one rank of an expert-parallel
+    layer before its exchange. ``bias`` corrects the choice only and
+    gets no gradient (its balancing rule is a training recipe's, not
+    this op's).
+
+    Dispatch: the ``tokens x top_k`` assignments are sorted by expert,
+    those bound for absent experts in a trailing group that no product
+    touches; one grouped matrix product (``jax.lax.ragged_dot``) a
+    projection runs over the stacked weights ``(held, in, out)`` with
+    the held experts' counts as group sizes. Shapes are static and
+    nothing is dropped: the sorted buffer has a row for every
+    assignment, so any imbalance fits. The counters ``moe.*`` of the
+    step's metrics go through ``ctx.count``."""
+    op_type = OperatorType.OP_ROUTED_EXPERTS
+
+    def infer(self, params, in_shapes, in_dtypes):
+        return [(in_shapes[0], in_dtypes[0])]
+
+    def weights(self, params, in_shapes, in_dtypes):
+        e, dt = in_shapes[0][-1], in_dtypes[0]
+        n, held = params["num_experts"], params["experts_held"]
+        f, fs = params["expert_dim"], params["shared_dim"]
+        up, down = {"fans": (e, f)}, {"fans": (f, e)}   # fans per expert
+        ws = [WeightSpec("wg", (e, n), dt),
+              # drawn once; corrects the choice, never trained
+              WeightSpec("bias", (n,), dt, InitializerType.NORMAL,
+                         {"stddev": params.get("bias_std", 0.0)},
+                         create_grad=False),
+              WeightSpec("w_gate", (held, e, f), dt, init_args=up),
+              WeightSpec("w_up", (held, e, f), dt, init_args=up),
+              WeightSpec("w_down", (held, f, e), dt, init_args=down)]
+        if fs:
+            ws += [WeightSpec("ws_gate", (e, fs), dt),
+                   WeightSpec("ws_up", (e, fs), dt),
+                   WeightSpec("ws_down", (fs, e), dt)]
+        return ws
+
+    @staticmethod
+    def rows_multiplied(tokens: int, params) -> int:
+        """Rows of the sorted buffer the grouped products are handed: one
+        for every assignment, so none can be dropped."""
+        return tokens * params["top_k"]
+
+    def emit(self, params, inputs, weights, ctx, name):
+        (x,) = inputs
+        cdt, mdt = x.dtype, compute_dtype(ctx, x.dtype)
+        n, held, first = (params["num_experts"], params["experts_held"],
+                          params.get("first_held", 0))
+        k = params["top_k"]
+        xt = x.reshape(-1, x.shape[-1])
+        t = xt.shape[0]
+        rows = self.rows_multiplied(t, params)
+        if events.enabled():
+            events.instant("moe.route", layer=name, experts_published=n,
+                           experts_held=held, first_held=first, top_k=k,
+                           tokens=t, rows_multiplied=rows)
+
+        # the router in float32, as published: a bf16 pass moves scores
+        # by 1e-2 and with them the choice of experts
+        scores = jax.nn.sigmoid(jnp.dot(
+            xt.astype(jnp.float32), weights["wg"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        idx, gates = route(scores, weights["bias"].astype(jnp.float32), k,
+                           float(params.get("scale", 1.0)))
+
+        # sort the assignments by held expert; absent ones trail
+        local = idx.reshape(-1) - first
+        group = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+            jnp.int32)
+
+        # Rows past the held groups are never multiplied, and on the
+        # TPU a grouped product leaves them UNWRITTEN, in its output and
+        # in the cotangent its transpose hands back (the CPU's writes
+        # zeros; found on the chip, PERF.md section 6, PR 29). Both
+        # sides of every product are therefore masked: autodiff carries
+        # the two selects into the backward, where they zero what the
+        # transposed products leave in those rows.
+        multiplied = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+
+        def grouped(a, w):
+            out = jax.lax.ragged_dot(
+                jnp.where(multiplied, a, 0).astype(mdt), w.astype(mdt),
+                sizes, preferred_element_type=jnp.float32)
+            return jnp.where(multiplied, out, 0.0)
+
+        # gathered at the products' operand width: half the bytes of
+        # the op's largest buffer, in both directions
+        xs = _rows_for(xt.astype(mdt), order, inverse)      # (rows, e)
+        act = jax.nn.silu(grouped(xs, weights["w_gate"])) \
+            * grouped(xs, weights["w_up"])
+        ys = grouped(act, weights["w_down"])
+        y = jnp.einsum("tk,tke->te", gates,
+                       _permute(ys, inverse, order).reshape(t, k, -1))
+        if "ws_gate" in weights:
+            g = matmul(xt, weights["ws_gate"], ctx=ctx)
+            u = matmul(xt, weights["ws_up"], ctx=ctx)
+            y = y + matmul(jax.nn.silu(g) * u, weights["ws_down"], ctx=ctx)
+
+        # what the router bound for this share, read from its choices,
+        # against what the grouped products reached: an assignment is
+        # reached when the sort put it on a row inside the span that
+        # ``sizes`` gives its chosen expert, since that is whose weights
+        # the row meets. Dropless by construction, so 0 unless sort,
+        # sizes and mask disagree. One compare over (rows, held).
+        bound = jnp.sum((idx >= first) & (idx < first + held))
+        ends = jnp.cumsum(sizes)
+        at = inverse[:, None]
+        reached = jnp.sum((group[:, None] == jnp.arange(held))
+                          & (at >= ends - sizes) & (at < ends))
+        load = sizes.astype(jnp.float32)
+        for key, v in (("moe.local_assignments", bound),
+                       ("moe.dropped", bound - reached),
+                       ("moe.load_max", jnp.max(load)),
+                       ("moe.load_mean", jnp.mean(load))):
+            ctx.count(key, v.astype(jnp.float32))
+        return [y.reshape(x.shape).astype(cdt)]
+
+    def flops(self, params, in_shapes, out_shapes):
+        tokens = float(np.prod(in_shapes[0][:-1]))
+        e = in_shapes[0][-1]
+        share = params["experts_held"] / params["num_experts"]
+        routed = 3 * e * params["expert_dim"] * params["top_k"] * share
+        return 2.0 * tokens * (e * params["num_experts"] + routed
+                               + 3 * e * params["shared_dim"])
+
+    def backward_flops_factor(self):
+        return 2.0

@@ -313,6 +313,13 @@ class LayerNormOp(OpDef):
 
 
 # ---------------------------------------------------------------------------
+def _rms(x, scale, eps):
+    """RMSNorm of the last axis in float32 (float32 result)."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return xf * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)
+
+
 @register
 class RMSNormOp(OpDef):
     """RMSNorm — TPU-native addition (used by T5/LLaMA-style models; the
@@ -328,10 +335,7 @@ class RMSNormOp(OpDef):
 
     def emit(self, params, inputs, weights, ctx, name):
         (x,) = inputs
-        eps = params.get("eps", 1e-6)
-        xf = x.astype(jnp.float32)
-        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + eps) * weights["scale"].astype(jnp.float32)
+        y = _rms(x, weights["scale"], params.get("eps", 1e-6))
         return [y.astype(x.dtype)]
 
 
@@ -816,6 +820,165 @@ class MultiHeadAttentionOp(OpDef):
 
     def backward_flops_factor(self):
         return 2.0
+
+
+# ---------------------------------------------------------------------------
+def _rope_interleaved(x, pos, theta: float):
+    """Rotary embedding over interleaved pairs ``(2i, 2i+1)`` of the
+    last axis (DeepSeek's ``rope_interleave``), in float32. ``x``:
+    (b, s, ..., d); ``pos``: (b, s) absolute positions."""
+    d = x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[..., None] * inv           # (b, s, d/2)
+    ang = ang.reshape(pos.shape + (1,) * (x.ndim - 3) + (d // 2,))
+    xf = x.astype(jnp.float32)
+    even, odd = xf[..., 0::2], xf[..., 1::2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                     axis=-1).reshape(xf.shape)
+
+
+@register
+class LatentAttentionOp(OpDef):
+    """Multi-head latent attention (DeepSeek-V2/V3's MLA) as one op:
+    causal self-attention whose queries and keys/values come through
+    low-rank latents with an RMSNorm on each.
+
+      c_q = RMSNorm(x wq_a);  q_h = c_q wq_b      -> [q_nope ; q_rope]
+      [c_kv ; k_rope] = x wkv_a;  c_kv <- RMSNorm(c_kv)
+      c_kv wkv_b                                  -> [k_nope ; v] a head
+      k_h = [k_nope_h ; rope(k_rope)]   (one rotary key for all heads)
+      o_h = softmax(q_h k_h / sqrt(d_nope + d_rope), causal) v_h
+      out = [o_1 .. o_H] wo
+
+    Inputs: the hidden states (b, s, e) and their positions (b, s).
+    The rotary embedding turns interleaved pairs of the ``rope_dim``
+    last dimensions of q and of the shared key. q and k have ``nope_dim + rope_dim`` per head, v and
+    o ``v_dim``: the flash kernels take the two sizes as they are
+    (``kernels/flash_attention.py``), chosen by shape and switches
+    exactly as in :class:`MultiHeadAttentionOp`."""
+    op_type = OperatorType.OP_LATENT_ATTENTION
+
+    def infer(self, params, in_shapes, in_dtypes):
+        return [(in_shapes[0], in_dtypes[0])]
+
+    def weights(self, params, in_shapes, in_dtypes):
+        e, dt = in_shapes[0][-1], in_dtypes[0]
+        h, qr, kvr = (params["num_heads"], params["q_rank"],
+                      params["kv_rank"])
+        dn, dr, dv = params["nope_dim"], params["rope_dim"], params["v_dim"]
+        one = InitializerType.ONE
+
+        def fans(i, o):          # per-head projections: fans as a matrix
+            return {"fans": (i, o)}
+        return [WeightSpec("wq_a", (e, qr), dt),
+                WeightSpec("q_norm", (qr,), dt, one),
+                WeightSpec("wq_b", (qr, h, dn + dr), dt,
+                           init_args=fans(qr, h * (dn + dr))),
+                WeightSpec("wkv_a", (e, kvr + dr), dt),
+                WeightSpec("kv_norm", (kvr,), dt, one),
+                WeightSpec("wkv_b", (kvr, h, dn + dv), dt,
+                           init_args=fans(kvr, h * (dn + dv))),
+                WeightSpec("wo", (h, dv, e), dt,
+                           init_args=fans(h * dv, e))]
+
+    def emit(self, params, inputs, weights, ctx, name):
+        x, pos = inputs
+        if getattr(ctx, "kv_mode", None) is not None:
+            raise NotImplementedError(
+                f"{name}: latent attention has no KV-cache decode path")
+        cdt, mdt = x.dtype, compute_dtype(ctx, x.dtype)
+        eps = params.get("eps", 1e-6)
+        dn, dr = params["nope_dim"], params["rope_dim"]
+        kvr = params["kv_rank"]
+        b, s, _ = x.shape
+
+        def mm(a, w, pattern):
+            return jnp.einsum(pattern, a.astype(mdt), w.astype(mdt),
+                              preferred_element_type=jnp.float32)
+
+        c_q = _rms(mm(x, weights["wq_a"], "bse,er->bsr"),
+                   weights["q_norm"], eps)
+        q = mm(c_q, weights["wq_b"], "bsr,rhd->bshd")
+        kv_a = mm(x, weights["wkv_a"], "bse,er->bsr")
+        c_kv = _rms(kv_a[..., :kvr], weights["kv_norm"], eps)
+        kv = mm(c_kv, weights["wkv_b"], "bsr,rhd->bshd")
+        theta = float(params["rope_theta"])
+        q_rope = _rope_interleaved(q[..., dn:], pos, theta)
+        k_rope = _rope_interleaved(kv_a[..., kvr:], pos, theta)
+        h = q.shape[2]
+        qh = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        kh = jnp.concatenate(
+            [kv[..., :dn],
+             jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, dr))],
+            axis=-1)
+        vh = kv[..., dn:]
+
+        mha = MultiHeadAttentionOp
+        flash_mode = {"flash": "true", "xla": "false"}.get(
+            mha._impl_for(ctx, name), mha._flash_mode(ctx))
+        if mha._flash_enabled(ctx, seq_len=s, mode=flash_mode):
+            from ..kernels import flash_attention
+            mha._note_impl(ctx, name, "flash")
+            mesh, spec = mha._kernel_shard_spec(ctx, b, h)
+            o = flash_attention(
+                jnp.swapaxes(qh, 1, 2).astype(mdt),
+                jnp.swapaxes(kh, 1, 2).astype(mdt),
+                jnp.swapaxes(vh, 1, 2).astype(mdt),
+                causal=True, mesh=mesh, spec=spec)
+            o = jnp.swapaxes(o, 1, 2)
+        else:
+            mha._note_impl(ctx, name, "xla")
+            logits = jnp.einsum(
+                "bqhd,bkhd->bhqk", qh.astype(mdt), kh.astype(mdt),
+                preferred_element_type=jnp.float32) / math.sqrt(dn + dr)
+            mask = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+            probs = jax.nn.softmax(
+                jnp.where(mask, logits, jnp.float32(-1e9)), axis=-1)
+            o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(mdt),
+                           vh.astype(mdt),
+                           preferred_element_type=jnp.float32)
+        out = mm(o, weights["wo"], "bqhd,hde->bqe")
+        return [out.astype(cdt)]
+
+    def flops(self, params, in_shapes, out_shapes):
+        b, s, e = in_shapes[0]
+        h, qr, kvr = (params["num_heads"], params["q_rank"],
+                      params["kv_rank"])
+        dn, dr, dv = params["nope_dim"], params["rope_dim"], params["v_dim"]
+        proj = (e * qr + qr * h * (dn + dr) + e * (kvr + dr)
+                + kvr * h * (dn + dv) + h * dv * e)
+        return 2.0 * b * s * (proj + s * h * (dn + dr + dv))
+
+    def backward_flops_factor(self):
+        return 2.0
+
+
+@register
+class NextTokenLossOp(OpDef):
+    """The loss of a second prediction head: the mean cross-entropy of
+    ``logits[:, t]`` against ``ids[:, t + offset]`` over the positions
+    that have such a target (the last ``offset`` of a sequence have none
+    and are masked), times ``weight``, added to the step's loss through
+    ``ctx.aux_losses``. The output is the unweighted mean, shape (1,)."""
+    op_type = OperatorType.OP_NEXT_TOKEN_LOSS
+
+    def infer(self, params, in_shapes, in_dtypes):
+        return [((1,), DataType.DT_FLOAT)]
+
+    @staticmethod
+    def mean_nll(logits, ids, offset: int):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        tgt = ids[:, offset:].astype(jnp.int32)
+        nll = -jnp.take_along_axis(logp[:, :-offset], tgt[..., None],
+                                   axis=-1)
+        return jnp.mean(nll)
+
+    def emit(self, params, inputs, weights, ctx, name):
+        logits, ids = inputs
+        loss = self.mean_nll(logits, ids, int(params["offset"]))
+        ctx.aux_losses.append(params["weight"] * loss)
+        return [loss.reshape(1)]
 
 
 # ---------------------------------------------------------------------------

@@ -758,7 +758,7 @@ def sharded_grads(executor, params, state, batch, step, residual):
             synced[lname] = sl
 
         def sync_metric(k, v):
-            if k in metrics_mod.COUNT_KEYS:
+            if metrics_mod.is_count(k):
                 return jax.lax.psum(v, axes)
             if k in metrics_mod.RMS_KEYS:
                 return jnp.sqrt(jax.lax.psum(v * v, axes) / n)

@@ -35,6 +35,12 @@ class EmitCtx:
         self.config = config
         self.seq_length = seq_length
         self.aux_losses: List[Any] = []  # e.g. MoE load-balancing terms
+        # device counters of the step: an op adds to keys of its own
+        # (``count``), the executor puts the sums among the step's
+        # metrics (``runtime.metrics.COUNTER_PREFIX``), and they reach
+        # the host where the loss does. Unlike ``aux_losses`` they may be
+        # written inside a rematerialised block, which returns them.
+        self.counters: Dict[str, Any] = {}
         # KV-cache decode plumbing (serving; the reference has no
         # generation path at all). kv_mode: None = normal forward,
         # "prefill" = full-sequence forward that also records each
@@ -72,6 +78,10 @@ class EmitCtx:
 
     def rng_for(self, name: str):
         return self.rngs.get(name)
+
+    def count(self, key: str, value) -> None:
+        """Add ``value`` (a device scalar) to the step's counter ``key``."""
+        self.counters[key] = self.counters.get(key, 0.0) + value
 
 
 class OpDef:

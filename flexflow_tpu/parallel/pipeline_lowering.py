@@ -458,12 +458,16 @@ def assign_tp_roles(template: Sequence[Layer], tp: int
     return roles
 
 
-def find_repeated_run(layers: Sequence[Layer], n_parts: int = 1
+def find_repeated_run(layers: Sequence[Layer], n_parts: int = 1,
+                      shared: frozenset = frozenset()
                       ) -> Optional[Tuple[int, int, int]]:
     """The maximal verified run of identical consecutive chunks whose
     repeat count is divisible by ``n_parts``. Returns
     ``(total_len, start, unit)`` or None. Shared by the pipeline region
-    finder and the block-rematerialization pass."""
+    finder and the block-rematerialization pass. ``shared``: guids every
+    chunk may read beside its entry tensor (the rematerialization pass
+    hands its blocks the graph's inputs; a pipeline stage gets only its
+    entry, so the region finder passes none)."""
     layers = list(layers)
     n = len(layers)
     sigs = [layer_signature(l) for l in layers]
@@ -482,7 +486,7 @@ def find_repeated_run(layers: Sequence[Layer], n_parts: int = 1
             reps -= reps % n_parts           # whole chunks only
             if reps >= max(n_parts, 2) and reps * unit > (best or (0,))[0]:
                 # verify structure before accepting
-                if _verify_run(layers, start, unit, reps):
+                if _verify_run(layers, start, unit, reps, shared):
                     best = (reps * unit, start, unit)
     return best
 
@@ -508,7 +512,7 @@ def chunk_boundaries(layers: Sequence[Layer], start: int, unit: int,
 
 
 def _verify_run(layers: Sequence[Layer], start: int, unit: int,
-                reps: int) -> bool:
+                reps: int, shared: frozenset = frozenset()) -> bool:
     """Cheap pre-check that consecutive unit chunks are chainable: each
     chunk's inputs come from itself or the previous chunk's outputs (or
     the tensor entering the first chunk)."""
@@ -517,7 +521,7 @@ def _verify_run(layers: Sequence[Layer], start: int, unit: int,
     external = set()
     for l in region:
         for t in l.inputs:
-            if t.guid not in internal:
+            if t.guid not in internal and t.guid not in shared:
                 external.add(t.guid)
     return len(external) == 1
 

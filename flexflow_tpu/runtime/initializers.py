@@ -16,6 +16,9 @@ from ..ffconst import InitializerType
 
 
 def _fan_in_out(shape: Tuple[int, ...]) -> Tuple[int, int]:
+    """Fans read off a weight's shape. A weight whose shape does not say
+    them (stacked experts, per-head projections) gives its own as
+    ``init_args["fans"] = (fan_in, fan_out)``."""
     if len(shape) == 0:
         return 1, 1
     if len(shape) == 1:
@@ -66,7 +69,7 @@ def initialize_host(spec, key_ints, np_dtype):
         mean, std = args.get("mean", 0.0), args.get("stddev", 0.05)
         return (mean + std * gen.standard_normal(shape)).astype(np_dtype)
     if kind == InitializerType.GLOROT_UNIFORM:
-        fan_in, fan_out = _fan_in_out(shape)
+        fan_in, fan_out = args.get("fans") or _fan_in_out(shape)
         limit = math.sqrt(6.0 / (fan_in + fan_out))
         return gen.uniform(-limit, limit, shape).astype(np_dtype)
     raise ValueError(kind)
@@ -90,7 +93,7 @@ def initialize(spec, rng, jnp_dtype):
         mean, std = args.get("mean", 0.0), args.get("stddev", 0.05)
         return mean + std * jax.random.normal(rng, shape, jnp_dtype)
     if kind == InitializerType.GLOROT_UNIFORM:
-        fan_in, fan_out = _fan_in_out(shape)
+        fan_in, fan_out = args.get("fans") or _fan_in_out(shape)
         limit = math.sqrt(6.0 / (fan_in + fan_out))
         return jax.random.uniform(rng, shape, jnp_dtype, -limit, limit)
     raise ValueError(kind)

@@ -15,10 +15,24 @@ import jax.numpy as jnp
 
 from ..ffconst import LossType, MetricsType
 
+#: Prefix, among a step's metrics, of the device counters its ops added
+#: to through ``EmitCtx.count``: whatever an op counts under ``<name>``
+#: is the metric ``COUNTER_PREFIX + <name>``, a sum over the step's
+#: layers, fetched with the loss and recorded as the ``obs.events``
+#: counter ``<name>`` at each flush of the metrics buffer. No name is
+#: listed here: the ops own theirs.
+COUNTER_PREFIX = "counter/"
+
 # batch-metric keys that are COUNTS over samples (vs per-sample means):
 # accumulation/reduction layers must SUM these across micro-batches,
 # never average (see Executor.make_train_step)
 COUNT_KEYS = frozenset({"accuracy_correct"})
+
+
+def is_count(key: str) -> bool:
+    """A count (summed across micro-batches and replicas): one of
+    ``COUNT_KEYS`` or an op's device counter."""
+    return key in COUNT_KEYS or key.startswith(COUNTER_PREFIX)
 
 # keys that are sqrt-of-a-mean: composing across micro-batches must
 # average the SQUARES and take one sqrt at the end (mean of per-micro

@@ -51,6 +51,7 @@ import jax
 from ..obs import events as obs_events
 from ..obs.events import _env_on
 from ..obs.metrics_registry import REGISTRY
+from .metrics import COUNTER_PREFIX
 
 ENV_SYNC = "FF_SYNC_EVERY_STEP"
 
@@ -177,6 +178,11 @@ class MetricsBuffer:
                 ok = loss is None or math.isfinite(float(loss))
             if self.pm is not None:
                 self.pm.update(vals, bsz)
+            if obs_events.enabled():
+                for key, v in vals.items():
+                    if key.startswith(COUNTER_PREFIX):
+                        obs_events.counter(key[len(COUNTER_PREFIX):],
+                                           float(v))
             if not bool(ok) and self.first_bad_step is None:
                 self.first_bad_step = step_idx
                 self.first_bad_value = float(loss) if loss is not None \

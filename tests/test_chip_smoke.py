@@ -15,7 +15,8 @@ import sys
 import pytest
 
 import chip_smoke
-from flexflow_tpu.models.nlp import BertConfig, GPTConfig
+from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
+                                     LatentMoEConfig)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -54,6 +55,26 @@ def test_leg_b_gpt2_tiny_on_the_cpu_mesh(capsys):
     assert "B/gpt2: resolved attention impl ['xla']" in out   # cpu: auto
     assert "B/generate: 4 tokens after a 8-token prompt" in out
     assert "B/fused-adam: " in out and "leaves match" in out
+
+
+def test_leg_c_latent_moe_tiny_on_the_cpu_mesh(capsys):
+    """A share of 4 of 16 experts on the 8-device mesh: the two expert
+    layers are the rematerialised run, each announces its routing."""
+    cfg = dataclasses.replace(LatentMoEConfig.tiny(), n_routed_experts=4,
+                              n_routed_experts_published=16)
+    chip_smoke.leg_latent_moe(cfg, seq=16, per_chip_batch=1,
+                              label="C/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "rematerialised run (12, 6, 2)" in out
+    assert "resolved attention impls ['xla'] in 4 layers" in out  # cpu
+    for layer in ("experts_1", "experts_2", "experts_mtp"):
+        assert (f"moe.route {layer}: 4 of 16 experts held from 0, top 4, "
+                f"128 tokens, 512 rows") in out
+    assert "moe.dropped 0.0" in out
+    # what this leg cannot see is named, and the named script exists
+    assert f"python3 {chip_smoke.VALIDATION}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

@@ -576,3 +576,87 @@ def test_grid_steps_of_cell_2_before_and_after():
         new = fa.grid_steps(kernel, 144, 1024, 1024, bq, bk, True)
         assert new["steps"] <= 9216 // 4
         assert new["fetched_steps"] == new["live_steps"]
+
+
+# ---------------------------------------------------------------------------
+# q.k over one head size, p.v over another (latent attention: 192 / 128)
+# ---------------------------------------------------------------------------
+def _rand_qk_v(d, dv, b=1, h=2, s=256, seed=3):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((b, h, s, w)), jnp.float32)
+                 for w in (d, d, dv))
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("d,dv", [(24, 16), (192, 128)])
+def test_flash_with_unequal_head_sizes_matches_reference(d, dv, causal,
+                                                         what):
+    """The three kernels with v and the output narrower than q and k, at
+    128-wide tiles so that every kernel accumulates over several blocks.
+    f32 operands in interpret mode: the tolerances are those of the
+    equal-size tests above."""
+    q, k, v = _rand_qk_v(d, dv)
+
+    def run(fn):
+        def out(q, k, v):
+            return fn(q, k, v)
+        if what == "out":
+            return out(q, k, v)
+        return jax.grad(lambda *a: jnp.sum(out(*a) ** 2),
+                        argnums="qkv".index(what[1]))(q, k, v)
+
+    got = run(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, interpret=True, block_q=128, block_k=128,
+        bwd_block_q=128, bwd_block_k=128))
+    want = run(lambda q, k, v: mha_reference(
+        q, k, v, causal=causal, precision=jax.lax.Precision.HIGHEST))
+    assert got.shape == (v.shape if what in ("out", "dv") else q.shape)
+    tol = 2e-5 if what == "out" else 5e-4
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=tol, rtol=tol)
+
+
+def test_unequal_head_sizes_scale_by_the_key_size_and_pad_neither():
+    q, k, v = _rand_qk_v(24, 16, s=128)
+    out = flash_attention(q, k, v, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(mha_reference(q, k, v, sm_scale=1 / np.sqrt(24))),
+        atol=2e-5, rtol=2e-5)
+    events.enable()
+    events.clear()
+    try:
+        jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=True)))(q, k, v)
+        grids = [e["attrs"]["kernel"] for e in events.events()
+                 if e["name"] == "flash.grid"]
+    finally:
+        events.disable()
+        events.clear()
+    assert sorted(grids) == ["flash_attention_bwd_dkv",
+                             "flash_attention_bwd_dq",
+                             "flash_attention_fwd"]
+
+
+@pytest.mark.parametrize("sq,sk,d,dtype,dropout", [
+    c for c in TILE_RULE_CASES if c[2] == 256])
+def test_bwd_tile_rule_counts_both_head_sizes(sq, sk, d, dtype, dropout):
+    """A narrower v needs no more room than an equal one, an equal one
+    exactly what it needed before there were two sizes, and the tile the
+    rule hands out for (d, dv) fits the budget as counted for both."""
+    item = jnp.dtype(dtype).itemsize
+    for kernel in ("bwd_dq", "bwd_dkv"):
+        same = fa._bwd_vmem_bytes(kernel, 512, 512, d, item, dropout)
+        assert same == fa._bwd_vmem_bytes(kernel, 512, 512, d, item,
+                                          dropout, d)
+        assert fa._bwd_vmem_bytes(kernel, 512, 512, d, item, dropout,
+                                  128) < same
+    narrow = fa.bwd_tiles(sq, sk, d, jnp.dtype(dtype), dropout, 128)
+    equal = fa.bwd_tiles(sq, sk, d, jnp.dtype(dtype), dropout)
+    for kernel, (bq, bk), (eq, ek) in zip(("bwd_dq", "bwd_dkv"), narrow,
+                                          equal):
+        assert bq * bk >= eq * ek
+        assert fa._bwd_vmem_bytes(kernel, bq, bk, d, item, dropout,
+                                  128) <= fa.BWD_VMEM_BUDGET \
+            or (bq, bk) == (fa._tile_sizes(sq)[0], fa._tile_sizes(sk)[0])

@@ -1,0 +1,372 @@
+"""The DeepSeek-V3-shaped decoder (latent attention, routed experts with
+a shared one, a multi-token-prediction module) against its plain
+reference (``benchmarks/reference/latent_moe_ref.py``), at a small size
+on the CPU with seeded random weights.
+
+Precision: the program computes in float32 here (``use_bf16_compute``
+off) and the CPU's float32 matrix product is exact to rounding, as is
+the reference's ``highest``; the two differ in the order of their sums
+(the sorted grouped product against a loop over experts, a fused
+softmax against an explicit one). ``TOL`` = 2e-4 relative to the largest
+entry is forty times what they read (5e-6) and a thousand times under a
+wrong gate, a dropped assignment or a rotation by the wrong pair, each
+of which moves the result by 1e-1 or more.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.harness import cells
+from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.models.nlp import LatentMoEConfig, build_latent_moe
+from flexflow_tpu.obs import events
+from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
+from flexflow_tpu.ops.registry import EmitCtx
+from flexflow_tpu.runtime.metrics import COUNTER_PREFIX, is_count
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
+                        "latent_moe_ref")
+TOL = 2e-4
+B, S = 2, 32
+
+
+def close(got, want, tol=TOL):
+    got, want = np.asarray(got), np.asarray(want)
+    scale = max(float(np.max(np.abs(want))), 1e-6)
+    err = float(np.max(np.abs(got - want))) / scale
+    assert err <= tol, f"relative error {err:.3e} > {tol}"
+
+
+def build(remat="none", flash="false", model_cfg=None, seed=0):
+    cfg = FFConfig()
+    cfg.batch_size = B
+    cfg.only_data_parallel = True        # no search: 0.3 s a compile
+    cfg.use_bf16_compute = False
+    cfg.use_flash_attention = flash
+    cfg.remat = remat
+    cfg.seed = seed
+    ff = FFModel(cfg)
+    mc = model_cfg or LatentMoEConfig.tiny()
+    out = build_latent_moe(ff, B, S, mc)
+    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
+               output_tensor=out)
+    return ff, mc
+
+
+def data(mc, seed=1):
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, mc.vocab_size, (B, S)).astype(np.int32)
+    pos = np.tile(np.arange(S, dtype=np.int32), (B, 1))
+    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
+            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
+
+
+def named(ff, params):
+    return [(l.name, params[l.name]) for l in ff.layers
+            if l.name in params]
+
+
+def program_loss(ff, params, batch, training=True):
+    ex = ff.executor
+    outs, _, aux, capture = ex._forward(
+        params, ff.state, batch, training, jnp.int32(0))
+    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
+    mtp = next(l for l in ex.program.layers if l.name == "mtp_loss")
+    return loss, (bm, outs[0], capture.get(mtp.inputs[0].guid))
+
+
+def reference_loss(ff, mc, params, batch):
+    return ref.loss(named(ff, params), dataclasses.asdict(mc),
+                    batch["input_ids"], batch["position_ids"],
+                    batch["label"][..., 0])
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    ff, mc = build()
+    return ff, mc, data(mc)
+
+
+def test_the_bias_changes_some_tokens_choice(tiny):
+    """The tests below would not see a bias that is ignored unless it
+    decides something: with these weights it does."""
+    ff, mc, batch = tiny
+    w = ff.params["experts_1"]
+    assert float(jnp.max(jnp.abs(w["bias"]))) > 0
+    x = jax.random.normal(jax.random.key(3), (64, mc.hidden_size))
+    s = jax.nn.sigmoid(x @ w["wg"])
+    k = mc.num_experts_per_tok
+    with_b = jnp.sort(jax.lax.top_k(s + w["bias"], k)[1], -1)
+    without = jnp.sort(jax.lax.top_k(s, k)[1], -1)
+    assert bool(jnp.any(with_b != without))
+
+
+@pytest.mark.parametrize("flash", ["true", "false"])
+def test_heads_and_loss_match_the_reference(flash):
+    ff, mc = build(flash=flash)
+    batch = data(mc)
+    loss, (_, probs, mtp_logits) = program_loss(ff, ff.params, batch,
+                                                training=False)
+    main, mtp = ref.heads(named(ff, ff.params), dataclasses.asdict(mc),
+                          batch["input_ids"], batch["position_ids"])
+    close(jnp.log(probs), main)
+    close(jax.nn.log_softmax(mtp_logits, -1), mtp)
+    close(loss, reference_loss(ff, mc, ff.params, batch))
+    assert set(ff.executor.resolved_attention_impls.values()) == {
+        "flash" if flash == "true" else "xla"}
+
+
+def test_every_weights_gradient_matches_the_reference(tiny):
+    ff, mc, batch = tiny
+    got = jax.jit(jax.grad(
+        lambda p: program_loss(ff, p, batch)[0]))(ff.params)
+    want = jax.jit(jax.grad(
+        lambda p: reference_loss(ff, mc, p, batch)))(ff.params)
+    assert {n for n in got} == {n for n in want}
+    for name in got:
+        for key in got[name]:
+            assert float(jnp.max(jnp.abs(want[name][key]))) > 0 \
+                or key == "bias", (name, key)
+            close(got[name][key], want[name][key])
+    # the correction bias decides the choice and gets no gradient
+    for name in got:
+        if name.startswith("experts_"):
+            assert not np.any(np.asarray(got[name]["bias"]))
+    # the norms on the two latents and the router were among them
+    assert {"q_norm", "kv_norm"} <= set(got["attn_0"])
+    assert "wg" in got["experts_mtp"]
+
+
+def _experts_layer(mc, x, weights, first, held, with_shared=True):
+    """One routed-experts op holding experts ``first .. first + held``."""
+    op = RoutedExpertsOp()
+    params = dict(num_experts=mc.n_routed_experts,
+                  top_k=mc.num_experts_per_tok,
+                  expert_dim=mc.moe_intermediate_size,
+                  shared_dim=mc.moe_intermediate_size, experts_held=held,
+                  first_held=first, scale=mc.routed_scaling_factor)
+    w = {k: v for k, v in _share(weights, first, held).items()
+         if with_shared or not k.startswith("ws_")}
+    cfg = FFConfig()
+    cfg.use_bf16_compute = False
+    ctx = EmitCtx(training=True, config=cfg)
+    (y,) = op.emit(params, [x], w, ctx, "experts")
+    return y, ctx.counters
+
+
+def _share(weights, first, held):
+    """The weights one share holds: its block of the stacked experts."""
+    return {k: (v[first:first + held] if k in ("w_gate", "w_up", "w_down")
+                else v) for k, v in weights.items()}
+
+
+def _layer_sizes(mc, held, first):
+    return dict(dataclasses.asdict(mc), n_routed_experts=held,
+                first_held_expert=first)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(tiny):
+    """model-configs guide, section 4: the routed parts that the four
+    shares of four experts each compute, plus the shared expert counted
+    once, are the uncut reference's layer output."""
+    ff, mc, _ = tiny
+    w = ff.params["experts_2"]
+    x = jax.random.normal(jax.random.key(5), (B, S, mc.hidden_size))
+    routed = sum(_experts_layer(mc, x, w, first, 4, with_shared=False)[0]
+                 for first in (0, 4, 8, 12))
+    whole, _ = _experts_layer(mc, x, w, 0, 4)
+    shared_once = whole - _experts_layer(mc, x, w, 0, 4,
+                                         with_shared=False)[0]
+    with jax.default_matmul_precision("highest"):
+        want = ref.routed(x, w, _layer_sizes(mc, 16, 0)) + ref.shared(x, w)
+        one_share = ref.routed(x, _share(w, 4, 4), _layer_sizes(mc, 4, 4))
+    close(routed + shared_once, want)
+    # and one share alone is the reference's same share, not the whole
+    close(_experts_layer(mc, x, w, 4, 4, with_shared=False)[0], one_share)
+    assert float(jnp.max(jnp.abs(one_share - want))) > 0.1
+
+
+@pytest.mark.parametrize("first,bound", [(0, B * S), (4, 0)])
+def test_nothing_is_dropped_under_the_worst_imbalance(tiny, first, bound):
+    """A router that sends every token to experts 3, 8, 9 and 10: the
+    share holding 0-3 gets every token at ONE expert, the share holding
+    4-7 gets none. Both are the reference, and nothing is dropped."""
+    ff, mc, _ = tiny
+    w = dict(ff.params["experts_2"])
+    w["wg"] = jnp.zeros_like(w["wg"])
+    w["bias"] = jnp.zeros_like(w["bias"]).at[jnp.array([3, 8, 9, 10])].set(1.)
+    x = jax.random.normal(jax.random.key(7), (B, S, mc.hidden_size))
+    y, counters = _experts_layer(mc, x, w, first, 4)
+    with jax.default_matmul_precision("highest"):
+        want = ref.routed(x, _share(w, first, 4),
+                          _layer_sizes(mc, 4, first)) + ref.shared(x, w)
+    close(y, want)
+    assert float(counters["moe.dropped"]) == 0
+    assert float(counters["moe.local_assignments"]) == bound
+    assert float(counters["moe.load_max"]) == bound
+
+
+@pytest.mark.parametrize("lost", [1, 7])
+def test_a_miscounted_group_shows_as_dropped(tiny, monkeypatch, lost):
+    """``moe.dropped`` compares what the router chose with the rows the
+    grouped products met under the chosen expert's weights: group sizes
+    that lose ``lost`` rows of expert 1 leave at least those unreached
+    (and shift every later group onto its neighbour's weights)."""
+    ff, mc, _ = tiny
+    x = jax.random.normal(jax.random.key(9), (B, S, mc.hidden_size))
+    _, sound = _experts_layer(mc, x, ff.params["experts_2"], 0, 4)
+    assert float(sound["moe.dropped"]) == 0
+    assert float(sound["moe.load_max"]) > lost
+    bincount = jnp.bincount
+    monkeypatch.setattr(jnp, "bincount", lambda g, length: bincount(
+        g, length=length).at[1].add(-lost))
+    _, short = _experts_layer(mc, x, ff.params["experts_2"], 0, 4)
+    assert float(short["moe.local_assignments"]) == \
+        float(sound["moe.local_assignments"])
+    assert float(short["moe.dropped"]) >= lost
+
+
+def test_rematerialised_blocks_give_the_same_step_and_their_counters():
+    """``remat = "blocks"`` wraps the expert layers in ``jax.checkpoint``:
+    same loss, same gradients, and the layers' counters come out of the
+    blocks (summed over the two in the run and the module's one)."""
+    plain, mc = build()
+    remat, _ = build(remat="blocks")
+    assert remat.executor._remat is not None
+    start, unit, reps = remat.executor._remat[:3]
+    block = remat.executor.program.layers[start:start + unit]
+    assert [l.name for l in block][:2] == ["input_norm_1", "attn_1"]
+    assert reps == 2 and block[-2].name == "experts_1"
+    batch = data(mc)
+    results = []
+    for ff in (plain, remat):
+        (loss, (bm, _, _)), grads = jax.jit(jax.value_and_grad(
+            lambda p, ff=ff: program_loss(ff, p, batch), has_aux=True))(
+                ff.params)
+        results.append((loss, bm, grads))
+    (l0, bm0, g0), (l1, bm1, g1) = results
+    close(l1, l0, 1e-6)
+    for name in g0:
+        for key in g0[name]:
+            close(g1[name][key], g0[name][key], 1e-5)
+    for key in ("moe.local_assignments", "moe.dropped", "moe.load_max",
+                "moe.load_mean"):
+        assert float(bm1[COUNTER_PREFIX + key]) == \
+            float(bm0[COUNTER_PREFIX + key])
+    # every assignment is local when all 16 experts are held: 3 layers
+    assert float(bm1[COUNTER_PREFIX + "moe.local_assignments"]) == \
+        3 * B * S * mc.num_experts_per_tok
+    assert float(bm1[COUNTER_PREFIX + "moe.dropped"]) == 0
+    # the ops own their counters' names: the runtime lists none
+    assert {k for k in bm1 if is_count(k)} == {
+        COUNTER_PREFIX + "moe." + k for k in (
+            "local_assignments", "dropped", "load_max", "load_mean")}
+
+
+def test_fit_records_instants_and_counters_and_leaves_the_bias():
+    events.enable()
+    events.clear()
+    try:
+        ff, mc = build(remat="blocks", flash="true")
+        batch = data(mc)
+        x = [np.asarray(batch["input_ids"]),
+             np.asarray(batch["position_ids"])]
+        bias = np.asarray(ff.params["experts_1"]["bias"]).copy()
+        gate = np.asarray(ff.params["experts_1"]["wg"]).copy()
+        hist = ff.fit(x=x, y=np.asarray(batch["label"]), epochs=3,
+                      verbose=False)
+        assert hist[-1]["loss"] < hist[0]["loss"]
+        # Adam from zeroed moments leaves what gets no gradient where the
+        # seed drew it: the correction bias, and not the router beside it
+        assert np.array_equal(ff.params["experts_1"]["bias"], bias)
+        assert not np.array_equal(ff.params["experts_1"]["wg"], gate)
+        routes = [e["attrs"] for e in events.events()
+                  if e["name"] == "moe.route"]
+        assert {r["layer"] for r in routes} == {
+            "experts_1", "experts_2", "experts_mtp"}
+        assert all(r["experts_published"] == 16 and r["experts_held"] == 16
+                   and r["rows_multiplied"] == B * S * 4 for r in routes)
+        grids = [e["attrs"] for e in events.events()
+                 if e["name"] == "flash.grid"]
+        assert {g["kernel"] for g in grids} == {
+            "flash_attention_fwd", "flash_attention_bwd_dq",
+            "flash_attention_bwd_dkv"}
+        c = events.counters()
+        # 3 steps x 3 expert layers x every assignment
+        assert c["moe.local_assignments"] == 3 * 3 * B * S * 4
+        assert c["moe.dropped"] == 0
+        assert c["moe.load_max"] >= c["moe.load_mean"] > 0
+    finally:
+        events.disable()
+        events.clear()
+
+
+def test_stacked_experts_take_their_fans_per_expert(tiny):
+    """Glorot's limit for a stacked weight is one expert's
+    sqrt(6 / (in + out)), not the stack's: the initial loss of the
+    benchmark's cell depends on it."""
+    ff, mc, _ = tiny
+    w = np.asarray(ff.params["experts_1"]["w_gate"])
+    limit = np.sqrt(6.0 / (mc.hidden_size + mc.moe_intermediate_size))
+    assert 0.9 * limit < np.abs(w).max() <= limit
+    wq_b = np.asarray(ff.params["attn_0"]["wq_b"])
+    limit = np.sqrt(6.0 / (mc.q_lora_rank + mc.num_attention_heads * (
+        mc.qk_nope_head_dim + mc.qk_rope_head_dim)))
+    assert 0.9 * limit < np.abs(wq_b).max() <= limit
+
+
+def test_the_builder_refuses_what_it_cannot_hold():
+    ff = FFModel(FFConfig())
+    x = ff.create_tensor((B, S, 64))
+    with pytest.raises(ValueError, match="are not among"):
+        ff.routed_experts(x, 16, 4, 32, experts_held=8, first_held=12)
+    with pytest.raises(ValueError, match="top_k"):
+        ff.routed_experts(x, 4, 8, 32)
+
+
+def test_rows_the_grouped_products_leave_unwritten_reach_nothing(
+        tiny, monkeypatch):
+    """On the TPU ``jax.lax.ragged_dot`` leaves the rows past its groups
+    unwritten, in its output and in the cotangent of its left operand;
+    the CPU's writes zeros there, which hid that the op once summed
+    those rows' cotangents into the tokens' gradients (found on the
+    chip: gradients 1e5 times the reference's). Here the product is
+    made to leave NaN where the chip leaves whatever was in memory: the
+    layer's output and every gradient must not notice."""
+    ff, mc, _ = tiny
+    w = ff.params["experts_2"]
+    x = jax.random.normal(jax.random.key(11), (B, S, mc.hidden_size))
+    real = jax.lax.ragged_dot
+
+    def leaves_rows_unwritten(lhs, rhs, sizes, **kw):
+        written = (jnp.arange(lhs.shape[0]) < jnp.sum(sizes))[:, None]
+
+        @jax.custom_vjp
+        def product(lhs, rhs):
+            return jnp.where(written, real(lhs, rhs, sizes, **kw), jnp.nan)
+
+        def bwd(res, g):
+            # the transposed products read and write the groups' rows only
+            d_lhs, d_rhs = jax.vjp(lambda a, b: real(a, b, sizes, **kw),
+                                   *res)[1](jnp.where(written, g, 0))
+            return jnp.where(written, d_lhs, jnp.nan), d_rhs
+
+        product.defvjp(lambda a, b: (product(a, b), (a, b)), bwd)
+        return product(lhs, rhs)
+
+    def loss(x, w):
+        y, _ = _experts_layer(mc, x, w, 4, 4)     # 12 of 16 experts absent
+        return jnp.sum(jnp.sin(y)), y
+
+    (_, y0), g0 = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(x, w)
+    monkeypatch.setattr(jax.lax, "ragged_dot", leaves_rows_unwritten)
+    (_, y1), g1 = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(x, w)
+    assert np.array_equal(np.asarray(y0), np.asarray(y1))
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+        assert np.all(np.isfinite(np.asarray(b)))
+        assert np.array_equal(np.asarray(a), np.asarray(b))

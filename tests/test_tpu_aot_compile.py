@@ -104,6 +104,29 @@ def test_flash_fwd_bwd_compiles_on_one_device(v5e_devices, chip_locations,
     assert _kernel_names(txt) == FLASH_NAMES
 
 
+def test_flash_with_unequal_head_sizes_compiles_at_the_latent_shape(
+        v5e_devices, chip_locations):
+    """The three kernels at the shape of ``joyai_llm_flash.train.1chip``:
+    32 heads, 4096 positions, q.k over 192 and p.v over 128, bf16,
+    causal, at the backward tiles ``bwd_tiles`` hands out for them."""
+    from flexflow_tpu.kernels.flash_attention import bwd_tiles
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    sh = NamedSharding(mesh, P())
+    qk = jax.ShapeDtypeStruct((1, 32, 4096, 192), jnp.bfloat16, sharding=sh)
+    v = jax.ShapeDtypeStruct((1, 32, 4096, 128), jnp.bfloat16, sharding=sh)
+    # v's 128 lanes beside q.k's 256: wider tiles fit than at 192 / 192
+    assert bwd_tiles(4096, 4096, 192, jnp.bfloat16, False, 128) == (
+        (1024, 1024), (1024, 1024))
+    assert bwd_tiles(4096, 4096, 192, jnp.bfloat16, False) != (
+        (1024, 1024), (1024, 1024))
+    txt = _compile_text(
+        jax.grad(functools.partial(_flash_loss, None, None),
+                 argnums=(0, 1, 2)), qk, qk, v)
+    assert _kernel_names(txt) == FLASH_NAMES
+    # no operand was padded to a common head size
+    assert "bf16[32,4096,256]" not in txt
+
+
 @pytest.mark.parametrize("dropout", [False, True])
 @pytest.mark.parametrize("dtype,d", [("bfloat16", 256), ("float32", 64),
                                      ("float32", 128), ("float32", 256)])

@@ -6,7 +6,9 @@ One process, normal entry points only (``FFConfig`` -> ``FFModel`` ->
 whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
 
   Leg A  trainer, the paper's model: BERT-large at published width,
-         Adam, a few steps on one fixed batch drawn from a seed.
+         Adam, a few steps on one fixed batch drawn from a seed; its
+         attention (512 positions, dropout 0.1) resolves to the compiled
+         flash kernel by itself, as the `auto` rule says for that shape.
   Leg B  the kernels on the same path: GPT-2 at published width and its
          own context length, so attention resolves to the compiled flash
          kernel by itself; KV-cache generation against the re-forward
@@ -288,7 +290,25 @@ def leg_bert_train(bert_cfg, seq: int, per_chip_batch: int,
          np.tile(np.arange(seq, dtype=np.int32), (batch, 1))]
     y = rng.integers(0, bert_cfg.num_labels, (batch, 1)).astype(np.int32)
     _fit(ff, x, y, "A/bert", dropout=bert_cfg.dropout > 0)
-    _check_step_program(ff, x, y, "A/bert", want_custom_call=False)
+    # attention by itself, as in leg B: a layer the search planned no
+    # kernel for takes what the `auto` rule gives at this leg's own
+    # shape (on the chip at 512 positions with dropout: the flash kernels)
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp as mha
+    head = bert_cfg.hidden_size // bert_cfg.num_heads
+    chip = jax.devices()[0].platform != "cpu"
+    rule = "flash" if chip and mha.auto_takes_flash(
+        seq, seq, head, head, bert_cfg.dropout) else "xla"
+    plan = ff.strategy.kernel_impls or {}
+    impls = ff.executor.resolved_attention_impls    # of the train step
+    want = {n: plan.get(n, plan.get("attention")) or rule for n in impls}
+    say(f"A/bert: resolved attention impl {sorted(set(impls.values()))}, "
+        f"kernel plan {plan or 'none'}, the rule alone gives {rule}")
+    check(impls == want,
+          f"A/bert: attention resolved to {impls}, not {want}, at seq "
+          f"{seq}, head size {head}, dropout {bert_cfg.dropout}")
+    flash = "flash" in want.values()
+    _check_step_program(ff, x, y, "A/bert", want_custom_call=flash)
+    _check_flash_grids("A/bert", want=flash)
     say(f"A/bert: per-chip batch {per_chip_batch} (global {batch}), "
         f"seq {seq}, {bert_cfg.num_layers} layers x "
         f"{bert_cfg.hidden_size}, peak_bytes_in_use {_peak_bytes()}")

@@ -17,17 +17,26 @@ Checks (each prints PASS/FAIL, exit code 1 on any failure):
      against the golden at ``highest`` precision;
   3. dropout>0: deterministic under one seed, decorrelated across seeds,
      empirical keep-rate ≈ 1-rate, and vjp matches jax.grad of an
-     explicit-masked golden built from the kernel's own keep-mask.
+     explicit-masked golden built from the kernel's own keep-mask;
+  4. the benchmark's cell-1 layer (8 x 16 x 512 x 64, bf16, dropout 0.1):
+     kernels and vjp against that golden at ``highest``, the mask's keep
+     share over the layer's 33.5M positions, and the attention op with
+     nothing forced: ``auto`` takes the kernels there (PERF.md, PR 30).
 """
+import os
 import sys
 
-import numpy as np
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
 
-import jax
-import jax.numpy as jnp
+import numpy as np  # noqa: E402
 
-from flexflow_tpu.kernels import flash_attention, mha_reference
-from flexflow_tpu.obs import events
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from flexflow_tpu.kernels import flash_attention, mha_reference  # noqa: E402
+from flexflow_tpu.obs import events  # noqa: E402
 
 FAILED = []
 
@@ -52,6 +61,66 @@ def rel_err(a, b):
     a = np.asarray(a, np.float32)
     b = np.asarray(b, np.float32)
     return float(np.abs(a - b).max() / (np.abs(b).max() + 1e-9))
+
+
+def cell1_layer(rng, b=8, s=512, d=64):
+    """-- 4: the benchmark's cell 1 (8 x 16 heads of 512 x 64, bf16, not
+    causal, dropout 0.1), which ``auto`` puts on the kernels since PR 30:
+    the kernels against the explicit-mask golden at ``highest``, the
+    share of the layer's 33.5M positions the mask keeps, and the
+    attention op with nothing forced, which must take the kernels."""
+    import math
+
+    from flexflow_tpu.kernels import dropout_keep_mask
+    h, rate, seed = 1024 // d, 0.1, 1234567
+    hi = jax.lax.Precision.HIGHEST
+    q, k, v, probe = (jnp.asarray(rng.normal(size=(b, h, s, d)),
+                                  jnp.bfloat16) for _ in range(4))
+    keep = dropout_keep_mask(b, h, s, s, rate, seed)
+    share = float(jnp.mean(keep.astype(jnp.float32)))
+    check("cell1 keep share", abs(share - (1 - rate)) < 1e-3,
+          f"{share:.6f} of {keep.size} positions")
+
+    def golden(qv, kv, vv):
+        qv, kv, vv = (a.astype(jnp.float32) for a in (qv, kv, vv))
+        sc = jnp.einsum("bhqd,bhkd->bhqk", qv, kv,
+                        precision=hi) / math.sqrt(d)
+        p = jnp.where(keep, jax.nn.softmax(sc, axis=-1) / (1.0 - rate), 0.0)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, vv, precision=hi)
+
+    def kernel(qv, kv, vv):
+        return flash_attention(qv, kv, vv, dropout_rate=rate,
+                               dropout_seed=seed).astype(jnp.float32)
+
+    def loss(f):
+        return lambda *x: jnp.sum(f(*x) * probe.astype(jnp.float32))
+
+    rel = rel_err(kernel(q, k, v), golden(q, k, v))
+    check("cell1 dropout fwd vs explicit-mask golden at HIGHEST",
+          rel < 2e-2, f"rel={rel:.2e}")
+    g = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(golden), argnums=(0, 1, 2))(q, k, v)
+    rels = [rel_err(a, b_) for a, b_ in zip(g, g_ref)]
+    check("cell1 dropout vjp vs explicit-mask golden at HIGHEST",
+          max(rels) < 4e-2,
+          "rel dq={:.2e} dk={:.2e} dv={:.2e} tiles ".format(*rels)
+          + tiles())
+
+    # the op, nothing forced (the layer of ``tpu_attention_choice.py``, 16
+    # heads of 64 over 1024): training takes the kernels, one step index
+    # draws one mask and another another, and the gradients are finite
+    import tpu_attention_choice as choice
+    step, resolved = choice.make_step("auto", b, s, d, rate, False)
+    args = choice.operands(b, s, d)
+    (l0, g0), (l0_again, _), (l1, _) = step(*args, 0), step(*args, 0), \
+        step(*args, 1)
+    check("cell1 op: auto takes the kernels in training",
+          resolved[choice.NAME] == "flash", f"resolved {resolved}")
+    check("cell1 op: one step index, one mask; another, another",
+          float(l0) == float(l0_again) != float(l1),
+          f"losses {float(l0):.6f} {float(l0_again):.6f} {float(l1):.6f}")
+    check("cell1 op: finite gradients", all(
+        bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(g0)))
 
 
 def main():
@@ -202,6 +271,8 @@ def main():
     worst = max(rel_err(a, b_) for a, b_ in zip(g_k, g_g))
     check("dropout vjp vs explicit-mask golden", worst < 2e-2,
           f"rel={worst:.2e} tiles {tiles()}")
+
+    cell1_layer(rng)
 
     print(f"\n{len(FAILED)} failures" if FAILED else "\nALL PASS")
     return 1 if FAILED else 0

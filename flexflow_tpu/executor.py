@@ -461,6 +461,7 @@ class Executor:
             getattr(strategy, "kernel_impls", None) or {})
         # layer name -> "xla" | "flash" | "ring", written while a step
         # is traced: the implementation each attention op really emitted
+        # (the train step's, once one was traced; else the eval step's)
         self.resolved_attention_impls: Dict[str, str] = {}
         # PartitionSpec per parameter leaf, set when they materialize
         self._param_specs = None

@@ -346,6 +346,20 @@ def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal):
             kernel, bh, sq, sk, block_q, block_k, causal))
 
 
+# The three calls are jitted with ``inline=True``: a step with many attention
+# layers of one shape then traces each kernel's body once, not once a
+# layer, and every layer's ``pallas_call`` holds the same jaxpr, which
+# JAX lowers to Mosaic once. The traced step is what it was without the
+# jit, equation for equation (a jit that is not inlined also saves the
+# time but hands XLA another module: BERT-large's step ran 1.9% slower
+# and GPT-2's 2.7% faster). Traced and lowered a layer at a time the
+# three kernels cost 85 ms a layer on the chip's host, 6 s of set-up for
+# BERT-large's 24 layers (PERF.md section 6, PR 30).
+_STATIC = ("kv_len", "sm_scale", "causal", "block_q", "block_k",
+           "dropout_rate", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
               dropout_rate, interpret):
     bh, sq, d = q.shape
@@ -354,7 +368,6 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         kv_len=kv_len, block_q=block_q, block_k=block_k,
         dropout_rate=dropout_rate)
-    _note_grid("fwd", bh, sq, sk, block_q, block_k, causal)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, sq // block_q, sk // block_k),
@@ -377,6 +390,7 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
     return o, lse[:, :, 0]
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_dq_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
                  causal, block_q, block_k, dropout_rate, interpret):
     bh, sq, d = q.shape
@@ -384,7 +398,6 @@ def _bwd_dq_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
     row = _row_spec(block_q)
     qs, ks = _q_spec(block_q, d), _k_spec(block_q, block_k, d, causal)
     dos, vs = _q_spec(block_q, dv), _k_spec(block_q, block_k, dv, causal)
-    _note_grid("bwd_dq", bh, sq, sk, block_q, block_k, causal)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -399,13 +412,13 @@ def _bwd_dq_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
     )(seed, q, k, v, do, lse_b, delta_b)
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_dkv_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
                   causal, block_q, block_k, dropout_rate, interpret):
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     qs2, ks2, row2 = _dkv_specs(block_q, block_k, d, causal)
     dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal)
-    _note_grid("bwd_dkv", bh, sq, sk, block_q, block_k, causal)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -430,16 +443,24 @@ def _bwd_dkv_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
                    nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
            dq_blocks, dkv_blocks, dropout_rate, interpret):
-    o, _ = _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                     block_k, dropout_rate, interpret)
+    o, _ = _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
+                           block_k, dropout_rate, interpret)
     return o
+
+
+def _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
+                    block_k, dropout_rate, interpret):
+    _note_grid("fwd", q.shape[0], q.shape[1], k.shape[1], block_q, block_k,
+               causal)
+    return _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
+                     block_k, dropout_rate, interpret)
 
 
 def _flash_fwd_rule(q, k, v, seed, kv_len, sm_scale, causal, block_q,
                     block_k, dq_blocks, dkv_blocks, dropout_rate,
                     interpret):
-    o, lse = _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                       block_k, dropout_rate, interpret)
+    o, lse = _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal,
+                             block_q, block_k, dropout_rate, interpret)
     return o, (q, k, v, seed, o, lse)
 
 
@@ -452,7 +473,9 @@ def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
     lse_b = jnp.broadcast_to(lse[:, :, None], (bh, sq, 128))
     delta_b = jnp.broadcast_to(delta[:, :, None], (bh, sq, 128))
     operands = (seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale, causal)
+    _note_grid("bwd_dq", bh, sq, k.shape[1], *dq_blocks, causal)
     dq = _bwd_dq_call(*operands, *dq_blocks, dropout_rate, interpret)
+    _note_grid("bwd_dkv", bh, sq, k.shape[1], *dkv_blocks, causal)
     dk, dv = _bwd_dkv_call(*operands, *dkv_blocks, dropout_rate, interpret)
     return dq, dk, dv, np.zeros(seed.shape, dtype=jax.dtypes.float0)
 
@@ -556,7 +579,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     64 (padded keys masked, padded head dims sliced off), runs the Pallas
     kernels, and is differentiable via the custom VJP. ``dropout_rate`` > 0
     applies in-kernel counter-based dropout to the attention
-    probabilities (requires ``dropout_seed``, an int32 scalar).
+    probabilities (requires ``dropout_seed``, an int32 scalar). The
+    attention ops call this by themselves from 1024 positions, and from
+    256 when they drop probabilities: the lengths from which one layer's
+    forward + backward was faster here than through XLA's materialised
+    s² attention in every column timed on a v5e (PERF.md section 6, PR
+    30; ``ops/nn_ops.py::MultiHeadAttentionOp.auto_takes_flash``).
 
     The forward's block default is measured on v5e (head_dim 64): large
     tiles (512x512 — k/v are re-streamed once per q block, so bigger q

@@ -449,30 +449,69 @@ class MultiHeadAttentionOp(OpDef):
             return None
         return plan.get(name, plan.get("attention"))
 
-    # "auto" without a kernel plan: the compiled kernel from this
-    # context length up, XLA's fused attention below it. The threshold
-    # is a builder's estimate that has not been timed on the chip under
-    # this JAX (PERF.md, open questions).
+    # "auto" without a kernel plan: the compiled kernel from the length
+    # at which the chip showed it faster than XLA's materialised s²
+    # attention in every measured column (PERF.md section 6, PR 30: one
+    # layer's forward + backward, the 54 rows of
+    # ``examples/tpu_attention_choice.py`` on a v5e: 128 to 1024
+    # positions, 4k-16k tokens a batch, head sizes 64 and 128, dropout 0
+    # and 0.1, causal and not). With no dropout the kernel wins every
+    # column from 1024 (0.60-0.65 of XLA's time) and loses or ties below
+    # (768: 0.97-1.07; 512: 0.83-0.92 at 64 but 1.19 at 128; 384: 1.12-
+    # 1.28). With dropout XLA's path also draws, writes and re-reads an
+    # s²-sized mask, and the kernel wins every column from 256 (0.67-0.70;
+    # 384: 0.47-0.49; 512: 0.38-0.42; 1024: 0.28-0.31); at 128 it loses
+    # at head size 64 (1.23).
     FLASH_AUTO_MIN_SEQ = 1024
+    FLASH_AUTO_MIN_SEQ_DROPOUT = 256
 
     @classmethod
-    def _flash_enabled(cls, ctx, seq_len: int = 0, mode: str = None) -> bool:
-        mode = mode or cls._flash_mode(ctx)
-        if mode == "false":
-            return False
-        if mode == "true":
+    def auto_takes_flash(cls, q_len: int, kv_len: int, head_dim: int,
+                         v_dim: int, dropout: float) -> bool:
+        """The ``auto`` rule, from the shapes and the dropout rate alone
+        (causal or not never changed a column's winner). Under
+        ``FLASH_AUTO_MIN_SEQ`` only what the table covers moves to the
+        kernel: self-attention with dropout over a multiple of 128
+        positions at head sizes 64 to 128; a length it does not cover
+        (197 was timed at one head size only) stays on XLA."""
+        s = max(q_len, kv_len)
+        if s >= cls.FLASH_AUTO_MIN_SEQ:
             return True
+        measured = (dropout > 0.0 and q_len == kv_len and s % 128 == 0
+                    and 64 <= min(head_dim, v_dim)
+                    and max(head_dim, v_dim) <= 128)
+        return measured and s >= cls.FLASH_AUTO_MIN_SEQ_DROPOUT
+
+    @classmethod
+    def _flash_enabled(cls, ctx, q_len: int, kv_len: int, head_dim: int,
+                       v_dim: int, dropout: float = 0.0, *,
+                       causal: bool = False, window: int = 0,
+                       mode: str = None) -> bool:
+        """Whether this attention call takes the Pallas flash kernel.
+        ``mode``: "true" / "false" force (a plan's impl or the legacy
+        switch), "auto" (the default: ``ctx``'s) asks
+        :meth:`auto_takes_flash` on a backend that compiles the kernel.
+        The kernel has no sliding-window mask and no causal mask for
+        ``q_len != kv_len``: those stay on XLA whoever asks."""
+        if window or (causal and q_len != kv_len):
+            return False
+        mode = mode or cls._flash_mode(ctx)
+        if mode in ("true", "false"):
+            return mode == "true"
         from ..kernels._interpret import pallas_interpret
-        return not pallas_interpret() \
-            and seq_len >= cls.FLASH_AUTO_MIN_SEQ
+        return not pallas_interpret() and cls.auto_takes_flash(
+            q_len, kv_len, head_dim, v_dim, dropout)
 
     @staticmethod
     def _note_impl(ctx, name: str, impl: str) -> None:
         """Record which implementation this trace emitted for the full
         (non-KV) forward: ``Executor.resolved_attention_impls`` is what
-        the step really runs, whatever the plan or the switches say."""
+        the step really runs, whatever the plan or the switches say. A
+        training trace's record stands: an eval trace, which draws no
+        dropout mask and may resolve otherwise, only fills a gap."""
         rec = getattr(ctx, "resolved_impls", None)
-        if rec is not None and ctx.kv_mode is None:
+        if rec is not None and ctx.kv_mode is None \
+                and (ctx.training or name not in rec):
             rec[name] = impl
 
     @staticmethod
@@ -598,43 +637,36 @@ class MultiHeadAttentionOp(OpDef):
         # reference path
         flash_mode = {"flash": "true", "xla": "false"}.get(
             impl, self._flash_mode(ctx))
-        if self._flash_enabled(ctx, seq_len=max(qh.shape[1], kh.shape[1]),
-                               mode=flash_mode) \
-                and not (causal and qh.shape[1] != kh.shape[1]) \
-                and not params.get("sliding_window", 0):
-            # (sliding-window masking stays on the XLA path — the Pallas
-            # kernel has no window support)
-            # Pallas flash kernel ((b,h,s,d) layout); counter-based
-            # in-kernel prob dropout runs compiled on TPU and in
-            # interpret mode alike.
-            # (causal cross-attention with sq != sk stays on the XLA path.)
-            # In "auto" mode the dropout>0 case stays on XLA (the in-kernel
-            # dropout path is opt-in via use_flash_attention="true").
+        if self._flash_enabled(ctx, qh.shape[1], kh.shape[1], qh.shape[-1],
+                               vh.shape[-1], rate, causal=causal,
+                               window=params.get("sliding_window", 0),
+                               mode=flash_mode):
+            # Pallas flash kernel ((b,h,s,d) layout); dropout on the
+            # probabilities is counter-based and in-kernel, compiled on
+            # TPU and in interpret mode alike, seeded from this layer's
+            # key of the step
             from ..kernels import flash_attention
-            if rate > 0.0 and flash_mode != "true":
-                pass  # fall through to the XLA path below
-            else:
-                seed = None
-                if rate > 0.0:
-                    seed = jax.random.randint(ctx.rng_for(name), (),
-                                              0, 2 ** 31 - 1, jnp.int32)
-                self._note_impl(ctx, name, "flash")
-                mesh, spec = self._kernel_shard_spec(
-                    ctx, qh.shape[0], qh.shape[2])
-                o = flash_attention(
-                    jnp.swapaxes(qh, 1, 2).astype(mdt),
-                    jnp.swapaxes(kh, 1, 2).astype(mdt),
-                    jnp.swapaxes(vh, 1, 2).astype(mdt),
-                    causal=causal,
-                    dropout_rate=rate, dropout_seed=seed,
-                    mesh=mesh, spec=spec)
-                ctxv = jnp.swapaxes(o, 1, 2).astype(jnp.float32)
-                out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(mdt),
-                                 weights["wo"].astype(mdt),
-                                 preferred_element_type=jnp.float32)
-                if "bo" in weights:
-                    out = out + weights["bo"].astype(jnp.float32)
-                return [out.astype(cdt)]
+            seed = None
+            if rate > 0.0:
+                seed = jax.random.randint(ctx.rng_for(name), (),
+                                          0, 2 ** 31 - 1, jnp.int32)
+            self._note_impl(ctx, name, "flash")
+            mesh, spec = self._kernel_shard_spec(
+                ctx, qh.shape[0], qh.shape[2])
+            o = flash_attention(
+                jnp.swapaxes(qh, 1, 2).astype(mdt),
+                jnp.swapaxes(kh, 1, 2).astype(mdt),
+                jnp.swapaxes(vh, 1, 2).astype(mdt),
+                causal=causal,
+                dropout_rate=rate, dropout_seed=seed,
+                mesh=mesh, spec=spec)
+            ctxv = jnp.swapaxes(o, 1, 2).astype(jnp.float32)
+            out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(mdt),
+                             weights["wo"].astype(mdt),
+                             preferred_element_type=jnp.float32)
+            if "bo" in weights:
+                out = out + weights["bo"].astype(jnp.float32)
+            return [out.astype(cdt)]
 
         self._note_impl(ctx, name, "xla")
         scale = 1.0 / math.sqrt(qh.shape[-1])
@@ -917,7 +949,8 @@ class LatentAttentionOp(OpDef):
         mha = MultiHeadAttentionOp
         flash_mode = {"flash": "true", "xla": "false"}.get(
             mha._impl_for(ctx, name), mha._flash_mode(ctx))
-        if mha._flash_enabled(ctx, seq_len=s, mode=flash_mode):
+        if mha._flash_enabled(ctx, s, s, dn + dr, vh.shape[-1], causal=True,
+                              mode=flash_mode):
             from ..kernels import flash_attention
             mha._note_impl(ctx, name, "flash")
             mesh, spec = mha._kernel_shard_spec(ctx, b, h)

@@ -42,6 +42,9 @@ def test_leg_a_bert_tiny_on_the_cpu_mesh(capsys):
     assert "A/bert: mesh {'x0': 2, 'x1': 2, 'x2': 2}" in out
     assert "floor guard {'skipped': " in out       # cpu: says so
     assert "collectives ['all-reduce'" in out
+    # cpu: the rule keeps `auto` on XLA, and the leg holds the step to it
+    assert "A/bert: resolved attention impl ['xla']" in out
+    assert "the rule alone gives xla" in out
 
 
 def test_leg_b_gpt2_tiny_on_the_cpu_mesh(capsys):

@@ -274,3 +274,196 @@ def test_kernel_impl_cost_orders_long_context():
                                 seq_degree=4 if name == "ring" else 0)
         t[name] = m.forward_time + m.backward_time
     assert t["ring"] < t["flash"] < t["xla"]
+
+
+# ---------------------------------------------------------------------------
+# attention with nothing forced and no plan: the `auto` rule, held to the
+# rows of the chip's table (PERF.md section 6, PR 30; flash's share of
+# XLA's time for one layer's forward + backward beside each row)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("q_len, kv_len, d, dv, rate, want", [
+    (512, 512, 64, 64, 0.1, "flash"),     # cell 1's layer: 0.38
+    (512, 512, 128, 128, 0.1, "flash"),   # 0.42
+    (256, 256, 64, 64, 0.1, "flash"),     # the smallest row that wins in
+    (256, 256, 128, 128, 0.1, "flash"),   # every column: 0.70, 0.67
+    (384, 384, 64, 64, 0.1, "flash"),     # 0.47
+    (768, 768, 128, 128, 0.1, "flash"),   # 0.45-0.48
+    (128, 128, 64, 64, 0.1, "xla"),       # one below it: 1.23
+    (128, 128, 128, 128, 0.1, "xla"),     # 0.97-0.98, inside the noise
+    (1024, 1024, 64, 64, 0.1, "flash"),   # 0.28-0.30
+    (1024, 1024, 64, 64, 0.0, "flash"),   # cell 2's length: 0.60-0.65
+    (1024, 1024, 128, 128, 0.0, "flash"),
+    (4096, 4096, 192, 128, 0.0, "flash"),  # cell 3, as before
+    (768, 768, 64, 64, 0.0, "xla"),       # one below 1024: 0.97-1.02
+    (512, 512, 128, 128, 0.0, "xla"),     # 1.19, so 512 does not move
+    (512, 512, 64, 64, 0.0, "xla"),       # though this column wins: 0.92
+    (256, 256, 64, 64, 0.0, "xla"),       # 1.49
+    (128, 128, 64, 64, 0.0, "xla"),       # 2.63
+    (197, 197, 64, 64, 0.1, "xla"),       # timed at one head size only
+    (200, 200, 64, 64, 0.1, "xla"),       # not covered: stays
+    (512, 512, 32, 32, 0.1, "xla"),       # head sizes the table has not
+    (512, 512, 256, 256, 0.1, "xla"),
+    (512, 512, 192, 128, 0.1, "xla"),
+    (256, 512, 64, 64, 0.1, "xla"),       # cross-attention: not timed
+    (64, 1024, 64, 64, 0.0, "flash"),     # as before: 1024 on either side
+])
+def test_the_auto_rule_says_what_the_chips_table_says(q_len, kv_len, d, dv,
+                                                      rate, want):
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp as mha
+    took = mha.auto_takes_flash(q_len, kv_len, d, dv, rate)
+    assert ("flash" if took else "xla") == want
+
+
+def _ctx(mode="auto", plan=None):
+    from flexflow_tpu.ops.registry import EmitCtx
+    cfg = FFConfig()
+    cfg.use_flash_attention = mode
+    ctx = EmitCtx(training=True, config=cfg)
+    ctx.kernel_impls = plan
+    return ctx
+
+
+@pytest.mark.parametrize("kw, compiled_backend, want", [
+    # interpret mode (the CPU platform): XLA whatever the shape
+    (dict(q=512, rate=0.1), False, False),
+    (dict(q=4096), False, False),
+    # a backend that compiles the kernel: the rule
+    (dict(q=512, rate=0.1), True, True),
+    (dict(q=512), True, False),
+    # the legacy switch forces either way, on either backend
+    (dict(q=16, mode="true"), False, True),
+    (dict(q=4096, mode="false"), True, False),
+    (dict(q=4096, ctx_mode="false"), True, False),
+    (dict(q=16, ctx_mode="true"), False, True),
+    # what the kernel cannot mask stays on XLA whoever asks
+    (dict(q=4096, window=1024, causal=True), True, False),
+    (dict(q=4096, window=1024, causal=True, mode="true"), True, False),
+    (dict(q=512, kv=1024, causal=True, mode="true"), True, False),
+    (dict(q=512, kv=1024), True, True),
+])
+def test_flash_enabled_backend_forcing_and_masks(monkeypatch, kw,
+                                                 compiled_backend, want):
+    from flexflow_tpu.kernels import _interpret
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp as mha
+    monkeypatch.setattr(_interpret, "pallas_interpret",
+                        lambda: not compiled_backend)
+    q = kw["q"]
+    got = mha._flash_enabled(
+        _ctx(kw.get("ctx_mode", "auto")), q, kw.get("kv", q), 64, 64,
+        kw.get("rate", 0.0), causal=kw.get("causal", False),
+        window=kw.get("window", 0), mode=kw.get("mode"))
+    assert got is want
+
+
+def _dropout_layer(b=1, s=256, e=64, h=1, rate=0.1):
+    layer = _attn_layer(b=b, s=s, e=e, h=h)
+    layer.params["dropout"] = rate
+    return layer
+
+
+def _emit_attention(layer, ctx, seed=0):
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp
+    op = MultiHeadAttentionOp()
+    b, s, e = layer.inputs[0].shape
+    rng = np.random.default_rng(seed)
+    specs = op.weights(layer.params, [(b, s, e)] * 3,
+                       [layer.inputs[0].dtype] * 3)
+    weights = {w.name: jnp.asarray(rng.normal(size=w.shape) * e ** -0.5,
+                                   jnp.float32) for w in specs}
+    x = jnp.asarray(rng.normal(size=(b, s, e)), jnp.float32)
+    ctx.resolved_impls = {}
+    (out,) = op.emit(layer.params, [x, x, x], weights, ctx, layer.name)
+    return np.asarray(out), ctx.resolved_impls[layer.name]
+
+
+def _step_rngs(executor_cls, layer, step):
+    """The keys the executor gives a step's layers, from its own code."""
+    import types
+    stand_in = types.SimpleNamespace(
+        seed=0, program=types.SimpleNamespace(layers=[layer]))
+    return executor_cls._rngs_for_step(stand_in, step)
+
+
+@pytest.mark.parametrize("plan, compiled_backend, want", [
+    (None, True, "flash"),                 # the rule, at a length it moves
+    (None, False, "xla"),
+    ({"attention": "xla"}, True, "xla"),   # a plan's impl wins over auto,
+    ({"attn0": "flash"}, False, "flash"),  # by kind and by layer name
+])
+def test_a_plans_impl_wins_over_auto_in_emit(monkeypatch, plan,
+                                             compiled_backend, want):
+    from flexflow_tpu.executor import Executor
+    from flexflow_tpu.kernels import _interpret
+    monkeypatch.setattr(_interpret, "pallas_interpret",
+                        lambda: not compiled_backend)
+    layer = _dropout_layer()
+    ctx = _ctx(plan=plan)
+    ctx.rngs = _step_rngs(Executor, layer, 0)
+    assert _emit_attention(layer, ctx)[1] == want
+
+
+def test_auto_hands_the_kernel_the_rate_and_a_seed_from_the_layers_key(
+        monkeypatch):
+    """With the backend check stubbed (the kernel itself stays in
+    interpret mode), `auto` at a length the table moves calls
+    ``flash_attention`` with the layer's dropout rate and a seed drawn
+    from ``ctx.rng_for(name)``: one step index gives one mask (what the
+    benchmark's ``loss_fell`` relies on), another index another."""
+    import flexflow_tpu.kernels as kernels
+    from flexflow_tpu.executor import Executor
+    from flexflow_tpu.kernels import _interpret
+    monkeypatch.setattr(_interpret, "pallas_interpret", lambda: False)
+    calls = []
+    real = kernels.flash_attention
+
+    def spy(*a, **kw):
+        calls.append(kw)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(kernels, "flash_attention", spy)
+    layer = _dropout_layer()
+
+    def run(step):
+        ctx = _ctx()
+        ctx.rngs = _step_rngs(Executor, layer, step)
+        out, impl = _emit_attention(layer, ctx)
+        assert impl == "flash"
+        want = jax.random.randint(ctx.rng_for(layer.name), (), 0,
+                                  2 ** 31 - 1, jnp.int32)
+        assert calls[-1]["dropout_rate"] == 0.1
+        assert int(calls[-1]["dropout_seed"]) == int(want)
+        return out, int(want)
+
+    (a, seed_a), (again, _), (b, seed_b) = run(0), run(0), run(1)
+    assert len(calls) == 3
+    np.testing.assert_array_equal(a, again)
+    assert seed_a != seed_b and not np.array_equal(a, b)
+    # and the mask does something: no dropout gives a third answer
+    layer.params["dropout"] = 0.0
+    ctx = _ctx("true")
+    assert not np.array_equal(_emit_attention(layer, ctx)[0], a)
+    assert calls[-1]["dropout_rate"] == 0.0
+    assert calls[-1]["dropout_seed"] is None
+
+
+def test_an_eval_trace_does_not_overwrite_the_train_steps_record(
+        monkeypatch):
+    """At 256 positions a train step (dropout) takes the kernels and an
+    eval step (no mask drawn) XLA: ``resolved_attention_impls`` keeps
+    what the train step runs whichever is traced last (``chip_smoke.py``
+    leg A reads it after ``fit`` and ``eval``), and an eval-only model
+    still gets its record."""
+    from flexflow_tpu.executor import Executor
+    from flexflow_tpu.kernels import _interpret
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp as mha
+    monkeypatch.setattr(_interpret, "pallas_interpret", lambda: False)
+    layer = _dropout_layer()
+    record = {}
+    for training, want in ((False, "xla"), (True, "flash"), (False, "flash")):
+        ctx = _ctx()
+        ctx.training = training
+        ctx.rngs = _step_rngs(Executor, layer, 0)
+        ctx.resolved_impls = record
+        mha._note_impl(ctx, layer.name, "flash" if mha._flash_enabled(
+            ctx, 256, 256, 64, 64, 0.1 if training else 0.0) else "xla")
+        assert record == {layer.name: want}

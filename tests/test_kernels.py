@@ -660,3 +660,47 @@ def test_bwd_tile_rule_counts_both_head_sizes(sq, sk, d, dtype, dropout):
         assert fa._bwd_vmem_bytes(kernel, bq, bk, d, item, dropout,
                                   128) <= fa.BWD_VMEM_BUDGET \
             or (bq, bk) == (fa._tile_sizes(sq)[0], fa._tile_sizes(sk)[0])
+
+
+def test_layers_of_one_shape_trace_each_kernel_body_once(monkeypatch):
+    """BERT-large's step has 24 attention layers of one shape. Traced a
+    layer at a time the three kernels cost the chip's host 85 ms a layer,
+    6 s of a benchmark run's set-up (PERF.md, PR 30); the calls are
+    jitted and inlined, so a step traces each body once, every layer's
+    call is still its own equation, and each still records its grid."""
+    import sys
+
+    from flexflow_tpu.obs import events
+    # (the package's attribute of this name is the function)
+    fa = sys.modules["flexflow_tpu.kernels.flash_attention"]
+    jax.clear_caches()
+    traced = {"fwd": 0, "bwd_dq": 0, "bwd_dkv": 0}
+
+    def counting(kind, body):
+        def kernel(*a, **kw):
+            traced[kind] += 1
+            return body(*a, **kw)
+        return kernel
+
+    for kind in traced:
+        monkeypatch.setattr(fa, f"_{kind}_kernel",
+                            counting(kind, getattr(fa, f"_{kind}_kernel")))
+    q, k, v = _rand_qkv(b=1, h=2, s=128, d=64)
+
+    def loss(q, k, v):
+        for seed in range(4):
+            q = flash_attention(q, k, v, interpret=True, dropout_rate=0.1,
+                                dropout_seed=jnp.int32(seed))
+        return jnp.sum(q)
+
+    events.enable()
+    try:
+        events.clear()
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        grids = [e for e in events.events() if e["name"] == "flash.grid"]
+    finally:
+        events.disable()
+    assert traced == {"fwd": 1, "bwd_dq": 1, "bwd_dkv": 1}
+    assert str(jaxpr).count("pallas_call[") == 12     # inlined, not shared
+    assert len(grids) == 12
+    jax.clear_caches()           # the counting bodies are in the jit cache

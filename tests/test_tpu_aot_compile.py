@@ -127,6 +127,29 @@ def test_flash_with_unequal_head_sizes_compiles_at_the_latent_shape(
     assert "bf16[32,4096,256]" not in txt
 
 
+# what `auto` moved onto the kernels in PR 30: not causal, dropout inside
+# the kernels; cell 1 of the benchmark (BERT-large, 8 x 512), and the two
+# head sizes at the shortest length the rule moves
+@pytest.mark.parametrize("shape", [(8, 16, 512, 64), (32, 16, 256, 64),
+                                   (32, 8, 256, 128)])
+def test_flash_with_dropout_compiles_at_the_shapes_auto_moved(
+        v5e_devices, chip_locations, shape):
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp as mha
+    s, d = shape[2:]
+    assert mha.auto_takes_flash(s, s, d, d, 0.1)
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                               sharding=NamedSharding(mesh, P()))
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, interpret=False, dropout_rate=0.1,
+                            dropout_seed=jnp.int32(3))
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert _kernel_names(txt) == FLASH_NAMES
+
+
 @pytest.mark.parametrize("dropout", [False, True])
 @pytest.mark.parametrize("dtype,d", [("bfloat16", 256), ("float32", 64),
                                      ("float32", 128), ("float32", 256)])

@@ -7,7 +7,6 @@
 #   ./ci.sh slow      slow tier: example integration tests + HF imports
 #   ./ci.sh dryrun    multi-chip compile/execute dryrun (8 virtual devices)
 #   ./ci.sh ab        osdi22ae searched-vs-DP A/B sweep (writes JSON)
-#   ./ci.sh bench     benchmark harness (one JSON line; TPU if available)
 #   ./ci.sh nightly   slow + dryrun + ab
 set -euo pipefail
 cd "$(dirname "$0")"
@@ -144,16 +143,13 @@ case "${1:-fast}" in
     # sweep's subprocesses to it whatever the host offers
     JAX_PLATFORMS=cpu python examples/osdi22ae/run_all.py
     ;;
-  bench)
-    python bench.py
-    ;;
   nightly)
     "$0" slow
     "$0" dryrun
     "$0" ab
     ;;
   *)
-    echo "usage: $0 {fast|slow|dryrun|ab|bench|nightly}" >&2
+    echo "usage: $0 {fast|slow|dryrun|ab|nightly}" >&2
     exit 2
     ;;
 esac

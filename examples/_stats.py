@@ -1,9 +1,10 @@
 """Dependency-free stats helpers shared by the example orchestrators.
 
 Deliberately imports nothing beyond the stdlib: the sweep parents
-(osdi22ae/run_all.py, tpu_fidelity.py) isolate framework/jax failures in
-per-model subprocesses, and a chip belongs to one process at a time, so
-the parent imports neither the framework nor JAX.
+(osdi22ae/run_all.py, osdi22ae/ranker_fidelity.py) isolate
+framework/jax failures in per-model subprocesses, and a chip belongs to
+one process at a time, so the parent imports neither the framework nor
+JAX.
 """
 from __future__ import annotations
 

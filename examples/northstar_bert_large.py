@@ -45,7 +45,7 @@ jax.config.update("jax_platforms", "cpu")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # `python examples/northstar_bert_large.py` puts examples/ (not the
 # repo root) on sys.path; make the import work without an installed
-# package or PYTHONPATH (same idiom as tpu_fidelity.py)
+# package or PYTHONPATH (same idiom as tpu_memory_validation.py)
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 

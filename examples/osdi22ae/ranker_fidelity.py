@@ -152,6 +152,7 @@ def main() -> int:
     path = os.environ.get(
         "FF_FIDELITY_OUT",
         os.path.join(REPO, "bench_results", "cpu_ranker_fidelity.json"))
+    os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out["spearman"]))

@@ -70,20 +70,22 @@ def rows():
     return out
 
 
-def make_step(mode, batch, seq, d, rate, causal):
+def make_step(impl, batch, seq, d, rate, causal):
     """Jitted ``(x, weights, probe, step) -> (loss, grads)`` of one layer
-    with ``use_flash_attention = mode``, and the path it emitted."""
+    under the plan a forced ``kernel_impls = "attention:<impl>"`` gives
+    the executor (none for ``auto``), and the path it emitted."""
     op = MultiHeadAttentionOp()
     heads = EMBED // d
     params = {"embed_dim": EMBED, "num_heads": heads, "dropout": rate,
               "causal": causal, "bias": True}
     cfg = FFConfig()
-    cfg.use_flash_attention = mode
+    plan = None if impl == "auto" else {"attention": impl}
     resolved = {}
 
     def loss(x, weights, probe, step):
         key = jax.random.fold_in(jax.random.key(1), step)
         ctx = EmitCtx(training=True, rngs={NAME: key}, config=cfg)
+        ctx.kernel_impls = plan
         ctx.resolved_impls = resolved
         (out,) = op.emit(params, [x, x, x], weights, ctx, NAME)
         return jnp.sum(out * probe)
@@ -143,15 +145,15 @@ def measure(row, calls):
     args = operands(batch, seq, d)
     line = {"row": tag, "batch": batch, "seq": seq, "head_dim": d,
             "dropout": rate, "causal": causal}
-    for mode, key in (("true", "flash"), ("false", "xla"), ("auto", "auto")):
-        fn, resolved = make_step(mode, batch, seq, d, rate, causal)
-        if mode == "auto":      # traced only: which path the rule takes
+    for impl in ("flash", "xla", "auto"):
+        fn, resolved = make_step(impl, batch, seq, d, rate, causal)
+        if impl == "auto":      # traced only: which path the rule takes
             jax.eval_shape(fn, *args, 0)
             line["auto"] = resolved[NAME]
             continue
         jax.block_until_ready(fn(*args, 0))           # compile, warm up
-        assert resolved[NAME] == key, (mode, resolved)
-        line[f"{key}_ms"], line[f"{key}_ops"] = device_ms_per_call(
+        assert resolved[NAME] == impl, resolved
+        line[f"{impl}_ms"], line[f"{impl}_ops"] = device_ms_per_call(
             fn, args, calls)
     line["flash_over_xla"] = line["flash_ms"] / line["xla_ms"]
     return line

@@ -39,11 +39,25 @@ for p in (REPO, HERE):
 ESTIMATE_WORKLOADS = ("bert_tiny", "candle_uno")
 
 
+def _build(ff, workload: str, batch: int):
+    """The estimate stage's two graphs: attention + matmuls, and a wide
+    multi-input MLP."""
+    from flexflow_tpu.models import (BertConfig, build_bert,
+                                     build_candle_uno)
+    if workload == "candle_uno":
+        import candle_uno
+        return build_candle_uno(ff, batch, candle_uno.CFG)
+    if workload == "bert_tiny":
+        bcfg = BertConfig.tiny()
+        bcfg.max_position = 64
+        return build_bert(ff, batch, 64, bcfg)
+    raise ValueError(workload)
+
+
 def _build_model(workload: str, only_dp: bool, mem_mb: int = 0,
                  batch: int = 16, builder=None, machine_file: str = ""):
     from flexflow_tpu import FFConfig, FFModel, SGDOptimizer
-    if builder is None:
-        from tpu_fidelity import _build as builder
+    builder = builder or _build
     cfg = FFConfig()
     cfg.batch_size = batch
     cfg.only_data_parallel = only_dp
@@ -167,6 +181,7 @@ def main() -> int:
     def flush_out():
         """(Re)write after every stage — a pipeline stage timeout must
         never discard results already captured."""
+        os.makedirs(os.path.dirname(a.out), exist_ok=True)
         tmp = a.out + ".tmp"
         with open(tmp, "w") as f:
             json.dump(out, f, indent=1)

@@ -997,20 +997,24 @@ def _check_kernel(report, kimpls, axis_sizes: Dict[str, int],
             continue
         ctx = attn_ctxs.get(key)
         if ctx is None:
-            if have_layers and key not in known_layers:
+            # the "attention" kind key names no layer: a forced choice
+            # for every attention op, of whatever kind
+            named = have_layers and key != kreg.ATTENTION
+            if named and key not in known_layers:
                 report.add(
                     "kernel", "error", key,
                     f"kernel impl {impl!r} is assigned to an op the "
                     f"program does not contain", "kernel-impl")
                 continue
-            if have_layers:
+            if named:
                 report.add(
                     "kernel", "error", key,
                     f"kernel impl {impl!r} is assigned to a "
                     f"non-attention op", "kernel-impl")
                 continue
-            # spec-only strategy file (no program block): shapes are
-            # unknown, but the one mesh-level requirement still binds
+            # no shapes to hold it to (the kind key, or a spec-only
+            # strategy file with no program block), but the one
+            # mesh-level requirement still binds
             if impl == "ring" and seq_deg < 2:
                 report.add(
                     "kernel", "error", key,

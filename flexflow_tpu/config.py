@@ -241,11 +241,6 @@ class FFConfig:
     # dispatched ahead of consumption; 0 disables, 1 is the old
     # single-slot double-buffer
     prefetch_batches: int = 2
-    # DEPRECATED tri-state (kept as a shim over the kernel tier): "true"
-    # forces attention:flash, "false" forces attention:xla, "auto" defers
-    # to the searched kernel_impls dimension (kernels/registry.py emits a
-    # DeprecationWarning for the non-auto values). See docs/kernels.md.
-    use_flash_attention: str = "auto"
     # searched per-op kernel-implementation tier (kernels/registry.py):
     # "auto" lets FFModel._plan_kernels pick each op's impl from the
     # calibrated (op, impl) costs; "<op>:<impl>[,...]" forces choices

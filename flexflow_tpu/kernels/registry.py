@@ -13,9 +13,7 @@ predicate on the adopted mesh/shapes (``plan_verifier._check_kernel``).
 
 Forcing: ``FFConfig.kernel_impls`` / ``--kernel-impl`` / the
 ``FF_KERNEL_IMPL`` env var take ``<op>:<impl>`` pairs (comma-separated),
-e.g. ``attention:flash`` or ``attention:ring,opt_update:fused``. The
-retired ``use_flash_attention`` tri-state keeps working through
-:func:`resolve_forced`'s deprecation shim.
+e.g. ``attention:flash`` or ``attention:ring,opt_update:fused``.
 
 See docs/kernels.md.
 """
@@ -23,7 +21,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import warnings
 from typing import Any, Callable, Dict, List, Optional
 
 # op kinds with a searchable implementation dimension
@@ -184,7 +181,7 @@ def attention_ctx(params: Dict[str, Any], q_len: int, kv_len: int,
 
 
 # ----------------------------------------------------------------------
-# forcing: config flag / env var / use_flash_attention deprecation shim
+# forcing: config flag / env var
 # ----------------------------------------------------------------------
 def parse_forced(spec: str) -> Dict[str, str]:
     """Parse ``"attention:ring,opt_update:fused"`` into an op->impl map.
@@ -213,24 +210,11 @@ def parse_forced(spec: str) -> Dict[str, str]:
 
 
 def resolve_forced(cfg) -> Dict[str, str]:
-    """Forced op->impl choices from config/env, deprecation shim included.
+    """Forced op->impl choices from config and environment.
 
-    Precedence (later wins): ``use_flash_attention`` shim <
-    ``cfg.kernel_impls`` < ``FF_KERNEL_IMPL``. The shim maps the retired
-    tri-state's "true"/"false" to a forced attention impl and warns;
-    "auto" forces nothing (the searched dimension subsumes it).
+    Precedence (later wins): ``cfg.kernel_impls`` < ``FF_KERNEL_IMPL``.
     """
-    forced: Dict[str, str] = {}
-    legacy = getattr(cfg, "use_flash_attention", "auto") \
-        if cfg is not None else "auto"
-    if legacy in ("true", "false"):
-        warnings.warn(
-            "FFConfig.use_flash_attention is deprecated; use "
-            "kernel_impls / --kernel-impl attention:<xla|flash|ring> "
-            "(FF_KERNEL_IMPL works too)", DeprecationWarning,
-            stacklevel=2)
-        forced[ATTENTION] = "flash" if legacy == "true" else "xla"
-    forced.update(parse_forced(getattr(cfg, "kernel_impls", "auto")
-                               if cfg is not None else "auto"))
+    forced = parse_forced(getattr(cfg, "kernel_impls", "auto")
+                          if cfg is not None else "auto")
     forced.update(parse_forced(os.environ.get("FF_KERNEL_IMPL", "")))
     return forced

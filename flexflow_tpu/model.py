@@ -1094,8 +1094,7 @@ class FFModel:
         attention ``xla``/``flash``/``ring``, the optimizer update
         ``fused``/``unfused``. An assignment already on the strategy
         (``--import`` round-trip) is honored verbatim; forced choices
-        (``--kernel-impl`` / ``FF_KERNEL_IMPL`` / the retired
-        ``use_flash_attention`` shim) bypass scoring but are
+        (``--kernel-impl`` / ``FF_KERNEL_IMPL``) bypass scoring but are
         predicate-checked — forcing ``ring`` on a mesh without a
         sequence axis is a typed compile-time error attributed to the
         op. Searched deviation from the defaults requires measured
@@ -1201,6 +1200,12 @@ class FFModel:
         plan: Dict[str, str] = {}
         audit_ops: List[Dict] = []
         f_attn = forced.get(kreg.ATTENTION)
+        if f_attn is not None:
+            # the kind key reaches every attention op of whatever kind
+            # (ops/nn_ops.py::MultiHeadAttentionOp._impl_for); the
+            # layers named below also get the predicate check and an
+            # audit row
+            plan[kreg.ATTENTION] = f_attn
         for layer in attn:
             q_len = int(layer.inputs[0].shape[1]) if layer.inputs else 0
             kv_len = int(layer.inputs[1].shape[1]) \

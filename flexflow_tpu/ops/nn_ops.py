@@ -433,17 +433,12 @@ class MultiHeadAttentionOp(OpDef):
         return ws
 
     @staticmethod
-    def _flash_mode(ctx) -> str:
-        """Resolved flash-attention mode: "true" | "false" | "auto"."""
-        return getattr(getattr(ctx, "config", None), "use_flash_attention",
-                       "auto")
-
-    @staticmethod
     def _impl_for(ctx, name: str):
         """This op's kernel impl from the adopted plan (the executor
         threads ``strategy.kernel_impls`` through EmitCtx): the
-        layer-name key wins over the "attention" kind key; None = no
-        plan, keep the legacy ``use_flash_attention`` resolution."""
+        layer-name key wins over the "attention" kind key, which a
+        forced choice sets for every attention op; None = no plan, the
+        ``auto`` rule decides."""
         plan = getattr(ctx, "kernel_impls", None)
         if not plan:
             return None
@@ -483,21 +478,19 @@ class MultiHeadAttentionOp(OpDef):
         return measured and s >= cls.FLASH_AUTO_MIN_SEQ_DROPOUT
 
     @classmethod
-    def _flash_enabled(cls, ctx, q_len: int, kv_len: int, head_dim: int,
+    def _flash_enabled(cls, impl, q_len: int, kv_len: int, head_dim: int,
                        v_dim: int, dropout: float = 0.0, *,
-                       causal: bool = False, window: int = 0,
-                       mode: str = None) -> bool:
+                       causal: bool = False, window: int = 0) -> bool:
         """Whether this attention call takes the Pallas flash kernel.
-        ``mode``: "true" / "false" force (a plan's impl or the legacy
-        switch), "auto" (the default: ``ctx``'s) asks
-        :meth:`auto_takes_flash` on a backend that compiles the kernel.
-        The kernel has no sliding-window mask and no causal mask for
-        ``q_len != kv_len``: those stay on XLA whoever asks."""
+        ``impl`` is the adopted plan's for this layer: "flash" and "xla"
+        decide, anything else (no plan) asks :meth:`auto_takes_flash` on
+        a backend that compiles the kernel. The kernel has no
+        sliding-window mask and no causal mask for ``q_len != kv_len``:
+        those stay on XLA whoever asks."""
         if window or (causal and q_len != kv_len):
             return False
-        mode = mode or cls._flash_mode(ctx)
-        if mode in ("true", "false"):
-            return mode == "true"
+        if impl in ("flash", "xla"):
+            return impl == "flash"
         from ..kernels._interpret import pallas_interpret
         return not pallas_interpret() and cls.auto_takes_flash(
             q_len, kv_len, head_dim, v_dim, dropout)
@@ -632,15 +625,9 @@ class MultiHeadAttentionOp(OpDef):
             self._note_impl(ctx, name, "ring")
             return self._emit_ring(weights, ctx, name, qh, kh, vh, mdt,
                                    cdt, causal)
-        # a planned impl overrides the legacy tri-state: "flash" forces
-        # the kernel path (in-kernel dropout included), "xla" forces the
-        # reference path
-        flash_mode = {"flash": "true", "xla": "false"}.get(
-            impl, self._flash_mode(ctx))
-        if self._flash_enabled(ctx, qh.shape[1], kh.shape[1], qh.shape[-1],
+        if self._flash_enabled(impl, qh.shape[1], kh.shape[1], qh.shape[-1],
                                vh.shape[-1], rate, causal=causal,
-                               window=params.get("sliding_window", 0),
-                               mode=flash_mode):
+                               window=params.get("sliding_window", 0)):
             # Pallas flash kernel ((b,h,s,d) layout); dropout on the
             # probabilities is counter-based and in-kernel, compiled on
             # TPU and in interpret mode alike, seeded from this layer's
@@ -947,10 +934,8 @@ class LatentAttentionOp(OpDef):
         vh = kv[..., dn:]
 
         mha = MultiHeadAttentionOp
-        flash_mode = {"flash": "true", "xla": "false"}.get(
-            mha._impl_for(ctx, name), mha._flash_mode(ctx))
-        if mha._flash_enabled(ctx, s, s, dn + dr, vh.shape[-1], causal=True,
-                              mode=flash_mode):
+        if mha._flash_enabled(mha._impl_for(ctx, name), s, s, dn + dr,
+                              vh.shape[-1], causal=True):
             from ..kernels import flash_attention
             mha._note_impl(ctx, name, "flash")
             mesh, spec = mha._kernel_shard_spec(ctx, b, h)

@@ -63,8 +63,8 @@ class EmitCtx:
         self.local_shape: bool = False
         # searched kernel tier (kernels/registry.py): the adopted
         # strategy's per-op impl map plus the mesh context ring
-        # attention lowers its shard_map against. None/empty = default
-        # impls (the legacy use_flash_attention resolution).
+        # attention lowers its shard_map against. None/empty = no plan:
+        # attention resolves by its ``auto`` rule.
         self.kernel_impls: Optional[Dict[str, str]] = None
         self.mesh = None                  # jax.sharding.Mesh
         self.seq_axis: Optional[str] = None

@@ -551,8 +551,7 @@ class OpCostModel:
         """Turn on the kernel-impl dimension (kernels/registry.py):
         ``op_cost_with_impl`` prices attention at its cheapest available
         implementation on this mesh. ``forced`` pins op kinds to one
-        impl (``--kernel-impl`` / FF_KERNEL_IMPL / the retired
-        use_flash_attention shim)."""
+        impl (``--kernel-impl`` / FF_KERNEL_IMPL)."""
         import jax
         tier = None
         seq_ax = getattr(dmesh, "seq_axis", None)

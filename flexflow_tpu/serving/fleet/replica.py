@@ -16,8 +16,8 @@ no drain — the failure mode the router's failover must absorb.
 Two model kinds:
 
 * ``synthetic``: a fixed-latency session (``--synthetic-ms`` per
-  device step) — scheduler/router policy measurement decoupled from
-  XLA compile noise; the bench harness's replicas.
+  device step) — scheduler/router policy decoupled from XLA compile
+  noise.
 * ``gpt2-tiny``: a real tiny GPT-2 compiled through the persistent
   XLA compile cache (``FFModel.compile`` enables it; whoever launches
   the fleet places it with ``JAX_COMPILATION_CACHE_DIR``), so a
@@ -46,8 +46,7 @@ def _build_repo(args):
         class SyntheticSession:
             """Fixed-latency device-step stand-in: one batched step
             costs ``--synthetic-ms`` regardless of rows (up to the
-            scheduler's max_batch) — the policy-measurement harness
-            bench.py's overload stage established."""
+            scheduler's max_batch)."""
             input_names = ["x"]
 
             def infer(self, inputs):

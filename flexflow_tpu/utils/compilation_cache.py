@@ -20,7 +20,7 @@ On an accelerator it also makes cache keys reproducible across starts
 (see the note on Mosaic kernels in ``enable_compilation_cache``).
 
 Called by every accelerator entry point: ``FFModel.compile``, the
-serving repository's loads, ``chip_smoke.py`` and ``bench.py``'s stages.
+serving repository's loads and ``chip_smoke.py``.
 """
 from __future__ import annotations
 

@@ -40,7 +40,7 @@ def _forward(build, inputs):
     cfg = FFConfig()
     cfg.only_data_parallel = True
     cfg.use_bf16_compute = False
-    cfg.use_flash_attention = "false"
+    cfg.kernel_impls = "attention:xla"
     ff = FFModel(cfg)
     out = build(ff)
     ff.compile(SGDOptimizer(0.01), "identity", [], output_tensor=out)
@@ -191,7 +191,7 @@ def test_align_multihead_attention():
     x = _gen((b, s, e), 8, scale=0.5)
     cfg = FFConfig()
     cfg.use_bf16_compute = False
-    cfg.use_flash_attention = "false"
+    cfg.kernel_impls = "attention:xla"
     ff = FFModel(cfg)
     t = ff.create_tensor((b, s, e), name="x")
     ff.multihead_attention(t, t, t, embed_dim=e, num_heads=h, bias=True)

@@ -1,5 +1,9 @@
 """API-surface tests: builder methods, dataloader parity path, name
 collisions, weights round-trip, flag parsing."""
+import functools
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -83,3 +87,65 @@ def test_kdim_vdim_attention():
              .astype(np.float32)}
     y = fwd(ff.params, ff.state, batch)
     assert y.shape == (4,)
+
+
+# ---------------------------------------------------------------------------
+# the documents name only paths that exist
+# ---------------------------------------------------------------------------
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_DOCUMENTS = ["README.md"] + sorted(
+    "docs/" + f for f in os.listdir(os.path.join(_REPO, "docs"))
+    if f.endswith(".md"))
+_PATH_SUFFIXES = (".py", ".md", ".json", ".jsonl", ".sh", ".toml", ".cc",
+                  ".yml", ".ipynb", "/")
+# what a run leaves behind (``.gitignore``) is named as a place, not a file
+_GENERATED = (".ffcache/", ".jax_cache/", ".bench_trace/", "chiprun_out/",
+              "bench_results/", "_clean_tree/", "_parent/", "_scratch/")
+# the files of a checkpoint directory, and one of the reference's sources
+_NOT_OURS = {"manifest.json", "meta.json", "model.cc"}
+
+
+def _named_paths(text):
+    """Backticked spans and link targets that read as a path of this
+    repo: one token with a file suffix (or a trailing slash), no
+    placeholder, no URL, nothing outside the checkout."""
+    spans = re.findall(r"`([^`\n]+)`", text)
+    spans += re.findall(r"\]\(([^)\s]+)\)", text)
+    for span in spans:
+        tok = span.strip().split("::")[0].split("#")[0]
+        tok = re.sub(r":\d+(-\d+)?$", "", tok)         # file.py:12-30
+        if (not tok or re.search(r"[\s*<>{}$|=,()\[\]]", tok)
+                or "://" in tok or tok.startswith(("/", "~", "-", "."))
+                or not tok.endswith(_PATH_SUFFIXES)
+                or tok.startswith(_GENERATED) or tok in _NOT_OURS):
+            continue
+        yield tok
+
+
+@functools.lru_cache(maxsize=None)
+def _tree():
+    """Every file and directory of the checkout, as ``/``-led paths."""
+    found = set()
+    for root, dirs, files in os.walk(_REPO):
+        dirs[:] = [d for d in dirs if d != ".git" and d != "__pycache__"
+                   and not (d + "/").startswith(_GENERATED)]
+        rel = os.path.relpath(root, _REPO)
+        lead = "/" if rel == "." else f"/{rel}/"
+        found.update(lead + f for f in files)
+        found.update(lead + d + "/" for d in dirs)
+    return found
+
+
+@pytest.mark.parametrize("document", _DOCUMENTS)
+def test_documents_name_only_paths_that_exist(document):
+    """Every repo path the README or a page of ``docs/`` names is the
+    tail of a path in the tree (the pages write ``ops/nn_ops.py`` for
+    ``flexflow_tpu/ops/nn_ops.py`` and ``fleet/router.py`` inside the
+    serving section)."""
+    with open(os.path.join(_REPO, document)) as f:
+        text = f.read()
+    tree = _tree()
+    missing = sorted({tok for tok in _named_paths(text)
+                      if not any(p.endswith("/" + tok) for p in tree)})
+    assert not missing, f"{document} names paths that do not exist: " \
+                        f"{missing}"

@@ -1,6 +1,6 @@
-"""Searchable kernel tier (kernels/registry.py): forcing flags +
-deprecation shim, availability predicates, fused-optimizer parity, and
-the per-op impl dimension in the cost model."""
+"""Searchable kernel tier (kernels/registry.py): forcing flags,
+availability predicates, fused-optimizer parity, and the per-op impl
+dimension in the cost model."""
 import os
 
 import jax
@@ -13,7 +13,7 @@ from flexflow_tpu.kernels import registry as kreg
 
 
 # ---------------------------------------------------------------------------
-# forcing: parse/resolve + the use_flash_attention deprecation shim
+# forcing: parse/resolve
 # ---------------------------------------------------------------------------
 
 def test_parse_forced_rejects_typos():
@@ -28,30 +28,16 @@ def test_parse_forced_rejects_typos():
         == {"attention": "ring", "opt_update": "fused"}
 
 
-def test_use_flash_attention_shim_warns_and_forces():
-    """The retired tri-state keeps working: "true"/"false" force the
-    attention impl through a DeprecationWarning; "auto" forces nothing."""
+def test_forcing_precedence_config_env(monkeypatch):
+    """Later wins: cfg.kernel_impls < FF_KERNEL_IMPL; nothing set forces
+    nothing."""
+    monkeypatch.delenv("FF_KERNEL_IMPL", raising=False)
     cfg = FFConfig()
-    cfg.use_flash_attention = "true"
-    with pytest.warns(DeprecationWarning, match="use_flash_attention"):
-        assert kreg.resolve_forced(cfg) == {"attention": "flash"}
-    cfg.use_flash_attention = "false"
-    with pytest.warns(DeprecationWarning):
-        assert kreg.resolve_forced(cfg) == {"attention": "xla"}
-    cfg.use_flash_attention = "auto"
     assert kreg.resolve_forced(cfg) == {}
-
-
-def test_forcing_precedence_shim_config_env(monkeypatch):
-    """Later wins: shim < cfg.kernel_impls < FF_KERNEL_IMPL."""
-    cfg = FFConfig()
-    cfg.use_flash_attention = "true"
     cfg.kernel_impls = "attention:xla"
-    with pytest.warns(DeprecationWarning):
-        assert kreg.resolve_forced(cfg)["attention"] == "xla"
+    assert kreg.resolve_forced(cfg) == {"attention": "xla"}
     monkeypatch.setenv("FF_KERNEL_IMPL", "attention:ring")
-    with pytest.warns(DeprecationWarning):
-        assert kreg.resolve_forced(cfg)["attention"] == "ring"
+    assert kreg.resolve_forced(cfg) == {"attention": "ring"}
 
 
 def test_kernel_impl_cli_flag_accumulates():
@@ -127,6 +113,44 @@ def test_forced_flash_plans_and_trains():
     assert rec["policy"] == "attention:flash"
     op = next(o for o in rec["ops"] if o["name"] == attn)
     assert op["impl"] == "flash" and op["forced"]
+
+
+@pytest.mark.parametrize("impl", ["flash", "xla"])
+@pytest.mark.parametrize("kind", ["multi-head", "latent"])
+def test_forced_attention_reaches_every_attention_op(kind, impl):
+    """``kernel_impls = "attention:<impl>"`` is the one way to force a
+    path, so it has to reach both attention op kinds: the plan carries
+    the kind key, every attention layer of the traced train step
+    records the forced impl, and a step forced onto XLA holds no Mosaic
+    call."""
+    from flexflow_tpu.ffconst import OperatorType
+    from flexflow_tpu.search.optimizer import _synth_batch
+    cfg = FFConfig()
+    cfg.only_data_parallel = True
+    cfg.kernel_impls = f"attention:{impl}"
+    ff = FFModel(cfg)
+    if kind == "multi-head":
+        q = ff.create_tensor((2, 64, 64), name="q")
+        ff.multihead_attention(q, q, q, embed_dim=64, num_heads=4,
+                               causal=True)
+        ff.compile(SGDOptimizer(0.01), "identity", [])
+    else:
+        from flexflow_tpu.models.nlp import (LatentMoEConfig,
+                                             build_latent_moe)
+        out = build_latent_moe(ff, 2, 32, LatentMoEConfig.tiny())
+        ff.compile(SGDOptimizer(0.01), "sparse_categorical_crossentropy",
+                   [], output_tensor=out)
+    assert ff.strategy.kernel_impls["attention"] == impl
+    step = ff.executor.make_train_step().__wrapped__
+    lowered = step.lower(ff.params, ff.opt_state, ff.state, jnp.int32(0),
+                         _synth_batch(ff))
+    attn = {l.name for l in ff.layers if l.op_type in (
+        OperatorType.OP_MULTIHEAD_ATTENTION,
+        OperatorType.OP_LATENT_ATTENTION)}
+    assert attn and ff.executor.resolved_attention_impls \
+        == dict.fromkeys(attn, impl)
+    if impl == "xla":
+        assert "tpu_custom_call" not in lowered.as_text()
 
 
 # ---------------------------------------------------------------------------
@@ -276,6 +300,53 @@ def test_kernel_impl_cost_orders_long_context():
     assert t["ring"] < t["flash"] < t["xla"]
 
 
+def test_ring_plan_fits_an_envelope_the_unsharded_plan_fails():
+    """What ring attention is for is memory: inside its shard_map every
+    live attention tensor is a 1/seq-degree chunk. Same context, same
+    mesh, an HBM budget between the two plans' static envelopes: the
+    verifier rejects the forced-XLA plan with a typed memory finding and
+    passes the ring plan (the smoke, tools/kernel_tier_smoke.py, shows
+    the searched tier adopting ring here and the plan training)."""
+    from flexflow_tpu.analysis.plan_verifier import (memory_envelope,
+                                                     verify_plan)
+    b, s, e, h = 4, 2048, 512, 8
+
+    def build(impl):
+        cfg = FFConfig()
+        cfg.batch_size = b
+        cfg.only_data_parallel = True
+        cfg.seq_parallel_degree = 4
+        cfg.kernel_impls = f"attention:{impl}"
+        ff = FFModel(cfg)
+        q = ff.create_tensor((b, s, e), name="q")
+        ff.multihead_attention(q, q, q, embed_dim=e, num_heads=h)
+        ff.compile(SGDOptimizer(0.01), "mean_squared_error", [])
+        return ff
+
+    def envelope(ff):
+        return memory_envelope(
+            ff.strategy, ff.executor.program.layers,
+            dict(ff.dmesh.axis_sizes), ff.optimizer)["envelope_bytes"]
+
+    ff_ring, ff_xla = build("ring"), build("xla")
+    assert ff_ring.dmesh.seq_degree == 4
+    env_ring, env_xla = envelope(ff_ring), envelope(ff_xla)
+    assert env_ring < env_xla
+    hbm = (env_ring + env_xla) / 2.0
+
+    def report(ff):
+        return verify_plan(
+            ff.strategy, ff.executor.program.layers,
+            machine_spec=ff.dmesh.spec, graph_inputs=ff.graph_inputs,
+            optimizer=ff.optimizer, hbm_bytes=hbm,
+            context="ring envelope test")
+
+    assert report(ff_ring).ok()
+    unsharded = report(ff_xla)
+    assert not unsharded.ok()
+    assert any(f.check == "memory" for f in unsharded.errors)
+
+
 # ---------------------------------------------------------------------------
 # attention with nothing forced and no plan: the `auto` rule, held to the
 # rows of the chip's table (PERF.md section 6, PR 30; flash's share of
@@ -314,11 +385,9 @@ def test_the_auto_rule_says_what_the_chips_table_says(q_len, kv_len, d, dv,
     assert ("flash" if took else "xla") == want
 
 
-def _ctx(mode="auto", plan=None):
+def _ctx(plan=None):
     from flexflow_tpu.ops.registry import EmitCtx
-    cfg = FFConfig()
-    cfg.use_flash_attention = mode
-    ctx = EmitCtx(training=True, config=cfg)
+    ctx = EmitCtx(training=True, config=FFConfig())
     ctx.kernel_impls = plan
     return ctx
 
@@ -330,15 +399,16 @@ def _ctx(mode="auto", plan=None):
     # a backend that compiles the kernel: the rule
     (dict(q=512, rate=0.1), True, True),
     (dict(q=512), True, False),
-    # the legacy switch forces either way, on either backend
-    (dict(q=16, mode="true"), False, True),
-    (dict(q=4096, mode="false"), True, False),
-    (dict(q=4096, ctx_mode="false"), True, False),
-    (dict(q=16, ctx_mode="true"), False, True),
+    # a plan's impl decides either way, on either backend; `ring` is
+    # not this call's to take, so the rule answers
+    (dict(q=16, impl="flash"), False, True),
+    (dict(q=4096, impl="xla"), True, False),
+    (dict(q=4096, impl="ring"), True, True),
+    (dict(q=16, impl="ring"), True, False),
     # what the kernel cannot mask stays on XLA whoever asks
     (dict(q=4096, window=1024, causal=True), True, False),
-    (dict(q=4096, window=1024, causal=True, mode="true"), True, False),
-    (dict(q=512, kv=1024, causal=True, mode="true"), True, False),
+    (dict(q=4096, window=1024, causal=True, impl="flash"), True, False),
+    (dict(q=512, kv=1024, causal=True, impl="flash"), True, False),
     (dict(q=512, kv=1024), True, True),
 ])
 def test_flash_enabled_backend_forcing_and_masks(monkeypatch, kw,
@@ -349,9 +419,9 @@ def test_flash_enabled_backend_forcing_and_masks(monkeypatch, kw,
                         lambda: not compiled_backend)
     q = kw["q"]
     got = mha._flash_enabled(
-        _ctx(kw.get("ctx_mode", "auto")), q, kw.get("kv", q), 64, 64,
+        kw.get("impl"), q, kw.get("kv", q), 64, 64,
         kw.get("rate", 0.0), causal=kw.get("causal", False),
-        window=kw.get("window", 0), mode=kw.get("mode"))
+        window=kw.get("window", 0))
     assert got is want
 
 
@@ -440,7 +510,7 @@ def test_auto_hands_the_kernel_the_rate_and_a_seed_from_the_layers_key(
     assert seed_a != seed_b and not np.array_equal(a, b)
     # and the mask does something: no dropout gives a third answer
     layer.params["dropout"] = 0.0
-    ctx = _ctx("true")
+    ctx = _ctx(plan={"attention": "flash"})
     assert not np.array_equal(_emit_attention(layer, ctx)[0], a)
     assert calls[-1]["dropout_rate"] == 0.0
     assert calls[-1]["dropout_seed"] is None
@@ -465,5 +535,5 @@ def test_an_eval_trace_does_not_overwrite_the_train_steps_record(
         ctx.rngs = _step_rngs(Executor, layer, 0)
         ctx.resolved_impls = record
         mha._note_impl(ctx, layer.name, "flash" if mha._flash_enabled(
-            ctx, 256, 256, 64, 64, 0.1 if training else 0.0) else "xla")
+            None, 256, 256, 64, 64, 0.1 if training else 0.0) else "xla")
         assert record == {layer.name: want}

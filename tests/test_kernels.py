@@ -224,13 +224,13 @@ def test_ring_attention_gqa_head_layout():
 # ---------------------------------------------------------------------------
 def test_mha_op_flash_path_matches_xla_path():
     """The MultiHeadAttention op emits the Pallas flash kernel when
-    use_flash_attention is on; numerics must match the XLA path."""
+    ``attention:flash`` is forced; numerics must match the XLA path."""
     from flexflow_tpu import FFConfig, FFModel, SGDOptimizer
 
-    def build(flash_mode):
+    def build(impl):
         cfg = FFConfig()
         cfg.only_data_parallel = True
-        cfg.use_flash_attention = flash_mode
+        cfg.kernel_impls = f"attention:{impl}"
         ff = FFModel(cfg)
         q = ff.create_tensor((2, 64, 64), name="q")
         ff.multihead_attention(q, q, q, embed_dim=64, num_heads=4)
@@ -239,8 +239,8 @@ def test_mha_op_flash_path_matches_xla_path():
 
     batch = {"q": np.random.default_rng(1).normal(size=(2, 64, 64))
              .astype(np.float32)}
-    ff_flash = build("true")
-    ff_xla = build("false")
+    ff_flash = build("flash")
+    ff_xla = build("xla")
     # identical init (same seed)
     y_flash = ff_flash.executor.make_forward()(ff_flash.params,
                                                ff_flash.state, batch)

@@ -42,12 +42,12 @@ def close(got, want, tol=TOL):
     assert err <= tol, f"relative error {err:.3e} > {tol}"
 
 
-def build(remat="none", flash="false", model_cfg=None, seed=0):
+def build(remat="none", attention="xla", model_cfg=None, seed=0):
     cfg = FFConfig()
     cfg.batch_size = B
     cfg.only_data_parallel = True        # no search: 0.3 s a compile
     cfg.use_bf16_compute = False
-    cfg.use_flash_attention = flash
+    cfg.kernel_impls = f"attention:{attention}"
     cfg.remat = remat
     cfg.seed = seed
     ff = FFModel(cfg)
@@ -106,9 +106,9 @@ def test_the_bias_changes_some_tokens_choice(tiny):
     assert bool(jnp.any(with_b != without))
 
 
-@pytest.mark.parametrize("flash", ["true", "false"])
-def test_heads_and_loss_match_the_reference(flash):
-    ff, mc = build(flash=flash)
+@pytest.mark.parametrize("attention", ["flash", "xla"])
+def test_heads_and_loss_match_the_reference(attention):
+    ff, mc = build(attention=attention)
     batch = data(mc)
     loss, (_, probs, mtp_logits) = program_loss(ff, ff.params, batch,
                                                 training=False)
@@ -118,7 +118,7 @@ def test_heads_and_loss_match_the_reference(flash):
     close(jax.nn.log_softmax(mtp_logits, -1), mtp)
     close(loss, reference_loss(ff, mc, ff.params, batch))
     assert set(ff.executor.resolved_attention_impls.values()) == {
-        "flash" if flash == "true" else "xla"}
+        attention}
 
 
 def test_every_weights_gradient_matches_the_reference(tiny):
@@ -272,7 +272,7 @@ def test_fit_records_instants_and_counters_and_leaves_the_bias():
     events.enable()
     events.clear()
     try:
-        ff, mc = build(remat="blocks", flash="true")
+        ff, mc = build(remat="blocks", attention="flash")
         batch = data(mc)
         x = [np.asarray(batch["input_ids"]),
              np.asarray(batch["position_ids"])]

@@ -6,7 +6,6 @@ overload-robustness contract: request deadlines end-to-end, admission
 control with Retry-After, circuit-breaker transitions, batch-poison
 isolation, and graceful drain under load."""
 import json
-import os
 import socket
 import threading
 import time
@@ -25,8 +24,6 @@ from flexflow_tpu.serving import (BatchScheduler, CircuitBreaker,
                                   InferenceSession, InvalidInputError,
                                   ModelRepository, QueueFullError,
                                   serve_http)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _mlp_session(buckets=(1, 4, 16)):
@@ -592,10 +589,9 @@ def test_infer_racing_close_fails_promptly():
 
 
 @pytest.mark.slow
-def test_concurrent_load_p50_p99_artifact():
+def test_concurrent_load_p50_p99_artifact(tmp_path):
     """Sustained concurrent load through the HTTP stack; writes the
-    p50/p99 artifact the judge asked for
-    (bench_results/serving_load_http.json)."""
+    p50/p99 record (CPU host latencies, not speeds) under ``tmp_path``."""
     import time
     repo = ModelRepository()
     repo.register("mlp", _mlp_session(buckets=(1, 4, 16, 64)),
@@ -650,8 +646,7 @@ def test_concurrent_load_p50_p99_artifact():
             "p99_ms": round(p(0.99) * 1e3, 2),
             "server_metrics": m,
         }
-        with open(os.path.join(REPO, "bench_results",
-                               "serving_load_http.json"), "w") as f:
+        with open(tmp_path / "serving_load_http.json", "w") as f:
             json.dump(rec, f, indent=1)
         # sanity: batching must actually aggregate under load
         assert m["mean_batch_rows"] > 2.0, m

@@ -3,6 +3,7 @@ per-device memory, and numerics vs the replicated-state baseline.
 
 Beyond-reference capability: the reference allocates full V/M per
 replica (``src/runtime/optimizer_kernel.cu``)."""
+import jax
 import numpy as np
 import pytest
 
@@ -265,6 +266,39 @@ def test_zero_auto_assignment_non_uniform_and_bit_exact():
     assert sharded and all(p["bytes_saved"] > 0 for p in sharded)
     assert all("overhead_s" in p and "replicated_s" in p
                for p in rec["per_param"])
+
+
+def test_zero_state_bytes_per_device_track_one_over_dp():
+    """At dp = 4 the searched assignment's optimizer state on a device
+    is well under the replicated one's: Adam on an MLP whose matrices
+    dominate, so the ratio is near 1/4 and must not pass 0.6."""
+    from flexflow_tpu.parallel.machine import MachineSpec
+
+    def state_bytes_on_device_0(policy):
+        cfg = FFConfig()
+        cfg.batch_size = 64
+        cfg.only_data_parallel = True
+        cfg.zero_policy = policy
+        ff = FFModel(cfg)
+        out = build_mlp(ff, 64, in_dim=64, hidden=(512, 512),
+                        num_classes=10)
+        ff.compile(AdamOptimizer(0.01),
+                   "sparse_categorical_crossentropy", [],
+                   output_tensor=out,
+                   machine_spec=MachineSpec(num_devices=4,
+                                            generation="cpu-sim"))
+        assert ff.dmesh.num_devices == 4
+        n_sharded = len(ff.strategy.zero.sharded_params()) \
+            if ff.strategy.zero else 0
+        # one shard a leaf: a replicated leaf's shard is the whole leaf
+        return n_sharded, sum(
+            leaf.addressable_shards[0].data.nbytes
+            for leaf in jax.tree.leaves(ff.opt_state))
+
+    n_sharded, sharded = state_bytes_on_device_0("auto")
+    _, replicated = state_bytes_on_device_0("off")
+    assert n_sharded > 0
+    assert sharded <= 0.6 * replicated, (sharded, replicated)
 
 
 def test_zero_memory_pressure_only_fits_with_assignment():

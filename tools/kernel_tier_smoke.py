@@ -15,7 +15,7 @@ the serialization contract:
     numerically (the kernels are implementations, not different math).
 
 See docs/kernels.md. The long-context memory-envelope gate lives in
-``bench.py stage_long_context``; this smoke keeps the fast tier honest.
+``tests/test_kernel_tier.py``; this smoke keeps the fast tier honest.
 """
 import os
 import sys

@@ -239,15 +239,18 @@ def _check_step_program(ff, x, y, label: str,
 
 def _check_flash_grids(label: str, want: bool) -> None:
     """The grids the flash kernels were emitted with (``flash.grid``
-    instants, one per traced call): tiles, steps, live and fetched."""
+    instants, one per traced call): tiles, steps, live and fetched, and
+    of the forward the pieces it walks its k blocks in."""
     from flexflow_tpu.obs import events
     grids = [dict(g) for g in sorted(
         {tuple(sorted(e["attrs"].items()))
          for e in events.events() if e["name"] == "flash.grid"})]
     for g in grids:
+        fwd = "" if "piece_k" not in g else (
+            f"; pieces of {g['piece_k']} keys, {g['live_pieces']} live")
         say(f"{label}: {g['kernel']} tiles {g['block_q']}x{g['block_k']}, "
             f"{g['steps']} steps, {g['live_steps']} live, "
-            f"{g['fetched_steps']} fetched")
+            f"{g['fetched_steps']} fetched" + fwd)
         check(g["fetched_steps"] == g["live_steps"] <= g["steps"],
               f"{label}: {g['kernel']} fetches {g['fetched_steps']} blocks "
               f"for {g['live_steps']} live steps")

@@ -11,17 +11,21 @@ differently-blocked backward could not regenerate.)
 Checks (each prints PASS/FAIL, exit code 1 on any failure):
   1. fwd numerics vs the plain-XLA golden, f32 + bf16, causal on/off,
      unpadded (512) and padded (393) sequence lengths;
-  2. full vjp (dq/dk/dv) vs jax.grad of the golden, the backward
-     kernels at the tiles derived from the shapes (printed), and once at
-     the benchmark's cell-2 shapes (bh 144, s 1024, d 64, bf16, causal)
-     against the golden at ``highest`` precision;
+  2. full vjp (dq/dk/dv) vs jax.grad of the golden, the kernels at the
+     tiles derived from the shapes (printed), once at the benchmark's
+     cell-2 shapes (bh 144, s 1024, d 64, bf16, causal) against the
+     golden at ``highest`` precision, and the forward at cell 3's (bh 32,
+     s 4096, q.k over 192, p.v over 128, bf16, causal) against the same;
   3. dropout>0: deterministic under one seed, decorrelated across seeds,
      empirical keep-rate ≈ 1-rate, and vjp matches jax.grad of an
      explicit-masked golden built from the kernel's own keep-mask;
   4. the benchmark's cell-1 layer (8 x 16 x 512 x 64, bf16, dropout 0.1):
      kernels and vjp against that golden at ``highest``, the mask's keep
      share over the layer's 33.5M positions, and the attention op with
-     nothing forced: ``auto`` takes the kernels there (PERF.md, PR 30).
+     nothing forced: ``auto`` takes the kernels there (PERF.md, PR 30);
+  5. float32 operands with dropout over 2,048 and 4,096 keys, not causal
+     (head sizes 128 and 256, sq != sk among them): forward and vjp
+     against the explicit-mask golden at ``highest``.
 """
 import os
 import sys
@@ -48,13 +52,19 @@ def check(name, ok, detail=""):
 
 
 def tiles():
-    """The tiles of the backward kernels emitted last (``flash.grid``)."""
+    """The tiles of the kernels emitted since the last call of this
+    (``flash.grid``): the forward's block and the pieces it walks its
+    keys in, the backward kernels' where a backward was traced."""
     last = {e["attrs"]["kernel"]: e["attrs"] for e in events.events()
             if e["name"] == "flash.grid"}
+    events.clear()
+    fwd = last["flash_attention_fwd"]
     return " ".join(
-        f"{name} {last[kernel]['block_q']}x{last[kernel]['block_k']}"
-        for name, kernel in (("dq", "flash_attention_bwd_dq"),
-                             ("dkv", "flash_attention_bwd_dkv")))
+        [f"fwd {fwd['block_q']}x{fwd['block_k']}/{fwd['piece_k']}"]
+        + [f"{name} {last[kernel]['block_q']}x{last[kernel]['block_k']}"
+           for name, kernel in (("dq", "flash_attention_bwd_dq"),
+                                ("dkv", "flash_attention_bwd_dkv"))
+           if kernel in last])
 
 
 def rel_err(a, b):
@@ -123,6 +133,49 @@ def cell1_layer(rng, b=8, s=512, d=64):
         bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(g0)))
 
 
+def long_f32_dropout():
+    """-- 5: float32 operands with dropout over 2,048 keys and more, not
+    causal: the forward walks its k block in several pieces there, and
+    Mosaic refused it for a described v5e while the pieces were unrolled
+    inline (PR 32's review). Forward and gradients against the
+    explicit-mask golden at ``highest``, f32 tolerances as in 1/2 and 3."""
+    import math
+
+    from flexflow_tpu.kernels import dropout_keep_mask
+    events.clear()                     # tiles(): this section's calls
+    rng = np.random.default_rng(5)     # draws of its own
+    hi = jax.lax.Precision.HIGHEST
+    b, h, rate, seed = 1, 4, 0.1, 77
+    for sq, sk, d in ((2048, 2048, 128), (1024, 4096, 128),
+                      (2048, 2048, 256)):
+        q, probe = (jnp.asarray(rng.normal(size=(b, h, sq, d)), jnp.float32)
+                    for _ in range(2))
+        k, v = (jnp.asarray(rng.normal(size=(b, h, sk, d)), jnp.float32)
+                for _ in range(2))
+        keep = dropout_keep_mask(b, h, sq, sk, rate, seed)
+
+        def golden(qv, kv, vv):
+            sc = jnp.einsum("bhqd,bhkd->bhqk", qv, kv,
+                            precision=hi) / math.sqrt(d)
+            p = jnp.where(keep, jax.nn.softmax(sc, axis=-1) / (1.0 - rate),
+                          0.0)
+            return jnp.einsum("bhqk,bhkd->bhqd", p, vv, precision=hi)
+
+        def kernel(qv, kv, vv):
+            return flash_attention(qv, kv, vv, dropout_rate=rate,
+                                   dropout_seed=seed)
+
+        tag = f"float32/dropout/sq={sq}/sk={sk}/d={d}"
+        rel = rel_err(kernel(q, k, v), golden(q, k, v))
+        check(f"fwd {tag}", rel < 1e-2, f"rel={rel:.2e} tiles {tiles()}")
+        g = jax.grad(lambda *x: jnp.sum(kernel(*x) * probe),
+                     argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.grad(lambda *x: jnp.sum(golden(*x) * probe),
+                         argnums=(0, 1, 2))(q, k, v)
+        worst = max(rel_err(a, b_) for a, b_ in zip(g, g_ref))
+        check(f"bwd {tag}", worst < 2e-2, f"rel={worst:.2e} tiles {tiles()}")
+
+
 def main():
     from flexflow_tpu.utils.compilation_cache import enable_compilation_cache
     enable_compilation_cache()   # no FFModel.compile here to do it
@@ -169,7 +222,7 @@ def main():
                 o = flash_attention(q, k, v, causal=causal)
                 o_ref = mha_reference(q, k, v, causal=causal)
                 check(f"fwd {tag}", rel_err(o, o_ref) < tol_f,
-                      f"rel={rel_err(o, o_ref):.2e}")
+                      f"rel={rel_err(o, o_ref):.2e} tiles {tiles()}")
 
                 def loss(f, a, b_, c):
                     return jnp.sum(
@@ -195,7 +248,8 @@ def main():
     hi = dict(precision=jax.lax.Precision.HIGHEST)
     o = flash_attention(q, k, v, causal=True)
     rel = rel_err(o, mha_reference(q, k, v, causal=True, **hi))
-    check("fwd cell2 vs HIGHEST", rel < 2e-2, f"rel={rel:.2e}")
+    check("fwd cell2 vs HIGHEST", rel < 2e-2,
+          f"rel={rel:.2e} tiles {tiles()}")
     g = jax.grad(lambda *x: loss2(flash_attention, *x),
                  argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(lambda *x: loss2(mha_reference, *x, **hi),
@@ -204,6 +258,20 @@ def main():
     check("bwd cell2 vs HIGHEST", max(rels) < 4e-2,
           "rel dq={:.2e} dk={:.2e} dv={:.2e} tiles ".format(*rels)
           + tiles())
+
+    # the benchmark's cell 3: 32 heads of 4096 x 192 / 128, bf16, causal;
+    # the forward alone (examples/tpu_validate_latent_moe.py holds the
+    # gradients at this shape to the same golden)
+    # (draws of its own, so the sections below read the data they read
+    # before this check came)
+    rng3 = np.random.default_rng(3)
+    q, k = (jnp.asarray(rng3.normal(size=(1, 32, 4096, 192)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng3.normal(size=(1, 32, 4096, 128)), jnp.bfloat16)
+    o = flash_attention(q, k, v, causal=True)
+    rel = rel_err(o, mha_reference(q, k, v, causal=True, **hi))
+    check("fwd cell3 vs HIGHEST", rel < 2e-2,
+          f"rel={rel:.2e} tiles {tiles()}")
 
     # -- 3: in-kernel dropout (TPU-only path) ---------------------------
     # seq 1024: a (512, 512) forward under backward tiles of another
@@ -273,6 +341,7 @@ def main():
           f"rel={worst:.2e} tiles {tiles()}")
 
     cell1_layer(rng)
+    long_f32_dropout()
 
     print(f"\n{len(FAILED)} failures" if FAILED else "\nALL PASS")
     return 1 if FAILED else 0

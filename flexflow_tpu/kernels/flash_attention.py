@@ -65,8 +65,9 @@ def _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k, rate):
 
     keep[i, j] is a pure hash of (seed, batch-head, ABSOLUTE query
     position, ABSOLUTE key position) — independent of the tiling — so
-    the forward (512x512 blocks) and the backward kernels (their own
-    tiles, :func:`bwd_tiles`) regenerate bit-identical masks. Found
+    the forward (its own blocks, :func:`fwd_tiles`, walked in pieces)
+    and the backward kernels (their own tiles, :func:`bwd_tiles`)
+    regenerate bit-identical masks. Found
     compiling on a real v5e: a pltpu-PRNG mask seeded per (b, iq, ik)
     tile cannot be reproduced by a differently-blocked backward pass,
     which silently corrupted dq
@@ -105,13 +106,56 @@ def _position_keep(seed, bh, q_pos, k_pos, rate):
 # ---------------------------------------------------------------------------
 # forward kernel: grid (bh, nq, nk), accumulate over the nk axis in scratch
 # ---------------------------------------------------------------------------
+LANES = 128
+#: keys one piece of a forward grid step scores at once. A step streams a
+#: k/v block as wide as fits and walks it in pieces, so the (block_q,
+#: piece) float32 intermediates stay small while a step's fixed cost is
+#: paid once a block. 512 was the fastest piece or within 2% of it at
+#: every shape timed on a v5e where it divides the block (1,024 is 2-10%
+#: behind at 2,048 keys and more, 256 is 15-35% behind); 768 keys are
+#: faster whole than in pieces of 384 (PERF.md section 6, PR 32).
+FWD_PIECE = 512
+
+
+def _fwd_piece(block_k):
+    """Width of the pieces a k block of ``block_k`` keys is walked in:
+    ``FWD_PIECE`` where it divides the block, else the whole block."""
+    return FWD_PIECE if block_k % FWD_PIECE == 0 else block_k
+
+
+def _piece_live(iq, piece, block_q, piece_k):
+    """Causal: whether any pair of q block ``iq`` with the keys
+    ``[piece * piece_k, (piece + 1) * piece_k)`` lies on or under the
+    diagonal (ints, or traced program ids). A dead piece is skipped."""
+    return piece * piece_k <= (iq + 1) * block_q - 1
+
+
+def _lanes(x, width):
+    """A lane-replicated (rows, 128) value at ``width`` lanes."""
+    if width <= LANES:
+        return x[:, :width]
+    return jnp.tile(x, (1, -(-width // LANES)))[:, :width]
+
+
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_sc, m_sc, l_sc, *, sm_scale, causal,
-                kv_len, block_q, block_k, dropout_rate):
+                acc_sc, m_sc, l_sc, *, sm_scale, causal, kv_len,
+                block_q, block_k, dropout_rate):
+    """Online softmax over the k blocks of one q block. The running
+    maximum lives lane-replicated in ``m_sc`` (rows, 128), so the
+    rescaling factors are whole vregs and never a (rows, 1) column; the
+    running sum lives LANE-WISE in ``l_sc``: each of its 128 lanes sums
+    the keys congruent to it, rescaled by the row's factor (uniform over
+    a row's lanes), and the lanes meet once, in ``_finish``. One
+    cross-lane reduction a piece is left, the row maximum. Every live
+    piece is masked, as every live tile was: leaving the select off the
+    pieces that need none was timed and was worth nothing (PERF.md
+    section 6, PR 32)."""
     b = pl.program_id(0)     # read out here: interpret mode has no
     iq = pl.program_id(1)    # program_id inside pl.when's cond
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
+    piece_k = _fwd_piece(block_k)
+    pieces = block_k // piece_k
 
     @pl.when(ik == 0)
     def _init():
@@ -119,43 +163,65 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_sc[:] = jnp.zeros_like(l_sc)
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    # causal: the kv block is live iff its first key is visible to the last
-    # query of this q block
-    live = (ik * block_k <= (iq + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]                      # (block_q, d)
-        k = k_ref[0]                      # (block_k, d)
-        v = v_ref[0]
+    def _piece(c):
+        piece = ik * pieces + c              # among all pieces of the row
+        keys = slice(None) if pieces == 1 else pl.ds(
+            pl.multiple_of(c * piece_k, piece_k), piece_k)
+        q = q_ref[0]                         # (block_q, d)
+        k = k_ref[0, keys, :]                # (piece_k, d)
+        v = v_ref[0, keys, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(_key_mask(iq, ik, block_q, block_k, kv_len, causal),
+        s = jnp.where(_key_mask(iq, piece, block_q, piece_k, kv_len, causal),
                       s, NEG_INF)
-        m_prev = m_sc[:, :1]                            # (block_q, 1)
+        m_prev = m_sc[:]                                 # (block_q, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                          # (block_q, block_k)
         alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - _lanes(m_new, piece_k))          # (block_q, piece_k)
         # softmax denominator uses UNdropped p; dropout only scales the
         # numerator (matches dropout-on-probs semantics)
-        l_new = l_sc[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if piece_k % LANES == 0:
+            l_part = p[:, :LANES]
+            for g in range(1, piece_k // LANES):
+                l_part = l_part + p[:, g * LANES:(g + 1) * LANES]
+        else:     # an explicit block Mosaic would not take: sum in lane 0
+            lane = jax.lax.broadcasted_iota(jnp.int32, l_sc.shape, 1)
+            l_part = jnp.where(lane == 0,
+                               jnp.sum(p, axis=1, keepdims=True), 0.0)
+        l_sc[:] = l_sc[:] * alpha + l_part
         if dropout_rate > 0.0:
-            keep = _tile_keep_mask(seed_ref, b, iq, ik,
-                                   block_q, block_k, dropout_rate)
-            p_eff = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-        else:
-            p_eff = p
+            keep = _tile_keep_mask(seed_ref, b, iq, piece,
+                                   block_q, piece_k, dropout_rate)
+            p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
         pv = jax.lax.dot_general(
-            p_eff.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_sc[:] = acc_sc[:] * alpha + pv
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+        acc_sc[:] = acc_sc[:] * _lanes(alpha, acc_sc.shape[1]) + pv
+        m_sc[:] = m_new
+
+    # One piece's intermediates at a time: each piece runs in a scope of
+    # its own. Unrolled inline, Mosaic kept several pieces' float32 and
+    # hash tiles live at once and refused float32 operands with dropout
+    # from 2,048 keys on, for a described v5e (2-13 MiB over its 16 MiB
+    # of scoped VMEM). Under ``causal`` the scope is the branch that
+    # skips a dead piece, and the pieces are unrolled so that k and v are
+    # sliced at static offsets (a loop was 20% slower at cell 2's shape,
+    # the same at cell 3's); without it, where a condition that always
+    # holds would be folded away, the scope is a loop's body (0-9% slower
+    # than inline where inline compiled; PERF.md section 6, PR 32).
+    if causal:
+        for c in range(pieces):
+            pl.when(_piece_live(iq, ik * pieces + c, block_q, piece_k))(
+                functools.partial(_piece, c))
+    elif pieces == 1:
+        _piece(0)
+    else:
+        jax.lax.fori_loop(0, pieces, lambda c, _: _piece(c), None)
 
     @pl.when(ik == nk - 1)
     def _finish():
-        l = l_sc[:, :1]
+        l = jnp.sum(l_sc[:], axis=1, keepdims=True)      # (block_q, 1)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
         lse = m_sc[:, :1] + jnp.log(l_safe)
@@ -321,7 +387,9 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
     ``bwd_dq``, q/do/row statistics for ``bwd_dkv``) than the step before
     in their row of the grid, so that the pipeline copies one. A row's
     first step counts as a fetch (Mosaic skips that one too where the
-    row before ended on the same block)."""
+    row before ended on the same block). The forward walks a step's k
+    block in pieces of ``piece_k`` keys (:func:`_fwd_piece`) and skips
+    the dead ones: ``live_pieces`` are those it computes."""
     nq, nk = sq // block_q, sk // block_k
     live = fetched = 0
     if kernel == "bwd_dkv":            # rows are k blocks, q blocks stream
@@ -334,9 +402,15 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
             last = _last_live_k(i, block_q, block_k) if causal else nk - 1
             live += min(nk, last + 1)
             fetched += len({min(j, last) for j in range(nk)})
-    return {"kernel": "flash_attention_" + kernel, "block_q": block_q,
-            "block_k": block_k, "steps": bh * nq * nk,
-            "live_steps": bh * live, "fetched_steps": bh * fetched}
+    out = {"kernel": "flash_attention_" + kernel, "block_q": block_q,
+           "block_k": block_k, "steps": bh * nq * nk,
+           "live_steps": bh * live, "fetched_steps": bh * fetched}
+    if kernel == "fwd":
+        piece_k = _fwd_piece(block_k)
+        out.update(piece_k=piece_k, live_pieces=bh * sum(
+            not causal or bool(_piece_live(i, p, block_q, piece_k))
+            for i in range(nq) for p in range(sk // piece_k)))
+    return out
 
 
 def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal):
@@ -520,10 +594,11 @@ def _bwd_vmem_bytes(kernel, block_q, block_k, d, itemsize, dropout,
     return 2 * (q_side + k_side + out) + scratch + stage + tiles
 
 
-def _tile_sizes(padded):
-    """Multiples of 128 that divide ``padded``; a sequence with none
-    (shorter than 128, or not a multiple of it) is one tile."""
-    sizes = [t for t in range(128, min(padded, MAX_BWD_TILE) + 1, 128)
+def _tile_sizes(padded, most=MAX_BWD_TILE):
+    """Multiples of 128, at most ``most``, that divide ``padded``; a
+    sequence with none (shorter than 128, or not a multiple of it) is
+    one tile."""
+    sizes = [t for t in range(128, min(padded, most) + 1, 128)
              if padded % t == 0]
     return sizes or [padded]
 
@@ -559,13 +634,75 @@ def _explicit_block(bwd_block, fwd_block):
 
 
 # ---------------------------------------------------------------------------
+# forward tiles
+# ---------------------------------------------------------------------------
+#: the tallest q block and the widest k block timed on the chip (PERF.md
+#: section 6, PR 32)
+MAX_FWD_BLOCK_Q = 1024
+MAX_FWD_BLOCK_K = 4096
+
+
+def _fwd_vmem_bytes(block_q, block_k, d, itemsize, dropout, dv=None):
+    """Working set of one forward grid step: the double-buffered q, k, v,
+    o and log-sum-exp blocks, the three float32 scratch buffers, the
+    (block_q, piece) intermediates of one piece of the k block (each
+    piece runs in a scope of its own: of ``s``, ``p``, the mask and the
+    cast Mosaic keeps about one float32 tile, one more for the dropout
+    hash and keep mask), and the float32 quotient and log-sum-exp that
+    ``_finish`` forms before it casts them into the output blocks (with
+    one k block a row they share the step with the pieces). Held to
+    ``BWD_VMEM_BUDGET`` and checked against Mosaic for a described v5e
+    (PERF.md section 6, PR 32): every block :func:`fwd_tiles` derives
+    over a grid of 488 (dtype, head sizes, dropout, causal, lengths of
+    256 to 8,192, sq != sk) compiles, and of explicit blocks around the
+    limit every one Mosaic refused counts over the budget."""
+    qk = -(-d // 128) * 128                  # a (rows, 64) block fills 128
+    pv = -(-(d if dv is None else dv) // 128) * 128
+    blocks = (block_q * (qk + pv) * itemsize + block_q * 128 * 4   # q, o, lse
+              + block_k * (qk + pv) * itemsize)                    # k, v
+    scratch = block_q * (pv + 2 * 128) * 4
+    tiles = (2 if dropout else 1) * block_q * _fwd_piece(block_k) * 4
+    finish = block_q * (pv + 128) * 4
+    return 2 * blocks + scratch + tiles + finish
+
+
+def fwd_tiles(sq, sk, d, dtype, dropout, dv=None):
+    """``(block_q, block_k)`` of the forward kernel for padded sequence
+    lengths ``sq``, ``sk`` and padded head dims ``d`` (q, k) and ``dv``
+    (v, the output; None: the same as ``d``). The q block is resident
+    while k/v blocks stream, and a step walks its k block in pieces
+    (:func:`_fwd_piece`), so a wider k block buys fewer steps and not
+    larger intermediates: the widest k block whose working set fits
+    ``BWD_VMEM_BUDGET`` won at every shape timed on a v5e (all 4,096 keys
+    of the benchmark's cell 3: 1.52-1.60 ms a call, 1.60 at 2,048,
+    1.70-1.74 on the best tiles without pieces, 3.25 on the 512 x 512 it
+    had). The q block is the tallest that fits beside it, up to 1,024
+    rows (the whole of cell 2's sequence, one step a (batch, head): 0.49
+    ms a call against 0.52 in two of 512). Where the keys do not fit one
+    block they are streamed again for every q block, and the taller
+    block halves that traffic (8,192 positions: 0.96 against 1.04 ms).
+    PERF.md section 6, PR 32."""
+    itemsize = jnp.dtype(dtype).itemsize
+    tiles = itertools.product(_tile_sizes(sq, MAX_FWD_BLOCK_Q),
+                              _tile_sizes(sk, MAX_FWD_BLOCK_K))
+    fits = [t for t in tiles
+            if _fwd_vmem_bytes(*t, d, itemsize, dropout, dv)
+            <= BWD_VMEM_BUDGET]
+    if not fits:                       # the smallest there is
+        return _tile_sizes(sq)[0], _tile_sizes(sk)[0]
+    block_k = max(bk for _, bk in fits)
+    return max(bq for bq, bk in fits if bk == block_k), block_k
+
+
+# ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     dropout_rate: float = 0.0,
                     dropout_seed=None,
-                    block_q: int = 512, block_k: int = 512,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     bwd_block_q: Optional[int] = None,
                     bwd_block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
@@ -575,9 +712,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``d`` (latent attention: q.k over 192, p.v over 128); the score scale
     defaults to ``1 / sqrt(d)``.
 
-    Pads seq dims to block multiples and each head dim to a multiple of
-    64 (padded keys masked, padded head dims sliced off), runs the Pallas
-    kernels, and is differentiable via the custom VJP. ``dropout_rate`` > 0
+    Pads the key length to a multiple of 128 and the query length to a
+    multiple of 8 (of 128 from 512 positions on), tiles them by divisors,
+    pads each head dim to a multiple of 64 (padded keys masked, padded
+    head dims sliced off), runs the Pallas kernels, and is differentiable
+    via the custom VJP. ``dropout_rate`` > 0
     applies in-kernel counter-based dropout to the attention
     probabilities (requires ``dropout_seed``, an int32 scalar). The
     attention ops call this by themselves from 1024 positions, and from
@@ -586,9 +725,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
     s² attention in every column timed on a v5e (PERF.md section 6, PR
     30; ``ops/nn_ops.py::MultiHeadAttentionOp.auto_takes_flash``).
 
-    The forward's block default is measured on v5e (head_dim 64): large
-    tiles (512x512 — k/v are re-streamed once per q block, so bigger q
-    blocks cut HBM traffic). ``bwd_block_q`` / ``bwd_block_k`` = None
+    ``block_q`` / ``block_k`` = None take the forward's blocks from the
+    shapes (:func:`fwd_tiles`): the k/v block as wide as fits, walked in
+    pieces of 512 keys, under a q block of up to 1,024 rows. Timed on a
+    v5e, device time, bf16 (PERF.md section 6, PR 32): at bh 144, s
+    1024, d 64, causal the forward takes 1.09 ms a call as it was
+    (512x512, a (rows, 1) column for each row statistic), 0.56 with the
+    running maximum lane-replicated and the running sum lane-wise, 0.49
+    at 1024x1024 in two pieces; at bh 32, s 4096, 192 / 128, causal 3.25,
+    1.84 and 1.60 at 1024x4096. Masks only on the pieces the diagonal
+    crosses and the score scale folded into q were timed too and were
+    worth nothing (the kernel waits on the matrix unit, not on the
+    selects); they are not in it. A value given wins.
+    ``bwd_block_q`` / ``bwd_block_k`` = None
     take each backward kernel's tile from the shapes (:func:`bwd_tiles`);
     a value given is used by both. Timed on a v5e over 128 to 1024 a
     side (PERF.md section 6, PR 28): at bh 144, s 1024, d 64, causal the
@@ -619,21 +768,25 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise NotImplementedError("causal flash requires sq == sk")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    # clamp blocks to (hardware-aligned) sequence sizes: sublane mult of 8,
-    # lane mult of 128
-    block_q = min(block_q, -(-sq // 8) * 8)
-    block_k = min(block_k, -(-sk // 128) * 128)
-
+    # hardware-aligned sequence sizes: keys in lanes of 128; q rows in
+    # sublanes of 8 up to 512 of them (one tile, no multiple of 128
+    # needed), in multiples of 128 above, to be tiled by a divisor. An
+    # explicit block is clamped to them and pads to a multiple of itself
+    sq_to = block_q and min(block_q, -(-sq // 8) * 8)
+    sk_to = block_k and min(block_k, -(-sk // 128) * 128)
     # head_dim: pad only to a multiple of 64. d=64 (BERT/GPT-class) stays
     # unpadded — padding to the full 128 lane width doubled k/v HBM
     # traffic and the PV-matmul passes (measured: flash lost to XLA
     # attention below seq 1024 because of it). The MXU handles 64-lane
     # tiles natively.
-    qp = _pad_to(_pad_to(q, block_q, 2), 64, 3)
-    kp = _pad_to(_pad_to(k, block_k, 2), 64, 3)
-    vp = _pad_to(_pad_to(v, block_k, 2), 64, 3)
+    qp = _pad_to(_pad_to(q, sq_to or (8 if sq <= 512 else 128), 2), 64, 3)
+    kp = _pad_to(_pad_to(k, sk_to or 128, 2), 64, 3)
+    vp = _pad_to(_pad_to(v, sk_to or 128, 2), 64, 3)
     sq_p, d_p = qp.shape[2], qp.shape[3]
     sk_p, dv_p = kp.shape[2], vp.shape[3]
+    # forward blocks from the shapes; an explicit one wins
+    derived = fwd_tiles(sq_p, sk_p, d_p, q.dtype, dropout_rate > 0.0, dv_p)
+    block_q, block_k = sq_to or derived[0], sk_to or derived[1]
     dq_blocks, dkv_blocks = bwd_tiles(sq_p, sk_p, d_p, q.dtype,
                                       dropout_rate > 0.0, dv_p)
     # an explicit backward block wins, for both kernels

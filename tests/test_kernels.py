@@ -360,7 +360,7 @@ def _qkv(sq, sk, d, h=1, seed=0):
 
 
 DERIVED_CASES = {
-    # cell 2's shapes: (512, 512) forward, two blocks a side
+    # cell 2's shapes: one forward step a head, in two pieces
     "s1024_d64_causal": dict(sq=1024, sk=1024, d=64, causal=True),
     "s512": dict(sq=512, sk=512, d=64, causal=False),
     "s380_padded_causal": dict(sq=380, sk=380, d=64, causal=True),
@@ -409,8 +409,6 @@ def test_flash_dropout_gradients_with_bwd_tiles_unlike_fwd(causal):
     """Dropout 0.1, forward at 128-wide blocks, backward at the derived
     ones (256): the position hash has to give both the same mask. Golden:
     the explicit mask in plain XLA."""
-    import math
-    from flexflow_tpu.kernels import dropout_keep_mask
     b, h, s, d, rate, seed = 1, 2, 256, 64, 0.1, 5
     q, k, v = _rand_qkv(b, h, s, d)
     kw = dict(causal=causal, dropout_rate=rate, dropout_seed=seed,
@@ -422,13 +420,7 @@ def test_flash_dropout_gradients_with_bwd_tiles_unlike_fwd(causal):
             grids["flash_attention_bwd_dkv"]["block_k"]) == (128, 256, 256)
 
     def golden(q, k, v):
-        s_ = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(d)
-        if causal:
-            s_ = jnp.where(np.tril(np.ones((s, s), bool)), s_, -1e30)
-        p = jax.nn.softmax(s_, -1)
-        keep = dropout_keep_mask(b, h, s, s, rate, seed)
-        return jnp.einsum("bhqk,bhkd->bhqd",
-                          jnp.where(keep, p / (1 - rate), 0.0), v)
+        return _fwd_golden(q, k, v, causal, rate, seed)
 
     g = jax.grad(lambda *x: jnp.sum(flash_attention(*x, **kw) ** 2),
                  argnums=(0, 1, 2))(q, k, v)
@@ -576,6 +568,200 @@ def test_grid_steps_of_cell_2_before_and_after():
         new = fa.grid_steps(kernel, 144, 1024, 1024, bq, bk, True)
         assert new["steps"] <= 9216 // 4
         assert new["fetched_steps"] == new["live_steps"]
+
+
+# ---------------------------------------------------------------------------
+# the forward's blocks derived from the shapes, its keys walked in pieces,
+# dead pieces skipped (PR 32; the compiled kernel:
+# tests/test_tpu_aot_compile.py, chip_smoke.py)
+# ---------------------------------------------------------------------------
+# (padded sq, padded sk, d, dv, dtype, dropout, causal: the rule does not
+# read it)
+FWD_RULE_CASES = {
+    "cell1": (512, 512, 64, None, "bfloat16", True, False),
+    "cell2": (1024, 1024, 64, None, "bfloat16", False, True),
+    "cell3": (4096, 4096, 192, 128, "bfloat16", False, True),
+    "s197": (200, 256, 64, None, "float32", False, False),
+    "s393_causal": (400, 512, 64, None, "float32", False, True),
+    "s768_causal": (768, 768, 64, None, "bfloat16", False, True),
+    "cross_256_640": (256, 640, 64, None, "float32", False, False),
+    "cross_2048_512": (2048, 512, 128, None, "bfloat16", True, False),
+    "short_100": (104, 128, 64, None, "float32", False, True),
+    "s1152": (1152, 1152, 64, None, "bfloat16", False, True),
+    "f32_d256_dropout": (2048, 2048, 256, None, "float32", True, True),
+    "s8192": (8192, 8192, 128, None, "bfloat16", False, True),
+    "s16384_f32_d256": (16384, 16384, 256, None, "float32", True, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FWD_RULE_CASES))
+def test_fwd_tile_rule(case):
+    sq, sk, d, dv, dtype, dropout, causal = FWD_RULE_CASES[case]
+    bq, bk = fa.fwd_tiles(sq, sk, d, jnp.dtype(dtype), dropout, dv)
+    assert sq % bq == 0 and sk % bk == 0
+    assert bq % 128 == 0 or bq == sq
+    assert bk % 128 == 0 or bk == sk
+    assert bq <= max(fa.MAX_FWD_BLOCK_Q, 0 if sq % 128 == 0 else sq)
+    assert bk <= fa.MAX_FWD_BLOCK_K
+    piece = fa._fwd_piece(bk)
+    assert piece == (fa.FWD_PIECE if bk % fa.FWD_PIECE == 0 else bk)
+    used = fa._fwd_vmem_bytes(bq, bk, d, jnp.dtype(dtype).itemsize,
+                              dropout, dv)
+    assert used <= fa.BWD_VMEM_BUDGET
+    # the widest k block that fits beside any q block
+    for t in itertools.product(fa._tile_sizes(sq, fa.MAX_FWD_BLOCK_Q),
+                               fa._tile_sizes(sk, fa.MAX_FWD_BLOCK_K)):
+        if t[1] > bk:
+            assert fa._fwd_vmem_bytes(
+                *t, d, jnp.dtype(dtype).itemsize, dropout,
+                dv) > fa.BWD_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("case,want", [
+    ("cell1", (512, 512, 512)),       # one tile, one piece
+    ("cell2", (1024, 1024, 512)),     # the whole sequence a step
+    ("cell3", (1024, 4096, 512)),     # every key resident, 4 steps a head
+    ("s768_causal", (768, 768, 768)),             # faster whole than 2 x 384
+    ("s8192", (1024, 4096, 512)),     # keys streamed twice: the taller q
+    ("f32_d256_dropout", (512, 2048, 512)),
+])
+def test_fwd_tile_rule_at_the_shapes_timed_on_the_chip(case, want):
+    """PERF.md section 6, PR 32: the block the rule picks was timed on
+    the chip at each of these shapes and is the swept winner at cells 1
+    and 2 and at 768 and 8,192 positions; at cell 3's, 512 rows beside
+    the same 4,096 keys were 5% faster in the kernel alone, 0.3% of the
+    step, which no pair of runs resolves: not a case of the rule."""
+    sq, sk, d, dv, dtype, dropout, causal = FWD_RULE_CASES[case]
+    bq, bk = fa.fwd_tiles(sq, sk, d, jnp.dtype(dtype), dropout, dv)
+    assert (bq, bk, fa._fwd_piece(bk)) == want
+
+
+def _fwd_golden(q, k, v, causal, rate=0.0, seed=0):
+    """Plain-XLA attention with the kernels' own keep mask."""
+    import math
+    from flexflow_tpu.kernels import dropout_keep_mask
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    s_ = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(d)
+    if causal:
+        s_ = jnp.where(np.tril(np.ones((sq, sk), bool)), s_, -1e30)
+    p = jax.nn.softmax(s_, -1)
+    if rate:
+        p = jnp.where(dropout_keep_mask(b, h, sq, sk, rate, seed),
+                      p / (1 - rate), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+# (sq, sk, d, dv, causal, dropout, explicit blocks) -> the forward's
+# (block_q, block_k, piece_k)
+FWD_CASES = {
+    "s1024_64": (1024, 1024, 64, 64, False, 0.0, {}, (1024, 1024, 512)),
+    "s1024_64_causal": (1024, 1024, 64, 64, True, 0.0, {},
+                        (1024, 1024, 512)),
+    "s1024_192_128": (1024, 1024, 192, 128, False, 0.0, {},
+                      (1024, 1024, 512)),
+    "s1024_192_128_causal": (1024, 1024, 192, 128, True, 0.0, {},
+                             (1024, 1024, 512)),
+    "s1024_dropout": (1024, 1024, 64, 64, False, 0.1, {},
+                      (1024, 1024, 512)),
+    "s1024_dropout_causal": (1024, 1024, 64, 64, True, 0.1, {},
+                             (1024, 1024, 512)),
+    # several q blocks under the diagonal, every key in each step
+    "s2048_causal": (2048, 2048, 64, 64, True, 0.0, {}, (1024, 2048, 512)),
+    # padded keys: 600 -> 640 and 1100 -> 1152, each walked in one piece
+    "s600_padded": (600, 600, 64, 64, False, 0.0, {}, (640, 640, 640)),
+    "s600_padded_causal": (600, 600, 64, 64, True, 0.0, {},
+                           (640, 640, 640)),
+    "s1100_padded": (1100, 1100, 64, 64, False, 0.1, {},
+                     (384, 1152, 1152)),
+    "cross_300_700": (300, 700, 64, 64, False, 0.0, {}, (304, 768, 768)),
+    "explicit_256x1024": (1024, 1024, 64, 64, True, 0.0,
+                          dict(block_q=256, block_k=1024),
+                          (256, 1024, 512)),
+    "explicit_128x256": (1024, 1024, 64, 64, True, 0.1,
+                         dict(block_q=128, block_k=256), (128, 256, 256)),
+    # 1100 keys padded to 2048: the last piece is padding alone
+    "explicit_padding_piece": (1100, 1100, 64, 64, False, 0.1,
+                               dict(block_q=512, block_k=1024),
+                               (512, 1024, 512)),
+    "explicit_k_only": (1024, 1024, 64, 64, False, 0.0,
+                        dict(block_k=512), (1024, 512, 512)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FWD_CASES))
+def test_flash_forward_at_derived_blocks(case):
+    sq, sk, d, dv, causal, rate, blocks, want = FWD_CASES[case]
+    rng = np.random.default_rng(7)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, s, w)), jnp.float32)
+               for s, w in ((sq, d), (sk, d), (sk, dv)))
+    kw = dict(causal=causal, dropout_rate=rate, dropout_seed=9,
+              interpret=True, **blocks)
+    g = _grids_of(lambda *x: flash_attention(*x, **kw), q, k, v)[
+        "flash_attention_fwd"]
+    assert (g["block_q"], g["block_k"], g["piece_k"]) == want
+    assert g["fetched_steps"] == g["live_steps"] <= g["steps"]
+    out = flash_attention(q, k, v, **kw)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_fwd_golden(q, k, v, causal, rate, 9)),
+        atol=2e-5, rtol=2e-4)
+
+
+# (block_q, block_k, padded s, kv_len, causal), block_q != block_k among
+# them; block_k 1152 and 640 walk in one piece
+PIECE_GRIDS = [(512, 512, 1024, 1024, True), (1024, 1024, 1024, 1024, True),
+               (512, 2048, 2048, 2048, True), (256, 1024, 2048, 2048, True),
+               (1024, 512, 2048, 2048, True), (384, 1152, 1152, 1100, True),
+               (384, 1152, 1152, 1100, False), (640, 640, 640, 600, False),
+               (512, 1024, 2048, 1990, False), (512, 1024, 1024, 1024, False)]
+
+
+@pytest.mark.parametrize("bq,bk,s,kv_len,causal", PIECE_GRIDS)
+def test_fwd_piece_classes_against_the_mask_itself(bq, bk, s, kv_len,
+                                                   causal):
+    """Every (q block, k block, piece) of the grid: a piece the kernel
+    skips has an all-false mask, and a piece it computes has a valid
+    pair (every live piece is masked, so none is classed "no mask")."""
+    piece_k = fa._fwd_piece(bk)
+    pieces = bk // piece_k
+    dead = 0
+    for iq, ik, c in itertools.product(range(s // bq), range(s // bk),
+                                       range(pieces)):
+        piece = ik * pieces + c
+        mask = np.asarray(fa._key_mask(iq, piece, bq, piece_k, kv_len,
+                                       causal))
+        if causal and not fa._piece_live(iq, piece, bq, piece_k):
+            dead += 1
+            assert not mask.any()
+        else:
+            assert mask.any()
+    g = fa.grid_steps("fwd", 1, s, s, bq, bk, causal)
+    assert g["live_pieces"] == (s // bq) * (s // bk) * pieces - dead
+
+
+@pytest.mark.parametrize("cell,bh,s,d,dv,want", [
+    ("cell2", 144, 1024, 64, None, dict(
+        block_q=1024, block_k=1024, piece_k=512, steps=144, live_steps=144,
+        fetched_steps=144, live_pieces=288)),
+    ("cell3", 32, 4096, 192, 128, dict(
+        block_q=1024, block_k=4096, piece_k=512, steps=128, live_steps=128,
+        fetched_steps=128, live_pieces=640)),
+])
+def test_grid_steps_of_the_forward_at_the_cells_shapes(cell, bh, s, d, dv,
+                                                       want):
+    """Cell 2 ran 576 steps a call (432 live), cell 3 2,048 (1,152 live):
+    144 and 128 now, none dead, the dead PIECES skipped inside a step."""
+    bq, bk = fa.fwd_tiles(s, s, d, jnp.bfloat16, False, dv)
+    g = fa.grid_steps("fwd", bh, s, s, bq, bk, True)
+    assert {k: g[k] for k in want} == want
+    old = fa.grid_steps("fwd", bh, s, s, 512, 512, True)
+    assert old["steps"] == {"cell2": 576, "cell3": 2048}[cell]
+    assert old["live_steps"] == old["live_pieces"] == {
+        "cell2": 432, "cell3": 1152}[cell]
+    # cell 1: not causal, one piece a step
+    g1 = fa.grid_steps("fwd", 128, 512, 512, 512, 512, False)
+    assert (g1["steps"], g1["live_steps"], g1["live_pieces"]) == (
+        128, 128, 128)
 
 
 # ---------------------------------------------------------------------------

@@ -645,9 +645,10 @@ def _flash_grid_events():
 
 def test_traced_train_step_records_one_flash_grid_instant_per_kernel_call(
         traced):
-    """Two causal attention layers at seq 2048: a (512, 512) forward and
-    the derived backward tiles, each on a grid of several blocks a side,
-    part of them above the diagonal."""
+    """Two causal attention layers at seq 2048: the derived backward
+    tiles on a grid of several blocks a side, part of them above the
+    diagonal, and a forward whose every step holds all 2048 keys and
+    walks them in four pieces, part of those above the diagonal."""
     tiny_gpt2_lowered_step(seq=2048)
     by_kernel = {}
     for e in _flash_grid_events():
@@ -659,11 +660,13 @@ def test_traced_train_step_records_one_flash_grid_instant_per_kernel_call(
         assert len(seen) == len(by_kernel["flash_attention_fwd"])
         assert len(seen) % 2 == 0
         for g in seen:
-            assert g["fetched_steps"] == g["live_steps"] < g["steps"], g
+            assert g["fetched_steps"] == g["live_steps"] <= g["steps"], g
+            assert (g["live_steps"] < g["steps"]) == ("bwd" in g["kernel"])
             assert 2048 % g["block_q"] == 0 and 2048 % g["block_k"] == 0
     fwd = by_kernel["flash_attention_fwd"][0]
-    assert (fwd["block_q"], fwd["block_k"]) == (512, 512)
-    assert fwd["live_steps"] * 16 == fwd["steps"] * 10
+    assert (fwd["block_q"], fwd["block_k"], fwd["piece_k"]) == (
+        1024, 2048, 512)
+    assert fwd["live_pieces"] * 8 == fwd["steps"] * 4 * 6
 
 
 def test_no_flash_grid_event_and_no_profiler_with_events_off(monkeypatch):

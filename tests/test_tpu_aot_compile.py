@@ -104,6 +104,76 @@ def test_flash_fwd_bwd_compiles_on_one_device(v5e_devices, chip_locations,
     assert _kernel_names(txt) == FLASH_NAMES
 
 
+# the forward alone at the blocks derived from the shapes
+# (flash_attention.fwd_tiles): (batch, heads, sq, sk, d, dv, dtype, causal,
+# dropout) -> (block_q, block_k). The benchmark's three cells, a length
+# whose keys do not fit one block, one walked in one piece; then float32
+# with dropout, not causal, over 2,048 keys and more, which Mosaic refused
+# while the pieces of a k block were unrolled inline (PR 32's review), and
+# the one causal case a sweep of 488 found refused after that
+FWD_COMPILE_CASES = {
+    "cell1": ((8, 16, 512, 512, 64, 64, "bfloat16", False, 0.1), (512, 512)),
+    "cell2": ((12, 12, 1024, 1024, 64, 64, "bfloat16", True, 0.0),
+              (1024, 1024)),
+    "cell3": ((1, 32, 4096, 4096, 192, 128, "bfloat16", True, 0.0),
+              (1024, 4096)),
+    "s8192": ((1, 4, 8192, 8192, 128, 128, "bfloat16", True, 0.0),
+              (1024, 4096)),
+    "s768": ((1, 4, 768, 768, 64, 64, "bfloat16", True, 0.0), (768, 768)),
+    "f32_dropout_2048_d128": (
+        (1, 4, 2048, 2048, 128, 128, "float32", False, 0.1), None),
+    "f32_dropout_4096_d128": (
+        (1, 4, 4096, 4096, 128, 128, "float32", False, 0.1), None),
+    "f32_dropout_1024x2048_d128": (
+        (1, 4, 1024, 2048, 128, 128, "float32", False, 0.1), None),
+    "f32_dropout_2048_d256": (
+        (1, 2, 2048, 2048, 256, 256, "float32", False, 0.1), None),
+    "f32_dropout_4096_d256": (
+        (1, 2, 4096, 4096, 256, 256, "float32", False, 0.1), None),
+    "bf16_dropout_4096_d256": (
+        (1, 2, 4096, 4096, 256, 256, "bfloat16", False, 0.1), None),
+    "f32_dropout_1024_d256_causal": (
+        (1, 2, 1024, 1024, 256, 256, "float32", True, 0.1), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FWD_COMPILE_CASES))
+def test_flash_forward_compiles_at_the_derived_blocks(
+        v5e_devices, chip_locations, case):
+    from flexflow_tpu.kernels.flash_attention import fwd_tiles
+    (b, h, sq, sk, d, dv, dtype, causal, rate), want = FWD_COMPILE_CASES[case]
+    dtype = jnp.dtype(dtype)
+    if want is not None:
+        assert fwd_tiles(sq, sk, d, dtype, rate > 0, dv) == want
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    sh = NamedSharding(mesh, P())
+    q = jax.ShapeDtypeStruct((b, h, sq, d), dtype, sharding=sh)
+    k = jax.ShapeDtypeStruct((b, h, sk, d), dtype, sharding=sh)
+    v = jax.ShapeDtypeStruct((b, h, sk, dv), dtype, sharding=sh)
+    txt = _compile_text(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, interpret=False, dropout_rate=rate,
+            dropout_seed=jnp.int32(3)), q, k, v)
+    assert _kernel_names(txt) == ["flash_attention_fwd"]
+    # the row statistics leave the kernel as they did: (bh, s, 128) f32
+    assert f"f32[{b * h},{sq},128]" in txt
+
+
+@pytest.mark.parametrize("shape", [(4, 12, 1024, 64), (2, 8, 4096, 128)])
+def test_flash_forward_under_shard_map_compiles_on_2x2(
+        v5e_devices, chip_locations, shape):
+    mesh = Mesh(np.array(v5e_devices).reshape(2, 2), ("x0", "x1"))
+    spec = P("x0", "x1")          # batch over x0, heads over x1
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                               sharding=NamedSharding(mesh, spec))
+    txt = _compile_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False, mesh=mesh,
+                                        spec=spec), qkv, qkv, qkv)
+    assert _kernel_names(txt) == ["flash_attention_fwd"]
+    assert "all-gather" not in txt    # operands stay where they are
+
+
 def test_flash_with_unequal_head_sizes_compiles_at_the_latent_shape(
         v5e_devices, chip_locations):
     """The three kernels at the shape of ``joyai_llm_flash.train.1chip``:

@@ -24,6 +24,14 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          It looks at no gradient and not at the MTP head's output, and
          neither does the benchmark's ``correct``: after a change to
          either op run ``examples/tpu_validate_latent_moe.py`` as well.
+  Leg D  gated short convolutions among grouped-query attention layers
+         with q/k norms, 4-of-64 routing with no shared expert
+         (``build_hybrid_conv_moe``): a small model, then one chip's
+         share of LFM2-24B-A2B at published widths, 1 x 8192 tokens a
+         chip, rematerialised as its benchmark cell is. It checks the
+         ``conv.short``, ``attn.qk_norm`` and ``moe.route`` instants and
+         looks at no gradient: ``examples/tpu_validate_hybrid_conv_moe.
+         py`` does.
 
 It claims no speed. The times it prints are set-up facts of one run.
 It exits non-zero, before building anything, unless JAX reports a TPU;
@@ -384,6 +392,74 @@ def leg_gpt2_fused_step(gpt_cfg, seq: int, per_chip_batch: int,
               f"optimizer kernel is not in the compiled step")
 
 
+def _lm_leg_setup(builder, model_cfg, seq: int, per_chip_batch: int,
+                  label: str, alpha: float):
+    """One fixed batch of ``seq`` tokens a chip from the seed and the
+    model ``builder`` makes of ``model_cfg``, compiled data parallel
+    with ``remat = "blocks"`` and no search. Returns ``(ff, x, y)``."""
+    import jax
+
+    from flexflow_tpu import AdamOptimizer, FFModel
+    from flexflow_tpu.obs import events
+    batch = per_chip_batch * len(jax.devices())
+    rng = np.random.default_rng(SEED)
+    ids = rng.integers(0, model_cfg.vocab_size,
+                       (batch, seq)).astype(np.int32)
+    x = [ids, np.tile(np.arange(seq, dtype=np.int32), (batch, 1))]
+    y = np.roll(ids, -1, axis=1)[..., None]
+    cfg = _config(batch)
+    cfg.search_budget = 0
+    cfg.only_data_parallel = True
+    cfg.remat = "blocks"
+    ff = FFModel(cfg)
+    out = builder(ff, batch, seq, model_cfg)
+    events.clear()
+    t0 = time.perf_counter()
+    ff.compile(AdamOptimizer(alpha=alpha),
+               "sparse_categorical_crossentropy", [], output_tensor=out)
+    n_params = sum(int(np.prod(w.shape)) for l in ff.params.values()
+                   for w in l.values())
+    say(f"{label}: compile {time.perf_counter() - t0:.1f}s, "
+        f"{len(ff.layers)} graph nodes, {n_params:,} parameters, mesh "
+        f"{dict(ff.dmesh.axis_sizes)}, rematerialised run "
+        f"{ff.executor._remat and ff.executor._remat[:3]}")
+    check(ff.executor._remat is not None,
+          f"{label}: remat = blocks found no repeated run")
+    return ff, x, y
+
+
+def _check_experts_counters(label: str) -> None:
+    from flexflow_tpu.obs import events
+    ctr = events.counters()
+    say(f"{label}: counters " + ", ".join(
+        f"{k} {ctr.get(k)}" for k in ("moe.local_assignments",
+                                      "moe.dropped", "moe.load_max",
+                                      "moe.load_mean")))
+    check(ctr.get("moe.dropped") == 0 and ctr.get(
+        "moe.local_assignments", 0) > 0,
+        f"{label}: the experts' counters read {ctr}")
+
+
+def _compiled_step_size(ff, x, y, label: str) -> int:
+    """Print the compiled train step's GiB a device (on a chip it has to
+    fit) and return its count of Mosaic calls."""
+    import jax
+    import jax.numpy as jnp
+    step = ff.executor.make_train_step()
+    batch0 = next(iter(ff._combined_loader(x, y, shuffle=False)))
+    compiled = step.lower(ff.params, ff.opt_state, ff.state, jnp.int32(0),
+                          batch0).compile()
+    ma = compiled.memory_analysis()
+    gib = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+           + ma.temp_size_in_bytes - ma.alias_size_in_bytes) / 2 ** 30
+    n_cc = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    say(f"{label}: compiled step {gib:.2f} GiB a device, {n_cc} "
+        f"tpu_custom_call(s), peak_bytes_in_use {_peak_bytes()}")
+    if jax.devices()[0].platform != "cpu":
+        check(gib <= 15.0, f"{label}: the step takes {gib:.2f} GiB")
+    return n_cc
+
+
 # ----------------------------------------------------------------------
 # Leg C — latent attention, routed experts, multi-token prediction
 # ----------------------------------------------------------------------
@@ -400,36 +476,12 @@ def leg_latent_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
     unwritten rows trained and passed): ``VALIDATION`` holds them, and
     the MTP head, to the reference, and this leg names it."""
     import jax
-    import jax.numpy as jnp
 
-    from flexflow_tpu import AdamOptimizer, FFModel
     from flexflow_tpu.models.nlp import build_latent_moe
     from flexflow_tpu.obs import events
     chip = jax.devices()[0].platform != "cpu"
-    batch = per_chip_batch * len(jax.devices())
-    rng = np.random.default_rng(SEED)
-    ids = rng.integers(0, model_cfg.vocab_size,
-                       (batch, seq)).astype(np.int32)
-    x = [ids, np.tile(np.arange(seq, dtype=np.int32), (batch, 1))]
-    y = np.roll(ids, -1, axis=1)[..., None]
-    cfg = _config(batch)
-    cfg.search_budget = 0
-    cfg.only_data_parallel = True
-    cfg.remat = "blocks"
-    ff = FFModel(cfg)
-    out = build_latent_moe(ff, batch, seq, model_cfg)
-    events.clear()
-    t0 = time.perf_counter()
-    ff.compile(AdamOptimizer(alpha=alpha),
-               "sparse_categorical_crossentropy", [], output_tensor=out)
-    n_params = sum(int(np.prod(w.shape)) for l in ff.params.values()
-                   for w in l.values())
-    say(f"{label}: compile {time.perf_counter() - t0:.1f}s, "
-        f"{len(ff.layers)} graph nodes, {n_params:,} parameters, mesh "
-        f"{dict(ff.dmesh.axis_sizes)}, rematerialised run "
-        f"{ff.executor._remat and ff.executor._remat[:3]}")
-    check(ff.executor._remat is not None,
-          f"{label}: remat = blocks found no repeated run of expert layers")
+    ff, x, y = _lm_leg_setup(build_latent_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
     _fit(ff, x, y, label)
     impls = ff.executor.resolved_attention_impls
     n_attn = model_cfg.num_hidden_layers + model_cfg.num_nextn_predict_layers
@@ -456,31 +508,66 @@ def leg_latent_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
     n_expert = n_attn - model_cfg.first_k_dense_replace
     check(len(routes) == n_expert, f"{label}: {len(routes)} expert layers "
                                    f"announced, the model has {n_expert}")
-    ctr = events.counters()
-    say(f"{label}: counters " + ", ".join(
-        f"{k} {ctr.get(k)}" for k in ("moe.local_assignments",
-                                      "moe.dropped", "moe.load_max",
-                                      "moe.load_mean")))
-    check(ctr.get("moe.dropped") == 0 and ctr.get(
-        "moe.local_assignments", 0) > 0,
-        f"{label}: the experts' counters read {ctr}")
+    _check_experts_counters(label)
     _check_flash_grids(label, want=chip)
     say(f"{label}: not checked here: gradients and the MTP head's "
         f"log-probabilities against the reference: python3 {VALIDATION}")
-    step = ff.executor.make_train_step()
-    batch0 = next(iter(ff._combined_loader(x, y, shuffle=False)))
-    compiled = step.lower(ff.params, ff.opt_state, ff.state, jnp.int32(0),
-                          batch0).compile()
-    ma = compiled.memory_analysis()
-    gib = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-           + ma.temp_size_in_bytes - ma.alias_size_in_bytes) / 2 ** 30
-    n_cc = compiled.as_text().count('custom_call_target="tpu_custom_call"')
-    say(f"{label}: compiled step {gib:.2f} GiB a device, {n_cc} "
-        f"tpu_custom_call(s), peak_bytes_in_use {_peak_bytes()}")
+    n_cc = _compiled_step_size(ff, x, y, label)
     if chip:
-        check(gib <= 15.0, f"{label}: the step takes {gib:.2f} GiB")
         check(n_cc >= 3 * n_attn, f"{label}: {n_cc} Mosaic calls for "
                                   f"{n_attn} attention layers")
+
+
+# ----------------------------------------------------------------------
+# Leg D — short convolutions, grouped-query attention, no shared expert
+# ----------------------------------------------------------------------
+VALIDATION_HYBRID = "examples/tpu_validate_hybrid_conv_moe.py"
+
+
+def leg_hybrid_conv_moe(model_cfg, seq: int, per_chip_batch: int,
+                        label: str, alpha: float = 1e-5) -> None:
+    """``build_hybrid_conv_moe`` through compile and fit with ``remat =
+    "blocks"``: the loss falls, every layer of ``layer_types`` announced
+    itself by its kind, the attention layers resolved to the flash
+    kernel (on a chip), nothing was dropped, and the step fits the chip.
+    ``VALIDATION_HYBRID`` holds the gradients to the reference, and this
+    leg names it."""
+    import jax
+
+    from flexflow_tpu.models.nlp import build_hybrid_conv_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    kinds = list(model_cfg.layer_types)
+    seen = {name: sorted({e["attrs"]["layer"] for e in events.events()
+                          if e["name"] == name})
+            for name in ("conv.short", "attn.qk_norm", "moe.route")}
+    say(f"{label}: instants " + "; ".join(
+        f"{n} {v}" for n, v in seen.items()))
+    check(seen["conv.short"] == [f"conv_{i}" for i, k in enumerate(kinds)
+                                 if k == "conv"]
+          and seen["attn.qk_norm"] == [f"attn_{i}" for i, k in
+                                       enumerate(kinds) if k != "conv"]
+          and seen["moe.route"] == [
+              f"experts_{i}" for i in range(model_cfg.num_dense_layers,
+                                            len(kinds))],
+          f"{label}: the layers that announced themselves are not "
+          f"layer_types {kinds}: {seen}")
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: resolved attention impls "
+        f"{sorted(set(impls.values()))} in {len(impls)} layers")
+    check(len(impls) == kinds.count("full_attention"),
+          f"{label}: {len(impls)} attention layers resolved")
+    if chip:
+        check(set(impls.values()) == {"flash"},
+              f"{label}: attention resolved to {impls} at seq {seq}")
+    _check_experts_counters(label)
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: gradients against the reference: "
+        f"python3 {VALIDATION_HYBRID}")
+    _compiled_step_size(ff, x, y, label)
 
 
 def _check_generate(ff, ids) -> None:
@@ -583,8 +670,9 @@ def main() -> int:
     t0 = time.perf_counter()
     from flexflow_tpu import MachineSpec, native
     from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
+                                         HybridConvMoEConfig,
                                          JoyAIFlashRankConfig,
-                                         LatentMoEConfig)
+                                         LatentMoEConfig, LFM2RankConfig)
     from flexflow_tpu.utils.compilation_cache import (
         cache_entries, enable_compilation_cache)
     cache = enable_compilation_cache()
@@ -605,6 +693,9 @@ def main() -> int:
         leg_latent_moe(LatentMoEConfig.tiny(), 1024, 1, "C/small",
                        alpha=1e-3)
         leg_latent_moe(JoyAIFlashRankConfig(), 4096, 1, "C/joyai")
+        leg_hybrid_conv_moe(HybridConvMoEConfig.tiny(), 1024, 1, "D/small",
+                            alpha=1e-3)
+        leg_hybrid_conv_moe(LFM2RankConfig(), 8192, 1, "D/lfm2")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

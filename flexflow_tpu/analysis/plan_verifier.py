@@ -379,6 +379,7 @@ def _check_op_shards(report, strategy, by_name, axis_sizes,
                 shape = layer.outputs[i].shape
             _check_spec(report, axis_sizes, name, f"output[{i}]", sp,
                         shape)
+            _check_conv_sequence(report, axis_sizes, layer, sp)
         for wname, sp in (getattr(os_, "weights", {}) or {}).items():
             if sp is None:
                 continue
@@ -389,6 +390,25 @@ def _check_op_shards(report, strategy, by_name, axis_sizes,
     for tname, sp in getattr(strategy, "inputs", {}).items():
         _check_spec(report, axis_sizes, tname, "input", sp,
                     in_shapes.get(tname))
+
+
+def _check_conv_sequence(report, axis_sizes, layer, spec) -> None:
+    """A gated short convolution reads the ``taps - 1`` positions before
+    each one: batch- and channel-sharded layouts are local, a
+    sequence-sharded one needs a halo exchange that no layer here emits
+    and no cost row prices (``search/opshard.py`` offers none)."""
+    from ..ffconst import OperatorType
+    if layer is None \
+            or layer.op_type != OperatorType.OP_GATED_SHORT_CONV:
+        return
+    entries = _spec_entries(spec)
+    if len(entries) > 1 and any(axis_sizes.get(a, 1) > 1
+                                for a in entries[1]):
+        report.add("op-shard", "error", layer.name,
+                   f"output spec {spec} shards the sequence of a short "
+                   f"convolution of {layer.params['taps']} taps: each "
+                   f"shard needs a halo of {layer.params['taps'] - 1} "
+                   f"positions from its neighbour, which is not built")
 
 
 # -- check 2: layout seams --------------------------------------------------

@@ -223,6 +223,9 @@ class OperatorType(enum.IntEnum):
     OP_LATENT_ATTENTION = enum.auto()
     OP_ROUTED_EXPERTS = enum.auto()
     OP_NEXT_TOKEN_LOSS = enum.auto()
+    # a gated short convolution: the sequence-mixing layer of hybrid
+    # convolution/attention decoders (no attention, no recurrence)
+    OP_GATED_SHORT_CONV = enum.auto()
 
 
 # Ops that are pure elementwise-unary (single input, same shape out).

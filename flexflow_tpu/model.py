@@ -213,6 +213,9 @@ class FFModel:
                             num_kv_heads: int = 0,
                             sliding_window: int = 0,
                             kernel_initializer=None,
+                            qk_norm: bool = False,
+                            qk_norm_eps: float = 1e-6,
+                            positions: Optional[Tensor] = None,
                             name: Optional[str] = None) -> Tensor:
         params = {"embed_dim": embed_dim, "num_heads": num_heads,
                   "kdim": kdim, "vdim": vdim, "dropout": dropout,
@@ -242,8 +245,31 @@ class FFModel:
             # flash-attention and KV-decode paths for RoPE models)
             params["rope"] = True
             params["rope_theta"] = float(rope_theta)
+        if qk_norm:
+            # an RMSNorm over each query and key head's entries, one
+            # learned scale a projection, before the rotary embedding
+            params["qk_norm"] = True
+            params["qk_norm_eps"] = float(qk_norm_eps)
+        inputs = [query, key, value]
+        if positions is not None:
+            # (batch, seq) int32: what the rotary embedding turns by
+            # (without it: 0 .. seq - 1)
+            if not rope:
+                raise ValueError("positions are read by rope=True only")
+            inputs.append(positions)
         return self._add_layer(OperatorType.OP_MULTIHEAD_ATTENTION,
-                               [query, key, value], params, name).outputs[0]
+                               inputs, params, name).outputs[0]
+
+    def gated_short_conv(self, input: Tensor, taps: int,
+                         name: Optional[str] = None) -> Tensor:
+        """A gated short convolution (``ops.nn_ops.GatedShortConvOp``):
+        ``[B ; C ; x] = u w_in``, a causal depthwise convolution of
+        ``taps`` positions over ``B * x``, ``y = (C * conv) w_out``, all
+        at the input's width."""
+        if taps < 1:
+            raise ValueError(f"a convolution of {taps} taps")
+        return self._unary(OperatorType.OP_GATED_SHORT_CONV, input, name,
+                           taps=int(taps))
 
     def latent_attention(self, input: Tensor, positions: Tensor,
                          num_heads: int, q_rank: int,

@@ -811,3 +811,157 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
                        name="mtp_loss")
     return ff.softmax(ff.slice_tensor(logits, [0], [s], [1],
                                       name="lm_logits"))
+
+
+def _lfm2_layer_types():
+    """LFM2-24B-A2B's 40 operators: conv, conv, then [full_attention,
+    conv, conv, conv] ten times less the last two (30 conv, 10
+    attention)."""
+    return (["conv", "conv"]
+            + ["full_attention", "conv", "conv", "conv"] * 10)[:40]
+
+
+@dataclasses.dataclass
+class HybridConvMoEConfig:
+    """Hybrid convolution/attention decoder with sparse experts
+    (``model_type: lfm2_moe``): every layer is an operator, a gated short
+    convolution or grouped-query attention as ``layer_types`` says, and a
+    feed-forward, SwiGLU in the first ``num_dense_layers`` and then
+    sigmoid-routed experts with no shared one. The fields carry the names
+    of the published ``config.json`` keys; the defaults are
+    LFM2-24B-A2B's.
+
+    ``num_experts`` counts the experts whose weights are HELD here,
+    ``first_held_expert`` onwards; the router, the top-k and the gates'
+    normalisation run over ``num_experts_published`` (None: the same),
+    as in :class:`LatentMoEConfig`."""
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    num_hidden_layers: int = 40
+    layer_types: list = dataclasses.field(default_factory=_lfm2_layer_types)
+    num_dense_layers: int = 2
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    head_dim: int | None = None          # not published: hidden / heads
+    rope_parameters: dict = dataclasses.field(
+        default_factory=lambda: {"rope_theta": 1000000.0,
+                                 "rope_type": "default"})
+    norm_eps: float = 1e-5
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    num_experts: int = 64
+    num_experts_published: int | None = None
+    first_held_expert: int = 0
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    # not in config.json: the spread of the routers' bias, which is
+    # drawn once and never trained
+    router_bias_std: float = 0.02
+
+    @classmethod
+    def tiny(cls):
+        """5 layers laid out as the benchmark's cut (a dense conv layer,
+        then one period of expert layers), 4 heads on 2 kv heads of 16,
+        16 experts top-4: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_hidden_layers=5,
+                   layer_types=["conv", "full_attention", "conv", "conv",
+                                "conv"],
+                   num_dense_layers=1, num_attention_heads=4,
+                   num_key_value_heads=2,
+                   rope_parameters={"rope_theta": 10000.0,
+                                    "rope_type": "default"},
+                   intermediate_size=160, moe_intermediate_size=32,
+                   num_experts=16, num_experts_per_tok=4,
+                   router_bias_std=0.05)
+
+
+@dataclasses.dataclass
+class LFM2RankConfig(HybridConvMoEConfig):
+    """What ONE chip holds of LFM2-24B-A2B where 8 chips share each layer
+    (the benchmark's ``lfm2_24b_a2b``): experts 0 to 7 of the 64, one of
+    eight slices of the vocabulary, and published layer 0 with layers 2
+    to 5 (one dense layer, then one whole period of expert layers; the
+    rest lie on further chips as pipeline stages); every width as
+    published."""
+    vocab_size: int = 8192
+    num_hidden_layers: int = 5
+    layer_types: list = dataclasses.field(
+        default_factory=lambda: ["conv", "full_attention", "conv", "conv",
+                                 "conv"])
+    num_dense_layers: int = 1
+    head_dim: int | None = 64            # assumed: hidden / heads
+    num_experts: int = 8
+    num_experts_published: int | None = 64
+
+
+def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
+                          cfg: HybridConvMoEConfig | None = None):
+    """Causal LM of :class:`HybridConvMoEConfig`: inputs ``[ids, pos]``,
+    output the softmax over the head, as :func:`build_latent_moe`. The
+    layers are laid out from ``layer_types`` and ``num_dense_layers``:
+    ``h += Op(norm(h))`` then ``h += FF(norm(h))``. ``pos`` is what the
+    attention layers' rotary embedding turns by (a layout with no
+    attention layer takes ``ids`` alone).
+
+    The published model ties the head to the embedding; this graph has
+    no weight read by two layers, so the head is its own matrix (as
+    :func:`build_gpt2`'s)."""
+    cfg = cfg or HybridConvMoEConfig()
+    kinds = list(cfg.layer_types)
+    if len(kinds) != cfg.num_hidden_layers \
+            or set(kinds) - {"conv", "full_attention"}:
+        raise ValueError(
+            f"layer_types must name {cfg.num_hidden_layers} layers, each "
+            f"'conv' or 'full_attention'; got {len(kinds)}: "
+            f"{sorted(set(kinds))}")
+    if cfg.conv_bias or not cfg.norm_topk_prob:
+        raise ValueError("conv_bias and gates that are not normalised "
+                         "over the chosen experts are not built")
+    b, s, hid = batch_size, seq_len, cfg.hidden_size
+    heads = cfg.num_attention_heads
+    head_dim = cfg.head_dim or hid // heads
+    published = cfg.num_experts_published or cfg.num_experts
+    ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
+    pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids")
+    h = ff.embedding(ids, cfg.vocab_size, hid, name="embed_tokens")
+
+    def norm(x, name):
+        return ff.rms_norm(x, eps=cfg.norm_eps, name=name)
+
+    for i, kind in enumerate(kinds):
+        x = norm(h, f"operator_norm_{i}")
+        if kind == "conv":
+            op = ff.gated_short_conv(x, cfg.conv_L_cache, name=f"conv_{i}")
+        else:
+            op = ff.multihead_attention(
+                x, x, x, hid, heads, kdim=heads * head_dim,
+                vdim=heads * head_dim, bias=False, causal=True, rope=True,
+                rope_theta=cfg.rope_parameters["rope_theta"],
+                num_kv_heads=cfg.num_key_value_heads, qk_norm=True,
+                qk_norm_eps=cfg.norm_eps, positions=pos, name=f"attn_{i}")
+        h = ff.add(h, op, name=f"operator_res_{i}")
+        x = norm(h, f"ffn_norm_{i}")
+        if i < cfg.num_dense_layers:
+            gate = ff.dense(x, cfg.intermediate_size, use_bias=False,
+                            name=f"gate_proj_{i}")
+            up = ff.dense(x, cfg.intermediate_size, use_bias=False,
+                          name=f"up_proj_{i}")
+            silu = ff.multiply(gate, ff.sigmoid(gate), name=f"silu_{i}")
+            y = ff.dense(ff.multiply(silu, up), hid, use_bias=False,
+                         name=f"down_proj_{i}")
+        else:
+            y = ff.routed_experts(
+                x, published, cfg.num_experts_per_tok,
+                cfg.moe_intermediate_size, shared_dim=0,
+                experts_held=cfg.num_experts,
+                first_held=cfg.first_held_expert,
+                scale=cfg.routed_scaling_factor,
+                bias_std=cfg.router_bias_std if cfg.use_expert_bias
+                else 0.0, name=f"experts_{i}")
+        h = ff.add(h, y, name=f"ffn_res_{i}")
+    return ff.softmax(ff.dense(norm(h, "final_norm"), cfg.vocab_size,
+                               use_bias=False, name="lm_head"))

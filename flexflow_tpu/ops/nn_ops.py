@@ -19,6 +19,7 @@ from ..ffconst import (ActiMode, AggrMode, DataType, InitializerType,
                        OperatorType, PoolType)
 from ..core.tensor import WeightSpec
 from ..dtypes import to_jnp
+from ..obs import events
 from .registry import (EmitCtx, OpDef, bf16_enabled, compute_dtype,
                        matmul, register)
 
@@ -376,6 +377,70 @@ class EmbeddingOp(OpDef):
 
 
 # ---------------------------------------------------------------------------
+def short_conv(z, taps):
+    """Causal depthwise convolution along the sequence:
+    ``c[:, t] = sum_j taps[:, j] * z[:, t - (K - 1) + j]`` with zeros left
+    of position 0. ``z``: (B, L, C); ``taps``: (C, K). K shifted
+    multiply-adds that XLA fuses into one pass over ``z``."""
+    k, length = taps.shape[1], z.shape[1]
+    zp = jnp.pad(z, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(zp[:, j:j + length] * taps[:, j] for j in range(k))
+
+
+@register
+class GatedShortConvOp(OpDef):
+    """The gated short convolution of hybrid convolution/attention
+    decoders: the operator that mixes positions without attention and
+    without a recurrence.
+
+      [B ; C ; x] = u w_in          (E -> 3 x E)
+      c = short_conv(B * x, taps)   depthwise over E, causal, K taps
+      y = (C * c) w_out             (E -> E)
+
+    Both projections are matrix products at the compute dtype with
+    float32 accumulation; the gates and the taps run in float32 on the
+    vector unit. No bias anywhere. Its backward is autodiff's."""
+    op_type = OperatorType.OP_GATED_SHORT_CONV
+
+    def infer(self, params, in_shapes, in_dtypes):
+        return [(in_shapes[0], in_dtypes[0])]
+
+    def weights(self, params, in_shapes, in_dtypes):
+        e, k, dt = in_shapes[0][-1], params["taps"], in_dtypes[0]
+        return [WeightSpec("w_in", (e, 3, e), dt,
+                           init_args={"fans": (e, 3 * e)}),
+                # one filter a channel: K taps in, K positions reached
+                WeightSpec("taps", (e, k), dt, init_args={"fans": (k, k)}),
+                WeightSpec("w_out", (e, e), dt)]
+
+    def emit(self, params, inputs, weights, ctx, name):
+        (u,) = inputs
+        mdt = compute_dtype(ctx, u.dtype)
+        if events.enabled():
+            events.instant("conv.short", layer=name,
+                           channels=weights["taps"].shape[0],
+                           taps=weights["taps"].shape[1],
+                           tokens=u.shape[0] * u.shape[1])
+        bcx = jnp.einsum("ble,egc->blgc", u.astype(mdt),
+                         weights["w_in"].astype(mdt),
+                         preferred_element_type=jnp.float32)
+        gate_b, gate_c, x = bcx[:, :, 0], bcx[:, :, 1], bcx[:, :, 2]
+        c = short_conv(gate_b * x, weights["taps"].astype(jnp.float32))
+        y = jnp.einsum("blc,ce->ble", (gate_c * c).astype(mdt),
+                       weights["w_out"].astype(mdt),
+                       preferred_element_type=jnp.float32)
+        return [y.astype(u.dtype)]
+
+    def flops(self, params, in_shapes, out_shapes):
+        tokens = float(np.prod(in_shapes[0][:-1]))
+        e, k = in_shapes[0][-1], params["taps"]
+        return tokens * (2.0 * e * 3 * e + 2.0 * e * e + (2 * k + 2) * e)
+
+    def backward_flops_factor(self):
+        return 2.0
+
+
+# ---------------------------------------------------------------------------
 def _apply_rope(x, pos, theta: float):
     """Rotary position embedding, LLaMA half-split-rotate convention.
     ``x``: (B, L, h, d) with d even; ``pos``: (L,) absolute indices
@@ -430,6 +495,12 @@ class MultiHeadAttentionOp(OpDef):
                    WeightSpec("bv", (kvh, vdim // h), dt,
                               InitializerType.ZERO),
                    WeightSpec("bo", (e,), dt, InitializerType.ZERO)]
+        if params.get("qk_norm", False):
+            # one learned scale a projection, shared by its heads
+            ws += [WeightSpec("q_norm", (kdim // h,), dt,
+                              InitializerType.ONE),
+                   WeightSpec("k_norm", (kdim // h,), dt,
+                              InitializerType.ONE)]
         return ws
 
     @staticmethod
@@ -536,7 +607,9 @@ class MultiHeadAttentionOp(OpDef):
         return mesh, P(entry(out, 0, batch), entry(wq, 1, heads))
 
     def emit(self, params, inputs, weights, ctx, name):
-        q, k, v = inputs
+        # an optional fourth input: (B, L) int32 positions that the
+        # rotary embedding turns by (default: 0 .. L - 1)
+        q, k, v, *positions = inputs
         cdt = q.dtype
         h = params["num_heads"]
 
@@ -553,6 +626,17 @@ class MultiHeadAttentionOp(OpDef):
         qh = proj(q, weights["wq"], weights.get("bq"))
         kh = proj(k, weights["wk"], weights.get("bk"))
         vh = proj(v, weights["wv"], weights.get("bv"))
+        if params.get("qk_norm", False):
+            # RMSNorm over each head's own entries, before the rotary
+            # embedding; ahead of the decode branch, so the cache holds
+            # normed (and rotated) keys
+            eps = params.get("qk_norm_eps", 1e-6)
+            qh = _rms(qh, weights["q_norm"], eps)
+            kh = _rms(kh, weights["k_norm"], eps)
+            if events.enabled():
+                events.instant("attn.qk_norm", layer=name, heads=h,
+                               kv_heads=kh.shape[2], head_dim=qh.shape[-1],
+                               tokens=qh.shape[0] * qh.shape[1])
         rate = params.get("dropout", 0.0) if ctx.training else 0.0
 
         causal = params.get("causal", False)
@@ -575,6 +659,8 @@ class MultiHeadAttentionOp(OpDef):
                 kvi = jnp.asarray(ctx.kv_index)
                 # scalar index -> (1,); per-row (ragged prompts) -> (B,1)
                 pos = kvi[:, None] if kvi.ndim else kvi[None]
+            elif positions:
+                pos = positions[0]
             else:
                 pos = jnp.arange(qh.shape[1], dtype=jnp.int32)
             qh = _apply_rope(qh, pos, theta)

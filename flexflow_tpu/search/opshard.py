@@ -69,6 +69,14 @@ def options_for(layer: Layer) -> List[ShardOption]:
         opts.append(ShardOption("parameter", -1,
                                 (("wq", 1), ("wk", 1), ("wv", 1), ("wo", 0),
                                  ("bq", 0), ("bk", 0), ("bv", 0))))
+    elif t == OperatorType.OP_GATED_SHORT_CONV:
+        sample()
+        # channel-parallel: w_in's and the taps' channel dim, w_out's
+        # input dim; the output stays whole on hidden (all-reduce after
+        # w_out). The sequence dim is NOT offered: a shard would need a
+        # halo of taps - 1 positions (the plan verifier refuses it)
+        opts.append(ShardOption("parameter", -1,
+                                (("w_in", 2), ("taps", 0), ("w_out", 0))))
     elif t == OperatorType.OP_LAYERNORM or t == OperatorType.OP_RMSNORM:
         sample()
         if r >= 3:

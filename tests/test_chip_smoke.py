@@ -16,7 +16,7 @@ import pytest
 
 import chip_smoke
 from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
-                                     LatentMoEConfig)
+                                     HybridConvMoEConfig, LatentMoEConfig)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -78,6 +78,26 @@ def test_leg_c_latent_moe_tiny_on_the_cpu_mesh(capsys):
     assert f"python3 {chip_smoke.VALIDATION}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION))
+
+
+def test_leg_d_hybrid_conv_moe_tiny_on_the_cpu_mesh(capsys):
+    """A share of 4 of 16 experts on the 8-device mesh: the layers
+    announce themselves by kind in ``layer_types``' order, the run of
+    [expert feed-forward, next convolution] blocks is rematerialised."""
+    cfg = dataclasses.replace(HybridConvMoEConfig.tiny(), num_experts=4,
+                              num_experts_published=16)
+    chip_smoke.leg_hybrid_conv_moe(cfg, seq=16, per_chip_batch=1,
+                                   label="D/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "rematerialised run (15, 6, 3)" in out
+    assert ("conv.short ['conv_0', 'conv_2', 'conv_3', 'conv_4']; "
+            "attn.qk_norm ['attn_1']; moe.route ['experts_1', 'experts_2',"
+            " 'experts_3', 'experts_4']") in out
+    assert "resolved attention impls ['xla'] in 1 layers" in out  # cpu
+    assert "moe.dropped 0.0" in out
+    assert f"python3 {chip_smoke.VALIDATION_HYBRID}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_HYBRID))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

@@ -170,22 +170,38 @@ def _layer_sizes(mc, held, first):
                 first_held_expert=first)
 
 
-def test_the_shares_add_up_to_the_uncut_layer(tiny):
+@pytest.mark.parametrize("shared", [True, False])
+def test_the_shares_add_up_to_the_uncut_layer(tiny, shared):
     """model-configs guide, section 4: the routed parts that the four
     shares of four experts each compute, plus the shared expert counted
-    once, are the uncut reference's layer output."""
+    once, are the uncut reference's layer output. Without a shared
+    expert (the hybrid convolution/attention configuration's layer, held
+    to ITS reference's ``routed``) the shares alone add up to it."""
     ff, mc, _ = tiny
     w = ff.params["experts_2"]
     x = jax.random.normal(jax.random.key(5), (B, S, mc.hidden_size))
     routed = sum(_experts_layer(mc, x, w, first, 4, with_shared=False)[0]
                  for first in (0, 4, 8, 12))
-    whole, _ = _experts_layer(mc, x, w, 0, 4)
-    shared_once = whole - _experts_layer(mc, x, w, 0, 4,
-                                         with_shared=False)[0]
+    if shared:
+        whole, _ = _experts_layer(mc, x, w, 0, 4)
+        once = whole - _experts_layer(mc, x, w, 0, 4, with_shared=False)[0]
+        sizes, plain = _layer_sizes, ref
+    else:
+        once = 0.0
+        plain = cells.load_module(os.path.join(ROOT, "benchmarks"),
+                                  "reference", "hybrid_conv_moe_ref")
+        w = {k: v for k, v in w.items() if not k.startswith("ws_")}
+
+        def sizes(mc, held, first):
+            return {"num_experts_per_tok": mc.num_experts_per_tok,
+                    "routed_scaling_factor": mc.routed_scaling_factor,
+                    "first_held_expert": first}
     with jax.default_matmul_precision("highest"):
-        want = ref.routed(x, w, _layer_sizes(mc, 16, 0)) + ref.shared(x, w)
-        one_share = ref.routed(x, _share(w, 4, 4), _layer_sizes(mc, 4, 4))
-    close(routed + shared_once, want)
+        want = plain.routed(x, w, sizes(mc, 16, 0))
+        if shared:
+            want = want + ref.shared(x, w)
+        one_share = plain.routed(x, _share(w, 4, 4), sizes(mc, 4, 4))
+    close(routed + once, want)
     # and one share alone is the reference's same share, not the whole
     close(_experts_layer(mc, x, w, 4, 4, with_shared=False)[0], one_share)
     assert float(jnp.max(jnp.abs(one_share - want))) > 0.1

@@ -429,15 +429,28 @@ def _lm_leg_setup(builder, model_cfg, seq: int, per_chip_batch: int,
 
 
 def _check_experts_counters(label: str) -> None:
+    """The ``moe.*`` counters over the leg's steps beside the layers'
+    row budgets (the ``moe.route`` instants): nothing dropped, no step
+    of any layer past its budget, and so on average (the counters are
+    sums over layers and steps) a layer inside it."""
     from flexflow_tpu.obs import events
     ctr = events.counters()
+    routes = {e["attrs"]["layer"]: e["attrs"] for e in events.events()
+              if e["name"] == "moe.route"}
+    budgets = sorted({r["rows_budget"] for r in routes.values()})
+    steps = 1 + TRAIN_STEPS
+    mean = ctr.get("moe.local_assignments", 0) / max(
+        1, len(routes) * steps)
     say(f"{label}: counters " + ", ".join(
-        f"{k} {ctr.get(k)}" for k in ("moe.local_assignments",
-                                      "moe.dropped", "moe.load_max",
-                                      "moe.load_mean")))
-    check(ctr.get("moe.dropped") == 0 and ctr.get(
-        "moe.local_assignments", 0) > 0,
-        f"{label}: the experts' counters read {ctr}")
+        f"{k} {ctr.get(k)}" for k in (
+            "moe.local_assignments", "moe.dropped", "moe.overflow",
+            "moe.load_max", "moe.load_mean"))
+        + f"; rows_budget {budgets} a layer, {mean:.0f} local assignments "
+        f"a layer a step over {steps} steps")
+    check(ctr.get("moe.dropped") == 0 and ctr.get("moe.overflow") == 0
+          and 0 < mean <= min(budgets),
+          f"{label}: the experts' counters read {ctr} against row "
+          f"budgets {budgets}")
 
 
 def _compiled_step_size(ff, x, y, label: str) -> int:
@@ -479,6 +492,7 @@ def leg_latent_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
 
     from flexflow_tpu.models.nlp import build_latent_moe
     from flexflow_tpu.obs import events
+    from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
     chip = jax.devices()[0].platform != "cpu"
     ff, x, y = _lm_leg_setup(build_latent_moe, model_cfg, seq,
                              per_chip_batch, label, alpha)
@@ -498,12 +512,15 @@ def leg_latent_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
         say(f"{label}: moe.route {name}: {r['experts_held']} of "
             f"{r['experts_published']} experts held from {r['first_held']}"
             f", top {r['top_k']}, {r['tokens']} tokens, "
-            f"{r['rows_multiplied']} rows in the sorted buffer")
+            f"{r['rows_budget']} rows of the "
+            f"{r['tokens'] * r['top_k']} sorted handed to the products")
         check(r["experts_published"] == (
             model_cfg.n_routed_experts_published
             or model_cfg.n_routed_experts)
             and r["experts_held"] == model_cfg.n_routed_experts
-            and r["rows_multiplied"] == r["tokens"] * r["top_k"],
+            and r["rows_multiplied"] == r["rows_budget"]
+            == RoutedExpertsOp.rows_multiplied(
+                r["tokens"], dict(r, num_experts=r["experts_published"])),
             f"{label}: {name} routes as {r}")
     n_expert = n_attn - model_cfg.first_k_dense_replace
     check(len(routes) == n_expert, f"{label}: {len(routes)} expert layers "

@@ -30,7 +30,13 @@ reference ``benchmarks/reference/hybrid_conv_moe_ref.py`` (float32,
      convolution's taps and ``w_in``, one held expert's weights, a
      router's and the two q/k norm weights, against ``jax.grad`` of the
      reference's loss, each held to twice what the reference itself
-     reads with bf16 operands. ``correct`` sees no gradient.
+     reads with bf16 operands; each expert layer's row budget beside
+     what its router sent this share. ``correct`` sees no gradient;
+  5. the same with the overflow forced (2 added to the held experts'
+     bias, in program and reference alike: every choice of every token
+     is theirs, four times the budget's rows): every layer runs the
+     further chunks, drops nothing, and the gradients are still the
+     reference's.
 """
 import argparse
 import json
@@ -48,7 +54,8 @@ from benchmarks.harness import cells  # noqa: E402
 # the other configuration's validation has the helpers: PASS/FAIL lines,
 # the runner's measure, the model through the normal path, its batch
 from examples.tpu_validate_latent_moe import (  # noqa: E402
-    BENCH, FAILED, READINGS, batch_of, build, check, named, rel)
+    BENCH, FAILED, READINGS, batch_of, build, check, compare_gradients,
+    named, rel)
 from flexflow_tpu.kernels import flash_attention, mha_reference  # noqa: E402
 
 ROUNDED = (("bf16, routers float32", dict(matmul=jnp.bfloat16)),
@@ -136,8 +143,6 @@ def gradient_checks(conf, ref, seed, seq=2048):
     ff = build(conf, seq, "blocks")
     ff.params, ff.state = ff.executor.init_params_and_state(
         jax.random.key(seed))
-    batch = batch_of(conf, seq, seed)
-    sizes = dict(conf)
     picked = (("conv_2", "taps"), ("conv_2", "w_in"), ("experts_3", "wg"),
               ("experts_3", "w_gate"), ("attn_1", "q_norm"),
               ("attn_1", "k_norm"))
@@ -147,57 +152,8 @@ def gradient_checks(conf, ref, seed, seq=2048):
         out["experts_3.w_gate"] = out["experts_3.w_gate"][3]   # one expert
         return out
 
-    @jax.jit
-    def program(params):
-        def loss(p):
-            ex = ff.executor
-            outs, _, aux, capture = ex._forward(
-                p, ff.state, batch, True, jnp.int32(0))
-            return ex._loss_and_metrics(outs, capture, batch["label"],
-                                        aux)[0]
-        value, grads = jax.value_and_grad(loss)(params)
-        return value, pick(grads)
-
-    def reference_grads(params):
-        value, grads = jax.value_and_grad(lambda p: ref.loss(
-            named(ff, p), sizes, batch["input_ids"], batch["position_ids"],
-            batch["label"][..., 0]))(params)
-        return value, pick(grads)
-
-    @jax.jit
-    def rounded(params):
-        with ref.rounded_operands(matmul=jnp.bfloat16):
-            return reference_grads(params)
-
-    (lp, gp) = program(ff.params)
-    (lr, gr) = jax.jit(reference_grads)(ff.params)
-    lb, gb = rounded(ff.params)
-    e = abs(float(lp) - float(lr)) / float(lr)
-    eb = abs(float(lb) - float(lr)) / float(lr)
-    READINGS["loss"] = {"program": float(lp), "reference": float(lr),
-                        "reference, bf16 operands": float(lb)}
-    # The yardstick is the reference itself with every product's
-    # operands rounded to bf16 (routers float32): the same mathematics
-    # at the precision the configuration states. An expert choice that
-    # flips under that rounding moves a token's whole contribution, so
-    # an expert's and a router's readings are far above a dense
-    # model's. What this catches is what the reference's forward
-    # cannot: a backward that is wrong by orders of magnitude
-    # (PERF.md section 6, PR 29: rows a grouped product leaves
-    # unwritten read 1e5 here).
-    check("loss", e <= 2 * eb + 1e-4,
-          f"{float(lp):.6f} against {float(lr):.6f}: rel {e:.3e}; the "
-          f"reference with bf16 operands reads {eb:.3e}")
-    for name in gp:
-        e, eb = float(rel(gp[name], gr[name])), float(rel(gb[name],
-                                                          gr[name]))
-        own = float(rel(gp[name], gb[name]))
-        READINGS[f"grad {name}"] = {"program": e,
-                                    "reference, bf16 operands": eb,
-                                    "program against that": own}
-        check(f"gradient {name}", e <= 2 * eb + 1e-3,
-              f"rel {e:.3e}; the reference with bf16 operands reads "
-              f"{eb:.3e}, and the program against THAT {own:.3e}")
+    compare_gradients(ff, ref, dict(conf), batch_of(conf, seq, seed), seq,
+                      pick, "loss")
 
 
 def main():

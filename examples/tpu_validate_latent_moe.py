@@ -31,7 +31,14 @@ reference ``benchmarks/reference/latent_moe_ref.py`` (float32,
      s x s probabilities): the loss and its gradient for one expert's
      weights, a router's and a latent projection's, against ``jax.grad``
      of the reference's loss, each held to twice what the reference
-     itself reads with bf16 operands.
+     itself reads with bf16 operands; each expert layer's row budget
+     beside what its router sent this share, with nothing dropped and no
+     second chunk run;
+  5. the same with the overflow forced (2 added to the held experts'
+     correction bias, in program and reference alike: every choice of
+     every token is theirs, eight times the budget's rows): every layer
+     runs the further chunks, drops nothing, and the gradients are still
+     the reference's.
 """
 import argparse
 import dataclasses
@@ -236,19 +243,64 @@ def forward_checks(conf, ref, seq, seeds):
     del ff
 
 
-def gradient_checks(conf, ref, seed, seq=1024):
-    ff = build(conf, seq, "blocks")
-    ff.params, ff.state = ff.executor.init_params_and_state(
-        jax.random.key(seed))
-    batch = batch_of(conf, seq, seed)
-    sizes = dict(conf)
-    picked = (("attn_4", "wq_a"), ("experts_4", "wg"),
-              ("experts_4", "w_gate"))
+def force_overflow(ff, params):
+    """2 added to the correction bias of the experts held here, in every
+    expert layer: sigmoid scores lie in (0, 1), so every choice of
+    every token is one of theirs, whatever the budget."""
+    out = dict(params)
+    for layer in expert_layers(ff):
+        first, held = layer.params["first_held"], layer.params["experts_held"]
+        w = params[layer.name]
+        out[layer.name] = dict(
+            w, bias=w["bias"].at[first:first + held].add(2.0))
+    return out
 
-    def pick(grads):
-        out = {f"{n}.{w}": grads[n][w] for n, w in picked}
-        out["experts_4.w_gate"] = out["experts_4.w_gate"][3]   # one expert
-        return out
+
+def expert_layers(ff):
+    from flexflow_tpu.ffconst import OperatorType
+    return [l for l in ff.executor.program.layers
+            if l.op_type == OperatorType.OP_ROUTED_EXPERTS]
+
+
+def check_budget(ff, seq, counters, forced):
+    """The step's ``moe.*`` counters (sums over the expert layers)
+    against the layers' row budgets: on the budget's path no layer ran
+    a second chunk; forced, every layer did. Nothing dropped either
+    way."""
+    from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
+    layers = expert_layers(ff)
+    budgets = [RoutedExpertsOp.rows_multiplied(seq, l.params)
+               for l in layers]
+    rows = [seq * l.params["top_k"] for l in layers]
+    c = {k: float(v) for k, v in counters.items()}
+    label = "overflow forced" if forced else "as routed"
+    READINGS[f"counters, {label}"] = dict(c, rows_budget=budgets)
+    print(f"{label}: {len(layers)} expert layers, rows_budget {budgets} "
+          f"of {rows} sorted rows; " + ", ".join(
+              f"{k} {v:.0f}" for k, v in sorted(c.items())), flush=True)
+    check(f"{label}: nothing dropped", c["moe.dropped"] == 0,
+          f"moe.dropped {c['moe.dropped']:.0f}")
+    if forced:
+        check("overflow forced: every layer ran the further chunks",
+              c["moe.overflow"] == len(layers)
+              and c["moe.local_assignments"] == sum(rows)
+              and all(b < r for b, r in zip(budgets, rows)),
+              f"moe.overflow {c['moe.overflow']:.0f} of {len(layers)}, "
+              f"moe.local_assignments {c['moe.local_assignments']:.0f} "
+              f"of {sum(rows)}")
+    else:
+        check("as routed: every layer inside its budget",
+              c["moe.overflow"] == 0
+              and c["moe.local_assignments"] <= sum(budgets),
+              f"moe.overflow {c['moe.overflow']:.0f}, "
+              f"moe.local_assignments {c['moe.local_assignments']:.0f} "
+              f"against budgets of {sum(budgets)} in all")
+
+
+def program_grads(ff, batch, pick):
+    """jitted ``params -> (loss, picked gradients, the moe.* counters)``
+    of the program's training loss."""
+    from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
 
     @jax.jit
     def program(params):
@@ -256,10 +308,23 @@ def gradient_checks(conf, ref, seed, seq=1024):
             ex = ff.executor
             outs, _, aux, capture = ex._forward(
                 p, ff.state, batch, True, jnp.int32(0))
-            return ex._loss_and_metrics(outs, capture, batch["label"],
-                                        aux)[0]
-        value, grads = jax.value_and_grad(loss)(params)
-        return value, pick(grads)
+            value, bm = ex._loss_and_metrics(outs, capture, batch["label"],
+                                             aux)
+            return value, {k[len(COUNTER_PREFIX):]: v for k, v in bm.items()
+                           if k.startswith(COUNTER_PREFIX + "moe.")}
+        (value, counters), grads = jax.value_and_grad(
+            loss, has_aux=True)(params)
+        return value, pick(grads), counters
+    return program
+
+
+def compare_gradients(ff, ref, sizes, batch, seq, pick, loss_label):
+    """The program's training loss and its ``pick``ed gradients against
+    ``jax.grad`` of the reference's loss, as routed and then with the
+    overflow forced, each gradient held to twice what the reference
+    itself reads with bf16 operands; the experts' counters against the
+    layers' row budgets both times."""
+    program = program_grads(ff, batch, pick)
 
     def reference_grads(params):
         value, grads = jax.value_and_grad(lambda p: ref.loss(
@@ -272,36 +337,58 @@ def gradient_checks(conf, ref, seed, seq=1024):
         with ref.rounded_operands(matmul=jnp.bfloat16):
             return reference_grads(params)
 
-    (lp, gp), (lr, gr) = program(ff.params), jax.jit(reference_grads)(
-        ff.params)
-    lb, gb = rounded(ff.params)
-    e = abs(float(lp) - float(lr)) / float(lr)
-    eb = abs(float(lb) - float(lr)) / float(lr)
-    READINGS["loss"] = {"program": float(lp), "reference": float(lr),
-                        "reference, bf16 operands": float(lb)}
-    # The yardstick for "as near as its precision allows" is the
-    # reference itself with every product's operands rounded to bf16
-    # (routers float32): the same mathematics at the precision the
-    # configuration states. An expert choice that flips under that
-    # rounding moves a token's whole contribution, so these readings
-    # are far above a dense model's (on the chip 7e-2 for a latent
-    # projection, 2e-1 to 4e-1 for one expert's weights and the router
-    # above them, whose gradients are sums over the few tokens routed
-    # there). What this catches is what the reference's forward cannot:
-    # a backward that is wrong by orders of magnitude. It caught one
-    # (rows the grouped products leave unwritten, read 1e5 here).
-    check("loss (with the MTP term)", e <= 2 * eb + 1e-4,
-          f"{float(lp):.6f} against {float(lr):.6f}: rel {e:.3e}; the "
-          f"reference with bf16 operands reads {eb:.3e}")
-    for name in gp:
-        e, eb = l2(gp[name], gr[name]), l2(gb[name], gr[name])
-        READINGS[f"grad {name}"] = {"program": e,
-                                    "reference, bf16 operands": eb}
-        own = l2(gp[name], gb[name])
-        READINGS[f"grad {name}"]["program against that"] = own
-        check(f"gradient {name}", e <= 2 * eb + 1e-3,
-              f"rel {e:.3e}; the reference with bf16 operands reads "
-              f"{eb:.3e}, and the program against THAT {own:.3e}")
+    plain = jax.jit(reference_grads)
+    for forced in (False, True):
+        params = force_overflow(ff, ff.params) if forced else ff.params
+        tag = "overflow forced: " if forced else ""
+        (lp, gp, counters), (lr, gr) = program(params), plain(params)
+        lb, gb = rounded(params)
+        check_budget(ff, seq, counters, forced)
+        e = abs(float(lp) - float(lr)) / float(lr)
+        eb = abs(float(lb) - float(lr)) / float(lr)
+        READINGS[f"{tag}loss"] = {"program": float(lp),
+                                  "reference": float(lr),
+                                  "reference, bf16 operands": float(lb)}
+        # The yardstick for "as near as its precision allows" is the
+        # reference itself with every product's operands rounded to bf16
+        # (routers float32): the same mathematics at the precision the
+        # configuration states. An expert choice that flips under that
+        # rounding moves a token's whole contribution, so these readings
+        # are far above a dense model's (on the chip 7e-2 for a latent
+        # projection, 2e-1 to 4e-1 for one expert's weights and the
+        # router above them, whose gradients are sums over the few
+        # tokens routed there). What this catches is what the
+        # reference's forward cannot: a backward that is wrong by orders
+        # of magnitude. It caught one (rows the grouped products leave
+        # unwritten, read 1e5 here; PERF.md section 6, PR 29).
+        check(f"{tag}{loss_label}", e <= 2 * eb + 1e-4,
+              f"{float(lp):.6f} against {float(lr):.6f}: rel {e:.3e}; the "
+              f"reference with bf16 operands reads {eb:.3e}")
+        for name in gp:
+            e, eb = l2(gp[name], gr[name]), l2(gb[name], gr[name])
+            own = l2(gp[name], gb[name])
+            READINGS[f"{tag}grad {name}"] = {
+                "program": e, "reference, bf16 operands": eb,
+                "program against that": own}
+            check(f"{tag}gradient {name}", e <= 2 * eb + 1e-3,
+                  f"rel {e:.3e}; the reference with bf16 operands reads "
+                  f"{eb:.3e}, and the program against THAT {own:.3e}")
+
+
+def gradient_checks(conf, ref, seed, seq=1024):
+    ff = build(conf, seq, "blocks")
+    ff.params, ff.state = ff.executor.init_params_and_state(
+        jax.random.key(seed))
+    picked = (("attn_4", "wq_a"), ("experts_4", "wg"),
+              ("experts_4", "w_gate"))
+
+    def pick(grads):
+        out = {f"{n}.{w}": grads[n][w] for n, w in picked}
+        out["experts_4.w_gate"] = out["experts_4.w_gate"][3]   # one expert
+        return out
+
+    compare_gradients(ff, ref, dict(conf), batch_of(conf, seq, seed), seq,
+                      pick, "loss (with the MTP term)")
 
 
 def main():

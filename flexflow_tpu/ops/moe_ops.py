@@ -17,6 +17,7 @@ Shapes (numpy order):
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, List
 
@@ -160,23 +161,43 @@ class CacheOp(OpDef):
 # ---------------------------------------------------------------------------
 # sparse, dropless routed experts (one op a layer)
 # ---------------------------------------------------------------------------
+def _from_rows(src, at):
+    """``src[at]`` for a ``src`` that holds ``len(src)`` rows of the
+    sort and assignments' places ``at`` among them: an assignment whose
+    row is not one of them reads zeros."""
+    b = src.shape[0]
+    there = ((at >= 0) & (at < b)).reshape((-1,) + (1,) * (src.ndim - 1))
+    return jnp.where(there, src[jnp.clip(at, 0, b - 1)], 0)
+
+
+def _of_each_choice(src, at, k: int):
+    """``_from_rows`` for each of a token's ``k`` assignments in turn,
+    ``k`` arrays of a row a token. One gather of ``tokens x k`` rows
+    reshaped to ``(tokens, k, width)`` is a relayout on the TPU wherever
+    ``k`` does not fill the tile's 8 sublanes: 8 of cell 4's 55 ms
+    (PERF.md section 6, PR 34)."""
+    at = at.reshape(-1, k)
+    return [_from_rows(src, at[:, j]) for j in range(k)]
+
+
 @jax.custom_vjp
-def _rows_for(x, order, inverse):
-    """Row ``order[r] // k`` of ``x`` for every sorted assignment ``r``
-    (``k = len(order) // len(x)`` assignments a token). Its transpose
-    gathers too: the ``k`` rows of a token sit at ``inverse`` and are
-    summed, so no scatter is emitted in either direction."""
-    return x[order // (order.shape[0] // x.shape[0])]
+def _rows_for(x, order, at):
+    """Row ``order[r] // k`` of ``x`` for each sorted assignment in
+    ``order`` (``k = len(at) // len(x)`` assignments a token). Its
+    transpose gathers too: the ``k`` rows of a token sit ``at`` their
+    places and are summed, so no scatter is emitted in either
+    direction."""
+    return x[order // (at.shape[0] // x.shape[0])]
 
 
-def _rows_for_fwd(x, order, inverse):
-    return _rows_for(x, order, inverse), (inverse, x.shape[0])
+def _rows_for_fwd(x, order, at):
+    return _rows_for(x, order, at), (at, x.shape[0])
 
 
 def _rows_for_bwd(res, g):
-    inverse, t = res
-    summed = g[inverse].astype(jnp.float32).reshape(
-        t, -1, g.shape[-1]).sum(axis=1)
+    at, t = res
+    summed = sum(rows.astype(jnp.float32)
+                 for rows in _of_each_choice(g, at, at.shape[0] // t))
     return summed.astype(g.dtype), None, None
 
 
@@ -184,14 +205,142 @@ _rows_for.defvjp(_rows_for_fwd, _rows_for_bwd)
 
 
 @jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation whose inverse is known: the
-    transpose is ``g[inverse]``, a gather, where autodiff would scatter."""
-    return x[perm]
+def _combine(ys, gates, order, at):
+    """``sum_k gates[t, k] * ys[row of assignment (t, k)]``: the sorted
+    rows ``ys`` back at their tokens, weighted. The transpose stays in
+    the sorted domain: a row's cotangent is its token's times its gate,
+    a gate's the product of its row with its token's cotangent, so the
+    backward gathers ``len(ys)`` rows, keeps ``ys`` and neither writes
+    nor keeps ``tokens x top_k`` of them."""
+    k = gates.shape[1]
+    return sum(gates[:, j:j + 1] * rows
+               for j, rows in enumerate(_of_each_choice(ys, at, k)))
 
 
-_permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
-                lambda inverse, g: (g[inverse], None, None))
+def _combine_fwd(ys, gates, order, at):
+    return _combine(ys, gates, order, at), (ys, gates, order, at)
+
+
+def _combine_bwd(res, g):
+    ys, gates, order, at = res
+    gs = g[order // gates.shape[1]]        # each row's token's cotangent
+    d_ys = gates.reshape(-1)[order][:, None] * gs
+    d_gates = _from_rows(jnp.sum(ys * gs, axis=-1), at)
+    return (d_ys.astype(ys.dtype),
+            d_gates.reshape(gates.shape).astype(gates.dtype), None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _chunk(budget: int, mdt, c, floats, ints):
+    """The held experts' part of the output from rows ``c * budget``
+    onwards of the sort, ``budget`` of them: the row gather, the three
+    grouped products and the activation over those rows, then each
+    token's sum of what they hold for it. Jitted and inlined for the
+    trace cache alone: a model's layers and a layer's loops trace and
+    differentiate this body once a shape, not once a use (a step's trace
+    is set-up time, twice in a benchmark run)."""
+    xm, gates, w_gate, w_up, w_down = floats
+    order, inverse, sizes = ints
+    lo, ends = c * budget, jnp.cumsum(sizes)
+    # each group's rows among these
+    inside = (jnp.clip(ends, lo, lo + budget)
+              - jnp.clip(ends - sizes, lo, lo + budget))
+    # Rows past the held groups are never multiplied, and on the TPU a
+    # grouped product leaves them UNWRITTEN, in its output and in the
+    # cotangent its transpose hands back (the CPU's writes zeros; found
+    # on the chip, PERF.md section 6, PR 29). Both sides of every
+    # product are therefore masked: autodiff carries the two selects
+    # into the backward, where they zero what the transposed products
+    # leave in those rows.
+    multiplied = (jnp.arange(budget) < jnp.sum(inside))[:, None]
+
+    def grouped(a, w):
+        out = jax.lax.ragged_dot(
+            jnp.where(multiplied, a, 0).astype(mdt), w, inside,
+            preferred_element_type=jnp.float32)
+        return jnp.where(multiplied, out, 0.0)
+
+    mine = jax.lax.dynamic_slice(order, (lo,), (budget,))
+    at = inverse - lo
+    # gathered at the products' operand width: half the bytes of the
+    # op's largest buffer, in both directions
+    xs = _rows_for(xm, mine, at)                            # (budget, e)
+    act = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+    return _combine(grouped(act, w_down), gates, mine, at)
+
+
+def _further_chunks(budget, sizes, body, start):
+    """``body(c, state)`` for every chunk of ``budget`` rows past the
+    first that holds a live row: none in the common step, and the
+    device decides by itself."""
+    return jax.lax.fori_loop(1, (jnp.sum(sizes) + budget - 1) // budget,
+                             body, start)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _sorted_domain(budget: int, mdt, floats, ints):
+    """``(y, chunks)``: the held experts' part of the output over the
+    live rows of the sort, ``budget`` rows at a time, and how many such
+    chunks ran. The first holds every live row unless the router sent
+    this share more than the budget; the step that overflows runs the
+    same body again over the next rows, in a loop whose trip count the
+    device reads from the group sizes, so nothing is dropped and no
+    buffer is ever larger than the budget's. Differentiated, the first
+    chunk keeps what autodiff keeps of it; the further ones keep
+    nothing and are computed again in the backward."""
+    return _further_outputs(
+        budget, mdt, floats, ints,
+        _chunk(budget, mdt, jnp.int32(0), floats, ints))
+
+
+def _sorted_domain_fwd(budget, mdt, floats, ints):
+    y, back = jax.vjp(lambda *f: _chunk(budget, mdt, jnp.int32(0), f, ints),
+                      *floats)
+    return _further_outputs(budget, mdt, floats, ints, y), (back, floats,
+                                                            ints)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _further_outputs(budget, mdt, floats, ints, y):
+    return _further_chunks(
+        budget, ints[2],
+        lambda c, s: (s[0] + _chunk(budget, mdt, c, floats, ints), s[1] + 1),
+        (y, jnp.int32(1)))
+
+
+def _sorted_domain_bwd(budget, mdt, res, g):
+    back, floats, ints = res
+    return _further_cotangents(budget, mdt, floats, ints, g[0],
+                               back(g[0])), None
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _further_cotangents(budget, mdt, floats, ints, g, first):
+    """The first chunk's cotangents ``first``, each the start of a loop
+    that adds the further chunks' in place. Two loops, one for the
+    activations' (input rows and gates, wanted by the layer before) and
+    one for the three weights', which only the optimizer wants: XLA
+    sinks the weights' products to their update at the end of the step,
+    and with them a loop of their own; through one loop with the
+    activations' they are written here and lie about until then, 150
+    MB a layer (PERF.md section 6, PR 34)."""
+    def further(part):
+        def more(c, sofar):
+            def chunk(*mine):
+                full = list(floats)
+                full[part] = mine
+                return _chunk(budget, mdt, c, tuple(full), ints)
+            return jax.tree.map(jnp.add, sofar,
+                                jax.vjp(chunk, *floats[part])[1](g))
+        return _further_chunks(budget, ints[2], more, first[part])
+
+    return further(slice(0, 2)) + further(slice(2, 5))
+
+
+_sorted_domain.defvjp(_sorted_domain_fwd, _sorted_domain_bwd)
 
 
 def route(scores, bias, top_k: int, scale: float):
@@ -229,9 +378,17 @@ class RoutedExpertsOp(OpDef):
     those bound for absent experts in a trailing group that no product
     touches; one grouped matrix product (``jax.lax.ragged_dot``) a
     projection runs over the stacked weights ``(held, in, out)`` with
-    the held experts' counts as group sizes. Shapes are static and
-    nothing is dropped: the sorted buffer has a row for every
-    assignment, so any imbalance fits. The counters ``moe.*`` of the
+    the held experts' counts as group sizes. Shapes are static: the
+    sorted domain (the row gather, the products with their masks, the
+    activation, and the transposes of all of them) runs over
+    ``rows_multiplied`` rows of the sort from its start, a budget read
+    from the op's shapes, with the live rows leading and the group
+    sizes beside them: what an expert-parallel exchange delivers.
+    Nothing is dropped: the step whose router sends this share more
+    than the budget runs the same body again over the next rows
+    (``_sorted_domain``: a loop whose trip count the device reads from
+    the group sizes, rematerialised in the backward), and where the
+    budget is every row there is no loop. The counters ``moe.*`` of the
     step's metrics go through ``ctx.count``."""
     op_type = OperatorType.OP_ROUTED_EXPERTS
 
@@ -259,9 +416,14 @@ class RoutedExpertsOp(OpDef):
 
     @staticmethod
     def rows_multiplied(tokens: int, params) -> int:
-        """Rows of the sorted buffer the grouped products are handed: one
-        for every assignment, so none can be dropped."""
-        return tokens * params["top_k"]
+        """The row budget: how many rows of the sort, from its start,
+        the grouped products are handed. Twice the share of the ``tokens
+        x top_k`` assignments that a uniform router sends the held
+        experts, rounded up to 512 rows, and at most all of them (with
+        half the experts or more held it IS all of them)."""
+        rows = tokens * params["top_k"]
+        twice = -(-2 * rows * params["experts_held"] // params["num_experts"])
+        return min(rows, -(-twice // 512) * 512)
 
     def emit(self, params, inputs, weights, ctx, name):
         (x,) = inputs
@@ -271,11 +433,12 @@ class RoutedExpertsOp(OpDef):
         k = params["top_k"]
         xt = x.reshape(-1, x.shape[-1])
         t = xt.shape[0]
-        rows = self.rows_multiplied(t, params)
+        rows, budget = t * k, self.rows_multiplied(t, params)
         if events.enabled():
             events.instant("moe.route", layer=name, experts_published=n,
                            experts_held=held, first_held=first, top_k=k,
-                           tokens=t, rows_multiplied=rows)
+                           tokens=t, rows_budget=budget,
+                           rows_multiplied=budget)
 
         # the router in float32, as published: a bf16 pass moves scores
         # by 1e-2 and with them the choice of experts
@@ -293,29 +456,18 @@ class RoutedExpertsOp(OpDef):
         sizes = jnp.bincount(group, length=held + 1)[:held].astype(
             jnp.int32)
 
-        # Rows past the held groups are never multiplied, and on the
-        # TPU a grouped product leaves them UNWRITTEN, in its output and
-        # in the cotangent its transpose hands back (the CPU's writes
-        # zeros; found on the chip, PERF.md section 6, PR 29). Both
-        # sides of every product are therefore masked: autodiff carries
-        # the two selects into the backward, where they zero what the
-        # transposed products leave in those rows.
-        multiplied = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
-
-        def grouped(a, w):
-            out = jax.lax.ragged_dot(
-                jnp.where(multiplied, a, 0).astype(mdt), w.astype(mdt),
-                sizes, preferred_element_type=jnp.float32)
-            return jnp.where(multiplied, out, 0.0)
-
-        # gathered at the products' operand width: half the bytes of
-        # the op's largest buffer, in both directions
-        xs = _rows_for(xt.astype(mdt), order, inverse)      # (rows, e)
-        act = jax.nn.silu(grouped(xs, weights["w_gate"])) \
-            * grouped(xs, weights["w_up"])
-        ys = grouped(act, weights["w_down"])
-        y = jnp.einsum("tk,tke->te", gates,
-                       _permute(ys, inverse, order).reshape(t, k, -1))
+        floats = (xt.astype(mdt), gates) + tuple(
+            weights[w].astype(mdt) for w in ("w_gate", "w_up", "w_down"))
+        if budget == rows:
+            y = _chunk(rows, mdt, jnp.int32(0), floats,
+                       (order, inverse, sizes))
+            chunks = None
+        else:
+            # whole chunks to slice: the padding sorts last, is never
+            # live and reads token 0
+            order = jnp.pad(order, (0, -rows % budget))
+            y, chunks = _sorted_domain(budget, mdt, floats,
+                                       (order, inverse, sizes))
         if "ws_gate" in weights:
             g = matmul(xt, weights["ws_gate"], ctx=ctx)
             u = matmul(xt, weights["ws_up"], ctx=ctx)
@@ -325,19 +477,23 @@ class RoutedExpertsOp(OpDef):
         # against what the grouped products reached: an assignment is
         # reached when the sort put it on a row inside the span that
         # ``sizes`` gives its chosen expert, since that is whose weights
-        # the row meets. Dropless by construction, so 0 unless sort,
-        # sizes and mask disagree. One compare over (rows, held).
+        # the row meets, and the row lies in a chunk that ran. Dropless
+        # by construction, so 0 unless sort, sizes, mask and the loop
+        # over chunks disagree. One compare over (rows, held).
         bound = jnp.sum((idx >= first) & (idx < first + held))
         ends = jnp.cumsum(sizes)
         at = inverse[:, None]
-        reached = jnp.sum((group[:, None] == jnp.arange(held))
-                          & (at >= ends - sizes) & (at < ends))
+        met = ((group[:, None] == jnp.arange(held))
+               & (at >= ends - sizes) & (at < ends))
+        if chunks is not None:
+            met &= at < chunks * budget
         load = sizes.astype(jnp.float32)
         for key, v in (("moe.local_assignments", bound),
-                       ("moe.dropped", bound - reached),
+                       ("moe.dropped", bound - jnp.sum(met)),
+                       ("moe.overflow", 0 if chunks is None else chunks > 1),
                        ("moe.load_max", jnp.max(load)),
                        ("moe.load_mean", jnp.mean(load))):
-            ctx.count(key, v.astype(jnp.float32))
+            ctx.count(key, jnp.asarray(v, jnp.float32))
         return [y.reshape(x.shape).astype(cdt)]
 
     def flops(self, params, in_shapes, out_shapes):

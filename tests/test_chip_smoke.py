@@ -72,8 +72,9 @@ def test_leg_c_latent_moe_tiny_on_the_cpu_mesh(capsys):
     assert "resolved attention impls ['xla'] in 4 layers" in out  # cpu
     for layer in ("experts_1", "experts_2", "experts_mtp"):
         assert (f"moe.route {layer}: 4 of 16 experts held from 0, top 4, "
-                f"128 tokens, 512 rows") in out
-    assert "moe.dropped 0.0" in out
+                f"128 tokens, 512 rows of the 512 sorted") in out
+    assert "moe.dropped 0.0, moe.overflow 0.0" in out
+    assert "rows_budget [512] a layer" in out
     # what this leg cannot see is named, and the named script exists
     assert f"python3 {chip_smoke.VALIDATION}" in out
     assert os.path.isfile(os.path.join(
@@ -94,7 +95,8 @@ def test_leg_d_hybrid_conv_moe_tiny_on_the_cpu_mesh(capsys):
             "attn.qk_norm ['attn_1']; moe.route ['experts_1', 'experts_2',"
             " 'experts_3', 'experts_4']") in out
     assert "resolved attention impls ['xla'] in 1 layers" in out  # cpu
-    assert "moe.dropped 0.0" in out
+    assert "moe.dropped 0.0, moe.overflow 0.0" in out
+    assert "rows_budget [512] a layer" in out
     assert f"python3 {chip_smoke.VALIDATION_HYBRID}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_HYBRID))

@@ -33,6 +33,17 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          looks at no gradient: ``examples/tpu_validate_hybrid_conv_moe.
          py`` does.
 
+  Leg E  gated delta-rule linear attention (a chunked scan and its
+         backward) among latent-attention layers with no q latent and no
+         rotary embedding, 8-of-256 routing with a shared expert
+         (``build_latent_moe`` from ``linear_attn_config``): a small
+         model, then one chip's share of Kimi-Linear-48B-A3B at
+         published widths, 1 x 4096 tokens a chip, rematerialised as its
+         benchmark cell is. It checks that the ``kda.scan`` and
+         ``attn.latent`` instants are the configuration's two lists and
+         prints the ``kda.*`` counters; it looks at no gradient:
+         ``examples/tpu_validate_linear_latent_moe.py`` does.
+
 It claims no speed. The times it prints are set-up facts of one run.
 It exits non-zero, before building anything, unless JAX reports a TPU;
 there is no option that lets it pass without one. Every later PR is
@@ -587,6 +598,84 @@ def leg_hybrid_conv_moe(model_cfg, seq: int, per_chip_batch: int,
     _compiled_step_size(ff, x, y, label)
 
 
+# ----------------------------------------------------------------------
+# Leg E — linear attention with a carried state, NoPE latent attention
+# ----------------------------------------------------------------------
+VALIDATION_LINEAR = "examples/tpu_validate_linear_latent_moe.py"
+
+
+def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
+                          label: str, alpha: float = 1e-5) -> None:
+    """``build_latent_moe`` with ``linear_attn_config`` through compile
+    and fit with ``remat = "blocks"``: the loss falls, the layers that
+    announce themselves as linear attention and as latent attention are
+    the configuration's two lists (numbered from 1 there), the latent
+    layers have no q latent and no rotation and resolved to the flash
+    kernel (on a chip), every scan ran, nothing was dropped, and the
+    step fits the chip. ``VALIDATION_LINEAR`` holds the recurrence and
+    the gradients to the token-by-token reference, and this leg names
+    it."""
+    import jax
+
+    from flexflow_tpu.models.nlp import build_latent_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_latent_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    lin = model_cfg.linear_attn_config
+    seen = {name: {e["attrs"]["layer"]: e["attrs"]
+                   for e in events.events() if e["name"] == name}
+            for name in ("kda.scan", "attn.latent", "moe.route")}
+    say(f"{label}: instants " + "; ".join(
+        f"{n} {sorted(v)}" for n, v in seen.items()))
+    check(sorted(seen["kda.scan"]) == [f"kda_{n - 1}"
+                                       for n in lin["kda_layers"]]
+          and sorted(seen["attn.latent"]) == [
+              f"attn_{n - 1}" for n in lin["full_attn_layers"]]
+          and sorted(seen["moe.route"]) == [
+              f"experts_{i}" for i in range(
+                  model_cfg.first_k_dense_replace,
+                  model_cfg.num_hidden_layers)],
+          f"{label}: the layers that announced themselves are not "
+          f"kda_layers {lin['kda_layers']} and full_attn_layers "
+          f"{lin['full_attn_layers']}: "
+          f"{ {n: sorted(v) for n, v in seen.items()} }")
+    scan = next(iter(seen["kda.scan"].values()))
+    say(f"{label}: kda.scan {scan['heads']} heads of {scan['head_dim']} "
+        f"behind {scan['taps']} taps, {scan['tokens']} tokens in "
+        f"{scan['chunks']} chunks of {scan['chunk']}, "
+        f"{scan['state_bytes'] / 2 ** 20:.0f} MiB of chunk-boundary "
+        f"states a layer")
+    check(all(a["q_rank"] is None and a["rope"] is False
+              for a in seen["attn.latent"].values()),
+          f"{label}: latent attention built as {seen['attn.latent']}")
+    ctr = events.counters()
+    scans = ctr.get("kda.scans", 0)
+    steps = 1 + TRAIN_STEPS
+    say(f"{label}: counters kda.scans {scans}, kda.log_decay_min "
+        f"{ctr.get('kda.log_decay_min')} (a sum over scans: "
+        f"{ctr.get('kda.log_decay_min', 0) / max(1, scans):.1f} a scan; "
+        f"exp(-G) is a float32 down to -88.7)")
+    check(scans == steps * len(lin["kda_layers"]),
+          f"{label}: {scans} scans counted in {steps} steps of "
+          f"{len(lin['kda_layers'])} linear-attention layers")
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: resolved attention impls "
+        f"{sorted(set(impls.values()))} in {len(impls)} layers")
+    check(len(impls) == len(lin["full_attn_layers"]),
+          f"{label}: {len(impls)} attention layers resolved")
+    if chip:
+        check(set(impls.values()) == {"flash"},
+              f"{label}: attention resolved to {impls} at seq {seq}")
+    _check_experts_counters(label)
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: the recurrence and the gradients "
+        f"against the token-by-token reference: python3 "
+        f"{VALIDATION_LINEAR}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def _check_generate(ff, ids) -> None:
     """KV-cache decode against the re-forward path on one prompt.
 
@@ -689,6 +778,7 @@ def main() -> int:
     from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
                                          HybridConvMoEConfig,
                                          JoyAIFlashRankConfig,
+                                         KimiLinearRankConfig,
                                          LatentMoEConfig, LFM2RankConfig)
     from flexflow_tpu.utils.compilation_cache import (
         cache_entries, enable_compilation_cache)
@@ -713,6 +803,9 @@ def main() -> int:
         leg_hybrid_conv_moe(HybridConvMoEConfig.tiny(), 1024, 1, "D/small",
                             alpha=1e-3)
         leg_hybrid_conv_moe(LFM2RankConfig(), 8192, 1, "D/lfm2")
+        leg_linear_latent_moe(KimiLinearRankConfig.tiny(), 1024, 1,
+                              "E/small", alpha=1e-3)
+        leg_linear_latent_moe(KimiLinearRankConfig(), 4096, 1, "E/kimi")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -393,21 +393,28 @@ def _check_op_shards(report, strategy, by_name, axis_sizes,
 
 
 def _check_conv_sequence(report, axis_sizes, layer, spec) -> None:
-    """A gated short convolution reads the ``taps - 1`` positions before
-    each one: batch- and channel-sharded layouts are local, a
-    sequence-sharded one needs a halo exchange that no layer here emits
-    and no cost row prices (``search/opshard.py`` offers none)."""
+    """Two kinds of layer read what lies before a position: a gated
+    short convolution its ``taps - 1`` predecessors, a gated delta rule
+    those AND the state every earlier position left. Batch- and
+    channel- (head-) sharded layouts are local; a sequence-sharded one
+    needs a halo exchange, and for the delta rule each shard's final
+    state handed to the next, which no layer here emits and no cost row
+    prices (``search/opshard.py`` offers none)."""
     from ..ffconst import OperatorType
-    if layer is None \
-            or layer.op_type != OperatorType.OP_GATED_SHORT_CONV:
+    needs = {OperatorType.OP_GATED_SHORT_CONV:
+             "a short convolution of {taps} taps: each shard needs",
+             OperatorType.OP_GATED_DELTA_RULE:
+             "a gated delta rule: each shard needs the state its "
+             "neighbour leaves and"}.get(getattr(layer, "op_type", None))
+    if needs is None:
         return
     entries = _spec_entries(spec)
     if len(entries) > 1 and any(axis_sizes.get(a, 1) > 1
                                 for a in entries[1]):
+        taps = layer.params["taps"]
         report.add("op-shard", "error", layer.name,
-                   f"output spec {spec} shards the sequence of a short "
-                   f"convolution of {layer.params['taps']} taps: each "
-                   f"shard needs a halo of {layer.params['taps'] - 1} "
+                   f"output spec {spec} shards the sequence of "
+                   f"{needs.format(taps=taps)} a halo of {taps - 1} "
                    f"positions from its neighbour, which is not built")
 
 

@@ -226,6 +226,9 @@ class OperatorType(enum.IntEnum):
     # a gated short convolution: the sequence-mixing layer of hybrid
     # convolution/attention decoders (no attention, no recurrence)
     OP_GATED_SHORT_CONV = enum.auto()
+    # a gated delta-rule linear-attention layer: a state carried along
+    # the sequence (a chunked scan and its backward)
+    OP_GATED_DELTA_RULE = enum.auto()
 
 
 # Ops that are pure elementwise-unary (single input, same shape out).

@@ -271,30 +271,51 @@ class FFModel:
         return self._unary(OperatorType.OP_GATED_SHORT_CONV, input, name,
                            taps=int(taps))
 
+    def gated_delta_rule(self, input: Tensor, num_heads: int,
+                         head_dim: int, taps: int, eps: float = 1e-5,
+                         name: Optional[str] = None) -> Tensor:
+        """A gated delta-rule linear-attention layer
+        (``ops.recurrent_ops.GatedDeltaRuleOp``): ``num_heads`` heads of
+        ``head_dim``, q, k and v each through a causal depthwise
+        convolution of ``taps`` positions, a decay a channel and a step
+        size a head, a gated RMSNorm (``eps``) before the output
+        projection."""
+        if taps < 1 or num_heads < 1 or head_dim < 1:
+            raise ValueError(f"{num_heads} heads of {head_dim} behind "
+                             f"convolutions of {taps} taps")
+        return self._unary(OperatorType.OP_GATED_DELTA_RULE, input, name,
+                           num_heads=int(num_heads),
+                           head_dim=int(head_dim), taps=int(taps),
+                           eps=float(eps))
+
     def latent_attention(self, input: Tensor, positions: Tensor,
-                         num_heads: int, q_rank: int,
+                         num_heads: int, q_rank: Optional[int],
                          kv_rank: int, nope_dim: int, rope_dim: int,
                          v_dim: int, rope_theta: float = 10000.0,
-                         eps: float = 1e-6,
+                         eps: float = 1e-6, rope: bool = True,
                          name: Optional[str] = None) -> Tensor:
         """Causal multi-head latent attention (``ops.nn_ops.
-        LatentAttentionOp``): low-rank q (``q_rank``) and kv
-        (``kv_rank``) with a norm on each latent, q/k heads of
-        ``nope_dim + rope_dim`` (rotary on the last ``rope_dim``, one
-        rotary key shared by the heads), v heads of ``v_dim``.
+        LatentAttentionOp``): low-rank q (``q_rank``; None: one full
+        projection and no norm) and kv (``kv_rank``) with a norm on each
+        latent, q/k heads of ``nope_dim + rope_dim`` (rotary on the last
+        ``rope_dim``, one rotary key shared by the heads; ``rope=False``:
+        those entries as they are), v heads of ``v_dim``.
         ``positions``: (batch, seq) int32, what the rotary embedding
         turns by."""
-        return self._add_layer(
-            OperatorType.OP_LATENT_ATTENTION, [input, positions],
-            dict(num_heads=num_heads, q_rank=q_rank, kv_rank=kv_rank,
-                 nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
-                 rope_theta=float(rope_theta), eps=eps), name).outputs[0]
+        params = dict(num_heads=num_heads, q_rank=q_rank, kv_rank=kv_rank,
+                      nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+                      rope_theta=float(rope_theta), eps=eps)
+        if not rope:
+            params["rope"] = False
+        return self._add_layer(OperatorType.OP_LATENT_ATTENTION,
+                               [input, positions], params,
+                               name).outputs[0]
 
     def routed_experts(self, input: Tensor, num_experts: int, top_k: int,
                        expert_dim: int, shared_dim: int = 0,
                        experts_held: Optional[int] = None,
                        first_held: int = 0, scale: float = 1.0,
-                       bias_std: float = 0.0,
+                       bias_std: float = 0.0, rows_factor: int = 2,
                        name: Optional[str] = None) -> Tensor:
         """One sparse, dropless mixture-of-experts feed-forward layer
         (``ops.moe_ops.RoutedExpertsOp``): sigmoid scores over
@@ -302,7 +323,10 @@ class FFModel:
         width ``expert_dim`` and a shared one of ``shared_dim`` (0: none).
         ``experts_held`` (default: all) and ``first_held`` say which
         experts' weights live here: the layer routes over all of them
-        and computes the part of the result that its own give."""
+        and computes the part of the result that its own give.
+        ``rows_factor``: the rows the grouped products are handed, in
+        uniform shares of the held experts (``RoutedExpertsOp.
+        rows_multiplied``)."""
         held = num_experts if experts_held is None else experts_held
         if not 0 <= first_held <= first_held + held <= num_experts:
             raise ValueError(
@@ -310,11 +334,15 @@ class FFModel:
                 f"among the {num_experts} the router scores")
         if top_k > num_experts:
             raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        if rows_factor < 1:
+            raise ValueError(f"a row budget of {rows_factor} shares")
+        more = {} if rows_factor == 2 else {"rows_factor": int(rows_factor)}
         return self._unary(OperatorType.OP_ROUTED_EXPERTS, input, name,
                            num_experts=num_experts, top_k=top_k,
                            expert_dim=expert_dim, shared_dim=shared_dim,
                            experts_held=held, first_held=first_held,
-                           scale=float(scale), bias_std=float(bias_std))
+                           scale=float(scale), bias_std=float(bias_std),
+                           **more)
 
     def next_token_loss(self, logits: Tensor, ids: Tensor, offset: int,
                         weight: float,

@@ -679,13 +679,26 @@ class LatentMoEConfig:
     ``first_held_expert`` onwards; the router, the top-k and the gates'
     normalisation run over ``n_routed_experts_published`` (None: the
     same, every expert is held). A device of an expert-parallel layer
-    holds its share and computes its own experts' part of the result."""
+    holds its share and computes its own experts' part of the result.
+
+    ``q_lora_rank`` None gives latent attention one full query
+    projection. :func:`build_latent_moe` reads three fields more where a
+    subclass has them (:class:`KimiLinearRankConfig`), two of them keys
+    of ``model_type: kimi_linear``. ``linear_attn_config`` (absent or
+    None: every layer's operator is latent attention) names, counting
+    layers from 1, the layers whose operator is a gated delta-rule
+    linear-attention layer (``kda_layers``, of ``num_heads`` heads of
+    ``head_dim`` behind convolutions of ``short_conv_kernel_size`` taps)
+    and those that keep latent attention (``full_attn_layers``);
+    ``mla_use_nope`` (absent: False) leaves the rotary embedding out of
+    latent attention; ``expert_rows_factor`` (absent: 2) is an expert
+    layer's row budget in uniform shares."""
     vocab_size: int = 129280
     hidden_size: int = 2048
     num_hidden_layers: int = 40
     first_k_dense_replace: int = 1
     num_attention_heads: int = 32
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -731,12 +744,73 @@ class JoyAIFlashRankConfig(LatentMoEConfig):
     n_routed_experts_published: int | None = 256
 
 
+@dataclasses.dataclass
+class KimiLinearRankConfig(LatentMoEConfig):
+    """What ONE chip holds of Kimi-Linear-48B-A3B where 32 chips share
+    each layer (the benchmark's ``kimi_linear_48b_a3b``): experts 0 to 7
+    of the 256, one of eight slices of the vocabulary, and published
+    layer 1 (the dense one) with layers 2 to 5 (one whole period: three
+    linear-attention layers around one latent-attention layer; the rest
+    lie on further chips as pipeline stages); every width as published.
+
+    ``model_type: kimi_linear`` names four of the parent's fields
+    otherwise; they are taken under its names and copied over."""
+    vocab_size: int = 20480
+    hidden_size: int = 2304
+    num_hidden_layers: int = 5
+    q_lora_rank: int | None = None
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    routed_scaling_factor: float = 2.446
+    num_nextn_predict_layers: int = 0
+    # the two keys the parent class does not have (its docstring)
+    linear_attn_config: dict | None = dataclasses.field(
+        default_factory=lambda: {
+            "kda_layers": [1, 2, 3, 5], "full_attn_layers": [4],
+            "num_heads": 32, "head_dim": 128, "short_conv_kernel_size": 4})
+    mla_use_nope: bool = True
+    num_experts: int = 8
+    num_experts_published: int | None = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    # not in config.json: the row budget of an expert layer, in uniform
+    # shares of the held experts (``RoutedExpertsOp.rows_multiplied``);
+    # a 32nd of the experts is sent up to 3.4 times its share
+    expert_rows_factor: int = 4
+
+    def __post_init__(self):
+        self.n_routed_experts = self.num_experts
+        self.n_routed_experts_published = self.num_experts_published
+        self.num_experts_per_tok = self.num_experts_per_token
+        self.n_shared_experts = self.num_shared_experts
+
+    @classmethod
+    def tiny(cls):
+        """The benchmark's layout at a small size: 4 linear-attention
+        heads of 8 behind 4 taps, 4 latent heads of 16 + 8 / 16, 16
+        experts top-4 and a shared one: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_attention_heads=4,
+                   kv_lora_rank=32, qk_nope_head_dim=16,
+                   qk_rope_head_dim=8, v_head_dim=16, intermediate_size=160,
+                   moe_intermediate_size=32, router_bias_std=0.05,
+                   linear_attn_config={
+                       "kda_layers": [1, 2, 3, 5], "full_attn_layers": [4],
+                       "num_heads": 4, "head_dim": 8,
+                       "short_conv_kernel_size": 4},
+                   num_experts=16, num_experts_published=None,
+                   num_experts_per_token=4)
+
+
 def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
                      cfg: LatentMoEConfig | None = None):
     """Causal LM of :class:`LatentMoEConfig`: inputs ``[ids, pos]``,
     output the softmax over the head (the executor's CE-on-logits path),
     as :func:`build_gpt2`; ``pos`` is what every layer's rotary
-    embedding turns by.
+    embedding turns by. A layer's operator is latent attention, or,
+    for the layers ``linear_attn_config`` names, a gated delta-rule
+    linear-attention layer; its feed-forward does not depend on which.
 
     The multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
     section 2.2) predicts token ``t + 2`` from the trunk's last hidden
@@ -750,6 +824,16 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
     cfg = cfg or LatentMoEConfig()
     if cfg.num_nextn_predict_layers not in (0, 1):
         raise ValueError("0 or 1 multi-token-prediction module")
+    lin = getattr(cfg, "linear_attn_config", None)
+    linear = set(lin["kda_layers"]) if lin else set()
+    if lin:                             # layers are numbered from 1 there
+        full = set(lin["full_attn_layers"])
+        if linear & full or linear | full != set(
+                range(1, cfg.num_hidden_layers + 1)):
+            raise ValueError(
+                f"kda_layers {sorted(linear)} and full_attn_layers "
+                f"{sorted(full)} must name each of the layers 1 to "
+                f"{cfg.num_hidden_layers} once")
     b, s, hid = batch_size, seq_len, cfg.hidden_size
     published = cfg.n_routed_experts_published or cfg.n_routed_experts
     ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
@@ -759,13 +843,21 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
     def norm(x, name):
         return ff.rms_norm(x, eps=cfg.rms_norm_eps, name=name)
 
-    def decoder_layer(h, tag, experts: bool):
-        attn = ff.latent_attention(
-            norm(h, f"input_norm_{tag}"), pos, cfg.num_attention_heads,
-            cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-            cfg.qk_rope_head_dim, cfg.v_head_dim,
-            rope_theta=cfg.rope_theta, eps=cfg.rms_norm_eps,
-            name=f"attn_{tag}")
+    def decoder_layer(h, tag, experts: bool, linear: bool = False):
+        x = norm(h, f"input_norm_{tag}")
+        if linear:
+            attn = ff.gated_delta_rule(
+                x, lin["num_heads"], lin["head_dim"],
+                lin["short_conv_kernel_size"], eps=cfg.rms_norm_eps,
+                name=f"kda_{tag}")
+        else:
+            attn = ff.latent_attention(
+                x, pos, cfg.num_attention_heads,
+                cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                cfg.qk_rope_head_dim, cfg.v_head_dim,
+                rope_theta=cfg.rope_theta, eps=cfg.rms_norm_eps,
+                rope=not getattr(cfg, "mla_use_nope", False),
+                name=f"attn_{tag}")
         h = ff.add(h, attn, name=f"attn_res_{tag}")
         x = norm(h, f"post_norm_{tag}")
         if experts:
@@ -776,7 +868,9 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
                 experts_held=cfg.n_routed_experts,
                 first_held=cfg.first_held_expert,
                 scale=cfg.routed_scaling_factor,
-                bias_std=cfg.router_bias_std, name=f"experts_{tag}")
+                bias_std=cfg.router_bias_std,
+                rows_factor=getattr(cfg, "expert_rows_factor", 2),
+                name=f"experts_{tag}")
         else:
             gate = ff.dense(x, cfg.intermediate_size, use_bias=False,
                             name=f"gate_proj_{tag}")
@@ -789,7 +883,8 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
 
     h = emb
     for i in range(cfg.num_hidden_layers):
-        h = decoder_layer(h, str(i), i >= cfg.first_k_dense_replace)
+        h = decoder_layer(h, str(i), i >= cfg.first_k_dense_replace,
+                          i + 1 in linear)
     out = norm(h, "final_norm")
     if not cfg.num_nextn_predict_layers:
         return ff.softmax(ff.dense(out, cfg.vocab_size, use_bias=False,

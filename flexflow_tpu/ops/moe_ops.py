@@ -420,10 +420,15 @@ class RoutedExpertsOp(OpDef):
         the grouped products are handed. Twice the share of the ``tokens
         x top_k`` assignments that a uniform router sends the held
         experts, rounded up to 512 rows, and at most all of them (with
-        half the experts or more held it IS all of them)."""
+        half the experts or more held it IS all of them). ``rows_factor``
+        (default 2) is that "twice": the smaller the share held, the
+        more its load swings about the uniform one (8 of 256 experts
+        were sent 3.1 to 3.4 times theirs at two seeds of five), and a
+        layer that overflows at its seed's weights loops in every step."""
         rows = tokens * params["top_k"]
-        twice = -(-2 * rows * params["experts_held"] // params["num_experts"])
-        return min(rows, -(-twice // 512) * 512)
+        share = -(-params.get("rows_factor", 2) * rows
+                  * params["experts_held"] // params["num_experts"])
+        return min(rows, -(-share // 512) * 512)
 
     def emit(self, params, inputs, weights, ctx, name):
         (x,) = inputs

@@ -961,7 +961,13 @@ class LatentAttentionOp(OpDef):
     last dimensions of q and of the shared key. q and k have ``nope_dim + rope_dim`` per head, v and
     o ``v_dim``: the flash kernels take the two sizes as they are
     (``kernels/flash_attention.py``), chosen by shape and switches
-    exactly as in :class:`MultiHeadAttentionOp`."""
+    exactly as in :class:`MultiHeadAttentionOp`.
+
+    Two parameters switch parts of it off. ``q_rank=None``: no q latent,
+    ``q_h = x wq`` with one ``wq`` (e, h, d_nope + d_rope) and no
+    ``q_norm``. ``rope=False``: the ``rope_dim`` entries of q and the
+    shared key are used as they are (NoPE); the positions are then not
+    read."""
     op_type = OperatorType.OP_LATENT_ATTENTION
 
     def infer(self, params, in_shapes, in_dtypes):
@@ -976,10 +982,14 @@ class LatentAttentionOp(OpDef):
 
         def fans(i, o):          # per-head projections: fans as a matrix
             return {"fans": (i, o)}
-        return [WeightSpec("wq_a", (e, qr), dt),
+        q_proj = [WeightSpec("wq", (e, h, dn + dr), dt,
+                             init_args=fans(e, h * (dn + dr)))] \
+            if qr is None else [
+                WeightSpec("wq_a", (e, qr), dt),
                 WeightSpec("q_norm", (qr,), dt, one),
                 WeightSpec("wq_b", (qr, h, dn + dr), dt,
-                           init_args=fans(qr, h * (dn + dr))),
+                           init_args=fans(qr, h * (dn + dr)))]
+        return q_proj + [
                 WeightSpec("wkv_a", (e, kvr + dr), dt),
                 WeightSpec("kv_norm", (kvr,), dt, one),
                 WeightSpec("wkv_b", (kvr, h, dn + dv), dt,
@@ -1002,16 +1012,27 @@ class LatentAttentionOp(OpDef):
             return jnp.einsum(pattern, a.astype(mdt), w.astype(mdt),
                               preferred_element_type=jnp.float32)
 
-        c_q = _rms(mm(x, weights["wq_a"], "bse,er->bsr"),
-                   weights["q_norm"], eps)
-        q = mm(c_q, weights["wq_b"], "bsr,rhd->bshd")
+        if params["q_rank"] is None:
+            q = mm(x, weights["wq"], "bse,ehd->bshd")
+        else:
+            c_q = _rms(mm(x, weights["wq_a"], "bse,er->bsr"),
+                       weights["q_norm"], eps)
+            q = mm(c_q, weights["wq_b"], "bsr,rhd->bshd")
         kv_a = mm(x, weights["wkv_a"], "bse,er->bsr")
         c_kv = _rms(kv_a[..., :kvr], weights["kv_norm"], eps)
         kv = mm(c_kv, weights["wkv_b"], "bsr,rhd->bshd")
-        theta = float(params["rope_theta"])
-        q_rope = _rope_interleaved(q[..., dn:], pos, theta)
-        k_rope = _rope_interleaved(kv_a[..., kvr:], pos, theta)
+        if params.get("rope", True):
+            theta = float(params["rope_theta"])
+            q_rope = _rope_interleaved(q[..., dn:], pos, theta)
+            k_rope = _rope_interleaved(kv_a[..., kvr:], pos, theta)
+        else:
+            q_rope, k_rope = q[..., dn:], kv_a[..., kvr:]
         h = q.shape[2]
+        if events.enabled():
+            events.instant("attn.latent", layer=name, heads=h,
+                           q_rank=params["q_rank"], kv_rank=kvr,
+                           rope=bool(params.get("rope", True)),
+                           tokens=b * s)
         qh = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
         kh = jnp.concatenate(
             [kv[..., :dn],
@@ -1050,8 +1071,10 @@ class LatentAttentionOp(OpDef):
         h, qr, kvr = (params["num_heads"], params["q_rank"],
                       params["kv_rank"])
         dn, dr, dv = params["nope_dim"], params["rope_dim"], params["v_dim"]
-        proj = (e * qr + qr * h * (dn + dr) + e * (kvr + dr)
-                + kvr * h * (dn + dv) + h * dv * e)
+        q_proj = e * h * (dn + dr) if qr is None \
+            else e * qr + qr * h * (dn + dr)
+        proj = (q_proj + e * (kvr + dr) + kvr * h * (dn + dv)
+                + h * dv * e)
         return 2.0 * b * s * (proj + s * h * (dn + dr + dv))
 
     def backward_flops_factor(self):

@@ -1,0 +1,326 @@
+"""Layers that carry a state along the sequence.
+
+The gated delta rule (linear attention with a decay a channel and a
+delta-rule write): a head keeps a ``d_k x d_v`` state ``S`` and, a token,
+
+    S <- Diag(exp(g_t)) S
+    S <- S + beta_t k_t (v_t - S^T k_t)^T
+    o_t = S^T q_t
+
+Run token by token that is ``T`` dependent steps. :func:`gated_delta_rule`
+computes the same thing in chunks of ``C`` tokens: inside a chunk the
+writes ``u_i = beta_i (v_i - (state before i)^T k_i)`` solve one
+unit-lower-triangular ``C x C`` system a head, which does not depend on
+the state the chunk starts from, so every chunk's system is solved at
+once; between chunks a ``lax.scan`` carries ``S``. With ``G`` the running
+sum of ``g`` inside the chunk (every entry <= 0), ``S_0`` the state at the
+chunk's start:
+
+    A_ij = sum_c k_ic k_jc exp(G_ic - G_jc)          (j <  i)
+    B_ij = sum_c q_ic k_jc exp(G_ic - G_jc)          (j <= i)
+    [W  U0] = (I + Diag(beta) A)^-1 Diag(beta) [k * exp(G)   v]
+    U   = U0 - W S_0
+    O   = (q * exp(G)) S_0 + B U
+    S_C = Diag(exp(G_C)) S_0 + (k * exp(G_C - G))^T U
+
+The decay is a channel's, so ``A`` and ``B`` are not a product of two
+decayed matrices a head: ``exp(G_i)`` times ``exp(-G_j)`` overflows
+float32 once a chunk's decays sum past 88, and they do. Every exponent
+taken here is a DIFFERENCE that is <= 0. A chunk is halved down to
+sub-blocks of ``SUB`` rows: the second half of a span against its first
+half goes through the second half's first row ``n`` (``exp(G_i - G_n)``
+on the rows' side, ``exp(G_n - G_j)`` on the columns': one matrix
+product a span, and the decayed copies of k and q made for all of a
+chunk's spans together are as large as k and q), a sub-block against
+itself through the ``(SUB, SUB, d)`` tensor of differences, reduced on
+the spot.
+"""
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..core.tensor import WeightSpec
+from ..ffconst import InitializerType, OperatorType
+from ..obs import events
+from .nn_ops import _rms, short_conv
+from .registry import OpDef, compute_dtype, register
+
+CHUNK = 64          # tokens a step of the scan
+SUB = 16            # rows of a sub-block of a chunk
+NORM_EPS = 1e-6     # under the root of q's and k's lengths
+
+
+def _chunk_terms(q, k, v, g, beta, mdt):
+    """Everything of a chunk that does not depend on the state it starts
+    from. ``q``, ``k``, ``g``: (B, H, N, C, dk), ``v``: (.., dv),
+    ``beta``: (B, H, N, C); float32. Returns ``W`` (.., C, dk), ``U0``
+    (.., C, dv), ``B`` (.., C, C), ``q * exp(G)``, ``k * exp(G_C - G)``,
+    ``exp(G_C)`` (.., dk) and the least ``G``."""
+    n_c = k.shape[3]
+    lead = k.shape[:3]
+    spans = n_c // SUB          # a power of two of sub-blocks, or one
+    sub = SUB if n_c % SUB == 0 and spans & (spans - 1) == 0 else n_c
+    big_g = jnp.cumsum(g, axis=3)
+
+    def prod(a, b):
+        return jnp.einsum("...id,...jd->...ij", a.astype(mdt),
+                          b.astype(mdt),
+                          preferred_element_type=jnp.float32)
+
+    def blocks(x, rows):
+        return x.reshape(lead + (n_c // rows, rows) + x.shape[4:])
+
+    def on_diagonal(x):                 # (.., m, r, r) -> (.., m r, m r)
+        m, r = x.shape[-3], x.shape[-1]
+        return jnp.einsum("...mij,mn->...minj", x, np.eye(m, dtype=x.dtype)
+                          ).reshape(x.shape[:-3] + (m * r, m * r))
+
+    # a sub-block against itself: the differences themselves
+    gs, ks, qs = blocks(big_g, sub), blocks(k, sub), blocks(q, sub)
+    low = np.tril(np.ones((sub, sub), bool))
+    e = jnp.exp(jnp.where(low[..., None],
+                          gs[..., :, None, :] - gs[..., None, :, :], 0.0))
+    a = on_diagonal(jnp.where(np.tril(low, -1), jnp.sum(
+        ks[..., :, None, :] * ks[..., None, :, :] * e, -1), 0.0))
+    b = on_diagonal(jnp.where(low, jnp.sum(
+        qs[..., :, None, :] * ks[..., None, :, :] * e, -1), 0.0))
+    # the second half of a span against its first half, through the
+    # second half's first row n: G_i - G_n <= 0 on the rows' side and
+    # G_n - G_j <= 0 on the columns'; spans of 2 SUB rows, 4 SUB, .. C
+    half = sub
+    while half < n_c:
+        gs, ks, qs = (blocks(x, 2 * half) for x in (big_g, k, q))
+        g_n = gs[..., half:half + 1, :]
+        rows = jnp.exp(gs[..., half:, :] - g_n)
+        cols = ks[..., :half, :] * jnp.exp(g_n - gs[..., :half, :])
+        pad = ((0, 0),) * (len(lead) + 1) + ((half, 0), (0, half))
+        a += on_diagonal(jnp.pad(prod(ks[..., half:, :] * rows, cols), pad))
+        b += on_diagonal(jnp.pad(prod(qs[..., half:, :] * rows, cols), pad))
+        half *= 2
+
+    # (I + Diag(beta) A)^-1 by one float32 triangular solve a chunk,
+    # against the identity; W and U0 are then products like any other
+    decay = jnp.exp(big_g)
+    eye = jnp.eye(n_c, dtype=jnp.float32)
+    inverse = jax.lax.linalg.triangular_solve(
+        eye + beta[..., None] * a, jnp.broadcast_to(eye, a.shape),
+        left_side=True, lower=True, unit_diagonal=True)
+    w = prod(inverse, jnp.swapaxes(beta[..., None] * k * decay, -1, -2))
+    u0 = prod(inverse, jnp.swapaxes(beta[..., None] * v, -1, -2))
+    g_last = big_g[..., -1:, :]
+    return (w.astype(mdt), u0, b.astype(mdt), (q * decay).astype(mdt),
+            (k * jnp.exp(g_last - big_g)).astype(mdt),
+            jnp.exp(g_last[..., 0, :]), jnp.min(big_g))
+
+
+def _chunk_step(mdt, state, terms):
+    """One chunk given the state it starts from (B, H, dk, dv float32):
+    its outputs (B, H, C, dv) and the state it leaves."""
+    w, u0, b, q_dec, k_dec, decay = terms
+
+    def mm(pattern, x, y):
+        return jnp.einsum(pattern, x.astype(mdt), y.astype(mdt),
+                          preferred_element_type=jnp.float32)
+
+    u = u0 - mm("bhcd,bhde->bhce", w, state)
+    out = mm("bhcd,bhde->bhce", q_dec, state) + mm("bhij,bhje->bhie", b, u)
+    state = decay[..., None] * state + mm("bhcd,bhce->bhde", k_dec, u)
+    return state, out
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
+                     mdt=jnp.float32):
+    """The recurrence of the module's docstring from a zero state, in
+    chunks, heads leading: ``q``, ``k``, ``g``: (B, H, T, dk), ``g`` <= 0
+    the log of the decay; ``v``: (B, H, T, dv); ``beta``: (B, H, T).
+    ``mdt``: the type the products' operands are rounded to (sums,
+    states, decays and the triangular solve are float32). Returns ``o``
+    (B, H, T, dv) float32 and the most negative running sum of ``g``
+    inside any chunk.
+
+    Its backward is autodiff's, with the chunks' terms and the body of
+    the scan each rematerialised: the backward pass holds the
+    chunk-boundary states, the terms the scan reads and one chunk's
+    matrices, not the ``(SUB, SUB, d)`` differences nor every chunk's
+    intermediate products."""
+    t = q.shape[2]
+    pad = -t % chunk
+
+    def chunks(x):
+        # (B, H, T, ..) -> (B, H, N, C, ..); padded positions write
+        # nothing (beta 0) and decay nothing (g 0)
+        x = jnp.pad(x.astype(jnp.float32),
+                    ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
+        return x.reshape(x.shape[:2] + (-1, chunk) + x.shape[3:])
+
+    *terms, least = jax.checkpoint(
+        lambda *a: _chunk_terms(*a, mdt))(*map(chunks, (q, k, v, g, beta)))
+    state = jnp.zeros(k.shape[:2] + (k.shape[-1], v.shape[-1]),
+                      jnp.float32)
+    _, out = jax.lax.scan(                      # over the chunks: N leads
+        jax.checkpoint(lambda s, xs: _chunk_step(mdt, s, xs)), state,
+        [jnp.moveaxis(x, 2, 0) for x in terms])
+    out = jnp.moveaxis(out, 0, 2)                  # (B, H, N, C, dv)
+    out = out.reshape(out.shape[:2] + (-1,) + out.shape[4:])[:, :, :t]
+    return out, jax.lax.stop_gradient(least)
+
+
+def _unit(x):
+    """x / |x|_2 over the last axis, float32."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + NORM_EPS)
+
+
+@register
+class GatedDeltaRuleOp(OpDef):
+    """A gated delta-rule linear-attention layer (Kimi Delta Attention's
+    form): ``H`` heads of ``d``, each the recurrence of
+    :func:`gated_delta_rule` over its own q, k, v.
+
+      q, k, v = silu(short_conv(x w))      K causal depthwise taps each
+      q <- q / |q| * d^-1/2;  k <- k / |k|            a head
+      g = -exp(A_log) * softplus((x wf_a) wf_b + dt_bias)   a channel
+      beta = sigmoid(x wb)                                   a head
+      o = gated_delta_rule(q, k, v, g, beta)
+      y = [RMSNorm_d(o; o_norm) * sigmoid((x wg_a) wg_b)] wo
+
+    No bias in any projection. The projections are matrix products at
+    the compute dtype with float32 accumulation; taps, gates, norms,
+    decays, the state and the triangular solve are float32. The
+    recurrence (solve and scan, not the projections) runs under the
+    name scope ``kda.scan``. Training and evaluation only: there is no
+    decode path that carries the state from call to call."""
+    op_type = OperatorType.OP_GATED_DELTA_RULE
+
+    def infer(self, params, in_shapes, in_dtypes):
+        return [(in_shapes[0], in_dtypes[0])]
+
+    def weights(self, params, in_shapes, in_dtypes):
+        e, dt = in_shapes[0][-1], in_dtypes[0]
+        h, d, k = params["num_heads"], params["head_dim"], params["taps"]
+        r = params.get("gate_rank") or d
+
+        def fans(i, o):
+            return {"fans": (i, o)}
+        ws = []
+        for n in "qkv":
+            ws += [WeightSpec(f"w{n}", (e, h, d), dt,
+                              init_args=fans(e, h * d)),
+                   # one filter a channel: K taps in, K positions reached
+                   WeightSpec(f"conv_{n}", (h, d, k), dt,
+                              init_args=fans(k, k))]
+        uniform = InitializerType.UNIFORM
+        return ws + [
+            WeightSpec("wf_a", (e, r), dt),
+            WeightSpec("wf_b", (r, h, d), dt, init_args=fans(r, h * d)),
+            # how fast a state decays: A = exp(A_log) uniform in (1, 16),
+            # softplus(dt_bias) log-uniform in (1e-3, 1e-1)
+            WeightSpec("A_log", (h,), dt, uniform,
+                       {"min": 1.0, "max": 16.0, "map": "log"}),
+            WeightSpec("dt_bias", (h, d), dt, uniform,
+                       {"min": math.log(1e-3), "max": math.log(1e-1),
+                        "map": "inverse_softplus_of_exp"}),
+            WeightSpec("wb", (e, h), dt),
+            WeightSpec("wg_a", (e, r), dt),
+            WeightSpec("wg_b", (r, h, d), dt, init_args=fans(r, h * d)),
+            WeightSpec("o_norm", (d,), dt, InitializerType.ONE),
+            WeightSpec("wo", (h, d, e), dt, init_args=fans(h * d, e))]
+
+    @staticmethod
+    def projections(x, weights, mdt):
+        """``q, k, v, g, beta`` as the recurrence takes them and the
+        output gate, all float32 and heads leading: (B, H, T, d) but
+        ``beta`` (B, H, T)."""
+        f32 = jnp.float32
+
+        def mm(pattern, a, w):
+            return jnp.einsum(pattern, a.astype(mdt), w.astype(mdt),
+                              preferred_element_type=f32)
+
+        def mixed(x, w, taps, unit):    # a head's channels: (B, T, d)
+            z = jax.nn.silu(jax.vmap(short_conv, (1, 0), 1)(
+                mm("bte,ehd->bhtd", x, w), taps.astype(f32)))
+            return _unit(z) if unit else z
+
+        def low_rank(x, a, b):
+            return mm("btr,rhd->bhtd", mm("bte,er->btr", x, a), b)
+
+        def decay(x, a, b, a_log, dt_bias):
+            return -jnp.exp(a_log.astype(f32))[:, None, None] \
+                * jax.nn.softplus(low_rank(x, a, b)
+                                  + dt_bias.astype(f32)[:, None])
+
+        # each branch rematerialised by itself: the backward pass then
+        # holds one branch's intermediate arrays at a time
+        def branch(fn, *names, **static):
+            return jax.checkpoint(lambda x, *w: fn(x, *w, **static))(
+                x, *(weights[n] for n in names))
+
+        d = weights["wq"].shape[-1]
+        q = branch(mixed, "wq", "conv_q", unit=True) * d ** -0.5
+        k = branch(mixed, "wk", "conv_k", unit=True)
+        v = branch(mixed, "wv", "conv_v", unit=False)
+        g = branch(decay, "wf_a", "wf_b", "A_log", "dt_bias")
+        beta = jnp.swapaxes(
+            jax.nn.sigmoid(mm("bte,eh->bth", x, weights["wb"])), 1, 2)
+        gate = jax.nn.sigmoid(branch(low_rank, "wg_a", "wg_b"))
+        return q, k, v, g, beta, gate
+
+    def emit(self, params, inputs, weights, ctx, name):
+        (x,) = inputs
+        if getattr(ctx, "kv_mode", None) is not None:
+            raise NotImplementedError(
+                f"{name}: the gated delta rule has no decode path that "
+                f"carries its state beside a KV cache")
+        mdt = compute_dtype(ctx, x.dtype)
+        chunk = int(params.get("chunk", CHUNK))
+        h, d = weights["wq"].shape[1:]
+        b, t = x.shape[:2]
+        if events.enabled():
+            chunks = -(-t // chunk)
+            events.instant("kda.scan", layer=name, heads=h, head_dim=d,
+                           taps=weights["conv_q"].shape[-1],
+                           tokens=b * t, chunk=chunk, chunks=chunks,
+                           state_bytes=4 * b * chunks * h * d * d)
+
+        # The layer is rematerialised whole, and inside it each branch
+        # of the projections once more: what it keeps for the backward
+        # pass is its input, and while the recurrence's backward runs,
+        # the five arrays the recurrence read. The projections,
+        # convolutions and gates are a dozen (tokens, H x d) float32
+        # arrays, 1.9 GB a layer at 8192 tokens; the price is the
+        # layer's forward pass run once more.
+        @jax.checkpoint
+        def layer(x, weights):
+            q, k, v, g, beta, gate = self.projections(x, weights, mdt)
+            with jax.named_scope("kda.scan"):
+                o, least = gated_delta_rule(q, k, v, g, beta, chunk, mdt)
+            y = _rms(o, weights["o_norm"], params.get("eps", 1e-5)) * gate
+            return jnp.einsum("bhtd,hde->bte", y.astype(mdt),
+                              weights["wo"].astype(mdt),
+                              preferred_element_type=jnp.float32), least
+
+        out, least = layer(x, weights)
+        # counters add over layers and steps: the sum of each scan's
+        # most negative running log-decay, beside the number of scans
+        ctx.count("kda.log_decay_min", least)
+        ctx.count("kda.scans", jnp.float32(1.0))
+        return [out.astype(x.dtype)]
+
+    def flops(self, params, in_shapes, out_shapes):
+        """By the recurrent form, which no implementation changes: a
+        head-token decays the state (d^2), reads it twice (S^T k, S^T q:
+        2 d^2 each) and writes it once (2 d^2)."""
+        tokens = float(np.prod(in_shapes[0][:-1]))
+        e = in_shapes[0][-1]
+        h, d, k = params["num_heads"], params["head_dim"], params["taps"]
+        r = params.get("gate_rank") or d
+        proj = 4 * e * h * d + 2 * (e * r + r * h * d) + e * h
+        return tokens * (2.0 * proj + 3 * (2 * k + 1) * h * d
+                         + 7.0 * h * d * d)
+
+    def backward_flops_factor(self):
+        return 2.0

@@ -32,6 +32,20 @@ def _fan_in_out(shape: Tuple[int, ...]) -> Tuple[int, int]:
     return shape[1] * receptive, shape[0] * receptive
 
 
+def _mapped(name, draw, xp):
+    """``init_args["map"]``: a function of a UNIFORM draw, for a weight
+    whose published initialisation is a distribution's image (``xp`` is
+    numpy or jax.numpy)."""
+    if name is None:
+        return draw
+    if name == "log":
+        return xp.log(draw)
+    if name == "inverse_softplus_of_exp":      # softplus(result) = e^draw
+        dt = xp.exp(draw)
+        return dt + xp.log(-xp.expm1(-dt))
+    raise ValueError(f"unknown map {name!r} of a uniform draw")
+
+
 def initialize_host(spec, key_ints, np_dtype):
     """Host-side twin of :func:`initialize`: numpy Philox keyed by the
     integer path ``key_ints`` (deterministic across runs/platforms).
@@ -64,7 +78,8 @@ def initialize_host(spec, key_ints, np_dtype):
     gen = np.random.Generator(np.random.Philox(key=key))
     if kind == InitializerType.UNIFORM:
         lo, hi = args.get("min", -0.05), args.get("max", 0.05)
-        return gen.uniform(lo, hi, shape).astype(np_dtype)
+        return _mapped(args.get("map"), gen.uniform(lo, hi, shape),
+                       np).astype(np_dtype)
     if kind == InitializerType.NORMAL:
         mean, std = args.get("mean", 0.0), args.get("stddev", 0.05)
         return (mean + std * gen.standard_normal(shape)).astype(np_dtype)
@@ -88,7 +103,9 @@ def initialize(spec, rng, jnp_dtype):
         return jnp.full(shape, args.get("value", 0.0), jnp_dtype)
     if kind == InitializerType.UNIFORM:
         lo, hi = args.get("min", -0.05), args.get("max", 0.05)
-        return jax.random.uniform(rng, shape, jnp_dtype, lo, hi)
+        return _mapped(args.get("map"),
+                       jax.random.uniform(rng, shape, jnp_dtype, lo, hi),
+                       jnp)
     if kind == InitializerType.NORMAL:
         mean, std = args.get("mean", 0.0), args.get("stddev", 0.05)
         return mean + std * jax.random.normal(rng, shape, jnp_dtype)

@@ -77,6 +77,19 @@ def options_for(layer: Layer) -> List[ShardOption]:
         # halo of taps - 1 positions (the plan verifier refuses it)
         opts.append(ShardOption("parameter", -1,
                                 (("w_in", 2), ("taps", 0), ("w_out", 0))))
+    elif t == OperatorType.OP_GATED_DELTA_RULE:
+        sample()
+        # head-parallel: heads are independent recurrences; every weight
+        # with a head dim co-shards and the output stays whole on hidden
+        # (all-reduce after wo, as attention's). The low-rank gates'
+        # first halves and the norm's scale are replicated. The sequence
+        # dim is NOT offered: a shard would need a halo of taps - 1
+        # positions AND the state its neighbour leaves (the plan
+        # verifier refuses it)
+        opts.append(ShardOption("parameter", -1, (
+            ("wq", 1), ("wk", 1), ("wv", 1), ("conv_q", 0), ("conv_k", 0),
+            ("conv_v", 0), ("wf_b", 1), ("A_log", 0), ("dt_bias", 0),
+            ("wb", 1), ("wg_b", 1), ("wo", 0))))
     elif t == OperatorType.OP_LAYERNORM or t == OperatorType.OP_RMSNORM:
         sample()
         if r >= 3:

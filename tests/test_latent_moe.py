@@ -355,6 +355,7 @@ def test_a_budget_forced_on_an_overflowing_step_shows_as_dropped(
 @pytest.mark.parametrize("tokens,k,held,published,budget", [
     (4096, 8, 16, 256, 4096),        # joyai_llm_flash.train.1chip: 1/8
     (8192, 4, 8, 64, 8192),          # lfm2_24b_a2b.train.1chip: 1/4
+    (4096, 8, 8, 256, 2048),         # a 32nd held, at the default 2 shares
     (4096, 8, 256, 256, 32768),      # every expert held: every row
     (4096, 8, 128, 256, 32768),      # half of them: twice that is all
     (1000, 6, 5, 160, 512),          # 375 rows wanted: rounded up

@@ -617,6 +617,7 @@ def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
     it."""
     import jax
 
+    from flexflow_tpu.kernels.gated_delta_rule import takes_kernel
     from flexflow_tpu.models.nlp import build_latent_moe
     from flexflow_tpu.obs import events
     chip = jax.devices()[0].platform != "cpu"
@@ -647,6 +648,25 @@ def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
         f"{scan['chunks']} chunks of {scan['chunk']}, "
         f"{scan['state_bytes'] / 2 ** 20:.0f} MiB of chunk-boundary "
         f"states a layer")
+    kernels = {}
+    for e in events.events():
+        if e["name"] == "kda.kernel":
+            kernels.setdefault(e["attrs"]["kernel"], e["attrs"])
+    took = {a["impl"] for a in seen["kda.scan"].values()}
+    want = "kernel" if takes_kernel(scan["chunk"], scan["head_dim"],
+                                    scan["head_dim"]) else "plain"
+    say(f"{label}: the chunks' terms by {sorted(took)} (the shapes say "
+        f"{want})")
+    for kind, a in sorted(kernels.items()):
+        say(f"{label}: kda.kernel {kind}: {a['grid_steps']} grid steps of "
+            f"{a['chunks_per_step']} chunks of {a['chunk']} (sub-blocks of "
+            f"{a['sub']}), {a['vmem_bytes'] / 2 ** 20:.1f} MiB of VMEM a "
+            f"step")
+    check(took == {want} and sorted(kernels) == (
+        ["bwd", "fwd"] if want == "kernel" else []),
+          f"{label}: the linear-attention layers announced "
+          f"{ {n: a['impl'] for n, a in seen['kda.scan'].items()} } and "
+          f"the kernels {sorted(kernels)} where the shapes say {want}")
     check(all(a["q_rank"] is None and a["rope"] is False
               for a in seen["attn.latent"].values()),
           f"{label}: latent attention built as {seen['attn.latent']}")
@@ -673,7 +693,14 @@ def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
     say(f"{label}: not checked here: the recurrence and the gradients "
         f"against the token-by-token reference: python3 "
         f"{VALIDATION_LINEAR}")
-    _compiled_step_size(ff, x, y, label)
+    n_cc = _compiled_step_size(ff, x, y, label)
+    if chip and kernels:
+        # at the benchmark's size 131 before the kernels (PERF.md section
+        # 6, PR 35): each layer's forward passes and its backward add one
+        # call each
+        say(f"{label}: {n_cc} Mosaic calls a step; the benchmark's cell "
+            f"counted 131 without the linear-attention kernels, "
+            f"{n_cc - 131} fewer")
 
 
 def _check_generate(ff, ids) -> None:

@@ -19,7 +19,8 @@ failure):
      against the token-by-token reference, each held to twice what that
      reference itself reads with bf16 operands; and the most negative
      in-chunk running log-decay, which says whether ``exp(-G)`` would
-     have overflowed;
+     have overflowed; then the chunks' terms timed alone at that
+     shape, the two kernels and the plain code they replace, ms a call;
   2. per seed at one sequence of ``--seq`` positions: the head's
      log-probabilities against the reference (``|sys - ref|_2 /
      |ref|_2``, the runner's measure);
@@ -123,6 +124,50 @@ def recurrence(ref, seq, heads=32, d=128):
               f"{eb:.3e}")
 
 
+def kernels_alone(seq, heads=32, d=128, chunk=64, calls=20):
+    """The chunks' terms timed alone at the recurrence's shape, host
+    clock around ``calls`` calls that end in ``block_until_ready``: the
+    forward and the backward kernel, and the plain ``_chunk_terms`` and
+    its autodiff beside them."""
+    import time
+
+    from flexflow_tpu.kernels.gated_delta_rule import chunk_terms
+    from flexflow_tpu.ops.recurrent_ops import _chunk_terms, _in_chunks
+    rng = np.random.default_rng(36)
+    shape = (1, heads, seq, d)
+    q, k, v = (jnp.asarray(rng.normal(size=shape).astype(np.float32)
+                           / d ** 0.5) for _ in range(3))
+    g = jnp.asarray(-rng.uniform(0.01, 2.0, shape).astype(np.float32))
+    beta = jnp.asarray(rng.uniform(0.05, 0.95, shape[:3]
+                                   ).astype(np.float32))
+    args = (q, k, v, g, beta)
+
+    def kernel(*a):
+        return tuple(chunk_terms(*a, chunk, jnp.bfloat16)[:6])
+
+    def plain(*a):
+        return tuple(_chunk_terms(*(_in_chunks(x, chunk) for x in a),
+                                  jnp.bfloat16)[:6])
+
+    for name, fn in (("kernel", kernel), ("plain", plain)):
+        fwd = jax.jit(fn)
+        cts = jax.tree.map(jnp.ones_like, fwd(*args))
+        # (the backward reads no output of the forward: XLA drops it)
+        bwd = jax.jit(lambda a, c, fn=fn: jax.vjp(fn, *a)[1](c))
+        for what, call in (("forward", lambda: fwd(*args)),
+                           ("backward", lambda: bwd(args, cts))):
+            jax.block_until_ready(call())
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                out = call()
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / calls * 1e3
+            READINGS[f"chunk terms {name} {what} ms"] = ms
+            print(f"chunk terms at {seq} x {heads} x {d}, chunks of "
+                  f"{chunk}: {name} {what} {ms:.3f} ms a call",
+                  flush=True)
+
+
 def forward_checks(conf, ref, seq, seeds):
     ff = build(conf, seq, "none")
     sizes = dict(conf)
@@ -201,6 +246,7 @@ def main():
     ref = cells.load_module(BENCH, "reference", "linear_latent_moe_ref")
     if not args.skip_recurrence:
         recurrence(ref, args.seq)
+        kernels_alone(args.seq)
     forward_checks(conf, ref, args.seq, args.seeds)
     if not args.skip_gradients:
         gradient_checks(conf, ref, args.seeds[0])

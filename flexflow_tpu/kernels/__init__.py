@@ -15,6 +15,9 @@ with:
     attention.
   - ``opt_update``: fused one-HBM-pass Adam update for the ZeRO-sharded
     optimizer path (the ``opt_update:fused`` kernel tier).
+  - ``gated_delta_rule``: the in-chunk terms of linear attention's chunked
+    recurrence (fwd+bwd), taken by ``ops/recurrent_ops.py`` where the
+    shapes allow; not a registry entry.
 
 ``registry`` makes the implementation choice a searched dimension: per-op
 variants with availability predicates and calibrated cost entry points

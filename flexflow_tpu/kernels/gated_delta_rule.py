@@ -1,0 +1,526 @@
+"""The gated delta rule's in-chunk terms as Pallas TPU kernels (forward +
+backward).
+
+``ops/recurrent_ops.py`` runs the recurrence in chunks of ``C`` tokens:
+everything of a chunk that does not depend on the state it starts from
+(``A``, ``B``, ``(I + Diag(beta) A)^-1``, ``W``, ``U0``, the decayed
+copies of q and k) is computed for all chunks at once, and a ``lax.scan``
+over the chunks carries the state. This module is that first half. A grid
+step takes a few chunks of one (batch, head): it reads q, k, v, g, beta of
+those tokens into VMEM once, and what XLA's version of the same algebra
+(:func:`flexflow_tpu.ops.recurrent_ops._chunk_terms`, the fallback and
+the tests' oracle) writes to HBM between its fusions stays there: the
+running log-decay ``G``, the ``(SUB, SUB, d)`` differences of a
+sub-block against itself, ``A``, the inverse. There is no triangular
+solve: the ``SUB x SUB`` diagonal blocks of ``I + N`` (``N = Diag(beta)
+A``, strictly lower) are inverted by the nilpotent doubling ``(I - N)(I +
+N^2)(I + N^4)(I + N^8)``, exact because ``N^SUB = 0``, and merged a level
+of halves at a time, ``T <- T - T N_level T``; all of it float32 products
+(``Precision.HIGHEST``).
+
+Every exponent taken is a difference of running log-decays that is <= 0,
+as in the plain code and for its reason (a chunk's decays sum past
+float32's -88.7): a sub-block against itself through ``G_i - G_j`` for
+``j <= i``; the later half of a span against its earlier half through the
+later half's first row ``n``, ``exp(G_i - G_n)`` on the rows' side and
+``exp(G_n - G_j)`` on the columns' (one exponential an element a level:
+a row is on one side or the other).
+
+The outputs leave in the layout the scan reads, chunk leading. The
+backward kernel takes the cotangents of the six terms and returns ``dq,
+dk, dv, dg, dbeta`` in float32; the residuals of the ``custom_vjp`` are
+the five inputs and nothing else: ``A`` and the inverse are formed again
+in VMEM (``d(M^-1) = -M^-T dT M^-T``).
+
+Products round their operands to ``mdt`` where the plain code does (the
+spans' products, ``W``, ``U0`` and their transposes in the backward);
+running sums, exponentials, the sub-blocks' sums, the inverse and the
+gradients are float32.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..obs import events
+from ._interpret import pallas_interpret
+
+SUB = 16                # rows of a sub-block of a chunk
+LANES = 128
+#: chunks of one (batch, head) a grid step takes: the blocks of the small
+#: operands (beta, the last row's decay) want 8 rows or all of them, and
+#: eight chunks' independent chains of float32 products fill the matrix
+#: unit's pipeline where one chunk's ten dependent ones wait on it
+CHUNKS_PER_STEP = 8
+F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def takes_kernel(chunk: int, dk: int, dv: int) -> bool:
+    """Whether these shapes run the kernels: a chunk that is a
+    power-of-two number of sub-blocks, head sizes in whole lanes."""
+    spans = chunk // SUB
+    return (chunk % SUB == 0 and spans > 0 and spans & (spans - 1) == 0
+            and dk % LANES == 0 and dv % LANES == 0)
+
+
+# ---------------------------------------------------------------------------
+# pieces of a grid step: values (n, c, .) of n chunks, float32
+# ---------------------------------------------------------------------------
+def _mm(a, b, ta=False, tb=False, mdt=None):
+    """Batched over the leading axis: ``a @ b`` with ``a`` / ``b``
+    transposed on request. ``mdt`` None: an exact float32 product;
+    otherwise the operands are rounded to ``mdt`` and summed in
+    float32."""
+    if mdt is not None:
+        a, b = a.astype(mdt), b.astype(mdt)
+    return jax.lax.dot_general(
+        a, b, (((1 if ta else 2,), (2 if tb else 1,)), ((0,), (0,))),
+        precision=_HIGHEST if mdt is None else None,
+        preferred_element_type=F32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _halves(c):
+    """The halves a chunk's spans are split at: SUB, 2 SUB, .. c / 2."""
+    out, half = [], SUB
+    while half < c:
+        out.append(half)
+        half *= 2
+    return out
+
+
+def _running(g):
+    """The running sum of ``g`` inside each chunk, as a product with the
+    lower triangle of ones."""
+    n, c, _ = g.shape
+    low = (_iota((n, c, c), 2) <= _iota((n, c, c), 1)).astype(F32)
+    return _mm(low, g)
+
+
+def _column(row):
+    """(n, 1, c) values along the lanes -> (n, c, 1) down the rows."""
+    n, _, c = row.shape
+    eye = _iota((n, c, c), 1) == _iota((n, c, c), 2)
+    return jnp.sum(jnp.where(eye, row, 0.0), -1, keepdims=True)
+
+
+def _sub_rows(x):
+    """(n, c, d) -> (n c / SUB, SUB, d): a sub-block a leading index."""
+    return x.reshape(-1, SUB, x.shape[-1])
+
+
+def _own_column(n, c):
+    """(n c, c) int32: a column's offset from the first column of its
+    row's own sub-block (0 .. SUB - 1 inside it), and the row's offset
+    inside its sub-block."""
+    row, col = _iota((n * c, c), 0), _iota((n * c, c), 1)
+    i_loc = row & (SUB - 1)
+    return col - ((row & (c - 1)) - i_loc), i_loc
+
+
+def _sub_block_terms(big_g, k, q):
+    """A sub-block against itself: ``A`` (strictly lower) and, with
+    ``q``, ``B`` (lower) on the diagonal ``SUB x SUB`` blocks of (n, c,
+    c), zero elsewhere. A column ``j`` of every sub-block a step: the
+    differences ``G_i - G_j`` of its rows, their exponentials and the two
+    sums over the channels are made and reduced where they are."""
+    n, c, _ = k.shape
+    gs, ks = _sub_rows(big_g), _sub_rows(k)
+    qs = None if q is None else _sub_rows(q)
+    sub_row = _iota(gs.shape, 1)
+    rel, i_loc = _own_column(n, c)
+    acc_a = jnp.zeros((n * c, c), F32)
+    acc_b = None if q is None else jnp.zeros((n * c, c), F32)
+    for j in range(SUB):
+        e = jnp.exp(jnp.where(sub_row >= j, gs - gs[:, j:j + 1], 0.0))
+        t = e * ks[:, j:j + 1]
+        hit = rel == j
+        acc_a = jnp.where(hit, jnp.sum(ks * t, -1, keepdims=True
+                                       ).reshape(n * c, 1), acc_a)
+        if q is not None:
+            acc_b = jnp.where(hit, jnp.sum(qs * t, -1, keepdims=True
+                                           ).reshape(n * c, 1), acc_b)
+    a = jnp.where(rel < i_loc, acc_a, 0.0).reshape(n, c, c)
+    if q is None:
+        return a, None
+    return a, jnp.where(rel <= i_loc, acc_b, 0.0).reshape(n, c, c)
+
+
+def _span_decay(big_g, half):
+    """A level of spans of ``2 half`` rows, each through its later half's
+    first row ``n``: ``exp(G_i - G_n)`` on the later half's rows,
+    ``exp(G_n - G_j)`` on the earlier half's, both <= 0 because ``G``
+    falls along a chunk. Returns it (n, c, d) and which rows are
+    later-half ones."""
+    shape = big_g.shape
+    g3 = big_g.reshape(-1, 2 * half, shape[-1])
+    diff = g3 - g3[:, half:half + 1]
+    e = jnp.exp(jnp.where(_iota(g3.shape, 1) >= half, diff, -diff))
+    return e.reshape(shape), (_iota(shape, 1) & half) != 0
+
+
+def _span_mask(n, c, half):
+    """(n, c, c): row in the later half and column in the earlier half
+    of one span of ``2 half`` rows."""
+    row, col = _iota((n, c, c), 1), _iota((n, c, c), 2)
+    span = 2 * half
+    same = (row - (row & (span - 1))) == (col - (col & (span - 1)))
+    return same & ((row & half) != 0) & ((col & half) == 0)
+
+
+def _inverse(n_sub, n_spans):
+    """``(I + N)^-1`` for ``N = n_sub + sum(n_spans)`` strictly lower:
+    ``n_sub`` on the diagonal sub-blocks, ``n_spans[l]`` on level l's
+    off-diagonal halves. Float32 throughout."""
+    n, c, _ = n_sub.shape
+    eye = (_iota((n, c, c), 1) == _iota((n, c, c), 2)).astype(F32)
+    t, x, p = eye - n_sub, n_sub, 2
+    while p < SUB:                      # (I - N)(I + N^2)(I + N^4)..
+        x = _mm(x, x)
+        t = t + _mm(t, x)
+        p *= 2
+    for level in n_spans:               # [[P, 0], [L, Q]]^-1
+        t = t - _mm(_mm(t, level), t)
+    return t
+
+
+def _in_chunk(q, k, g, beta_row, mdt, with_b):
+    """What forward and backward both form: the running log-decay, beta
+    down the rows, ``A``, ``B`` (forward only), each level's decayed
+    copies and mask, and ``(I + Diag(beta) A)^-1``."""
+    n, c, _ = k.shape
+    big_g = _running(g)
+    beta = _column(beta_row)
+    a, b = _sub_block_terms(big_g, k, q if with_b else None)
+    n_sub, n_spans, levels = beta * a, [], []
+    for half in _halves(c):
+        e, later = _span_decay(big_g, half)
+        ke, qe = k * e, q * e
+        mask = _span_mask(n, c, half)
+        a_l = jnp.where(mask, _mm(ke, ke, tb=True, mdt=mdt), 0.0)
+        a = a + a_l
+        n_spans.append(beta * a_l)
+        if with_b:
+            b = b + jnp.where(mask, _mm(qe, ke, tb=True, mdt=mdt), 0.0)
+        levels.append((half, e, later, ke, qe, mask))
+    return big_g, beta, a, b, levels, _inverse(n_sub, n_spans)
+
+
+def _last_row(x):
+    """(n, c, d) -> (n, 1, d), the chunk's last row, as a sum over the
+    rows with the others masked (Mosaic takes a row out of the middle of
+    a tile as a broadcast's operand, not as a value to store)."""
+    last = _iota(x.shape, 1) == x.shape[1] - 1
+    return jnp.sum(jnp.where(last, x, 0.0), 1, keepdims=True)
+
+
+def _load(ref, n):
+    x = ref[...].astype(F32)
+    return x.reshape(n, -1, x.shape[-1])
+
+
+# ---------------------------------------------------------------------------
+# forward kernel: grid (batch x head, groups of chunks)
+# ---------------------------------------------------------------------------
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref, b_ref,
+                qd_ref, kd_ref, dec_ref, least_ref, *, mdt):
+    n = beta_ref.shape[0]
+    q, k, v, g = (_load(r, n) for r in (q_ref, k_ref, v_ref, g_ref))
+    big_g, beta, _, b, _, t = _in_chunk(q, k, g, beta_ref[...], mdt, True)
+    decay = jnp.exp(big_g)
+    g_last = _last_row(big_g)
+    w_ref[...] = _mm(t, beta * k * decay, mdt=mdt).astype(w_ref.dtype)
+    u_ref[...] = _mm(t, beta * v, mdt=mdt)
+    b_ref[...] = b.astype(b_ref.dtype)
+    qd_ref[...] = (q * decay).astype(qd_ref.dtype)
+    kd_ref[...] = (k * jnp.exp(g_last - big_g)).astype(kd_ref.dtype)
+    dec_ref[...] = jnp.exp(g_last)
+    least_ref[...] = jnp.min(big_g, axis=1, keepdims=True)
+
+
+# ---------------------------------------------------------------------------
+# backward kernel: same grid
+# ---------------------------------------------------------------------------
+def _sub_block_grads(big_g, k, q, da, db):
+    """The cotangents ``da`` (strictly lower) and ``db`` (lower) of the
+    diagonal sub-blocks of ``A`` and ``B``, (n, c, c) and zero off
+    them, back to k, q and ``G``: (n, c, d) each."""
+    n, c, d = k.shape
+    gs, ks, qs = _sub_rows(big_g), _sub_rows(k), _sub_rows(q)
+    sub_row = _iota(gs.shape, 1)
+    # a sub-block's SUB columns of da, db, gathered beside its rows by an
+    # exact product with a 0 / 1 matrix: (n c / SUB, SUB, SUB)
+    pick = ((_iota((n, c, SUB), 1) & (SUB - 1)) == _iota((n, c, SUB), 2)
+            ).astype(F32)
+    das = _mm(da, pick).reshape(-1, SUB, SUB)
+    dbs = _mm(db, pick).reshape(-1, SUB, SUB)
+    dks, dqs, dgs = (jnp.zeros(gs.shape, F32) for _ in range(3))
+    col_k = jnp.zeros(gs.shape, F32)    # row j: what column j sent to k_j
+    for j in range(SUB):
+        e = jnp.exp(jnp.where(sub_row >= j, gs - gs[:, j:j + 1], 0.0))
+        t = e * ks[:, j:j + 1]
+        ca, cb = das[:, :, j:j + 1], dbs[:, :, j:j + 1]
+        u = ca * ks + cb * qs
+        dks = dks + ca * t
+        dqs = dqs + cb * t
+        dgs = dgs + u * t
+        col_k = jnp.where(sub_row == j,
+                          jnp.sum(u * e, 1, keepdims=True), col_k)
+    # G_j takes minus what its rows took: k_j times what k_j took
+    return ((dks + col_k).reshape(n, c, d), dqs.reshape(n, c, d),
+            (dgs - ks * col_k).reshape(n, c, d))
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, dw_ref, du_ref,
+                db_ref, dqd_ref, dkd_ref, ddec_ref, dq_ref, dk_ref, dv_ref,
+                dg_ref, dbeta_ref, *, mdt):
+    n = beta_ref.shape[0]
+    q, k, v, g = (_load(r, n) for r in (q_ref, k_ref, v_ref, g_ref))
+    c = k.shape[1]
+    big_g, beta, a, _, levels, t = _in_chunk(q, k, g, beta_ref[...], mdt,
+                                             False)
+    dw, du, db, dqd, dkd = (r[...].astype(F32) for r in (
+        dw_ref, du_ref, db_ref, dqd_ref, dkd_ref))
+    decay = jnp.exp(big_g)
+    k_dec = k * decay
+    # W = T (beta k exp G), U0 = T (beta v)
+    xw, xu = beta * k_dec, beta * v
+    dt = _mm(dw, xw, tb=True, mdt=mdt) + _mm(du, xu, tb=True, mdt=mdt)
+    dxw, dxu = _mm(t, dw, ta=True, mdt=mdt), _mm(t, du, ta=True, mdt=mdt)
+    # T = (I + Diag(beta) A)^-1
+    dm = -_mm(_mm(t, dt, ta=True), t, tb=True)
+    da = beta * dm
+    dbeta = (jnp.sum(dm * a, -1, keepdims=True)
+             + jnp.sum(dxw * k_dec, -1, keepdims=True)
+             + jnp.sum(dxu * v, -1, keepdims=True))
+    dv = beta * dxu
+    dk = dxw * beta * decay
+    d_g = dxw * xw
+    # q exp(G), k exp(G_C - G), exp(G_C)
+    dq = dqd * decay
+    d_g = d_g + dqd * q * decay
+    g_last = _last_row(big_g)
+    fall = jnp.exp(g_last - big_g)
+    dk = dk + dkd * fall
+    through = dkd * k * fall
+    d_g = d_g - through
+    row = _iota(d_g.shape, 1)
+    d_g = d_g + jnp.where(
+        row == c - 1, jnp.sum(through, 1, keepdims=True)
+        + ddec_ref[...] * jnp.exp(g_last), 0.0)
+    # the spans, a level at a time
+    for half, e, later, ke, qe, mask in levels:
+        da_l, db_l = jnp.where(mask, da, 0.0), jnp.where(mask, db, 0.0)
+        dke = (_mm(da_l, ke, mdt=mdt) + _mm(da_l, ke, ta=True, mdt=mdt)
+               + _mm(db_l, qe, ta=True, mdt=mdt))
+        dqe = _mm(db_l, ke, mdt=mdt)
+        dk = dk + dke * e
+        dq = dq + dqe * e
+        z = dke * ke + dqe * qe
+        z = jnp.where(later, z, -z)     # the exponent is +-(G - G_n)
+        z3 = z.reshape(-1, 2 * half, z.shape[-1])
+        z3 = z3 - jnp.where(_iota(z3.shape, 1) == half,
+                            jnp.sum(z3, 1, keepdims=True), 0.0)
+        d_g = d_g + z3.reshape(z.shape)
+    # the sub-blocks against themselves
+    rel, i_loc = _own_column(n, c)
+    on_a = (rel >= 0) & (rel < i_loc)
+    on_b = (rel >= 0) & (rel <= i_loc)
+    dks, dqs, dgs = _sub_block_grads(
+        big_g, k, q, jnp.where(on_a.reshape(da.shape), da, 0.0),
+        jnp.where(on_b.reshape(db.shape), db, 0.0))
+    d_g = d_g + dgs
+    # g's cotangent: the running sum's transpose
+    up = (_iota((n, c, c), 2) >= _iota((n, c, c), 1)).astype(F32)
+    dq_ref[...] = (dq + dqs).reshape(dq_ref.shape)
+    dk_ref[...] = (dk + dks).reshape(dk_ref.shape)
+    dv_ref[...] = dv.reshape(dv_ref.shape)
+    dg_ref[...] = _mm(up, d_g).reshape(dg_ref.shape)
+    eye = _iota((n, c, c), 1) == _iota((n, c, c), 2)
+    dbeta_ref[...] = jnp.sum(jnp.where(eye, dbeta, 0.0), 1, keepdims=True)
+
+
+# ---------------------------------------------------------------------------
+# the calls
+# ---------------------------------------------------------------------------
+#: Mosaic's scoped-VMEM limit for these kernels: a v5e core has 128 MiB,
+#: and the default limit of 16 MiB is under a backward step's working set
+#: at eight chunks (:func:`vmem_bytes`).
+VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def vmem_bytes(kernel, chunk, dk, dv, itemsize, per_step=CHUNKS_PER_STEP):
+    """Working set of one grid step: the double-buffered operand and
+    output blocks and the float32 (rows, d) and (rows, chunk) values the
+    step holds at once (a (rows, chunk) value fills whole lanes in VMEM).
+    The counts of values are held to Mosaic for a described v5e at cell
+    5's shape: the forward compiles under a limit of 12 MiB and not of
+    10, the backward under 18 and not 16 (PERF.md section 6, PR 36)."""
+    rows = per_step * chunk
+    wide = rows * max(dk, dv) * 4
+    square = rows * max(chunk, LANES) * 4
+    small = per_step * 8 * LANES * 4
+    inputs = rows * (3 * dk + dv) * 4 + small
+    terms = (rows * (3 * dk * itemsize + dv * 4)
+             + rows * max(chunk, LANES) * itemsize + 2 * small)
+    if kernel == "fwd":
+        return 2 * (inputs + terms) + 18 * wide + 12 * square
+    return 2 * (2 * inputs + terms) + 32 * wide + 16 * square
+
+
+def _specs(bh_first, rows, *last):
+    """A block of ``rows`` along the axis after (before) the batch x
+    head axis, whole in the axes after it."""
+    zeros = (0,) * len(last)
+    if bh_first:
+        return pl.BlockSpec((None, rows) + last,
+                            lambda b, i: (b, i) + zeros)
+    return pl.BlockSpec((rows, None) + last, lambda b, i: (i, b) + zeros)
+
+
+def _layout(q, v, chunk, per_step):
+    bh, t, dk = q.shape
+    dv, n = v.shape[2], t // chunk
+    rows = per_step * chunk
+    inputs = [_specs(True, rows, dk)] * 2 + [
+        _specs(True, rows, dv), _specs(True, rows, dk),
+        _specs(True, per_step, 1, chunk)]
+    terms = [_specs(False, per_step, chunk, dk),        # W
+             _specs(False, per_step, chunk, dv),        # U0
+             _specs(False, per_step, chunk, chunk),     # B
+             _specs(False, per_step, chunk, dk),        # q exp(G)
+             _specs(False, per_step, chunk, dk),        # k exp(G_C - G)
+             _specs(False, per_step, 1, dk)]            # exp(G_C)
+    return (bh, n // per_step), n, inputs, terms
+
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel"),
+    vmem_limit_bytes=VMEM_LIMIT)
+_STATIC = ("chunk", "per_step", "mdt", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
+def _fwd_call(q, k, v, g, beta, chunk, per_step, mdt, interpret):
+    grid, n, inputs, terms = _layout(q, v, chunk, per_step)
+    bh, _, dk = q.shape
+    dv = v.shape[2]
+
+    def out(dt, *last):
+        return jax.ShapeDtypeStruct((n, bh) + last, dt)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, mdt=mdt),
+        grid=grid, in_specs=inputs,
+        out_specs=terms + [_specs(False, per_step, 1, dk)],
+        out_shape=[out(mdt, chunk, dk), out(F32, chunk, dv),
+                   out(mdt, chunk, chunk), out(mdt, chunk, dk),
+                   out(mdt, chunk, dk), out(F32, 1, dk), out(F32, 1, dk)],
+        compiler_params=_PARAMS, interpret=interpret,
+        name="gated_delta_rule_fwd",
+    )(q, k, v, g, beta)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
+def _bwd_call(q, k, v, g, beta, cts, chunk, per_step, mdt, interpret):
+    grid, _, inputs, terms = _layout(q, v, chunk, per_step)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, mdt=mdt),
+        grid=grid, in_specs=inputs + terms, out_specs=inputs,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, F32)
+                   for x in (q, k, v, g, beta)],
+        compiler_params=_PARAMS, interpret=interpret,
+        name="gated_delta_rule_bwd",
+    )(q, k, v, g, beta, *cts)
+
+
+def _note(kernel, layer, q, v, chunk, per_step, mdt):
+    """One ``kda.kernel`` instant per emitted call, at trace time."""
+    if events.enabled():
+        bh, t, dk = q.shape
+        n = t // chunk
+        events.instant(
+            "kda.kernel", kernel=kernel, layer=layer, chunk=chunk, sub=SUB,
+            chunks=bh * n, grid_steps=bh * n // per_step,
+            chunks_per_step=per_step,
+            vmem_bytes=vmem_bytes(kernel, chunk, dk, v.shape[2],
+                                  jnp.dtype(mdt).itemsize, per_step))
+
+
+def _noted_fwd_call(q, k, v, g, beta, chunk, per_step, mdt, layer,
+                    interpret):
+    _note("fwd", layer, q, v, chunk, per_step, mdt)
+    return tuple(_fwd_call(q, k, v, g, beta, chunk, per_step, mdt,
+                           interpret))
+
+
+_terms = jax.custom_vjp(_noted_fwd_call, nondiff_argnums=(5, 6, 7, 8, 9))
+
+
+def _terms_fwd(q, k, v, g, beta, *static):
+    return _noted_fwd_call(q, k, v, g, beta, *static), (q, k, v, g, beta)
+
+
+def _terms_bwd(chunk, per_step, mdt, layer, interpret, res, cts):
+    _note("bwd", layer, res[0], res[2], chunk, per_step, mdt)
+    # (the least running log-decay is a reading, not a term)
+    return tuple(_bwd_call(*res, list(cts[:6]), chunk, per_step, mdt,
+                           interpret))
+
+
+_terms.defvjp(_terms_fwd, _terms_bwd)
+
+
+def chunk_terms(q, k, v, g, beta, chunk, mdt, *, layer=None,
+                interpret=None, mesh=None, spec=None):
+    """The state-independent terms of every chunk, by the kernels:
+    ``q``, ``k``, ``g``: (B, H, T, dk), ``v``: (B, H, T, dv), ``beta``:
+    (B, H, T), float32. Returns what ``lax.scan`` over the chunks reads,
+    chunk leading: ``W`` (N, B, H, C, dk), ``U0`` (.., C, dv) float32,
+    ``B`` (.., C, C), ``q exp(G)``, ``k exp(G_C - G)`` in ``mdt``,
+    ``exp(G_C)`` (N, B, H, dk) float32; and the least running log-decay
+    a chunk and channel, (N, B, H, dk). ``T`` is padded to whole grid
+    steps (``N`` counts the padded chunks): a padded token writes
+    nothing (beta 0) and decays nothing (g 0).
+
+    ``mesh`` / ``spec`` as :func:`flash_attention` takes them: under a
+    mesh of more than one device the call runs under ``shard_map`` over
+    the batch and head entries of ``spec``; every (batch, head) is a
+    grid row of its own."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    if mesh is not None and mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+        bh = (tuple(spec or ()) + (None, None))[:2]
+        local = functools.partial(chunk_terms, chunk=chunk, mdt=mdt,
+                                  layer=layer, interpret=interpret)
+        # check_vma off: pallas_call outputs carry no varying-axes info
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(*bh, None, None),) * 4 + (P(*bh, None),),
+            out_specs=(P(None, *bh, None, None),) * 5
+            + (P(None, *bh, None),) * 2, check_vma=False)(q, k, v, g, beta)
+    b, h, t, dk = q.shape
+    n = -(-t // chunk)
+    per_step = min(n, CHUNKS_PER_STEP)
+    n = -(-n // per_step) * per_step
+    pad = n * chunk - t
+
+    def rows(x):
+        x = jnp.pad(x.astype(F32),
+                    ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
+        return x.reshape((b * h,) + x.shape[2:])
+
+    *terms, dec, least = _terms(
+        rows(q), rows(k), rows(v), rows(g),
+        rows(beta).reshape(b * h, n, 1, chunk), chunk, per_step,
+        jnp.dtype(mdt), layer, bool(interpret))
+    return tuple([x.reshape((n, b, h) + x.shape[2:]) for x in terms]
+                 + [dec.reshape(n, b, h, dk), least.reshape(n, b, h, dk)])

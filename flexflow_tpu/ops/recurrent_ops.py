@@ -45,21 +45,23 @@ import numpy as np
 
 from ..core.tensor import WeightSpec
 from ..ffconst import InitializerType, OperatorType
+from ..kernels.gated_delta_rule import SUB, chunk_terms, takes_kernel
 from ..obs import events
-from .nn_ops import _rms, short_conv
+from .nn_ops import MultiHeadAttentionOp, _rms, short_conv
 from .registry import OpDef, compute_dtype, register
 
 CHUNK = 64          # tokens a step of the scan
-SUB = 16            # rows of a sub-block of a chunk
 NORM_EPS = 1e-6     # under the root of q's and k's lengths
 
 
 def _chunk_terms(q, k, v, g, beta, mdt):
     """Everything of a chunk that does not depend on the state it starts
-    from. ``q``, ``k``, ``g``: (B, H, N, C, dk), ``v``: (.., dv),
-    ``beta``: (B, H, N, C); float32. Returns ``W`` (.., C, dk), ``U0``
-    (.., C, dv), ``B`` (.., C, C), ``q * exp(G)``, ``k * exp(G_C - G)``,
-    ``exp(G_C)`` (.., dk) and the least ``G``."""
+    from, in plain JAX: the path of the shapes the kernels do not take,
+    and what the kernels are tested against. ``q``, ``k``, ``g``: (B, H,
+    N, C, dk), ``v``: (.., dv), ``beta``: (B, H, N, C); float32. Returns
+    ``W`` (.., C, dk), ``U0`` (.., C, dv), ``B`` (.., C, C), ``q *
+    exp(G)``, ``k * exp(G_C - G)``, ``exp(G_C)`` (.., dk) and the least
+    ``G``."""
     n_c = k.shape[3]
     lead = k.shape[:3]
     spans = n_c // SUB          # a power of two of sub-blocks, or one
@@ -132,38 +134,50 @@ def _chunk_step(mdt, state, terms):
     return state, out
 
 
+def _in_chunks(x, chunk):
+    """(B, H, T, ..) -> (B, H, N, C, ..) float32; the padded positions
+    write nothing (beta 0) and decay nothing (g 0)."""
+    pad = -x.shape[2] % chunk
+    x = jnp.pad(x.astype(jnp.float32),
+                ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
+    return x.reshape(x.shape[:2] + (-1, chunk) + x.shape[3:])
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
-                     mdt=jnp.float32):
+                     mdt=jnp.float32, *, layer=None, mesh=None, spec=None):
     """The recurrence of the module's docstring from a zero state, in
     chunks, heads leading: ``q``, ``k``, ``g``: (B, H, T, dk), ``g`` <= 0
     the log of the decay; ``v``: (B, H, T, dv); ``beta``: (B, H, T).
     ``mdt``: the type the products' operands are rounded to (sums,
-    states, decays and the triangular solve are float32). Returns ``o``
-    (B, H, T, dv) float32 and the most negative running sum of ``g``
-    inside any chunk.
+    states, decays and the inverse are float32). Returns ``o`` (B, H,
+    T, dv) float32 and the most negative running sum of ``g`` inside any
+    chunk.
 
-    Its backward is autodiff's, with the chunks' terms and the body of
-    the scan each rematerialised: the backward pass holds the
-    chunk-boundary states, the terms the scan reads and one chunk's
-    matrices, not the ``(SUB, SUB, d)`` differences nor every chunk's
-    intermediate products."""
+    The chunks' terms come from the Pallas kernels of
+    ``kernels/gated_delta_rule.py`` where the shapes take them
+    (:func:`takes_kernel`: a chunk of a power-of-two number of
+    sub-blocks, head sizes in whole lanes; ``layer`` names the caller in
+    their ``kda.kernel`` instants, ``mesh`` / ``spec`` are
+    ``flash_attention``'s), forward and backward under one
+    ``custom_vjp`` that keeps the five inputs; otherwise from
+    :func:`_chunk_terms`, rematerialised, with autodiff's backward. The
+    scan over the chunks is plain JAX either way, its body
+    rematerialised: the backward pass holds the chunk-boundary states,
+    the terms the scan reads and one chunk's matrices, not the ``(SUB,
+    SUB, d)`` differences nor every chunk's intermediate products."""
     t = q.shape[2]
-    pad = -t % chunk
-
-    def chunks(x):
-        # (B, H, T, ..) -> (B, H, N, C, ..); padded positions write
-        # nothing (beta 0) and decay nothing (g 0)
-        x = jnp.pad(x.astype(jnp.float32),
-                    ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
-        return x.reshape(x.shape[:2] + (-1, chunk) + x.shape[3:])
-
-    *terms, least = jax.checkpoint(
-        lambda *a: _chunk_terms(*a, mdt))(*map(chunks, (q, k, v, g, beta)))
+    if takes_kernel(chunk, k.shape[-1], v.shape[-1]):
+        *terms, least = chunk_terms(q, k, v, g, beta, chunk, mdt,
+                                    layer=layer, mesh=mesh, spec=spec)
+        least = jnp.min(least)
+    else:
+        *terms, least = jax.checkpoint(lambda *a: _chunk_terms(*a, mdt))(
+            *(_in_chunks(x, chunk) for x in (q, k, v, g, beta)))
+        terms = [jnp.moveaxis(x, 2, 0) for x in terms]
     state = jnp.zeros(k.shape[:2] + (k.shape[-1], v.shape[-1]),
                       jnp.float32)
     _, out = jax.lax.scan(                      # over the chunks: N leads
-        jax.checkpoint(lambda s, xs: _chunk_step(mdt, s, xs)), state,
-        [jnp.moveaxis(x, 2, 0) for x in terms])
+        jax.checkpoint(lambda s, xs: _chunk_step(mdt, s, xs)), state, terms)
     out = jnp.moveaxis(out, 0, 2)                  # (B, H, N, C, dv)
     out = out.reshape(out.shape[:2] + (-1,) + out.shape[4:])[:, :, :t]
     return out, jax.lax.stop_gradient(least)
@@ -189,9 +203,11 @@ class GatedDeltaRuleOp(OpDef):
 
     No bias in any projection. The projections are matrix products at
     the compute dtype with float32 accumulation; taps, gates, norms,
-    decays, the state and the triangular solve are float32. The
-    recurrence (solve and scan, not the projections) runs under the
-    name scope ``kda.scan``. Training and evaluation only: there is no
+    decays, the state and the chunks' inverse are float32. The
+    recurrence (the chunks' terms, by the kernels of
+    ``kernels/gated_delta_rule.py`` at head sizes in whole lanes, and
+    the scan; not the projections) runs under the name scope
+    ``kda.scan``. Training and evaluation only: there is no
     decode path that carries the state from call to call."""
     op_type = OperatorType.OP_GATED_DELTA_RULE
 
@@ -284,7 +300,12 @@ class GatedDeltaRuleOp(OpDef):
             events.instant("kda.scan", layer=name, heads=h, head_dim=d,
                            taps=weights["conv_q"].shape[-1],
                            tokens=b * t, chunk=chunk, chunks=chunks,
-                           state_bytes=4 * b * chunks * h * d * d)
+                           state_bytes=4 * b * chunks * h * d * d,
+                           impl="kernel" if takes_kernel(chunk, d, d)
+                           else "plain")
+        # a compiled kernel inside a multi-device jit runs on each
+        # device's (batch, head) shard, as the attention kernels do
+        mesh, spec = MultiHeadAttentionOp._kernel_shard_spec(ctx, b, h)
 
         # The layer is rematerialised whole, and inside it each branch
         # of the projections once more: what it keeps for the backward
@@ -297,7 +318,9 @@ class GatedDeltaRuleOp(OpDef):
         def layer(x, weights):
             q, k, v, g, beta, gate = self.projections(x, weights, mdt)
             with jax.named_scope("kda.scan"):
-                o, least = gated_delta_rule(q, k, v, g, beta, chunk, mdt)
+                o, least = gated_delta_rule(q, k, v, g, beta, chunk, mdt,
+                                            layer=name, mesh=mesh,
+                                            spec=spec)
             y = _rms(o, weights["o_norm"], params.get("eps", 1e-5)) * gate
             return jnp.einsum("bhtd,hde->bte", y.astype(mdt),
                               weights["wo"].astype(mdt),

@@ -343,3 +343,91 @@ def test_fused_adam_compiles_for_bert_large_leaves(v5e_devices, shape):
         functools.partial(_adam_step, None, None, None),
         *_adam_operands(mesh, shape, P()))
     assert MOSAIC_CALL in txt
+
+
+# ----------------------------------------------------------------------
+# the gated delta rule's in-chunk terms (kernels/gated_delta_rule.py)
+# ----------------------------------------------------------------------
+KDA_NAMES = ["gated_delta_rule_bwd", "gated_delta_rule_fwd"]
+
+
+@pytest.fixture
+def compiled_kda(monkeypatch):
+    """The op asks the platform whether to interpret, and the platform
+    here is the CPU: answer for the described chip."""
+    monkeypatch.setattr(
+        "flexflow_tpu.kernels.gated_delta_rule.pallas_interpret",
+        lambda: False)
+
+
+def _kda_loss(mesh, spec, q, k, v, g, beta, chunk=64):
+    from flexflow_tpu.ops.recurrent_ops import gated_delta_rule
+    with jax.named_scope("ff.forward"), jax.named_scope("kda_2"), \
+            jax.named_scope("kda.scan"):
+        out, _ = gated_delta_rule(q, k, v, g, beta, chunk, jnp.bfloat16,
+                                  mesh=mesh, spec=spec)
+    return jnp.sum(out)
+
+
+def _kda_operands(mesh, spec, b, h, t, d):
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.float32, sharding=NamedSharding(
+                mesh, P(*(tuple(spec) + (None,) * len(shape))[:len(shape)])))
+    return [arr(b, h, t, d)] * 4 + [arr(b, h, t)]
+
+
+# (batch, heads, tokens, head size, chunk): cell 5 of the benchmark, the
+# sequence ISSUE 35 asked for, a padded tail over two grid steps, and the
+# shortest and a longer chunk the shape rule takes
+KDA_SHAPES = [(1, 32, 4096, 128, 64), (1, 32, 8192, 128, 64),
+              (2, 4, 64 * 9 + 5, 128, 64), (1, 4, 256, 128, 16),
+              (1, 4, 512, 256, 128)]
+
+
+@pytest.mark.parametrize("b,h,t,d,chunk", KDA_SHAPES)
+def test_the_linear_attention_kernels_compile(v5e_devices, compiled_kda,
+                                              b, h, t, d, chunk):
+    """Forward and backward for a described v5e: no triangular solve
+    (XLA's is a custom call) and no other custom call than the two
+    kernels' and XLA's own buffers is left in the recurrence."""
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    txt = _compile_text(
+        jax.grad(functools.partial(_kda_loss, None, None, chunk=chunk),
+                 argnums=range(5)), *_kda_operands(mesh, (), b, h, t, d))
+    assert _kernel_names(txt) == KDA_NAMES
+    assert set(re.findall(r'custom_call_target="([^"]+)"', txt)) <= {
+        "tpu_custom_call", "AllocateBuffer"}
+
+
+@pytest.mark.parametrize("spec", [("x0", None), (None, "x0")],
+                         ids=["batch", "heads"])
+def test_the_linear_attention_kernels_compile_under_a_mesh(
+        v5e_devices, compiled_kda, spec):
+    """Four chips by batch or by heads: each runs the kernels on its own
+    (batch, head) rows under ``shard_map``; GSPMD could not partition
+    the Mosaic calls."""
+    mesh = Mesh(np.array(v5e_devices), ("x0",))
+    txt = _compile_text(
+        jax.grad(functools.partial(_kda_loss, mesh, P(*spec)),
+                 argnums=range(5)),
+        *_kda_operands(mesh, spec, 4, 8, 512, 128))
+    assert _kernel_names(txt) == KDA_NAMES
+
+
+def test_the_linear_attention_kernels_keep_their_scope(
+        v5e_devices, compiled_kda, chip_locations):
+    """Both calls carry the layer's name and ``kda.scan`` in their
+    ``op_name``, the backward's inside the ``transpose(``: the
+    benchmark's ``kda_time_share.train`` and
+    ``kda_scan_time_share.train`` find them by those parts."""
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    txt = _compile_text(
+        jax.grad(functools.partial(_kda_loss, None, None),
+                 argnums=range(5)), *_kda_operands(mesh, (), 1, 4, 512, 128))
+    by_name = {n: l for n in KDA_NAMES for l in txt.splitlines()
+               if MOSAIC_CALL in l and f"{n}." in l.split(" = ")[0]}
+    assert 'jvp(ff.forward)/kda_2/kda.scan/gated_delta_rule_fwd/' \
+        'pallas_call"' in by_name["gated_delta_rule_fwd"]
+    assert 'transpose(jvp(ff.forward))/kda_2/kda.scan/' \
+        'gated_delta_rule_bwd/pallas_call"' in by_name["gated_delta_rule_bwd"]

@@ -82,7 +82,8 @@ class FFConfig:
     # process — "false" also stops tracing of other models/servers in
     # it); "auto" (default) honors the FF_TRACE env var so recorded
     # benchmarks are unchanged unless asked. Near-zero-cost when
-    # disabled (bench's obs-overhead leg pins it at <= 3%).
+    # disabled: one flag check a site, and every PR's end-to-end numbers
+    # are the driver's untraced runs through those sites.
     trace: str = "auto"           # "auto" | "true" | "false"
     # write a Chrome trace-event JSON (Perfetto/TensorBoard-viewable)
     # of the recorded spans here when fit() completes; "" = off

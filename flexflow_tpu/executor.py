@@ -66,17 +66,30 @@ def _instrument_step(fn, name: str):
     With ``FF_TRACE_SYNC=1`` the span additionally blocks on the step's
     outputs, so it records device latency, not dispatch latency.
 
-    Disabled-mode cost is one flag check plus an int increment — the
-    bench's obs-overhead leg pins this at <= 3% of a train step, and the
-    raw jitted callable stays reachable as ``wrapped.__wrapped__`` so
-    the leg can time both sides of exactly this wrapper. The jit
-    inspection surface callers rely on (``lower`` for HLO dumps —
-    utils/debug.py — plus ``trace``/``eval_shape``) is re-exposed on the
-    wrapper."""
+    Wrapping records one ``executor.jit`` instant: ``name`` and the
+    ``fun_name`` under which XLA's own events (``xla.trace`` /
+    ``xla.lower`` / ``xla.backend_compile``, obs/xla_events.py) will
+    tell of this function, so a reader of the ring knows the program's
+    steps from whatever else the process compiled. The train and the
+    eval step are both ``step_fn`` to JAX (the name is in the HLO
+    module's, so in every cache key: it stays); an event of that name
+    inside an ``executor.eval_step`` span is the eval step's.
+
+    Disabled-mode cost is one flag check plus an int increment. What
+    holds it is the benchmark itself: the driver's runs are untraced, so
+    every PR's ``train_tokens_per_s`` is measured through this wrapper
+    with the recorder off. The raw jitted callable stays reachable as
+    ``wrapped.__wrapped__``, and the jit inspection surface callers rely
+    on (``lower`` for HLO dumps — utils/debug.py — plus ``trace``/
+    ``eval_shape``) is re-exposed on the wrapper: a compile made through
+    it lies under no ``executor.<name>_step`` span, and is known by its
+    ``fun_name`` alone."""
     # itertools.count: serving instance clones share one compiled
     # forward across N scheduler workers, and next() is atomic under
     # the GIL — a read-modify-write int would double-label "compile"
     calls = itertools.count()
+    obs_events.instant("executor.jit", name=name,
+                       fun_name=getattr(fn, "__name__", "<unnamed>"))
 
     def wrapped(*args, **kwargs):
         n = next(calls)
@@ -559,14 +572,20 @@ class Executor:
             seed = self.seed
         psh: Dict[str, Dict[str, Any]] = {}
         ssh: Dict[str, Dict[str, Any]] = {}
-        params, state = self._build_params_and_state(seed, psh, ssh)
-        # the fused optimizer kernel runs under shard_map with these
-        self._param_specs = jax.tree.map(lambda sh: sh.spec, psh)
-        # placement via the reshard planner's host→device step: sharded
-        # leaves hand each device only its own slice instead of staging
-        # a full per-device replica (parallel/reshard.place_host)
-        params = jax.tree.map(reshard_mod.place_host, params, psh)
-        state = jax.tree.map(reshard_mod.place_host, state, ssh)
+        with obs_events.span("executor.init_params") as sp:
+            params, state = self._build_params_and_state(seed, psh, ssh)
+            if obs_events.enabled():
+                leaves = jax.tree.leaves(params)
+                sp.set(parameters=sum(int(a.size) for a in leaves),
+                       bytes=sum(int(a.nbytes) for a in leaves))
+            # the fused optimizer kernel runs under shard_map with these
+            self._param_specs = jax.tree.map(lambda sh: sh.spec, psh)
+            # placement via the reshard planner's host→device step:
+            # sharded leaves hand each device only its own slice instead
+            # of staging a full per-device replica
+            # (parallel/reshard.place_host)
+            params = jax.tree.map(reshard_mod.place_host, params, psh)
+            state = jax.tree.map(reshard_mod.place_host, state, ssh)
         return params, state
 
     def _build_params_and_state(self, seed, psh, ssh):

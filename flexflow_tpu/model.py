@@ -17,6 +17,7 @@ TPU-native differences:
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
@@ -42,6 +43,11 @@ from .runtime.dataloader import SingleDataLoader
 from .runtime.metrics import PerfMetrics
 from .runtime.metrics_buffer import MetricsBuffer
 from .runtime.optimizers import AdamOptimizer, Optimizer, SGDOptimizer
+
+#: help string of ``ff_model_compiles_total``: it counts calls, not what
+#: XLA built (the recorder's ``xla.compiles/<fun_name>`` does that)
+_COMPILES_HELP = ("FFModel.compile() calls and fresh decode programs "
+                  "(not XLA builds: see xla.compiles/<fun_name>)")
 
 _LOSS_NAMES = {
     "categorical_crossentropy": LossType.LOSS_CATEGORICAL_CROSSENTROPY,
@@ -613,10 +619,42 @@ class FFModel:
                 search_budget: Optional[int] = None):
         """Lower graph → (strategy, jitted step). Reference call stack:
         ``FFModel::compile`` → graph_optimize → convert_graph_to_operators
-        → NCCL setup (``model.cc:2803-3168``)."""
-        from .obs import events as obs_events
+        → NCCL setup (``model.cc:2803-3168``).
+
+        The whole call is the recorder's span ``model.compile`` and its
+        phases are ``compile.mesh`` / ``search`` / ``plan`` / ``verify``
+        / ``init`` / ``opt_state`` inside it (docs/observability.md,
+        "Set-up and compiles"). ``_compile_phases`` holds the seconds of
+        this call's search, verify and init phases and of the whole,
+        recorder on or off, each the very reading its span carries."""
         obs_events.configure(self.config)
-        _compile_t0 = time.perf_counter()
+        self._compile_phases: Dict[str, float] = {}
+        with obs_events.timed_span("model.compile") as whole:
+            self._compile(optimizer, loss_type, metrics, comp_mode,
+                          machine_spec, strategy, output_tensor,
+                          search_budget)
+            whole.set(n_devices=self.dmesh.num_devices,
+                      n_layers=len(self.layers))
+        self._compile_phases["compile_s"] = round(whole.dur, 6)
+        # one a compile() CALL, whatever XLA then built or found cached:
+        # XLA's own builds are the recorder's ``xla.compiles/<fun_name>``
+        # and ``xla.cache_hits`` / ``xla.cache_misses`` (obs/xla_events.py)
+        from .obs.metrics_registry import REGISTRY
+        REGISTRY.counter("ff_model_compiles_total", _COMPILES_HELP).inc(
+            model=getattr(self, "_model_name", "") or "<unnamed>")
+
+    @contextlib.contextmanager
+    def _compile_phase(self, key: str, ndigits: int):
+        """One phase of ``compile()``: the recorder's span
+        ``compile.<key>`` and ``_compile_phases["<key>_s"]``, both from
+        one reading of the clock at each end."""
+        with obs_events.timed_span("compile." + key) as phase:
+            yield phase
+        self._compile_phases[key + "_s"] = round(phase.dur, ndigits)
+
+    def _compile(self, optimizer, loss_type, metrics, comp_mode,
+                 machine_spec, strategy, output_tensor, search_budget):
+        """The body of :meth:`compile`, under its span."""
         # phase -> typed reason, for every compile phase that was
         # allowed to fail and did (search/optimizer.py note_skip)
         self._compile_skips: Dict[str, str] = {}
@@ -653,40 +691,41 @@ class FFModel:
         if self.label_tensor is None and len(unconsumed) == 1:
             self.label_tensor = unconsumed[0]
 
-        # join the multi-host world first (reference: GASNet launch +
-        # control replication happen before graph_optimize) so that
-        # MachineSpec.detect sees the GLOBAL device view
-        from .parallel.distributed import maybe_initialize
-        if maybe_initialize(self.config):
-            # multi-process world: start the failure-detection layer
-            # (per-rank heartbeats + bounded barriers) alongside it —
-            # every later cross-rank wait goes through it
-            from .resilience import coord
-            c = coord.ensure_started(self.config)
-            try:
-                # clock handshake for cross-rank trace alignment
-                # (tools/fftrace.py): one bounded barrier, every rank
-                # anchors its monotonic clock at the release instant.
-                # Unconditional — every rank reaches compile, so the
-                # rendezvous can never depend on per-rank trace flags
-                c.clock_sync("compile")
-            except Exception:  # noqa: BLE001 — alignment is best-effort
-                pass
-        # (after the rendezvous: asking for the platform starts the
-        # backend, which jax.distributed must precede)
-        from .utils.compilation_cache import enable_compilation_cache
-        enable_compilation_cache()
-        if machine_spec is not None:
-            spec = machine_spec
-        elif self.config.machine_model_file:
-            # --machine-model-file: the described machine drives the cost
-            # model / simulator / topology (reference machine_model.cc);
-            # execution is clamped to the live devices
-            spec = MachineSpec.from_file(self.config.machine_model_file)
-            import jax
-            spec.num_devices = min(spec.num_devices, len(jax.devices()))
-        else:
-            spec = MachineSpec.detect()
+        with obs_events.span("compile.mesh"):
+            # join the multi-host world first (reference: GASNet launch +
+            # control replication happen before graph_optimize) so that
+            # MachineSpec.detect sees the GLOBAL device view
+            from .parallel.distributed import maybe_initialize
+            if maybe_initialize(self.config):
+                # multi-process world: start the failure-detection layer
+                # (per-rank heartbeats + bounded barriers) alongside it —
+                # every later cross-rank wait goes through it
+                from .resilience import coord
+                c = coord.ensure_started(self.config)
+                try:
+                    # clock handshake for cross-rank trace alignment
+                    # (tools/fftrace.py): one bounded barrier, every rank
+                    # anchors its monotonic clock at the release instant.
+                    # Unconditional — every rank reaches compile, so the
+                    # rendezvous can never depend on per-rank trace flags
+                    c.clock_sync("compile")
+                except Exception:  # noqa: BLE001 — alignment is best-effort
+                    pass
+            # (after the rendezvous: asking for the platform starts the
+            # backend, which jax.distributed must precede)
+            from .utils.compilation_cache import enable_compilation_cache
+            enable_compilation_cache()
+            if machine_spec is not None:
+                spec = machine_spec
+            elif self.config.machine_model_file:
+                # --machine-model-file: the described machine drives the cost
+                # model / simulator / topology (reference machine_model.cc);
+                # execution is clamped to the live devices
+                spec = MachineSpec.from_file(self.config.machine_model_file)
+                import jax
+                spec.num_devices = min(spec.num_devices, len(jax.devices()))
+            else:
+                spec = MachineSpec.detect()
         mesh_shape = self.config.mesh_shape
         pp = self.config.pipeline_stages
         pp_tp = max(self.config.pipeline_tp, 1)
@@ -798,10 +837,8 @@ class FFModel:
         if strategy is not None:
             self.strategy = strategy
         else:
-            _t0 = time.perf_counter()
-            self.strategy, program_info = self._optimize_strategy()
-            self._compile_phases = {
-                "search_s": round(time.perf_counter() - _t0, 3)}
+            with self._compile_phase("search", 3):
+                self.strategy, program_info = self._optimize_strategy()
             if self.strategy.dmesh is not self.dmesh:
                 # the search chose a strategy on its own mesh layout
                 # (e.g. a (dp, S) pipeline mesh) — adopt it
@@ -813,76 +850,77 @@ class FFModel:
                 exec_outputs = program_info.output_tensors
                 self._output_tensor = exec_outputs[0]
 
-        # label tensor adopts the final op's batch sharding
-        # (reference model.cc:3086-3124)
-        prebuilt = getattr(self, "_prebuilt_executor", None)
-        if prebuilt is not None and prebuilt[0] is self.strategy \
-                and prebuilt[1] is not None:
-            # the floor guard already compiled this exact program
-            # (same strategy object, same metrics) — adopt its executor
-            # so the jitted train step is not rebuilt; params/state are
-            # re-initialized below
-            self.executor = prebuilt[1]
-            self._prebuilt_executor = None
-        else:
-            program = GraphProgram(exec_layers,
-                                   self.graph_inputs + self.const_inputs,
-                                   exec_outputs)
-            self.executor = Executor(program, self.config, self.dmesh,
-                                     self.strategy, self.optimizer,
-                                     self.loss_type, self.metrics,
-                                     seed=self.config.seed)
-        # searched data movement: one reshard planner per strategy plans
-        # every layout transition (bank boundaries, pipeline-region
-        # entry/exit, layout-op output constraints) with scored explicit
-        # collectives; chosen step sequences annotate the strategy audit
-        from .parallel.reshard import ReshardPlanner
-        pl = getattr(self.strategy, "resharder", None)
-        if pl is None or pl.dmesh is not self.dmesh:
-            pl = ReshardPlanner(self.dmesh)
-            self.strategy.resharder = pl
-        pl.audit_path = getattr(self, "_strategy_audit_path", None)
-        # overlap (runtime/overlap.py): multi-leg tier-staged reshard
-        # plans execute with their fabric legs pipelined when on
-        from .runtime.overlap import overlap_enabled
-        pl.overlap_on = overlap_enabled(self.config)
-        if self.config.export_strategy_file \
-                and getattr(self.strategy, "overlap", None):
-            # the search exported before the executor built the bucket
-            # schedule (same ordering as banks/zero): rewrite the
-            # overlap section so --import round-trips the exact
-            # schedule this compile audited and verified
-            try:
-                import json as _json
-                with open(self.config.export_strategy_file) as f:
-                    doc = _json.load(f)
-                doc["overlap"] = dict(self.strategy.overlap)
-                with open(self.config.export_strategy_file, "w") as f:
-                    _json.dump(doc, f, indent=1)
-            except Exception:  # noqa: BLE001 — export is best-effort
-                pass
-        # per-parameter ZeRO (search/zero_plan.py, arXiv 2004.13336):
-        # score each parameter's update path (replicated all-reduce vs
-        # reduce-scatter + sharded update + all-gather over the placed
-        # tier path) and adopt an assignment under the device-memory
-        # envelope. Runs BEFORE plan verification so the verifier's
-        # memory envelope and zero-soundness checks bind on the
-        # assignment the run will actually use. The uniform --zero flag
-        # bypasses this entirely (pinned legacy behavior below).
-        self._plan_zero()
-        # quantized gradient collectives (ops/quantized_collectives.py,
-        # arXiv 2506.17615): plan per-tensor/per-phase wire dtypes for
-        # gradient sync, scored by the same calibrated cost model.
-        # Runs BEFORE plan verification so the qsync check binds on the
-        # plan the run will actually use.
-        self._plan_qsync()
-        # searchable kernel tier (kernels/registry.py): adopt a per-op
-        # implementation assignment (attention xla/flash/ring, the
-        # optimizer update fused/unfused) — searched by calibrated cost,
-        # forced by --kernel-impl, imported verbatim. Runs BEFORE plan
-        # verification so the kernel check and the seq-aware memory
-        # envelope bind on the impls the run will actually execute.
-        self._plan_kernels()
+        with obs_events.span("compile.plan"):
+            # label tensor adopts the final op's batch sharding
+            # (reference model.cc:3086-3124)
+            prebuilt = getattr(self, "_prebuilt_executor", None)
+            if prebuilt is not None and prebuilt[0] is self.strategy \
+                    and prebuilt[1] is not None:
+                # the floor guard already compiled this exact program
+                # (same strategy object, same metrics) — adopt its executor
+                # so the jitted train step is not rebuilt; params/state are
+                # re-initialized below
+                self.executor = prebuilt[1]
+                self._prebuilt_executor = None
+            else:
+                program = GraphProgram(exec_layers,
+                                       self.graph_inputs + self.const_inputs,
+                                       exec_outputs)
+                self.executor = Executor(program, self.config, self.dmesh,
+                                         self.strategy, self.optimizer,
+                                         self.loss_type, self.metrics,
+                                         seed=self.config.seed)
+            # searched data movement: one reshard planner per strategy plans
+            # every layout transition (bank boundaries, pipeline-region
+            # entry/exit, layout-op output constraints) with scored explicit
+            # collectives; chosen step sequences annotate the strategy audit
+            from .parallel.reshard import ReshardPlanner
+            pl = getattr(self.strategy, "resharder", None)
+            if pl is None or pl.dmesh is not self.dmesh:
+                pl = ReshardPlanner(self.dmesh)
+                self.strategy.resharder = pl
+            pl.audit_path = getattr(self, "_strategy_audit_path", None)
+            # overlap (runtime/overlap.py): multi-leg tier-staged reshard
+            # plans execute with their fabric legs pipelined when on
+            from .runtime.overlap import overlap_enabled
+            pl.overlap_on = overlap_enabled(self.config)
+            if self.config.export_strategy_file \
+                    and getattr(self.strategy, "overlap", None):
+                # the search exported before the executor built the bucket
+                # schedule (same ordering as banks/zero): rewrite the
+                # overlap section so --import round-trips the exact
+                # schedule this compile audited and verified
+                try:
+                    import json as _json
+                    with open(self.config.export_strategy_file) as f:
+                        doc = _json.load(f)
+                    doc["overlap"] = dict(self.strategy.overlap)
+                    with open(self.config.export_strategy_file, "w") as f:
+                        _json.dump(doc, f, indent=1)
+                except Exception:  # noqa: BLE001 — export is best-effort
+                    pass
+            # per-parameter ZeRO (search/zero_plan.py, arXiv 2004.13336):
+            # score each parameter's update path (replicated all-reduce vs
+            # reduce-scatter + sharded update + all-gather over the placed
+            # tier path) and adopt an assignment under the device-memory
+            # envelope. Runs BEFORE plan verification so the verifier's
+            # memory envelope and zero-soundness checks bind on the
+            # assignment the run will actually use. The uniform --zero flag
+            # bypasses this entirely (pinned legacy behavior below).
+            self._plan_zero()
+            # quantized gradient collectives (ops/quantized_collectives.py,
+            # arXiv 2506.17615): plan per-tensor/per-phase wire dtypes for
+            # gradient sync, scored by the same calibrated cost model.
+            # Runs BEFORE plan verification so the qsync check binds on the
+            # plan the run will actually use.
+            self._plan_qsync()
+            # searchable kernel tier (kernels/registry.py): adopt a per-op
+            # implementation assignment (attention xla/flash/ring, the
+            # optimizer update fused/unfused) — searched by calibrated cost,
+            # forced by --kernel-impl, imported verbatim. Runs BEFORE plan
+            # verification so the kernel check and the seq-aware memory
+            # envelope bind on the impls the run will actually execute.
+            self._plan_kernels()
         # static plan verification (analysis/plan_verifier.py): prove
         # the adopted strategy executable — axis soundness, shard
         # divisibility, legal reshard lowerings at every seam, memory
@@ -892,20 +930,16 @@ class FFModel:
         if self.config.plan_verify \
                 and os.environ.get("FF_PLAN_VERIFY", "") != "0":
             from .analysis.plan_verifier import verify_model
-            _t0 = time.perf_counter()
-            report = verify_model(self)
-            self.__dict__.setdefault("_compile_phases", {})["verify_s"] \
-                = round(time.perf_counter() - _t0, 6)
+            with self._compile_phase("verify", 6):
+                report = verify_model(self)
             self._plan_verify_report = report
-        _t0 = time.perf_counter()
-        self.params, self.state = self.executor.init_params_and_state()
-        if hasattr(self, "_compile_phases"):
-            # init/materialization separated from search: on a virtual
-            # many-device CPU mesh the replicated-shard host copies
-            # dominate, which would misattribute wall time to the search
-            self._compile_phases["init_s"] = round(
-                time.perf_counter() - _t0, 3)
-        self.opt_state = self.optimizer.init_state(self.params)
+        # init/materialization separated from search: on a virtual
+        # many-device CPU mesh the replicated-shard host copies
+        # dominate, which would misattribute wall time to the search
+        with self._compile_phase("init", 3):
+            self.params, self.state = self.executor.init_params_and_state()
+        with obs_events.span("compile.opt_state"):
+            self.opt_state = self.optimizer.init_state(self.params)
         if self.config.shard_optimizer_states and self.opt_state:
             # ZeRO-1: moments sharded over the axes their weight is
             # replicated on (runtime/zero.py); the executor pins the
@@ -940,22 +974,6 @@ class FFModel:
             if res:
                 self.opt_state[qsync_mod.RESIDUAL_SLOT] = res
         self._step = 0
-        self.__dict__.setdefault("_compile_phases", {})["compile_s"] = \
-            round(time.perf_counter() - _compile_t0, 6)
-        # recompile observability for the warm-start path: every program
-        # (re)build increments the per-model counter — a fleet whose
-        # persistent compilation cache is actually warm shows this flat
-        # across process restarts while compile_s collapses to the
-        # cache-hit cost
-        from .obs.metrics_registry import REGISTRY
-        REGISTRY.counter(
-            "ff_model_compiles_total",
-            "Model program compiles (trace + XLA build events)").inc(
-            model=getattr(self, "_model_name", "") or "<unnamed>")
-        obs_events.record_span("model.compile", _compile_t0,
-                               time.perf_counter() - _compile_t0,
-                               n_devices=self.dmesh.num_devices,
-                               n_layers=len(self.layers))
 
     def _optimize_strategy(self):
         """Strategy selection: search unless --only-data-parallel.
@@ -1955,13 +1973,10 @@ class FFModel:
         fn = cache.get(ck)
         if fn is None:
             fn = cache[ck] = jax.jit(builder)
-            # a fresh decode program is a recompile event too — same
-            # per-model counter as FFModel.compile so the warm-start
-            # signal covers the generate paths
+            # a fresh decode program counts like a compile() call: the
+            # same per-model counter, so it covers the generate paths
             from .obs.metrics_registry import REGISTRY
-            REGISTRY.counter(
-                "ff_model_compiles_total",
-                "Model program compiles (trace + XLA build events)").inc(
+            REGISTRY.counter("ff_model_compiles_total", _COMPILES_HELP).inc(
                 model=getattr(self, "_model_name", "") or "<unnamed>")
         else:
             cache.move_to_end(ck)

@@ -23,7 +23,10 @@ Design constraints (ISSUE 2 tentpole):
 
 Enabling: ``FF_TRACE=1`` in the environment (read at import), or
 ``FFConfig.trace = "true"`` (applied by ``FFModel.compile`` via
-:func:`configure`), or :func:`enable` directly.
+:func:`configure`), or :func:`enable` directly. While it is on, XLA's
+own trace / lower / compile / cache events are on the ring too
+(:mod:`.xla_events`: ``jax.monitoring`` listeners that :func:`enable`
+registers and :func:`disable` takes off again).
 """
 from __future__ import annotations
 
@@ -63,12 +66,16 @@ def enable(capacity: Optional[int] = None) -> None:
             _capacity = capacity
             _reset_locked()
         _enabled = True
+    from . import xla_events
+    xla_events.install()
 
 
 def disable() -> None:
     global _enabled
     with _lock:
         _enabled = False
+    from . import xla_events
+    xla_events.uninstall()
 
 
 def _reset_locked() -> None:
@@ -157,10 +164,11 @@ def _record(ev: Dict[str, Any]) -> None:
 
 def record_span(name: str, t0: float, dur: float, **attrs) -> None:
     """Record one completed span explicitly (``t0`` from
-    ``time.perf_counter()``). Used where a ``with`` block would force
-    reindenting a long phase — e.g. ``FFModel.compile``. Recorded after
-    the fact, so it cannot reach the profiler's trace: hot-loop sites
-    use ``with span``."""
+    ``time.perf_counter()``). Used where the span is known only once it
+    is over: a request's phases (``request_trace.py``), a checkpoint's
+    save, XLA's compile events (``xla_events.py``). Recorded after the
+    fact, so it cannot reach the profiler's trace: hot-loop sites use
+    ``with span``."""
     # benign race: disabled fast path (see enabled())
     if not _enabled:  # ffcheck: ok(guarded-field)
         return
@@ -169,7 +177,7 @@ def record_span(name: str, t0: float, dur: float, **attrs) -> None:
              "attrs": attrs or None})
 
 
-def instant(name: str, **attrs) -> None:
+def instant(name: str, /, **attrs) -> None:
     """Record a point-in-time event (e.g. a recompile trigger)."""
     # benign race: disabled fast path (see enabled())
     if not _enabled:  # ffcheck: ok(guarded-field)
@@ -229,6 +237,33 @@ class span:
         return False
 
 
+class timed_span(span):
+    """A span whose duration the caller needs whether or not the
+    recorder is on (a set-up phase that is also printed): the clock is
+    read always, ONCE at each end, and ``dur`` after the block is the
+    very reading the recorded span carries. Enabled, it is a ``span``
+    in every other respect, profiler annotation included. For set-up
+    code only: a hot loop's ``span`` reads no clock when disabled."""
+
+    __slots__ = ("dur",)
+
+    def __enter__(self) -> "timed_span":
+        super().__enter__()
+        if self._t0 is None:      # recorder off: the clock is still read
+            self._annotation = None
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            # benign race: see span.__exit__
+            if _enabled:  # ffcheck: ok(guarded-field)
+                record_span(self.name, self._t0, self.dur, **self.attrs)
+        return False
+
+
 def events() -> List[Dict[str, Any]]:
     """Snapshot of recorded events, oldest first."""
     with _lock:
@@ -259,4 +294,4 @@ def snapshot(max_events: Optional[int] = None) -> Dict[str, Any]:
 # FF_TRACE honored at import so serving entry points (which never see an
 # FFConfig) are covered too
 if _env_on(os.environ.get("FF_TRACE")):
-    _enabled = True
+    enable()

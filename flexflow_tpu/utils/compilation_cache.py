@@ -92,7 +92,11 @@ def one_frame_locations() -> None:
 
 def cache_entries(path: str | None) -> set[str]:
     """Names of the executables in the cache directory (each starts with
-    the jitted function's name); empty when it does not exist."""
+    the jitted function's name); empty when it does not exist. A count
+    of these sees a compile that MISSED; what hit, what it cost to
+    load and which function asked are the recorder's ``xla.cache_hits``
+    / ``xla.cache_load`` / ``xla.compiles/<fun_name>``
+    (``obs/xla_events.py``)."""
     if not path or not os.path.isdir(path):
         return set()
     return {name for name in os.listdir(path) if name.endswith("-cache")}

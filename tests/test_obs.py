@@ -247,7 +247,7 @@ def test_executor_step_spans_compile_vs_steady(traced):
     assert spans[0]["dur"] > spans[1]["dur"]
     assert events.counters()["executor.train_steps"] == 3
     assert any(e["name"] == "model.compile" for e in events.events())
-    # raw jitted callable stays reachable for the bench overhead leg
+    # the raw jitted callable stays reachable
     assert callable(step.__wrapped__)
 
 
@@ -546,6 +546,14 @@ def test_fit_loop_spans_nest_in_fit_epoch_on_one_thread(traced):
         assert e["tid"] == epoch["tid"], e
         assert e["ts"] >= epoch["ts"] and \
             e["ts"] + e["dur"] <= epoch["ts"] + epoch["dur"] + 1e-9, e
+    # the epoch that builds the step also holds its ``executor.jit``
+    # mark and XLA's events of its one compile (and of what it traced
+    # on the way), on the loop's thread like the rest
+    assert count.pop("executor.jit") == 1
+    xla = {k: count.pop(k) for k in sorted(count) if k.startswith("xla.")}
+    assert xla["xla.lower"] == xla["xla.backend_compile"] == 1
+    assert xla["xla.trace"] >= 1 and len(xla) == 3
+    inner = [e for e in inner if e["name"] in count]
     # three batches: four fetches (the last one ends the epoch), three
     # dispatches, two waits on the step leaving a window of one
     assert count == {"fit.loader_next": 4, "executor.train_step": 3,
@@ -688,3 +696,215 @@ def test_no_flash_grid_event_and_no_profiler_with_events_off(monkeypatch):
     finally:
         if was_enabled:
             events.enable()
+
+
+# ----------------------------------------------------------------------
+# set-up and compiles: XLA's own events on the recorder
+# (obs/xla_events.py), the phases of compile(), executor.jit
+# ----------------------------------------------------------------------
+
+XLA_SPANS = ("xla.trace", "xla.lower", "xla.backend_compile")
+
+
+def _of(name, fun_name=None):
+    return [e for e in events.events() if e["name"] == name
+            and (fun_name is None
+                 or (e["attrs"] or {}).get("fun_name") == fun_name)]
+
+
+def _ours(listeners):
+    return [f for f in listeners
+            if getattr(f, "__module__", "") == "flexflow_tpu.obs.xla_events"]
+
+
+def _listeners():
+    from jax._src import monitoring
+    return (_ours(monitoring.get_event_time_span_listeners()),
+            _ours(monitoring.get_event_duration_listeners()),
+            _ours(monitoring.get_event_listeners()))
+
+
+def test_a_fresh_jit_leaves_its_trace_lower_and_compile_inside_the_caller(
+        traced):
+    import jax
+    import jax.numpy as jnp
+
+    def probe_fn(x):
+        return jnp.sin(x) * 2.0
+
+    f = jax.jit(probe_fn)
+    x3, x4 = jnp.ones((3,)), jnp.ones((4,))     # (eager ops compile here)
+    events.clear()
+    with events.span("caller"):
+        f(x3).block_until_ready()
+    caller, = _of("caller")
+    for name in XLA_SPANS:
+        got, = _of(name, "probe_fn")
+        # on the recorder's clock, though JAX stamped it on another
+        assert caller["ts"] - 5e-3 <= got["ts"]
+        assert got["ts"] + got["dur"] <= caller["ts"] + caller["dur"] + 5e-3
+        assert got["dur"] > 0 and got["kind"] == "span"
+    assert events.counters()["xla.compiles/probe_fn"] == 1
+    # a cached dispatch tells nothing
+    n = len(events.events())
+    f(x3).block_until_ready()
+    assert len(events.events()) == n
+    # a new shape is a recompile, and it is named
+    f(x4).block_until_ready()
+    assert [len(_of(name, "probe_fn")) for name in XLA_SPANS] == [2, 2, 2]
+    assert events.counters()["xla.compiles/probe_fn"] == 2
+
+
+def test_cache_events_move_the_counters_and_a_load_is_a_span(traced):
+    from jax import monitoring
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event(
+        "/jax/compilation_cache/compile_requests_use_cache")
+    monitoring.record_event("/jax/compilation_cache/tasks_using_cache")
+    assert events.counters() == {
+        "xla.cache_hits": 2, "xla.cache_misses": 1, "xla.cache_requests": 1}
+    monitoring.record_event_duration_secs(
+        "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+    monitoring.record_event_duration_secs(
+        "/jax/compilation_cache/compile_time_saved_sec", 3.0)
+    now = time.perf_counter()
+    load, = [e for e in events.events() if e["name"].startswith("xla.")]
+    assert load["name"] == "xla.cache_load" and load["dur"] == 0.25
+    assert abs(load["ts"] + load["dur"] - now) < 5e-3   # ends at the call
+    # the name JAX gives a lowering or a compile is the module's
+    t = time.time()
+    monitoring.record_event_time_span(
+        "/jax/core/compile/backend_compile_duration", t - 1.5, t,
+        fun_name="jit(step_fn)")
+    monitoring.record_event_time_span("/jax/other", t - 1.5, t)
+    span, = _of("xla.backend_compile")
+    assert span["attrs"] == {"fun_name": "step_fn"}
+    assert span["dur"] == pytest.approx(1.5)
+    assert abs(span["ts"] + 1.5 - time.perf_counter()) < 5e-3
+    assert events.counters()["xla.compiles/step_fn"] == 1
+
+
+def test_the_listeners_exist_only_while_the_recorder_is_on():
+    import jax
+    import jax.numpy as jnp
+    was_enabled = events.enabled()
+    try:
+        events.enable()
+        events.enable()                          # idempotent
+        assert [len(fs) for fs in _listeners()] == [1, 1, 1]
+        events.disable()
+        assert [len(fs) for fs in _listeners()] == [0, 0, 0]
+        events.clear()
+        jax.jit(lambda x: x + 1)(jnp.ones((5,))).block_until_ready()
+        assert events.events() == [] and events.counters() == {}
+    finally:
+        if was_enabled:
+            events.enable()
+        events.clear()
+
+
+def test_a_process_that_never_traced_has_no_listener_of_ours():
+    """With the recorder never on, a compile and a fit register nothing
+    with ``jax.monitoring`` and never import the listeners' module."""
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = (
+        "import os, sys; os.environ.pop('FF_TRACE', None);"
+        " sys.path[:0] = [%r, %r]; import conftest; import test_obs;"
+        " ff, batch = test_obs._tiny_mlp();"
+        " ff._run_train_step(ff.executor.make_train_step(), batch);"
+        " from jax._src import monitoring as m;"
+        " print(len(m.get_event_duration_listeners()),"
+        " len(m.get_event_time_span_listeners()),"
+        " len(m.get_event_listeners()),"
+        " 'flexflow_tpu.obs.xla_events' in sys.modules,"
+        " sorted(ff._compile_phases))" % (os.path.dirname(here), here))
+    out = subprocess.run([sys.executable, "-c", code], text=True,
+                         stdout=subprocess.PIPE, timeout=300, check=True)
+    assert out.stdout.strip().splitlines()[-1] == \
+        "0 0 0 False ['compile_s', 'init_s', 'search_s', 'verify_s']"
+
+
+def test_a_timed_span_gives_its_one_reading_on_or_off(traced):
+    with events.timed_span("phase", k=1) as on:
+        time.sleep(0.002)
+    span, = _of("phase")
+    assert span["dur"] == on.dur >= 0.002 and span["attrs"] == {"k": 1}
+    events.disable()
+    try:
+        with events.timed_span("phase") as off:
+            time.sleep(0.002)
+    finally:
+        events.enable()
+    assert off.dur >= 0.002 and len(_of("phase")) == 1
+
+
+@pytest.mark.parametrize("search_budget", [None, 4])
+def test_compile_phases_are_the_readings_of_the_compile_spans(
+        traced, search_budget):
+    import jax
+    ff, _ = _tiny_mlp(search_budget=search_budget)
+    whole, = _of("model.compile")
+    digits = {"search": 3, "verify": 6, "init": 3}
+    for key, n in digits.items():
+        span, = _of("compile." + key)
+        assert ff._compile_phases[key + "_s"] == round(span["dur"], n)
+    assert ff._compile_phases["compile_s"] == round(whole["dur"], 6)
+    assert list(ff._compile_phases) == ["search_s", "verify_s", "init_s",
+                                        "compile_s"]
+    # every phase lies in the whole, in the order compile() runs them
+    order = ["compile.mesh", "compile.search", "compile.plan",
+             "compile.verify", "compile.init", "compile.opt_state"]
+    phases = [_of(name)[0] for name in order]
+    assert [p["ts"] for p in phases] == sorted(p["ts"] for p in phases)
+    assert whole["ts"] <= phases[0]["ts"]
+    assert phases[-1]["ts"] + phases[-1]["dur"] \
+        <= whole["ts"] + whole["dur"]
+    assert whole["attrs"] == {"n_devices": ff.dmesh.num_devices,
+                              "n_layers": len(ff.layers)}
+    # the draw of the weights is inside compile.init and says its size
+    draw, = _of("executor.init_params")
+    init = phases[4]
+    assert init["ts"] <= draw["ts"] \
+        and draw["ts"] + draw["dur"] <= init["ts"] + init["dur"]
+    leaves = jax.tree.leaves(ff.params)
+    assert draw["attrs"] == {
+        "parameters": sum(a.size for a in leaves),
+        "bytes": sum(a.nbytes for a in leaves)}
+    # a second draw (a benchmark's, from its seed) is a second span
+    ff.executor.init_params_and_state(jax.random.key(7))
+    assert len(_of("executor.init_params")) == 2
+
+
+def test_a_given_strategy_is_compiled_without_a_search_phase(traced):
+    from flexflow_tpu import SGDOptimizer
+    ff, _ = _tiny_mlp()
+    events.clear()
+    ff.compile(SGDOptimizer(0.01), "sparse_categorical_crossentropy", [],
+               output_tensor=ff._output_tensor, strategy=ff.strategy)
+    assert _of("compile.search") == []
+    assert list(ff._compile_phases) == ["verify_s", "init_s", "compile_s"]
+
+
+def test_a_jit_says_which_function_it_is(traced):
+    ff, batch = _tiny_mlp()
+    step = ff.executor.make_train_step()
+    ff.executor.make_eval_step()
+    assert ff.executor.make_train_step() is step          # (kept: no mark)
+    marks = {e["attrs"]["name"]: e["attrs"]["fun_name"]
+             for e in _of("executor.jit")}
+    assert marks == {"train": "step_fn", "eval": "step_fn"}
+    assert all(e["kind"] == "instant" for e in _of("executor.jit"))
+    assert _of("xla.trace", "step_fn") == []              # nothing yet
+    ff._run_train_step(step, batch)
+    ff._run_train_step(step, batch)
+    first, second = _of("executor.train_step")
+    assert first["attrs"]["phase"] == "compile"
+    for name in XLA_SPANS:
+        got, = _of(name, marks["train"])
+        assert first["ts"] - 5e-3 <= got["ts"] and got["ts"] + got["dur"] \
+            <= first["ts"] + first["dur"] + 5e-3
+    assert events.counters()["xla.compiles/step_fn"] == 1

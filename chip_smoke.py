@@ -256,10 +256,16 @@ def _check_step_program(ff, x, y, label: str,
     return n_cc
 
 
-def _check_flash_grids(label: str, want: bool) -> None:
+def _check_flash_grids(label: str, want: bool, rows: int = 0) -> None:
     """The grids the flash kernels were emitted with (``flash.grid``
-    instants, one per traced call): tiles, steps, live and fetched, and
-    of the forward the pieces it walks its k blocks in."""
+    instants, one per traced call): tiles, steps, live and fetched, of
+    the forward the pieces it walks its k blocks in, and of the two
+    backward kernels which way they hold the tile and the bytes of row
+    statistics a call is handed: one float32 a row for each of the two,
+    8 bytes a (batch, head, position) of this device's share of
+    ``rows``, the step's batch x heads x seq (0: not checked)."""
+    import jax
+
     from flexflow_tpu.obs import events
     grids = [dict(g) for g in sorted(
         {tuple(sorted(e["attrs"].items()))
@@ -267,12 +273,24 @@ def _check_flash_grids(label: str, want: bool) -> None:
     for g in grids:
         fwd = "" if "piece_k" not in g else (
             f"; pieces of {g['piece_k']} keys, {g['live_pieces']} live")
+        bwd = "" if "tile" not in g else (
+            f"; {g['tile']}, {g['stat_bytes']} bytes of row statistics")
         say(f"{label}: {g['kernel']} tiles {g['block_q']}x{g['block_k']}, "
             f"{g['steps']} steps, {g['live_steps']} live, "
-            f"{g['fetched_steps']} fetched" + fwd)
+            f"{g['fetched_steps']} fetched" + fwd + bwd)
         check(g["fetched_steps"] == g["live_steps"] <= g["steps"],
               f"{label}: {g['kernel']} fetches {g['fetched_steps']} blocks "
               f"for {g['live_steps']} live steps")
+        if g["kernel"] != "flash_attention_fwd":
+            check(g["tile"] == ("keys_major" if g["kernel"].endswith("dkv")
+                                else "queries_major"),
+                  f"{label}: {g['kernel']} holds its tile {g['tile']}")
+            shards, rest = divmod(8 * rows, g["stat_bytes"])
+            check(not rows or (
+                rest == 0 and 1 <= shards <= len(jax.devices())),
+                f"{label}: {g['kernel']} is handed {g['stat_bytes']} "
+                f"bytes of row statistics for {rows} rows: not 8 a row "
+                f"of a device's share")
     if want:
         check({g["kernel"] for g in grids} >= {
             "flash_attention_fwd", "flash_attention_bwd_dq",
@@ -330,7 +348,8 @@ def leg_bert_train(bert_cfg, seq: int, per_chip_batch: int,
           f"{seq}, head size {head}, dropout {bert_cfg.dropout}")
     flash = "flash" in want.values()
     _check_step_program(ff, x, y, "A/bert", want_custom_call=flash)
-    _check_flash_grids("A/bert", want=flash)
+    _check_flash_grids("A/bert", want=flash,
+                       rows=batch * bert_cfg.num_heads * seq)
     say(f"A/bert: per-chip batch {per_chip_batch} (global {batch}), "
         f"seq {seq}, {bert_cfg.num_layers} layers x "
         f"{bert_cfg.hidden_size}, peak_bytes_in_use {_peak_bytes()}")
@@ -373,7 +392,8 @@ def leg_gpt2_kernels(gpt_cfg, seq: int, per_chip_batch: int) -> int:
               f"seq {seq}")
     n_flash = _check_step_program(ff, x, y, "B/gpt2",
                                   want_custom_call=chip)
-    _check_flash_grids("B/gpt2", want=chip)
+    _check_flash_grids("B/gpt2", want=chip,
+                       rows=ids.shape[0] * gpt_cfg.num_heads * seq)
     _check_generate(ff, ids)
     _check_fused_adam(ff)
     say(f"B/gpt2: per-chip batch {per_chip_batch} (global "
@@ -537,7 +557,8 @@ def leg_latent_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
     check(len(routes) == n_expert, f"{label}: {len(routes)} expert layers "
                                    f"announced, the model has {n_expert}")
     _check_experts_counters(label)
-    _check_flash_grids(label, want=chip)
+    _check_flash_grids(label, want=chip, rows=x[0].shape[0] * seq
+                       * model_cfg.num_attention_heads)
     say(f"{label}: not checked here: gradients and the MTP head's "
         f"log-probabilities against the reference: python3 {VALIDATION}")
     n_cc = _compiled_step_size(ff, x, y, label)

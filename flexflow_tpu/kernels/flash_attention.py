@@ -13,6 +13,12 @@ absolute q/k positions), so the differently-blocked backward kernels
 regenerate the identical keep mask without storing it, and the same
 hash lowers in interpret mode for CPU CI.
 
+Row statistics (the forward's log-sum-exp, the backward's ``delta``)
+travel one float32 a row, (bh, 1, sq) with the rows along the lanes,
+from the forward kernel through the residual to the backward kernels,
+and inside a kernel meet a score tile as whole lane-replicated vregs or
+as a sublane broadcast, never as a (rows, 1) column (docs/kernels.md).
+
 Layout: (batch, heads, seq, head_dim), batch*heads collapsed into one grid
 axis. Sequence/head dims are padded to block/lane multiples; the padded-key
 mask is baked in statically (shapes are static under jit). TPU grids
@@ -48,19 +54,31 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, pad)
 
 
-def _key_mask(iq, ik, block_q, block_k, kv_len, causal):
+def _tile_positions(iq, ik, block_q, block_k, keys_major):
+    """Absolute (query, key) positions of the elements of one (q block,
+    k block) tile, (block_q, block_k) with the queries down the sublanes
+    or, ``keys_major``, its transpose (block_k, block_q). The masks are
+    functions of these positions alone, so a kernel that holds the tile
+    transposed draws the same mask, bit for bit."""
+    shape, q_axis = (((block_k, block_q), 1) if keys_major
+                     else ((block_q, block_k), 0))
+    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                    1 - q_axis)
+    return q_pos, k_pos
+
+
+def _key_mask(iq, ik, block_q, block_k, kv_len, causal, keys_major=False):
     """Validity mask for one (q block, k block) tile; kv_len is static."""
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    q_pos, k_pos = _tile_positions(iq, ik, block_q, block_k, keys_major)
     mask = k_pos < kv_len
     if causal:
-        q_pos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
         mask = jnp.logical_and(mask, k_pos <= q_pos)
     return mask
 
 
-def _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k, rate):
+def _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k, rate,
+                    keys_major=False):
     """Counter-based dropout keep-mask (rate is static).
 
     keep[i, j] is a pure hash of (seed, batch-head, ABSOLUTE query
@@ -75,10 +93,7 @@ def _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k, rate):
     A position hash also lowers in interpret mode, so CPU CI now covers
     the dropout path. Mix: odd-constant multiplies folded by xor, then
     the murmur3 fmix32 finalizer in uint32."""
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    q_pos, k_pos = _tile_positions(iq, ik, block_q, block_k, keys_major)
     return _position_keep(seed_ref[0, 0], jnp.asarray(b, jnp.int32),
                           q_pos, k_pos, rate)
 
@@ -224,16 +239,54 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l = jnp.sum(l_sc[:], axis=1, keepdims=True)      # (block_q, 1)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
-        lse = m_sc[:, :1] + jnp.log(l_safe)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        # one float32 a row, the rows along the lanes of a (1, block_q)
+        # block: the lane-replicated tile, transposed, is 128 such rows
+        lse_ref[0] = (m_sc[:] + jnp.log(l_safe)).T[:1]
 
 
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
+def _scores(queries, keys, keys_major):
+    """``queries . keys^T`` over the head size in float32, (rows of
+    ``queries``, rows of ``keys``) or, ``keys_major``, its transpose:
+    either way a product over the last dimension of both operands."""
+    lhs, rhs = (keys, queries) if keys_major else (queries, keys)
+    return jax.lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dropped(x, keep, rate):
+    """``x`` as the forward's dropout left it; ``keep`` None: as it is."""
+    return x if keep is None else jnp.where(keep, x / (1.0 - rate), 0.0)
+
+
+def _bwd_p(q, k, lse, mask, sm_scale, keys_major):
+    """The tile's softmax probabilities from the row statistic ``lse``,
+    which meets the tile as whole vregs or as a sublane broadcast."""
+    s = jnp.where(mask, _scores(q, k, keys_major) * sm_scale, NEG_INF)
+    return jnp.exp(s - lse)
+
+
+def _bwd_ds(p, do, v, delta, keep, rate, sm_scale, keys_major):
+    """The cotangent of the tile's scaled scores."""
+    dp = _dropped(_scores(do, v, keys_major), keep, rate)
+    return p * (dp - delta) * sm_scale
+
+
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_sc, *, sm_scale, causal, kv_len, block_q,
-                   block_k, dropout_rate):
+                   dq_ref, dq_sc, lse_sc, delta_sc, *, sm_scale, causal,
+                   kv_len, block_q, block_k, dropout_rate):
+    """A q block stays while k blocks stream, so the tile is held
+    queries-major, (block_q, block_k), and the q block's two statistics
+    are made lane-replicated (block_q, 128) tiles ONCE, into scratch
+    (the (1, block_q) row over 128 sublanes, transposed), and used as
+    whole vregs (:func:`_lanes`). Never a (rows, 1) column: in a Mosaic
+    kernel that is as dear as 128 lanes to hold and dearer to use
+    (PERF.md section 6, PR 32 and PR 39). Timed alone on a v5e against
+    the keys-major form of ``bwd_dkv`` (here one transposed-left product
+    more): within 2% either way at cells 2 to 5's shapes, 9% faster
+    with dropout at cell 1's."""
     b = pl.program_id(0)     # read out here: interpret mode has no
     iq = pl.program_id(1)    # program_id inside pl.when's cond
     ik = pl.program_id(2)
@@ -242,28 +295,21 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(ik == 0)
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
+        for row_ref, sc in ((lse_ref, lse_sc), (delta_ref, delta_sc)):
+            sc[:] = jnp.broadcast_to(row_ref[0], (LANES, block_q)).T
 
     live = (ik * block_k <= (iq + 1) * block_q - 1) if causal else True
 
     @pl.when(live)
     def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse = lse_ref[0][:, :1]              # (block_q, 1)
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(_key_mask(iq, ik, block_q, block_k, kv_len, causal),
-                      s, NEG_INF)
-        p = jnp.exp(s - lse)                 # (block_q, block_k)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            keep = _tile_keep_mask(seed_ref, b, iq, ik,
-                                   block_q, block_k, dropout_rate)
-            dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-        ds = p * (dp - delta) * sm_scale
+        k = k_ref[0]
+        keep = _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k,
+                               dropout_rate) if dropout_rate > 0.0 else None
+        p = _bwd_p(q_ref[0], k, _lanes(lse_sc[:], block_k),
+                   _key_mask(iq, ik, block_q, block_k, kv_len, causal),
+                   sm_scale, keys_major=False)
+        ds = _bwd_ds(p, do_ref[0], v_ref[0], _lanes(delta_sc[:], block_k),
+                     keep, dropout_rate, sm_scale, keys_major=False)
         dq_sc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -276,6 +322,18 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, sm_scale,
                     causal, kv_len, block_q, block_k, dropout_rate):
+    """A k block stays while q blocks stream, and the tile is held
+    KEYS-MAJOR, (block_k, block_q): ``s^T = k q^T`` and ``dp^T = v do^T``
+    contract the last dimension of both operands as ``s`` always did,
+    ``dv += p^T do`` and ``dk += ds^T q`` are then plain products with
+    no transposed tile (queries-major they contracted dimension 0 of
+    both operands, two (block_q, block_k) transposes a step), and a
+    streamed q block's statistics, (1, block_q) rows, meet the tile as a
+    sublane broadcast. The masks are functions of absolute positions, so
+    the transposed tile draws the forward's mask from swapped iotas.
+    ``dv`` is accumulated before ``dp`` is formed, so that ``p_eff`` is
+    dead by then: with both products after both tiles the call was 3%
+    slower at cell 2's shape (PERF.md section 6, PR 39)."""
     b = pl.program_id(0)
     ik = pl.program_id(1)
     iq = pl.program_id(2)
@@ -291,34 +349,22 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(live)
     def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(_key_mask(iq, ik, block_q, block_k, kv_len, causal),
-                      s, NEG_INF)
-        p = jnp.exp(s - lse)                             # (bq, bk)
-        if dropout_rate > 0.0:
-            # same (seed, b, iq, ik) tuple as forward → identical mask
-            keep = _tile_keep_mask(seed_ref, b, iq, ik,
-                                   block_q, block_k, dropout_rate)
-            p_eff = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-        else:
-            keep = None
-            p_eff = p
+        q, do = q_ref[0], do_ref[0]
+        # the forward's (seed, b, absolute positions) -> the forward's mask
+        keep = _tile_keep_mask(
+            seed_ref, b, iq, ik, block_q, block_k, dropout_rate,
+            keys_major=True) if dropout_rate > 0.0 else None
+        p = _bwd_p(q, k_ref[0], lse_ref[0],
+                   _key_mask(iq, ik, block_q, block_k, kv_len, causal,
+                             keys_major=True), sm_scale, keys_major=True)
+        over_q = (((1,), (0,)), ((), ()))
         dv_sc[:] += jax.lax.dot_general(
-            p_eff.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            _dropped(p, keep, dropout_rate).astype(do.dtype), do, over_q,
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if keep is not None:
-            dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-        ds = p * (dp - delta) * sm_scale                 # (bq, bk)
+        ds = _bwd_ds(p, do, v_ref[0], delta_ref[0], keep, dropout_rate,
+                     sm_scale, keys_major=True)
         dk_sc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, over_q,
             preferred_element_type=jnp.float32)
 
     @pl.when(iq == nq - 1)
@@ -360,8 +406,11 @@ def _k_spec(block_q, block_k, d, causal):
                          0))
 
 
-def _row_spec(block_q):
-    return pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
+def _stat_spec(block_q):
+    """A q block's row statistics (log-sum-exp, delta) in the (bh, nq,
+    nk) grids: one float32 a row, the rows along the lanes of a (bh, 1,
+    sq) array."""
+    return pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
 
 
 def _dkv_specs(block_q, block_k, d, causal):
@@ -376,8 +425,8 @@ def _dkv_specs(block_q, block_k, d, causal):
             return i
     return (pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, iq(j, i), 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, 128),
-                         lambda b, j, i: (b, iq(j, i), 0)))
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b, j, i: (b, 0, iq(j, i))))
 
 
 def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
@@ -410,6 +459,10 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
         out.update(piece_k=piece_k, live_pieces=bh * sum(
             not causal or bool(_piece_live(i, p, block_q, piece_k))
             for i in range(nq) for p in range(sk // piece_k)))
+    else:       # the row statistics the call is handed, and its tile's form
+        out.update(stat_bytes=2 * bh * sq * 4,
+                   tile="keys_major" if kernel == "bwd_dkv"
+                   else "queries_major")
     return out
 
 
@@ -448,10 +501,10 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
         in_specs=[_SEED_SPEC, _q_spec(block_q, d),
                   _k_spec(block_q, block_k, d, causal),
                   _k_spec(block_q, block_k, dv, causal)],
-        out_specs=[_q_spec(block_q, dv), _row_spec(block_q)],
+        out_specs=[_q_spec(block_q, dv), _stat_spec(block_q)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, dv), jnp.float32),
@@ -461,15 +514,16 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
         interpret=interpret,
         name="flash_attention_fwd",
     )(seed, q, k, v)
-    return o, lse[:, :, 0]
+    return o, lse[:, 0, :]
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _bwd_dq_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
+def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
                  causal, block_q, block_k, dropout_rate, interpret):
+    """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
-    row = _row_spec(block_q)
+    stat = _stat_spec(block_q)
     qs, ks = _q_spec(block_q, d), _k_spec(block_q, block_k, d, causal)
     dos, vs = _q_spec(block_q, dv), _k_spec(block_q, block_k, dv, causal)
     return pl.pallas_call(
@@ -477,28 +531,33 @@ def _bwd_dq_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
                           dropout_rate=dropout_rate),
         grid=(bh, sq // block_q, sk // block_k),
-        in_specs=[_SEED_SPEC, qs, ks, vs, dos, row, row],
+        in_specs=[_SEED_SPEC, qs, ks, vs, dos, stat, stat],
         out_specs=qs,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[     # dq, and the q block's lse and delta by lanes
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+        ],
         interpret=interpret,
         name="flash_attention_bwd_dq",
-    )(seed, q, k, v, do, lse_b, delta_b)
+    )(seed, q, k, v, do, lse, delta)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _bwd_dkv_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
+def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
                   causal, block_q, block_k, dropout_rate, interpret):
+    """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
-    qs2, ks2, row2 = _dkv_specs(block_q, block_k, d, causal)
+    qs2, ks2, stat2 = _dkv_specs(block_q, block_k, d, causal)
     dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
                           dropout_rate=dropout_rate),
         grid=(bh, sk // block_k, sq // block_q),
-        in_specs=[_SEED_SPEC, qs2, ks2, vs2, dos2, row2, row2],
+        in_specs=[_SEED_SPEC, qs2, ks2, vs2, dos2, stat2, stat2],
         out_specs=[ks2, vs2],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
@@ -510,7 +569,7 @@ def _bwd_dkv_call(seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale,
         ],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(seed, q, k, v, do, lse_b, delta_b)
+    )(seed, q, k, v, do, lse, delta)
 
 
 @functools.partial(jax.custom_vjp,
@@ -544,9 +603,9 @@ def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
     q, k, v, seed, o, lse = res
     bh, sq, _ = q.shape
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    lse_b = jnp.broadcast_to(lse[:, :, None], (bh, sq, 128))
-    delta_b = jnp.broadcast_to(delta[:, :, None], (bh, sq, 128))
-    operands = (seed, q, k, v, do, lse_b, delta_b, kv_len, sm_scale, causal)
+    # one float32 a row, the rows along the lanes: nothing is replicated
+    operands = (seed, q, k, v, do, lse[:, None, :], delta[:, None, :],
+                kv_len, sm_scale, causal)
     _note_grid("bwd_dq", bh, sq, k.shape[1], *dq_blocks, causal)
     dq = _bwd_dq_call(*operands, *dq_blocks, dropout_rate, interpret)
     _note_grid("bwd_dkv", bh, sq, k.shape[1], *dkv_blocks, causal)
@@ -573,20 +632,23 @@ MAX_BWD_TILE = 1024
 def _bwd_vmem_bytes(kernel, block_q, block_k, d, itemsize, dropout,
                     dv=None):
     """Working set of one grid step of ``bwd_dq`` / ``bwd_dkv``: the
-    double-buffered operand and output blocks, the f32 scratch
-    accumulators, a staging copy of the q-side and k-side blocks at twice
-    their width around the matmuls, and the (block_q, block_k)
-    intermediates: of ``s``, ``p``, ``dp``, ``ds``, the masks and the
-    casts Mosaic streams through registers and keeps about one f32 tile,
-    one more for the dropout hash and keep mask. ``d`` is the head size
-    of q and k (and dq, dk), ``dv`` that of v and do (and dv); None means
-    the same."""
+    double-buffered operand and output blocks (the two row statistics
+    are (1, block_q) float32 rows, which fill 8 sublanes), the f32
+    scratch accumulators and, in ``bwd_dq``, the q block's two
+    lane-replicated (block_q, 128) statistics, a staging copy of the
+    q-side and k-side blocks at twice their width around the matmuls,
+    and the (block_q, block_k) intermediates: of ``s``, ``p``, ``dp``,
+    ``ds``, the masks and the casts Mosaic streams through registers and
+    keeps about one f32 tile, one more for the dropout hash and keep
+    mask. ``d`` is the head size of q and k (and dq, dk), ``dv`` that of
+    v and do (and dv); None means the same."""
     qk = -(-d // 128) * 128                  # a (rows, 64) block fills 128
     both = qk + -(-(d if dv is None else dv) // 128) * 128
-    q_side = block_q * both * itemsize + 2 * block_q * 128 * 4   # q, do
+    q_side = block_q * both * itemsize + 2 * 8 * block_q * 4     # q, do
     k_side = block_k * both * itemsize                           # k, v
     if kernel == "bwd_dq":
-        out, scratch = block_q * qk * itemsize, block_q * qk * 4
+        out = block_q * qk * itemsize
+        scratch = block_q * (qk + 2 * LANES) * 4
     else:
         out, scratch = block_k * both * itemsize, block_k * both * 4
     stage = (block_q + block_k) * both * itemsize
@@ -648,9 +710,11 @@ def _fwd_vmem_bytes(block_q, block_k, d, itemsize, dropout, dv=None):
     (block_q, piece) intermediates of one piece of the k block (each
     piece runs in a scope of its own: of ``s``, ``p``, the mask and the
     cast Mosaic keeps about one float32 tile, one more for the dropout
-    hash and keep mask), and the float32 quotient and log-sum-exp that
-    ``_finish`` forms before it casts them into the output blocks (with
-    one k block a row they share the step with the pieces). Held to
+    hash and keep mask), and the float32 quotient and the lane-replicated
+    log-sum-exp with its transpose that ``_finish`` forms before it
+    writes the output blocks (with one k block a row they share the step
+    with the pieces; the log-sum-exp block itself is a (1, block_q) row,
+    which fills 8 sublanes). Held to
     ``BWD_VMEM_BUDGET`` and checked against Mosaic for a described v5e
     (PERF.md section 6, PR 32): every block :func:`fwd_tiles` derives
     over a grid of 488 (dtype, head sizes, dropout, causal, lengths of
@@ -658,11 +722,11 @@ def _fwd_vmem_bytes(block_q, block_k, d, itemsize, dropout, dv=None):
     limit every one Mosaic refused counts over the budget."""
     qk = -(-d // 128) * 128                  # a (rows, 64) block fills 128
     pv = -(-(d if dv is None else dv) // 128) * 128
-    blocks = (block_q * (qk + pv) * itemsize + block_q * 128 * 4   # q, o, lse
+    blocks = (block_q * (qk + pv) * itemsize + 8 * block_q * 4     # q, o, lse
               + block_k * (qk + pv) * itemsize)                    # k, v
     scratch = block_q * (pv + 2 * 128) * 4
     tiles = (2 if dropout else 1) * block_q * _fwd_piece(block_k) * 4
-    finish = block_q * (pv + 128) * 4
+    finish = block_q * (pv + 2 * 128) * 4
     return 2 * blocks + scratch + tiles + finish
 
 

@@ -521,7 +521,11 @@ GRIDS = [(1024, 128, 128), (1024, 512, 512), (1024, 256, 128),
 def test_causal_index_maps_name_the_nearest_live_block(s, bq, bk):
     nq, nk = s // bq, s // bk
     k_map = fa._k_spec(bq, bk, 64, True).index_map
-    q_spec, k_spec2, row_spec = fa._dkv_specs(bq, bk, 64, True)
+    q_spec, k_spec2, stat_spec = fa._dkv_specs(bq, bk, 64, True)
+    assert q_spec.block_shape == (1, bq, 64)
+    # a q block's statistics: one float32 a row, the rows along the lanes
+    assert stat_spec.block_shape == (1, 1, bq)
+    assert fa._stat_spec(bq).block_shape == (1, 1, bq)
     fetched_k = fetched_q = live = 0
     for i in range(nq):
         seen = set()
@@ -541,12 +545,17 @@ def test_causal_index_maps_name_the_nearest_live_block(s, bq, bk):
             is_live = (i + 1) * bq - 1 >= j * bk
             first_live = min(ii for ii in range(nq)
                              if (ii + 1) * bq - 1 >= j * bk)
-            for spec in (q_spec, row_spec):
-                b, blk, z = (int(x) for x in spec.index_map(3, j, i))
-                assert (b, z) == (3, 0)
-                assert blk == (i if is_live else first_live)
+            want = i if is_live else first_live
+            assert tuple(int(x) for x in q_spec.index_map(3, j, i)) \
+                == (3, want, 0)
+            assert tuple(int(x) for x in stat_spec.index_map(3, j, i)) \
+                == (3, 0, want)
+            blk = want
             assert tuple(int(x) for x in k_spec2.index_map(3, j, i)) \
                 == (3, j, 0)
+            # dq's grid: the statistics follow the resident q block
+            assert tuple(int(x) for x in fa._stat_spec(bq).index_map(
+                3, i, j)) == (3, 0, i)
             seen.add(blk)
         fetched_q += len(seen)
     assert fetched_k == fetched_q == live < nq * nk
@@ -890,3 +899,146 @@ def test_layers_of_one_shape_trace_each_kernel_body_once(monkeypatch):
     assert str(jaxpr).count("pallas_call[") == 12     # inlined, not shared
     assert len(grids) == 12
     jax.clear_caches()           # the counting bodies are in the jit cache
+
+
+# ---------------------------------------------------------------------------
+# the backward kernels' row statistics: one float32 a row from the residual
+# to the use, and the tile held whichever way needs no (rows, 1) column
+# (PR 39; the compiled kernels: tests/test_tpu_aot_compile.py)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("iq,ik,bq,bk", [(0, 0, 128, 128), (1, 0, 128, 256),
+                                         (2, 3, 64, 128), (0, 1, 256, 128)])
+def test_keys_major_masks_are_the_transposed_masks(iq, ik, bq, bk):
+    """Both masks are functions of absolute positions: a kernel that
+    holds the tile keys-major draws the transpose, bit for bit, and the
+    dropout mask is ``dropout_keep_mask``'s."""
+    from flexflow_tpu.kernels import dropout_keep_mask
+    rate, seed, bh = 0.1, 11, 3
+    seed_ref = jnp.full((1, 1), seed, jnp.int32)
+    for causal in (False, True):
+        kv_len = (ik + 1) * bk - 40
+        qm = fa._key_mask(iq, ik, bq, bk, kv_len, causal)
+        km = fa._key_mask(iq, ik, bq, bk, kv_len, causal, keys_major=True)
+        assert km.shape == (bk, bq) and jnp.array_equal(km, qm.T)
+    keep_q = fa._tile_keep_mask(seed_ref, bh, iq, ik, bq, bk, rate)
+    keep_k = fa._tile_keep_mask(seed_ref, bh, iq, ik, bq, bk, rate,
+                                keys_major=True)
+    whole = dropout_keep_mask(1, bh + 1, (iq + 1) * bq, (ik + 1) * bk, rate,
+                              seed)[0, bh]
+    assert jnp.array_equal(keep_q, whole[iq * bq:, ik * bk:])
+    assert keep_k.shape == (bk, bq) and jnp.array_equal(keep_k, keep_q.T)
+    assert 0.8 < float(jnp.mean(keep_k)) < 0.97
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":       # not a kernel's body
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("case", ["cell2_like", "dropout", "unequal_heads"])
+def test_no_row_statistic_is_replicated_over_128_lanes(case):
+    """Forward and backward traced with bf16 operands, so that what is
+    float32 is the statistics: the forward writes its log-sum-exp as
+    (bh, 1, sq), each backward call is handed two such operands and none
+    with a trailing 128 that is not a head size, no (bh, sq, 128) float32
+    array exists anywhere in the step, and each backward call's
+    ``flash.grid`` says so (``stat_bytes`` = 2 x bh x sq x 4)."""
+    b, h, s, d, dv, kw = {
+        "cell2_like": (2, 3, 256, 64, 64, dict(causal=True)),
+        "dropout": (1, 2, 256, 64, 64, dict(dropout_rate=0.1,
+                                            dropout_seed=3)),
+        # (v narrower than 128: ``do * o`` in float32 has v's width)
+        "unequal_heads": (1, 2, 256, 192, 64, dict(causal=True)),
+    }[case]
+    q, k, v = (jax.ShapeDtypeStruct((b, h, s, w), jnp.bfloat16)
+               for w in (d, d, dv))
+    events.enable()
+    events.clear()
+    try:
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *x: jnp.sum(flash_attention(*x, interpret=True, **kw)
+                               .astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, k, v)
+        grids = {e["attrs"]["kernel"]: e["attrs"] for e in events.events()
+                 if e["name"] == "flash.grid"}
+    finally:
+        events.clear()
+        events.disable()
+    bh = b * h
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    calls = {e.params["name"]: e for e in eqns
+             if e.primitive.name == "pallas_call"}
+    assert sorted(calls) == ["flash_attention_bwd_dkv",
+                             "flash_attention_bwd_dq", "flash_attention_fwd"]
+    assert [tuple(x.aval.shape) for x in
+            calls["flash_attention_fwd"].outvars] == [(bh, s, dv),
+                                                      (bh, 1, s)]
+    for name in ("flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        handed = [(tuple(x.aval.shape), x.aval.dtype)
+                  for x in calls[name].invars]
+        assert handed.count(((bh, 1, s), jnp.float32)) == 2, handed
+        assert not [sh for sh, dt in handed
+                    if dt == jnp.float32 and sh[-1] == 128], handed
+        assert grids[name]["stat_bytes"] == 2 * bh * s * 4
+    assert grids["flash_attention_bwd_dq"]["tile"] == "queries_major"
+    assert grids["flash_attention_bwd_dkv"]["tile"] == "keys_major"
+    assert "stat_bytes" not in grids["flash_attention_fwd"]
+    held = {tuple(x.aval.shape) for e in eqns
+            for x in list(e.invars) + list(e.outvars)
+            if getattr(getattr(x, "aval", None), "dtype", None)
+            == jnp.float32}
+    assert (bh, s, 128) not in held and (b, h, s, 128) not in held
+
+
+# dq (queries-major, statistics from scratch by lanes) and dk, dv
+# (keys-major, statistics as rows) against plain XLA with the kernels'
+# own keep mask: causal x dropout x head sizes x (square with tiles
+# smaller than the sequence | sq != sk | a padded kv_len and q length)
+BWD_FORM_LAYOUTS = {
+    "tiled_256": dict(sq=256, sk=256, bwd_block_q=128, bwd_block_k=128),
+    "cross_128_384": dict(sq=128, sk=384, bwd_block_q=128, bwd_block_k=128),
+    "padded_200": dict(sq=200, sk=200),
+}
+BWD_FORM_CASES = [
+    (layout, d, dv, causal, rate)
+    for layout in BWD_FORM_LAYOUTS for d, dv in ((64, 64), (192, 128))
+    for causal in (False, True) for rate in (0.0, 0.1)
+    if not (causal and layout == "cross_128_384")]
+
+
+@functools.lru_cache(maxsize=None)
+def _bwd_form_grads(layout, d, dv, causal, rate):
+    lay = dict(BWD_FORM_LAYOUTS[layout])
+    sq, sk = lay.pop("sq"), lay.pop("sk")
+    rng = np.random.default_rng(39)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, s, w)), jnp.float32)
+               for s, w in ((sq, d), (sk, d), (sk, dv)))
+    kw = dict(causal=causal, interpret=True, **lay)
+    if rate:
+        kw.update(dropout_rate=rate, dropout_seed=5)
+    grids = _grids_of(jax.grad(lambda *x: jnp.sum(
+        flash_attention(*x, **kw))), q, k, v)
+    got = jax.grad(lambda *x: jnp.sum(flash_attention(*x, **kw) ** 2),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *x: jnp.sum(
+        _fwd_golden(*x, causal, rate, 5) ** 2), argnums=(0, 1, 2))(q, k, v)
+    return grids, got, want
+
+
+@pytest.mark.parametrize("what", ["dq", "dk", "dv"])
+@pytest.mark.parametrize("layout,d,dv,causal,rate", BWD_FORM_CASES)
+def test_flash_backward_forms_match_reference(layout, d, dv, causal, rate,
+                                              what):
+    grids, got, want = _bwd_form_grads(layout, d, dv, causal, rate)
+    kernel = "flash_attention_bwd_" + ("dq" if what == "dq" else "dkv")
+    assert grids[kernel]["tile"] == (
+        "queries_major" if what == "dq" else "keys_major")
+    if layout == "tiled_256":      # several blocks on both sides
+        assert grids[kernel]["steps"] == 2 * 2 * 2
+    i = ("dq", "dk", "dv").index(what)
+    assert got[i].shape == want[i].shape
+    np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want[i]),
+                               atol=1e-3, rtol=1e-3)

@@ -155,8 +155,10 @@ def test_flash_forward_compiles_at_the_derived_blocks(
             q, k, v, causal=causal, interpret=False, dropout_rate=rate,
             dropout_seed=jnp.int32(3)), q, k, v)
     assert _kernel_names(txt) == ["flash_attention_fwd"]
-    # the row statistics leave the kernel as they did: (bh, s, 128) f32
-    assert f"f32[{b * h},{sq},128]" in txt
+    # the log-sum-exp leaves the kernel one float32 a row (PR 39), not
+    # replicated over 128 lanes
+    assert f"f32[{b * h},1,{sq}]" in txt
+    assert dtype == jnp.float32 or f"f32[{b * h},{sq},128]" not in txt
 
 
 @pytest.mark.parametrize("shape", [(4, 12, 1024, 64), (2, 8, 4096, 128)])
@@ -184,10 +186,9 @@ def test_flash_with_unequal_head_sizes_compiles_at_the_latent_shape(
     sh = NamedSharding(mesh, P())
     qk = jax.ShapeDtypeStruct((1, 32, 4096, 192), jnp.bfloat16, sharding=sh)
     v = jax.ShapeDtypeStruct((1, 32, 4096, 128), jnp.bfloat16, sharding=sh)
-    # v's 128 lanes beside q.k's 256: wider tiles fit than at 192 / 192
+    # (since PR 39 counts one float32 a row for the statistics, 192 / 192
+    # fits the same tiles; before, v's 128 lanes bought dkv its width)
     assert bwd_tiles(4096, 4096, 192, jnp.bfloat16, False, 128) == (
-        (1024, 1024), (1024, 1024))
-    assert bwd_tiles(4096, 4096, 192, jnp.bfloat16, False) != (
         (1024, 1024), (1024, 1024))
     txt = _compile_text(
         jax.grad(functools.partial(_flash_loss, None, None),
@@ -232,7 +233,7 @@ def test_flash_bwd_compiles_where_the_tile_rule_has_to_step_down(
     mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
     qkv = jax.ShapeDtypeStruct((1, 2, 2048, d), jnp.dtype(dtype),
                                sharding=NamedSharding(mesh, P()))
-    if dropout or d == 256:
+    if dropout or (d == 256 and dtype == "float32"):
         assert bwd_tiles(2048, 2048, d, qkv.dtype, dropout) != (
             (1024, 1024), (1024, 1024))
 
@@ -244,6 +245,41 @@ def test_flash_bwd_compiles_where_the_tile_rule_has_to_step_down(
 
     txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
     assert _kernel_names(txt) == FLASH_NAMES
+
+
+# the backward as PR 39 left it (one float32 a row for the statistics,
+# dkv's tile keys-major, dq's statistics by lanes from scratch) at the
+# shapes of the benchmark's five cells: (batch, heads, s, d, dv, causal,
+# dropout); cell 5 runs cell 3's shape
+CELL_SHAPES = {
+    "cell1_bert_large": (8, 16, 512, 64, 64, False, 0.1),
+    "cell2_gpt2_124m": (12, 12, 1024, 64, 64, True, 0.0),
+    "cell3_joyai_cell5_kimi": (1, 32, 4096, 192, 128, True, 0.0),
+    "cell4_lfm2": (1, 32, 8192, 64, 64, True, 0.0),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_flash_backward_compiles_at_the_cells_shapes(v5e_devices,
+                                                     chip_locations, cell):
+    b, h, s, d, dv, causal, rate = CELL_SHAPES[cell]
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    sh = NamedSharding(mesh, P())
+    qk = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=sh)
+    v = jax.ShapeDtypeStruct((b, h, s, dv), jnp.bfloat16, sharding=sh)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, interpret=False,
+                            dropout_rate=rate,
+                            dropout_seed=jnp.int32(3) if rate else None)
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert _kernel_names(txt) == FLASH_NAMES
+    # no row statistic replicated over 128 lanes, in or around the calls
+    # (at dv 128 that is also the shape of ``do * o`` in float32)
+    assert dv == 128 or f"f32[{b * h},{s},128]" not in txt
+    assert f"f32[{b * h},1,{s}]" in txt
 
 
 def test_flash_kernels_are_named_in_the_compiled_step(v5e_devices,

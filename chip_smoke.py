@@ -43,6 +43,18 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          ``attn.latent`` instants are the configuration's two lists and
          prints the ``kda.*`` counters; it looks at no gradient:
          ``examples/tpu_validate_linear_latent_moe.py`` does.
+  Leg F  four residual streams under manifold-constrained
+         hyper-connections around every attention and feed-forward, a
+         YaRN-rescaled rotary embedding in latent attention, 4-of-64
+         routing with a shared expert and an MTP module
+         (``build_latent_moe`` from ``hc_mult`` and ``rope_scaling``): a
+         small model, then one chip's share of Xing4.0-29B-A4B at
+         published widths, 1 x 4096 tokens a chip, rematerialised as its
+         benchmark cell is. It checks that every sub-layer announced its
+         maps (``mhc.maps``) and that a rematerialised block is entered
+         by the one stream tensor, and prints the ``mhc.*`` counters; it
+         looks at no gradient: ``examples/tpu_validate_mhc_latent_moe.
+         py`` does.
 
 It claims no speed. The times it prints are set-up facts of one run.
 It exits non-zero, before building anything, unless JAX reports a TPU;
@@ -724,6 +736,85 @@ def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
             f"{n_cc - 131} fewer")
 
 
+# ----------------------------------------------------------------------
+# Leg F — hyper-connected residual streams, YaRN latent attention
+# ----------------------------------------------------------------------
+VALIDATION_MHC = "examples/tpu_validate_mhc_latent_moe.py"
+
+
+def leg_mhc_latent_moe(model_cfg, seq: int, per_chip_batch: int,
+                       label: str, alpha: float = 1e-5) -> None:
+    """``build_latent_moe`` with ``hc_mult`` streams and ``rope_scaling``
+    through compile and fit with ``remat = "blocks"``: the loss falls,
+    two hyper-connection sub-layers a decoder layer announced their maps
+    (the module's layer too), every one ran in every step, no entry of
+    ``Hres~`` met the clamp, the blocks that repeat are entered by the
+    one stream tensor, attention resolved to the flash kernel (on a
+    chip), nothing was dropped, and the step fits the chip.
+    ``VALIDATION_MHC`` holds the maps and the gradients to the reference
+    of the literal iterations, and this leg names it."""
+    import jax
+
+    from flexflow_tpu.models.nlp import build_latent_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_latent_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    n_layers = model_cfg.num_hidden_layers \
+        + model_cfg.num_nextn_predict_layers
+    maps = {e["attrs"]["layer"]: e["attrs"] for e in events.events()
+            if e["name"] == "mhc.maps"}
+    one = next(iter(maps.values()))
+    say(f"{label}: mhc.maps in {len(maps)} sub-layers: {one['streams']} "
+        f"streams of {one['channels']}, {one['iters']} iterations, "
+        f"{one['tokens']} tokens, {one['stream_bytes'] / 2 ** 20:.0f} MiB "
+        f"of streams a pass")
+    check(len(maps) == 2 * n_layers
+          and all(a["streams"] == model_cfg.hc_mult
+                  and a["iters"] == model_cfg.hc_sinkhorn_iters
+                  for a in maps.values()),
+          f"{label}: {len(maps)} sub-layers announced their maps, the "
+          f"model has {2 * n_layers}: {sorted(maps)}")
+    latent = {e["attrs"]["layer"] for e in events.events()
+              if e["name"] == "attn.latent"}
+    check(len(latent) == n_layers,
+          f"{label}: {len(latent)} latent-attention layers announced")
+    scaled = [l.params.get("rope_scaling") for l in ff.layers
+              if l.name in latent]
+    check(all(s == model_cfg.rope_scaling for s in scaled),
+          f"{label}: latent attention built with rope_scaling {scaled}")
+    entry = next(t for l in ff.layers for t in l.outputs
+                 if t.guid == ff.executor._remat[3][0])
+    say(f"{label}: a rematerialised block is entered by {entry.shape}")
+    check(entry.shape == (x[0].shape[0], seq, model_cfg.hc_mult,
+                          model_cfg.hidden_size),
+          f"{label}: a block is entered by {entry.shape}")
+    ctr = events.counters()
+    steps = 1 + TRAIN_STEPS
+    subs = ctr.get("mhc.sublayers", 0)
+    say(f"{label}: counters mhc.sublayers {subs}, mhc.clamped "
+        f"{ctr.get('mhc.clamped')}, mhc.sum_err "
+        f"{ctr.get('mhc.sum_err', 0) / max(1, subs):.2e} a sub-layer (the "
+        f"worst token's |row or column sum - 1| after "
+        f"{model_cfg.hc_sinkhorn_iters} iterations)")
+    check(subs == steps * 2 * n_layers and ctr.get("mhc.clamped") == 0,
+          f"{label}: {subs} sub-layers counted in {steps} steps of "
+          f"{2 * n_layers}, {ctr.get('mhc.clamped')} entries clamped")
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: resolved attention impls "
+        f"{sorted(set(impls.values()))} in {len(impls)} layers")
+    if chip:
+        check(set(impls.values()) == {"flash"},
+              f"{label}: attention resolved to {impls} at seq {seq}")
+    _check_experts_counters(label)
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: the maps and the gradients against "
+        f"the reference of the literal iterations: python3 "
+        f"{VALIDATION_MHC}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def _check_generate(ff, ids) -> None:
     """KV-cache decode against the re-forward path on one prompt.
 
@@ -827,7 +918,8 @@ def main() -> int:
                                          HybridConvMoEConfig,
                                          JoyAIFlashRankConfig,
                                          KimiLinearRankConfig,
-                                         LatentMoEConfig, LFM2RankConfig)
+                                         LatentMoEConfig, LFM2RankConfig,
+                                         XingRankConfig)
     from flexflow_tpu.utils.compilation_cache import (
         cache_entries, enable_compilation_cache)
     cache = enable_compilation_cache()
@@ -854,6 +946,9 @@ def main() -> int:
         leg_linear_latent_moe(KimiLinearRankConfig.tiny(), 1024, 1,
                               "E/small", alpha=1e-3)
         leg_linear_latent_moe(KimiLinearRankConfig(), 4096, 1, "E/kimi")
+        leg_mhc_latent_moe(XingRankConfig.tiny(), 1024, 1, "F/small",
+                           alpha=1e-3)
+        leg_mhc_latent_moe(XingRankConfig(), 4096, 1, "F/xing")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -380,6 +380,7 @@ def _check_op_shards(report, strategy, by_name, axis_sizes,
             _check_spec(report, axis_sizes, name, f"output[{i}]", sp,
                         shape)
             _check_conv_sequence(report, axis_sizes, layer, sp)
+            _check_stream_axes(report, axis_sizes, layer, sp)
         for wname, sp in (getattr(os_, "weights", {}) or {}).items():
             if sp is None:
                 continue
@@ -390,6 +391,26 @@ def _check_op_shards(report, strategy, by_name, axis_sizes,
     for tname, sp in getattr(strategy, "inputs", {}).items():
         _check_spec(report, axis_sizes, tname, "input", sp,
                     in_shapes.get(tname))
+
+
+def _check_stream_axes(report, axis_sizes, layer, spec) -> None:
+    """A hyper-connection node is per token: batch and sequence shard
+    freely. Every axis after them does not. The maps mix all of a
+    token's streams, and the norm and the product with ``phi`` sum over
+    all of its channels: a shard of either would need its partial sums
+    reduced, which no layer here emits and no cost row prices
+    (``search/opshard.py`` offers neither)."""
+    from ..ffconst import OperatorType
+    if getattr(layer, "op_type", None) != OperatorType.OP_HYPER_CONNECTION:
+        return
+    for dim, axes in enumerate(_spec_entries(spec)):
+        if dim >= 2 and any(axis_sizes.get(a, 1) > 1 for a in axes):
+            report.add("op-shard", "error", layer.name,
+                       f"output spec {spec} shards dim {dim} of a "
+                       f"hyper-connection node: beyond batch and sequence "
+                       f"the streams, the channels and the maps are one "
+                       f"token's, and the partial sums a shard of them "
+                       f"needs reduced are not built")
 
 
 def _check_conv_sequence(report, axis_sizes, layer, spec) -> None:

@@ -229,6 +229,10 @@ class OperatorType(enum.IntEnum):
     # a gated delta-rule linear-attention layer: a state carried along
     # the sequence (a chunked scan and its backward)
     OP_GATED_DELTA_RULE = enum.auto()
+    # manifold-constrained hyper-connections: a residual of several
+    # streams a token, read and written through learned, token-dependent
+    # maps, the stream-to-stream one projected doubly stochastic
+    OP_HYPER_CONNECTION = enum.auto()
 
 
 # Ops that are pure elementwise-unary (single input, same shape out).

@@ -299,6 +299,7 @@ class FFModel:
                          kv_rank: int, nope_dim: int, rope_dim: int,
                          v_dim: int, rope_theta: float = 10000.0,
                          eps: float = 1e-6, rope: bool = True,
+                         rope_scaling: Optional[dict] = None,
                          name: Optional[str] = None) -> Tensor:
         """Causal multi-head latent attention (``ops.nn_ops.
         LatentAttentionOp``): low-rank q (``q_rank``; None: one full
@@ -307,15 +308,62 @@ class FFModel:
         ``rope_dim``, one rotary key shared by the heads; ``rope=False``:
         those entries as they are), v heads of ``v_dim``.
         ``positions``: (batch, seq) int32, what the rotary embedding
-        turns by."""
+        turns by. ``rope_scaling``: the published ``config.json`` group
+        of a YaRN-rescaled rotary embedding (``type: "yarn"``, ``factor``,
+        ``original_max_position_embeddings``, ``beta_fast``,
+        ``beta_slow``, ``mscale``, ``mscale_all_dim``): blended
+        frequencies and a larger score scale (``ops.nn_ops.
+        rope_frequencies``, ``yarn_mscale``); None: neither."""
         params = dict(num_heads=num_heads, q_rank=q_rank, kv_rank=kv_rank,
                       nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
                       rope_theta=float(rope_theta), eps=eps)
         if not rope:
             params["rope"] = False
+        if rope_scaling:
+            if rope_scaling.get("type") != "yarn" or not rope:
+                raise ValueError(
+                    f"rope_scaling {rope_scaling} (rope={rope}): only "
+                    f"type 'yarn' on a rotary embedding is built")
+            params["rope_scaling"] = dict(rope_scaling)
         return self._add_layer(OperatorType.OP_LATENT_ATTENTION,
                                [input, positions], params,
                                name).outputs[0]
+
+    def hyper_connection_pre(self, streams: Tensor, iters: int = 20,
+                             eps: float = 1e-6, norm_eps: float = 1e-6,
+                             clamp: Tuple[float, float] = (-30.0, 30.0),
+                             name: Optional[str] = None
+                             ) -> Tuple[Tensor, Tensor]:
+        """The reading half of a hyper-connected sub-layer (``ops.
+        hyper_ops.HyperConnectionOp``): from the residual ``streams``
+        (batch, seq, n, hidden) the sub-layer's input ``Hpre X`` (batch,
+        seq, hidden) and the maps ``[Hpost ; Hres]`` of every token, for
+        :meth:`hyper_connection_post`. ``iters`` Sinkhorn-Knopp
+        iterations with ``eps`` in each denominator over ``Hres~``
+        clipped to ``clamp``; ``norm_eps`` under the root of the
+        streams' mean square. The draw of its weights is the op's own
+        (``hyper_ops.MAPS_DRAW``)."""
+        if len(streams.shape) != 4:
+            raise ValueError(f"streams of shape {streams.shape}: "
+                             f"(batch, seq, streams, hidden) is wanted")
+        if iters < 1 or not clamp[0] < clamp[1]:
+            raise ValueError(f"{iters} iterations, clamp {clamp}")
+        u, maps = self._add_layer(
+            OperatorType.OP_HYPER_CONNECTION, [streams],
+            dict(stage="pre", iters=int(iters), eps=float(eps),
+                 norm_eps=float(norm_eps),
+                 clamp=[float(clamp[0]), float(clamp[1])]), name).outputs
+        return u, maps
+
+    def hyper_connection_post(self, streams: Tensor, output: Tensor,
+                              maps: Tensor,
+                              name: Optional[str] = None) -> Tensor:
+        """The writing half: ``Hres X + Hpost^T output``, the new
+        streams, from the maps :meth:`hyper_connection_pre` gave for
+        these ``streams``."""
+        return self._add_layer(OperatorType.OP_HYPER_CONNECTION,
+                               [streams, output, maps],
+                               dict(stage="post"), name).outputs[0]
 
     def routed_experts(self, input: Tensor, num_experts: int, top_k: int,
                        expert_dim: int, shared_dim: int = 0,

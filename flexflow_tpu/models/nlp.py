@@ -692,7 +692,17 @@ class LatentMoEConfig:
     and those that keep latent attention (``full_attn_layers``);
     ``mla_use_nope`` (absent: False) leaves the rotary embedding out of
     latent attention; ``expert_rows_factor`` (absent: 2) is an expert
-    layer's row budget in uniform shares."""
+    layer's row budget in uniform shares.
+
+    Six more where a subclass has them (:class:`XingRankConfig`), keys
+    of ``model_type: xing4_0``. ``rope_scaling`` (absent or None: plain
+    frequencies) is a ``type: "yarn"`` group for latent attention's
+    rotary embedding. ``hc_mult`` (absent or 1: a layer's output is
+    ADDED to one residual stream) is the number of residual streams of
+    manifold-constrained hyper-connections: every attention and
+    feed-forward then reads ``Hpre X`` and is written back as ``Hres X +
+    Hpost^T F`` (``ops.hyper_ops``), with ``hc_sinkhorn_iters``,
+    ``hc_eps``, ``mhc_h_res_clamp_min`` and ``mhc_h_res_clamp_max``."""
     vocab_size: int = 129280
     hidden_size: int = 2048
     num_hidden_layers: int = 40
@@ -803,6 +813,61 @@ class KimiLinearRankConfig(LatentMoEConfig):
                    num_experts_per_token=4)
 
 
+def _xing_rope_scaling():
+    return {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
+            "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+            "type": "yarn"}
+
+
+@dataclasses.dataclass
+class XingRankConfig(LatentMoEConfig):
+    """What ONE chip holds of Xing4.0-29B-A4B where 8 chips share each
+    layer as one tensor- and expert-parallel group working on the same
+    micro-batch (the benchmark's ``xing4_29b_a4b``): heads 0 to 3 of the
+    32 (``wq_b``, ``wkv_b`` and ``wo`` by head; this chip's heads' part
+    of the output projection is what goes on), experts 0 to 7 of the 64,
+    one of eight slices of the vocabulary, and published layer 0 (a
+    dense one) with layers 2 to 5 (the rest lie on further chips as
+    pipeline stages); every width as published. The down-projections to
+    the latents, the routers, the shared expert, the dense feed-forward
+    and the hyper-connection maps are computed whole on every chip."""
+    vocab_size: int = 16384
+    hidden_size: int = 3584
+    num_hidden_layers: int = 5
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 4
+    q_lora_rank: int | None = 768
+    rope_theta: float = 10000.0
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    n_routed_experts: int = 8
+    n_routed_experts_published: int | None = 64
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 2.0
+    # the six keys the parent class does not have (its docstring)
+    rope_scaling: dict | None = dataclasses.field(
+        default_factory=_xing_rope_scaling)
+    hc_mult: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30
+    mhc_h_res_clamp_max: float = 30
+
+    @classmethod
+    def tiny(cls):
+        """The benchmark's layout at a small size: 3 + 1 layers of 4
+        streams, 4 heads of 16 + 8 / 16 under YaRN with a factor of 4
+        over 16 original positions, 16 experts top-4: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_hidden_layers=3,
+                   q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                   qk_rope_head_dim=8, v_head_dim=16, intermediate_size=160,
+                   moe_intermediate_size=32, n_routed_experts=16,
+                   n_routed_experts_published=None, router_bias_std=0.05,
+                   rope_scaling=dict(_xing_rope_scaling(), factor=4,
+                                     original_max_position_embeddings=16,
+                                     beta_fast=4))
+
+
 def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
                      cfg: LatentMoEConfig | None = None):
     """Causal LM of :class:`LatentMoEConfig`: inputs ``[ids, pos]``,
@@ -812,6 +877,13 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
     for the layers ``linear_attn_config`` names, a gated delta-rule
     linear-attention layer; its feed-forward does not depend on which.
 
+    The residual rule is chosen once from ``hc_mult``. Absent or 1: a
+    sub-layer's output is added to the one stream. ``n`` > 1: the
+    embedding is copied to ``n`` streams (batch, seq, n, hidden), every
+    sub-layer reads and writes them through its own maps
+    (``FFModel.hyper_connection_pre`` / ``_post``), and the streams are
+    summed before the final norm.
+
     The multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
     section 2.2) predicts token ``t + 2`` from the trunk's last hidden
     state at ``t`` and the embedding of token ``t + 1`` through one more
@@ -820,7 +892,9 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
     graph has no weight shared by two layers, so the module reads the
     trunk's embedding output shifted by one position, and its final
     hidden state goes through the ONE head layer beside the trunk's
-    (joined along the sequence, split again after)."""
+    (joined along the sequence, split again after). Under several
+    streams it reads the SUMMED trunk state, copies ``h'`` to streams of
+    its own and sums them after its layer."""
     cfg = cfg or LatentMoEConfig()
     if cfg.num_nextn_predict_layers not in (0, 1):
         raise ValueError("0 or 1 multi-token-prediction module")
@@ -843,7 +917,35 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
     def norm(x, name):
         return ff.rms_norm(x, eps=cfg.rms_norm_eps, name=name)
 
+    streams = getattr(cfg, "hc_mult", None) or 1
+
+    def spread(x, name):                # a copy of x in each stream
+        if streams == 1:
+            return x
+        return ff.concat([ff.unsqueeze(x, [2])] * streams, axis=2,
+                         name=name)
+
+    def gathered(x, name):
+        return x if streams == 1 else ff.reduce_sum(x, [2], name=name)
+
+    def residual(h, sublayer, name):
+        """``h`` after one sub-layer: ``sublayer`` maps what it reads to
+        what it adds."""
+        if streams == 1:
+            return ff.add(h, sublayer(h), name=name)
+        u, maps = ff.hyper_connection_pre(
+            h, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.rms_norm_eps,
+            (cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
+            name=name + "_pre")
+        return ff.hyper_connection_post(h, sublayer(u), maps, name=name)
+
     def decoder_layer(h, tag, experts: bool, linear: bool = False):
+        h = residual(h, lambda u: operator(u, tag, linear),
+                     f"attn_res_{tag}")
+        return residual(h, lambda u: feed_forward(u, tag, experts),
+                        f"mlp_res_{tag}")
+
+    def operator(h, tag, linear: bool):
         x = norm(h, f"input_norm_{tag}")
         if linear:
             attn = ff.gated_delta_rule(
@@ -857,8 +959,11 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
                 cfg.qk_rope_head_dim, cfg.v_head_dim,
                 rope_theta=cfg.rope_theta, eps=cfg.rms_norm_eps,
                 rope=not getattr(cfg, "mla_use_nope", False),
+                rope_scaling=getattr(cfg, "rope_scaling", None),
                 name=f"attn_{tag}")
-        h = ff.add(h, attn, name=f"attn_res_{tag}")
+        return attn
+
+    def feed_forward(h, tag, experts: bool):
         x = norm(h, f"post_norm_{tag}")
         if experts:
             y = ff.routed_experts(
@@ -879,12 +984,13 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
             silu = ff.multiply(gate, ff.sigmoid(gate), name=f"silu_{tag}")
             y = ff.dense(ff.multiply(silu, up), hid, use_bias=False,
                          name=f"down_proj_{tag}")
-        return ff.add(h, y, name=f"mlp_res_{tag}")
+        return y
 
-    h = emb
+    h = spread(emb, "streams")
     for i in range(cfg.num_hidden_layers):
         h = decoder_layer(h, str(i), i >= cfg.first_k_dense_replace,
                           i + 1 in linear)
+    h = gathered(h, "streams_sum")
     out = norm(h, "final_norm")
     if not cfg.num_nextn_predict_layers:
         return ff.softmax(ff.dense(out, cfg.vocab_size, use_bias=False,
@@ -898,7 +1004,9 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
     joined = ff.concat([norm(nxt, "mtp_enorm"), norm(h, "mtp_hnorm")],
                        axis=-1, name="mtp_concat")
     hm = ff.dense(joined, hid, use_bias=False, name="mtp_eh_proj")
-    hm = norm(decoder_layer(hm, "mtp", True), "mtp_final_norm")
+    hm = gathered(decoder_layer(spread(hm, "mtp_streams"), "mtp", True),
+                  "mtp_streams_sum")
+    hm = norm(hm, "mtp_final_norm")
     logits = ff.dense(ff.concat([out, hm], axis=1, name="head_in"),
                       cfg.vocab_size, use_bias=False, name="lm_head")
     ff.next_token_loss(ff.slice_tensor(logits, [s], [2 * s], [1]), ids,

@@ -19,4 +19,5 @@ from . import tensor_ops    # noqa: F401
 from . import moe_ops       # noqa: F401
 from . import rnn_ops       # noqa: F401
 from . import recurrent_ops  # noqa: F401
+from . import hyper_ops     # noqa: F401
 from . import parallel_ops  # noqa: F401

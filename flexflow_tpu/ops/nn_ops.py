@@ -928,12 +928,50 @@ class MultiHeadAttentionOp(OpDef):
 
 
 # ---------------------------------------------------------------------------
-def _rope_interleaved(x, pos, theta: float):
+def yarn_correction_range(rope_dim: int, theta: float, scaling: dict):
+    """``(low, high)``: the rotary pairs below ``low`` turn more than
+    ``beta_fast`` times over the original context and keep their
+    frequency, those from ``high`` on turn less than ``beta_slow`` times
+    and are divided by ``factor`` (YaRN, arXiv:2309.00071, as DeepSeek-V3
+    computes it)."""
+    def pair(turns):
+        return rope_dim * math.log(
+            scaling["original_max_position_embeddings"]
+            / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    low = math.floor(pair(scaling["beta_fast"]))
+    high = math.ceil(pair(scaling["beta_slow"]))
+    return max(low, 0), min(high, rope_dim - 1)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """``0.1 mscale ln(factor) + 1``: what YaRN multiplies by to keep
+    the scores' spread over the stretched context."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(rope_dim: int, theta: float, scaling=None):
+    """A pair's angle a position, ``theta ^ (-2i / d)`` for pair ``i``
+    (float32, (d / 2,)). ``scaling`` (a ``type: "yarn"`` group): pair
+    ``i`` keeps that, ``f_i``, below the correction range, takes ``f_i /
+    factor`` above it and the blend ``f_i (1 - r_i) + (f_i / factor)
+    r_i`` on the ramp ``r_i = (i - low) / (high - low)`` between."""
+    if not scaling:
+        return 1.0 / theta ** (jnp.arange(0, rope_dim, 2, dtype=jnp.float32)
+                               / rope_dim)
+    i = np.arange(rope_dim // 2)
+    f = theta ** (-2.0 * i / rope_dim)
+    low, high = yarn_correction_range(rope_dim, theta, scaling)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return jnp.asarray(f * (1 - ramp) + f / scaling["factor"] * ramp,
+                       jnp.float32)
+
+
+def _rope_interleaved(x, pos, inv):
     """Rotary embedding over interleaved pairs ``(2i, 2i+1)`` of the
     last axis (DeepSeek's ``rope_interleave``), in float32. ``x``:
-    (b, s, ..., d); ``pos``: (b, s) absolute positions."""
+    (b, s, ..., d); ``pos``: (b, s) absolute positions; ``inv``: (d / 2,)
+    the pairs' frequencies (:func:`rope_frequencies`)."""
     d = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[..., None] * inv           # (b, s, d/2)
     ang = ang.reshape(pos.shape + (1,) * (x.ndim - 3) + (d // 2,))
     xf = x.astype(jnp.float32)
@@ -962,6 +1000,12 @@ class LatentAttentionOp(OpDef):
     o ``v_dim``: the flash kernels take the two sizes as they are
     (``kernels/flash_attention.py``), chosen by shape and switches
     exactly as in :class:`MultiHeadAttentionOp`.
+
+    ``rope_scaling`` (a published ``type: "yarn"`` group; absent: none)
+    rescales the rotary embedding for a context stretched ``factor``
+    times: the slow pairs' frequencies divided by it, the fast ones
+    kept, a ramp between (:func:`rope_frequencies`), and the scores
+    times ``yarn_mscale(factor, mscale_all_dim) ^ 2``.
 
     Two parameters switch parts of it off. ``q_rank=None``: no q latent,
     ``q_h = x wq`` with one ``wq`` (e, h, d_nope + d_rope) and no
@@ -1012,6 +1056,26 @@ class LatentAttentionOp(OpDef):
             return jnp.einsum(pattern, a.astype(mdt), w.astype(mdt),
                               preferred_element_type=jnp.float32)
 
+        scaling = params.get("rope_scaling")
+        # None without rope_scaling: the kernels' and XLA's 1 / sqrt(d)
+        sm_scale = None
+        if scaling:
+            # cos and sin times m(mscale) / m(mscale_all_dim), the
+            # scores times m(mscale_all_dim)^2
+            m_all = yarn_mscale(scaling["factor"],
+                                scaling.get("mscale_all_dim", 0.0))
+            rot_scale = yarn_mscale(scaling["factor"],
+                                    scaling.get("mscale", 1.0)) / m_all
+            if rot_scale != 1.0:
+                raise NotImplementedError(
+                    f"{name}: mscale != mscale_all_dim scales the rotary "
+                    f"embedding itself by {rot_scale}, which is not built")
+            sm_scale = m_all * m_all / math.sqrt(dn + dr)
+
+        def inv_freq():
+            return rope_frequencies(dr, float(params["rope_theta"]),
+                                    scaling)
+
         if params["q_rank"] is None:
             q = mm(x, weights["wq"], "bse,ehd->bshd")
         else:
@@ -1022,9 +1086,10 @@ class LatentAttentionOp(OpDef):
         c_kv = _rms(kv_a[..., :kvr], weights["kv_norm"], eps)
         kv = mm(c_kv, weights["wkv_b"], "bsr,rhd->bshd")
         if params.get("rope", True):
-            theta = float(params["rope_theta"])
-            q_rope = _rope_interleaved(q[..., dn:], pos, theta)
-            k_rope = _rope_interleaved(kv_a[..., kvr:], pos, theta)
+            # the frequencies are made anew for each use: the step's
+            # text stays what it was when each call made its own
+            q_rope = _rope_interleaved(q[..., dn:], pos, inv_freq())
+            k_rope = _rope_interleaved(kv_a[..., kvr:], pos, inv_freq())
         else:
             q_rope, k_rope = q[..., dn:], kv_a[..., kvr:]
         h = q.shape[2]
@@ -1050,13 +1115,15 @@ class LatentAttentionOp(OpDef):
                 jnp.swapaxes(qh, 1, 2).astype(mdt),
                 jnp.swapaxes(kh, 1, 2).astype(mdt),
                 jnp.swapaxes(vh, 1, 2).astype(mdt),
-                causal=True, mesh=mesh, spec=spec)
+                causal=True, sm_scale=sm_scale, mesh=mesh, spec=spec)
             o = jnp.swapaxes(o, 1, 2)
         else:
             mha._note_impl(ctx, name, "xla")
             logits = jnp.einsum(
                 "bqhd,bkhd->bhqk", qh.astype(mdt), kh.astype(mdt),
-                preferred_element_type=jnp.float32) / math.sqrt(dn + dr)
+                preferred_element_type=jnp.float32)
+            logits = logits / math.sqrt(dn + dr) if sm_scale is None \
+                else logits * sm_scale
             mask = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
             probs = jax.nn.softmax(
                 jnp.where(mask, logits, jnp.float32(-1e9)), axis=-1)

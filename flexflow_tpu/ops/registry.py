@@ -112,6 +112,11 @@ class OpDef:
         """bwd/fwd FLOP ratio. 2.0 for matmul-like ops (dgrad+wgrad)."""
         return 1.0
 
+    def bytes_moved(self, params, in_shapes, out_shapes) -> Optional[float]:
+        """Forward bytes to and from memory, for an op that moves more
+        than its inputs, outputs and weights once each. None: that."""
+        return None
+
 
 OPS: Dict[OperatorType, OpDef] = {}
 

@@ -46,6 +46,15 @@ def _mapped(name, draw, xp):
     raise ValueError(f"unknown map {name!r} of a uniform draw")
 
 
+def _diagonal(args, shape, xp):
+    """``init_args["diagonal"]``: added on the diagonal of a square
+    matrix drawn NORMAL (a map that starts near a multiple of the
+    identity)."""
+    if not args.get("diagonal"):
+        return 0.0
+    return args["diagonal"] * xp.eye(*shape)
+
+
 def initialize_host(spec, key_ints, np_dtype):
     """Host-side twin of :func:`initialize`: numpy Philox keyed by the
     integer path ``key_ints`` (deterministic across runs/platforms).
@@ -82,7 +91,8 @@ def initialize_host(spec, key_ints, np_dtype):
                        np).astype(np_dtype)
     if kind == InitializerType.NORMAL:
         mean, std = args.get("mean", 0.0), args.get("stddev", 0.05)
-        return (mean + std * gen.standard_normal(shape)).astype(np_dtype)
+        return (mean + std * gen.standard_normal(shape)
+                + _diagonal(args, shape, np)).astype(np_dtype)
     if kind == InitializerType.GLOROT_UNIFORM:
         fan_in, fan_out = args.get("fans") or _fan_in_out(shape)
         limit = math.sqrt(6.0 / (fan_in + fan_out))
@@ -108,7 +118,8 @@ def initialize(spec, rng, jnp_dtype):
                        jnp)
     if kind == InitializerType.NORMAL:
         mean, std = args.get("mean", 0.0), args.get("stddev", 0.05)
-        return mean + std * jax.random.normal(rng, shape, jnp_dtype)
+        return mean + std * jax.random.normal(rng, shape, jnp_dtype) \
+            + _diagonal(args, shape, jnp)
     if kind == InitializerType.GLOROT_UNIFORM:
         fan_in, fan_out = args.get("fans") or _fan_in_out(shape)
         limit = math.sqrt(6.0 / (fan_in + fan_out))

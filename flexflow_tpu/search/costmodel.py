@@ -887,6 +887,9 @@ class OpCostModel:
         w_bytes = sum(int(np.prod(w.shape)) * itemsize(w.dtype)
                       for w in layer.weights) // max(weight_shard_degree, 1)
         bytes_moved = in_bytes + out_bytes + w_bytes
+        own = op.bytes_moved(layer.params, in_shapes, out_shapes)
+        if own is not None:
+            bytes_moved = own / total_deg + w_bytes
         t_compute = flops / (self.spec.peak_flops * self.mxu_eff)
         # calibration v2: measured memory bandwidth replaces the
         # datasheet HBM constant; measured host dispatch overhead

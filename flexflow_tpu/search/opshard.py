@@ -90,6 +90,14 @@ def options_for(layer: Layer) -> List[ShardOption]:
             ("wq", 1), ("wk", 1), ("wv", 1), ("conv_q", 0), ("conv_k", 0),
             ("conv_v", 0), ("wf_b", 1), ("A_log", 0), ("dt_bias", 0),
             ("wb", 1), ("wg_b", 1), ("wo", 0))))
+    elif t == OperatorType.OP_HYPER_CONNECTION:
+        sample()
+        # per token: the maps of a position read that position's streams
+        # alone, so the sequence shards with no halo. The stream axis is
+        # NOT offered (a map mixes all of a token's streams), nor the
+        # channels: the norm's and phi's sums over them would need a
+        # reduction that is not built (the plan verifier refuses both)
+        opts.append(ShardOption("attribute", 1))
     elif t == OperatorType.OP_LAYERNORM or t == OperatorType.OP_RMSNORM:
         sample()
         if r >= 3:

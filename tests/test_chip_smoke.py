@@ -17,7 +17,8 @@ import pytest
 import chip_smoke
 from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
                                      HybridConvMoEConfig,
-                                     KimiLinearRankConfig, LatentMoEConfig)
+                                     KimiLinearRankConfig, LatentMoEConfig,
+                                     XingRankConfig)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -124,6 +125,29 @@ def test_leg_e_linear_latent_moe_tiny_on_the_cpu_mesh(capsys):
     assert f"python3 {chip_smoke.VALIDATION_LINEAR}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_LINEAR))
+
+
+def test_leg_f_mhc_latent_moe_tiny_on_the_cpu_mesh(capsys):
+    """A share of 4 of 16 experts on the 8-device mesh: every sub-layer
+    announces its maps, the two whole layers that repeat are entered by
+    the stream tensor and rematerialised, every sub-layer is counted in
+    every step."""
+    cfg = dataclasses.replace(XingRankConfig.tiny(), n_routed_experts=4,
+                              n_routed_experts_published=16)
+    chip_smoke.leg_mhc_latent_moe(cfg, seq=16, per_chip_batch=1,
+                                  label="F/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "rematerialised run (16, 8, 2)" in out
+    assert ("mhc.maps in 8 sub-layers: 4 streams of 64, 20 iterations, "
+            "128 tokens") in out
+    assert "a rematerialised block is entered by (8, 16, 4, 64)" in out
+    assert f"counters mhc.sublayers {8.0 * (1 + chip_smoke.TRAIN_STEPS)}, " \
+           f"mhc.clamped 0.0, mhc.sum_err " in out
+    assert "resolved attention impls ['xla'] in 4 layers" in out  # cpu
+    assert "moe.dropped 0.0, moe.overflow 0.0" in out
+    assert f"python3 {chip_smoke.VALIDATION_MHC}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_MHC))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

@@ -51,8 +51,10 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          small model, then one chip's share of Xing4.0-29B-A4B at
          published widths, 1 x 4096 tokens a chip, rematerialised as its
          benchmark cell is. It checks that every sub-layer announced its
-         maps (``mhc.maps``) and that a rematerialised block is entered
-         by the one stream tensor, and prints the ``mhc.*`` counters; it
+         maps (``mhc.maps``, with the path the mixes took: ``impl``, the
+         ``mhc.kernel`` instants and the kernels' calls in the compiled
+         step) and that a rematerialised block is entered by the one
+         stream tensor, and prints the ``mhc.*`` counters; it
          looks at no gradient: ``examples/tpu_validate_mhc_latent_moe.
          py`` does.
 
@@ -496,9 +498,10 @@ def _check_experts_counters(label: str) -> None:
           f"budgets {budgets}")
 
 
-def _compiled_step_size(ff, x, y, label: str) -> int:
+def _compiled_step_size(ff, x, y, label: str, named: str = "") -> int:
     """Print the compiled train step's GiB a device (on a chip it has to
-    fit) and return its count of Mosaic calls."""
+    fit) and return its count of Mosaic calls: all of them, or with
+    ``named`` those whose line has that part of a kernel's name."""
     import jax
     import jax.numpy as jnp
     step = ff.executor.make_train_step()
@@ -508,12 +511,13 @@ def _compiled_step_size(ff, x, y, label: str) -> int:
     ma = compiled.memory_analysis()
     gib = (ma.argument_size_in_bytes + ma.output_size_in_bytes
            + ma.temp_size_in_bytes - ma.alias_size_in_bytes) / 2 ** 30
-    n_cc = compiled.as_text().count('custom_call_target="tpu_custom_call"')
-    say(f"{label}: compiled step {gib:.2f} GiB a device, {n_cc} "
+    calls = [l for l in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    say(f"{label}: compiled step {gib:.2f} GiB a device, {len(calls)} "
         f"tpu_custom_call(s), peak_bytes_in_use {_peak_bytes()}")
     if jax.devices()[0].platform != "cpu":
         check(gib <= 15.0, f"{label}: the step takes {gib:.2f} GiB")
-    return n_cc
+    return sum(named in l for l in calls)
 
 
 # ----------------------------------------------------------------------
@@ -755,6 +759,7 @@ def leg_mhc_latent_moe(model_cfg, seq: int, per_chip_batch: int,
     of the literal iterations, and this leg names it."""
     import jax
 
+    from flexflow_tpu.kernels.hyper_connection import KERNELS, takes_kernel
     from flexflow_tpu.models.nlp import build_latent_moe
     from flexflow_tpu.obs import events
     chip = jax.devices()[0].platform != "cpu"
@@ -776,6 +781,23 @@ def leg_mhc_latent_moe(model_cfg, seq: int, per_chip_batch: int,
                   for a in maps.values()),
           f"{label}: {len(maps)} sub-layers announced their maps, the "
           f"model has {2 * n_layers}: {sorted(maps)}")
+    kernels = {}
+    for e in events.events():
+        if e["name"] == "mhc.kernel":
+            kernels.setdefault(e["attrs"]["kernel"], e["attrs"])
+    took = {a["impl"] for a in maps.values()}
+    want = "kernel" if takes_kernel(one["streams"], one["channels"],
+                                    one["tokens"]) else "plain"
+    say(f"{label}: the streams' mixes by {sorted(took)} (the shapes say "
+        f"{want})")
+    for kind, a in sorted(kernels.items()):
+        say(f"{label}: mhc.kernel {kind}: {a['grid_steps']} grid steps of "
+            f"{a['tile']} tokens, {a['vmem_bytes'] / 2 ** 20:.1f} MiB of "
+            f"VMEM a step")
+    check(took == {want} and sorted(kernels) == (
+        sorted(KERNELS) if want == "kernel" else []),
+          f"{label}: the hyper-connection nodes announced {sorted(took)} "
+          f"and the kernels {sorted(kernels)} where the shapes say {want}")
     latent = {e["attrs"]["layer"] for e in events.events()
               if e["name"] == "attn.latent"}
     check(len(latent) == n_layers,
@@ -812,7 +834,15 @@ def leg_mhc_latent_moe(model_cfg, seq: int, per_chip_batch: int,
     say(f"{label}: not checked here: the maps and the gradients against "
         f"the reference of the literal iterations: python3 "
         f"{VALIDATION_MHC}")
-    _compiled_step_size(ff, x, y, label)
+    calls = _compiled_step_size(ff, x, y, label, named="hyper_connection_")
+    say(f"{label}: {calls} of the step's Mosaic calls are the "
+        f"hyper-connection kernels'")
+    # (compiled for the chip: on the CPU a kernel is interpreted into
+    # plain ops and the step has no Mosaic call at all)
+    check(calls >= 4 * len(maps) if chip and want == "kernel"
+          else calls == 0,
+          f"{label}: {calls} hyper-connection kernel calls in the step of "
+          f"{len(maps)} sub-layers whose shapes say {want}")
 
 
 def _check_generate(ff, ids) -> None:

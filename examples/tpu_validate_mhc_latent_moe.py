@@ -51,8 +51,11 @@ on any failure):
      random streams with a later one's. ``Hres`` against the 20 literal
      iterations entry by entry, the new streams, and the gradient of a
      fixed cotangent for the streams and each of the five weights
-     against ``jax.grad`` of the reference (the scan's transpose, the
-     rematerialised ``pre``). The same operator with 5 iterations has
+     against ``jax.grad`` of the reference (the scan's transpose; since
+     PR 42 the four kernels of ``kernels/hyper_connection.py``, which
+     this width takes: the check says which path ran, from the
+     ``mhc.maps`` and ``mhc.kernel`` instants), each within 1e-5. The
+     same operator with 5 iterations has
      to FAIL the ``Hres`` comparison, and its ``mhc.sum_err`` has to
      leave the band that 20 iterations read in (``SUM_ERR_BAND``).
 """
@@ -86,7 +89,7 @@ WRONG = (("no token-dependent maps", dict(dynamic_maps=True), {}),
 #: published iterations and this script's operator 0.011 to 0.017, at 5
 #: iterations 0.11 to 0.15 (PERF.md section 6, PR 40)
 SUM_ERR_BAND = (0.005, 0.06)
-HRES_TOL, STREAMS_TOL, GRAD_TOL = 1e-4, 1e-4, 1e-3
+HRES_TOL, STREAMS_TOL, GRAD_TOL = 1e-5, 1e-5, 1e-5
 
 
 def forward_checks(conf, ref, seq, seeds):
@@ -185,11 +188,40 @@ def operator_checks(conf, ref, ff, batch, tokens=512):
         counts = {}
         ctx = types.SimpleNamespace(
             kv_mode=None, count=lambda k, v: counts.__setitem__(k, v))
-        u, maps = op.emit(params, [x], w, ctx, "pre")
-        out, = op.emit({"stage": "post"}, [x, jnp.tanh(u), maps], {}, ctx,
+        u, maps, xs = op.emit(params, [x], w, ctx, "pre")
+        out, = op.emit({"stage": "post"}, [xs, jnp.tanh(u), maps], {}, ctx,
                        "post")
         hres = maps[..., n:].reshape(maps.shape[:2] + (n, n))
         return jnp.sum(out * cot), (out, hres, counts["mhc.sum_err"])
+
+    # which path the shapes take, and that the traced calls say so: at
+    # the published width the four kernels, forward and backward
+    from flexflow_tpu.kernels.hyper_connection import KERNELS, takes_kernel
+    from flexflow_tpu.obs import events
+    impl = "kernel" if takes_kernel(n, c, tokens) else "plain"
+    events.enable()
+    events.clear()
+    try:
+        jax.eval_shape(                 # traced, not run
+            jax.grad(lambda x, w: program(
+                x, w, by_name["attn_res_0_pre"].params)[0]), drawn,
+            {k: v.astype(jnp.float32)
+             for k, v in ff.params["attn_res_0_pre"].items()})
+        seen = events.events()
+    finally:
+        events.disable()
+        events.clear()
+    said = {e["attrs"]["impl"] for e in seen if e["name"] == "mhc.maps"}
+    tiles = {e["attrs"]["kernel"]: e["attrs"]["tile"] for e in seen
+             if e["name"] == "mhc.kernel"}
+    calls = sorted(tiles)
+    READINGS["operator path"] = {"impl": impl, "kernels": calls,
+                                 "tiles": tiles}
+    check(f"operator: the mixes at {n} x {c} run the {impl} path",
+          said == {impl} and calls == (sorted(KERNELS) if impl == "kernel"
+                                       else []),
+          f"mhc.maps says {sorted(said)}, mhc.kernel {calls} at tiles "
+          f"{tiles}")
 
     def reference(x, w):
         with jax.default_matmul_precision("highest"):

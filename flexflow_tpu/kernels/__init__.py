@@ -18,6 +18,9 @@ with:
   - ``gated_delta_rule``: the in-chunk terms of linear attention's chunked
     recurrence (fwd+bwd), taken by ``ops/recurrent_ops.py`` where the
     shapes allow; not a registry entry.
+  - ``hyper_connection``: the residual streams' mixes (fwd+bwd), a tile of
+    tokens' whole ``n x C`` entries in VMEM, taken by ``ops/hyper_ops.py``
+    where the channels are whole lanes; not a registry entry.
 
 ``registry`` makes the implementation choice a searched dimension: per-op
 variants with availability predicates and calibrated cost entry points

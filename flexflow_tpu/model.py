@@ -333,12 +333,14 @@ class FFModel:
                              eps: float = 1e-6, norm_eps: float = 1e-6,
                              clamp: Tuple[float, float] = (-30.0, 30.0),
                              name: Optional[str] = None
-                             ) -> Tuple[Tensor, Tensor]:
+                             ) -> Tuple[Tensor, Tensor, Tensor]:
         """The reading half of a hyper-connected sub-layer (``ops.
         hyper_ops.HyperConnectionOp``): from the residual ``streams``
         (batch, seq, n, hidden) the sub-layer's input ``Hpre X`` (batch,
-        seq, hidden) and the maps ``[Hpost ; Hres]`` of every token, for
-        :meth:`hyper_connection_post`. ``iters`` Sinkhorn-Knopp
+        seq, hidden), the maps ``[Hpost ; Hres]`` of every token and the
+        streams again, both for :meth:`hyper_connection_post` (which
+        takes them from here so that this node is the incoming streams'
+        one consumer). ``iters`` Sinkhorn-Knopp
         iterations with ``eps`` in each denominator over ``Hres~``
         clipped to ``clamp``; ``norm_eps`` under the root of the
         streams' mean square. The draw of its weights is the op's own
@@ -348,19 +350,18 @@ class FFModel:
                              f"(batch, seq, streams, hidden) is wanted")
         if iters < 1 or not clamp[0] < clamp[1]:
             raise ValueError(f"{iters} iterations, clamp {clamp}")
-        u, maps = self._add_layer(
+        return tuple(self._add_layer(
             OperatorType.OP_HYPER_CONNECTION, [streams],
             dict(stage="pre", iters=int(iters), eps=float(eps),
                  norm_eps=float(norm_eps),
-                 clamp=[float(clamp[0]), float(clamp[1])]), name).outputs
-        return u, maps
+                 clamp=[float(clamp[0]), float(clamp[1])]), name).outputs)
 
     def hyper_connection_post(self, streams: Tensor, output: Tensor,
                               maps: Tensor,
                               name: Optional[str] = None) -> Tensor:
         """The writing half: ``Hres X + Hpost^T output``, the new
-        streams, from the maps :meth:`hyper_connection_pre` gave for
-        these ``streams``."""
+        streams, from the maps and the ``streams``
+        :meth:`hyper_connection_pre` gave."""
         return self._add_layer(OperatorType.OP_HYPER_CONNECTION,
                                [streams, output, maps],
                                dict(stage="post"), name).outputs[0]

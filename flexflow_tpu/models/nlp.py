@@ -933,7 +933,7 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
         what it adds."""
         if streams == 1:
             return ff.add(h, sublayer(h), name=name)
-        u, maps = ff.hyper_connection_pre(
+        u, maps, h = ff.hyper_connection_pre(
             h, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.rms_norm_eps,
             (cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
             name=name + "_pre")

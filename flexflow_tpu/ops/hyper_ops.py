@@ -20,8 +20,9 @@ streams' mean. Everything here is float32, whatever the compute dtype.
 
 One operator kind, two nodes a sub-layer: ``stage: "pre"`` holds the
 weights (``phi`` as ONE ``n C x n (n + 2)`` matrix: the three products
-are one) and yields ``u`` and the finished ``[Hpost ; Hres]`` a token;
-``stage: "post"`` takes ``X``, ``F``'s output and those maps. Between
+are one) and yields ``u``, the finished ``[Hpost ; Hres]`` a token and
+``X`` again; ``stage: "post"`` takes that ``X``, ``F``'s output and
+those maps. Between
 sub-layers only ``X`` is live, so a rematerialised block is entered by
 the one stream tensor.
 
@@ -32,17 +33,40 @@ What decides the cost on the chip, and what is done about each:
     token-major (2 MB padded at 4096 tokens, against 235 MB of ``X``);
   * the iterations are ONE ``lax.scan`` in the step's text a pass
     (forward, recomputation, backward), not ``iters`` unrolled copies;
-  * ``Hres X + Hpost^T y`` is written as broadcasts and sums, one fused
-    pass over ``X``; ``Hpre X`` is one more read. The ``pre`` node is
-    rematerialised: the backward pass keeps ``X`` and not the norm's,
-    the product's or the iterations' intermediates.
+  * the passes over ``X``. Written as broadcasts and sums (the plain
+    functions here: every shape's path before PR 42, now that of the
+    shapes the kernels do not take and the tests' oracle), XLA does NOT
+    make ``Hres X + Hpost^T y`` one pass nor ``Hpre X`` one read: at
+    4 x 3584 a sub-layer's forward and backward moved the stream tensor
+    31.6 times (9.06 ms at 819 GB/s), the product with ``phi`` at
+    ``HIGHEST`` and its transpose as vector-unit fusions of 3.2-3.5
+    passes each, every output stream's sum a fusion of its own in the
+    backward, the tensor turned between two tiled layouts seven times a
+    pair of sub-layers (PERF.md section 6, PR 40). Where the channels
+    are whole lanes (:func:`flexflow_tpu.kernels.hyper_connection.
+    takes_kernel`) four Pallas kernels do them instead, on the streams
+    seen stream-major, ``(n, b s, C)`` (a view XLA makes free by laying
+    the four-axis array so): a tile of tokens' whole ``n C`` entries in
+    VMEM, ``pre`` one read of ``X`` forward (norm, product, ``Hpre X``)
+    and two reads and one write backward (the WHOLE ``dX``: ``post``
+    takes its streams from the ``pre`` node's third output, which is its
+    input, so what ``post`` left for ``X`` is an operand of that kernel
+    and not an add of two stream tensors after it), ``post`` one read
+    and one write forward, two reads and one write backward. The
+    residuals are ``X`` and 25 floats a token (the raw products and the
+    norm's reciprocal); the gates of ``Hpost`` and ``Hres`` and the
+    Sinkhorn scan stay in XLA, tokens-last, rematerialised (they are
+    1% of the step and their intermediates 2.5 KB a token).
 
 Name scopes inside the layer's own: ``mhc.maps`` (norm, product, affine,
 gates), ``mhc.sinkhorn`` (the iterations), ``mhc.mix`` (the passes over
-``X``). Counters: ``mhc.sublayers`` (one a ``pre`` node), ``mhc.sum_err``
-(that node's largest ``|rowsum - 1|`` or ``|colsum - 1|`` over its
-tokens; counters add, so divide by ``mhc.sublayers``), ``mhc.clamped``
-(entries of ``Hres~`` at or beyond the clamp).
+``X``: the kernels' calls, or the plain functions). Instants at trace
+time: ``mhc.maps`` a ``pre`` node (``impl`` = ``kernel`` | ``plain``),
+``mhc.kernel`` a kernel call. Counters: ``mhc.sublayers`` (one a ``pre``
+node), ``mhc.sum_err`` (that node's largest ``|rowsum - 1|`` or
+``|colsum - 1|`` over its tokens; counters add, so divide by
+``mhc.sublayers``), ``mhc.clamped`` (entries of ``Hres~`` at or beyond
+the clamp).
 """
 from __future__ import annotations
 
@@ -52,6 +76,7 @@ import numpy as np
 
 from ..core.tensor import WeightSpec
 from ..ffconst import DataType, InitializerType, OperatorType
+from ..kernels import hyper_connection as hck
 from ..obs import events
 from .registry import OpDef, register
 
@@ -72,36 +97,53 @@ def sinkhorn(logits, iters: int, eps: float):
     return jax.lax.scan(step, jnp.exp(logits), None, length=iters)[0]
 
 
-def stream_maps(x, w, params):
-    """The three maps of every token of ``x`` (b, s, n, C) float32,
-    tokens last: ``Hpre`` (n, b, s), ``Hpost`` (n, b, s), ``Hres``
-    (n, n, b, s), and how many entries of ``Hres~`` met the clamp."""
-    b, s, n, c = x.shape
+def stream_products(x, phi, norm_eps):
+    """``x phi`` under the norm, tokens last: ``x`` (b, s, n, C) float32,
+    ``phi`` (n C, K) -> (K, b, s). The plain path's: two reads of ``x``."""
+    n, c = x.shape[-2:]
+    phi = phi.astype(F32).reshape(n, c, -1)
+    # x phi = (X phi) / rms(X): the norm has no weight of its own
+    # (the product token-major and its 24 columns turned after: asked
+    # for tokens-last, XLA turns the streams themselves, 235 MB)
+    raw = jnp.einsum("bsnc,nck->bsk", x, phi,
+                     precision=jax.lax.Precision.HIGHEST)
+    return jnp.moveaxis(raw, -1, 0) * jax.lax.rsqrt(
+        jnp.mean(x * x, axis=(-2, -1)) + norm_eps)
+
+
+def _affine(scalar, rows, bias):
+    bias = bias.astype(F32)
+    return scalar * rows.reshape(bias.shape + rows.shape[1:]) \
+        + bias[..., None, None]
+
+
+def write_maps(t, w, params):
+    """``Hpost`` (n, b, s) and ``Hres`` (n, n, b, s) from the products
+    ``t`` (K, b, s) tokens-last, and how many entries of ``Hres~`` met
+    the clamp."""
+    n = w["b_post"].shape[0]
     lo, hi = params["clamp"]
+    a = w["alpha"].astype(F32)
     with jax.named_scope("mhc.maps"):
-        phi = w["phi"].astype(F32).reshape(n, c, -1)
-        # x phi = (X phi) / rms(X): the norm has no weight of its own
-        # (the product token-major and its 24 columns turned after: asked
-        # for tokens-last, XLA turns the streams themselves, 235 MB)
-        raw = jnp.einsum("bsnc,nck->bsk", x, phi,
-                         precision=jax.lax.Precision.HIGHEST)
-        t = jnp.moveaxis(raw, -1, 0) * jax.lax.rsqrt(
-            jnp.mean(x * x, axis=(-2, -1)) + params["norm_eps"])
-        a = w["alpha"].astype(F32)
-
-        def affine(scalar, rows, bias):
-            bias = bias.astype(F32)
-            return scalar * rows.reshape(bias.shape + (b, s)) \
-                + bias[..., None, None]
-
-        pre = jax.nn.sigmoid(affine(a[0], t[:n], w["b_pre"]))
-        post = 2.0 * jax.nn.sigmoid(affine(a[1], t[n:2 * n], w["b_post"]))
-        res = affine(a[2], t[2 * n:], w["b_res"])
+        post = 2.0 * jax.nn.sigmoid(_affine(a[1], t[n:2 * n], w["b_post"]))
+        res = _affine(a[2], t[2 * n:], w["b_res"])
         clamped = jnp.sum(((res <= lo) | (res >= hi)).astype(F32))
     with jax.named_scope("mhc.sinkhorn"):
         res = sinkhorn(jnp.clip(res, lo, hi), params["iters"],
                        params["eps"])
-    return pre, post, res, clamped
+    return post, res, clamped
+
+
+def stream_maps(x, w, params):
+    """The three maps of every token of ``x`` (b, s, n, C) float32,
+    tokens last: ``Hpre`` (n, b, s), ``Hpost`` (n, b, s), ``Hres``
+    (n, n, b, s), and how many entries of ``Hres~`` met the clamp."""
+    n = x.shape[2]
+    with jax.named_scope("mhc.maps"):
+        t = stream_products(x, w["phi"], params["norm_eps"])
+        pre = jax.nn.sigmoid(_affine(w["alpha"].astype(F32)[0], t[:n],
+                                     w["b_pre"]))
+    return (pre,) + write_maps(t, w, params)
 
 
 def read_streams(x, hpre):
@@ -119,17 +161,44 @@ def write_streams(x, y, hpost, hres):
         + jnp.sum(hres[..., None] * x[..., None, :, :], axis=-2)
 
 
+def _kernel_shard_spec(ctx, batch: int, seq: int):
+    """``(mesh, spec)`` for the kernels inside the executor's
+    multi-device jit: the batch and sequence entries of the node's
+    adopted output sharding (the only axes it may be sharded by), where
+    they divide the axis. ``(None, None)``: call them directly, as
+    ``MultiHeadAttentionOp._kernel_shard_spec`` says (its rule, over
+    other axes; ``nn_ops.py`` is left as it is so that the other
+    configurations' steps keep their compile-cache keys)."""
+    mesh = getattr(ctx, "mesh", None)
+    if mesh is None or mesh.size == 1 or getattr(ctx, "local_shape", False):
+        return None, None
+    from jax.sharding import PartitionSpec as P
+    sh = getattr(ctx, "op_sharding", None)
+    out = tuple(sh.outputs[0]) if sh is not None and sh.outputs else ()
+
+    def entry(i, dim):
+        e = out[i] if len(out) > i else None
+        axes = e if isinstance(e, tuple) else (e,)
+        deg = int(np.prod([mesh.shape[a] for a in axes if a is not None]))
+        return e if dim % deg == 0 else None
+
+    return mesh, P(entry(0, batch), entry(1, seq))
+
+
 @register
 class HyperConnectionOp(OpDef):
     """One of the two nodes of a hyper-connected sub-layer (the module's
     docstring). ``stage: "pre"``: input ``X`` (b, s, n, C); outputs
-    ``u`` (b, s, C) and the maps (b, s, n + n n), ``[Hpost ; Hres]``
-    row-major; weights ``phi``, ``b_pre``, ``b_post``, ``b_res`` and
-    ``alpha`` = ``(a_pre, a_post, a_res)``. ``stage: "post"``: inputs
-    ``X``, ``F``'s output (b, s, C) and the maps; output the new ``X``;
-    no weights. Per token: batch and sequence may be sharded, the stream
-    axis never, the channels only with the norm's and ``phi``'s partial
-    sums reduced, which is not built. Training and evaluation only."""
+    ``u`` (b, s, C), the maps (b, s, n + n n), ``[Hpost ; Hres]``
+    row-major, and ``X`` itself, for the ``post`` node to take (so that
+    the one consumer of a sub-layer's incoming streams is this node,
+    and their whole cotangent is written once, by its backward);
+    weights ``phi``, ``b_pre``, ``b_post``, ``b_res`` and ``alpha`` =
+    ``(a_pre, a_post, a_res)``. ``stage: "post"``: inputs ``X``, ``F``'s
+    output (b, s, C) and the maps; output the new ``X``; no weights. Per
+    token: batch and sequence may be sharded, the stream axis never, the
+    channels only with the norm's and ``phi``'s partial sums reduced,
+    which is not built. Training and evaluation only."""
     op_type = OperatorType.OP_HYPER_CONNECTION
 
     def infer(self, params, in_shapes, in_dtypes):
@@ -143,7 +212,8 @@ class HyperConnectionOp(OpDef):
                     f"{in_shapes[1]} and {in_shapes[2]}")
             return [(tuple(in_shapes[0]), in_dtypes[0])]
         return [((b, s, c), in_dtypes[0]),
-                ((b, s, n + n * n), DataType.DT_FLOAT)]
+                ((b, s, n + n * n), DataType.DT_FLOAT),
+                (tuple(in_shapes[0]), in_dtypes[0])]
 
     def weights(self, params, in_shapes, in_dtypes):
         if params["stage"] == "post":
@@ -168,36 +238,65 @@ class HyperConnectionOp(OpDef):
                 f"{name}: the residual streams have no decode path")
         x = inputs[0]
         b, s, n, c = x.shape
+        kernel = hck.takes_kernel(n, c, b * s)
+        # a compiled kernel inside a multi-device jit runs on each
+        # device's tokens, as the attention kernels on their heads
+        mesh, spec = _kernel_shard_spec(ctx, b, s) if kernel else (None, None)
         if params["stage"] == "post":
             _, y, maps = inputs
-            maps = maps.astype(F32)
+            xf, y, maps = x.astype(F32), y.astype(F32), maps.astype(F32)
             with jax.named_scope("mhc.mix"):
-                out = write_streams(
-                    x.astype(F32), y.astype(F32), maps[..., :n],
-                    maps[..., n:].reshape(b, s, n, n))
+                if kernel:
+                    out = hck.write_streams(xf, y, maps, layer=name,
+                                            mesh=mesh, spec=spec)
+                else:
+                    out = write_streams(xf, y, maps[..., :n],
+                                        maps[..., n:].reshape(b, s, n, n))
             return [out.astype(x.dtype)]
         if events.enabled():
             events.instant("mhc.maps", layer=name, streams=n, channels=c,
                            iters=params["iters"], tokens=b * s,
-                           stream_bytes=4 * b * s * n * c)
+                           stream_bytes=4 * b * s * n * c,
+                           impl="kernel" if kernel else "plain")
 
-        # rematerialised whole: the backward pass keeps X, and computes
-        # the norm, the product and the iterations again
-        @jax.checkpoint
-        def pre(x, w):
-            hpre, hpost, hres, clamped = stream_maps(x, w, params)
-            with jax.named_scope("mhc.mix"):
-                u = read_streams(x, jnp.moveaxis(hpre, 0, -1))
+        def finish(hpost, hres, clamped):
             err = jnp.maximum(jnp.max(jnp.abs(jnp.sum(hres, 0) - 1.0)),
                               jnp.max(jnp.abs(jnp.sum(hres, 1) - 1.0)))
             maps = jnp.concatenate([hpost, hres.reshape(n * n, b, s)], 0)
-            return u, jnp.moveaxis(maps, 0, -1), err, clamped
+            return jnp.moveaxis(maps, 0, -1), err, clamped
 
-        u, maps, err, clamped = pre(x.astype(F32), weights)
+        # rematerialised: the backward pass keeps X (and, of the
+        # kernels, 25 floats a token), and computes the gates and the
+        # iterations (the plain path: the norm and the product too) again
+        xf = x.astype(F32)
+        if kernel:
+            with jax.named_scope("mhc.mix"):
+                u, stats, xf = hck.read_streams(
+                    xf, *hck.pre_operands(
+                        weights["phi"], weights["alpha"][0],
+                        weights["b_pre"]), params["norm_eps"], layer=name,
+                    mesh=mesh, spec=spec)
+
+            @jax.checkpoint
+            def maps_of(stats, w):
+                k = n * (n + 2)
+                t = jnp.moveaxis(stats[..., :k] * stats[..., k:k + 1], -1, 0)
+                return finish(*write_maps(t, w, params))
+
+            maps, err, clamped = maps_of(stats, weights)
+        else:
+            @jax.checkpoint
+            def plain(x, w):
+                hpre, *rest = stream_maps(x, w, params)
+                with jax.named_scope("mhc.mix"):
+                    u = read_streams(x, jnp.moveaxis(hpre, 0, -1))
+                return (u,) + finish(*rest)
+
+            u, maps, err, clamped = plain(xf, weights)
         ctx.count("mhc.sublayers", jnp.float32(1.0))
         ctx.count("mhc.sum_err", err)
         ctx.count("mhc.clamped", clamped)
-        return [u.astype(x.dtype), maps]
+        return [u.astype(x.dtype), maps, xf.astype(x.dtype)]
 
     def flops(self, params, in_shapes, out_shapes):
         b, s, n, c = in_shapes[0]
@@ -207,12 +306,17 @@ class HyperConnectionOp(OpDef):
                                + params["iters"] * 4.0 * n * n)
 
     def bytes_moved(self, params, in_shapes, out_shapes):
-        """A memory-bound op that reads ``X`` more than once: ``pre`` for
-        the norm, for the product and for ``Hpre X``."""
-        reads = 1 if params["stage"] == "post" else 3
+        """A memory-bound op, by what the path taken reads: ``post`` the
+        streams once; ``pre`` once where the kernel runs, three times
+        otherwise (the norm, the product, ``Hpre X``). ``pre``'s third
+        output is its input and moves nothing."""
+        b, s, n, c = in_shapes[0]
+        post = params["stage"] == "post"
+        reads = 1 if post or hck.takes_kernel(n, c, b * s) else 3
+        outs = out_shapes if post else out_shapes[:2]
         return 4.0 * (reads * float(np.prod(in_shapes[0]))
                       + sum(float(np.prod(sh)) for sh in in_shapes[1:])
-                      + sum(float(np.prod(sh)) for sh in out_shapes))
+                      + sum(float(np.prod(sh)) for sh in outs))
 
     def backward_flops_factor(self):
         return 2.0

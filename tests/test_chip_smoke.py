@@ -127,20 +127,31 @@ def test_leg_e_linear_latent_moe_tiny_on_the_cpu_mesh(capsys):
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_LINEAR))
 
 
-def test_leg_f_mhc_latent_moe_tiny_on_the_cpu_mesh(capsys):
+@pytest.mark.parametrize("hidden,impl", [(64, "plain"), (128, "kernel")])
+def test_leg_f_mhc_latent_moe_tiny_on_the_cpu_mesh(capsys, hidden, impl):
     """A share of 4 of 16 experts on the 8-device mesh: every sub-layer
-    announces its maps, the two whole layers that repeat are entered by
-    the stream tensor and rematerialised, every sub-layer is counted in
-    every step."""
+    announces its maps and the path its mixes took (the plain functions
+    at 64 channels; at 128 the four kernels, interpreted here and under
+    ``shard_map`` over the mesh's batch axis), the two whole layers that
+    repeat are entered by the stream tensor and rematerialised, every
+    sub-layer is counted in every step."""
     cfg = dataclasses.replace(XingRankConfig.tiny(), n_routed_experts=4,
-                              n_routed_experts_published=16)
+                              n_routed_experts_published=16,
+                              hidden_size=hidden)
     chip_smoke.leg_mhc_latent_moe(cfg, seq=16, per_chip_batch=1,
                                   label="F/small", alpha=1e-3)
     out = capsys.readouterr().out
     assert "rematerialised run (16, 8, 2)" in out
-    assert ("mhc.maps in 8 sub-layers: 4 streams of 64, 20 iterations, "
-            "128 tokens") in out
-    assert "a rematerialised block is entered by (8, 16, 4, 64)" in out
+    assert (f"mhc.maps in 8 sub-layers: 4 streams of {hidden}, 20 "
+            f"iterations, 128 tokens") in out
+    assert f"the streams' mixes by ['{impl}'] (the shapes say {impl})" in out
+    for kernel in ("pre_fwd", "post_fwd", "post_bwd", "pre_bwd"):
+        assert (f"mhc.kernel {kernel}: 1 grid steps of 16 tokens" in out) \
+            == (impl == "kernel")
+    # (a kernel is a Mosaic call in a step compiled for the chip only)
+    assert "0 of the step's Mosaic calls are the hyper-connection" in out
+    assert f"a rematerialised block is entered by (8, 16, 4, {hidden})" \
+        in out
     assert f"counters mhc.sublayers {8.0 * (1 + chip_smoke.TRAIN_STEPS)}, " \
            f"mhc.clamped 0.0, mhc.sum_err " in out
     assert "resolved attention impls ['xla'] in 4 layers" in out  # cpu

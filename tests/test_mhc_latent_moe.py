@@ -272,8 +272,9 @@ def test_the_nodes_are_the_references_sub_layer():
         size=(B, S, n, c)).astype(np.float32))
     op = HyperConnectionOp()
     ctx = f32_ctx()
-    u, maps = op.emit(HC_PARAMS, [x], w, ctx, "res_pre")
-    out, = op.emit({"stage": "post"}, [x, jnp.tanh(u), maps], {}, ctx,
+    u, maps, xs = op.emit(HC_PARAMS, [x], w, ctx, "res_pre")
+    assert xs is x      # the third output is the input: nothing is copied
+    out, = op.emit({"stage": "post"}, [xs, jnp.tanh(u), maps], {}, ctx,
                    "res")
     sizes = {"rms_norm_eps": 1e-6, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
              "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30}
@@ -297,7 +298,7 @@ def test_the_clamp_is_reached_on_a_forced_input():
         size=(B, S, n, c)).astype(np.float32))
     op = HyperConnectionOp()
     ctx = f32_ctx()
-    _, maps = op.emit(HC_PARAMS, [x], w, ctx, "res_pre")
+    _, maps, _ = op.emit(HC_PARAMS, [x], w, ctx, "res_pre")
     assert float(ctx.counters["mhc.clamped"]) == B * S * n * n
     res = maps[..., n:].reshape(B, S, n, n)
     assert bool(jnp.all(jnp.isfinite(res)))
@@ -527,7 +528,7 @@ def test_sharded_nodes_are_the_unsharded_ones(by):
     op = HyperConnectionOp()
 
     def sublayer(x, w):
-        u, maps = op.emit(HC_PARAMS, [x], w, f32_ctx(), "res_pre")
+        u, maps, x = op.emit(HC_PARAMS, [x], w, f32_ctx(), "res_pre")
         return op.emit({"stage": "post"}, [x, jnp.tanh(u), maps], {},
                        f32_ctx(), "res")[0]
 
@@ -541,8 +542,11 @@ def test_sharded_nodes_are_the_unsharded_ones(by):
 
 
 def test_the_cost_row_is_by_bytes(tiny):
-    """``pre`` reads the streams three times (norm, product, ``Hpre
-    X``), ``post`` once; both are bound by memory in the cost model."""
+    """At this width (64 channels: no whole lane) the plain path runs:
+    ``pre`` reads the streams three times (norm, product, ``Hpre X``),
+    ``post`` once; both are bound by memory in the cost model. ``pre``'s
+    third output is its input and moves nothing; where the kernels run
+    it reads them once (``tests/test_mhc_kernel.py``)."""
     ff, mc, _ = tiny
     from flexflow_tpu.parallel.machine import MachineSpec
     from flexflow_tpu.search.costmodel import OpCostModel
@@ -587,6 +591,16 @@ def test_the_remat_finder_on_the_new_graph():
         assert shapes[guid] == (B, S, mc.hc_mult, mc.hidden_size)
     assert exits[-1] == next(l for l in ff.layers
                              if l.name == "mlp_res_4").outputs[0].guid
+    # the streams that enter a sub-layer have ONE consumer, its ``pre``
+    # node: ``post`` takes them from that node's third output
+    by_name = {l.name: l for l in ff.layers}
+    for name in ("attn_res_1", "mlp_res_3", "mlp_res_0"):
+        pre, post = by_name[name + "_pre"], by_name[name]
+        assert post.inputs[0].guid == pre.outputs[2].guid
+        assert post.inputs[2].guid == pre.outputs[1].guid
+        readers = [l.name for l in ff.layers
+                   if any(t.guid == pre.inputs[0].guid for t in l.inputs)]
+        assert readers == [pre.name]
 
 
 def test_rematerialised_blocks_give_the_same_step_and_counters():

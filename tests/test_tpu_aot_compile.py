@@ -467,3 +467,100 @@ def test_the_linear_attention_kernels_keep_their_scope(
         'pallas_call"' in by_name["gated_delta_rule_fwd"]
     assert 'transpose(jvp(ff.forward))/kda_2/kda.scan/' \
         'gated_delta_rule_bwd/pallas_call"' in by_name["gated_delta_rule_bwd"]
+
+
+# the residual streams' mixes (kernels/hyper_connection.py)
+# ----------------------------------------------------------------------
+MHC_NAMES = ["hyper_connection_post_bwd", "hyper_connection_post_fwd",
+             "hyper_connection_pre_bwd", "hyper_connection_pre_fwd"]
+
+
+@pytest.fixture
+def compiled_mhc(monkeypatch):
+    monkeypatch.setattr(
+        "flexflow_tpu.kernels.hyper_connection.pallas_interpret",
+        lambda: False)
+
+
+def _mhc_loss(mesh, spec, x, phi_t, gate, y, maps):
+    """Both nodes' calls as a sub-layer has them: ``post`` takes the
+    streams the ``pre`` call hands on."""
+    from flexflow_tpu.kernels import hyper_connection as hck
+    with jax.named_scope("ff.forward"):
+        with jax.named_scope("attn_res_2_pre"), jax.named_scope("mhc.mix"):
+            u, stats, xs = hck.read_streams(x, phi_t, gate, 1e-6,
+                                            mesh=mesh, spec=spec)
+        with jax.named_scope("attn_res_2"), jax.named_scope("mhc.mix"):
+            out = hck.write_streams(xs, y + u, maps, mesh=mesh, spec=spec)
+    return jnp.sum(out ** 2) + jnp.sum(stats ** 2)
+
+
+def _mhc_operands(mesh, spec, b, s, n, c):
+    from flexflow_tpu.kernels.hyper_connection import stats_width
+
+    def arr(*shape, sharded=True):
+        axes = (tuple(spec) + (None,) * len(shape))[:len(shape)]
+        return jax.ShapeDtypeStruct(
+            shape, jnp.float32, sharding=NamedSharding(
+                mesh, P(*axes) if sharded else P()))
+    kp = stats_width(n)
+    return [arr(b, s, n, c), arr(kp, n * c, sharded=False),
+            arr(2, kp, sharded=False), arr(b, s, c), arr(b, s, n + n * n)]
+
+
+# (batch, positions, streams, channels): cell 6 of the benchmark, a
+# padded tail (1,000 tokens in tiles of 256 and 128), two streams of one
+# lane tile, and channels so wide that the backward tiles step down to 32
+MHC_SHAPES = [(1, 4096, 4, 3584), (2, 500, 4, 3584), (1, 64, 2, 128),
+              (1, 512, 4, 8192)]
+
+
+@pytest.mark.parametrize("b,s,n,c", MHC_SHAPES)
+def test_the_hyper_connection_kernels_compile(v5e_devices, compiled_mhc,
+                                              b, s, n, c):
+    """Forward and backward for a described v5e, at the tiles the shapes
+    give: Mosaic takes the working set ``vmem_bytes`` counts inside the
+    budget, the three products at ``HIGHEST`` and ``dphi``'s block
+    summed over a sequential grid."""
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    txt = _compile_text(
+        jax.grad(functools.partial(_mhc_loss, None, None),
+                 argnums=range(5)), *_mhc_operands(mesh, (), b, s, n, c))
+    assert _kernel_names(txt) == MHC_NAMES
+
+
+@pytest.mark.parametrize("spec", [("x0", None), (None, "x0")],
+                         ids=["batch", "sequence"])
+def test_the_hyper_connection_kernels_compile_under_a_mesh(
+        v5e_devices, compiled_mhc, spec):
+    """Four chips by batch or by sequence: each runs the kernels on its
+    own tokens under ``shard_map``, and ``dphi`` is all-reduced."""
+    mesh = Mesh(np.array(v5e_devices), ("x0",))
+    txt = _compile_text(
+        jax.grad(functools.partial(_mhc_loss, mesh, P(*spec)),
+                 argnums=range(5)),
+        *_mhc_operands(mesh, spec, 4, 512, 4, 512))
+    assert _kernel_names(txt) == MHC_NAMES
+    assert "all-reduce" in txt
+
+
+def test_the_hyper_connection_kernels_keep_their_scope(
+        v5e_devices, compiled_mhc, chip_locations):
+    """All four calls carry their node's name and ``mhc.mix`` in their
+    ``op_name``, the backward's inside the ``transpose(``: the
+    benchmark's ``xing_mhc_time_share.train`` finds them by the node's
+    name."""
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    txt = _compile_text(
+        jax.grad(functools.partial(_mhc_loss, None, None),
+                 argnums=range(5)),
+        *_mhc_operands(mesh, (), 1, 512, 4, 512))
+    by_name = {n: l for n in MHC_NAMES for l in txt.splitlines()
+               if MOSAIC_CALL in l and f"{n}." in l.split(" = ")[0]}
+    for name, node in (("pre", "attn_res_2_pre"), ("post", "attn_res_2")):
+        assert f'jvp(ff.forward)/{node}/mhc.mix/hyper_connection_' \
+            f'{name}_fwd/pallas_call"' in by_name[
+                f"hyper_connection_{name}_fwd"]
+        assert f'transpose(jvp(ff.forward))/{node}/mhc.mix/' \
+            f'hyper_connection_{name}_bwd/pallas_call"' in by_name[
+                f"hyper_connection_{name}_bwd"]

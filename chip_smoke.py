@@ -560,7 +560,8 @@ def leg_latent_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
             f"{r['experts_published']} experts held from {r['first_held']}"
             f", top {r['top_k']}, {r['tokens']} tokens, "
             f"{r['rows_budget']} rows of the "
-            f"{r['tokens'] * r['top_k']} sorted handed to the products")
+            f"{r['tokens'] * r['top_k']} sorted handed to the products, "
+            f"back to the tokens by the {r['token_sum']} path")
         check(r["experts_published"] == (
             model_cfg.n_routed_experts_published
             or model_cfg.n_routed_experts)

@@ -38,7 +38,17 @@ reference ``benchmarks/reference/latent_moe_ref.py`` (float32,
      correction bias, in program and reference alike: every choice of
      every token is theirs, eight times the budget's rows): every layer
      runs the further chunks, drops nothing, and the gradients are still
-     the reference's.
+     the reference's;
+  6. one routed-experts layer ALONE at the cell's width (4096 tokens of
+     hidden 2048, top 8 of 256, 16 held, the shared expert beside
+     them), as routed and with the overflow forced: the way back to the
+     tokens through ``kernels/moe_token_sum.py`` (the check prints the
+     ``moe.route`` instant's ``token_sum`` and the ``moe.kernel``
+     calls, and fails if the shapes did not take the kernel), its
+     output and the five gradients (the input, the router, the three
+     stacked weights) against ``jax.grad`` of the reference's layer,
+     each held to twice what the reference itself reads with bf16
+     operands, and against the same layer down the plain path.
 """
 import argparse
 import dataclasses
@@ -391,12 +401,121 @@ def gradient_checks(conf, ref, seed, seq=1024):
                       pick, "loss (with the MTP term)")
 
 
+def layer_checks(conf, ref, seed, tokens=4096):
+    """Check 6: the experts' layer alone, kernel path against plain
+    path and against the reference."""
+    from flexflow_tpu.kernels import moe_token_sum as mts
+    from flexflow_tpu.obs import events
+    from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    e, f = conf["hidden_size"], conf["moe_intermediate_size"]
+    n, held = conf["n_routed_experts_published"], conf["n_routed_experts"]
+    first = conf.get("first_held_expert", 0)
+    params = dict(num_experts=n, top_k=conf["num_experts_per_tok"],
+                  expert_dim=f, shared_dim=f, experts_held=held,
+                  first_held=first, scale=conf["routed_scaling_factor"])
+    sizes = {"num_experts_per_tok": params["top_k"],
+             "routed_scaling_factor": params["scale"],
+             "first_held_expert": first}
+    ks = iter(jax.random.split(jax.random.key(seed), 10))
+
+    def draw(*shape, scale):
+        return scale * jax.random.normal(next(ks), shape, jnp.float32)
+    w = {"wg": draw(e, n, scale=e ** -0.5),
+         "bias": draw(n, scale=conf["router_bias_std"]),
+         "w_gate": draw(held, e, f, scale=e ** -0.5),
+         "w_up": draw(held, e, f, scale=e ** -0.5),
+         "w_down": draw(held, f, e, scale=f ** -0.5),
+         "ws_gate": draw(e, f, scale=e ** -0.5),
+         "ws_up": draw(e, f, scale=e ** -0.5),
+         "ws_down": draw(f, e, scale=f ** -0.5)}
+    x, ct = draw(1, tokens, e, scale=1.0), draw(1, tokens, e, scale=1.0)
+    wanted = ("wg", "w_gate", "w_up", "w_down")
+
+    def program():
+        """Made anew for each path and each routing: ``jax.jit`` keeps
+        its traces by function, and the path is chosen at trace time."""
+        def loss(x, w):
+            ctx = EmitCtx(training=True, config=FFConfig())
+            (y,) = RoutedExpertsOp().emit(params, [x], w, ctx, "experts")
+            return jnp.sum(y * ct), (y, ctx.counters)
+
+        def run(x, w):
+            (_, (y, counters)), (dx, dw) = jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True)(x, w)
+            return dict({k: dw[k] for k in wanted}, y=y, x=dx), counters
+        return jax.jit(run)
+
+    def reference(x, w):
+        def loss(x, w):
+            with jax.default_matmul_precision("highest"):
+                y = ref.routed(x, w, sizes) + ref.shared(x, w)
+            return jnp.sum(y * ct), y
+        (_, y), (dx, dw) = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(x, w)
+        return dict({k: dw[k] for k in wanted}, y=y, x=dx)
+
+    @jax.jit
+    def rounded(x, w):
+        with ref.rounded_operands(matmul=jnp.bfloat16):
+            return reference(x, w)
+
+    budget = RoutedExpertsOp.rows_multiplied(tokens, params)
+    takes = mts.takes_kernel
+    for forced in (False, True):
+        tag = "layer alone, overflow forced: " if forced \
+            else "layer alone: "
+        wf = dict(w, bias=w["bias"].at[first:first + held].add(2.0)) \
+            if forced else w
+        events.enable()
+        events.clear()
+        try:
+            got, counters = program()(x, wf)
+            noted = [ev["attrs"] for ev in events.events()
+                     if ev["name"] in ("moe.route", "moe.kernel")]
+        finally:
+            events.disable()
+            events.clear()
+        mts.takes_kernel = lambda *a: False
+        try:
+            plain, _ = program()(x, wf)
+        finally:
+            mts.takes_kernel = takes
+        print(f"{tag}" + json.dumps(noted), flush=True)
+        c = {k: float(v) for k, v in counters.items()}
+        check(f"{tag}the shapes took the kernel",
+              noted and noted[0].get("token_sum") == "kernel"
+              and [a.get("use") for a in noted[1:]] == ["combine",
+                                                        "rows_for_bwd"],
+              f"token_sum {noted[0].get('token_sum') if noted else None}")
+        check(f"{tag}nothing dropped, the loop ran {'' if forced else 'not'}",
+              c["moe.dropped"] == 0 and c["moe.overflow"] == forced
+              and (c["moe.local_assignments"] == tokens * params["top_k"]
+                   if forced else c["moe.local_assignments"] <= budget),
+              f"rows_budget {budget}, " + ", ".join(
+                  f"{k} {v:.0f}" for k, v in sorted(c.items())))
+        want, low = jax.jit(reference)(x, wf), rounded(x, wf)
+        for name in ("y", "x") + wanted:
+            err, eb = l2(got[name], want[name]), l2(low[name], want[name])
+            own = l2(got[name], plain[name])
+            READINGS[f"{tag}{name}"] = {
+                "kernel path": err, "plain path": l2(plain[name],
+                                                     want[name]),
+                "reference, bf16 operands": eb,
+                "kernel path against plain path": own}
+            what = "output" if name == "y" else f"gradient {name}"
+            check(f"{tag}{what}", err <= 2 * eb + 1e-3 and own <= 1e-3,
+                  f"rel {err:.3e}; the reference with bf16 operands reads "
+                  f"{eb:.3e}; against the plain path {own:.3e}")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seeds", type=int, nargs="+", default=[2900101])
     ap.add_argument("--seq", type=int, default=4096)
     ap.add_argument("--skip-kernels", action="store_true")
     ap.add_argument("--skip-gradients", action="store_true")
+    ap.add_argument("--skip-layer", action="store_true")
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         print("this validation needs a TPU", file=sys.stderr)
@@ -411,6 +530,8 @@ def main():
     forward_checks(conf, ref, args.seq, args.seeds)
     if not args.skip_gradients:
         gradient_checks(conf, ref, args.seeds[0])
+    if not args.skip_layer:
+        layer_checks(conf, ref, args.seeds[0])
     print("READINGS " + json.dumps(READINGS), flush=True)
     print(f"{len(FAILED)} failed: {FAILED}" if FAILED else "all passed")
     return 1 if FAILED else 0

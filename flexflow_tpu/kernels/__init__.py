@@ -21,6 +21,12 @@ with:
   - ``hyper_connection``: the residual streams' mixes (fwd+bwd), a tile of
     tokens' whole ``n x C`` entries in VMEM, taken by ``ops/hyper_ops.py``
     where the channels are whole lanes; not a registry entry.
+  - ``moe_token_sum``: the routed experts' way back to tokens, forward
+    and in the row gather's transpose: a tile of tokens' float32 sums in
+    VMEM, the live rows of the sorted domain copied in aligned blocks by
+    the kernel's own DMAs and added by index, taken by
+    ``ops/moe_ops.py`` where the shapes allow (whole lanes, fewer rows
+    than ``top_k`` arrays of tokens, one device); not a registry entry.
 
 ``registry`` makes the implementation choice a searched dimension: per-op
 variants with availability predicates and calibrated cost entry points

@@ -27,6 +27,7 @@ import numpy as np
 
 from ..core.tensor import WeightSpec
 from ..ffconst import DataType, InitializerType, OperatorType
+from ..kernels import moe_token_sum as mts
 from ..obs import events
 from .registry import EmitCtx, OpDef, compute_dtype, matmul, register
 
@@ -172,53 +173,73 @@ def _from_rows(src, at):
 
 def _of_each_choice(src, at, k: int):
     """``_from_rows`` for each of a token's ``k`` assignments in turn,
-    ``k`` arrays of a row a token. One gather of ``tokens x k`` rows
-    reshaped to ``(tokens, k, width)`` is a relayout on the TPU wherever
-    ``k`` does not fill the tile's 8 sublanes: 8 of cell 4's 55 ms
-    (PERF.md section 6, PR 34)."""
+    ``k`` arrays of a row a token: the plain way back to the tokens,
+    which WRITES ``k`` arrays of ``tokens`` rows, zeros wherever a
+    choice has no row here, for the sum that follows to read back
+    (where ``kernels/moe_token_sum.py`` takes the shapes it walks the
+    rows instead and writes the sum alone). One gather of ``tokens x
+    k`` rows reshaped to ``(tokens, k, width)`` is a relayout on the TPU
+    wherever ``k`` does not fill the tile's 8 sublanes: 8 of cell 4's
+    55 ms (PERF.md section 6, PR 34)."""
     at = at.reshape(-1, k)
     return [_from_rows(src, at[:, j]) for j in range(k)]
 
 
 @jax.custom_vjp
-def _rows_for(x, order, at):
+def _rows_for(x, order, at, inside):
     """Row ``order[r] // k`` of ``x`` for each sorted assignment in
     ``order`` (``k = len(at) // len(x)`` assignments a token). Its
     transpose gathers too: the ``k`` rows of a token sit ``at`` their
     places and are summed, so no scatter is emitted in either
-    direction."""
+    direction. With ``inside`` (the held groups' rows, each group's
+    count) that sum is ``moe_token_sum``'s walk over those rows (each
+    read once, the sum written once); with None,
+    ``_of_each_choice``'s ``k`` arrays and their sum."""
     return x[order // (at.shape[0] // x.shape[0])]
 
 
-def _rows_for_fwd(x, order, at):
-    return _rows_for(x, order, at), (at, x.shape[0])
+def _rows_for_fwd(x, order, at, inside):
+    return (_rows_for(x, order, at, inside),
+            (at, None if inside is None else (order, inside), x.shape[0]))
 
 
 def _rows_for_bwd(res, g):
-    at, t = res
-    summed = sum(rows.astype(jnp.float32)
-                 for rows in _of_each_choice(g, at, at.shape[0] // t))
-    return summed.astype(g.dtype), None, None
+    at, sort, t = res
+    k = at.shape[0] // t
+    if sort is not None:
+        summed = mts.token_sum(g, *sort, t, k)
+    else:
+        summed = sum(rows.astype(jnp.float32)
+                     for rows in _of_each_choice(g, at, k))
+    return summed.astype(g.dtype), None, None, None
 
 
 _rows_for.defvjp(_rows_for_fwd, _rows_for_bwd)
 
 
 @jax.custom_vjp
-def _combine(ys, gates, order, at):
+def _combine(ys, gates, order, at, inside):
     """``sum_k gates[t, k] * ys[row of assignment (t, k)]``: the sorted
-    rows ``ys`` back at their tokens, weighted. The transpose stays in
-    the sorted domain: a row's cotangent is its token's times its gate,
-    a gate's the product of its row with its token's cotangent, so the
-    backward gathers ``len(ys)`` rows, keeps ``ys`` and neither writes
-    nor keeps ``tokens x top_k`` of them."""
+    rows ``ys`` back at their tokens, weighted, in float32. With
+    ``inside`` by ``moe_token_sum`` (each of the held groups' rows of
+    ``ys``, ``inside`` of them each, read once, times its gate, onto
+    its token's row; ``tokens`` rows written); with None,
+    ``_of_each_choice`` writes ``top_k`` arrays of ``tokens`` rows and
+    the sum reads them back.
+    The transpose stays in the sorted domain either way: a row's
+    cotangent is its token's times its gate, a gate's the product of
+    its row with its token's cotangent, so the backward gathers
+    ``len(ys)`` rows, keeps ``ys`` and neither writes nor keeps
+    ``tokens x top_k`` of them."""
     k = gates.shape[1]
+    if inside is not None:
+        return mts.token_sum(ys, order, inside, gates.shape[0], k, gates)
     return sum(gates[:, j:j + 1] * rows
                for j, rows in enumerate(_of_each_choice(ys, at, k)))
 
 
-def _combine_fwd(ys, gates, order, at):
-    return _combine(ys, gates, order, at), (ys, gates, order, at)
+def _combine_fwd(ys, gates, order, at, inside):
+    return _combine(ys, gates, order, at, inside), (ys, gates, order, at)
 
 
 def _combine_bwd(res, g):
@@ -227,21 +248,25 @@ def _combine_bwd(res, g):
     d_ys = gates.reshape(-1)[order][:, None] * gs
     d_gates = _from_rows(jnp.sum(ys * gs, axis=-1), at)
     return (d_ys.astype(ys.dtype),
-            d_gates.reshape(gates.shape).astype(gates.dtype), None, None)
+            d_gates.reshape(gates.shape).astype(gates.dtype), None, None,
+            None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
-def _chunk(budget: int, mdt, c, floats, ints):
+def _chunk(plan, mdt, c, floats, ints):
     """The held experts' part of the output from rows ``c * budget``
     onwards of the sort, ``budget`` of them: the row gather, the three
     grouped products and the activation over those rows, then each
-    token's sum of what they hold for it. Jitted and inlined for the
+    token's sum of what they hold for it. ``plan`` is ``(budget,
+    kernel)``: with ``kernel`` that sum and the row gather's transpose
+    are ``kernels/moe_token_sum.py``'s. Jitted and inlined for the
     trace cache alone: a model's layers and a layer's loops trace and
     differentiate this body once a shape, not once a use (a step's trace
     is set-up time, twice in a benchmark run)."""
+    budget, kernel = plan
     xm, gates, w_gate, w_up, w_down = floats
     order, inverse, sizes = ints
     lo, ends = c * budget, jnp.cumsum(sizes)
@@ -265,23 +290,27 @@ def _chunk(budget: int, mdt, c, floats, ints):
 
     mine = jax.lax.dynamic_slice(order, (lo,), (budget,))
     at = inverse - lo
+    # the kernel walks the held groups' rows alone (the others are
+    # zeros on both sides, and nobody's)
+    held = inside if kernel else None
     # gathered at the products' operand width: half the bytes of the
     # op's largest buffer, in both directions
-    xs = _rows_for(xm, mine, at)                            # (budget, e)
+    xs = _rows_for(xm, mine, at, held)                      # (budget, e)
     act = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
-    return _combine(grouped(act, w_down), gates, mine, at)
+    return _combine(grouped(act, w_down), gates, mine, at, held)
 
 
-def _further_chunks(budget, sizes, body, start):
+def _further_chunks(plan, sizes, body, start):
     """``body(c, state)`` for every chunk of ``budget`` rows past the
     first that holds a live row: none in the common step, and the
     device decides by itself."""
+    budget = plan[0]
     return jax.lax.fori_loop(1, (jnp.sum(sizes) + budget - 1) // budget,
                              body, start)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _sorted_domain(budget: int, mdt, floats, ints):
+def _sorted_domain(plan, mdt, floats, ints):
     """``(y, chunks)``: the held experts' part of the output over the
     live rows of the sort, ``budget`` rows at a time, and how many such
     chunks ran. The first holds every live row unless the router sent
@@ -292,33 +321,33 @@ def _sorted_domain(budget: int, mdt, floats, ints):
     chunk keeps what autodiff keeps of it; the further ones keep
     nothing and are computed again in the backward."""
     return _further_outputs(
-        budget, mdt, floats, ints,
-        _chunk(budget, mdt, jnp.int32(0), floats, ints))
+        plan, mdt, floats, ints,
+        _chunk(plan, mdt, jnp.int32(0), floats, ints))
 
 
-def _sorted_domain_fwd(budget, mdt, floats, ints):
-    y, back = jax.vjp(lambda *f: _chunk(budget, mdt, jnp.int32(0), f, ints),
+def _sorted_domain_fwd(plan, mdt, floats, ints):
+    y, back = jax.vjp(lambda *f: _chunk(plan, mdt, jnp.int32(0), f, ints),
                       *floats)
-    return _further_outputs(budget, mdt, floats, ints, y), (back, floats,
-                                                            ints)
+    return _further_outputs(plan, mdt, floats, ints, y), (back, floats,
+                                                          ints)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
-def _further_outputs(budget, mdt, floats, ints, y):
+def _further_outputs(plan, mdt, floats, ints, y):
     return _further_chunks(
-        budget, ints[2],
-        lambda c, s: (s[0] + _chunk(budget, mdt, c, floats, ints), s[1] + 1),
+        plan, ints[2],
+        lambda c, s: (s[0] + _chunk(plan, mdt, c, floats, ints), s[1] + 1),
         (y, jnp.int32(1)))
 
 
-def _sorted_domain_bwd(budget, mdt, res, g):
+def _sorted_domain_bwd(plan, mdt, res, g):
     back, floats, ints = res
-    return _further_cotangents(budget, mdt, floats, ints, g[0],
+    return _further_cotangents(plan, mdt, floats, ints, g[0],
                                back(g[0])), None
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
-def _further_cotangents(budget, mdt, floats, ints, g, first):
+def _further_cotangents(plan, mdt, floats, ints, g, first):
     """The first chunk's cotangents ``first``, each the start of a loop
     that adds the further chunks' in place. Two loops, one for the
     activations' (input rows and gates, wanted by the layer before) and
@@ -332,10 +361,10 @@ def _further_cotangents(budget, mdt, floats, ints, g, first):
             def chunk(*mine):
                 full = list(floats)
                 full[part] = mine
-                return _chunk(budget, mdt, c, tuple(full), ints)
+                return _chunk(plan, mdt, c, tuple(full), ints)
             return jax.tree.map(jnp.add, sofar,
                                 jax.vjp(chunk, *floats[part])[1](g))
-        return _further_chunks(budget, ints[2], more, first[part])
+        return _further_chunks(plan, ints[2], more, first[part])
 
     return further(slice(0, 2)) + further(slice(2, 5))
 
@@ -439,11 +468,30 @@ class RoutedExpertsOp(OpDef):
         xt = x.reshape(-1, x.shape[-1])
         t = xt.shape[0]
         rows, budget = t * k, self.rows_multiplied(t, params)
+        # the way back to the tokens: the kernel where the shapes take
+        # it, on one device (under a mesh the sort is over the global
+        # batch, which no kernel call of a shard's own could walk)
+        mesh = getattr(ctx, "mesh", None)
+        kernel = ((mesh is None or mesh.size == 1) and all(
+            mts.takes_kernel(t, x.shape[-1], k, budget, held, dt)
+            for dt in (jnp.float32, mdt)))
         if events.enabled():
             events.instant("moe.route", layer=name, experts_published=n,
                            experts_held=held, first_held=first, top_k=k,
                            tokens=t, rows_budget=budget,
-                           rows_multiplied=budget)
+                           rows_multiplied=budget,
+                           token_sum="kernel" if kernel else "plain")
+            if kernel:
+                # ``_chunk`` is traced once a shape, so its calls are
+                # noted here, where the layer has a name: the forward's
+                # sum and the row gather's transpose
+                for use, dt in (("combine", jnp.float32),
+                                ("rows_for_bwd", mdt)):
+                    tile = mts.tile_tokens(t, x.shape[-1], dt)
+                    events.instant(
+                        "moe.kernel", use=use, layer=name, tile=tile,
+                        rows=budget, vmem_bytes=mts.vmem_bytes(
+                            tile, x.shape[-1], dt))
 
         # the router in float32, as published: a bf16 pass moves scores
         # by 1e-2 and with them the choice of experts
@@ -464,14 +512,14 @@ class RoutedExpertsOp(OpDef):
         floats = (xt.astype(mdt), gates) + tuple(
             weights[w].astype(mdt) for w in ("w_gate", "w_up", "w_down"))
         if budget == rows:
-            y = _chunk(rows, mdt, jnp.int32(0), floats,
+            y = _chunk((rows, kernel), mdt, jnp.int32(0), floats,
                        (order, inverse, sizes))
             chunks = None
         else:
             # whole chunks to slice: the padding sorts last, is never
             # live and reads token 0
             order = jnp.pad(order, (0, -rows % budget))
-            y, chunks = _sorted_domain(budget, mdt, floats,
+            y, chunks = _sorted_domain((budget, kernel), mdt, floats,
                                        (order, inverse, sizes))
         if "ws_gate" in weights:
             g = matmul(xt, weights["ws_gate"], ctx=ctx)

@@ -564,3 +564,72 @@ def test_the_hyper_connection_kernels_keep_their_scope(
         assert f'transpose(jvp(ff.forward))/{node}/mhc.mix/' \
             f'hyper_connection_{name}_bwd/pallas_call"' in by_name[
                 f"hyper_connection_{name}_bwd"]
+
+
+# the routed experts' way back to tokens (kernels/moe_token_sum.py)
+# ----------------------------------------------------------------------
+@pytest.fixture
+def compiled_token_sum(monkeypatch):
+    monkeypatch.setattr(
+        "flexflow_tpu.kernels.moe_token_sum.pallas_interpret",
+        lambda: False)
+
+
+def _experts_loss(params, x, w):
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    ctx = EmitCtx(training=True, config=FFConfig())
+    with jax.named_scope("ff.forward"), jax.named_scope("experts_1"):
+        (y,) = RoutedExpertsOp().emit(params, [x], w, ctx, "experts_1")
+    return jnp.sum(y ** 2)
+
+
+def _experts_operands(device, tokens, hidden, params):
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
+    one = jax.sharding.SingleDeviceSharding(device)
+    specs = RoutedExpertsOp().weights(params, [(1, tokens, hidden)],
+                                      [DataType.DT_FLOAT])
+    return (jax.ShapeDtypeStruct((1, tokens, hidden), jnp.float32,
+                                 sharding=one),
+            {s.name: jax.ShapeDtypeStruct(s.shape, jnp.float32,
+                                          sharding=one) for s in specs})
+
+
+# (tokens, hidden, published, held, top_k, expert width, rows_factor):
+# cells 3 and 4 of the benchmark (4,096 and 8,192 rows of 32,768, tiles
+# of 512 tokens), and a layer whose every row is live (no loop; the
+# shapes leave it to the plain path)
+EXPERT_SHAPES = [(4096, 2048, 256, 16, 8, 768, 2, 4),
+                 (8192, 2048, 64, 8, 4, 1536, 2, 4),
+                 (512, 256, 8, 8, 2, 128, 2, 0)]
+
+
+@pytest.mark.parametrize("tokens,hidden,n,held,k,f,factor,calls",
+                         EXPERT_SHAPES)
+def test_the_experts_layer_compiles_through_the_token_sum_kernel(
+        v5e_devices, compiled_token_sum, chip_locations, tokens, hidden, n,
+        held, k, f, factor, calls):
+    """Forward and backward of one routed-experts layer for a described
+    v5e: the first chunk calls the kernel once forward (``_combine``)
+    and once backward (the row gather's transpose), and so does the
+    further chunks' loop (of the backward's two loops the activations'
+    alone wants the transpose, and neither the sum it rematerialises);
+    every call keeps the layer's scope, which is how the benchmark's
+    ``moe_time_share.train`` finds it."""
+    from flexflow_tpu.kernels import moe_token_sum as mts
+    from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
+    params = dict(num_experts=n, top_k=k, expert_dim=f, shared_dim=0,
+                  experts_held=held, first_held=0, rows_factor=factor)
+    budget = RoutedExpertsOp.rows_multiplied(tokens, params)
+    assert mts.takes_kernel(tokens, hidden, k, budget, held,
+                            jnp.bfloat16) == bool(calls)
+    txt = _compile_text(
+        jax.grad(functools.partial(_experts_loss, params), argnums=(0, 1)),
+        *_experts_operands(v5e_devices[0], tokens, hidden, params))
+    mine = [l for l in txt.splitlines()
+            if MOSAIC_CALL in l and "moe_token_sum" in l.split(" = ")[0]]
+    assert len(mine) == calls
+    assert all('/experts_1/' in l.split('op_name="')[1].split('"')[0]
+               for l in mine)

@@ -473,6 +473,25 @@ def _lm_leg_setup(builder, model_cfg, seq: int, per_chip_batch: int,
     return ff, x, y
 
 
+def _check_kept_outputs(label: str, ff) -> None:
+    """What the rematerialised run keeps beside its blocks' entries (the
+    ``remat.kept`` instants: one a marked layer a block in each trace of
+    the step): the output of every linear-attention layer inside the
+    run, the one op that rematerialises itself whole, and nothing else."""
+    from flexflow_tpu.ffconst import OperatorType
+    from flexflow_tpu.obs import events
+    start, unit, reps = ff.executor._remat[:3]
+    want = sorted(l.name for l in ff.layers[start:start + unit * reps]
+                  if l.op_type == OperatorType.OP_GATED_DELTA_RULE)
+    kept = {(e["attrs"]["block"], e["attrs"]["layer"]): e["attrs"]["bytes"]
+            for e in events.events() if e["name"] == "remat.kept"}
+    say(f"{label}: rematerialised run {(start, unit, reps)} keeps "
+        f"{len(kept)} outputs, {sum(kept.values()) / 1e6:.1f} MB")
+    check(sorted(layer for _, layer in kept) == want,
+          f"{label}: the rematerialised blocks keep {sorted(kept)} where "
+          f"the layers that rematerialise themselves whole are {want}")
+
+
 def _check_experts_counters(label: str) -> None:
     """The ``moe.*`` counters over the leg's steps beside the layers'
     row budgets (the ``moe.route`` instants): nothing dropped, no step
@@ -544,6 +563,7 @@ def leg_latent_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
     ff, x, y = _lm_leg_setup(build_latent_moe, model_cfg, seq,
                              per_chip_batch, label, alpha)
     _fit(ff, x, y, label)
+    _check_kept_outputs(label, ff)
     impls = ff.executor.resolved_attention_impls
     n_attn = model_cfg.num_hidden_layers + model_cfg.num_nextn_predict_layers
     say(f"{label}: resolved attention impls "
@@ -606,6 +626,7 @@ def leg_hybrid_conv_moe(model_cfg, seq: int, per_chip_batch: int,
     ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
                              per_chip_batch, label, alpha)
     _fit(ff, x, y, label)
+    _check_kept_outputs(label, ff)
     kinds = list(model_cfg.layer_types)
     seen = {name: sorted({e["attrs"]["layer"] for e in events.events()
                           if e["name"] == name})
@@ -662,6 +683,7 @@ def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
     ff, x, y = _lm_leg_setup(build_latent_moe, model_cfg, seq,
                              per_chip_batch, label, alpha)
     _fit(ff, x, y, label)
+    _check_kept_outputs(label, ff)
     lin = model_cfg.linear_attn_config
     seen = {name: {e["attrs"]["layer"]: e["attrs"]
                    for e in events.events() if e["name"] == name}
@@ -767,6 +789,7 @@ def leg_mhc_latent_moe(model_cfg, seq: int, per_chip_batch: int,
     ff, x, y = _lm_leg_setup(build_latent_moe, model_cfg, seq,
                              per_chip_batch, label, alpha)
     _fit(ff, x, y, label)
+    _check_kept_outputs(label, ff)
     n_layers = model_cfg.num_hidden_layers \
         + model_cfg.num_nextn_predict_layers
     maps = {e["attrs"]["layer"]: e["attrs"] for e in events.events()

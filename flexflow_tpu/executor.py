@@ -27,6 +27,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from .ffconst import (CompMode, DataType, LossType, MetricsType, OperatorType)
 from .core.layer import Layer
@@ -123,6 +124,15 @@ def _emit_scoped(op, layer: Layer, ins, w, ctx):
     Trace-time metadata only: no computation changes."""
     with jax.named_scope(layer.name):
         return op.emit(layer.params, ins, w, ctx, layer.name)
+
+
+# The one name (``checkpoint_name``) under which ``emit_layers`` marks
+# the outputs of an op that says ``OpDef.keeps_output_for_block``, and
+# the policy by which a rematerialised block that holds such an op keeps
+# them beside its entry (``_emit_remat``). One object for every trace:
+# JAX caches its partial evaluation by the policy.
+KEPT_BY_BLOCK = "ff.kept_by_block"
+KEEP_MARKED = jax.checkpoint_policies.save_only_these_names(KEPT_BY_BLOCK)
 
 
 def _needs_rng(layer: Layer) -> bool:
@@ -231,6 +241,10 @@ class GraphProgram:
                         if cast:
                             pre_cast = reshard_mod.constrain_output(
                                 pre_cast, sh, strategy, layer)
+                if op.keeps_output_for_block:
+                    # the identity but under a rematerialised block's
+                    # policy (_emit_remat), which keeps what is so named
+                    o = checkpoint_name(o, KEPT_BY_BLOCK)
                 env[t.guid] = o
                 if capture is not None:
                     # capture keeps the pre-bf16-cast (but still
@@ -1141,7 +1155,10 @@ class Executor:
                     strategy="__use_own__"):
         """Forward with each repeated block wrapped in ``jax.checkpoint``:
         block-internal activations are recomputed in the backward pass
-        instead of living in HBM for the whole step."""
+        instead of living in HBM for the whole step. A block keeps its
+        entry and the outputs of the ops inside it that rematerialise
+        themselves whole (``OpDef.keeps_output_for_block``), each
+        announced by a ``remat.kept`` instant."""
         st = self.strategy if strategy == "__use_own__" else strategy
         start, unit, reps, entries, exits = self._remat
         layers = self.program.layers
@@ -1156,9 +1173,11 @@ class Executor:
         for b in range(reps):
             block = layers[start + b * unit:start + (b + 1) * unit]
             entry_g, exit_g = entries[b], exits[b]
+            kept = [l for l in block
+                    if get_op_def(l.op_type).keeps_output_for_block]
 
             def block_fn(x_, p_, _block=block, _entry=entry_g,
-                         _exit=exit_g):
+                         _exit=exit_g, _b=b, _kept=kept):
                 benv = {**inputs_env, _entry: x_}
                 bctx = EmitCtx(training=ctx.training, rngs=ctx.rngs,
                                state=ctx.state, config=self.config,
@@ -1170,13 +1189,22 @@ class Executor:
                 if bctx.new_state or bctx.aux_losses:
                     raise RuntimeError(
                         "stateful/aux op inside a rematted block")
+                for l in _kept:
+                    for o in (benv[t.guid] for t in l.outputs):
+                        obs_events.instant(
+                            "remat.kept", block=_b, layer=l.name,
+                            bytes=o.size * o.dtype.itemsize)
                 # the block's device counters leave it as outputs: a
                 # side channel cannot cross jax.checkpoint
                 return benv[_exit], bctx.counters
 
             bp = {l.name: params[l.name] for l in block
                   if l.name in params}
-            x, counted = jax.checkpoint(block_fn)(x, bp)
+            # no policy where there is nothing to keep: JAX keys its
+            # partial evaluation on the policy, and such a block's step
+            # stays the text it was under a plain jax.checkpoint
+            x, counted = jax.checkpoint(
+                block_fn, policy=KEEP_MARKED if kept else None)(x, bp)
             for key, v in counted.items():
                 ctx.count(key, v)
             env[exit_g] = x

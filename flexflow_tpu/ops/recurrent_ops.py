@@ -210,6 +210,7 @@ class GatedDeltaRuleOp(OpDef):
     ``kda.scan``. Training and evaluation only: there is no
     decode path that carries the state from call to call."""
     op_type = OperatorType.OP_GATED_DELTA_RULE
+    keeps_output_for_block = True   # ``emit``: the layer is one checkpoint
 
     def infer(self, params, in_shapes, in_dtypes):
         return [(in_shapes[0], in_dtypes[0])]
@@ -312,8 +313,14 @@ class GatedDeltaRuleOp(OpDef):
         # pass is its input, and while the recurrence's backward runs,
         # the five arrays the recurrence read. The projections,
         # convolutions and gates are a dozen (tokens, H x d) float32
-        # arrays, 1.9 GB a layer at 8192 tokens; the price is the
-        # layer's forward pass run once more.
+        # arrays, 0.95 GB a layer at 4096 tokens (1.9 GB at 8192); the
+        # price is the layer's forward pass run once more, and each
+        # branch's twice more. A rematerialised block around the layer
+        # would run it a third time (its branches a fourth) only to hand
+        # its output on: ``keeps_output_for_block`` has the block keep
+        # the output instead (one (tokens, hidden) array, 37.7 MB at
+        # 4096 x 2304), so the counts are 2 and 3 inside a block as
+        # outside one.
         @jax.checkpoint
         def layer(x, weights):
             q, k, v, g, beta, gate = self.projections(x, weights, mdt)

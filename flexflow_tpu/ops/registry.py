@@ -86,6 +86,19 @@ class EmitCtx:
 
 class OpDef:
     op_type: OperatorType = OperatorType.OP_INVALID
+    # True for an op whose ``emit`` wraps its WHOLE body in
+    # ``jax.checkpoint``. Such an op keeps its input and runs itself
+    # again for its own backward, so a rematerialised block around it
+    # would call it a third time only to hand its output on: the block
+    # keeps that output instead (``executor.py::KEPT_BY_BLOCK``), one
+    # array for each such layer of each block, live until the block's
+    # backward. Not for an op that rematerialises a PART of itself
+    # (``HyperConnectionOp`` wraps its maps and its second run is one
+    # read of the streams, where keeping its outputs would hold eight
+    # (tokens, hidden) arrays a block; ``RoutedExpertsOp`` wraps its
+    # overflow loop): the block's second run is where its backward's
+    # residuals come from.
+    keeps_output_for_block: bool = False
 
     # ---- graph level ----
     def infer(self, params: Dict[str, Any],

@@ -70,7 +70,7 @@ def test_leg_c_latent_moe_tiny_on_the_cpu_mesh(capsys):
     chip_smoke.leg_latent_moe(cfg, seq=16, per_chip_batch=1,
                               label="C/small", alpha=1e-3)
     out = capsys.readouterr().out
-    assert "rematerialised run (12, 6, 2)" in out
+    assert "rematerialised run (12, 6, 2) keeps 0 outputs" in out
     assert "resolved attention impls ['xla'] in 4 layers" in out  # cpu
     for layer in ("experts_1", "experts_2", "experts_mtp"):
         assert (f"moe.route {layer}: 4 of 16 experts held from 0, top 4, "
@@ -92,7 +92,7 @@ def test_leg_d_hybrid_conv_moe_tiny_on_the_cpu_mesh(capsys):
     chip_smoke.leg_hybrid_conv_moe(cfg, seq=16, per_chip_batch=1,
                                    label="D/small", alpha=1e-3)
     out = capsys.readouterr().out
-    assert "rematerialised run (15, 6, 3)" in out
+    assert "rematerialised run (15, 6, 3) keeps 0 outputs" in out
     assert ("conv.short ['conv_0', 'conv_2', 'conv_3', 'conv_4']; "
             "attn.qk_norm ['attn_1']; moe.route ['experts_1', 'experts_2',"
             " 'experts_3', 'experts_4']") in out
@@ -113,7 +113,9 @@ def test_leg_e_linear_latent_moe_tiny_on_the_cpu_mesh(capsys):
     chip_smoke.leg_linear_latent_moe(cfg, seq=16, per_chip_batch=1,
                                      label="E/small", alpha=1e-3)
     out = capsys.readouterr().out
-    assert "rematerialised run (12, 6, 2)" in out
+    # float32 (8, 16, 64): the two linear-attention layers' outputs
+    assert ("rematerialised run (12, 6, 2) keeps 2 outputs, 0.1 MB"
+            in out)
     assert ("kda.scan ['kda_0', 'kda_1', 'kda_2', 'kda_4']; attn.latent "
             "['attn_3']; moe.route ['experts_1', 'experts_2', 'experts_3',"
             " 'experts_4']") in out
@@ -141,7 +143,7 @@ def test_leg_f_mhc_latent_moe_tiny_on_the_cpu_mesh(capsys, hidden, impl):
     chip_smoke.leg_mhc_latent_moe(cfg, seq=16, per_chip_batch=1,
                                   label="F/small", alpha=1e-3)
     out = capsys.readouterr().out
-    assert "rematerialised run (16, 8, 2)" in out
+    assert "rematerialised run (16, 8, 2) keeps 0 outputs" in out
     assert (f"mhc.maps in 8 sub-layers: 4 streams of {hidden}, 20 "
             f"iterations, 128 tokens") in out
     assert f"the streams' mixes by ['{impl}'] (the shapes say {impl})" in out

@@ -683,6 +683,70 @@ def test_one_step_of_fit_is_the_same_with_and_without_remat():
         events.clear()
 
 
+def _forward_scans(ff, mc):
+    """The chunk scans that run FORWARD (a layer's forward pass has one,
+    its backward runs in reverse) in the train step's jaxpr, gradient
+    and sub-jaxprs included, by the linear-attention layer in whose
+    name scope they stand."""
+    batch = data(mc)
+    batch = next(iter(ff._combined_loader(
+        [np.asarray(batch["input_ids"]), np.asarray(batch["position_ids"])],
+        np.asarray(batch["label"]), shuffle=False)))
+    step = jax.make_jaxpr(ff.executor.make_train_step())(
+        ff.params, ff.opt_state, ff.state, jnp.int32(0), batch)
+    seen = {}
+
+    def walk(jaxpr, scope):
+        for eqn in jaxpr.eqns:
+            inner = f"{scope}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "scan" and not eqn.params["reverse"]:
+                (layer,) = {part for part in inner.split("/")
+                            if part.startswith("kda_")}
+                seen[layer] = seen.get(layer, 0) + 1
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, inner)
+
+    walk(step.jaxpr, "")
+    return seen
+
+
+@pytest.mark.parametrize("remat", ["none", "blocks"])
+def test_a_layer_runs_forward_twice_a_backward_inside_a_block_or_not(remat):
+    """The layer keeps its input and runs itself again for its own
+    backward: two forward scans a layer. A block around it (``kda_1``,
+    ``kda_2``) keeps the layer's marked output, so the block's second
+    run does not call the layer a third time (3 without the policy)."""
+    events.enable()
+    events.clear()
+    try:
+        ff, mc = build(remat=remat)
+        assert _forward_scans(ff, mc) == {
+            "kda_0": 2, "kda_1": 2, "kda_2": 2, "kda_4": 2}
+        kept = [e["attrs"] for e in events.events()
+                if e["name"] == "remat.kept"]
+    finally:
+        events.disable()
+        events.clear()
+    # once a marked layer a block, with the array's bytes: float32
+    # (batch, positions, hidden); none outside a block
+    assert kept == ([] if remat == "none" else [
+        {"block": b, "layer": f"kda_{b + 1}",
+         "bytes": 4 * B * S * mc.hidden_size} for b in range(2)])
+
+
+def test_without_the_policy_a_block_runs_its_layer_a_third_time(monkeypatch):
+    """What the count above is held against: the same step with the
+    block's ``jax.checkpoint`` given no policy, as before PR 46."""
+    monkeypatch.setattr("flexflow_tpu.executor.KEEP_MARKED", None)
+    ff, mc = build(remat="blocks")
+    assert _forward_scans(ff, mc) == {
+        "kda_0": 2, "kda_1": 3, "kda_2": 3, "kda_4": 2}
+
+
 def test_the_counters_leave_rematerialised_blocks():
     ff, mc = build(remat="blocks")
     _, (bm, _) = jax.jit(lambda p: program_loss(ff, p, data(mc)))(ff.params)
@@ -705,20 +769,19 @@ PARENT_STEPS = {        # sha256 of the lowered train step at commit 6d698b5
     ("hybrid_conv_moe", "none"):
         "986657c7f81d237946de938e8683eb83fb819fe15030b1e198d9259e4613fca6",
     ("hybrid_conv_moe", "blocks"):
-        "5267450d7768fb65bdbadc5d268369cf04d7535b8a491692efc3da0be97b1e47"}
+        "5267450d7768fb65bdbadc5d268369cf04d7535b8a491692efc3da0be97b1e47",
+    # xing4_29b_a4b's builder, at commit 6053682 (PR 46's parent)
+    ("mhc_latent_moe", "none"):
+        "8da932559957478b41da65ade76ee1f85d278f46367190d4b64dbf151b10f50e",
+    ("mhc_latent_moe", "blocks"):
+        "74a6dda12d90e86d5758c3c1a4a1ad9b0697d1b99b8171cf49940d5a64f9a5d7"}
 
 
-@pytest.mark.parametrize("model,remat", sorted(PARENT_STEPS))
-def test_the_older_configurations_lower_to_the_parents_step(model, remat):
-    """``LatentAttentionOp``'s two new parameters and
-    ``LatentMoEConfig``'s three new fields default to the parent's
-    graph: the train steps of ``joyai_llm_flash``'s and
-    ``lfm2_24b_a2b``'s builders (a share of 4 of 16 experts, 8 x 32
-    tokens, default ``FFConfig`` but no search) lower to the text they
-    lowered to at the parent commit, byte for byte. A later PR that
-    means to change either step replaces the hash it changes."""
-    import hashlib
-    from flexflow_tpu.models.nlp import (HybridConvMoEConfig,
+def lowered_step(model, remat):
+    """The lowered train step of ``joyai_llm_flash``'s,
+    ``lfm2_24b_a2b``'s or ``xing4_29b_a4b``'s builder: a share of 4 of
+    16 experts, 8 x 32 tokens, default ``FFConfig`` but no search."""
+    from flexflow_tpu.models.nlp import (HybridConvMoEConfig, XingRankConfig,
                                          build_hybrid_conv_moe)
     builder, mc = {
         "latent_moe": (build_latent_moe, dataclasses.replace(
@@ -726,7 +789,10 @@ def test_the_older_configurations_lower_to_the_parents_step(model, remat):
             n_routed_experts_published=16)),
         "hybrid_conv_moe": (build_hybrid_conv_moe, dataclasses.replace(
             HybridConvMoEConfig.tiny(), num_experts=4,
-            num_experts_published=16))}[model]
+            num_experts_published=16)),
+        "mhc_latent_moe": (build_latent_moe, dataclasses.replace(
+            XingRankConfig.tiny(), n_routed_experts=4,
+            n_routed_experts_published=16))}[model]
     cfg = FFConfig()
     cfg.batch_size = 8
     cfg.only_data_parallel = True
@@ -739,7 +805,22 @@ def test_the_older_configurations_lower_to_the_parents_step(model, remat):
     pos = np.tile(np.arange(32, dtype=np.int32), (8, 1))
     batch = next(iter(ff._combined_loader(
         [ids, pos], np.zeros((8, 32, 1), np.int32), shuffle=False)))
-    text = ff.executor.make_train_step().lower(
+    return ff.executor.make_train_step().lower(
         ff.params, ff.opt_state, ff.state, jnp.int32(0), batch).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == \
-        PARENT_STEPS[model, remat]
+
+
+@pytest.mark.parametrize("model,remat", sorted(PARENT_STEPS))
+def test_the_older_configurations_lower_to_the_parents_step(model, remat):
+    """``LatentAttentionOp``'s two new parameters and
+    ``LatentMoEConfig``'s three new fields default to the parent's
+    graph: the train steps of ``joyai_llm_flash``'s and
+    ``lfm2_24b_a2b``'s builders lower to the text they lowered to at
+    the parent commit, byte for byte. Since PR 46 ``xing4_29b_a4b``'s
+    too: a rematerialised block is given a policy only where an op
+    inside it says ``keeps_output_for_block``, none of these three's
+    does, and their blocks lower as they did under the plain
+    ``jax.checkpoint``. A later PR that means to change a step replaces
+    the hash it changes."""
+    import hashlib
+    assert hashlib.sha256(lowered_step(model, remat).encode()).hexdigest() \
+        == PARENT_STEPS[model, remat]

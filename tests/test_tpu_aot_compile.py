@@ -469,6 +469,58 @@ def test_the_linear_attention_kernels_keep_their_scope(
         'gated_delta_rule_bwd/pallas_call"' in by_name["gated_delta_rule_bwd"]
 
 
+def test_a_rematerialised_block_calls_the_forward_kernel_twice_a_layer(
+        v5e_devices, compiled_kda, chip_locations):
+    """The benchmark's layout at two heads of 128 over 512 positions,
+    ``remat = "blocks"``, the train step compiled for a described v5e:
+    every linear-attention layer's forward kernel is called twice (the
+    forward pass and the layer's own second run for its backward), the
+    two inside the rematerialised blocks (``kda_1``, ``kda_2``) too,
+    where the block's second run called it a third time before the
+    block kept the layer's marked output; one backward call a layer."""
+    import dataclasses
+
+    from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+    from flexflow_tpu.models.nlp import (KimiLinearRankConfig,
+                                         build_latent_moe)
+    from flexflow_tpu.parallel.machine import MachineSpec
+    tiny = KimiLinearRankConfig.tiny()
+    mc = dataclasses.replace(
+        tiny, hidden_size=128, num_experts=4, num_experts_published=16,
+        linear_attn_config=dict(tiny.linear_attn_config, num_heads=2,
+                                head_dim=128))
+    cfg = FFConfig()
+    cfg.batch_size = 1
+    cfg.only_data_parallel = True
+    cfg.kernel_impls = "attention:xla"
+    cfg.remat = "blocks"
+    ff = FFModel(cfg)
+    out = build_latent_moe(ff, 1, 512, mc)
+    # a mesh of one device, as the described chip is
+    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
+               output_tensor=out,
+               machine_spec=MachineSpec.detect(jax.devices()[:1]))
+    assert ff.executor._remat[:3] == (12, 6, 2)
+    ids = np.zeros((1, 512), np.int32)
+    batch = next(iter(ff._combined_loader(
+        [ids, ids], np.zeros((1, 512, 1), np.int32), shuffle=False)))
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=one),
+        (ff.params, ff.opt_state, ff.state, jnp.int32(0), batch))
+    txt = ff.executor.make_train_step().lower(*shapes).compile().as_text()
+    calls = {}
+    for line in txt.splitlines():
+        if MOSAIC_CALL in line and "gated_delta_rule" in line:
+            op_name = line.split('op_name="')[1].split('"')[0]
+            (layer,) = {p for p in op_name.split("/")
+                        if p.startswith("kda_")}
+            kernel = "fwd" if "gated_delta_rule_fwd" in op_name else "bwd"
+            calls[layer, kernel] = calls.get((layer, kernel), 0) + 1
+    assert calls == {(f"kda_{i}", k): n for i in (0, 1, 2, 4)
+                     for k, n in (("fwd", 2), ("bwd", 1))}
+
+
 # the residual streams' mixes (kernels/hyper_connection.py)
 # ----------------------------------------------------------------------
 MHC_NAMES = ["hyper_connection_post_bwd", "hyper_connection_post_fwd",

@@ -12,9 +12,7 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
   Leg B  the kernels on the same path: GPT-2 at published width and its
          own context length, so attention resolves to the compiled flash
          kernel by itself; KV-cache generation against the re-forward
-         path; the fused Adam kernel against ``AdamOptimizer.update`` on
-         the model's own leaves; then a second compile with
-         ``opt_update:fused`` in the step.
+         path.
   Leg C  latent attention, routed experts and a multi-token-prediction
          module (``build_latent_moe``): a small model, then one chip's
          share of JoyAI-LLM-Flash at published widths, 1 x 4096 tokens a
@@ -105,13 +103,12 @@ def say(msg: str) -> None:
 # ----------------------------------------------------------------------
 # shared pieces
 # ----------------------------------------------------------------------
-def _config(batch: int, kernel_impls: str = "auto"):
+def _config(batch: int):
     from flexflow_tpu import FFConfig
     cfg = FFConfig()
     cfg.batch_size = batch
     cfg.seed = SEED
     cfg.search_budget = SEARCH_BUDGET   # the search runs on one chip too
-    cfg.kernel_impls = kernel_impls
     cfg.trace = "true"                  # spans and counters are read below
     return cfg
 
@@ -372,29 +369,21 @@ def leg_bert_train(bert_cfg, seq: int, per_chip_batch: int,
 # ----------------------------------------------------------------------
 # Leg B — the kernels on the same path
 # ----------------------------------------------------------------------
-def _gpt2(gpt_cfg, seq: int, per_chip_batch: int, kernel_impls: str):
-    """(model, output, x, y, ids): GPT-2 and its one fixed batch."""
+def leg_gpt2_kernels(gpt_cfg, seq: int, per_chip_batch: int) -> int:
+    """Nothing forced — the executor resolves attention by itself.
+    Returns the number of Mosaic calls in the compiled train step."""
     import jax
 
-    from flexflow_tpu import FFModel
+    from flexflow_tpu import AdamOptimizer, FFModel
     from flexflow_tpu.models.nlp import build_gpt2
+    chip = jax.devices()[0].platform != "cpu"
     batch = per_chip_batch * len(jax.devices())
     rng = np.random.default_rng(SEED)
     ids = rng.integers(0, gpt_cfg.vocab_size, (batch, seq)).astype(np.int32)
     x = [ids, np.tile(np.arange(seq, dtype=np.int32), (batch, 1))]
     y = np.roll(ids, -1, axis=1)[..., None]        # next token
-    ff = FFModel(_config(batch, kernel_impls))
-    return ff, build_gpt2(ff, batch, seq, gpt_cfg), x, y, ids
-
-
-def leg_gpt2_kernels(gpt_cfg, seq: int, per_chip_batch: int) -> int:
-    """B1: nothing forced — the executor resolves attention by itself.
-    Returns the number of Mosaic calls in the compiled train step."""
-    import jax
-
-    from flexflow_tpu import AdamOptimizer
-    chip = jax.devices()[0].platform != "cpu"
-    ff, out, x, y, ids = _gpt2(gpt_cfg, seq, per_chip_batch, "auto")
+    ff = FFModel(_config(batch))
+    out = build_gpt2(ff, batch, seq, gpt_cfg)
     _compile(ff, out, AdamOptimizer(alpha=3e-5), "B/gpt2")
     _fit(ff, x, y, "B/gpt2")
     impls = sorted(set(ff.executor.resolved_attention_impls.values()))
@@ -409,32 +398,9 @@ def leg_gpt2_kernels(gpt_cfg, seq: int, per_chip_batch: int) -> int:
     _check_flash_grids("B/gpt2", want=chip,
                        rows=ids.shape[0] * gpt_cfg.num_heads * seq)
     _check_generate(ff, ids)
-    _check_fused_adam(ff)
     say(f"B/gpt2: per-chip batch {per_chip_batch} (global "
         f"{ids.shape[0]}), seq {seq}, peak_bytes_in_use {_peak_bytes()}")
     return n_flash
-
-
-def leg_gpt2_fused_step(gpt_cfg, seq: int, per_chip_batch: int,
-                        n_flash: int) -> None:
-    """B2: the same model with the fused optimizer kernel in the step."""
-    import jax
-
-    from flexflow_tpu import AdamOptimizer
-    chip = jax.devices()[0].platform != "cpu"
-    ff, out, x, y, _ = _gpt2(gpt_cfg, seq, per_chip_batch,
-                             "opt_update:fused")
-    _compile(ff, out, AdamOptimizer(alpha=3e-5), "B/gpt2+fused")
-    check(ff.executor._kernel_impls.get("opt_update") == "fused",
-          "B/gpt2+fused: the executor did not adopt opt_update:fused")
-    _fit(ff, x, y, "B/gpt2+fused")
-    n_fused = _check_step_program(ff, x, y, "B/gpt2+fused",
-                                  want_custom_call=chip)
-    if chip:
-        check(n_fused > n_flash,
-              f"B/gpt2+fused: {n_fused} Mosaic kernels in the step, no "
-              f"more than the {n_flash} without the fused update — the "
-              f"optimizer kernel is not in the compiled step")
 
 
 def _lm_leg_setup(builder, model_cfg, seq: int, per_chip_batch: int,
@@ -926,38 +892,6 @@ def _check_generate(ff, ids) -> None:
         f"{ties} diverged at a numerical tie")
 
 
-def _check_fused_adam(ff) -> None:
-    """The fused Adam kernel against ``AdamOptimizer.update`` on the
-    model's own parameter leaves and shardings, mid-training moments."""
-    import jax
-    import jax.numpy as jnp
-
-    from flexflow_tpu import AdamOptimizer
-    from flexflow_tpu.runtime.optimizers import fused_adam_tree_update
-    opt = AdamOptimizer(alpha=1e-3, weight_decay=0.01)
-    ex = ff.executor
-    params = ff.params
-    grads = jax.tree.map(lambda w: jnp.sin(w * 37.0) * 1e-2, params)
-    state = {"m": jax.tree.map(lambda g: 0.3 * g, grads),
-             "v": jax.tree.map(lambda g: 0.5 * g * g + 1e-9, grads)}
-    step = jnp.int32(7)
-    want = jax.jit(opt.update)(params, grads, state, step)
-    got = jax.jit(lambda p, g, s, t: fused_adam_tree_update(
-        opt, p, g, s, t, mesh=ex.dmesh.mesh,
-        param_specs=ex._param_specs))(params, grads, state, step)
-    worst = 0.0
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
-                            jax.tree.leaves(want)):
-        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-        err = float(np.max(np.abs(a - b) / (np.abs(b) + 1e-6)))
-        worst = max(worst, err)
-        check(err <= 1e-5,
-              f"B/fused-adam: {jax.tree_util.keystr(path)} differs from "
-              f"AdamOptimizer.update by {err:.3g} (relative)")
-    say(f"B/fused-adam: {len(jax.tree.leaves(params))} leaves match "
-        f"AdamOptimizer.update, worst relative difference {worst:.2g}")
-
-
 # ----------------------------------------------------------------------
 def main() -> int:
     import jax
@@ -987,8 +921,7 @@ def main() -> int:
     try:
         leg_bert_train(BertConfig(), 512, BERT_PER_CHIP_BATCH)
         # (B1's model is gone by now: one training state at a time)
-        n_flash = leg_gpt2_kernels(GPTConfig(), 1024, GPT_PER_CHIP_BATCH)
-        leg_gpt2_fused_step(GPTConfig(), 1024, GPT_PER_CHIP_BATCH, n_flash)
+        leg_gpt2_kernels(GPTConfig(), 1024, GPT_PER_CHIP_BATCH)
         # a small model at a length where attention resolves to flash,
         # then one chip's share of the published model
         leg_latent_moe(LatentMoEConfig.tiny(), 1024, 1, "C/small",

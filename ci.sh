@@ -74,13 +74,6 @@ case "${1:-fast}" in
     # strategy must round-trip its per-tensor/per-phase wire plan
     # through --import verbatim
     python tools/quantized_sync_smoke.py
-    # kernel-tier smoke: calibrated search on the 2-slice seq=4 virtual
-    # mesh must adopt a NON-DEFAULT attention impl (ring), pass the plan
-    # verifier's kernel check, export/import the kernel_impls block
-    # verbatim (bit-identical first-step loss), price the searched
-    # choice against forced-XLA in the audit record, and agree
-    # numerically with a forced-xla control on the same mesh
-    python tools/kernel_tier_smoke.py
     # attribution smoke: search -> 3 train steps under FF_ATTRIB=1 ->
     # the strategy audit record must carry a measured per-op side keyed
     # 1:1 to the predicted entries AND a drift report must exist — the

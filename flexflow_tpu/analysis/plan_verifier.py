@@ -326,17 +326,10 @@ def verify_plan(strategy, layers: Sequence, *,
                  unaddressable=unaddressable)
     kimpls = getattr(strategy, "kernel_impls", None) or {}
     if kimpls:
-        from ..ffconst import OperatorType
         from ..kernels import registry as kreg
         seq_deg = int(axis_sizes.get("seq", 0) or 0)
-        attn_ctxs: Dict[str, Dict[str, Any]] = {}
-        for name, l in by_name.items():
-            if l.op_type == OperatorType.OP_MULTIHEAD_ATTENTION:
-                q_len = int(l.inputs[0].shape[1]) if l.inputs else 0
-                kv_len = int(l.inputs[1].shape[1]) \
-                    if len(l.inputs) > 1 else q_len
-                attn_ctxs[name] = kreg.attention_ctx(
-                    l.params, q_len, kv_len, seq_degree=seq_deg)
+        attn_ctxs = {name: ctx for name, l in by_name.items()
+                     if (ctx := kreg.layer_ctx(l, seq_deg)) is not None}
         _check_kernel(report, kimpls, axis_sizes, attn_ctxs,
                       have_layers=bool(by_name),
                       known_layers=set(by_name))
@@ -1025,16 +1018,13 @@ def _check_kernel(report, kimpls, axis_sizes: Dict[str, int],
     from ..kernels import registry as kreg
     seq_deg = int(axis_sizes.get("seq", 0) or 0)
     for key, impl in (kimpls or {}).items():
-        if key == kreg.OPT_UPDATE:
-            if impl not in kreg.impl_names(kreg.OPT_UPDATE):
-                report.add(
-                    "kernel", "error", key,
-                    f"unknown opt_update impl {impl!r} (known: "
-                    f"{sorted(kreg.impl_names(kreg.OPT_UPDATE))})",
-                    "kernel-impl")
-            # the fused predicate is backend-gated (TPU-only): a
-            # runtime property, re-checked when the importing process
-            # plans (FFModel._plan_kernels), not statically here
+        if key == "opt_update":
+            # not a layer name: the kind key of strategy files written
+            # while there was a fused optimizer update to choose
+            report.add(
+                "kernel", "error", key,
+                f"unknown kernel op kind 'opt_update' (impl {impl!r}; "
+                f"known kinds: {sorted(kreg.REGISTRY)})", "kernel-impl")
             continue
         if impl not in kreg.impl_names(kreg.ATTENTION):
             report.add(
@@ -1780,8 +1770,10 @@ def verify_strategy_file(path: str, doc: Optional[Dict] = None
             from ..search.serialization import _param_from_json
             for ls in prog_layers:
                 known.add(ls["name"])
-                if ls.get("op_type") != "OP_MULTIHEAD_ATTENTION":
+                if ls.get("op_type") not in ("OP_MULTIHEAD_ATTENTION",
+                                             "OP_LATENT_ATTENTION"):
                     continue
+                latent = ls["op_type"] == "OP_LATENT_ATTENTION"
                 try:
                     params = {k: _param_from_json(v)
                               for k, v in ls.get("params", {}).items()}
@@ -1790,12 +1782,14 @@ def verify_strategy_file(path: str, doc: Optional[Dict] = None
                         if shapes and len(shapes[0]) > 1 else 0
                     attn_ctxs[ls["name"]] = kreg.attention_ctx(
                         params, q_len, q_len,
-                        seq_degree=axis_sizes.get("seq", 0))
+                        seq_degree=axis_sizes.get("seq", 0),
+                        latent=latent)
                 except Exception:  # noqa: BLE001 — shape unknown ≠ unsound
                     # minimal ctx: mesh-level predicates (the ring seq
                     # axis) still bind; shape-level ones pass open
                     attn_ctxs[ls["name"]] = kreg.attention_ctx(
-                        {}, 0, 0, seq_degree=axis_sizes.get("seq", 0))
+                        {}, 0, 0, seq_degree=axis_sizes.get("seq", 0),
+                        latent=latent)
         _check_kernel(report, kdoc, axis_sizes, attn_ctxs,
                       have_layers=bool(prog_layers),
                       known_layers=known)

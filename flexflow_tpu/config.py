@@ -9,8 +9,23 @@ reference launch scripts port over directly.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import sys
 from typing import List, Optional, Sequence
+
+
+# Reference flags (``model.cc:3566-3730``) that select nothing here: the
+# parser takes them, and the value of those that carry one, so that a
+# script written for the reference still starts, and sets nothing.
+_IGNORED_FLAGS = frozenset({
+    "--enable-parameter-parallel", "--enable-attribute-parallel",
+    "--enable-sample-parallel", "--enable-propagation",
+    "--enable-inplace-optimizations", "--overlap", "--fusion",
+    "--include-costs-dot-graph"})
+_IGNORED_VALUE_FLAGS = frozenset({
+    "-d", "--dataset", "--search-num-nodes", "--search-num-workers",
+    "--simulator-workspace-size", "--compgraph", "-ll:tpu", "-ll:gpu",
+    "-ll:cpu"})
 
 
 @dataclasses.dataclass
@@ -21,11 +36,8 @@ class FFConfig:
     learning_rate: float = 0.01
     weight_decay: float = 1e-4
     print_freq: int = 10
-    dataset_path: str = ""
     # -------- machine --------
     num_nodes: int = 1
-    workers_per_node: int = 0     # 0 = use all local devices
-    cpus_per_node: int = 1
     # multi-host rendezvous (reference: GASNet/mpirun launch, MULTI-NODE.md;
     # here: jax.distributed — see parallel/distributed.py). Empty = also
     # honor FF_COORDINATOR_ADDRESS / FF_NUM_PROCESSES / FF_PROCESS_ID env.
@@ -47,20 +59,11 @@ class FFConfig:
     search_budget: int = -1
     search_alpha: float = 1.2
     only_data_parallel: bool = False
-    enable_parameter_parallel: bool = False
-    enable_attribute_parallel: bool = False
-    enable_sample_parallel: bool = False
-    enable_propagation: bool = False
-    enable_inplace_optimizations: bool = False
-    search_overlap_backward_update: bool = False
-    search_num_nodes: int = -1
-    search_num_workers: int = -1
     base_optimize_threshold: int = 10
     enable_memory_search: bool = False
     search_algo: str = "unity"    # "unity" (substitution DP) | "mcmc" | "dp"
     substitution_json_path: Optional[str] = None
     # -------- simulator --------
-    simulator_workspace_mb: int = 2048
     machine_model_version: int = 0
     machine_model_file: str = ""
     simulator_segment_size: int = 16777216
@@ -101,9 +104,7 @@ class FFConfig:
     # (FF_ATTRIB_STEPS overrides)
     attribution_steps: int = 3
     # -------- execution --------
-    perform_fusion: bool = False
     allow_tensor_op_math_conversion: bool = True   # = allow bf16 matmul accum
-    computation_mode: str = "training"
     profiling: bool = False
     # static plan verification (analysis/plan_verifier.py): compile
     # proves the adopted strategy executable — mesh-axis soundness,
@@ -118,8 +119,6 @@ class FFConfig:
     export_strategy_file: str = ""
     import_strategy_file: str = ""
     export_strategy_task_graph_file: str = ""
-    export_strategy_computation_graph_file: str = ""
-    include_costs_dot_graph: bool = False
     # -------- TPU-native --------
     mesh_shape: Optional[Sequence[int]] = None     # explicit ICI mesh, else auto
     # pipeline parallelism through the product path (reference reserves
@@ -242,12 +241,12 @@ class FFConfig:
     # dispatched ahead of consumption; 0 disables, 1 is the old
     # single-slot double-buffer
     prefetch_batches: int = 2
-    # searched per-op kernel-implementation tier (kernels/registry.py):
-    # "auto" lets FFModel._plan_kernels pick each op's impl from the
-    # calibrated (op, impl) costs; "<op>:<impl>[,...]" forces choices
-    # (e.g. "attention:ring,opt_update:fused"). FF_KERNEL_IMPL env and
-    # --kernel-impl override. Forced-but-unavailable impls are rejected
-    # by the plan verifier's `kernel` check with op attribution.
+    # forced kernel implementations (kernels/registry.py): "auto"
+    # leaves each op to choose its kernel from its shapes;
+    # "<op>:<impl>[,...]" forces choices (e.g. "attention:ring").
+    # FF_KERNEL_IMPL env and --kernel-impl override. A forced impl that
+    # is not available on the mesh/shapes is a typed compile-time error
+    # naming the op.
     kernel_impls: str = "auto"
     # sequence-parallel (context) mesh axis degree: N >= 2 carves a
     # dedicated "seq" axis out of the device factorization; attention
@@ -289,29 +288,12 @@ class FFConfig:
     serving_floor_guard: str = "auto"  # "auto" | "true" | "false"
     seed: int = 0
 
-    def __post_init__(self):
-        self._devices = None
-
     def serving_buckets_list(self) -> List[int]:
         """Parsed ``serving_buckets`` ([] = caller defaults)."""
         if not self.serving_buckets:
             return []
         return sorted({int(b) for b in
                        str(self.serving_buckets).split(",") if b})
-
-    # ---- machine queries (lazy; avoids importing jax at flag-parse time) ----
-    @property
-    def devices(self):
-        if self._devices is None:
-            import jax
-            self._devices = jax.devices()
-        return self._devices
-
-    @property
-    def num_devices(self) -> int:
-        if self.workers_per_node:
-            return self.workers_per_node * self.num_nodes
-        return len(self.devices)
 
     @property
     def seq_length(self) -> int:  # reference FFIterationConfig::seq_length
@@ -327,6 +309,7 @@ class FFConfig:
         """
         cfg = cls()
         args = list(sys.argv[1:] if argv is None else argv)
+        ignored: List[str] = []
         i = 0
 
         def take() -> str:
@@ -346,8 +329,6 @@ class FFConfig:
                 cfg.weight_decay = float(take())
             elif a in ("-p", "--print-freq"):
                 cfg.print_freq = int(take())
-            elif a in ("-d", "--dataset"):
-                cfg.dataset_path = take()
             elif a == "--budget" or a == "--search-budget":
                 cfg.search_budget = int(take())
             elif a == "--alpha" or a == "--search-alpha":
@@ -356,22 +337,6 @@ class FFConfig:
                 cfg.only_data_parallel = True
             elif a == "--no-plan-verify":
                 cfg.plan_verify = False
-            elif a == "--enable-parameter-parallel":
-                cfg.enable_parameter_parallel = True
-            elif a == "--enable-attribute-parallel":
-                cfg.enable_attribute_parallel = True
-            elif a == "--enable-sample-parallel":
-                cfg.enable_sample_parallel = True
-            elif a == "--enable-propagation":
-                cfg.enable_propagation = True
-            elif a == "--enable-inplace-optimizations":
-                cfg.enable_inplace_optimizations = True
-            elif a == "--overlap":
-                cfg.search_overlap_backward_update = True
-            elif a == "--search-num-nodes":
-                cfg.search_num_nodes = int(take())
-            elif a == "--search-num-workers":
-                cfg.search_num_workers = int(take())
             elif a == "--base-optimize-threshold":
                 cfg.base_optimize_threshold = int(take())
             elif a == "--memory-search":
@@ -384,8 +349,6 @@ class FFConfig:
                 cfg.search_floor_guard = take().lower()
             elif a == "--no-floor-guard":
                 cfg.search_floor_guard = "false"
-            elif a == "--simulator-workspace-size":
-                cfg.simulator_workspace_mb = int(take())
             elif a == "--machine-model-version":
                 cfg.machine_model_version = int(take())
             elif a == "--machine-model-file":
@@ -413,8 +376,6 @@ class FFConfig:
                 cfg.attribution = "false"
             elif a == "--attribution-steps":
                 cfg.attribution_steps = int(take())
-            elif a == "--fusion":
-                cfg.perform_fusion = True
             elif a == "--profiling":
                 cfg.profiling = True
             elif a == "--allow-tensor-op-math-conversion":
@@ -433,14 +394,6 @@ class FFConfig:
                 cfg.import_strategy_file = take()
             elif a == "--taskgraph":
                 cfg.export_strategy_task_graph_file = take()
-            elif a == "--compgraph":
-                cfg.export_strategy_computation_graph_file = take()
-            elif a == "--include-costs-dot-graph":
-                cfg.include_costs_dot_graph = True
-            elif a == "-ll:tpu" or a == "-ll:gpu":
-                cfg.workers_per_node = int(take())
-            elif a == "-ll:cpu":
-                cfg.cpus_per_node = int(take())
             elif a == "-ll:fsize":
                 cfg.device_mem_mb = int(take())
             elif a == "--nodes":
@@ -466,8 +419,8 @@ class FFConfig:
             elif a == "--seq-parallel":
                 cfg.seq_parallel_degree = int(take())
             elif a == "--kernel-impl":
-                # repeated flags accumulate: --kernel-impl attention:ring
-                # --kernel-impl opt_update:fused
+                # repeated flags accumulate (the later pair of one op
+                # wins in kernels/registry.parse_forced)
                 v = take()
                 cfg.kernel_impls = v if cfg.kernel_impls == "auto" \
                     else f"{cfg.kernel_impls},{v}"
@@ -525,8 +478,16 @@ class FFConfig:
                 cfg.serving_floor_guard = take()
             elif a == "--seed":
                 cfg.seed = int(take())
+            elif a in _IGNORED_FLAGS:
+                ignored.append(a)
+            elif a in _IGNORED_VALUE_FLAGS:
+                ignored.append(f"{a} {take()}")
             # unknown flags: skip (reference forwards to Legion)
             i += 1
+        if ignored:
+            logging.getLogger("flexflow_tpu").debug(
+                "reference flags with no effect here, ignored: %s",
+                ", ".join(ignored))
         return cfg
 
 

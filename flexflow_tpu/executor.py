@@ -479,19 +479,17 @@ class Executor:
         # _plan_qsync) re-resolves via attach_qsync().
         self._qsync = None
         self.attach_qsync()
-        # searched kernel tier (kernels/registry.py): the adopted
+        # forced kernel impls (kernels/registry.py): the adopted
         # strategy's per-op impl map, threaded through EmitCtx so
         # attention emission resolves its impl (ring lowers one
-        # shard_map over the mesh's seq axis) and the optimizer update
-        # dispatches fused/unfused. Empty = default impls everywhere.
+        # shard_map over the mesh's seq axis). Empty = every op's own
+        # rule decides.
         self._kernel_impls: Dict[str, str] = dict(
             getattr(strategy, "kernel_impls", None) or {})
         # layer name -> "xla" | "flash" | "ring", written while a step
         # is traced: the implementation each attention op really emitted
         # (the train step's, once one was traced; else the eval step's)
         self.resolved_attention_impls: Dict[str, str] = {}
-        # PartitionSpec per parameter leaf, set when they materialize
-        self._param_specs = None
         # pipeline region (parallel/pipeline_lowering): pre/post layer
         # split + GPipe lowering of the repeated-block region
         self.pipe = getattr(strategy, "pipeline", None)
@@ -592,8 +590,6 @@ class Executor:
                 leaves = jax.tree.leaves(params)
                 sp.set(parameters=sum(int(a.size) for a in leaves),
                        bytes=sum(int(a.nbytes) for a in leaves))
-            # the fused optimizer kernel runs under shard_map with these
-            self._param_specs = jax.tree.map(lambda sh: sh.spec, psh)
             # placement via the reshard planner's host→device step:
             # sharded leaves hand each device only its own slice instead
             # of staging a full per-device replica
@@ -1091,7 +1087,7 @@ class Executor:
         return rngs
 
     def _attach_kernel_ctx(self, ctx):
-        """Thread the adopted kernel tier (kernels/registry.py) plus the
+        """Thread the forced kernel impls (kernels/registry.py) plus the
         seq-axis mesh context into an EmitCtx — ring attention lowers
         its shard_map against ctx.mesh/ctx.seq_axis."""
         if self._kernel_impls:
@@ -1232,7 +1228,7 @@ class Executor:
 
     def _apply_update(self, params, grads, opt_state, step):
         """The optimizer phase of the train step (``step`` is 1-based):
-        overlapped, fused-kernel or plain update, by the adopted plan."""
+        overlapped or plain update, by the adopted plan."""
         if self._overlap_schedule is not None:
             # overlap path (runtime/overlap.py): per-bucket updates
             # chained in backward-completion order — identity math
@@ -1244,22 +1240,6 @@ class Executor:
             new_params, new_opt_state = overlap_mod.overlapped_update(
                 self.optimizer, params, grads, opt_state, step,
                 self._overlap_schedule, self.opt_state_constraints)
-        elif self._kernel_impls.get("opt_update") == "fused":
-            # searched kernel tier: one-HBM-pass Pallas Adam update
-            # (kernels/opt_update.py) — bit-equal math to
-            # AdamOptimizer.update, adopted only when the registry
-            # predicate held (TPU backend, adam) at plan time
-            from .runtime.optimizers import fused_adam_tree_update
-            zero = self.opt_state_constraints
-            new_params, new_opt_state = fused_adam_tree_update(
-                self.optimizer, params, grads, opt_state, step,
-                mesh=self.dmesh.mesh, param_specs=self._param_specs,
-                state_specs=None if zero is None else jax.tree.map(
-                    lambda sh: sh.spec, zero["m"]))
-            if self.opt_state_constraints is not None:
-                new_opt_state = jax.tree.map(
-                    jax.lax.with_sharding_constraint,
-                    new_opt_state, self.opt_state_constraints)
         else:
             new_params, new_opt_state = self.optimizer.update(
                 params, grads, opt_state, step)

@@ -10,11 +10,6 @@ with:
   - ``ring_attention``: sequence/context-parallel attention over a sharded
     sequence axis (a capability the reference LACKS — SURVEY.md §5
     "Long-context / sequence parallelism: not present").
-  - ``ulysses_attention``: all-to-all (DeepSpeed-Ulysses style) sequence
-    parallelism: swap seq-sharding for head-sharding around local flash
-    attention.
-  - ``opt_update``: fused one-HBM-pass Adam update for the ZeRO-sharded
-    optimizer path (the ``opt_update:fused`` kernel tier).
   - ``gated_delta_rule``: the in-chunk terms of linear attention's chunked
     recurrence (fwd+bwd), taken by ``ops/recurrent_ops.py`` where the
     shapes allow; not a registry entry.
@@ -28,34 +23,29 @@ with:
     ``ops/moe_ops.py`` where the shapes allow (whole lanes, fewer rows
     than ``top_k`` arrays of tokens, one device); not a registry entry.
 
-``registry`` makes the implementation choice a searched dimension: per-op
-variants with availability predicates and calibrated cost entry points
-(docs/kernels.md).
+Each op chooses its kernel from what it can observe (shapes, dropout,
+platform). ``registry`` holds the one override: attention's three names,
+the predicate a forced choice is held to, and the parser of the forcing
+spec (docs/kernels.md).
 
 All kernels run compiled on TPU and in Pallas interpret mode on CPU, so the
 test suite exercises them without hardware.
 """
 from .flash_attention import (dropout_keep_mask, flash_attention,
                               mha_reference)
-from .opt_update import fused_adam_update
-from .registry import (DEFAULT_IMPLS, KernelImpl, REGISTRY, attention_ctx,
-                       available_impls, get_impl, parse_forced,
-                       resolve_forced)
-from .ring_attention import ring_attention, ulysses_attention
+from .registry import (KernelImpl, REGISTRY, attention_ctx, get_impl,
+                       parse_forced, resolve_forced)
+from .ring_attention import ring_attention
 
 __all__ = [
-    "DEFAULT_IMPLS",
     "KernelImpl",
     "REGISTRY",
     "attention_ctx",
-    "available_impls",
     "dropout_keep_mask",
     "flash_attention",
-    "fused_adam_update",
     "get_impl",
     "mha_reference",
     "parse_forced",
     "resolve_forced",
     "ring_attention",
-    "ulysses_attention",
 ]

@@ -1,23 +1,18 @@
-"""Sequence/context-parallel attention: ring attention and Ulysses.
+"""Sequence/context-parallel attention: ring attention.
 
 The reference has NO sequence parallelism (SURVEY.md §5: "no ring
 attention, no blockwise, no Ulysses") — this module is the beyond-reference
-capability the rebuild makes first-class. Two schemes:
+capability the rebuild makes first-class.
 
-- :func:`ring_attention` — blockwise attention with K/V chunks rotating
-  around the mesh axis via ``lax.ppermute`` (ICI neighbor exchange), log-
-  sum-exp merging of per-chunk partial results, and a custom VJP that runs
-  a second ring pass rotating (k, v, dk, dv) together so every device
-  accumulates gradient contributions for every chunk. Peak memory per
-  device stays O(seq/N · seq/N) and communication rides the ICI ring.
-- :func:`ulysses_attention` — all-to-all the (seq-sharded) q/k/v into
-  head-sharded layout, run local flash attention over the full sequence,
-  all-to-all back. One all-to-all pair instead of N ring steps; requires
-  heads % axis_size == 0.
+:func:`ring_attention` — blockwise attention with K/V chunks rotating
+around the mesh axis via ``lax.ppermute`` (ICI neighbor exchange), log-
+sum-exp merging of per-chunk partial results, and a custom VJP that runs
+a second ring pass rotating (k, v, dk, dv) together so every device
+accumulates gradient contributions for every chunk. Peak memory per
+device stays O(seq/N · seq/N) and communication rides the ICI ring.
 
-Both are written to be used inside ``shard_map`` over a mesh axis that
-shards the sequence dimension; per-chunk compute uses the Pallas flash
-kernel (:mod:`flash_attention`) when block structure allows.
+Written to be used inside ``shard_map`` over a mesh axis that shards the
+sequence dimension.
 """
 from __future__ import annotations
 
@@ -28,7 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import NEG_INF, flash_attention
+from .flash_attention import NEG_INF
 
 
 def _chunk_attn(q, k, v, sm_scale, mode):
@@ -198,27 +193,3 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     return _ring_core(q, k, v, axis_name, causal, sm_scale)
-
-
-def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
-                      sm_scale: Optional[float] = None,
-                      interpret: Optional[bool] = None):
-    """DeepSpeed-Ulysses-style sequence parallelism.
-
-    Inside ``shard_map`` with q/k/v sequence-sharded (b, h, seq/N, d):
-    all-to-all seq-shards ↔ head-shards, local flash attention over the
-    full sequence with heads/N local heads, then all-to-all back.
-    Requires h % axis_size == 0."""
-    n = jax.lax.psum(1, axis_name)
-    # (b, h, s/N, d) -> (b, h/N, s, d)
-    qh = jax.lax.all_to_all(q, axis_name, split_axis=1, concat_axis=2,
-                            tiled=True)
-    kh = jax.lax.all_to_all(k, axis_name, split_axis=1, concat_axis=2,
-                            tiled=True)
-    vh = jax.lax.all_to_all(v, axis_name, split_axis=1, concat_axis=2,
-                            tiled=True)
-    o = flash_attention(qh, kh, vh, causal=causal, sm_scale=sm_scale,
-                        interpret=interpret)
-    # (b, h/N, s, d) -> (b, h, s/N, d)
-    return jax.lax.all_to_all(o, axis_name, split_axis=2, concat_axis=1,
-                              tiled=True)

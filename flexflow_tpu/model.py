@@ -42,7 +42,7 @@ from .parallel.strategy import ShardingStrategy
 from .runtime.dataloader import SingleDataLoader
 from .runtime.metrics import PerfMetrics
 from .runtime.metrics_buffer import MetricsBuffer
-from .runtime.optimizers import AdamOptimizer, Optimizer, SGDOptimizer
+from .runtime.optimizers import Optimizer, SGDOptimizer
 
 #: help string of ``ff_model_compiles_total``: it counts calls, not what
 #: XLA built (the recorder's ``xla.compiles/<fun_name>`` does that)
@@ -963,12 +963,11 @@ class FFModel:
             # Runs BEFORE plan verification so the qsync check binds on the
             # plan the run will actually use.
             self._plan_qsync()
-            # searchable kernel tier (kernels/registry.py): adopt a per-op
-            # implementation assignment (attention xla/flash/ring, the
-            # optimizer update fused/unfused) — searched by calibrated cost,
-            # forced by --kernel-impl, imported verbatim. Runs BEFORE plan
-            # verification so the kernel check and the seq-aware memory
-            # envelope bind on the impls the run will actually execute.
+            # forced kernel impls (kernels/registry.py): adopt what
+            # --kernel-impl forces or an imported strategy carries
+            # (attention xla/flash/ring). Runs BEFORE plan verification so
+            # the kernel check and the seq-aware memory envelope bind on
+            # the impls the run will actually execute.
             self._plan_kernels()
         # static plan verification (analysis/plan_verifier.py): prove
         # the adopted strategy executable — axis soundness, shard
@@ -1211,18 +1210,14 @@ class FFModel:
                   f"{s['quantized_s_total'] * 1e3:.3f} ms/step")
 
     def _plan_kernels(self):
-        """Adopt per-op kernel implementations (kernels/registry.py):
-        attention ``xla``/``flash``/``ring``, the optimizer update
-        ``fused``/``unfused``. An assignment already on the strategy
-        (``--import`` round-trip) is honored verbatim; forced choices
-        (``--kernel-impl`` / ``FF_KERNEL_IMPL``) bypass scoring but are
-        predicate-checked — forcing ``ring`` on a mesh without a
+        """Adopt forced kernel implementations (kernels/registry.py).
+        With nothing forced every op chooses its own kernel from its
+        shapes and there is no plan. An assignment already on the
+        strategy (``--import`` round-trip) is honored verbatim; a forced
+        choice (``--kernel-impl`` / ``FF_KERNEL_IMPL``) is held to its
+        availability predicate — forcing ``ring`` on a mesh without a
         sequence axis is a typed compile-time error attributed to the
-        op. Searched deviation from the defaults requires measured
-        calibration evidence (``FF_CALIBRATION_V2``): the analytic
-        curves alone would flip CPU runs onto interpret-mode kernels
-        the host executes orders of magnitude slower than its own XLA
-        path."""
+        op."""
         cfg = self.config
         if self.strategy is None or self.executor is None:
             return
@@ -1237,211 +1232,43 @@ class FFModel:
             return
         from .kernels import registry as kreg
         forced = kreg.resolve_forced(cfg)
+        if not forced:
+            return
         if getattr(strat, "pipeline", None) is not None:
             # pipeline stages emit inside their own shard_map region —
             # the ring collective cannot nest there and the kernel ctx
-            # is not threaded through stage emission; keep defaults
-            if forced:
-                import logging
-                logging.getLogger("flexflow_tpu").warning(
-                    "kernel impls are not planned under pipeline "
-                    "parallelism; ignoring forced %s", dict(forced))
+            # is not threaded through stage emission; keep the ops' rules
+            import logging
+            logging.getLogger("flexflow_tpu").warning(
+                "kernel impls are not planned under pipeline "
+                "parallelism; ignoring forced %s", dict(forced))
             return
-        from .search.calibration import calibration_enabled
-        if not forced and not calibration_enabled(cfg):
-            # nothing to do: no forced choices and no measured evidence
-            # to search on — the defaults stand, at zero compile cost
-            return
-        backend = jax.default_backend()
+        impl = forced[kreg.ATTENTION]   # the registry's one kind
         seq_deg = int(getattr(self.dmesh, "seq_degree", 0) or 0)
-        layers = self.executor.program.layers
-        attn = [l for l in layers
-                if l.op_type == OperatorType.OP_MULTIHEAD_ATTENTION]
-        cost_model = getattr(self, "_search_cost_model", None)
-        if cost_model is None or cost_model.spec is not self.dmesh.spec:
-            # non-searched paths (DP preset, --seq-parallel without a
-            # budget): a bare cost model, placement-aware, calibrated
-            # when the opt-in is on — same construction as _plan_zero
-            from .search.costmodel import OpCostModel
-            from .search.optimizer import _attach_placement
-            cost_model = OpCostModel(self.dmesh.spec)
-            _attach_placement(cfg, cost_model, self.dmesh)
-            from .search.calibration import (calibrate_mesh,
-                                             calibration_enabled)
-            if calibration_enabled(cfg) and not cfg.machine_model_file:
-                try:
-                    cost_model.attach_calibration(
-                        calibrate_mesh(self.dmesh))
-                except Exception as e:  # noqa: BLE001 — analytic terms
-                    from .search.optimizer import note_skip
-                    note_skip(self, "calibration_v2", e)
-        searchable = cost_model.calib is not None
-        if searchable:
-            try:
-                # grow the impl-keyed rows (op_attention@<impl>); a warm
-                # table makes this measurement-free
-                from .search.calibration import calibrate_kernel_impls
-                calibrate_kernel_impls(self.dmesh,
-                                       cost_model.calib.table)
-            except Exception as e:  # noqa: BLE001 — priced analytically
-                from .search.optimizer import note_skip
-                note_skip(self, "kernel_impl_rows", e)
-        tier = None
-        if self.dmesh.seq_axis:
-            tier = self.dmesh.axis_tiers.get(self.dmesh.seq_axis)
-
-        def _degrees(name):
-            """Adopted (output shard degrees, weight shard degree)."""
-            os_ = strat.ops.get(name)
-            sd: Dict[int, int] = {}
-            wdeg = 1
-            if os_ is None:
-                return sd, wdeg
-            spec0 = os_.outputs[0] if os_.outputs else None
-            for i, ax in enumerate(spec0 or ()):
-                if ax is None:
-                    continue
-                axes = ax if isinstance(ax, (tuple, list)) else (ax,)
-                d = 1
-                for a in axes:
-                    d *= int(self.dmesh.axis_sizes.get(a, 1))
-                if d > 1:
-                    sd[i] = d
-            for wspec in (os_.weights or {}).values():
-                d = 1
-                for ax in wspec or ():
-                    if ax is None:
-                        continue
-                    axes = ax if isinstance(ax, (tuple, list)) else (ax,)
-                    for a in axes:
-                        d *= int(self.dmesh.axis_sizes.get(a, 1))
-                wdeg = max(wdeg, d)
-            return sd, wdeg
-
-        plan: Dict[str, str] = {}
+        # the kind key reaches every attention op of whatever kind
+        # (ops/nn_ops.py::MultiHeadAttentionOp._impl_for); the layers
+        # named below also get the predicate check and an audit row
+        plan: Dict[str, str] = {kreg.ATTENTION: impl}
         audit_ops: List[Dict] = []
-        f_attn = forced.get(kreg.ATTENTION)
-        if f_attn is not None:
-            # the kind key reaches every attention op of whatever kind
-            # (ops/nn_ops.py::MultiHeadAttentionOp._impl_for); the
-            # layers named below also get the predicate check and an
-            # audit row
-            plan[kreg.ATTENTION] = f_attn
-        for layer in attn:
-            q_len = int(layer.inputs[0].shape[1]) if layer.inputs else 0
-            kv_len = int(layer.inputs[1].shape[1]) \
-                if len(layer.inputs) > 1 else q_len
-            ctx = kreg.attention_ctx(layer.params, q_len, kv_len,
-                                     backend=backend,
-                                     seq_degree=seq_deg)
-            if f_attn is not None:
-                reason = kreg.get_impl(kreg.ATTENTION,
-                                       f_attn).available(ctx)
-                if reason is not None:
-                    raise ValueError(
-                        f"{layer.name}: forced kernel impl "
-                        f"attention:{f_attn} is not available on this "
-                        f"mesh/shapes: {reason}")
-                choice = f_attn
-            elif searchable:
-                sd, wdeg = _degrees(layer.name)
-                best_t, choice = None, kreg.DEFAULT_IMPLS[kreg.ATTENTION]
-                for name in kreg.available_impls(kreg.ATTENTION, ctx):
-                    cm = cost_model.kernel_impl_cost(
-                        layer, kreg.ATTENTION, name, sd, wdeg,
-                        seq_degree=seq_deg if name == "ring" else 0,
-                        tier=tier)
-                    t = cm.forward_time + cm.backward_time
-                    if best_t is None or t < best_t:
-                        best_t, choice = t, name
-            else:
+        for layer in self.executor.program.layers:
+            ctx = kreg.layer_ctx(layer, seq_deg)
+            if ctx is None:
                 continue
-            sd, wdeg = _degrees(layer.name)
-            cm_x = cost_model.kernel_impl_cost(
-                layer, kreg.ATTENTION, "xla", sd, wdeg)
-            cm_c = cost_model.kernel_impl_cost(
-                layer, kreg.ATTENTION, choice, sd, wdeg,
-                seq_degree=seq_deg if choice == "ring" else 0,
-                tier=tier)
-            t_x = cm_x.forward_time + cm_x.backward_time
-            t_c = cm_c.forward_time + cm_c.backward_time
-            audit_ops.append({
-                "name": layer.name, "op": kreg.ATTENTION,
-                "impl": choice, "forced": f_attn is not None,
-                "predicted_s": round(t_c, 9),
-                "forced_xla_s": round(t_x, 9),
-                "delta_s": round(t_x - t_c, 9)})
-            plan[layer.name] = choice
-
-        # optimizer update: one graph-wide choice for the step's
-        # parameter update (fused single-HBM-pass Pallas Adam vs the
-        # tree-mapped jnp path)
-        f_opt = forced.get(kreg.OPT_UPDATE)
-        overlap_active = getattr(self.executor, "_overlap_schedule",
-                                 None) is not None
-        opt_kind = "adam" if isinstance(self.optimizer, AdamOptimizer) \
-            else type(self.optimizer).__name__.lower()
-        octx = {"backend": backend, "optimizer": opt_kind}
-        param_bytes = 0.0
-        for l in layers:
-            for w in l.weights or ():
-                n = 1
-                for s in w.shape:
-                    n *= int(s)
-                param_bytes += float(n) * 4.0
-        if f_opt is not None:
-            if overlap_active and f_opt == "fused":
-                raise ValueError(
-                    "forced kernel impl opt_update:fused does not "
-                    "compose with the overlapped update schedule "
-                    "(--overlap); disable one of them")
-            reason = kreg.get_impl(kreg.OPT_UPDATE, f_opt).available(octx)
+            reason = kreg.get_impl(kreg.ATTENTION, impl).available(ctx)
             if reason is not None:
                 raise ValueError(
-                    f"forced kernel impl opt_update:{f_opt} is not "
-                    f"available here: {reason}")
-            o_choice = f_opt
-        elif searchable and not overlap_active and param_bytes:
-            best_t, o_choice = None, kreg.DEFAULT_IMPLS[kreg.OPT_UPDATE]
-            for name in kreg.available_impls(kreg.OPT_UPDATE, octx):
-                cm = cost_model.kernel_impl_cost(
-                    None, kreg.OPT_UPDATE, name,
-                    param_bytes=param_bytes)
-                if best_t is None or cm.forward_time < best_t:
-                    best_t, o_choice = cm.forward_time, name
-        else:
-            o_choice = None
-        if o_choice is not None:
-            cm_u = cost_model.kernel_impl_cost(
-                None, kreg.OPT_UPDATE, "unfused",
-                param_bytes=param_bytes)
-            cm_c = cost_model.kernel_impl_cost(
-                None, kreg.OPT_UPDATE, o_choice,
-                param_bytes=param_bytes)
-            audit_ops.append({
-                "name": "__opt_update__", "op": kreg.OPT_UPDATE,
-                "impl": o_choice, "forced": f_opt is not None,
-                "predicted_s": round(cm_c.forward_time, 9),
-                "forced_xla_s": round(cm_u.forward_time, 9),
-                "delta_s": round(cm_u.forward_time
-                                 - cm_c.forward_time, 9)})
-            if o_choice != kreg.DEFAULT_IMPLS[kreg.OPT_UPDATE]:
-                plan[kreg.OPT_UPDATE] = o_choice
-
-        if not plan and not audit_ops:
-            return
+                    f"{layer.name}: forced kernel impl "
+                    f"attention:{impl} is not available on this "
+                    f"mesh/shapes: {reason}")
+            audit_ops.append({"name": layer.name, "op": kreg.ATTENTION,
+                              "impl": impl, "forced": True})
+            plan[layer.name] = impl
         strat.kernel_impls = plan
         # the executor snapshotted (the then-empty) strategy.kernel_impls
         # at construction — refresh so the jitted step traces the plan
         self.executor.set_kernel_impls(plan)
-        n_nondefault = sum(
-            1 for e in audit_ops
-            if e["impl"] != kreg.DEFAULT_IMPLS[e["op"]])
-        record = {"policy": policy, "backend": backend,
-                  "seq_degree": seq_deg,
-                  "n_ops": len(audit_ops),
-                  "n_nondefault": n_nondefault,
-                  "measured": bool(searchable),
+        record = {"policy": policy, "backend": jax.default_backend(),
+                  "seq_degree": seq_deg, "n_ops": len(audit_ops),
                   "ops": audit_ops}
         self._kernel_record = record
         audit_path = getattr(self, "_strategy_audit_path", None)
@@ -1462,10 +1289,8 @@ class FFModel:
             except Exception:  # noqa: BLE001 — export is best-effort
                 pass
         if cfg.profiling:
-            tot = sum(e["delta_s"] for e in audit_ops)
-            print(f"kernel plan ({policy}): {n_nondefault}/"
-                  f"{len(audit_ops)} ops off the default impl, "
-                  f"predicted {tot * 1e3:+.3f} ms/step vs forced-xla")
+            print(f"kernel plan ({policy}): {len(audit_ops)} attention "
+                  f"ops forced onto {impl}")
 
     # ------------------------------------------------------------------
     def create_data_loader(self, tensor: Tensor, data: np.ndarray):

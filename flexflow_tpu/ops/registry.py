@@ -61,7 +61,7 @@ class EmitCtx:
         # by the shard factor ONLY when this is set — global emission
         # keeps the exact historical error behavior
         self.local_shape: bool = False
-        # searched kernel tier (kernels/registry.py): the adopted
+        # forced kernel impls (kernels/registry.py): the adopted
         # strategy's per-op impl map plus the mesh context ring
         # attention lowers its shard_map against. None/empty = no plan:
         # attention resolves by its ``auto`` rule.

@@ -99,11 +99,10 @@ class ShardingStrategy:
         # serving pass (KV sharding sound, envelope fits at the largest
         # bucket).
         self.serving = None
-        # searched per-op kernel-implementation assignment
-        # (kernels/registry.py, planned by FFModel._plan_kernels):
-        # op kind -> impl for graph-wide kinds ("opt_update": "fused")
+        # forced kernel-implementation assignment (kernels/registry.py,
+        # adopted by FFModel._plan_kernels): the "attention" kind key
         # and layer-name -> impl for attention ops ("attn0": "ring").
-        # {} / missing key = the kind's default impl. Serializes as the
+        # {} / missing key = the op's own rule. Serializes as the
         # artifact's "kernel_impls" block (--import honors it verbatim)
         # and is statically checked by analysis/plan_verifier's kernel
         # pass (every chosen impl's availability predicate must hold on

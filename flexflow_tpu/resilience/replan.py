@@ -27,7 +27,7 @@ deserves a fresh budget.
 Training swaps ride the same machinery as checkpoint restore: the live
 params/opt-state/state are snapshotted to host, the candidate strategy
 is compiled through the ordinary ``FFModel.compile`` path (so the ZeRO
-planner, qsync planner, kernel tier and plan verifier all re-bind on
+planner, qsync planner, forced kernels and plan verifier all re-bind on
 it), and the snapshot is re-placed onto the new shardings via
 ``reshard.place_host`` — values bit-identical, only placement changes.
 Serving swaps go through ``ModelRepository.hot_swap`` under graceful
@@ -293,7 +293,7 @@ class ReplanController:
 
     def _fresh_cost_model(self, ff):
         """A cost model calibrated the way ``optimize_strategy`` does it
-        — measured collectives, persisted tables, kernel tier — so the
+        — measured collectives, persisted tables — so the
         re-search ranks plans on the machine as it is NOW (the refreshed
         rows from ``remeasure_stale``, the degradation factors from the
         fault registry)."""
@@ -313,16 +313,6 @@ class ReplanController:
                     cm.attach_calibration(calibrate_mesh(dmesh))
                 except Exception:  # noqa: BLE001 — best-effort
                     pass
-        kpolicy = str(getattr(cfg, "kernel_impls", "auto") or
-                      "auto").lower()
-        if kpolicy not in ("off", "none") and cm.calib is not None:
-            try:
-                from ..search.calibration import calibrate_kernel_impls
-                calibrate_kernel_impls(dmesh, cm.calib.table)
-            except Exception:  # noqa: BLE001
-                pass
-            from ..kernels.registry import resolve_forced
-            cm.attach_kernel_tier(dmesh, forced=resolve_forced(cfg))
         return cm
 
     def _incumbent_assignment(self, ff, sim):

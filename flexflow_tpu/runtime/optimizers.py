@@ -12,7 +12,6 @@ hand-rolled so the update math exactly mirrors the reference kernels.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, NamedTuple
 
 import jax
@@ -113,58 +112,3 @@ class AdamOptimizer(Optimizer):
         return (jax.tree.map(lambda t3: t3[0], flat, is_leaf=is_t),
                 {"m": jax.tree.map(lambda t3: t3[1], flat, is_leaf=is_t),
                  "v": jax.tree.map(lambda t3: t3[2], flat, is_leaf=is_t)})
-
-
-def fused_adam_tree_update(opt: AdamOptimizer, params, grads, state, step,
-                           *, mesh=None, param_specs=None,
-                           state_specs=None, interpret=None):
-    """Adam update through the one-HBM-pass Pallas kernel
-    (kernels/opt_update.py fused_adam_update), selected by the searched
-    kernel tier (``opt_update: fused``). Same update math as
-    ``AdamOptimizer.update`` — w/g/m/v stream through VMEM once instead
-    of XLA's per-term HBM round trips.
-
-    Inside a multi-device ``jit`` GSPMD cannot partition a Mosaic
-    kernel, so with a ``mesh`` of more than one device each leaf runs
-    under ``shard_map``. The update is elementwise, hence every operand
-    takes one spec: the moments' (``state_specs``, the ZeRO placement)
-    where given, else the parameter's own (``param_specs``); both are
-    PartitionSpec pytrees congruent with ``params``. A ZeRO leaf's new
-    weight comes back on the moments' spec and is constrained to the
-    parameter's — the all-gather of the sharded update."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..kernels.opt_update import fused_adam_update
-
-    t = step.astype(jnp.float32)
-    alpha_t = opt.alpha * jnp.sqrt(1.0 - opt.beta2 ** t) \
-        / (1.0 - opt.beta1 ** t)
-    kern = functools.partial(
-        fused_adam_update, beta1=opt.beta1, beta2=opt.beta2,
-        eps=opt.epsilon, wd=opt.weight_decay, interpret=interpret)
-
-    def upd(w, g, m, v, pspec, sspec):
-        spec = sspec if sspec is not None else pspec
-        # check_vma off: pallas_call outputs carry no varying-axes info
-        nw, nm, nv = jax.shard_map(
-            kern, mesh=mesh, in_specs=(spec,) * 4 + (P(),),
-            out_specs=(spec,) * 3, check_vma=False)(w, g, m, v, alpha_t)
-        if spec != pspec:
-            nw = jax.lax.with_sharding_constraint(
-                nw, NamedSharding(mesh, pspec))
-        return nw, nm, nv
-
-    if mesh is None or mesh.size == 1:
-        flat = jax.tree.map(lambda *wgmv: kern(*wgmv, alpha_t), params,
-                            grads, state["m"], state["v"])
-    else:
-        # spec trees are matched up to the structure of ``params``, so
-        # a PartitionSpec (a tuple) or None arrives at ``upd`` whole
-        flat = jax.tree.map(
-            upd, params, grads, state["m"], state["v"], param_specs,
-            state_specs if state_specs is not None
-            else jax.tree.map(lambda _: None, params))
-    is_t = lambda x: isinstance(x, tuple)
-    return (jax.tree.map(lambda t3: t3[0], flat, is_leaf=is_t),
-            {"m": jax.tree.map(lambda t3: t3[1], flat, is_leaf=is_t),
-             "v": jax.tree.map(lambda t3: t3[2], flat, is_leaf=is_t)})

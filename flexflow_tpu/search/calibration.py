@@ -60,13 +60,6 @@ COLLECTIVES = ("all_reduce", "all_gather", "reduce_scatter", "all_to_all")
 #: (coll_ppermute rows): one neighbor-exchange of the local K/V block
 PPERMUTE_SIZES = (1 << 16, 1 << 20, 1 << 23)
 
-#: attention-core payload sizes for the kernel-impl rows
-#: (op_attention@<impl>): q bytes at (b=1, h=8, d=64) — the two classes
-#: span s=128..512; larger contexts extrapolate on the measured pair.
-#: Kept small on purpose: the flash row times the Pallas kernel in
-#: interpret mode on CPU hosts, which is minutes-slow at long s.
-ATTN_IMPL_SIZES = (1 << 16, 1 << 20)
-
 
 def shape_class(nbytes: int) -> int:
     """Power-of-two size bucket: measurements and lookups for payloads
@@ -336,19 +329,6 @@ class CalibrationTable:
             if mesh is None or dmesh.num_devices != axis_size:
                 return None
             return _bench_parallel_eff(mesh, axis_size)
-        if kind.startswith("op_attention@"):
-            impl = kind.split("@", 1)[1]
-            seq_axis = getattr(dmesh, "seq_axis", None) \
-                if dmesh is not None else None
-            if impl == "ring":
-                if mesh is None or seq_axis is None \
-                        or int(mesh.shape[seq_axis]) != axis_size:
-                    return None
-                s = _attn_seq_len(sclass, axis_size)
-            else:
-                s = _attn_seq_len(sclass)
-            return _bench_attention_impl(impl, s, mesh=mesh,
-                                         seq_axis=seq_axis)
         if kind.startswith("coll_"):
             if mesh is None:
                 return None
@@ -572,118 +552,6 @@ def _bench_collective(mesh, coll: str, nbytes: int,
     return _timed(f, (x,), repeats=3)
 
 
-def _attn_seq_len(nbytes: int, deg: int = 1) -> int:
-    """Sequence length whose q payload is ``nbytes`` at the canonical
-    bench geometry (b=1, h=8, d=64, f32), rounded so flash blocks and
-    ring chunks both divide."""
-    s = max(nbytes // (4 * 8 * 64), 128)
-    step = 128 * max(deg, 1)
-    return max(s - s % step, step)
-
-
-def _bench_attention_impl(impl: str, s: int, mesh=None,
-                          seq_axis: Optional[str] = None) -> float:
-    """Forward time of one attention core at sequence length ``s`` and
-    the canonical bench geometry (b=1, h=8, d=64, f32) — the measured
-    anchor for the searchable kernel tier (``op_attention@<impl>``
-    rows). ``xla`` is the materialized-scores reference, ``flash`` the
-    Pallas kernel (interpret mode off-TPU), ``ring`` one shard_map over
-    the mesh's seq axis with ppermute hops (requires
-    ``mesh``/``seq_axis``)."""
-    import jax
-    import jax.numpy as jnp
-
-    b, h, d = 1, 8, 64
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((b, h, s, d)) * 0.02, jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, h, s, d)) * 0.02, jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, h, s, d)) * 0.02, jnp.float32)
-    sc = 1.0 / math.sqrt(d)
-
-    if impl == "xla":
-        def f(q_, k_, v_):
-            sm = jnp.einsum("bhqd,bhkd->bhqk", q_, k_) * sc
-            i = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-            j = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-            sm = jnp.where(j <= i, sm, -1e9)
-            p = jax.nn.softmax(sm, axis=-1)
-            return jnp.sum(jnp.einsum("bhqk,bhkd->bhqd", p, v_))[None]
-    elif impl == "flash":
-        from ..kernels import flash_attention
-
-        def f(q_, k_, v_):
-            o = flash_attention(q_, k_, v_, causal=True)
-            return jnp.sum(o.astype(jnp.float32))[None]
-    elif impl == "ring":
-        if mesh is None or seq_axis is None:
-            raise ValueError("ring bench needs a mesh with a seq axis")
-        from jax.sharding import PartitionSpec as P
-
-        from ..kernels import ring_attention
-        from jax import shard_map
-        spec = P(None, None, seq_axis, None)
-
-        def body(q_, k_, v_):
-            o = ring_attention(q_, k_, v_, seq_axis, causal=True)
-            return jnp.sum(o.astype(jnp.float32))[None]
-
-        inner = shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
-                          out_specs=P(seq_axis), check_vma=False)
-
-        def f(q_, k_, v_):
-            return jnp.sum(inner(q_, k_, v_))[None]
-    else:
-        raise ValueError(impl)
-
-    return _timed(jax.jit(f), (q, k, v), repeats=3)
-
-
-def calibrate_kernel_impls(dmesh=None,
-                           table: Optional[CalibrationTable] = None,
-                           cache_dir: Optional[str] = None,
-                           impls: Tuple[str, ...] = ("xla", "flash",
-                                                     "ring"),
-                           sizes: Tuple[int, ...] = ATTN_IMPL_SIZES
-                           ) -> CalibrationTable:
-    """Measure (or warm-load) the kernel-impl rows the searchable
-    kernel tier prices from: ``op_attention@<impl>`` keyed by the q
-    payload's shape class (``ring`` additionally by the seq degree).
-    Persisted like every other calibration row — a warm table makes
-    this call measurement-free. Called by ``FFModel._plan_kernels``
-    (not the base ``calibrate_mesh``) so searches without the kernel
-    tier pay nothing new."""
-    import jax
-    tab = table if table is not None else CalibrationTable(cache_dir)
-    backend = jax.default_backend()
-    mesh = dmesh.mesh if dmesh is not None else None
-    seq_axis = getattr(dmesh, "seq_axis", None) if dmesh is not None \
-        else None
-    for impl in impls:
-        deg = 0
-        if impl == "ring":
-            if mesh is None or seq_axis is None:
-                continue               # no seq axis: no ring row
-            deg = int(mesh.shape[seq_axis])
-            # ring's chunking floor (128*deg) collapses the small size
-            # classes onto one sequence length — bench two DISTINCT
-            # lengths so the row interpolates instead of degenerating
-            # to a single point
-            seqs = (128 * deg, 256 * deg)
-        else:
-            seqs = tuple(_attn_seq_len(nb) for nb in sizes)
-        for s in sorted(set(seqs)):
-            # keyed by the ACTUAL q payload of the benched shape, not
-            # the requested class — ring's rounding must not file an
-            # s=512 measurement under the s=128 class
-            qbytes = 4 * 8 * 64 * s
-            tab.get_or_measure(
-                backend, f"op_attention@{impl}", "float32",
-                shape_class(qbytes), deg,
-                lambda i=impl, n=s: _bench_attention_impl(
-                    i, n, mesh=mesh, seq_axis=seq_axis))
-    return tab
-
-
 # ----------------------------------------------------------------------
 # the attachable calibration object
 # ----------------------------------------------------------------------
@@ -816,27 +684,6 @@ class MeshCalibration:
             if not (0.5 <= near / degree <= 2.0):
                 return None          # too far to stand in
             pts = self._points(coll, near)
-        return self._interp(pts, nbytes)
-
-    def op_time(self, kind: str, nbytes: float,
-                degree: int = 0) -> Optional[float]:
-        """Measured time of one kernel-impl row (``op_<kind>`` —
-        e.g. ``attention@ring``), interpolated across the measured
-        shape classes. ``degree`` keys the rows that depend on a mesh
-        axis size (ring's seq degree); 0 for degree-free impls. None =
-        never measured — the cost model falls back to its analytic
-        curve for that impl."""
-        if self.table is None or nbytes <= 0:
-            return None
-        self._sync_gen()
-        key = (f"op:{kind}", degree, self.dtype)
-        pts = self._pts.get(key)
-        if pts is None:
-            pts = self.table.entries(self.backend, f"op_{kind}",
-                                     self.dtype, axis_size=degree)
-            self._pts[key] = pts
-        if not pts:
-            return None
         return self._interp(pts, nbytes)
 
     @staticmethod

@@ -35,6 +35,12 @@ from ..parallel.machine import DeviceMesh, MachineSpec
 from ..parallel.topology import link_degradation_factor
 
 
+#: where the measured rows persist unless a caller names a directory
+#: (tests/conftest.py points it outside the checkout)
+_DEFAULT_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))), ".ffcache")
+
+
 @dataclasses.dataclass
 class CostMetrics:
     """Reference ``CostMetrics`` (``simulator.h:54``) parity."""
@@ -132,20 +138,6 @@ class OpCostModel:
         self.overlap_mode = False
         # on-device measurement (reference measure_operator_cost analog)
         self.measure_on_device = False
-        # searchable kernel tier (kernels/registry.py): per-(op, impl)
-        # answers memoized like the op cache — kernel_impl_cost sits in
-        # the planner's candidate loop
-        self._impl_cache: Dict[Tuple, CostMetrics] = {}
-        # attach_kernel_tier installs this: {"seq_degree", "backend",
-        # "tier", "forced"}. With it set, op_cost_with_impl prices
-        # attention at its cheapest AVAILABLE implementation — the impl
-        # becomes a per-op dimension of the search; the argmin of the
-        # most recent pricing is left in last_kernel_impl for the audit
-        # breakdown and accumulated per layer name in kernel_choice for
-        # FFModel._plan_kernels to adopt.
-        self.kernel_tier: Optional[Dict[str, Any]] = None
-        self.last_kernel_impl: Optional[str] = None
-        self.kernel_choice: Dict[str, str] = {}
         self.measure_budget_s = 120.0   # total wall budget for microbenches
         self._measure_spent_s = 0.0
         # layer name -> "ExcType: message" of a microbenchmark that
@@ -153,9 +145,7 @@ class OpCostModel:
         self.measure_failures: Dict[str, str] = {}
         self._unmeasurable: set = set()  # per-process, deliberately not on disk
         self._disk: Optional[Dict[str, Any]] = None
-        self._cache_dir = cache_dir or os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), ".ffcache")
+        self._cache_dir = cache_dir or _DEFAULT_DIR
 
     # ------------------------------------------------------------------
     # disk cache (reference hash_to_operator_cost persisted)
@@ -543,71 +533,6 @@ class OpCostModel:
         priced under the old terms must not survive."""
         self.calib = calib
         self.cache.clear()
-        self._impl_cache.clear()
-
-    # ------------------------------------------------------------------
-    def attach_kernel_tier(self, dmesh, forced: Optional[Dict[str, str]]
-                           = None) -> None:
-        """Turn on the kernel-impl dimension (kernels/registry.py):
-        ``op_cost_with_impl`` prices attention at its cheapest available
-        implementation on this mesh. ``forced`` pins op kinds to one
-        impl (``--kernel-impl`` / FF_KERNEL_IMPL)."""
-        import jax
-        tier = None
-        seq_ax = getattr(dmesh, "seq_axis", None)
-        if seq_ax:
-            tier = getattr(dmesh, "axis_tiers", {}).get(seq_ax)
-        self.kernel_tier = {
-            "seq_degree": int(getattr(dmesh, "seq_degree", 0) or 0),
-            "backend": jax.default_backend(),
-            "tier": tier,
-            "forced": dict(forced or {}),
-        }
-        self.kernel_choice = {}
-        self.cache.clear()
-        self._impl_cache.clear()
-
-    def op_cost_with_impl(self, layer: Layer,
-                          shard_degrees: Dict[int, int],
-                          weight_shard_degree: int = 1) -> CostMetrics:
-        """``op_cost`` with the kernel-impl dimension resolved: when a
-        kernel tier is attached and the op has registered variants, every
-        AVAILABLE impl is priced (``kernel_impl_cost``) and the cheapest
-        answers; the argmin lands in ``last_kernel_impl`` (audit
-        breakdowns) and ``kernel_choice[layer.name]``
-        (``FFModel._plan_kernels``). Without a tier this IS ``op_cost``."""
-        self.last_kernel_impl = None
-        kt = self.kernel_tier
-        if kt is None \
-                or layer.op_type != OperatorType.OP_MULTIHEAD_ATTENTION:
-            return self.op_cost(layer, shard_degrees, weight_shard_degree)
-        from ..kernels import registry as kreg
-        q_len = int(layer.inputs[0].shape[1]) if layer.inputs else 0
-        kv_len = int(layer.inputs[1].shape[1]) \
-            if len(layer.inputs) > 1 else q_len
-        ctx = kreg.attention_ctx(layer.params, q_len, kv_len,
-                                 backend=kt["backend"],
-                                 seq_degree=kt["seq_degree"])
-        forced = kt["forced"].get(kreg.ATTENTION)
-        if forced is not None:
-            names = [forced]  # pinned: availability enforced at adopt
-        else:
-            names = kreg.available_impls(kreg.ATTENTION, ctx)
-        best_name, best = None, None
-        for name in names:
-            cm = self.kernel_impl_cost(
-                layer, kreg.ATTENTION, name, shard_degrees,
-                weight_shard_degree,
-                seq_degree=kt["seq_degree"] if name == "ring" else 0,
-                tier=kt["tier"])
-            t = cm.forward_time + cm.backward_time
-            if best is None or t < best.forward_time + best.backward_time:
-                best_name, best = name, cm
-        if best is None:  # no registered impl legal — reference path
-            return self.op_cost(layer, shard_degrees, weight_shard_degree)
-        self.last_kernel_impl = best_name
-        self.kernel_choice[layer.name] = best_name
-        return best
 
     # ------------------------------------------------------------------
     def calibrate(self):
@@ -956,182 +881,6 @@ class OpCostModel:
                                                 0, n))
         else:
             self._prov("compute", None)
-
-    # ------------------------------------------------------------------
-    # searchable kernel tier (kernels/registry.py)
-    # ------------------------------------------------------------------
-    def kernel_impl_cost(self, layer: Optional[Layer], op: str,
-                         impl_name: str,
-                         shard_degrees: Optional[Dict[int, int]] = None,
-                         weight_shard_degree: int = 1, *,
-                         seq_degree: int = 0,
-                         tier: Optional[str] = None,
-                         param_bytes: float = 0.0,
-                         **_ignored) -> CostMetrics:
-        """Price one (op, kernel-impl) pair — the registry's cost entry
-        point (``kernels/registry.py KernelImpl.cost``).
-
-        ``attention``: starts from :meth:`op_cost` (the XLA reference
-        path) and swaps the attention CORE term for the chosen impl's.
-        Measured ``op_attention@<impl>`` calibration rows answer first
-        (both sides of the swap from the same table, so the delta is
-        apples-to-apples); off-table impls use the analytic curves:
-        flash = same matmul flops minus the (s, s) score-matrix HBM
-        round trip; ring = core/deg + (deg-1) ``ppermute`` hops of the
-        local K/V block, each hop priced from the ``coll_ppermute``
-        rows (``tier``-scoped when given) and — under ``overlap_mode``
-        — charged only for its EXPOSED remainder after the concurrent
-        block compute (the PR-13 bucket model applied to ring slices).
-
-        ``opt_update``: absolute update time over ``param_bytes`` —
-        ``fused`` streams w/g/m/v through VMEM once (~7 HBM passes),
-        ``unfused`` pays XLA's multi-kernel round trips (~2x).
-        """
-        sd = dict(shard_degrees or {})
-        key = (layer.param_key() if layer is not None else None, op,
-               impl_name, tuple(sorted(sd.items())), weight_shard_degree,
-               seq_degree, tier, int(param_bytes))
-        hit = self._impl_cache.get(key)
-        if hit is not None:
-            return hit
-        mem_bw = self.spec.hbm_bandwidth
-        if self.calib is not None and self.calib.mem_bw:
-            mem_bw = self.calib.mem_bw
-
-        if op == "opt_update":
-            b = max(float(param_bytes), 0.0)
-            passes = 7.0 if impl_name == "fused" else 14.0
-            t = passes * b / max(mem_bw, 1.0)
-            if self.calib is not None:
-                m = self.calib.op_time(f"opt_update@{impl_name}", b)
-                if m is not None:
-                    t = m
-            cm = CostMetrics(forward_time=t)
-            self._impl_cache[key] = cm
-            return cm
-
-        if op != "attention" or layer is None:
-            raise ValueError(f"unpriceable kernel op {op!r}")
-        base = self.op_cost(layer, sd, weight_shard_degree)
-        out = layer.outputs[0].shape
-        bsz, s = int(out[0]), int(out[1])
-        h = int(layer.params.get("num_heads", 1))
-        # kdim/embed_dim of 0 are unset placeholders, not real dims
-        e = int(layer.params.get("kdim") or
-                layer.params.get("embed_dim") or out[-1])
-        dh = e // max(h, 1)
-        total_deg = 1
-        for d in sd.values():
-            total_deg *= max(d, 1)
-        pe = 1.0
-        if self.calib is not None:
-            pe = max(self.calib.efficiency(
-                max(self.spec.num_devices, 1)), 1e-6)
-        # fwd core: the two (s, s) contractions (qk^T and p·v),
-        # stretched by measured parallel efficiency exactly like the
-        # base roofline so "base minus core" stays non-negative
-        core_flops = 4.0 * bsz * s * s * h * dh / total_deg
-        t_core = core_flops / (self.spec.peak_flops * self.mxu_eff) / pe
-        # the XLA path's score-matrix HBM round trip (write + read of
-        # the (b, h, s, s) logits) — the traffic flash/ring never pay,
-        # and the base roofline (inputs+outputs+weights only) misses
-        t_scores = 2.0 * 4.0 * bsz * h * s * s / total_deg \
-            / max(mem_bw, 1.0) / pe
-        q_bytes = 4.0 * bsz * s * h * dh / total_deg
-        # everything in the op that is NOT the attention core
-        # (projections, bias, softmax overhead) — shared by every impl
-        rest_f = max(base.forward_time - t_core, 0.0)
-        rest_b = max(base.backward_time - 2.0 * t_core, 0.0)
-
-        def _measured(name: str, deg: int = 0):
-            """Measured impl time, ONLY within the measured payload
-            range (x2 margin): the bench grid spans s=128..1024 at its
-            own geometry, and extrapolating the near-quadratic xla
-            curve an order of magnitude out turns the impl comparison
-            into noise larger than the base cost itself. Out-of-range
-            queries fall back to the analytic curve."""
-            if self.calib is None:
-                return None
-            key = (f"op:attention@{name}", deg, self.calib.dtype)
-            pts = self.calib._pts.get(key)
-            if pts is None:
-                self.calib.op_time(f"attention@{name}", 1, degree=deg)
-                pts = self.calib._pts.get(key) or []
-            if not pts or not (pts[0][0] / 2 <= q_bytes
-                               <= pts[-1][0] * 2):
-                return None
-            return self.calib.op_time(f"attention@{name}", q_bytes,
-                                      degree=deg)
-
-        if impl_name == "xla":
-            m = _measured("xla")
-            t_impl = m if m is not None else t_core + t_scores
-            t_impl_b = 2.0 * t_impl
-        elif impl_name == "flash":
-            m = _measured("flash")
-            t_impl = m if m is not None else t_core
-            t_impl_b = 2.0 * t_impl
-        elif impl_name == "ring":
-            deg = max(int(seq_degree), 1)
-            # per-device: deg blocks of (s/deg, s/deg) scores — core
-            # compute drops by deg, per-chunk score traffic by deg^2
-            # summed over deg chunks
-            t_blocks = (t_core + t_scores / deg) / deg
-            hop_bytes = 2.0 * 4.0 * bsz * h * (s / max(deg, 1)) * dh
-            hop_t = None
-            if self.calib is not None:
-                hop_t = self.calib.collective_time(
-                    "ppermute", deg, hop_bytes, tier=tier)
-                if hop_t is None and tier is not None:
-                    hop_t = self.calib.collective_time(
-                        "ppermute", deg, hop_bytes)
-            if hop_t is None:
-                ici_bw = (self.coll_bw or self.spec.ici_bandwidth) \
-                    / link_degradation_factor(tier or "ici")
-                ici_lat = self.coll_lat if self.coll_lat is not None \
-                    else self.spec.ici_latency_us * 1e-6
-                hop_t = hop_bytes / max(ici_bw, 1.0) + ici_lat
-            per_hop_block = t_blocks / max(deg, 1)
-            if self.overlap_mode:
-                # each hop's transfer overlaps the concurrent block's
-                # compute — only the exposed remainder is charged
-                # (PR-13's bucket split applied to ring slices)
-                exposed = max(hop_t - per_hop_block, 0.0)
-            else:
-                exposed = hop_t
-            comm_f = (deg - 1) * exposed
-            m = _measured("ring", deg=deg)
-            if m is not None:
-                t_impl = m              # the bench times hops included
-                t_impl_b = 2.0 * m + comm_f   # bwd rings 2x payload
-            else:
-                t_impl = t_blocks + comm_f
-                # backward rotates (k, v, dk, dv) — double payload
-                t_impl_b = 2.0 * t_blocks + 2.0 * comm_f
-        else:
-            raise ValueError(f"unknown attention impl {impl_name!r}")
-
-        fwd = rest_f + t_impl
-        bwd = rest_b + t_impl_b
-        cm = CostMetrics(forward_time=fwd, backward_time=bwd,
-                         inputs_memory=base.inputs_memory,
-                         outputs_memory=base.outputs_memory,
-                         weights_memory=base.weights_memory)
-        self._impl_cache[key] = cm
-        if self.provenance is not None:
-            row = None
-            if self.calib is not None:
-                from .calibration import CalibrationTable, shape_class
-                d = seq_degree if impl_name == "ring" else 0
-                if self.calib.op_time(f"attention@{impl_name}", q_bytes,
-                                      degree=d) is not None:
-                    row = CalibrationTable.key(
-                        self.calib.backend,
-                        f"op_attention@{impl_name}", "float32",
-                        shape_class(q_bytes), d)
-            self._prov("compute", f"op_attention@{impl_name}", row,
-                       tier)
-        return cm
 
     # ------------------------------------------------------------------
     def xfer_cost(self, volume_bytes: float, collective: str,

@@ -104,9 +104,7 @@ class StrategySimulator:
             for opt, d in zip(opts, assign.get(layer.name, ())):
                 if d > 1 and opt.weight_dims:
                     wdeg *= d
-            # kernel tier attached: attention prices at its cheapest
-            # available implementation (the impl is a search dimension)
-            cm = self.cost.op_cost_with_impl(layer, degs, wdeg)
+            cm = self.cost.op_cost(layer, degs, wdeg)
             compute += cm.forward_time + cm.backward_time
             l_mem = cm.weights_memory + cm.outputs_memory
             mem += l_mem
@@ -152,10 +150,6 @@ class StrategySimulator:
                     e["sync_wire"] = getattr(self.cost,
                                              "last_sync_wire",
                                              "float32")
-                if getattr(self.cost, "last_kernel_impl", None):
-                    # kernel implementation this op was priced at
-                    # (searchable kernel tier; same contract as unity)
-                    e["kernel_impl"] = self.cost.last_kernel_impl
                 prov = self.cost.provenance
                 if prov:
                     e["calib"] = list(prov)

@@ -139,24 +139,6 @@ def optimize_strategy(ff, mode: str = "train"):
                         calibrate_mesh(dmesh, wire_dtypes=wires))
                 except Exception as e:  # noqa: BLE001 — analytic terms
                     note_skip(ff, "calibration_v2", e)
-    # searchable kernel tier (kernels/registry.py): grow the impl-keyed
-    # calibration rows (warm table: zero re-measurement) and price every
-    # attention op at its cheapest AVAILABLE implementation during the
-    # search. Gated on an attached calibration: without measured machine
-    # evidence the analytic curves would flip CPU runs onto
-    # interpret-mode kernels the host executes orders of magnitude
-    # slower than its own XLA path. Forced specs resolve unconditionally
-    # (a typo'd --kernel-impl must fail loudly, so no try around it).
-    from ..kernels.registry import resolve_forced as _kernel_forced
-    _kpolicy = str(getattr(cfg, "kernel_impls", "auto") or "auto").lower()
-    if _kpolicy not in ("off", "none") and cost_model.calib is not None:
-        _forced = _kernel_forced(cfg)
-        try:
-            from .calibration import calibrate_kernel_impls
-            calibrate_kernel_impls(dmesh, cost_model.calib.table)
-        except Exception as e:  # noqa: BLE001 — priced analytically
-            note_skip(ff, "kernel_impl_rows", e)
-        cost_model.attach_kernel_tier(dmesh, forced=_forced)
     t0 = time.perf_counter()
     if cfg.search_algo == "unity":
         return _apply_floor_guard(

@@ -69,10 +69,10 @@ def save_strategy(path: str, strategy: ShardingStrategy,
         # check on the exported artifact
         doc["overlap"] = dict(strategy.overlap)
     if getattr(strategy, "kernel_impls", None):
-        # per-op kernel implementations (kernels/registry.py): layer
-        # names -> attention impl, plus the graph-wide "opt_update"
-        # kind; --import honors it verbatim and the plan verifier
-        # re-checks every predicate on the importing mesh
+        # forced kernel implementations (kernels/registry.py): layer
+        # names -> attention impl, plus the "attention" kind key;
+        # --import honors it verbatim and the plan verifier re-checks
+        # every predicate on the importing mesh
         doc["kernel_impls"] = dict(strategy.kernel_impls)
     banks_doc = banks_to_json(strategy)
     if banks_doc:

@@ -225,13 +225,6 @@ class GraphCostEvaluator:
                     e["sync_wire"] = getattr(self.cost,
                                              "last_sync_wire",
                                              "float32")
-                if (fwd or bwd) and getattr(self.cost,
-                                            "last_kernel_impl", None):
-                    # the kernel implementation this compute node was
-                    # priced at (searchable kernel tier) — fresh only
-                    # right after op_cost_with_impl, hence the fwd/bwd
-                    # guard keeps it off reshard-only entries
-                    e["kernel_impl"] = self.cost.last_kernel_impl
                 prov = self.cost.provenance
                 if prov:
                     e["calib"] = list(prov)
@@ -303,10 +296,7 @@ class GraphCostEvaluator:
             for g in scale_groups:
                 scale *= ann.degree_of(g)
             degs = {0: scale} if scale > 1 else {}
-            # kernel tier attached: attention prices at its cheapest
-            # available implementation (the impl is a search dimension)
-            cm = self.cost.op_cost_with_impl(n.layer, degs,
-                                             ann.weight_degree())
+            cm = self.cost.op_cost(n.layer, degs, ann.weight_degree())
             compute += cm.forward_time + cm.backward_time
             n_mem = cm.weights_memory * 4 + cm.outputs_memory
             mem += n_mem

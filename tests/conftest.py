@@ -12,8 +12,25 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 assert len(jax.devices()) == 8, jax.devices()
+
+
+@pytest.fixture(autouse=True, scope="session")
+def _timed_fits_stay_out_of_the_checkout(tmp_path_factory):
+    """``OpCostModel.calibrate_collectives`` times an all-reduce on the
+    8-virtual-device mesh and keeps the fit on disk. Under six loaded
+    workers that fit is noise (a 10 ms latency, the clamp's ceiling, in
+    the run that wrote ``<repo>/.ffcache/opcost_cpu-sim.json`` at PR
+    46), and a file in the checkout hands it to every later run: each
+    worker keeps its own in a directory that goes with the session."""
+    from flexflow_tpu.search import costmodel
+    mp = pytest.MonkeyPatch()
+    mp.setattr(costmodel, "_DEFAULT_DIR",
+               str(tmp_path_factory.mktemp("opcost")))
+    yield
+    mp.undo()
 
 
 # Three positional tests of tests/benchmark_suite/ cannot hold once the

@@ -557,7 +557,7 @@ def test_zero_assignment_on_bank_member_rejected():
 
 
 # ===========================================================================
-# searchable kernel tier: known-bad fixture + seq-aware envelope (ISSUE 19)
+# forced kernel impls: known-bad fixture + seq-aware envelope (ISSUE 19)
 # ===========================================================================
 
 def test_badplan_kernel_ring_noseq_rejected():
@@ -609,7 +609,7 @@ def test_kernel_unknown_impl_and_unknown_op_rejected():
     msgs = " | ".join(f.message for f in report.errors)
     assert "unknown attention impl 'warp'" in msgs
     assert "does not contain" in msgs
-    assert "unknown opt_update impl 'mega'" in msgs
+    assert "unknown kernel op kind 'opt_update' (impl 'mega'" in msgs
 
 
 def test_memory_envelope_ring_divides_attention_residency():

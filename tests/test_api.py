@@ -56,20 +56,31 @@ def test_weights_roundtrip():
     assert np.allclose(ff.get_weights("fc", "kernel"), 1.0)
 
 
-def test_parse_args_reference_flags():
-    cfg = FFConfig.parse_args(
-        ["-e", "3", "-b", "128", "--lr", "0.02", "--budget", "30",
-         "--only-data-parallel", "-ll:gpu", "4", "-ll:fsize", "14000",
-         "--fusion", "--enable-parameter-parallel"])
+def test_parse_args_reference_flags(caplog):
+    """The reference's spellings parse. Those that select nothing here
+    (``-ll:gpu``, ``--fusion``, ...) are taken with their values, leave
+    the flags around them alone and are named in one debug line."""
+    import logging
+    with caplog.at_level(logging.DEBUG, logger="flexflow_tpu"):
+        cfg = FFConfig.parse_args(
+            ["-e", "3", "-b", "128", "--lr", "0.02", "-d", "/data/x",
+             "--budget", "30", "--only-data-parallel", "-ll:gpu", "4",
+             "-ll:fsize", "14000", "--fusion", "--compgraph", "g.dot",
+             "--enable-parameter-parallel", "--seed", "7"])
     assert cfg.epochs == 3
     assert cfg.batch_size == 128
     assert cfg.learning_rate == 0.02
     assert cfg.search_budget == 30
     assert cfg.only_data_parallel
-    assert cfg.workers_per_node == 4
     assert cfg.device_mem_mb == 14000
-    assert cfg.perform_fusion
-    assert cfg.enable_parameter_parallel
+    assert cfg.seed == 7
+    (line,) = [r.getMessage() for r in caplog.records
+               if "ignored" in r.getMessage()]
+    assert line.endswith("-d /data/x, -ll:gpu 4, --fusion, "
+                         "--compgraph g.dot, --enable-parameter-parallel")
+    for gone in ("workers_per_node", "perform_fusion", "dataset_path",
+                 "enable_parameter_parallel"):
+        assert not hasattr(cfg, gone)
 
 
 def test_kdim_vdim_attention():

@@ -124,6 +124,47 @@ def test_xfer_cost_prefers_measured_table(tmp_path):
                                                      "all_reduce", 2))
 
 
+def test_a_table_with_kernel_impl_rows_loads_and_ignores_them(tmp_path):
+    """A table cached while the search still priced attention by
+    implementation holds ``op_attention@<impl>`` and
+    ``op_opt_update@<impl>`` rows. Nothing reads them now: the table
+    loads, its other rows answer, an attention layer costs what it
+    costs without them, and marked stale they are left for nobody to
+    re-measure, without an error."""
+    import json
+    from flexflow_tpu import FFConfig, FFModel
+    old = {"cpu|op_attention@flash|float32|65536|0": 3e-4,
+           "cpu|op_attention@ring|float32|1048576|4": 9e-4,
+           "cpu|op_opt_update@fused|float32|1048576|0": 2e-4}
+    kept = {"cpu|coll_all_reduce|float32|1048576|8": 0.123}
+
+    def table(rows, name):
+        d = tmp_path / name
+        d.mkdir()
+        (d / "calibration_v2.json").write_text(json.dumps(rows))
+        return CalibrationTable(str(d))
+
+    ff = FFModel(FFConfig())
+    q = ff.create_tensor((4, 256, 64), name="q")
+    ff.multihead_attention(q, q, q, embed_dim=64, num_heads=4)
+    attn = ff.layers[-1]
+    spec = MachineSpec.detect()
+    costs = []
+    for tab in (table({**old, **kept}, "with"), table(kept, "without")):
+        assert tab.get("cpu", "coll_all_reduce", "float32",
+                       1 << 20, 8) == 0.123
+        cm = OpCostModel(spec)
+        cm.attach_calibration(MeshCalibration(backend="cpu", table=tab))
+        assert cm.xfer_cost(1 << 20, "all_reduce", 8) \
+            == pytest.approx(0.123)
+        costs.append(cm.op_cost(attn, {0: 4}))
+    assert costs[0] == costs[1]
+    tab = table(old, "stale")
+    assert tab.mark_stale(list(old)) == len(old)
+    assert tab.remeasure_stale(DeviceMesh(spec, seq=4)) == {}
+    assert sorted(tab.stale_keys()) == sorted(old)
+
+
 def test_shape_class_buckets():
     assert shape_class(1 << 20) == 1 << 20
     assert shape_class((1 << 20) + 100) == 1 << 20

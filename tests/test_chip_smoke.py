@@ -50,16 +50,13 @@ def test_leg_a_bert_tiny_on_the_cpu_mesh(capsys):
 
 
 def test_leg_b_gpt2_tiny_on_the_cpu_mesh(capsys):
-    """(Its second half, the fused update inside the step, is TPU-only
-    by predicate; tests/test_kernel_tier.py drives that path on the CPU
-    mesh through the executor.)"""
     n = chip_smoke.leg_gpt2_kernels(_one_layer(GPTConfig.tiny()), seq=16,
                                     per_chip_batch=1)
     out = capsys.readouterr().out
     assert n == 0                                   # cpu: interpret mode
     assert "B/gpt2: resolved attention impl ['xla']" in out   # cpu: auto
     assert "B/generate: 4 tokens after a 8-token prompt" in out
-    assert "B/fused-adam: " in out and "leaves match" in out
+    assert "kernel plan none" in out                # nothing forced
 
 
 def test_leg_c_latent_moe_tiny_on_the_cpu_mesh(capsys):

@@ -1,6 +1,6 @@
-"""Searchable kernel tier (kernels/registry.py): forcing flags,
-availability predicates, fused-optimizer parity, and the per-op impl
-dimension in the cost model."""
+"""Which kernel an op runs: the forcing flags and their availability
+predicates (kernels/registry.py), the attention ops' own `auto` rule,
+and a search that prices nothing by implementation."""
 import os
 
 import jax
@@ -24,8 +24,8 @@ def test_parse_forced_rejects_typos():
     with pytest.raises(ValueError, match="<op>:<impl>"):
         kreg.parse_forced("flash")
     assert kreg.parse_forced("auto") == {}
-    assert kreg.parse_forced("attention:ring,opt_update:fused") \
-        == {"attention": "ring", "opt_update": "fused"}
+    assert kreg.parse_forced("attention:ring, attention:xla") \
+        == {"attention": "xla"}
 
 
 def test_forcing_precedence_config_env(monkeypatch):
@@ -42,9 +42,9 @@ def test_forcing_precedence_config_env(monkeypatch):
 
 def test_kernel_impl_cli_flag_accumulates():
     cfg = FFConfig.parse_args(["--kernel-impl", "attention:flash",
-                               "--kernel-impl", "opt_update:fused"])
-    assert kreg.parse_forced(cfg.kernel_impls) \
-        == {"attention": "flash", "opt_update": "fused"}
+                               "--kernel-impl", "attention:ring"])
+    assert cfg.kernel_impls == "attention:flash,attention:ring"
+    assert kreg.parse_forced(cfg.kernel_impls) == {"attention": "ring"}
 
 
 # ---------------------------------------------------------------------------
@@ -74,25 +74,140 @@ def test_flash_predicate_rejects_causal_cross_attention():
     assert kreg.get_impl("attention", "flash").available(ctx) is None
 
 
-def test_available_impls_default_first():
+def test_every_impl_is_available_to_self_attention_on_a_seq_axis():
     ctx = kreg.attention_ctx({"embed_dim": 64, "num_heads": 4},
                              128, 128, seq_degree=4)
-    names = kreg.available_impls(kreg.ATTENTION, ctx)
-    assert names[0] == "xla" and set(names) == {"xla", "flash", "ring"}
+    names = kreg.impl_names(kreg.ATTENTION)
+    assert names == ["xla", "flash", "ring"]
+    assert [kreg.get_impl(kreg.ATTENTION, n).available(ctx)
+            for n in names] == [None, None, None]
+    # latent attention has the first two only, whatever the mesh
+    ctx = kreg.attention_ctx({}, 128, 128, seq_degree=4, latent=True)
+    assert [kreg.get_impl(kreg.ATTENTION, n).available(ctx) is None
+            for n in names] == [True, True, False]
 
 
-def test_forced_ring_without_seq_axis_rejected_at_compile():
-    """The acceptance fixture's compile-time analog: a forced-`ring`
-    plan on a mesh with no sequence axis fails TYPED with the op
-    attributed — never silently falls back to xla."""
+def _attention_model(kind, cfg, causal=True):
+    """One attention layer of ``kind`` (or the tiny latent model), not
+    yet compiled: ``(ff, compile)``."""
+    ff = FFModel(cfg)
+    if kind == "multi-head":
+        q = ff.create_tensor((2, 64, 64), name="q")
+        ff.multihead_attention(q, q, q, embed_dim=64, num_heads=4,
+                               causal=causal)
+        return ff, lambda: ff.compile(SGDOptimizer(0.01),
+                                      "mean_squared_error", [])
+    from flexflow_tpu.models.nlp import LatentMoEConfig, build_latent_moe
+    out = build_latent_moe(ff, 2, 32, LatentMoEConfig.tiny())
+    return ff, lambda: ff.compile(
+        SGDOptimizer(0.01), "sparse_categorical_crossentropy", [],
+        output_tensor=out)
+
+
+@pytest.mark.parametrize("kind, seq, refusal", [
+    # the one way to reach ring: forced, on a mesh with a sequence axis
+    ("multi-head", 4, None),
+    # the acceptance fixture's compile-time analog: no sequence axis
+    ("multi-head", 0, "sequence axis"),
+    ("latent", 0, "sequence axis"),
+    # and the latent op has no ring path on any mesh
+    ("latent", 4, "latent attention has no ring path"),
+])
+def test_forced_ring_by_attention_kind_and_mesh(kind, seq, refusal):
+    """A forced ``ring`` is held to its predicate at compile: where it
+    cannot run the failure is TYPED and names the op — never a silent
+    fall back to the op's own rule — and where it can, the traced train
+    step records ring for the layer and one step stays finite."""
+    from flexflow_tpu.ffconst import OperatorType
+    cfg = FFConfig()
+    cfg.batch_size = 2
+    cfg.only_data_parallel = True
+    cfg.seq_parallel_degree = seq
+    cfg.kernel_impls = "attention:ring"
+    ff, compile_ = _attention_model(kind, cfg, causal=False)
+    if refusal is not None:
+        attn = next(l.name for l in ff.layers if l.op_type in (
+            OperatorType.OP_MULTIHEAD_ATTENTION,
+            OperatorType.OP_LATENT_ATTENTION))
+        with pytest.raises(ValueError, match=refusal) as e:
+            compile_()
+        assert str(e.value).startswith(f"{attn}: forced kernel impl "
+                                       f"attention:ring")
+        return
+    compile_()
+    assert ff.dmesh.seq_degree == seq
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(2, 64, 64)).astype(np.float32)
+    hist = ff.fit(x=x, y=np.zeros((2, 64, 64), np.float32), epochs=1,
+                  verbose=False)
+    assert np.isfinite(hist[-1]["loss"])
+    (attn,) = ff.executor.resolved_attention_impls
+    assert ff.executor.resolved_attention_impls == {attn: "ring"}
+    assert ff.strategy.kernel_impls == {"attention": "ring",
+                                        attn: "ring"}
+
+
+def test_forced_flash_on_causal_cross_attention_rejected_at_compile():
+    """The flash predicate at compile: the kernel has no causal mask for
+    ``q_len != kv_len``, and a forced choice says so with the op's
+    name."""
     cfg = FFConfig()
     cfg.only_data_parallel = True
-    cfg.kernel_impls = "attention:ring"
+    cfg.kernel_impls = "attention:flash"
     ff = FFModel(cfg)
-    q = ff.create_tensor((2, 64, 64), name="q")
-    ff.multihead_attention(q, q, q, embed_dim=64, num_heads=4)
-    with pytest.raises(ValueError, match="sequence axis"):
+    q = ff.create_tensor((2, 32, 64), name="q")
+    kv = ff.create_tensor((2, 64, 64), name="kv")
+    ff.multihead_attention(q, kv, kv, embed_dim=64, num_heads=4,
+                           causal=True)
+    with pytest.raises(ValueError, match="causal cross-attention") as e:
         ff.compile(SGDOptimizer(0.01), "identity", [])
+    assert "forced kernel impl attention:flash" in str(e.value)
+
+
+@pytest.mark.parametrize("where", ["forced spec", "imported strategy"])
+def test_an_opt_update_kind_is_a_typed_error(where, tmp_path,
+                                             monkeypatch):
+    """There is no optimizer-update kernel to choose any more: a spec
+    or a strategy file that still names the kind is refused with the
+    kind's name, at compile and in the verifier, not ignored."""
+    from flexflow_tpu.analysis.plan_verifier import PlanVerificationError
+    from flexflow_tpu.search.serialization import save_strategy
+    monkeypatch.delenv("FF_KERNEL_IMPL", raising=False)
+    cfg = FFConfig()
+    cfg.only_data_parallel = True
+    if where == "forced spec":
+        cfg.kernel_impls = "attention:flash,opt_update:fused"
+        ff, compile_ = _attention_model("multi-head", cfg)
+        with pytest.raises(ValueError,
+                           match="unknown kernel op 'opt_update'"):
+            compile_()
+        return
+    ff, compile_ = _attention_model("multi-head", cfg)
+    compile_()
+    ff.strategy.kernel_impls = {"opt_update": "fused"}
+    path = str(tmp_path / "strat.json")
+    save_strategy(path, ff.strategy, {})
+    cfg2 = FFConfig()
+    cfg2.import_strategy_file = path
+    ff2, compile2 = _attention_model("multi-head", cfg2)
+    with pytest.raises(PlanVerificationError,
+                       match="unknown kernel op kind 'opt_update'"):
+        compile2()
+
+
+@pytest.mark.parametrize("policy", ["off", "none"])
+def test_off_ignores_a_forced_spec(policy, monkeypatch):
+    """``off`` / ``none`` keep meaning "ignore what is forced", the
+    environment's spec included: no plan, no record, the rule's path."""
+    monkeypatch.setenv("FF_KERNEL_IMPL", "attention:flash")
+    cfg = FFConfig()
+    cfg.only_data_parallel = True
+    cfg.kernel_impls = policy
+    ff, compile_ = _attention_model("multi-head", cfg)
+    compile_()
+    assert ff.strategy.kernel_impls == {}
+    assert not hasattr(ff, "_kernel_record")
+    assert ff.executor._kernel_impls == {}
 
 
 def test_forced_flash_plans_and_trains():
@@ -123,34 +238,57 @@ def test_forced_attention_reaches_every_attention_op(kind, impl):
     the kind key, every attention layer of the traced train step
     records the forced impl, and a step forced onto XLA holds no Mosaic
     call."""
-    from flexflow_tpu.ffconst import OperatorType
-    from flexflow_tpu.search.optimizer import _synth_batch
     cfg = FFConfig()
     cfg.only_data_parallel = True
     cfg.kernel_impls = f"attention:{impl}"
-    ff = FFModel(cfg)
-    if kind == "multi-head":
-        q = ff.create_tensor((2, 64, 64), name="q")
-        ff.multihead_attention(q, q, q, embed_dim=64, num_heads=4,
-                               causal=True)
-        ff.compile(SGDOptimizer(0.01), "identity", [])
-    else:
-        from flexflow_tpu.models.nlp import (LatentMoEConfig,
-                                             build_latent_moe)
-        out = build_latent_moe(ff, 2, 32, LatentMoEConfig.tiny())
-        ff.compile(SGDOptimizer(0.01), "sparse_categorical_crossentropy",
-                   [], output_tensor=out)
+    ff, compile_ = _attention_model(kind, cfg)
+    compile_()
     assert ff.strategy.kernel_impls["attention"] == impl
+    attn, lowered = _attention_layers_and_lowered_step(ff)
+    assert attn and ff.executor.resolved_attention_impls \
+        == dict.fromkeys(attn, impl)
+    # every attention layer was held to the predicate and is in the
+    # audit-visible record, of whichever kind
+    assert {o["name"] for o in ff._kernel_record["ops"]} == attn
+    if impl == "xla":
+        assert "tpu_custom_call" not in lowered.as_text()
+
+
+def _attention_layers_and_lowered_step(ff):
+    from flexflow_tpu.ffconst import OperatorType
+    from flexflow_tpu.search.optimizer import _synth_batch
     step = ff.executor.make_train_step().__wrapped__
     lowered = step.lower(ff.params, ff.opt_state, ff.state, jnp.int32(0),
                          _synth_batch(ff))
-    attn = {l.name for l in ff.layers if l.op_type in (
+    return {l.name for l in ff.layers if l.op_type in (
         OperatorType.OP_MULTIHEAD_ATTENTION,
-        OperatorType.OP_LATENT_ATTENTION)}
-    assert attn and ff.executor.resolved_attention_impls \
-        == dict.fromkeys(attn, impl)
-    if impl == "xla":
-        assert "tpu_custom_call" not in lowered.as_text()
+        OperatorType.OP_LATENT_ATTENTION)}, lowered
+
+
+@pytest.mark.parametrize("seq", [0, 4])
+def test_calibration_on_and_nothing_forced_adopts_no_plan(seq,
+                                                          monkeypatch):
+    """With measured calibration asked for (``calibration_v2``) and
+    nothing forced, on the 8-device mesh with and without a sequence
+    axis: compile prices nothing and adopts no kernel plan, and the
+    traced step runs what the op's own rule says (on the CPU platform:
+    XLA; never ring, which only a forced choice reaches)."""
+    monkeypatch.delenv("FF_KERNEL_IMPL", raising=False)
+    cfg = FFConfig()
+    cfg.only_data_parallel = True
+    cfg.calibration_v2 = "true"
+    cfg.seq_parallel_degree = seq
+    ff, compile_ = _attention_model("multi-head", cfg)
+    compile_()
+    assert ff.dmesh.seq_degree == max(seq, 1)
+    assert ff.strategy.kernel_impls == {}
+    assert ff.executor._kernel_impls == {}
+    assert not hasattr(ff, "_kernel_record")
+    attn, _ = _attention_layers_and_lowered_step(ff)
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp as mha
+    assert not mha._flash_enabled(None, 64, 64, 16, 16, causal=True)
+    assert ff.executor.resolved_attention_impls \
+        == dict.fromkeys(attn, "xla")
 
 
 # ---------------------------------------------------------------------------
@@ -174,79 +312,7 @@ def test_kernel_impls_roundtrip_through_strategy_file(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# fused optimizer update: bit-parity with AdamOptimizer.update
-# ---------------------------------------------------------------------------
-
-def test_fused_adam_update_matches_unfused_bitwise():
-    from flexflow_tpu.runtime.optimizers import (AdamOptimizer,
-                                                 fused_adam_tree_update)
-    opt = AdamOptimizer(alpha=1e-3, beta1=0.9, beta2=0.999,
-                        weight_decay=0.01, epsilon=1e-8)
-    rng = np.random.default_rng(0)
-    # ragged leaf sizes exercise the kernel's lane padding
-    params = {"w1": jnp.asarray(rng.standard_normal((33, 17)),
-                                jnp.float32),
-              "w2": jnp.asarray(rng.standard_normal((5,)), jnp.float32)}
-    grads = jax.tree.map(
-        lambda w: jnp.asarray(rng.standard_normal(w.shape), w.dtype),
-        params)
-    state = opt.init_state(params)
-    step = jnp.asarray(3, jnp.int32)
-    p_ref, s_ref = opt.update(params, grads, state, step)
-    p_fus, s_fus = fused_adam_tree_update(opt, params, grads, state,
-                                          step)
-    for k in params:
-        np.testing.assert_allclose(np.asarray(p_fus[k]),
-                                   np.asarray(p_ref[k]),
-                                   rtol=1e-6, atol=1e-7, err_msg=k)
-        np.testing.assert_allclose(np.asarray(s_fus["m"][k]),
-                                   np.asarray(s_ref["m"][k]),
-                                   rtol=1e-6, atol=1e-7)
-        np.testing.assert_allclose(np.asarray(s_fus["v"][k]),
-                                   np.asarray(s_ref["v"][k]),
-                                   rtol=1e-6, atol=1e-7)
-
-
-def test_forced_fused_update_runs_even_when_the_guard_built_the_step(
-        monkeypatch):
-    """The floor guard compiles and runs the train step before the
-    kernel plan exists. Found on the chip (where the guard is on by
-    default): the adopted executor kept replaying that unfused trace,
-    so a forced ``opt_update:fused`` never ran."""
-    import dataclasses
-
-    from flexflow_tpu import AdamOptimizer
-    from flexflow_tpu.kernels import registry as kreg
-    from flexflow_tpu.runtime import optimizers as opt_mod
-    monkeypatch.setitem(
-        kreg.REGISTRY[kreg.OPT_UPDATE], "fused", dataclasses.replace(
-            kreg.REGISTRY[kreg.OPT_UPDATE]["fused"],
-            predicate=lambda ctx: None))   # let the CPU interpret it
-    calls = []
-    real = opt_mod.fused_adam_tree_update
-    monkeypatch.setattr(
-        opt_mod, "fused_adam_tree_update",
-        lambda *a, **k: calls.append(1) or real(*a, **k))
-    cfg = FFConfig()
-    cfg.batch_size = 8
-    cfg.search_budget = 2
-    cfg.search_floor_guard = "true"
-    cfg.kernel_impls = "opt_update:fused"
-    ff = FFModel(cfg)
-    x = ff.create_tensor((8, 64), name="x")
-    out = ff.dense(ff.dense(x, 64), 10)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    assert "adopted" in ff._floor_guard_record and not calls
-    rng = np.random.default_rng(0)
-    ff.fit(x=rng.normal(size=(8, 64)).astype(np.float32),
-           y=rng.integers(0, 10, size=(8, 1)).astype(np.int32),
-           epochs=1, verbose=False)
-    assert calls, "the step that trained never traced the fused update"
-
-
-# ---------------------------------------------------------------------------
-# cost model: the per-op impl dimension
+# the search: no implementation dimension
 # ---------------------------------------------------------------------------
 
 def _attn_layer(b=4, s=2048, e=512, h=8):
@@ -260,44 +326,43 @@ def _attn_layer(b=4, s=2048, e=512, h=8):
     return l
 
 
-def test_op_cost_with_impl_scores_and_records_argmin():
+# the parent's totals for this graph with no kernel tier attached
+# (PR 47: ``git archive`` of ea08918, the same lines), which is what the
+# search priced in every benchmark cell: an attention layer costs
+# ``op_cost`` whatever implementations exist
+@pytest.mark.parametrize("search, total", [
+    ("mcmc", 6.505876395604396e-05),
+    ("unity", 1.6533643956043958e-05),
+])
+def test_the_search_prices_attention_with_op_cost(search, total):
     from flexflow_tpu.parallel.machine import DeviceMesh, MachineSpec
     from flexflow_tpu.search.costmodel import OpCostModel
-    dm = DeviceMesh(MachineSpec.detect(), seq=4)
-    cm = OpCostModel(dm.spec)
-    layer = _attn_layer()
-    base = cm.op_cost(layer, {}, 1)
-    # no tier attached: op_cost_with_impl is op_cost, nothing recorded
-    assert cm.op_cost_with_impl(layer, {}, 1).forward_time \
-        == base.forward_time
-    assert cm.last_kernel_impl is None
-    cm.attach_kernel_tier(dm)
-    scored = cm.op_cost_with_impl(layer, {}, 1)
-    assert cm.last_kernel_impl in ("xla", "flash", "ring")
-    assert cm.kernel_choice["attn0"] == cm.last_kernel_impl
-    assert scored.forward_time + scored.backward_time \
-        <= base.forward_time + base.backward_time + 1e-12
-    # forcing pins the argmin
-    cm.attach_kernel_tier(dm, forced={"attention": "xla"})
-    cm.op_cost_with_impl(layer, {}, 1)
-    assert cm.last_kernel_impl == "xla"
-
-
-def test_kernel_impl_cost_orders_long_context():
-    """At long context the analytic tier must order ring < flash < xla
-    (the score-matrix traffic xla re-reads dominates; ring amortizes it
-    over the seq axis)."""
-    from flexflow_tpu.parallel.machine import DeviceMesh, MachineSpec
-    from flexflow_tpu.search.costmodel import OpCostModel
-    dm = DeviceMesh(MachineSpec.detect(), seq=4)
-    cm = OpCostModel(dm.spec)
-    layer = _attn_layer(b=4, s=8192, e=512, h=8)
-    t = {}
-    for name in ("xla", "flash", "ring"):
-        m = cm.kernel_impl_cost(layer, "attention", name, {}, 1,
-                                seq_degree=4 if name == "ring" else 0)
-        t[name] = m.forward_time + m.backward_time
-    assert t["ring"] < t["flash"] < t["xla"]
+    cfg = FFConfig()
+    cfg.batch_size = 8
+    ff = FFModel(cfg)
+    q = ff.create_tensor((8, 256, 64), name="q")
+    out = ff.multihead_attention(q, q, q, embed_dim=64, num_heads=4)
+    dmesh = DeviceMesh(MachineSpec(num_devices=8), seq=2)
+    cm = OpCostModel(dmesh.spec)
+    if search == "mcmc":
+        from flexflow_tpu.search.mcmc import (StrategySimulator,
+                                              data_parallel_assignment)
+        sim = StrategySimulator(ff.layers, dmesh, cm)
+        gc, entries = sim.evaluate_breakdown(
+            data_parallel_assignment(ff.layers, dmesh, sim.options))
+    else:
+        from flexflow_tpu.search.unity import (GraphCostEvaluator,
+                                               data_parallel_graph)
+        g = data_parallel_graph(ff.layers, ff.input_tensors, [out], dmesh)
+        gc, entries = GraphCostEvaluator(cm, dmesh).graph_cost_breakdown(g)
+    assert gc.total == pytest.approx(total, rel=1e-12)
+    (e,) = [e for e in entries
+            if e["op_type"] == "OP_MULTIHEAD_ATTENTION"]
+    priced = cm.op_cost(ff.layers[-1], {0: 8}, 1)
+    assert (e["fwd_s"], e["bwd_s"]) \
+        == (priced.forward_time, priced.backward_time)
+    assert gc.compute == priced.forward_time + priced.backward_time
+    assert "kernel_impl" not in e
 
 
 def test_ring_plan_fits_an_envelope_the_unsharded_plan_fails():
@@ -305,8 +370,7 @@ def test_ring_plan_fits_an_envelope_the_unsharded_plan_fails():
     live attention tensor is a 1/seq-degree chunk. Same context, same
     mesh, an HBM budget between the two plans' static envelopes: the
     verifier rejects the forced-XLA plan with a typed memory finding and
-    passes the ring plan (the smoke, tools/kernel_tier_smoke.py, shows
-    the searched tier adopting ring here and the plan training)."""
+    passes the ring plan."""
     from flexflow_tpu.analysis.plan_verifier import (memory_envelope,
                                                      verify_plan)
     b, s, e, h = 4, 2048, 512, 8

@@ -11,7 +11,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flexflow_tpu.kernels import (flash_attention, mha_reference,
-                                  ring_attention, ulysses_attention)
+                                  ring_attention)
 from flexflow_tpu.obs import events
 from jax import shard_map
 
@@ -109,26 +109,8 @@ def test_ring_attention_gradients(causal):
                                    atol=5e-4, rtol=5e-4, err_msg=name)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_ulysses_attention_matches_reference(causal):
-    mesh = _seq_mesh()
-    q, k, v = _rand_qkv(b=1, h=4, s=128, d=32)
-
-    fn = shard_map(
-        functools.partial(ulysses_attention, axis_name="sp", causal=causal,
-                          interpret=True),
-        mesh=mesh,
-        in_specs=(P(None, None, "sp", None),) * 3,
-        out_specs=P(None, None, "sp", None),
-        check_vma=False)  # pallas_call outputs carry no vma info
-    out = jax.jit(fn)(q, k, v)
-    ref = mha_reference(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
 # ---------------------------------------------------------------------------
-# kernel-tier numerics satellites: ragged lengths, GQA head layouts,
+# kernel numerics satellites: ragged lengths, GQA head layouts,
 # ring at both supported seq degrees
 # ---------------------------------------------------------------------------
 def test_flash_ragged_cross_lengths_match_reference():

@@ -180,14 +180,20 @@ def test_envelope_gate_binds_between_sharded_and_replicated(ff, plan):
         f.message for f in e.value.findings)
 
 
-def test_kv_seq_shard_scored_on_seq_mesh(ff, cost_model):
+def test_kv_seq_shard_scored_on_seq_mesh(ff):
     """On a sequence-axis mesh, long-context buckets adopt seq-sharded
     KV: per-device cache bytes drop by the seq degree and the decode
     step picks up the per-token partial-output combine. A flat mesh
-    never scores the option."""
+    never scores the option. Priced on the machine model's own link
+    constants (``cpu-sim``: 5 GB/s, 1 us a hop), not on the module's
+    model, whose constants are a timed all-reduce of this host: the
+    assertion is about the rule."""
     from flexflow_tpu.parallel.machine import DeviceMesh
+    from flexflow_tpu.search.costmodel import OpCostModel
     from flexflow_tpu.search.serving_plan import \
         serving_baseline_assignment
+    cost_model = OpCostModel(ff.dmesh.spec)
+    assert cost_model.coll_bw is None and cost_model.coll_lat is None
     dm = DeviceMesh(ff.dmesh.spec, seq=4)
     assert dm.seq_degree == 4
     long_seq = 4096

@@ -19,8 +19,6 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.kernels import flash_attention
-from flexflow_tpu.runtime.optimizers import (AdamOptimizer,
-                                             fused_adam_tree_update)
 
 
 @pytest.fixture(scope="module")
@@ -56,23 +54,6 @@ def _flash_loss(mesh, spec, q, k, v):
     o = flash_attention(q, k, v, causal=True, interpret=False, mesh=mesh,
                         spec=spec)
     return jnp.sum(o.astype(jnp.float32))
-
-
-def _adam_step(mesh, pspecs, sspecs, params, grads, state):
-    return fused_adam_tree_update(
-        AdamOptimizer(1e-3), params, grads, state, jnp.int32(1),
-        mesh=mesh, param_specs=pspecs, state_specs=sspecs,
-        interpret=False)
-
-
-def _adam_operands(mesh, shape, pspec, sspec=None):
-    w = jax.ShapeDtypeStruct(shape, jnp.float32,
-                             sharding=NamedSharding(mesh, pspec))
-    s = jax.ShapeDtypeStruct(
-        shape, jnp.float32,
-        sharding=NamedSharding(mesh, sspec if sspec is not None
-                               else pspec))
-    return {"w": w}, {"w": w}, {"m": {"w": s}, "v": {"w": s}}
 
 
 def _kernel_names(txt):
@@ -358,27 +339,6 @@ def test_flash_without_shard_map_is_refused_on_2x2(v5e_devices):
     with pytest.raises(NotImplementedError, match="shard_map"):
         _compile_text(functools.partial(_flash_loss, None, None),
                       qkv, qkv, qkv)
-
-
-def test_fused_adam_under_shard_map_compiles_on_2x2(v5e_devices):
-    mesh = Mesh(np.array(v5e_devices).reshape(2, 2), ("x0", "x1"))
-    # tensor-parallel weight whose moments are ZeRO-sharded as well
-    pspec, sspec = P(None, "x1"), P("x0", "x1")
-    txt = _compile_text(
-        functools.partial(_adam_step, mesh, {"w": pspec}, {"w": sspec}),
-        *_adam_operands(mesh, (1024, 4096), pspec, sspec))
-    assert MOSAIC_CALL in txt
-    assert "all-gather" in txt        # the new weight returns to pspec
-
-
-@pytest.mark.parametrize("shape", [(1024, 4096), (30522, 1024),
-                                   (1024, 16, 64), (1024,)])
-def test_fused_adam_compiles_for_bert_large_leaves(v5e_devices, shape):
-    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
-    txt = _compile_text(
-        functools.partial(_adam_step, None, None, None),
-        *_adam_operands(mesh, shape, P()))
-    assert MOSAIC_CALL in txt
 
 
 # ----------------------------------------------------------------------

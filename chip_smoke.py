@@ -55,6 +55,18 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          stream tensor, and prints the ``mhc.*`` counters; it
          looks at no gradient: ``examples/tpu_validate_mhc_latent_moe.
          py`` does.
+  Leg G  grouped-query attention over the keys a learned indexer
+         selects, trained by its alignment loss, and softmax 8-of-128
+         routing (``build_hybrid_conv_moe`` with ``"sparse_attention"``
+         layers): a small model, then one chip's share of
+         Keye-VL-2.0-30B-A3B at published widths, 1 x 8192 tokens a
+         chip, rematerialised as its benchmark cell is (every block
+         holds a layer with an auxiliary loss). It checks that every
+         layer announced its indexer (``attn.sparse_index``), stayed on
+         XLA and is kept by its block, and that the ``dsa.*`` counters
+         give the share of the causal pairs that ``topk`` and the length
+         do; it looks at no selection and no gradient:
+         ``examples/tpu_validate_sparse_index_moe.py`` does.
 
 It claims no speed. The times it prints are set-up facts of one run.
 It exits non-zero, before building anything, unless JAX reports a TPU;
@@ -66,6 +78,7 @@ at tiny widths on the CPU mesh, where the chip-only checks do not apply.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import sys
 import time
@@ -835,6 +848,59 @@ def leg_mhc_latent_moe(model_cfg, seq: int, per_chip_batch: int,
           f"{len(maps)} sub-layers whose shapes say {want}")
 
 
+# ----------------------------------------------------------------------
+# Leg G — attention over the keys a learned indexer selects
+# ----------------------------------------------------------------------
+VALIDATION_SPARSE = "examples/tpu_validate_sparse_index_moe.py"
+
+
+def leg_sparse_index_moe(model_cfg, seq: int, per_chip_batch: int,
+                         label: str, alpha: float = 1e-5) -> None:
+    """``build_hybrid_conv_moe`` with ``"sparse_attention"`` layers
+    through compile and fit with ``remat = "blocks"``: the loss (the
+    cross-entropy plus every layer's alignment loss, which leaves the
+    rematerialised blocks as their output) falls, every layer announced
+    its indexer and stayed on XLA, the share of the causal pairs kept is
+    what ``topk`` and ``seq`` give, each block keeps its attention
+    layer's output and nothing else, nothing was dropped, and the step
+    fits the chip. ``VALIDATION_SPARSE`` holds the selection and the
+    gradients to the reference, and this leg names it."""
+    from flexflow_tpu.models.nlp import build_hybrid_conv_moe
+    from flexflow_tpu.obs import events
+    ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    layers = [f"attn_{i}" for i in range(model_cfg.num_hidden_layers)]
+    seen = {e["attrs"]["layer"]: e["attrs"] for e in events.events()
+            if e["name"] == "attn.sparse_index"}
+    kept = sorted({e["attrs"]["layer"] for e in events.events()
+                   if e["name"] == "remat.kept"})
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: attn.sparse_index {seen.get('attn_0')} in "
+        f"{sorted(seen)}; resolved {sorted(set(impls.values()))}; the "
+        f"rematerialised run {ff.executor._remat[:3]} keeps {kept}")
+    check(sorted(seen) == layers and set(impls.values()) == {"xla"}
+          and kept == layers,
+          f"{label}: {layers} should each announce an indexer, stay on "
+          f"XLA and be kept by its block: {sorted(seen)}, {impls}, {kept}")
+    ctr = events.counters()
+    topk = model_cfg.sa_config["topk"]
+    want = sum(min(t + 1, topk) for t in range(seq)) / (seq * (seq + 1) / 2)
+    share = ctr.get("dsa.kept_pairs", 0) / max(
+        1.0, ctr.get("dsa.causal_pairs", 0))
+    index_kl = ctr.get("dsa.index_kl", 0) / max(1.0, ctr.get("dsa.layers", 0))
+    say(f"{label}: kept {share:.6f} of the causal pairs (topk {topk} of "
+        f"{seq}: {want:.6f}), mean L_I {index_kl:.4f}, rows tied at the "
+        f"threshold {ctr.get('dsa.threshold_ties')}")
+    check(abs(share - want) < 1e-5 and index_kl > 0,
+          f"{label}: kept {share} of the causal pairs, expected {want}; "
+          f"mean L_I {index_kl}")
+    _check_experts_counters(label)
+    say(f"{label}: not checked here: the selection and the gradients "
+        f"against the reference: python3 {VALIDATION_SPARSE}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def _check_generate(ff, ids) -> None:
     """KV-cache decode against the re-forward path on one prompt.
 
@@ -905,6 +971,7 @@ def main() -> int:
     from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
                                          HybridConvMoEConfig,
                                          JoyAIFlashRankConfig,
+                                         KeyeRankConfig,
                                          KimiLinearRankConfig,
                                          LatentMoEConfig, LFM2RankConfig,
                                          XingRankConfig)
@@ -936,6 +1003,14 @@ def main() -> int:
         leg_mhc_latent_moe(XingRankConfig.tiny(), 1024, 1, "F/small",
                            alpha=1e-3)
         leg_mhc_latent_moe(XingRankConfig(), 4096, 1, "F/xing")
+        # chunks of 128 queries keeping 256 keys: tiny()'s chunks of 16
+        # are 64 chunk shapes a layer at 1024 positions, a 6-minute compile
+        leg_sparse_index_moe(dataclasses.replace(
+            KeyeRankConfig.tiny(), sa_config=dict(
+                KeyeRankConfig.tiny().sa_config, q_chunk_size=128,
+                kv_chunk_size=128, topk=256)), 1024, 1, "G/small",
+            alpha=1e-3)
+        leg_sparse_index_moe(KeyeRankConfig(), 8192, 1, "G/keye")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -1,0 +1,339 @@
+"""On-chip validation of the sparse-attention mixture-of-experts decoder
+at published widths (run on a real TPU): what the benchmark's
+``reference`` check cannot see, and the readings its tolerance is set
+from.
+
+    python3 examples/tpu_validate_sparse_index_moe.py [--seeds 1 2 3]
+        [--seq 8192] [--load-seeds 4800101 ...] [--skip-forward]
+        [--skip-gradients]
+
+The model is ``benchmarks/configs/keye_vl2_30b_a3b.json`` through the
+normal path (``FFModel`` -> ``build_hybrid_conv_moe`` -> ``compile``),
+the reference ``benchmarks/reference/sparse_index_moe_ref.py`` (float32,
+``highest``), both at the same weights drawn from each seed. There is no
+kernel to check: the layer is plain XLA. Checks (each prints PASS/FAIL,
+exit code 1 on any failure):
+
+  1. per seed at one sequence of ``--seq`` positions: the head's
+     log-probabilities against the reference (``|sys - ref|_2 /
+     |ref|_2``, the runner's measure), the eval-mode loss with its four
+     ``L_I`` (the band's reading), and the share of the causal pairs the
+     layers kept (0.4375 at 8192);
+  2. what a lower precision would read, by the same measure, from the
+     reference itself with its products' operands rounded
+     (``rounded_operands``): bf16 everywhere but the routers (the
+     configuration's stated precision), bf16 in the routers too, and an
+     8-bit float (e4m3) everywhere but the routers. The tolerance has to
+     lie over the first and under the last;
+  3. the selection, layer by layer from the layer's OWN input as the
+     program computed it: of the (query, key) pairs the reference
+     selects (float32 index scores, ``jax.lax.top_k``) for the queries
+     that have more than ``topk`` causal keys, the share the program's
+     threshold search over its bf16-operand scores selects too, beside
+     what the reference's own scores read with bf16 and e4m3 operands.
+     With random weights the indexer's order says nothing about a key's
+     attention weight, so a flip at the threshold trades one key of 2048
+     for another of the same expected weight;
+  4. at 4096 positions (half the queries select; the reference's layers
+     under ``jax.checkpoint`` so that its backward fits): the loss and
+     its gradient for attention's ``wq`` and ``q_norm``, a router and one
+     held expert (the cross-entropy's) and for the indexer's three
+     matrices (``L_I``'s), against ``jax.grad`` of the reference's loss,
+     each held to twice what the reference itself reads with bf16
+     operands; each expert layer's row budget beside what its router
+     sent this share. ``correct`` sees no gradient;
+  5. ``--load-seeds``: at each seed's weights, every expert layer's rows
+     bound for the 16 held experts against its budget of 16384 (the
+     acceptance's "quiet before it is offered": no layer over at any
+     seed).
+"""
+import argparse
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks.harness import cells  # noqa: E402
+# the other configurations' validation has the helpers: PASS/FAIL lines,
+# the runner's measure, the model through the normal path, its batch
+from examples.tpu_validate_latent_moe import (  # noqa: E402
+    BENCH, FAILED, READINGS, batch_of, build, check, check_budget,
+    expert_layers, l2, named, program_grads, rel)
+from flexflow_tpu.ops import sparse_attention as dsa  # noqa: E402
+from flexflow_tpu.ops.moe_ops import RoutedExpertsOp  # noqa: E402
+from flexflow_tpu.runtime.metrics import COUNTER_PREFIX  # noqa: E402
+
+ROUNDED = (("bf16, routers float32", dict(matmul=jnp.bfloat16)),
+           ("bf16, routers too", dict(matmul=jnp.bfloat16,
+                                      router=jnp.bfloat16)),
+           ("float8_e4m3, routers float32",
+            dict(matmul=jnp.float8_e4m3fn)))
+
+
+def attention_layers(ff):
+    return [l for l in ff.executor.program.layers
+            if l.params.get("indexer_heads")]
+
+
+def selection_agreement(ff, ref, params, capture, sizes):
+    """A layer -> {precision: share}: of the pairs the reference selects
+    from the layer's own input, for the rows that select at all, the
+    share selected at each lower precision too."""
+    sa = sizes["sa_config"]
+    topk, q_chunk = sa["topk"], sa["q_chunk_size"]
+    out = {}
+    for layer in attention_layers(ff):
+        x = capture[layer.inputs[0].guid].astype(jnp.float32)
+        w = params[layer.name]
+        s = x.shape[1]
+        rows = jnp.arange(s)
+
+        def reference_set(**kw):
+            with jax.default_matmul_precision("highest"), \
+                    ref.rounded_operands(**kw):
+                qi, ki, wi = ref.indexer(x, w)
+                return jnp.concatenate([
+                    ref.selected(ref.index_scores(
+                        qi[:, lo:lo + ref.QUERY_ROWS], ki,
+                        wi[:, lo:lo + ref.QUERY_ROWS]),
+                        rows[lo:lo + ref.QUERY_ROWS], topk)
+                    for lo in range(0, s, ref.QUERY_ROWS)], 1)
+
+        want = reference_set()
+        selecting = (rows >= topk)[None, :, None]
+        wanted = jnp.sum(want & selecting)
+
+        def share(got):
+            return jnp.sum(got & want & selecting) / jnp.maximum(wanted, 1)
+
+        out[layer.name] = {
+            "program": share(dsa.selection(
+                *dsa.indexer_inputs(x, w, jnp.bfloat16), topk, q_chunk,
+                jnp.bfloat16)),
+            "bf16 operands": share(reference_set(matmul=jnp.bfloat16)),
+            "float8_e4m3 operands": share(
+                reference_set(matmul=jnp.float8_e4m3fn))}
+    return out
+
+
+def forward_checks(conf, ref, seq, seeds):
+    ff = build(conf, seq, "none")
+    sizes = dict(conf)
+
+    @jax.jit
+    def compare(params, batch):
+        ex = ff.executor
+        outs, _, aux, capture = ex._forward(params, ff.state, batch, False,
+                                            jnp.int32(0))
+        loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
+        got = jnp.log(jnp.clip(outs[0], 1e-30))
+        args = (named(ff, params), sizes, batch["input_ids"],
+                batch["position_ids"])
+        want = ref.sparse_index_moe_decoder(*args)
+        out = {"program": rel(got, want), "loss": loss,
+               "index_kl": sum(aux) / len(aux),
+               "kept_share": bm[COUNTER_PREFIX + "dsa.kept_pairs"]
+               / bm[COUNTER_PREFIX + "dsa.causal_pairs"],
+               "threshold_ties": bm[COUNTER_PREFIX + "dsa.threshold_ties"]}
+        for label, kw in ROUNDED:
+            with ref.rounded_operands(**kw):
+                low = ref.sparse_index_moe_decoder(*args)
+            out[label] = rel(low, want)
+            if label == ROUNDED[0][0]:
+                # the program against the reference at its OWN precision
+                out["program, against bf16 reference"] = rel(got, low)
+        return out, selection_agreement(ff, ref, params, capture, sizes)
+
+    tol = conf["reference_rel_tol"]
+    lo, hi = conf["initial_loss_band"]
+    kept = sum(min(t + 1, conf["sa_config"]["topk"]) for t in range(seq)) \
+        / (seq * (seq + 1) / 2)
+    for seed in seeds:
+        ff.params, ff.state = ff.executor.init_params_and_state(
+            jax.random.key(seed))
+        errs, agree = jax.device_get(compare(ff.params,
+                                             batch_of(conf, seq, seed)))
+        errs = {n: float(v) for n, v in errs.items()}
+        agree = {n: {k: float(v) for k, v in a.items()}
+                 for n, a in agree.items()}
+        READINGS[f"seed {seed}"] = dict(errs, selection=agree)
+        print(f"seed {seed}: " + ", ".join(
+            f"{n} {v:.4g}" for n, v in errs.items()), flush=True)
+        for name, a in agree.items():
+            print(f"seed {seed} {name} selected as the reference: "
+                  + ", ".join(f"{k} {v:.5f}" for k, v in a.items()),
+                  flush=True)
+        check(f"seed {seed} within the cell's tolerance",
+              errs["program"] <= tol, f"{errs['program']:.3e} <= {tol}")
+        check(f"seed {seed} as near as bf16 operands allow",
+              errs["program"] <= 2 * errs["bf16, routers float32"],
+              f"{errs['program']:.3e} against "
+              f"{errs['bf16, routers float32']:.3e}")
+        check(f"seed {seed} 8-bit operands would be caught",
+              errs["float8_e4m3, routers float32"] > tol,
+              f"{errs['float8_e4m3, routers float32']:.3e} > {tol}")
+        check(f"seed {seed} loss inside the cell's band",
+              lo <= errs["loss"] <= hi, f"{errs['loss']:.4f} in [{lo}, {hi}]")
+        check(f"seed {seed} kept pairs", abs(errs["kept_share"] - kept) < 1e-6,
+              f"{errs['kept_share']:.6f} against {kept:.6f}")
+        check(f"seed {seed} selects as its precision does",
+              all(a["program"] >= a["bf16 operands"] - 0.01
+                  and a["program"] > a["float8_e4m3 operands"]
+                  for a in agree.values()),
+              "every layer within 0.01 of the reference's own bf16 reading "
+              "and over its e4m3 one")
+    del ff
+
+
+def gradient_checks(conf, ref, seed, seq=4096):
+    ff = build(conf, seq, "blocks")
+    ff.params, ff.state = ff.executor.init_params_and_state(
+        jax.random.key(seed))
+    sizes, batch = dict(conf), batch_of(conf, seq, seed)
+    picked = (("attn_1", "wq"), ("attn_1", "q_norm"), ("experts_2", "wg"),
+              ("experts_2", "w_gate"), ("attn_1", "wq_idx"),
+              ("attn_1", "wk_idx"), ("attn_1", "w_idx"),
+              ("attn_3", "w_idx"))
+
+    def pick(grads):
+        out = {f"{n}.{w}": grads[n][w] for n, w in picked}
+        out["experts_2.w_gate"] = out["experts_2.w_gate"][3]   # one expert
+        return out
+
+    # the reference's layers one at a time in its backward: 32 x 4096^2
+    # probabilities a layer are 2 GiB, and there are four; and in four
+    # blocks of query rows, not sixteen: the host that compiles the
+    # three programs below has 40 GiB (sixteen ended a call at that)
+    whole, rows = ref.decoder_layer, ref.QUERY_ROWS
+    ref.QUERY_ROWS = 1024
+    ref.decoder_layer = lambda *a: jax.checkpoint(
+        lambda *t: whole(*t, a[-1]))(*a[:-1])
+
+    def reference_grads(params):
+        value, grads = jax.value_and_grad(lambda p: ref.loss(
+            named(ff, p), sizes, batch["input_ids"], batch["position_ids"],
+            batch["label"][..., 0]))(params)
+        return value, pick(grads)
+
+    @jax.jit
+    def rounded(params):
+        with ref.rounded_operands(matmul=jnp.bfloat16):
+            return reference_grads(params)
+
+    try:
+        lp, gp, counters = jax.device_get(
+            program_grads(ff, batch, pick)(ff.params))
+        jax.clear_caches()
+        lr, gr = jax.device_get(jax.jit(reference_grads)(ff.params))
+        jax.clear_caches()
+        lb, gb = jax.device_get(rounded(ff.params))
+    finally:
+        ref.decoder_layer, ref.QUERY_ROWS = whole, rows
+    jax.clear_caches()
+    check_budget(ff, seq, counters, False)
+    e = abs(float(lp) - float(lr)) / float(lr)
+    eb = abs(float(lb) - float(lr)) / float(lr)
+    READINGS["loss"] = {"program": float(lp), "reference": float(lr),
+                        "reference, bf16 operands": float(lb)}
+    check("loss (with the four L_I)", e <= 2 * eb + 1e-4,
+          f"{float(lp):.6f} against {float(lr):.6f}: rel {e:.3e}; the "
+          f"reference with bf16 operands reads {eb:.3e}")
+    for name in gp:
+        e, eb = l2(gp[name], gr[name]), l2(gb[name], gr[name])
+        own = l2(gp[name], gb[name])
+        READINGS[f"grad {name}"] = {"program": e,
+                                    "reference, bf16 operands": eb,
+                                    "program against that": own}
+        check(f"gradient {name}", e <= 2 * eb + 1e-3,
+              f"rel {e:.3e}; the reference with bf16 operands reads "
+              f"{eb:.3e}, and the program against THAT {own:.3e}")
+    del ff
+
+
+def load_checks(conf, seq, seeds):
+    """Every expert layer's rows for the held experts, at each seed's
+    weights, against its budget: the counters by layer, which the step's
+    sums over layers do not give."""
+    emit = RoutedExpertsOp.emit
+
+    def by_layer(self, params, inputs, weights, ctx, name):
+        before = dict(ctx.counters)
+        out = emit(self, params, inputs, weights, ctx, name)
+        for key in ("moe.local_assignments", "moe.overflow", "moe.load_max"):
+            ctx.count(f"{key}@{name}", ctx.counters[key] - before.get(key, 0))
+        return out
+
+    RoutedExpertsOp.emit = by_layer
+    try:
+        ff = build(conf, seq, "none")
+
+        @jax.jit
+        def counted(params, batch):
+            ex = ff.executor
+            outs, _, aux, capture = ex._forward(params, ff.state, batch,
+                                                False, jnp.int32(0))
+            _, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
+            return {k[len(COUNTER_PREFIX):]: v for k, v in bm.items()
+                    if "@" in k}
+
+        budgets = {l.name: RoutedExpertsOp.rows_multiplied(seq, l.params)
+                   for l in expert_layers(ff)}
+        worst = 0.0
+        for seed in seeds:
+            ff.params, ff.state = ff.executor.init_params_and_state(
+                jax.random.key(seed))
+            c = {k: float(v) for k, v in jax.device_get(
+                counted(ff.params, batch_of(conf, seq, seed))).items()}
+            rows = {n: c[f"moe.local_assignments@{n}"] for n in budgets}
+            over = [n for n in budgets if c[f"moe.overflow@{n}"]
+                    or rows[n] > budgets[n]]
+            worst = max(worst, max(rows[n] / budgets[n] for n in budgets))
+            READINGS[f"loads, seed {seed}"] = c
+            print(f"seed {seed}: rows for the held experts " + ", ".join(
+                f"{n} {rows[n]:.0f}/{budgets[n]} (largest expert "
+                f"{c[f'moe.load_max@{n}']:.0f})" for n in budgets),
+                flush=True)
+            check(f"seed {seed}: every expert layer inside its budget",
+                  not over, f"over: {over}")
+        READINGS["loads, fullest layer over its budget"] = worst
+        print(f"fullest layer: {worst:.4f} of its budget", flush=True)
+    finally:
+        RoutedExpertsOp.emit = emit
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seeds", type=int, nargs="*", default=[4800001])
+    ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--load-seeds", type=int, nargs="*", default=[])
+    ap.add_argument("--skip-forward", action="store_true")
+    ap.add_argument("--skip-gradients", action="store_true")
+    args = ap.parse_args()
+    if jax.devices()[0].platform != "tpu":
+        print("this validation needs a TPU", file=sys.stderr)
+        return 2
+    from flexflow_tpu.utils.compilation_cache import enable_compilation_cache
+    enable_compilation_cache()
+    with open(os.path.join(BENCH, "configs", "keye_vl2_30b_a3b.json")) as f:
+        conf = json.load(f)
+    ref = cells.load_module(BENCH, "reference", "sparse_index_moe_ref")
+    if args.seeds and not args.skip_forward:
+        forward_checks(conf, ref, args.seq, args.seeds)
+        jax.clear_caches()
+    if args.seeds and not args.skip_gradients:
+        gradient_checks(conf, ref, args.seeds[0])
+    if args.load_seeds:
+        load_checks(conf, args.seq, args.load_seeds)
+    print("READINGS " + json.dumps(READINGS), flush=True)
+    print(f"{len(FAILED)} failed: {FAILED}" if FAILED else "all passed")
+    return 1 if FAILED else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

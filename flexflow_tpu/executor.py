@@ -35,6 +35,7 @@ from .core.tensor import Tensor
 from .dtypes import to_jnp
 from .obs import events as obs_events
 from .ops import EmitCtx, ensure_weight_specs, get_op_def
+from .ops.registry import KEPT_BY_BLOCK
 from .parallel import reshard as reshard_mod
 from .parallel.machine import DeviceMesh
 from .parallel.strategy import ShardingStrategy
@@ -126,12 +127,11 @@ def _emit_scoped(op, layer: Layer, ins, w, ctx):
         return op.emit(layer.params, ins, w, ctx, layer.name)
 
 
-# The one name (``checkpoint_name``) under which ``emit_layers`` marks
-# the outputs of an op that says ``OpDef.keeps_output_for_block``, and
-# the policy by which a rematerialised block that holds such an op keeps
-# them beside its entry (``_emit_remat``). One object for every trace:
-# JAX caches its partial evaluation by the policy.
-KEPT_BY_BLOCK = "ff.kept_by_block"
+# The policy by which a rematerialised block that holds a layer that
+# says ``OpDef.keeps_for_block`` keeps what is marked ``KEPT_BY_BLOCK``
+# beside its entry (``_emit_remat``): ``emit_layers`` marks such a
+# layer's outputs. One object for every trace: JAX caches its partial
+# evaluation by the policy.
 KEEP_MARKED = jax.checkpoint_policies.save_only_these_names(KEPT_BY_BLOCK)
 
 
@@ -241,7 +241,7 @@ class GraphProgram:
                         if cast:
                             pre_cast = reshard_mod.constrain_output(
                                 pre_cast, sh, strategy, layer)
-                if op.keeps_output_for_block:
+                if op.keeps_for_block(layer.params):
                     # the identity but under a rematerialised block's
                     # policy (_emit_remat), which keeps what is so named
                     o = checkpoint_name(o, KEPT_BY_BLOCK)
@@ -402,8 +402,9 @@ def _find_remat_blocks(layers):
     """Block boundaries for ``--remat``: the maximal repeated-block run,
     each block single-input/single-output (beside the graph's own inputs
     and constants, which every block may read: positions, masks),
-    containing no stateful or aux-loss-emitting ops (their side-channel
-    writes cannot cross a ``jax.checkpoint`` boundary). Returns
+    containing no stateful op (a write to ``ctx.new_state`` cannot
+    cross a ``jax.checkpoint`` boundary; device counters and auxiliary
+    losses leave a block as its outputs). Returns
     ``(start, unit, reps, entry_guids, exit_guids)`` or None."""
     from .parallel.pipeline_lowering import (_has_state, chunk_boundaries,
                                              find_repeated_run)
@@ -416,11 +417,7 @@ def _find_remat_blocks(layers):
     reps = total // unit
     layers = list(layers)
     region = layers[start:start + total]
-    # ops whose emit writes ctx side-channels (aux losses / state) cannot
-    # sit inside a jax.checkpoint boundary; AggregateSpec inherits
-    # Aggregate's aux-loss emit
-    aux_ops = {OperatorType.OP_AGGREGATE, OperatorType.OP_AGG_SPEC}
-    if any(_has_state(l) or l.op_type in aux_ops for l in region):
+    if any(_has_state(l) for l in region):
         return None
     entries = chunk_boundaries(layers, start, unit, reps)
     if entries is None:
@@ -1153,7 +1150,7 @@ class Executor:
         block-internal activations are recomputed in the backward pass
         instead of living in HBM for the whole step. A block keeps its
         entry and the outputs of the ops inside it that rematerialise
-        themselves whole (``OpDef.keeps_output_for_block``), each
+        themselves whole (``OpDef.keeps_for_block``), each
         announced by a ``remat.kept`` instant."""
         st = self.strategy if strategy == "__use_own__" else strategy
         start, unit, reps, entries, exits = self._remat
@@ -1170,7 +1167,7 @@ class Executor:
             block = layers[start + b * unit:start + (b + 1) * unit]
             entry_g, exit_g = entries[b], exits[b]
             kept = [l for l in block
-                    if get_op_def(l.op_type).keeps_output_for_block]
+                    if get_op_def(l.op_type).keeps_for_block(l.params)]
 
             def block_fn(x_, p_, _block=block, _entry=entry_g,
                          _exit=exit_g, _b=b, _kept=kept):
@@ -1182,27 +1179,29 @@ class Executor:
                 self._attach_kernel_ctx(bctx)
                 self.program.emit_layers(_block, benv, p_, bctx,
                                          st, None)
-                if bctx.new_state or bctx.aux_losses:
+                if bctx.new_state:
                     raise RuntimeError(
-                        "stateful/aux op inside a rematted block")
+                        "stateful op inside a rematted block")
                 for l in _kept:
                     for o in (benv[t.guid] for t in l.outputs):
                         obs_events.instant(
                             "remat.kept", block=_b, layer=l.name,
                             bytes=o.size * o.dtype.itemsize)
-                # the block's device counters leave it as outputs: a
-                # side channel cannot cross jax.checkpoint
-                return benv[_exit], bctx.counters
+                # the block's device counters and its ops' auxiliary
+                # losses leave it as outputs: a side channel cannot
+                # cross jax.checkpoint
+                return benv[_exit], bctx.counters, bctx.aux_losses
 
             bp = {l.name: params[l.name] for l in block
                   if l.name in params}
             # no policy where there is nothing to keep: JAX keys its
             # partial evaluation on the policy, and such a block's step
             # stays the text it was under a plain jax.checkpoint
-            x, counted = jax.checkpoint(
+            x, counted, aux = jax.checkpoint(
                 block_fn, policy=KEEP_MARKED if kept else None)(x, bp)
             for key, v in counted.items():
                 ctx.count(key, v)
+            ctx.aux_losses.extend(aux)
             env[exit_g] = x
             capture[exit_g] = x
         self.program.emit_layers(layers[start + reps * unit:], env,

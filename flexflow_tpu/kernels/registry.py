@@ -39,6 +39,9 @@ def _attn_flash(ctx: Dict[str, Any]) -> Optional[str]:
     """
     if ctx.get("sliding_window", 0):
         return "flash kernel has no sliding-window mask support"
+    if ctx.get("indexer", False):
+        return "flash kernel takes no mask of selected keys (a layer " \
+               "with a sparse-attention indexer runs on XLA)"
     if ctx.get("causal", False) and \
             ctx.get("q_len", 0) != ctx.get("kv_len", 0):
         return "flash kernel does not mask causal cross-attention " \
@@ -55,6 +58,8 @@ def _attn_ring(ctx: Dict[str, Any]) -> Optional[str]:
     if ctx.get("latent", False):
         return "ring attention is the multi-head op's; latent " \
                "attention has no ring path"
+    if ctx.get("indexer", False):
+        return "ring attention takes no mask of selected keys"
     q_len = int(ctx.get("q_len", 0) or 0)
     kv_len = int(ctx.get("kv_len", 0) or 0)
     if q_len != kv_len:
@@ -117,6 +122,7 @@ def attention_ctx(params: Dict[str, Any], q_len: int, kv_len: int,
         "dropout": float(params.get("dropout", 0.0) or 0.0),
         "seq_degree": int(seq_degree),
         "latent": bool(latent),
+        "indexer": bool(params.get("indexer_heads")),
     }
 
 

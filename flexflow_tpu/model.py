@@ -222,6 +222,7 @@ class FFModel:
                             qk_norm: bool = False,
                             qk_norm_eps: float = 1e-6,
                             positions: Optional[Tensor] = None,
+                            indexer: Optional[dict] = None,
                             name: Optional[str] = None) -> Tensor:
         params = {"embed_dim": embed_dim, "num_heads": num_heads,
                   "kdim": kdim, "vdim": vdim, "dropout": dropout,
@@ -256,6 +257,17 @@ class FFModel:
             # learned scale a projection, before the rotary embedding
             params["qk_norm"] = True
             params["qk_norm_eps"] = float(qk_norm_eps)
+        if indexer:
+            # learned sparse attention (``ops/sparse_attention``): each
+            # query attends the ``topk`` keys its index scores select
+            if not causal or dropout or sliding_window:
+                raise ValueError("an indexer selects among the causal "
+                                 "keys of a layer with no dropout and "
+                                 "no window")
+            for size in ("heads", "head_dim", "topk", "q_chunk"):
+                if int(indexer[size]) < 1:
+                    raise ValueError(f"indexer {size} = {indexer[size]}")
+                params["indexer_" + size] = int(indexer[size])
         inputs = [query, key, value]
         if positions is not None:
             # (batch, seq) int32: what the rotary embedding turns by
@@ -371,10 +383,12 @@ class FFModel:
                        experts_held: Optional[int] = None,
                        first_held: int = 0, scale: float = 1.0,
                        bias_std: float = 0.0, rows_factor: int = 2,
+                       scoring: str = "sigmoid",
                        name: Optional[str] = None) -> Tensor:
         """One sparse, dropless mixture-of-experts feed-forward layer
-        (``ops.moe_ops.RoutedExpertsOp``): sigmoid scores over
-        ``num_experts``, bias-corrected top-``top_k``, SwiGLU experts of
+        (``ops.moe_ops.RoutedExpertsOp``): ``scoring`` (``"sigmoid"``
+        with a bias-corrected choice, or ``"softmax"`` with none) over
+        ``num_experts``, the top ``top_k``, SwiGLU experts of
         width ``expert_dim`` and a shared one of ``shared_dim`` (0: none).
         ``experts_held`` (default: all) and ``first_held`` say which
         experts' weights live here: the layer routes over all of them
@@ -391,7 +405,13 @@ class FFModel:
             raise ValueError(f"top_k {top_k} of {num_experts} experts")
         if rows_factor < 1:
             raise ValueError(f"a row budget of {rows_factor} shares")
+        if scoring not in ("sigmoid", "softmax") \
+                or (scoring == "softmax" and bias_std):
+            raise ValueError(f"scores by {scoring!r} with a choice bias "
+                             f"of spread {bias_std}")
         more = {} if rows_factor == 2 else {"rows_factor": int(rows_factor)}
+        if scoring != "sigmoid":
+            more["scoring"] = scoring
         return self._unary(OperatorType.OP_ROUTED_EXPERTS, input, name,
                            num_experts=num_experts, top_k=top_k,
                            expert_dim=expert_dim, shared_dim=shared_dim,

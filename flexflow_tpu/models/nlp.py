@@ -1101,12 +1101,84 @@ class LFM2RankConfig(HybridConvMoEConfig):
     num_experts_published: int | None = 64
 
 
+@dataclasses.dataclass
+class KeyeRankConfig(HybridConvMoEConfig):
+    """What ONE chip holds of Keye-VL-2.0-30B-A3B's language model
+    (``model_type: KeyeVL2``) where 8 chips share each layer (the
+    benchmark's ``keye_vl2_30b_a3b``): every layer is grouped-query
+    attention (32 query heads on 4 key/value heads of 128, q/k norms,
+    rotary embedding) over the ``sa_config["topk"]`` keys that a learned
+    indexer selects for each query (``ops/sparse_attention``), then 128
+    softmax-routed experts of 768, 8 a token, no shared expert and no
+    choice bias. Here: experts 0 to 15, one of eight slices of the
+    vocabulary and published layers 0 to 3 (the rest lie on further
+    chips as pipeline stages); every width as published.
+
+    ``config.json`` names three of the parent's fields otherwise
+    (``rms_norm_eps``, ``rope_theta``, the expert count, which it gives
+    twice); they are taken under its names and copied over. The step is
+    text-only: the three position streams of ``rope_scaling``'s
+    ``mrope_section`` are equal there and the rotary embedding is the
+    ordinary one."""
+    vocab_size: int = 18992
+    num_hidden_layers: int = 4
+    layer_types: list = dataclasses.field(
+        default_factory=lambda: ["sparse_attention"] * 4)
+    num_dense_layers: int = 0
+    num_key_value_heads: int = 4
+    head_dim: int | None = 128
+    intermediate_size: int = 6144        # published; no dense layer reads it
+    moe_intermediate_size: int = 768
+    num_experts: int = 16
+    num_experts_published: int | None = 128
+    num_experts_per_tok: int = 8
+    use_expert_bias: bool = False
+    # the keys the parent class does not have
+    num_local_experts: int = 16
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000000.0
+    scoring_func: str = "softmax"        # not in config.json: the family's
+    sa_config: dict = dataclasses.field(
+        default_factory=lambda: {
+            "indexer_head_dim": 64, "indexer_num_heads": 16,
+            "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+            "q_chunk_size": 512, "topk": 2048})
+
+    def __post_init__(self):
+        if self.num_local_experts != self.num_experts \
+                or self.sa_config["indexer_num_kv_heads"] != 1:
+            raise ValueError(
+                "num_local_experts repeats num_experts and the indexer "
+                "has one key head")
+        self.norm_eps = self.rms_norm_eps
+        self.rope_parameters = {"rope_theta": self.rope_theta,
+                                "rope_type": "default"}
+
+    @classmethod
+    def tiny(cls):
+        """4 equal layers, 4 heads on 2 kv heads of 16, an indexer of 2
+        heads of 8 that keeps 24 keys a query in chunks of 16 queries,
+        16 experts top-4, all held: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_attention_heads=4,
+                   num_key_value_heads=2, head_dim=16, rope_theta=10000.0,
+                   moe_intermediate_size=32, num_experts=16,
+                   num_local_experts=16, num_experts_published=None,
+                   num_experts_per_tok=4,
+                   sa_config={"indexer_head_dim": 8, "indexer_num_heads": 2,
+                              "indexer_num_kv_heads": 1,
+                              "kv_chunk_size": 16, "q_chunk_size": 16,
+                              "topk": 24})
+
+
 def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                           cfg: HybridConvMoEConfig | None = None):
     """Causal LM of :class:`HybridConvMoEConfig`: inputs ``[ids, pos]``,
     output the softmax over the head, as :func:`build_latent_moe`. The
     layers are laid out from ``layer_types`` and ``num_dense_layers``:
-    ``h += Op(norm(h))`` then ``h += FF(norm(h))``. ``pos`` is what the
+    ``h += Op(norm(h))`` then ``h += FF(norm(h))``. A
+    ``"sparse_attention"`` layer (:class:`KeyeRankConfig`) is a
+    ``"full_attention"`` one whose queries attend the keys its indexer
+    selects; its alignment loss joins the step's. ``pos`` is what the
     attention layers' rotary embedding turns by (a layout with no
     attention layer takes ``ids`` alone).
 
@@ -1116,11 +1188,11 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     cfg = cfg or HybridConvMoEConfig()
     kinds = list(cfg.layer_types)
     if len(kinds) != cfg.num_hidden_layers \
-            or set(kinds) - {"conv", "full_attention"}:
+            or set(kinds) - {"conv", "full_attention", "sparse_attention"}:
         raise ValueError(
             f"layer_types must name {cfg.num_hidden_layers} layers, each "
-            f"'conv' or 'full_attention'; got {len(kinds)}: "
-            f"{sorted(set(kinds))}")
+            f"'conv', 'full_attention' or 'sparse_attention'; got "
+            f"{len(kinds)}: {sorted(set(kinds))}")
     if cfg.conv_bias or not cfg.norm_topk_prob:
         raise ValueError("conv_bias and gates that are not normalised "
                          "over the chosen experts are not built")
@@ -1128,6 +1200,17 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     heads = cfg.num_attention_heads
     head_dim = cfg.head_dim or hid // heads
     published = cfg.num_experts_published or cfg.num_experts
+    # fields of a subclass (``KeyeRankConfig``): absent, the graph is
+    # the one it was
+    sa = getattr(cfg, "sa_config", None)
+    if "sparse_attention" in kinds and sa is None:
+        raise ValueError("a 'sparse_attention' layer needs the "
+                         "configuration's sa_config")
+    indexer = {} if sa is None else {"indexer": {
+        "heads": sa["indexer_num_heads"], "head_dim": sa["indexer_head_dim"],
+        "topk": sa["topk"], "q_chunk": sa["q_chunk_size"]}}
+    scoring = {"scoring": cfg.scoring_func} \
+        if hasattr(cfg, "scoring_func") else {}
     ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
     pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids")
     h = ff.embedding(ids, cfg.vocab_size, hid, name="embed_tokens")
@@ -1145,7 +1228,8 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                 vdim=heads * head_dim, bias=False, causal=True, rope=True,
                 rope_theta=cfg.rope_parameters["rope_theta"],
                 num_kv_heads=cfg.num_key_value_heads, qk_norm=True,
-                qk_norm_eps=cfg.norm_eps, positions=pos, name=f"attn_{i}")
+                qk_norm_eps=cfg.norm_eps, positions=pos, name=f"attn_{i}",
+                **(indexer if kind == "sparse_attention" else {}))
         h = ff.add(h, op, name=f"operator_res_{i}")
         x = norm(h, f"ffn_norm_{i}")
         if i < cfg.num_dense_layers:
@@ -1164,7 +1248,7 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                 first_held=cfg.first_held_expert,
                 scale=cfg.routed_scaling_factor,
                 bias_std=cfg.router_bias_std if cfg.use_expert_bias
-                else 0.0, name=f"experts_{i}")
+                else 0.0, name=f"experts_{i}", **scoring)
         h = ff.add(h, y, name=f"ffn_res_{i}")
     return ff.softmax(ff.dense(norm(h, "final_norm"), cfg.vocab_size,
                                use_bias=False, name="lm_head"))

@@ -372,13 +372,26 @@ def _further_cotangents(plan, mdt, floats, ints, g, first):
 _sorted_domain.defvjp(_sorted_domain_fwd, _sorted_domain_bwd)
 
 
-def route(scores, bias, top_k: int, scale: float):
-    """Bias-corrected top-k (DeepSeek-V3's ``noaux_tc`` with one group):
-    the choice is over ``scores + bias``, the gates are the chosen
-    experts' own scores, normalised over all ``top_k`` chosen and scaled.
-    ``scores``: (tokens, experts) float32. Returns ``(idx, gates)``, both
-    (tokens, top_k)."""
-    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+def route(logits, bias, top_k: int, scale: float, scoring: str = "sigmoid"):
+    """``(idx, gates)``, both (tokens, top_k), from the router's
+    ``logits`` (tokens, experts) float32, by one of two score functions:
+
+    ``"sigmoid"``  bias-corrected top-k (DeepSeek-V3's ``noaux_tc`` with
+                   one group): ``s = sigmoid(logits)``, the choice is
+                   over ``s + bias``, the gates are the chosen experts'
+                   own scores.
+    ``"softmax"``  ``s = softmax(logits)`` over all experts (the
+                   Qwen3-MoE family): the choice is over ``s`` alone and
+                   ``bias`` is not read.
+
+    Either way the gates are normalised over all ``top_k`` chosen and
+    scaled."""
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(scores, top_k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     gates = scale * chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
     return idx, gates
@@ -391,9 +404,15 @@ class RoutedExpertsOp(OpDef):
     ``num_experts`` routed experts (``first_held`` onwards) and a shared
     expert beside them.
 
-      s = sigmoid(x wg)                      float32, over ALL experts
+      s = sigmoid(x wg)  or  softmax(x wg)   float32, over ALL experts
       S = top-k of (s + bias);  g_i = scale * s_i / sum_{j in S} s_j
       y = sum_{i in S, i held} g_i E_i(x)  +  E_shared(x)
+
+    ``scoring`` in the parameters says which score function
+    (:func:`route`): ``"sigmoid"`` where it is absent, the DeepSeek-V3
+    family's, whose choice a bias corrects; ``"softmax"``, the Qwen3-MoE
+    family's, has no choice bias: the weight ``bias`` is still in the
+    op's list, all zeros (``bias_std`` 0) and never read.
 
     Every expert is a SwiGLU, ``w_down(silu(w_gate x) * w_up x)``. The
     router, the choice and the gates' normalisation run over the
@@ -495,11 +514,12 @@ class RoutedExpertsOp(OpDef):
 
         # the router in float32, as published: a bf16 pass moves scores
         # by 1e-2 and with them the choice of experts
-        scores = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             xt.astype(jnp.float32), weights["wg"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        idx, gates = route(scores, weights["bias"].astype(jnp.float32), k,
-                           float(params.get("scale", 1.0)))
+            precision=jax.lax.Precision.HIGHEST)
+        idx, gates = route(logits, weights["bias"].astype(jnp.float32), k,
+                           float(params.get("scale", 1.0)),
+                           params.get("scoring", "sigmoid"))
 
         # sort the assignments by held expert; absent ones trail
         local = idx.reshape(-1) - first

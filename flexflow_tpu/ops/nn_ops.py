@@ -14,14 +14,15 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ffconst import (ActiMode, AggrMode, DataType, InitializerType,
                        OperatorType, PoolType)
 from ..core.tensor import WeightSpec
 from ..dtypes import to_jnp
 from ..obs import events
-from .registry import (EmitCtx, OpDef, bf16_enabled, compute_dtype,
-                       matmul, register)
+from .registry import (KEPT_BY_BLOCK, EmitCtx, OpDef, bf16_enabled,
+                       compute_dtype, matmul, register)
 
 
 def apply_activation(x, acti: ActiMode):
@@ -501,6 +502,13 @@ class MultiHeadAttentionOp(OpDef):
                               InitializerType.ONE),
                    WeightSpec("k_norm", (kdim // h,), dt,
                               InitializerType.ONE)]
+        if params.get("indexer_heads"):
+            # the sparse-attention indexer: its queries, its one key
+            # head and a weight a query head (``ops/sparse_attention``)
+            j, c = params["indexer_heads"], params["indexer_head_dim"]
+            ws += [WeightSpec("wq_idx", (qe, j, c), dt),
+                   WeightSpec("wk_idx", (qe, c), dt),
+                   WeightSpec("w_idx", (qe, j), dt)]
         return ws
 
     @staticmethod
@@ -665,6 +673,13 @@ class MultiHeadAttentionOp(OpDef):
                 pos = jnp.arange(qh.shape[1], dtype=jnp.int32)
             qh = _apply_rope(qh, pos, theta)
             kh = _apply_rope(kh, pos, theta)
+        if params.get("indexer_heads") and (
+                kv_mode is not None or not causal or rate > 0.0
+                or params.get("sliding_window", 0)):
+            raise ValueError(
+                f"{name}: an attention layer with an indexer is causal "
+                f"self-attention on the training path, with no dropout, "
+                f"window or key/value cache")
         if kv_mode == "prefill":
             # record per-position K/V for incremental decode; padded
             # positions hold garbage but every one is rewritten by the
@@ -694,6 +709,9 @@ class MultiHeadAttentionOp(OpDef):
                 ctx.new_kv[name] = {"k": kh, "v": vh}
         elif kv_mode == "decode":
             return self._emit_decode(params, weights, ctx, name, qh, kh,
+                                     vh, mdt, cdt)
+        if params.get("indexer_heads"):
+            return self._emit_sparse(params, q, weights, ctx, name, qh, kh,
                                      vh, mdt, cdt)
         # GQA: expand kv-head groups to the query head count for the
         # attention contraction (cache/weights stay at kvh heads).
@@ -770,6 +788,52 @@ class MultiHeadAttentionOp(OpDef):
         out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(mdt),
                          weights["wo"].astype(mdt),
                          preferred_element_type=jnp.float32)
+        if "bo" in weights:
+            out = out + weights["bo"].astype(jnp.float32)
+        return [out.astype(cdt)]
+
+    def keeps_for_block(self, params):
+        return bool(params.get("indexer_heads"))
+
+    def _emit_sparse(self, params, x, weights, ctx, name, qh, kh, vh, mdt,
+                     cdt):
+        """The layer with an indexer (``indexer_heads`` in its
+        parameters): attention over the ``indexer_topk`` keys a query's
+        index scores select, in query chunks on XLA
+        (``ops/sparse_attention``), whatever a plan says of kernels: no
+        kernel takes a mask. The indexer reads the layer's input
+        detached and its alignment loss joins the step's through
+        ``ctx.aux_losses`` with weight 1; where the sequence is no
+        longer than ``indexer_topk`` every causal key is selected and
+        the output is the plain causal path's."""
+        from . import sparse_attention as dsa
+        topk, q_chunk = params["indexer_topk"], params["indexer_q_chunk"]
+        with jax.named_scope("dsa.index"):
+            qi, ki, wi = dsa.indexer_inputs(x, weights, mdt)
+        self._note_impl(ctx, name, "xla")
+        s = qh.shape[1]
+        if events.enabled():
+            events.instant("attn.sparse_index", layer=name,
+                           heads=params["indexer_heads"],
+                           head_dim=params["indexer_head_dim"], topk=topk,
+                           q_chunk=q_chunk, chunks=-(-s // q_chunk),
+                           selecting=s > topk, positions=s)
+        o, loss, kept, ties = dsa.sparse_index_attention(
+            qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt)
+        # every chunk rematerialises itself: a rematerialised block
+        # around the layer keeps the chunks' output (the output
+        # projection's backward reads it) and does not run them a third
+        # time (``keeps_for_block``); outside such a block the identity
+        o = checkpoint_name(o.astype(mdt), KEPT_BY_BLOCK)
+        ctx.aux_losses.append(loss)
+        for key, v in (("dsa.kept_pairs", kept),
+                       ("dsa.causal_pairs", qh.shape[0] * s * (s + 1) / 2),
+                       ("dsa.index_kl", loss), ("dsa.layers", 1.0),
+                       ("dsa.threshold_ties", ties)):
+            ctx.count(key, jnp.asarray(v, jnp.float32))
+        with jax.named_scope("dsa.attend"):
+            out = jnp.einsum("bqhd,hde->bqe", o, weights["wo"].astype(mdt),
+                             preferred_element_type=jnp.float32)
         if "bo" in weights:
             out = out + weights["bo"].astype(jnp.float32)
         return [out.astype(cdt)]

@@ -38,8 +38,8 @@ class EmitCtx:
         # device counters of the step: an op adds to keys of its own
         # (``count``), the executor puts the sums among the step's
         # metrics (``runtime.metrics.COUNTER_PREFIX``), and they reach
-        # the host where the loss does. Unlike ``aux_losses`` they may be
-        # written inside a rematerialised block, which returns them.
+        # the host where the loss does. A rematerialised block returns
+        # them, and its ops' ``aux_losses``, as outputs.
         self.counters: Dict[str, Any] = {}
         # KV-cache decode plumbing (serving; the reference has no
         # generation path at all). kv_mode: None = normal forward,
@@ -84,6 +84,13 @@ class EmitCtx:
         self.counters[key] = self.counters.get(key, 0.0) + value
 
 
+# The one name (``checkpoint_name``) under which ``executor.py::
+# emit_layers`` marks the outputs of a layer that says
+# ``OpDef.keeps_for_block``, and which a rematerialised block that holds
+# such a layer keeps beside its entry (``executor.py::KEEP_MARKED``).
+KEPT_BY_BLOCK = "ff.kept_by_block"
+
+
 class OpDef:
     op_type: OperatorType = OperatorType.OP_INVALID
     # True for an op whose ``emit`` wraps its WHOLE body in
@@ -99,6 +106,13 @@ class OpDef:
     # overflow loop): the block's second run is where its backward's
     # residuals come from.
     keeps_output_for_block: bool = False
+
+    def keeps_for_block(self, params: Dict[str, Any]) -> bool:
+        """Whether THIS layer says so: the class's answer, unless an op
+        rematerialises itself only under some of its parameters
+        (``MultiHeadAttentionOp`` with an indexer). Such an op may mark
+        further values of its own ``KEPT_BY_BLOCK``."""
+        return self.keeps_output_for_block
 
     # ---- graph level ----
     def infer(self, params: Dict[str, Any],

@@ -1,0 +1,191 @@
+"""Attention over the keys a learned indexer selects (DeepSeek-V3.2's
+sparse attention, arXiv:2512.02556 section 2.1), in plain XLA, a chunk
+of queries at a time. :class:`~.nn_ops.MultiHeadAttentionOp` takes this
+path whenever its parameters name an indexer; there is no kernel.
+
+  I[t, s] = scale * sum_j w[t, j] * relu(qI[t, j] . kI[s])      s <= t
+  S_t     = the min(t + 1, topk) keys s <= t of largest I[t, s];
+            equal scores: the lower s first (``jax.lax.top_k``'s order)
+  a[t, i, s] = softmax over S_t of q[t, i] . k[s, i // g] / sqrt(d)
+  o[t, i] = sum_{S_t} a[t, i, s] v[s, i // g]
+  p[t, s] = stop_gradient(mean_i a[t, i, s])
+  L_I     = mean_t sum_{S_t} p (log p - log softmax_{S_t} I[t, :])
+
+The selection passes no gradient: ``L_I`` alone moves what ``I`` is made
+of, and nothing else reaches it.
+
+A chunk of ``q_chunk`` queries ending at position ``e`` reads keys
+``0 .. e`` only, so the causal half of the square is not computed, and
+runs under ``jax.checkpoint``: a chunk's scores (heads x q_chunk x keys
+float32) live while it runs, forward or backward, and the layer's never
+do. The k-th largest index score of a row is found WITHOUT sorting the
+row: 32 compare-and-count passes over the chunk's scores, one a bit of
+the threshold (:func:`kth_largest`); a chunk whose keys are ``topk`` or
+fewer selects every causal key and skips that.
+"""
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+MASKED = -1e9             # what ``MultiHeadAttentionOp``'s plain path uses
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' (-0.0 as
+    +0.0, so that equal scores are equal bits)."""
+    x = jnp.where(x == 0, jnp.float32(0), x)
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    i = jnp.where(i < 0, i ^ jnp.int32(0x7fffffff), i)
+    return jax.lax.bitcast_convert_type(i, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+
+def kth_largest(bits, k):
+    """The ``k``-th largest entry of each row of ``bits`` (uint32, rows
+    on the last axis; ``k``: int32, one a row with a trailing axis of
+    1): the largest ``T`` with ``count(bits >= T) >= k``, built from its
+    top bit down, one compare-and-count pass over ``bits`` a bit."""
+    def one_bit(i, t):
+        cand = t | jnp.left_shift(jnp.uint32(1), (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(bits >= cand, -1, keepdims=True,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, t)
+    return jax.lax.fori_loop(
+        0, 32, one_bit, jnp.zeros(bits.shape[:-1] + (1,), jnp.uint32))
+
+
+def select(scores, causal, k):
+    """``(chosen, ties)``: for each row the ``k`` entries of largest
+    score among those ``causal`` allows, equal scores to the lower
+    index, as ``jax.lax.top_k`` orders them; ``ties`` counts the rows
+    whose threshold value occurs more than once among the allowed.
+    ``scores`` (..., rows, keys) float32, ``causal`` (rows, keys) bool,
+    ``k`` (rows, 1) int32, at most the row's allowed entries."""
+    bits = jnp.where(causal, _ordered_bits(scores), jnp.uint32(0))
+    t = kth_largest(bits, k)
+    above = bits > t
+    equal = (bits == t) & causal
+    # of the equal ones, the first few by a running count
+    wanted = k - jnp.sum(above, -1, keepdims=True, dtype=jnp.int32)
+    first = jnp.cumsum(equal.astype(jnp.int32), -1) <= wanted
+    ties = jnp.sum(jnp.sum(equal, -1, dtype=jnp.int32) > 1)
+    return above | (equal & first), ties
+
+
+def indexer_inputs(x, weights, mdt):
+    """``(qi, ki, wi)``: the indexer's queries (b, s, j, c), its one key
+    head (b, s, c) and its head weights (b, s, j), from the layer's
+    input DETACHED: nothing the indexer computes reaches the input."""
+    x = jax.lax.stop_gradient(x).astype(mdt)
+
+    def proj(pattern, w):
+        return jnp.einsum(pattern, x, weights[w].astype(mdt),
+                          preferred_element_type=jnp.float32)
+    return (proj("ble,ejc->bljc", "wq_idx"), proj("ble,ec->blc", "wk_idx"),
+            proj("ble,ej->blj", "w_idx"))
+
+
+def index_scores(qi, ki, wi, mdt):
+    """``I`` for a chunk: ``qi`` (b, q, j, c), ``ki`` (b, k, c), ``wi``
+    (b, q, j) -> (b, q, k) float32; the products' operands in ``mdt``."""
+    j, c = qi.shape[2], qi.shape[3]
+    raw = jnp.einsum("bqjc,bkc->bjqk", qi.astype(mdt), ki.astype(mdt),
+                     preferred_element_type=jnp.float32)
+    w = jnp.swapaxes(wi.astype(jnp.float32), 1, 2)[..., None]
+    return jnp.sum(w * jax.nn.relu(raw), 1) * (j * c) ** -0.5
+
+
+def _choose(start: int, topk: int, scores):
+    """``(chosen, ties)`` for a chunk's index scores (b, rows, keys), its
+    rows at positions ``start ..``: :func:`select` among the causal
+    keys, or all of them where the chunk sees ``topk`` keys or fewer."""
+    rows, keys = scores.shape[-2:]
+    at = start + jnp.arange(rows, dtype=jnp.int32)[:, None]
+    causal = jnp.arange(keys, dtype=jnp.int32)[None, :] <= at
+    if keys <= topk:
+        return jnp.broadcast_to(causal, scores.shape), jnp.int32(0)
+    with jax.named_scope("dsa.select"):
+        return select(jax.lax.stop_gradient(scores), causal,
+                      jnp.minimum(at + 1, topk))
+
+
+def _chunk(start: int, topk: int, mdt, q, k, v, qi, ki, wi):
+    """One chunk of queries, positions ``start ..``, against keys ``0 ..
+    start + rows``: ``(o, kl, kept, ties)``, the chunk's attention
+    output (b, rows, kv, g, d), the sum of its rows' divergences, how
+    many (query, key) pairs it kept and how many rows tied at the
+    threshold. ``q`` (b, rows, kv, g, d); ``k``, ``v`` (b, keys, kv, d);
+    ``qi`` (b, rows, j, c); ``ki`` (b, keys, c); ``wi`` (b, rows, j)."""
+    with jax.named_scope("dsa.index"):
+        scores = index_scores(qi, ki, wi, mdt)
+    chosen, ties = _choose(start, topk, scores)
+    with jax.named_scope("dsa.attend"):
+        logits = jnp.einsum("bqjgd,bkjd->bjgqk", q.astype(mdt),
+                            k.astype(mdt),
+                            preferred_element_type=jnp.float32) \
+            * (1.0 / math.sqrt(q.shape[-1]))
+        probs = jax.nn.softmax(
+            jnp.where(chosen[:, None, None], logits, jnp.float32(MASKED)),
+            axis=-1)
+        o = jnp.einsum("bjgqk,bkjd->bqjgd", probs.astype(mdt),
+                       v.astype(mdt), preferred_element_type=jnp.float32)
+    with jax.named_scope("dsa.loss"):
+        p = jax.lax.stop_gradient(jnp.mean(probs, (1, 2)))
+        log_i = jax.nn.log_softmax(
+            jnp.where(chosen, scores, jnp.float32(MASKED)), axis=-1)
+        live = chosen & (p > 0)                       # 0 log 0 = 0
+        kl = jnp.sum(jnp.where(
+            live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_i), 0.0))
+    return o, kl, jnp.sum(chosen, dtype=jnp.int32), ties
+
+
+def _chunks(s: int, q_chunk: int):
+    return [(lo, min(lo + q_chunk, s)) for lo in range(0, s, q_chunk)]
+
+
+def selection(qi, ki, wi, topk: int, q_chunk: int, mdt):
+    """The (b, s, s) bool mask of the pairs :func:`sparse_index_attention`
+    attends, built chunk by chunk as it builds them: for tests and the
+    chip validation, which compare it with the reference's."""
+    s = qi.shape[1]
+    return jnp.concatenate([
+        jnp.pad(_choose(lo, topk, index_scores(
+            qi[:, lo:hi], ki[:, :hi], wi[:, lo:hi], mdt))[0],
+            ((0, 0), (0, 0), (0, s - hi)))
+        for lo, hi in _chunks(s, q_chunk)], 1)
+
+
+def sparse_index_attention(q, k, v, qi, ki, wi, topk: int, q_chunk: int,
+                           mdt):
+    """``(o, loss, kept, ties)`` of the module's equations for whole
+    sequences: ``q`` (b, s, h, d) and ``k``, ``v`` (b, s, kv, d) after
+    norms and rotary embedding, the indexer's ``qi`` (b, s, j, c), ``ki``
+    (b, s, c), ``wi`` (b, s, j). ``o`` (b, s, h, d) float32; ``loss`` is
+    ``L_I``, the mean over sequences and positions; ``kept`` and
+    ``ties`` are float32 counts over the batch.
+
+    The chunks run one after another, forward and backward: each
+    chunk's operands pass an optimization barrier together with the
+    chunk before's output, and the barrier's transpose holds a chunk's
+    backward (which ``jax.checkpoint`` starts from its cotangents) until
+    the next chunk's is done. Left to itself XLA's scheduler ran the
+    sixteen independent chunks' recomputations side by side, and a
+    step's temporaries read 10.4 GiB."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    q = q.reshape(b, s, kv, h // kv, d)
+    outs, kl, kept, ties = [], 0.0, 0, 0
+    for lo, hi in _chunks(s, q_chunk):
+        args = (q[:, lo:hi], k[:, :hi], v[:, :hi], qi[:, lo:hi], ki[:, :hi],
+                wi[:, lo:hi])
+        if outs:
+            outs[-1], args = jax.lax.optimization_barrier((outs[-1], args))
+        o, kl_c, kept_c, ties_c = jax.checkpoint(
+            lambda *a, _lo=lo: _chunk(_lo, topk, mdt, *a))(*args)
+        outs.append(o)
+        kl, kept, ties = kl + kl_c, kept + kept_c, ties + ties_c
+    o = jnp.concatenate(outs, 1).reshape(b, s, h, v.shape[-1])
+    return (o, kl / (b * s), jnp.asarray(kept, jnp.float32),
+            jnp.asarray(ties, jnp.float32))

@@ -16,7 +16,7 @@ import pytest
 
 import chip_smoke
 from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
-                                     HybridConvMoEConfig,
+                                     HybridConvMoEConfig, KeyeRankConfig,
                                      KimiLinearRankConfig, LatentMoEConfig,
                                      XingRankConfig)
 
@@ -158,6 +158,29 @@ def test_leg_f_mhc_latent_moe_tiny_on_the_cpu_mesh(capsys, hidden, impl):
     assert f"python3 {chip_smoke.VALIDATION_MHC}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_MHC))
+
+
+def test_leg_g_sparse_index_moe_tiny_on_the_cpu_mesh(capsys):
+    """A share of 4 of 16 experts on the 8-device mesh, 32 positions
+    with 24 keys a query in chunks of 16: the four equal layers are the
+    rematerialised run, each block keeps its attention layer, whose
+    alignment loss leaves the block as an output."""
+    cfg = dataclasses.replace(KeyeRankConfig.tiny(), num_experts=4,
+                              num_local_experts=4,
+                              num_experts_published=16)
+    chip_smoke.leg_sparse_index_moe(cfg, seq=32, per_chip_batch=1,
+                                    label="G/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    layers = "['attn_0', 'attn_1', 'attn_2', 'attn_3']"
+    assert (f"in {layers}; resolved ['xla']; the rematerialised run "
+            f"(1, 6, 4) keeps {layers}") in out
+    assert "'topk': 24, 'q_chunk': 16, 'chunks': 2, 'selecting': True" in out
+    want = sum(min(t + 1, 24) for t in range(32)) / (32 * 33 / 2)
+    assert f"kept {want:.6f} of the causal pairs" in out
+    assert "moe.dropped 0.0, moe.overflow 0.0" in out
+    assert f"python3 {chip_smoke.VALIDATION_SPARSE}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_SPARSE))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

@@ -62,10 +62,13 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          Keye-VL-2.0-30B-A3B at published widths, 1 x 8192 tokens a
          chip, rematerialised as its benchmark cell is (every block
          holds a layer with an auxiliary loss). It checks that every
-         layer announced its indexer (``attn.sparse_index``), stayed on
-         XLA and is kept by its block, and that the ``dsa.*`` counters
-         give the share of the causal pairs that ``topk`` and the length
-         do; it looks at no selection and no gradient:
+         layer announced its indexer (``attn.sparse_index``), took the
+         path its shapes and the mesh give (the flash kernels under the
+         selection as their mask on one chip from 1024 positions, query
+         chunks on XLA on a mesh of several) and is kept by its block,
+         and that the ``dsa.*`` counters give the share of the causal
+         pairs that ``topk`` and the length do and the layer-steps the
+         kernels ran in; it looks at no selection and no gradient:
          ``examples/tpu_validate_sparse_index_moe.py`` does.
 
 It claims no speed. The times it prints are set-up facts of one run.
@@ -860,10 +863,12 @@ def leg_sparse_index_moe(model_cfg, seq: int, per_chip_batch: int,
     through compile and fit with ``remat = "blocks"``: the loss (the
     cross-entropy plus every layer's alignment loss, which leaves the
     rematerialised blocks as their output) falls, every layer announced
-    its indexer and stayed on XLA, the share of the causal pairs kept is
-    what ``topk`` and ``seq`` give, each block keeps its attention
-    layer's output and nothing else, nothing was dropped, and the step
-    fits the chip. ``VALIDATION_SPARSE`` holds the selection and the
+    its indexer and took the path its shapes and the mesh give (the
+    kernels on one device that compiles them, from
+    ``FLASH_AUTO_MIN_SEQ`` positions; else query chunks on XLA), the
+    share of the causal pairs kept is what ``topk`` and ``seq`` give,
+    each block keeps its attention layer's output, nothing was dropped,
+    and the step fits the chip. ``VALIDATION_SPARSE`` holds the selection and the
     gradients to the reference, and this leg names it."""
     from flexflow_tpu.models.nlp import build_hybrid_conv_moe
     from flexflow_tpu.obs import events
@@ -876,14 +881,26 @@ def leg_sparse_index_moe(model_cfg, seq: int, per_chip_batch: int,
     kept = sorted({e["attrs"]["layer"] for e in events.events()
                    if e["name"] == "remat.kept"})
     impls = ff.executor.resolved_attention_impls
+    import jax
+
+    from flexflow_tpu.kernels._interpret import pallas_interpret
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp
+    want_impl = "flash" if (
+        len(jax.devices()) == 1 and not pallas_interpret()
+        and seq >= MultiHeadAttentionOp.FLASH_AUTO_MIN_SEQ) else "xla"
     say(f"{label}: attn.sparse_index {seen.get('attn_0')} in "
         f"{sorted(seen)}; resolved {sorted(set(impls.values()))}; the "
         f"rematerialised run {ff.executor._remat[:3]} keeps {kept}")
-    check(sorted(seen) == layers and set(impls.values()) == {"xla"}
+    check(sorted(seen) == layers and set(impls.values()) == {want_impl}
+          and {a["impl"] for a in seen.values()} == {want_impl}
           and kept == layers,
-          f"{label}: {layers} should each announce an indexer, stay on "
-          f"XLA and be kept by its block: {sorted(seen)}, {impls}, {kept}")
+          f"{label}: {layers} should each announce an indexer, take the "
+          f"{want_impl} path and be kept by its block: {sorted(seen)}, "
+          f"{impls}, {kept}")
     ctr = events.counters()
+    ran = ctr.get("dsa.kernel_layers", 0) / max(1.0, ctr.get("dsa.layers", 0))
+    check(ran == float(want_impl == "flash"),
+          f"{label}: the kernels ran in {ran} of the layer-steps")
     topk = model_cfg.sa_config["topk"]
     want = sum(min(t + 1, topk) for t in range(seq)) / (seq * (seq + 1) / 2)
     share = ctr.get("dsa.kept_pairs", 0) / max(

@@ -123,13 +123,17 @@ def kernels():
     READINGS["flash_fwd"] = out
 
 
-def build(conf, seq, remat):
+def build(conf, seq, remat, impl=None):
+    """The model through the normal path; ``impl`` forces its attention
+    layers' kernel (None: each layer's own choice from its shapes)."""
     cls = cells.load_attr(conf["config_class"])
     model_cfg = cls(**{f.name: conf[f.name]
                        for f in dataclasses.fields(cls) if f.name in conf})
     cfg = FFConfig()
     cfg.batch_size = 1
     cfg.remat = remat
+    if impl:
+        cfg.kernel_impls = f"attention:{impl}"
     ff = FFModel(cfg)
     out = cells.load_attr(conf["builder"])(ff, 1, seq, model_cfg)
     ff.compile(AdamOptimizer(1e-5), "sparse_categorical_crossentropy", [],
@@ -307,9 +311,10 @@ def check_budget(ff, seq, counters, forced):
               f"against budgets of {sum(budgets)} in all")
 
 
-def program_grads(ff, batch, pick):
-    """jitted ``params -> (loss, picked gradients, the moe.* counters)``
-    of the program's training loss."""
+def program_grads(ff, batch, pick, prefixes=("moe.",)):
+    """jitted ``params -> (loss, picked gradients, the counters whose
+    names start with one of ``prefixes``)`` of the program's training
+    loss."""
     from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
 
     @jax.jit
@@ -321,7 +326,8 @@ def program_grads(ff, batch, pick):
             value, bm = ex._loss_and_metrics(outs, capture, batch["label"],
                                              aux)
             return value, {k[len(COUNTER_PREFIX):]: v for k, v in bm.items()
-                           if k.startswith(COUNTER_PREFIX + "moe.")}
+                           if k.startswith(tuple(COUNTER_PREFIX + c
+                                                 for c in prefixes))}
         (value, counters), grads = jax.value_and_grad(
             loss, has_aux=True)(params)
         return value, pick(grads), counters

@@ -32,7 +32,9 @@ All kernels run compiled on TPU and in Pallas interpret mode on CPU, so the
 test suite exercises them without hardware.
 """
 from .flash_attention import (dropout_keep_mask, flash_attention,
-                              mha_reference)
+                              flash_attention_forward,
+                              flash_attention_from_forward,
+                              flash_attention_head_mean, mha_reference)
 from .registry import (KernelImpl, REGISTRY, attention_ctx, get_impl,
                        parse_forced, resolve_forced)
 from .ring_attention import ring_attention
@@ -43,6 +45,9 @@ __all__ = [
     "attention_ctx",
     "dropout_keep_mask",
     "flash_attention",
+    "flash_attention_forward",
+    "flash_attention_from_forward",
+    "flash_attention_head_mean",
     "get_impl",
     "mha_reference",
     "parse_forced",

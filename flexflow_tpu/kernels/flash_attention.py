@@ -31,7 +31,7 @@ from __future__ import annotations
 import functools
 import itertools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -152,10 +152,26 @@ def _lanes(x, width):
     return jnp.tile(x, (1, -(-width // LANES)))[:, :width]
 
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_sc, m_sc, l_sc, *, sm_scale, causal, kv_len,
-                block_q, block_k, dropout_rate):
-    """Online softmax over the k blocks of one q block. The running
+def _split_mask(refs, masked):
+    """``(mask_ref or None, the other refs)``: a masked call's mask tile
+    comes last of its operands, ahead of the outputs and the scratch."""
+    return (refs[0], refs[1:]) if masked else (None, refs)
+
+
+def _attended(mask_ref, keys=slice(None)):
+    """The mask tile's ``keys`` columns as booleans. The tile is int8 (a
+    quarter of the bytes of the narrowest type Mosaic compares in place)
+    and is widened to int32 here, in registers, for the comparison."""
+    return mask_ref[0, :, keys].astype(jnp.int32) != 0
+
+
+def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
+                kv_len, block_q, block_k, dropout_rate, masked=False):
+    """Online softmax over the k blocks of one q block. ``masked``: a
+    (1, block_q, block_k) int8 tile of the call's mask follows v, and a
+    pair is attended where it is not 0 AND :func:`_key_mask` holds (the
+    tile is shared by the heads of a batch row; no piece is skipped for
+    it beyond the causal ones). The running
     maximum lives lane-replicated in ``m_sc`` (rows, 128), so the
     rescaling factors are whole vregs and never a (rows, 1) column; the
     running sum lives LANE-WISE in ``l_sc``: each of its 128 lanes sums
@@ -165,6 +181,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     piece is masked, as every live tile was: leaving the select off the
     pieces that need none was timed and was worth nothing (PERF.md
     section 6, PR 32)."""
+    mask_ref, (o_ref, lse_ref, acc_sc, m_sc, l_sc) = _split_mask(refs,
+                                                                 masked)
     b = pl.program_id(0)     # read out here: interpret mode has no
     iq = pl.program_id(1)    # program_id inside pl.when's cond
     ik = pl.program_id(2)
@@ -188,8 +206,10 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(_key_mask(iq, piece, block_q, piece_k, kv_len, causal),
-                      s, NEG_INF)
+        valid = _key_mask(iq, piece, block_q, piece_k, kv_len, causal)
+        if masked:
+            valid = jnp.logical_and(valid, _attended(mask_ref, keys))
+        s = jnp.where(valid, s, NEG_INF)
         m_prev = m_sc[:]                                 # (block_q, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -275,8 +295,8 @@ def _bwd_ds(p, do, v, delta, keep, rate, sm_scale, keys_major):
 
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_sc, lse_sc, delta_sc, *, sm_scale, causal,
-                   kv_len, block_q, block_k, dropout_rate):
+                   *refs, sm_scale, causal, kv_len, block_q, block_k,
+                   dropout_rate, masked=False):
     """A q block stays while k blocks stream, so the tile is held
     queries-major, (block_q, block_k), and the q block's two statistics
     are made lane-replicated (block_q, 128) tiles ONCE, into scratch
@@ -286,7 +306,9 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     (PERF.md section 6, PR 32 and PR 39). Timed alone on a v5e against
     the keys-major form of ``bwd_dkv`` (here one transposed-left product
     more): within 2% either way at cells 2 to 5's shapes, 9% faster
-    with dropout at cell 1's."""
+    with dropout at cell 1's. ``masked``: the forward's (1, block_q,
+    block_k) mask tile follows ``delta``."""
+    mask_ref, (dq_ref, dq_sc, lse_sc, delta_sc) = _split_mask(refs, masked)
     b = pl.program_id(0)     # read out here: interpret mode has no
     iq = pl.program_id(1)    # program_id inside pl.when's cond
     ik = pl.program_id(2)
@@ -305,9 +327,11 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0]
         keep = _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k,
                                dropout_rate) if dropout_rate > 0.0 else None
-        p = _bwd_p(q_ref[0], k, _lanes(lse_sc[:], block_k),
-                   _key_mask(iq, ik, block_q, block_k, kv_len, causal),
-                   sm_scale, keys_major=False)
+        q, lse = q_ref[0], _lanes(lse_sc[:], block_k)
+        valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal)
+        if masked:
+            valid = jnp.logical_and(valid, _attended(mask_ref))
+        p = _bwd_p(q, k, lse, valid, sm_scale, keys_major=False)
         ds = _bwd_ds(p, do_ref[0], v_ref[0], _lanes(delta_sc[:], block_k),
                      keep, dropout_rate, sm_scale, keys_major=False)
         dq_sc[:] += jax.lax.dot_general(
@@ -320,8 +344,8 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, sm_scale,
-                    causal, kv_len, block_q, block_k, dropout_rate):
+                    delta_ref, *refs, sm_scale, causal, kv_len, block_q,
+                    block_k, dropout_rate, masked=False):
     """A k block stays while q blocks stream, and the tile is held
     KEYS-MAJOR, (block_k, block_q): ``s^T = k q^T`` and ``dp^T = v do^T``
     contract the last dimension of both operands as ``s`` always did,
@@ -333,7 +357,10 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     the transposed tile draws the forward's mask from swapped iotas.
     ``dv`` is accumulated before ``dp`` is formed, so that ``p_eff`` is
     dead by then: with both products after both tiles the call was 3%
-    slower at cell 2's shape (PERF.md section 6, PR 39)."""
+    slower at cell 2's shape (PERF.md section 6, PR 39). ``masked``: a
+    (1, block_k, block_q) tile of the call's mask TRANSPOSED (keys by
+    queries, as this tile is held) follows ``delta``."""
+    mask_ref, (dk_ref, dv_ref, dk_sc, dv_sc) = _split_mask(refs, masked)
     b = pl.program_id(0)
     ik = pl.program_id(1)
     iq = pl.program_id(2)
@@ -354,9 +381,12 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         keep = _tile_keep_mask(
             seed_ref, b, iq, ik, block_q, block_k, dropout_rate,
             keys_major=True) if dropout_rate > 0.0 else None
-        p = _bwd_p(q, k_ref[0], lse_ref[0],
-                   _key_mask(iq, ik, block_q, block_k, kv_len, causal,
-                             keys_major=True), sm_scale, keys_major=True)
+        k, lse = k_ref[0], lse_ref[0]
+        valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal,
+                          keys_major=True)
+        if masked:
+            valid = jnp.logical_and(valid, _attended(mask_ref))
+        p = _bwd_p(q, k, lse, valid, sm_scale, keys_major=True)
         over_q = (((1,), (0,)), ((), ()))
         dv_sc[:] += jax.lax.dot_general(
             _dropped(p, keep, dropout_rate).astype(do.dtype), do, over_q,
@@ -413,16 +443,40 @@ def _stat_spec(block_q):
     return pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
 
 
+def _mask_spec(block_q, block_k, causal, heads):
+    """A masked call's (1, block_q, block_k) int8 mask tile in the (bh,
+    nq, nk) grids: the mask is (batch, sq, sk), one for the ``heads``
+    heads of a batch row, and a dead causal step names the tile of the
+    row's last live k block, as :func:`_k_spec` does."""
+    def live_k(i, j):
+        return jnp.minimum(j, _last_live_k(i, block_q, block_k)) \
+            if causal else j
+    return pl.BlockSpec((1, block_q, block_k),
+                        lambda b, i, j: (b // heads, i, live_k(i, j)))
+
+
+def _dkv_live_q(block_q, block_k, causal):
+    """``iq(j, i)``: the q block step ``i`` of k block ``j``'s row of the
+    (bh, nk, nq) dkv grid names: a dead causal step names the column's
+    first live q block (see _k_spec)."""
+    if causal:
+        return lambda j, i: jnp.maximum(i, _first_live_q(j, block_q,
+                                                         block_k))
+    return lambda j, i: i
+
+
+def _dkv_mask_spec(block_q, block_k, causal, heads):
+    """The (1, block_k, block_q) tile of the TRANSPOSED mask, (batch, sk,
+    sq), in the dkv grid."""
+    iq = _dkv_live_q(block_q, block_k, causal)
+    return pl.BlockSpec((1, block_k, block_q),
+                        lambda b, j, i: (b // heads, j, iq(j, i)))
+
+
 def _dkv_specs(block_q, block_k, d, causal):
     """(q/do, k/v, row statistics) specs of the (bh, nk, nq) dkv grid:
-    the index maps swap the roles of grid axes 1 and 2, and a dead causal
-    step names the column's first live q block (see _k_spec)."""
-    if causal:
-        def iq(j, i):
-            return jnp.maximum(i, _first_live_q(j, block_q, block_k))
-    else:
-        def iq(j, i):
-            return i
+    the index maps swap the roles of grid axes 1 and 2."""
+    iq = _dkv_live_q(block_q, block_k, causal)
     return (pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, iq(j, i), 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, 1, block_q),
@@ -466,11 +520,13 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
     return out
 
 
-def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal):
-    """One ``flash.grid`` instant per emitted call, at trace time."""
+def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal, masked=False):
+    """One ``flash.grid`` instant per emitted call, at trace time; a
+    masked call's says so."""
     if events.enabled():
         events.instant("flash.grid", **grid_steps(
-            kernel, bh, sq, sk, block_q, block_k, causal))
+            kernel, bh, sq, sk, block_q, block_k, causal),
+            **({"masked": True} if masked else {}))
 
 
 # The three calls are jitted with ``inline=True``: a step with many attention
@@ -483,24 +539,35 @@ def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal):
 # three kernels cost 85 ms a layer on the chip's host, 6 s of set-up for
 # BERT-large's 24 layers (PERF.md section 6, PR 30).
 _STATIC = ("kv_len", "sm_scale", "causal", "block_q", "block_k",
-           "dropout_rate", "interpret")
+           "dropout_rate", "interpret", "heads")
+
+
+def _masked(mask, spec):
+    """What a mask adds to a call: ``(kernel options, in_specs,
+    operands)``, nothing where ``mask`` is None."""
+    if mask is None:
+        return {}, [], ()
+    return {"masked": True}, [spec()], (mask,)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
-              dropout_rate, interpret):
+              dropout_rate, interpret, mask=None, heads=1):
+    """``mask``: None or (bh // heads, sq, sk) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]      # q.k over d, p.v over dv
+    opts, mask_specs, mask_args = _masked(mask, functools.partial(
+        _mask_spec, block_q, block_k, causal, heads))
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         kv_len=kv_len, block_q=block_q, block_k=block_k,
-        dropout_rate=dropout_rate)
+        dropout_rate=dropout_rate, **opts)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[_SEED_SPEC, _q_spec(block_q, d),
                   _k_spec(block_q, block_k, d, causal),
-                  _k_spec(block_q, block_k, dv, causal)],
+                  _k_spec(block_q, block_k, dv, causal)] + mask_specs,
         out_specs=[_q_spec(block_q, dv), _stat_spec(block_q)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
@@ -513,25 +580,29 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
         ],
         interpret=interpret,
         name="flash_attention_fwd",
-    )(seed, q, k, v)
+    )(seed, q, k, v, *mask_args)
     return o, lse[:, 0, :]
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
-                 causal, block_q, block_k, dropout_rate, interpret):
-    """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row."""
+                 causal, block_q, block_k, dropout_rate, interpret,
+                 mask=None, heads=1):
+    """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row;
+    ``mask``: None or (bh // heads, sq, sk) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
+    opts, mask_specs, mask_args = _masked(mask, functools.partial(
+        _mask_spec, block_q, block_k, causal, heads))
     stat = _stat_spec(block_q)
     qs, ks = _q_spec(block_q, d), _k_spec(block_q, block_k, d, causal)
     dos, vs = _q_spec(block_q, dv), _k_spec(block_q, block_k, dv, causal)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
-                          dropout_rate=dropout_rate),
+                          dropout_rate=dropout_rate, **opts),
         grid=(bh, sq // block_q, sk // block_k),
-        in_specs=[_SEED_SPEC, qs, ks, vs, dos, stat, stat],
+        in_specs=[_SEED_SPEC, qs, ks, vs, dos, stat, stat] + mask_specs,
         out_specs=qs,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[     # dq, and the q block's lse and delta by lanes
@@ -541,23 +612,28 @@ def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
         ],
         interpret=interpret,
         name="flash_attention_bwd_dq",
-    )(seed, q, k, v, do, lse, delta)
+    )(seed, q, k, v, do, lse, delta, *mask_args)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
-                  causal, block_q, block_k, dropout_rate, interpret):
-    """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row."""
+                  causal, block_q, block_k, dropout_rate, interpret,
+                  mask=None, heads=1):
+    """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row;
+    ``mask``: None or the mask TRANSPOSED, (bh // heads, sk, sq) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
+    opts, mask_specs, mask_args = _masked(mask, functools.partial(
+        _dkv_mask_spec, block_q, block_k, causal, heads))
     qs2, ks2, stat2 = _dkv_specs(block_q, block_k, d, causal)
     dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
-                          dropout_rate=dropout_rate),
+                          dropout_rate=dropout_rate, **opts),
         grid=(bh, sk // block_k, sq // block_q),
-        in_specs=[_SEED_SPEC, qs2, ks2, vs2, dos2, stat2, stat2],
+        in_specs=[_SEED_SPEC, qs2, ks2, vs2, dos2, stat2, stat2]
+        + mask_specs,
         out_specs=[ks2, vs2],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
@@ -569,7 +645,7 @@ def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
         ],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(seed, q, k, v, do, lse, delta)
+    )(seed, q, k, v, do, lse, delta, *mask_args)
 
 
 @functools.partial(jax.custom_vjp,
@@ -582,11 +658,12 @@ def _flash(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
 
 
 def _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                    block_k, dropout_rate, interpret):
+                    block_k, dropout_rate, interpret, mask=None, heads=1):
     _note_grid("fwd", q.shape[0], q.shape[1], k.shape[1], block_q, block_k,
-               causal)
+               causal, mask is not None)
     return _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                     block_k, dropout_rate, interpret)
+                     block_k, dropout_rate, interpret, mask=mask,
+                     heads=heads)
 
 
 def _flash_fwd_rule(q, k, v, seed, kv_len, sm_scale, causal, block_q,
@@ -597,23 +674,75 @@ def _flash_fwd_rule(q, k, v, seed, kv_len, sm_scale, causal, block_q,
     return o, (q, k, v, seed, o, lse)
 
 
-def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
-                    dq_blocks, dkv_blocks, dropout_rate, interpret, res,
-                    do):
-    q, k, v, seed, o, lse = res
+def _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
+                    dropout_rate, interpret, q, k, v, seed, o, lse, do,
+                    mask=None, heads=1):
+    """``(dq, dk, dv)`` from the forward's operands, output and
+    log-sum-exp; ``mask``: the forward's, which the dkv call is handed
+    transposed (an (sk, sq) int8 copy a batch row, made here)."""
     bh, sq, _ = q.shape
+    masked = mask is not None
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     # one float32 a row, the rows along the lanes: nothing is replicated
     operands = (seed, q, k, v, do, lse[:, None, :], delta[:, None, :],
                 kv_len, sm_scale, causal)
-    _note_grid("bwd_dq", bh, sq, k.shape[1], *dq_blocks, causal)
-    dq = _bwd_dq_call(*operands, *dq_blocks, dropout_rate, interpret)
-    _note_grid("bwd_dkv", bh, sq, k.shape[1], *dkv_blocks, causal)
-    dk, dv = _bwd_dkv_call(*operands, *dkv_blocks, dropout_rate, interpret)
-    return dq, dk, dv, np.zeros(seed.shape, dtype=jax.dtypes.float0)
+    _note_grid("bwd_dq", bh, sq, k.shape[1], *dq_blocks, causal, masked)
+    dq = _bwd_dq_call(*operands, *dq_blocks, dropout_rate, interpret,
+                      mask=mask, heads=heads)
+    _note_grid("bwd_dkv", bh, sq, k.shape[1], *dkv_blocks, causal, masked)
+    dk, dv = _bwd_dkv_call(
+        *operands, *dkv_blocks, dropout_rate, interpret,
+        mask=jnp.swapaxes(mask, 1, 2) if masked else None, heads=heads)
+    return dq, dk, dv
+
+
+def _no_cotangent(x):
+    return np.zeros(x.shape, dtype=jax.dtypes.float0)
+
+
+def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
+                    dq_blocks, dkv_blocks, dropout_rate, interpret, res,
+                    do):
+    q, k, v, seed, o, lse = res
+    return _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
+                           dropout_rate, interpret, q, k, v, seed, o, lse,
+                           do) + (_no_cotangent(seed),)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+# A masked call is two steps, so that a caller may stand between them:
+# the forward kernel alone, with no gradient, and ``_from_forward``,
+# which IS the forward's output to whoever reads it and whose backward
+# runs the dq and dkv kernels from the operands, the mask and the
+# forward's output and log-sum-exp. Those two are then ARGUMENTS of the
+# differentiated function, not residuals made inside it: a caller that
+# names them (``jax.ad_checkpoint.checkpoint_name``) for an enclosing
+# ``jax.checkpoint``'s policy keeps them, and the forward kernel does
+# not run again for the backward (``ops/sparse_attention.py``).
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14))
+def _from_forward(q, k, v, seed, mask, o, lse, kv_len, sm_scale, causal,
+                  heads, dq_blocks, dkv_blocks, dropout_rate, interpret):
+    return o
+
+
+def _from_forward_fwd_rule(q, k, v, seed, mask, o, lse, *static):
+    return o, (q, k, v, seed, mask, o, lse)
+
+
+def _from_forward_bwd_rule(kv_len, sm_scale, causal, heads, dq_blocks,
+                           dkv_blocks, dropout_rate, interpret, res, do):
+    q, k, v, seed, mask, o, lse = res
+    grads = _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
+                            dropout_rate, interpret, q, k, v, seed, o, lse,
+                            do, mask=mask, heads=heads)
+    return grads + (_no_cotangent(seed), _no_cotangent(mask),
+                    jnp.zeros_like(o), jnp.zeros_like(lse))
+
+
+_from_forward.defvjp(_from_forward_fwd_rule, _from_forward_bwd_rule)
 
 
 # ---------------------------------------------------------------------------
@@ -629,8 +758,19 @@ BWD_VMEM_BUDGET = 15 * 1024 * 1024
 MAX_BWD_TILE = 1024
 
 
+def _mask_vmem_bytes(rows, cols):
+    """What a masked call's (rows, cols) int8 mask tile adds to a grid
+    step's working set: the tile, double-buffered. What it is widened to
+    for the comparison streams through registers with the other masks
+    (of 150 masked compiles for a described v5e, bf16 / f32, d 64 / 128
+    / 256, dropout, the three kernels at six tiles each, every one
+    Mosaic refused counts 18 MiB or more this way; PERF.md section 6,
+    PR 49)."""
+    return 2 * rows * cols
+
+
 def _bwd_vmem_bytes(kernel, block_q, block_k, d, itemsize, dropout,
-                    dv=None):
+                    dv=None, masked=False):
     """Working set of one grid step of ``bwd_dq`` / ``bwd_dkv``: the
     double-buffered operand and output blocks (the two row statistics
     are (1, block_q) float32 rows, which fill 8 sublanes), the f32
@@ -641,7 +781,8 @@ def _bwd_vmem_bytes(kernel, block_q, block_k, d, itemsize, dropout,
     ``ds``, the masks and the casts Mosaic streams through registers and
     keeps about one f32 tile, one more for the dropout hash and keep
     mask. ``d`` is the head size of q and k (and dq, dk), ``dv`` that of
-    v and do (and dv); None means the same."""
+    v and do (and dv); None means the same. ``masked``: the call's mask
+    tile beside them (:func:`_mask_vmem_bytes`)."""
     qk = -(-d // 128) * 128                  # a (rows, 64) block fills 128
     both = qk + -(-(d if dv is None else dv) // 128) * 128
     q_side = block_q * both * itemsize + 2 * 8 * block_q * 4     # q, do
@@ -653,6 +794,8 @@ def _bwd_vmem_bytes(kernel, block_q, block_k, d, itemsize, dropout,
         out, scratch = block_k * both * itemsize, block_k * both * 4
     stage = (block_q + block_k) * both * itemsize
     tiles = (2 if dropout else 1) * block_q * block_k * 4
+    if masked:
+        tiles += _mask_vmem_bytes(block_q, block_k)
     return 2 * (q_side + k_side + out) + scratch + stage + tiles
 
 
@@ -665,7 +808,7 @@ def _tile_sizes(padded, most=MAX_BWD_TILE):
     return sizes or [padded]
 
 
-def bwd_tiles(sq, sk, d, dtype, dropout, dv=None):
+def bwd_tiles(sq, sk, d, dtype, dropout, dv=None, masked=False):
     """``((block_q, block_k) of bwd_dq, (block_q, block_k) of bwd_dkv)``
     for padded sequence lengths ``sq``, ``sk`` and padded head dims ``d``
     (q, k) and ``dv`` (v, the output; None: the same as ``d``):
@@ -682,7 +825,8 @@ def bwd_tiles(sq, sk, d, dtype, dropout, dv=None):
     for kernel, resident in (("bwd_dq", 0), ("bwd_dkv", 1)):
         tiles = list(itertools.product(_tile_sizes(sq), _tile_sizes(sk)))
         fits = [t for t in tiles
-                if _bwd_vmem_bytes(kernel, *t, d, itemsize, dropout, dv)
+                if _bwd_vmem_bytes(kernel, *t, d, itemsize, dropout, dv,
+                                   masked)
                 <= BWD_VMEM_BUDGET] or tiles[:1]      # the smallest there is
         out.append(max(fits, key=lambda t: (t[0] * t[1], t[resident])))
     return tuple(out)
@@ -704,7 +848,8 @@ MAX_FWD_BLOCK_Q = 1024
 MAX_FWD_BLOCK_K = 4096
 
 
-def _fwd_vmem_bytes(block_q, block_k, d, itemsize, dropout, dv=None):
+def _fwd_vmem_bytes(block_q, block_k, d, itemsize, dropout, dv=None,
+                    masked=False):
     """Working set of one forward grid step: the double-buffered q, k, v,
     o and log-sum-exp blocks, the three float32 scratch buffers, the
     (block_q, piece) intermediates of one piece of the k block (each
@@ -726,11 +871,13 @@ def _fwd_vmem_bytes(block_q, block_k, d, itemsize, dropout, dv=None):
               + block_k * (qk + pv) * itemsize)                    # k, v
     scratch = block_q * (pv + 2 * 128) * 4
     tiles = (2 if dropout else 1) * block_q * _fwd_piece(block_k) * 4
+    if masked:      # the whole (block_q, block_k) tile, read by pieces
+        tiles += _mask_vmem_bytes(block_q, block_k)
     finish = block_q * (pv + 2 * 128) * 4
     return 2 * blocks + scratch + tiles + finish
 
 
-def fwd_tiles(sq, sk, d, dtype, dropout, dv=None):
+def fwd_tiles(sq, sk, d, dtype, dropout, dv=None, masked=False):
     """``(block_q, block_k)`` of the forward kernel for padded sequence
     lengths ``sq``, ``sk`` and padded head dims ``d`` (q, k) and ``dv``
     (v, the output; None: the same as ``d``). The q block is resident
@@ -750,11 +897,21 @@ def fwd_tiles(sq, sk, d, dtype, dropout, dv=None):
     tiles = itertools.product(_tile_sizes(sq, MAX_FWD_BLOCK_Q),
                               _tile_sizes(sk, MAX_FWD_BLOCK_K))
     fits = [t for t in tiles
-            if _fwd_vmem_bytes(*t, d, itemsize, dropout, dv)
+            if _fwd_vmem_bytes(*t, d, itemsize, dropout, dv, masked)
             <= BWD_VMEM_BUDGET]
     if not fits:                       # the smallest there is
         return _tile_sizes(sq)[0], _tile_sizes(sk)[0]
     block_k = max(bk for _, bk in fits)
+    if masked and block_k < sk:
+        # the mask's tile grows with both sides, and the widest k block
+        # would leave a q block of half the rows: where the keys are
+        # streamed again for every q block anyway, the tallest q block
+        # first (8,192 keys at head size 128 on a v5e, ms a call: 4.97 at
+        # 1024 x 2048, 5.69 at 512 x 4096; at 4,096 keys, all in one
+        # block, 512 x 4096 wins, 1.33 against 1.53; PERF.md section 6,
+        # PR 49)
+        block_q = max(bq for bq, _ in fits)
+        return block_q, max(bk for bq, bk in fits if bq == block_q)
     return max(bq for bq, bk in fits if bk == block_k), block_k
 
 
@@ -770,11 +927,20 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     bwd_block_q: Optional[int] = None,
                     bwd_block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    mesh=None, spec=None):
+                    mesh=None, spec=None, mask=None):
     """Tiled flash attention. q: (b, h, sq, d); k: (b, h, sk, d); v:
     (b, h, sk, dv), and the output (b, h, sq, dv). ``dv`` may differ from
     ``d`` (latent attention: q.k over 192, p.v over 128); the score scale
     defaults to ``1 / sqrt(d)``.
+
+    ``mask``: None, or a (b, sq, sk) int8 (or bool) array shared by the
+    heads of a batch row, not 0 where the pair is attended; under
+    ``causal`` a pair is attended where both say so. It is no
+    differentiated operand. The three kernels read its (block_q,
+    block_k) tile beside q, k and v, and the blocks are derived with the
+    tile counted, so a masked call's are narrower (docs/kernels.md). A
+    row with no attended key has no meaning (its output is a mean of
+    values, its gradients 0 where the cotangent is).
 
     Pads the key length to a multiple of 128 and the query length to a
     multiple of 8 (of 128 from 512 positions on), tiles them by divisors,
@@ -816,6 +982,17 @@ def flash_attention(q, k, v, *, causal: bool = False,
     the call runs under ``shard_map``. ``spec`` is the (b, h, s, d)
     PartitionSpec of the operands; only its batch and head entries are
     used — sequence and head_dim stay whole on every device."""
+    if mask is not None:
+        o, lse = flash_attention_forward(
+            q, k, v, mask, causal=causal, sm_scale=sm_scale,
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            mesh=mesh)
+        return flash_attention_from_forward(
+            q, k, v, mask, o, lse, causal=causal, sm_scale=sm_scale,
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            block_q=block_q, block_k=block_k, bwd_block_q=bwd_block_q,
+            bwd_block_k=bwd_block_k, interpret=interpret, mesh=mesh)
     if interpret is None:
         interpret = pallas_interpret()
     if dropout_rate > 0.0 and dropout_seed is None:
@@ -826,6 +1003,33 @@ def flash_attention(q, k, v, *, causal: bool = False,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
             block_q=block_q, block_k=block_k, bwd_block_q=bwd_block_q,
             bwd_block_k=bwd_block_k, interpret=interpret)
+    (qp, kp, vp), seed, cut, plan = _prepare(
+        q, k, v, False, causal, sm_scale, dropout_rate, dropout_seed,
+        block_q, block_k, bwd_block_q, bwd_block_k)
+    o = _flash(qp, kp, vp, seed, plan.kv_len, plan.sm_scale, causal,
+               *plan.fwd_blocks, plan.dq_blocks, plan.dkv_blocks,
+               float(dropout_rate), interpret)
+    return cut(o)
+
+
+class _Plan(NamedTuple):
+    """What a call's shapes decide: the keys that are no padding, the
+    score scale, the heads of a batch row and each kernel's blocks."""
+    kv_len: int
+    sm_scale: float
+    heads: int
+    fwd_blocks: tuple
+    dq_blocks: tuple
+    dkv_blocks: tuple
+
+
+def _prepare(q, k, v, masked, causal, sm_scale, dropout_rate, dropout_seed,
+             block_q, block_k, bwd_block_q, bwd_block_k):
+    """``((qp, kp, vp), seed, cut, plan)``: the operands padded and flat,
+    (batch * heads, s, d); the dropout seed as the kernels read it;
+    ``cut``, which gives a flat padded output its (b, h, sq, dv) form
+    back; and the :class:`_Plan`, its blocks derived for a ``masked``
+    call or an unmasked one."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     if causal and sq != sk:
@@ -849,10 +1053,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
     sq_p, d_p = qp.shape[2], qp.shape[3]
     sk_p, dv_p = kp.shape[2], vp.shape[3]
     # forward blocks from the shapes; an explicit one wins
-    derived = fwd_tiles(sq_p, sk_p, d_p, q.dtype, dropout_rate > 0.0, dv_p)
+    derived = fwd_tiles(sq_p, sk_p, d_p, q.dtype, dropout_rate > 0.0, dv_p,
+                        masked)
     block_q, block_k = sq_to or derived[0], sk_to or derived[1]
     dq_blocks, dkv_blocks = bwd_tiles(sq_p, sk_p, d_p, q.dtype,
-                                      dropout_rate > 0.0, dv_p)
+                                      dropout_rate > 0.0, dv_p, masked)
     # an explicit backward block wins, for both kernels
     if bwd_block_q is not None:
         bq = _explicit_block(bwd_block_q, block_q)
@@ -865,12 +1070,237 @@ def flash_attention(q, k, v, *, causal: bool = False,
         seed = jnp.zeros((1, 1), jnp.int32)
     else:
         seed = jnp.asarray(dropout_seed, jnp.int32).reshape(1, 1)
-    o = _flash(qp.reshape(b * h, sq_p, d_p),
-               kp.reshape(b * h, sk_p, d_p),
-               vp.reshape(b * h, sk_p, dv_p),
-               seed, sk, sm_scale, causal, block_q, block_k,
-               dq_blocks, dkv_blocks, float(dropout_rate), interpret)
-    return o.reshape(b, h, sq_p, dv_p)[:, :, :sq, :dv]
+
+    def cut(o):
+        return o.reshape(b, h, sq_p, dv_p)[:, :, :sq, :dv]
+
+    flat = (qp.reshape(b * h, sq_p, d_p), kp.reshape(b * h, sk_p, d_p),
+            vp.reshape(b * h, sk_p, dv_p))
+    return flat, seed, cut, _Plan(sk, sm_scale, h, (block_q, block_k),
+                                  dq_blocks, dkv_blocks)
+
+
+def _padded_mask(mask, sq_p, sk_p):
+    """The (batch, sq, sk) mask as the kernels read it: int8, padded with
+    0 (a padded pair is not attended) to the operands' padded lengths."""
+    mask = mask.astype(jnp.int8)
+    return jnp.pad(mask, ((0, 0), (0, sq_p - mask.shape[1]),
+                          (0, sk_p - mask.shape[2])))
+
+
+def _masked_call_checks(q, k, mask, mesh, interpret, dropout_rate,
+                        dropout_seed):
+    if mask.shape != (q.shape[0], q.shape[2], k.shape[2]):
+        raise ValueError(
+            f"mask {mask.shape} is not (batch, sq, sk) = "
+            f"{(q.shape[0], q.shape[2], k.shape[2])}")
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "a masked flash call runs on one device (no shard_map wrap)")
+    if dropout_rate > 0.0 and dropout_seed is None:
+        raise ValueError("dropout_rate > 0 requires dropout_seed")
+    return pallas_interpret() if interpret is None else interpret
+
+
+def flash_attention_forward(q, k, v, mask, *, causal: bool = False,
+                            sm_scale: Optional[float] = None,
+                            dropout_rate: float = 0.0, dropout_seed=None,
+                            block_q: Optional[int] = None,
+                            block_k: Optional[int] = None,
+                            interpret: Optional[bool] = None, mesh=None):
+    """The forward kernel of a MASKED call alone: ``(o, lse)``, the
+    output (b, h, sq, dv) and each row's log-sum-exp over its attended
+    keys (b, h, sq) float32, with NO gradient (the operands are read
+    detached). :func:`flash_attention_from_forward` makes ``o``
+    differentiable; :func:`flash_attention_head_mean` reads ``lse``.
+    ``mask`` and the other arguments as :func:`flash_attention`'s."""
+    interpret = _masked_call_checks(q, k, mask, mesh, interpret,
+                                    dropout_rate, dropout_seed)
+    (qp, kp, vp), seed, cut, plan = _prepare(
+        *map(jax.lax.stop_gradient, (q, k, v)), True, causal, sm_scale,
+        dropout_rate, dropout_seed, block_q, block_k, None, None)
+    o, lse = _noted_fwd_call(
+        qp, kp, vp, seed, plan.kv_len, plan.sm_scale, causal,
+        *plan.fwd_blocks, float(dropout_rate), interpret,
+        mask=_padded_mask(mask, qp.shape[1], kp.shape[1]), heads=plan.heads)
+    b, h, sq = q.shape[:3]
+    return cut(o), lse.reshape(b, h, -1)[:, :, :sq]
+
+
+def flash_attention_from_forward(q, k, v, mask, o, lse, *,
+                                 causal: bool = False,
+                                 sm_scale: Optional[float] = None,
+                                 dropout_rate: float = 0.0,
+                                 dropout_seed=None,
+                                 block_q: Optional[int] = None,
+                                 block_k: Optional[int] = None,
+                                 bwd_block_q: Optional[int] = None,
+                                 bwd_block_k: Optional[int] = None,
+                                 interpret: Optional[bool] = None,
+                                 mesh=None):
+    """``o``, differentiable in q, k and v: the value is the ``o`` handed
+    in, which :func:`flash_attention_forward` gave for the same
+    operands, mask and options, and the backward runs the dq and dkv
+    kernels from them and ``lse``. ``o`` and ``lse`` are arguments here,
+    so a caller under ``jax.checkpoint`` may name them for its policy
+    and keep them (see ``_from_forward``)."""
+    interpret = _masked_call_checks(q, k, mask, mesh, interpret,
+                                    dropout_rate, dropout_seed)
+    (qp, kp, vp), seed, cut, plan = _prepare(
+        q, k, v, True, causal, sm_scale, dropout_rate, dropout_seed,
+        block_q, block_k, bwd_block_q, bwd_block_k)
+    b, h, sq_p = q.shape[0], q.shape[1], qp.shape[1]
+    op = _pad_to(_pad_to(o, sq_p, 2), 64, 3).reshape(b * h, sq_p, -1)
+    lsep = _pad_to(lse, sq_p, 2).reshape(b * h, sq_p)
+    out = _from_forward(
+        qp, kp, vp, seed, _padded_mask(mask, sq_p, kp.shape[1]), op, lsep,
+        plan.kv_len, plan.sm_scale, causal, plan.heads, plan.dq_blocks,
+        plan.dkv_blocks, float(dropout_rate), interpret)
+    return cut(out)
+
+
+# ---------------------------------------------------------------------------
+# the heads' mean probability of a masked call
+# ---------------------------------------------------------------------------
+def _head_mean_kernel(q_ref, k_ref, lse_ref, mask_ref, out_ref, acc_sc, *,
+                      sm_scale, causal, kv_len, block_q, block_k):
+    """One head's probabilities of one tile, added into the tile's
+    float32 accumulator, which stays in scratch while the heads (the
+    last grid axis) go by; the last head's step writes it out. The tile
+    is held keys-major, (block_k, block_q), as ``bwd_dkv`` holds it: the
+    head's log-sum-exp, a (1, block_q) row, then meets it as a sublane
+    broadcast, the mask tile is the transposed mask's, and the
+    accumulator is transposed ONCE a tile, into the (block_q, block_k)
+    output block. ``lse`` comes with log(heads) added (the mean's
+    division) and +inf-like on padded rows, whose probabilities are then
+    0 like every pair the masks leave out: no second select. A tile
+    above the causal diagonal computes nothing and is written as
+    zeros."""
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
+    head = pl.program_id(3)
+    heads = pl.num_programs(3)
+
+    @pl.when(head == 0)
+    def _init():
+        acc_sc[:] = jnp.zeros_like(acc_sc)
+
+    live = ((iq + 1) * block_q - 1 >= ik * block_k) if causal else True
+
+    @pl.when(live)
+    def _compute():
+        valid = jnp.logical_and(
+            _key_mask(iq, ik, block_q, block_k, kv_len, causal,
+                      keys_major=True), _attended(mask_ref))
+        acc_sc[:] += _bwd_p(q_ref[0], k_ref[0], lse_ref[0], valid, sm_scale,
+                            keys_major=True)
+
+    @pl.when(head == heads - 1)
+    def _finish():
+        out_ref[0] = acc_sc[:].T
+
+
+def _head_mean_vmem_bytes(block_q, block_k, d, itemsize):
+    """Working set of one grid step of the head-mean kernel: the
+    double-buffered float32 output block, q and k blocks and log-sum-exp
+    row, the mask tile (:func:`_mask_vmem_bytes`), the float32
+    accumulator and about two float32 tiles of intermediates (the scores
+    and probabilities on their way into it; its transpose on the way
+    out)."""
+    qk = -(-d // 128) * 128
+    blocks = (block_q + block_k) * qk * itemsize + 8 * block_q * 4
+    return (2 * (blocks + block_q * block_k * 4)
+            + _mask_vmem_bytes(block_k, block_q)
+            + 3 * block_q * block_k * 4)
+
+
+def head_mean_tiles(sq, sk, d, dtype):
+    """``(block_q, block_k)`` of the head-mean kernel for padded lengths:
+    the tile of the most pairs that fits ``BWD_VMEM_BUDGET``, of two
+    such the one with more queries (its rows are the lanes)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    tiles = list(itertools.product(_tile_sizes(sq), _tile_sizes(sk)))
+    fits = [t for t in tiles if _head_mean_vmem_bytes(*t, d, itemsize)
+            <= BWD_VMEM_BUDGET] or tiles[:1]
+    return max(fits, key=lambda t: (t[0] * t[1], t[0]))
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=tuple(
+    n for n in _STATIC if n != "dropout_rate"))
+def _head_mean_call(q, k, lse, mask_t, kv_len, sm_scale, causal, block_q,
+                    block_k, interpret, heads):
+    """q, k (b * heads, s, d); lse (b * heads, 1, sq); the transposed
+    mask (b, sk, sq) int8 -> (b, sq, sk) float32."""
+    bh, sq, d = q.shape
+    sk = k.shape[1]
+    dead = (lambda i, j: j * block_k > (i + 1) * block_q - 1) if causal \
+        else (lambda i, j: False)
+
+    def row(b, i, j, n):
+        # a dead tile's steps all name head 0's blocks: one copy, not
+        # one a head
+        return b * heads + jnp.where(dead(i, j), 0, n)
+
+    return pl.pallas_call(
+        functools.partial(_head_mean_kernel, sm_scale=sm_scale,
+                          causal=causal, kv_len=kv_len, block_q=block_q,
+                          block_k=block_k),
+        grid=(bh // heads, sq // block_q, sk // block_k, heads),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d),
+                         lambda b, i, j, n: (row(b, i, j, n), i, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b, i, j, n: (row(b, i, j, n), j, 0)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b, i, j, n: (row(b, i, j, n), 0, i)),
+            pl.BlockSpec((1, block_k, block_q),
+                         lambda b, i, j, n: (b, j, i))],
+        out_specs=pl.BlockSpec((1, block_q, block_k),
+                               lambda b, i, j, n: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((bh // heads, sq, sk), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_k, block_q), jnp.float32)],
+        interpret=interpret,
+        name="flash_attention_head_mean",
+    )(q, k, lse, mask_t)
+
+
+def flash_attention_head_mean(q, k, lse, mask, *, causal: bool = False,
+                              sm_scale: Optional[float] = None,
+                              interpret: Optional[bool] = None):
+    """The heads' mean attention probability of a masked call,
+    ``p[b, t, s] = (1 / h) sum_i exp(q_i[t] . k_i[s] * scale -
+    lse_i[t])`` on the attended pairs and 0 elsewhere, (b, sq, sk)
+    float32: q, k (b, h, s, d), ``lse`` (b, h, sq) the log-sum-exp
+    :func:`flash_attention_forward` gave for them and ``mask``. The one
+    array over (queries, keys) it writes has no head axis: the heads are
+    the innermost, sequential grid axis and add into an accumulator that
+    stays in VMEM (docs/kernels.md). No gradient: the operands are read
+    detached."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    q, k, lse = map(jax.lax.stop_gradient, (q, k, lse))
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    qp = _pad_to(_pad_to(q, 8 if sq <= 512 else 128, 2), 64, 3)
+    kp = _pad_to(_pad_to(k, 128, 2), 64, 3)
+    sq_p, sk_p, d_p = qp.shape[2], kp.shape[2], qp.shape[3]
+    block_q, block_k = head_mean_tiles(sq_p, sk_p, d_p, q.dtype)
+    mask_t = jnp.swapaxes(_padded_mask(mask, sq_p, sk_p), 1, 2)
+    # exp(s - (lse + log h)) is the head's share of the mean; a padded
+    # row's statistic is so large that its every probability is 0
+    lse = jnp.pad(lse.astype(jnp.float32) + math.log(h),
+                  ((0, 0), (0, 0), (0, sq_p - sq)), constant_values=-NEG_INF)
+    if events.enabled():
+        events.instant("flash.grid", kernel="flash_attention_head_mean",
+                       block_q=block_q, block_k=block_k, tile="keys_major",
+                       steps=b * h * (sq_p // block_q) * (sk_p // block_k))
+    p = _head_mean_call(
+        qp.reshape(b * h, sq_p, d_p), kp.reshape(b * h, sk_p, d_p),
+        lse.reshape(b * h, 1, sq_p), mask_t, sk, sm_scale, causal, block_q,
+        block_k, interpret, h)
+    return p[:, :sq, :sk]
 
 
 def _flash_sharded(q, k, v, mesh, spec, *, dropout_rate, dropout_seed,

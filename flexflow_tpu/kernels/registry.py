@@ -39,9 +39,6 @@ def _attn_flash(ctx: Dict[str, Any]) -> Optional[str]:
     """
     if ctx.get("sliding_window", 0):
         return "flash kernel has no sliding-window mask support"
-    if ctx.get("indexer", False):
-        return "flash kernel takes no mask of selected keys (a layer " \
-               "with a sparse-attention indexer runs on XLA)"
     if ctx.get("causal", False) and \
             ctx.get("q_len", 0) != ctx.get("kv_len", 0):
         return "flash kernel does not mask causal cross-attention " \

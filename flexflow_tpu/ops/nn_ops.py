@@ -799,36 +799,56 @@ class MultiHeadAttentionOp(OpDef):
                      cdt):
         """The layer with an indexer (``indexer_heads`` in its
         parameters): attention over the ``indexer_topk`` keys a query's
-        index scores select, in query chunks on XLA
-        (``ops/sparse_attention``), whatever a plan says of kernels: no
-        kernel takes a mask. The indexer reads the layer's input
-        detached and its alignment loss joins the step's through
-        ``ctx.aux_losses`` with weight 1; where the sequence is no
-        longer than ``indexer_topk`` every causal key is selected and
-        the output is the plain causal path's."""
+        index scores select (``ops/sparse_attention``). Two paths of the
+        same equations, chosen as a layer without an indexer chooses
+        (:meth:`_flash_enabled`: the forced implementation, else the
+        shapes on a backend that compiles the kernels): the flash
+        kernels with the selection as their mask operand and a fourth
+        kernel for the heads' mean probability, or query chunks on XLA
+        (short sequences; a mesh of more than one device, where the
+        masked kernels have no ``shard_map`` wrap). The indexer reads
+        the layer's input detached and its alignment loss joins the
+        step's through ``ctx.aux_losses`` with weight 1; where the
+        sequence is no longer than ``indexer_topk`` every causal key is
+        selected and the output is the plain causal path's."""
         from . import sparse_attention as dsa
         topk, q_chunk = params["indexer_topk"], params["indexer_q_chunk"]
         with jax.named_scope("dsa.index"):
             qi, ki, wi = dsa.indexer_inputs(x, weights, mdt)
-        self._note_impl(ctx, name, "xla")
         s = qh.shape[1]
+        impl = self._impl_for(ctx, name)
+        if impl == "ring":
+            raise ValueError(f"{name}: kernel impl 'ring' takes no mask "
+                             f"of selected keys")
+        kernels = self._kernel_shard_spec(ctx, qh.shape[0],
+                                          qh.shape[2])[0] is None \
+            and self._flash_enabled(impl, s, s, qh.shape[-1], vh.shape[-1],
+                                    causal=True)
+        path = "flash" if kernels else "xla"
+        self._note_impl(ctx, name, path)
         if events.enabled():
             events.instant("attn.sparse_index", layer=name,
                            heads=params["indexer_heads"],
                            head_dim=params["indexer_head_dim"], topk=topk,
                            q_chunk=q_chunk, chunks=-(-s // q_chunk),
-                           selecting=s > topk, positions=s)
-        o, loss, kept, ties = dsa.sparse_index_attention(
-            qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt)
-        # every chunk rematerialises itself: a rematerialised block
-        # around the layer keeps the chunks' output (the output
-        # projection's backward reads it) and does not run them a third
-        # time (``keeps_for_block``); outside such a block the identity
-        o = checkpoint_name(o.astype(mdt), KEPT_BY_BLOCK)
+                           selecting=s > topk, positions=s, impl=path)
+        attend = dsa.sparse_index_attention_flash if kernels \
+            else dsa.sparse_index_attention
+        o, loss, kept, ties = attend(qh, kh, vh, qi, ki, wi, topk, q_chunk,
+                                     mdt)
+        # either path rematerialises itself: a rematerialised block
+        # around the layer keeps the attention's output (the output
+        # projection's backward reads it; the kernel path marks its own,
+        # with the log-sum-exp and the mask) and does not run the chunks
+        # or the forward kernel again (``keeps_for_block``); outside
+        # such a block the identity
+        if not kernels:
+            o = checkpoint_name(o.astype(mdt), KEPT_BY_BLOCK)
         ctx.aux_losses.append(loss)
         for key, v in (("dsa.kept_pairs", kept),
                        ("dsa.causal_pairs", qh.shape[0] * s * (s + 1) / 2),
                        ("dsa.index_kl", loss), ("dsa.layers", 1.0),
+                       ("dsa.kernel_layers", float(kernels)),
                        ("dsa.threshold_ties", ties)):
             ctx.count(key, jnp.asarray(v, jnp.float32))
         with jax.named_scope("dsa.attend"):

@@ -1,7 +1,12 @@
 """Attention over the keys a learned indexer selects (DeepSeek-V3.2's
-sparse attention, arXiv:2512.02556 section 2.1), in plain XLA, a chunk
-of queries at a time. :class:`~.nn_ops.MultiHeadAttentionOp` takes this
-path whenever its parameters name an indexer; there is no kernel.
+sparse attention, arXiv:2512.02556 section 2.1).
+:class:`~.nn_ops.MultiHeadAttentionOp` comes here whenever its
+parameters name an indexer, and chooses between two paths of the same
+equations by its shapes, as it chooses for a layer without one:
+:func:`sparse_index_attention`, plain XLA a chunk of queries at a time
+(short sequences, and what the other is tested against), and
+:func:`sparse_index_attention_flash`, which hands the selection to the
+flash kernels as their mask operand.
 
   I[t, s] = scale * sum_j w[t, j] * relu(qI[t, j] . kI[s])      s <= t
   S_t     = the min(t + 1, topk) keys s <= t of largest I[t, s];
@@ -14,7 +19,10 @@ path whenever its parameters name an indexer; there is no kernel.
 The selection passes no gradient: ``L_I`` alone moves what ``I`` is made
 of, and nothing else reaches it.
 
-A chunk of ``q_chunk`` queries ending at position ``e`` reads keys
+On both paths the index scores and the selection are made by the same
+code, a chunk of ``q_chunk`` queries at a time (:func:`_index_chunk`).
+
+The XLA path: a chunk of ``q_chunk`` queries ending at position ``e`` reads keys
 ``0 .. e`` only, so the causal half of the square is not computed, and
 runs under ``jax.checkpoint``: a chunk's scores (heads x q_chunk x keys
 float32) live while it runs, forward or backward, and the layer's never
@@ -22,13 +30,28 @@ do. The k-th largest index score of a row is found WITHOUT sorting the
 row: 32 compare-and-count passes over the chunk's scores, one a bit of
 the threshold (:func:`kth_largest`); a chunk whose keys are ``topk`` or
 fewer selects every causal key and skips that.
+
+The kernel path: the chunks' selections make one (b, s, s) int8 mask,
+the three flash kernels attend under it (``kernels/flash_attention``:
+scores and probabilities stay in VMEM), a fourth kernel writes ``p``,
+and ``L_I`` and its gradient into the indexer come from ``I``, the mask
+and ``p``: arrays over (queries, keys) that the heads share may live in
+HBM, arrays with a head axis over them are never written.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+from ..kernels.flash_attention import (flash_attention_forward,
+                                       flash_attention_from_forward,
+                                       flash_attention_head_mean)
+from .registry import KEPT_BY_BLOCK
 
 MASKED = -1e9             # what ``MultiHeadAttentionOp``'s plain path uses
 
@@ -111,16 +134,32 @@ def _choose(start: int, topk: int, scores):
                       jnp.minimum(at + 1, topk))
 
 
-def _chunk(start: int, topk: int, mdt, q, k, v, qi, ki, wi):
-    """One chunk of queries, positions ``start ..``, against keys ``0 ..
-    start + rows``: ``(o, kl, kept, ties)``, the chunk's attention
-    output (b, rows, kv, g, d), the sum of its rows' divergences, how
-    many (query, key) pairs it kept and how many rows tied at the
-    threshold. ``q`` (b, rows, kv, g, d); ``k``, ``v`` (b, keys, kv, d);
-    ``qi`` (b, rows, j, c); ``ki`` (b, keys, c); ``wi`` (b, rows, j)."""
+def _index_chunk(start: int, topk: int, mdt, qi, ki, wi):
+    """``(scores, chosen, ties)`` of one chunk of queries, positions
+    ``start ..``, against keys ``0 .. start + rows``: both paths'
+    index scores and selection. ``qi`` (b, rows, j, c); ``ki`` (b, keys,
+    c); ``wi`` (b, rows, j)."""
     with jax.named_scope("dsa.index"):
         scores = index_scores(qi, ki, wi, mdt)
-    chosen, ties = _choose(start, topk, scores)
+    return (scores,) + _choose(start, topk, scores)
+
+
+def _divergence(scores, chosen, p):
+    """``sum_t KL(p[t] || softmax over the chosen of scores[t])``."""
+    log_i = jax.nn.log_softmax(
+        jnp.where(chosen, scores, jnp.float32(MASKED)), axis=-1)
+    live = chosen & (p > 0)                           # 0 log 0 = 0
+    return jnp.sum(jnp.where(
+        live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_i), 0.0))
+
+
+def _chunk(start: int, topk: int, mdt, q, k, v, qi, ki, wi):
+    """One chunk of queries of the XLA path: ``(o, kl, kept, ties)``,
+    the chunk's attention output (b, rows, kv, g, d), the sum of its
+    rows' divergences, how many (query, key) pairs it kept and how many
+    rows tied at the threshold. ``q`` (b, rows, kv, g, d); ``k``, ``v``
+    (b, keys, kv, d); the indexer's as :func:`_index_chunk`'s."""
+    scores, chosen, ties = _index_chunk(start, topk, mdt, qi, ki, wi)
     with jax.named_scope("dsa.attend"):
         logits = jnp.einsum("bqjgd,bkjd->bjgqk", q.astype(mdt),
                             k.astype(mdt),
@@ -132,12 +171,8 @@ def _chunk(start: int, topk: int, mdt, q, k, v, qi, ki, wi):
         o = jnp.einsum("bjgqk,bkjd->bqjgd", probs.astype(mdt),
                        v.astype(mdt), preferred_element_type=jnp.float32)
     with jax.named_scope("dsa.loss"):
-        p = jax.lax.stop_gradient(jnp.mean(probs, (1, 2)))
-        log_i = jax.nn.log_softmax(
-            jnp.where(chosen, scores, jnp.float32(MASKED)), axis=-1)
-        live = chosen & (p > 0)                       # 0 log 0 = 0
-        kl = jnp.sum(jnp.where(
-            live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_i), 0.0))
+        kl = _divergence(scores, chosen,
+                         jax.lax.stop_gradient(jnp.mean(probs, (1, 2))))
     return o, kl, jnp.sum(chosen, dtype=jnp.int32), ties
 
 
@@ -187,5 +222,129 @@ def sparse_index_attention(q, k, v, qi, ki, wi, topk: int, q_chunk: int,
         outs.append(o)
         kl, kept, ties = kl + kl_c, kept + kept_c, ties + ties_c
     o = jnp.concatenate(outs, 1).reshape(b, s, h, v.shape[-1])
+    return (o, kl / (b * s), jnp.asarray(kept, jnp.float32),
+            jnp.asarray(ties, jnp.float32))
+
+
+# ----------------------------------------------------------------------
+# the kernel path
+# ----------------------------------------------------------------------
+def _scores_and_mask(qi, ki, wi, topk: int, q_chunk: int, mdt):
+    """``(I, mask, kept, ties)`` for whole sequences, with no gradient:
+    the index scores (b, s, s) float32 and the selection (b, s, s) int8,
+    chunk by chunk as the XLA path makes them (zeros past a chunk's
+    end), each chunk written into the two arrays in place and one chunk
+    after another (an optimization barrier between them: a chunk's 16
+    heads of scores are 268 MB at 8,192 keys)."""
+    qi, ki, wi = map(jax.lax.stop_gradient, (qi, ki, wi))
+    b, s = qi.shape[:2]
+    out = (jnp.zeros((b, s, s), jnp.float32), jnp.zeros((b, s, s), jnp.int8))
+    kept, ties = 0, 0
+    for lo, hi in _chunks(s, q_chunk):
+        args = (qi[:, lo:hi], ki[:, :hi], wi[:, lo:hi])
+        if lo:
+            out, args = jax.lax.optimization_barrier((out, args))
+        scores, chosen, ties_c = _index_chunk(lo, topk, mdt, *args)
+        out = tuple(whole.at[:, lo:hi, :hi].set(part.astype(whole.dtype))
+                    for whole, part in zip(out, (scores, chosen)))
+        kept, ties = kept + jnp.sum(chosen, dtype=jnp.int32), ties + ties_c
+    return out + (kept, ties)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _index_loss(qi, ki, wi, scores, mask, q, k, lse, q_chunk, mdt):
+    """``sum_t KL(p[t] || softmax_{S_t} I[t])`` on the kernel path, as a
+    function of the indexer's ``qi``, ``ki``, ``wi`` alone. Its value
+    comes from the ``scores`` the selection was made from (no product is
+    run for it) and ``p``, the heads' mean probability, which the fourth
+    flash kernel writes from ``q``, ``k`` (b, h, s, d), the forward's
+    ``lse`` and the mask. Its backward keeps neither ``scores`` nor
+    ``p``: it runs that kernel again and, a chunk of queries at a time,
+    the index products once more and their transposes, as the XLA
+    path's chunks do under their ``jax.checkpoint``."""
+    with jax.named_scope("dsa.loss"):
+        p = flash_attention_head_mean(q, k, lse, mask, causal=True)
+        return _divergence(scores, mask != 0, p)
+
+
+def _index_loss_fwd(qi, ki, wi, scores, mask, q, k, lse, q_chunk, mdt):
+    return (_index_loss(qi, ki, wi, scores, mask, q, k, lse, q_chunk, mdt),
+            (qi, ki, wi, mask, q, k, lse))
+
+
+def _index_loss_bwd(q_chunk, mdt, res, g):
+    # nothing below starts before the cotangent is there: what it reads
+    # are residuals, which XLA's scheduler would otherwise be free to
+    # turn into every layer's ``p`` (268 MB each at 8,192 positions) at
+    # the start of the backward pass
+    g, res = jax.lax.optimization_barrier((g, res))
+    qi, ki, wi, mask, q, k, lse = res
+    s = qi.shape[1]
+    with jax.named_scope("dsa.loss"):
+        p = flash_attention_head_mean(q, k, lse, mask, causal=True)
+    dqi, dwi, dki = [], [], jnp.zeros(ki.shape, jnp.float32)
+    for lo, hi in _chunks(s, q_chunk):
+        args = (qi[:, lo:hi], ki[:, :hi], wi[:, lo:hi], mask[:, lo:hi, :hi],
+                p[:, lo:hi, :hi])
+        if dqi:      # one chunk's scores at a time, as the forward's
+            dki, args = jax.lax.optimization_barrier((dki, args))
+        *idx, chosen, p_c = args
+        with jax.named_scope("dsa.index"):
+            scores, pull = jax.vjp(
+                lambda *a: index_scores(*a, mdt), *idx)
+        with jax.named_scope("dsa.loss"):
+            d_scores = g * jax.grad(_divergence)(scores, chosen != 0, p_c)
+        with jax.named_scope("dsa.index"):
+            dqi_c, dki_c, dwi_c = pull(d_scores)
+        dqi.append(dqi_c)
+        dwi.append(dwi_c)
+        dki = dki.at[:, :hi].add(dki_c.astype(jnp.float32))
+    return (jnp.concatenate(dqi, 1), dki.astype(ki.dtype),
+            jnp.concatenate(dwi, 1), jnp.zeros(mask.shape, jnp.float32),
+            np.zeros(mask.shape, jax.dtypes.float0), jnp.zeros_like(q),
+            jnp.zeros_like(k), jnp.zeros_like(lse))
+
+
+_index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
+
+
+def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
+                                 q_chunk: int, mdt):
+    """:func:`sparse_index_attention` through the flash kernels: the
+    same arguments, the same ``(o, loss, kept, ties)``. ``o`` is in
+    ``mdt``, the kernels' output type.
+
+    What a rematerialised block around the layer keeps is named here
+    (``KEPT_BY_BLOCK``): the selection's mask (int8), the forward
+    kernel's output and its log-sum-exp. The block's second run then
+    makes q, k, v and the indexer's inputs again and nothing else: no
+    index product, no selection and no forward kernel; the backward runs
+    the dq and dkv kernels, the head-mean kernel and each chunk's index
+    products. Outside such a block the names do nothing."""
+    b, s, h, d = q.shape
+    scores, mask, kept, ties = _scores_and_mask(qi, ki, wi, topk, q_chunk,
+                                                mdt)
+    mask = checkpoint_name(mask, KEPT_BY_BLOCK)
+    with jax.named_scope("dsa.attend"):
+        def heads_first(x):      # (b, s, heads, d) -> (b, h, s, d)
+            x = jnp.repeat(x, h // x.shape[2], axis=2) \
+                if x.shape[2] != h else x
+            return jnp.swapaxes(x, 1, 2).astype(mdt)
+        qh, kh, vh = heads_first(q), heads_first(k), heads_first(v)
+        o, lse = flash_attention_forward(qh, kh, vh, mask,
+                                               causal=True)
+        o = checkpoint_name(o, KEPT_BY_BLOCK)
+        lse = checkpoint_name(lse, KEPT_BY_BLOCK)
+        o = flash_attention_from_forward(qh, kh, vh, mask, o, lse,
+                                               causal=True)
+        o = jnp.swapaxes(o, 1, 2)
+    kl = _index_loss(qi, ki, wi, scores, mask,
+                     *map(jax.lax.stop_gradient, (qh, kh, lse)), q_chunk,
+                     mdt)
+    # the loss is there when the output is, and ``I`` and ``p`` are
+    # dead by then (nothing else asks for it before the step's end);
+    # the barrier's transpose hands the loss's backward its cotangent
+    # with the output's: each layer's in its turn, not all four at once
+    o, kl = jax.lax.optimization_barrier((o, kl))
     return (o, kl / (b * s), jnp.asarray(kept, jnp.float32),
             jnp.asarray(ties, jnp.float32))

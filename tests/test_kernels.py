@@ -1024,3 +1024,257 @@ def test_flash_backward_forms_match_reference(layout, d, dv, causal, rate,
     assert got[i].shape == want[i].shape
     np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want[i]),
                                atol=1e-3, rtol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# the mask operand (PR 49): a (b, sq, sk) int8 array shared by the heads
+# ---------------------------------------------------------------------------
+def _topk_causal_mask(b, s, topk, seed=0):
+    """A row's ``min(t + 1, topk)`` keys of largest random score among
+    its causal ones: what a sparse-attention indexer hands the kernels."""
+    rng = np.random.default_rng(seed)
+    scores = rng.standard_normal((b, s, s))
+    causal = np.tril(np.ones((s, s), bool))
+    scores = np.where(causal, scores, -np.inf)
+    kth = np.sort(scores, -1)[..., ::-1][..., topk - 1:topk]
+    return jnp.asarray(causal & (scores >= kth), jnp.int8)
+
+
+def _random_mask(b, sq, sk, seed=0):
+    """A third of the pairs, and key 0 for every row (no empty row)."""
+    rng = np.random.default_rng(seed)
+    mask = rng.random((b, sq, sk)) < 0.3
+    mask[..., 0] = True
+    return jnp.asarray(mask, jnp.int8)
+
+
+# name -> (b, h, sq, sk, d, causal, the mask)
+MASK_CASES = {
+    "random": (2, 3, 256, 256, 64, False, lambda: _random_mask(2, 256, 256)),
+    "random_ragged": (1, 2, 200, 328, 48, False,
+                      lambda: _random_mask(1, 200, 328)),
+    "topk_causal": (2, 4, 256, 256, 64, True,
+                    lambda: _topk_causal_mask(2, 256, 64)),
+    # the mask's tile is counted: at this shape the forward's derived
+    # blocks are narrower than an unmasked call's
+    "narrower_blocks": (1, 1, 2048, 2048, 128, True,
+                        lambda: _topk_causal_mask(1, 2048, 256)),
+}
+
+
+def _masked_softmax(q, k, mask, causal):
+    """``(probabilities, lse)`` of a plain masked softmax at HIGHEST."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") \
+        / np.sqrt(q.shape[-1])
+    valid = mask[:, None] != 0
+    if causal:
+        valid = valid & np.tril(np.ones(s.shape[-2:], bool))
+    s = jnp.where(valid, s, -1e30)
+    return jax.nn.softmax(s, -1), jax.nn.logsumexp(s, -1), valid
+
+
+def _masked_reference(q, k, v, mask, causal):
+    p = _masked_softmax(q, k, mask, causal)[0]
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+
+def _mask_case(case):
+    b, h, sq, sk, d, causal, make = MASK_CASES[case]
+    rng = np.random.default_rng(1)
+    q = jnp.asarray(rng.standard_normal((b, h, sq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, h, sk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, h, sk, d)), jnp.float32)
+    return q, k, v, make(), causal
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", sorted(MASK_CASES))
+def test_masked_flash_matches_a_plain_masked_softmax(case, what):
+    q, k, v, mask, causal = _mask_case(case)
+    if case == "narrower_blocks":
+        sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
+        masked = fa.fwd_tiles(sq, sk, d, q.dtype, False, None, True)
+        plain = fa.fwd_tiles(sq, sk, d, q.dtype, False)
+        assert masked[0] * masked[1] < plain[0] * plain[1]
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, mask=mask,
+                               interpret=True)
+
+    def ref(q, k, v):
+        return _masked_reference(q, k, v, mask, causal)
+
+    if what == "out":
+        got, want = flash(q, k, v), ref(q, k, v)
+    else:
+        i = "qkv".index(what[1])
+        got, want = (jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))), i)(q, k, v)
+                     for f in (flash, ref))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+def test_a_mask_equal_to_the_causal_one_is_the_causal_call_bit_for_bit(
+        what):
+    """ANDed into what ``_key_mask`` gives, the diagonal changes nothing:
+    the same blocks, the same arithmetic, the same bits."""
+    q, k, v = _rand_qkv(b=2, h=2, s=256, d=64)
+    tril = jnp.asarray(np.tril(np.ones((2, 256, 256), np.int8)))
+    blocks = dict(block_q=128, block_k=128, bwd_block_q=128,
+                  bwd_block_k=128, interpret=True)
+
+    def run(**kw):
+        f = lambda q, k, v: flash_attention(q, k, v, causal=True, **blocks,
+                                            **kw)
+        if what == "out":
+            return f(q, k, v)
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))),
+                        "qkv".index(what[1]))(q, k, v)
+
+    assert np.array_equal(np.asarray(run(mask=tril)), np.asarray(run()))
+
+
+@pytest.mark.parametrize("case", sorted(MASK_CASES))
+def test_a_masked_calls_lse_is_the_masked_rows_own(case):
+    """The forward's row statistic is over the attended keys alone, and
+    the output beside it is the differentiable call's."""
+    q, k, v, mask, causal = _mask_case(case)
+    o, lse = fa.flash_attention_forward(q, k, v, mask, causal=causal,
+                                        interpret=True)
+    assert lse.shape == q.shape[:3] and lse.dtype == jnp.float32
+    want = _masked_softmax(q, k, mask, causal)[1]
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    assert np.array_equal(
+        np.asarray(o), np.asarray(flash_attention(
+            q, k, v, causal=causal, mask=mask, interpret=True)))
+    # rows that attend fewer keys have the smaller statistic than the
+    # unmasked call's, every one of them
+    full = fa.flash_attention_forward(
+        q, k, v, jnp.ones_like(mask), causal=causal, interpret=True)[1]
+    assert np.all(np.asarray(lse) <= np.asarray(full) + 1e-5)
+
+
+@pytest.mark.parametrize("case", sorted(MASK_CASES))
+def test_head_mean_is_the_mean_of_the_heads_softmax(case):
+    q, k, v, mask, causal = _mask_case(case)
+    _, lse = fa.flash_attention_forward(q, k, v, mask, causal=causal,
+                                        interpret=True)
+    got = fa.flash_attention_head_mean(q, k, lse, mask, causal=causal,
+                                       interpret=True)
+    p, _, valid = _masked_softmax(q, k, mask, causal)
+    assert got.shape == mask.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(p.mean(1)),
+                               atol=2e-6, rtol=2e-5)
+    # exactly 0 off the attended pairs, and each row sums to 1
+    assert not np.any(np.asarray(got)[~np.asarray(valid[:, 0])])
+    np.testing.assert_allclose(np.asarray(got).sum(-1), 1.0, atol=1e-5)
+
+
+def test_head_mean_passes_no_gradient():
+    q, k, v, mask, causal = _mask_case("topk_causal")
+    _, lse = fa.flash_attention_forward(q, k, v, mask, causal=causal,
+                                        interpret=True)
+    g = jax.grad(lambda q, k, lse: jnp.sum(fa.flash_attention_head_mean(
+        q, k, lse, mask, causal=causal, interpret=True) ** 2),
+        (0, 1, 2))(q, k, lse)
+    assert not any(np.any(np.asarray(x)) for x in g)
+
+
+@pytest.mark.parametrize("sq,sk,d,dtype", [
+    (8192, 8192, 128, "bfloat16"), (4096, 4096, 128, "bfloat16"),
+    (2048, 2048, 128, "float32"), (1024, 1024, 64, "float32")])
+def test_the_tile_rules_count_the_mask(sq, sk, d, dtype):
+    """A masked call's blocks fit the budget with the mask's tile
+    counted, and are no larger than the unmasked call's; the head-mean
+    kernel's tile fits too."""
+    dtype = jnp.dtype(dtype)
+    it = dtype.itemsize
+    bq, bk = fa.fwd_tiles(sq, sk, d, dtype, False, None, True)
+    assert fa._fwd_vmem_bytes(bq, bk, d, it, False, None, True) \
+        <= fa.BWD_VMEM_BUDGET
+    plain = fa.fwd_tiles(sq, sk, d, dtype, False)
+    assert bq * bk <= plain[0] * plain[1]
+    assert fa._fwd_vmem_bytes(bq, bk, d, it, False, None, True) \
+        - fa._fwd_vmem_bytes(bq, bk, d, it, False) >= 2 * bq * bk
+    for kernel, tile, was in zip(
+            ("bwd_dq", "bwd_dkv"),
+            fa.bwd_tiles(sq, sk, d, dtype, False, None, True),
+            fa.bwd_tiles(sq, sk, d, dtype, False)):
+        assert fa._bwd_vmem_bytes(kernel, *tile, d, it, False, None, True) \
+            <= fa.BWD_VMEM_BUDGET
+        assert tile[0] * tile[1] <= was[0] * was[1]
+    tile = fa.head_mean_tiles(sq, sk, d, dtype)
+    assert fa._head_mean_vmem_bytes(*tile, d, it) <= fa.BWD_VMEM_BUDGET
+    assert sq % tile[0] == 0 and sk % tile[1] == 0
+
+
+def test_a_block_policy_that_keeps_o_and_lse_skips_the_forward_kernel():
+    """``flash_attention_from_forward`` takes the forward's output and
+    log-sum-exp as ARGUMENTS: named for a ``jax.checkpoint`` policy they
+    are kept, and the differentiated step holds one forward call, not
+    two; unnamed, the forward runs again for the backward."""
+    from jax.ad_checkpoint import checkpoint_name
+    q, k, v, mask, causal = _mask_case("topk_causal")
+
+    def layer(named, q, k, v):
+        o, lse = fa.flash_attention_forward(q, k, v, mask, causal=causal,
+                                            interpret=True)
+        if named:
+            o, lse = (checkpoint_name(x, "kept") for x in (o, lse))
+        return fa.flash_attention_from_forward(q, k, v, mask, o, lse,
+                                               causal=causal, interpret=True)
+
+    def forward_calls(named):
+        block = jax.checkpoint(
+            functools.partial(layer, named),
+            policy=jax.checkpoint_policies.save_only_these_names("kept"))
+        txt = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(block(*a))), (0, 1, 2)))(q, k, v))
+        return txt.count("name=flash_attention_fwd")
+
+    assert (forward_calls(True), forward_calls(False)) == (1, 2)
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(jax.checkpoint(
+        functools.partial(layer, True),
+        policy=jax.checkpoint_policies.save_only_these_names("kept"))(*a))),
+        (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(_masked_reference(
+        *a, mask, causal))), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=5e-5, rtol=5e-5)
+
+
+def test_a_masked_call_checks_its_mask_and_runs_on_one_device():
+    q, k, v = _rand_qkv(b=2, h=2, s=128, d=64)
+    with pytest.raises(ValueError, match="batch, sq, sk"):
+        flash_attention(q, k, v, mask=jnp.ones((2, 2, 128, 128), jnp.int8),
+                        interpret=True)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("x",))
+    with pytest.raises(NotImplementedError, match="one device"):
+        flash_attention(q, k, v, mask=jnp.ones((2, 128, 128), jnp.int8),
+                        interpret=True, mesh=mesh, spec=P("x"))
+
+
+def test_a_masked_call_says_so_in_its_grid_instants():
+    q, k, v, mask, causal = _mask_case("topk_causal")
+    events.enable()
+    try:
+        events.clear()
+        jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, causal=causal, mask=mask, interpret=True)))(q)
+        _, lse = fa.flash_attention_forward(q, k, v, mask, causal=causal,
+                                            interpret=True)
+        fa.flash_attention_head_mean(q, k, lse, mask, causal=causal,
+                                     interpret=True)
+        grids = [e["attrs"] for e in events.events()
+                 if e["name"] == "flash.grid"]
+    finally:
+        events.disable()
+    by_kernel = {g["kernel"]: g for g in grids}
+    assert set(by_kernel) == {
+        "flash_attention_fwd", "flash_attention_bwd_dq",
+        "flash_attention_bwd_dkv", "flash_attention_head_mean"}
+    assert all(g.get("masked") for name, g in by_kernel.items()
+               if name != "flash_attention_head_mean")

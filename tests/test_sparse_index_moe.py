@@ -19,6 +19,7 @@ the same sets exactly.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,7 @@ from flexflow_tpu.ops import sparse_attention as dsa
 from flexflow_tpu.ops.moe_ops import RoutedExpertsOp, route
 from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp
 from flexflow_tpu.ops.registry import EmitCtx
+from flexflow_tpu.parallel.machine import MachineSpec
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -62,17 +64,24 @@ def sizes_of(mc):
                 or mc.num_experts)
 
 
-def build(remat="none", model_cfg=None, seq=S):
+def build(remat="none", model_cfg=None, seq=S, impl=None):
     cfg = FFConfig()
     cfg.batch_size = B
     cfg.only_data_parallel = True        # no search: 0.3 s a compile
     cfg.use_bf16_compute = False
     cfg.remat = remat
+    if impl:
+        cfg.kernel_impls = f"attention:{impl}"
     ff = FFModel(cfg)
     mc = model_cfg or KeyeRankConfig.tiny()
     out = build_hybrid_conv_moe(ff, B, seq, mc)
+    # a forced path runs on a mesh of one device, as the benchmark's
+    # chip is: on more the masked kernels have no shard_map wrap and the
+    # layer stays on the chunked path
+    one = {"machine_spec": MachineSpec.detect(jax.devices()[:1])} \
+        if impl else {}
     ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
+               output_tensor=out, **one)
     return ff, mc
 
 
@@ -227,10 +236,13 @@ def attn_weights(seed=0, e=32):
             "w_idx": w(e, J, scale=4.0)}
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def attn_op(x, pos, w, topk, q_chunk, training=True):
-    """``(y, L_I, counters)`` of the op with an indexer."""
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def attn_op(x, pos, w, topk, q_chunk, training=True, impl=None):
+    """``(y, L_I, counters)`` of the op with an indexer; ``impl``
+    forces its path (on the CPU it takes the chunked one by itself)."""
     ctx = f32_ctx(training)
+    if impl:
+        ctx.kernel_impls = {"attention": impl}
     params = dict(ATTN_PARAMS, indexer_heads=J, indexer_head_dim=C,
                   indexer_topk=topk, indexer_q_chunk=q_chunk)
     (y,) = MultiHeadAttentionOp().emit(params, [x, x, x, pos], w, ctx,
@@ -383,16 +395,167 @@ def test_an_indexer_is_refused_where_it_is_not_built(what, kwargs):
 
 
 @pytest.mark.parametrize("impl", ["flash", "ring"])
-def test_a_kernel_forced_on_a_layer_with_an_indexer_is_refused(impl):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True
-    cfg.kernel_impls = f"attention:{impl}"
-    ff = FFModel(cfg)
-    out = build_hybrid_conv_moe(ff, B, 32, KeyeRankConfig.tiny())
-    with pytest.raises(Exception, match="selected keys|sequence axis"):
-        ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy",
-                   [], output_tensor=out)
+def test_a_kernel_forced_on_a_layer_with_an_indexer(impl):
+    """``ring`` takes no mask and is refused; ``flash`` runs (in
+    interpret mode here) and its step is the chunked path's."""
+    if impl == "ring":
+        cfg = FFConfig()
+        cfg.batch_size = B
+        cfg.only_data_parallel = True
+        cfg.kernel_impls = "attention:ring"
+        ff = FFModel(cfg)
+        out = build_hybrid_conv_moe(ff, B, 32, KeyeRankConfig.tiny())
+        with pytest.raises(Exception, match="selected keys|sequence axis"):
+            ff.compile(AdamOptimizer(1e-3),
+                       "sparse_categorical_crossentropy", [],
+                       output_tensor=out)
+        return
+    steps = {}
+    for forced in ("xla", "flash"):
+        ff, mc = build(remat="blocks", impl=forced)
+        step, batch = ff.executor.make_train_step(), data(mc)
+        p, o, st, first = step(ff.params, ff.opt_state, ff.state,
+                               jnp.int32(0), batch)
+        _, _, _, second = step(p, o, st, jnp.int32(1), batch)
+        assert set(ff.executor.resolved_attention_impls.values()) == {forced}
+        steps[forced] = (first, second)
+    # the second step's loss is a function of every gradient of the first
+    for chunked, kernels in zip(steps["xla"], steps["flash"]):
+        close(kernels["loss"], chunked["loss"], 1e-5)
+        assert float(chunked[COUNTER_PREFIX + "dsa.kernel_layers"]) == 0.0
+        assert float(kernels[COUNTER_PREFIX + "dsa.kernel_layers"]) \
+            == float(kernels[COUNTER_PREFIX + "dsa.layers"]) == 4.0
+        close(kernels[COUNTER_PREFIX + "dsa.index_kl"],
+              chunked[COUNTER_PREFIX + "dsa.index_kl"], 1e-5)
+    assert float(steps["flash"][1]["loss"]) < float(steps["flash"][0]["loss"])
+
+
+# ----------------------------------------------------------------------
+# the kernel path (the flash kernels under the selection as their mask)
+# against the chunked path
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("seq,topk,q_chunk", [
+    (48, 12, 16), (40, 12, 16), (64, 24, 8), (33, 5, 7), (24, 64, 16)])
+def test_the_kernel_path_is_the_chunked_path(seq, topk, q_chunk):
+    """Output, ``L_I``, the counters and both families of gradients, for
+    sequences that fill their chunks and tiles and ones that do not, and
+    one no longer than ``topk`` (every causal key selected)."""
+    x, pos = attn_inputs(seq)
+    w = attn_weights()
+
+    def both(impl):
+        def f(x, w):
+            y, kl, counted = attn_op(x, pos, w, topk, q_chunk, True, impl)
+            return jnp.sum(y * jnp.cos(y)) + kl, (y, kl, counted)
+        return jitted(jax.value_and_grad(f, (0, 1), has_aux=True))(x, w)
+
+    ((v1, (y1, kl1, c1)), (gx1, gw1)) = both("xla")
+    ((v2, (y2, kl2, c2)), (gx2, gw2)) = both("flash")
+    close(y2, y1, 1e-5)
+    close(kl2, kl1, 1e-5)
+    close(v2, v1, 1e-5)
+    for key in ("dsa.kept_pairs", "dsa.causal_pairs", "dsa.threshold_ties",
+                "dsa.layers"):
+        assert float(c2[key]) == float(c1[key]), key
+    assert (float(c1["dsa.kernel_layers"]),
+            float(c2["dsa.kernel_layers"])) == (0.0, 1.0)
+    close(gx2, gx1, 1e-5)
+    for k in gw1:
+        close(gw2[k], gw1[k], 1e-5)
+
+
+def test_the_kernel_paths_losses_reach_disjoint_weights_exactly():
+    """As on the chunked path: ``L_I`` moves the indexer alone and the
+    output everything but the indexer, zeros to the last bit."""
+    x, pos = attn_inputs(48)
+    w = attn_weights()
+    index_keys = {"wq_idx", "wk_idx", "w_idx"}
+    gx, gw = jitted(jax.grad(
+        lambda x, w: attn_op(x, pos, w, 12, 16, True, "flash")[1],
+        (0, 1)))(x, w)
+    assert not np.any(np.asarray(gx))
+    for k, g in gw.items():
+        assert bool(np.any(np.asarray(g))) == (k in index_keys), k
+    gx, gw = jitted(jax.grad(
+        lambda x, w: jnp.sum(jnp.sin(
+            attn_op(x, pos, w, 12, 16, True, "flash")[0])), (0, 1)))(x, w)
+    assert np.any(np.asarray(gx))
+    for k, g in gw.items():
+        assert bool(np.any(np.asarray(g))) == (k not in index_keys), k
+
+
+@pytest.mark.parametrize("remat", ["none", "blocks"])
+def test_the_kernel_paths_step_is_the_chunked_paths(remat):
+    """The model's loss with its four ``L_I``, the counters and every
+    gradient, alone and inside a rematerialised step."""
+    chunked, mc = build(remat=remat, impl="xla")
+    kernels, _ = build(remat=remat, impl="flash")
+    batch = data(mc)
+    params = spread(chunked.params)
+
+    def both(ff):
+        def f(p):
+            ce, kl, bm, _ = program_terms(ff, p, batch)
+            return ce + kl, (kl, bm)
+        return jitted(jax.value_and_grad(f, has_aux=True))(params)
+
+    (l1, (kl1, bm1)), g1 = both(chunked)
+    (l2, (kl2, bm2)), g2 = both(kernels)
+    assert float(kl1) > 0.1
+    close(l2, l1, 1e-6)
+    close(kl2, kl1, 1e-5)
+    for key in bm1:
+        if key.startswith(COUNTER_PREFIX) and "kernel_layers" not in key:
+            close(bm2[key], bm1[key], 1e-6)
+    assert float(bm2[COUNTER_PREFIX + "dsa.kernel_layers"]) == 4.0
+    for name, ws in g1.items():
+        for k in ws:
+            close(g2[name][k], ws[k], 2e-5)
+
+
+def test_the_path_taken_is_on_the_record():
+    """``resolved_attention_impls`` and each layer's ``attn.sparse_index``
+    instant name the path the trace emitted."""
+    from flexflow_tpu.obs import events
+    events.enable()
+    try:
+        for impl in ("xla", "flash"):
+            events.clear()
+            ff, mc = build(impl=impl)
+            jax.eval_shape(lambda p: program_terms(ff, p, data(mc)),
+                           ff.params)
+            seen = [e["attrs"]["impl"] for e in events.events()
+                    if e["name"] == "attn.sparse_index"]
+            assert seen and set(seen) == {impl}
+            assert set(ff.executor.resolved_attention_impls.values()) \
+                == {impl}
+    finally:
+        events.clear()
+        events.disable()
+
+
+def test_a_rematerialised_block_keeps_what_the_kernel_path_names():
+    """``remat = "blocks"``: the differentiated step calls the forward
+    kernel once a layer (the block keeps its output and log-sum-exp, and
+    the mask: no second selection either), dq and dkv once, and the
+    head-mean kernel for the loss's value and for its backward. The
+    jaxpr holds a third head-mean call a layer, the block's second run
+    of the loss's value behind the layer's optimization barrier; nothing
+    reads it and XLA drops it (the chip's compiled step holds 5 kernel
+    calls a layer, PERF.md section 5)."""
+    ff, mc = build(remat="blocks", impl="flash")
+    batch = data(mc)
+
+    def f(p):
+        ce, kl, _, _ = program_terms(ff, p, batch)
+        return ce + kl
+    txt = str(jax.make_jaxpr(jax.grad(f))(ff.params))
+    layers = mc.num_hidden_layers
+
+    def calls(kernel):
+        return len(re.findall(rf"name=flash_attention_{kernel}\b", txt))
+    assert [calls(k) for k in ("fwd", "bwd_dq", "bwd_dkv")] == [layers] * 3
+    assert calls("head_mean") == 3 * layers
 
 
 def test_an_indexer_has_no_key_value_cache():

@@ -645,3 +645,110 @@ def test_the_experts_layer_compiles_through_the_token_sum_kernel(
     assert len(mine) == calls
     assert all('/experts_1/' in l.split('op_name="')[1].split('"')[0]
                for l in mine)
+
+
+# ----------------------------------------------------------------------
+# the flash kernels' mask operand and the head-mean kernel (PR 49)
+# ----------------------------------------------------------------------
+def test_the_masked_kernels_compile_at_cell_7s_shapes(v5e_devices,
+                                                       chip_locations):
+    """``keye_vl2_30b_a3b.train.1chip``: one sequence of 8,192, 32 heads
+    of 128, bf16, causal, an int8 mask a batch row: the three kernels at
+    the blocks derived with the mask's tile counted, then the heads'
+    mean probability, which writes ONE (b, s, s) float32 array."""
+    import importlib
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    b, h, s, d = 1, 32, 8192, 128
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    qkv = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=one)
+    mask = jax.ShapeDtypeStruct((b, s, s), jnp.int8, sharding=one)
+    lse = jax.ShapeDtypeStruct((b, h, s), jnp.float32, sharding=one)
+    assert fa.fwd_tiles(s, s, d, jnp.bfloat16, False, None, True) \
+        != fa.fwd_tiles(s, s, d, jnp.bfloat16, False)
+
+    def loss(q, k, v, m):
+        o = flash_attention(q, k, v, causal=True, mask=m, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv,
+                        mask)
+    assert _kernel_names(txt) == FLASH_NAMES
+    # every call reads an int8 tile of the one mask (dkv its transpose)
+    calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+    assert all(f"s8[{b},{s},{s}]" in l for l in calls)
+    # and nothing with a head axis over (queries, keys) exists
+    assert not re.search(rf"\[({h}|{b},{h}|{b * h}),{s},{s}\]", txt)
+
+    txt = _compile_text(
+        lambda q, k, l, m: fa.flash_attention_head_mean(
+            q, k, l, m, causal=True, interpret=False), qkv, qkv, lse, mask)
+    assert _kernel_names(txt) == ["flash_attention_head_mean"]
+    assert f"f32[{b},{s},{s}]" in txt
+    assert not re.search(rf"\[({h}|{b},{h}|{b * h}),{s},{s}\]", txt)
+
+
+def _without_debug_info(lowered: str) -> str:
+    """A lowered module's text with each Mosaic call's serialized body
+    replaced by the body's assembly WITHOUT locations (which carry this
+    checkout's path and the kernels' line numbers)."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        cfg = match.group(1).replace("\\22", '"').replace("\\5C", "\\")
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(
+                json.loads(cfg)["custom_call_config"]["body"]))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'backend_config = "(.*?)"(?=[,}\s])', body, lowered,
+                  flags=re.S)
+
+
+# sha256 of the three calls' lowered text (forward + backward of a sum,
+# bf16, for a described v5e, locations stripped) at the shapes cells 1
+# to 6 call the kernels with, taken from the parent of PR 49 (``git
+# archive 385e301``) by these same lines: with ``mask=None`` the kernels
+# lower to what they were, operand for operand and tile for tile. A PR
+# that means to change the unmasked kernels replaces the hashes.
+UNMASKED_SHA256 = {
+    "cell1_bert_large":
+        "d79e1659cd00bba44104e458da1d69e14e2dd4225a4485d0cbb9cae678031d94",
+    "cell2_gpt2_124m":
+        "deceda128c3c6cf885ec0c971352e2e78e918a35c6d14ae8115b789b7d267af9",
+    "cell3_joyai_cell5_kimi":
+        "d2f353e9636ffe5607b90e41665c8fefbbc4d8805e19ed22587a527aa995bae0",
+    "cell4_lfm2":
+        "e4d977a36d40ad0554e6e0c9a674bd084d4a53970153ac384880b090a95eea3a",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_without_a_mask_the_kernels_lower_to_the_parents_text(
+        v5e_devices, cell):
+    import hashlib
+    b, h, s, d, dv, causal, rate = CELL_SHAPES[cell]
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    qk = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((b, h, s, dv), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, interpret=False,
+                            dropout_rate=rate,
+                            dropout_seed=jnp.int32(3) if rate else None)
+        return jnp.sum(o.astype(jnp.float32))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).as_text()
+    assert lowered.count("tpu_custom_call") == 3
+    text = _without_debug_info(lowered)
+    assert "stable_mosaic" in text and "loc(" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == UNMASKED_SHA256[cell]

@@ -901,6 +901,15 @@ def leg_sparse_index_moe(model_cfg, seq: int, per_chip_batch: int,
     ran = ctr.get("dsa.kernel_layers", 0) / max(1.0, ctr.get("dsa.layers", 0))
     check(ran == float(want_impl == "flash"),
           f"{label}: the kernels ran in {ran} of the layer-steps")
+    # q/k norm and rotary embedding in one kernel where the flash path
+    # runs and a head is whole lanes (``kernels/qk_norm_rope``)
+    fused = ctr.get("attn.norm_rope_kernel_layers", 0) / max(
+        1.0, ctr.get("dsa.layers", 0))
+    head_dim = model_cfg.head_dim \
+        or model_cfg.hidden_size // model_cfg.num_attention_heads
+    check(fused == float(want_impl == "flash" and head_dim % 128 == 0),
+          f"{label}: q and k went through the norm-and-rotary kernel in "
+          f"{fused} of the layer-steps (heads of {head_dim})")
     topk = model_cfg.sa_config["topk"]
     want = sum(min(t + 1, topk) for t in range(seq)) / (seq * (seq + 1) / 2)
     share = ctr.get("dsa.kept_pairs", 0) / max(

@@ -43,9 +43,10 @@ failure):
      for another of the same expected weight;
   4. at 4096 positions (half the queries select; the reference's layers
      under ``jax.checkpoint`` so that its backward fits): the loss and
-     its gradient for attention's ``wq`` and ``q_norm``, a router and one
-     held expert (the cross-entropy's) and for the indexer's three
-     matrices (``L_I``'s), against ``jax.grad`` of the reference's loss,
+     its gradient for attention's ``wq``, ``q_norm``, ``wk`` and
+     ``k_norm``, a router and one held expert (the cross-entropy's) and
+     for the indexer's three matrices (``L_I``'s), against ``jax.grad``
+     of the reference's loss,
      each held to twice what the reference itself reads with bf16
      operands; each expert layer's row budget beside what its router
      sent this share, for the kernel path and the chunked path, and the
@@ -97,6 +98,13 @@ PATHS = (("kernels", None), ("chunked", "xla"))
 def kernel_share(bm):
     return bm[COUNTER_PREFIX + "dsa.kernel_layers"] \
         / bm[COUNTER_PREFIX + "dsa.layers"]
+
+
+def norm_rope_share(counters, prefix=COUNTER_PREFIX):
+    """The layers whose q and k went through ``kernels/qk_norm_rope``
+    over all of them (the counter exists only where one did)."""
+    return counters.get(prefix + "attn.norm_rope_kernel_layers", 0.0) \
+        / counters[prefix + "dsa.layers"]
 
 
 def attention_layers(ff):
@@ -164,7 +172,8 @@ def forward_checks(conf, ref, seq, seeds):
                      / bm[COUNTER_PREFIX + "dsa.causal_pairs"],
                      "threshold_ties":
                          bm[COUNTER_PREFIX + "dsa.threshold_ties"],
-                     "kernel_layers_share": kernel_share(bm)},
+                     "kernel_layers_share": kernel_share(bm),
+                     "norm_rope_kernel_share": norm_rope_share(bm)},
                     {l.name: capture[l.inputs[0].guid]
                      for l in attention_layers(ff)})
         return run
@@ -235,7 +244,14 @@ def forward_checks(conf, ref, seq, seeds):
                   f"{'every' if path == 'kernels' else 'no'} layer",
                   r["kernel_layers_share"] == float(path == "kernels"),
                   f"dsa.kernel_layers / dsa.layers = "
-                  f"{r['kernel_layers_share']}")
+                  f"{r['kernel_layers_share']}; "
+                  f"attn.norm_rope_kernel_layers / dsa.layers = "
+                  f"{r['norm_rope_kernel_share']}")
+            check(f"{tag} {path}: q and k through the norm-and-rotary "
+                  f"kernel in {'every' if path == 'kernels' else 'no'} "
+                  f"layer",
+                  r["norm_rope_kernel_share"] == float(path == "kernels"),
+                  f"{r['norm_rope_kernel_share']}")
         check(f"{tag} the kernel path is the chunked path",
               errs["kernels against chunked"]
               <= errs["bf16, routers float32"]
@@ -271,7 +287,8 @@ def gradient_checks(conf, ref, seed, seq=4096):
     ff.params, ff.state = ff.executor.init_params_and_state(
         jax.random.key(seed))
     sizes, batch = dict(conf), batch_of(conf, seq, seed)
-    picked = (("attn_1", "wq"), ("attn_1", "q_norm"), ("experts_2", "wg"),
+    picked = (("attn_1", "wq"), ("attn_1", "q_norm"), ("attn_1", "wk"),
+              ("attn_1", "k_norm"), ("experts_2", "wg"),
               ("experts_2", "w_gate"), ("attn_1", "wq_idx"),
               ("attn_1", "wk_idx"), ("attn_1", "w_idx"),
               ("attn_3", "w_idx"))
@@ -305,7 +322,7 @@ def gradient_checks(conf, ref, seed, seq=4096):
         of_path = {}
         for path, model in models.items():
             of_path[path] = jax.device_get(
-                program_grads(model, batch, pick, ("moe.", "dsa."))(
+                program_grads(model, batch, pick, ("moe.", "dsa.", "attn."))(
                     ff.params))
             jax.clear_caches()
         lr, gr = jax.device_get(jax.jit(reference_grads)(ff.params))
@@ -320,10 +337,12 @@ def gradient_checks(conf, ref, seed, seq=4096):
     for path, (lp, gp, counters) in of_path.items():
         check_budget(models[path], seq, counters, False)
         share = float(counters["dsa.kernel_layers"] / counters["dsa.layers"])
+        fused = float(norm_rope_share(counters, ""))
         check(f"{path}: the kernels ran in "
               f"{'every' if path == 'kernels' else 'no'} layer-step",
-              share == float(path == "kernels"),
-              f"dsa.kernel_layers / dsa.layers = {share}")
+              share == float(path == "kernels") and fused == share,
+              f"dsa.kernel_layers / dsa.layers = {share}, "
+              f"attn.norm_rope_kernel_layers / dsa.layers = {fused}")
         e = abs(float(lp) - float(lr)) / float(lr)
         READINGS["loss"][path] = float(lp)
         check(f"{path} loss (with the four L_I)", e <= 2 * eb + 1e-4,

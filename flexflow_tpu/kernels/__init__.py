@@ -22,6 +22,12 @@ with:
     the kernel's own DMAs and added by index, taken by
     ``ops/moe_ops.py`` where the shapes allow (whole lanes, fewer rows
     than ``top_k`` arrays of tokens, one device); not a registry entry.
+  - ``qk_norm_rope``: an attention layer's q and k from the projections'
+    float32 output to the flash kernels' operand in one pass (the heads'
+    RMSNorm, the rotary embedding, the cast, the turn to heads-first;
+    fwd+bwd), taken by ``ops/nn_ops.py::MultiHeadAttentionOp`` where a
+    head is whole lanes and the layer is on the flash path on one
+    device; not a registry entry.
 
 Each op chooses its kernel from what it can observe (shapes, dropout,
 platform). ``registry`` holds the one override: attention's three names,

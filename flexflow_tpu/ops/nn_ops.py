@@ -614,6 +614,36 @@ class MultiHeadAttentionOp(OpDef):
 
         return mesh, P(entry(out, 0, batch), entry(wq, 1, heads))
 
+    def _takes_norm_rope_kernel(self, params, ctx, name, qh, kh, vh, rate,
+                                mdt) -> bool:
+        """Whether q and k go from the projections to the flash kernels
+        through ``kernels/qk_norm_rope`` (norm, rotary embedding, cast
+        and the turn to heads-first in one pass), from what the op can
+        observe: both q/k norm and rotary embedding, heads in whole
+        lanes, the full training or eval forward (no key/value cache),
+        one device, and a path that hands q and k to the flash kernels
+        heads-first (this method's last line is the test
+        :meth:`emit` and :meth:`_emit_sparse` make). Every other layer
+        keeps ``_rms``, ``_apply_rope`` and its own turn."""
+        if not (params.get("qk_norm", False) and params.get("rope", False)) \
+                or getattr(ctx, "kv_mode", None) is not None \
+                or not params.get("causal", False) \
+                or qh.shape[1] != kh.shape[1] or qh.shape[2] % kh.shape[2]:
+            return False
+        mesh = getattr(ctx, "mesh", None)
+        if (mesh is not None and mesh.size > 1) \
+                or getattr(ctx, "local_shape", False):
+            return False
+        from ..kernels import qk_norm_rope as nrk
+        _, s, h, d = qh.shape
+        kv = kh.shape[2]
+        impl = self._impl_for(ctx, name)
+        return (impl != "ring" and nrk.takes_kernel(s, h, d, 1, mdt)
+                and nrk.takes_kernel(s, kv, d, h // kv, mdt)
+                and self._flash_enabled(
+                    impl, s, s, d, vh.shape[-1], rate, causal=True,
+                    window=params.get("sliding_window", 0)))
+
     def emit(self, params, inputs, weights, ctx, name):
         # an optional fourth input: (B, L) int32 positions that the
         # rotary embedding turns by (default: 0 .. L - 1)
@@ -634,18 +664,28 @@ class MultiHeadAttentionOp(OpDef):
         qh = proj(q, weights["wq"], weights.get("bq"))
         kh = proj(k, weights["wk"], weights.get("bk"))
         vh = proj(v, weights["wv"], weights.get("bv"))
+        # qh.shape[2], not params["num_heads"]: under the tp attn role
+        # this code runs inside shard_map with LOCAL head counts
+        heads = qh.shape[2]
+        rate = params.get("dropout", 0.0) if ctx.training else 0.0
+        # q and k from the projections to the flash kernels' operands in
+        # one kernel (norm, rotary embedding, cast, heads-first): from
+        # here on they are (B, h, L, d) in ``mdt`` where ``fused``
+        fused = self._takes_norm_rope_kernel(params, ctx, name, qh, kh, vh,
+                                             rate, mdt)
         if params.get("qk_norm", False):
             # RMSNorm over each head's own entries, before the rotary
             # embedding; ahead of the decode branch, so the cache holds
             # normed (and rotated) keys
             eps = params.get("qk_norm_eps", 1e-6)
-            qh = _rms(qh, weights["q_norm"], eps)
-            kh = _rms(kh, weights["k_norm"], eps)
+            if not fused:
+                qh = _rms(qh, weights["q_norm"], eps)
+                kh = _rms(kh, weights["k_norm"], eps)
             if events.enabled():
                 events.instant("attn.qk_norm", layer=name, heads=h,
                                kv_heads=kh.shape[2], head_dim=qh.shape[-1],
-                               tokens=qh.shape[0] * qh.shape[1])
-        rate = params.get("dropout", 0.0) if ctx.training else 0.0
+                               tokens=qh.shape[0] * qh.shape[1],
+                               impl="kernel" if fused else "xla")
 
         causal = params.get("causal", False)
         kv_mode = getattr(ctx, "kv_mode", None)
@@ -671,8 +711,18 @@ class MultiHeadAttentionOp(OpDef):
                 pos = positions[0]
             else:
                 pos = jnp.arange(qh.shape[1], dtype=jnp.int32)
-            qh = _apply_rope(qh, pos, theta)
-            kh = _apply_rope(kh, pos, theta)
+            if fused:
+                from ..kernels import qk_norm_rope as nrk
+                tables = nrk.rope_tables(pos, qh.shape[-1], theta)
+                qh = nrk.qk_norm_rope(qh, weights["q_norm"], tables,
+                                      eps=eps, dtype=mdt)
+                kh = nrk.qk_norm_rope(kh, weights["k_norm"], tables,
+                                      eps=eps, dtype=mdt,
+                                      repeat=heads // kh.shape[2])
+                ctx.count("attn.norm_rope_kernel_layers", jnp.float32(1.0))
+            else:
+                qh = _apply_rope(qh, pos, theta)
+                kh = _apply_rope(kh, pos, theta)
         if params.get("indexer_heads") and (
                 kv_mode is not None or not causal or rate > 0.0
                 or params.get("sliding_window", 0)):
@@ -712,13 +762,12 @@ class MultiHeadAttentionOp(OpDef):
                                      vh, mdt, cdt)
         if params.get("indexer_heads"):
             return self._emit_sparse(params, q, weights, ctx, name, qh, kh,
-                                     vh, mdt, cdt)
+                                     vh, mdt, cdt, fused)
         # GQA: expand kv-head groups to the query head count for the
-        # attention contraction (cache/weights stay at kvh heads).
-        # qh.shape[2], not params["num_heads"]: under the tp attn role
-        # this code runs inside shard_map with LOCAL head counts
-        kh = self._expand_kv(kh, qh.shape[2])
-        vh = self._expand_kv(vh, qh.shape[2])
+        # attention contraction (cache/weights stay at kvh heads)
+        if not fused:                  # the kernel has repeated its k
+            kh = self._expand_kv(kh, heads)
+        vh = self._expand_kv(vh, heads)
         impl = self._impl_for(ctx, name)
         if impl == "ring" and kv_mode is None:
             if rate > 0.0:
@@ -729,9 +778,9 @@ class MultiHeadAttentionOp(OpDef):
             self._note_impl(ctx, name, "ring")
             return self._emit_ring(weights, ctx, name, qh, kh, vh, mdt,
                                    cdt, causal)
-        if self._flash_enabled(impl, qh.shape[1], kh.shape[1], qh.shape[-1],
-                               vh.shape[-1], rate, causal=causal,
-                               window=params.get("sliding_window", 0)):
+        if fused or self._flash_enabled(
+                impl, qh.shape[1], kh.shape[1], qh.shape[-1], vh.shape[-1],
+                rate, causal=causal, window=params.get("sliding_window", 0)):
             # Pallas flash kernel ((b,h,s,d) layout); dropout on the
             # probabilities is counter-based and in-kernel, compiled on
             # TPU and in interpret mode alike, seeded from this layer's
@@ -742,11 +791,11 @@ class MultiHeadAttentionOp(OpDef):
                 seed = jax.random.randint(ctx.rng_for(name), (),
                                           0, 2 ** 31 - 1, jnp.int32)
             self._note_impl(ctx, name, "flash")
-            mesh, spec = self._kernel_shard_spec(
-                ctx, qh.shape[0], qh.shape[2])
+            mesh, spec = self._kernel_shard_spec(ctx, qh.shape[0], heads)
             o = flash_attention(
-                jnp.swapaxes(qh, 1, 2).astype(mdt),
-                jnp.swapaxes(kh, 1, 2).astype(mdt),
+                *((qh, kh) if fused else
+                  (jnp.swapaxes(qh, 1, 2).astype(mdt),
+                   jnp.swapaxes(kh, 1, 2).astype(mdt))),
                 jnp.swapaxes(vh, 1, 2).astype(mdt),
                 causal=causal,
                 dropout_rate=rate, dropout_seed=seed,
@@ -796,7 +845,7 @@ class MultiHeadAttentionOp(OpDef):
         return bool(params.get("indexer_heads"))
 
     def _emit_sparse(self, params, x, weights, ctx, name, qh, kh, vh, mdt,
-                     cdt):
+                     cdt, fused=False):
         """The layer with an indexer (``indexer_heads`` in its
         parameters): attention over the ``indexer_topk`` keys a query's
         index scores select (``ops/sparse_attention``). Two paths of the
@@ -810,20 +859,22 @@ class MultiHeadAttentionOp(OpDef):
         the layer's input detached and its alignment loss joins the
         step's through ``ctx.aux_losses`` with weight 1; where the
         sequence is no longer than ``indexer_topk`` every causal key is
-        selected and the output is the plain causal path's."""
+        selected and the output is the plain causal path's. ``fused``:
+        ``qh`` and ``kh`` come heads-first in ``mdt`` from
+        ``kernels/qk_norm_rope`` (only ever on the kernel path)."""
         from . import sparse_attention as dsa
         topk, q_chunk = params["indexer_topk"], params["indexer_q_chunk"]
         with jax.named_scope("dsa.index"):
             qi, ki, wi = dsa.indexer_inputs(x, weights, mdt)
-        s = qh.shape[1]
+        s = x.shape[1]
         impl = self._impl_for(ctx, name)
         if impl == "ring":
             raise ValueError(f"{name}: kernel impl 'ring' takes no mask "
                              f"of selected keys")
-        kernels = self._kernel_shard_spec(ctx, qh.shape[0],
-                                          qh.shape[2])[0] is None \
+        kernels = fused or (
+            self._kernel_shard_spec(ctx, qh.shape[0], qh.shape[2])[0] is None
             and self._flash_enabled(impl, s, s, qh.shape[-1], vh.shape[-1],
-                                    causal=True)
+                                    causal=True))
         path = "flash" if kernels else "xla"
         self._note_impl(ctx, name, path)
         if events.enabled():
@@ -832,10 +883,13 @@ class MultiHeadAttentionOp(OpDef):
                            head_dim=params["indexer_head_dim"], topk=topk,
                            q_chunk=q_chunk, chunks=-(-s // q_chunk),
                            selecting=s > topk, positions=s, impl=path)
-        attend = dsa.sparse_index_attention_flash if kernels \
-            else dsa.sparse_index_attention
-        o, loss, kept, ties = attend(qh, kh, vh, qi, ki, wi, topk, q_chunk,
-                                     mdt)
+        if kernels:
+            o, loss, kept, ties = dsa.sparse_index_attention_flash(
+                qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt,
+                qk_heads_first=fused)
+        else:
+            o, loss, kept, ties = dsa.sparse_index_attention(
+                qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt)
         # either path rematerialises itself: a rematerialised block
         # around the layer keeps the attention's output (the output
         # projection's backward reads it; the kernel path marks its own,

@@ -309,10 +309,14 @@ _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 
 def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
-                                 q_chunk: int, mdt):
+                                 q_chunk: int, mdt, *,
+                                 qk_heads_first: bool = False):
     """:func:`sparse_index_attention` through the flash kernels: the
     same arguments, the same ``(o, loss, kept, ties)``. ``o`` is in
-    ``mdt``, the kernels' output type.
+    ``mdt``, the kernels' output type. ``qk_heads_first``: ``q`` and
+    ``k`` come as the kernels take them, (b, h, s, d) in ``mdt`` with
+    k's heads repeated (``kernels/qk_norm_rope``), and only ``v`` is
+    turned here.
 
     What a rematerialised block around the layer keeps is named here
     (``KEPT_BY_BLOCK``): the selection's mask (int8), the forward
@@ -321,7 +325,8 @@ def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
     index product, no selection and no forward kernel; the backward runs
     the dq and dkv kernels, the head-mean kernel and each chunk's index
     products. Outside such a block the names do nothing."""
-    b, s, h, d = q.shape
+    b, h, s = q.shape[:3] if qk_heads_first \
+        else (q.shape[0], q.shape[2], q.shape[1])
     scores, mask, kept, ties = _scores_and_mask(qi, ki, wi, topk, q_chunk,
                                                 mdt)
     mask = checkpoint_name(mask, KEPT_BY_BLOCK)
@@ -330,7 +335,9 @@ def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
             x = jnp.repeat(x, h // x.shape[2], axis=2) \
                 if x.shape[2] != h else x
             return jnp.swapaxes(x, 1, 2).astype(mdt)
-        qh, kh, vh = heads_first(q), heads_first(k), heads_first(v)
+        qh, kh = (q, k) if qk_heads_first \
+            else (heads_first(q), heads_first(k))
+        vh = heads_first(v)
         o, lse = flash_attention_forward(qh, kh, vh, mask,
                                                causal=True)
         o = checkpoint_name(o, KEPT_BY_BLOCK)

@@ -752,3 +752,130 @@ def test_without_a_mask_the_kernels_lower_to_the_parents_text(
     assert "stable_mosaic" in text and "loc(" not in text
     assert hashlib.sha256(text.encode()).hexdigest() \
         == UNMASKED_SHA256[cell]
+
+
+# ----------------------------------------------------------------------
+# q/k norm, rotary embedding, cast and the turn to heads-first (PR 50)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("heads,repeat", [(32, 1), (4, 8)], ids=["q", "k"])
+def test_the_norm_rope_kernels_compile_at_cell_7s_shapes(
+        v5e_devices, chip_locations, heads, repeat):
+    """``keye_vl2_30b_a3b.train.1chip``: 8,192 positions, heads of 128,
+    bf16, a row's own positions; q's 32 heads and k's 4, whose backward
+    reads the 32 query heads' cotangents. Forward and backward at the
+    derived tiles; the output is the flash kernels' operand and ``dx``
+    the projection's own bytes."""
+    from flexflow_tpu.kernels import qk_norm_rope as nrk
+    b, s, d = 1, 8192, 128
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    assert nrk.takes_kernel(s, heads, d, repeat, jnp.bfloat16)
+
+    def both(x, scale, pos, ct):
+        tables = nrk.rope_tables(pos, d, 1e7)
+        y, pull = jax.vjp(lambda x, scale: nrk.qk_norm_rope(
+            x, scale, tables, eps=1e-6, dtype=jnp.bfloat16, repeat=repeat,
+            interpret=False), x, scale)
+        return y, pull(ct)
+
+    txt = _compile_text(
+        both, jax.ShapeDtypeStruct((b, s, heads, d), jnp.float32,
+                                   sharding=one),
+        jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((b, heads * repeat, s, d), jnp.bfloat16,
+                             sharding=one))
+    assert _kernel_names(txt) == ["qk_norm_rope_bwd", "qk_norm_rope_fwd"]
+    calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+    # in: the projection's (b, s, heads * d) float32; out: heads-first
+    assert all(f"f32[{b},{s},{heads * d}]" in l for l in calls)
+    assert any(f"bf16[{b},{heads},{s},{d}]" in l.split(" custom-call(")[0]
+               for l in calls)
+
+
+# one attention layer, forward and backward of a sum through
+# ``MultiHeadAttentionOp.emit`` with the flash kernels forced, lowered
+# for a described v5e: (batch, positions, hidden, heads, key/value
+# heads, head size, the layer's further parameters)
+LAYER_SHAPES = {
+    "cell2_gpt2_124m": (12, 1024, 768, 12, 12, 64, {"bias": True}),
+    "cell4_lfm2": (1, 8192, 2048, 32, 8, 64,
+                   {"bias": False, "rope": True, "rope_theta": 1e6,
+                    "qk_norm": True, "qk_norm_eps": 1e-5}),
+    "cell7_keye_without_indexer": (1, 8192, 2048, 32, 4, 128, {
+        "bias": False, "rope": True, "rope_theta": 1e7, "qk_norm": True,
+        "qk_norm_eps": 1e-6}),
+}
+
+# sha256 of the layer's lowered text (locations stripped) taken from the
+# parent of PR 50 (``git archive ad1f078``) by these same lines: where
+# ``_takes_norm_rope_kernel`` says no, the layer lowers to what it was,
+# equation for equation. A PR that means to change those layers'
+# emission replaces the hashes.
+LAYER_SHA256 = {
+    "cell2_gpt2_124m":
+        "28bb6501df1dd541cd0152f0d29fac5bc6b5de4ff9505c5b1e3ebe019ff4f550",
+    "cell4_lfm2":
+        "b202c6f970e7b5ef7ef9a576a32e18fb4a08e00539351de6b9e82f49a31610f7",
+}
+
+
+def _lowered_layer(device, monkeypatch, cell):
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    import importlib
+    monkeypatch.setattr(
+        importlib.import_module("flexflow_tpu.kernels.flash_attention"),
+        "pallas_interpret", lambda: False)
+    b, s, e, h, kv, d, more = LAYER_SHAPES[cell]
+    params = dict({"embed_dim": e, "num_heads": h, "num_kv_heads": kv,
+                   "kdim": h * d, "vdim": h * d, "causal": True}, **more)
+    op = MultiHeadAttentionOp()
+    one = jax.sharding.SingleDeviceSharding(device)
+    x = jax.ShapeDtypeStruct((b, s, e), jnp.float32, sharding=one)
+    pos = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one)
+    weights = {w.name: jax.ShapeDtypeStruct(w.shape, jnp.float32,
+                                            sharding=one)
+               for w in op.weights(params, [(b, s, e)] * 3,
+                                   [DataType.DT_FLOAT] * 3)}
+    counted = {}
+
+    def loss(x, weights, pos):
+        ctx = EmitCtx(training=True, config=FFConfig())
+        ctx.kernel_impls = {"attention": "flash"}
+        (y,) = op.emit(params, [x, x, x, pos], weights, ctx, "attn")
+        counted.update(ctx.counters)
+        return jnp.sum(y.astype(jnp.float32))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, weights, pos).as_text()
+    return lowered, counted
+
+
+@pytest.mark.parametrize("cell", sorted(LAYER_SHA256))
+def test_where_the_predicate_says_no_the_layer_lowers_to_the_parents_text(
+        v5e_devices, monkeypatch, cell):
+    """Cell 2 (no rotary embedding, no q/k norm) and cell 4 (both, on
+    heads of 64): the three flash calls and nothing of this PR's."""
+    import hashlib
+    lowered, counted = _lowered_layer(v5e_devices[0], monkeypatch, cell)
+    assert lowered.count("tpu_custom_call") == 3
+    assert "qk_norm_rope" not in lowered and not counted
+    text = _without_debug_info(lowered)
+    assert hashlib.sha256(text.encode()).hexdigest() == LAYER_SHA256[cell]
+
+
+def test_where_it_says_yes_the_layer_calls_the_kernels(v5e_devices,
+                                                       monkeypatch):
+    """Cell 7's layer shape without its indexer (``emit``'s own flash
+    call): q and k forward, the three flash kernels, q and k backward,
+    and no rotate-half or repeat of a float32 array around them."""
+    monkeypatch.setattr("flexflow_tpu.kernels.qk_norm_rope."
+                        "pallas_interpret", lambda: False)
+    lowered, counted = _lowered_layer(v5e_devices[0], monkeypatch,
+                                      "cell7_keye_without_indexer")
+    assert lowered.count("tpu_custom_call") == 7
+    assert lowered.count("qk_norm_rope_fwd") >= 2 \
+        and lowered.count("qk_norm_rope_bwd") >= 2
+    assert set(counted) == {"attn.norm_rope_kernel_layers"}

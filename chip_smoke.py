@@ -985,6 +985,90 @@ def _check_generate(ff, ids) -> None:
 
 
 # ----------------------------------------------------------------------
+# ----------------------------------------------------------------------
+# Leg H — window and full attention layers mixed, a gated output
+# ----------------------------------------------------------------------
+VALIDATION_WINDOW = "examples/tpu_validate_window_gated_moe.py"
+
+
+def leg_window_gated_moe(model_cfg, seq: int, per_chip_batch: int,
+                         label: str, alpha: float = 1e-5) -> None:
+    """``build_hybrid_conv_moe`` with ``"sliding_attention"`` layers
+    through compile and fit with ``remat = "blocks"``: the loss falls,
+    every attention layer announced its q/k norm and every expert layer
+    its routing, the window layers' kernels say their window and walk
+    fewer live steps than the full layer's (on a chip; from a length
+    over the window), the counters give the band's share of the causal
+    pairs and a mean gate of a half, nothing was dropped, and the step
+    fits the chip. ``VALIDATION_WINDOW`` holds the gradients to the
+    reference, and this leg names it."""
+    import jax
+
+    from flexflow_tpu.models.nlp import build_hybrid_conv_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    _check_kept_outputs(label, ff)
+    kinds = list(model_cfg.layer_types)
+    seen = {name: sorted({e["attrs"]["layer"] for e in events.events()
+                          if e["name"] == name})
+            for name in ("attn.qk_norm", "moe.route")}
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: instants " + "; ".join(
+        f"{n} {v}" for n, v in seen.items())
+        + f"; resolved {sorted(set(impls.values()))} in {len(impls)} layers")
+    check(seen["attn.qk_norm"] == [f"attn_{i}" for i in range(len(kinds))]
+          and seen["moe.route"] == [
+              f"experts_{i}" for i in range(model_cfg.num_dense_layers,
+                                            len(kinds))]
+          and len(impls) == len(kinds),
+          f"{label}: the layers that announced themselves are not "
+          f"layer_types {kinds}: {seen}, {impls}")
+    ctr = events.counters()
+    window, s = model_cfg.sliding_window, seq
+    w = min(window, s)
+    want = (w * s - w * (w - 1) / 2) / (s * (s + 1) / 2)
+    kept = ctr.get("attn.window_pairs", 0) / max(
+        1.0, ctr.get("attn.causal_pairs", 0))
+    gate = ctr.get("attn.gate_mean", 0) / max(
+        1.0, ctr.get("attn.gate_layers", 0))
+    say(f"{label}: a window of {window} over {seq} positions keeps "
+        f"{kept:.6f} of the causal pairs ({want:.6f} by count); mean gate "
+        f"{gate:.4f} over {len(kinds)} layers a step")
+    check(abs(kept - want) < 1e-6 and abs(gate - 0.5) < 0.05,
+          f"{label}: kept {kept} against {want}, mean gate {gate}")
+    if chip:
+        check(set(impls.values()) == {"flash"},
+              f"{label}: attention resolved to {impls} at seq {seq}")
+        grids = [e["attrs"] for e in events.events()
+                 if e["name"] == "flash.grid"]
+        banded = {g["kernel"]: g for g in grids if g.get("window")}
+        whole = {g["kernel"]: g for g in grids if not g.get("window")}
+        check(len(banded) == 3 == len(whole) if window < seq
+              else not banded,
+              f"{label}: windowed grids {sorted(banded)}, others "
+              f"{sorted(whole)}")
+        for kernel, g in sorted(banded.items()):
+            key = "live_pieces" if "live_pieces" in g else "live_steps"
+            say(f"{label}: {kernel} window {g['window']}: {g[key]} {key} "
+                f"against the full layer's {whole[kernel][key]}")
+            # a tile (a forward piece) lies wholly outside the band only
+            # where the sequence holds the window and two tiles more
+            side = max(g["block_q"], g.get("piece_k", g["block_k"]))
+            skips = g[key] < whole[kernel][key] \
+                if seq >= window + 2 * side else g[key] <= whole[kernel][key]
+            check(skips and g["window"] == window,
+                  f"{label}: {kernel} does not skip the band's outside: "
+                  f"{g} against {whole[kernel]}")
+    _check_experts_counters(label)
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: gradients against the reference: "
+        f"python3 {VALIDATION_WINDOW}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def main() -> int:
     import jax
     devs = jax.devices()
@@ -1000,7 +1084,7 @@ def main() -> int:
                                          KeyeRankConfig,
                                          KimiLinearRankConfig,
                                          LatentMoEConfig, LFM2RankConfig,
-                                         XingRankConfig)
+                                         TrinityRankConfig, XingRankConfig)
     from flexflow_tpu.utils.compilation_cache import (
         cache_entries, enable_compilation_cache)
     cache = enable_compilation_cache()
@@ -1037,6 +1121,11 @@ def main() -> int:
                 kv_chunk_size=128, topk=256)), 1024, 1, "G/small",
             alpha=1e-3)
         leg_sparse_index_moe(KeyeRankConfig(), 8192, 1, "G/keye")
+        # a window of 256 under 1024 positions, then the cell's shapes
+        leg_window_gated_moe(dataclasses.replace(
+            TrinityRankConfig.tiny(), sliding_window=256), 1024, 1,
+            "H/small", alpha=1e-3)
+        leg_window_gated_moe(TrinityRankConfig(), 8192, 1, "H/trinity")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -404,13 +404,20 @@ def _find_remat_blocks(layers):
     and constants, which every block may read: positions, masks),
     containing no stateful op (a write to ``ctx.new_state`` cannot
     cross a ``jax.checkpoint`` boundary; device counters and auxiliary
-    losses leave a block as its outputs). Returns
+    losses leave a block as its outputs). Every block is emitted from
+    its own layers with its own parameters (``_emit_remat``), so two
+    blocks are the same where their ops and their outputs' shapes are:
+    a period of attention layers that differ in window, rotary
+    embedding or positions is a run of blocks all the same. Returns
     ``(start, unit, reps, entry_guids, exit_guids)`` or None."""
     from .parallel.pipeline_lowering import (_has_state, chunk_boundaries,
                                              find_repeated_run)
     graph_inputs = frozenset(t.guid for l in layers for t in l.inputs
                              if t.owner_layer is None)
-    run = find_repeated_run(list(layers), 1, graph_inputs)
+    run = find_repeated_run(
+        list(layers), 1, graph_inputs,
+        signature=lambda l: (l.op_type,
+                             tuple(t.shape for t in l.outputs)))
     if run is None:
         return None
     total, start, unit = run
@@ -431,7 +438,7 @@ def _find_remat_blocks(layers):
 # None = replicated (biases applied once, after the psum).
 _TP_WEIGHT_DIMS = {
     "attn": {"wq": 1, "wk": 1, "wv": 1, "bq": 0, "bk": 0, "bv": 0,
-             "wo": 0, "bo": None},
+             "wo": 0, "bo": None, "wg": 1},
     "col": {"kernel": 1, "bias": 0},
     "row": {"kernel": 0, "bias": None},
 }

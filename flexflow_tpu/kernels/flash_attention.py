@@ -19,6 +19,13 @@ from the forward kernel through the residual to the backward kernels,
 and inside a kernel meet a score tile as whole lane-replicated vregs or
 as a sublane broadcast, never as a (rows, 1) column (docs/kernels.md).
 
+A sliding window (``window=W`` under ``causal``: a query sees the ``W``
+keys that end with its own) is index arithmetic like the causal edge:
+the mask from the tile's positions, the grid steps and forward pieces on
+either side of the band skipped, their index maps naming the band's
+nearest block so that nothing is copied for them. It is never an operand;
+with ``window=0`` every kernel is the text it was (docs/kernels.md).
+
 Layout: (batch, heads, seq, head_dim), batch*heads collapsed into one grid
 axis. Sequence/head dims are padded to block/lane multiples; the padded-key
 mask is baked in statically (shapes are static under jit). TPU grids
@@ -68,12 +75,17 @@ def _tile_positions(iq, ik, block_q, block_k, keys_major):
     return q_pos, k_pos
 
 
-def _key_mask(iq, ik, block_q, block_k, kv_len, causal, keys_major=False):
-    """Validity mask for one (q block, k block) tile; kv_len is static."""
+def _key_mask(iq, ik, block_q, block_k, kv_len, causal, keys_major=False,
+              window=0):
+    """Validity mask for one (q block, k block) tile; kv_len and window
+    are static. ``window``: a query sees the ``window`` keys that end
+    with its own, ``k_pos > q_pos - window`` beside the causal edge."""
     q_pos, k_pos = _tile_positions(iq, ik, block_q, block_k, keys_major)
     mask = k_pos < kv_len
     if causal:
         mask = jnp.logical_and(mask, k_pos <= q_pos)
+    if window:
+        mask = jnp.logical_and(mask, k_pos > q_pos - window)
     return mask
 
 
@@ -138,11 +150,16 @@ def _fwd_piece(block_k):
     return FWD_PIECE if block_k % FWD_PIECE == 0 else block_k
 
 
-def _piece_live(iq, piece, block_q, piece_k):
+def _piece_live(iq, piece, block_q, piece_k, window=0):
     """Causal: whether any pair of q block ``iq`` with the keys
     ``[piece * piece_k, (piece + 1) * piece_k)`` lies on or under the
-    diagonal (ints, or traced program ids). A dead piece is skipped."""
-    return piece * piece_k <= (iq + 1) * block_q - 1
+    diagonal and, with a ``window``, inside the band: the piece's last
+    key within the window of the block's first query (ints, or traced
+    program ids). A dead piece is skipped."""
+    live = piece * piece_k <= (iq + 1) * block_q - 1
+    if window:
+        live = live & ((piece + 1) * piece_k - 1 > iq * block_q - window)
+    return live
 
 
 def _lanes(x, width):
@@ -166,8 +183,13 @@ def _attended(mask_ref, keys=slice(None)):
 
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
-                kv_len, block_q, block_k, dropout_rate, masked=False):
-    """Online softmax over the k blocks of one q block. ``masked``: a
+                kv_len, block_q, block_k, dropout_rate, masked=False,
+                window=0):
+    """Online softmax over the k blocks of one q block. ``window``: the
+    band's other edge, index arithmetic like the causal one (a row's
+    first live pieces may lie wholly left of ITS window: what they add
+    at the stand-in maximum is scaled to 0 by the first real score).
+    ``masked``: a
     (1, block_q, block_k) int8 tile of the call's mask follows v, and a
     pair is attended where it is not 0 AND :func:`_key_mask` holds (the
     tile is shared by the heads of a batch row; no piece is skipped for
@@ -206,7 +228,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        valid = _key_mask(iq, piece, block_q, piece_k, kv_len, causal)
+        valid = _key_mask(iq, piece, block_q, piece_k, kv_len, causal,
+                          window=window)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref, keys))
         s = jnp.where(valid, s, NEG_INF)
@@ -247,8 +270,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
     # than inline where inline compiled; PERF.md section 6, PR 32).
     if causal:
         for c in range(pieces):
-            pl.when(_piece_live(iq, ik * pieces + c, block_q, piece_k))(
-                functools.partial(_piece, c))
+            pl.when(_piece_live(iq, ik * pieces + c, block_q, piece_k,
+                                window))(functools.partial(_piece, c))
     elif pieces == 1:
         _piece(0)
     else:
@@ -296,7 +319,7 @@ def _bwd_ds(p, do, v, delta, keep, rate, sm_scale, keys_major):
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    *refs, sm_scale, causal, kv_len, block_q, block_k,
-                   dropout_rate, masked=False):
+                   dropout_rate, masked=False, window=0):
     """A q block stays while k blocks stream, so the tile is held
     queries-major, (block_q, block_k), and the q block's two statistics
     are made lane-replicated (block_q, 128) tiles ONCE, into scratch
@@ -320,7 +343,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for row_ref, sc in ((lse_ref, lse_sc), (delta_ref, delta_sc)):
             sc[:] = jnp.broadcast_to(row_ref[0], (LANES, block_q)).T
 
-    live = (ik * block_k <= (iq + 1) * block_q - 1) if causal else True
+    live = _piece_live(iq, ik, block_q, block_k, window) if causal else True
 
     @pl.when(live)
     def _compute():
@@ -328,7 +351,8 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         keep = _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k,
                                dropout_rate) if dropout_rate > 0.0 else None
         q, lse = q_ref[0], _lanes(lse_sc[:], block_k)
-        valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal)
+        valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal,
+                          window=window)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref))
         p = _bwd_p(q, k, lse, valid, sm_scale, keys_major=False)
@@ -345,7 +369,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, *refs, sm_scale, causal, kv_len, block_q,
-                    block_k, dropout_rate, masked=False):
+                    block_k, dropout_rate, masked=False, window=0):
     """A k block stays while q blocks stream, and the tile is held
     KEYS-MAJOR, (block_k, block_q): ``s^T = k q^T`` and ``dp^T = v do^T``
     contract the last dimension of both operands as ``s`` always did,
@@ -373,6 +397,8 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     # causal: the q block is live iff its last query can see the first key
     live = ((iq + 1) * block_q - 1 >= ik * block_k) if causal else True
+    if window:      # and its first query still sees the block's last key
+        live = live & (iq * block_q < (ik + 1) * block_k - 1 + window)
 
     @pl.when(live)
     def _compute():
@@ -383,7 +409,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             keys_major=True) if dropout_rate > 0.0 else None
         k, lse = k_ref[0], lse_ref[0]
         valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal,
-                          keys_major=True)
+                          keys_major=True, window=window)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref))
         p = _bwd_p(q, k, lse, valid, sm_scale, keys_major=True)
@@ -420,20 +446,41 @@ def _first_live_q(ik, block_q, block_k):
     return (ik * block_k) // block_q
 
 
+def _first_live_k(iq, block_q, block_k, window):
+    """First k block with a key inside the window of q block ``iq``'s
+    first query; below 0 where the band reaches the first key."""
+    return (iq * block_q - window + 1) // block_k
+
+
+def _last_live_q(ik, block_q, block_k, window):
+    """Last q block whose first query still sees k block ``ik``'s last
+    key through the window (it may lie past the grid's end)."""
+    return ((ik + 1) * block_k - 2 + window) // block_q
+
+
 def _q_spec(block_q, d):
     return pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
 
 
-def _k_spec(block_q, block_k, d, causal):
-    """k/v blocks of the (bh, nq, nk) grids. A step above the causal
-    diagonal, whose arithmetic ``pl.when(live)`` skips, names the row's
-    last live block again, so the pipeline issues no copy for it."""
+def _live_k(block_q, block_k, causal, window):
+    """``ik(i, j)``: the k block step ``j`` of q block ``i``'s row of the
+    (bh, nq, nk) grids names. A step above the causal diagonal, whose
+    arithmetic ``pl.when(live)`` skips, names the row's last live block
+    again, and a step left of the window's band its first, so the
+    pipeline issues no copy for either."""
     if not causal:
-        return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    return pl.BlockSpec(
-        (1, block_k, d),
-        lambda b, i, j: (b, jnp.minimum(j, _last_live_k(i, block_q, block_k)),
-                         0))
+        return lambda i, j: j
+    if not window:
+        return lambda i, j: jnp.minimum(j, _last_live_k(i, block_q, block_k))
+    return lambda i, j: jnp.minimum(
+        jnp.maximum(j, _first_live_k(i, block_q, block_k, window)),
+        _last_live_k(i, block_q, block_k))
+
+
+def _k_spec(block_q, block_k, d, causal, window=0):
+    """k/v blocks of the (bh, nq, nk) grids (see :func:`_live_k`)."""
+    ik = _live_k(block_q, block_k, causal, window)
+    return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, ik(i, j), 0))
 
 
 def _stat_spec(block_q):
@@ -448,17 +495,20 @@ def _mask_spec(block_q, block_k, causal, heads):
     nq, nk) grids: the mask is (batch, sq, sk), one for the ``heads``
     heads of a batch row, and a dead causal step names the tile of the
     row's last live k block, as :func:`_k_spec` does."""
-    def live_k(i, j):
-        return jnp.minimum(j, _last_live_k(i, block_q, block_k)) \
-            if causal else j
+    live_k = _live_k(block_q, block_k, causal, 0)
     return pl.BlockSpec((1, block_q, block_k),
                         lambda b, i, j: (b // heads, i, live_k(i, j)))
 
 
-def _dkv_live_q(block_q, block_k, causal):
+def _dkv_live_q(block_q, block_k, causal, window=0):
     """``iq(j, i)``: the q block step ``i`` of k block ``j``'s row of the
     (bh, nk, nq) dkv grid names: a dead causal step names the column's
-    first live q block (see _k_spec)."""
+    first live q block, a step under the window's band its last (see
+    _live_k)."""
+    if causal and window:
+        return lambda j, i: jnp.minimum(
+            jnp.maximum(i, _first_live_q(j, block_q, block_k)),
+            _last_live_q(j, block_q, block_k, window))
     if causal:
         return lambda j, i: jnp.maximum(i, _first_live_q(j, block_q,
                                                          block_k))
@@ -473,19 +523,21 @@ def _dkv_mask_spec(block_q, block_k, causal, heads):
                         lambda b, j, i: (b // heads, j, iq(j, i)))
 
 
-def _dkv_specs(block_q, block_k, d, causal):
+def _dkv_specs(block_q, block_k, d, causal, window=0):
     """(q/do, k/v, row statistics) specs of the (bh, nk, nq) dkv grid:
     the index maps swap the roles of grid axes 1 and 2."""
-    iq = _dkv_live_q(block_q, block_k, causal)
+    iq = _dkv_live_q(block_q, block_k, causal, window)
     return (pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, iq(j, i), 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, 1, block_q),
                          lambda b, j, i: (b, 0, iq(j, i))))
 
 
-def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
-    """What the grid of one call costs. ``steps``: all of them, each paid
-    for; ``live_steps``: those whose arithmetic runs; ``fetched_steps``:
+def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0):
+    """What the grid of one call costs (``window``: under the causal
+    band of that many keys a query, both of whose edges are skipped).
+    ``steps``: all of them, each paid for; ``live_steps``: those whose
+    arithmetic runs; ``fetched_steps``:
     those that name another streamed block (k/v for ``fwd`` and
     ``bwd_dq``, q/do/row statistics for ``bwd_dkv``) than the step before
     in their row of the grid, so that the pipeline copies one. A row's
@@ -498,20 +550,26 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
     if kernel == "bwd_dkv":            # rows are k blocks, q blocks stream
         for j in range(nk):
             first = _first_live_q(j, block_q, block_k) if causal else 0
-            live += nq - first
-            fetched += len({max(i, first) for i in range(nq)})
+            last = min(nq - 1, _last_live_q(j, block_q, block_k, window)) \
+                if window else nq - 1
+            live += max(0, last + 1 - first)
+            fetched += len({min(max(i, first), last) for i in range(nq)})
     else:                              # rows are q blocks, k blocks stream
         for i in range(nq):
             last = _last_live_k(i, block_q, block_k) if causal else nk - 1
-            live += min(nk, last + 1)
-            fetched += len({min(j, last) for j in range(nk)})
+            first = max(0, _first_live_k(i, block_q, block_k, window)) \
+                if window else 0
+            live += max(0, min(nk, last + 1) - first)
+            fetched += len({min(max(j, first), last) for j in range(nk)})
     out = {"kernel": "flash_attention_" + kernel, "block_q": block_q,
            "block_k": block_k, "steps": bh * nq * nk,
            "live_steps": bh * live, "fetched_steps": bh * fetched}
+    if window:
+        out["window"] = window
     if kernel == "fwd":
         piece_k = _fwd_piece(block_k)
         out.update(piece_k=piece_k, live_pieces=bh * sum(
-            not causal or bool(_piece_live(i, p, block_q, piece_k))
+            not causal or bool(_piece_live(i, p, block_q, piece_k, window))
             for i in range(nq) for p in range(sk // piece_k)))
     else:       # the row statistics the call is handed, and its tile's form
         out.update(stat_bytes=2 * bh * sq * 4,
@@ -520,12 +578,13 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal):
     return out
 
 
-def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal, masked=False):
+def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal, masked=False,
+               window=0):
     """One ``flash.grid`` instant per emitted call, at trace time; a
-    masked call's says so."""
+    masked call's says so, a windowed call's says ``window=``."""
     if events.enabled():
         events.instant("flash.grid", **grid_steps(
-            kernel, bh, sq, sk, block_q, block_k, causal),
+            kernel, bh, sq, sk, block_q, block_k, causal, window),
             **({"masked": True} if masked else {}))
 
 
@@ -539,25 +598,27 @@ def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal, masked=False):
 # three kernels cost 85 ms a layer on the chip's host, 6 s of set-up for
 # BERT-large's 24 layers (PERF.md section 6, PR 30).
 _STATIC = ("kv_len", "sm_scale", "causal", "block_q", "block_k",
-           "dropout_rate", "interpret", "heads")
+           "dropout_rate", "interpret", "heads", "window")
 
 
-def _masked(mask, spec):
-    """What a mask adds to a call: ``(kernel options, in_specs,
-    operands)``, nothing where ``mask`` is None."""
+def _masked(mask, spec, window=0):
+    """What a mask or a window adds to a call: ``(kernel options,
+    in_specs, operands)``, nothing where ``mask`` is None and the window
+    0. A window is an option alone, never an operand."""
+    opts = {"window": window} if window else {}
     if mask is None:
-        return {}, [], ()
-    return {"masked": True}, [spec()], (mask,)
+        return opts, [], ()
+    return dict(opts, masked=True), [spec()], (mask,)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
-              dropout_rate, interpret, mask=None, heads=1):
+              dropout_rate, interpret, mask=None, heads=1, window=0):
     """``mask``: None or (bh // heads, sq, sk) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]      # q.k over d, p.v over dv
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
-        _mask_spec, block_q, block_k, causal, heads))
+        _mask_spec, block_q, block_k, causal, heads), window)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -566,8 +627,8 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
         kernel,
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[_SEED_SPEC, _q_spec(block_q, d),
-                  _k_spec(block_q, block_k, d, causal),
-                  _k_spec(block_q, block_k, dv, causal)] + mask_specs,
+                  _k_spec(block_q, block_k, d, causal, window),
+                  _k_spec(block_q, block_k, dv, causal, window)] + mask_specs,
         out_specs=[_q_spec(block_q, dv), _stat_spec(block_q)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
@@ -587,16 +648,18 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
                  causal, block_q, block_k, dropout_rate, interpret,
-                 mask=None, heads=1):
+                 mask=None, heads=1, window=0):
     """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row;
     ``mask``: None or (bh // heads, sq, sk) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
-        _mask_spec, block_q, block_k, causal, heads))
+        _mask_spec, block_q, block_k, causal, heads), window)
     stat = _stat_spec(block_q)
-    qs, ks = _q_spec(block_q, d), _k_spec(block_q, block_k, d, causal)
-    dos, vs = _q_spec(block_q, dv), _k_spec(block_q, block_k, dv, causal)
+    qs = _q_spec(block_q, d)
+    ks = _k_spec(block_q, block_k, d, causal, window)
+    dos = _q_spec(block_q, dv)
+    vs = _k_spec(block_q, block_k, dv, causal, window)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -618,15 +681,15 @@ def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
                   causal, block_q, block_k, dropout_rate, interpret,
-                  mask=None, heads=1):
+                  mask=None, heads=1, window=0):
     """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row;
     ``mask``: None or the mask TRANSPOSED, (bh // heads, sk, sq) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
-        _dkv_mask_spec, block_q, block_k, causal, heads))
-    qs2, ks2, stat2 = _dkv_specs(block_q, block_k, d, causal)
-    dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal)
+        _dkv_mask_spec, block_q, block_k, causal, heads), window)
+    qs2, ks2, stat2 = _dkv_specs(block_q, block_k, d, causal, window)
+    dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal, window)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -649,34 +712,36 @@ def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
 def _flash(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
-           dq_blocks, dkv_blocks, dropout_rate, interpret):
+           dq_blocks, dkv_blocks, dropout_rate, interpret, window):
     o, _ = _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                           block_k, dropout_rate, interpret)
+                           block_k, dropout_rate, interpret, window=window)
     return o
 
 
 def _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                    block_k, dropout_rate, interpret, mask=None, heads=1):
+                    block_k, dropout_rate, interpret, mask=None, heads=1,
+                    window=0):
     _note_grid("fwd", q.shape[0], q.shape[1], k.shape[1], block_q, block_k,
-               causal, mask is not None)
+               causal, mask is not None, window)
     return _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
                      block_k, dropout_rate, interpret, mask=mask,
-                     heads=heads)
+                     heads=heads, window=window)
 
 
 def _flash_fwd_rule(q, k, v, seed, kv_len, sm_scale, causal, block_q,
                     block_k, dq_blocks, dkv_blocks, dropout_rate,
-                    interpret):
+                    interpret, window):
     o, lse = _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal,
-                             block_q, block_k, dropout_rate, interpret)
+                             block_q, block_k, dropout_rate, interpret,
+                             window=window)
     return o, (q, k, v, seed, o, lse)
 
 
 def _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
                     dropout_rate, interpret, q, k, v, seed, o, lse, do,
-                    mask=None, heads=1):
+                    mask=None, heads=1, window=0):
     """``(dq, dk, dv)`` from the forward's operands, output and
     log-sum-exp; ``mask``: the forward's, which the dkv call is handed
     transposed (an (sk, sq) int8 copy a batch row, made here)."""
@@ -686,13 +751,16 @@ def _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
     # one float32 a row, the rows along the lanes: nothing is replicated
     operands = (seed, q, k, v, do, lse[:, None, :], delta[:, None, :],
                 kv_len, sm_scale, causal)
-    _note_grid("bwd_dq", bh, sq, k.shape[1], *dq_blocks, causal, masked)
+    _note_grid("bwd_dq", bh, sq, k.shape[1], *dq_blocks, causal, masked,
+               window)
     dq = _bwd_dq_call(*operands, *dq_blocks, dropout_rate, interpret,
-                      mask=mask, heads=heads)
-    _note_grid("bwd_dkv", bh, sq, k.shape[1], *dkv_blocks, causal, masked)
+                      mask=mask, heads=heads, window=window)
+    _note_grid("bwd_dkv", bh, sq, k.shape[1], *dkv_blocks, causal, masked,
+               window)
     dk, dv = _bwd_dkv_call(
         *operands, *dkv_blocks, dropout_rate, interpret,
-        mask=jnp.swapaxes(mask, 1, 2) if masked else None, heads=heads)
+        mask=jnp.swapaxes(mask, 1, 2) if masked else None, heads=heads,
+        window=window)
     return dq, dk, dv
 
 
@@ -701,12 +769,12 @@ def _no_cotangent(x):
 
 
 def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
-                    dq_blocks, dkv_blocks, dropout_rate, interpret, res,
-                    do):
+                    dq_blocks, dkv_blocks, dropout_rate, interpret, window,
+                    res, do):
     q, k, v, seed, o, lse = res
     return _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
                            dropout_rate, interpret, q, k, v, seed, o, lse,
-                           do) + (_no_cotangent(seed),)
+                           do, window=window) + (_no_cotangent(seed),)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -927,7 +995,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     bwd_block_q: Optional[int] = None,
                     bwd_block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    mesh=None, spec=None, mask=None):
+                    mesh=None, spec=None, mask=None, window: int = 0):
     """Tiled flash attention. q: (b, h, sq, d); k: (b, h, sk, d); v:
     (b, h, sk, dv), and the output (b, h, sq, dv). ``dv`` may differ from
     ``d`` (latent attention: q.k over 192, p.v over 128); the score scale
@@ -941,6 +1009,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
     tile counted, so a masked call's are narrower (docs/kernels.md). A
     row with no attended key has no meaning (its output is a mean of
     values, its gradients 0 where the cotangent is).
+
+    ``window``: 0, or the keys a query sees under ``causal``, its own
+    and the ``window - 1`` before it (``s <= t and s > t - window``). It
+    is index arithmetic inside the kernels, like the causal edge, and
+    never an operand: the mask is drawn from the tile's positions, a
+    step or a forward piece on either side of the band computes nothing,
+    and its index map names the band's nearest block, so nothing is
+    copied for it (docs/kernels.md). A window of at least the keys is
+    the causal call. The tiles are the causal call's.
 
     Pads the key length to a multiple of 128 and the query length to a
     multiple of 8 (of 128 from 512 positions on), tiles them by divisors,
@@ -982,7 +1059,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
     the call runs under ``shard_map``. ``spec`` is the (b, h, s, d)
     PartitionSpec of the operands; only its batch and head entries are
     used — sequence and head_dim stay whole on every device."""
+    if window < 0 or (window and not causal):
+        raise ValueError(f"window={window} wants causal=True and >= 0")
+    if window >= k.shape[2]:           # the band is the whole triangle
+        window = 0
     if mask is not None:
+        if window:
+            raise NotImplementedError(
+                "a masked flash call takes no window: say it in the mask")
         o, lse = flash_attention_forward(
             q, k, v, mask, causal=causal, sm_scale=sm_scale,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
@@ -1002,13 +1086,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
             q, k, v, mesh, spec, causal=causal, sm_scale=sm_scale,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
             block_q=block_q, block_k=block_k, bwd_block_q=bwd_block_q,
-            bwd_block_k=bwd_block_k, interpret=interpret)
+            bwd_block_k=bwd_block_k, interpret=interpret, window=window)
     (qp, kp, vp), seed, cut, plan = _prepare(
         q, k, v, False, causal, sm_scale, dropout_rate, dropout_seed,
         block_q, block_k, bwd_block_q, bwd_block_k)
     o = _flash(qp, kp, vp, seed, plan.kv_len, plan.sm_scale, causal,
                *plan.fwd_blocks, plan.dq_blocks, plan.dkv_blocks,
-               float(dropout_rate), interpret)
+               float(dropout_rate), interpret, window)
     return cut(o)
 
 
@@ -1226,7 +1310,7 @@ def head_mean_tiles(sq, sk, d, dtype):
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=tuple(
-    n for n in _STATIC if n != "dropout_rate"))
+    n for n in _STATIC if n not in ("dropout_rate", "window")))
 def _head_mean_call(q, k, lse, mask_t, kv_len, sm_scale, causal, block_q,
                     block_k, interpret, heads):
     """q, k (b * heads, s, d); lse (b * heads, 1, sq); the transposed

@@ -37,13 +37,11 @@ def _attn_flash(ctx: Dict[str, Any]) -> Optional[str]:
     Structural legality only — the kernel runs compiled on TPU and in
     interpret mode on CPU, so the backend is no availability question.
     """
-    if ctx.get("sliding_window", 0):
-        return "flash kernel has no sliding-window mask support"
     if ctx.get("causal", False) and \
             ctx.get("q_len", 0) != ctx.get("kv_len", 0):
         return "flash kernel does not mask causal cross-attention " \
                "(q_len != kv_len)"
-    return None
+    return None      # a sliding window is the kernels' own band arithmetic
 
 
 def _attn_ring(ctx: Dict[str, Any]) -> Optional[str]:

@@ -223,7 +223,26 @@ class FFModel:
                             qk_norm_eps: float = 1e-6,
                             positions: Optional[Tensor] = None,
                             indexer: Optional[dict] = None,
+                            output_gate: bool = False,
                             name: Optional[str] = None) -> Tensor:
+        """Multi-head attention (``ops.nn_ops.MultiHeadAttentionOp``):
+        ``num_heads`` query heads of ``kdim / num_heads`` on
+        ``num_kv_heads`` key/value heads (0: as many), through the
+        output projection to ``embed_dim``. ``causal`` masks the keys
+        after a query, ``sliding_window`` also those more than that
+        many positions before it (on the full training or eval forward
+        the flash kernels draw the band themselves, from 1,024
+        positions on a TPU; prefill and decode keep XLA and the
+        ring-buffer cache). ``rope`` turns q and k by ``positions``
+        ((batch, seq) int32; default 0 .. seq - 1) and ``qk_norm`` puts
+        an RMSNorm with one learned scale a projection on every query
+        and key head before it; each may be set without the other (a
+        layer with ``qk_norm`` and no ``rope`` is a NoPE layer, and takes
+        no ``positions``). ``output_gate``: the heads' outputs are
+        multiplied, element by element, by the sigmoid of a projection
+        of the query input with a weight of its own (``wg``, input x
+        heads x head size, no bias), before the output projection.
+        ``indexer``: learned sparse attention (below)."""
         params = {"embed_dim": embed_dim, "num_heads": num_heads,
                   "kdim": kdim, "vdim": vdim, "dropout": dropout,
                   "bias": bias, "add_bias_kv": add_bias_kv,
@@ -268,6 +287,11 @@ class FFModel:
                 if int(indexer[size]) < 1:
                     raise ValueError(f"indexer {size} = {indexer[size]}")
                 params["indexer_" + size] = int(indexer[size])
+        if output_gate:
+            if indexer:
+                raise ValueError("an output gate beside an indexer is "
+                                 "not built")
+            params["output_gate"] = True
         inputs = [query, key, value]
         if positions is not None:
             # (batch, seq) int32: what the rotary embedding turns by

@@ -9,6 +9,7 @@ https://openai.com 124M/355M/774M/1.5B).
 from __future__ import annotations
 
 import dataclasses
+import math
 
 from ..ffconst import ActiMode, AggrMode, DataType
 from ..model import FFModel
@@ -1170,6 +1171,81 @@ class KeyeRankConfig(HybridConvMoEConfig):
                               "topk": 24})
 
 
+@dataclasses.dataclass
+class TrinityRankConfig(HybridConvMoEConfig):
+    """What ONE chip holds of Trinity-Mini (``model_type: afmoe``, 26B
+    parameters, 3B a token) where 8 chips share each layer (the
+    benchmark's ``trinity_mini``): grouped-query attention (32 query
+    heads on 4 key/value heads of 128, q/k norms) with a sigmoid output
+    gate on every layer, three ``"sliding_attention"`` layers (the
+    ``sliding_window`` keys that end with the query's own, rotary
+    embedding) to one ``"full_attention"`` layer (every causal key, NO
+    rotary embedding); four norms a layer, the two after the sub-layers
+    inside the residual branch; the embedding scaled by sqrt(hidden);
+    128 sigmoid-routed experts of 1024, 8 a token, beside a shared one.
+    Here: experts 0 to 15, one of eight slices of the vocabulary, and
+    published layer 0 (dense, window) with layers 2 to 5 (window, full,
+    window, window: one whole period of expert layers); every width as
+    published.
+
+    ``config.json`` names six of the parent's fields otherwise
+    (``rms_norm_eps``, ``rope_theta``, ``route_scale``, ``route_norm``,
+    ``score_func``, the shared expert as a count); they are taken under
+    its names and copied over. The three fields after them are forms of
+    the model's published code that ``config.json`` has no key for."""
+    vocab_size: int = 25024
+    num_hidden_layers: int = 5
+    layer_types: list = dataclasses.field(
+        default_factory=lambda: ["sliding_attention", "sliding_attention",
+                                 "full_attention", "sliding_attention",
+                                 "sliding_attention"])
+    num_dense_layers: int = 1
+    num_key_value_heads: int = 4
+    head_dim: int | None = 128
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    num_experts: int = 16
+    num_experts_published: int | None = 128
+    num_experts_per_tok: int = 8
+    # the keys the parent class does not have
+    sliding_window: int = 2048
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    route_scale: float = 2.826
+    route_norm: bool = True
+    score_func: str = "sigmoid"
+    num_shared_experts: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    mup_enabled: bool = True             # the embedding times sqrt(hidden)
+    # not in config.json: modeling_afmoe.py's forms
+    attention_output_gate: bool = True   # o * sigmoid(x Wg), before Wo
+    sandwich_norms: bool = True          # a norm after each sub-layer too
+    full_attention_rope: bool = False    # NoPE on the full layers
+
+    def __post_init__(self):
+        if self.n_group != 1 or self.topk_group != 1 \
+                or self.num_shared_experts not in (0, 1):
+            raise ValueError("grouped routing and several shared experts "
+                             "are not built")
+        self.norm_eps = self.rms_norm_eps
+        self.rope_parameters = {"rope_theta": self.rope_theta,
+                                "rope_type": "default"}
+        self.routed_scaling_factor = self.route_scale
+        self.norm_topk_prob = self.route_norm
+
+    @classmethod
+    def tiny(cls):
+        """The benchmark's layout (a dense window layer, then window,
+        full, window, window with experts), 4 heads on 2 kv heads of 16,
+        a window of 24, 16 experts top-4, all held: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_attention_heads=4,
+                   num_key_value_heads=2, head_dim=16, sliding_window=24,
+                   intermediate_size=160, moe_intermediate_size=32,
+                   num_experts=16, num_experts_published=None,
+                   num_experts_per_tok=4, router_bias_std=0.05)
+
+
 def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                           cfg: HybridConvMoEConfig | None = None):
     """Causal LM of :class:`HybridConvMoEConfig`: inputs ``[ids, pos]``,
@@ -1178,7 +1254,13 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     ``h += Op(norm(h))`` then ``h += FF(norm(h))``. A
     ``"sparse_attention"`` layer (:class:`KeyeRankConfig`) is a
     ``"full_attention"`` one whose queries attend the keys its indexer
-    selects; its alignment loss joins the step's. ``pos`` is what the
+    selects; its alignment loss joins the step's. A
+    ``"sliding_attention"`` layer (:class:`TrinityRankConfig`) is one
+    whose queries see the ``sliding_window`` keys that end with their
+    own; that class also turns the full layers' rotary embedding off,
+    gates every attention layer's output, norms each sub-layer's output
+    before the residual add, scales the embedding and adds a shared
+    expert. ``pos`` is what the
     attention layers' rotary embedding turns by (a layout with no
     attention layer takes ``ids`` alone).
 
@@ -1188,11 +1270,12 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     cfg = cfg or HybridConvMoEConfig()
     kinds = list(cfg.layer_types)
     if len(kinds) != cfg.num_hidden_layers \
-            or set(kinds) - {"conv", "full_attention", "sparse_attention"}:
+            or set(kinds) - {"conv", "full_attention", "sparse_attention",
+                             "sliding_attention"}:
         raise ValueError(
             f"layer_types must name {cfg.num_hidden_layers} layers, each "
-            f"'conv', 'full_attention' or 'sparse_attention'; got "
-            f"{len(kinds)}: {sorted(set(kinds))}")
+            f"'conv', 'full_attention', 'sparse_attention' or "
+            f"'sliding_attention'; got {len(kinds)}: {sorted(set(kinds))}")
     if cfg.conv_bias or not cfg.norm_topk_prob:
         raise ValueError("conv_bias and gates that are not normalised "
                          "over the chosen experts are not built")
@@ -1211,9 +1294,22 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
         "topk": sa["topk"], "q_chunk": sa["q_chunk_size"]}}
     scoring = {"scoring": cfg.scoring_func} \
         if hasattr(cfg, "scoring_func") else {}
+    if getattr(cfg, "score_func", "sigmoid") != "sigmoid":
+        scoring = {"scoring": cfg.score_func}
+    window = getattr(cfg, "sliding_window", 0)
+    if "sliding_attention" in kinds and not window:
+        raise ValueError("a 'sliding_attention' layer needs the "
+                         "configuration's sliding_window")
+    gated = {"output_gate": True} \
+        if getattr(cfg, "attention_output_gate", False) else {}
+    sandwich = getattr(cfg, "sandwich_norms", False)
+    shared_dim = cfg.moe_intermediate_size \
+        * getattr(cfg, "num_shared_experts", 0)
     ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
     pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids")
     h = ff.embedding(ids, cfg.vocab_size, hid, name="embed_tokens")
+    if getattr(cfg, "mup_enabled", False):
+        h = ff.scalar_multiply(h, math.sqrt(hid), name="embed_scale")
 
     def norm(x, name):
         return ff.rms_norm(x, eps=cfg.norm_eps, name=name)
@@ -1223,13 +1319,22 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
         if kind == "conv":
             op = ff.gated_short_conv(x, cfg.conv_L_cache, name=f"conv_{i}")
         else:
+            # a full layer turns by the positions unless the class says
+            # it has no rotary embedding; a window layer always does
+            turns = kind != "full_attention" \
+                or getattr(cfg, "full_attention_rope", True)
             op = ff.multihead_attention(
                 x, x, x, hid, heads, kdim=heads * head_dim,
-                vdim=heads * head_dim, bias=False, causal=True, rope=True,
+                vdim=heads * head_dim, bias=False, causal=True, rope=turns,
                 rope_theta=cfg.rope_parameters["rope_theta"],
                 num_kv_heads=cfg.num_key_value_heads, qk_norm=True,
-                qk_norm_eps=cfg.norm_eps, positions=pos, name=f"attn_{i}",
-                **(indexer if kind == "sparse_attention" else {}))
+                qk_norm_eps=cfg.norm_eps,
+                positions=pos if turns else None, name=f"attn_{i}",
+                **(indexer if kind == "sparse_attention" else {}),
+                **({"sliding_window": window}
+                   if kind == "sliding_attention" else {}), **gated)
+        if sandwich:
+            op = norm(op, f"post_operator_norm_{i}")
         h = ff.add(h, op, name=f"operator_res_{i}")
         x = norm(h, f"ffn_norm_{i}")
         if i < cfg.num_dense_layers:
@@ -1243,12 +1348,14 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
         else:
             y = ff.routed_experts(
                 x, published, cfg.num_experts_per_tok,
-                cfg.moe_intermediate_size, shared_dim=0,
+                cfg.moe_intermediate_size, shared_dim=shared_dim,
                 experts_held=cfg.num_experts,
                 first_held=cfg.first_held_expert,
                 scale=cfg.routed_scaling_factor,
                 bias_std=cfg.router_bias_std if cfg.use_expert_bias
                 else 0.0, name=f"experts_{i}", **scoring)
+        if sandwich:
+            y = norm(y, f"post_ffn_norm_{i}")
         h = ff.add(h, y, name=f"ffn_res_{i}")
     return ff.softmax(ff.dense(norm(h, "final_norm"), cfg.vocab_size,
                                use_bias=False, name="lm_head"))

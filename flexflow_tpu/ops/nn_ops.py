@@ -502,6 +502,10 @@ class MultiHeadAttentionOp(OpDef):
                               InitializerType.ONE),
                    WeightSpec("k_norm", (kdim // h,), dt,
                               InitializerType.ONE)]
+        if params.get("output_gate", False):
+            # an elementwise sigmoid gate on the attention's output, read
+            # from the layer's input: a projection of its own, as wq is
+            ws.append(WeightSpec("wg", (qe, h, vdim // h), dt))
         if params.get("indexer_heads"):
             # the sparse-attention indexer: its queries, its one key
             # head and a weight a query head (``ops/sparse_attention``)
@@ -563,10 +567,12 @@ class MultiHeadAttentionOp(OpDef):
         """Whether this attention call takes the Pallas flash kernel.
         ``impl`` is the adopted plan's for this layer: "flash" and "xla"
         decide, anything else (no plan) asks :meth:`auto_takes_flash` on
-        a backend that compiles the kernel. The kernel has no
-        sliding-window mask and no causal mask for ``q_len != kv_len``:
-        those stay on XLA whoever asks."""
-        if window or (causal and q_len != kv_len):
+        a backend that compiles the kernel. A ``window`` is the
+        kernels' own band arithmetic on the full training or eval
+        forward (``q_len == kv_len``); the kernels have no causal mask
+        for ``q_len != kv_len`` (prefill against a cache, decode), with
+        a window or without: those stay on XLA whoever asks."""
+        if causal and q_len != kv_len:
             return False
         if impl in ("flash", "xla"):
             return impl == "flash"
@@ -661,9 +667,19 @@ class MultiHeadAttentionOp(OpDef):
                 y = y + b.astype(jnp.float32)
             return y
 
-        qh = proj(q, weights["wq"], weights.get("bq"))
-        kh = proj(k, weights["wk"], weights.get("bk"))
-        vh = proj(v, weights["wv"], weights.get("bv"))
+        with jax.named_scope("attn.proj"):
+            qh = proj(q, weights["wq"], weights.get("bq"))
+            kh = proj(k, weights["wk"], weights.get("bk"))
+            vh = proj(v, weights["wv"], weights.get("bv"))
+            # the output gate's pre-activation, (B, L, h, dv) float32,
+            # from the input the query projection reads
+            gate = proj(q, weights["wg"], None) \
+                if params.get("output_gate", False) else None
+        window = params.get("sliding_window", 0)
+        if gate is not None and (params.get("indexer_heads")
+                                 or self._impl_for(ctx, name) == "ring"):
+            raise ValueError(f"{name}: an output gate is built on the "
+                             f"flash, XLA and decode paths only")
         # qh.shape[2], not params["num_heads"]: under the tp attn role
         # this code runs inside shard_map with LOCAL head counts
         heads = qh.shape[2]
@@ -673,56 +689,57 @@ class MultiHeadAttentionOp(OpDef):
         # here on they are (B, h, L, d) in ``mdt`` where ``fused``
         fused = self._takes_norm_rope_kernel(params, ctx, name, qh, kh, vh,
                                              rate, mdt)
-        if params.get("qk_norm", False):
-            # RMSNorm over each head's own entries, before the rotary
-            # embedding; ahead of the decode branch, so the cache holds
-            # normed (and rotated) keys
-            eps = params.get("qk_norm_eps", 1e-6)
-            if not fused:
-                qh = _rms(qh, weights["q_norm"], eps)
-                kh = _rms(kh, weights["k_norm"], eps)
-            if events.enabled():
-                events.instant("attn.qk_norm", layer=name, heads=h,
-                               kv_heads=kh.shape[2], head_dim=qh.shape[-1],
-                               tokens=qh.shape[0] * qh.shape[1],
-                               impl="kernel" if fused else "xla")
+        with jax.named_scope("attn.norm_rope"):
+            if params.get("qk_norm", False):
+                # RMSNorm over each head's own entries, before the rotary
+                # embedding; ahead of the decode branch, so the cache holds
+                # normed (and rotated) keys
+                eps = params.get("qk_norm_eps", 1e-6)
+                if not fused:
+                    qh = _rms(qh, weights["q_norm"], eps)
+                    kh = _rms(kh, weights["k_norm"], eps)
+                if events.enabled():
+                    events.instant("attn.qk_norm", layer=name, heads=h,
+                                   kv_heads=kh.shape[2], head_dim=qh.shape[-1],
+                                   tokens=qh.shape[0] * qh.shape[1],
+                                   impl="kernel" if fused else "xla")
 
-        causal = params.get("causal", False)
-        kv_mode = getattr(ctx, "kv_mode", None)
-        if params.get("rope", False):
-            # rotary embeddings applied in-op (LLaMA convention,
-            # half-split rotate) — positions are absolute indices, so
-            # the single decode token rotates at kv_index and the cache
-            # stores already-rotated keys
-            if not causal:
-                raise ValueError(
-                    "rope is only supported for causal attention")
-            if qh.shape[1] != kh.shape[1]:
-                raise ValueError(
-                    "rope=True requires self-attention (Lq == Lk); "
-                    "cross-attention has no single absolute position "
-                    "stream")
-            theta = float(params.get("rope_theta", 10000.0))
-            if kv_mode == "decode":
-                kvi = jnp.asarray(ctx.kv_index)
-                # scalar index -> (1,); per-row (ragged prompts) -> (B,1)
-                pos = kvi[:, None] if kvi.ndim else kvi[None]
-            elif positions:
-                pos = positions[0]
-            else:
-                pos = jnp.arange(qh.shape[1], dtype=jnp.int32)
-            if fused:
-                from ..kernels import qk_norm_rope as nrk
-                tables = nrk.rope_tables(pos, qh.shape[-1], theta)
-                qh = nrk.qk_norm_rope(qh, weights["q_norm"], tables,
-                                      eps=eps, dtype=mdt)
-                kh = nrk.qk_norm_rope(kh, weights["k_norm"], tables,
-                                      eps=eps, dtype=mdt,
-                                      repeat=heads // kh.shape[2])
-                ctx.count("attn.norm_rope_kernel_layers", jnp.float32(1.0))
-            else:
-                qh = _apply_rope(qh, pos, theta)
-                kh = _apply_rope(kh, pos, theta)
+            causal = params.get("causal", False)
+            kv_mode = getattr(ctx, "kv_mode", None)
+            if params.get("rope", False):
+                # rotary embeddings applied in-op (LLaMA convention,
+                # half-split rotate) — positions are absolute indices, so
+                # the single decode token rotates at kv_index and the cache
+                # stores already-rotated keys
+                if not causal:
+                    raise ValueError(
+                        "rope is only supported for causal attention")
+                if qh.shape[1] != kh.shape[1]:
+                    raise ValueError(
+                        "rope=True requires self-attention (Lq == Lk); "
+                        "cross-attention has no single absolute position "
+                        "stream")
+                theta = float(params.get("rope_theta", 10000.0))
+                if kv_mode == "decode":
+                    kvi = jnp.asarray(ctx.kv_index)
+                    # scalar index -> (1,); per-row (ragged prompts) -> (B,1)
+                    pos = kvi[:, None] if kvi.ndim else kvi[None]
+                elif positions:
+                    pos = positions[0]
+                else:
+                    pos = jnp.arange(qh.shape[1], dtype=jnp.int32)
+                if fused:
+                    from ..kernels import qk_norm_rope as nrk
+                    tables = nrk.rope_tables(pos, qh.shape[-1], theta)
+                    qh = nrk.qk_norm_rope(qh, weights["q_norm"], tables,
+                                          eps=eps, dtype=mdt)
+                    kh = nrk.qk_norm_rope(kh, weights["k_norm"], tables,
+                                          eps=eps, dtype=mdt,
+                                          repeat=heads // kh.shape[2])
+                    ctx.count("attn.norm_rope_kernel_layers", jnp.float32(1.0))
+                else:
+                    qh = _apply_rope(qh, pos, theta)
+                    kh = _apply_rope(kh, pos, theta)
         if params.get("indexer_heads") and (
                 kv_mode is not None or not causal or rate > 0.0
                 or params.get("sliding_window", 0)):
@@ -759,7 +776,7 @@ class MultiHeadAttentionOp(OpDef):
                 ctx.new_kv[name] = {"k": kh, "v": vh}
         elif kv_mode == "decode":
             return self._emit_decode(params, weights, ctx, name, qh, kh,
-                                     vh, mdt, cdt)
+                                     vh, mdt, cdt, gate)
         if params.get("indexer_heads"):
             return self._emit_sparse(params, q, weights, ctx, name, qh, kh,
                                      vh, mdt, cdt, fused)
@@ -778,9 +795,19 @@ class MultiHeadAttentionOp(OpDef):
             self._note_impl(ctx, name, "ring")
             return self._emit_ring(weights, ctx, name, qh, kh, vh, mdt,
                                    cdt, causal)
-        if fused or self._flash_enabled(
+        if window and kv_mode is None:
+            # the band's pairs beside the causal ones, whoever masks them
+            b_, s_ = q.shape[0], q.shape[1]
+            causal_pairs = b_ * s_ * (s_ + 1) / 2
+            w_ = min(window, s_)
+            ctx.count("attn.window_pairs",
+                      jnp.float32(b_ * (w_ * s_ - w_ * (w_ - 1) / 2)))
+            ctx.count("attn.causal_pairs", jnp.float32(causal_pairs))
+        # a windowed prefill keeps its XLA path beside the ring-buffer
+        # cache it fills: the kernels' window is the full forward's
+        if fused or ((not window or kv_mode is None) and self._flash_enabled(
                 impl, qh.shape[1], kh.shape[1], qh.shape[-1], vh.shape[-1],
-                rate, causal=causal, window=params.get("sliding_window", 0)):
+                rate, causal=causal, window=window)):
             # Pallas flash kernel ((b,h,s,d) layout); dropout on the
             # probabilities is counter-based and in-kernel, compiled on
             # TPU and in interpret mode alike, seeded from this layer's
@@ -792,21 +819,21 @@ class MultiHeadAttentionOp(OpDef):
                                           0, 2 ** 31 - 1, jnp.int32)
             self._note_impl(ctx, name, "flash")
             mesh, spec = self._kernel_shard_spec(ctx, qh.shape[0], heads)
-            o = flash_attention(
-                *((qh, kh) if fused else
-                  (jnp.swapaxes(qh, 1, 2).astype(mdt),
-                   jnp.swapaxes(kh, 1, 2).astype(mdt))),
-                jnp.swapaxes(vh, 1, 2).astype(mdt),
-                causal=causal,
-                dropout_rate=rate, dropout_seed=seed,
-                mesh=mesh, spec=spec)
+            with jax.named_scope("attn.kernels"):
+                # a window is the kernels' own band arithmetic, never a
+                # mask operand; under a mesh the shard_map wrap passes
+                # it through (heads and batch are what is sharded)
+                o = flash_attention(
+                    *((qh, kh) if fused else
+                      (jnp.swapaxes(qh, 1, 2).astype(mdt),
+                       jnp.swapaxes(kh, 1, 2).astype(mdt))),
+                    jnp.swapaxes(vh, 1, 2).astype(mdt),
+                    causal=causal,
+                    dropout_rate=rate, dropout_seed=seed,
+                    mesh=mesh, spec=spec,
+                    **({"window": window} if window else {}))
             ctxv = jnp.swapaxes(o, 1, 2).astype(jnp.float32)
-            out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(mdt),
-                             weights["wo"].astype(mdt),
-                             preferred_element_type=jnp.float32)
-            if "bo" in weights:
-                out = out + weights["bo"].astype(jnp.float32)
-            return [out.astype(cdt)]
+            return self._project_out(ctxv, gate, weights, ctx, mdt, cdt)
 
         self._note_impl(ctx, name, "xla")
         scale = 1.0 / math.sqrt(qh.shape[-1])
@@ -834,11 +861,28 @@ class MultiHeadAttentionOp(OpDef):
         ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(mdt),
                           vh.astype(mdt),
                           preferred_element_type=jnp.float32)
-        out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(mdt),
-                         weights["wo"].astype(mdt),
-                         preferred_element_type=jnp.float32)
-        if "bo" in weights:
-            out = out + weights["bo"].astype(jnp.float32)
+        return self._project_out(ctxv, gate, weights, ctx, mdt, cdt)
+
+    @staticmethod
+    def _project_out(ctxv, gate, weights, ctx, mdt, cdt):
+        """The heads' outputs ``ctxv`` (B, L, h, dv) float32, times the
+        sigmoid of the output gate's pre-activation where the layer has
+        one (elementwise, in float32, one pass over the array: plain
+        XLA, bound by bytes), through the output projection."""
+        if gate is not None:
+            with jax.named_scope("attn.gate"):
+                g = jax.nn.sigmoid(gate)
+                ctxv = ctxv * g
+                # the layers' mean gate: 0.5 at untrained weights, and a
+                # step that lost its gate reads no ``attn.gate_layers``
+                ctx.count("attn.gate_mean", jnp.mean(g))
+                ctx.count("attn.gate_layers", jnp.float32(1.0))
+        with jax.named_scope("attn.out"):
+            out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(mdt),
+                             weights["wo"].astype(mdt),
+                             preferred_element_type=jnp.float32)
+            if "bo" in weights:
+                out = out + weights["bo"].astype(jnp.float32)
         return [out.astype(cdt)]
 
     def keeps_for_block(self, params):
@@ -967,7 +1011,7 @@ class MultiHeadAttentionOp(OpDef):
         return [out.astype(cdt)]
 
     def _emit_decode(self, params, weights, ctx, name, qh, kh, vh, mdt,
-                     cdt):
+                     cdt, gate=None):
         """Single-token decode against the KV cache: write this
         position's K/V into the cache, attend the length-1 query over
         positions <= kv_index. Exactly matches the full re-forward's row
@@ -1042,12 +1086,7 @@ class MultiHeadAttentionOp(OpDef):
                           v_full.astype(mdt),
                           preferred_element_type=jnp.float32)
         ctxv = ctxv.reshape(b_, lq_, hq, d_)
-        out = jnp.einsum("bqhd,hde->bqe", ctxv.astype(mdt),
-                         weights["wo"].astype(mdt),
-                         preferred_element_type=jnp.float32)
-        if "bo" in weights:
-            out = out + weights["bo"].astype(jnp.float32)
-        return [out.astype(cdt)]
+        return self._project_out(ctxv, gate, weights, ctx, mdt, cdt)
 
     def flops(self, params, in_shapes, out_shapes):
         b, lq, _ = in_shapes[0]
@@ -1058,7 +1097,11 @@ class MultiHeadAttentionOp(OpDef):
         proj = (2.0 * b * lq * e * e                      # q proj
                 + 2.0 * b * 2 * lk * e * e * kv_frac     # k+v (GQA)
                 + 2.0 * b * lq * e * e)                  # out proj
-        attn = 2.0 * b * lq * lk * e * 2
+        if params.get("output_gate", False):
+            proj += 2.0 * b * lq * e * e                 # the gate's
+        # a window's cost is the band's: at most ``window`` keys a query
+        keys = min(lk, params.get("sliding_window", 0) or lk)
+        attn = 2.0 * b * lq * keys * e * 2
         return proj + attn
 
     def backward_flops_factor(self):

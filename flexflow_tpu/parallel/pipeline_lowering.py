@@ -459,7 +459,8 @@ def assign_tp_roles(template: Sequence[Layer], tp: int
 
 
 def find_repeated_run(layers: Sequence[Layer], n_parts: int = 1,
-                      shared: frozenset = frozenset()
+                      shared: frozenset = frozenset(),
+                      signature=layer_signature
                       ) -> Optional[Tuple[int, int, int]]:
     """The maximal verified run of identical consecutive chunks whose
     repeat count is divisible by ``n_parts``. Returns
@@ -467,10 +468,14 @@ def find_repeated_run(layers: Sequence[Layer], n_parts: int = 1,
     finder and the block-rematerialization pass. ``shared``: guids every
     chunk may read beside its entry tensor (the rematerialization pass
     hands its blocks the graph's inputs; a pipeline stage gets only its
-    entry, so the region finder passes none)."""
+    entry, so the region finder passes none). ``signature``: what makes
+    two layers the same (a pipeline stage is one template run with
+    stacked weights, so its chunks are equal to the last parameter;
+    a rematerialised block is emitted from its own layers and asks
+    less)."""
     layers = list(layers)
     n = len(layers)
-    sigs = [layer_signature(l) for l in layers]
+    sigs = [signature(l) for l in layers]
     best: Optional[Tuple[int, int, int]] = None  # (total_len, start, unit)
     for unit in range(1, n // max(n_parts, 2) + 1):
         for start in range(n - unit * 2 + 1):

@@ -77,6 +77,7 @@ def transformer_strategy(layers, input_tensors, dmesh: DeviceMesh,
             if heads % tp_size == 0:
                 w = {"wq": P(None, tp, None), "wk": P(None, tp, None),
                      "wv": P(None, tp, None), "wo": P(tp, None, None),
+                     "wg": P(None, tp, None),
                      "bq": P(tp, None), "bk": P(tp, None), "bv": P(tp, None),
                      "bo": P()}
             else:

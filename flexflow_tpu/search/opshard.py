@@ -68,7 +68,8 @@ def options_for(layer: Layer) -> List[ShardOption]:
         # create_partition_attention_combine
         opts.append(ShardOption("parameter", -1,
                                 (("wq", 1), ("wk", 1), ("wv", 1), ("wo", 0),
-                                 ("bq", 0), ("bk", 0), ("bv", 0))))
+                                 ("bq", 0), ("bk", 0), ("bv", 0),
+                                 ("wg", 1))))
     elif t == OperatorType.OP_GATED_SHORT_CONV:
         sample()
         # channel-parallel: w_in's and the taps' channel dim, w_out's

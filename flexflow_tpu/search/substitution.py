@@ -555,7 +555,7 @@ def create_partition_attention_combine(degree: int) -> GraphXfer:
               ann=ParAnn(groups=((g, degree),),
                          weights=(("wq", 1, g), ("wk", 1, g), ("wv", 1, g),
                                   ("wo", 0, g), ("bq", 0, g), ("bk", 0, g),
-                                  ("bv", 0, g)),
+                                  ("bv", 0, g), ("wg", 1, g)),
                          reduce=g))
     red = _reduction(dst.out(), degree, g)
     return GraphXfer(f"partition_attention_combine_deg{degree}", [src],
@@ -705,7 +705,7 @@ def create_partition_attention_combine_2d(dp: int, tp: int) -> GraphXfer:
                          weights=(("wq", 1, g2), ("wk", 1, g2),
                                   ("wv", 1, g2), ("wo", 0, g2),
                                   ("bq", 0, g2), ("bk", 0, g2),
-                                  ("bv", 0, g2)),
+                                  ("bv", 0, g2), ("wg", 1, g2)),
                          reduce=g2))
     red = _reduction(dst.out(), tp, g2)
     comb = _combine(red.out(), 0, dp, g1)

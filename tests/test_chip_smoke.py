@@ -18,7 +18,7 @@ import chip_smoke
 from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
                                      HybridConvMoEConfig, KeyeRankConfig,
                                      KimiLinearRankConfig, LatentMoEConfig,
-                                     XingRankConfig)
+                                     TrinityRankConfig, XingRankConfig)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -181,6 +181,29 @@ def test_leg_g_sparse_index_moe_tiny_on_the_cpu_mesh(capsys):
     assert f"python3 {chip_smoke.VALIDATION_SPARSE}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_SPARSE))
+
+
+def test_leg_h_window_gated_moe_tiny_on_the_cpu_mesh(capsys):
+    """A share of 4 of 16 experts on the 8-device mesh, 32 positions
+    under a window of 24: the four expert layers, whose attention layers
+    differ (window, full, window, window), are the rematerialised run;
+    the counters give the band's share and the gate's mean."""
+    cfg = dataclasses.replace(TrinityRankConfig.tiny(), num_experts=4,
+                              num_experts_published=16)
+    chip_smoke.leg_window_gated_moe(cfg, seq=32, per_chip_batch=1,
+                                    label="H/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    layers = "['attn_0', 'attn_1', 'attn_2', 'attn_3', 'attn_4']"
+    assert "rematerialised run (15, 8, 4) keeps 0 outputs" in out
+    assert (f"attn.qk_norm {layers}; moe.route ['experts_1', 'experts_2', "
+            f"'experts_3', 'experts_4']; resolved ['xla'] in 5 layers") in out
+    want = sum(min(t + 1, 24) for t in range(32)) / (32 * 33 / 2)
+    assert f"keeps {want:.6f} of the causal pairs ({want:.6f} by count)" \
+        in out
+    assert "moe.dropped 0.0, moe.overflow 0.0" in out
+    assert f"python3 {chip_smoke.VALIDATION_WINDOW}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_WINDOW))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

@@ -469,9 +469,13 @@ def _ctx(plan=None):
     (dict(q=4096, impl="xla"), True, False),
     (dict(q=4096, impl="ring"), True, True),
     (dict(q=16, impl="ring"), True, False),
-    # what the kernel cannot mask stays on XLA whoever asks
-    (dict(q=4096, window=1024, causal=True), True, False),
-    (dict(q=4096, window=1024, causal=True, impl="flash"), True, False),
+    # a window on the full forward is the kernels' own band arithmetic
+    # (PR 51); what they cannot mask stays on XLA whoever asks
+    (dict(q=4096, window=1024, causal=True), True, True),
+    (dict(q=4096, window=1024, causal=True, impl="flash"), True, True),
+    (dict(q=512, window=256, causal=True), True, False),
+    (dict(q=512, kv=1024, window=256, causal=True, impl="flash"), True,
+     False),
     (dict(q=512, kv=1024, causal=True, impl="flash"), True, False),
     (dict(q=512, kv=1024), True, True),
 ])

@@ -210,7 +210,7 @@ def mesh_of(size):
     ("no rotary embedding", {"rope": False}, {}, 128, False),
     ("a mesh of several devices", {}, {"mesh": mesh_of(4)}, 128, False),
     ("inside a manual region", {}, {"local_shape": True}, 128, False),
-    ("a sliding window", {"sliding_window": 8}, {}, 128, False),
+    ("a sliding window", {"sliding_window": 8}, {}, 128, True),
     ("the XLA path forced", {}, {"impl": "xla"}, 128, False),
     ("ring forced", {}, {"impl": "ring"}, 128, False),
 ], ids=lambda v: v.replace(" ", "_").replace("/", "_")

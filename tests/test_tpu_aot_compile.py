@@ -879,3 +879,40 @@ def test_where_it_says_yes_the_layer_calls_the_kernels(v5e_devices,
     assert lowered.count("qk_norm_rope_fwd") >= 2 \
         and lowered.count("qk_norm_rope_bwd") >= 2
     assert set(counted) == {"attn.norm_rope_kernel_layers"}
+
+
+# ----------------------------------------------------------------------
+# the window inside the flash kernels (PR 51)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("window", [2048, 1000])
+def test_the_windowed_kernels_compile_at_cell_8s_shapes(v5e_devices,
+                                                        chip_locations,
+                                                        window):
+    """``trinity_mini.train.1chip``: 32 heads of 128 over 8,192
+    positions, bf16, causal, a window of 2,048 (and one that is no
+    multiple of a tile or of the forward's piece): the three kernels
+    with the band's index maps and liveness tests compile under Mosaic,
+    and the call has no operand beyond the causal call's (a window is
+    never a mask)."""
+    b, h, s, d = 1, 32, 8192, 128
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    qkv = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v, window):
+        o = flash_attention(q, k, v, causal=True, interpret=False,
+                            window=window)
+        return jnp.sum(o.astype(jnp.float32))
+
+    def text(window):
+        return _compile_text(jax.grad(functools.partial(
+            loss, window=window), argnums=(0, 1, 2)), qkv, qkv, qkv)
+
+    banded, causal = text(window), text(0)
+    assert _kernel_names(banded) == FLASH_NAMES
+
+    def operands(txt):
+        return sorted(l.split(" custom-call(")[1].count("%")
+                      for l in txt.splitlines() if MOSAIC_CALL in l)
+
+    assert operands(banded) == operands(causal)
+    assert "s8[" not in banded

@@ -910,6 +910,14 @@ def leg_sparse_index_moe(model_cfg, seq: int, per_chip_batch: int,
     check(fused == float(want_impl == "flash" and head_dim % 128 == 0),
           f"{label}: q and k went through the norm-and-rotary kernel in "
           f"{fused} of the layer-steps (heads of {head_dim})")
+    # and the flash kernels read the key/value heads in place there
+    grouped = ctr.get("attn.grouped_kv_layers", 0) / max(
+        1.0, ctr.get("dsa.layers", 0))
+    check(grouped == float(
+        want_impl == "flash"
+        and model_cfg.num_key_value_heads < model_cfg.num_attention_heads),
+        f"{label}: the kernels read grouped key/value heads in place in "
+        f"{grouped} of the layer-steps")
     topk = model_cfg.sa_config["topk"]
     want = sum(min(t + 1, topk) for t in range(seq)) / (seq * (seq + 1) / 2)
     share = ctr.get("dsa.kept_pairs", 0) / max(

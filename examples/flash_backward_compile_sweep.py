@@ -11,9 +11,12 @@ more at 192 / 128. It passes where every tile the rule admits compiles;
 a tile Mosaic refuses (over its 16 MiB of scoped VMEM) has to count over
 the budget. Run it after a change to either kernel's body, operands or
 scratch, before any chip time (PERF.md section 6, PR 28 and PR 39).
+``--kv-group N`` hands k and v at an N-th of q's heads (grouped-query
+attention read in place, PR 52: ``bwd_dkv``'s other grid and index
+maps); the default, 1, is the kernels at equal head counts.
 
     JAX_PLATFORMS=cpu python3 examples/flash_backward_compile_sweep.py \\
-        [--out chiprun_out/bwd_sweep.jsonl]
+        [--kv-group 4] [--out chiprun_out/bwd_sweep.jsonl]
 """
 import argparse
 import importlib
@@ -37,7 +40,10 @@ S, BH = 2048, 2
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None, help="one JSON line a compile")
+    ap.add_argument("--kv-group", type=int, default=1,
+                    help="query heads that read one key/value head")
     args = ap.parse_args()
+    heads = BH * args.kv_group
 
     import jax
     import jax.numpy as jnp
@@ -63,11 +69,11 @@ def main():
         bq, bk = (large, small) if kernel == "bwd_dq" else (small, large)
         counted = fa._bwd_vmem_bytes(kernel, bq, bk, d, dtype.itemsize,
                                      dropout, dv)
-        operands = (shape((1, 1), jnp.int32), shape((BH, S, d), dtype),
+        operands = (shape((1, 1), jnp.int32), shape((heads, S, d), dtype),
                     shape((BH, S, d), dtype), shape((BH, S, dv), dtype),
-                    shape((BH, S, dv), dtype),
-                    shape((BH, 1, S), jnp.float32),
-                    shape((BH, 1, S), jnp.float32))
+                    shape((heads, S, dv), dtype),
+                    shape((heads, 1, S), jnp.float32),
+                    shape((heads, 1, S), jnp.float32))
         try:
             jax.jit(lambda *a: call(
                 *a, S, d ** -0.5, causal, bq, bk, 0.1 if dropout else 0.0,
@@ -76,8 +82,9 @@ def main():
         except Exception as e:  # noqa: BLE001 — whatever Mosaic raises
             refused = str(e).splitlines()[0][:200]
         row = {"kernel": "flash_attention_" + kernel,
-               "tile_form": fa.grid_steps(kernel, BH, S, S, bq, bk,
+               "tile_form": fa.grid_steps(kernel, heads, S, S, bq, bk,
                                           causal)["tile"],
+               "kv_group": args.kv_group,
                "dtype": dtype.name, "d": d, "dv": dv, "dropout": dropout,
                "causal": causal, "block_q": bq, "block_k": bk,
                "counted_mib": counted / 2 ** 20,

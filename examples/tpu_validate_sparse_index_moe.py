@@ -57,7 +57,7 @@ failure):
      acceptance's "quiet before it is offered": no layer over at any
      seed);
   6. ``--time-kernels``: each of the four kernels alone at the cell's
-     shape (1 x 32 heads x ``--seq`` x 128, bf16, causal, a mask of the
+     shape (1 x 32 heads on 4 x ``--seq`` x 128, bf16, causal, a mask of the
      2,048 largest of random scores a row), ms a call on the host's
      clock over 10 calls and the FLOP/s over all causal pairs that
      makes, at the derived blocks and at explicit ones around them.
@@ -368,15 +368,16 @@ def gradient_checks(conf, ref, seed, seq=4096):
     del models, ff
 
 
-def time_kernels(seq, heads=32, d=128, topk=2048, calls=10):
-    """Each of the four kernels alone at the cell's shape: ms a call and
-    the FLOP/s over ALL causal pairs (what the kernels multiply: the
+def time_kernels(seq, heads=32, kv_heads=4, d=128, topk=2048, calls=10):
+    """Each of the four kernels alone at the cell's shape, k and v at
+    the model's own ``kv_heads`` (read in place since PR 52): ms a call
+    and the FLOP/s over ALL causal pairs (what the kernels multiply: the
     selection is scattered over every causal tile) that makes."""
     import importlib
     fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
     ks = jax.random.split(jax.random.key(49), 5)
-    q, k, v, do = (jax.random.normal(ks[i], (1, heads, seq, d),
-                                     jnp.bfloat16) for i in range(4))
+    q, k, v, do = (jax.random.normal(ks[i], (1, n, seq, d), jnp.bfloat16)
+                   for i, n in enumerate((heads, kv_heads, kv_heads, heads)))
     scores = jax.random.normal(ks[4], (1, seq, seq), jnp.float32)
     causal = jnp.tril(jnp.ones((seq, seq), bool))
     kth = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf),

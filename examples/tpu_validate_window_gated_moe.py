@@ -15,9 +15,10 @@ reference ``benchmarks/reference/window_gated_moe_ref.py`` (float32,
 ``highest``), both at the same weights drawn from each seed. Checks
 (each prints PASS/FAIL, exit code 1 on any failure):
 
-  1. the three flash kernels under a window of 2,048 at (bh 4, s 8192,
-     d 128, bf16: half a kv head's group of query heads; the golden's
-     s x s scores fit for no more), forward and the three gradients,
+  1. the three flash kernels under a window of 2,048 at (4 query heads
+     on 1 key/value head, read in place, s 8192, d 128, bf16: half a kv
+     head's group of query heads; the golden's s x s scores fit for no
+     more), forward and the three gradients,
      against a plain banded softmax at ``highest`` precision, and the
      same without the window against the causal one; the ``flash.grid``
      instants say the band's live steps beside the causal call's;
@@ -99,10 +100,12 @@ def banded(q, k, v, window):
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def kernels(seq, window):
+def kernels(seq, window, kv_heads=1):
+    """4 query heads on ``kv_heads`` key/value heads, which the kernels
+    read in place (PR 52) and the plain softmax reads repeated."""
     ks = jax.random.split(jax.random.key(51), 4)
-    q, k, v = (jax.random.normal(ks[i], (1, 4, seq, 128), jnp.bfloat16)
-               for i in range(3))
+    q, k, v = (jax.random.normal(ks[i], (1, n, seq, 128), jnp.bfloat16)
+               for i, n in enumerate((4, kv_heads, kv_heads)))
     w = jax.random.normal(ks[3], (1, 4, seq, 128), jnp.float32)
 
     def graded(fn):
@@ -114,7 +117,9 @@ def kernels(seq, window):
         tag = f"flash 128/128 at {seq}, window {win}"
 
         def gold(q, k, v, win=win):
-            return banded(*(x.astype(jnp.float32) for x in (q, k, v)), win)
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+            return banded(q, *(jnp.repeat(x, 4 // kv_heads, axis=1)
+                               for x in (k, v)), win)
 
         def flash(q, k, v, win=win):
             return flash_attention(q, k, v, causal=True, window=win)

@@ -26,6 +26,13 @@ either side of the band skipped, their index maps naming the band's
 nearest block so that nothing is copied for them. It is never an operand;
 with ``window=0`` every kernel is the text it was (docs/kernels.md).
 
+Grouped-query attention (k and v at fewer heads than q) is index
+arithmetic too: the kernels read the key/value heads in place, a query
+head naming its group's row, and ``bwd_dkv`` walks the key/value heads
+and adds a group's query heads into one accumulator. Nothing is repeated
+before the calls and no gradient is summed after them; with equal head
+counts every kernel is the text it was (docs/kernels.md).
+
 Layout: (batch, heads, seq, head_dim), batch*heads collapsed into one grid
 axis. Sequence/head dims are padded to block/lane multiples; the padded-key
 mask is baked in statically (shapes are static under jit). TPU grids
@@ -369,7 +376,8 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, *refs, sm_scale, causal, kv_len, block_q,
-                    block_k, dropout_rate, masked=False, window=0):
+                    block_k, dropout_rate, masked=False, window=0, group=1,
+                    q_blocks=0):
     """A k block stays while q blocks stream, and the tile is held
     KEYS-MAJOR, (block_k, block_q): ``s^T = k q^T`` and ``dp^T = v do^T``
     contract the last dimension of both operands as ``s`` always did,
@@ -383,14 +391,21 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     dead by then: with both products after both tiles the call was 3%
     slower at cell 2's shape (PERF.md section 6, PR 39). ``masked``: a
     (1, block_k, block_q) tile of the call's mask TRANSPOSED (keys by
-    queries, as this tile is held) follows ``delta``."""
+    queries, as this tile is held) follows ``delta``. ``group`` > 1
+    (grouped-query attention): grid axis 0 walks the KEY/VALUE heads and
+    axis 2 the ``group`` query heads that read this one, ``q_blocks``
+    steps each, so the k block stays for all of them and their ``dk``
+    and ``dv`` meet in the float32 scratch, rounded once; the dropout's
+    counter is the QUERY head's, as in the forward."""
     mask_ref, (dk_ref, dv_ref, dk_sc, dv_sc) = _split_mask(refs, masked)
     b = pl.program_id(0)
     ik = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = iq = pl.program_id(2)
+    steps = pl.num_programs(2)
+    if group > 1:       # member ``step // q_blocks`` of k/v head ``b``
+        b, iq = b * group + step // q_blocks, step % q_blocks
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
@@ -423,7 +438,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds.astype(q.dtype), q, over_q,
             preferred_element_type=jnp.float32)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
@@ -477,10 +492,19 @@ def _live_k(block_q, block_k, causal, window):
         _last_live_k(i, block_q, block_k))
 
 
-def _k_spec(block_q, block_k, d, causal, window=0):
-    """k/v blocks of the (bh, nq, nk) grids (see :func:`_live_k`)."""
-    ik = _live_k(block_q, block_k, causal, window)
-    return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, ik(i, j), 0))
+def _kv_row(group):
+    """``row(b)``: the row of the flat (batch * kv heads, sk, d) k and v
+    that query head ``b`` of the flat (batch * heads, ...) arrays reads:
+    its own where the head counts are equal, else its group's."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
+def _k_spec(block_q, block_k, d, causal, window=0, group=1):
+    """k/v blocks of the (bh, nq, nk) grids (see :func:`_live_k`);
+    ``group`` query heads read one k/v head (:func:`_kv_row`)."""
+    ik, row = _live_k(block_q, block_k, causal, window), _kv_row(group)
+    return pl.BlockSpec((1, block_k, d),
+                        lambda b, i, j: (row(b), ik(i, j), 0))
 
 
 def _stat_spec(block_q):
@@ -515,27 +539,49 @@ def _dkv_live_q(block_q, block_k, causal, window=0):
     return lambda j, i: i
 
 
-def _dkv_mask_spec(block_q, block_k, causal, heads):
+def _dkv_member(group, nq):
+    """``(head(b, t), block(t))`` of step ``t`` of the dkv grid's last
+    axis under k/v head ``b``: the query head whose blocks stream and
+    which of its ``nq`` q blocks. With equal head counts the axis is the
+    q blocks of head ``b``; with ``group`` query heads a k/v head it is
+    the group's members one after another, ``nq`` steps each."""
+    if group == 1:
+        return (lambda b, t: b), (lambda t: t)
+    return (lambda b, t: b * group + t // nq), (lambda t: t % nq)
+
+
+def _dkv_mask_spec(block_q, block_k, causal, heads, group=1, nq=0):
     """The (1, block_k, block_q) tile of the TRANSPOSED mask, (batch, sk,
-    sq), in the dkv grid."""
+    sq), in the dkv grid, whose axis 0 walks the ``heads // group`` k/v
+    heads of a batch row."""
     iq = _dkv_live_q(block_q, block_k, causal)
+    _, block = _dkv_member(group, nq)
+    kv_heads = heads // group
     return pl.BlockSpec((1, block_k, block_q),
-                        lambda b, j, i: (b // heads, j, iq(j, i)))
+                        lambda b, j, i: (b // kv_heads, j, iq(j, block(i))))
 
 
-def _dkv_specs(block_q, block_k, d, causal, window=0):
-    """(q/do, k/v, row statistics) specs of the (bh, nk, nq) dkv grid:
-    the index maps swap the roles of grid axes 1 and 2."""
+def _dkv_specs(block_q, block_k, d, causal, window=0, group=1, nq=0):
+    """(q/do, k/v, row statistics) specs of the (b * kv heads, nk,
+    group * nq) dkv grid: the index maps swap the roles of grid axes 1
+    and 2, and a dead step names its OWN member's nearest live block
+    (:func:`_dkv_member`, :func:`_dkv_live_q`)."""
     iq = _dkv_live_q(block_q, block_k, causal, window)
-    return (pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, iq(j, i), 0)),
+    head, block = _dkv_member(group, nq)
+    return (pl.BlockSpec((1, block_q, d),
+                         lambda b, j, i: (head(b, i), iq(j, block(i)), 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, 1, block_q),
-                         lambda b, j, i: (b, 0, iq(j, i))))
+                         lambda b, j, i: (head(b, i), 0, iq(j, block(i)))))
 
 
-def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0):
+def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0,
+               kv_group=1):
     """What the grid of one call costs (``window``: under the causal
-    band of that many keys a query, both of whose edges are skipped).
+    band of that many keys a query, both of whose edges are skipped;
+    ``kv_group``: the query heads that read one k/v head, which changes
+    none of the counts: ``bwd_dkv`` walks the same steps a k/v head at a
+    time).
     ``steps``: all of them, each paid for; ``live_steps``: those whose
     arithmetic runs; ``fetched_steps``:
     those that name another streamed block (k/v for ``fwd`` and
@@ -566,6 +612,8 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0):
            "live_steps": bh * live, "fetched_steps": bh * fetched}
     if window:
         out["window"] = window
+    if kv_group > 1:
+        out["kv_group"] = kv_group
     if kernel == "fwd":
         piece_k = _fwd_piece(block_k)
         out.update(piece_k=piece_k, live_pieces=bh * sum(
@@ -578,13 +626,15 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0):
     return out
 
 
-def _note_grid(kernel, bh, sq, sk, block_q, block_k, causal, masked=False,
+def _note_grid(kernel, q, k, block_q, block_k, causal, masked=False,
                window=0):
-    """One ``flash.grid`` instant per emitted call, at trace time; a
-    masked call's says so, a windowed call's says ``window=``."""
+    """One ``flash.grid`` instant per emitted call (flat operands ``q``
+    and ``k``), at trace time; a masked call's says so, a windowed
+    call's says ``window=``, a grouped one's ``kv_group=``."""
     if events.enabled():
         events.instant("flash.grid", **grid_steps(
-            kernel, bh, sq, sk, block_q, block_k, causal, window),
+            kernel, q.shape[0], q.shape[1], k.shape[1], block_q, block_k,
+            causal, window, q.shape[0] // k.shape[0]),
             **({"masked": True} if masked else {}))
 
 
@@ -614,9 +664,12 @@ def _masked(mask, spec, window=0):
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
               dropout_rate, interpret, mask=None, heads=1, window=0):
-    """``mask``: None or (bh // heads, sq, sk) int8."""
+    """q (bh, sq, d); k and v (bh // group, sk, .): a query head reads
+    its group's row (:func:`_kv_row`). ``mask``: None or (bh // heads,
+    sq, sk) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]      # q.k over d, p.v over dv
+    group = bh // k.shape[0]
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
         _mask_spec, block_q, block_k, causal, heads), window)
     kernel = functools.partial(
@@ -627,8 +680,9 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
         kernel,
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[_SEED_SPEC, _q_spec(block_q, d),
-                  _k_spec(block_q, block_k, d, causal, window),
-                  _k_spec(block_q, block_k, dv, causal, window)] + mask_specs,
+                  _k_spec(block_q, block_k, d, causal, window, group),
+                  _k_spec(block_q, block_k, dv, causal, window, group)]
+        + mask_specs,
         out_specs=[_q_spec(block_q, dv), _stat_spec(block_q)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
@@ -650,16 +704,18 @@ def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
                  causal, block_q, block_k, dropout_rate, interpret,
                  mask=None, heads=1, window=0):
     """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row;
-    ``mask``: None or (bh // heads, sq, sk) int8."""
+    k and v (bh // group, sk, .) as the forward's; ``mask``: None or
+    (bh // heads, sq, sk) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
+    group = bh // k.shape[0]
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
         _mask_spec, block_q, block_k, causal, heads), window)
     stat = _stat_spec(block_q)
     qs = _q_spec(block_q, d)
-    ks = _k_spec(block_q, block_k, d, causal, window)
+    ks = _k_spec(block_q, block_k, d, causal, window, group)
     dos = _q_spec(block_q, dv)
-    vs = _k_spec(block_q, block_k, dv, causal, window)
+    vs = _k_spec(block_q, block_k, dv, causal, window, group)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -683,24 +739,32 @@ def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
                   causal, block_q, block_k, dropout_rate, interpret,
                   mask=None, heads=1, window=0):
     """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row;
-    ``mask``: None or the mask TRANSPOSED, (bh // heads, sk, sq) int8."""
+    k and v (bh // group, sk, .), and ``dk``, ``dv`` in their shapes:
+    the grid walks the k/v heads, and a k block stays while its group's
+    query heads' q blocks stream (:func:`_dkv_member`). ``mask``: None
+    or the mask TRANSPOSED, (bh // heads, sk, sq) int8."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
+    group, nq = bh // k.shape[0], sq // block_q
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
-        _dkv_mask_spec, block_q, block_k, causal, heads), window)
-    qs2, ks2, stat2 = _dkv_specs(block_q, block_k, d, causal, window)
-    dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal, window)
+        _dkv_mask_spec, block_q, block_k, causal, heads, group, nq), window)
+    if group > 1:
+        opts = dict(opts, group=group, q_blocks=nq)
+    qs2, ks2, stat2 = _dkv_specs(block_q, block_k, d, causal, window, group,
+                                 nq)
+    dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal, window, group,
+                              nq)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
                           dropout_rate=dropout_rate, **opts),
-        grid=(bh, sk // block_k, sq // block_q),
+        grid=(bh // group, sk // block_k, group * nq),
         in_specs=[_SEED_SPEC, qs2, ks2, vs2, dos2, stat2, stat2]
         + mask_specs,
         out_specs=[ks2, vs2],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, dv), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -723,8 +787,8 @@ def _flash(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
 def _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
                     block_k, dropout_rate, interpret, mask=None, heads=1,
                     window=0):
-    _note_grid("fwd", q.shape[0], q.shape[1], k.shape[1], block_q, block_k,
-               causal, mask is not None, window)
+    _note_grid("fwd", q, k, block_q, block_k, causal, mask is not None,
+               window)
     return _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
                      block_k, dropout_rate, interpret, mask=mask,
                      heads=heads, window=window)
@@ -745,18 +809,15 @@ def _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
     """``(dq, dk, dv)`` from the forward's operands, output and
     log-sum-exp; ``mask``: the forward's, which the dkv call is handed
     transposed (an (sk, sq) int8 copy a batch row, made here)."""
-    bh, sq, _ = q.shape
     masked = mask is not None
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     # one float32 a row, the rows along the lanes: nothing is replicated
     operands = (seed, q, k, v, do, lse[:, None, :], delta[:, None, :],
                 kv_len, sm_scale, causal)
-    _note_grid("bwd_dq", bh, sq, k.shape[1], *dq_blocks, causal, masked,
-               window)
+    _note_grid("bwd_dq", q, k, *dq_blocks, causal, masked, window)
     dq = _bwd_dq_call(*operands, *dq_blocks, dropout_rate, interpret,
                       mask=mask, heads=heads, window=window)
-    _note_grid("bwd_dkv", bh, sq, k.shape[1], *dkv_blocks, causal, masked,
-               window)
+    _note_grid("bwd_dkv", q, k, *dkv_blocks, causal, masked, window)
     dk, dv = _bwd_dkv_call(
         *operands, *dkv_blocks, dropout_rate, interpret,
         mask=jnp.swapaxes(mask, 1, 2) if masked else None, heads=heads,
@@ -996,10 +1057,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     bwd_block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     mesh=None, spec=None, mask=None, window: int = 0):
-    """Tiled flash attention. q: (b, h, sq, d); k: (b, h, sk, d); v:
-    (b, h, sk, dv), and the output (b, h, sq, dv). ``dv`` may differ from
-    ``d`` (latent attention: q.k over 192, p.v over 128); the score scale
-    defaults to ``1 / sqrt(d)``.
+    """Tiled flash attention. q: (b, h, sq, d); k: (b, kvh, sk, d); v:
+    (b, kvh, sk, dv), and the output (b, h, sq, dv). ``dv`` may differ
+    from ``d`` (latent attention: q.k over 192, p.v over 128); the score
+    scale defaults to ``1 / sqrt(d)``.
+
+    ``kvh`` divides ``h`` (grouped-query attention; the group is read
+    off the shapes): query head ``i`` reads k/v head ``i // (h / kvh)``,
+    as if both were repeated with ``jnp.repeat`` on the heads' axis. They
+    are not: the forward and ``bwd_dq`` name the group's row in their
+    k/v index maps, ``bwd_dkv`` walks the k/v heads and sums a group's
+    ``dk`` and ``dv`` in its float32 accumulator, and ``dk``, ``dv``
+    come at ``kvh`` heads. With ``kvh == h`` every call is the text it
+    was (docs/kernels.md).
 
     ``mask``: None, or a (b, sq, sk) int8 (or bool) array shared by the
     heads of a batch row, not 0 where the pair is attended; under
@@ -1110,12 +1180,16 @@ class _Plan(NamedTuple):
 def _prepare(q, k, v, masked, causal, sm_scale, dropout_rate, dropout_seed,
              block_q, block_k, bwd_block_q, bwd_block_k):
     """``((qp, kp, vp), seed, cut, plan)``: the operands padded and flat,
-    (batch * heads, s, d); the dropout seed as the kernels read it;
-    ``cut``, which gives a flat padded output its (b, h, sq, dv) form
-    back; and the :class:`_Plan`, its blocks derived for a ``masked``
-    call or an unmasked one."""
+    q (batch * heads, sq, d), k and v (batch * kv heads, sk, .) at their
+    own head count (the calls take the group from the two); the dropout
+    seed as the kernels read it; ``cut``, which gives a flat padded
+    output its (b, h, sq, dv) form back; and the :class:`_Plan`, its
+    blocks derived for a ``masked`` call or an unmasked one."""
     b, h, sq, d = q.shape
-    sk, dv = k.shape[2], v.shape[3]
+    kvh, sk, dv = k.shape[1], k.shape[2], v.shape[3]
+    if h % kvh or v.shape[1] != kvh:
+        raise ValueError(f"{h} query heads do not read k's {kvh} and v's "
+                         f"{v.shape[1]} heads in groups")
     if causal and sq != sk:
         raise NotImplementedError("causal flash requires sq == sk")
     if sm_scale is None:
@@ -1158,8 +1232,8 @@ def _prepare(q, k, v, masked, causal, sm_scale, dropout_rate, dropout_seed,
     def cut(o):
         return o.reshape(b, h, sq_p, dv_p)[:, :, :sq, :dv]
 
-    flat = (qp.reshape(b * h, sq_p, d_p), kp.reshape(b * h, sk_p, d_p),
-            vp.reshape(b * h, sk_p, dv_p))
+    flat = (qp.reshape(b * h, sq_p, d_p), kp.reshape(b * kvh, sk_p, d_p),
+            vp.reshape(b * kvh, sk_p, dv_p))
     return flat, seed, cut, _Plan(sk, sm_scale, h, (block_q, block_k),
                                   dq_blocks, dkv_blocks)
 
@@ -1313,10 +1387,13 @@ def head_mean_tiles(sq, sk, d, dtype):
     n for n in _STATIC if n not in ("dropout_rate", "window")))
 def _head_mean_call(q, k, lse, mask_t, kv_len, sm_scale, causal, block_q,
                     block_k, interpret, heads):
-    """q, k (b * heads, s, d); lse (b * heads, 1, sq); the transposed
-    mask (b, sk, sq) int8 -> (b, sq, sk) float32."""
+    """q (b * heads, s, d), k (b * kv heads, s, d); lse (b * heads, 1,
+    sq); the transposed mask (b, sk, sq) int8 -> (b, sq, sk) float32.
+    The heads are the innermost axis, so a group's heads name one k
+    block one after another and it is copied once a group."""
     bh, sq, d = q.shape
     sk = k.shape[1]
+    kv_row = _kv_row(bh // k.shape[0])
     dead = (lambda i, j: j * block_k > (i + 1) * block_q - 1) if causal \
         else (lambda i, j: False)
 
@@ -1334,7 +1411,7 @@ def _head_mean_call(q, k, lse, mask_t, kv_len, sm_scale, causal, block_q,
             pl.BlockSpec((1, block_q, d),
                          lambda b, i, j, n: (row(b, i, j, n), i, 0)),
             pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j, n: (row(b, i, j, n), j, 0)),
+                         lambda b, i, j, n: (kv_row(row(b, i, j, n)), j, 0)),
             pl.BlockSpec((1, 1, block_q),
                          lambda b, i, j, n: (row(b, i, j, n), 0, i)),
             pl.BlockSpec((1, block_k, block_q),
@@ -1354,7 +1431,9 @@ def flash_attention_head_mean(q, k, lse, mask, *, causal: bool = False,
     """The heads' mean attention probability of a masked call,
     ``p[b, t, s] = (1 / h) sum_i exp(q_i[t] . k_i[s] * scale -
     lse_i[t])`` on the attended pairs and 0 elsewhere, (b, sq, sk)
-    float32: q, k (b, h, s, d), ``lse`` (b, h, sq) the log-sum-exp
+    float32: q (b, h, s, d), k (b, kvh, s, d) with ``h % kvh == 0`` (head
+    ``i`` reads k's head ``i // (h / kvh)``), ``lse`` (b, h, sq) the
+    log-sum-exp
     :func:`flash_attention_forward` gave for them and ``mask``. The one
     array over (queries, keys) it writes has no head axis: the heads are
     the innermost, sequential grid axis and add into an accumulator that
@@ -1364,7 +1443,9 @@ def flash_attention_head_mean(q, k, lse, mask, *, causal: bool = False,
         interpret = pallas_interpret()
     q, k, lse = map(jax.lax.stop_gradient, (q, k, lse))
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    kvh, sk = k.shape[1], k.shape[2]
+    if h % kvh:
+        raise ValueError(f"{h} query heads do not read {kvh} in groups")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     qp = _pad_to(_pad_to(q, 8 if sq <= 512 else 128, 2), 64, 3)
@@ -1379,9 +1460,10 @@ def flash_attention_head_mean(q, k, lse, mask, *, causal: bool = False,
     if events.enabled():
         events.instant("flash.grid", kernel="flash_attention_head_mean",
                        block_q=block_q, block_k=block_k, tile="keys_major",
-                       steps=b * h * (sq_p // block_q) * (sk_p // block_k))
+                       steps=b * h * (sq_p // block_q) * (sk_p // block_k),
+                       **({"kv_group": h // kvh} if kvh != h else {}))
     p = _head_mean_call(
-        qp.reshape(b * h, sq_p, d_p), kp.reshape(b * h, sk_p, d_p),
+        qp.reshape(b * h, sq_p, d_p), kp.reshape(b * kvh, sk_p, d_p),
         lse.reshape(b * h, 1, sq_p), mask_t, sk, sm_scale, causal, block_q,
         block_k, interpret, h)
     return p[:, :sq, :sk]

@@ -41,9 +41,11 @@ but the operands), and writes ``dx`` in ``x``'s shape:
   dx     = r * (dn * scale - xh * mean(dn * scale * xh))
 
 all float32. Where the output was repeated to ``repeat`` times the heads
-(grouped-query attention's k: the repeat is one broadcast of the bf16
-heads-first array, made here after the kernel), the kernel reads a
-group's ``repeat`` cotangents and sums them in float32 first. ``dscale``
+(one broadcast of the bf16 heads-first array, made here after the
+kernel), the kernel reads a group's ``repeat`` cotangents and sums them
+in float32 first; the attention op no longer asks for that (the flash
+kernels read grouped-query attention's k at its own heads, PR 52).
+``dscale``
 is accumulated over the heads of a grid row in a resident ``(8, d)``
 block, one a (batch row, tile of positions), and the partials are summed
 outside the kernel.
@@ -280,8 +282,9 @@ def qk_norm_rope(x, scale, tables, *, eps: float, dtype, repeat: int = 1,
     (b, heads * repeat, s, d) in ``dtype``: each head's RMSNorm under
     ``scale`` (d,), the rotary embedding of :func:`rope_tables`'
     ``tables``, rounded once and heads-first, every head ``repeat``
-    times in a row (``jnp.repeat`` on the heads' axis). Differentiable
-    in ``x`` and ``scale``. ``block_s`` and ``heads_per_step`` override
+    times in a row (``jnp.repeat`` on the heads' axis; the flash path
+    leaves it at 1 since its kernels read a group's k head in place).
+    Differentiable in ``x`` and ``scale``. ``block_s`` and ``heads_per_step`` override
     both kernels' tiles (the tests' and the timing script's)."""
     b, s, heads, d = x.shape
     if interpret is None:

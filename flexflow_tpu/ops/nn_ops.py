@@ -645,7 +645,7 @@ class MultiHeadAttentionOp(OpDef):
         kv = kh.shape[2]
         impl = self._impl_for(ctx, name)
         return (impl != "ring" and nrk.takes_kernel(s, h, d, 1, mdt)
-                and nrk.takes_kernel(s, kv, d, h // kv, mdt)
+                and nrk.takes_kernel(s, kv, d, 1, mdt)
                 and self._flash_enabled(
                     impl, s, s, d, vh.shape[-1], rate, causal=True,
                     window=params.get("sliding_window", 0)))
@@ -700,7 +700,9 @@ class MultiHeadAttentionOp(OpDef):
                     kh = _rms(kh, weights["k_norm"], eps)
                 if events.enabled():
                     events.instant("attn.qk_norm", layer=name, heads=h,
-                                   kv_heads=kh.shape[2], head_dim=qh.shape[-1],
+                                   kv_heads=kh.shape[2],
+                                   kv_group=heads // kh.shape[2],
+                                   head_dim=qh.shape[-1],
                                    tokens=qh.shape[0] * qh.shape[1],
                                    impl="kernel" if fused else "xla")
 
@@ -733,9 +735,10 @@ class MultiHeadAttentionOp(OpDef):
                     tables = nrk.rope_tables(pos, qh.shape[-1], theta)
                     qh = nrk.qk_norm_rope(qh, weights["q_norm"], tables,
                                           eps=eps, dtype=mdt)
+                    # k at its own heads: the flash kernels that follow
+                    # read a group's k/v head in place
                     kh = nrk.qk_norm_rope(kh, weights["k_norm"], tables,
-                                          eps=eps, dtype=mdt,
-                                          repeat=heads // kh.shape[2])
+                                          eps=eps, dtype=mdt)
                     ctx.count("attn.norm_rope_kernel_layers", jnp.float32(1.0))
                 else:
                     qh = _apply_rope(qh, pos, theta)
@@ -780,13 +783,28 @@ class MultiHeadAttentionOp(OpDef):
         if params.get("indexer_heads"):
             return self._emit_sparse(params, q, weights, ctx, name, qh, kh,
                                      vh, mdt, cdt, fused)
-        # GQA: expand kv-head groups to the query head count for the
-        # attention contraction (cache/weights stay at kvh heads)
-        if not fused:                  # the kernel has repeated its k
-            kh = self._expand_kv(kh, heads)
-        vh = self._expand_kv(vh, heads)
         impl = self._impl_for(ctx, name)
-        if impl == "ring" and kv_mode is None:
+        ring = impl == "ring" and kv_mode is None
+        # a windowed prefill keeps its XLA path beside the ring-buffer
+        # cache it fills: the kernels' window is the full forward's
+        flash = not ring and (fused or (
+            (not window or kv_mode is None) and self._flash_enabled(
+                impl, qh.shape[1], kh.shape[1], qh.shape[-1], vh.shape[-1],
+                rate, causal=causal, window=window)))
+        mesh, spec = self._kernel_shard_spec(ctx, qh.shape[0], heads) \
+            if flash else (None, None)
+        # GQA: the flash kernels read the kv heads in place (a query
+        # head names its group's row; ``bwd_dkv`` sums a group's heads in
+        # its accumulator). The XLA and ring contractions, and a mesh
+        # whose head axis does not divide the kv heads (the shard_map
+        # wrap shards q, k and v by the one spec: the kv heads have to
+        # get the query heads' entry), get kv-head groups repeated to
+        # the query head count (cache/weights stay at kvh heads)
+        if not (flash and self._kernel_shard_spec(
+                ctx, qh.shape[0], vh.shape[2])[1] == spec):
+            kh = self._expand_kv(kh, heads)
+            vh = self._expand_kv(vh, heads)
+        if ring:
             if rate > 0.0:
                 raise ValueError(
                     f"{name}: kernel impl 'ring' has no in-kernel "
@@ -803,11 +821,7 @@ class MultiHeadAttentionOp(OpDef):
             ctx.count("attn.window_pairs",
                       jnp.float32(b_ * (w_ * s_ - w_ * (w_ - 1) / 2)))
             ctx.count("attn.causal_pairs", jnp.float32(causal_pairs))
-        # a windowed prefill keeps its XLA path beside the ring-buffer
-        # cache it fills: the kernels' window is the full forward's
-        if fused or ((not window or kv_mode is None) and self._flash_enabled(
-                impl, qh.shape[1], kh.shape[1], qh.shape[-1], vh.shape[-1],
-                rate, causal=causal, window=window)):
+        if flash:
             # Pallas flash kernel ((b,h,s,d) layout); dropout on the
             # probabilities is counter-based and in-kernel, compiled on
             # TPU and in interpret mode alike, seeded from this layer's
@@ -818,7 +832,8 @@ class MultiHeadAttentionOp(OpDef):
                 seed = jax.random.randint(ctx.rng_for(name), (),
                                           0, 2 ** 31 - 1, jnp.int32)
             self._note_impl(ctx, name, "flash")
-            mesh, spec = self._kernel_shard_spec(ctx, qh.shape[0], heads)
+            if vh.shape[2] != heads:
+                ctx.count("attn.grouped_kv_layers", jnp.float32(1.0))
             with jax.named_scope("attn.kernels"):
                 # a window is the kernels' own band arithmetic, never a
                 # mask operand; under a mesh the shard_map wrap passes
@@ -905,7 +920,8 @@ class MultiHeadAttentionOp(OpDef):
         sequence is no longer than ``indexer_topk`` every causal key is
         selected and the output is the plain causal path's. ``fused``:
         ``qh`` and ``kh`` come heads-first in ``mdt`` from
-        ``kernels/qk_norm_rope`` (only ever on the kernel path)."""
+        ``kernels/qk_norm_rope`` (only ever on the kernel path). The
+        kernels read ``kh`` and ``vh`` at their own head count."""
         from . import sparse_attention as dsa
         topk, q_chunk = params["indexer_topk"], params["indexer_q_chunk"]
         with jax.named_scope("dsa.index"):
@@ -928,6 +944,8 @@ class MultiHeadAttentionOp(OpDef):
                            q_chunk=q_chunk, chunks=-(-s // q_chunk),
                            selecting=s > topk, positions=s, impl=path)
         if kernels:
+            if vh.shape[2] != params["num_heads"]:
+                ctx.count("attn.grouped_kv_layers", jnp.float32(1.0))
             o, loss, kept, ties = dsa.sparse_index_attention_flash(
                 qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt,
                 qk_heads_first=fused)

@@ -314,9 +314,10 @@ def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
     """:func:`sparse_index_attention` through the flash kernels: the
     same arguments, the same ``(o, loss, kept, ties)``. ``o`` is in
     ``mdt``, the kernels' output type. ``qk_heads_first``: ``q`` and
-    ``k`` come as the kernels take them, (b, h, s, d) in ``mdt`` with
-    k's heads repeated (``kernels/qk_norm_rope``), and only ``v`` is
-    turned here.
+    ``k`` come as the kernels take them, (b, h, s, d) and (b, kvh, s, d)
+    in ``mdt`` (``kernels/qk_norm_rope``), and only ``v`` is turned
+    here. Nothing is repeated: the kernels read k's and v's ``kvh``
+    heads in place, a group of ``h / kvh`` query heads each.
 
     What a rematerialised block around the layer keeps is named here
     (``KEPT_BY_BLOCK``): the selection's mask (int8), the forward
@@ -325,15 +326,12 @@ def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
     index product, no selection and no forward kernel; the backward runs
     the dq and dkv kernels, the head-mean kernel and each chunk's index
     products. Outside such a block the names do nothing."""
-    b, h, s = q.shape[:3] if qk_heads_first \
-        else (q.shape[0], q.shape[2], q.shape[1])
+    b, s = q.shape[0], q.shape[2 if qk_heads_first else 1]
     scores, mask, kept, ties = _scores_and_mask(qi, ki, wi, topk, q_chunk,
                                                 mdt)
     mask = checkpoint_name(mask, KEPT_BY_BLOCK)
     with jax.named_scope("dsa.attend"):
-        def heads_first(x):      # (b, s, heads, d) -> (b, h, s, d)
-            x = jnp.repeat(x, h // x.shape[2], axis=2) \
-                if x.shape[2] != h else x
+        def heads_first(x):      # (b, s, heads, d) -> (b, heads, s, d)
             return jnp.swapaxes(x, 1, 2).astype(mdt)
         qh, kh = (q, k) if qk_heads_first \
             else (heads_first(q), heads_first(k))

@@ -149,6 +149,33 @@ def test_windowed_kernels_with_grouped_keys_and_values(window):
                                    rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("window", [1, 100, 192])
+@pytest.mark.parametrize("what", ["o", "dq", "dk", "dv"])
+@pytest.mark.parametrize("kvh", [1, 2])
+def test_windowed_kernels_read_grouped_keys_and_values_in_place(window, what,
+                                                                kvh):
+    """4 query heads on 1 or 2 k/v heads handed as they are (PR 52): a
+    query head names its group's row, ``bwd_dkv`` walks the k/v heads
+    and a dead step of the band names its own member's nearest live
+    block; against the plain banded softmax on the heads repeated."""
+    q, k, v = _qkv(h=4, kvh=kvh, seed=6)
+    ct = jnp.asarray(np.random.default_rng(8).standard_normal(
+        q.shape, dtype=np.float32))
+    rep = lambda x: jnp.repeat(x, 4 // kvh, axis=1)           # noqa: E731
+    blocks = dict(block_q=BLOCK, block_k=BLOCK, bwd_block_q=64)
+    flash = lambda q, k, v: _flash(q, k, v, window, **blocks)  # noqa: E731
+    plain = lambda q, k, v: _plain(q, rep(k), rep(v), window)  # noqa: E731
+    if what == "o":
+        got, want = flash(q, k, v), plain(q, k, v)
+    else:
+        i = "dq dk dv".split().index(what)
+        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * ct), argnums=i)(
+            q, k, v) for f in (flash, plain))
+        assert got.shape == (q, k, v)[i].shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+
+
 def _by_hand(kernel, s, bq, bk, window, piece=None):
     """Tiles (or forward pieces) with at least one pair in the band, and
     the blocks a row of the grid names, from the band itself."""

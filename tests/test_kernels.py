@@ -1278,3 +1278,234 @@ def test_a_masked_call_says_so_in_its_grid_instants():
         "flash_attention_bwd_dkv", "flash_attention_head_mean"}
     assert all(g.get("masked") for name, g in by_kernel.items()
                if name != "flash_attention_head_mean")
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention (PR 52): k and v at their own head count, read
+# in place; ``bwd_dkv`` sums a group's query heads in its accumulator
+# ---------------------------------------------------------------------------
+# name -> (b, h, kvh, sq, sk, d, dv, causal, the call's further options)
+GROUPED = dict(block_q=128, block_k=128)          # several q and k blocks
+GQA_CASES = {
+    "causal_group4": (2, 8, 2, 256, 256, 64, 64, True, GROUPED),
+    "causal_group8": (1, 8, 1, 256, 256, 32, 32, True, GROUPED),
+    "derived_tiles": (1, 8, 2, 384, 384, 64, 64, True, {}),
+    "window": (1, 8, 2, 256, 256, 32, 32, True, dict(GROUPED, window=96)),
+    "window_group8": (2, 8, 1, 256, 256, 32, 32, True,
+                      dict(GROUPED, window=160, bwd_block_q=64)),
+    "mask": (2, 4, 1, 256, 256, 64, 64, True, {"mask": "topk"}),
+    "mask_not_causal": (2, 6, 2, 256, 256, 64, 64, False,
+                        dict(GROUPED, mask="random")),
+    "not_causal": (2, 8, 2, 256, 384, 64, 64, False, GROUPED),
+    "dropout": (2, 8, 2, 256, 256, 64, 64, True,
+                dict(GROUPED, dropout_rate=0.25, dropout_seed=11)),
+    "dropout_group8": (1, 8, 1, 128, 256, 32, 32, False,
+                       dict(dropout_rate=0.4, dropout_seed=5, block_k=128,
+                            bwd_block_q=64)),
+    "unequal_head_sizes": (1, 8, 2, 256, 256, 24, 16, True, GROUPED),
+    "latent_sizes": (1, 4, 1, 256, 256, 192, 128, True, {}),
+    "pads": (2, 8, 2, 200, 200, 48, 48, True, {}),
+    "pads_cross": (1, 8, 4, 100, 328, 64, 64, False, {}),
+}
+
+
+def _gqa_case(case):
+    b, h, kvh, sq, sk, d, dv, causal, opts = GQA_CASES[case]
+    rng = np.random.default_rng(52)
+    q = jnp.asarray(rng.standard_normal((b, h, sq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, kvh, sk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, kvh, sk, dv)), jnp.float32)
+    opts = dict(opts)
+    if opts.get("mask"):
+        opts["mask"] = _topk_causal_mask(b, sq, 48) \
+            if opts["mask"] == "topk" else _random_mask(b, sq, sk)
+    return q, k, v, causal, opts
+
+
+def _repeated_reference(q, k, v, causal, opts):
+    """A plain softmax at HIGHEST on k and v REPEATED to the query
+    heads (``jnp.repeat``: what the layers did before the kernels read
+    the heads in place), under the call's window, mask and dropout."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    if set(opts) <= {"block_q", "block_k", "bwd_block_q", "bwd_block_k"}:
+        return mha_reference(q, k, v, causal=causal, precision="highest")
+    sq, sk = q.shape[2], k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") \
+        / np.sqrt(q.shape[-1])
+    t, u = np.arange(sq)[:, None], np.arange(sk)[None, :]
+    valid = np.ones((sq, sk), bool)
+    if causal:
+        valid &= u <= t
+    if opts.get("window"):
+        valid &= u > t - opts["window"]
+    valid = jnp.asarray(valid)[None, None]
+    if opts.get("mask") is not None:
+        valid = valid & (opts["mask"][:, None] != 0)
+    p = jax.nn.softmax(jnp.where(valid, s, -1e30), -1)
+    rate = opts.get("dropout_rate", 0.0)
+    if rate:
+        keep = fa.dropout_keep_mask(q.shape[0], q.shape[1], sq, sk, rate,
+                                    opts["dropout_seed"])
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", sorted(GQA_CASES))
+def test_grouped_flash_is_the_reference_on_repeated_keys_and_values(case,
+                                                                    what):
+    q, k, v, causal, opts = _gqa_case(case)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, interpret=True,
+                               **opts)
+
+    def ref(q, k, v):
+        return _repeated_reference(q, k, v, causal, opts)
+
+    if what == "out":
+        got, want = flash(q, k, v), ref(q, k, v)
+    else:
+        i = "qkv".index(what[1])
+        got, want = (jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))), i)(q, k, v)
+                     for f in (flash, ref))
+        assert got.shape == (q, k, v)[i].shape      # dk, dv at kvh heads
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("case", ["causal_group4", "window_group8",
+                                  "dropout", "mask"])
+def test_grouped_flash_is_the_call_on_repeated_operands(case):
+    """The same tiles, the same products and the same dropout counters
+    (the QUERY head's): the output and ``dq`` to the bit, ``dk`` and
+    ``dv`` the group's sum (float32 here, so to rounding)."""
+    q, k, v, causal, opts = _gqa_case(case)
+    group = q.shape[1] // k.shape[1]
+
+    def grads(repeat):
+        def f(q, k, v):
+            if repeat:
+                k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+            o = flash_attention(q, k, v, causal=causal, interpret=True,
+                                **opts)
+            return jnp.sum(jnp.sin(o)), o
+        return jax.value_and_grad(f, (0, 1, 2), has_aux=True)(q, k, v)
+
+    ((_, o1), (dq1, dk1, dv1)), ((_, o2), (dq2, dk2, dv2)) = \
+        grads(False), grads(True)
+    assert np.array_equal(np.asarray(o1), np.asarray(o2))
+    assert np.array_equal(np.asarray(dq1), np.asarray(dq2))
+    for got, want in ((dk1, dk2), (dv1, dv2)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("h,kvh", [(4, 1), (6, 2), (8, 8)])
+@pytest.mark.parametrize("case", ["topk_causal", "random_ragged"])
+def test_head_mean_reads_grouped_keys_in_place(case, h, kvh):
+    b, _, sq, sk, d, causal, make = MASK_CASES[case]
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.standard_normal((b, h, sq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, kvh, sk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, kvh, sk, d)), jnp.float32)
+    mask = make()
+    _, lse = fa.flash_attention_forward(q, k, v, mask, causal=causal,
+                                        interpret=True)
+    got = fa.flash_attention_head_mean(q, k, lse, mask, causal=causal,
+                                       interpret=True)
+    wide = jnp.repeat(k, h // kvh, axis=1)
+    p, want_lse, _ = _masked_softmax(q, wide, mask, causal)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(p.mean(1)),
+                               atol=2e-6, rtol=2e-5)
+    assert np.array_equal(np.asarray(got), np.asarray(
+        fa.flash_attention_head_mean(q, wide, lse, mask, causal=causal,
+                                     interpret=True)))
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+@pytest.mark.parametrize("causal,window", [(False, 0), (True, 0),
+                                           (True, 2048)])
+@pytest.mark.parametrize("group", [4, 8])
+def test_grid_steps_of_a_grouped_call_are_the_ungrouped_calls(kernel, causal,
+                                                              window, group):
+    """Cells 4, 7 and 8's (32 query heads over 8,192 positions): the
+    group changes which blocks a step names, not how many steps there
+    are nor which compute."""
+    args = (kernel, 32, 8192, 8192, 1024, 1024, causal, window)
+    plain, grouped = fa.grid_steps(*args), fa.grid_steps(*args, group)
+    assert "kv_group" not in plain and grouped.pop("kv_group") == group
+    assert grouped == plain
+    assert fa.grid_steps(*args, 1) == plain
+
+
+def test_a_grouped_call_says_so_in_its_grid_instants():
+    q, k, v, causal, opts = _gqa_case("mask")
+    events.enable()
+    try:
+        events.clear()
+        jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, causal=causal, interpret=True, **opts)))(q)
+        _, lse = fa.flash_attention_forward(q, k, v, opts["mask"],
+                                            causal=causal, interpret=True)
+        fa.flash_attention_head_mean(q, k, lse, opts["mask"], causal=causal,
+                                     interpret=True)
+        events.instant("mark")
+        wide = jnp.repeat(k, 4, axis=1)
+        flash_attention(q, wide, jnp.repeat(v, 4, axis=1), causal=causal,
+                        interpret=True, **opts)
+        grids = [e for e in events.events()
+                 if e["name"] in ("flash.grid", "mark")]
+    finally:
+        events.disable()
+    cut = [e["name"] for e in grids].index("mark")
+    grouped = [e["attrs"] for e in grids[:cut]]
+    assert {g["kernel"] for g in grouped} == {
+        "flash_attention_fwd", "flash_attention_bwd_dq",
+        "flash_attention_bwd_dkv", "flash_attention_head_mean"}
+    assert all(g["kv_group"] == 4 for g in grouped)
+    assert grids[cut + 1:] and all(
+        "kv_group" not in e["attrs"] for e in grids[cut + 1:])
+
+
+@pytest.mark.parametrize("h,kvh,vh", [(8, 3, 3), (8, 2, 4), (4, 8, 8)])
+def test_heads_that_do_not_form_groups_are_refused(h, kvh, vh):
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, n, 128, 32)), jnp.float32)
+               for n in (h, kvh, vh))
+    with pytest.raises(ValueError, match="groups"):
+        flash_attention(q, k, v, interpret=True)
+
+
+@pytest.mark.parametrize("head_axis,kvh,in_place", [
+    ("x", 4, True), ("x", 2, True), ("x", 1, False), (None, 1, True)])
+def test_under_a_mesh_the_head_axis_shards_the_key_value_heads_too(
+        head_axis, kvh, in_place):
+    """Two devices on the head axis: 8 query heads on 4 or 2 k/v heads
+    are sharded group by group and read in place on each device; 1 k/v
+    head cannot be, and the layer repeats it as before (the op asks
+    ``_kernel_shard_spec`` with the k/v heads and compares)."""
+    import types
+    from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp
+    mesh = Mesh(np.array(jax.devices()[:2]), ("x",))
+    ctx = types.SimpleNamespace(mesh=mesh, op_sharding=types.SimpleNamespace(
+        outputs=[P(None)], weights={"wq": P(None, head_axis)}))
+    _, spec = MultiHeadAttentionOp._kernel_shard_spec(ctx, 2, 8)
+    assert spec == P(None, head_axis)
+    assert (MultiHeadAttentionOp._kernel_shard_spec(ctx, 2, kvh)[1]
+            == spec) is in_place
+    if not in_place:
+        return
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.standard_normal((2, 8, 128, 32)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, kvh, 128, 32)), jnp.float32)
+            for _ in range(2))
+    f = lambda **kw: jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(   # noqa
+        flash_attention(*a, causal=True, interpret=True, **kw))),
+        (0, 1, 2)))(q, k, v)
+    for got, want in zip(f(mesh=mesh, spec=spec), f()):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)

@@ -464,6 +464,56 @@ def test_the_kernel_path_is_the_chunked_path(seq, topk, q_chunk):
         close(gw2[k], gw1[k], 1e-5)
 
 
+@pytest.mark.parametrize("heads_first", [False, True])
+@pytest.mark.parametrize("kv", [1, 2])
+def test_the_kernel_path_reads_grouped_keys_and_values_in_place(kv,
+                                                                heads_first):
+    """``sparse_index_attention_flash`` with k and v at ``kv`` heads is
+    the call on heads repeated to the 4 query heads (what the layer
+    handed it before PR 52): output, ``L_I``, the counts and every
+    gradient, k's and v's summed over their group; ``heads_first`` as
+    ``kernels/qk_norm_rope`` hands q and k."""
+    rng = np.random.default_rng(52)
+    seq, topk, q_chunk = 40, 12, 16
+    q, k, v = (jnp.asarray(rng.normal(size=(B, seq, n, D)), jnp.float32)
+               for n in (HEADS, kv, kv))
+    qi = jnp.asarray(rng.normal(size=(B, seq, J, C)), jnp.float32)
+    ki = jnp.asarray(rng.normal(size=(B, seq, C)), jnp.float32)
+    wi = jnp.asarray(rng.normal(size=(B, seq, J)), jnp.float32)
+
+    def run(repeat):
+        def f(q, k, v, qi, ki, wi):
+            if repeat:
+                k, v = (jnp.repeat(x, HEADS // kv, axis=2) for x in (k, v))
+            if heads_first:
+                q, k = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
+            o, kl, kept, ties = dsa.sparse_index_attention_flash(
+                q, k, v, qi, ki, wi, topk, q_chunk, jnp.float32,
+                qk_heads_first=heads_first)
+            return jnp.sum(jnp.sin(o)) + kl, (o, kl, kept, ties)
+        return jitted(jax.value_and_grad(f, (0, 1, 2, 3, 4, 5),
+                                         has_aux=True))(q, k, v, qi, ki, wi)
+
+    (_, got), g_got = run(False)
+    (_, want), g_want = run(True)
+    for a, b in zip(got, want):
+        close(a, b, 1e-6)
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape
+        close(a, b, 1e-5)
+
+
+@pytest.mark.parametrize("impl,counted", [("flash", 1.0), ("xla", None)])
+def test_a_layer_whose_kernels_read_grouped_heads_says_so(impl, counted):
+    """``attn.grouped_kv_layers``: 1 where the flash kernels got fewer
+    k/v heads than query heads, absent on the chunked path (which
+    contracts on repeated heads)."""
+    x, pos = attn_inputs(48)
+    _, _, counters = attn_op(x, pos, attn_weights(), 12, 16, True, impl)
+    got = counters.get("attn.grouped_kv_layers")
+    assert (got if got is None else float(got)) == counted
+
+
 def test_the_kernel_paths_losses_reach_disjoint_weights_exactly():
     """As on the chunked path: ``L_I`` moves the indexer alone and the
     output everything but the indexer, zeros to the last bit."""

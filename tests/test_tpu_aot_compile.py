@@ -804,18 +804,31 @@ LAYER_SHAPES = {
     "cell7_keye_without_indexer": (1, 8192, 2048, 32, 4, 128, {
         "bias": False, "rope": True, "rope_theta": 1e7, "qk_norm": True,
         "qk_norm_eps": 1e-6}),
+    "cell7_keye": (1, 8192, 2048, 32, 4, 128, {
+        "bias": False, "rope": True, "rope_theta": 1e7, "qk_norm": True,
+        "qk_norm_eps": 1e-6, "indexer_heads": 16, "indexer_head_dim": 64,
+        "indexer_topk": 2048, "indexer_q_chunk": 512}),
+    "cell8_trinity_window": (1, 8192, 2048, 32, 4, 128, {
+        "bias": False, "rope": True, "rope_theta": 1e4, "qk_norm": True,
+        "qk_norm_eps": 1e-5, "output_gate": True, "sliding_window": 2048}),
+    "cell8_trinity_full": (1, 8192, 2048, 32, 4, 128, {
+        "bias": False, "qk_norm": True, "qk_norm_eps": 1e-5,
+        "output_gate": True}),
 }
 
 # sha256 of the layer's lowered text (locations stripped) taken from the
 # parent of PR 50 (``git archive ad1f078``) by these same lines: where
 # ``_takes_norm_rope_kernel`` says no, the layer lowers to what it was,
 # equation for equation. A PR that means to change those layers'
-# emission replaces the hashes.
+# emission replaces the hashes. Cell 4's is PR 52's: since then the flash
+# kernels read its 8 key/value heads in place and the layer repeats
+# nothing, so it no longer matches PR 51's commit ``f028476`` (whose
+# text hashed b202c6f9...10f7, as ``ad1f078``'s did).
 LAYER_SHA256 = {
     "cell2_gpt2_124m":
         "28bb6501df1dd541cd0152f0d29fac5bc6b5de4ff9505c5b1e3ebe019ff4f550",
     "cell4_lfm2":
-        "b202c6f970e7b5ef7ef9a576a32e18fb4a08e00539351de6b9e82f49a31610f7",
+        "788c1a9db0ae3fb44e640ed56a913f567ea7f4bb4943d50d96c5322a4fb3ab73",
 }
 
 
@@ -857,11 +870,14 @@ def _lowered_layer(device, monkeypatch, cell):
 def test_where_the_predicate_says_no_the_layer_lowers_to_the_parents_text(
         v5e_devices, monkeypatch, cell):
     """Cell 2 (no rotary embedding, no q/k norm) and cell 4 (both, on
-    heads of 64): the three flash calls and nothing of this PR's."""
+    heads of 64): the three flash calls and nothing of PR 50's. Cell 2's
+    equal head counts count no grouped layer either."""
     import hashlib
     lowered, counted = _lowered_layer(v5e_devices[0], monkeypatch, cell)
     assert lowered.count("tpu_custom_call") == 3
-    assert "qk_norm_rope" not in lowered and not counted
+    assert "qk_norm_rope" not in lowered
+    assert set(counted) == ({"attn.grouped_kv_layers"}
+                            if cell == "cell4_lfm2" else set())
     text = _without_debug_info(lowered)
     assert hashlib.sha256(text.encode()).hexdigest() == LAYER_SHA256[cell]
 
@@ -878,7 +894,8 @@ def test_where_it_says_yes_the_layer_calls_the_kernels(v5e_devices,
     assert lowered.count("tpu_custom_call") == 7
     assert lowered.count("qk_norm_rope_fwd") >= 2 \
         and lowered.count("qk_norm_rope_bwd") >= 2
-    assert set(counted) == {"attn.norm_rope_kernel_layers"}
+    assert set(counted) == {"attn.norm_rope_kernel_layers",
+                            "attn.grouped_kv_layers"}
 
 
 # ----------------------------------------------------------------------
@@ -916,3 +933,88 @@ def test_the_windowed_kernels_compile_at_cell_8s_shapes(v5e_devices,
 
     assert operands(banded) == operands(causal)
     assert "s8[" not in banded
+
+
+# ----------------------------------------------------------------------
+# grouped-query attention's key/value heads read in place (PR 52)
+# ----------------------------------------------------------------------
+# one sequence of 8,192, 32 query heads, bf16, causal: (key/value heads,
+# head size, window, masked)
+GROUPED_CELLS = {
+    "cell4_lfm2": (8, 64, 0, False),
+    "cell7_keye_masked": (4, 128, 0, True),
+    "cell8_trinity_window": (4, 128, 2048, False),
+    "cell8_trinity_full": (4, 128, 0, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
+def test_the_grouped_kernels_compile_at_the_cells_shapes(v5e_devices,
+                                                         chip_locations,
+                                                         cell):
+    """The three calls with k and v at the model's own head count: every
+    call reads them as ``bf16[kvh,8192,d]``, ``bwd_dkv`` writes ``dk``
+    and ``dv`` in that shape, and nothing of 32 heads stands for them
+    (q, ``do``, the output and ``dq`` are all there is at 32)."""
+    import importlib
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    kvh, d, window, masked = GROUPED_CELLS[cell]
+    b, h, s = 1, 32, 8192
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, kvh, s, d), jnp.bfloat16, sharding=one)
+    mask = [jax.ShapeDtypeStruct((b, s, s), jnp.int8, sharding=one)] \
+        if masked else []
+
+    def loss(q, k, v, *m):
+        o = flash_attention(q, k, v, causal=True, interpret=False,
+                            window=window, mask=m[0] if m else None)
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, *mask)
+    assert _kernel_names(txt) == FLASH_NAMES
+    calls = {re.sub(r"^(transpose_)?(jvp_)?|_+$", "", l.split(" = ")[0]
+                    .split("%")[-1].rsplit(".", 1)[0]): l
+             for l in txt.splitlines() if MOSAIC_CALL in l}
+    narrow, wide = f"bf16[{b * kvh},{s},{d}]", f"bf16[{b * h},{s},{d}]"
+    for name, line in calls.items():
+        result, operands = line.split(" custom-call(")
+        assert operands.count(narrow) == 2, name            # k and v
+        assert operands.count(wide) == (1 if name.endswith("fwd") else 2)
+    dkv = calls["flash_attention_bwd_dkv"].split(" custom-call(")[0]
+    assert dkv.count(narrow) == 2 and wide not in dkv       # dk and dv
+    if masked:
+        lse = jax.ShapeDtypeStruct((b, h, s), jnp.float32, sharding=one)
+        txt = _compile_text(
+            lambda q, k, l, m: fa.flash_attention_head_mean(
+                q, k, l, m, causal=True, interpret=False), q, kv, lse, *mask)
+        (line,) = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+        assert _kernel_names(txt) == ["flash_attention_head_mean"]
+        assert line.split(" custom-call(")[1].count(narrow) == 1
+
+
+@pytest.mark.parametrize("cell,calls,counted", [
+    ("cell2_gpt2_124m", 3, set()),
+    ("cell4_lfm2", 3, {"attn.grouped_kv_layers"}),
+    ("cell7_keye", 9, {"attn.grouped_kv_layers",
+                       "attn.norm_rope_kernel_layers"}),
+    ("cell8_trinity_window", 7, {"attn.grouped_kv_layers",
+                                 "attn.norm_rope_kernel_layers"}),
+    ("cell8_trinity_full", 3, {"attn.grouped_kv_layers"})])
+def test_a_layer_whose_kernels_read_grouped_heads_counts_itself(
+        v5e_devices, monkeypatch, cell, calls, counted):
+    """``attn.grouped_kv_layers`` is counted by a layer of cells 4, 7
+    and 8 (the indexer's path and the norm-and-rotary kernel's
+    included) and not by cell 2's equal head counts, and nothing is
+    repeated to 32 heads on the way, in float32 or heads-first."""
+    monkeypatch.setattr("flexflow_tpu.kernels.qk_norm_rope."
+                        "pallas_interpret", lambda: False)
+    lowered, got = _lowered_layer(v5e_devices[0], monkeypatch, cell)
+    assert lowered.count("tpu_custom_call") == calls
+    assert {k for k in got if not k.startswith(
+        ("dsa.", "attn.window_pairs", "attn.causal_pairs", "attn.gate_"))} \
+        == counted
+    # ``jnp.repeat`` on the heads' axis goes through (.., kvh, group, ..)
+    b, s, _, h, kv, d, _ = LAYER_SHAPES[cell]
+    assert f"tensor<{b}x{s}x{kv}x{h // kv}x{d}x" not in lowered
+    assert f"tensor<{b}x{kv}x{h // kv}x{s}x{d}x" not in lowered

@@ -455,6 +455,26 @@ def _lm_leg_setup(builder, model_cfg, seq: int, per_chip_batch: int,
     return ff, x, y
 
 
+def _check_wraps(label: str, reps: int) -> None:
+    """Every wrap of the step by its key, the last trace's (the
+    ``remat.wrap`` instants of ``ops/registry.py::checkpointed``): one of
+    site ``block`` a rematerialised block, and what the blocks hold for
+    their backward."""
+    from flexflow_tpu.obs import events
+    wraps = {(a["site"], a.get("layer"), a.get("block"), a.get("part")): a
+             for a in (e["attrs"] for e in events.events()
+                       if e["name"] == "remat.wrap")}
+    blocks = [a for a in wraps.values() if a["site"] == "block"]
+    say(f"{label}: {len(wraps)} remat.wrap instants a trace at sites "
+        f"{sorted({a['site'] for a in wraps.values()})}; the {len(blocks)} "
+        f"blocks hold "
+        f"{sum(a['entry_bytes'] + a['kept_bytes'] for a in blocks) / 1e6:.1f}"
+        f" MB (entries + kept) for their backward")
+    check(len(blocks) == reps,
+          f"{label}: {len(blocks)} remat.wrap instants of site 'block' "
+          f"for {reps} rematerialised blocks")
+
+
 def _check_kept_outputs(label: str, ff) -> None:
     """What the rematerialised run keeps beside its blocks' entries (the
     ``remat.kept`` instants: one a marked layer a block in each trace of
@@ -469,6 +489,7 @@ def _check_kept_outputs(label: str, ff) -> None:
             for e in events.events() if e["name"] == "remat.kept"}
     say(f"{label}: rematerialised run {(start, unit, reps)} keeps "
         f"{len(kept)} outputs, {sum(kept.values()) / 1e6:.1f} MB")
+    _check_wraps(label, reps)
     check(sorted(layer for _, layer in kept) == want,
           f"{label}: the rematerialised blocks keep {sorted(kept)} where "
           f"the layers that rematerialise themselves whole are {want}")
@@ -891,6 +912,7 @@ def leg_sparse_index_moe(model_cfg, seq: int, per_chip_batch: int,
     say(f"{label}: attn.sparse_index {seen.get('attn_0')} in "
         f"{sorted(seen)}; resolved {sorted(set(impls.values()))}; the "
         f"rematerialised run {ff.executor._remat[:3]} keeps {kept}")
+    _check_wraps(label, ff.executor._remat[2])
     check(sorted(seen) == layers and set(impls.values()) == {want_impl}
           and {a["impl"] for a in seen.values()} == {want_impl}
           and kept == layers,

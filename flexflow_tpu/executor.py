@@ -27,7 +27,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 
 from .ffconst import (CompMode, DataType, LossType, MetricsType, OperatorType)
 from .core.layer import Layer
@@ -35,7 +34,7 @@ from .core.tensor import Tensor
 from .dtypes import to_jnp
 from .obs import events as obs_events
 from .ops import EmitCtx, ensure_weight_specs, get_op_def
-from .ops.registry import KEPT_BY_BLOCK
+from .ops.registry import KEPT_BY_BLOCK, checkpointed, kept_by_block
 from .parallel import reshard as reshard_mod
 from .parallel.machine import DeviceMesh
 from .parallel.strategy import ShardingStrategy
@@ -135,6 +134,17 @@ def _emit_scoped(op, layer: Layer, ins, w, ctx):
 KEEP_MARKED = jax.checkpoint_policies.save_only_these_names(KEPT_BY_BLOCK)
 
 
+def device_bytes(tree) -> int:
+    """Bytes of the placed arrays of ``tree`` on the device that holds
+    the most of them (its shards; on one device, everything)."""
+    held: Dict[Any, int] = {}
+    for a in jax.tree.leaves(tree):
+        for shard in getattr(a, "addressable_shards", ()):
+            held[shard.device] = held.get(shard.device, 0) \
+                + int(shard.data.nbytes)
+    return max(held.values(), default=0)
+
+
 def _needs_rng(layer: Layer) -> bool:
     if layer.op_type == OperatorType.OP_DROPOUT:
         return True
@@ -214,6 +224,8 @@ class GraphProgram:
             w = params.get(layer.name, {})
             ctx.op_sharding = strategy.ops.get(layer.name) \
                 if strategy is not None else None
+            ctx.input_specs = [strategy.tensor_spec(t) for t in layer.inputs] \
+                if strategy is not None else None
             outs = _emit_scoped(op, layer, ins, w, ctx)
             if len(outs) != len(layer.outputs):
                 raise RuntimeError(
@@ -244,7 +256,9 @@ class GraphProgram:
                 if op.keeps_for_block(layer.params):
                     # the identity but under a rematerialised block's
                     # policy (_emit_remat), which keeps what is so named
-                    o = checkpoint_name(o, KEPT_BY_BLOCK)
+                    o = kept_by_block(
+                        o, strategy.tensor_spec(t)
+                        if strategy is not None else None, ctx.mesh)
                 env[t.guid] = o
                 if capture is not None:
                     # capture keeps the pre-bf16-cast (but still
@@ -600,6 +614,8 @@ class Executor:
             # (parallel/reshard.place_host)
             params = jax.tree.map(reshard_mod.place_host, params, psh)
             state = jax.tree.map(reshard_mod.place_host, state, ssh)
+            if obs_events.enabled():
+                sp.set(device_bytes=device_bytes((params, state)))
         return params, state
 
     def _build_params_and_state(self, seed, psh, ssh):
@@ -1170,6 +1186,7 @@ class Executor:
         inputs_env = {t.guid: env[t.guid]
                       for l in layers[start:start + reps * unit]
                       for t in l.inputs if t.owner_layer is None}
+        tensors = {t.guid: t for l in layers for t in l.inputs}
         for b in range(reps):
             block = layers[start + b * unit:start + (b + 1) * unit]
             entry_g, exit_g = entries[b], exits[b]
@@ -1204,8 +1221,14 @@ class Executor:
             # no policy where there is nothing to keep: JAX keys its
             # partial evaluation on the policy, and such a block's step
             # stays the text it was under a plain jax.checkpoint
-            x, counted, aux = jax.checkpoint(
-                block_fn, policy=KEEP_MARKED if kept else None)(x, bp)
+            x, counted, aux = checkpointed(
+                block_fn, site="block", block=b,
+                policy=KEEP_MARKED if kept else None, weights=(1,),
+                specs=None if st is None else (
+                    st.tensor_spec(tensors[entry_g]),
+                    {l.name: st.ops[l.name].weights for l in block
+                     if l.name in st.ops}),
+                mesh=self.dmesh.mesh, layers=[l.name for l in block])(x, bp)
             for key, v in counted.items():
                 ctx.count(key, v)
             ctx.aux_losses.extend(aux)

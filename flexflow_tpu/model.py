@@ -31,7 +31,7 @@ from .config import FFConfig, FFIterationConfig
 from .core.layer import Layer
 from .core.tensor import Tensor, WeightSpec
 from .dtypes import from_numpy_dtype, to_jnp
-from .executor import Executor, GraphProgram
+from .executor import Executor, GraphProgram, device_bytes
 from .ffconst import (ActiMode, AggrMode, CompMode, DataType, InitializerType,
                       LossType, MetricsType, OperatorType, ParameterSyncType,
                       PoolType)
@@ -1030,8 +1030,16 @@ class FFModel:
         # dominate, which would misattribute wall time to the search
         with self._compile_phase("init", 3):
             self.params, self.state = self.executor.init_params_and_state()
-        with obs_events.span("compile.opt_state"):
+        with obs_events.span("compile.opt_state") as sp:
             self.opt_state = self.optimizer.init_state(self.params)
+            self._place_opt_state()
+            if obs_events.enabled():
+                sp.set(device_bytes=device_bytes(self.opt_state))
+        self._step = 0
+
+    def _place_opt_state(self):
+        """The fresh optimizer state onto the plan's placement: ZeRO's
+        sharded moments, the quantized sync's residuals."""
         if self.config.shard_optimizer_states and self.opt_state:
             # ZeRO-1: moments sharded over the axes their weight is
             # replicated on (runtime/zero.py); the executor pins the
@@ -1065,7 +1073,6 @@ class FFModel:
                 self.executor._qsync, self.executor.program, self.dmesh)
             if res:
                 self.opt_state[qsync_mod.RESIDUAL_SLOT] = res
-        self._step = 0
 
     def _optimize_strategy(self):
         """Strategy selection: search unless --only-data-parallel.

@@ -78,7 +78,7 @@ from ..core.tensor import WeightSpec
 from ..ffconst import DataType, InitializerType, OperatorType
 from ..kernels import hyper_connection as hck
 from ..obs import events
-from .registry import OpDef, register
+from .registry import OpDef, checkpointed, register, wrap_specs
 
 F32 = jnp.float32
 #: the draw of a sub-layer's maps, a trained model's being in no config
@@ -269,6 +269,7 @@ class HyperConnectionOp(OpDef):
         # kernels, 25 floats a token), and computes the gates and the
         # iterations (the plain path: the norm and the product too) again
         xf = x.astype(F32)
+        (x_spec, w_specs), wrap_mesh = wrap_specs(ctx)
         if kernel:
             with jax.named_scope("mhc.mix"):
                 u, stats, xf = hck.read_streams(
@@ -277,22 +278,24 @@ class HyperConnectionOp(OpDef):
                         weights["b_pre"]), params["norm_eps"], layer=name,
                     mesh=mesh, spec=spec)
 
-            @jax.checkpoint
             def maps_of(stats, w):
                 k = n * (n + 2)
                 t = jnp.moveaxis(stats[..., :k] * stats[..., k:k + 1], -1, 0)
                 return finish(*write_maps(t, w, params))
 
-            maps, err, clamped = maps_of(stats, weights)
+            maps, err, clamped = checkpointed(
+                maps_of, site="mhc.maps", layer=name, weights=(1,),
+                specs=(spec, w_specs), mesh=mesh)(stats, weights)
         else:
-            @jax.checkpoint
             def plain(x, w):
                 hpre, *rest = stream_maps(x, w, params)
                 with jax.named_scope("mhc.mix"):
                     u = read_streams(x, jnp.moveaxis(hpre, 0, -1))
                 return (u,) + finish(*rest)
 
-            u, maps, err, clamped = plain(xf, weights)
+            u, maps, err, clamped = checkpointed(
+                plain, site="mhc.plain", layer=name, weights=(1,),
+                specs=(x_spec, w_specs), mesh=wrap_mesh)(xf, weights)
         ctx.count("mhc.sublayers", jnp.float32(1.0))
         ctx.count("mhc.sum_err", err)
         ctx.count("mhc.clamped", clamped)

@@ -14,15 +14,14 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 
 from ..ffconst import (ActiMode, AggrMode, DataType, InitializerType,
                        OperatorType, PoolType)
 from ..core.tensor import WeightSpec
 from ..dtypes import to_jnp
 from ..obs import events
-from .registry import (KEPT_BY_BLOCK, EmitCtx, OpDef, bf16_enabled,
-                       compute_dtype, matmul, register)
+from .registry import (EmitCtx, OpDef, bf16_enabled, compute_dtype,
+                       kept_by_block, matmul, register)
 
 
 def apply_activation(x, acti: ActiMode):
@@ -951,7 +950,7 @@ class MultiHeadAttentionOp(OpDef):
                 qk_heads_first=fused)
         else:
             o, loss, kept, ties = dsa.sparse_index_attention(
-                qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt)
+                qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt, layer=name)
         # either path rematerialises itself: a rematerialised block
         # around the layer keeps the attention's output (the output
         # projection's backward reads it; the kernel path marks its own,
@@ -959,7 +958,7 @@ class MultiHeadAttentionOp(OpDef):
         # or the forward kernel again (``keeps_for_block``); outside
         # such a block the identity
         if not kernels:
-            o = checkpoint_name(o.astype(mdt), KEPT_BY_BLOCK)
+            o = kept_by_block(o.astype(mdt))
         ctx.aux_losses.append(loss)
         for key, v in (("dsa.kept_pairs", kept),
                        ("dsa.causal_pairs", qh.shape[0] * s * (s + 1) / 2),

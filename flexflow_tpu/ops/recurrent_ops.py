@@ -48,7 +48,8 @@ from ..ffconst import InitializerType, OperatorType
 from ..kernels.gated_delta_rule import SUB, chunk_terms, takes_kernel
 from ..obs import events
 from .nn_ops import MultiHeadAttentionOp, _rms, short_conv
-from .registry import OpDef, compute_dtype, register
+from .registry import (OpDef, checkpointed, compute_dtype, register,
+                       wrap_specs)
 
 CHUNK = 64          # tokens a step of the scan
 NORM_EPS = 1e-6     # under the root of q's and k's lengths
@@ -171,13 +172,17 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
                                     layer=layer, mesh=mesh, spec=spec)
         least = jnp.min(least)
     else:
-        *terms, least = jax.checkpoint(lambda *a: _chunk_terms(*a, mdt))(
+        *terms, least = checkpointed(
+            lambda *a: _chunk_terms(*a, mdt), site="kda.terms", layer=layer,
+            specs=(spec,) * 5, mesh=mesh)(
             *(_in_chunks(x, chunk) for x in (q, k, v, g, beta)))
         terms = [jnp.moveaxis(x, 2, 0) for x in terms]
     state = jnp.zeros(k.shape[:2] + (k.shape[-1], v.shape[-1]),
                       jnp.float32)
     _, out = jax.lax.scan(                      # over the chunks: N leads
-        jax.checkpoint(lambda s, xs: _chunk_step(mdt, s, xs)), state, terms)
+        checkpointed(lambda s, xs: _chunk_step(mdt, s, xs), site="kda.step",
+                     layer=layer, specs=(spec, spec), mesh=mesh),
+        state, terms)
     out = jnp.moveaxis(out, 0, 2)                  # (B, H, N, C, dv)
     out = out.reshape(out.shape[:2] + (-1,) + out.shape[4:])[:, :, :t]
     return out, jax.lax.stop_gradient(least)
@@ -247,10 +252,12 @@ class GatedDeltaRuleOp(OpDef):
             WeightSpec("wo", (h, d, e), dt, init_args=fans(h * d, e))]
 
     @staticmethod
-    def projections(x, weights, mdt):
+    def projections(x, weights, mdt, *, layer=None, specs=None, mesh=None):
         """``q, k, v, g, beta`` as the recurrence takes them and the
         output gate, all float32 and heads leading: (B, H, T, d) but
-        ``beta`` (B, H, T)."""
+        ``beta`` (B, H, T). ``layer`` names the caller in its branches'
+        ``remat.wrap`` instants, which count one device's bytes by
+        ``specs`` (``x``'s, the weights' by name) over ``mesh``."""
         f32 = jnp.float32
 
         def mm(pattern, a, w):
@@ -272,8 +279,14 @@ class GatedDeltaRuleOp(OpDef):
 
         # each branch rematerialised by itself: the backward pass then
         # holds one branch's intermediate arrays at a time
+        x_spec, w_specs = specs or (None, {})
+
         def branch(fn, *names, **static):
-            return jax.checkpoint(lambda x, *w: fn(x, *w, **static))(
+            return checkpointed(
+                lambda x, *w: fn(x, *w, **static), site="kda.branch",
+                layer=layer, part=names[0],
+                weights=range(1, len(names) + 1), mesh=mesh,
+                specs=(x_spec,) + tuple(w_specs.get(n) for n in names))(
                 x, *(weights[n] for n in names))
 
         d = weights["wq"].shape[-1]
@@ -321,9 +334,11 @@ class GatedDeltaRuleOp(OpDef):
         # the output instead (one (tokens, hidden) array, 37.7 MB at
         # 4096 x 2304), so the counts are 2 and 3 inside a block as
         # outside one.
-        @jax.checkpoint
+        specs, wrap_mesh = wrap_specs(ctx)
+
         def layer(x, weights):
-            q, k, v, g, beta, gate = self.projections(x, weights, mdt)
+            q, k, v, g, beta, gate = self.projections(
+                x, weights, mdt, layer=name, specs=specs, mesh=wrap_mesh)
             with jax.named_scope("kda.scan"):
                 o, least = gated_delta_rule(q, k, v, g, beta, chunk, mdt,
                                             layer=name, mesh=mesh,
@@ -333,7 +348,9 @@ class GatedDeltaRuleOp(OpDef):
                               weights["wo"].astype(mdt),
                               preferred_element_type=jnp.float32), least
 
-        out, least = layer(x, weights)
+        out, least = checkpointed(
+            layer, site="kda.layer", layer=name, weights=(1,), specs=specs,
+            mesh=wrap_mesh)(x, weights)
         # counters add over layers and steps: the sum of each scan's
         # most negative running log-decay, beside the number of scans
         ctx.count("kda.log_decay_min", least)

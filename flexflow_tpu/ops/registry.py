@@ -14,6 +14,7 @@ each operator type registers an ``OpDef`` implementing
 """
 from __future__ import annotations
 
+import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -73,6 +74,9 @@ class EmitCtx:
         # run under shard_map with these specs, because GSPMD cannot
         # partition a Mosaic call
         self.op_sharding = None
+        # the adopted specs of that op's inputs, in their order (None:
+        # no strategy, or an input the plan leaves open)
+        self.input_specs: Optional[List[Any]] = None
         # layer name -> emitted attention impl, shared with the executor
         self.resolved_impls: Optional[Dict[str, str]] = None
 
@@ -89,6 +93,118 @@ class EmitCtx:
 # ``OpDef.keeps_for_block``, and which a rematerialised block that holds
 # such a layer keeps beside its entry (``executor.py::KEEP_MARKED``).
 KEPT_BY_BLOCK = "ff.kept_by_block"
+
+# The wraps open in this thread's trace, innermost last, while the
+# recorder is on (``checkpointed``): what ``kept_by_block`` adds to.
+_WRAPS = threading.local()
+
+
+def _shard_bytes(x, spec=None, mesh=None) -> int:
+    """Bytes of the arrays of ``x`` (an array, or a dict, list or tuple
+    of them) that ONE device holds under ``spec``: a ``PartitionSpec``
+    over ``mesh`` for every array, or a dict of them by ``x``'s keys.
+    No spec or no mesh: the whole arrays."""
+    if isinstance(x, dict):
+        by_key = spec if isinstance(spec, dict) else None
+        return sum(_shard_bytes(v, by_key.get(k) if by_key is not None
+                                else spec, mesh) for k, v in x.items())
+    if isinstance(x, (list, tuple)):
+        return sum(_shard_bytes(v, spec, mesh) for v in x)
+    if not hasattr(x, "dtype") or not hasattr(x, "shape"):
+        return 0
+    n = int(np.prod(x.shape)) * x.dtype.itemsize
+    if mesh is None or spec is None or isinstance(spec, dict):
+        return n
+    for entry in spec:
+        for axis in (entry if isinstance(entry, tuple) else (entry,)):
+            if axis is not None:
+                n = -(-n // mesh.shape[axis])
+    return n
+
+
+def wrap_specs(ctx):
+    """``(specs, mesh)`` for an op's own wrap of ``(x, weights)``: the
+    adopted spec of its first input, its weights' specs by name, and
+    the mesh they are over (``EmitCtx``; anything else: whole arrays)."""
+    own = getattr(getattr(ctx, "op_sharding", None), "weights", None)
+    return (((getattr(ctx, "input_specs", None) or [None])[0], own or {}),
+            getattr(ctx, "mesh", None))
+
+
+def kept_by_block(x, spec=None, mesh=None):
+    """``x`` under the name ``KEPT_BY_BLOCK``: the identity, but a
+    rematerialised block whose policy is ``executor.py::KEEP_MARKED``
+    keeps it for its backward. Recorder on, its bytes (one device's,
+    under ``spec`` over ``mesh``) are added to the innermost open wrap's
+    ``kept_bytes`` (``checkpointed``)."""
+    from jax.ad_checkpoint import checkpoint_name
+    open_ = getattr(_WRAPS, "open", None)
+    if open_:
+        open_[-1]["kept"] += _shard_bytes(x, spec, mesh)
+    return checkpoint_name(x, KEPT_BY_BLOCK)
+
+
+def checkpointed(fn, *, site: str, policy=None, layer=None, block=None,
+                 part=None, layers=None, weights: Sequence[int] = (),
+                 specs=None, mesh=None):
+    """``jax.checkpoint(fn, policy=policy)``: the ONE place where the
+    package rematerialises. ``site`` names who asks (``block``,
+    ``kda.layer``, ``kda.branch``, ``kda.terms``, ``kda.step``,
+    ``mhc.maps``, ``mhc.plain``, ``dsa.chunk``); what is wrapped, and
+    what is kept, is the caller's decision and stays with it.
+
+    On the device: the call runs under ``jax.named_scope("remat.<site>")``,
+    so every op inside carries its owner in ``op_name`` beside JAX's own
+    ``checkpoint`` / ``rematted_computation`` parts. Metadata only.
+
+    On the recorder (``obs/events.py``; off: one flag read a call): one
+    ``remat.wrap`` instant each time the wrap is traced, with ``site``,
+    ``layer`` or ``block`` (a wrap inside a block carries both; a block
+    also its ``layers``' names), ``part`` (what tells apart the wraps one
+    layer makes at one site: a branch's first weight, a chunk's first
+    row), ``depth`` (the wraps that enclose it in this trace),
+    ``policy`` (``keep_marked`` | ``none``) and what it holds from the
+    forward to the backward: ``entry_bytes`` (its array arguments but
+    those at the positions ``weights``),
+    ``weights_bytes``, ``kept_bytes`` (what ``kept_by_block`` marked
+    inside it, 0 under no policy). One device's bytes: ``specs`` gives a
+    ``PartitionSpec`` over ``mesh`` (or a dict of them) for each
+    argument, None for a whole array."""
+    import jax
+    from ..obs import events
+
+    def call(*args):
+        recording = events.enabled()
+        if recording:
+            if not hasattr(_WRAPS, "open"):
+                _WRAPS.open = []
+            open_ = _WRAPS.open
+            frame = {"kept": 0, "block": block if block is not None
+                     or not open_ else open_[-1]["block"]}
+            open_.append(frame)
+        try:
+            with jax.named_scope("remat." + site):
+                out = jax.checkpoint(fn, policy=policy)(*args)
+        finally:
+            if recording:
+                open_.pop()
+        if not recording:
+            return out
+        held = [_shard_bytes(a, specs[i] if specs else None, mesh)
+                for i, a in enumerate(args)]
+        of_weights = sum(held[i] for i in weights)
+        where = {k: v for k, v in (("layer", layer),
+                                   ("block", frame["block"]),
+                                   ("part", part), ("layers", layers))
+                 if v is not None}
+        events.instant(
+            "remat.wrap", site=site, **where, depth=len(open_),
+            policy="none" if policy is None else "keep_marked",
+            entry_bytes=sum(held) - of_weights, weights_bytes=of_weights,
+            kept_bytes=frame["kept"] if policy is not None else 0)
+        return out
+
+    return call
 
 
 class OpDef:

@@ -46,12 +46,11 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 
 from ..kernels.flash_attention import (flash_attention_forward,
                                        flash_attention_from_forward,
                                        flash_attention_head_mean)
-from .registry import KEPT_BY_BLOCK
+from .registry import checkpointed, kept_by_block
 
 MASKED = -1e9             # what ``MultiHeadAttentionOp``'s plain path uses
 
@@ -193,7 +192,7 @@ def selection(qi, ki, wi, topk: int, q_chunk: int, mdt):
 
 
 def sparse_index_attention(q, k, v, qi, ki, wi, topk: int, q_chunk: int,
-                           mdt):
+                           mdt, *, layer=None):
     """``(o, loss, kept, ties)`` of the module's equations for whole
     sequences: ``q`` (b, s, h, d) and ``k``, ``v`` (b, s, kv, d) after
     norms and rotary embedding, the indexer's ``qi`` (b, s, j, c), ``ki``
@@ -217,8 +216,9 @@ def sparse_index_attention(q, k, v, qi, ki, wi, topk: int, q_chunk: int,
                 wi[:, lo:hi])
         if outs:
             outs[-1], args = jax.lax.optimization_barrier((outs[-1], args))
-        o, kl_c, kept_c, ties_c = jax.checkpoint(
-            lambda *a, _lo=lo: _chunk(_lo, topk, mdt, *a))(*args)
+        o, kl_c, kept_c, ties_c = checkpointed(
+            lambda *a, _lo=lo: _chunk(_lo, topk, mdt, *a), site="dsa.chunk",
+            layer=layer, part=lo)(*args)
         outs.append(o)
         kl, kept, ties = kl + kl_c, kept + kept_c, ties + ties_c
     o = jnp.concatenate(outs, 1).reshape(b, s, h, v.shape[-1])
@@ -329,7 +329,7 @@ def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
     b, s = q.shape[0], q.shape[2 if qk_heads_first else 1]
     scores, mask, kept, ties = _scores_and_mask(qi, ki, wi, topk, q_chunk,
                                                 mdt)
-    mask = checkpoint_name(mask, KEPT_BY_BLOCK)
+    mask = kept_by_block(mask)
     with jax.named_scope("dsa.attend"):
         def heads_first(x):      # (b, s, heads, d) -> (b, heads, s, d)
             return jnp.swapaxes(x, 1, 2).astype(mdt)
@@ -338,8 +338,8 @@ def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
         vh = heads_first(v)
         o, lse = flash_attention_forward(qh, kh, vh, mask,
                                                causal=True)
-        o = checkpoint_name(o, KEPT_BY_BLOCK)
-        lse = checkpoint_name(lse, KEPT_BY_BLOCK)
+        o = kept_by_block(o)
+        lse = kept_by_block(lse)
         o = flash_attention_from_forward(qh, kh, vh, mask, o, lse,
                                                causal=True)
         o = jnp.swapaxes(o, 1, 2)

@@ -121,6 +121,17 @@ class ShardingStrategy:
             return None
         return NamedSharding(self.dmesh.mesh, os.outputs[idx])
 
+    def tensor_spec(self, tensor) -> Optional[P]:
+        """The adopted spec of a tensor of the graph (its producer's
+        output, a graph input's own); None where the plan leaves it
+        open."""
+        if tensor.owner_layer is None:
+            return self.inputs.get(tensor.name)
+        os = self.ops.get(tensor.owner_layer.name)
+        if os is None or tensor.owner_idx >= len(os.outputs):
+            return None
+        return os.outputs[tensor.owner_idx]
+
     def weight_sharding(self, layer_name: str, wname: str) -> NamedSharding:
         os = self.ops.get(layer_name)
         spec = os.weights.get(wname, P()) if os else P()

@@ -676,8 +676,11 @@ def test_spans_and_counters_in_a_fit():
                 np.asarray(batch["label"]), shuffle=False)))
         ).as_text(debug_info=True)
         for scope in ("mhc.maps", "mhc.sinkhorn", "mhc.mix"):
+            # under the layer's name, the wrap's own scope (PR 53) and
+            # JAX's ``checkpoint``
             assert f"attn_res_1_pre/{scope}" in step or \
-                f"attn_res_1_pre/checkpoint/{scope}" in step, scope
+                f"attn_res_1_pre/remat.mhc.plain/checkpoint/{scope}" \
+                in step, scope
         assert "mlp_res_2/mhc.mix" in step
     finally:
         events.disable()

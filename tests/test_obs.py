@@ -871,9 +871,16 @@ def test_compile_phases_are_the_readings_of_the_compile_spans(
     assert init["ts"] <= draw["ts"] \
         and draw["ts"] + draw["dur"] <= init["ts"] + init["dur"]
     leaves = jax.tree.leaves(ff.params)
+    from flexflow_tpu.executor import device_bytes
     assert draw["attrs"] == {
         "parameters": sum(a.size for a in leaves),
-        "bytes": sum(a.nbytes for a in leaves)}
+        "bytes": sum(a.nbytes for a in leaves),
+        # what the fullest device holds once placed (PR 53)
+        "device_bytes": device_bytes((ff.params, ff.state))}
+    assert 0 < draw["attrs"]["device_bytes"] <= draw["attrs"]["bytes"] \
+        + sum(a.nbytes for a in jax.tree.leaves(ff.state))
+    assert phases[5]["attrs"] == {
+        "device_bytes": device_bytes(ff.opt_state)}
     # a second draw (a benchmark's, from its seed) is a second span
     ff.executor.init_params_and_state(jax.random.key(7))
     assert len(_of("executor.init_params")) == 2

@@ -60,7 +60,13 @@ failure):
      shape (1 x 32 heads on 4 x ``--seq`` x 128, bf16, causal, a mask of the
      2,048 largest of random scores a row), ms a call on the host's
      clock over 10 calls and the FLOP/s over all causal pairs that
-     makes, at the derived blocks and at explicit ones around them.
+     makes, at the derived blocks and at explicit ones around them;
+     then the two index-score kernels (``kernels/index_scores.py``, 16
+     heads of 64 on one key head, the whole sequence in one causal call
+     each) beside the plain products they stand for (a layer's chunks of
+     512 queries, each against the keys up to its end, in one jit), the
+     FLOP/s over the pairs either visits, and the kernels' results
+     against the plain ones.
 """
 import argparse
 import json
@@ -386,7 +392,7 @@ def time_kernels(seq, heads=32, kv_heads=4, d=128, topk=2048, calls=10):
     del scores, kth
     product = 2.0 * heads * d * seq * (seq + 1) / 2      # one q.k or p.v
 
-    def timed(name, products, fn, *args):
+    def timed(name, products, fn, *args, product=product):
         out = fn(*args)
         jax.block_until_ready(out)
         t0 = time.perf_counter()
@@ -428,6 +434,64 @@ def time_kernels(seq, heads=32, kv_heads=4, d=128, topk=2048, calls=10):
         q, k, lse, m, causal=True))
     timed(f"head_mean {fa.head_mean_tiles(seq, seq, d, jnp.bfloat16)}", 1,
           mean, q, k, lse, mask)
+    del q, k, v, do, mask, o, lse
+    time_index_kernels(seq, timed)
+
+
+def time_index_kernels(seq, timed, j=16, c=64, chunk=512):
+    """The loss's backward as the step runs it: the index scores again
+    and their pull-back, the whole sequence in one causal call of each
+    kernel; beside them what they stand for, ``jax.vjp`` of
+    ``index_scores`` a chunk of queries at a time against the keys up to
+    its end, every chunk of a layer in one jit. FLOP/s over the pairs
+    either visits (the tiles at or under the diagonal are the chunks'
+    pairs)."""
+    from flexflow_tpu.kernels import index_scores as isk
+    ks = jax.random.split(jax.random.key(54), 4)
+    qi = jax.random.normal(ks[0], (1, seq, j, c), jnp.float32)
+    ki = jax.random.normal(ks[1], (1, seq, c), jnp.float32)
+    wi = jax.random.normal(ks[2], (1, seq, j), jnp.float32)
+    d = jnp.tril(jax.random.normal(ks[3], (1, seq, seq), jnp.float32))
+    chunks = dsa._chunks(seq, chunk)
+    pairs = 2.0 * j * c * sum((hi - lo) * hi for lo, hi in chunks)
+    mdt = jnp.bfloat16
+
+    def plain(qi, ki, wi, d):
+        out = []
+        for lo, hi in chunks:
+            args = (qi[:, lo:hi], ki[:, :hi], wi[:, lo:hi], d[:, lo:hi, :hi])
+            if out:     # one chunk's 16 heads of scores at a time
+                out[-1], args = jax.lax.optimization_barrier((out[-1], args))
+            scores, pull = jax.vjp(
+                lambda *a: dsa.index_scores(*a, mdt), *args[:3])
+            out.append((scores,) + pull(args[3]))
+        scores, dqi, dki, dwi = zip(*out)
+        dk = jnp.zeros(ki.shape, jnp.float32)
+        for (lo, hi), part in zip(chunks, dki):
+            dk = dk.at[:, :hi].add(part)
+        return (jnp.concatenate([jnp.pad(x, ((0, 0), (0, 0),
+                                             (0, seq - x.shape[2])))
+                                 for x in scores], 1),
+                jnp.concatenate(dqi, 1), dk, jnp.concatenate(dwi, 1))
+
+    tiles = {k: isk.tiles(k, seq, seq, j, c, mdt) for k in ("fwd", "bwd")}
+    want = timed("index scores and their vjp, plain, a layer's chunks", 4,
+                 jax.jit(plain), qi, ki, wi, d, product=pairs)
+    scores = timed(f"index_scores_fwd {tiles['fwd']}", 1, jax.jit(
+        lambda qi, ki, wi: isk.index_scores_fwd(qi, ki, wi, mdt,
+                                                causal=True)),
+        qi, ki, wi, product=pairs)
+    pulled = timed(f"index_scores_bwd {tiles['bwd']}", 3, jax.jit(
+        lambda *a: isk.index_scores_bwd(*a, mdt, causal=True)),
+        qi, ki, wi, d, product=pairs)
+    # the forward writes whole tiles and none past the diagonal
+    worst = [l2(jnp.tril(scores), jnp.tril(want[0]))] + [
+        l2(got, ref) for got, ref in zip(pulled, want[1:])]
+    READINGS[f"index kernels against plain, seq {seq}"] = worst
+    check(f"seq {seq}: the index kernels are the plain products",
+          worst[0] <= 1e-5 and max(worst[1:]) <= 1e-2,
+          f"scores {worst[0]:.2e}, dqi {worst[1]:.2e}, dki {worst[2]:.2e}, "
+          f"dwi {worst[3]:.2e} (relative)")
 
 
 def load_checks(conf, seq, seeds):

@@ -28,6 +28,12 @@ with:
     fwd+bwd), taken by ``ops/nn_ops.py::MultiHeadAttentionOp`` where a
     head is whole lanes and the layer is on the flash path on one
     device; not a registry entry.
+  - ``index_scores``: the sparse-attention indexer's scores and their
+    pull-back inside its loss's backward (two kernels): a (query tile x
+    key tile)'s heads of scores stay in VMEM and only arrays without a
+    head axis over (queries, keys) are written, taken by
+    ``ops/sparse_attention.py`` where the heads are whole lanes and the
+    tiles divide the chunks; not a registry entry.
 
 Each op chooses its kernel from what it can observe (shapes, dropout,
 platform). ``registry`` holds the one override: attention's three names,

@@ -947,7 +947,7 @@ class MultiHeadAttentionOp(OpDef):
                 ctx.count("attn.grouped_kv_layers", jnp.float32(1.0))
             o, loss, kept, ties = dsa.sparse_index_attention_flash(
                 qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt,
-                qk_heads_first=fused)
+                qk_heads_first=fused, layer=name)
         else:
             o, loss, kept, ties = dsa.sparse_index_attention(
                 qh, kh, vh, qi, ki, wi, topk, q_chunk, mdt, layer=name)

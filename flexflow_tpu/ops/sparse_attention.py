@@ -36,7 +36,13 @@ the three flash kernels attend under it (``kernels/flash_attention``:
 scores and probabilities stay in VMEM), a fourth kernel writes ``p``,
 and ``L_I`` and its gradient into the indexer come from ``I``, the mask
 and ``p``: arrays over (queries, keys) that the heads share may live in
-HBM, arrays with a head axis over them are never written.
+HBM, arrays with a head axis over them are never written. In the loss's
+backward that holds for the indexer's own 16 heads too wherever the
+shapes take ``kernels/index_scores`` (heads in whole lanes, tiles that
+divide the sequence): two kernels make ``I`` again and pull its
+cotangent back with a tile's heads of scores in VMEM, the whole sequence
+in one causal call each; every other shape pulls it back through
+``jax.vjp`` of :func:`index_scores` a chunk at a time.
 """
 from __future__ import annotations
 
@@ -47,9 +53,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import index_scores as isk
 from ..kernels.flash_attention import (flash_attention_forward,
                                        flash_attention_from_forward,
                                        flash_attention_head_mean)
+from ..obs import events
 from .registry import checkpointed, kept_by_block
 
 MASKED = -1e9             # what ``MultiHeadAttentionOp``'s plain path uses
@@ -259,9 +267,11 @@ def _index_loss(qi, ki, wi, scores, mask, q, k, lse, q_chunk, mdt):
     run for it) and ``p``, the heads' mean probability, which the fourth
     flash kernel writes from ``q``, ``k`` (b, h, s, d), the forward's
     ``lse`` and the mask. Its backward keeps neither ``scores`` nor
-    ``p``: it runs that kernel again and, a chunk of queries at a time,
-    the index products once more and their transposes, as the XLA
-    path's chunks do under their ``jax.checkpoint``."""
+    ``p``: it runs that kernel again, then the index products once more
+    and their transposes: through ``kernels/index_scores`` over the
+    whole sequence where the shapes take them (:func:`_index_kernels`),
+    else a chunk of queries at a time, as the XLA path's chunks do
+    under their ``jax.checkpoint``."""
     with jax.named_scope("dsa.loss"):
         p = flash_attention_head_mean(q, k, lse, mask, causal=True)
         return _divergence(scores, mask != 0, p)
@@ -270,6 +280,14 @@ def _index_loss(qi, ki, wi, scores, mask, q, k, lse, q_chunk, mdt):
 def _index_loss_fwd(qi, ki, wi, scores, mask, q, k, lse, q_chunk, mdt):
     return (_index_loss(qi, ki, wi, scores, mask, q, k, lse, q_chunk, mdt),
             (qi, ki, wi, mask, q, k, lse))
+
+
+def _index_kernels(qi, mdt) -> bool:
+    """Whether the loss's backward makes the index scores and pulls
+    their cotangent back through ``kernels/index_scores``, the whole
+    sequence in one causal call each: told by the shapes."""
+    _, s, j, c = qi.shape
+    return isk.takes_kernel(s, s, j, c, mdt)
 
 
 def _index_loss_bwd(q_chunk, mdt, res, g):
@@ -282,6 +300,28 @@ def _index_loss_bwd(q_chunk, mdt, res, g):
     s = qi.shape[1]
     with jax.named_scope("dsa.loss"):
         p = flash_attention_head_mean(q, k, lse, mask, causal=True)
+    rest = (jnp.zeros(mask.shape, jnp.float32),
+            np.zeros(mask.shape, jax.dtypes.float0), jnp.zeros_like(q),
+            jnp.zeros_like(k), jnp.zeros_like(lse))
+    if _index_kernels(qi, mdt):
+        # the kernels keep a tile's heads of scores in VMEM, so the
+        # whole sequence's ``I``, (s, s) float32 like ``p``, is all that
+        # a layer holds, and each kernel is called once; the loss's
+        # passes between them still go chunk by chunk, over the keys up
+        # to the chunk's end (half the square), and write the scores'
+        # cotangent where the chunk's scores were (past the diagonal the
+        # backward kernel reads nothing)
+        with jax.named_scope("dsa.index"):
+            d_scores = isk.index_scores_fwd(qi, ki, wi, mdt, causal=True)
+        with jax.named_scope("dsa.loss"):
+            for lo, hi in _chunks(s, q_chunk):
+                d_scores = d_scores.at[:, lo:hi, :hi].set(
+                    g * jax.grad(_divergence)(
+                        d_scores[:, lo:hi, :hi], mask[:, lo:hi, :hi] != 0,
+                        p[:, lo:hi, :hi]))
+        with jax.named_scope("dsa.index"):
+            return isk.index_scores_bwd(qi, ki, wi, d_scores, mdt,
+                                        causal=True) + rest
     dqi, dwi, dki = [], [], jnp.zeros(ki.shape, jnp.float32)
     for lo, hi in _chunks(s, q_chunk):
         args = (qi[:, lo:hi], ki[:, :hi], wi[:, lo:hi], mask[:, lo:hi, :hi],
@@ -300,9 +340,7 @@ def _index_loss_bwd(q_chunk, mdt, res, g):
         dwi.append(dwi_c)
         dki = dki.at[:, :hi].add(dki_c.astype(jnp.float32))
     return (jnp.concatenate(dqi, 1), dki.astype(ki.dtype),
-            jnp.concatenate(dwi, 1), jnp.zeros(mask.shape, jnp.float32),
-            np.zeros(mask.shape, jax.dtypes.float0), jnp.zeros_like(q),
-            jnp.zeros_like(k), jnp.zeros_like(lse))
+            jnp.concatenate(dwi, 1)) + rest
 
 
 _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
@@ -310,7 +348,7 @@ _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
                                  q_chunk: int, mdt, *,
-                                 qk_heads_first: bool = False):
+                                 qk_heads_first: bool = False, layer=None):
     """:func:`sparse_index_attention` through the flash kernels: the
     same arguments, the same ``(o, loss, kept, ties)``. ``o`` is in
     ``mdt``, the kernels' output type. ``qk_heads_first``: ``q`` and
@@ -324,9 +362,19 @@ def sparse_index_attention_flash(q, k, v, qi, ki, wi, topk: int,
     kernel's output and its log-sum-exp. The block's second run then
     makes q, k, v and the indexer's inputs again and nothing else: no
     index product, no selection and no forward kernel; the backward runs
-    the dq and dkv kernels, the head-mean kernel and each chunk's index
-    products. Outside such a block the names do nothing."""
+    the dq and dkv kernels, the head-mean kernel and the index products
+    (the two index-score kernels, or each chunk's on XLA). ``layer``
+    names the layer on the ``dsa.index_kernel`` instant of a traced
+    run. Outside such a block the names do nothing."""
     b, s = q.shape[0], q.shape[2 if qk_heads_first else 1]
+    if events.enabled():
+        kernels = _index_kernels(qi, mdt)
+        events.instant(
+            "dsa.index_kernel", layer=layer, impl="kernel" if kernels
+            else "plain", heads=qi.shape[2], head_dim=qi.shape[3],
+            q_chunk=q_chunk, chunks=-(-s // q_chunk),
+            **({f"{k}_tile": isk.tiles(k, s, s, *qi.shape[2:], mdt)
+                for k in ("fwd", "bwd")} if kernels else {}))
     scores, mask, kept, ties = _scores_and_mask(qi, ki, wi, topk, q_chunk,
                                                 mdt)
     mask = kept_by_block(mask)

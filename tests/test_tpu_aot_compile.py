@@ -1018,3 +1018,38 @@ def test_a_layer_whose_kernels_read_grouped_heads_counts_itself(
     b, s, _, h, kv, d, _ = LAYER_SHAPES[cell]
     assert f"tensor<{b}x{s}x{kv}x{h // kv}x{d}x" not in lowered
     assert f"tensor<{b}x{kv}x{h // kv}x{s}x{d}x" not in lowered
+
+
+# ----------------------------------------------------------------------
+# the index scores and their pull-back (PR 54)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("rows,keys,causal", [
+    (8192, 8192, True), (4096, 4096, True), (512, 8192, False)],
+    ids=["cell7", "validation", "one_chunk"])
+def test_the_index_score_kernels_compile_at_cell_7s_shapes(
+        v5e_devices, chip_locations, rows, keys, causal):
+    """``keye_vl2_30b_a3b.train.1chip``: 16 heads of 64 against one key
+    head, bf16 operands, the whole sequence in one causal call at the
+    derived 512 x 512 tiles (and a chunk of it against every key): the
+    scores again, then ``dqi``, ``dki`` and ``dwi`` from their
+    cotangent. Nothing with a head axis over (queries, keys) exists, and
+    each call tells XLA's scheduler what it costs."""
+    from flexflow_tpu.kernels import index_scores as isk
+    b, j, c = 1, 16, 64
+    assert isk.takes_kernel(rows, keys, j, c, jnp.bfloat16)
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+
+    def both(qi, ki, wi, d):
+        return (isk.index_scores_fwd(qi, ki, wi, jnp.bfloat16,
+                                     causal=causal, interpret=False),
+                isk.index_scores_bwd(qi, ki, wi, d, jnp.bfloat16,
+                                     causal=causal, interpret=False))
+
+    lowered = jax.jit(both).lower(*(
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+        for shape in ((b, rows, j, c), (b, keys, c), (b, rows, j),
+                      (b, rows, keys))))
+    txt = lowered.compile().as_text()
+    assert _kernel_names(txt) == ["index_scores_bwd", "index_scores_fwd"]
+    assert not re.search(rf"\[({j}|{b},{j}),{rows},{keys}\]", txt)
+    assert lowered.as_text().count("cost_estimate") == 2

@@ -480,11 +480,11 @@ def _check_kept_outputs(label: str, ff) -> None:
     ``remat.kept`` instants: one a marked layer a block in each trace of
     the step): the output of every linear-attention layer inside the
     run, the one op that rematerialises itself whole, and nothing else."""
-    from flexflow_tpu.ffconst import OperatorType
     from flexflow_tpu.obs import events
+    from flexflow_tpu.ops.registry import get_op_def
     start, unit, reps = ff.executor._remat[:3]
     want = sorted(l.name for l in ff.layers[start:start + unit * reps]
-                  if l.op_type == OperatorType.OP_GATED_DELTA_RULE)
+                  if get_op_def(l.op_type).keeps_output_for_block)
     kept = {(e["attrs"]["block"], e["attrs"]["layer"]): e["attrs"]["bytes"]
             for e in events.events() if e["name"] == "remat.kept"}
     say(f"{label}: rematerialised run {(start, unit, reps)} keeps "
@@ -1099,6 +1099,71 @@ def leg_window_gated_moe(model_cfg, seq: int, per_chip_batch: int,
     _compiled_step_size(ff, x, y, label)
 
 
+# ----------------------------------------------------------------------
+# Leg I — state-space mixers beside an attention layer with its own scale
+# ----------------------------------------------------------------------
+VALIDATION_SSM = "examples/tpu_validate_ssm_hybrid.py"
+
+
+def leg_ssm_hybrid(model_cfg, seq: int, per_chip_batch: int, label: str,
+                   alpha: float = 1e-5) -> None:
+    """``build_hybrid_conv_moe`` with ``"mamba"`` and ``"attention"``
+    layers through compile and fit with ``remat = "blocks"``: the loss
+    falls, every layer is one block of the rematerialised run and every
+    mixer's output is kept by its block, each mixer announced its sizes
+    and the attention layer its scale, the counters give a log-decay
+    below zero for every mixer, and the step fits the chip.
+    ``VALIDATION_SSM`` holds the recurrence and the gradients to the
+    token-by-token reference, and this leg names it."""
+    import jax
+
+    from flexflow_tpu.models.nlp import build_hybrid_conv_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    _check_kept_outputs(label, ff)
+    kinds = list(model_cfg.layer_types)
+    check(ff.executor._remat[2] == len(kinds),
+          f"{label}: {ff.executor._remat[2]} blocks for {len(kinds)} layers")
+    said = {name: sorted({e["attrs"]["layer"]: e["attrs"]
+                          for e in events.events()
+                          if e["name"] == name}.items())
+            for name in ("ssm.layer", "attn.sm_scale")}
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: instants " + "; ".join(
+        f"{n} {[k for k, _ in v]}" for n, v in said.items())
+        + f"; resolved {sorted(set(impls.values()))} in {len(impls)} layers")
+    check([k for k, _ in said["ssm.layer"]] == sorted(
+        f"mamba_{i}" for i, k in enumerate(kinds) if k == "mamba")
+        and [k for k, _ in said["attn.sm_scale"]] == sorted(
+            f"attn_{i}" for i, k in enumerate(kinds) if k == "attention"),
+        f"{label}: the layers that announced themselves are not "
+        f"layer_types {kinds}: {said}")
+    chunks = -(-seq // model_cfg.mamba_chunk_size)
+    check(all(a["chunks"] == chunks and a["heads"] == model_cfg.mamba_n_heads
+              and a["state"] == model_cfg.mamba_d_state
+              for _, a in said["ssm.layer"])
+          and all(a["sm_scale"] == model_cfg.attention_multiplier
+                  for _, a in said["attn.sm_scale"]),
+          f"{label}: sizes {said}")
+    ctr = events.counters()
+    layers = ctr.get("ssm.layers", 0)
+    least = ctr.get("ssm.min_chunk_log_decay", 0) / max(1.0, layers)
+    say(f"{label}: {chunks} chunks of {model_cfg.mamba_chunk_size}; a "
+        f"layer's most negative whole-chunk log-decay {least:.2f} on "
+        f"average over {layers:.0f} layer-steps")
+    check(layers > 0 and least < 0.0, f"{label}: ssm counters {ctr}")
+    if chip:
+        check(set(impls.values()) == {"flash"},
+              f"{label}: attention resolved to {impls} at seq {seq}")
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: the recurrence and the gradients "
+        f"against the reference: python3 {VALIDATION_SSM}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def main() -> int:
     import jax
     devs = jax.devices()
@@ -1109,6 +1174,7 @@ def main() -> int:
     t0 = time.perf_counter()
     from flexflow_tpu import MachineSpec, native
     from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
+                                         GraniteHybridRankConfig,
                                          HybridConvMoEConfig,
                                          JoyAIFlashRankConfig,
                                          KeyeRankConfig,
@@ -1156,6 +1222,11 @@ def main() -> int:
             TrinityRankConfig.tiny(), sliding_window=256), 1024, 1,
             "H/small", alpha=1e-3)
         leg_window_gated_moe(TrinityRankConfig(), 8192, 1, "H/trinity")
+        # four chunks of 256 at the small size, then the cell's shapes
+        leg_ssm_hybrid(dataclasses.replace(
+            GraniteHybridRankConfig.tiny(), mamba_chunk_size=256), 1024, 1,
+            "I/small", alpha=1e-3)
+        leg_ssm_hybrid(GraniteHybridRankConfig(), 4096, 1, "I/granite")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

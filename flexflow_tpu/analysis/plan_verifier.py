@@ -407,9 +407,10 @@ def _check_stream_axes(report, axis_sizes, layer, spec) -> None:
 
 
 def _check_conv_sequence(report, axis_sizes, layer, spec) -> None:
-    """Two kinds of layer read what lies before a position: a gated
+    """Three kinds of layer read what lies before a position: a gated
     short convolution its ``taps - 1`` predecessors, a gated delta rule
-    those AND the state every earlier position left. Batch- and
+    and a state-space mixer those AND the state every earlier position
+    left. Batch- and
     channel- (head-) sharded layouts are local; a sequence-sharded one
     needs a halo exchange, and for the delta rule each shard's final
     state handed to the next, which no layer here emits and no cost row
@@ -419,6 +420,9 @@ def _check_conv_sequence(report, axis_sizes, layer, spec) -> None:
              "a short convolution of {taps} taps: each shard needs",
              OperatorType.OP_GATED_DELTA_RULE:
              "a gated delta rule: each shard needs the state its "
+             "neighbour leaves and",
+             OperatorType.OP_STATE_SPACE_MIXER:
+             "a state-space mixer: each shard needs the state its "
              "neighbour leaves and"}.get(getattr(layer, "op_type", None))
     if needs is None:
         return

@@ -412,6 +412,14 @@ class GraphProgram:
         return [env[t.guid] for t in self.output_tensors]
 
 
+# A sequence mixer that stands where another kind does in a layout of
+# layers otherwise the same: to the block finder the two are one op. Only
+# a kind no older layout holds may be named here (their runs, and so
+# their steps, stay the ones they were).
+_MIXES_LIKE = {OperatorType.OP_STATE_SPACE_MIXER:
+               OperatorType.OP_MULTIHEAD_ATTENTION}
+
+
 def _find_remat_blocks(layers):
     """Block boundaries for ``--remat``: the maximal repeated-block run,
     each block single-input/single-output (beside the graph's own inputs
@@ -422,7 +430,10 @@ def _find_remat_blocks(layers):
     its own layers with its own parameters (``_emit_remat``), so two
     blocks are the same where their ops and their outputs' shapes are:
     a period of attention layers that differ in window, rotary
-    embedding or positions is a run of blocks all the same. Returns
+    embedding or positions is a run of blocks all the same, and so is
+    a period of state-space mixers with one attention layer among them
+    (``_MIXES_LIKE``: nine layers of ten would otherwise be two runs,
+    and the shorter one held whole). Returns
     ``(start, unit, reps, entry_guids, exit_guids)`` or None."""
     from .parallel.pipeline_lowering import (_has_state, chunk_boundaries,
                                              find_repeated_run)
@@ -430,7 +441,7 @@ def _find_remat_blocks(layers):
                              if t.owner_layer is None)
     run = find_repeated_run(
         list(layers), 1, graph_inputs,
-        signature=lambda l: (l.op_type,
+        signature=lambda l: (_MIXES_LIKE.get(l.op_type, l.op_type),
                              tuple(t.shape for t in l.outputs)))
     if run is None:
         return None

@@ -233,6 +233,10 @@ class OperatorType(enum.IntEnum):
     # streams a token, read and written through learned, token-dependent
     # maps, the stream-to-stream one projected doubly stochastic
     OP_HYPER_CONNECTION = enum.auto()
+    # a state-space (Mamba-2) mixer: a (head_dim x state) state a head
+    # carried along the sequence under a scalar decay a head-token (a
+    # chunked scan over the chunk states, and its backward)
+    OP_STATE_SPACE_MIXER = enum.auto()
 
 
 # Ops that are pure elementwise-unary (single input, same shape out).

@@ -75,6 +75,7 @@ class FFModel:
         self.layers: List[Layer] = []
         self.input_tensors: List[Tensor] = []
         self.graph_inputs: List[Tensor] = []
+        self._may_be_unread: set = set()   # guids, ``create_tensor``
         self.optimizer: Optional[Optimizer] = None
         self.loss_type: Optional[LossType] = None
         self.metrics: List[MetricsType] = []
@@ -139,10 +140,16 @@ class FFModel:
     # ==================================================================
     def create_tensor(self, dims: Sequence[int],
                       dtype: DataType = DataType.DT_FLOAT,
-                      create_grad: bool = True, name: Optional[str] = None
-                      ) -> Tensor:
+                      create_grad: bool = True, name: Optional[str] = None,
+                      may_be_unread: bool = False) -> Tensor:
+        """A graph input. ``may_be_unread``: the caller feeds it whether
+        or not a layer reads it (the positions of a model with no
+        positional embedding); unread, ``fit`` takes its array and drops
+        it, and ``compile`` does not take it for the label."""
         t = Tensor(dims, dtype, None, 0, name=name, create_grad=create_grad)
         self.input_tensors.append(t)
+        if may_be_unread:
+            self._may_be_unread.add(t.guid)
         return t
 
     def create_constant(self, dims: Sequence[int], value: float,
@@ -224,6 +231,7 @@ class FFModel:
                             positions: Optional[Tensor] = None,
                             indexer: Optional[dict] = None,
                             output_gate: bool = False,
+                            sm_scale: Optional[float] = None,
                             name: Optional[str] = None) -> Tensor:
         """Multi-head attention (``ops.nn_ops.MultiHeadAttentionOp``):
         ``num_heads`` query heads of ``kdim / num_heads`` on
@@ -242,7 +250,11 @@ class FFModel:
         multiplied, element by element, by the sigmoid of a projection
         of the query input with a weight of its own (``wg``, input x
         heads x head size, no bias), before the output projection.
-        ``indexer``: learned sparse attention (below)."""
+        ``sm_scale``: what the scores are multiplied by before the
+        softmax (None: ``1 / sqrt(head size)``; a model that publishes
+        its own multiplier gives it here; not built beside an indexer
+        or on the ring path). ``indexer``: learned sparse attention
+        (below)."""
         params = {"embed_dim": embed_dim, "num_heads": num_heads,
                   "kdim": kdim, "vdim": vdim, "dropout": dropout,
                   "bias": bias, "add_bias_kv": add_bias_kv,
@@ -292,6 +304,11 @@ class FFModel:
                 raise ValueError("an output gate beside an indexer is "
                                  "not built")
             params["output_gate"] = True
+        if sm_scale is not None:
+            if indexer or not sm_scale > 0:
+                raise ValueError(f"sm_scale {sm_scale}: a positive "
+                                 f"multiplier, on a layer with no indexer")
+            params["sm_scale"] = float(sm_scale)
         inputs = [query, key, value]
         if positions is not None:
             # (batch, seq) int32: what the rotary embedding turns by
@@ -328,6 +345,31 @@ class FFModel:
         return self._unary(OperatorType.OP_GATED_DELTA_RULE, input, name,
                            num_heads=int(num_heads),
                            head_dim=int(head_dim), taps=int(taps),
+                           eps=float(eps))
+
+    def state_space_mixer(self, input: Tensor, num_heads: int,
+                          head_dim: int, state: int, taps: int,
+                          chunk: int, groups: int = 1, eps: float = 1e-5,
+                          name: Optional[str] = None) -> Tensor:
+        """A state-space (Mamba-2) mixer
+        (``ops.recurrent_ops.StateSpaceMixerOp``): ``num_heads`` heads of
+        ``head_dim`` channels, each a ``head_dim x state`` state under a
+        scalar decay a token; x, B and C through one causal depthwise
+        convolution of ``taps`` positions with a bias, B and C shared by
+        all heads (``groups`` 1: several groups are not built), computed
+        in chunks of ``chunk`` positions, a gated RMSNorm (``eps``) over
+        all channels before the output projection."""
+        if min(taps, num_heads, head_dim, state, chunk) < 1:
+            raise ValueError(
+                f"{num_heads} heads of {head_dim} x {state} in chunks of "
+                f"{chunk} behind a convolution of {taps} taps")
+        if groups != 1:
+            raise ValueError(f"{groups} groups of B and C: a state-space "
+                             f"mixer is built with one")
+        return self._unary(OperatorType.OP_STATE_SPACE_MIXER, input, name,
+                           num_heads=int(num_heads),
+                           head_dim=int(head_dim), state=int(state),
+                           taps=int(taps), chunk=int(chunk),
                            eps=float(eps))
 
     def latent_attention(self, input: Tensor, positions: Tensor,
@@ -780,6 +822,7 @@ class FFModel:
                              and t.get_tensor() is not None]
         unconsumed = [t for t in self.input_tensors
                       if t.guid not in consumed
+                      and t.guid not in self._may_be_unread
                       and t.get_tensor() is None]
         if self.label_tensor is None and len(unconsumed) == 1:
             self.label_tensor = unconsumed[0]
@@ -1359,6 +1402,11 @@ class FFModel:
         graph_inputs = getattr(self, "graph_inputs", self.input_tensors)
         if x is not None or y is not None:
             xs = x if isinstance(x, (list, tuple)) else [x]
+            fed = [t for t in self.input_tensors if t in graph_inputs
+                   or t.guid in self._may_be_unread]
+            if len(xs) == len(fed) != len(graph_inputs):
+                # an array for an input that no layer reads is dropped
+                xs = [a for t, a in zip(fed, xs) if t in graph_inputs]
             if len(xs) != len(graph_inputs):
                 raise ValueError(f"{len(xs)} arrays for "
                                  f"{len(graph_inputs)} inputs")

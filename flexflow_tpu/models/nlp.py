@@ -1246,6 +1246,101 @@ class TrinityRankConfig(HybridConvMoEConfig):
                    num_experts_per_tok=4, router_bias_std=0.05)
 
 
+def _granite_layer_types():
+    # granite-4.0-h-micro's first period: published layers 0 to 9
+    return ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
+
+
+@dataclasses.dataclass
+class GraniteHybridRankConfig(HybridConvMoEConfig):
+    """What ONE chip holds of Granite-4.0-H-Micro (``model_type:
+    granitemoehybrid``, 3B parameters, dense) as stage 0 of a four-stage
+    pipeline with the vocabulary in eight slices (the benchmark's
+    ``granite_4_0_h_micro``): by ``layer_types`` nine ``"mamba"`` layers
+    (a state-space mixer: ``mamba_n_heads`` heads of ``mamba_d_head``
+    channels, a state of ``mamba_d_state``, one group, a convolution of
+    ``mamba_d_conv`` taps with a bias, chunks of ``mamba_chunk_size``)
+    to one ``"attention"`` layer (32 query heads on 8 key/value heads of
+    64, NO rotary embedding, no q/k norm, scores times
+    ``attention_multiplier`` and not ``1 / sqrt(64)``), a dense SwiGLU of
+    ``shared_intermediate_size`` in EVERY layer and no routed expert;
+    the embedding times ``embedding_multiplier``, each sub-layer's output
+    times ``residual_multiplier`` before it is added, the logits divided
+    by ``logits_scaling``. Here: published layers 0 to 9 (one whole
+    period) and one of eight slices of the vocabulary; every width as
+    published.
+
+    The fields after the parent's carry ``config.json``'s keys by their
+    names; ``rms_norm_eps`` and the MLP's width are copied over the
+    parent's names for them, and the parent's expert fields read
+    ``num_local_experts`` (0: every layer is dense)."""
+    vocab_size: int = 12544
+    num_hidden_layers: int = 10
+    layer_types: list = dataclasses.field(
+        default_factory=_granite_layer_types)
+    num_dense_layers: int = 10
+    head_dim: int | None = 64            # not published: hidden / heads
+    intermediate_size: int = 8192
+    moe_intermediate_size: int = 0       # no expert layer reads it
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    use_expert_bias: bool = False
+    # the keys the parent class does not have
+    num_local_experts: int = 0
+    shared_intermediate_size: int = 8192
+    rms_norm_eps: float = 1e-5
+    position_embedding_type: str = "nope"
+    attention_bias: bool = False
+    attention_multiplier: float = 0.015625
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 8.0
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_expand: int = 2
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    mamba_chunk_size: int = 256
+
+    def __post_init__(self):
+        if self.num_local_experts or self.num_experts_per_tok \
+                or self.position_embedding_type != "nope" \
+                or self.attention_bias or self.mamba_proj_bias \
+                or not self.mamba_conv_bias:
+            raise ValueError(
+                "routed experts, a rotary embedding, a bias in a "
+                "projection and a convolution without one are not built "
+                "for this family")
+        if self.mamba_n_heads * self.mamba_d_head \
+                != self.mamba_expand * self.hidden_size \
+                or self.intermediate_size != self.shared_intermediate_size:
+            raise ValueError(
+                "mamba_n_heads x mamba_d_head is mamba_expand x "
+                "hidden_size, and intermediate_size repeats "
+                "shared_intermediate_size")
+        self.norm_eps = self.rms_norm_eps
+        self.num_experts = self.num_local_experts
+        self.num_dense_layers = self.num_hidden_layers
+
+    @classmethod
+    def tiny(cls):
+        """6 layers of both kinds (mamba x3, attention, mamba x2), 4
+        state-space heads of 16 with a state of 8 in chunks of 16 (two
+        chunks of a 32-token sequence), 4 attention heads on 2 kv heads
+        of 16: tests."""
+        return cls(vocab_size=96, hidden_size=32, num_hidden_layers=6,
+                   layer_types=["mamba"] * 3 + ["attention"]
+                   + ["mamba"] * 2,
+                   num_attention_heads=4, num_key_value_heads=2,
+                   head_dim=8, intermediate_size=64,
+                   shared_intermediate_size=64, attention_multiplier=0.25,
+                   mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
+                   mamba_chunk_size=16)
+
+
 def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                           cfg: HybridConvMoEConfig | None = None):
     """Causal LM of :class:`HybridConvMoEConfig`: inputs ``[ids, pos]``,
@@ -1260,9 +1355,14 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     own; that class also turns the full layers' rotary embedding off,
     gates every attention layer's output, norms each sub-layer's output
     before the residual add, scales the embedding and adds a shared
-    expert. ``pos`` is what the
-    attention layers' rotary embedding turns by (a layout with no
-    attention layer takes ``ids`` alone).
+    expert. A ``"mamba"`` layer (:class:`GraniteHybridRankConfig`) is a
+    state-space mixer and that class's ``"attention"`` layer grouped-query
+    attention with no rotary embedding, no q/k norm and the scores'
+    multiplier the configuration gives; the class also multiplies the
+    embedding, each sub-layer's output before its add and the logits by
+    scalars of its own. ``pos`` is what the
+    attention layers' rotary embedding turns by (a layout in which no
+    layer turns by it still declares it, and ``fit`` drops its array).
 
     The published model ties the head to the embedding; this graph has
     no weight read by two layers, so the head is its own matrix (as
@@ -1271,11 +1371,12 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     kinds = list(cfg.layer_types)
     if len(kinds) != cfg.num_hidden_layers \
             or set(kinds) - {"conv", "full_attention", "sparse_attention",
-                             "sliding_attention"}:
+                             "sliding_attention", "mamba", "attention"}:
         raise ValueError(
             f"layer_types must name {cfg.num_hidden_layers} layers, each "
-            f"'conv', 'full_attention', 'sparse_attention' or "
-            f"'sliding_attention'; got {len(kinds)}: {sorted(set(kinds))}")
+            f"'conv', 'full_attention', 'sparse_attention', "
+            f"'sliding_attention', 'mamba' or 'attention'; got "
+            f"{len(kinds)}: {sorted(set(kinds))}")
     if cfg.conv_bias or not cfg.norm_topk_prob:
         raise ValueError("conv_bias and gates that are not normalised "
                          "over the chosen experts are not built")
@@ -1305,19 +1406,50 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     sandwich = getattr(cfg, "sandwich_norms", False)
     shared_dim = cfg.moe_intermediate_size \
         * getattr(cfg, "num_shared_experts", 0)
+    if "mamba" in kinds and not hasattr(cfg, "mamba_n_heads"):
+        raise ValueError("a 'mamba' layer needs the configuration's "
+                         "mamba_* sizes")
+    if "attention" in kinds and not hasattr(cfg, "attention_multiplier"):
+        raise ValueError("an 'attention' layer needs the configuration's "
+                         "attention_multiplier")
+    # the scalars of a subclass (``GraniteHybridRankConfig``): absent,
+    # the graph has no node for them
+    residual_scale = getattr(cfg, "residual_multiplier", None)
     ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
-    pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids")
+    pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids",
+                           may_be_unread=not set(kinds) - {"mamba",
+                                                           "attention"})
     h = ff.embedding(ids, cfg.vocab_size, hid, name="embed_tokens")
     if getattr(cfg, "mup_enabled", False):
         h = ff.scalar_multiply(h, math.sqrt(hid), name="embed_scale")
+    if hasattr(cfg, "embedding_multiplier"):
+        h = ff.scalar_multiply(h, cfg.embedding_multiplier,
+                               name="embedding_multiplier")
 
     def norm(x, name):
         return ff.rms_norm(x, eps=cfg.norm_eps, name=name)
+
+    def scaled(x, name):
+        return x if residual_scale is None \
+            else ff.scalar_multiply(x, residual_scale, name=name)
 
     for i, kind in enumerate(kinds):
         x = norm(h, f"operator_norm_{i}")
         if kind == "conv":
             op = ff.gated_short_conv(x, cfg.conv_L_cache, name=f"conv_{i}")
+        elif kind == "mamba":
+            op = ff.state_space_mixer(
+                x, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
+                cfg.mamba_d_conv, cfg.mamba_chunk_size,
+                groups=cfg.mamba_n_groups, eps=cfg.norm_eps,
+                name=f"mamba_{i}")
+        elif kind == "attention":
+            # no rotary embedding, no q/k norm, the model's own scale
+            op = ff.multihead_attention(
+                x, x, x, hid, heads, kdim=heads * head_dim,
+                vdim=heads * head_dim, bias=False, causal=True,
+                num_kv_heads=cfg.num_key_value_heads,
+                sm_scale=cfg.attention_multiplier, name=f"attn_{i}")
         else:
             # a full layer turns by the positions unless the class says
             # it has no rotary embedding; a window layer always does
@@ -1335,7 +1467,8 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                    if kind == "sliding_attention" else {}), **gated)
         if sandwich:
             op = norm(op, f"post_operator_norm_{i}")
-        h = ff.add(h, op, name=f"operator_res_{i}")
+        h = ff.add(h, scaled(op, f"operator_scale_{i}"),
+                   name=f"operator_res_{i}")
         x = norm(h, f"ffn_norm_{i}")
         if i < cfg.num_dense_layers:
             gate = ff.dense(x, cfg.intermediate_size, use_bias=False,
@@ -1356,6 +1489,10 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                 else 0.0, name=f"experts_{i}", **scoring)
         if sandwich:
             y = norm(y, f"post_ffn_norm_{i}")
-        h = ff.add(h, y, name=f"ffn_res_{i}")
-    return ff.softmax(ff.dense(norm(h, "final_norm"), cfg.vocab_size,
-                               use_bias=False, name="lm_head"))
+        h = ff.add(h, scaled(y, f"ffn_scale_{i}"), name=f"ffn_res_{i}")
+    logits = ff.dense(norm(h, "final_norm"), cfg.vocab_size,
+                      use_bias=False, name="lm_head")
+    if hasattr(cfg, "logits_scaling"):
+        logits = ff.scalar_true_divide(logits, cfg.logits_scaling,
+                                       name="logits_scaling")
+    return ff.softmax(logits)

@@ -679,6 +679,20 @@ class MultiHeadAttentionOp(OpDef):
                                  or self._impl_for(ctx, name) == "ring"):
             raise ValueError(f"{name}: an output gate is built on the "
                              f"flash, XLA and decode paths only")
+        # the scores' multiplier where the model publishes its own
+        # (absent: 1 / sqrt(head size), and nothing below changes)
+        sm_scale = params.get("sm_scale")
+        if sm_scale is not None:
+            if params.get("indexer_heads") \
+                    or self._impl_for(ctx, name) == "ring":
+                raise ValueError(f"{name}: a softmax scale of the "
+                                 f"model's own is built on the flash, XLA "
+                                 f"and decode paths only")
+            if events.enabled():
+                events.instant("attn.sm_scale", layer=name, heads=h,
+                               kv_heads=kh.shape[2], head_dim=qh.shape[-1],
+                               sm_scale=sm_scale,
+                               default=1.0 / math.sqrt(qh.shape[-1]))
         # qh.shape[2], not params["num_heads"]: under the tp attn role
         # this code runs inside shard_map with LOCAL head counts
         heads = qh.shape[2]
@@ -845,12 +859,14 @@ class MultiHeadAttentionOp(OpDef):
                     causal=causal,
                     dropout_rate=rate, dropout_seed=seed,
                     mesh=mesh, spec=spec,
-                    **({"window": window} if window else {}))
+                    **({"window": window} if window else {}),
+                    **({} if sm_scale is None else {"sm_scale": sm_scale}))
             ctxv = jnp.swapaxes(o, 1, 2).astype(jnp.float32)
             return self._project_out(ctxv, gate, weights, ctx, mdt, cdt)
 
         self._note_impl(ctx, name, "xla")
-        scale = 1.0 / math.sqrt(qh.shape[-1])
+        scale = 1.0 / math.sqrt(qh.shape[-1]) if sm_scale is None \
+            else sm_scale
         logits = jnp.einsum("bqhd,bkhd->bhqk", qh.astype(mdt),
                             kh.astype(mdt),
                             preferred_element_type=jnp.float32) * scale
@@ -1080,7 +1096,7 @@ class MultiHeadAttentionOp(OpDef):
         kvh = k_full.shape[2]
         g = hq // kvh
         qg = qh.reshape(b_, lq_, kvh, g, d_)
-        scale = 1.0 / math.sqrt(d_)
+        scale = params.get("sm_scale") or 1.0 / math.sqrt(d_)
         logits = jnp.einsum("bqkgd,bmkd->bkgqm", qg.astype(mdt),
                             k_full.astype(mdt),
                             preferred_element_type=jnp.float32) * scale

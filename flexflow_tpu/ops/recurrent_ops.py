@@ -34,6 +34,24 @@ product a span, and the decayed copies of k and q made for all of a
 chunk's spans together are as large as k and q), a sub-block against
 itself through the ``(SUB, SUB, d)`` tensor of differences, reduced on
 the spot.
+
+The state-space (Mamba-2) recurrence: a head keeps a ``P x N`` state
+(``P`` its channels, ``N`` the state size) under a SCALAR decay a token,
+
+    S_t = a_t S_{t-1} + dt_t x_t B_t^T,   a_t = exp(dt_t A),  A < 0
+    y_t = S_t C_t + D x_t
+
+with ``B_t``, ``C_t`` (N wide) shared by every head. In chunks
+(:func:`state_space_scan`), ``G`` the running sum of ``dt A`` inside a
+chunk and ``S_0`` the state at its start:
+
+    L_ij = exp(G_i - G_j)                             (j <= i)
+    Y    = (L * C B^T)(dt * X) + exp(G) * (C S_0^T)
+    S_C  = exp(G_C) S_0 + ((dt * X) * exp(G_C - G))^T B
+
+Again every exponent is a difference that is <= 0. The decay is a
+head's, so ``L`` is one ``C x C`` matrix a head and ``C B^T`` one for all
+heads; nothing is halved or solved.
 """
 from __future__ import annotations
 
@@ -135,13 +153,14 @@ def _chunk_step(mdt, state, terms):
     return state, out
 
 
-def _in_chunks(x, chunk):
-    """(B, H, T, ..) -> (B, H, N, C, ..) float32; the padded positions
-    write nothing (beta 0) and decay nothing (g 0)."""
-    pad = -x.shape[2] % chunk
-    x = jnp.pad(x.astype(jnp.float32),
-                ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
-    return x.reshape(x.shape[:2] + (-1, chunk) + x.shape[3:])
+def _in_chunks(x, chunk, axis=2):
+    """(B, H, T, ..) -> (B, H, N, C, ..) float32 (``axis``: where T
+    stands); the padded positions write nothing (beta, dt 0) and decay
+    nothing (g 0)."""
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, -x.shape[axis] % chunk)
+    x = jnp.pad(x.astype(jnp.float32), widths)
+    return x.reshape(x.shape[:axis] + (-1, chunk) + x.shape[axis + 1:])
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
@@ -186,6 +205,79 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
     out = jnp.moveaxis(out, 0, 2)                  # (B, H, N, C, dv)
     out = out.reshape(out.shape[:2] + (-1,) + out.shape[4:])[:, :, :t]
     return out, jax.lax.stop_gradient(least)
+
+
+def _ssm_chunks(mdt, dtx, bm, cm, big_g):
+    """Everything of the chunks that does not depend on the state a
+    chunk starts from, all chunks at once: ``dtx = dt * x`` (B, M, C, H,
+    P), ``bm``, ``cm`` (B, M, C, N), ``big_g`` the running log-decay
+    inside each chunk (B, M, C, H); float32. Returns the outputs the
+    chunk's own tokens give, ``(L * C B^T)(dt x)`` (B, M, C, H, P), and
+    what the chunk adds to the state, ``((dt x) * exp(G_C - G))^T B``
+    (B, M, H, P, N)."""
+    def mm(pattern, x, y):
+        return jnp.einsum(pattern, x.astype(mdt), y.astype(mdt),
+                          preferred_element_type=jnp.float32)
+
+    n_c = dtx.shape[2]
+    g = jnp.moveaxis(big_g, 2, 3)                       # (B, M, H, C)
+    low = np.tril(np.ones((n_c, n_c), bool))
+    # L: the differences themselves, a head; 0 above the diagonal
+    decay = jnp.where(low, jnp.exp(jnp.where(
+        low, g[..., :, None] - g[..., None, :], 0.0)), 0.0)
+    inside = mm("bmhij,bmjhp->bmihp",
+                mm("bmin,bmjn->bmij", cm, bm)[:, :, None] * decay, dtx)
+    added = mm("bmjhp,bmjn->bmhpn",
+               dtx * jnp.exp(big_g[:, :, -1:] - big_g)[..., None], bm)
+    return inside, added
+
+
+def state_space_scan(x, dt, a, bm, cm, chunk: int, mdt=jnp.float32, *,
+                     layer=None):
+    """The state-space recurrence of the module's docstring from a zero
+    state, in chunks, without the ``D`` skip: ``x`` (B, T, H, P), ``dt``
+    (B, T, H) > 0 the step size, ``a`` (H,) < 0, ``bm``, ``cm`` (B, T, N)
+    (one group: every head reads the same). ``mdt``: the type the
+    products' operands are rounded to (sums, log-decays, their
+    exponentials and the states are float32). Returns ``y`` (B, T, H, P)
+    float32 and the most negative log-decay summed over one chunk.
+
+    What a chunk's own tokens give, and what it adds to the state, is
+    made for all chunks at once (:func:`_ssm_chunks`), rematerialised:
+    ``L`` is a ``C x C`` float32 matrix a head a chunk, and the backward
+    pass makes it again instead of holding it. One ``lax.scan`` over the
+    chunks then carries the state, ``S <- exp(G_C) S + added``, and keeps
+    the state each chunk starts from (M of them: what the scan stacks is
+    ``P x N`` a head a chunk, not a chunk's outputs); those states are
+    read by one product for all chunks. A sequence that is no whole
+    number of chunks is padded with positions that write nothing and
+    decay nothing (``dt`` 0)."""
+    t = x.shape[1]
+    f32 = jnp.float32
+
+    def in_chunks(v):           # (B, T, ..) -> (B, M, C, ..)
+        return _in_chunks(v, chunk, axis=1)
+
+    dt_c, cm_c = in_chunks(dt), in_chunks(cm)
+    big_g = jnp.cumsum(dt_c * a.astype(f32), axis=2)    # (B, M, C, H)
+    inside, added = checkpointed(
+        lambda *v: _ssm_chunks(mdt, *v), site="ssm.chunk", layer=layer)(
+        in_chunks(x) * dt_c[..., None], in_chunks(bm), cm_c, big_g)
+    whole = jnp.exp(big_g[:, :, -1])                    # (B, M, H)
+
+    def step(state, now):       # the state a chunk starts from, stacked
+        keeps, adds = now
+        return keeps[..., None, None] * state + adds, state
+
+    _, starts = jax.lax.scan(
+        step, jnp.zeros(added.shape[:1] + added.shape[2:], f32),
+        (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(added, 1, 0)))
+    before = jnp.einsum("bmin,mbhpn->bmihp", cm_c.astype(mdt),
+                        starts.astype(mdt), preferred_element_type=f32) \
+        * jnp.exp(big_g)[..., None]
+    y = inside + before                                 # (B, M, C, H, P)
+    y = y.reshape((y.shape[0], -1) + y.shape[3:])[:, :t]
+    return y, jax.lax.stop_gradient(jnp.min(big_g[:, :, -1]))
 
 
 def _unit(x):
@@ -368,6 +460,128 @@ class GatedDeltaRuleOp(OpDef):
         proj = 4 * e * h * d + 2 * (e * r + r * h * d) + e * h
         return tokens * (2.0 * proj + 3 * (2 * k + 1) * h * d
                          + 7.0 * h * d * d)
+
+    def backward_flops_factor(self):
+        return 2.0
+
+
+@register
+class StateSpaceMixerOp(OpDef):
+    """A state-space mixer (Mamba-2's form, one group of B/C): ``H``
+    heads of ``P`` channels, each the recurrence of
+    :func:`state_space_scan` over a state of ``P x N``.
+
+      [z | xBC | dt] = x in_proj           H P | H P + 2 N | H
+      xBC = silu(short_conv(xBC; conv_w) + conv_b)     K causal taps
+      [x | B | C] = xBC                    x: (T, H, P); B, C: (T, N)
+      dt = softplus(dt + dt_bias);  A = -exp(A_log)            a head
+      y = state_space_scan(x, dt, A, B, C) + D x               D a head
+      y = RMSNorm(y * silu(z); norm)       the gate BEFORE the norm,
+                                           the mean over all H P channels
+      out = y out_proj
+
+    No bias but the convolution's. The two projections and the
+    recurrence's products are at the compute dtype with float32
+    accumulation; the taps, softplus, log-decays and their exponentials,
+    ``L``, the state and the norm are float32. Plain JAX on XLA, its
+    backward autodiff's; the scan over the chunks runs under the name
+    scope ``ssm.scan``. Training and evaluation only: there is no decode
+    path that carries the state from call to call."""
+    op_type = OperatorType.OP_STATE_SPACE_MIXER
+    keeps_output_for_block = True   # ``emit``: the layer is one checkpoint
+
+    def infer(self, params, in_shapes, in_dtypes):
+        return [(in_shapes[0], in_dtypes[0])]
+
+    def weights(self, params, in_shapes, in_dtypes):
+        e, dt = in_shapes[0][-1], in_dtypes[0]
+        h, p, n, k = (params["num_heads"], params["head_dim"],
+                      params["state"], params["taps"])
+        inner, uniform = h * p, InitializerType.UNIFORM
+        return [
+            WeightSpec("in_proj", (e, 2 * inner + 2 * n + h), dt),
+            # one filter a channel: K taps in, K positions reached
+            WeightSpec("conv_w", (inner + 2 * n, k), dt,
+                       init_args={"fans": (k, k)}),
+            WeightSpec("conv_b", (inner + 2 * n,), dt,
+                       InitializerType.ZERO),
+            # how fast a state decays: A = exp(A_log) uniform in (1, 16),
+            # softplus(dt_bias) log-uniform in (1e-3, 1e-1)
+            WeightSpec("dt_bias", (h,), dt, uniform,
+                       {"min": math.log(1e-3), "max": math.log(1e-1),
+                        "map": "inverse_softplus_of_exp"}),
+            WeightSpec("A_log", (h,), dt, uniform,
+                       {"min": 1.0, "max": 16.0, "map": "log"}),
+            WeightSpec("D", (h,), dt, InitializerType.ONE),
+            WeightSpec("norm", (inner,), dt, InitializerType.ONE),
+            WeightSpec("out_proj", (inner, e), dt)]
+
+    def emit(self, params, inputs, weights, ctx, name):
+        (u,) = inputs
+        if getattr(ctx, "kv_mode", None) is not None:
+            raise NotImplementedError(
+                f"{name}: the state-space mixer has no decode path that "
+                f"carries its state beside a KV cache")
+        mdt = compute_dtype(ctx, u.dtype)
+        f32 = jnp.float32
+        h, p, n = params["num_heads"], params["head_dim"], params["state"]
+        chunk, inner = int(params["chunk"]), h * p
+        b, t = u.shape[:2]
+        if events.enabled():
+            events.instant("ssm.layer", layer=name, heads=h, head_dim=p,
+                           state=n, groups=1, taps=params["taps"],
+                           tokens=b * t, chunk=chunk,
+                           chunks=-(-t // chunk))
+
+        # The layer is rematerialised whole, as the gated delta rule is
+        # and for its reasons: what it keeps for the backward pass is
+        # its input, not the (tokens, 2 H P + 2 N + H) float32
+        # projection and the half dozen (tokens, H P) arrays behind it;
+        # a rematerialised block around it keeps its output
+        # (``keeps_output_for_block``) and does not run it a third time.
+        def layer(u, w):
+            zxbcdt = jnp.einsum("bte,ec->btc", u.astype(mdt),
+                                w["in_proj"].astype(mdt),
+                                preferred_element_type=f32)
+            z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], -1)
+            xbc = jax.nn.silu(short_conv(xbc, w["conv_w"].astype(f32))
+                              + w["conv_b"].astype(f32))
+            x, bm, cm = jnp.split(xbc, [inner, inner + n], -1)
+            x = x.reshape(b, t, h, p)
+            dt = jax.nn.softplus(dt + w["dt_bias"].astype(f32))
+            with jax.named_scope("ssm.scan"):
+                y, least = state_space_scan(
+                    x, dt, -jnp.exp(w["A_log"].astype(f32)), bm, cm, chunk,
+                    mdt, layer=name)
+            y = y + w["D"].astype(f32)[:, None] * x
+            y = _rms(y.reshape(b, t, inner) * jax.nn.silu(z), w["norm"],
+                     params["eps"])
+            return jnp.einsum("btc,ce->bte", y.astype(mdt),
+                              w["out_proj"].astype(mdt),
+                              preferred_element_type=f32), least
+
+        specs, wrap_mesh = wrap_specs(ctx)
+        out, least = checkpointed(
+            layer, site="ssm.layer", layer=name, weights=(1,), specs=specs,
+            mesh=wrap_mesh)(u, weights)
+        # counters add over layers and steps: the sum of each layer's
+        # most negative whole-chunk log-decay, beside the number of layers
+        ctx.count("ssm.min_chunk_log_decay", least)
+        ctx.count("ssm.layers", jnp.float32(1.0))
+        return [out.astype(u.dtype)]
+
+    def flops(self, params, in_shapes, out_shapes):
+        """By the recurrent form, which no implementation changes: a
+        head-token decays the state (P N), writes it (2 P N) and reads
+        it (2 P N), and adds the skip."""
+        tokens = float(np.prod(in_shapes[0][:-1]))
+        e = in_shapes[0][-1]
+        h, p, n, k = (params["num_heads"], params["head_dim"],
+                      params["state"], params["taps"])
+        inner = h * p
+        proj = e * (2 * inner + 2 * n + h) + inner * e
+        return tokens * (2.0 * proj + (2 * k + 1) * (inner + 2 * n)
+                         + 5.0 * inner * n + 2 * inner)
 
     def backward_flops_factor(self):
         return 2.0

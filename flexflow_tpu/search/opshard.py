@@ -91,6 +91,20 @@ def options_for(layer: Layer) -> List[ShardOption]:
             ("wq", 1), ("wk", 1), ("wv", 1), ("conv_q", 0), ("conv_k", 0),
             ("conv_v", 0), ("wf_b", 1), ("A_log", 0), ("dt_bias", 0),
             ("wb", 1), ("wg_b", 1), ("wo", 0))))
+    elif t == OperatorType.OP_STATE_SPACE_MIXER:
+        sample()
+        # head-parallel: heads are independent recurrences. The fused
+        # input projection's columns are [z | x | B | C | dt], so a
+        # shard of them is not a shard of heads: what co-shards by heads
+        # is the output side (the norm's scale and out_proj's rows, the
+        # output whole on hidden after an all-reduce), and the
+        # partitioner places the seams inside the layer (the gated
+        # norm's mean over all channels is its reduction). The sequence
+        # dim is NOT offered: a shard would need a halo of taps - 1
+        # positions AND the state its neighbour leaves (the plan
+        # verifier refuses it)
+        opts.append(ShardOption("parameter", -1, (
+            ("norm", 0), ("out_proj", 0))))
     elif t == OperatorType.OP_HYPER_CONNECTION:
         sample()
         # per token: the maps of a position read that position's streams

@@ -16,6 +16,7 @@ import pytest
 
 import chip_smoke
 from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
+                                     GraniteHybridRankConfig,
                                      HybridConvMoEConfig, KeyeRankConfig,
                                      KimiLinearRankConfig, LatentMoEConfig,
                                      TrinityRankConfig, XingRankConfig)
@@ -204,6 +205,25 @@ def test_leg_h_window_gated_moe_tiny_on_the_cpu_mesh(capsys):
     assert f"python3 {chip_smoke.VALIDATION_WINDOW}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_WINDOW))
+
+
+def test_leg_i_ssm_hybrid_tiny_on_the_cpu_mesh(capsys):
+    """32 positions in two chunks of 16 on the 8-device mesh: the six
+    layers (mamba x3, attention, mamba x2) are six rematerialised
+    blocks, the five mixers' outputs kept, every mixer and the scaled
+    attention layer announced."""
+    chip_smoke.leg_ssm_hybrid(GraniteHybridRankConfig.tiny(), seq=32,
+                              per_chip_batch=1, label="I/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "rematerialised run (2, 13, 6) keeps 5 outputs" in out
+    assert ("ssm.layer ['mamba_0', 'mamba_1', 'mamba_2', 'mamba_4', "
+            "'mamba_5']; attn.sm_scale ['attn_3']; resolved ['xla'] in 1 "
+            "layers") in out
+    assert "2 chunks of 16; a layer's most negative whole-chunk log-decay -" \
+        in out
+    assert f"python3 {chip_smoke.VALIDATION_SSM}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_SSM))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

@@ -32,13 +32,15 @@ B, S = 8, 40              # a batch the 8 virtual devices divide
 SITES = {"block": "executor.py", "kda.layer": "ops/recurrent_ops.py",
          "kda.branch": "ops/recurrent_ops.py",
          "kda.terms": "ops/recurrent_ops.py",
-         "kda.step": "ops/recurrent_ops.py", "mhc.maps": "ops/hyper_ops.py",
+         "kda.step": "ops/recurrent_ops.py",
+         "ssm.layer": "ops/recurrent_ops.py",
+         "ssm.chunk": "ops/recurrent_ops.py", "mhc.maps": "ops/hyper_ops.py",
          "mhc.plain": "ops/hyper_ops.py",
          "dsa.chunk": "ops/sparse_attention.py"}
 
 
 # ----------------------------------------------------------------------
-# one wrap, eight sites: the sources
+# one wrap, ten sites: the sources
 # ----------------------------------------------------------------------
 def _code_tokens(path):
     """The file's tokens without comments and strings (a docstring may

@@ -1053,3 +1053,75 @@ def test_the_index_score_kernels_compile_at_cell_7s_shapes(
     assert _kernel_names(txt) == ["index_scores_bwd", "index_scores_fwd"]
     assert not re.search(rf"\[({j}|{b},{j}),{rows},{keys}\]", txt)
     assert lowered.as_text().count("cost_estimate") == 2
+
+
+# ----------------------------------------------------------------------
+# the state-space / attention hybrid (PR 55)
+# ----------------------------------------------------------------------
+def test_the_grouped_kernels_compile_at_cell_9s_shapes_with_its_scale(
+        v5e_devices, chip_locations):
+    """``granite_4_0_h_micro.train.1chip``: 32 query heads on 8 key/value
+    heads of 64 over 4,096 positions, bf16, causal, the scores times
+    1/64: the three kernels compile, K and V read at their own 8 heads,
+    and the scale is a constant of the kernels (no operand more than the
+    default's)."""
+    b, h, kvh, s, d = 1, 32, 8, 4096, 64
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, kvh, s, d), jnp.bfloat16, sharding=one)
+
+    def text(**scale):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True, interpret=False,
+                                **scale)
+            return jnp.sum(o.astype(jnp.float32))
+        return _compile_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+    scaled, default = text(sm_scale=0.015625), text()
+    assert _kernel_names(scaled) == FLASH_NAMES
+
+    def operands(txt):
+        return sorted(l.split(" custom-call(")[1].count("%")
+                      for l in txt.splitlines() if MOSAIC_CALL in l)
+
+    assert operands(scaled) == operands(default)
+    narrow = f"bf16[{b * kvh},{s},{d}]"
+    assert all(l.split(" custom-call(")[1].count(narrow) == 2
+               for l in scaled.splitlines() if MOSAIC_CALL in l)
+
+
+def test_the_state_space_mixer_compiles_at_the_published_width(
+        v5e_devices):
+    """One mixer's forward and backward at 2048 -> 64 heads of 64 x 128
+    over 4,096 positions in 16 chunks of 256, bf16 operands, compiled
+    for a described v5e: plain XLA (no Mosaic call), the scan over the
+    chunk states a ``while`` under ``ssm.scan``, and the layer's
+    temporaries stay far under what 64 heads' ``L`` held for every chunk
+    of a forward AND a backward pass at once would take."""
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.ops.recurrent_ops import StateSpaceMixerOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    params = {"num_heads": 64, "head_dim": 64, "state": 128, "taps": 4,
+              "chunk": 256, "eps": 1e-5}
+    op = StateSpaceMixerOp()
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.float32, sharding=one)
+    w = {s.name: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=one)
+         for s in op.weights(params, [(1, 4096, 2048)],
+                             [DataType.DT_FLOAT])}
+
+    def loss(x, w):
+        (y,) = op.emit(params, [x], w,
+                       EmitCtx(training=True, config=FFConfig()), "mamba_0")
+        return jnp.sum(y)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
+    txt = compiled.as_text()
+    assert MOSAIC_CALL not in txt
+    assert "ssm.scan" in txt and "remat.ssm.chunk" in txt \
+        and "remat.ssm.layer" in txt and " while(" in txt
+    # L for all chunks is 256 MiB in float32; the layer's peak holds a
+    # few arrays of that size while the chunks' backward runs, not a
+    # forward's and a backward's worth of them beside each other
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30

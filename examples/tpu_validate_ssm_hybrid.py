@@ -8,7 +8,7 @@ scalar multipliers.
 
     python3 examples/tpu_validate_ssm_hybrid.py [--seeds 1 2 3]
         [--seq 4096] [--grad-seq 1024] [--skip-layer] [--skip-forward]
-        [--skip-gradients]
+        [--skip-gradients] [--skip-kernels]
 
 The model is ``benchmarks/configs/granite_4_0_h_micro.json`` through the
 normal path (``FFModel`` -> ``build_hybrid_conv_moe`` -> ``compile``),
@@ -21,7 +21,9 @@ failure):
      heads of 64 x 128, chunks of 256) over ``--seq`` positions with
      bf16 operands, at the first seed's ``mamba_0`` weights: its output
      against the reference's token-by-token layer, held to twice what
-     the reference itself reads with bf16 operands; and the
+     the reference itself reads with bf16 operands (on one chip this is
+     the kernels of ``kernels/state_space.py``: the ``ssm.layer``
+     instant has to say ``impl="kernel"``); and the
      ``ssm.layer`` instant's sizes; and, printed and not judged, how
      much of that output the carried state is at the seed's weights: the
      reference's layer with every chunk run as a sequence of its own;
@@ -47,7 +49,14 @@ failure):
      norm's scale, one SwiGLU, the embedding and the head, against
      ``jax.grad`` of the reference's loss, each held to twice what the
      reference itself reads with bf16 operands. ``correct`` sees no
-     gradient.
+     gradient;
+  5. the recurrence ALONE at the published width over ``--seq``
+     positions with bf16 operands, down the kernels and down the plain
+     path (``state_space_scan(kernels=False)``): the two outputs against
+     each other, and the output and each of the five gradients of both
+     against the plain path in float32 (``highest``), the kernels held
+     to twice what the plain path itself reads with bf16 operands; one
+     ``ssm.kernel`` instant a call.
 """
 import argparse
 import json
@@ -69,7 +78,9 @@ from examples.tpu_validate_latent_moe import (  # noqa: E402
     program_grads, rel)
 from flexflow_tpu import FFConfig  # noqa: E402
 from flexflow_tpu.obs import events  # noqa: E402
-from flexflow_tpu.ops.recurrent_ops import StateSpaceMixerOp  # noqa: E402
+from flexflow_tpu.kernels import state_space as ssm_kernels  # noqa: E402
+from flexflow_tpu.ops.recurrent_ops import (StateSpaceMixerOp,  # noqa: E402
+                                            state_space_scan)
 from flexflow_tpu.ops.registry import EmitCtx  # noqa: E402
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX  # noqa: E402
 
@@ -120,7 +131,64 @@ def layer_check(conf, ref, ff, seq):
             ("head_dim", conf["mamba_d_head"]),
             ("state", conf["mamba_d_state"]), ("groups", 1),
             ("chunk", conf["mamba_chunk_size"]),
-            ("chunks", -(-seq // conf["mamba_chunk_size"])))), str(said))
+            ("chunks", -(-seq // conf["mamba_chunk_size"])),
+            ("impl", "kernel"))), str(said))
+
+
+def kernel_check(conf, seq):
+    """Check 5: the recurrence alone, kernels against the plain path."""
+    h, p, n, chunk = (conf["mamba_n_heads"], conf["mamba_d_head"],
+                      conf["mamba_d_state"], conf["mamba_chunk_size"])
+    keys = jax.random.split(jax.random.key(56), 6)
+    x, bm, cm, ct = (jax.random.normal(k, s, jnp.float32) for k, s in zip(
+        keys, ((1, seq, h, p), (1, seq, n), (1, seq, n), (1, seq, h, p))))
+    # the cell's seeds: steps of 0.01-0.15 a token under A in (-16, -1)
+    dt = jax.random.uniform(keys[4], (1, seq, h), jnp.float32, 0.01, 0.15)
+    a_log = jnp.log(jax.random.uniform(keys[5], (h,), jnp.float32, 1., 16.))
+
+    def run(mdt, kernels):
+        def scan(*v):
+            return state_space_scan(v[0], v[1], -jnp.exp(v[2]), v[3], v[4],
+                                    chunk, mdt, kernels=kernels)[0]
+
+        def both(*v):
+            y, pull = jax.vjp(scan, *v)
+            return (y,) + pull(ct)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(both)(x, dt, a_log, bm, cm)
+
+    def far(got, want):
+        return [float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w)))
+                for g, w in zip(got, want)]
+
+    events.enable()
+    events.clear()
+    by_kernels = run(jnp.bfloat16, True)
+    said = [ev["attrs"]["kernel"] for ev in events.events()
+            if ev["name"] == "ssm.kernel"]
+    events.clear()
+    events.disable()
+    plain, truth = run(jnp.bfloat16, False), run(jnp.float32, False)
+    names = ("y", "d_x", "d_dt", "d_A_log", "d_B", "d_C")
+    k_err, p_err = far(by_kernels, truth), far(plain, truth)
+    READINGS["recurrence alone"] = {
+        "kernels against float32": dict(zip(names, k_err)),
+        "plain against float32": dict(zip(names, p_err)),
+        "kernels against plain": dict(zip(names, far(by_kernels, plain)))}
+    check("the shapes take the kernels, one instant a call",
+          ssm_kernels.takes_kernel(chunk, h, p, n)
+          and sorted(said) == ["bwd", "fwd"], str(said))
+    check(f"the recurrence alone over {seq} positions: the kernels' output "
+          f"is the plain path's", far(by_kernels[:1], plain[:1])[0] <= 1e-5,
+          f"{far(by_kernels[:1], plain[:1])[0]:.3e} of the largest entry")
+    for name, k, q in zip(names, k_err, p_err):
+        # (``A_log``'s gradient is a number a head, the sum over every
+        # token of terms of both signs: either path's rounding moves it
+        # by a few thousandths, seed by seed one more than the other)
+        check(f"the recurrence alone: {name} by the kernels",
+              k <= 2 * q + (1e-2 if name == "d_A_log" else 1e-4),
+              f"{k:.3e} of the largest entry from the float32 plain path; "
+              f"the bf16 plain path reads {q:.3e}")
 
 
 def forward_checks(conf, ref, seq, seeds, layer_alone):
@@ -257,6 +325,7 @@ def main():
     ap.add_argument("--skip-layer", action="store_true")
     ap.add_argument("--skip-forward", action="store_true")
     ap.add_argument("--skip-gradients", action="store_true")
+    ap.add_argument("--skip-kernels", action="store_true")
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         print("this validation needs a TPU", file=sys.stderr)
@@ -272,6 +341,9 @@ def main():
         jax.clear_caches()
     if not args.skip_gradients:
         gradient_checks(conf, ref, args.seeds[0], args.grad_seq)
+        jax.clear_caches()
+    if not args.skip_kernels:
+        kernel_check(conf, args.seq)
     print("READINGS " + json.dumps(READINGS), flush=True)
     print(f"{len(FAILED)} failed: {FAILED}" if FAILED else "all passed")
     return 1 if FAILED else 0

@@ -63,6 +63,7 @@ import numpy as np
 
 from ..core.tensor import WeightSpec
 from ..ffconst import InitializerType, OperatorType
+from ..kernels import state_space as ssm_kernels
 from ..kernels.gated_delta_rule import SUB, chunk_terms, takes_kernel
 from ..obs import events
 from .nn_ops import MultiHeadAttentionOp, _rms, short_conv
@@ -233,7 +234,7 @@ def _ssm_chunks(mdt, dtx, bm, cm, big_g):
 
 
 def state_space_scan(x, dt, a, bm, cm, chunk: int, mdt=jnp.float32, *,
-                     layer=None):
+                     layer=None, kernels=True):
     """The state-space recurrence of the module's docstring from a zero
     state, in chunks, without the ``D`` skip: ``x`` (B, T, H, P), ``dt``
     (B, T, H) > 0 the step size, ``a`` (H,) < 0, ``bm``, ``cm`` (B, T, N)
@@ -242,16 +243,25 @@ def state_space_scan(x, dt, a, bm, cm, chunk: int, mdt=jnp.float32, *,
     exponentials and the states are float32). Returns ``y`` (B, T, H, P)
     float32 and the most negative log-decay summed over one chunk.
 
-    What a chunk's own tokens give, and what it adds to the state, is
-    made for all chunks at once (:func:`_ssm_chunks`), rematerialised:
-    ``L`` is a ``C x C`` float32 matrix a head a chunk, and the backward
-    pass makes it again instead of holding it. One ``lax.scan`` over the
-    chunks then carries the state, ``S <- exp(G_C) S + added``, and keeps
-    the state each chunk starts from (M of them: what the scan stacks is
-    ``P x N`` a head a chunk, not a chunk's outputs); those states are
-    read by one product for all chunks. A sequence that is no whole
-    number of chunks is padded with positions that write nothing and
-    decay nothing (``dt`` 0)."""
+    Where the shapes take them (``ssm_kernels.takes_kernel``: a chunk in
+    whole tiles of 128, the state and ``H P`` in whole lanes of whole
+    heads) the chunks run in the Pallas kernels of
+    ``kernels/state_space.py``, forward and backward under one
+    ``custom_vjp`` that keeps its five inputs and the chunk-boundary
+    states: ``L``, a ``C x C`` float32 matrix a head a chunk, exists a
+    128 x 128 tile at a time in VMEM, and a block of heads' state rides
+    there from chunk to chunk (``layer`` names the caller in their
+    ``ssm.kernel`` instants). Every other shape, and a caller that says
+    ``kernels=False`` (a mesh of several devices), takes plain JAX: what
+    a chunk's own tokens give, and what it adds to the state, is made
+    for all chunks at once (:func:`_ssm_chunks`), rematerialised, so the
+    backward pass makes ``L`` again instead of holding it; one
+    ``lax.scan`` over the chunks then carries the state, ``S <- exp(G_C)
+    S + added``, and keeps the state each chunk starts from (M of them:
+    what the scan stacks is ``P x N`` a head a chunk, not a chunk's
+    outputs); those states are read by one product for all chunks. A
+    sequence that is no whole number of chunks is padded with positions
+    that write nothing and decay nothing (``dt`` 0)."""
     t = x.shape[1]
     f32 = jnp.float32
 
@@ -260,24 +270,37 @@ def state_space_scan(x, dt, a, bm, cm, chunk: int, mdt=jnp.float32, *,
 
     dt_c, cm_c = in_chunks(dt), in_chunks(cm)
     big_g = jnp.cumsum(dt_c * a.astype(f32), axis=2)    # (B, M, C, H)
-    inside, added = checkpointed(
-        lambda *v: _ssm_chunks(mdt, *v), site="ssm.chunk", layer=layer)(
-        in_chunks(x) * dt_c[..., None], in_chunks(bm), cm_c, big_g)
-    whole = jnp.exp(big_g[:, :, -1])                    # (B, M, H)
+    if kernels and ssm_kernels.takes_kernel(chunk, *x.shape[2:],
+                                            bm.shape[-1]):
+        def whole(v):           # (B, M, C, ..) -> (B, M C, ..)
+            return v.reshape((v.shape[0], -1) + v.shape[3:])
 
-    def step(state, now):       # the state a chunk starts from, stacked
-        keeps, adds = now
-        return keeps[..., None, None] * state + adds, state
+        def last(v):            # .. -> (B, .., M C): tokens along lanes
+            return jnp.moveaxis(whole(v), 1, -1)
 
-    _, starts = jax.lax.scan(
-        step, jnp.zeros(added.shape[:1] + added.shape[2:], f32),
-        (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(added, 1, 0)))
-    before = jnp.einsum("bmin,mbhpn->bmihp", cm_c.astype(mdt),
-                        starts.astype(mdt), preferred_element_type=f32) \
-        * jnp.exp(big_g)[..., None]
-    y = inside + before                                 # (B, M, C, H, P)
-    y = y.reshape((y.shape[0], -1) + y.shape[3:])[:, :t]
-    return y, jax.lax.stop_gradient(jnp.min(big_g[:, :, -1]))
+        y, _ = ssm_kernels.scan_chunks(
+            last(in_chunks(x)), last(dt_c), last(big_g),
+            whole(in_chunks(bm)), whole(cm_c), chunk, mdt, layer=layer)
+        y = jnp.moveaxis(y, -1, 1)                      # (B, M C, H, P)
+    else:
+        inside, added = checkpointed(
+            lambda *v: _ssm_chunks(mdt, *v), site="ssm.chunk", layer=layer)(
+            in_chunks(x) * dt_c[..., None], in_chunks(bm), cm_c, big_g)
+        whole = jnp.exp(big_g[:, :, -1])                # (B, M, H)
+
+        def step(state, now):   # the state a chunk starts from, stacked
+            keeps, adds = now
+            return keeps[..., None, None] * state + adds, state
+
+        _, starts = jax.lax.scan(
+            step, jnp.zeros(added.shape[:1] + added.shape[2:], f32),
+            (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(added, 1, 0)))
+        before = jnp.einsum("bmin,mbhpn->bmihp", cm_c.astype(mdt),
+                            starts.astype(mdt), preferred_element_type=f32) \
+            * jnp.exp(big_g)[..., None]
+        y = inside + before                             # (B, M, C, H, P)
+        y = y.reshape((y.shape[0], -1) + y.shape[3:])
+    return y[:, :t], jax.lax.stop_gradient(jnp.min(big_g[:, :, -1]))
 
 
 def _unit(x):
@@ -483,10 +506,15 @@ class StateSpaceMixerOp(OpDef):
     No bias but the convolution's. The two projections and the
     recurrence's products are at the compute dtype with float32
     accumulation; the taps, softplus, log-decays and their exponentials,
-    ``L``, the state and the norm are float32. Plain JAX on XLA, its
-    backward autodiff's; the scan over the chunks runs under the name
-    scope ``ssm.scan``. Training and evaluation only: there is no decode
-    path that carries the state from call to call."""
+    ``L``, the state and the norm are float32. The recurrence (the
+    chunks' terms, by the kernels of ``kernels/state_space.py`` on one
+    device at a chunk in whole tiles of 128 and ``H P`` and ``N`` in
+    whole lanes, which the published 256, 64 x 64 and 128 are; the scan
+    over the chunk states and the product that reads them; not the
+    projections) runs under the name scope ``ssm.scan``, the kernels'
+    backward too; everything else is plain JAX on XLA, its backward
+    autodiff's. Training and evaluation only: there is no decode path
+    that carries the state from call to call."""
     op_type = OperatorType.OP_STATE_SPACE_MIXER
     keeps_output_for_block = True   # ``emit``: the layer is one checkpoint
 
@@ -527,11 +555,19 @@ class StateSpaceMixerOp(OpDef):
         h, p, n = params["num_heads"], params["head_dim"], params["state"]
         chunk, inner = int(params["chunk"]), h * p
         b, t = u.shape[:2]
+        # the chunks' terms by the kernels where the shapes take them,
+        # on one device (under a mesh the plain path, which GSPMD
+        # partitions like any other XLA op)
+        mesh = getattr(ctx, "mesh", None)
+        kernels = mesh is None or mesh.size == 1
         if events.enabled():
             events.instant("ssm.layer", layer=name, heads=h, head_dim=p,
                            state=n, groups=1, taps=params["taps"],
                            tokens=b * t, chunk=chunk,
-                           chunks=-(-t // chunk))
+                           chunks=-(-t // chunk),
+                           impl="kernel" if kernels
+                           and ssm_kernels.takes_kernel(chunk, h, p, n)
+                           else "plain")
 
         # The layer is rematerialised whole, as the gated delta rule is
         # and for its reasons: what it keeps for the backward pass is
@@ -547,15 +583,18 @@ class StateSpaceMixerOp(OpDef):
             xbc = jax.nn.silu(short_conv(xbc, w["conv_w"].astype(f32))
                               + w["conv_b"].astype(f32))
             x, bm, cm = jnp.split(xbc, [inner, inner + n], -1)
-            x = x.reshape(b, t, h, p)
             dt = jax.nn.softplus(dt + w["dt_bias"].astype(f32))
             with jax.named_scope("ssm.scan"):
                 y, least = state_space_scan(
-                    x, dt, -jnp.exp(w["A_log"].astype(f32)), bm, cm, chunk,
-                    mdt, layer=name)
-            y = y + w["D"].astype(f32)[:, None] * x
-            y = _rms(y.reshape(b, t, inner) * jax.nn.silu(z), w["norm"],
-                     params["eps"])
+                    x.reshape(b, t, h, p), dt,
+                    -jnp.exp(w["A_log"].astype(f32)), bm, cm, chunk, mdt,
+                    layer=name, kernels=kernels)
+            # the skip on the channels as they lie, a head's ``D`` along
+            # its own: a (T, H, P) view of x or y between the projection
+            # and the kernels is a copy of either on the chip (P is 64,
+            # half a vector's lanes)
+            y = y.reshape(b, t, inner) + jnp.repeat(w["D"].astype(f32), p) * x
+            y = _rms(y * jax.nn.silu(z), w["norm"], params["eps"])
             return jnp.einsum("btc,ce->bte", y.astype(mdt),
                               w["out_proj"].astype(mdt),
                               preferred_element_type=f32), least

@@ -626,10 +626,14 @@ def test_the_layer_says_its_sizes_and_the_scan_has_its_scope():
         events.disable()
         events.clear()
     assert len(said) == 5
+    # (chunks of 16 and a state of 8: the plain path, which is what the
+    # kernels of ``kernels/state_space.py`` are held to; the published
+    # shape's own instant and scopes are in tests/test_tpu_aot_compile.py
+    # and tests/test_state_space_kernel.py)
     assert {k: said[0][k] for k in ("heads", "head_dim", "state", "groups",
-                                    "chunk", "chunks")} == {
+                                    "chunk", "chunks", "impl")} == {
         "heads": 4, "head_dim": 16, "state": 8, "groups": 1, "chunk": 16,
-        "chunks": 3}
+        "chunks": 3, "impl": "plain"}
     assert "mamba_0/remat.ssm.layer/" in text.replace("checkpoint/", "") \
         and "ssm.scan" in text and "remat.ssm.chunk" in text
 

@@ -1091,17 +1091,23 @@ def test_the_grouped_kernels_compile_at_cell_9s_shapes_with_its_scale(
 
 
 def test_the_state_space_mixer_compiles_at_the_published_width(
-        v5e_devices):
+        v5e_devices, monkeypatch):
     """One mixer's forward and backward at 2048 -> 64 heads of 64 x 128
     over 4,096 positions in 16 chunks of 256, bf16 operands, compiled
-    for a described v5e: plain XLA (no Mosaic call), the scan over the
-    chunk states a ``while`` under ``ssm.scan``, and the layer's
-    temporaries stay far under what 64 heads' ``L`` held for every chunk
-    of a forward AND a backward pass at once would take."""
+    for a described v5e: the recurrence is two Mosaic calls (the forward
+    in the layer's second run, the backward; this loss reads no value of
+    the first run), both under ``ssm.scan``, each with a cost estimate;
+    no ``(256, 256)`` array a head is in the compiled text, forward or
+    backward (``L`` for all chunks was 256 MiB in float32), no ``while``
+    carries the chunk states and nothing the size of ``x`` is copied or
+    turned on its way into or out of the calls; the layer's temporaries
+    are the projection and a few arrays the size of ``x``."""
     from flexflow_tpu import FFConfig
     from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.kernels import state_space
     from flexflow_tpu.ops.recurrent_ops import StateSpaceMixerOp
     from flexflow_tpu.ops.registry import EmitCtx
+    monkeypatch.setattr(state_space, "pallas_interpret", lambda: False)
     params = {"num_heads": 64, "head_dim": 64, "state": 128, "taps": 4,
               "chunk": 256, "eps": 1e-5}
     op = StateSpaceMixerOp()
@@ -1116,12 +1122,47 @@ def test_the_state_space_mixer_compiles_at_the_published_width(
                        EmitCtx(training=True, config=FFConfig()), "mamba_0")
         return jnp.sum(y)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w)
+    assert lowered.as_text().count("cost_estimate") == 2
+    compiled = lowered.compile()
     txt = compiled.as_text()
-    assert MOSAIC_CALL not in txt
-    assert "ssm.scan" in txt and "remat.ssm.chunk" in txt \
-        and "remat.ssm.layer" in txt and " while(" in txt
-    # L for all chunks is 256 MiB in float32; the layer's peak holds a
-    # few arrays of that size while the chunks' backward runs, not a
-    # forward's and a backward's worth of them beside each other
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+    calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+    assert _kernel_names(txt) == ["state_space_bwd", "state_space_fwd"]
+    assert all("ssm.scan" in l for l in calls)
+    assert "remat.ssm.chunk" not in txt and "remat.ssm.layer" in txt \
+        and " while(" not in txt
+    # a (chunk, chunk) matrix a head: more than the 16 chunks' one C B^T
+    square = re.findall(r"\b(?:f32|bf16)\[([0-9,]*),256,256\]", txt)
+    assert not [s for s in square
+                if np.prod([int(v) for v in s.split(",")]) > 16]
+    # x lies tokens last as the projection writes it, and the calls read
+    # it so: no copy or transpose of 4,096 x 4,096 float32
+    assert not re.search(
+        r"= f32\[1,4096,4096\]\S* (copy|transpose)\(", txt)
+    # the compile reads 0.479 GiB (the plain path's was held under 3)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * 2 ** 30
+
+
+@pytest.mark.parametrize("mdt", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_the_state_space_kernels_compile_under_a_default_of_highest(
+        v5e_devices, mdt):
+    """Both kernels alone at cell 9's shape with
+    ``jax_default_matmul_precision`` at ``highest`` around the call, as
+    a validation that compares against float32 sets it: the kernels name
+    their products' precision themselves (Mosaic refused a bf16 operand
+    at ``highest``, ``Bad lhs type``, when they left it to the default)."""
+    from flexflow_tpu.kernels import state_space
+    b, h, p, t, n, c = 1, 64, 64, 4096, 128, 256
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
+            for s in ((b, h, p, t), (b, h, t), (b, h, t), (b, t, n),
+                      (b, t, n))]
+
+    def loss(*a):
+        y, _ = state_space.scan_chunks(*a, c, mdt, interpret=False)
+        return jnp.sum(y * y)
+
+    with jax.default_matmul_precision("highest"):
+        txt = _compile_text(jax.grad(loss, argnums=range(5)), *args)
+    assert _kernel_names(txt) == ["state_space_bwd", "state_space_fwd"]

@@ -1164,6 +1164,74 @@ def leg_ssm_hybrid(model_cfg, seq: int, per_chip_batch: int, label: str,
     _compiled_step_size(ff, x, y, label)
 
 
+# ----------------------------------------------------------------------
+# Leg J — delta rules with a decay a head beside a gated attention layer
+# ----------------------------------------------------------------------
+VALIDATION_GDN = "examples/tpu_validate_gdn_gated_moe.py"
+
+
+def leg_gdn_gated_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
+                      alpha: float = 1e-5) -> None:
+    """``build_hybrid_conv_moe`` with ``"linear_attention"`` layers (a
+    decay a head, fewer key heads than value heads) through compile and
+    fit with ``remat = "blocks"``: the loss falls, every layer is one
+    block of the rematerialised run and every linear layer's output is
+    kept by its block, each linear layer announced its heads and the
+    attention layer the part of a head it turns and the path it took
+    (the plain chain), the experts the shared expert's gate, the
+    counters give a log-decay below zero, nothing was dropped, and the
+    step fits the chip. ``VALIDATION_GDN`` holds the recurrence and the
+    gradients to the token-by-token reference, and this leg names it."""
+    import jax
+
+    from flexflow_tpu.models.nlp import build_hybrid_conv_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    _check_kept_outputs(label, ff)
+    kinds = list(model_cfg.layer_types)
+    check(ff.executor._remat[2] == len(kinds),
+          f"{label}: {ff.executor._remat[2]} blocks for {len(kinds)} layers")
+    said = {name: sorted({e["attrs"]["layer"]: e["attrs"]
+                          for e in events.events()
+                          if e["name"] == name}.items())
+            for name in ("gdn.scan", "attn.qk_norm", "moe.route")}
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: instants " + "; ".join(
+        f"{n} {[k for k, _ in v]}" for n, v in said.items())
+        + f"; resolved {sorted(set(impls.values()))} in {len(impls)} layers")
+    turned = int((model_cfg.head_dim or 0) * model_cfg.partial_rotary_factor)
+    check([k for k, _ in said["gdn.scan"]] == sorted(
+        f"linear_attn_{i}" for i, k in enumerate(kinds)
+        if k == "linear_attention")
+        and all(a["key_heads"] == model_cfg.linear_num_key_heads
+                and a["value_heads"] == model_cfg.linear_num_value_heads
+                and a["chunks"] == -(-seq // a["chunk"])
+                for _, a in said["gdn.scan"])
+        and all(a.get("rotary_dim") == turned and a["impl"] == "xla"
+                for _, a in said["attn.qk_norm"])
+        and all(a.get("shared_gate") for _, a in said["moe.route"]),
+        f"{label}: what the layers announced is not the "
+        f"configuration's: {said}")
+    ctr = events.counters()
+    scans = ctr.get("gdn.scans", 0)
+    least = ctr.get("gdn.log_decay_min", 0) / max(1.0, scans)
+    say(f"{label}: a layer's most negative in-chunk log-decay {least:.2f} "
+        f"on average over {scans:.0f} layer-steps; the shared experts' "
+        f"gates {ctr.get('moe.shared_gate_mean', 0):.2f} summed")
+    check(scans > 0 and least < 0.0, f"{label}: gdn counters {ctr}")
+    _check_experts_counters(label)
+    if chip:
+        check(set(impls.values()) == {"flash"},
+              f"{label}: attention resolved to {impls} at seq {seq}")
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: the recurrence and the gradients "
+        f"against the reference: python3 {VALIDATION_GDN}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def main() -> int:
     import jax
     devs = jax.devices()
@@ -1180,6 +1248,7 @@ def main() -> int:
                                          KeyeRankConfig,
                                          KimiLinearRankConfig,
                                          LatentMoEConfig, LFM2RankConfig,
+                                         Qwen3NextRankConfig,
                                          TrinityRankConfig, XingRankConfig)
     from flexflow_tpu.utils.compilation_cache import (
         cache_entries, enable_compilation_cache)
@@ -1227,6 +1296,9 @@ def main() -> int:
             GraniteHybridRankConfig.tiny(), mamba_chunk_size=256), 1024, 1,
             "I/small", alpha=1e-3)
         leg_ssm_hybrid(GraniteHybridRankConfig(), 4096, 1, "I/granite")
+        leg_gdn_gated_moe(Qwen3NextRankConfig.tiny(), 1024, 1, "J/small",
+                          alpha=1e-3)
+        leg_gdn_gated_moe(Qwen3NextRankConfig(), 8192, 1, "J/qwen3next")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

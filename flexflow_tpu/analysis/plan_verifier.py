@@ -409,8 +409,8 @@ def _check_stream_axes(report, axis_sizes, layer, spec) -> None:
 def _check_conv_sequence(report, axis_sizes, layer, spec) -> None:
     """Three kinds of layer read what lies before a position: a gated
     short convolution its ``taps - 1`` predecessors, a gated delta rule
-    and a state-space mixer those AND the state every earlier position
-    left. Batch- and
+    (with a decay a channel or a head) and a state-space mixer those AND
+    the state every earlier position left. Batch- and
     channel- (head-) sharded layouts are local; a sequence-sharded one
     needs a halo exchange, and for the delta rule each shard's final
     state handed to the next, which no layer here emits and no cost row

@@ -417,6 +417,11 @@ class GraphProgram:
 # a kind no older layout holds may be named here (their runs, and so
 # their steps, stay the ones they were).
 _MIXES_LIKE = {OperatorType.OP_STATE_SPACE_MIXER:
+               OperatorType.OP_MULTIHEAD_ATTENTION,
+               # beside multi-head attention only in the layout of PR 57
+               # (three linear layers to one full layer); the older
+               # layout that holds it has latent attention for the rest
+               OperatorType.OP_GATED_DELTA_RULE:
                OperatorType.OP_MULTIHEAD_ATTENTION}
 
 
@@ -431,9 +436,10 @@ def _find_remat_blocks(layers):
     blocks are the same where their ops and their outputs' shapes are:
     a period of attention layers that differ in window, rotary
     embedding or positions is a run of blocks all the same, and so is
-    a period of state-space mixers with one attention layer among them
-    (``_MIXES_LIKE``: nine layers of ten would otherwise be two runs,
-    and the shorter one held whole). Returns
+    a period of state-space mixers or gated delta rules with one
+    attention layer among them (``_MIXES_LIKE``: nine layers of ten, or
+    three of four, would otherwise be two runs, and the shorter one held
+    whole). Returns
     ``(start, unit, reps, entry_guids, exit_guids)`` or None."""
     from .parallel.pipeline_lowering import (_has_state, chunk_boundaries,
                                              find_repeated_run)

@@ -232,6 +232,8 @@ class FFModel:
                             indexer: Optional[dict] = None,
                             output_gate: bool = False,
                             sm_scale: Optional[float] = None,
+                            rotary_dim: Optional[int] = None,
+                            qk_norm_zero_centered: bool = False,
                             name: Optional[str] = None) -> Tensor:
         """Multi-head attention (``ops.nn_ops.MultiHeadAttentionOp``):
         ``num_heads`` query heads of ``kdim / num_heads`` on
@@ -253,7 +255,14 @@ class FFModel:
         ``sm_scale``: what the scores are multiplied by before the
         softmax (None: ``1 / sqrt(head size)``; a model that publishes
         its own multiplier gives it here; not built beside an indexer
-        or on the ring path). ``indexer``: learned sparse attention
+        or on the ring path). ``rotary_dim`` (None: the head size): the
+        rotary embedding turns the first ``rotary_dim`` entries of each
+        head among themselves and passes the rest (a model's
+        ``partial_rotary_factor`` times its head size; such a layer
+        keeps the plain chain, not ``kernels/qk_norm_rope``; not built
+        beside an indexer or on the ring path).
+        ``qk_norm_zero_centered``: the q/k norms multiply by ``1 + w``
+        with ``w`` drawn at 0. ``indexer``: learned sparse attention
         (below)."""
         params = {"embed_dim": embed_dim, "num_heads": num_heads,
                   "kdim": kdim, "vdim": vdim, "dropout": dropout,
@@ -288,6 +297,20 @@ class FFModel:
             # learned scale a projection, before the rotary embedding
             params["qk_norm"] = True
             params["qk_norm_eps"] = float(qk_norm_eps)
+            if qk_norm_zero_centered:
+                params["qk_norm_zero_centered"] = True
+        elif qk_norm_zero_centered:
+            raise ValueError("qk_norm_zero_centered is read by "
+                             "qk_norm=True only")
+        if rotary_dim is not None:
+            head = (kdim or embed_dim) // num_heads
+            if not rope or indexer or rotary_dim % 2 \
+                    or not 0 < rotary_dim <= head:
+                raise ValueError(
+                    f"rotary_dim {rotary_dim}: an even share of a head of "
+                    f"{head}, on a rope=True layer with no indexer")
+            if rotary_dim != head:
+                params["rotary_dim"] = int(rotary_dim)
         if indexer:
             # learned sparse attention (``ops/sparse_attention``): each
             # query attends the ``topk`` keys its index scores select
@@ -332,20 +355,41 @@ class FFModel:
 
     def gated_delta_rule(self, input: Tensor, num_heads: int,
                          head_dim: int, taps: int, eps: float = 1e-5,
+                         num_key_heads: Optional[int] = None,
+                         decay: str = "channel",
                          name: Optional[str] = None) -> Tensor:
         """A gated delta-rule linear-attention layer
         (``ops.recurrent_ops.GatedDeltaRuleOp``): ``num_heads`` heads of
         ``head_dim``, q, k and v each through a causal depthwise
-        convolution of ``taps`` positions, a decay a channel and a step
-        size a head, a gated RMSNorm (``eps``) before the output
-        projection."""
+        convolution of ``taps`` positions, a step size a head, a gated
+        RMSNorm (``eps``) before the output projection. ``decay``:
+        ``"channel"`` (Kimi Delta Attention's form: a decay a channel
+        from a low-rank pair, a low-rank sigmoid gate) or ``"head"``
+        (Gated DeltaNet's: one scalar a head-token, a full-rank SiLU
+        gate), where ``num_key_heads`` heads of q and k (default: as
+        many) may serve ``num_heads`` heads of v, each ``num_heads /
+        num_key_heads`` consecutive ones."""
         if taps < 1 or num_heads < 1 or head_dim < 1:
             raise ValueError(f"{num_heads} heads of {head_dim} behind "
                              f"convolutions of {taps} taps")
+        more = {}
+        if decay == "head":
+            more["decay"] = "head"
+            if num_key_heads and num_key_heads != num_heads:
+                if num_heads % num_key_heads:
+                    raise ValueError(
+                        f"{num_key_heads} key heads do not divide "
+                        f"{num_heads} value heads")
+                more["num_key_heads"] = int(num_key_heads)
+        elif decay != "channel" or num_key_heads not in (None, num_heads):
+            raise ValueError(
+                f"decay {decay!r} with {num_key_heads} key heads: a decay "
+                f"a 'channel' (as many key heads as value heads) or a "
+                f"'head'")
         return self._unary(OperatorType.OP_GATED_DELTA_RULE, input, name,
                            num_heads=int(num_heads),
                            head_dim=int(head_dim), taps=int(taps),
-                           eps=float(eps))
+                           eps=float(eps), **more)
 
     def state_space_mixer(self, input: Tensor, num_heads: int,
                           head_dim: int, state: int, taps: int,
@@ -450,6 +494,8 @@ class FFModel:
                        first_held: int = 0, scale: float = 1.0,
                        bias_std: float = 0.0, rows_factor: int = 2,
                        scoring: str = "sigmoid",
+                       shared_gate: bool = False,
+                       choice_bias: bool = True,
                        name: Optional[str] = None) -> Tensor:
         """One sparse, dropless mixture-of-experts feed-forward layer
         (``ops.moe_ops.RoutedExpertsOp``): ``scoring`` (``"sigmoid"``
@@ -461,7 +507,10 @@ class FFModel:
         and computes the part of the result that its own give.
         ``rows_factor``: the rows the grouped products are handed, in
         uniform shares of the held experts (``RoutedExpertsOp.
-        rows_multiplied``)."""
+        rows_multiplied``). ``shared_gate``: the shared expert's output
+        times ``sigmoid(x . w_s)``, one scalar a token from a weight of
+        its own. ``choice_bias`` false: the op has no ``bias`` weight
+        (softmax scores only, whose choice reads none)."""
         held = num_experts if experts_held is None else experts_held
         if not 0 <= first_held <= first_held + held <= num_experts:
             raise ValueError(
@@ -478,6 +527,15 @@ class FFModel:
         more = {} if rows_factor == 2 else {"rows_factor": int(rows_factor)}
         if scoring != "sigmoid":
             more["scoring"] = scoring
+        if shared_gate:
+            if not shared_dim:
+                raise ValueError("shared_gate without a shared expert")
+            more["shared_gate"] = True
+        if not choice_bias:
+            if scoring != "softmax":
+                raise ValueError(f"scores by {scoring!r} read a choice "
+                                 f"bias")
+            more["choice_bias"] = False
         return self._unary(OperatorType.OP_ROUTED_EXPERTS, input, name,
                            num_experts=num_experts, top_k=top_k,
                            expert_dim=expert_dim, shared_dim=shared_dim,
@@ -510,8 +568,13 @@ class FFModel:
                            elementwise_affine=elementwise_affine, eps=eps)
 
     def rms_norm(self, input: Tensor, eps: float = 1e-6,
-                 name: Optional[str] = None) -> Tensor:
-        return self._unary(OperatorType.OP_RMSNORM, input, name, eps=eps)
+                 name: Optional[str] = None,
+                 zero_centered: bool = False) -> Tensor:
+        """``x / rms(x) * scale``; ``zero_centered``: ``* (1 + scale)``
+        with ``scale`` drawn at 0."""
+        more = {"zero_centered": True} if zero_centered else {}
+        return self._unary(OperatorType.OP_RMSNORM, input, name, eps=eps,
+                           **more)
 
     def lstm(self, input: Tensor, hidden_size: int, num_layers: int = 1,
              name: Optional[str] = None) -> Tensor:

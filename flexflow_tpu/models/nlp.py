@@ -1341,6 +1341,103 @@ class GraniteHybridRankConfig(HybridConvMoEConfig):
                    mamba_chunk_size=16)
 
 
+@dataclasses.dataclass
+class Qwen3NextRankConfig(HybridConvMoEConfig):
+    """What ONE chip holds of Qwen3-Next-80B-A3B (``model_type:
+    qwen3_next``, 80B parameters, 3B a token) where 16 chips share each
+    layer (the benchmark's ``qwen3_next_80b_a3b``): layer ``i`` is
+    ``"full_attention"`` where ``(i + 1) % full_attention_interval ==
+    0`` and ``"linear_attention"`` otherwise. A linear layer is a gated
+    delta rule with a decay a HEAD (Gated DeltaNet): ``linear_num_key_
+    heads`` heads of q and k serve ``linear_num_value_heads`` heads of v,
+    each through a convolution of ``linear_conv_kernel_dim`` taps, a
+    full-rank SiLU gate on the normed output. A full layer is
+    grouped-query attention (16 query heads on 2 key/value heads of 256)
+    with zero-centred q/k norms, a rotary embedding over the first
+    ``partial_rotary_factor`` of each head and a sigmoid output gate.
+    Every layer has 512 softmax-routed experts of 512, 10 a token, gates
+    normalised, beside a shared expert of ``shared_expert_intermediate_
+    size`` times ``sigmoid(x . w_s)``. Every norm but the linear layer's
+    gated one multiplies by ``1 + w``. Here: experts 0 to 31, one of
+    eight slices of the vocabulary, and published layers 0 to 3 (one
+    whole period); every width as published.
+
+    The fields after the parent's carry ``config.json``'s keys by their
+    names; ``rms_norm_eps`` and ``rope_theta`` are copied over the
+    parent's names for them and ``layer_types`` is laid out from
+    ``full_attention_interval``. The last three are forms of the
+    model's published code that ``config.json`` has no key for."""
+    vocab_size: int = 18992
+    num_hidden_layers: int = 4
+    layer_types: list | None = None      # from full_attention_interval
+    num_dense_layers: int = 0
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 2
+    head_dim: int | None = 256
+    intermediate_size: int = 5120        # published; no dense layer reads it
+    moe_intermediate_size: int = 512
+    num_experts: int = 32
+    num_experts_published: int | None = 512
+    num_experts_per_tok: int = 10
+    use_expert_bias: bool = False
+    # the keys the parent class does not have
+    full_attention_interval: int = 4
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    partial_rotary_factor: float = 0.25
+    shared_expert_intermediate_size: int = 512
+    decoder_sparse_step: int = 1
+    mlp_only_layers: list = dataclasses.field(default_factory=list)
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000000.0
+    scoring_func: str = "softmax"        # not in config.json: the family's
+    # not in config.json: modeling_qwen3_next.py's forms
+    attention_output_gate: bool = True   # o * sigmoid(x Wg), before Wo
+    zero_centered_norms: bool = True     # x / rms(x) * (1 + w), w from 0
+    shared_expert_gate: bool = True      # E_shared(x) * sigmoid(x . w_s)
+    # rows the experts' products are handed, in uniform shares of the
+    # held experts: in training the routers learn within tens of steps to
+    # prefer the experts whose output is not left out, and a layer's held
+    # share of the assignments triples in 90 steps (PERF.md section 6, PR
+    # 57); 2 shares, enough at a seed's weights, overflow from step 63
+    expert_rows_factor: int = 6
+
+    def __post_init__(self):
+        if self.decoder_sparse_step != 1 or self.mlp_only_layers \
+                or self.linear_key_head_dim != self.linear_value_head_dim:
+            raise ValueError(
+                "a layer without experts and linear-attention key heads of "
+                "another size than the value heads' are not built for this "
+                "family")
+        kinds = ["full_attention" if (i + 1) % self.full_attention_interval
+                 == 0 else "linear_attention"
+                 for i in range(self.num_hidden_layers)]
+        if self.layer_types is None:
+            self.layer_types = kinds
+        elif list(self.layer_types) != kinds:
+            raise ValueError(f"layer_types {self.layer_types} are not "
+                             f"full_attention_interval's {kinds}")
+        self.norm_eps = self.rms_norm_eps
+        self.rope_parameters = {"rope_theta": self.rope_theta,
+                                "rope_type": "default"}
+
+    @classmethod
+    def tiny(cls):
+        """One period (linear x 3, full): 2 key heads under 4 value
+        heads of 16, 4 attention heads on 2 kv heads of 16 of which 4
+        entries turn, 16 experts top-4 of width 32, all held: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_attention_heads=4,
+                   num_key_value_heads=2, head_dim=16, rope_theta=10000.0,
+                   moe_intermediate_size=32,
+                   shared_expert_intermediate_size=32, num_experts=16,
+                   num_experts_published=None, num_experts_per_tok=4,
+                   linear_num_key_heads=2, linear_num_value_heads=4,
+                   linear_key_head_dim=16, linear_value_head_dim=16)
+
+
 def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                           cfg: HybridConvMoEConfig | None = None):
     """Causal LM of :class:`HybridConvMoEConfig`: inputs ``[ids, pos]``,
@@ -1360,7 +1457,11 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     attention with no rotary embedding, no q/k norm and the scores'
     multiplier the configuration gives; the class also multiplies the
     embedding, each sub-layer's output before its add and the logits by
-    scalars of its own. ``pos`` is what the
+    scalars of its own. A ``"linear_attention"`` layer
+    (:class:`Qwen3NextRankConfig`) is a gated delta rule with a decay a
+    head; that class also turns only part of each head on its full
+    layers, gates their output and the shared expert, and multiplies
+    every norm by ``1 + w``. ``pos`` is what the
     attention layers' rotary embedding turns by (a layout in which no
     layer turns by it still declares it, and ``fit`` drops its array).
 
@@ -1371,11 +1472,13 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     kinds = list(cfg.layer_types)
     if len(kinds) != cfg.num_hidden_layers \
             or set(kinds) - {"conv", "full_attention", "sparse_attention",
-                             "sliding_attention", "mamba", "attention"}:
+                             "sliding_attention", "mamba", "attention",
+                             "linear_attention"}:
         raise ValueError(
             f"layer_types must name {cfg.num_hidden_layers} layers, each "
             f"'conv', 'full_attention', 'sparse_attention', "
-            f"'sliding_attention', 'mamba' or 'attention'; got "
+            f"'sliding_attention', 'mamba', 'attention' or "
+            f"'linear_attention'; got "
             f"{len(kinds)}: {sorted(set(kinds))}")
     if cfg.conv_bias or not cfg.norm_topk_prob:
         raise ValueError("conv_bias and gates that are not normalised "
@@ -1406,6 +1509,24 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     sandwich = getattr(cfg, "sandwich_norms", False)
     shared_dim = cfg.moe_intermediate_size \
         * getattr(cfg, "num_shared_experts", 0)
+    if "linear_attention" in kinds \
+            and not hasattr(cfg, "linear_num_value_heads"):
+        raise ValueError("a 'linear_attention' layer needs the "
+                         "configuration's linear_* sizes")
+    # the forms of a subclass (``Qwen3NextRankConfig``): absent, the
+    # graph is the one it was
+    centred = {"zero_centered": True} \
+        if getattr(cfg, "zero_centered_norms", False) else {}
+    if hasattr(cfg, "partial_rotary_factor"):
+        gated["rotary_dim"] = int(head_dim * cfg.partial_rotary_factor)
+    if centred:
+        gated["qk_norm_zero_centered"] = True
+    experts = dict(scoring)
+    if hasattr(cfg, "shared_expert_intermediate_size"):
+        shared_dim = cfg.shared_expert_intermediate_size
+        experts.update(shared_gate=cfg.shared_expert_gate,
+                       choice_bias=cfg.use_expert_bias,
+                       rows_factor=cfg.expert_rows_factor)
     if "mamba" in kinds and not hasattr(cfg, "mamba_n_heads"):
         raise ValueError("a 'mamba' layer needs the configuration's "
                          "mamba_* sizes")
@@ -1427,7 +1548,7 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                                name="embedding_multiplier")
 
     def norm(x, name):
-        return ff.rms_norm(x, eps=cfg.norm_eps, name=name)
+        return ff.rms_norm(x, eps=cfg.norm_eps, name=name, **centred)
 
     def scaled(x, name):
         return x if residual_scale is None \
@@ -1443,6 +1564,12 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                 cfg.mamba_d_conv, cfg.mamba_chunk_size,
                 groups=cfg.mamba_n_groups, eps=cfg.norm_eps,
                 name=f"mamba_{i}")
+        elif kind == "linear_attention":
+            op = ff.gated_delta_rule(
+                x, cfg.linear_num_value_heads, cfg.linear_value_head_dim,
+                cfg.linear_conv_kernel_dim, eps=cfg.norm_eps,
+                num_key_heads=cfg.linear_num_key_heads, decay="head",
+                name=f"linear_attn_{i}")
         elif kind == "attention":
             # no rotary embedding, no q/k norm, the model's own scale
             op = ff.multihead_attention(
@@ -1486,7 +1613,7 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                 first_held=cfg.first_held_expert,
                 scale=cfg.routed_scaling_factor,
                 bias_std=cfg.router_bias_std if cfg.use_expert_bias
-                else 0.0, name=f"experts_{i}", **scoring)
+                else 0.0, name=f"experts_{i}", **experts)
         if sandwich:
             y = norm(y, f"post_ffn_norm_{i}")
         h = ff.add(h, scaled(y, f"ffn_scale_{i}"), name=f"ffn_res_{i}")

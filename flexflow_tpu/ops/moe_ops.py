@@ -408,6 +408,12 @@ class RoutedExpertsOp(OpDef):
       S = top-k of (s + bias);  g_i = scale * s_i / sum_{j in S} s_j
       y = sum_{i in S, i held} g_i E_i(x)  +  E_shared(x)
 
+    With ``shared_gate`` in the parameters the shared expert's output is
+    multiplied by ``sigmoid(x . w_s)``, one scalar a token from a
+    weight of its own (``ws_scalar``, hidden x 1; the Qwen2-MoE / Qwen3-
+    Next family's). With ``choice_bias`` false (softmax scores, whose
+    choice reads none) the weight ``bias`` is not in the op's list.
+
     ``scoring`` in the parameters says which score function
     (:func:`route`): ``"sigmoid"`` where it is absent, the DeepSeek-V3
     family's, whose choice a bias corrects; ``"softmax"``, the Qwen3-MoE
@@ -448,18 +454,21 @@ class RoutedExpertsOp(OpDef):
         n, held = params["num_experts"], params["experts_held"]
         f, fs = params["expert_dim"], params["shared_dim"]
         up, down = {"fans": (e, f)}, {"fans": (f, e)}   # fans per expert
-        ws = [WeightSpec("wg", (e, n), dt),
-              # drawn once; corrects the choice, never trained
-              WeightSpec("bias", (n,), dt, InitializerType.NORMAL,
-                         {"stddev": params.get("bias_std", 0.0)},
-                         create_grad=False),
-              WeightSpec("w_gate", (held, e, f), dt, init_args=up),
-              WeightSpec("w_up", (held, e, f), dt, init_args=up),
-              WeightSpec("w_down", (held, f, e), dt, init_args=down)]
+        ws = [WeightSpec("wg", (e, n), dt)]
+        if params.get("choice_bias", True):
+            # drawn once; corrects the choice, never trained
+            ws.append(WeightSpec("bias", (n,), dt, InitializerType.NORMAL,
+                                 {"stddev": params.get("bias_std", 0.0)},
+                                 create_grad=False))
+        ws += [WeightSpec("w_gate", (held, e, f), dt, init_args=up),
+               WeightSpec("w_up", (held, e, f), dt, init_args=up),
+               WeightSpec("w_down", (held, f, e), dt, init_args=down)]
         if fs:
             ws += [WeightSpec("ws_gate", (e, fs), dt),
                    WeightSpec("ws_up", (e, fs), dt),
                    WeightSpec("ws_down", (fs, e), dt)]
+            if params.get("shared_gate"):
+                ws.append(WeightSpec("ws_scalar", (e, 1), dt))
         return ws
 
     @staticmethod
@@ -499,7 +508,9 @@ class RoutedExpertsOp(OpDef):
                            experts_held=held, first_held=first, top_k=k,
                            tokens=t, rows_budget=budget,
                            rows_multiplied=budget,
-                           token_sum="kernel" if kernel else "plain")
+                           token_sum="kernel" if kernel else "plain",
+                           **({"shared_gate": True}
+                              if "ws_scalar" in weights else {}))
             if kernel:
                 # ``_chunk`` is traced once a shape, so its calls are
                 # noted here, where the layer has a name: the forward's
@@ -517,7 +528,9 @@ class RoutedExpertsOp(OpDef):
         logits = jnp.dot(
             xt.astype(jnp.float32), weights["wg"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
-        idx, gates = route(logits, weights["bias"].astype(jnp.float32), k,
+        bias = weights["bias"].astype(jnp.float32) \
+            if "bias" in weights else None      # softmax scores read none
+        idx, gates = route(logits, bias, k,
                            float(params.get("scale", 1.0)),
                            params.get("scoring", "sigmoid"))
 
@@ -544,7 +557,16 @@ class RoutedExpertsOp(OpDef):
         if "ws_gate" in weights:
             g = matmul(xt, weights["ws_gate"], ctx=ctx)
             u = matmul(xt, weights["ws_up"], ctx=ctx)
-            y = y + matmul(jax.nn.silu(g) * u, weights["ws_down"], ctx=ctx)
+            shared = matmul(jax.nn.silu(g) * u, weights["ws_down"], ctx=ctx)
+            if "ws_scalar" in weights:
+                # one scalar a token, float32 as the router's scores are
+                opened = jax.nn.sigmoid(jnp.dot(
+                    xt.astype(jnp.float32),
+                    weights["ws_scalar"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST))
+                shared = shared * opened
+                ctx.count("moe.shared_gate_mean", jnp.mean(opened))
+            y = y + shared
 
         # what the router bound for this share, read from its choices,
         # against what the grouped products reached: an assignment is

@@ -324,7 +324,9 @@ def _rms(x, scale, eps):
 @register
 class RMSNormOp(OpDef):
     """RMSNorm — TPU-native addition (used by T5/LLaMA-style models; the
-    reference fuses T5LayerNorm patterns in its fx frontend)."""
+    reference fuses T5LayerNorm patterns in its fx frontend).
+    ``zero_centered`` in the parameters: ``x / rms(x) * (1 + scale)``
+    with ``scale`` drawn at 0 (the Qwen3-Next family's norm)."""
     op_type = OperatorType.OP_RMSNORM
 
     def infer(self, params, in_shapes, in_dtypes):
@@ -332,11 +334,16 @@ class RMSNormOp(OpDef):
 
     def weights(self, params, in_shapes, in_dtypes):
         return [WeightSpec("scale", (in_shapes[0][-1],), in_dtypes[0],
-                           InitializerType.ONE)]
+                           InitializerType.ZERO
+                           if params.get("zero_centered")
+                           else InitializerType.ONE)]
 
     def emit(self, params, inputs, weights, ctx, name):
         (x,) = inputs
-        y = _rms(x, weights["scale"], params.get("eps", 1e-6))
+        scale = weights["scale"]
+        if params.get("zero_centered"):
+            scale = 1.0 + scale
+        y = _rms(x, scale, params.get("eps", 1e-6))
         return [y.astype(x.dtype)]
 
 
@@ -441,10 +448,17 @@ class GatedShortConvOp(OpDef):
 
 
 # ---------------------------------------------------------------------------
-def _apply_rope(x, pos, theta: float):
+def _apply_rope(x, pos, theta: float, rotary_dim: int | None = None):
     """Rotary position embedding, LLaMA half-split-rotate convention.
     ``x``: (B, L, h, d) with d even; ``pos``: (L,) absolute indices
-    shared by the batch, or (B, L) per-row (ragged-prompt decode)."""
+    shared by the batch, or (B, L) per-row (ragged-prompt decode).
+    ``rotary_dim`` (None: the head): only the first ``rotary_dim``
+    entries of a head turn, in half-split pairs among themselves with
+    frequencies ``theta ** (-2 i / rotary_dim)``; the rest pass."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [_apply_rope(x[..., :rotary_dim], pos, theta),
+             x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     pf = pos.astype(jnp.float32)
@@ -496,11 +510,13 @@ class MultiHeadAttentionOp(OpDef):
                               InitializerType.ZERO),
                    WeightSpec("bo", (e,), dt, InitializerType.ZERO)]
         if params.get("qk_norm", False):
-            # one learned scale a projection, shared by its heads
-            ws += [WeightSpec("q_norm", (kdim // h,), dt,
-                              InitializerType.ONE),
-                   WeightSpec("k_norm", (kdim // h,), dt,
-                              InitializerType.ONE)]
+            # one learned scale a projection, shared by its heads; a
+            # zero-centred norm multiplies by 1 + w with w drawn at 0
+            at = InitializerType.ZERO \
+                if params.get("qk_norm_zero_centered") \
+                else InitializerType.ONE
+            ws += [WeightSpec("q_norm", (kdim // h,), dt, at),
+                   WeightSpec("k_norm", (kdim // h,), dt, at)]
         if params.get("output_gate", False):
             # an elementwise sigmoid gate on the attention's output, read
             # from the layer's input: a projection of its own, as wq is
@@ -629,8 +645,11 @@ class MultiHeadAttentionOp(OpDef):
         one device, and a path that hands q and k to the flash kernels
         heads-first (this method's last line is the test
         :meth:`emit` and :meth:`_emit_sparse` make). Every other layer
-        keeps ``_rms``, ``_apply_rope`` and its own turn."""
+        keeps ``_rms``, ``_apply_rope`` and its own turn; so does a
+        layer that turns PART of a head (``rotary_dim``): the kernel's
+        pairs are the whole head's halves."""
         if not (params.get("qk_norm", False) and params.get("rope", False)) \
+                or params.get("rotary_dim") is not None \
                 or getattr(ctx, "kv_mode", None) is not None \
                 or not params.get("causal", False) \
                 or qh.shape[1] != kh.shape[1] or qh.shape[2] % kh.shape[2]:
@@ -708,16 +727,24 @@ class MultiHeadAttentionOp(OpDef):
                 # embedding; ahead of the decode branch, so the cache holds
                 # normed (and rotated) keys
                 eps = params.get("qk_norm_eps", 1e-6)
+                q_scale, k_scale = weights["q_norm"], weights["k_norm"]
+                if params.get("qk_norm_zero_centered"):
+                    q_scale, k_scale = 1.0 + q_scale, 1.0 + k_scale
                 if not fused:
-                    qh = _rms(qh, weights["q_norm"], eps)
-                    kh = _rms(kh, weights["k_norm"], eps)
+                    qh = _rms(qh, q_scale, eps)
+                    kh = _rms(kh, k_scale, eps)
                 if events.enabled():
+                    # ``rotary_dim``: the entries of a head that turn
+                    # (absent from a layer that gives none: the head)
+                    turned = {} if params.get("rotary_dim") is None else {
+                        "rotary_dim": params["rotary_dim"]}
                     events.instant("attn.qk_norm", layer=name, heads=h,
                                    kv_heads=kh.shape[2],
                                    kv_group=heads // kh.shape[2],
                                    head_dim=qh.shape[-1],
                                    tokens=qh.shape[0] * qh.shape[1],
-                                   impl="kernel" if fused else "xla")
+                                   impl="kernel" if fused else "xla",
+                                   **turned)
 
             causal = params.get("causal", False)
             kv_mode = getattr(ctx, "kv_mode", None)
@@ -746,16 +773,24 @@ class MultiHeadAttentionOp(OpDef):
                 if fused:
                     from ..kernels import qk_norm_rope as nrk
                     tables = nrk.rope_tables(pos, qh.shape[-1], theta)
-                    qh = nrk.qk_norm_rope(qh, weights["q_norm"], tables,
+                    qh = nrk.qk_norm_rope(qh, q_scale, tables,
                                           eps=eps, dtype=mdt)
                     # k at its own heads: the flash kernels that follow
                     # read a group's k/v head in place
-                    kh = nrk.qk_norm_rope(kh, weights["k_norm"], tables,
+                    kh = nrk.qk_norm_rope(kh, k_scale, tables,
                                           eps=eps, dtype=mdt)
                     ctx.count("attn.norm_rope_kernel_layers", jnp.float32(1.0))
                 else:
-                    qh = _apply_rope(qh, pos, theta)
-                    kh = _apply_rope(kh, pos, theta)
+                    part = params.get("rotary_dim")
+                    if part is not None and (
+                            params.get("indexer_heads")
+                            or self._impl_for(ctx, name) == "ring"):
+                        raise ValueError(
+                            f"{name}: a rotary embedding over part of a "
+                            f"head (rotary_dim {part}) is built on the "
+                            f"flash, XLA and decode paths only")
+                    qh = _apply_rope(qh, pos, theta, part)
+                    kh = _apply_rope(kh, pos, theta, part)
         if params.get("indexer_heads") and (
                 kv_mode is not None or not causal or rate > 0.0
                 or params.get("sliding_window", 0)):

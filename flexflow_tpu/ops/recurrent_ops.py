@@ -35,6 +35,19 @@ chunk's spans together are as large as k and q), a sub-block against
 itself through the ``(SUB, SUB, d)`` tensor of differences, reduced on
 the spot.
 
+With a decay a HEAD (``g`` one scalar a head-token, Gated DeltaNet's
+form) the same equations hold with ``exp(g_t)`` a scalar, and the
+chunk's matrices are products after all:
+
+    A = (K K^T) * L,   B = (Q K^T) * L,   L_ij = exp(G_i - G_j)  (j <= i)
+
+one matrix product a head-chunk and one ``C x C`` matrix of differences
+(:func:`_chunk_terms_head`; what :func:`_ssm_chunks` builds): no
+halving, no ``(SUB, SUB, d)`` tensor, one exponential a pair of rows
+and not one a pair a channel. There a head of q and k may serve several
+heads of v (value head ``j`` reads q/k head ``j // group``): the raw
+products are made at the q/k heads and shared.
+
 The state-space (Mamba-2) recurrence: a head keeps a ``P x N`` state
 (``P`` its channels, ``N`` the state size) under a SCALAR decay a token,
 
@@ -139,6 +152,43 @@ def _chunk_terms(q, k, v, g, beta, mdt):
             jnp.exp(g_last[..., 0, :]), jnp.min(big_g))
 
 
+def _chunk_terms_head(q, k, v, g, beta, mdt):
+    """:func:`_chunk_terms` for a decay a head: ``g``, ``beta``: (B, H,
+    N, C) at ``v``'s heads; ``q``, ``k``: (B, H / group, N, C, dk), a
+    head of theirs read by ``group`` consecutive heads of ``v``. The
+    same seven terms, ``exp(G_C)`` as (B, H, N, 1)."""
+    group = v.shape[1] // k.shape[1]
+    n_c = k.shape[3]
+    big_g = jnp.cumsum(g, axis=3)
+
+    def prod(a, b):
+        return jnp.einsum("...id,...jd->...ij", a.astype(mdt),
+                          b.astype(mdt),
+                          preferred_element_type=jnp.float32)
+
+    def served(x):              # a q/k head's array at each head it serves
+        return jnp.repeat(x, group, axis=1) if group > 1 else x
+
+    # L: the differences themselves, a head; 0 above the diagonal
+    low = np.tril(np.ones((n_c, n_c), bool))
+    big_l = jnp.where(low, jnp.exp(jnp.where(
+        low, big_g[..., :, None] - big_g[..., None, :], 0.0)), 0.0)
+    a = jnp.where(np.tril(low, -1), served(prod(k, k)) * big_l, 0.0)
+    b = served(prod(q, k)) * big_l
+    q, k = served(q), served(k)
+    decay = jnp.exp(big_g)[..., None]
+    eye = jnp.eye(n_c, dtype=jnp.float32)
+    inverse = jax.lax.linalg.triangular_solve(
+        eye + beta[..., None] * a, jnp.broadcast_to(eye, a.shape),
+        left_side=True, lower=True, unit_diagonal=True)
+    w = prod(inverse, jnp.swapaxes(beta[..., None] * k * decay, -1, -2))
+    u0 = prod(inverse, jnp.swapaxes(beta[..., None] * v, -1, -2))
+    g_last = big_g[..., -1:]
+    return (w.astype(mdt), u0, b.astype(mdt), (q * decay).astype(mdt),
+            (k * jnp.exp(g_last - big_g)[..., None]).astype(mdt),
+            jnp.exp(g_last), jnp.min(big_g))
+
+
 def _chunk_step(mdt, state, terms):
     """One chunk given the state it starts from (B, H, dk, dv float32):
     its outputs (B, H, C, dv) and the state it leaves."""
@@ -174,6 +224,11 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
     T, dv) float32 and the most negative running sum of ``g`` inside any
     chunk.
 
+    ``g`` of (B, H, T), one scalar a head-token, is a decay a HEAD and
+    takes a path of its own (:func:`_chunk_terms_head`, plain JAX,
+    rematerialised: nothing is broadcast over the channels into the
+    channel kernels); ``q`` and ``k`` may then have ``H / group`` heads.
+
     The chunks' terms come from the Pallas kernels of
     ``kernels/gated_delta_rule.py`` where the shapes take them
     (:func:`takes_kernel`: a chunk of a power-of-two number of
@@ -187,7 +242,13 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
     the terms the scan reads and one chunk's matrices, not the ``(SUB,
     SUB, d)`` differences nor every chunk's intermediate products."""
     t = q.shape[2]
-    if takes_kernel(chunk, k.shape[-1], v.shape[-1]):
+    if g.ndim == 3:
+        *terms, least = checkpointed(
+            lambda *a: _chunk_terms_head(*a, mdt), site="gdn.terms",
+            layer=layer, specs=(spec,) * 5, mesh=mesh)(
+            *(_in_chunks(x, chunk) for x in (q, k, v, g, beta)))
+        terms = [jnp.moveaxis(x, 2, 0) for x in terms]
+    elif takes_kernel(chunk, k.shape[-1], v.shape[-1]):
         *terms, least = chunk_terms(q, k, v, g, beta, chunk, mdt,
                                     layer=layer, mesh=mesh, spec=spec)
         least = jnp.min(least)
@@ -197,7 +258,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
             specs=(spec,) * 5, mesh=mesh)(
             *(_in_chunks(x, chunk) for x in (q, k, v, g, beta)))
         terms = [jnp.moveaxis(x, 2, 0) for x in terms]
-    state = jnp.zeros(k.shape[:2] + (k.shape[-1], v.shape[-1]),
+    state = jnp.zeros(v.shape[:2] + (k.shape[-1], v.shape[-1]),
                       jnp.float32)
     _, out = jax.lax.scan(                      # over the chunks: N leads
         checkpointed(lambda s, xs: _chunk_step(mdt, s, xs), site="kda.step",
@@ -321,6 +382,19 @@ class GatedDeltaRuleOp(OpDef):
       o = gated_delta_rule(q, k, v, g, beta)
       y = [RMSNorm_d(o; o_norm) * sigmoid((x wg_a) wg_b)] wo
 
+    With ``decay = "head"`` in the parameters (Gated DeltaNet's form,
+    Qwen3-Next's linear layers) ``num_key_heads`` heads of q and k serve
+    ``num_heads`` heads of v (value head ``j`` reads q/k head ``j //
+    group``), the decay is a scalar a value head and the gate is
+    full-rank under a SiLU:
+
+      g = -exp(A_log) * softplus(x wa + dt_bias)             a head
+      y = [RMSNorm_d(o; o_norm) * silu(x wz)] wo
+
+    with ``A_log`` and ``dt_bias`` a head and no low-rank pair. The
+    recurrence then runs under the name scope ``gdn.scan`` (plain JAX:
+    :func:`_chunk_terms_head`), its instant and counters are ``gdn.*``.
+
     No bias in any projection. The projections are matrix products at
     the compute dtype with float32 accumulation; taps, gates, norms,
     decays, the state and the chunks' inverse are float32. The
@@ -339,17 +413,32 @@ class GatedDeltaRuleOp(OpDef):
         e, dt = in_shapes[0][-1], in_dtypes[0]
         h, d, k = params["num_heads"], params["head_dim"], params["taps"]
         r = params.get("gate_rank") or d
+        hk = params.get("num_key_heads") or h
 
         def fans(i, o):
             return {"fans": (i, o)}
         ws = []
-        for n in "qkv":
-            ws += [WeightSpec(f"w{n}", (e, h, d), dt,
-                              init_args=fans(e, h * d)),
+        for n, heads in (("q", hk), ("k", hk), ("v", h)):
+            ws += [WeightSpec(f"w{n}", (e, heads, d), dt,
+                              init_args=fans(e, heads * d)),
                    # one filter a channel: K taps in, K positions reached
-                   WeightSpec(f"conv_{n}", (h, d, k), dt,
+                   WeightSpec(f"conv_{n}", (heads, d, k), dt,
                               init_args=fans(k, k))]
         uniform = InitializerType.UNIFORM
+        if params.get("decay") == "head":
+            return ws + [
+                WeightSpec("wa", (e, h), dt),
+                # A = exp(A_log) uniform in (1e-4, 16), softplus(dt_bias)
+                # log-uniform in (1e-3, 1e-1), both a head
+                WeightSpec("A_log", (h,), dt, uniform,
+                           {"min": 1e-4, "max": 16.0, "map": "log"}),
+                WeightSpec("dt_bias", (h,), dt, uniform,
+                           {"min": math.log(1e-3), "max": math.log(1e-1),
+                            "map": "inverse_softplus_of_exp"}),
+                WeightSpec("wb", (e, h), dt),
+                WeightSpec("wz", (e, h, d), dt, init_args=fans(e, h * d)),
+                WeightSpec("o_norm", (d,), dt, InitializerType.ONE),
+                WeightSpec("wo", (h, d, e), dt, init_args=fans(h * d, e))]
         return ws + [
             WeightSpec("wf_a", (e, r), dt),
             WeightSpec("wf_b", (r, h, d), dt, init_args=fans(r, h * d)),
@@ -404,14 +493,28 @@ class GatedDeltaRuleOp(OpDef):
                 specs=(x_spec,) + tuple(w_specs.get(n) for n in names))(
                 x, *(weights[n] for n in names))
 
+        def head_decay(x, w, a_log, dt_bias):   # (B, H, T): a head's own
+            return -jnp.exp(a_log.astype(f32))[:, None] * jnp.swapaxes(
+                jax.nn.softplus(mm("bte,eh->bth", x, w)
+                                + dt_bias.astype(f32)), 1, 2)
+
+        def full_rank(x, w):
+            return jax.nn.silu(mm("bte,ehd->bhtd", x, w))
+
         d = weights["wq"].shape[-1]
         q = branch(mixed, "wq", "conv_q", unit=True) * d ** -0.5
         k = branch(mixed, "wk", "conv_k", unit=True)
         v = branch(mixed, "wv", "conv_v", unit=False)
-        g = branch(decay, "wf_a", "wf_b", "A_log", "dt_bias")
+        if "wa" in weights:
+            g = branch(head_decay, "wa", "A_log", "dt_bias")
+        else:
+            g = branch(decay, "wf_a", "wf_b", "A_log", "dt_bias")
         beta = jnp.swapaxes(
             jax.nn.sigmoid(mm("bte,eh->bth", x, weights["wb"])), 1, 2)
-        gate = jax.nn.sigmoid(branch(low_rank, "wg_a", "wg_b"))
+        if "wz" in weights:
+            gate = branch(full_rank, "wz")
+        else:
+            gate = jax.nn.sigmoid(branch(low_rank, "wg_a", "wg_b"))
         return q, k, v, g, beta, gate
 
     def emit(self, params, inputs, weights, ctx, name):
@@ -422,9 +525,20 @@ class GatedDeltaRuleOp(OpDef):
                 f"carries its state beside a KV cache")
         mdt = compute_dtype(ctx, x.dtype)
         chunk = int(params.get("chunk", CHUNK))
-        h, d = weights["wq"].shape[1:]
+        h, d = weights["wv"].shape[1:]
         b, t = x.shape[:2]
-        if events.enabled():
+        by_head = "wa" in weights
+        scope = "gdn" if by_head else "kda"
+        if events.enabled() and by_head:
+            chunks = -(-t // chunk)
+            events.instant("gdn.scan", layer=name,
+                           key_heads=weights["wk"].shape[1], value_heads=h,
+                           key_head_dim=weights["wk"].shape[2], head_dim=d,
+                           taps=weights["conv_q"].shape[-1],
+                           tokens=b * t, chunk=chunk, chunks=chunks,
+                           state_bytes=4 * b * chunks * h * d * d,
+                           impl="plain")
+        elif events.enabled():
             chunks = -(-t // chunk)
             events.instant("kda.scan", layer=name, heads=h, head_dim=d,
                            taps=weights["conv_q"].shape[-1],
@@ -454,7 +568,7 @@ class GatedDeltaRuleOp(OpDef):
         def layer(x, weights):
             q, k, v, g, beta, gate = self.projections(
                 x, weights, mdt, layer=name, specs=specs, mesh=wrap_mesh)
-            with jax.named_scope("kda.scan"):
+            with jax.named_scope(scope + ".scan"):
                 o, least = gated_delta_rule(q, k, v, g, beta, chunk, mdt,
                                             layer=name, mesh=mesh,
                                             spec=spec)
@@ -468,8 +582,8 @@ class GatedDeltaRuleOp(OpDef):
             mesh=wrap_mesh)(x, weights)
         # counters add over layers and steps: the sum of each scan's
         # most negative running log-decay, beside the number of scans
-        ctx.count("kda.log_decay_min", least)
-        ctx.count("kda.scans", jnp.float32(1.0))
+        ctx.count(scope + ".log_decay_min", least)
+        ctx.count(scope + ".scans", jnp.float32(1.0))
         return [out.astype(x.dtype)]
 
     def flops(self, params, in_shapes, out_shapes):
@@ -480,8 +594,12 @@ class GatedDeltaRuleOp(OpDef):
         e = in_shapes[0][-1]
         h, d, k = params["num_heads"], params["head_dim"], params["taps"]
         r = params.get("gate_rank") or d
-        proj = 4 * e * h * d + 2 * (e * r + r * h * d) + e * h
-        return tokens * (2.0 * proj + 3 * (2 * k + 1) * h * d
+        hk = params.get("num_key_heads") or h
+        if params.get("decay") == "head":
+            proj = e * d * (2 * hk + 3 * h) + 2 * e * h
+        else:
+            proj = 4 * e * h * d + 2 * (e * r + r * h * d) + e * h
+        return tokens * (2.0 * proj + (2 * k + 1) * (2 * hk + h) * d
                          + 7.0 * h * d * d)
 
     def backward_flops_factor(self):

@@ -150,7 +150,7 @@ def checkpointed(fn, *, site: str, policy=None, layer=None, block=None,
     """``jax.checkpoint(fn, policy=policy)``: the ONE place where the
     package rematerialises. ``site`` names who asks (``block``,
     ``kda.layer``, ``kda.branch``, ``kda.terms``, ``kda.step``,
-    ``ssm.layer``, ``ssm.chunk``, ``mhc.maps``, ``mhc.plain``,
+    ``gdn.terms``, ``ssm.layer``, ``ssm.chunk``, ``mhc.maps``, ``mhc.plain``,
     ``dsa.chunk``); what is wrapped, and
     what is kept, is the caller's decision and stays with it.
 

@@ -83,14 +83,22 @@ def options_for(layer: Layer) -> List[ShardOption]:
         # head-parallel: heads are independent recurrences; every weight
         # with a head dim co-shards and the output stays whole on hidden
         # (all-reduce after wo, as attention's). The low-rank gates'
-        # first halves and the norm's scale are replicated. The sequence
+        # first halves and the norm's scale are replicated; with a decay
+        # a head (``wa``, ``wz``: no low-rank pair) the q/k heads
+        # co-shard with the value heads they serve, consecutive ones,
+        # so a degree that divides the key heads keeps every group
+        # whole (the plan verifier holds ``wq``'s head dim to it). The
+        # sequence
         # dim is NOT offered: a shard would need a halo of taps - 1
         # positions AND the state its neighbour leaves (the plan
         # verifier refuses it)
+        by_head = (getattr(layer, "params", None) or {}).get("decay") \
+            == "head"
         opts.append(ShardOption("parameter", -1, (
             ("wq", 1), ("wk", 1), ("wv", 1), ("conv_q", 0), ("conv_k", 0),
-            ("conv_v", 0), ("wf_b", 1), ("A_log", 0), ("dt_bias", 0),
-            ("wb", 1), ("wg_b", 1), ("wo", 0))))
+            ("conv_v", 0), ("wa", 1) if by_head else ("wf_b", 1),
+            ("A_log", 0), ("dt_bias", 0), ("wb", 1),
+            ("wz", 1) if by_head else ("wg_b", 1), ("wo", 0))))
     elif t == OperatorType.OP_STATE_SPACE_MIXER:
         sample()
         # head-parallel: heads are independent recurrences. The fused

@@ -19,7 +19,8 @@ from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
                                      GraniteHybridRankConfig,
                                      HybridConvMoEConfig, KeyeRankConfig,
                                      KimiLinearRankConfig, LatentMoEConfig,
-                                     TrinityRankConfig, XingRankConfig)
+                                     Qwen3NextRankConfig, TrinityRankConfig,
+                                     XingRankConfig)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -224,6 +225,26 @@ def test_leg_i_ssm_hybrid_tiny_on_the_cpu_mesh(capsys):
     assert f"python3 {chip_smoke.VALIDATION_SSM}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_SSM))
+
+
+def test_leg_j_gdn_gated_moe_tiny_on_the_cpu_mesh(capsys):
+    """32 positions on the 8-device mesh: the four layers (linear x3,
+    full) are four rematerialised blocks, the three linear layers'
+    outputs kept, every linear layer, the partial turn and the gated
+    shared experts announced."""
+    chip_smoke.leg_gdn_gated_moe(Qwen3NextRankConfig.tiny(), seq=32,
+                                 per_chip_batch=1, label="J/small",
+                                 alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "rematerialised run (1, 6, 4) keeps 3 outputs" in out
+    assert ("gdn.scan ['linear_attn_0', 'linear_attn_1', 'linear_attn_2']; "
+            "attn.qk_norm ['attn_3']; moe.route ['experts_0', 'experts_1', "
+            "'experts_2', 'experts_3']; resolved ['xla'] in 1 layers") in out
+    assert "a layer's most negative in-chunk log-decay -" in out
+    assert "moe.dropped 0.0, moe.overflow 0.0" in out
+    assert f"python3 {chip_smoke.VALIDATION_GDN}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_GDN))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

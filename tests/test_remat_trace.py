@@ -33,6 +33,7 @@ SITES = {"block": "executor.py", "kda.layer": "ops/recurrent_ops.py",
          "kda.branch": "ops/recurrent_ops.py",
          "kda.terms": "ops/recurrent_ops.py",
          "kda.step": "ops/recurrent_ops.py",
+         "gdn.terms": "ops/recurrent_ops.py",
          "ssm.layer": "ops/recurrent_ops.py",
          "ssm.chunk": "ops/recurrent_ops.py", "mhc.maps": "ops/hyper_ops.py",
          "mhc.plain": "ops/hyper_ops.py",

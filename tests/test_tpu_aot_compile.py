@@ -1166,3 +1166,63 @@ def test_the_state_space_kernels_compile_under_a_default_of_highest(
     with jax.default_matmul_precision("highest"):
         txt = _compile_text(jax.grad(loss, argnums=range(5)), *args)
     assert _kernel_names(txt) == ["state_space_bwd", "state_space_fwd"]
+
+
+def test_the_grouped_kernels_compile_at_cell_10s_shapes(v5e_devices,
+                                                        chip_locations):
+    """``qwen3_next_80b_a3b.train.1chip``: 16 query heads on 2 key/value
+    heads of 256 over 8,192 positions, bf16, causal (an 8-fold group at
+    the largest head size a cell runs): the three kernels compile and
+    read K and V at their own 2 heads."""
+    b, h, kvh, s, d = 1, 16, 2, 8192, 256
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, kvh, s, d), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert _kernel_names(txt) == FLASH_NAMES
+    narrow = f"bf16[{b * kvh},{s},{d}]"
+    assert all(l.split(" custom-call(")[1].count(narrow) == 2
+               for l in txt.splitlines() if MOSAIC_CALL in l)
+
+
+def test_the_head_decay_delta_rule_compiles_at_the_published_width(
+        v5e_devices):
+    """One linear layer's forward and backward at 2048 -> 16 q/k heads
+    under 32 value heads of 128 over 8,192 positions in 128 chunks of
+    64, bf16 operands, compiled for a described v5e: plain XLA (no
+    Mosaic call: a decay a head takes neither channel kernel), the
+    recurrence under ``gdn.scan`` with its terms under
+    ``remat.gdn.terms`` and a ``while`` over the chunk states; no
+    ``(64, 64, 128)`` tensor of channel differences a head-chunk is in
+    the text, and the layer's temporaries stay under 3 GiB."""
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.ops.recurrent_ops import GatedDeltaRuleOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    params = {"num_heads": 32, "num_key_heads": 16, "head_dim": 128,
+              "taps": 4, "eps": 1e-6, "decay": "head"}
+    op = GatedDeltaRuleOp()
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32, sharding=one)
+    w = {s.name: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=one)
+         for s in op.weights(params, [(1, 8192, 2048)],
+                             [DataType.DT_FLOAT])}
+
+    def loss(x, w):
+        (y,) = op.emit(params, [x], w,
+                       EmitCtx(training=True, config=FFConfig()),
+                       "linear_attn_0")
+        return jnp.sum(y)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
+    txt = compiled.as_text()
+    assert MOSAIC_CALL not in txt
+    assert "gdn.scan" in txt and "remat.gdn.terms" in txt \
+        and " while(" in txt and "kda.scan" not in txt
+    assert not re.search(r"\b(?:f32|bf16)\[[0-9,]*,64,64,128\]", txt)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30

@@ -1178,7 +1178,9 @@ def leg_gdn_gated_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
     block of the rematerialised run and every linear layer's output is
     kept by its block, each linear layer announced its heads and the
     attention layer the part of a head it turns and the path it took
-    (the plain chain), the experts the shared expert's gate, the
+    (the plain chain), the chunks' terms ran the path the shapes say
+    (``impl``: the head form of the kernels at the published width, the
+    plain terms at the tiny one), the experts the shared expert's gate, the
     counters give a log-decay below zero, nothing was dropped, and the
     step fits the chip. ``VALIDATION_GDN`` holds the recurrence and the
     gradients to the token-by-token reference, and this leg names it."""
@@ -1215,6 +1217,30 @@ def leg_gdn_gated_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
         and all(a.get("shared_gate") for _, a in said["moe.route"]),
         f"{label}: what the layers announced is not the "
         f"configuration's: {said}")
+    from flexflow_tpu.ops.recurrent_ops import head_decay_impl
+    scan = said["gdn.scan"][0][1]
+    took = {a["impl"] for _, a in said["gdn.scan"]}
+    kernels = {}
+    for e in events.events():
+        if e["name"] == "gdn.kernel":
+            kernels.setdefault(e["attrs"]["kernel"], e["attrs"])
+    # (on one device; a mesh's head axis has to hold whole groups too)
+    want = head_decay_impl(scan["chunk"], scan["key_heads"],
+                           scan["value_heads"], scan["key_head_dim"],
+                           scan["head_dim"])
+    say(f"{label}: the chunks' terms by {sorted(took)} (the shapes say "
+        f"{want})")
+    for kind, a in sorted(kernels.items()):
+        say(f"{label}: gdn.kernel {kind}: {a['grid_steps']} grid steps of "
+            f"{a['chunks_per_step']} chunks of {a['chunk']} ({a['group']} "
+            f"value heads a q/k head), {a['vmem_bytes'] / 2 ** 20:.1f} MiB "
+            f"of VMEM a step")
+    check(len(took) == 1 and (took == {want} or took == {"plain"})
+          and sorted(kernels) == (["bwd", "fwd"] if took == {"kernel"}
+                                  else []),
+          f"{label}: the linear layers announced "
+          f"{ {n: a['impl'] for n, a in said['gdn.scan']} } and the "
+          f"kernels {sorted(kernels)} where the shapes say {want}")
     ctr = events.counters()
     scans = ctr.get("gdn.scans", 0)
     least = ctr.get("gdn.log_decay_min", 0) / max(1.0, scans)

@@ -26,7 +26,10 @@ the reference ``benchmarks/reference/gdn_gated_moe_ref.py`` (float32,
   2. the recurrence alone at the published width (16 q/k heads under 32
      value heads of 128, 4,096 positions, chunks of 64, bf16 operands)
      against the token-by-token walk in float32: the output and the five
-     gradients;
+     gradients, through the kernels (the head form of
+     ``kernels/gated_delta_rule.py``, which these shapes take) and
+     through the plain terms, the kernels' readings held to the plain
+     path's own;
   3. per seed at one sequence of ``--seq`` positions: the head's
      log-probabilities against the reference (``|sys - ref|_2 /
      |ref|_2``, the runner's measure), and the eval-mode loss;
@@ -75,6 +78,7 @@ from examples.tpu_validate_window_gated_moe import (  # noqa: E402
     ROUNDED, banded, load_checks)
 from flexflow_tpu.kernels import flash_attention  # noqa: E402
 from flexflow_tpu.obs import events  # noqa: E402
+from flexflow_tpu.ops import recurrent_ops  # noqa: E402
 from flexflow_tpu.ops.recurrent_ops import gated_delta_rule  # noqa: E402
 
 
@@ -152,20 +156,42 @@ def recurrence(conf, ref, seq=4096):
         return jnp.sum(o * w), o
 
     args = (q, k, v, g, beta)
-    (_, o), gp = jax.jit(jax.value_and_grad(
-        program, (0, 1, 2, 3, 4), has_aux=True))(*args)
     (_, want), gr = jax.jit(jax.value_and_grad(
         walk, (0, 1, 2, 3, 4), has_aux=True))(*args)
-    tag = f"recurrence {hk}/{hv} x {d} at {seq}"
-    e = l2(o[0], want)
-    READINGS[f"{tag} o"] = e
-    check(f"{tag} a chunk's decays pass float32's exponent",
-          float(o[1]) < -88.7, f"least in-chunk log-decay {float(o[1]):.1f}")
-    check(f"{tag} output", e < 2e-2, f"rel {e:.3e}")
-    for name, a, b in zip(("dq", "dk", "dv", "dg", "dbeta"), gp, gr):
-        e = l2(a, b)
-        READINGS[f"{tag} {name}"] = e
-        check(f"{tag} {name}", e < 5e-2, f"rel {e:.3e}")
+    # the path is chosen at trace time from the shapes: the kernels
+    # first, then the plain terms with the predicate stubbed and the
+    # jitted function built anew
+    takes = recurrent_ops.takes_head_kernel
+    paths = [recurrent_ops.head_decay_impl(64, hk, hv, d, d)]
+    paths += ["plain"] if paths[0] == "kernel" else []
+    read = {}
+    for path in paths:
+        if path == "plain":
+            recurrent_ops.takes_head_kernel = lambda *a: False
+        jax.clear_caches()
+        try:
+            (_, o), gp = jax.jit(jax.value_and_grad(
+                program, (0, 1, 2, 3, 4), has_aux=True))(*args)
+        finally:
+            recurrent_ops.takes_head_kernel = takes
+        tag = f"recurrence {hk}/{hv} x {d} at {seq} ({path})"
+        e = read[path, "o"] = l2(o[0], want)
+        READINGS[f"{tag} o"] = e
+        check(f"{tag} a chunk's decays pass float32's exponent",
+              float(o[1]) < -88.7,
+              f"least in-chunk log-decay {float(o[1]):.1f}")
+        check(f"{tag} output", e < 2e-2, f"rel {e:.3e}")
+        for name, a, b in zip(("dq", "dk", "dv", "dg", "dbeta"), gp, gr):
+            e = read[path, name] = l2(a, b)
+            READINGS[f"{tag} {name}"] = e
+            check(f"{tag} {name}", e < 5e-2, f"rel {e:.3e}")
+    if len(paths) == 2:
+        # the kernels round where the plain terms do: each of their
+        # readings within a quarter of the plain path's own
+        for name in ("o", "dq", "dk", "dv", "dg", "dbeta"):
+            a, b = read["kernel", name], read["plain", name]
+            check(f"recurrence {name}: the kernels read as the plain "
+                  f"terms do", a < 1.25 * b, f"{a:.3e} against {b:.3e}")
 
 
 def forward_checks(conf, ref, seq, seeds):

@@ -36,6 +36,20 @@ Products round their operands to ``mdt`` where the plain code does (the
 spans' products, ``W``, ``U0`` and their transposes in the backward);
 running sums, exponentials, the sub-blocks' sums, the inverse and the
 gradients are float32.
+
+The second half of the module is the HEAD form (a decay a head, Gated
+DeltaNet's: ``g`` one scalar a head-token), the same algorithm whose
+``A`` and ``B`` are formed differently: ``A = (K K^T) * L``, ``B = (Q
+K^T) * L`` with ``L_ij = exp(G_i - G_j)``, one product each and one ``(C,
+C)`` matrix of differences, so no sub-block loop and no span levels
+(:func:`head_chunk_terms`, against
+:func:`flexflow_tpu.ops.recurrent_ops._chunk_terms_head`). A grid step
+takes a few chunks of one q/k head and of the ``group`` value heads it
+serves: q and k are read at their own head through the block index,
+never repeated, and their raw products are made once for the group. It
+shares ``_mm``, ``_column``, ``_span_mask``, ``_inverse`` (the same
+blocked inverse, ``N`` split by masks alone), the block specs and
+``VMEM_LIMIT`` with the channel form and changes no line of it.
 """
 from __future__ import annotations
 
@@ -524,3 +538,390 @@ def chunk_terms(q, k, v, g, beta, chunk, mdt, *, layer=None,
         jnp.dtype(mdt), layer, bool(interpret))
     return tuple([x.reshape((n, b, h) + x.shape[2:]) for x in terms]
                  + [dec.reshape(n, b, h, dk), least.reshape(n, b, h, dk)])
+
+
+# ---------------------------------------------------------------------------
+# the head form: a decay a HEAD (Gated DeltaNet's). ``g`` is one scalar a
+# head-token, so ``A = (K K^T) * L`` and ``B = (Q K^T) * L`` with ``L_ij =
+# exp(G_i - G_j)``: one product each and one (c, c) matrix of differences,
+# no sub-block loop and no span levels. A grid step takes a few chunks of
+# one q/k head and of the ``group`` value heads it serves: values of the
+# value heads are (group n, c, .), a head's n chunks after another's.
+# ---------------------------------------------------------------------------
+#: chunks of value heads a grid step of the head form takes (the chunks of
+#: one q/k head times the heads it serves)
+HEAD_CHUNKS_PER_STEP = 8
+
+
+def _head_per_step(chunks: int, group: int) -> int:
+    """Chunks of a q/k head a grid step takes."""
+    return max(1, min(chunks, HEAD_CHUNKS_PER_STEP // group))
+
+
+def head_vmem_bytes(kernel, chunk, dk, dv, group, itemsize, per_step=None):
+    """Working set of one grid step of the head form, as
+    :func:`vmem_bytes` counts the channel form's: the double-buffered
+    operand and output blocks and the float32 (rows, d) and (rows,
+    chunk) values a step holds at once, ``rows`` the group's. The counts
+    of values are held to Mosaic for a described v5e at cell 10's shape
+    (two heads of four chunks a step): the forward compiles under a
+    limit of 6.5 MiB and not of 6, the backward under 8 and not 7.5
+    (PERF.md section 6, PR 58)."""
+    if per_step is None:
+        per_step = _head_per_step(HEAD_CHUNKS_PER_STEP, group)
+    rows = group * per_step * chunk
+    wide = rows * max(dk, dv) * 4
+    square = rows * max(chunk, LANES) * 4
+    small = group * per_step * 8 * LANES * 4
+    inputs = rows * (2 * dk // group + dv) * 4 + 2 * small
+    terms = (rows * (3 * dk * itemsize + dv * 4)
+             + rows * max(chunk, LANES) * itemsize + 2 * small)
+    if kernel == "fwd":
+        return 2 * (inputs + terms) + 6 * wide + 8 * square
+    return 2 * (2 * inputs + terms) + 8 * wide + 8 * square
+
+
+def takes_head_kernel(chunk: int, dk: int, dv: int, group: int) -> bool:
+    """Whether a decay a head runs the kernels at these shapes: what the
+    channel form asks (head sizes in whole lanes, a chunk the inverse's
+    levels divide) and a group whose backward step fits the limit."""
+    return (takes_kernel(chunk, dk, dv) and group >= 1
+            and head_vmem_bytes("bwd", chunk, dk, dv, group, 4)
+            <= VMEM_LIMIT)
+
+
+def _tile(x, group):
+    """A q/k head's (n, ..) value at each head it serves: (group n, ..)."""
+    return x if group == 1 else jnp.concatenate([x] * group, 0)
+
+
+def _fold(x, group):
+    """(group n, ..) -> (n, ..): summed over the heads a q/k head serves."""
+    n = x.shape[0] // group
+    out = x[:n]
+    for h in range(1, group):
+        out = out + x[h * n:(h + 1) * n]
+    return out
+
+
+def _along_lanes(col):
+    """(n, c, 1) values down the rows -> (n, 1, c) along the lanes."""
+    n, c, _ = col.shape
+    eye = _iota((n, c, c), 1) == _iota((n, c, c), 2)
+    return jnp.sum(jnp.where(eye, col, 0.0), 1, keepdims=True)
+
+
+def _load_step(q_ref, k_ref, v_ref, g_ref, beta_ref):
+    """A grid step's operands as float32 values: the group's size; q, k
+    (n, c, dk); v (group n, c, dv); g and beta (group n, 1, c), a chunk's
+    scalars along the lanes."""
+    group, n = g_ref.shape[:2]
+    gn = group * n
+    return (group, _load(q_ref, n), _load(k_ref, n), _load(v_ref, gn),
+            _load(g_ref, gn), _load(beta_ref, gn))
+
+
+def _in_chunk_head(q, k, g_row, beta_row, mdt, group):
+    """What forward and backward both form for a decay a head: the
+    running log-decay down the rows and along the lanes, ``L``, beta
+    down the rows, the raw ``K K^T`` and ``Q K^T`` (made once a q/k head
+    and read by each head it serves), ``A`` and ``(I + Diag(beta)
+    A)^-1``. ``q``, ``k``: (n, c, dk); ``g_row``, ``beta_row``: (group n,
+    1, c)."""
+    gn, _, c = g_row.shape
+    row, col = _iota((gn, c, c), 1), _iota((gn, c, c), 2)
+    low = col <= row
+    g_col = jnp.sum(jnp.where(low, g_row, 0.0), -1, keepdims=True)
+    g_lane = _along_lanes(g_col)
+    # every exponent a difference <= 0, exactly 0 on the diagonal
+    big_l = jnp.where(low, jnp.exp(jnp.where(low, g_col - g_lane, 0.0)), 0.0)
+    beta = _column(beta_row)
+    kk = _tile(_mm(k, k, tb=True, mdt=mdt), group)
+    qk = _tile(_mm(q, k, tb=True, mdt=mdt), group)
+    a = jnp.where(col < row, kk * big_l, 0.0)
+    # N = Diag(beta) A split for the inverse by masks alone
+    nn = beta * a
+    own = (row - (row & (SUB - 1))) == (col - (col & (SUB - 1)))
+    t = _inverse(jnp.where(own, nn, 0.0),
+                 [jnp.where(_span_mask(gn, c, half), nn, 0.0)
+                  for half in _halves(c)])
+    return g_col, g_lane, big_l, beta, kk, qk, a, t
+
+
+def _last_lane(row):
+    """(n, 1, c) -> (n, 1, 1): the chunk's last entry."""
+    last = _iota(row.shape, 2) == row.shape[2] - 1
+    return jnp.sum(jnp.where(last, row, 0.0), 2, keepdims=True)
+
+
+def _store_heads(ref, x):
+    """(group n, ..) head after head -> the block (n, group, ..) the scan
+    reads, chunk leading."""
+    n, group = ref.shape[:2]
+    for h in range(group):
+        ref[:, h] = x[h * n:(h + 1) * n].astype(ref.dtype)
+
+
+def _load_terms(ref):
+    """The block (n, group, ..) of a term's cotangent -> (group n, ..)
+    float32, head after head."""
+    group = ref.shape[1]
+    return jnp.concatenate([ref[:, h] for h in range(group)],
+                           0).astype(F32)
+
+
+def _head_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref,
+                     b_ref, qd_ref, kd_ref, dec_ref, least_ref, *, mdt):
+    group, q, k, v, g_row, beta_row = _load_step(q_ref, k_ref, v_ref, g_ref,
+                                                 beta_ref)
+    g_col, g_lane, big_l, beta, _, qk, _, t = _in_chunk_head(
+        q, k, g_row, beta_row, mdt, group)
+    decay = jnp.exp(g_col)
+    g_last = _last_lane(g_lane)
+    kt, qt = _tile(k, group), _tile(q, group)
+    _store_heads(w_ref, _mm(t, beta * kt * decay, mdt=mdt))
+    _store_heads(u_ref, _mm(t, beta * v, mdt=mdt))
+    _store_heads(b_ref, qk * big_l)
+    _store_heads(qd_ref, qt * decay)
+    _store_heads(kd_ref, kt * jnp.exp(g_last - g_col))
+    lanes = (v.shape[0], 1, dec_ref.shape[-1])
+    _store_heads(dec_ref, jnp.broadcast_to(jnp.exp(g_last), lanes))
+    _store_heads(least_ref, jnp.broadcast_to(
+        jnp.min(g_lane, axis=2, keepdims=True), lanes))
+
+
+def _head_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, dw_ref, du_ref,
+                     db_ref, dqd_ref, dkd_ref, ddec_ref, dq_ref, dk_ref,
+                     dv_ref, dg_ref, dbeta_ref, *, mdt):
+    group, q, k, v, g_row, beta_row = _load_step(q_ref, k_ref, v_ref, g_ref,
+                                                 beta_ref)
+    gn, c = v.shape[:2]
+    g_col, g_lane, big_l, beta, kk, qk, a, t = _in_chunk_head(
+        q, k, g_row, beta_row, mdt, group)
+    row, col = _iota((gn, c, c), 1), _iota((gn, c, c), 2)
+    low, strict = col <= row, col < row
+    dw, du, db, dqd, dkd = (_load_terms(r) for r in (
+        dw_ref, du_ref, db_ref, dqd_ref, dkd_ref))
+    ddec = jnp.sum(_load_terms(ddec_ref), 2, keepdims=True)     # (gn, 1, 1)
+
+    def over_d(x):
+        return jnp.sum(x, -1, keepdims=True)
+
+    decay = jnp.exp(g_col)
+    kt, qt = _tile(k, group), _tile(q, group)
+    k_dec = kt * decay
+    # W = T (beta k exp G), U0 = T (beta v)
+    xw, xu = beta * k_dec, beta * v
+    dt = _mm(dw, xw, tb=True, mdt=mdt) + _mm(du, xu, tb=True, mdt=mdt)
+    dxw, dxu = _mm(t, dw, ta=True, mdt=mdt), _mm(t, du, ta=True, mdt=mdt)
+    # T = (I + Diag(beta) A)^-1
+    dm = -_mm(_mm(t, dt, ta=True), t, tb=True)
+    da = jnp.where(strict, beta * dm, 0.0)
+    dbeta = over_d(dm * a) + over_d(dxw * k_dec) + over_d(dxu * v)
+    dv = beta * dxu
+    dk = dxw * beta * decay
+    d_g = over_d(dxw * xw)
+    # q exp(G), k exp(G_C - G), exp(G_C)
+    dq = dqd * decay
+    d_g = d_g + over_d(dqd * qt) * decay
+    g_last = _last_lane(g_lane)
+    fall = jnp.exp(g_last - g_col)
+    dk = dk + dkd * fall
+    through = over_d(dkd * kt) * fall
+    d_g = d_g - through
+    d_g = d_g + jnp.where(
+        _iota(d_g.shape, 1) == c - 1,
+        jnp.sum(through, 1, keepdims=True) + ddec * jnp.exp(g_last), 0.0)
+    # A = (K K^T) * L, B = (Q K^T) * L: L's cotangent folded into G's, a
+    # row takes what its differences took and a column gives it back
+    db = jnp.where(low, db, 0.0)
+    dl = jnp.where(strict, (da * kk + db * qk) * big_l, 0.0)
+    d_g = d_g + jnp.sum(dl, 2, keepdims=True) \
+        - _column(jnp.sum(dl, 1, keepdims=True))
+    # the raw products, their cotangents summed over the heads served
+    dkk, dqk = _fold(da * big_l, group), _fold(db * big_l, group)
+    dk = (_fold(dk, group) + _mm(dkk, k, mdt=mdt)
+          + _mm(dkk, k, ta=True, mdt=mdt) + _mm(dqk, q, ta=True, mdt=mdt))
+    dq = _fold(dq, group) + _mm(dqk, k, mdt=mdt)
+    dq_ref[...] = dq.reshape(dq_ref.shape)
+    dk_ref[...] = dk.reshape(dk_ref.shape)
+    dv_ref[...] = dv.reshape(dv_ref.shape)
+    # g's cotangent: the running sum's transpose, along the lanes
+    dg_ref[...] = jnp.sum(jnp.where(low, d_g, 0.0), 1,
+                          keepdims=True).reshape(dg_ref.shape)
+    dbeta_ref[...] = _along_lanes(dbeta).reshape(dbeta_ref.shape)
+
+
+def _head_layout(q, v, chunk, per_step):
+    """Grid (batch x q/k head, groups of chunks): q, k (BH, T, dk) read
+    at their own head; v (BH, group, T, dv), g and beta (BH, group, N,
+    1, C) at the heads it serves; the terms (N, BH, group, C, .)."""
+    bh, t, dk = q.shape
+    group, dv = v.shape[1], v.shape[3]
+    n, rows = t // chunk, per_step * chunk
+
+    def of_head(*block):        # blocks along the axis after the group's
+        zeros = (0,) * (len(block) - 1)
+        return pl.BlockSpec((None, group) + block,
+                            lambda b, i: (b, 0, i) + zeros)
+
+    def term(*last):
+        zeros = (0,) * (len(last) + 1)
+        return pl.BlockSpec((per_step, None, group) + last,
+                            lambda b, i: (i, b) + zeros)
+
+    inputs = [_specs(True, rows, dk)] * 2 + [
+        of_head(rows, dv), of_head(per_step, 1, chunk),
+        of_head(per_step, 1, chunk)]
+    terms = [term(chunk, dk), term(chunk, dv), term(chunk, chunk),
+             term(chunk, dk), term(chunk, dk), term(1, LANES)]
+    return (bh, n // per_step), n, group, inputs, terms
+
+
+def _head_cost(kernel, q, v, chunk, itemsize):
+    """What a call does, for XLA's scheduler: the products' operations
+    (the float32 ones of the inverse at their six passes), an
+    exponential a pair of rows, the operands' and terms' bytes."""
+    bh, t, dk = q.shape
+    group, dv = v.shape[1], v.shape[3]
+    heads = bh * group * (t // chunk)           # chunks of value heads
+    square, wide = 2 * chunk ** 3, 2 * chunk * chunk * (dk + dv)
+    raw = 2 * 2 * chunk * chunk * dk / group
+    flops = raw + 10 * 6 * square + wide
+    inputs = 4 * chunk * (2 * dk / group + dv + 2)
+    terms = chunk * (3 * dk * itemsize + 4 * dv + chunk * itemsize)
+    if kernel == "bwd":
+        flops += 2 * 6 * square + 2 * wide + 2 * raw
+        inputs *= 2
+    return pl.CostEstimate(flops=int(heads * flops),
+                           transcendentals=heads * chunk * (chunk + 2),
+                           bytes_accessed=int(heads * (inputs + terms)))
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
+def _head_fwd_call(q, k, v, g, beta, chunk, per_step, mdt, interpret):
+    grid, n, group, inputs, terms = _head_layout(q, v, chunk, per_step)
+    bh, _, dk = q.shape
+    dv = v.shape[3]
+
+    def out(dt, *last):
+        return jax.ShapeDtypeStruct((n, bh, group) + last, dt)
+    return pl.pallas_call(
+        functools.partial(_head_fwd_kernel, mdt=mdt),
+        grid=grid, in_specs=inputs, out_specs=terms + [terms[-1]],
+        out_shape=[out(mdt, chunk, dk), out(F32, chunk, dv),
+                   out(mdt, chunk, chunk), out(mdt, chunk, dk),
+                   out(mdt, chunk, dk), out(F32, 1, LANES),
+                   out(F32, 1, LANES)],
+        compiler_params=_PARAMS, interpret=interpret,
+        cost_estimate=_head_cost("fwd", q, v, chunk,
+                                 jnp.dtype(mdt).itemsize),
+        name="gated_delta_rule_head_fwd",
+    )(q, k, v, g, beta)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
+def _head_bwd_call(q, k, v, g, beta, cts, chunk, per_step, mdt, interpret):
+    grid, _, _, inputs, terms = _head_layout(q, v, chunk, per_step)
+    return pl.pallas_call(
+        functools.partial(_head_bwd_kernel, mdt=mdt),
+        grid=grid, in_specs=inputs + terms, out_specs=inputs,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, F32)
+                   for x in (q, k, v, g, beta)],
+        compiler_params=_PARAMS, interpret=interpret,
+        cost_estimate=_head_cost("bwd", q, v, chunk,
+                                 jnp.dtype(mdt).itemsize),
+        name="gated_delta_rule_head_bwd",
+    )(q, k, v, g, beta, *cts)
+
+
+def _head_note(kernel, layer, q, v, chunk, per_step, mdt):
+    """One ``gdn.kernel`` instant per emitted call, at trace time."""
+    if events.enabled():
+        bh, t, dk = q.shape
+        group, dv = v.shape[1], v.shape[3]
+        n = t // chunk
+        events.instant(
+            "gdn.kernel", kernel=kernel, layer=layer, chunk=chunk,
+            chunks=bh * group * n, grid_steps=bh * n // per_step,
+            chunks_per_step=group * per_step, group=group,
+            vmem_bytes=head_vmem_bytes(kernel, chunk, dk, dv, group,
+                                       jnp.dtype(mdt).itemsize, per_step))
+
+
+def _noted_head_fwd_call(q, k, v, g, beta, chunk, per_step, mdt, layer,
+                         interpret):
+    _head_note("fwd", layer, q, v, chunk, per_step, mdt)
+    return tuple(_head_fwd_call(q, k, v, g, beta, chunk, per_step, mdt,
+                                interpret))
+
+
+_head_terms = jax.custom_vjp(_noted_head_fwd_call,
+                             nondiff_argnums=(5, 6, 7, 8, 9))
+
+
+def _head_terms_fwd(q, k, v, g, beta, *static):
+    return _noted_head_fwd_call(q, k, v, g, beta, *static), \
+        (q, k, v, g, beta)
+
+
+def _head_terms_bwd(chunk, per_step, mdt, layer, interpret, res, cts):
+    _head_note("bwd", layer, res[0], res[2], chunk, per_step, mdt)
+    # (the least running log-decay is a reading, not a term)
+    return tuple(_head_bwd_call(*res, list(cts[:6]), chunk, per_step, mdt,
+                                interpret))
+
+
+_head_terms.defvjp(_head_terms_fwd, _head_terms_bwd)
+
+
+def head_chunk_terms(q, k, v, g, beta, chunk, mdt, *, layer=None,
+                     interpret=None, mesh=None, spec=None):
+    """:func:`chunk_terms` for a decay a HEAD: ``q``, ``k``: (B, H /
+    group, T, dk), a head of theirs read in place by the ``group``
+    consecutive heads of ``v`` it serves; ``v``: (B, H, T, dv); ``g``,
+    ``beta``: (B, H, T), float32. Returns, chunk leading, ``W`` (N, B,
+    H, C, dk), ``U0`` (.., C, dv) float32, ``B`` (.., C, C), ``q
+    exp(G)``, ``k exp(G_C - G)`` in ``mdt``, ``exp(G_C)`` (N, B, H, 1)
+    float32; and the least running log-decay a chunk, (N, B, H, 1).
+    ``T`` is padded to whole grid steps as there.
+
+    Under a mesh of more than one device the call runs under
+    ``shard_map`` over the batch and head entries of ``spec``; the
+    caller sees to it that every device holds whole groups (the head
+    entry's degree divides q's heads)."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    if mesh is not None and mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+        bh = (tuple(spec or ()) + (None, None))[:2]
+        local = functools.partial(head_chunk_terms, chunk=chunk, mdt=mdt,
+                                  layer=layer, interpret=interpret)
+        # check_vma off: pallas_call outputs carry no varying-axes info
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(*bh, None, None),) * 3 + (P(*bh, None),) * 2,
+            out_specs=(P(None, *bh, None, None),) * 5
+            + (P(None, *bh, None),) * 2, check_vma=False)(q, k, v, g, beta)
+    b, hk, t, dk = q.shape
+    h, dv = v.shape[1], v.shape[3]
+    group = h // hk
+    n = -(-t // chunk)
+    per_step = _head_per_step(n, group)
+    n = -(-n // per_step) * per_step
+    pad = n * chunk - t
+
+    def rows(x, *shape):
+        x = jnp.pad(x.astype(F32),
+                    ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
+        return x.reshape(shape)
+
+    *terms, dec, least = _head_terms(
+        rows(q, b * hk, n * chunk, dk), rows(k, b * hk, n * chunk, dk),
+        rows(v, b * hk, group, n * chunk, dv),
+        rows(g, b * hk, group, n, 1, chunk),
+        rows(beta, b * hk, group, n, 1, chunk), chunk, per_step,
+        jnp.dtype(mdt), layer, bool(interpret))
+    return tuple([x.reshape((n, b, h) + x.shape[3:]) for x in terms]
+                 + [dec.reshape(n, b, h, LANES)[..., :1],
+                    least.reshape(n, b, h, LANES)[..., :1]])

@@ -42,11 +42,16 @@ chunk's matrices are products after all:
     A = (K K^T) * L,   B = (Q K^T) * L,   L_ij = exp(G_i - G_j)  (j <= i)
 
 one matrix product a head-chunk and one ``C x C`` matrix of differences
-(:func:`_chunk_terms_head`; what :func:`_ssm_chunks` builds): no
-halving, no ``(SUB, SUB, d)`` tensor, one exponential a pair of rows
-and not one a pair a channel. There a head of q and k may serve several
-heads of v (value head ``j`` reads q/k head ``j // group``): the raw
-products are made at the q/k heads and shared.
+(what :func:`_ssm_chunks` builds): no halving, no ``(SUB, SUB, d)``
+tensor, one exponential a pair of rows and not one a pair a channel.
+There a head of q and k may serve several heads of v (value head ``j``
+reads q/k head ``j // group``): the raw products are made at the q/k
+heads and shared. Where the shapes take them (:func:`head_decay_impl`)
+these terms come from the head form of the kernels
+(``kernels/gated_delta_rule.py::head_chunk_terms``: ``L``, ``A`` and the
+inverse stay in VMEM, q and k are read at their own heads); otherwise
+from :func:`_chunk_terms_head`, plain JAX, which is also the kernels'
+oracle.
 
 The state-space (Mamba-2) recurrence: a head keeps a ``P x N`` state
 (``P`` its channels, ``N`` the state size) under a SCALAR decay a token,
@@ -77,7 +82,8 @@ import numpy as np
 from ..core.tensor import WeightSpec
 from ..ffconst import InitializerType, OperatorType
 from ..kernels import state_space as ssm_kernels
-from ..kernels.gated_delta_rule import SUB, chunk_terms, takes_kernel
+from ..kernels.gated_delta_rule import (SUB, chunk_terms, head_chunk_terms,
+                                        takes_head_kernel, takes_kernel)
 from ..obs import events
 from .nn_ops import MultiHeadAttentionOp, _rms, short_conv
 from .registry import (OpDef, checkpointed, compute_dtype, register,
@@ -214,6 +220,24 @@ def _in_chunks(x, chunk, axis=2):
     return x.reshape(x.shape[:axis] + (-1, chunk) + x.shape[axis + 1:])
 
 
+def head_decay_impl(chunk, q_heads, heads, dk, dv, mesh=None, spec=None):
+    """``"kernel"`` or ``"plain"`` for a decay a head, from what the
+    call can observe: the shapes (:func:`takes_head_kernel`) and, under a
+    mesh of several devices, whether every device holds whole groups
+    (the head entry of ``spec`` divides q's and k's heads too)."""
+    if heads % q_heads or not takes_head_kernel(chunk, dk, dv,
+                                                heads // q_heads):
+        return "plain"
+    if mesh is not None and mesh.size > 1:
+        entry = (tuple(spec or ()) + (None, None))[1]
+        degree = 1
+        for axis in (entry if isinstance(entry, tuple) else (entry,)):
+            degree *= mesh.shape[axis] if axis is not None else 1
+        if q_heads % degree:
+            return "plain"
+    return "kernel"
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
                      mdt=jnp.float32, *, layer=None, mesh=None, spec=None):
     """The recurrence of the module's docstring from a zero state, in
@@ -225,9 +249,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
     chunk.
 
     ``g`` of (B, H, T), one scalar a head-token, is a decay a HEAD and
-    takes a path of its own (:func:`_chunk_terms_head`, plain JAX,
-    rematerialised: nothing is broadcast over the channels into the
-    channel kernels); ``q`` and ``k`` may then have ``H / group`` heads.
+    takes a path of its own (nothing is broadcast over the channels into
+    the channel kernels); ``q`` and ``k`` may then have ``H / group``
+    heads. Its chunks' terms come from the head form of the kernels
+    (``head_chunk_terms``, one ``gdn.kernel`` instant a call) where
+    :func:`head_decay_impl` says ``"kernel"``, otherwise from
+    :func:`_chunk_terms_head` under ``remat.gdn.terms``.
 
     The chunks' terms come from the Pallas kernels of
     ``kernels/gated_delta_rule.py`` where the shapes take them
@@ -242,7 +269,13 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
     the terms the scan reads and one chunk's matrices, not the ``(SUB,
     SUB, d)`` differences nor every chunk's intermediate products."""
     t = q.shape[2]
-    if g.ndim == 3:
+    if g.ndim == 3 and head_decay_impl(
+            chunk, k.shape[1], v.shape[1], k.shape[-1], v.shape[-1], mesh,
+            spec) == "kernel":
+        *terms, least = head_chunk_terms(q, k, v, g, beta, chunk, mdt,
+                                         layer=layer, mesh=mesh, spec=spec)
+        least = jnp.min(least)
+    elif g.ndim == 3:
         *terms, least = checkpointed(
             lambda *a: _chunk_terms_head(*a, mdt), site="gdn.terms",
             layer=layer, specs=(spec,) * 5, mesh=mesh)(
@@ -392,8 +425,11 @@ class GatedDeltaRuleOp(OpDef):
       y = [RMSNorm_d(o; o_norm) * silu(x wz)] wo
 
     with ``A_log`` and ``dt_bias`` a head and no low-rank pair. The
-    recurrence then runs under the name scope ``gdn.scan`` (plain JAX:
-    :func:`_chunk_terms_head`), its instant and counters are ``gdn.*``.
+    recurrence then runs under the name scope ``gdn.scan`` (the chunks'
+    terms by the head form of the kernels at head sizes in whole lanes,
+    their backward too, otherwise :func:`_chunk_terms_head`; the
+    ``gdn.scan`` instant's ``impl`` says which), its instant and
+    counters are ``gdn.*``.
 
     No bias in any projection. The projections are matrix products at
     the compute dtype with float32 accumulation; taps, gates, norms,
@@ -529,15 +565,20 @@ class GatedDeltaRuleOp(OpDef):
         b, t = x.shape[:2]
         by_head = "wa" in weights
         scope = "gdn" if by_head else "kda"
+        # a compiled kernel inside a multi-device jit runs on each
+        # device's (batch, head) shard, as the attention kernels do
+        mesh, spec = MultiHeadAttentionOp._kernel_shard_spec(ctx, b, h)
         if events.enabled() and by_head:
             chunks = -(-t // chunk)
+            hk, dk = weights["wk"].shape[1:]
             events.instant("gdn.scan", layer=name,
-                           key_heads=weights["wk"].shape[1], value_heads=h,
-                           key_head_dim=weights["wk"].shape[2], head_dim=d,
+                           key_heads=hk, value_heads=h,
+                           key_head_dim=dk, head_dim=d,
                            taps=weights["conv_q"].shape[-1],
                            tokens=b * t, chunk=chunk, chunks=chunks,
                            state_bytes=4 * b * chunks * h * d * d,
-                           impl="plain")
+                           impl=head_decay_impl(chunk, hk, h, dk, d, mesh,
+                                                spec))
         elif events.enabled():
             chunks = -(-t // chunk)
             events.instant("kda.scan", layer=name, heads=h, head_dim=d,
@@ -546,9 +587,6 @@ class GatedDeltaRuleOp(OpDef):
                            state_bytes=4 * b * chunks * h * d * d,
                            impl="kernel" if takes_kernel(chunk, d, d)
                            else "plain")
-        # a compiled kernel inside a multi-device jit runs on each
-        # device's (batch, head) shard, as the attention kernels do
-        mesh, spec = MultiHeadAttentionOp._kernel_shard_spec(ctx, b, h)
 
         # The layer is rematerialised whole, and inside it each branch
         # of the projections once more: what it keeps for the backward

@@ -240,6 +240,8 @@ def test_leg_j_gdn_gated_moe_tiny_on_the_cpu_mesh(capsys):
     assert ("gdn.scan ['linear_attn_0', 'linear_attn_1', 'linear_attn_2']; "
             "attn.qk_norm ['attn_3']; moe.route ['experts_0', 'experts_1', "
             "'experts_2', 'experts_3']; resolved ['xla'] in 1 layers") in out
+    assert "J/small: the chunks' terms by ['plain'] (the shapes say plain)" \
+        in out and "gdn.kernel" not in out
     assert "a layer's most negative in-chunk log-decay -" in out
     assert "moe.dropped 0.0, moe.overflow 0.0" in out
     assert f"python3 {chip_smoke.VALIDATION_GDN}" in out

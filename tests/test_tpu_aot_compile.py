@@ -1191,15 +1191,17 @@ def test_the_grouped_kernels_compile_at_cell_10s_shapes(v5e_devices,
 
 
 def test_the_head_decay_delta_rule_compiles_at_the_published_width(
-        v5e_devices):
+        v5e_devices, compiled_kda):
     """One linear layer's forward and backward at 2048 -> 16 q/k heads
     under 32 value heads of 128 over 8,192 positions in 128 chunks of
-    64, bf16 operands, compiled for a described v5e: plain XLA (no
-    Mosaic call: a decay a head takes neither channel kernel), the
-    recurrence under ``gdn.scan`` with its terms under
-    ``remat.gdn.terms`` and a ``while`` over the chunk states; no
-    ``(64, 64, 128)`` tensor of channel differences a head-chunk is in
-    the text, and the layer's temporaries stay under 3 GiB."""
+    64, bf16 operands, compiled for a described v5e: the chunks' terms by
+    the head form of the kernels, three Mosaic calls (the layer's run,
+    its recomputation and the backward) under ``gdn.scan``, q and k read
+    at their own 16 heads, a ``while`` over the chunk states; no
+    triangular solve, no ``remat.gdn.terms``, no float32 ``(64, 64)``
+    matrix a head-chunk (64 MiB a layer) and no ``(64, 64, 128)`` tensor
+    of channel differences is in the text, and the layer's temporaries
+    stay under 3 GiB."""
     from flexflow_tpu import FFConfig
     from flexflow_tpu.ffconst import DataType
     from flexflow_tpu.ops.recurrent_ops import GatedDeltaRuleOp
@@ -1217,12 +1219,78 @@ def test_the_head_decay_delta_rule_compiles_at_the_published_width(
         (y,) = op.emit(params, [x], w,
                        EmitCtx(training=True, config=FFConfig()),
                        "linear_attn_0")
-        return jnp.sum(y)
+        return jnp.sum(y * y)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))
+                       ).lower(x, w).compile()
     txt = compiled.as_text()
-    assert MOSAIC_CALL not in txt
-    assert "gdn.scan" in txt and "remat.gdn.terms" in txt \
+    calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+    assert _kernel_names(txt) == ["gated_delta_rule_head_bwd"] \
+        + ["gated_delta_rule_head_fwd"] * 2
+    assert all("gdn.scan" in l and "f32[16,8192,128]" in l for l in calls)
+    assert "triangular" not in txt and "remat.gdn.terms" not in txt \
         and " while(" in txt and "kda.scan" not in txt
+    assert not re.search(r"\bf32\[[0-9,]*,64,64\]", txt)
     assert not re.search(r"\b(?:f32|bf16)\[[0-9,]*,64,64,128\]", txt)
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+GDN_NAMES = ["gated_delta_rule_head_bwd", "gated_delta_rule_head_fwd"]
+
+
+def _gdn_loss(mesh, spec, q, k, v, g, beta):
+    from flexflow_tpu.ops.recurrent_ops import gated_delta_rule
+    with jax.named_scope("ff.forward"), jax.named_scope("linear_attn_1"), \
+            jax.named_scope("gdn.scan"):
+        out, _ = gated_delta_rule(q, k, v, g, beta, 64, jnp.bfloat16,
+                                  mesh=mesh, spec=spec)
+    return jnp.sum(out)
+
+
+def _gdn_operands(mesh, spec, b, hk, h, t, d):
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.float32, sharding=NamedSharding(
+                mesh, P(*(tuple(spec) + (None,) * len(shape))[:len(shape)])))
+    ops = [arr(b, h, t, d)] + [arr(b, h, t)] * 2
+    if len(spec) > 1 and spec[1] and hk % mesh.shape[spec[1]]:
+        spec = ()               # q/k heads the axis cannot split: whole
+    return [arr(b, hk, t, d)] * 2 + ops
+
+
+@pytest.mark.parametrize("spec,hk,names", [
+    (("x0", None), 2, GDN_NAMES), ((None, "x0"), 4, GDN_NAMES),
+    ((None, "x0"), 2, [])], ids=["batch", "heads", "split_groups"])
+def test_the_head_decay_kernels_compile_under_a_mesh(
+        v5e_devices, compiled_kda, spec, hk, names):
+    """Four chips by batch or by heads: each runs the head form's
+    kernels on its own q/k heads and the value heads they serve under
+    ``shard_map``; two q/k heads over four devices would split a group,
+    and that layer keeps the plain terms."""
+    mesh = Mesh(np.array(v5e_devices), ("x0",))
+    txt = _compile_text(
+        jax.grad(functools.partial(_gdn_loss, mesh, P(*spec)),
+                 argnums=range(5)),
+        *_gdn_operands(mesh, spec, 4, hk, 2 * hk, 512, 128))
+    assert _kernel_names(txt) == names
+
+
+def test_the_head_decay_kernels_keep_their_scope(v5e_devices, compiled_kda,
+                                                 chip_locations):
+    """Both calls carry the layer's name and ``gdn.scan`` in their
+    ``op_name``, the backward's inside the ``transpose(``: the
+    benchmark's ``qwen3next_gdn_scan_time_share.train`` finds them by
+    those parts."""
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    txt = _compile_text(
+        jax.grad(functools.partial(_gdn_loss, None, None),
+                 argnums=range(5)), *_gdn_operands(mesh, (), 1, 2, 4, 512,
+                                                   128))
+    by_name = {n: l for n in GDN_NAMES for l in txt.splitlines()
+               if MOSAIC_CALL in l and f"{n}." in l.split(" = ")[0]}
+    assert 'jvp(ff.forward)/linear_attn_1/gdn.scan/' \
+        'gated_delta_rule_head_fwd/pallas_call"' \
+        in by_name["gated_delta_rule_head_fwd"]
+    assert 'transpose(jvp(ff.forward))/linear_attn_1/gdn.scan/' \
+        'gated_delta_rule_head_bwd/pallas_call"' \
+        in by_name["gated_delta_rule_head_bwd"]

@@ -110,19 +110,30 @@ def test_plan_session_bucket_pinning_bit_exact(gpt2_sess):
                                       w[:plen + mnew])
 
 
-def test_staggered_midflight_admission_bit_exact(gpt2_sess):
+def test_staggered_midflight_admission_bit_exact(gpt2_sess, monkeypatch):
     work = _mixed_work(n=8, seed=3)
     want = [_oracle(gpt2_sess, *w) for w in work]
+    # the engine's first segment says when it is in flight and does not
+    # end before the late work is queued: a sleep here was the machine's
+    # load deciding whether anything was still running when it arrived
+    in_flight, queued = threading.Event(), threading.Event()
+    generate = gpt2_sess.ff.generate
+
+    def held(*args, **kw):
+        out = generate(*args, **kw)
+        in_flight.set()
+        assert queued.wait(300.0)
+        return out
+
+    monkeypatch.setattr(gpt2_sess.ff, "generate", held)
     cb = ContinuousBatcher(gpt2_sess, capacity=CAP, eos_token_id=EOS)
     try:
         first = [cb.submit(*w) for w in work[:CAP]]
-        # let the first batch get in flight, then trickle in the rest —
+        # the first batch is in flight; the rest arrive while it is —
         # they must be admitted at segment boundaries into freed slots
-        time.sleep(0.05)
-        late = []
-        for w in work[CAP:]:
-            late.append(cb.submit(*w))
-            time.sleep(0.02)
+        assert in_flight.wait(300.0)
+        late = [cb.submit(*w) for w in work[CAP:]]
+        queued.set()
         got = [s.wait(timeout_s=300.0) for s in first + late]
         midflight = sum(1 for s in first + late if s.admitted_midflight)
     finally:

@@ -171,12 +171,27 @@ def test_guard_real_timing_path():
     assert rec["adopted"] in ("searched", "dp")
 
 
-def test_fit_runs_the_step_the_guard_compiled():
+def test_fit_runs_the_step_the_guard_compiled(monkeypatch):
     """The guard feeds its batch the way fit() does, so the winning
     side's executable is the one training runs — not a near-copy that
     differs in input placement and compiles all over again (on the chip
-    that second compile of BERT-large cost 106 s)."""
+    that second compile of BERT-large cost 106 s). Which side wins is
+    not this test's question and, between two programs this small, is
+    the machine's load: both sides are compiled and timed for real, and
+    the searched side's times are then read as 10 s a step, so the floor
+    is what is adopted in every run."""
+    real, calls = opt_mod._time_strategy, []
+
+    def searched_loses(ff, strategy, info):
+        t, executor, times, run = real(ff, strategy, info)
+        calls.append(t)
+        if len(calls) == 1:             # the searched side is timed first
+            return 10.0, executor, [10.0, 10.0], run
+        return t, executor, times, run
+
+    monkeypatch.setattr(opt_mod, "_time_strategy", searched_loses)
     ff = _searched_model(floor_guard="true", budget=2)
+    assert len(calls) == 2 and ff._floor_guard_record["adopted"] == "dp"
     step = ff.executor.make_train_step().__wrapped__
     assert step._cache_size() == 1
     rng = np.random.default_rng(0)

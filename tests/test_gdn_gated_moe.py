@@ -16,15 +16,15 @@ under what a wrong pairing of heads, one decay for all heads, a whole
 head turned or a lost gate moves.
 """
 import dataclasses
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells
-from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+import rank_family as rf
+from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.executor import _find_remat_blocks
 from flexflow_tpu.ffconst import DataType
 from flexflow_tpu.models.nlp import (HybridConvMoEConfig, KeyeRankConfig,
@@ -35,112 +35,35 @@ from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
 from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp, RMSNormOp
 from flexflow_tpu.ops.recurrent_ops import (GatedDeltaRuleOp,
                                             gated_delta_rule)
-from flexflow_tpu.ops.registry import EmitCtx
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
+from rank_family import B, apart, close, f32_ctx, program
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                        "gdn_gated_moe_ref")
-TOL = 2e-4
-B, S = 2, 48
-
-
-def close(got, want, tol=TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"relative error {err:.3e} > {tol}"
+ref = rf.reference("gdn_gated_moe_ref")
+S = 48
+build = functools.partial(rf.build, Qwen3NextRankConfig,
+                          build_hybrid_conv_moe, seq=S)
+data = functools.partial(rf.data, seq=S)
 
 
-def apart(got, want, tol=50 * TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    assert float(np.max(np.abs(got - want))) / scale > tol
-
-
-def f32_ctx(training=True):
-    cfg = FFConfig()
-    cfg.use_bf16_compute = False
-    return EmitCtx(training=training, config=cfg)
-
-
-def sizes_of(mc):
-    return dict(dataclasses.asdict(mc),
-                num_experts_published=mc.num_experts_published
-                or mc.num_experts)
-
-
-def build(remat="none", model_cfg=None, seq=S):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True        # no search: 0.3 s a compile
-    cfg.use_bf16_compute = False
-    cfg.remat = remat
-    ff = FFModel(cfg)
-    mc = model_cfg or Qwen3NextRankConfig.tiny()
-    out = build_hybrid_conv_moe(ff, B, seq, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    return ff, mc
-
-
-def data(mc, seed=1, seq=S):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, mc.vocab_size, (B, seq)).astype(np.int32)
-    pos = np.tile(np.arange(seq, dtype=np.int32), (B, 1))
-    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
-            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-
-
-def spread(params, seed=3):
+def spread(params):
     """The seed's weights with every zero-centred scale off 0, the gated
     norm's off 1, the attention gate's projection three times as large
     and the shared expert's scalar gate off a half, so that a plain
     scale where ``1 + w`` belongs, a lost gate and a gate that is not
     0.5 all show."""
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, ws in params.items():
-        out[name] = {}
-        for k, w in ws.items():
-            if k in ("scale", "q_norm", "k_norm"):
-                w = w + jnp.asarray(rng.uniform(-0.5, 0.5, w.shape), w.dtype)
-            elif k == "o_norm":
-                w = w * jnp.asarray(rng.uniform(0.5, 1.5, w.shape), w.dtype)
-            elif k in ("wg", "wz") and not name.startswith("experts_"):
-                w = w * 3.0
-            elif k == "ws_scalar":
-                w = w * 8.0
-            out[name][k] = w
-    return out
+    def rule(name, k, w, rng):
+        if k in ("scale", "q_norm", "k_norm"):
+            return rf.shifted(w, rng)
+        if k == "o_norm":
+            return rf.scaled(w, rng)
+        if k in ("wg", "wz") and not name.startswith("experts_"):
+            return w * 3.0
+        if k == "ws_scalar":
+            return w * 8.0
+    return rf.spread(params, rule)
 
 
-def named(ff, params):
-    return [(l.name, params[l.name]) for l in ff.layers
-            if l.name in params]
-
-
-def program(ff, params, batch, training=True):
-    """``(loss, metrics, probabilities)`` of the program's step."""
-    ex = ff.executor
-    outs, _, aux, capture = ex._forward(
-        params, ff.state, batch, training, jnp.int32(0))
-    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
-    return loss, bm, outs[0]
-
-
-def reference_loss(ff, mc, params, batch):
-    return ref.loss(named(ff, params), sizes_of(mc), batch["input_ids"],
-                    batch["position_ids"], batch["label"][..., 0])
-
-
-jitted = jax.jit
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    ff, mc = build()
-    return ff, mc, data(mc), spread(ff.params)
+tiny, tiny_step = rf.fixtures(build, data, spread)
 
 
 # ----------------------------------------------------------------------
@@ -178,13 +101,18 @@ def gdn_input(seq=S, seed=1):
         size=(B, seq, E)), jnp.float32)
 
 
-def run_gdn(x, w, chunk=16):
+def gdn_layer(x, w, chunk=16):
+    """``(output, counters)`` of the layer, traced where it is called."""
     ctx = f32_ctx()
     (y,) = GatedDeltaRuleOp().emit(dict(GDN, chunk=chunk), [x], w, ctx,
                                    "linear_attn")
-    return y, ctx
+    return y, ctx.counters
 
 
+run_gdn = jax.jit(gdn_layer, static_argnames="chunk")
+
+
+@jax.jit
 def want_gdn(x, w):
     with jax.default_matmul_precision("highest"):
         return ref.linear_attention(x, w, SIZES)
@@ -207,9 +135,9 @@ def test_the_layer_and_every_gradient_are_the_token_by_token_references(
         y = want_gdn(x, w)
         return jnp.sum(y * jnp.cos(y)), y
 
-    (_, y1), (gx1, gw1) = jitted(jax.value_and_grad(got, (0, 1),
+    (_, y1), (gx1, gw1) = jax.jit(jax.value_and_grad(got, (0, 1),
                                                     has_aux=True))(x, w)
-    (_, y2), (gx2, gw2) = jitted(jax.value_and_grad(want, (0, 1),
+    (_, y2), (gx2, gw2) = jax.jit(jax.value_and_grad(want, (0, 1),
                                                     has_aux=True))(x, w)
     close(y1, y2)
     close(gx1, gx2, 1e-3)
@@ -244,7 +172,9 @@ def test_a_reference_of_another_form_is_apart(what):
     x, w = gdn_input(), gdn_weights()
     got, _ = run_gdn(x, w)
     close(got, want_gdn(x, w))
-    with jax.default_matmul_precision("highest"):
+
+    @jax.jit
+    def another_form(x, w):
         q, k, v, g, beta = ref.linear_inputs(x, w)
         z = jnp.einsum("bse,ehd->bshd", x, w["wz"])
         gate = jax.nn.silu(z)
@@ -259,7 +189,10 @@ def test_a_reference_of_another_form_is_apart(what):
             y = ref.rms_norm(o * gate, w["o_norm"], 1e-6)
         else:
             y = ref.rms_norm(o, w["o_norm"], 1e-6) * gate
-        apart(got, jnp.einsum("bshd,hde->bse", y, w["wo"]))
+        return jnp.einsum("bshd,hde->bse", y, w["wo"])
+
+    with jax.default_matmul_precision("highest"):
+        apart(got, another_form(x, w))
 
 
 def test_decays_that_overflow_when_formed_apart():
@@ -272,15 +205,15 @@ def test_decays_that_overflow_when_formed_apart():
     q, k = ref.unit(arr(1, HK, 64, D)), ref.unit(arr(1, HK, 64, D))
     v, beta = arr(1, HV, 64, D), jax.nn.sigmoid(arr(1, HV, 64))
     g = -jnp.asarray(rng.uniform(4.0, 9.0, (1, HV, 64)), jnp.float32)
-    got, least = jitted(lambda *a: gated_delta_rule(*a, 64))(
+    got, least = jax.jit(lambda *a: gated_delta_rule(*a, 64))(
         q, k, v, g, beta)
     assert float(least) < -250 and np.all(np.isfinite(np.asarray(got)))
     with jax.default_matmul_precision("highest"):
-        want = ref.delta_rule_by_token(*(
+        want = jax.jit(ref.delta_rule_by_token)(*(
             jnp.moveaxis(a, 1, 2) for a in (
                 jnp.repeat(q, 2, 1), jnp.repeat(k, 2, 1), v, g, beta)))
     close(got, jnp.moveaxis(want, 2, 1), 1e-5)
-    grads = jitted(jax.grad(lambda *a: jnp.sum(
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(
         gated_delta_rule(*a, 64)[0] ** 2), (0, 1, 2, 3, 4)))(
         q, k, v, g, beta)
     assert all(np.all(np.isfinite(np.asarray(a))) for a in grads)
@@ -290,7 +223,9 @@ def test_the_layers_span_and_counters():
     events.enable()
     events.clear()
     try:
-        _, ctx = run_gdn(gdn_input(), gdn_weights(), 16)
+        # (a trace of its own: the instant is said while tracing)
+        _, counters = jax.jit(lambda x, w: gdn_layer(x, w, 16))(
+            gdn_input(), gdn_weights())
         (scan,) = [e["attrs"] for e in events.events()
                    if e["name"] == "gdn.scan"]
         assert not [e for e in events.events() if e["name"] == "kda.scan"]
@@ -302,9 +237,9 @@ def test_the_layers_span_and_counters():
         "key_head_dim": D, "head_dim": D, "taps": TAPS, "tokens": B * S,
         "chunk": 16, "chunks": 3, "state_bytes": 4 * B * 3 * HV * D * D,
         "impl": "plain"}
-    assert float(ctx.counters["gdn.scans"]) == 1.0
-    assert float(ctx.counters["gdn.log_decay_min"]) < 0.0
-    assert not [k for k in ctx.counters if k.startswith("kda.")]
+    assert float(counters["gdn.scans"]) == 1.0
+    assert float(counters["gdn.log_decay_min"]) < 0.0
+    assert not [k for k in counters if k.startswith("kda.")]
 
 
 @pytest.mark.parametrize("fields,match", [
@@ -344,11 +279,16 @@ def attn_weights(seed=0):
 
 
 def run_attn(x, pos, w, impl="xla", **over):
-    ctx = f32_ctx()
-    ctx.kernel_impls = {"attention": impl}
-    (y,) = MultiHeadAttentionOp().emit(dict(ATTN, **over), [x, x, x, pos],
-                                       w, ctx, "attn")
-    return y
+    return jax.jit(lambda x, pos, w: MultiHeadAttentionOp().emit(
+        dict(ATTN, **over), [x, x, x, pos], w, f32_ctx(impl=impl),
+        "attn")[0])(x, pos, w)
+
+
+def whole_turn(x, pos, w):
+    """The layer without ``rotary_dim``: the whole head turns."""
+    whole = {k: v for k, v in ATTN.items() if k != "rotary_dim"}
+    return jax.jit(lambda x, pos, w: MultiHeadAttentionOp().emit(
+        whole, [x, x, x, pos], w, f32_ctx(), "attn")[0])(x, pos, w)
 
 
 @pytest.mark.parametrize("impl", ["xla", "flash"])
@@ -365,9 +305,9 @@ def test_the_partial_turn_and_every_gradient_are_the_references(impl):
             y = ref.attention(x, pos, w, ATTN_SIZES)
         return jnp.sum(y * jnp.cos(y)), y
 
-    (_, y1), (gx1, gw1) = jitted(jax.value_and_grad(got, (0, 1),
+    (_, y1), (gx1, gw1) = jax.jit(jax.value_and_grad(got, (0, 1),
                                                     has_aux=True))(x, w)
-    (_, y2), (gx2, gw2) = jitted(jax.value_and_grad(want, (0, 1),
+    (_, y2), (gx2, gw2) = jax.jit(jax.value_and_grad(want, (0, 1),
                                                     has_aux=True))(x, w)
     close(y1, y2)
     close(gx1, gx2, 1e-3)
@@ -379,19 +319,15 @@ def test_a_whole_turn_a_plain_scale_and_no_turn_are_apart():
     x, w = gdn_input(), attn_weights()
     pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), (B, 1))
     part = run_attn(x, pos, w)
-    whole = {k: v for k, v in ATTN.items() if k != "rotary_dim"}
-    ctx = f32_ctx()
-    (turned,) = MultiHeadAttentionOp().emit(whole, [x, x, x, pos], w, ctx,
-                                            "attn")
+    turned = whole_turn(x, pos, w)
     apart(part, turned)
     with jax.default_matmul_precision("highest"):
-        close(turned, ref.attention(x, pos, w, dict(
-            ATTN_SIZES, partial_rotary_factor=1.0)))
+        close(turned, jax.jit(lambda x, w: ref.attention(x, pos, w, dict(
+            ATTN_SIZES, partial_rotary_factor=1.0)))(x, w))
     apart(part, run_attn(x, pos, w, qk_norm_zero_centered=False))
     # turning by position 0 is no turn, of a part or of the whole
-    (still,) = MultiHeadAttentionOp().emit(
-        whole, [x, x, x, jnp.zeros_like(pos)], w, f32_ctx(), "attn")
-    close(run_attn(x, jnp.zeros_like(pos), w), still, 1e-6)
+    close(run_attn(x, jnp.zeros_like(pos), w),
+          whole_turn(x, jnp.zeros_like(pos), w), 1e-6)
 
 
 def test_the_norm_instant_says_what_turned_and_by_which_path():
@@ -456,10 +392,13 @@ def test_a_zero_centred_norm():
     w = {"scale": jnp.asarray(np.random.default_rng(0).uniform(
         -0.5, 0.5, E), jnp.float32)}
     op = RMSNormOp()
-    (y,) = op.emit({"eps": 1e-6, "zero_centered": True}, [x], w, f32_ctx(),
-                   "norm")
+
+    def norm(**params):
+        return jax.jit(lambda x, w: op.emit(dict(eps=1e-6, **params), [x], w,
+                                           f32_ctx(), "norm")[0])(x, w)
+
+    y, plain = norm(zero_centered=True), norm()
     close(y, ref.rms_norm(x, 1.0 + w["scale"], 1e-6), 1e-6)
-    (plain,) = op.emit({"eps": 1e-6}, [x], w, f32_ctx(), "norm")
     apart(y, plain)
     (spec,) = op.weights({"zero_centered": True}, [(B, S, E)],
                          [DataType.DT_FLOAT])
@@ -503,9 +442,9 @@ def test_the_gated_shared_expert_forward_and_backward():
             y = ref.routed(x, w, EXPERT_SIZES) + ref.shared(x, w)
         return jnp.sum(y * jnp.cos(y)), y
 
-    (_, (y1, counters)), (gx1, gw1) = jitted(jax.value_and_grad(
+    (_, (y1, counters)), (gx1, gw1) = jax.jit(jax.value_and_grad(
         got, (0, 1), has_aux=True))(x, w)
-    (_, y2), (gx2, gw2) = jitted(jax.value_and_grad(
+    (_, y2), (gx2, gw2) = jax.jit(jax.value_and_grad(
         want, (0, 1), has_aux=True))(x, w)
     close(y1, y2)
     close(gx1, gx2, 1e-3)
@@ -573,13 +512,11 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
 # ----------------------------------------------------------------------
 def test_the_model_is_the_reference_log_probabilities_and_loss(tiny):
     ff, mc, batch, params = tiny
-    loss, bm, probs = jitted(lambda p: program(ff, p, batch,
-                                               training=False))(params)
-    want = jitted(lambda p: ref.gdn_gated_moe_decoder(
-        named(ff, p), sizes_of(mc), batch["input_ids"],
-        batch["position_ids"]))(params)
+    loss, bm, probs = program(ff, params, batch, training=False)
+    want = rf.reference_call(ref.gdn_gated_moe_decoder, ff, mc, params,
+                             batch)
     close(jnp.log(probs), want)
-    close(loss, jitted(lambda p: reference_loss(ff, mc, p, batch))(params))
+    close(loss, rf.reference_loss(ref, ff, mc, params, batch))
     assert float(bm[COUNTER_PREFIX + "gdn.scans"]) == 3.0
     assert float(bm[COUNTER_PREFIX + "attn.gate_layers"]) == 1.0
     assert float(bm[COUNTER_PREFIX + "moe.dropped"]) == 0.0
@@ -626,12 +563,10 @@ def test_a_model_without_one_form_is_apart_from_the_reference(field, value):
     ff, _ = build(model_cfg=mc)
     batch = data(mc)
     params = spread(ff.params)
-    _, _, probs = jitted(lambda p: program(ff, p, batch, False))(params)
-    whole = sizes_of(Qwen3NextRankConfig.tiny())
+    _, _, probs = program(ff, params, batch, False)
     try:
-        want = jitted(lambda p: ref.gdn_gated_moe_decoder(
-            named(ff, p), whole, batch["input_ids"],
-            batch["position_ids"]))(params)
+        want = rf.reference_call(ref.gdn_gated_moe_decoder, ff,
+                                 Qwen3NextRankConfig.tiny(), params, batch)
     except ref.ReferenceMismatch:
         assert field in ("shared_expert_gate", "attention_output_gate")
         return
@@ -639,14 +574,13 @@ def test_a_model_without_one_form_is_apart_from_the_reference(field, value):
     apart(jnp.log(probs), want, 1e-3)
 
 
-def test_every_gradient_is_the_references(tiny):
+def test_every_gradient_is_the_references(tiny, tiny_step):
     """The cross-entropy's gradient for every weight: the decay's, the
     full-rank gate's, both kinds of norm and the shared expert's scalar
     gate among them."""
     ff, mc, batch, params = tiny
-    got = jitted(jax.grad(lambda p: program(ff, p, batch)[0]))(params)
-    want = jitted(jax.grad(
-        lambda p: reference_loss(ff, mc, p, batch)))(params)
+    _, got = tiny_step
+    want = rf.reference_gradients(ref, ff, mc, params, batch)
     seen = set()
     for name, ws in params.items():
         for k in ws:
@@ -677,27 +611,10 @@ def test_the_remat_finder_takes_the_period_for_four_blocks():
     assert ff.executor._remat[:3] == (start, unit, reps)
 
 
-def test_a_rematerialised_step_is_the_step_and_trains():
-    plain, mc = build()
+def test_a_rematerialised_step_is_the_step_and_trains(tiny, tiny_step):
+    _, _, batch, params = tiny
     remat, _ = build(remat="blocks")
-    batch = data(mc)
-    params = spread(plain.params)
-
-    def both(ff):
-        def f(p):
-            loss, bm, _ = program(ff, p, batch)
-            return loss, bm
-        return jitted(jax.value_and_grad(f, has_aux=True))(params)
-
-    (l1, bm1), g1 = both(plain)
-    (l2, bm2), g2 = both(remat)
-    close(l2, l1, 1e-6)
-    for key in bm1:
-        if key.startswith(COUNTER_PREFIX):
-            close(bm2[key], bm1[key], 1e-6)
-    for name, ws in g1.items():
-        for k in ws:
-            close(g2[name][k], ws[k], 1e-5)
+    rf.same_step(rf.step_and_gradients(remat, params, batch), tiny_step)
     step = remat.executor.make_train_step()
     before = jax.tree.map(np.asarray, remat.params["linear_attn_0"])
     losses = []
@@ -715,9 +632,8 @@ def test_a_rematerialised_step_is_the_step_and_trains():
 def test_the_older_graphs_name_none_of_the_new_parameters(cls):
     """A graph built from the classes the older cells use has the layers
     and parameters it had: the new fields live on
-    ``Qwen3NextRankConfig`` alone. (``tests/test_window_gated_moe.py``
-    pins the sha256 of the six older rank configurations' lowered steps,
-    cell 5's and cell 8's among them.)"""
+    ``Qwen3NextRankConfig`` alone. (``tests/test_lowered_steps.py``
+    pins the sha256 of every rank configuration's lowered step.)"""
     ff = FFModel(FFConfig())
     build_hybrid_conv_moe(ff, 1, 32, cls.tiny())
     for l in ff.layers:

@@ -173,7 +173,8 @@ def test_the_recurrence_through_the_kernels_is_the_token_by_token_one(
         want, d_want = jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(by_token(*a) * mix),
             argnums=range(5)))(*args)
-        close(gated_delta_rule(*args)[0], by_token(*args))
+        close(jax.jit(lambda *a: gated_delta_rule(*a)[0])(*args),
+              jax.jit(by_token)(*args))
     close(got, want)
     if decay >= 3.0:
         assert float(least) < -88.7
@@ -192,7 +193,8 @@ def test_an_output_through_the_kernels_does_not_move_when_later_inputs_change(
     args, other = inputs(130, seed=5), inputs(130, seed=6)
     moved = [jnp.concatenate([a[:, :, :t + 1], b[:, :, t + 1:]], 2)
              for a, b in zip(args, other)]
-    base, after = gated_delta_rule(*args)[0], gated_delta_rule(*moved)[0]
+    rule = jax.jit(lambda *a: gated_delta_rule(*a)[0])
+    base, after = rule(*args), rule(*moved)
     np.testing.assert_array_equal(np.asarray(base[:, :, :t + 1]),
                                   np.asarray(after[:, :, :t + 1]))
     assert float(jnp.max(jnp.abs(base[:, :, t + 1:]
@@ -205,10 +207,11 @@ def test_many_chunks_are_padded_to_whole_grid_steps(group, steps):
     three of four chunks of two; the padded ones write nothing and the
     outputs are the ten chunks'."""
     args = inputs(10 * CHUNK - 3, seed=7, group=group)
-    *terms, _ = kernel.head_chunk_terms(*args, CHUNK, jnp.float32)
+    *terms, _ = jax.jit(lambda *a: kernel.head_chunk_terms(
+        *a, CHUNK, jnp.float32))(*args)
     padded = steps * (kernel.HEAD_CHUNKS_PER_STEP // group)
     assert [x.shape[0] for x in terms] == [padded] * 6
-    want, _ = plain_terms(*args)
+    want, _ = jax.jit(plain_terms)(*args)
     with jax.default_matmul_precision("highest"):
         for got, w in zip(terms, want):
             close(got[:10], w)
@@ -225,12 +228,12 @@ def test_a_chunk_whose_decays_sum_past_float32s_range_gives_finite_terms():
     args[3] = -jnp.asarray(rng.uniform(4.0, 9.0, args[3].shape),
                            jnp.float32)
     with jax.default_matmul_precision("highest"):
-        got, least = kernel_terms(*args)
-        want, _ = plain_terms(*args)
+        got, least = jax.jit(kernel_terms)(*args)
+        want, _ = jax.jit(plain_terms)(*args)
         for a, b in zip(got, want):
             close(a, b)
-        out, _ = gated_delta_rule(*args)
-        close(out, by_token(*args), 1e-5)
+        out, _ = jax.jit(gated_delta_rule)(*args)
+        close(out, jax.jit(by_token)(*args), 1e-5)
         grads = jax.jit(jax.grad(lambda *a: jnp.sum(
             gated_delta_rule(*a)[0] ** 2), range(5)))(*args)
     assert float(least) < -250
@@ -297,7 +300,8 @@ def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
     events.enable()
     events.clear()
     try:
-        jax.grad(loss, argnums=1)(u, w)
+        # (the instants are said while tracing: no value is wanted)
+        jax.eval_shape(jax.grad(loss, argnums=1), u, w)
         seen = events.events()
     finally:
         events.disable()

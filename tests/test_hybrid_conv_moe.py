@@ -16,16 +16,16 @@ after the rotation or a query head on the wrong kv head, each of which
 moves the result by 1e-1 or more.
 """
 import dataclasses
+import functools
 import logging
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells
-from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+import rank_family as rf
+from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.analysis.plan_verifier import verify_plan
 from flexflow_tpu.executor import _find_remat_blocks
 from flexflow_tpu.ffconst import DataType, OperatorType
@@ -33,77 +33,18 @@ from flexflow_tpu.models.nlp import (HybridConvMoEConfig, LFM2RankConfig,
                                      build_hybrid_conv_moe)
 from flexflow_tpu.obs import events
 from flexflow_tpu.ops.nn_ops import GatedShortConvOp, MultiHeadAttentionOp
-from flexflow_tpu.ops.registry import EmitCtx
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
 from flexflow_tpu.search import opshard
+from rank_family import B, close, f32_ctx
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                        "hybrid_conv_moe_ref")
-TOL = 2e-4
-B, S = 2, 32
-
-
-def close(got, want, tol=TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"relative error {err:.3e} > {tol}"
+ref = rf.reference("hybrid_conv_moe_ref")
+S = 32
+build = functools.partial(rf.build, HybridConvMoEConfig,
+                          build_hybrid_conv_moe, seq=S, attention="xla")
+data = functools.partial(rf.data, seq=S)
 
 
-def f32_ctx(training=True):
-    cfg = FFConfig()
-    cfg.use_bf16_compute = False
-    cfg.kernel_impls = "attention:xla"
-    return EmitCtx(training=training, config=cfg)
-
-
-def build(remat="none", attention="xla", model_cfg=None, seq=S):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True        # no search: 0.3 s a compile
-    cfg.use_bf16_compute = False
-    cfg.kernel_impls = f"attention:{attention}"
-    cfg.remat = remat
-    ff = FFModel(cfg)
-    mc = model_cfg or HybridConvMoEConfig.tiny()
-    out = build_hybrid_conv_moe(ff, B, seq, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    return ff, mc
-
-
-def data(mc, seed=1, seq=S):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, mc.vocab_size, (B, seq)).astype(np.int32)
-    pos = np.tile(np.arange(seq, dtype=np.int32), (B, 1))
-    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
-            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-
-
-def named(ff, params):
-    return [(l.name, params[l.name]) for l in ff.layers
-            if l.name in params]
-
-
-def program_loss(ff, params, batch, training=True):
-    ex = ff.executor
-    outs, _, aux, capture = ex._forward(
-        params, ff.state, batch, training, jnp.int32(0))
-    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
-    return loss, (bm, outs[0])
-
-
-def reference_loss(ff, mc, params, batch):
-    return ref.loss(named(ff, params), dataclasses.asdict(mc),
-                    batch["input_ids"], batch["position_ids"],
-                    batch["label"][..., 0])
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    ff, mc = build()
-    return ff, mc, data(mc)
+tiny, tiny_step = rf.fixtures(build, data)
 
 
 # ----------------------------------------------------------------------
@@ -130,6 +71,7 @@ def conv_weights(e=16, k=3, seed=0):
             "w_out": rng.normal(size=(e, e)).astype(np.float32) / 4}
 
 
+@jax.jit
 def conv_op(u, w):
     (y,) = GatedShortConvOp().emit({"taps": w["taps"].shape[1]}, [u], w,
                                    f32_ctx(), "conv")
@@ -151,10 +93,10 @@ def test_the_conv_op_is_the_equations_values_and_gradients(length):
             precision="highest")
     probe = jnp.asarray(np.random.default_rng(7).normal(
         size=(2, length, 16)).astype(np.float32))
-    got = jax.grad(lambda u, w: jnp.sum(conv_op(u, w) * probe),
-                   argnums=(0, 1))(jnp.asarray(u), w)
-    want = jax.grad(lambda u, w: jnp.sum(by_jnp(u, w) * probe),
-                    argnums=(0, 1))(jnp.asarray(u), w)
+    got = jax.jit(jax.grad(lambda u, w: jnp.sum(conv_op(u, w) * probe),
+                          argnums=(0, 1)))(jnp.asarray(u), w)
+    want = jax.jit(jax.grad(lambda u, w: jnp.sum(by_jnp(u, w) * probe),
+                           argnums=(0, 1)))(jnp.asarray(u), w)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         close(a, b, 1e-5)
 
@@ -222,21 +164,26 @@ def test_qk_norm_before_the_rotation_is_the_references_attention(qk_norm):
          for n, s in specs.items()}
     u = jnp.asarray(rng.normal(size=(B, S, e)).astype(np.float32))
     pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), (B, 1))
-    (got,) = op.emit(params, [u, u, u, pos], w, f32_ctx(False), "attn")
     sizes = {"norm_eps": 1e-5, "rope_parameters": {"rope_theta": 10000.0}}
     normed = dict(w) if qk_norm else dict(
         w, q_norm=jnp.ones(d), k_norm=jnp.ones(d))
-    with jax.default_matmul_precision("highest"):
-        want = ref.attention(u, pos, normed, sizes)
+
+    @jax.jit
+    def emit(*positions):
+        return op.emit(params, [u, u, u, *positions], w, f32_ctx(False),
+                       "attn")[0]
+
+    @jax.jit
+    def want_at(pos):
+        with jax.default_matmul_precision("highest"):
+            return ref.attention(u, pos, normed, sizes)
+
+    got, want = emit(pos), want_at(pos)
     if qk_norm:
         close(got, want)
         # the positions given are what the rotation turns by
-        (moved,) = op.emit(params, [u, u, u, pos + 3], w, f32_ctx(False),
-                           "attn")
-        with jax.default_matmul_precision("highest"):
-            close(moved, ref.attention(u, pos + 3, normed, sizes))
-        (default,) = op.emit(params, [u, u, u], w, f32_ctx(False), "attn")
-        close(default, got, 1e-6)
+        close(emit(pos + 3), want_at(pos + 3))
+        close(emit(), got, 1e-6)
     else:
         scale = float(jnp.max(jnp.abs(want)))
         assert float(jnp.max(jnp.abs(got - want))) > 0.1 * scale
@@ -299,22 +246,19 @@ def test_the_builder_refuses_a_layout_it_cannot_lay_out():
 def test_log_probabilities_and_loss_match_the_reference(attention):
     ff, mc = build(attention=attention)
     batch = data(mc)
-    loss, (_, probs) = program_loss(ff, ff.params, batch, training=False)
-    want = ref.hybrid_conv_moe_decoder(
-        named(ff, ff.params), dataclasses.asdict(mc), batch["input_ids"],
-        batch["position_ids"])
+    loss, _, probs = rf.program(ff, ff.params, batch, training=False)
+    want = rf.reference_call(ref.hybrid_conv_moe_decoder, ff, mc, ff.params,
+                             batch)
     close(jnp.log(probs), want)
-    close(loss, reference_loss(ff, mc, ff.params, batch))
+    close(loss, rf.reference_loss(ref, ff, mc, ff.params, batch))
     assert set(ff.executor.resolved_attention_impls.values()) == {
         attention}
 
 
 def test_every_weights_gradient_matches_the_reference(tiny):
     ff, mc, batch = tiny
-    got = jax.jit(jax.grad(
-        lambda p: program_loss(ff, p, batch)[0]))(ff.params)
-    want = jax.jit(jax.grad(
-        lambda p: reference_loss(ff, mc, p, batch)))(ff.params)
+    _, got = rf.step_and_gradients(ff, ff.params, batch)
+    want = rf.reference_gradients(ref, ff, mc, ff.params, batch)
     assert set(got) == set(want)
     for name in got:
         for key in got[name]:
@@ -329,9 +273,24 @@ def test_every_weights_gradient_matches_the_reference(tiny):
     assert not np.any(np.asarray(got["experts_4"]["bias"]))
 
 
+@pytest.fixture(scope="module")
+def share():
+    """One rank's model under ``remat = "blocks"`` at 256 tokens, and
+    its step's and the reference's gradients as functions of the weights
+    (compiled once for both cases below)."""
+    mc = dataclasses.replace(HybridConvMoEConfig.tiny(), num_experts=4,
+                             num_experts_published=32,
+                             first_held_expert=8)
+    ff, mc = build(remat="blocks", model_cfg=mc, seq=4 * S)
+    batch = data(mc, seq=4 * S)
+    return ff, mc, rf.stepper(ff, batch), rf.reference_grader(ref, ff, mc,
+                                                              batch)
+
+
 @pytest.mark.parametrize("overflow", [False, True],
                          ids=["inside_the_budget", "over_it"])
-def test_a_share_of_the_experts_under_remat_is_the_reference_too(overflow):
+def test_a_share_of_the_experts_under_remat_is_the_reference_too(share,
+                                                                 overflow):
     """One rank's model (experts 8 to 11 of 32, as the benchmark's cell
     holds 8 of 64) at 256 tokens: 1,024 sorted rows a layer against a
     budget of 512, so every expert layer has its loop, three of the four
@@ -339,21 +298,15 @@ def test_a_share_of_the_experts_under_remat_is_the_reference_too(overflow):
     fit; with 2 added to the held experts' bias every choice is theirs
     and every layer runs a second chunk. Either way every weight's
     gradient is the reference's and the counters leave the blocks."""
-    share = dataclasses.replace(HybridConvMoEConfig.tiny(), num_experts=4,
-                                num_experts_published=32,
-                                first_held_expert=8)
-    ff, mc = build(remat="blocks", model_cfg=share, seq=4 * S)
+    ff, mc, step, reference = share
     assert ff.executor._remat is not None
-    batch = data(mc, seq=4 * S)
     params = ff.params
     if overflow:
         params = {n: dict(w, bias=w["bias"].at[8:12].add(2.0))
                   if n.startswith("experts_") else w
                   for n, w in params.items()}
-    (_, (bm, _)), got = jax.jit(jax.value_and_grad(
-        lambda p: program_loss(ff, p, batch), has_aux=True))(params)
-    want = jax.jit(jax.grad(
-        lambda p: reference_loss(ff, mc, p, batch)))(params)
+    (_, bm), got = step(params)
+    want = reference(params)
     for name in got:
         for key in got[name]:
             close(got[name][key], want[name][key])
@@ -369,14 +322,11 @@ def test_a_share_of_the_experts_under_remat_is_the_reference_too(overflow):
 def test_the_reference_refuses_a_graph_it_does_not_know(tiny):
     ff, mc, batch = tiny
     sizes = dataclasses.asdict(mc)
-    layers = named(ff, ff.params)
     for wrong, match in (
             (dict(sizes, layer_types=["full_attention"] * 5), "expects"),
             (dict(sizes, num_dense_layers=2), "expects"),
             (dict(sizes, layer_types=["conv"] * 4), "layer_types")):
-        with pytest.raises(ref.ReferenceMismatch, match=match):
-            ref.hybrid_conv_moe_decoder(layers, wrong, batch["input_ids"],
-                                        batch["position_ids"])
+        rf.refuses(ref, ref.hybrid_conv_moe_decoder, match, ff, wrong, batch)
 
 
 # ----------------------------------------------------------------------
@@ -479,13 +429,20 @@ def test_decode_through_the_cache_norms_its_keys_too():
                              * 0.3)
          for s in op.weights(params, [(1, 8, e)] * 3, [DataType.DT_FLOAT] * 3)}
     u = jnp.asarray(rng.normal(size=(1, 8, e)).astype(np.float32))
-    (full,) = op.emit(params, [u, u, u], w, f32_ctx(False), "attn")
-    ctx = f32_ctx(False)
-    ctx.kv_mode = "prefill"
-    op.emit(params, [u, u, u], w, ctx, "attn")
-    cache = {"attn": {k: v.at[:, 7:].set(0.0)
-                      for k, v in ctx.new_kv["attn"].items()}}
-    dec = f32_ctx(False)
-    dec.kv_mode, dec.kv_cache, dec.kv_index = "decode", cache, jnp.int32(7)
-    (row,) = op.emit(params, [u[:, 7:], u[:, 7:], u[:, 7:]], w, dec, "attn")
+    @jax.jit
+    def full_and_decoded(u, w):
+        (full,) = op.emit(params, [u, u, u], w, f32_ctx(False), "attn")
+        ctx = f32_ctx(False)
+        ctx.kv_mode = "prefill"
+        op.emit(params, [u, u, u], w, ctx, "attn")
+        cache = {"attn": {k: v.at[:, 7:].set(0.0)
+                          for k, v in ctx.new_kv["attn"].items()}}
+        dec = f32_ctx(False)
+        dec.kv_mode, dec.kv_cache, dec.kv_index = "decode", cache, \
+            jnp.int32(7)
+        (row,) = op.emit(params, [u[:, 7:], u[:, 7:], u[:, 7:]], w, dec,
+                         "attn")
+        return full, row
+
+    full, row = full_and_decoded(u, w)
     close(row[:, 0], full[:, 7], 1e-5)

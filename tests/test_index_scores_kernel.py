@@ -30,8 +30,12 @@ def operands(rows, keys, j, c, seed=0, causal=False):
 
 
 def plain(qi, ki, wi, d, mdt):
-    scores, pull = jax.vjp(lambda *a: dsa.index_scores(*a, mdt), qi, ki, wi)
-    return scores, pull(d)
+    @jax.jit
+    def scores_and_pulled(qi, ki, wi, d):
+        scores, pull = jax.vjp(lambda *a: dsa.index_scores(*a, mdt), qi, ki,
+                               wi)
+        return scores, pull(d)
+    return scores_and_pulled(qi, ki, wi, d)
 
 
 # (query rows, keys): one tile; three key tiles; a chunk whose keys end

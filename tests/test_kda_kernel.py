@@ -155,7 +155,8 @@ def test_the_recurrence_through_the_kernels_is_the_token_by_token_one(
         want, d_want = jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(by_token(*a) * mix),
             argnums=range(5)))(*args)
-        close(gated_delta_rule(*args)[0], by_token(*args))
+        close(jax.jit(lambda *a: gated_delta_rule(*a)[0])(*args),
+              jax.jit(by_token)(*args))
     close(got, want)
     if decay >= 5.0:
         assert not np.isfinite(np.exp(np.float32(-float(least))))
@@ -171,7 +172,8 @@ def test_an_output_through_the_kernels_does_not_move_when_later_inputs_change(
     args, other = inputs(130, seed=5), inputs(130, seed=6)
     moved = [jnp.concatenate([a[:, :, :t + 1], b[:, :, t + 1:]], 2)
              for a, b in zip(args, other)]
-    base, after = gated_delta_rule(*args)[0], gated_delta_rule(*moved)[0]
+    rule = jax.jit(lambda *a: gated_delta_rule(*a)[0])
+    base, after = rule(*args), rule(*moved)
     np.testing.assert_array_equal(np.asarray(base[:, :, :t + 1]),
                                   np.asarray(after[:, :, :t + 1]))
     assert float(jnp.max(jnp.abs(base[:, :, t + 1:]
@@ -182,9 +184,10 @@ def test_many_chunks_are_padded_to_whole_grid_steps():
     """Ten chunks run as two grid steps of eight; the padded six write
     nothing and the outputs are the ten chunks'."""
     args = inputs(10 * CHUNK - 3, seed=7, heads=1)
-    *terms, _ = kernel.chunk_terms(*args, CHUNK, jnp.float32)
+    *terms, _ = jax.jit(lambda *a: kernel.chunk_terms(
+        *a, CHUNK, jnp.float32))(*args)
     assert [x.shape[0] for x in terms] == [16] * 6
-    want, _ = plain_terms(*args)
+    want, _ = jax.jit(plain_terms)(*args)
     with jax.default_matmul_precision("highest"):
         for got, w in zip(terms, want):
             close(got[:10], w)
@@ -232,7 +235,8 @@ def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
     events.enable()
     events.clear()
     try:
-        jax.grad(loss, argnums=1)(u, w)
+        # (the instants are said while tracing: no value is wanted)
+        jax.eval_shape(jax.grad(loss, argnums=1), u, w)
         seen = events.events()
     finally:
         events.disable()

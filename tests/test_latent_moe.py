@@ -13,83 +13,29 @@ wrong gate, a dropped assignment or a rotation by the wrong pair, each
 of which moves the result by 1e-1 or more.
 """
 import dataclasses
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells
-from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+import rank_family as rf
+from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.models.nlp import LatentMoEConfig, build_latent_moe
 from flexflow_tpu.obs import events
 from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
-from flexflow_tpu.ops.registry import EmitCtx
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX, is_count
+from rank_family import B, close, f32_ctx
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                        "latent_moe_ref")
-TOL = 2e-4
-B, S = 2, 32
-
-
-def close(got, want, tol=TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"relative error {err:.3e} > {tol}"
+ref = rf.reference("latent_moe_ref")
+S = 32
+build = functools.partial(rf.build, LatentMoEConfig, build_latent_moe, seq=S,
+                          attention="xla")
+data = functools.partial(rf.data, seq=S)
 
 
-def build(remat="none", attention="xla", model_cfg=None, seed=0):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True        # no search: 0.3 s a compile
-    cfg.use_bf16_compute = False
-    cfg.kernel_impls = f"attention:{attention}"
-    cfg.remat = remat
-    cfg.seed = seed
-    ff = FFModel(cfg)
-    mc = model_cfg or LatentMoEConfig.tiny()
-    out = build_latent_moe(ff, B, S, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    return ff, mc
-
-
-def data(mc, seed=1):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, mc.vocab_size, (B, S)).astype(np.int32)
-    pos = np.tile(np.arange(S, dtype=np.int32), (B, 1))
-    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
-            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-
-
-def named(ff, params):
-    return [(l.name, params[l.name]) for l in ff.layers
-            if l.name in params]
-
-
-def program_loss(ff, params, batch, training=True):
-    ex = ff.executor
-    outs, _, aux, capture = ex._forward(
-        params, ff.state, batch, training, jnp.int32(0))
-    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
-    mtp = next(l for l in ex.program.layers if l.name == "mtp_loss")
-    return loss, (bm, outs[0], capture.get(mtp.inputs[0].guid))
-
-
-def reference_loss(ff, mc, params, batch):
-    return ref.loss(named(ff, params), dataclasses.asdict(mc),
-                    batch["input_ids"], batch["position_ids"],
-                    batch["label"][..., 0])
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    ff, mc = build()
-    return ff, mc, data(mc)
+tiny, tiny_step = rf.fixtures(build, data)
 
 
 def test_the_bias_changes_some_tokens_choice(tiny):
@@ -110,23 +56,27 @@ def test_the_bias_changes_some_tokens_choice(tiny):
 def test_heads_and_loss_match_the_reference(attention):
     ff, mc = build(attention=attention)
     batch = data(mc)
-    loss, (_, probs, mtp_logits) = program_loss(ff, ff.params, batch,
-                                                training=False)
-    main, mtp = ref.heads(named(ff, ff.params), dataclasses.asdict(mc),
-                          batch["input_ids"], batch["position_ids"])
+    mtp_loss = next(l for l in ff.executor.program.layers
+                    if l.name == "mtp_loss")
+
+    def heads(params):
+        loss, _, outs, _, capture = rf.forward(ff, params, batch,
+                                               training=False)
+        return loss, outs[0], capture.get(mtp_loss.inputs[0].guid)
+
+    loss, probs, mtp_logits = jax.jit(heads)(rf.on_one_device(ff.params))
+    main, mtp = rf.reference_call(ref.heads, ff, mc, ff.params, batch)
     close(jnp.log(probs), main)
     close(jax.nn.log_softmax(mtp_logits, -1), mtp)
-    close(loss, reference_loss(ff, mc, ff.params, batch))
+    close(loss, rf.reference_loss(ref, ff, mc, ff.params, batch))
     assert set(ff.executor.resolved_attention_impls.values()) == {
         attention}
 
 
-def test_every_weights_gradient_matches_the_reference(tiny):
+def test_every_weights_gradient_matches_the_reference(tiny, tiny_step):
     ff, mc, batch = tiny
-    got = jax.jit(jax.grad(
-        lambda p: program_loss(ff, p, batch)[0]))(ff.params)
-    want = jax.jit(jax.grad(
-        lambda p: reference_loss(ff, mc, p, batch)))(ff.params)
+    _, got = tiny_step
+    want = rf.reference_gradients(ref, ff, mc, ff.params, batch)
     assert {n for n in got} == {n for n in want}
     for name in got:
         for key in got[name]:
@@ -154,12 +104,14 @@ def _experts_layer(mc, x, weights, first, held, with_shared=True):
     """One routed-experts op holding experts ``first .. first + held``."""
     w = {k: v for k, v in _share(weights, first, held).items()
          if with_shared or not k.startswith("ws_")}
-    cfg = FFConfig()
-    cfg.use_bf16_compute = False
-    ctx = EmitCtx(training=True, config=cfg)
-    (y,) = RoutedExpertsOp().emit(_op_params(mc, first, held), [x], w, ctx,
-                                  "experts")
-    return y, ctx.counters
+
+    def layer(x, w):
+        ctx = f32_ctx()
+        (y,) = RoutedExpertsOp().emit(_op_params(mc, first, held), [x], w,
+                                      ctx, "experts")
+        return y, ctx.counters
+
+    return jax.jit(layer)(x, w)
 
 
 def _share(weights, first, held):
@@ -191,8 +143,7 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny, shared):
         sizes, plain = _layer_sizes, ref
     else:
         once = 0.0
-        plain = cells.load_module(os.path.join(ROOT, "benchmarks"),
-                                  "reference", "hybrid_conv_moe_ref")
+        plain = rf.reference("hybrid_conv_moe_ref")
         w = {k: v for k, v in w.items() if not k.startswith("ws_")}
 
         def sizes(mc, held, first):
@@ -297,8 +248,7 @@ def test_one_chunk_or_two_are_the_reference_in_value_and_gradient(
     input and every weight, say whether a second chunk ran, and drop
     nothing."""
     mc, x, w, first, held = _wide_layer(shared, overflow)
-    plain = ref if shared else cells.load_module(
-        os.path.join(ROOT, "benchmarks"), "reference", "hybrid_conv_moe_ref")
+    plain = ref if shared else rf.reference("hybrid_conv_moe_ref")
     sizes = {"num_experts_per_tok": mc.num_experts_per_tok,
              "routed_scaling_factor": mc.routed_scaling_factor,
              "first_held_expert": first}
@@ -381,25 +331,20 @@ def test_a_layer_loops_only_where_the_budget_is_not_every_row(
     assert " cond[" not in text
 
 
-def test_rematerialised_blocks_give_the_same_step_and_their_counters():
+def test_rematerialised_blocks_give_the_same_step_and_their_counters(
+        tiny, tiny_step):
     """``remat = "blocks"`` wraps the expert layers in ``jax.checkpoint``:
     same loss, same gradients, and the layers' counters come out of the
     blocks (summed over the two in the run and the module's one)."""
-    plain, mc = build()
+    _, mc, batch = tiny
     remat, _ = build(remat="blocks")
     assert remat.executor._remat is not None
     start, unit, reps = remat.executor._remat[:3]
     block = remat.executor.program.layers[start:start + unit]
     assert [l.name for l in block][:2] == ["input_norm_1", "attn_1"]
     assert reps == 2 and block[-2].name == "experts_1"
-    batch = data(mc)
-    results = []
-    for ff in (plain, remat):
-        (loss, (bm, _, _)), grads = jax.jit(jax.value_and_grad(
-            lambda p, ff=ff: program_loss(ff, p, batch), has_aux=True))(
-                ff.params)
-        results.append((loss, bm, grads))
-    (l0, bm0, g0), (l1, bm1, g1) = results
+    (l0, bm0), g0 = tiny_step
+    (l1, bm1), g1 = rf.step_and_gradients(remat, remat.params, batch)
     close(l1, l0, 1e-6)
     for name in g0:
         for key in g0[name]:
@@ -533,10 +478,14 @@ def test_rows_the_grouped_products_leave_unwritten_reach_nothing(
         y, _ = _experts_layer(mc, x, w, 4, 4)     # 12 of 16 experts absent
         return jnp.sum(jnp.sin(y)), y
 
-    (_, y0), g0 = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(x, w)
+    def both():
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                         has_aux=True))(x, w)
+
+    (_, y0), g0 = both()
     monkeypatch.setattr(jax.lax, "ragged_dot", leaves_rows_unwritten)
     jax.clear_caches()           # the chunk's trace is cached by shape
-    (_, y1), g1 = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(x, w)
+    (_, y1), g1 = both()
     jax.clear_caches()
     assert len(faked) >= 3 and np.array_equal(np.asarray(y0), np.asarray(y1))
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):

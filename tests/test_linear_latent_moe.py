@@ -15,14 +15,14 @@ a decay applied after the write, a mask off by one row or a state handed
 over one chunk late, each of which moves the result by 1e-1 or more.
 """
 import dataclasses
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells
+import rank_family as rf
 from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
 from flexflow_tpu.analysis.plan_verifier import verify_plan
 from flexflow_tpu.ffconst import DataType, OperatorType
@@ -33,80 +33,20 @@ from flexflow_tpu.obs import events
 from flexflow_tpu.ops.nn_ops import LatentAttentionOp
 from flexflow_tpu.ops.recurrent_ops import (GatedDeltaRuleOp,
                                             gated_delta_rule)
-from flexflow_tpu.ops.registry import EmitCtx
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
 from flexflow_tpu.search import opshard
+from rank_family import B, close, f32_ctx
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                        "linear_latent_moe_ref")
-older = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                          "latent_moe_ref")
-TOL = 2e-4
-B, S = 2, 40
+ref = rf.reference("linear_latent_moe_ref")
+older = rf.reference("latent_moe_ref")
+S = 40
 H, D = 3, 8
+build = functools.partial(rf.build, KimiLinearRankConfig, build_latent_moe,
+                          seq=S, attention="xla")
+data = functools.partial(rf.data, seq=S)
 
 
-def close(got, want, tol=TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"relative error {err:.3e} > {tol}"
-
-
-def f32_ctx(training=True):
-    cfg = FFConfig()
-    cfg.use_bf16_compute = False
-    cfg.kernel_impls = "attention:xla"
-    return EmitCtx(training=training, config=cfg)
-
-
-def build(remat="none", model_cfg=None, seq=S):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True        # no search: 0.3 s a compile
-    cfg.use_bf16_compute = False
-    cfg.kernel_impls = "attention:xla"
-    cfg.remat = remat
-    ff = FFModel(cfg)
-    mc = model_cfg or KimiLinearRankConfig.tiny()
-    out = build_latent_moe(ff, B, seq, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    return ff, mc
-
-
-def data(mc, seed=1, seq=S):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, mc.vocab_size, (B, seq)).astype(np.int32)
-    pos = np.tile(np.arange(seq, dtype=np.int32), (B, 1))
-    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
-            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-
-
-def named(ff, params):
-    return [(l.name, params[l.name]) for l in ff.layers
-            if l.name in params]
-
-
-def program_loss(ff, params, batch, training=True):
-    ex = ff.executor
-    outs, _, aux, capture = ex._forward(
-        params, ff.state, batch, training, jnp.int32(0))
-    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
-    return loss, (bm, outs[0])
-
-
-def reference_loss(ff, mc, params, batch):
-    return ref.loss(named(ff, params), dataclasses.asdict(mc),
-                    batch["input_ids"], batch["position_ids"],
-                    batch["label"][..., 0])
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    ff, mc = build()
-    return ff, mc, data(mc)
+tiny, tiny_step = rf.fixtures(build, data)
 
 
 # ----------------------------------------------------------------------
@@ -129,6 +69,7 @@ def recurrence_inputs(length, decay=0.3, seed=0):
                                      beta)]
 
 
+@functools.partial(jax.jit, static_argnames="chunk")
 def chunked(q, k, v, g, beta, chunk):
     """The op's recurrence takes heads before positions, the reference
     positions before heads."""
@@ -143,12 +84,12 @@ def both_ways(args, chunk):
     mix = jnp.asarray(np.random.default_rng(9).normal(
         size=args[2].shape).astype(np.float32))
     with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(
+        got = jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(chunked(*a, chunk=chunk)[0] * mix),
-            argnums=range(5))(*args)
-        want = jax.value_and_grad(
+            argnums=range(5)))(*args)
+        want = jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(ref.delta_rule_by_token(*a) * mix),
-            argnums=range(5))(*args)
+            argnums=range(5)))(*args)
         out = chunked(*args, chunk=chunk)
     return got, want, out
 
@@ -162,7 +103,7 @@ def test_the_chunked_recurrence_is_the_token_by_token_one(length):
     args = recurrence_inputs(length)
     (got, d_got), (want, d_want), (out, _) = both_ways(args, 32)
     with jax.default_matmul_precision("highest"):
-        close(out, ref.delta_rule_by_token(*args))
+        close(out, jax.jit(ref.delta_rule_by_token)(*args))
     close(got, want)
     for name, a, b in zip("q k v g beta".split(), d_got, d_want):
         # (one token decays a state that is still zero)
@@ -257,13 +198,14 @@ def test_the_layer_is_the_equations_values_and_every_gradient(length):
 
     def reference(u, w):
         with jax.default_matmul_precision("highest"):
-            return jnp.sum(ref.kda(u, w, {"rms_norm_eps": 1e-5}) * mix)
+            y = ref.kda(u, w, {"rms_norm_eps": 1e-5})
+        return jnp.sum(y * mix), y
 
-    (got, (y, counters)), d_got = jax.value_and_grad(
-        program, argnums=(0, 1), has_aux=True)(u, w)
-    want, d_want = jax.value_and_grad(reference, argnums=(0, 1))(u, w)
-    with jax.default_matmul_precision("highest"):
-        close(y, ref.kda(u, w, {"rms_norm_eps": 1e-5}))
+    (got, (y, counters)), d_got = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(u, w)
+    (want, want_y), d_want = jax.jit(jax.value_and_grad(
+        reference, argnums=(0, 1), has_aux=True))(u, w)
+    close(y, want_y)
     close(got, want)
     close(d_got[0], d_want[0])
     assert set(d_got[1]) == set(ref.KDA)
@@ -275,7 +217,7 @@ def test_the_layer_is_the_equations_values_and_every_gradient(length):
         close(d_got[1][name], d_want[1][name])
     assert float(counters["kda.scans"]) == 1
     with jax.default_matmul_precision("highest"):
-        g = ref.kda_inputs(u, w)[3]
+        g = jax.jit(lambda u, w: ref.kda_inputs(u, w)[3])(u, w)
     pad = -length % 32
     sums = jnp.cumsum(jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0))
                               ).reshape(B, -1, 32, H, D), axis=2)
@@ -402,18 +344,20 @@ def test_latent_attention_with_each_part_on_and_off(q_rank, rope):
              "qk_rope_head_dim": 8, "kv_lora_rank": 32,
              "rope_theta": 10000.0}
 
-    def emit(params, pos):
+    @jax.jit
+    def emit(pos):
         return op.emit(params, [u, pos], w, f32_ctx(False), "attn")[0]
 
-    got = emit(params, pos)
-    with jax.default_matmul_precision("highest"):
-        if q_rank is None:
-            want = ref.latent_attention(u, w, sizes)
-        else:
-            want = older.latent_attention(u, pos if rope else 0 * pos, w,
-                                          sizes)
+    @jax.jit
+    def reference(pos):
+        with jax.default_matmul_precision("highest"):
+            if q_rank is None:
+                return ref.latent_attention(u, w, sizes)
+            return older.latent_attention(u, pos, w, sizes)
+
+    got, want = emit(pos), reference(pos if rope else 0 * pos)
     if q_rank is None and rope:
-        close(emit(params, 0 * pos), want)
+        close(emit(0 * pos), want)
         assert float(jnp.max(jnp.abs(got - want))) > 1e-2
     else:
         close(got, want)
@@ -533,20 +477,17 @@ def test_the_rank_config_takes_the_published_names():
 
 def test_log_probabilities_and_loss_match_the_reference(tiny):
     ff, mc, batch = tiny
-    loss, (_, probs) = program_loss(ff, ff.params, batch, training=False)
-    want = ref.linear_latent_moe_decoder(
-        named(ff, ff.params), dataclasses.asdict(mc), batch["input_ids"],
-        batch["position_ids"])
+    loss, _, probs = rf.program(ff, ff.params, batch, training=False)
+    want = rf.reference_call(ref.linear_latent_moe_decoder, ff, mc,
+                             ff.params, batch)
     close(jnp.log(probs), want)
-    close(loss, reference_loss(ff, mc, ff.params, batch))
+    close(loss, rf.reference_loss(ref, ff, mc, ff.params, batch))
 
 
-def test_every_weights_gradient_matches_the_reference(tiny):
+def test_every_weights_gradient_matches_the_reference(tiny, tiny_step):
     ff, mc, batch = tiny
-    got = jax.jit(jax.grad(
-        lambda p: program_loss(ff, p, batch)[0]))(ff.params)
-    want = jax.jit(jax.grad(
-        lambda p: reference_loss(ff, mc, p, batch)))(ff.params)
+    _, got = tiny_step
+    want = rf.reference_gradients(ref, ff, mc, ff.params, batch)
     assert set(got) == set(want)
     for name in got:
         for key in got[name]:
@@ -565,7 +506,6 @@ def test_every_weights_gradient_matches_the_reference(tiny):
 def test_the_reference_refuses_a_graph_it_does_not_know(tiny):
     ff, mc, batch = tiny
     sizes = dataclasses.asdict(mc)
-    layers = named(ff, ff.params)
     lin = sizes["linear_attn_config"]
     for wrong, match in (
             (dict(sizes, linear_attn_config=dict(
@@ -575,9 +515,8 @@ def test_the_reference_refuses_a_graph_it_does_not_know(tiny):
             (dict(sizes, num_hidden_layers=4), "expects"),
             (dict(sizes, linear_attn_config=dict(lin, kda_layers=[1, 2])),
              "neither")):
-        with pytest.raises(ref.ReferenceMismatch, match=match):
-            ref.linear_latent_moe_decoder(
-                layers, wrong, batch["input_ids"], batch["position_ids"])
+        rf.refuses(ref, ref.linear_latent_moe_decoder, match, ff, wrong,
+                   batch)
 
 
 def test_the_thirty_two_shares_add_up_to_the_uncut_layer():
@@ -606,15 +545,18 @@ def test_the_thirty_two_shares_add_up_to_the_uncut_layer():
 
     routed = sum(share(first, False) for first in range(0, n, held))
     once = share(0, True) - share(0, False)
+    sixth = share(6, False)
     sizes = {"num_experts_per_token": 8, "routed_scaling_factor": 2.446,
              "first_held_expert": 0}
     with jax.default_matmul_precision("highest"):
-        want = ref.routed(x, w, sizes) + ref.shared(x, w)
-        one = ref.routed(x, {k: (v[6:8] if k in ("w_gate", "w_up", "w_down")
-                                 else v) for k, v in w.items()},
-                         dict(sizes, first_held_expert=6))
+        want = jax.jit(lambda x, w: ref.routed(x, w, sizes)
+                      + ref.shared(x, w))(x, w)
+        one = jax.jit(lambda x, w: ref.routed(
+            x, w, dict(sizes, first_held_expert=6)))(x, {
+                k: (v[6:8] if k in ("w_gate", "w_up", "w_down") else v)
+                for k, v in w.items()})
     close(routed + once, want)
-    close(share(6, False), one)
+    close(sixth, one)
     assert float(jnp.max(jnp.abs(one - want))) > 0.1
 
 
@@ -747,80 +689,10 @@ def test_without_the_policy_a_block_runs_its_layer_a_third_time(monkeypatch):
         "kda_0": 2, "kda_1": 3, "kda_2": 3, "kda_4": 2}
 
 
-def test_the_counters_leave_rematerialised_blocks():
+def test_the_counters_leave_rematerialised_blocks(tiny_step):
     ff, mc = build(remat="blocks")
-    _, (bm, _) = jax.jit(lambda p: program_loss(ff, p, data(mc)))(ff.params)
-    plain, mc = build()
-    _, (want, _) = jax.jit(lambda p: program_loss(plain, p, data(mc)))(
-        plain.params)
+    _, bm, _ = rf.program(ff, ff.params, data(mc))
+    (_, want), _ = tiny_step
     assert float(bm[COUNTER_PREFIX + "kda.scans"]) == 4
     assert float(bm[COUNTER_PREFIX + "kda.log_decay_min"]) == pytest.approx(
         float(want[COUNTER_PREFIX + "kda.log_decay_min"]), rel=1e-6)
-
-
-# ----------------------------------------------------------------------
-# the configurations that share the touched code run the parent's program
-# ----------------------------------------------------------------------
-PARENT_STEPS = {        # sha256 of the lowered train step at commit 6d698b5
-    ("latent_moe", "none"):
-        "9ef1f14b8a318985afaa2aeec4a6b52f364738760c59b0368740532216baf40e",
-    ("latent_moe", "blocks"):
-        "45548317dbcc3bbfe3ed1aa29b5fd2098bcaf1c1a02e0e4b33775881a3daec27",
-    ("hybrid_conv_moe", "none"):
-        "986657c7f81d237946de938e8683eb83fb819fe15030b1e198d9259e4613fca6",
-    ("hybrid_conv_moe", "blocks"):
-        "5267450d7768fb65bdbadc5d268369cf04d7535b8a491692efc3da0be97b1e47",
-    # xing4_29b_a4b's builder, at commit 6053682 (PR 46's parent)
-    ("mhc_latent_moe", "none"):
-        "8da932559957478b41da65ade76ee1f85d278f46367190d4b64dbf151b10f50e",
-    ("mhc_latent_moe", "blocks"):
-        "74a6dda12d90e86d5758c3c1a4a1ad9b0697d1b99b8171cf49940d5a64f9a5d7"}
-
-
-def lowered_step(model, remat):
-    """The lowered train step of ``joyai_llm_flash``'s,
-    ``lfm2_24b_a2b``'s or ``xing4_29b_a4b``'s builder: a share of 4 of
-    16 experts, 8 x 32 tokens, default ``FFConfig`` but no search."""
-    from flexflow_tpu.models.nlp import (HybridConvMoEConfig, XingRankConfig,
-                                         build_hybrid_conv_moe)
-    builder, mc = {
-        "latent_moe": (build_latent_moe, dataclasses.replace(
-            LatentMoEConfig.tiny(), n_routed_experts=4,
-            n_routed_experts_published=16)),
-        "hybrid_conv_moe": (build_hybrid_conv_moe, dataclasses.replace(
-            HybridConvMoEConfig.tiny(), num_experts=4,
-            num_experts_published=16)),
-        "mhc_latent_moe": (build_latent_moe, dataclasses.replace(
-            XingRankConfig.tiny(), n_routed_experts=4,
-            n_routed_experts_published=16))}[model]
-    cfg = FFConfig()
-    cfg.batch_size = 8
-    cfg.only_data_parallel = True
-    cfg.remat = remat
-    ff = FFModel(cfg)
-    out = builder(ff, 8, 32, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    ids = np.zeros((8, 32), np.int32)
-    pos = np.tile(np.arange(32, dtype=np.int32), (8, 1))
-    batch = next(iter(ff._combined_loader(
-        [ids, pos], np.zeros((8, 32, 1), np.int32), shuffle=False)))
-    return ff.executor.make_train_step().lower(
-        ff.params, ff.opt_state, ff.state, jnp.int32(0), batch).as_text()
-
-
-@pytest.mark.parametrize("model,remat", sorted(PARENT_STEPS))
-def test_the_older_configurations_lower_to_the_parents_step(model, remat):
-    """``LatentAttentionOp``'s two new parameters and
-    ``LatentMoEConfig``'s three new fields default to the parent's
-    graph: the train steps of ``joyai_llm_flash``'s and
-    ``lfm2_24b_a2b``'s builders lower to the text they lowered to at
-    the parent commit, byte for byte. Since PR 46 ``xing4_29b_a4b``'s
-    too: a rematerialised block is given a policy only where an op
-    inside it says ``keeps_output_for_block``, none of these three's
-    does, and their blocks lower as they did under the plain
-    ``jax.checkpoint``. A later PR that means to change a step replaces
-    the hash it changes."""
-    import hashlib
-    assert hashlib.sha256(lowered_step(model, remat).encode()).hexdigest() \
-        == PARENT_STEPS[model, remat]

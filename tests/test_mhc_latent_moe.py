@@ -15,125 +15,67 @@ under the model without its token-dependent maps or without YaRN (1e-1
 of the log-probabilities), or with 3 iterations for 20 (3e-2).
 """
 import dataclasses
-import hashlib
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells
+import rank_family as rf
 from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
 from flexflow_tpu.analysis.plan_verifier import verify_plan
 from flexflow_tpu.executor import _find_remat_blocks
 from flexflow_tpu.ffconst import DataType, OperatorType
-from flexflow_tpu.models.nlp import (KimiLinearRankConfig, LatentMoEConfig,
-                                     XingRankConfig, build_latent_moe)
+from flexflow_tpu.models.nlp import (LatentMoEConfig, XingRankConfig,
+                                     build_latent_moe)
 from flexflow_tpu.obs import events
 from flexflow_tpu.ops import hyper_ops
 from flexflow_tpu.ops.hyper_ops import HyperConnectionOp
 from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
 from flexflow_tpu.ops.nn_ops import (LatentAttentionOp, rope_frequencies,
                                      yarn_correction_range, yarn_mscale)
-from flexflow_tpu.ops.registry import EmitCtx
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
 from flexflow_tpu.search import opshard
+from rank_family import B, TOL, close, f32_ctx
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                        "mhc_latent_moe_ref")
-TOL = 2e-4
-B, S = 2, 40
+ref = rf.reference("mhc_latent_moe_ref")
+S = 40
 PUBLISHED_YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 64,
                   "mscale": 1, "mscale_all_dim": 1,
                   "original_max_position_embeddings": 4096, "type": "yarn"}
+build = functools.partial(rf.build, XingRankConfig, build_latent_moe, seq=S,
+                          attention="xla")
+data = functools.partial(rf.data, seq=S)
 
 
-def close(got, want, tol=TOL, floor=1e-6):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), floor)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"relative error {err:.3e} > {tol}"
-
-
-def f32_ctx(training=True):
-    cfg = FFConfig()
-    cfg.use_bf16_compute = False
-    cfg.kernel_impls = "attention:xla"
-    return EmitCtx(training=training, config=cfg)
-
-
-def build(remat="none", model_cfg=None):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True        # no search: 0.3 s a compile
-    cfg.use_bf16_compute = False
-    cfg.kernel_impls = "attention:xla"
-    cfg.remat = remat
-    ff = FFModel(cfg)
-    mc = model_cfg or XingRankConfig.tiny()
-    out = build_latent_moe(ff, B, S, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    return ff, mc
-
-
-def data(mc, seed=1):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, mc.vocab_size, (B, S)).astype(np.int32)
-    pos = np.tile(np.arange(S, dtype=np.int32), (B, 1))
-    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
-            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-
-
-def named(ff, params):
-    return [(l.name, params[l.name]) for l in ff.layers
-            if l.name in params]
-
-
-def program_loss(ff, params, batch, training=True):
-    ex = ff.executor
-    outs, _, aux, capture = ex._forward(
-        params, ff.state, batch, training, jnp.int32(0))
-    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
-    return loss, (bm, outs[0])
-
-
-def reference_loss(ff, mc, params, batch):
-    return ref.loss(named(ff, params), dataclasses.asdict(mc),
-                    batch["input_ids"], batch["position_ids"],
-                    batch["label"][..., 0])
+tiny, tiny_step = rf.fixtures(build, data)
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    ff, mc = build()
-    return ff, mc, data(mc)
-
-
-@pytest.fixture(scope="module")
-def both_gradients(tiny):
+def both_gradients(tiny, tiny_step):
     ff, mc, batch = tiny
-    got = jax.jit(jax.grad(lambda p: program_loss(ff, p, batch)[0]))(
-        ff.params)
-    want = jax.jit(jax.grad(
-        lambda p: reference_loss(ff, mc, p, batch)))(ff.params)
-    return got, want
+    return tiny_step[1], rf.reference_gradients(ref, ff, mc, ff.params,
+                                                batch)
+
+
+@pytest.fixture(scope="module")
+def tiny_eval(tiny):
+    """``(loss, metrics, probabilities)`` of the tiny model's forward."""
+    ff, _, batch = tiny
+    return rf.program(ff, ff.params, batch, training=False)
 
 
 # ----------------------------------------------------------------------
 # the whole model against the reference
 # ----------------------------------------------------------------------
-def test_log_probabilities_and_both_losses(tiny):
+def test_log_probabilities_and_both_losses(tiny, tiny_eval):
     ff, mc, batch = tiny
-    loss, (bm, probs) = jax.jit(
-        lambda p: program_loss(ff, p, batch, training=False))(ff.params)
-    main, mtp = ref.heads(named(ff, ff.params), dataclasses.asdict(mc),
-                          batch["input_ids"], batch["position_ids"])
+    loss, bm, probs = tiny_eval
+    main, mtp = rf.reference_call(ref.heads, ff, mc, ff.params, batch)
     close(jnp.log(probs), main)
     assert mtp is not None
-    want = reference_loss(ff, mc, ff.params, batch)
+    want = rf.reference_loss(ref, ff, mc, ff.params, batch)
     close(loss, want, 1e-5)
     ce_main = -jnp.mean(jnp.take_along_axis(main, batch["label"], -1))
     ce_mtp = -jnp.mean(jnp.take_along_axis(
@@ -185,21 +127,18 @@ def test_every_gradient_against_the_references(both_gradients):
 
 @pytest.mark.parametrize("wrong", ["no_dynamic_maps", "no_yarn",
                                    "three_iterations"])
-def test_a_wrong_model_is_told_apart(tiny, wrong):
+def test_a_wrong_model_is_told_apart(tiny, tiny_eval, wrong):
     """The comparison that passes the program fails each of: the maps
     without their token-dependent part, plain rotary frequencies and
     scale, 3 Sinkhorn iterations for 20."""
     ff, mc, batch = tiny
-    _, (_, probs) = jax.jit(
-        lambda p: program_loss(ff, p, batch, training=False))(ff.params)
-    sizes = dataclasses.asdict(mc)
+    _, _, probs = tiny_eval
     knobs = {"no_dynamic_maps": {"dynamic_maps": True},
              "no_yarn": {"yarn": True}, "three_iterations": {}}[wrong]
     if wrong == "three_iterations":
-        sizes["hc_sinkhorn_iters"] = 3
+        mc = dataclasses.replace(mc, hc_sinkhorn_iters=3)
     with ref.without(**knobs):
-        other, _ = ref.heads(named(ff, ff.params), sizes,
-                             batch["input_ids"], batch["position_ids"])
+        other, _ = rf.reference_call(ref.heads, ff, mc, ff.params, batch)
     with pytest.raises(AssertionError, match="relative error"):
         close(jnp.log(probs), other, 50 * TOL)
 
@@ -271,20 +210,27 @@ def test_the_nodes_are_the_references_sub_layer():
     x = jnp.asarray(np.random.default_rng(5).normal(
         size=(B, S, n, c)).astype(np.float32))
     op = HyperConnectionOp()
-    ctx = f32_ctx()
-    u, maps, xs = op.emit(HC_PARAMS, [x], w, ctx, "res_pre")
-    assert xs is x      # the third output is the input: nothing is copied
-    out, = op.emit({"stage": "post"}, [xs, jnp.tanh(u), maps], {}, ctx,
-                   "res")
+
+    @jax.jit
+    def nodes(x, w):
+        ctx = f32_ctx()
+        u, maps, xs = op.emit(HC_PARAMS, [x], w, ctx, "res_pre")
+        assert xs is x  # the third output is the input: nothing is copied
+        out, = op.emit({"stage": "post"}, [xs, jnp.tanh(u), maps], {}, ctx,
+                       "res")
+        return u, maps, out, ctx.counters
+
+    u, maps, out, counters = nodes(x, w)
     sizes = {"rms_norm_eps": 1e-6, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
              "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30}
-    pre, post, res = ref.stream_maps(x, w, sizes)
+    pre, post, res = jax.jit(lambda x, w: ref.stream_maps(x, w, sizes))(x, w)
     close(u, jnp.einsum("bsn,bsnc->bsc", pre, x))
     close(maps[..., :n], post)
     close(maps[..., n:], res.reshape(B, S, n * n))
-    close(out, ref.hyper_connected(x, w, sizes, jnp.tanh))
-    assert float(ctx.counters["mhc.sublayers"]) == 1
-    assert float(ctx.counters["mhc.clamped"]) == 0
+    close(out, jax.jit(lambda x, w: ref.hyper_connected(
+        x, w, sizes, jnp.tanh))(x, w))
+    assert float(counters["mhc.sublayers"]) == 1
+    assert float(counters["mhc.clamped"]) == 0
 
 
 def test_the_clamp_is_reached_on_a_forced_input():
@@ -297,20 +243,27 @@ def test_the_clamp_is_reached_on_a_forced_input():
     x = jnp.asarray(np.random.default_rng(6).normal(
         size=(B, S, n, c)).astype(np.float32))
     op = HyperConnectionOp()
-    ctx = f32_ctx()
-    _, maps, _ = op.emit(HC_PARAMS, [x], w, ctx, "res_pre")
-    assert float(ctx.counters["mhc.clamped"]) == B * S * n * n
+
+    @jax.jit
+    def pre_node(x, w):
+        ctx = f32_ctx()
+        _, maps, _ = op.emit(HC_PARAMS, [x], w, ctx, "res_pre")
+        return maps, ctx.counters
+
+    maps, counters = pre_node(x, w)
+    assert float(counters["mhc.clamped"]) == B * S * n * n
     res = maps[..., n:].reshape(B, S, n, n)
     assert bool(jnp.all(jnp.isfinite(res)))
     close(res, jnp.broadcast_to(jnp.eye(n), res.shape), 1e-5)
     sizes = {"rms_norm_eps": 1e-6, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
              "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30}
-    close(res, ref.stream_maps(x, w, sizes)[2], 1e-5)
+    close(res, jax.jit(lambda x, w: ref.stream_maps(x, w, sizes)[2])(x, w),
+          1e-5)
 
     def through(b_res):
         return jnp.sum(op.emit(HC_PARAMS, [x], dict(w, b_res=b_res),
                                f32_ctx(), "res_pre")[1][..., n:] ** 2)
-    assert not np.any(np.asarray(jax.grad(through)(forced)))
+    assert not np.any(np.asarray(jax.jit(jax.grad(through))(forced)))
 
 
 def test_there_is_no_decode_path():
@@ -386,14 +339,21 @@ def test_latent_attention_under_yarn(scaling):
     sizes = {"rms_norm_eps": 1e-6, "qk_nope_head_dim": 16,
              "qk_rope_head_dim": dr, "kv_lora_rank": 32,
              "rope_theta": 10000.0, "rope_scaling": yarn}
-    got = op.emit(params, [u, pos], w, f32_ctx(False), "attn")[0]
-    with jax.default_matmul_precision("highest"):
-        want = ref.latent_attention(u, pos, w, sizes)
-        with ref.without(yarn=True):
-            plain = ref.latent_attention(u, pos, w, sizes)
+    def emit(params):
+        return jax.jit(lambda u, w: op.emit(
+            params, [u, pos], w, f32_ctx(False), "attn")[0])(u, w)
+
+    def reference():
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(lambda u, w: ref.latent_attention(
+                u, pos, w, sizes))(u, w)
+
+    got, want = emit(params), reference()
+    with ref.without(yarn=True):
+        plain = reference()
     close(got, want)
     del params["rope_scaling"]
-    close(op.emit(params, [u, pos], w, f32_ctx(False), "attn")[0], plain)
+    close(emit(params), plain)
     assert float(jnp.max(jnp.abs(plain - want))) > 0.1
 
 
@@ -431,20 +391,24 @@ def test_the_head_shares_add_up_to_the_uncut_layer():
     u = jnp.asarray(rng.normal(size=(B, S, e)).astype(np.float32))
     pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), (B, 1))
 
+    @functools.partial(jax.jit, static_argnums=1)
+    def emit(cut, held):    # one compiled layer for the eight shares
+        return op.emit(dict(params, num_heads=held), [u, pos], cut,
+                       f32_ctx(False), "attn")[0]
+
     def share(first, held):
         cut = dict(w, wq_b=w["wq_b"][:, first:first + held],
                    wkv_b=w["wkv_b"][:, first:first + held],
                    wo=w["wo"][first:first + held])
-        return op.emit(dict(params, num_heads=held), [u, pos], cut,
-                       f32_ctx(False), "attn")[0], cut
+        return emit(cut, held), cut
 
     parts = [share(first, 2)[0] for first in range(0, heads, 2)]
     sizes = {"rms_norm_eps": 1e-6, "qk_nope_head_dim": 16,
              "qk_rope_head_dim": dr, "kv_lora_rank": 32,
              "rope_theta": 10000.0, "rope_scaling": yarn}
     with jax.default_matmul_precision("highest"):
-        want = ref.latent_attention(u, pos, w, sizes)
-        one = ref.latent_attention(u, pos, share(2, 2)[1], sizes)
+        reference = jax.jit(lambda w: ref.latent_attention(u, pos, w, sizes))
+        want, one = reference(w), reference(share(2, 2)[1])
     close(sum(parts), want)
     close(parts[1], one)
     assert float(jnp.max(jnp.abs(one - want))) > 0.1
@@ -455,7 +419,7 @@ def test_the_expert_shares_add_up_to_the_uncut_layer(tiny):
     plus the shared expert counted once, are the uncut reference's
     layer output (top 4 of 16, scale 2)."""
     ff, mc, _ = tiny
-    w = ff.params["experts_2"]
+    w = rf.on_one_device(ff.params["experts_2"])
     x = jax.random.normal(jax.random.key(5), (B, S, mc.hidden_size))
 
     def share(first, held, with_shared):
@@ -463,21 +427,20 @@ def test_the_expert_shares_add_up_to_the_uncut_layer(tiny):
                    if k in ("w_gate", "w_up", "w_down") else v)
                for k, v in w.items()
                if with_shared or not k.startswith("ws_")}
-        cfg = FFConfig()
-        cfg.use_bf16_compute = False
         return RoutedExpertsOp().emit(
             dict(num_experts=mc.n_routed_experts,
                  top_k=mc.num_experts_per_tok,
                  expert_dim=mc.moe_intermediate_size,
                  shared_dim=mc.moe_intermediate_size, experts_held=held,
                  first_held=first, scale=mc.routed_scaling_factor),
-            [x], cut, EmitCtx(training=True, config=cfg), "experts")[0]
+            [x], cut, f32_ctx(), "experts")[0]
 
     routed = sum(share(first, 2, False) for first in range(0, 16, 2))
     once = share(0, 2, True) - share(0, 2, False)
     sizes = dataclasses.asdict(mc)
     with jax.default_matmul_precision("highest"):
-        want = ref.routed(x, w, sizes) + ref.shared(x, w)
+        want = jax.jit(lambda x, w: ref.routed(x, w, sizes)
+                      + ref.shared(x, w))(x, w)
     close(routed + once, want)
     assert float(jnp.max(jnp.abs(share(0, 2, True) - want))) > 0.1
 
@@ -603,18 +566,12 @@ def test_the_remat_finder_on_the_new_graph():
         assert readers == [pre.name]
 
 
-def test_rematerialised_blocks_give_the_same_step_and_counters():
+def test_rematerialised_blocks_give_the_same_step_and_counters(tiny,
+                                                               tiny_step):
     ff, mc = build(remat="blocks")
     assert ff.executor._remat[1:3] == (8, 2)
-    plain, _ = build()
-    batch = data(mc)
-
-    def both(model):
-        return jax.jit(jax.value_and_grad(
-            lambda p: program_loss(model, p, batch), has_aux=True))(
-            model.params)
-    (loss, (bm, _)), grads = both(ff)
-    (want, (want_bm, _)), want_grads = both(plain)
+    (loss, bm), grads = rf.step_and_gradients(ff, ff.params, tiny[2])
+    (want, want_bm), want_grads = tiny_step
     close(loss, want, 1e-6)
     for name in grads:
         for key in grads[name]:
@@ -687,60 +644,14 @@ def test_spans_and_counters_in_a_fit():
         events.clear()
 
 
-# ----------------------------------------------------------------------
-# the configurations that share the touched code run the parent's program
-# ----------------------------------------------------------------------
-def lowered_hash(mc, remat, batch=8, seq=32):
-    cfg = FFConfig()
-    cfg.batch_size = batch
-    cfg.only_data_parallel = True
-    cfg.remat = remat
-    ff = FFModel(cfg)
-    out = build_latent_moe(ff, batch, seq, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    ids = np.zeros((batch, seq), np.int32)
-    pos = np.tile(np.arange(seq, dtype=np.int32), (batch, 1))
-    feed = next(iter(ff._combined_loader(
-        [ids, pos], np.zeros((batch, seq, 1), np.int32), shuffle=False)))
-    text = ff.executor.make_train_step().lower(
-        ff.params, ff.opt_state, ff.state, jnp.int32(0), feed).as_text()
-    return hashlib.sha256(text.encode()).hexdigest()
-
-
-PARENT_STEPS = {        # sha256 of the lowered train step at commit 8147231
-    "none": "5e04d18d98ea5881bd95cda59ceab336337212b623e8812d02f9ef664e978cd0",
-    # replaced by PR 46, which meant to change it: the two rematerialised
-    # blocks keep their linear-attention layer's output and do not run
-    # the layer a third time (d7a76c8f... before)
-    "blocks": "d02e51cd694f12a4d942f2ef2febe39d845876598737582948d6eb5db9e09a27"}
-
-
-@pytest.mark.parametrize("remat", sorted(PARENT_STEPS))
-def test_the_linear_attention_configuration_lowers_to_the_parents_step(
-        remat):
-    """``kimi_linear_48b_a3b``'s builder path (a share of 4 of 16
-    experts, 8 x 32 tokens, default ``FFConfig`` but no search) lowers
-    to the text it lowered to at the parent commit, byte for byte:
-    ``hc_mult`` and ``rope_scaling`` absent are the parent's graph
-    (``"none"`` is also the witness that the layer's mark on its output
-    is the identity outside a rematerialised block).
-    ``joyai_llm_flash``'s is held by ``tests/test_linear_latent_moe.py::
-    test_the_older_configurations_lower_to_the_parents_step``, whose
-    hashes this PR leaves as they were."""
-    mc = dataclasses.replace(KimiLinearRankConfig.tiny(), num_experts=4,
-                             num_experts_published=16)
-    assert lowered_hash(mc, remat) == PARENT_STEPS[remat]
-
-
 def test_one_stream_and_no_scaling_is_the_older_graph():
     """The new configuration class with ``hc_mult`` 1 and
     ``rope_scaling`` None builds the parent class's graph: the residual
     rule is chosen from the configuration, not from the class."""
-    older = dataclasses.replace(LatentMoEConfig.tiny(),
-                                routed_scaling_factor=2.0)
-    same = dataclasses.replace(XingRankConfig.tiny(), hc_mult=1,
-                               rope_scaling=None)
-    assert lowered_hash(same, "blocks") == lowered_hash(older, "blocks")
-    assert lowered_hash(XingRankConfig.tiny(), "blocks") \
-        != lowered_hash(older, "blocks")
+    def lowered(mc):
+        return rf.lowered_step(mc, build_latent_moe, "blocks")
+    older = lowered(dataclasses.replace(LatentMoEConfig.tiny(),
+                                        routed_scaling_factor=2.0))
+    assert lowered(dataclasses.replace(XingRankConfig.tiny(), hc_mult=1,
+                                       rope_scaling=None)) == older
+    assert lowered(XingRankConfig.tiny()) != older

@@ -16,13 +16,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+import rank_family as rf
+from flexflow_tpu import FFConfig
 from flexflow_tpu.kernels import qk_norm_rope as kernel
 from flexflow_tpu.models.nlp import KeyeRankConfig, build_hybrid_conv_moe
 from flexflow_tpu.obs import events
 from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp, _apply_rope, _rms
 from flexflow_tpu.ops.registry import EmitCtx
-from flexflow_tpu.parallel.machine import MachineSpec
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
 
 TOL = 1e-5
@@ -287,19 +287,9 @@ def small_keye():
 
 
 def build(impl, remat):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True
-    cfg.use_bf16_compute = False
-    cfg.remat = remat
-    cfg.kernel_impls = f"attention:{impl}"
-    ff = FFModel(cfg)
-    mc = small_keye()
-    out = build_hybrid_conv_moe(ff, B, SEQ, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out,
-               machine_spec=MachineSpec.detect(jax.devices()[:1]))
-    return ff, mc
+    return rf.build(KeyeRankConfig, build_hybrid_conv_moe, remat,
+                    model_cfg=small_keye(), batch=B, seq=SEQ, attention=impl,
+                    devices=1)
 
 
 #: what ``examples/tpu_validate_sparse_index_moe.py`` holds on the chip
@@ -318,26 +308,9 @@ def test_a_small_model_through_the_kernel_is_the_model_on_xla(remat):
     ``x``)."""
     plain_ff, mc = build("xla", remat)
     kernel_ff, _ = build("flash", remat)
-    rng = np.random.default_rng(1)
-    ids = rng.integers(0, mc.vocab_size, (B, SEQ)).astype(np.int32)
-    batch = {"input_ids": jnp.asarray(ids),
-             "position_ids": jnp.tile(jnp.arange(SEQ, dtype=jnp.int32),
-                                      (B, 1)),
-             "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-    params = plain_ff.params
-
-    def both(ff):
-        def f(p):
-            ex = ff.executor
-            outs, _, aux, capture = ex._forward(p, ff.state, batch, True,
-                                                jnp.int32(0))
-            loss, bm = ex._loss_and_metrics(outs, capture, batch["label"],
-                                            aux)
-            return loss, bm
-        return jax.jit(jax.value_and_grad(f, has_aux=True))(params)
-
-    (l1, bm1), g1 = both(plain_ff)
-    (l2, bm2), g2 = both(kernel_ff)
+    batch = rf.data(mc, SEQ, batch=B)
+    (l1, bm1), g1 = rf.step_and_gradients(plain_ff, plain_ff.params, batch)
+    (l2, bm2), g2 = rf.step_and_gradients(kernel_ff, plain_ff.params, batch)
     key = COUNTER_PREFIX + "attn.norm_rope_kernel_layers"
     assert key not in bm1 and float(bm2[key]) == 4.0
     assert float(bm2[COUNTER_PREFIX + "dsa.kernel_layers"]) == 4.0

@@ -256,19 +256,18 @@ def test_attach_training_swaps_mid_fit(monkeypatch):
 class _Sess:
     input_names = ["x"]
 
-    def __init__(self, tag, profile=None, delay_s=0.0):
+    def __init__(self, tag, profile=None, gate=None):
         self.tag, self.served = tag, 0
         self._profile = profile or {}
-        self._delay = delay_s
+        self._gate = gate           # an Event a batch waits for
 
     def clone(self):
         return self
 
     def infer(self, inputs):
-        import time as _t
         self.served += 1
-        if self._delay:
-            _t.sleep(self._delay)
+        if self._gate is not None:
+            assert self._gate.wait(15.0)
         return np.zeros((inputs["x"].shape[0], 1), np.float32)
 
     def measured_profile(self):
@@ -277,10 +276,12 @@ class _Sess:
 
 def test_serving_swap_under_load_and_rescore_rollback():
     import threading
+    import time
 
     from flexflow_tpu.serving import BatchScheduler, ModelRepository
+    drained = threading.Event()
     old = _Sess("old", {"1": {"decode_step_s": 0.001, "n": 4}},
-                delay_s=0.02)
+                gate=drained)
     new = _Sess("new", {"1": {"decode_step_s": 0.01, "n": 4}})
     repo = ModelRepository()
     repo.register("m", old)
@@ -296,14 +297,29 @@ def test_serving_swap_under_load_and_rescore_rollback():
             except Exception as e:  # noqa: BLE001
                 errs.append(e)
 
+        def until(what, holds):
+            end = time.monotonic() + 15.0
+            while not holds():
+                assert time.monotonic() < end, what
+                time.sleep(0.002)
+
         inflight = [threading.Thread(target=fire) for _ in range(4)]
         for t in inflight:
             t.start()
+        # the swap starts with all four ADMITTED (a request that meets
+        # the drain at the door is shed, by design, and was this test's
+        # race) and the old session holds its batch until the drain has
+        # begun: the backlog flushes on the old instances under load
+        until("four admitted", lambda: sched._pending == 4)
+        opener = threading.Thread(target=lambda: (
+            until("draining", lambda: sched.stats()["draining"]),
+            drained.set()))
+        opener.start()
         ctl = ReplanController(policy=ReplanPolicy(debounce_polls=1))
         faults.set_link_degradation("dcn", 4.0)
         out = ctl.serve_replan(repo, "m", scheduler=sched,
                                builder=lambda: new, session=old)
-        for t in inflight:
+        for t in inflight + [opener]:
             t.join()
         assert out == "adopted"
         assert not errs and len(results) == 4     # nothing dropped

@@ -13,6 +13,9 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.serving import ModelRepository, serve_async, serve_http
+from flexflow_tpu.serving.async_server import _MAX_HEADER_BYTES, _MAX_HEADERS
+
+STREAM_LIMIT = 64 << 10     # asyncio's default: ``serve_async`` sets none
 
 
 def _free_port():
@@ -237,12 +240,25 @@ def test_header_flood_bounded():
     repo.load_onnx("m", model)
     srv = serve_async(repo, port=_free_port(), block=False)
 
-    def flood(payload):
+    def until(lines, tripped):
+        """The header lines up to and including the first at which
+        ``tripped(count, bytes)`` holds. The server answers at that line,
+        mid-stream (deliberately NO terminating blank line), and nothing
+        is left unread behind it: bytes still unread when it closes come
+        back as a reset, which beside other work reaches the client
+        ahead of the reply."""
+        sent, size = [], 0
+        for line in lines:
+            sent.append(line)
+            size += len(line)
+            if tripped(len(sent), size):
+                return b"".join(sent)
+        raise AssertionError("the flood never reaches the bound")
+
+    def refused(payload):
         s = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
         s.settimeout(10)
         s.sendall(payload)
-        # deliberately NO terminating blank line: the server must
-        # respond from the bound alone, mid-stream
         data = b""
         while True:
             try:
@@ -253,35 +269,29 @@ def test_header_flood_bounded():
                 break              # server closed — required
             data += chunk
         s.close()
-        return data
+        head = data.split(b"\r\n\r\n", 1)[0].decode("latin1").lower()
+        assert "400" in head.split("\r\n")[0], head
+        assert "connection: close" in head
 
+    get = b"GET /v2/health/ready HTTP/1.1\r\n"
     try:
-        # byte bound: ~80 KB of header lines (cap is 64 KB)
-        big = b"GET /v2/health/ready HTTP/1.1\r\n" + \
-            b"".join(b"x-filler-%d: %s\r\n" % (i, b"v" * 100)
-                     for i in range(800))
-        head = flood(big).split(b"\r\n\r\n", 1)[0].decode("latin1").lower()
-        assert "400" in head.split("\r\n")[0], head
-        assert "connection: close" in head
-        # count bound: 300 tiny headers (cap is 256) is only ~3 KB
-        many = b"GET /v2/health/ready HTTP/1.1\r\n" + \
-            b"".join(b"h%d: a\r\n" % i for i in range(300))
-        head = flood(many).split(b"\r\n\r\n", 1)[0].decode("latin1").lower()
-        assert "400" in head.split("\r\n")[0], head
-        assert "connection: close" in head
-        # ONE header line at/over the asyncio stream limit (64 KiB):
+        # byte bound: 1 KB header lines past the 64 KB cap, well under
+        # the count cap
+        big = until((b"x-filler-%d: %s\r\n" % (i, b"v" * 1000)
+                     for i in range(800)),
+                    lambda n, size: size > _MAX_HEADER_BYTES)
+        assert big.count(b"\r\n") < _MAX_HEADERS
+        refused(get + big)
+        # count bound: one tiny header past the cap of 256 is only ~2 KB
+        many = until((b"h%d: a\r\n" % i for i in range(300)),
+                     lambda n, size: n > _MAX_HEADERS)
+        refused(get + many)
+        # ONE header line over the asyncio stream limit (64 KiB):
         # readline raises before the byte bound can trip — must still
         # be a framed 400-close, not a dead socket
-        one = b"GET /v2/health/ready HTTP/1.1\r\n" + \
-            b"x-huge: " + b"v" * (80 << 10) + b"\r\n"
-        head = flood(one).split(b"\r\n\r\n", 1)[0].decode("latin1").lower()
-        assert "400" in head.split("\r\n")[0], head
-        assert "connection: close" in head
+        refused(get + b"x-huge: " + b"v" * (STREAM_LIMIT + 1 - 8))
         # ...and an oversized REQUEST line gets the same treatment
-        head = flood(b"GET /" + b"a" * (80 << 10)) \
-            .split(b"\r\n\r\n", 1)[0].decode("latin1").lower()
-        assert "400" in head.split("\r\n")[0], head
-        assert "connection: close" in head
+        refused(b"GET /" + b"a" * (STREAM_LIMIT + 1 - 5))
         # the server is still healthy for well-formed clients
         ready = json.loads(urllib.request.urlopen(
             f"http://127.0.0.1:{srv.port}/v2/health/ready").read())

@@ -18,7 +18,6 @@ the same sets exactly.
 """
 import dataclasses
 import functools
-import os
 import re
 
 import jax
@@ -26,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells
+import rank_family as rf
 from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
 from flexflow_tpu.executor import _find_remat_blocks
 from flexflow_tpu.models.nlp import (HybridConvMoEConfig, KeyeRankConfig,
@@ -34,95 +33,32 @@ from flexflow_tpu.models.nlp import (HybridConvMoEConfig, KeyeRankConfig,
 from flexflow_tpu.ops import sparse_attention as dsa
 from flexflow_tpu.ops.moe_ops import RoutedExpertsOp, route
 from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp
-from flexflow_tpu.ops.registry import EmitCtx
-from flexflow_tpu.parallel.machine import MachineSpec
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
+from rank_family import B, close, f32_ctx, named, sizes_of
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                        "sparse_index_moe_ref")
-TOL = 2e-4
-B, S = 2, 48              # tiny(): 24 keys a query in chunks of 16
-
-
-def close(got, want, tol=TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"relative error {err:.3e} > {tol}"
+ref = rf.reference("sparse_index_moe_ref")
+S = 48                    # tiny(): 24 keys a query in chunks of 16
+build = functools.partial(rf.build, KeyeRankConfig, build_hybrid_conv_moe,
+                          seq=S)
+data = functools.partial(rf.data, seq=S)
 
 
-def f32_ctx(training=True):
-    cfg = FFConfig()
-    cfg.use_bf16_compute = False
-    return EmitCtx(training=training, config=cfg)
-
-
-def sizes_of(mc):
-    return dict(dataclasses.asdict(mc),
-                num_experts_published=mc.num_experts_published
-                or mc.num_experts)
-
-
-def build(remat="none", model_cfg=None, seq=S, impl=None):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True        # no search: 0.3 s a compile
-    cfg.use_bf16_compute = False
-    cfg.remat = remat
-    if impl:
-        cfg.kernel_impls = f"attention:{impl}"
-    ff = FFModel(cfg)
-    mc = model_cfg or KeyeRankConfig.tiny()
-    out = build_hybrid_conv_moe(ff, B, seq, mc)
-    # a forced path runs on a mesh of one device, as the benchmark's
-    # chip is: on more the masked kernels have no shard_map wrap and the
-    # layer stays on the chunked path
-    one = {"machine_spec": MachineSpec.detect(jax.devices()[:1])} \
-        if impl else {}
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out, **one)
-    return ff, mc
-
-
-def data(mc, seed=1, seq=S):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, mc.vocab_size, (B, seq)).astype(np.int32)
-    pos = np.tile(np.arange(seq, dtype=np.int32), (B, 1))
-    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
-            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-
-
-def spread(params, seed=3):
+def spread(params):
     """The seed's weights with the norms' scales off 1 and the indexer's
     three matrices four times as large, so that a wrong scale and a
     selection that follows the scores both show."""
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, ws in params.items():
-        out[name] = {}
-        for k, w in ws.items():
-            if k in ("scale", "q_norm", "k_norm"):
-                w = w * jnp.asarray(
-                    rng.uniform(0.5, 1.5, w.shape), w.dtype)
-            elif k in ("wq_idx", "wk_idx", "w_idx"):
-                w = w * 4.0
-            out[name][k] = w
-    return out
-
-
-def named(ff, params):
-    return [(l.name, params[l.name]) for l in ff.layers
-            if l.name in params]
+    def rule(name, k, w, rng):
+        if k in ("scale", "q_norm", "k_norm"):
+            return rf.scaled(w, rng)
+        if k in ("wq_idx", "wk_idx", "w_idx"):
+            return w * 4.0
+    return rf.spread(params, rule)
 
 
 def program_terms(ff, params, batch, training=True):
     """``(cross-entropy, sum of L_I, metrics, probabilities)`` of the
     program's step: the loss less its auxiliary terms, and those."""
-    ex = ff.executor
-    outs, _, aux, capture = ex._forward(
-        params, ff.state, batch, training, jnp.int32(0))
-    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
+    loss, bm, outs, aux, _ = rf.forward(ff, params, batch, training)
     kl = sum(aux)
     return loss - kl, kl, bm, outs[0]
 
@@ -132,14 +68,24 @@ def reference_terms(ff, mc, params, batch):
                       batch["position_ids"], batch["label"][..., 0])
 
 
-# eagerly every chunk's loop and checkpoint would run op by op
-jitted = jax.jit
+tiny, tiny_step = rf.fixtures(build, data, spread)
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    ff, mc = build()
-    return ff, mc, data(mc), spread(ff.params)
+@functools.cache
+def terms_and_gradients(remat, attention):
+    """``((loss, (sum of L_I, metrics)), gradients)`` of the model built
+    with ``remat`` and every attention layer forced down ``attention``,
+    at the spread weights. Each of the four is a compile of the whole
+    step; the test of the kernel path and the test of rematerialisation
+    read the same ones (the chunked path is what an unforced layer takes
+    here: the kernels are not chosen in interpret mode)."""
+    ff, mc = build(remat=remat, attention=attention, devices=1)
+    batch = data(mc)
+
+    def f(p):
+        ce, kl, bm, _ = program_terms(ff, p, batch)
+        return ce + kl, (kl, bm)
+    return jax.jit(jax.value_and_grad(f, has_aux=True))(spread(ff.params))
 
 
 # ----------------------------------------------------------------------
@@ -240,9 +186,7 @@ def attn_weights(seed=0, e=32):
 def attn_op(x, pos, w, topk, q_chunk, training=True, impl=None):
     """``(y, L_I, counters)`` of the op with an indexer; ``impl``
     forces its path (on the CPU it takes the chunked one by itself)."""
-    ctx = f32_ctx(training)
-    if impl:
-        ctx.kernel_impls = {"attention": impl}
+    ctx = f32_ctx(training, impl)
     params = dict(ATTN_PARAMS, indexer_heads=J, indexer_head_dim=C,
                   indexer_topk=topk, indexer_q_chunk=q_chunk)
     (y,) = MultiHeadAttentionOp().emit(params, [x, x, x, pos], w, ctx,
@@ -267,13 +211,13 @@ def test_the_layer_is_the_reference_output_loss_and_selection(seq, topk,
     y, kl, counted = attn_op(x, pos, w, topk, q_chunk)
     sizes = dict(ATTN_SIZES, sa_config={"topk": topk})
     with jax.default_matmul_precision("highest"):
-        want_y, want_kl, want_set = jitted(
+        want_y, want_kl, want_set = jax.jit(
             lambda x, w: ref.sparse_attention(x, pos, w, sizes))(x, w)
     close(y, want_y)
     close(kl, want_kl)
     assert float(kl) > 1e-3
-    qi, ki, wi = ref.indexer(x, w)
-    got_set = dsa.selection(qi, ki, wi, topk, q_chunk, jnp.float32)
+    got_set = jax.jit(lambda x, w: dsa.selection(
+        *ref.indexer(x, w), topk, q_chunk, jnp.float32))(x, w)
     assert np.array_equal(np.asarray(got_set), np.asarray(want_set))
     per_row = np.minimum(np.arange(seq) + 1, topk)
     assert float(counted["dsa.kept_pairs"]) == B * per_row.sum() \
@@ -317,7 +261,7 @@ def test_the_chunked_path_is_the_unchunked_one():
         def f(x, w):
             y, kl, _ = attn_op(x, pos, w, 12, q_chunk)
             return jnp.sum(y * jnp.cos(y)) + kl
-        return jitted(jax.value_and_grad(f, (0, 1)))(x, w)
+        return jax.jit(jax.value_and_grad(f, (0, 1)))(x, w)
 
     (v1, (gx1, gw1)), (v2, (gx2, gw2)) = both(48), both(16)
     close(v2, v1, 1e-6)
@@ -334,11 +278,10 @@ def test_no_more_positions_than_topk_is_the_plain_causal_path(seq, topk):
     x, pos = attn_inputs(seq)
     w = attn_weights()
     y, kl, counted = attn_op(x, pos, w, topk, 16)
-    ctx = f32_ctx()
-    ctx.kernel_impls = {"attention": "xla"}
     plain = {k: v for k, v in w.items() if not k.endswith("_idx")}
-    (want,) = MultiHeadAttentionOp().emit(ATTN_PARAMS, [x, x, x, pos],
-                                          plain, ctx, "attn")
+    (want,) = jax.jit(lambda x, w: MultiHeadAttentionOp().emit(
+        ATTN_PARAMS, [x, x, x, pos], w, f32_ctx(impl="xla"), "attn"))(
+            x, plain)
     close(y, want, 1e-6)
     assert float(counted["dsa.kept_pairs"]) \
         == float(counted["dsa.causal_pairs"])
@@ -361,12 +304,12 @@ def test_the_two_losses_reach_disjoint_weights_exactly():
         y = attn_op(x, pos, w, 12, 16)[0]
         return jnp.sum(y * jnp.sin(y))
 
-    gx, gw = jitted(jax.grad(kl_of, (0, 1)))(x, w)
+    gx, gw = jax.jit(jax.grad(kl_of, (0, 1)))(x, w)
     gw_kl = gw
     assert not np.any(np.asarray(gx))
     for k, g in gw.items():
         assert bool(np.any(np.asarray(g))) == (k in index_keys), k
-    gx, gw = jitted(jax.grad(out_of, (0, 1)))(x, w)
+    gx, gw = jax.jit(jax.grad(out_of, (0, 1)))(x, w)
     assert np.any(np.asarray(gx))
     for k, g in gw.items():
         assert bool(np.any(np.asarray(g))) == (k not in index_keys), k
@@ -376,7 +319,7 @@ def test_the_two_losses_reach_disjoint_weights_exactly():
     def ref_kl(w):
         with jax.default_matmul_precision("highest"):
             return ref.sparse_attention(x, pos, w, sizes)[1]
-    want = jitted(jax.grad(ref_kl))(w)
+    want = jax.jit(jax.grad(ref_kl))(w)
     got = gw_kl
     for k in index_keys:
         close(got[k], want[k])
@@ -412,7 +355,7 @@ def test_a_kernel_forced_on_a_layer_with_an_indexer(impl):
         return
     steps = {}
     for forced in ("xla", "flash"):
-        ff, mc = build(remat="blocks", impl=forced)
+        ff, mc = build(remat="blocks", attention=forced, devices=1)
         step, batch = ff.executor.make_train_step(), data(mc)
         p, o, st, first = step(ff.params, ff.opt_state, ff.state,
                                jnp.int32(0), batch)
@@ -447,7 +390,7 @@ def test_the_kernel_path_is_the_chunked_path(seq, topk, q_chunk):
         def f(x, w):
             y, kl, counted = attn_op(x, pos, w, topk, q_chunk, True, impl)
             return jnp.sum(y * jnp.cos(y)) + kl, (y, kl, counted)
-        return jitted(jax.value_and_grad(f, (0, 1), has_aux=True))(x, w)
+        return jax.jit(jax.value_and_grad(f, (0, 1), has_aux=True))(x, w)
 
     ((v1, (y1, kl1, c1)), (gx1, gw1)) = both("xla")
     ((v2, (y2, kl2, c2)), (gx2, gw2)) = both("flash")
@@ -491,7 +434,7 @@ def test_the_kernel_path_reads_grouped_keys_and_values_in_place(kv,
                 q, k, v, qi, ki, wi, topk, q_chunk, jnp.float32,
                 qk_heads_first=heads_first)
             return jnp.sum(jnp.sin(o)) + kl, (o, kl, kept, ties)
-        return jitted(jax.value_and_grad(f, (0, 1, 2, 3, 4, 5),
+        return jax.jit(jax.value_and_grad(f, (0, 1, 2, 3, 4, 5),
                                          has_aux=True))(q, k, v, qi, ki, wi)
 
     (_, got), g_got = run(False)
@@ -520,13 +463,13 @@ def test_the_kernel_paths_losses_reach_disjoint_weights_exactly():
     x, pos = attn_inputs(48)
     w = attn_weights()
     index_keys = {"wq_idx", "wk_idx", "w_idx"}
-    gx, gw = jitted(jax.grad(
+    gx, gw = jax.jit(jax.grad(
         lambda x, w: attn_op(x, pos, w, 12, 16, True, "flash")[1],
         (0, 1)))(x, w)
     assert not np.any(np.asarray(gx))
     for k, g in gw.items():
         assert bool(np.any(np.asarray(g))) == (k in index_keys), k
-    gx, gw = jitted(jax.grad(
+    gx, gw = jax.jit(jax.grad(
         lambda x, w: jnp.sum(jnp.sin(
             attn_op(x, pos, w, 12, 16, True, "flash")[0])), (0, 1)))(x, w)
     assert np.any(np.asarray(gx))
@@ -538,19 +481,8 @@ def test_the_kernel_paths_losses_reach_disjoint_weights_exactly():
 def test_the_kernel_paths_step_is_the_chunked_paths(remat):
     """The model's loss with its four ``L_I``, the counters and every
     gradient, alone and inside a rematerialised step."""
-    chunked, mc = build(remat=remat, impl="xla")
-    kernels, _ = build(remat=remat, impl="flash")
-    batch = data(mc)
-    params = spread(chunked.params)
-
-    def both(ff):
-        def f(p):
-            ce, kl, bm, _ = program_terms(ff, p, batch)
-            return ce + kl, (kl, bm)
-        return jitted(jax.value_and_grad(f, has_aux=True))(params)
-
-    (l1, (kl1, bm1)), g1 = both(chunked)
-    (l2, (kl2, bm2)), g2 = both(kernels)
+    (l1, (kl1, bm1)), g1 = terms_and_gradients(remat, "xla")
+    (l2, (kl2, bm2)), g2 = terms_and_gradients(remat, "flash")
     assert float(kl1) > 0.1
     close(l2, l1, 1e-6)
     close(kl2, kl1, 1e-5)
@@ -571,7 +503,7 @@ def test_the_path_taken_is_on_the_record():
     try:
         for impl in ("xla", "flash"):
             events.clear()
-            ff, mc = build(impl=impl)
+            ff, mc = build(attention=impl, devices=1)
             jax.eval_shape(lambda p: program_terms(ff, p, data(mc)),
                            ff.params)
             seen = [e["attrs"]["impl"] for e in events.events()
@@ -593,7 +525,7 @@ def test_a_rematerialised_block_keeps_what_the_kernel_path_names():
     of the loss's value behind the layer's optimization barrier; nothing
     reads it and XLA drops it (the chip's compiled step holds 5 kernel
     calls a layer, PERF.md section 5)."""
-    ff, mc = build(remat="blocks", impl="flash")
+    ff, mc = build(remat="blocks", attention="flash", devices=1)
     batch = data(mc)
 
     def f(p):
@@ -703,13 +635,13 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 # ----------------------------------------------------------------------
 def test_the_model_is_the_reference_log_probabilities_and_losses(tiny):
     ff, mc, batch, params = tiny
-    ce, kl, bm, probs = jitted(lambda p: program_terms(
+    ce, kl, bm, probs = jax.jit(lambda p: program_terms(
         ff, p, batch, training=False))(params)
-    want = jitted(lambda p: ref.sparse_index_moe_decoder(
+    want = jax.jit(lambda p: ref.sparse_index_moe_decoder(
         named(ff, p), sizes_of(mc), batch["input_ids"],
         batch["position_ids"]))(params)
     close(jnp.log(probs), want)
-    want_ce, want_kl = jitted(
+    want_ce, want_kl = jax.jit(
         lambda p: reference_terms(ff, mc, p, batch))(params)
     close(ce, want_ce)
     close(kl, want_kl)
@@ -729,7 +661,7 @@ def test_the_models_selections_are_the_references(tiny):
     ff, mc, batch, params = tiny
     sizes = sizes_of(mc)
 
-    @jitted
+    @jax.jit
     def masks(params):
         with jax.default_matmul_precision("highest"):
             want = ref.selections(named(ff, params), sizes,
@@ -766,10 +698,10 @@ def test_both_families_of_gradients_are_the_references(tiny):
     def terms(p):
         ce, kl, _, _ = program_terms(ff, p, batch)
         return ce, kl
-    g_ce, g_kl = jitted(lambda p: (
+    g_ce, g_kl = jax.jit(lambda p: (
         jax.grad(lambda p: terms(p)[0])(p),
         jax.grad(lambda p: terms(p)[1])(p)))(params)
-    want_ce, want_kl = jitted(lambda p: (
+    want_ce, want_kl = jax.jit(lambda p: (
         jax.grad(lambda p: reference_terms(ff, mc, p, batch)[0])(p),
         jax.grad(lambda p: reference_terms(ff, mc, p, batch)[1])(p)))(
             params)
@@ -802,19 +734,8 @@ def test_a_rematerialised_step_is_the_step_loss_aux_and_gradients():
     loss, which leaves ``jax.checkpoint`` as an output of the block;
     loss (with the four ``L_I``), counters and every gradient equal the
     step's without rematerialisation."""
-    plain, mc = build()
-    remat, _ = build(remat="blocks")
-    batch = data(mc)
-    params = spread(plain.params)
-
-    def both(ff):
-        def f(p):
-            ce, kl, bm, _ = program_terms(ff, p, batch)
-            return ce + kl, (kl, bm)
-        return jitted(jax.value_and_grad(f, has_aux=True))(params)
-
-    (l1, (kl1, bm1)), g1 = both(plain)
-    (l2, (kl2, bm2)), g2 = both(remat)
+    (l1, (kl1, bm1)), g1 = terms_and_gradients("none", "xla")
+    (l2, (kl2, bm2)), g2 = terms_and_gradients("blocks", "xla")
     assert float(kl1) > 0.1
     close(l2, l1, 1e-6)
     close(kl2, kl1, 1e-6)
@@ -849,8 +770,8 @@ def test_a_train_step_moves_the_indexer_and_lowers_the_loss():
 def test_the_older_graph_names_no_indexer_and_no_score_function(cls):
     """A graph built from the classes ``lfm2_24b_a2b`` uses has the
     parameters it had: the new fields live on ``KeyeRankConfig`` alone.
-    (``tests/test_linear_latent_moe.py`` and ``tests/test_mhc_latent_moe
-    .py`` pin the sha256 of the older configurations' lowered steps.)"""
+    (``tests/test_lowered_steps.py`` pins the sha256 of every rank
+    configuration's lowered step.)"""
     ff = FFModel(FFConfig())
     build_hybrid_conv_moe(ff, 1, 32, cls.tiny() if cls is HybridConvMoEConfig
                           else dataclasses.replace(

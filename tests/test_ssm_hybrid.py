@@ -15,16 +15,16 @@ times what they read and far under what a lost multiplier (12, 0.22,
 convolution bias, ``D`` or a decay of another size moves.
 """
 import dataclasses
+import functools
 import hashlib
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells
-from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+import rank_family as rf
+from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.analysis.plan_verifier import verify_plan
 from flexflow_tpu.executor import _find_remat_blocks
 from flexflow_tpu.ffconst import OperatorType
@@ -35,106 +35,42 @@ from flexflow_tpu.obs import events
 from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp
 from flexflow_tpu.ops.recurrent_ops import (StateSpaceMixerOp,
                                             state_space_scan)
-from flexflow_tpu.ops.registry import EmitCtx
-from flexflow_tpu.parallel.machine import MachineSpec
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
 from flexflow_tpu.search import opshard
+from rank_family import (B, TOL, apart, close, f32_ctx, named, program,
+                         sizes_of)
+from test_lowered_steps import LOWERED
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                        "ssm_hybrid_ref")
-TOL = 2e-4
-B, S = 2, 40              # tiny(): chunks of 16, so two and a half
-
-
-def close(got, want, tol=TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"relative error {err:.3e} > {tol}"
+ref = rf.reference("ssm_hybrid_ref")
+S = 40                    # tiny(): chunks of 16, so two and a half
+build = functools.partial(rf.build, GraniteHybridRankConfig,
+                          build_hybrid_conv_moe, seq=S)
+data = functools.partial(rf.data, seq=S)
 
 
-def apart(got, want, tol=50 * TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    assert float(np.max(np.abs(got - want))) / scale > tol
-
-
-def f32_ctx(training=True):
-    cfg = FFConfig()
-    cfg.use_bf16_compute = False
-    return EmitCtx(training=training, config=cfg)
-
-
-def sizes_of(mc):
-    return dataclasses.asdict(mc)
-
-
-def build(remat="none", model_cfg=None, seq=S, batch=B, devices=None):
-    cfg = FFConfig()
-    cfg.batch_size = batch
-    cfg.only_data_parallel = True        # no search: 0.3 s a compile
-    cfg.use_bf16_compute = False
-    cfg.remat = remat
-    ff = FFModel(cfg)
-    mc = model_cfg or GraniteHybridRankConfig.tiny()
-    out = build_hybrid_conv_moe(ff, batch, seq, mc)
-    some = {"machine_spec": MachineSpec.detect(jax.devices()[:devices])} \
-        if devices else {}
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out, **some)
-    return ff, mc
-
-
-def data(mc, seed=1, seq=S, batch=B):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, mc.vocab_size, (batch, seq)).astype(np.int32)
-    pos = np.tile(np.arange(seq, dtype=np.int32), (batch, 1))
-    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
-            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-
-
-def spread(params, seed=3):
+def spread(params):
     """The seed's weights with every norm's scale and ``D`` off 1, a
     convolution bias off 0 and the attention layer's projections four
     times as large (scores that the softmax does not flatten), so that
     a wrong scale, a lost norm, a lost skip and a lost bias all show."""
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, ws in params.items():
-        out[name] = {}
-        for k, w in ws.items():
-            if k in ("scale", "norm", "D"):
-                w = w * jnp.asarray(rng.uniform(0.5, 1.5, w.shape), w.dtype)
-            elif k == "conv_b":
-                w = w + jnp.asarray(rng.uniform(-0.5, 0.5, w.shape), w.dtype)
-            elif k in ("wq", "wk", "wv", "wo"):
-                w = w * 4.0
-            out[name][k] = w
-    return out
+    def rule(name, k, w, rng):
+        if k in ("scale", "norm", "D"):
+            return rf.scaled(w, rng)
+        if k == "conv_b":
+            return rf.shifted(w, rng)
+        if k in ("wq", "wk", "wv", "wo"):
+            return w * 4.0
+    return rf.spread(params, rule)
 
 
-def named(ff, params):
-    return [(l.name, params[l.name]) for l in ff.layers
-            if l.name in params]
-
-
-def program(ff, params, batch, training=True):
-    """``(loss, metrics, probabilities)`` of the program's step."""
-    ex = ff.executor
-    outs, _, aux, capture = ex._forward(
-        params, ff.state, batch, training, jnp.int32(0))
-    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
-    return loss, bm, outs[0]
-
-
-jitted = jax.jit
+tiny, tiny_step = rf.fixtures(build, data, spread)
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    ff, mc = build()
-    return ff, mc, data(mc), spread(ff.params)
+def tiny_reference(tiny):
+    """The reference's log-probabilities at the tiny model's weights."""
+    ff, mc, batch, params = tiny
+    return rf.reference_call(ref.ssm_hybrid_decoder, ff, mc, params, batch)
 
 
 # ----------------------------------------------------------------------
@@ -155,6 +91,7 @@ def scan_inputs(seq, seed=0, strength=1.0):
     return x, dt, a_log, bm, cm
 
 
+@jax.jit
 def reference_scan(x, dt, a_log, bm, cm):
     with jax.default_matmul_precision("highest"):
         return ref.recurrence(x, dt, a_log, bm, cm, jnp.zeros(HM))
@@ -166,7 +103,7 @@ def test_the_chunked_scan_is_the_recurrence_token_by_token(chunk, seq):
     """Two chunk sizes; 40 positions are two and a half chunks of 16:
     the padded positions write nothing and decay nothing."""
     x, dt, a_log, bm, cm = scan_inputs(seq)
-    y, least = jitted(lambda *a: state_space_scan(
+    y, least = jax.jit(lambda *a: state_space_scan(
         a[0], a[1], -jnp.exp(a[2]), a[3], a[4], chunk))(x, dt, a_log, bm, cm)
     close(y, reference_scan(x, dt, a_log, bm, cm))
     # the most negative log-decay summed over one chunk, by hand
@@ -187,8 +124,8 @@ def test_the_scans_gradients_are_the_recurrences():
         y = reference_scan(*a)
         return jnp.sum(y * jnp.cos(y))
 
-    g1 = jitted(jax.grad(got, range(5)))(*args)
-    g2 = jitted(jax.grad(want, range(5)))(*args)
+    g1 = jax.jit(jax.grad(got, range(5)))(*args)
+    g2 = jax.jit(jax.grad(want, range(5)))(*args)
     for a, b in zip(g1, g2):
         close(a, b, 1e-3)
 
@@ -213,9 +150,9 @@ def test_decays_that_overflow_when_formed_apart_still_agree():
         y = reference_scan(*a)
         return jnp.sum(y * jnp.cos(y)), y
 
-    (_, y1), g1 = jitted(jax.value_and_grad(got, (0, 1, 3, 4),
+    (_, y1), g1 = jax.jit(jax.value_and_grad(got, (0, 1, 3, 4),
                                             has_aux=True))(*args)
-    (_, y2), g2 = jitted(jax.value_and_grad(want, (0, 1, 3, 4),
+    (_, y2), g2 = jax.jit(jax.value_and_grad(want, (0, 1, 3, 4),
                                             has_aux=True))(*args)
     assert np.isfinite(np.asarray(y1)).all()
     close(y1, y2)
@@ -259,12 +196,16 @@ def mixer_input(seq=S, seed=1):
 
 
 def run_mixer(x, w, **over):
-    ctx = f32_ctx()
-    (y,) = StateSpaceMixerOp().emit(dict(LAYER, **over), [x], w, ctx,
-                                    "mamba")
-    return y, ctx
+    """``(output, counters)`` of the layer."""
+    def layer(x, w):
+        ctx = f32_ctx()
+        (y,) = StateSpaceMixerOp().emit(dict(LAYER, **over), [x], w, ctx,
+                                        "mamba")
+        return y, ctx.counters
+    return jax.jit(layer)(x, w)
 
 
+@jax.jit
 def reference_mixer(x, w):
     with jax.default_matmul_precision("highest"):
         return ref.mixer(x, w, SIZES)
@@ -284,9 +225,9 @@ def test_a_layers_output_and_every_gradient_are_the_references():
         y = reference_mixer(x, w)
         return jnp.sum(y * jnp.cos(y)), y
 
-    (_, y1), (gx1, gw1) = jitted(jax.value_and_grad(got, (0, 1),
+    (_, y1), (gx1, gw1) = jax.jit(jax.value_and_grad(got, (0, 1),
                                                     has_aux=True))(x, w)
-    (_, y2), (gx2, gw2) = jitted(jax.value_and_grad(want, (0, 1),
+    (_, y2), (gx2, gw2) = jax.jit(jax.value_and_grad(want, (0, 1),
                                                     has_aux=True))(x, w)
     close(y1, y2)
     close(gx1, gx2, 1e-3)
@@ -320,7 +261,8 @@ def test_the_gate_comes_before_the_norm():
     the program is the first."""
     x, w = mixer_input(), mixer_weights()
 
-    with jax.default_matmul_precision("highest"):
+    @jax.jit
+    def both_orders(x, w):
         zxbcdt = jnp.einsum("bte,ec->btc", x, w["in_proj"])
         z = zxbcdt[..., :INNER]
         xbc = jax.nn.silu(ref.causal_conv(
@@ -333,6 +275,10 @@ def test_the_gate_comes_before_the_norm():
             @ w["out_proj"]
         gate_last = (ref.rms_norm(y, w["norm"], 1e-5) * jax.nn.silu(z)) \
             @ w["out_proj"]
+        return gate_first, gate_last
+
+    with jax.default_matmul_precision("highest"):
+        gate_first, gate_last = both_orders(x, w)
     got = run_mixer(x, w)[0]
     apart(gate_first, gate_last)
     close(got, gate_first)
@@ -390,12 +336,15 @@ def attn_weights(seed=0):
             "wo": w(H, D, E)}
 
 
-def run_attention(x, w, impl, **over):
-    ctx = f32_ctx()
-    ctx.kernel_impls = {"attention": impl}
+def attention_layer(x, w, impl, **over):
+    """The layer's output, traced where it is called."""
     (y,) = MultiHeadAttentionOp().emit(dict(ATTN, **over), [x, x, x], w,
-                                       ctx, "attn")
+                                       f32_ctx(impl=impl), "attn")
     return y
+
+
+def run_attention(x, w, impl, **over):
+    return jax.jit(lambda x, w: attention_layer(x, w, impl, **over))(x, w)
 
 
 @pytest.mark.parametrize("impl", ["xla", "flash"])
@@ -413,9 +362,9 @@ def test_the_scaled_layer_and_its_gradients_are_the_references(impl):
             y = ref.attention(x, w, ATTN_SIZES)
         return jnp.sum(y * jnp.cos(y)), y
 
-    (_, y1), (gx1, gw1) = jitted(jax.value_and_grad(got, (0, 1),
+    (_, y1), (gx1, gw1) = jax.jit(jax.value_and_grad(got, (0, 1),
                                                     has_aux=True))(x, w)
-    (_, y2), (gx2, gw2) = jitted(jax.value_and_grad(want, (0, 1),
+    (_, y2), (gx2, gw2) = jax.jit(jax.value_and_grad(want, (0, 1),
                                                     has_aux=True))(x, w)
     close(y1, y2)
     close(gx1, gx2, 1e-3)
@@ -428,35 +377,6 @@ def test_the_scaled_layer_and_its_gradients_are_the_references(impl):
           run_attention(x, w, impl), 1e-6)
 
 
-# what ``MultiHeadAttentionOp.emit`` lowered to at the parent commit (PR
-# 54) for ``lowered_attention`` below: the same lines run against a
-# ``git archive`` of the parent give these
-PARENT_ATTENTION_SHA256 = {
-    "xla": 
-        "42694f5f039ec7b5be8f3bf3fb0ac4be16fa42807be7a53dd667a65aa9b407bb",
-    "flash": 
-        "1f67df5a42a5ece58e9e596581abd5711f2707c9a791e5996e8aaacd1a16c7ea",
-}
-
-
-def lowered_attention(impl):
-    # cold caches, as ``tests/test_window_gated_moe.py::lowered_step``
-    # says: how many private functions JAX emits depends on what the
-    # process traced before
-    jax.clear_caches()
-    x, w = mixer_input(), attn_weights()
-    return jax.jit(lambda x, w: jax.grad(
-        lambda x, w: jnp.sum(run_attention(x, w, impl)), (0, 1))(x, w)
-    ).lower(x, w).as_text()
-
-
-@pytest.mark.parametrize("impl", sorted(PARENT_ATTENTION_SHA256))
-def test_without_a_scale_the_layer_lowers_as_at_the_parent(impl):
-    text = lowered_attention(impl)
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == PARENT_ATTENTION_SHA256[impl]
-
-
 def test_the_scale_reaches_the_decode_path_and_the_recorder():
     """A prefill and a decode step at the model's scale agree with the
     full forward's last position; the layer says its scale once a
@@ -464,16 +384,21 @@ def test_the_scale_reaches_the_decode_path_and_the_recorder():
     x, w = mixer_input(seq=16), attn_weights()
     full = run_attention(x, w, "xla", sm_scale=0.25)
     params = dict(ATTN, sm_scale=0.25)
-    ctx = f32_ctx(training=False)
-    ctx.kv_mode = "prefill"
-    MultiHeadAttentionOp().emit(params, [x] * 3, w, ctx, "attn")
-    cache = {"attn": {k: v.at[:, 15:].set(0.0)
-                      for k, v in ctx.new_kv["attn"].items()}}
-    ctx = f32_ctx(training=False)
-    ctx.kv_mode, ctx.kv_cache, ctx.kv_index = "decode", cache, jnp.int32(15)
-    (last,) = MultiHeadAttentionOp().emit(params, [x[:, 15:]] * 3, w, ctx,
-                                          "attn")
-    close(last[:, 0], full[:, 15], 1e-5)
+
+    @jax.jit
+    def decoded(x, w):
+        ctx = f32_ctx(training=False)
+        ctx.kv_mode = "prefill"
+        MultiHeadAttentionOp().emit(params, [x] * 3, w, ctx, "attn")
+        cache = {"attn": {k: v.at[:, 15:].set(0.0)
+                          for k, v in ctx.new_kv["attn"].items()}}
+        ctx = f32_ctx(training=False)
+        ctx.kv_mode, ctx.kv_cache, ctx.kv_index = "decode", cache, \
+            jnp.int32(15)
+        return MultiHeadAttentionOp().emit(params, [x[:, 15:]] * 3, w, ctx,
+                                           "attn")[0]
+
+    close(decoded(x, w)[:, 0], full[:, 15], 1e-5)
     events.enable()
     events.clear()
     try:
@@ -490,22 +415,18 @@ def test_the_scale_reaches_the_decode_path_and_the_recorder():
 # ----------------------------------------------------------------------
 # the model
 # ----------------------------------------------------------------------
-def test_the_model_is_the_reference_log_probabilities_and_loss(tiny):
+def test_the_model_is_the_reference_log_probabilities_and_loss(
+        tiny, tiny_reference):
     ff, mc, batch, params = tiny
-    loss, _, probs = jitted(lambda p: program(ff, p, batch, False))(params)
-    want = jitted(lambda p: ref.ssm_hybrid_decoder(
-        named(ff, p), sizes_of(mc), batch["input_ids"],
-        batch["position_ids"]))(params)
-    close(jnp.log(probs), want)
-    close(loss, ref.loss(named(ff, params), sizes_of(mc),
-                         batch["input_ids"], batch["position_ids"],
-                         batch["label"][..., 0]), 1e-5)
+    loss, _, probs = program(ff, params, batch, False)
+    close(jnp.log(probs), tiny_reference)
+    close(loss, rf.reference_loss(ref, ff, mc, params, batch), 1e-5)
 
 
-def test_every_gradient_is_the_references(tiny):
+def test_every_gradient_is_the_references(tiny, tiny_step):
     ff, mc, batch, params = tiny
-    got = jitted(jax.grad(lambda p: program(ff, p, batch)[0]))(params)
-    _, want = jitted(lambda p: ref.loss_and_gradients(
+    _, got = tiny_step
+    _, want = jax.jit(lambda p: ref.loss_and_gradients(
         named(ff, p), sizes_of(mc), batch["input_ids"],
         batch["position_ids"], batch["label"][..., 0]))(params)
     kinds = set()
@@ -543,15 +464,13 @@ def test_the_graph_has_what_the_equations_have(tiny):
     ("embedding_multiplier", 1.0), ("residual_multiplier", 1.0),
     ("attention_multiplier", 8 ** -0.5), ("logits_scaling", 1.0)])
 def test_a_model_without_one_scalar_is_apart_from_the_reference(
-        tiny, field, value):
+        tiny, tiny_reference, field, value):
     """12, 0.22, the scores' 0.25 against ``1 / sqrt(8)``, 8: a program
     built with one of them at its neutral value is another function."""
     ff, mc, batch, params = tiny
     other, _ = build(model_cfg=dataclasses.replace(mc, **{field: value}))
-    want = ref.ssm_hybrid_decoder(named(ff, params), sizes_of(mc),
-                                  batch["input_ids"], batch["position_ids"])
-    _, _, probs = jitted(lambda p: program(other, p, batch, False))(params)
-    apart(jnp.log(probs), want, 10 * TOL)
+    _, _, probs = program(other, params, batch, False)
+    apart(jnp.log(probs), tiny_reference, 10 * TOL)
 
 
 # ----------------------------------------------------------------------
@@ -588,28 +507,13 @@ def test_the_remat_finder_takes_the_ten_layers_as_ten_blocks():
         + ["OP_MULTIHEAD_ATTENTION"] + ["OP_STATE_SPACE_MIXER"] * 4
 
 
-def test_a_rematerialised_step_is_the_step(tiny):
-    plain, mc, batch, params = tiny
+def test_a_rematerialised_step_is_the_step(tiny, tiny_step):
+    _, _, batch, params = tiny
     remat, _ = build(remat="blocks")
-
-    def both(ff):
-        def f(p):
-            loss, bm, _ = program(ff, p, batch)
-            return loss, bm
-        return jitted(jax.value_and_grad(f, has_aux=True))(params)
-
-    (l1, bm1), g1 = both(plain)
-    (l2, bm2), g2 = both(remat)
-    close(l2, l1, 1e-6)
-    counted = [k for k in bm1 if k.startswith(COUNTER_PREFIX)]
-    assert COUNTER_PREFIX + "ssm.layers" in counted
-    for key in counted:
-        close(bm2[key], bm1[key], 1e-6)
-    assert float(bm1[COUNTER_PREFIX + "ssm.layers"]) == 5.0
-    assert float(bm1[COUNTER_PREFIX + "ssm.min_chunk_log_decay"]) < 0.0
-    for name, ws in g1.items():
-        for k in ws:
-            close(g2[name][k], ws[k], 1e-5)
+    rf.same_step(rf.step_and_gradients(remat, params, batch), tiny_step)
+    (_, bm), _ = tiny_step
+    assert float(bm[COUNTER_PREFIX + "ssm.layers"]) == 5.0
+    assert float(bm[COUNTER_PREFIX + "ssm.min_chunk_log_decay"]) < 0.0
 
 
 def test_the_layer_says_its_sizes_and_the_scan_has_its_scope():
@@ -618,7 +522,7 @@ def test_the_layer_says_its_sizes_and_the_scan_has_its_scope():
     try:
         ff, mc = build()
         batch = data(mc)
-        text = jax.jit(lambda p: program(ff, p, batch)[0]).lower(
+        text = jax.jit(lambda p: rf.forward(ff, p, batch)[0]).lower(
             ff.params).as_text(debug_info=True)
         said = [e["attrs"] for e in events.events()
                 if e["name"] == "ssm.layer"]
@@ -684,3 +588,26 @@ def test_the_search_offers_batch_and_heads_and_the_verifier_refuses_the_sequence
                 if "halo" in f.message]
         assert (not halo) == ok
         assert ok or "state-space mixer" in halo[0].message
+
+
+# (last in the file: ``lowered_text`` clears JAX's caches)
+def lowered_attention(impl, **over):
+    x, w = mixer_input(), attn_weights()
+    return rf.lowered_text(jax.grad(
+        lambda x, w: jnp.sum(attention_layer(x, w, impl, **over)), (0, 1)),
+        x, w)
+
+
+@pytest.mark.parametrize("impl", ["flash", "xla"])
+def test_without_a_scale_the_layer_lowers_as_at_the_parent(impl):
+    """A layer that names no ``sm_scale`` lowers, value and gradients,
+    to the text it lowered to at the parent, the ``1 / sqrt(d)`` layer
+    (``tests/test_lowered_steps.py::LOWERED`` holds the two hashes: the
+    tiny models' steps there take the XLA path on the CPU, so this is
+    the one pin of the layer through the flash kernels), and the scale
+    given is another text."""
+    text = lowered_attention(impl)
+    got = hashlib.sha256(text.encode()).hexdigest()
+    assert got == LOWERED["attention layer", impl], \
+        f'\n    ("attention layer", "{impl}"):\n        "{got}",'
+    assert text != lowered_attention(impl, sm_scale=0.25)

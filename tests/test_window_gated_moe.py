@@ -15,132 +15,46 @@ under what one key more or fewer in a window of 24, a lost gate (a
 factor of two), a lost norm or a lost scale of 8 moves.
 """
 import dataclasses
-import hashlib
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells
-from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+import rank_family as rf
+from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.executor import _find_remat_blocks
 from flexflow_tpu.ffconst import DataType
-from flexflow_tpu.models import nlp
 from flexflow_tpu.models.nlp import (HybridConvMoEConfig, KeyeRankConfig,
                                      LFM2RankConfig, TrinityRankConfig,
                                      build_hybrid_conv_moe)
 from flexflow_tpu.obs import events
 from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
 from flexflow_tpu.ops.nn_ops import MultiHeadAttentionOp
-from flexflow_tpu.ops.registry import EmitCtx
-from flexflow_tpu.parallel.machine import MachineSpec
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX
+from rank_family import B, apart, close, f32_ctx, program
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ref = cells.load_module(os.path.join(ROOT, "benchmarks"), "reference",
-                        "window_gated_moe_ref")
-TOL = 2e-4
-B, S = 2, 48              # tiny(): a window of 24
-
-
-def close(got, want, tol=TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    err = float(np.max(np.abs(got - want))) / scale
-    assert err <= tol, f"relative error {err:.3e} > {tol}"
+ref = rf.reference("window_gated_moe_ref")
+S = 48                    # tiny(): a window of 24
+build = functools.partial(rf.build, TrinityRankConfig,
+                          build_hybrid_conv_moe, seq=S)
+data = functools.partial(rf.data, seq=S)
 
 
-def apart(got, want, tol=50 * TOL):
-    got, want = np.asarray(got), np.asarray(want)
-    scale = max(float(np.max(np.abs(want))), 1e-6)
-    assert float(np.max(np.abs(got - want))) / scale > tol
-
-
-def f32_ctx(training=True, impl=None):
-    cfg = FFConfig()
-    cfg.use_bf16_compute = False
-    ctx = EmitCtx(training=training, config=cfg)
-    ctx.kernel_impls = {"attention": impl} if impl else None
-    return ctx
-
-
-def sizes_of(mc):
-    return dict(dataclasses.asdict(mc),
-                num_experts_published=mc.num_experts_published
-                or mc.num_experts)
-
-
-def build(remat="none", model_cfg=None, seq=S, impl=None):
-    cfg = FFConfig()
-    cfg.batch_size = B
-    cfg.only_data_parallel = True        # no search: 0.3 s a compile
-    cfg.use_bf16_compute = False
-    cfg.remat = remat
-    if impl:
-        cfg.kernel_impls = f"attention:{impl}"
-    ff = FFModel(cfg)
-    mc = model_cfg or TrinityRankConfig.tiny()
-    out = build_hybrid_conv_moe(ff, B, seq, mc)
-    one = {"machine_spec": MachineSpec.detect(jax.devices()[:1])} \
-        if impl else {}
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out, **one)
-    return ff, mc
-
-
-def data(mc, seed=1, seq=S):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, mc.vocab_size, (B, seq)).astype(np.int32)
-    pos = np.tile(np.arange(seq, dtype=np.int32), (B, 1))
-    return {"input_ids": jnp.asarray(ids), "position_ids": jnp.asarray(pos),
-            "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-
-
-def spread(params, seed=3):
+def spread(params):
     """The seed's weights with every norm's scale off 1 and the gate's
     projection three times as large, so that a wrong scale, a lost norm
     and a gate that is not 0.5 all show."""
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, ws in params.items():
-        out[name] = {}
-        for k, w in ws.items():
-            if k in ("scale", "q_norm", "k_norm"):
-                w = w * jnp.asarray(rng.uniform(0.5, 1.5, w.shape), w.dtype)
-            elif k == "wg" and name.startswith("attn_"):
-                w = w * 3.0
-            out[name][k] = w
-    return out
+    def rule(name, k, w, rng):
+        if k in ("scale", "q_norm", "k_norm"):
+            return rf.scaled(w, rng)
+        if k == "wg" and name.startswith("attn_"):
+            return w * 3.0
+    return rf.spread(params, rule)
 
 
-def named(ff, params):
-    return [(l.name, params[l.name]) for l in ff.layers
-            if l.name in params]
-
-
-def program(ff, params, batch, training=True):
-    """``(loss, metrics, probabilities)`` of the program's step."""
-    ex = ff.executor
-    outs, _, aux, capture = ex._forward(
-        params, ff.state, batch, training, jnp.int32(0))
-    loss, bm = ex._loss_and_metrics(outs, capture, batch["label"], aux)
-    return loss, bm, outs[0]
-
-
-def reference_loss(ff, mc, params, batch):
-    return ref.loss(named(ff, params), sizes_of(mc), batch["input_ids"],
-                    batch["position_ids"], batch["label"][..., 0])
-
-
-jitted = jax.jit
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    ff, mc = build()
-    return ff, mc, data(mc), spread(ff.params)
+tiny, tiny_step = rf.fixtures(build, data, spread)
 
 
 # ----------------------------------------------------------------------
@@ -179,11 +93,24 @@ def attn_inputs(seq=S, seed=1):
 
 
 def run_layer(kind, x, pos, w, impl=None, **over):
+    """``(output, counters)`` of one layer of ``kind``; traced where it
+    is called, for the gradients' sake."""
     params = dict(KIND[kind], **over)
     ctx = f32_ctx(impl=impl)
     ins = [x, x, x] + ([pos] if params.get("rope") else [])
     (y,) = MultiHeadAttentionOp().emit(params, ins, w, ctx, "attn")
-    return y, ctx
+    return y, ctx.counters
+
+
+def layer(kind, x, pos, w, impl=None, **over):
+    return jax.jit(lambda x, pos, w: run_layer(kind, x, pos, w, impl,
+                                              **over))(x, pos, w)
+
+
+def reference_layer(kind, x, pos, w, **sizes):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda x, pos, w: ref.attention(
+            x, pos, w, dict(SIZES, **sizes), kind))(x, pos, w)
 
 
 @pytest.mark.parametrize("impl", ["xla", "flash"])
@@ -204,9 +131,9 @@ def test_a_layers_output_and_gradients_are_the_references(kind, impl):
             y = ref.attention(x, pos, w, SIZES, kind)
         return jnp.sum(y * jnp.cos(y)), y
 
-    (_, y1), (gx1, gw1) = jitted(jax.value_and_grad(got, (0, 1),
+    (_, y1), (gx1, gw1) = jax.jit(jax.value_and_grad(got, (0, 1),
                                                     has_aux=True))(x, w)
-    (_, y2), (gx2, gw2) = jitted(jax.value_and_grad(want, (0, 1),
+    (_, y2), (gx2, gw2) = jax.jit(jax.value_and_grad(want, (0, 1),
                                                     has_aux=True))(x, w)
     close(y1, y2)
     close(gx1, gx2, 1e-3)
@@ -220,12 +147,10 @@ def test_a_window_of_at_least_the_sequence_is_the_full_layer_with_rotation(
         impl):
     x, pos = attn_inputs()
     w = attn_weights()
-    wide, _ = run_layer("sliding_attention", x, pos, w, impl,
-                        sliding_window=S)
-    none, _ = run_layer("sliding_attention", x, pos, w, impl,
-                        sliding_window=0)
+    wide, _ = layer("sliding_attention", x, pos, w, impl, sliding_window=S)
+    none, _ = layer("sliding_attention", x, pos, w, impl, sliding_window=0)
     close(wide, none, 1e-6)
-    narrow, _ = run_layer("sliding_attention", x, pos, w, impl)
+    narrow, _ = layer("sliding_attention", x, pos, w, impl)
     apart(narrow, none)
 
 
@@ -234,13 +159,11 @@ def test_the_window_moves_what_a_query_sees_by_one_key():
     window of 23 or 25 is another function."""
     x, pos = attn_inputs()
     w = attn_weights()
-    got, _ = run_layer("sliding_attention", x, pos, w)
-    with jax.default_matmul_precision("highest"):
-        close(got, ref.attention(x, pos, w, SIZES, "sliding_attention"))
-        for other in (23, 25):
-            apart(got, ref.attention(x, pos, w, dict(SIZES,
-                                                     sliding_window=other),
-                                     "sliding_attention"), 1e-3)
+    got, _ = layer("sliding_attention", x, pos, w)
+    close(got, reference_layer("sliding_attention", x, pos, w))
+    for other in (23, 25):
+        apart(got, reference_layer("sliding_attention", x, pos, w,
+                                   sliding_window=other), 1e-3)
 
 
 @pytest.mark.parametrize("rows", [8, 16, 24])
@@ -252,13 +175,13 @@ def test_the_reference_in_blocks_of_rows_is_the_reference_whole(
     one block of all 48, and against a loop over the pairs' mask."""
     x, pos = attn_inputs()
     w = attn_weights()
-    with jax.default_matmul_precision("highest"):
-        whole = ref.attention(x, pos, w, SIZES, kind)
-        monkeypatch.setattr(ref, "QUERY_ROWS", rows)
-        close(ref.attention(x, pos, w, SIZES, kind), whole, 1e-6)
+    whole = reference_layer(kind, x, pos, w)
+    monkeypatch.setattr(ref, "QUERY_ROWS", rows)
+    close(reference_layer(kind, x, pos, w), whole, 1e-6)
     mask = np.array([[s <= t and (kind == "full_attention" or s > t - 24)
                       for s in range(S)] for t in range(S)])
-    with jax.default_matmul_precision("highest"):
+    @jax.jit
+    def by_the_mask(x, w):
         q = ref.rms_norm(jnp.einsum("bse,ehd->bshd", x, w["wq"]),
                          w["q_norm"], 1e-5)
         k = ref.rms_norm(jnp.einsum("bse,ehd->bshd", x, w["wk"]),
@@ -271,7 +194,10 @@ def test_the_reference_in_blocks_of_rows_is_the_reference_whole(
         a = jax.nn.softmax(jnp.where(mask, sc, -jnp.inf), -1)
         o = jnp.einsum("bhqk,bkhd->bqhd", a, v) * jax.nn.sigmoid(
             jnp.einsum("bse,ehd->bshd", x, w["wg"]))
-        close(whole, jnp.einsum("bqhd,hde->bqe", o, w["wo"]), 1e-5)
+        return jnp.einsum("bqhd,hde->bqe", o, w["wo"])
+
+    with jax.default_matmul_precision("highest"):
+        close(whole, by_the_mask(x, w), 1e-5)
 
 
 @pytest.mark.parametrize("kind", sorted(KIND))
@@ -287,12 +213,18 @@ def test_without_a_gate_the_op_is_the_parents(kind):
         KIND[kind], [(B, S, E)] * 3, [DataType.DT_FLOAT] * 3)]
     x, pos = attn_inputs()
     w = attn_weights(gate=False)
-    ctx = f32_ctx()
-    ins = [x, x, x] + ([pos] if params.get("rope") else [])
-    (y,) = MultiHeadAttentionOp().emit(params, ins, w, ctx, "attn")
-    assert not any(k.startswith("attn.gate") for k in ctx.counters)
+
+    @jax.jit
+    def ungated(x, pos, w):
+        ctx = f32_ctx()
+        ins = [x, x, x] + ([pos] if params.get("rope") else [])
+        (y,) = MultiHeadAttentionOp().emit(params, ins, w, ctx, "attn")
+        return y, ctx.counters
+
+    y, counters = ungated(x, pos, w)
+    assert not any(k.startswith("attn.gate") for k in counters)
     # the gate at 0 is half the ungated layer: sigmoid(0) = 0.5
-    gated, _ = run_layer(kind, x, pos, dict(
+    gated, _ = layer(kind, x, pos, dict(
         w, wg=jnp.zeros((E, H, D), jnp.float32)))
     close(2.0 * gated, y, 1e-6)
 
@@ -300,19 +232,19 @@ def test_without_a_gate_the_op_is_the_parents(kind):
 def test_the_gates_counters_and_the_windows_pairs():
     x, pos = attn_inputs()
     w = dict(attn_weights(), wg=jnp.zeros((E, H, D), jnp.float32))
-    _, ctx = run_layer("sliding_attention", x, pos, w)
-    assert float(ctx.counters["attn.gate_mean"]) == 0.5
-    assert float(ctx.counters["attn.gate_layers"]) == 1.0
+    _, counters = layer("sliding_attention", x, pos, w)
+    assert float(counters["attn.gate_mean"]) == 0.5
+    assert float(counters["attn.gate_layers"]) == 1.0
     by_loop = sum(1 for t in range(S) for s in range(S)
                   if s <= t and s > t - 24)
-    assert float(ctx.counters["attn.window_pairs"]) == B * by_loop \
+    assert float(counters["attn.window_pairs"]) == B * by_loop \
         == B * ref.band_pairs(S, 24)
-    assert float(ctx.counters["attn.causal_pairs"]) == B * S * (S + 1) / 2
-    _, ctx = run_layer("full_attention", x, pos, w)
-    assert "attn.window_pairs" not in ctx.counters
-    _, ctx = run_layer("sliding_attention", x, pos, w, sliding_window=64)
-    assert float(ctx.counters["attn.window_pairs"]) \
-        == float(ctx.counters["attn.causal_pairs"])
+    assert float(counters["attn.causal_pairs"]) == B * S * (S + 1) / 2
+    _, counters = layer("full_attention", x, pos, w)
+    assert "attn.window_pairs" not in counters
+    _, counters = layer("sliding_attention", x, pos, w, sliding_window=64)
+    assert float(counters["attn.window_pairs"]) \
+        == float(counters["attn.causal_pairs"])
 
 
 @pytest.mark.parametrize("what,fields,match", [
@@ -391,13 +323,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 # ----------------------------------------------------------------------
 def test_the_model_is_the_reference_log_probabilities_and_loss(tiny):
     ff, mc, batch, params = tiny
-    loss, bm, probs = jitted(lambda p: program(ff, p, batch,
-                                               training=False))(params)
-    want = jitted(lambda p: ref.window_gated_moe_decoder(
-        named(ff, p), sizes_of(mc), batch["input_ids"],
-        batch["position_ids"]))(params)
+    loss, bm, probs = program(ff, params, batch, training=False)
+    want = rf.reference_call(ref.window_gated_moe_decoder, ff, mc, params,
+                             batch)
     close(jnp.log(probs), want)
-    close(loss, jitted(lambda p: reference_loss(ff, mc, p, batch))(params))
+    close(loss, rf.reference_loss(ref, ff, mc, params, batch))
     # four window layers of five, S = 48 > window = 24
     pairs = float(bm[COUNTER_PREFIX + "attn.window_pairs"])
     assert pairs == 4 * B * ref.band_pairs(S, mc.sliding_window)
@@ -443,12 +373,10 @@ def test_a_model_without_one_form_is_apart_from_the_reference(field, value):
     ff, _ = build(model_cfg=mc)
     batch = data(mc)
     params = spread(ff.params)
-    _, _, probs = jitted(lambda p: program(ff, p, batch, False))(params)
-    whole = sizes_of(TrinityRankConfig.tiny())
+    _, _, probs = program(ff, params, batch, False)
     try:
-        want = jitted(lambda p: ref.window_gated_moe_decoder(
-            named(ff, p), whole, batch["input_ids"],
-            batch["position_ids"]))(params)
+        want = rf.reference_call(ref.window_gated_moe_decoder, ff,
+                                 TrinityRankConfig.tiny(), params, batch)
     except ref.ReferenceMismatch:
         assert field in ("sandwich_norms", "attention_output_gate",
                          "num_shared_experts")
@@ -457,13 +385,12 @@ def test_a_model_without_one_form_is_apart_from_the_reference(field, value):
     apart(jnp.log(probs), want, 1e-3)
 
 
-def test_every_gradient_is_the_references(tiny):
+def test_every_gradient_is_the_references(tiny, tiny_step):
     """The cross-entropy's gradient for every weight: ``wg``, the four
     norms a layer and the shared expert among them."""
     ff, mc, batch, params = tiny
-    got = jitted(jax.grad(lambda p: program(ff, p, batch)[0]))(params)
-    want = jitted(jax.grad(
-        lambda p: reference_loss(ff, mc, p, batch)))(params)
+    _, got = tiny_step
+    want = rf.reference_gradients(ref, ff, mc, params, batch)
     seen = set()
     for name, ws in params.items():
         for k in ws:
@@ -482,13 +409,12 @@ def test_the_kernel_paths_step_is_the_xla_paths():
     """The whole model with the flash kernels forced (the window layers
     through the band arithmetic) against the XLA path: loss and every
     gradient, and the record says which ran."""
-    xla, mc = build(impl="xla")
-    flash, _ = build(impl="flash")
+    xla, mc = build(attention="xla", devices=1)
+    flash, _ = build(attention="flash", devices=1)
     batch = data(mc)
     params = spread(xla.params)
-    f = lambda ff: jitted(jax.value_and_grad(                 # noqa: E731
-        lambda p: program(ff, p, batch)[0]))(params)
-    (l1, g1), (l2, g2) = f(xla), f(flash)
+    ((l1, _), g1), ((l2, _), g2) = (
+        rf.step_and_gradients(ff, params, batch) for ff in (xla, flash))
     close(l2, l1, 1e-5)
     for name, ws in g1.items():
         for k in ws:
@@ -501,8 +427,8 @@ def test_the_kernels_say_their_window_and_the_full_layer_says_none():
     events.enable()
     events.clear()
     try:
-        ff, mc = build(impl="flash")
-        jax.eval_shape(lambda p: program(ff, p, data(mc))[0], ff.params)
+        ff, mc = build(attention="flash", devices=1)
+        jax.eval_shape(lambda p: rf.forward(ff, p, data(mc))[0], ff.params)
         grids = [e["attrs"] for e in events.events()
                  if e["name"] == "flash.grid"]
         norms = [e["attrs"] for e in events.events()
@@ -533,27 +459,10 @@ def test_the_remat_finder_on_a_dense_layer_and_four_unequal_expert_layers():
     assert ff.executor._remat[:3] == (start, unit, reps)
 
 
-def test_a_rematerialised_step_is_the_step():
-    plain, mc = build()
+def test_a_rematerialised_step_is_the_step(tiny, tiny_step):
+    _, _, batch, params = tiny
     remat, _ = build(remat="blocks")
-    batch = data(mc)
-    params = spread(plain.params)
-
-    def both(ff):
-        def f(p):
-            loss, bm, _ = program(ff, p, batch)
-            return loss, bm
-        return jitted(jax.value_and_grad(f, has_aux=True))(params)
-
-    (l1, bm1), g1 = both(plain)
-    (l2, bm2), g2 = both(remat)
-    close(l2, l1, 1e-6)
-    for key in bm1:
-        if key.startswith(COUNTER_PREFIX):
-            close(bm2[key], bm1[key], 1e-6)
-    for name, ws in g1.items():
-        for k in ws:
-            close(g2[name][k], ws[k], 1e-5)
+    rf.same_step(rf.step_and_gradients(remat, params, batch), tiny_step)
 
 
 def test_a_train_step_moves_the_gate_and_lowers_the_loss():
@@ -584,8 +493,8 @@ def test_the_older_graphs_name_no_gate_no_window_and_no_new_layer(cls):
     """A graph built from the classes ``lfm2_24b_a2b`` and
     ``keye_vl2_30b_a3b`` use has the layers and parameters it had: the
     new fields live on ``TrinityRankConfig`` alone. (``tests/
-    test_linear_latent_moe.py`` and ``tests/test_mhc_latent_moe.py`` pin
-    the sha256 of the older configurations' lowered steps.)"""
+    test_lowered_steps.py`` pins the sha256 of every rank configuration's
+    lowered step.)"""
     ff = FFModel(FFConfig())
     mc = KeyeRankConfig.tiny() if cls is KeyeRankConfig \
         else HybridConvMoEConfig.tiny()
@@ -606,71 +515,3 @@ def test_a_sliding_attention_layer_needs_a_window():
                              layer_types=["sliding_attention"] * 5)
     with pytest.raises(ValueError, match="sliding_window"):
         build_hybrid_conv_moe(FFModel(FFConfig()), 1, 32, mc)
-
-
-# what these lowered to at the parent commit (PR 50): the train step of
-# each rank configuration's builder at its tiny size, with the blocks
-# rematerialised; the construction is ``lowered_step`` below, and the
-# same lines run against a ``git archive`` of the parent give these
-PARENT_STEP_SHA256 = {
-    "LFM2RankConfig":
-        "e39329f73030ad79e44711e72529ab8beb79297855b13b4672e1d90e91bc44cc",
-    "KeyeRankConfig":
-        "0d20f419bb84cdfb8951da276ea2479f1c690fabe605a2d669523c9d7fc86e65",
-    "JoyAIFlashRankConfig":
-        "b60cef900927287072a1daacb2c7b189d6816245e4ceaf133ac55cf47e341049",
-    "KimiLinearRankConfig":
-        "cba9000de8746dfce1a2cca52294f8b4f08c9417ac725d4ba2c6d8f6dd608d03",
-    "XingRankConfig":
-        "c0feb6265391f7b9f074e1784e0224456d34f4cef09cdab6c300c76bc0f0ea7a",
-    # this file's own configuration, at commit 30ea119 (PR 55's parent:
-    # the sixth rank configuration that ``sm_scale``, the state-space
-    # layer kind and the scalar multipliers must leave as it was)
-    "TrinityRankConfig":
-        "5a64243ea76f334805bbe7f542884c20a3b5d4465e302e224d2f2934d7f96b58",
-}
-
-
-def lowered_step(cls):
-    # The text is JAX's, and JAX shares a jitted ``jnp`` function (``_where``,
-    # ``floor_divide``, ``clip``...) between its call sites as ONE private
-    # function only where both sites' traces came out of the same cache
-    # entry. After enough other tests in the process (PR 55 found it with
-    # five files ahead of this one, none of which does it alone) some of
-    # those entries have been evicted while the experts' inline-jitted
-    # loops still hold jaxprs traced from them: the same step then lowers
-    # with 82 private functions where a fresh process emits 78, and its
-    # hash is another. The pin owns what it reads: cold caches, as in the
-    # process that wrote the hashes.
-    jax.clear_caches()
-    cfg = FFConfig()
-    cfg.batch_size = 2
-    cfg.only_data_parallel = True
-    cfg.remat = "blocks"
-    ff = FFModel(cfg)
-    mc = cls.tiny()
-    builder = nlp.build_hybrid_conv_moe \
-        if isinstance(mc, HybridConvMoEConfig) else nlp.build_latent_moe
-    out = builder(ff, 2, 32, mc)
-    ff.compile(AdamOptimizer(1e-3), "sparse_categorical_crossentropy", [],
-               output_tensor=out)
-    rng = np.random.default_rng(0)
-    ids = rng.integers(0, mc.vocab_size, (2, 32)).astype(np.int32)
-    batch = {"input_ids": jnp.asarray(ids),
-             "position_ids": jnp.tile(jnp.arange(32, dtype=jnp.int32),
-                                      (2, 1)),
-             "label": jnp.asarray(np.roll(ids, -1, 1)[..., None])}
-    return ff.executor.make_train_step().lower(
-        ff.params, ff.opt_state, ff.state, jnp.int32(0), batch).as_text()
-
-
-@pytest.mark.parametrize("name", sorted(PARENT_STEP_SHA256))
-def test_the_older_configurations_steps_lower_as_at_the_parent(name):
-    """No window, no gate, no new layer kind: the five older rank
-    configurations' rematerialised train steps lower to the text they
-    lowered to before this model's fields, the op's gate and the looser
-    block finder, and since PR 55 this model's own to its text at that
-    PR's parent."""
-    text = lowered_step(getattr(nlp, name))
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == PARENT_STEP_SHA256[name]

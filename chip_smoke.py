@@ -38,7 +38,10 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          model, then one chip's share of Kimi-Linear-48B-A3B at
          published widths, 1 x 4096 tokens a chip, rematerialised as its
          benchmark cell is. It checks that the ``kda.scan`` and
-         ``attn.latent`` instants are the configuration's two lists and
+         ``attn.latent`` instants are the configuration's two lists,
+         that the layers took the path their shapes give (at published
+         widths the kernels: the chunks' terms and the scan, each
+         forward and backward, four kinds of ``kda.kernel`` instant) and
          prints the ``kda.*`` counters; it looks at no gradient:
          ``examples/tpu_validate_linear_latent_moe.py`` does.
   Leg F  four residual streams under manifold-constrained
@@ -91,6 +94,10 @@ import numpy as np
 SEED = 0
 SEARCH_BUDGET = 8
 TRAIN_STEPS = 5           # after the step that compiles
+#: the ``kernel=`` of a delta-rule layer's ``kda.kernel`` / ``gdn.kernel``
+#: instants on the kernel path: the chunks' terms and the scan, each
+#: forward and backward
+DELTA_RULE_KERNELS = ["bwd", "fwd", "scan_bwd", "scan_fwd"]
 PROMPT_LEN, NEW_TOKENS = 128, 16
 # Per-chip batches for f32 weights, gradients and Adam moments, no
 # rematerialization. BERT-large: XLA's memory analysis of the compiled
@@ -666,6 +673,18 @@ def leg_hybrid_conv_moe(model_cfg, seq: int, per_chip_batch: int,
 VALIDATION_LINEAR = "examples/tpu_validate_linear_latent_moe.py"
 
 
+def _say_delta_rule_kernels(label, name, kernels, of_terms):
+    """One line a kind of ``kda.kernel`` / ``gdn.kernel`` instant: the
+    grid, and what a step holds (``of_terms`` words the terms' kernels,
+    the scan's say how many heads' states ride in VMEM)."""
+    for kind, a in sorted(kernels.items()):
+        held = (f"{a['heads_per_step']} heads' states in VMEM"
+                if kind.startswith("scan") else of_terms(a))
+        say(f"{label}: {name} {kind}: {a['grid_steps']} grid steps of "
+            f"{a['chunks_per_step']} chunks of {a['chunk']} ({held}), "
+            f"{a['vmem_bytes'] / 2 ** 20:.1f} MiB of VMEM a step")
+
+
 def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
                           label: str, alpha: float = 1e-5) -> None:
     """``build_latent_moe`` with ``linear_attn_config`` through compile
@@ -720,13 +739,10 @@ def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
                                     scan["head_dim"]) else "plain"
     say(f"{label}: the chunks' terms by {sorted(took)} (the shapes say "
         f"{want})")
-    for kind, a in sorted(kernels.items()):
-        say(f"{label}: kda.kernel {kind}: {a['grid_steps']} grid steps of "
-            f"{a['chunks_per_step']} chunks of {a['chunk']} (sub-blocks of "
-            f"{a['sub']}), {a['vmem_bytes'] / 2 ** 20:.1f} MiB of VMEM a "
-            f"step")
+    _say_delta_rule_kernels(label, "kda.kernel", kernels,
+                            lambda a: f"sub-blocks of {a['sub']}")
     check(took == {want} and sorted(kernels) == (
-        ["bwd", "fwd"] if want == "kernel" else []),
+        DELTA_RULE_KERNELS if want == "kernel" else []),
           f"{label}: the linear-attention layers announced "
           f"{ {n: a['impl'] for n, a in seen['kda.scan'].items()} } and "
           f"the kernels {sorted(kernels)} where the shapes say {want}")
@@ -1230,14 +1246,12 @@ def leg_gdn_gated_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
                            scan["head_dim"])
     say(f"{label}: the chunks' terms by {sorted(took)} (the shapes say "
         f"{want})")
-    for kind, a in sorted(kernels.items()):
-        say(f"{label}: gdn.kernel {kind}: {a['grid_steps']} grid steps of "
-            f"{a['chunks_per_step']} chunks of {a['chunk']} ({a['group']} "
-            f"value heads a q/k head), {a['vmem_bytes'] / 2 ** 20:.1f} MiB "
-            f"of VMEM a step")
+    _say_delta_rule_kernels(
+        label, "gdn.kernel", kernels,
+        lambda a: f"{a['group']} value heads a q/k head")
     check(len(took) == 1 and (took == {want} or took == {"plain"})
-          and sorted(kernels) == (["bwd", "fwd"] if took == {"kernel"}
-                                  else []),
+          and sorted(kernels) == (DELTA_RULE_KERNELS
+                                  if took == {"kernel"} else []),
           f"{label}: the linear layers announced "
           f"{ {n: a['impl'] for n, a in said['gdn.scan']} } and the "
           f"kernels {sorted(kernels)} where the shapes say {want}")

@@ -27,9 +27,11 @@ the reference ``benchmarks/reference/gdn_gated_moe_ref.py`` (float32,
      value heads of 128, 4,096 positions, chunks of 64, bf16 operands)
      against the token-by-token walk in float32: the output and the five
      gradients, through the kernels (the head form of
-     ``kernels/gated_delta_rule.py``, which these shapes take) and
-     through the plain terms, the kernels' readings held to the plain
-     path's own;
+     ``kernels/gated_delta_rule.py`` for the chunks' terms and its scan
+     kernel pair for the state from chunk to chunk, which these shapes
+     take and whose ``gdn.kernel`` instants the check prints) and
+     through the plain terms and the plain ``lax.scan``, the kernels'
+     readings held to the plain path's own;
   3. per seed at one sequence of ``--seq`` positions: the head's
      log-probabilities against the reference (``|sys - ref|_2 /
      |ref|_2``, the runner's measure), and the eval-mode loss;
@@ -169,12 +171,26 @@ def recurrence(conf, ref, seq=4096):
         if path == "plain":
             recurrent_ops.takes_head_kernel = lambda *a: False
         jax.clear_caches()
+        events.enable()
+        events.clear()
         try:
             (_, o), gp = jax.jit(jax.value_and_grad(
                 program, (0, 1, 2, 3, 4), has_aux=True))(*args)
+            said = [e["attrs"] for e in events.events()
+                    if e["name"] == "gdn.kernel"]
         finally:
             recurrent_ops.takes_head_kernel = takes
+            events.clear()
+            events.disable()
         tag = f"recurrence {hk}/{hv} x {d} at {seq} ({path})"
+        for a in said:
+            print(f"  gdn.kernel {a}", flush=True)
+        # the terms' pair and the scan's, or no kernel at all
+        check(f"{tag} ran the kernels it says",
+              sorted(a["kernel"] for a in said) == (
+                  ["bwd", "fwd", "scan_bwd", "scan_fwd"]
+                  if path == "kernel" else []),
+              f"gdn.kernel instants {[a['kernel'] for a in said]}")
         e = read[path, "o"] = l2(o[0], want)
         READINGS[f"{tag} o"] = e
         check(f"{tag} a chunk's decays pass float32's exponent",

@@ -15,7 +15,10 @@ failure):
 
   1. the recurrence alone at (1, ``--seq``, 32 x 128), decays drawn as
      the layer's initialisation draws them: ``gated_delta_rule`` with
-     bf16 operands, forward and the gradients of q, k, v, g and beta,
+     bf16 operands (the chunks' terms and the state from chunk to chunk
+     both by the kernels of ``kernels/gated_delta_rule.py``, whose
+     ``kda.kernel`` instants the check prints), forward and the
+     gradients of q, k, v, g and beta,
      against the token-by-token reference, each held to twice what that
      reference itself reads with bf16 operands; and the most negative
      in-chunk running log-decay, which says whether ``exp(-G)`` would
@@ -104,7 +107,25 @@ def recurrence(ref, seq, heads=32, d=128):
         return jax.jit(jax.value_and_grad(fn, argnums=range(5),
                                           has_aux=True))
 
-    (_, (got, least)), d_got = graded(program)(*args)
+    from flexflow_tpu.kernels.gated_delta_rule import takes_kernel
+    from flexflow_tpu.obs import events
+    events.enable()
+    events.clear()
+    try:
+        (_, (got, least)), d_got = graded(program)(*args)
+        said = [e["attrs"] for e in events.events()
+                if e["name"] == "kda.kernel"]
+    finally:
+        events.clear()
+        events.disable()
+    for a in said:
+        print(f"  kda.kernel {a}", flush=True)
+    # the terms' pair and the scan's where the shapes take the kernels
+    check("recurrence ran the kernels the shapes say",
+          sorted(a["kernel"] for a in said) == (
+              ["bwd", "fwd", "scan_bwd", "scan_fwd"]
+              if takes_kernel(64, d, d) else []),
+          f"kda.kernel instants {[a['kernel'] for a in said]}")
     (_, want), d_want = graded(reference)(*args)
     (_, low), d_low = graded(rounded)(*args)
     least = float(least)

@@ -1,11 +1,13 @@
-"""The gated delta rule's in-chunk terms as Pallas TPU kernels (forward +
-backward).
+"""The gated delta rule as Pallas TPU kernels (forward + backward): the
+chunks' terms, for a decay a channel and a decay a head, and the scan
+that carries the state from chunk to chunk.
 
 ``ops/recurrent_ops.py`` runs the recurrence in chunks of ``C`` tokens:
 everything of a chunk that does not depend on the state it starts from
 (``A``, ``B``, ``(I + Diag(beta) A)^-1``, ``W``, ``U0``, the decayed
-copies of q and k) is computed for all chunks at once, and a ``lax.scan``
-over the chunks carries the state. This module is that first half. A grid
+copies of q and k) is computed for all chunks at once, then the state is
+carried over the chunks in order. The first part of this module is that
+first half. A grid
 step takes a few chunks of one (batch, head): it reads q, k, v, g, beta of
 those tokens into VMEM once, and what XLA's version of the same algebra
 (:func:`flexflow_tpu.ops.recurrent_ops._chunk_terms`, the fallback and
@@ -37,7 +39,7 @@ spans' products, ``W``, ``U0`` and their transposes in the backward);
 running sums, exponentials, the sub-blocks' sums, the inverse and the
 gradients are float32.
 
-The second half of the module is the HEAD form (a decay a head, Gated
+The second part of the module is the HEAD form (a decay a head, Gated
 DeltaNet's: ``g`` one scalar a head-token), the same algorithm whose
 ``A`` and ``B`` are formed differently: ``A = (K K^T) * L``, ``B = (Q
 K^T) * L`` with ``L_ij = exp(G_i - G_j)``, one product each and one ``(C,
@@ -50,6 +52,14 @@ never repeated, and their raw products are made once for the group. It
 shares ``_mm``, ``_column``, ``_span_mask``, ``_inverse`` (the same
 blocked inverse, ``N`` split by masks alone), the block specs and
 ``VMEM_LIMIT`` with the channel form and changes no line of it.
+
+The third part is the SCAN, one kernel pair for both forms
+(:func:`scan_chunks`, against ``jax.lax.scan`` over
+:func:`flexflow_tpu.ops.recurrent_ops._chunk_step`): the grid's last
+axis is the groups of chunks in order, a block of heads' state rides in
+VMEM scratch from a chunk to the next, the backward walks the chunks
+last to first carrying the state's cotangent. It reads the six terms
+where the terms kernels left them and changes no line of theirs.
 """
 from __future__ import annotations
 
@@ -925,3 +935,277 @@ def head_chunk_terms(q, k, v, g, beta, chunk, mdt, *, layer=None,
     return tuple([x.reshape((n, b, h) + x.shape[3:]) for x in terms]
                  + [dec.reshape(n, b, h, LANES)[..., :1],
                     least.reshape(n, b, h, LANES)[..., :1]])
+
+
+# ---------------------------------------------------------------------------
+# the scan: the state from chunk to chunk, for both forms of the decay.
+# Given the state ``S`` a chunk starts from (dk, dv a head, float32) and
+# the chunk's six terms,
+#
+#     U  = U0 - W S
+#     O  = (q e^G) S + B U
+#     S' = e^{G_C} S + (k e^{G_C - G})^T U
+#
+# (``ops/recurrent_ops.py::_chunk_step``, the fallback and the tests'
+# oracle, under a ``lax.scan``: a ``while`` of N dependent iterations, a
+# dynamic slice of every term and a dynamic update of the stacked outputs
+# an iteration, the state to HBM and back between them). Here the grid's
+# last axis is the groups of chunks in order, a block of heads' state
+# rides in VMEM scratch from a chunk to the next, and a step's products
+# are batched over the block's heads, whose chains do not wait on each
+# other. The state is held TRANSPOSED, (dv, dk): the channel form's decay
+# a channel of k then lies along the lanes as the terms kernel wrote it
+# and is spread down the rows for nothing, and the head form's scalar is
+# the same operand with a row of 1. The terms come in chunk leading as
+# the terms kernels leave them, ``O`` leaves as (B H, T, dv) rows, and
+# the state each chunk started from is kept for the backward, which walks
+# the chunks last to first carrying the state's cotangent, forms ``U``
+# again and writes the six terms' cotangents in the terms' own types.
+# Products round their operands to the terms' ``mdt`` exactly where
+# ``_chunk_step`` does (``S`` and ``U``, and in the backward the
+# cotangents they meet); sums, states and decays are float32.
+# ---------------------------------------------------------------------------
+#: (batch x value head) rows a grid step of the scan takes: their chains
+#: of dependent products are independent of each other
+SCAN_HEADS_PER_STEP = 8
+#: chunks a grid step of the scan walks
+SCAN_CHUNKS_PER_STEP = 4
+#: Mosaic's scoped-VMEM limit for the scan kernels (:func:`scan_vmem_bytes`)
+SCAN_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n`` that is no more than ``most``."""
+    return next(d for d in range(min(n, most), 0, -1) if n % d == 0)
+
+
+def scan_vmem_bytes(kernel, chunk, dk, dv, itemsize,
+                    heads=SCAN_HEADS_PER_STEP,
+                    per_step=SCAN_CHUNKS_PER_STEP):
+    """Working set of one grid step of the scan: the double-buffered
+    blocks of the terms, the outputs' rows and the starting states (the
+    backward: the terms' cotangents too), the state in scratch and the
+    float32 values a chunk's products hold at once."""
+    lanes = max(chunk, LANES)
+    terms = chunk * (3 * dk * itemsize + dv * 4 + lanes * itemsize) \
+        + 8 * LANES * 4
+    rows, state = chunk * dv * 4, dk * dv * 4
+    blocks = terms + rows + state
+    if kernel == "scan_bwd":
+        blocks += terms
+    values = (6 if kernel == "scan_fwd" else 12) * rows + 3 * state
+    return heads * (2 * per_step * blocks + state + values)
+
+
+def _scan_fwd_kernel(w_ref, u0_ref, b_ref, qd_ref, kd_ref, dec_ref, o_ref,
+                     starts_ref, s_ref):
+    per_step, c, mdt = w_ref.shape[0], w_ref.shape[2], w_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    state = s_ref[...]                          # (heads, dv, dk): S^T
+    for i in range(per_step):
+        starts_ref[i] = state
+        sm = state.astype(mdt)
+        u = (u0_ref[i] - _mm(w_ref[i], sm, tb=True, mdt=mdt)).astype(mdt)
+        o_ref[:, i * c:(i + 1) * c, :] = (
+            _mm(qd_ref[i], sm, tb=True, mdt=mdt) + _mm(b_ref[i], u, mdt=mdt))
+        state = dec_ref[i] * state + _mm(u, kd_ref[i], ta=True, mdt=mdt)
+    s_ref[...] = state
+
+
+def _scan_bwd_kernel(w_ref, u0_ref, b_ref, qd_ref, kd_ref, dec_ref,
+                     starts_ref, do_ref, dw_ref, du0_ref, db_ref, dqd_ref,
+                     dkd_ref, ddec_ref, ds_ref):
+    per_step, c, mdt = w_ref.shape[0], w_ref.shape[2], w_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    ds = ds_ref[...]            # (heads, dv, dk): dS'^T, the state a chunk
+    for i in reversed(range(per_step)):         # leaves
+        state = starts_ref[i]
+        w, b, qd, kd = w_ref[i], b_ref[i], qd_ref[i], kd_ref[i]
+        sm, dsm = state.astype(mdt), ds.astype(mdt)
+        u = (u0_ref[i] - _mm(w, sm, tb=True, mdt=mdt)).astype(mdt)
+        do = do_ref[:, i * c:(i + 1) * c, :].astype(mdt)
+        # O = (q e^G) S + B U;  S' = e^{G_C} S + (k e^{G_C - G})^T U
+        du = _mm(b, do, ta=True, mdt=mdt) + _mm(kd, dsm, tb=True, mdt=mdt)
+        dum = du.astype(mdt)
+        db_ref[i] = _mm(do, u, tb=True, mdt=mdt).astype(db_ref.dtype)
+        dqd_ref[i] = _mm(do, sm, mdt=mdt).astype(dqd_ref.dtype)
+        dkd_ref[i] = _mm(u, dsm, mdt=mdt).astype(dkd_ref.dtype)
+        held = jnp.sum(ds * state, axis=1, keepdims=True)   # (heads, 1, dk)
+        if ddec_ref.shape[-1] == 1:             # a decay a head
+            held = jnp.sum(held, axis=2, keepdims=True)
+        ddec_ref[i] = held
+        # U = U0 - W S
+        du0_ref[i] = du
+        dw_ref[i] = (-_mm(dum, sm, mdt=mdt)).astype(dw_ref.dtype)
+        ds = (dec_ref[i] * ds + _mm(do, qd, ta=True, mdt=mdt)
+              - _mm(dum, w, ta=True, mdt=mdt))
+    ds_ref[...] = ds
+
+
+def _scan_layout(terms, heads, per_step, backward):
+    """Grid (blocks of batch x head rows, groups of chunks), the block
+    specs of the six terms (N, BH, ..), of the rows (BH, N C, dv) and of
+    the states (N, BH, dv, dk); the backward walks the groups last to
+    first."""
+    n, bh, c, dk = terms[0].shape
+    dv, groups = terms[1].shape[3], n // per_step
+
+    def at(j):
+        return groups - 1 - j if backward else j
+
+    def term(*last):
+        zeros = (0,) * len(last)
+        return pl.BlockSpec((per_step, heads) + last,
+                            lambda r, j: (at(j), r) + zeros)
+
+    rows = pl.BlockSpec((heads, per_step * c, dv),
+                        lambda r, j: (r, at(j), 0))
+    return ((bh // heads, groups), [term(*x.shape[2:]) for x in terms],
+            rows, term(dv, dk))
+
+
+def _scan_cost(kernel, w, u0, dec):
+    """What a call does, for XLA's scheduler: the products of a (head,
+    chunk), the terms', rows' and states' bytes."""
+    n, bh, c, dk = w.shape
+    dv, itemsize = u0.shape[3], w.dtype.itemsize
+    wide, square = 2 * c * dk * dv, 2 * c * c * dv
+    terms = c * (3 * dk * itemsize + 4 * dv + c * itemsize) \
+        + 4 * dec.shape[3]
+    flops, moved = 3 * wide + square, terms + 4 * (c + dk) * dv
+    if kernel == "scan_bwd":
+        flops, moved = 8 * wide + 2 * square, 2 * terms + 4 * (c + dk) * dv
+    return pl.CostEstimate(flops=n * bh * flops, transcendentals=0,
+                           bytes_accessed=n * bh * moved)
+
+
+_SCAN_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=SCAN_VMEM_LIMIT)
+_SCAN_STATIC = ("heads", "per_step", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_SCAN_STATIC, inline=True)
+def _scan_fwd_call(w, u0, b, qd, kd, dec, heads, per_step, interpret):
+    n, bh, c, dk = w.shape
+    dv = u0.shape[3]
+    grid, terms, rows, states = _scan_layout((w, u0, b, qd, kd, dec), heads,
+                                             per_step, False)
+    return pl.pallas_call(
+        _scan_fwd_kernel, grid=grid, in_specs=terms,
+        out_specs=[rows, states],
+        out_shape=[jax.ShapeDtypeStruct((bh, n * c, dv), F32),
+                   jax.ShapeDtypeStruct((n, bh, dv, dk), F32)],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), F32)],
+        compiler_params=_SCAN_PARAMS, interpret=interpret,
+        cost_estimate=_scan_cost("scan_fwd", w, u0, dec),
+        name="gated_delta_rule_scan_fwd",
+    )(w, u0, b, qd, kd, dec)
+
+
+@functools.partial(jax.jit, static_argnames=_SCAN_STATIC, inline=True)
+def _scan_bwd_call(w, u0, b, qd, kd, dec, starts, do, heads, per_step,
+                   interpret):
+    dv, dk = starts.shape[2:]
+    grid, terms, rows, states = _scan_layout((w, u0, b, qd, kd, dec), heads,
+                                             per_step, True)
+    return pl.pallas_call(
+        _scan_bwd_kernel, grid=grid, in_specs=terms + [states, rows],
+        out_specs=terms,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (w, u0, b, qd, kd, dec)],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), F32)],
+        compiler_params=_SCAN_PARAMS, interpret=interpret,
+        cost_estimate=_scan_cost("scan_bwd", w, u0, dec),
+        name="gated_delta_rule_scan_bwd",
+    )(w, u0, b, qd, kd, dec, starts, do)
+
+
+def _scan_note(kernel, scope, layer, w, u0, heads, per_step):
+    """One ``kda.kernel`` / ``gdn.kernel`` instant per emitted call of
+    the scan, at trace time."""
+    if events.enabled():
+        n, bh, c, dk = w.shape
+        events.instant(
+            scope + ".kernel", kernel=kernel, layer=layer, chunk=c,
+            chunks=bh * n, grid_steps=bh * n // (heads * per_step),
+            heads_per_step=heads, chunks_per_step=per_step,
+            vmem_bytes=scan_vmem_bytes(kernel, c, dk, u0.shape[3],
+                                       w.dtype.itemsize, heads, per_step))
+
+
+def _noted_scan_fwd_call(w, u0, b, qd, kd, dec, heads, per_step, scope,
+                         layer, interpret):
+    _scan_note("scan_fwd", scope, layer, w, u0, heads, per_step)
+    return tuple(_scan_fwd_call(w, u0, b, qd, kd, dec, heads, per_step,
+                                interpret))
+
+
+_scan = jax.custom_vjp(_noted_scan_fwd_call,
+                       nondiff_argnums=(6, 7, 8, 9, 10))
+
+
+def _scan_fwd(w, u0, b, qd, kd, dec, *static):
+    o, starts = _noted_scan_fwd_call(w, u0, b, qd, kd, dec, *static)
+    return (o, starts), (w, u0, b, qd, kd, dec, starts)
+
+
+def _scan_bwd(heads, per_step, scope, layer, interpret, res, cts):
+    _scan_note("scan_bwd", scope, layer, res[0], res[1], heads, per_step)
+    # (the states are handed out for the tests to read, not to be pulled
+    # back through)
+    return tuple(_scan_bwd_call(*res, cts[0], heads, per_step, interpret))
+
+
+_scan.defvjp(_scan_fwd, _scan_bwd)
+
+
+def scan_chunks(w, u0, b, q_dec, k_dec, decay, *, scope="kda", layer=None,
+                interpret=None, mesh=None, spec=None):
+    """The recurrence over the chunks from a zero state, by the scan
+    kernels, on the terms as :func:`chunk_terms` / :func:`head_chunk_terms`
+    return them, chunk leading: ``W`` (N, B, H, C, dk), ``U0`` (.., C, dv)
+    float32, ``B`` (.., C, C), ``q exp(G)``, ``k exp(G_C - G)``, and
+    ``exp(G_C)`` (N, B, H, dk), a decay a channel, or (N, B, H, 1), a
+    decay a head. Returns ``O`` (B, H, N C, dv) float32 and the state
+    each chunk starts from, transposed, (N, B, H, dv, dk) (what the
+    backward keeps; no cotangent is taken for it). ``scope`` (``"kda"`` /
+    ``"gdn"``) and ``layer`` name the caller in the ``<scope>.kernel``
+    instants.
+
+    Under a mesh of more than one device the call runs under
+    ``shard_map`` over the batch and head entries of ``spec``: every
+    (batch, head) carries a state of its own."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    if mesh is not None and mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+        bh = (tuple(spec or ()) + (None, None))[:2]
+        local = functools.partial(scan_chunks, scope=scope, layer=layer,
+                                  interpret=interpret)
+        # check_vma off: pallas_call outputs carry no varying-axes info
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(None, *bh, None, None),) * 5 + (P(None, *bh, None),),
+            out_specs=(P(*bh, None, None), P(None, *bh, None, None)),
+            check_vma=False)(w, u0, b, q_dec, k_dec, decay)
+    n, bsz, h, c, dk = w.shape
+    dv = u0.shape[-1]
+
+    def rows(x, *last):
+        return x.reshape((n, bsz * h) + (last or x.shape[3:]))
+
+    o, starts = _scan(
+        rows(w), rows(u0), rows(b), rows(q_dec), rows(k_dec),
+        rows(decay, 1, decay.shape[-1]),
+        _divisor(bsz * h, SCAN_HEADS_PER_STEP),
+        _divisor(n, SCAN_CHUNKS_PER_STEP), scope, layer, bool(interpret))
+    return o.reshape(bsz, h, n * c, dv), starts.reshape(n, bsz, h, dv, dk)

@@ -12,7 +12,9 @@ computes the same thing in chunks of ``C`` tokens: inside a chunk the
 writes ``u_i = beta_i (v_i - (state before i)^T k_i)`` solve one
 unit-lower-triangular ``C x C`` system a head, which does not depend on
 the state the chunk starts from, so every chunk's system is solved at
-once; between chunks a ``lax.scan`` carries ``S``. With ``G`` the running
+once; between chunks ``S`` is carried in order (by the scan kernels of
+``kernels/gated_delta_rule.py`` wherever the terms come from its
+kernels, otherwise by a ``lax.scan``). With ``G`` the running
 sum of ``g`` inside the chunk (every entry <= 0), ``S_0`` the state at the
 chunk's start:
 
@@ -83,7 +85,8 @@ from ..core.tensor import WeightSpec
 from ..ffconst import InitializerType, OperatorType
 from ..kernels import state_space as ssm_kernels
 from ..kernels.gated_delta_rule import (SUB, chunk_terms, head_chunk_terms,
-                                        takes_head_kernel, takes_kernel)
+                                        scan_chunks, takes_head_kernel,
+                                        takes_kernel)
 from ..obs import events
 from .nn_ops import MultiHeadAttentionOp, _rms, short_conv
 from .registry import (OpDef, checkpointed, compute_dtype, register,
@@ -210,6 +213,22 @@ def _chunk_step(mdt, state, terms):
     return state, out
 
 
+def _plain_scan(terms, mdt, *, layer=None, mesh=None, spec=None):
+    """The state carried over the chunks by a ``lax.scan`` over
+    :func:`_chunk_step`, its body rematerialised, on the six terms chunk
+    leading (N, B, H, ..): ``O`` (B, H, N C, dv). The fallback, and what
+    the scan kernels are timed and tested against."""
+    w, u0 = terms[0], terms[1]
+    state = jnp.zeros(w.shape[1:3] + (w.shape[-1], u0.shape[-1]),
+                      jnp.float32)
+    _, out = jax.lax.scan(                      # over the chunks: N leads
+        checkpointed(lambda s, xs: _chunk_step(mdt, s, xs), site="kda.step",
+                     layer=layer, specs=(spec, spec), mesh=mesh),
+        state, terms)
+    out = jnp.moveaxis(out, 0, 2)                  # (B, H, N, C, dv)
+    return out.reshape(out.shape[:2] + (-1,) + out.shape[4:])
+
+
 def _in_chunks(x, chunk, axis=2):
     """(B, H, T, ..) -> (B, H, N, C, ..) float32 (``axis``: where T
     stands); the padded positions write nothing (beta, dt 0) and decay
@@ -263,15 +282,26 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
     their ``kda.kernel`` instants, ``mesh`` / ``spec`` are
     ``flash_attention``'s), forward and backward under one
     ``custom_vjp`` that keeps the five inputs; otherwise from
-    :func:`_chunk_terms`, rematerialised, with autodiff's backward. The
-    scan over the chunks is plain JAX either way, its body
-    rematerialised: the backward pass holds the chunk-boundary states,
-    the terms the scan reads and one chunk's matrices, not the ``(SUB,
-    SUB, d)`` differences nor every chunk's intermediate products."""
+    :func:`_chunk_terms`, rematerialised, with autodiff's backward.
+
+    Wherever the terms came from the kernels, of either form, the state
+    is carried over the chunks by the scan kernel pair of the same
+    module (``scan_chunks``: the same predicate, no other; the state in
+    VMEM from a chunk to the next, the outputs written as ``(B H, T,
+    dv)`` rows, one ``kda.kernel`` / ``gdn.kernel`` instant a call with
+    ``kernel="scan_fwd"`` / ``"scan_bwd"``) under a ``custom_vjp`` that
+    keeps the six terms and the state each chunk starts from. Every
+    other shape takes a plain ``lax.scan`` over :func:`_chunk_step`, its
+    body rematerialised: the backward pass holds the chunk-boundary
+    states, the terms the scan reads and one chunk's matrices, not the
+    ``(SUB, SUB, d)`` differences nor every chunk's intermediate
+    products. That scan is also what the kernels are tested against."""
     t = q.shape[2]
+    scope = None                # the kernels' instants, where they run
     if g.ndim == 3 and head_decay_impl(
             chunk, k.shape[1], v.shape[1], k.shape[-1], v.shape[-1], mesh,
             spec) == "kernel":
+        scope = "gdn"
         *terms, least = head_chunk_terms(q, k, v, g, beta, chunk, mdt,
                                          layer=layer, mesh=mesh, spec=spec)
         least = jnp.min(least)
@@ -282,6 +312,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
             *(_in_chunks(x, chunk) for x in (q, k, v, g, beta)))
         terms = [jnp.moveaxis(x, 2, 0) for x in terms]
     elif takes_kernel(chunk, k.shape[-1], v.shape[-1]):
+        scope = "kda"
         *terms, least = chunk_terms(q, k, v, g, beta, chunk, mdt,
                                     layer=layer, mesh=mesh, spec=spec)
         least = jnp.min(least)
@@ -291,15 +322,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK,
             specs=(spec,) * 5, mesh=mesh)(
             *(_in_chunks(x, chunk) for x in (q, k, v, g, beta)))
         terms = [jnp.moveaxis(x, 2, 0) for x in terms]
-    state = jnp.zeros(v.shape[:2] + (k.shape[-1], v.shape[-1]),
-                      jnp.float32)
-    _, out = jax.lax.scan(                      # over the chunks: N leads
-        checkpointed(lambda s, xs: _chunk_step(mdt, s, xs), site="kda.step",
-                     layer=layer, specs=(spec, spec), mesh=mesh),
-        state, terms)
-    out = jnp.moveaxis(out, 0, 2)                  # (B, H, N, C, dv)
-    out = out.reshape(out.shape[:2] + (-1,) + out.shape[4:])[:, :, :t]
-    return out, jax.lax.stop_gradient(least)
+    if scope is not None:       # the terms as the kernels left them
+        out, _ = scan_chunks(*terms, scope=scope, layer=layer, mesh=mesh,
+                             spec=spec)
+    else:
+        out = _plain_scan(terms, mdt, layer=layer, mesh=mesh, spec=spec)
+    return out[:, :, :t], jax.lax.stop_gradient(least)
 
 
 def _ssm_chunks(mdt, dtx, bm, cm, big_g):
@@ -426,17 +454,18 @@ class GatedDeltaRuleOp(OpDef):
 
     with ``A_log`` and ``dt_bias`` a head and no low-rank pair. The
     recurrence then runs under the name scope ``gdn.scan`` (the chunks'
-    terms by the head form of the kernels at head sizes in whole lanes,
-    their backward too, otherwise :func:`_chunk_terms_head`; the
-    ``gdn.scan`` instant's ``impl`` says which), its instant and
-    counters are ``gdn.*``.
+    terms by the head form of the kernels and the state by the scan
+    kernels at head sizes in whole lanes, their backward too, otherwise
+    :func:`_chunk_terms_head` and a ``lax.scan``; the ``gdn.scan``
+    instant's ``impl`` and ``scan`` say which), its instant and counters
+    are ``gdn.*``.
 
     No bias in any projection. The projections are matrix products at
     the compute dtype with float32 accumulation; taps, gates, norms,
     decays, the state and the chunks' inverse are float32. The
-    recurrence (the chunks' terms, by the kernels of
-    ``kernels/gated_delta_rule.py`` at head sizes in whole lanes, and
-    the scan; not the projections) runs under the name scope
+    recurrence (the chunks' terms and the scan over the chunk states,
+    both by the kernels of ``kernels/gated_delta_rule.py`` at head sizes
+    in whole lanes; not the projections) runs under the name scope
     ``kda.scan``. Training and evaluation only: there is no
     decode path that carries the state from call to call."""
     op_type = OperatorType.OP_GATED_DELTA_RULE
@@ -571,22 +600,23 @@ class GatedDeltaRuleOp(OpDef):
         if events.enabled() and by_head:
             chunks = -(-t // chunk)
             hk, dk = weights["wk"].shape[1:]
+            # (the scan kernels run wherever the terms' do: one predicate)
+            impl = head_decay_impl(chunk, hk, h, dk, d, mesh, spec)
             events.instant("gdn.scan", layer=name,
                            key_heads=hk, value_heads=h,
                            key_head_dim=dk, head_dim=d,
                            taps=weights["conv_q"].shape[-1],
                            tokens=b * t, chunk=chunk, chunks=chunks,
                            state_bytes=4 * b * chunks * h * d * d,
-                           impl=head_decay_impl(chunk, hk, h, dk, d, mesh,
-                                                spec))
+                           impl=impl, scan=impl)
         elif events.enabled():
             chunks = -(-t // chunk)
+            impl = "kernel" if takes_kernel(chunk, d, d) else "plain"
             events.instant("kda.scan", layer=name, heads=h, head_dim=d,
                            taps=weights["conv_q"].shape[-1],
                            tokens=b * t, chunk=chunk, chunks=chunks,
                            state_bytes=4 * b * chunks * h * d * d,
-                           impl="kernel" if takes_kernel(chunk, d, d)
-                           else "plain")
+                           impl=impl, scan=impl)
 
         # The layer is rematerialised whole, and inside it each branch
         # of the projections once more: what it keeps for the backward

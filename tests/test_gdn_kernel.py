@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import delta_rule_scan as drs
 from benchmarks.harness import cells
 from flexflow_tpu import FFConfig
 from flexflow_tpu.ffconst import DataType
@@ -290,12 +291,14 @@ def gdn_layer(d, chunk, key_heads=1, heads=2, e=24, length=40):
 
 
 @pytest.mark.parametrize("d,chunk,impl,calls", [
-    (128, 64, "kernel", ["fwd", "bwd"]), (128, 16, "kernel", ["fwd", "bwd"]),
+    (128, 64, "kernel", ["fwd", "bwd", "scan_fwd", "scan_bwd"]),
+    (128, 16, "kernel", ["fwd", "bwd", "scan_fwd", "scan_bwd"]),
     (16, 64, "plain", []), (128, 8, "plain", [])])
 def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
-    """``impl`` on the layer's ``gdn.scan`` instant, and one
-    ``gdn.kernel`` instant a kernel traced under ``jax.grad``: the
-    forward rule's call and the backward's; no ``kda.*`` name."""
+    """``impl`` and ``scan`` on the layer's ``gdn.scan`` instant, and
+    one ``gdn.kernel`` instant a kernel traced under ``jax.grad``: the
+    forward rule's call and the backward's, of the terms and of the
+    scan; no ``kda.*`` name."""
     loss, u, w = gdn_layer(d, chunk)
     events.enable()
     events.clear()
@@ -308,6 +311,7 @@ def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
         events.clear()
     (scan,) = [e["attrs"] for e in seen if e["name"] == "gdn.scan"]
     assert scan["impl"] == impl and scan["layer"] == "gdn_7"
+    assert scan["scan"] == impl
     assert not [e for e in seen if e["name"].startswith("kda.")]
     kernels = [e["attrs"] for e in seen if e["name"] == "gdn.kernel"]
     wraps = [e["attrs"]["site"] for e in seen if e["name"] == "remat.wrap"]
@@ -320,6 +324,11 @@ def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
         # 40 tokens: one chunk of 64 or three of 16 a head, two heads of
         # one q/k head a step
         n = -(-40 // chunk)
+        if k["kernel"].startswith("scan"):
+            assert k["chunks"] == 2 * n and k["heads_per_step"] == 2
+            assert k["chunks_per_step"] == n and k["grid_steps"] == 1
+            assert 0 < k["vmem_bytes"] < kernel.SCAN_VMEM_LIMIT
+            continue
         assert k["group"] == 2 and k["chunks"] == 2 * n
         assert k["chunks_per_step"] == 2 * n and k["grid_steps"] == 1
         assert 0 < k["vmem_bytes"] < kernel.VMEM_LIMIT
@@ -372,3 +381,46 @@ def test_the_kernels_under_a_mesh_are_the_unsharded_ones(by):
         lambda *a: loss(mesh, spec, *a), argnums=range(5)))(q, k, v, g, beta)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         close(a, b, 2e-5)
+
+
+# ----------------------------------------------------------------------
+# the scan kernel pair: the state from chunk to chunk in VMEM (the
+# checks' bodies are ``tests/delta_rule_scan.py``'s, here with a decay
+# a head)
+# ----------------------------------------------------------------------
+SHAPE_IDS = ["x".join(map(str, s)) for s in drs.SHAPES]
+
+
+@pytest.fixture(scope="module")
+def scanned():
+    return drs.both_paths(by_head=True)
+
+
+@pytest.mark.parametrize("what", range(7), ids=drs.NAMES)
+@pytest.mark.parametrize("mdt", sorted(drs.MDTS))
+@pytest.mark.parametrize("shape", drs.SHAPES, ids=SHAPE_IDS)
+def test_the_scan_kernels_are_the_plain_scan_on_the_same_terms(
+        scanned, shape, mdt, what):
+    """The output and the six terms' cotangents, float32 and bf16 terms:
+    two groups of chunks over two blocks of (batch x head) rows with a
+    batch of two, a sequence of five chunks (no whole number of groups
+    of four), two groups of four."""
+    drs.check_against_the_plain_scan(scanned, shape, mdt, what)
+
+
+@pytest.mark.parametrize("mdt", sorted(drs.MDTS))
+@pytest.mark.parametrize("shape", drs.SHAPES, ids=SHAPE_IDS)
+def test_the_scan_keeps_the_state_each_chunk_starts_from(scanned, shape,
+                                                         mdt):
+    drs.check_the_starting_states(scanned, shape, mdt)
+
+
+@pytest.mark.parametrize("heads,chunks,steps", drs.STEPS)
+def test_the_scan_says_what_it_ran(heads, chunks, steps):
+    """One ``gdn.kernel`` instant a call, forward and backward."""
+    drs.check_what_the_scan_says(True, "gdn", heads, chunks, steps)
+
+
+@pytest.mark.parametrize("by", ["batch", "heads"])
+def test_the_scan_under_a_mesh_is_the_unsharded_one(by):
+    drs.check_under_a_mesh(True, by)

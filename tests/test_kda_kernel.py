@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import delta_rule_scan as drs
 from benchmarks.harness import cells
 from flexflow_tpu import FFConfig
 from flexflow_tpu.ffconst import DataType
@@ -225,12 +226,14 @@ def kda_layer(d, chunk, heads=2, e=24, length=40):
 
 
 @pytest.mark.parametrize("d,chunk,impl,calls", [
-    (128, 64, "kernel", ["fwd", "bwd"]), (128, 16, "kernel", ["fwd", "bwd"]),
+    (128, 64, "kernel", ["fwd", "bwd", "scan_fwd", "scan_bwd"]),
+    (128, 16, "kernel", ["fwd", "bwd", "scan_fwd", "scan_bwd"]),
     (16, 64, "plain", []), (128, 8, "plain", [])])
 def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
-    """``impl`` on the layer's ``kda.scan`` instant, and one
-    ``kda.kernel`` instant a kernel traced under ``jax.grad``: the
-    forward rule's call and the backward's."""
+    """``impl`` and ``scan`` on the layer's ``kda.scan`` instant, and
+    one ``kda.kernel`` instant a kernel traced under ``jax.grad``: the
+    forward rule's call and the backward's, of the terms and of the
+    scan."""
     loss, u, w = kda_layer(d, chunk)
     events.enable()
     events.clear()
@@ -243,12 +246,20 @@ def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
         events.clear()
     (scan,) = [e["attrs"] for e in seen if e["name"] == "kda.scan"]
     assert scan["impl"] == impl and scan["layer"] == "kda_7"
+    assert scan["scan"] == impl
     kernels = [e["attrs"] for e in seen if e["name"] == "kda.kernel"]
     # (the layer is rematerialised whole: jax.checkpoint traces its
     # forward once more before the rules run)
     assert sorted({k["kernel"] for k in kernels}) == sorted(calls)
     for k in kernels:
         assert k["layer"] == "kda_7" and k["chunk"] == chunk
+        if k["kernel"].startswith("scan"):
+            # two heads a step, all of a head's chunks
+            assert k["chunks"] == 2 * -(-40 // chunk)
+            assert k["heads_per_step"] == 2 and k["grid_steps"] == 1
+            assert k["chunks_per_step"] == -(-40 // chunk)
+            assert 0 < k["vmem_bytes"] < kernel.SCAN_VMEM_LIMIT
+            continue
         # 40 tokens: one chunk of 64 or three of 16 a head, a head a step
         assert k["sub"] == kernel.SUB and k["chunks"] == 2 * -(-40 // chunk)
         assert k["chunks_per_step"] == -(-40 // chunk)
@@ -305,3 +316,46 @@ def test_the_kernels_under_a_mesh_are_the_unsharded_ones(by):
         lambda *a: loss(mesh, spec, *a), argnums=range(5)))(q, k, v, g, beta)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         close(a, b, 2e-5)
+
+
+# ----------------------------------------------------------------------
+# the scan kernel pair: the state from chunk to chunk in VMEM (the
+# checks' bodies are ``tests/delta_rule_scan.py``'s, here with a decay
+# a channel)
+# ----------------------------------------------------------------------
+SHAPE_IDS = ["x".join(map(str, s)) for s in drs.SHAPES]
+
+
+@pytest.fixture(scope="module")
+def scanned():
+    return drs.both_paths(by_head=False)
+
+
+@pytest.mark.parametrize("what", range(7), ids=drs.NAMES)
+@pytest.mark.parametrize("mdt", sorted(drs.MDTS))
+@pytest.mark.parametrize("shape", drs.SHAPES, ids=SHAPE_IDS)
+def test_the_scan_kernels_are_the_plain_scan_on_the_same_terms(
+        scanned, shape, mdt, what):
+    """The output and the six terms' cotangents, float32 and bf16 terms:
+    two groups of chunks over two blocks of (batch x head) rows with a
+    batch of two, a sequence of five chunks (no whole number of groups
+    of four), two groups of four."""
+    drs.check_against_the_plain_scan(scanned, shape, mdt, what)
+
+
+@pytest.mark.parametrize("mdt", sorted(drs.MDTS))
+@pytest.mark.parametrize("shape", drs.SHAPES, ids=SHAPE_IDS)
+def test_the_scan_keeps_the_state_each_chunk_starts_from(scanned, shape,
+                                                         mdt):
+    drs.check_the_starting_states(scanned, shape, mdt)
+
+
+@pytest.mark.parametrize("heads,chunks,steps", drs.STEPS)
+def test_the_scan_says_what_it_ran(heads, chunks, steps):
+    """One ``kda.kernel`` instant a call, forward and backward."""
+    drs.check_what_the_scan_says(False, "kda", heads, chunks, steps)
+
+
+@pytest.mark.parametrize("by", ["batch", "heads"])
+def test_the_scan_under_a_mesh_is_the_unsharded_one(by):
+    drs.check_under_a_mesh(False, by)

@@ -344,7 +344,8 @@ def test_flash_without_shard_map_is_refused_on_2x2(v5e_devices):
 # ----------------------------------------------------------------------
 # the gated delta rule's in-chunk terms (kernels/gated_delta_rule.py)
 # ----------------------------------------------------------------------
-KDA_NAMES = ["gated_delta_rule_bwd", "gated_delta_rule_fwd"]
+KDA_NAMES = ["gated_delta_rule_bwd", "gated_delta_rule_fwd",
+             "gated_delta_rule_scan_bwd", "gated_delta_rule_scan_fwd"]
 
 
 @pytest.fixture
@@ -385,15 +386,18 @@ KDA_SHAPES = [(1, 32, 4096, 128, 64), (1, 32, 8192, 128, 64),
 def test_the_linear_attention_kernels_compile(v5e_devices, compiled_kda,
                                               b, h, t, d, chunk):
     """Forward and backward for a described v5e: no triangular solve
-    (XLA's is a custom call) and no other custom call than the two
-    kernels' and XLA's own buffers is left in the recurrence."""
+    (XLA's is a custom call), no other custom call than the four
+    kernels' (the terms' pair and the scan's) and XLA's own buffers,
+    and no ``while`` is left in the recurrence."""
     mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
     txt = _compile_text(
         jax.grad(functools.partial(_kda_loss, None, None, chunk=chunk),
                  argnums=range(5)), *_kda_operands(mesh, (), b, h, t, d))
     assert _kernel_names(txt) == KDA_NAMES
+    # (ConcatBitcast: XLA's own, the decays sliced into fast memory)
     assert set(re.findall(r'custom_call_target="([^"]+)"', txt)) <= {
-        "tpu_custom_call", "AllocateBuffer"}
+        "tpu_custom_call", "AllocateBuffer", "ConcatBitcast"}
+    assert " while(" not in txt
 
 
 @pytest.mark.parametrize("spec", [("x0", None), (None, "x0")],
@@ -413,7 +417,7 @@ def test_the_linear_attention_kernels_compile_under_a_mesh(
 
 def test_the_linear_attention_kernels_keep_their_scope(
         v5e_devices, compiled_kda, chip_locations):
-    """Both calls carry the layer's name and ``kda.scan`` in their
+    """All four calls carry the layer's name and ``kda.scan`` in their
     ``op_name``, the backward's inside the ``transpose(``: the
     benchmark's ``kda_time_share.train`` and
     ``kda_scan_time_share.train`` find them by those parts."""
@@ -427,17 +431,24 @@ def test_the_linear_attention_kernels_keep_their_scope(
         'pallas_call"' in by_name["gated_delta_rule_fwd"]
     assert 'transpose(jvp(ff.forward))/kda_2/kda.scan/' \
         'gated_delta_rule_bwd/pallas_call"' in by_name["gated_delta_rule_bwd"]
+    assert 'jvp(ff.forward)/kda_2/kda.scan/gated_delta_rule_scan_fwd/' \
+        'pallas_call"' in by_name["gated_delta_rule_scan_fwd"]
+    assert 'transpose(jvp(ff.forward))/kda_2/kda.scan/' \
+        'gated_delta_rule_scan_bwd/pallas_call"' \
+        in by_name["gated_delta_rule_scan_bwd"]
 
 
 def test_a_rematerialised_block_calls_the_forward_kernel_twice_a_layer(
         v5e_devices, compiled_kda, chip_locations):
     """The benchmark's layout at two heads of 128 over 512 positions,
     ``remat = "blocks"``, the train step compiled for a described v5e:
-    every linear-attention layer's forward kernel is called twice (the
-    forward pass and the layer's own second run for its backward), the
-    two inside the rematerialised blocks (``kda_1``, ``kda_2``) too,
-    where the block's second run called it a third time before the
-    block kept the layer's marked output; one backward call a layer."""
+    every linear-attention layer's forward kernels (the terms' and the
+    scan's) are called twice (the forward pass and the layer's own
+    second run for its backward), the two inside the rematerialised
+    blocks (``kda_1``, ``kda_2``) too, where the block's second run
+    called them a third time before the block kept the layer's marked
+    output; one backward call of each a layer, and no ``while`` under
+    ``kda.scan``."""
     import dataclasses
 
     from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
@@ -475,10 +486,13 @@ def test_a_rematerialised_block_calls_the_forward_kernel_twice_a_layer(
             op_name = line.split('op_name="')[1].split('"')[0]
             (layer,) = {p for p in op_name.split("/")
                         if p.startswith("kda_")}
-            kernel = "fwd" if "gated_delta_rule_fwd" in op_name else "bwd"
+            (kernel,) = re.findall(r"gated_delta_rule_(\w+)/", op_name)
             calls[layer, kernel] = calls.get((layer, kernel), 0) + 1
     assert calls == {(f"kda_{i}", k): n for i in (0, 1, 2, 4)
-                     for k, n in (("fwd", 2), ("bwd", 1))}
+                     for k, n in (("fwd", 2), ("bwd", 1), ("scan_fwd", 2),
+                                  ("scan_bwd", 1))}
+    assert not [l for l in txt.splitlines()
+                if " while(" in l and "kda.scan" in l]
 
 
 # the residual streams' mixes (kernels/hyper_connection.py)
@@ -1195,9 +1209,10 @@ def test_the_head_decay_delta_rule_compiles_at_the_published_width(
     """One linear layer's forward and backward at 2048 -> 16 q/k heads
     under 32 value heads of 128 over 8,192 positions in 128 chunks of
     64, bf16 operands, compiled for a described v5e: the chunks' terms by
-    the head form of the kernels, three Mosaic calls (the layer's run,
-    its recomputation and the backward) under ``gdn.scan``, q and k read
-    at their own 16 heads, a ``while`` over the chunk states; no
+    the head form of the kernels and the state by the scan kernels,
+    three Mosaic calls of each (the layer's run, its recomputation and
+    the backward) under ``gdn.scan``, q and k read at their own 16
+    heads, no ``while`` over the chunk states; no
     triangular solve, no ``remat.gdn.terms``, no float32 ``(64, 64)``
     matrix a head-chunk (64 MiB a layer) and no ``(64, 64, 128)`` tensor
     of channel differences is in the text, and the layer's temporaries
@@ -1226,16 +1241,24 @@ def test_the_head_decay_delta_rule_compiles_at_the_published_width(
     txt = compiled.as_text()
     calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
     assert _kernel_names(txt) == ["gated_delta_rule_head_bwd"] \
-        + ["gated_delta_rule_head_fwd"] * 2
-    assert all("gdn.scan" in l and "f32[16,8192,128]" in l for l in calls)
+        + ["gated_delta_rule_head_fwd"] * 2 \
+        + ["gated_delta_rule_scan_bwd"] \
+        + ["gated_delta_rule_scan_fwd"] * 2
+    assert all("gdn.scan" in l for l in calls)
+    assert all("f32[16,8192,128]" in l for l in calls if "_head_" in l)
+    # the scan's rows leave as (B H, T, dv) and its states stay (N, B H,
+    # dv, dk): no turn of the stacked outputs after a loop
+    assert all("f32[32,8192,128]" in l and "f32[128,32,128,128]" in l
+               for l in calls if "_scan_" in l)
     assert "triangular" not in txt and "remat.gdn.terms" not in txt \
-        and " while(" in txt and "kda.scan" not in txt
+        and " while(" not in txt and "kda.scan" not in txt
     assert not re.search(r"\bf32\[[0-9,]*,64,64\]", txt)
     assert not re.search(r"\b(?:f32|bf16)\[[0-9,]*,64,64,128\]", txt)
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
 
 
-GDN_NAMES = ["gated_delta_rule_head_bwd", "gated_delta_rule_head_fwd"]
+GDN_NAMES = ["gated_delta_rule_head_bwd", "gated_delta_rule_head_fwd",
+             "gated_delta_rule_scan_bwd", "gated_delta_rule_scan_fwd"]
 
 
 def _gdn_loss(mesh, spec, q, k, v, g, beta):
@@ -1277,7 +1300,7 @@ def test_the_head_decay_kernels_compile_under_a_mesh(
 
 def test_the_head_decay_kernels_keep_their_scope(v5e_devices, compiled_kda,
                                                  chip_locations):
-    """Both calls carry the layer's name and ``gdn.scan`` in their
+    """All four calls carry the layer's name and ``gdn.scan`` in their
     ``op_name``, the backward's inside the ``transpose(``: the
     benchmark's ``qwen3next_gdn_scan_time_share.train`` finds them by
     those parts."""
@@ -1294,3 +1317,88 @@ def test_the_head_decay_kernels_keep_their_scope(v5e_devices, compiled_kda,
     assert 'transpose(jvp(ff.forward))/linear_attn_1/gdn.scan/' \
         'gated_delta_rule_head_bwd/pallas_call"' \
         in by_name["gated_delta_rule_head_bwd"]
+    assert 'jvp(ff.forward)/linear_attn_1/gdn.scan/' \
+        'gated_delta_rule_scan_fwd/pallas_call"' \
+        in by_name["gated_delta_rule_scan_fwd"]
+    assert 'transpose(jvp(ff.forward))/linear_attn_1/gdn.scan/' \
+        'gated_delta_rule_scan_bwd/pallas_call"' \
+        in by_name["gated_delta_rule_scan_bwd"]
+
+
+# ----------------------------------------------------------------------
+# the delta rule's scan kernel pair (the state from chunk to chunk in
+# VMEM), alone at the two cells' shapes and inside cell 5's layer
+# ----------------------------------------------------------------------
+SCAN_NAMES = ["gated_delta_rule_scan_bwd", "gated_delta_rule_scan_fwd"]
+
+
+@pytest.mark.parametrize("chunks,lanes", [(64, 128), (128, 1)],
+                         ids=["cell_5_a_channel", "cell_10_a_head"])
+def test_the_scan_kernels_compile_at_the_cells_shapes(v5e_devices, chunks,
+                                                      lanes):
+    """The pair alone on the terms as the terms kernels leave them, bf16,
+    32 (batch x head) rows of 128 x 128 in chunks of 64: cell 5's 64
+    chunks with a decay a channel of k, cell 10's 128 with a decay a
+    head (the same operand with a row of 1, spread inside). Both calls
+    compile for a described v5e, take the terms chunk leading as they
+    lie and hand the rows out as ``(B H, T, dv)``."""
+    from flexflow_tpu.kernels.gated_delta_rule import scan_chunks
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+
+    def arr(dtype, *last):
+        return jax.ShapeDtypeStruct((chunks, 1, 32) + last, dtype,
+                                    sharding=one)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    terms = [arr(bf16, 64, 128), arr(f32, 64, 128), arr(bf16, 64, 64),
+             arr(bf16, 64, 128), arr(bf16, 64, 128), arr(f32, lanes)]
+    txt = _compile_text(jax.grad(
+        lambda *t: jnp.sum(scan_chunks(*t, interpret=False)[0] ** 2),
+        argnums=range(6)), *terms)
+    calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+    assert _kernel_names(txt) == SCAN_NAMES and " while(" not in txt
+    rows = f"f32[32,{chunks * 64},128]"
+    assert all(rows in l and f"bf16[{chunks},32,64,128]" in l
+               and f"f32[{chunks},32,128,128]" in l for l in calls)
+
+
+def test_the_channel_decay_delta_rule_compiles_at_the_published_width(
+        v5e_devices, compiled_kda):
+    """One linear layer's forward and backward at cell 5's shape (2304 ->
+    32 heads of 128 over 4,096 positions in 64 chunks of 64, bf16
+    operands) compiled for a described v5e: three Mosaic calls of the
+    terms' kernels and three of the scan's (the layer's run, its
+    recomputation and the backward), every one under ``kda.scan``; no
+    ``while`` is left under that scope (nor anywhere in the layer), no
+    triangular solve, and no array the size of the stacked outputs is
+    turned or copied around the scan's calls."""
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.ops.recurrent_ops import GatedDeltaRuleOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    params = {"num_heads": 32, "head_dim": 128, "taps": 4, "eps": 1e-5}
+    op = GatedDeltaRuleOp()
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    x = jax.ShapeDtypeStruct((1, 4096, 2304), jnp.float32, sharding=one)
+    w = {s.name: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=one)
+         for s in op.weights(params, [(1, 4096, 2304)],
+                             [DataType.DT_FLOAT])}
+
+    def loss(x, w):
+        (y,) = op.emit(params, [x], w,
+                       EmitCtx(training=True, config=FFConfig()), "kda_1")
+        return jnp.sum(y * y)
+
+    txt = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))
+                  ).lower(x, w).compile().as_text()
+    calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+    assert _kernel_names(txt) == ["gated_delta_rule_bwd"] \
+        + ["gated_delta_rule_fwd"] * 2 + ["gated_delta_rule_scan_bwd"] \
+        + ["gated_delta_rule_scan_fwd"] * 2
+    assert all("kda.scan" in l for l in calls)
+    assert " while(" not in txt and "triangular" not in txt
+    turned = [l for l in txt.splitlines()
+              if re.search(r"= f32\[[0-9,]*\]\S* (copy|transpose)\(", l)
+              and "kda.scan" in l and np.prod([int(n) for n in re.search(
+                  r"= f32\[([0-9,]*)\]", l).group(1).split(",")])
+              >= 32 * 4096 * 128]
+    assert not turned, turned

@@ -1272,6 +1272,87 @@ def leg_gdn_gated_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
     _compiled_step_size(ff, x, y, label)
 
 
+# ----------------------------------------------------------------------
+# Leg K — selective scans, differential attention, tensors handed on
+# ----------------------------------------------------------------------
+VALIDATION_SAMBAY = "examples/tpu_validate_sambay.py"
+
+
+def leg_sambay(model_cfg, seq: int, per_chip_batch: int, label: str,
+               alpha: float = 1e-5) -> None:
+    """``build_hybrid_conv_moe`` with the six SambaY kinds through
+    compile and fit with ``remat = "blocks"``: the loss falls, every
+    layer is one block of the rematerialised run although no two are
+    built alike, the blocks hand the scan's output and the keys and
+    values across their edges and say so, each scan and each
+    differential layer announced what it ran (a window, whose keys, two
+    calls), the counters give a step's ``dt A`` below zero and a lambda
+    a layer, and the step fits the chip. ``VALIDATION_SAMBAY`` holds the
+    model and its gradients to the token-by-token reference, and this
+    leg names it."""
+    import jax
+
+    from flexflow_tpu.models.nlp import build_hybrid_conv_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    kinds = list(model_cfg.layer_types)
+    start, units, reps = ff.executor._remat[:3]
+    handed = sorted({(e["attrs"]["block"], e["attrs"]["layer"]):
+                     e["attrs"]["bytes"] for e in events.events()
+                     if e["name"] == "remat.kept"
+                     and e["attrs"].get("handed_on")}.items())
+    say(f"{label}: rematerialised run {(start, units, reps)} hands on "
+        f"{len(handed)} layers' outputs, "
+        f"{sum(b for _, b in handed) / 1e6:.1f} MB a tensor a layer")
+    _check_wraps(label, reps)
+    check(reps == len(kinds),
+          f"{label}: {reps} blocks for {len(kinds)} layers")
+    check([k for k, _ in handed] == [
+        (i, f"{'ssm' if k == 'mamba1_memory' else 'attn'}_{i}")
+        for i, k in enumerate(kinds)
+        if k in ("mamba1_memory", "diff_attention_kv")],
+        f"{label}: the blocks hand on {handed}")
+    said = {name: sorted({e["attrs"]["layer"]: e["attrs"]
+                          for e in events.events()
+                          if e["name"] == name}.items())
+            for name in ("ssm1.scan", "attn.diff")}
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: instants " + "; ".join(
+        f"{n} {[k for k, _ in v]}" for n, v in said.items())
+        + f"; resolved {sorted(set(impls.values()))} in {len(impls)} layers")
+    window = {"diff_sliding_attention": min(model_cfg.sliding_window, seq)}
+    check([(k, a["memory_out"]) for k, a in said["ssm1.scan"]] == [
+        (f"ssm_{i}", k == "mamba1_memory") for i, k in enumerate(kinds)
+        if k.startswith("mamba1")]
+        and [(k, min(a["window"] or seq, seq) if a["window"] else 0,
+              a["kv_source"], a["calls"]) for k, a in said["attn.diff"]] == [
+            (f"attn_{i}", window.get(k, 0),
+             "own" if k != "diff_cross_attention" else
+             f"attn_{kinds.index('diff_attention_kv')}", 2)
+            for i, k in enumerate(kinds) if k.startswith("diff_")],
+        f"{label}: what the layers announced is not layer_types {kinds}: "
+        f"{said}")
+    ctr = events.counters()
+    scans = ctr.get("ssm1.scans", 0)
+    least = ctr.get("ssm1.log_decay_min", 0) / max(1.0, scans)
+    lam = ctr.get("attn.diff_lambda_mean", 0) \
+        / max(1.0, ctr.get("attn.diff_layers", 0))
+    say(f"{label}: a scan's most negative dt A {least:.3f} on average "
+        f"over {scans:.0f} layer-steps; lambda {lam:.3f} a layer")
+    check(scans > 0 and least < 0.0 and 0.5 < lam < 1.1,
+          f"{label}: counters {ctr}")
+    if chip:
+        check(set(impls.values()) == {"flash"},
+              f"{label}: attention resolved to {impls} at seq {seq}")
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: the model and its gradients against "
+        f"the reference: python3 {VALIDATION_SAMBAY}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def main() -> int:
     import jax
     devs = jax.devices()
@@ -1288,6 +1369,7 @@ def main() -> int:
                                          KeyeRankConfig,
                                          KimiLinearRankConfig,
                                          LatentMoEConfig, LFM2RankConfig,
+                                         Phi4FlashRankConfig,
                                          Qwen3NextRankConfig,
                                          TrinityRankConfig, XingRankConfig)
     from flexflow_tpu.utils.compilation_cache import (
@@ -1339,6 +1421,10 @@ def main() -> int:
         leg_gdn_gated_moe(Qwen3NextRankConfig.tiny(), 1024, 1, "J/small",
                           alpha=1e-3)
         leg_gdn_gated_moe(Qwen3NextRankConfig(), 8192, 1, "J/qwen3next")
+        leg_sambay(dataclasses.replace(
+            Phi4FlashRankConfig.tiny(), sliding_window=256), 1024, 1,
+            "K/small", alpha=1e-3)
+        leg_sambay(Phi4FlashRankConfig(), 8192, 1, "K/phi4flash")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -377,6 +377,7 @@ def _check_op_shards(report, strategy, by_name, axis_sizes,
         for wname, sp in (getattr(os_, "weights", {}) or {}).items():
             if sp is None:
                 continue
+            _check_paired_heads(report, axis_sizes, layer, wname, sp)
             shape = weight_shapes.get(name, {}).get(wname)
             _check_spec(report, axis_sizes, name, f"weight {wname!r}",
                         sp, shape, seam="checkpoint-restore")
@@ -384,6 +385,24 @@ def _check_op_shards(report, strategy, by_name, axis_sizes,
     for tname, sp in getattr(strategy, "inputs", {}).items():
         _check_spec(report, axis_sizes, tname, "input", sp,
                     in_shapes.get(tname))
+
+
+def _check_paired_heads(report, axis_sizes, layer, wname, spec) -> None:
+    """Differential attention pairs adjacent heads, and a layer may read
+    the keys and values another projected, in heads: a shard of a
+    weight's heads would have to keep every pair, and the group of
+    query pairs on a key pair, whole on both layers, which nothing
+    emits (``search/opshard.py`` offers batch only)."""
+    params = getattr(layer, "params", None) or {}
+    if not params.get("differential") \
+            or wname not in ("wq", "wk", "wv", "wo", "bq", "bk", "bv"):
+        return
+    if any(axis_sizes.get(a, 1) > 1
+           for axes in _spec_entries(spec) for a in axes):
+        report.add("op-shard", "error", layer.name,
+                   f"weight {wname!r} spec {spec} shards a differential "
+                   f"attention layer's heads: adjacent heads are a pair "
+                   f"and a shard that keeps the pairs whole is not built")
 
 
 def _check_stream_axes(report, axis_sizes, layer, spec) -> None:
@@ -423,6 +442,9 @@ def _check_conv_sequence(report, axis_sizes, layer, spec) -> None:
              "neighbour leaves and",
              OperatorType.OP_STATE_SPACE_MIXER:
              "a state-space mixer: each shard needs the state its "
+             "neighbour leaves and",
+             OperatorType.OP_SELECTIVE_SCAN_MIXER:
+             "a selective-scan mixer: each shard needs the state its "
              "neighbour leaves and"}.get(getattr(layer, "op_type", None))
     if needs is None:
         return

@@ -440,9 +440,22 @@ def _find_remat_blocks(layers):
     attention layer among them (``_MIXES_LIKE``: nine layers of ten, or
     three of four, would otherwise be two runs, and the shorter one held
     whole). Returns
-    ``(start, unit, reps, entry_guids, exit_guids)`` or None."""
+    ``(start, unit, reps, entry_guids, exit_guids)`` or None.
+
+    A graph in which an op hands an output on beside the residual
+    stream (``OpDef.hands_on``: a scan's output a later layer gates,
+    keys and values a later layer attends over) has no such run: its
+    boundaries cross more than one tensor and the layers that read what
+    another made are built of other ops. Its blocks are found by where
+    the stream is cut (:func:`_find_stream_blocks`), and ``unit`` is
+    then each block's length, a tuple."""
     from .parallel.pipeline_lowering import (_has_state, chunk_boundaries,
                                              find_repeated_run)
+    layers = list(layers)
+    handed = frozenset(l.outputs[i].guid for l in layers
+                       for i in get_op_def(l.op_type).hands_on(l.params))
+    if handed:
+        return _find_stream_blocks(layers, handed)
     graph_inputs = frozenset(t.guid for l in layers for t in l.inputs
                              if t.owner_layer is None)
     run = find_repeated_run(
@@ -462,6 +475,58 @@ def _find_remat_blocks(layers):
         return None
     exits = entries[1:] + [region[-1].outputs[0].guid]
     return start, unit, reps, entries, exits
+
+
+def _find_stream_blocks(layers, handed):
+    """Blocks of unlike interior along one residual stream, for a graph
+    with ``handed`` tensors (guids) that cross from the layer that makes
+    them to a later one. A CUT is a place between two layers that one
+    tensor crosses beside the handed ones; the stream is the shape most
+    cuts carry, and the layers between two of its cuts are a segment (a
+    mixer with its norm and add; a feed-forward with its). The segment
+    that recurs most, layer for layer by op and output shapes, closes a
+    block: a block is whatever segments stand before it since the last
+    one (a scan, an attention layer, a gated unit of five plain ops),
+    and that segment. Blocks agree in their entry's and exit's shape and
+    in their last segment, in nothing else: each is emitted from its own
+    layers. Returns ``(start, units, reps, entry_guids, exit_guids)``
+    with ``units`` the blocks' lengths, or None (under two blocks, or a
+    stateful op in one)."""
+    from .parallel.pipeline_lowering import _has_state
+    made = {t.guid: i for i, l in enumerate(layers) for t in l.outputs}
+    shape = {t.guid: tuple(t.shape) for l in layers for t in l.outputs}
+    last_read = {}
+    for i, l in enumerate(layers):
+        for t in l.inputs:
+            if t.guid in made:
+                last_read[t.guid] = i
+    cuts = []                           # (first layer after it, stream guid)
+    for at in range(1, len(layers)):
+        crossing = [g for g, i in made.items()
+                    if i < at <= last_read.get(g, -1) and g not in handed]
+        if len(crossing) == 1:
+            cuts.append((at, crossing[0]))
+    if not cuts:
+        return None
+    shapes = [shape[g] for _, g in cuts]
+    stream = max(set(shapes), key=shapes.count)
+    cuts = [c for c in cuts if shape[c[1]] == stream]
+    segments = [tuple((l.op_type, tuple(tuple(t.shape) for t in l.outputs))
+                      for l in layers[lo:hi])
+                for (lo, _), (hi, _) in zip(cuts, cuts[1:])]
+    if not segments:
+        return None
+    closing = max(segments, key=segments.count)     # the first, on a tie
+    ends = [i + 1 for i, seg in enumerate(segments) if seg == closing]
+    if len(ends) < 2:
+        return None
+    bounds = [cuts[i] for i in [0] + ends]           # a cut a block edge
+    start, stop = bounds[0][0], bounds[-1][0]
+    if any(_has_state(l) for l in layers[start:stop]):
+        return None
+    units = tuple(hi - lo for (lo, _), (hi, _) in zip(bounds, bounds[1:]))
+    guids = [g for _, g in bounds]
+    return start, units, len(units), guids[:-1], guids[1:]
 
 
 # Megatron tp split of stacked stage weights: role -> weight name ->
@@ -1195,24 +1260,41 @@ class Executor:
         st = self.strategy if strategy == "__use_own__" else strategy
         start, unit, reps, entries, exits = self._remat
         layers = self.program.layers
+        # blocks of one length, or (``_find_stream_blocks``) of their own
+        edges = np.cumsum([start] + list(
+            (unit,) * reps if isinstance(unit, int) else unit)).tolist()
         env = self.program.init_env(batch)
         self.program.emit_layers(layers[:start], env, params, ctx,
                                  st, capture)
         x = env[entries[0]]
         # what a block may read beside its entry: the graph's inputs
         inputs_env = {t.guid: env[t.guid]
-                      for l in layers[start:start + reps * unit]
+                      for l in layers[start:edges[-1]]
                       for t in l.inputs if t.owner_layer is None}
         tensors = {t.guid: t for l in layers for t in l.inputs}
         for b in range(reps):
-            block = layers[start + b * unit:start + (b + 1) * unit]
+            block = layers[edges[b]:edges[b + 1]]
             entry_g, exit_g = entries[b], exits[b]
             kept = [l for l in block
                     if get_op_def(l.op_type).keeps_for_block(l.params)]
+            # what crosses the block's edges beside the stream: tensors
+            # an earlier layer handed on (arguments of the checkpoint:
+            # held, and their cotangents flow back) and tensors a layer
+            # of this block hands to one after it (its results)
+            own = {t.guid: l for l in block for t in l.outputs}
+            taken = list(dict.fromkeys(
+                t.guid for l in block for t in l.inputs
+                if t.owner_layer is not None and t.guid not in own
+                and t.guid != entry_g))
+            handed = [g for g in own if g != exit_g and any(
+                t.guid == g for l in layers[edges[b + 1]:]
+                for t in l.inputs)]
 
-            def block_fn(x_, p_, _block=block, _entry=entry_g,
-                         _exit=exit_g, _b=b, _kept=kept):
-                benv = {**inputs_env, _entry: x_}
+            def block_fn(x_, p_, *taken_, _block=block, _entry=entry_g,
+                         _exit=exit_g, _b=b, _kept=kept, _taken=taken,
+                         _handed=handed, _own=own):
+                benv = {**inputs_env, _entry: x_, **dict(zip(_taken,
+                                                             taken_))}
                 bctx = EmitCtx(training=ctx.training, rngs=ctx.rngs,
                                state=ctx.state, config=self.config,
                                seq_length=ctx.seq_length)
@@ -1228,30 +1310,39 @@ class Executor:
                         obs_events.instant(
                             "remat.kept", block=_b, layer=l.name,
                             bytes=o.size * o.dtype.itemsize)
+                for g in _handed:
+                    obs_events.instant(
+                        "remat.kept", block=_b, layer=_own[g].name,
+                        bytes=benv[g].size * benv[g].dtype.itemsize,
+                        handed_on=True)
                 # the block's device counters and its ops' auxiliary
                 # losses leave it as outputs: a side channel cannot
                 # cross jax.checkpoint
-                return benv[_exit], bctx.counters, bctx.aux_losses
+                return (benv[_exit], bctx.counters, bctx.aux_losses,
+                        [benv[g] for g in _handed])
 
             bp = {l.name: params[l.name] for l in block
                   if l.name in params}
             # no policy where there is nothing to keep: JAX keys its
             # partial evaluation on the policy, and such a block's step
             # stays the text it was under a plain jax.checkpoint
-            x, counted, aux = checkpointed(
+            x, counted, aux, made = checkpointed(
                 block_fn, site="block", block=b,
                 policy=KEEP_MARKED if kept else None, weights=(1,),
                 specs=None if st is None else (
                     st.tensor_spec(tensors[entry_g]),
                     {l.name: st.ops[l.name].weights for l in block
-                     if l.name in st.ops}),
-                mesh=self.dmesh.mesh, layers=[l.name for l in block])(x, bp)
+                     if l.name in st.ops},
+                    *(st.tensor_spec(tensors[g]) for g in taken)),
+                mesh=self.dmesh.mesh, layers=[l.name for l in block])(
+                x, bp, *(env[g] for g in taken))
             for key, v in counted.items():
                 ctx.count(key, v)
             ctx.aux_losses.extend(aux)
             env[exit_g] = x
             capture[exit_g] = x
-        self.program.emit_layers(layers[start + reps * unit:], env,
+            env.update(zip(handed, made))
+        self.program.emit_layers(layers[edges[-1]:], env,
                                  params, ctx, st, capture)
         return [env[t.guid] for t in self.program.output_tensors]
 

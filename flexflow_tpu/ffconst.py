@@ -237,6 +237,11 @@ class OperatorType(enum.IntEnum):
     # carried along the sequence under a scalar decay a head-token (a
     # chunked scan over the chunk states, and its backward)
     OP_STATE_SPACE_MIXER = enum.auto()
+    # a selective-scan (Mamba-1) mixer: a (channels x state) state carried
+    # along the sequence under a decay that differs by channel AND by
+    # state entry (token steps inside checkpointed chunks), the scan's
+    # output optionally handed on to a later layer
+    OP_SELECTIVE_SCAN_MIXER = enum.auto()
 
 
 # Ops that are pure elementwise-unary (single input, same shape out).

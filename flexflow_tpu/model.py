@@ -234,7 +234,11 @@ class FFModel:
                             sm_scale: Optional[float] = None,
                             rotary_dim: Optional[int] = None,
                             qk_norm_zero_centered: bool = False,
-                            name: Optional[str] = None) -> Tensor:
+                            differential: Optional[dict] = None,
+                            kv_out: bool = False,
+                            kv_projected: bool = False,
+                            name: Optional[str] = None
+                            ) -> Union[Tensor, Tuple[Tensor, ...]]:
         """Multi-head attention (``ops.nn_ops.MultiHeadAttentionOp``):
         ``num_heads`` query heads of ``kdim / num_heads`` on
         ``num_kv_heads`` key/value heads (0: as many), through the
@@ -263,7 +267,18 @@ class FFModel:
         beside an indexer or on the ring path).
         ``qk_norm_zero_centered``: the q/k norms multiply by ``1 + w``
         with ``w`` drawn at 0. ``indexer``: learned sparse attention
-        (below)."""
+        (below). ``differential`` (``{"lambda_init": x}``, optionally
+        ``"eps"``): differential attention, adjacent heads in pairs, two
+        softmaxes a pair over a value twice as wide, their difference
+        under a learned scalar that starts at ``lambda_init``, an
+        RMSNorm over the pair's values (``MultiHeadAttentionOp.
+        _emit_differential``); causal, with or without a window, and
+        nothing else of the above but ``bias`` and ``num_kv_heads``.
+        Such a layer may hand its projected keys and values on
+        (``kv_out``: the result is ``(output, k, v)``, k and v (batch,
+        seq, kv heads, head size) after the bias) and may take another
+        layer's in their place (``kv_projected``: ``key`` and ``value``
+        are such tensors, and the layer has no ``wk``, ``wv``)."""
         params = {"embed_dim": embed_dim, "num_heads": num_heads,
                   "kdim": kdim, "vdim": vdim, "dropout": dropout,
                   "bias": bias, "add_bias_kv": add_bias_kv,
@@ -332,6 +347,38 @@ class FFModel:
                 raise ValueError(f"sm_scale {sm_scale}: a positive "
                                  f"multiplier, on a layer with no indexer")
             params["sm_scale"] = float(sm_scale)
+        if differential is not None:
+            unbuilt = [k for k, v in (
+                ("rope", rope), ("qk_norm", qk_norm), ("indexer", indexer),
+                ("output_gate", output_gate), ("sm_scale", sm_scale),
+                ("dropout", dropout), ("not causal", not causal)) if v]
+            kvh = num_kv_heads or num_heads
+            if unbuilt or num_heads % 2 or kvh % 2 \
+                    or (num_heads // 2) % (kvh // 2) \
+                    or (kdim or embed_dim) != (vdim or embed_dim):
+                raise ValueError(
+                    f"differential attention: causal, {num_heads} query "
+                    f"heads on {kvh} key/value heads in adjacent pairs, "
+                    f"one head size; not built beside {unbuilt}")
+            params["differential"] = True
+            params["lambda_init"] = float(differential["lambda_init"])
+            params["subln_eps"] = float(differential.get("eps", 1e-5))
+        elif kv_out or kv_projected:
+            raise ValueError("kv_out and kv_projected are built for "
+                             "differential attention only")
+        if kv_projected:
+            want = (query.shape[0], query.shape[1],
+                    num_kv_heads or num_heads,
+                    (kdim or embed_dim) // num_heads)
+            if tuple(key.shape) != want or tuple(value.shape) != want:
+                raise ValueError(
+                    f"kv_projected: keys {key.shape} and values "
+                    f"{value.shape} in heads, {want}, are wanted")
+            params["kv_projected"] = True
+            # whose keys and values: the trace says so (``attn.diff``)
+            params["kv_source"] = getattr(key.owner_layer, "name", "input")
+        if kv_out:
+            params["kv_out"] = True
         inputs = [query, key, value]
         if positions is not None:
             # (batch, seq) int32: what the rotary embedding turns by
@@ -339,8 +386,9 @@ class FFModel:
             if not rope:
                 raise ValueError("positions are read by rope=True only")
             inputs.append(positions)
-        return self._add_layer(OperatorType.OP_MULTIHEAD_ATTENTION,
-                               inputs, params, name).outputs[0]
+        outputs = self._add_layer(OperatorType.OP_MULTIHEAD_ATTENTION,
+                                  inputs, params, name).outputs
+        return tuple(outputs) if kv_out else outputs[0]
 
     def gated_short_conv(self, input: Tensor, taps: int,
                          name: Optional[str] = None) -> Tensor:
@@ -415,6 +463,33 @@ class FFModel:
                            head_dim=int(head_dim), state=int(state),
                            taps=int(taps), chunk=int(chunk),
                            eps=float(eps))
+
+    def selective_scan_mixer(self, input: Tensor, inner: int, state: int,
+                             dt_rank: int, taps: int, chunk: int,
+                             memory_out: bool = False,
+                             name: Optional[str] = None
+                             ) -> Union[Tensor, Tuple[Tensor, Tensor]]:
+        """A selective-scan (Mamba-1) mixer
+        (``ops.recurrent_ops.SelectiveScanMixerOp``): ``inner`` channels,
+        each a state of ``state`` entries under a decay a channel and a
+        state entry; x through a causal depthwise convolution of ``taps``
+        positions with a bias, the step size from it through a low-rank
+        pair of ``dt_rank``, B and C from it too, the output gated by
+        ``silu(z)`` with no norm; token steps in rematerialised chunks
+        of ``chunk`` positions. ``memory_out``: the result is ``(output,
+        memory)``, the second the scan's output with the skip and BEFORE
+        the gate, (batch, seq, inner), for a later layer to read."""
+        if min(inner, state, dt_rank, taps, chunk) < 1:
+            raise ValueError(
+                f"{inner} channels of {state} state entries, a step size "
+                f"of rank {dt_rank}, in chunks of {chunk} behind a "
+                f"convolution of {taps} taps")
+        more = {"memory_out": True} if memory_out else {}
+        outputs = self._add_layer(
+            OperatorType.OP_SELECTIVE_SCAN_MIXER, [input],
+            dict(inner=int(inner), state=int(state), dt_rank=int(dt_rank),
+                 taps=int(taps), chunk=int(chunk), **more), name).outputs
+        return tuple(outputs) if memory_out else outputs[0]
 
     def latent_attention(self, input: Tensor, positions: Tensor,
                          num_heads: int, q_rank: Optional[int],
@@ -1776,7 +1851,11 @@ class FFModel:
             return False
         mha = [l for l in self.executor.program.layers
                if l.op_type == OperatorType.OP_MULTIHEAD_ATTENTION]
+        # differential attention has no cache of its own, and none in
+        # which layers share one layer's keys and values: the re-forward
+        # path decodes such a model
         return bool(mha) and all(l.params.get("causal", False)
+                                 and not l.params.get("differential")
                                  for l in mha)
 
     def _generate_kv(self, ids0, prompt_len, max_new_tokens, temperature,

@@ -1017,6 +1017,11 @@ def build_latent_moe(ff: FFModel, batch_size: int, seq_len: int,
                                       name="lm_logits"))
 
 
+_SAMBAY_KINDS = frozenset({
+    "mamba1", "mamba1_memory", "diff_sliding_attention",
+    "diff_attention_kv", "gated_memory", "diff_cross_attention"})
+
+
 def _lfm2_layer_types():
     """LFM2-24B-A2B's 40 operators: conv, conv, then [full_attention,
     conv, conv, conv] ten times less the last two (30 conv, 10
@@ -1438,6 +1443,119 @@ class Qwen3NextRankConfig(HybridConvMoEConfig):
                    linear_key_head_dim=16, linear_value_head_dim=16)
 
 
+@dataclasses.dataclass
+class Phi4FlashRankConfig(HybridConvMoEConfig):
+    """What ONE chip holds of Phi-4-mini-flash-reasoning (``model_type:
+    phi4flash``, 3.8B parameters, dense; the SambaY decoder-hybrid-decoder
+    of arXiv:2507.06607 with differential attention) as one stage of a
+    pipeline with the vocabulary in eight slices (the benchmark's
+    ``phi4_mini_flash_reasoning``). Published layer ``i`` of
+    ``num_hidden_layers_published`` = 32 is a state-space position where
+    ``i % mb_per_layer == 0`` and an attention position otherwise; the
+    first half is the self-decoder, the second the cross-decoder:
+
+      i < 16, even   ``"mamba1"``: a selective-scan (Mamba-1) mixer
+      i < 16, odd    ``"diff_sliding_attention"``: differential
+                     attention in a window of ``sliding_window``
+      i == 16        ``"mamba1_memory"``: a mixer that also hands on its
+                     scan's output ``m`` (before the gate)
+      i == 17        ``"diff_attention_kv"``: whole differential
+                     attention that also hands on its keys and values
+      i > 17, even   ``"gated_memory"``: ``(m * silu(u W1)) W2``
+      i > 17, odd    ``"diff_cross_attention"``: differential attention
+                     of this layer's queries over layer 17's keys and
+                     values
+
+    Every layer ends in a dense SwiGLU of ``intermediate_size``; every
+    norm is a LayerNorm with a bias; there is no positional embedding.
+    Here: published layers ``first_layer_index`` = 14 to 19 (one of each
+    kind, and two of the two that stand on both sides of the middle)
+    and one of eight slices of the vocabulary; every width as published.
+
+    The fields after the parent's carry ``config.json``'s keys by their
+    names; ``layer_norm_eps`` is copied over the parent's ``norm_eps``
+    and ``layer_types`` is laid out from the indices. The ``mamba_*``
+    sizes are Mamba-1's defaults, which ``config.json`` leaves to the
+    model's configuration class (the benchmark file lists them as
+    assumed); ``mamba_chunk_size`` is the program's, not the model's."""
+    vocab_size: int = 25008
+    hidden_size: int = 2560
+    num_hidden_layers: int = 6
+    layer_types: list | None = None      # from the published indices
+    num_dense_layers: int = 6
+    num_attention_heads: int = 40
+    num_key_value_heads: int = 20
+    head_dim: int | None = 64            # not published: hidden / heads
+    intermediate_size: int = 10240
+    moe_intermediate_size: int = 0       # no expert layer reads it
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    use_expert_bias: bool = False
+    # the keys the parent class does not have
+    mb_per_layer: int = 2
+    sliding_window: int = 512
+    layer_norm_eps: float = 1e-5
+    mlp_bias: bool = False
+    lm_head_bias: bool = False
+    tie_word_embeddings: bool = True     # published; untied here (R11)
+    embd_pdrop: float = 0.0
+    resid_pdrop: float = 0.0
+    hidden_act: str = "silu"
+    max_position_embeddings: int = 262144
+    # not in config.json: where this share stands, and Mamba-1's sizes
+    first_layer_index: int = 14
+    num_hidden_layers_published: int = 32
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 160             # ceil(hidden / 16)
+    mamba_chunk_size: int = 64
+
+    def kind_of(self, i: int) -> str:
+        """The kind of PUBLISHED layer ``i``."""
+        half = self.num_hidden_layers_published // 2
+        if i % self.mb_per_layer == 0:
+            return "mamba1" if i < half else \
+                "mamba1_memory" if i == half else "gated_memory"
+        return "diff_sliding_attention" if i < half else \
+            "diff_attention_kv" if i == half + 1 else "diff_cross_attention"
+
+    def __post_init__(self):
+        if self.mlp_bias or self.lm_head_bias or self.embd_pdrop \
+                or self.resid_pdrop or self.hidden_act != "silu" \
+                or self.mb_per_layer != 2:
+            raise ValueError(
+                "a bias in the MLP or the head, dropout, another "
+                "activation than silu and another layout than one "
+                "attention position after each state-space position are "
+                "not built for this family")
+        last = self.first_layer_index + self.num_hidden_layers
+        if last > self.num_hidden_layers_published:
+            raise ValueError(
+                f"layers {self.first_layer_index} to {last - 1} of "
+                f"{self.num_hidden_layers_published}")
+        kinds = [self.kind_of(i)
+                 for i in range(self.first_layer_index, last)]
+        if self.layer_types is None:
+            self.layer_types = kinds
+        elif list(self.layer_types) != kinds:
+            raise ValueError(f"layer_types {self.layer_types} are not the "
+                             f"published indices' {kinds}")
+        self.norm_eps = self.layer_norm_eps
+        self.num_dense_layers = self.num_hidden_layers
+
+    @classmethod
+    def tiny(cls):
+        """The benchmark's six kinds in its order: 4 heads on 2 kv heads
+        of 8 (two query pairs on one key pair), a window of 8, 64
+        channels of 4 state entries, a step size of rank 2, chunks of 16
+        (two of a 32-token sequence): tests."""
+        return cls(vocab_size=96, hidden_size=32, num_attention_heads=4,
+                   num_key_value_heads=2, head_dim=8, intermediate_size=64,
+                   sliding_window=8, mamba_d_state=4, mamba_dt_rank=2,
+                   mamba_chunk_size=16)
+
+
 def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                           cfg: HybridConvMoEConfig | None = None):
     """Causal LM of :class:`HybridConvMoEConfig`: inputs ``[ids, pos]``,
@@ -1461,7 +1579,13 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     (:class:`Qwen3NextRankConfig`) is a gated delta rule with a decay a
     head; that class also turns only part of each head on its full
     layers, gates their output and the shared expert, and multiplies
-    every norm by ``1 + w``. ``pos`` is what the
+    every norm by ``1 + w``. The six kinds of
+    :class:`Phi4FlashRankConfig` (``"mamba1"``, ``"mamba1_memory"``,
+    ``"diff_sliding_attention"``, ``"diff_attention_kv"``,
+    ``"gated_memory"``, ``"diff_cross_attention"``) are a selective-scan
+    mixer, differential attention and a gated memory unit of plain ops;
+    two of them read what an earlier layer handed on, and that class's
+    norms are LayerNorms with a bias. ``pos`` is what the
     attention layers' rotary embedding turns by (a layout in which no
     layer turns by it still declares it, and ``fit`` drops its array).
 
@@ -1473,12 +1597,12 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     if len(kinds) != cfg.num_hidden_layers \
             or set(kinds) - {"conv", "full_attention", "sparse_attention",
                              "sliding_attention", "mamba", "attention",
-                             "linear_attention"}:
+                             "linear_attention"} - _SAMBAY_KINDS:
         raise ValueError(
             f"layer_types must name {cfg.num_hidden_layers} layers, each "
             f"'conv', 'full_attention', 'sparse_attention', "
-            f"'sliding_attention', 'mamba', 'attention' or "
-            f"'linear_attention'; got "
+            f"'sliding_attention', 'mamba', 'attention', "
+            f"'linear_attention' or one of {sorted(_SAMBAY_KINDS)}; got "
             f"{len(kinds)}: {sorted(set(kinds))}")
     if cfg.conv_bias or not cfg.norm_topk_prob:
         raise ValueError("conv_bias and gates that are not normalised "
@@ -1527,6 +1651,10 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
         experts.update(shared_gate=cfg.shared_expert_gate,
                        choice_bias=cfg.use_expert_bias,
                        rows_factor=cfg.expert_rows_factor)
+    if _SAMBAY_KINDS & set(kinds) and not hasattr(cfg, "mamba_dt_rank"):
+        raise ValueError("a selective-scan, differential or gated-memory "
+                         "layer needs the configuration's mamba_* sizes "
+                         "and first_layer_index")
     if "mamba" in kinds and not hasattr(cfg, "mamba_n_heads"):
         raise ValueError("a 'mamba' layer needs the configuration's "
                          "mamba_* sizes")
@@ -1539,7 +1667,8 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
     pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids",
                            may_be_unread=not set(kinds) - {"mamba",
-                                                           "attention"})
+                                                           "attention"}
+                           - _SAMBAY_KINDS)
     h = ff.embedding(ids, cfg.vocab_size, hid, name="embed_tokens")
     if getattr(cfg, "mup_enabled", False):
         h = ff.scalar_multiply(h, math.sqrt(hid), name="embed_scale")
@@ -1548,7 +1677,22 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                                name="embedding_multiplier")
 
     def norm(x, name):
+        if hasattr(cfg, "layer_norm_eps"):      # with a scale and a bias
+            return ff.layer_norm(x, [2], eps=cfg.layer_norm_eps, name=name)
         return ff.rms_norm(x, eps=cfg.norm_eps, name=name, **centred)
+
+    def differential(x, i, **more):
+        # lambda starts from the layer's PUBLISHED index
+        depth = cfg.first_layer_index + i
+        return ff.multihead_attention(
+            x, more.pop("key", x), more.pop("value", x), hid, heads,
+            kdim=heads * head_dim, vdim=heads * head_dim, bias=True,
+            causal=True, num_kv_heads=cfg.num_key_value_heads,
+            differential={"lambda_init": 0.8 - 0.6 * math.exp(-0.3 * depth),
+                          "eps": cfg.layer_norm_eps},
+            name=f"attn_{i}", **more)
+
+    memory = handed_kv = None       # what layers 16 and 17 hand on
 
     def scaled(x, name):
         return x if residual_scale is None \
@@ -1558,6 +1702,35 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
         x = norm(h, f"operator_norm_{i}")
         if kind == "conv":
             op = ff.gated_short_conv(x, cfg.conv_L_cache, name=f"conv_{i}")
+        elif kind in ("mamba1", "mamba1_memory"):
+            op = ff.selective_scan_mixer(
+                x, cfg.mamba_expand * hid, cfg.mamba_d_state,
+                cfg.mamba_dt_rank, cfg.mamba_d_conv, cfg.mamba_chunk_size,
+                memory_out=kind == "mamba1_memory", name=f"ssm_{i}")
+            if kind == "mamba1_memory":
+                op, memory = op
+        elif kind == "diff_sliding_attention":
+            op = differential(x, i, sliding_window=window)
+        elif kind == "diff_attention_kv":
+            op, *handed_kv = differential(x, i, kv_out=True)
+        elif kind == "diff_cross_attention":
+            if handed_kv is None:
+                raise ValueError(f"layer {i}: no earlier "
+                                 f"'diff_attention_kv' layer's keys and "
+                                 f"values to attend over")
+            op = differential(x, i, key=handed_kv[0], value=handed_kv[1],
+                              kv_projected=True)
+        elif kind == "gated_memory":
+            # (m * silu(u W1)) W2 from ops the graph has
+            if memory is None:
+                raise ValueError(f"layer {i}: no earlier 'mamba1_memory' "
+                                 f"layer's scan output to gate")
+            pre = ff.dense(x, memory.shape[-1], use_bias=False,
+                           name=f"gmu_in_{i}")
+            act = ff.multiply(pre, ff.sigmoid(pre, name=f"gmu_sigmoid_{i}"),
+                              name=f"gmu_silu_{i}")
+            op = ff.dense(ff.multiply(memory, act, name=f"gmu_gate_{i}"),
+                          hid, use_bias=False, name=f"gmu_out_{i}")
         elif kind == "mamba":
             op = ff.state_space_mixer(
                 x, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,

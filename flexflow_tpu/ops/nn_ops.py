@@ -485,9 +485,22 @@ class MultiHeadAttentionOp(OpDef):
     """
     op_type = OperatorType.OP_MULTIHEAD_ATTENTION
 
+    def hands_on(self, params):
+        return (1, 2) if params.get("kv_out") else ()
+
     def infer(self, params, in_shapes, in_dtypes):
         q = in_shapes[0]
-        return [((q[0], q[1], params["embed_dim"]), in_dtypes[0])]
+        outs = [((q[0], q[1], params["embed_dim"]), in_dtypes[0])]
+        if params.get("kv_out"):
+            # the projected keys and values, in heads, after the bias
+            h = params["num_heads"]
+            kvh = params.get("num_kv_heads", 0) or h
+            e = params["embed_dim"]
+            for size in (params.get("kdim", 0) or e,
+                         params.get("vdim", 0) or e):
+                outs.append(((in_shapes[1][0], in_shapes[1][1], kvh,
+                              size // h), in_dtypes[0]))
+        return outs
 
     def weights(self, params, in_shapes, in_dtypes):
         e = params["embed_dim"]
@@ -509,6 +522,18 @@ class MultiHeadAttentionOp(OpDef):
                    WeightSpec("bv", (kvh, vdim // h), dt,
                               InitializerType.ZERO),
                    WeightSpec("bo", (e,), dt, InitializerType.ZERO)]
+        if params.get("kv_projected"):
+            # keys and values come in heads from the layer that
+            # projected them: this one has no wk, wv of its own
+            ws = [w for w in ws if w.name not in ("wk", "wv", "bk", "bv")]
+        if params.get("differential"):
+            # the four vectors of the learned scalar lambda and the
+            # scale of the norm over a pair's 2 d values
+            ws += [WeightSpec(f"lambda_{k}", (kdim // h,), dt,
+                              InitializerType.NORMAL, {"stddev": 0.1})
+                   for k in ("q1", "k1", "q2", "k2")]
+            ws.append(WeightSpec("subln", (2 * vdim // h,), dt,
+                                 InitializerType.ONE))
         if params.get("qk_norm", False):
             # one learned scale a projection, shared by its heads; a
             # zero-centred norm multiplies by 1 + w with w drawn at 0
@@ -685,15 +710,26 @@ class MultiHeadAttentionOp(OpDef):
                 y = y + b.astype(jnp.float32)
             return y
 
+        # keys and values that another layer projected come in heads
+        own_kv = not params.get("kv_projected")
         with jax.named_scope("attn.proj"):
             qh = proj(q, weights["wq"], weights.get("bq"))
-            kh = proj(k, weights["wk"], weights.get("bk"))
-            vh = proj(v, weights["wv"], weights.get("bv"))
+            kh = proj(k, weights["wk"], weights.get("bk")) if own_kv \
+                else k.astype(jnp.float32)
+            vh = proj(v, weights["wv"], weights.get("bv")) if own_kv \
+                else v.astype(jnp.float32)
             # the output gate's pre-activation, (B, L, h, dv) float32,
             # from the input the query projection reads
             gate = proj(q, weights["wg"], None) \
                 if params.get("output_gate", False) else None
         window = params.get("sliding_window", 0)
+        if params.get("differential"):
+            return self._emit_differential(params, weights, ctx, name, qh,
+                                           kh, vh, mdt, cdt)
+        if not own_kv or params.get("kv_out"):
+            raise ValueError(f"{name}: keys and values handed from layer "
+                             f"to layer are built for differential "
+                             f"attention only")
         if gate is not None and (params.get("indexer_heads")
                                  or self._impl_for(ctx, name) == "ring"):
             raise ValueError(f"{name}: an output gate is built on the "
@@ -927,6 +963,110 @@ class MultiHeadAttentionOp(OpDef):
                           vh.astype(mdt),
                           preferred_element_type=jnp.float32)
         return self._project_out(ctxv, gate, weights, ctx, mdt, cdt)
+
+    def _emit_differential(self, params, weights, ctx, name, qh, kh, vh,
+                           mdt, cdt):
+        """Differential attention (arXiv:2410.05258) from the projected
+        heads, float32 ``qh`` (B, L, h, d), ``kh``, ``vh`` (B, L, kvh,
+        d): adjacent heads are a pair. Query pair ``j`` is heads ``2j,
+        2j + 1`` (``q1``, ``q2``), key pair ``g`` heads ``2g, 2g + 1`` of
+        k with ``V_g = [v_2g | v_2g+1]`` (2 d wide), and query pair ``j``
+        reads key pair ``j // group``:
+
+            o_j = (1 - lambda_init) RMSNorm(P1 V - lambda P2 V; subln)
+            P1 = softmax(q1 k1^T / sqrt(d) + mask),  P2 likewise
+            lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+
+        ``attend(q1, k1, V)`` is ONE grouped call at ``d`` / ``2 d`` on
+        ``h / 2`` heads reading ``kvh / 2``, so a layer is two calls,
+        down the flash kernels (a window is their own band) or XLA's
+        masked softmax as the plan or the ``auto`` rule says. The
+        softmaxes, lambda and the norm are float32. Causal
+        self-attention with no dropout, rotary embedding, q/k norm,
+        scale, gate or indexer; not on the ring path and not beside a
+        key/value cache."""
+        b, l, h, d = qh.shape
+        kvh = kh.shape[2]
+        impl = self._impl_for(ctx, name)
+        unbuilt = [k for k in ("indexer_heads", "output_gate", "rope",
+                               "qk_norm", "sm_scale", "dropout")
+                   if params.get(k)]
+        if unbuilt or impl == "ring" or not params.get("causal", False) \
+                or getattr(ctx, "kv_mode", None) is not None:
+            raise ValueError(
+                f"{name}: differential attention is causal, on the flash "
+                f"and XLA paths of the training and eval forward; not "
+                f"built beside {unbuilt or 'the ring path or a KV cache'}")
+        if h % 2 or kvh % 2 or (h // 2) % (kvh // 2) \
+                or kh.shape[1] != l or vh.shape[-1] != d:
+            raise ValueError(f"{name}: {h} query heads on {kvh} key/value "
+                             f"heads do not pair (adjacent heads, a query "
+                             f"pair on key pair j // group)")
+        f32 = jnp.float32
+        window = params.get("sliding_window", 0)
+        lam_init = float(params["lambda_init"])
+        flash = self._flash_enabled(impl, l, l, d, 2 * d, 0.0, causal=True,
+                                    window=window)
+        self._note_impl(ctx, name, "flash" if flash else "xla")
+        mesh, spec = self._kernel_shard_spec(ctx, b, h // 2) \
+            if flash else (None, None)
+        if events.enabled():
+            events.instant(
+                "attn.diff", layer=name, pairs=h // 2, key_pairs=kvh // 2,
+                head_dim=d, value_dim=2 * d, window=window,
+                lambda_init=lam_init,
+                kv_source=params.get("kv_source") or "own",
+                kv_out=bool(params.get("kv_out")),
+                impl="flash" if flash else "xla", calls=2)
+        if window:
+            # the band's pairs beside the causal ones, a layer
+            w_ = min(window, l)
+            ctx.count("attn.window_pairs",
+                      jnp.float32(b * (w_ * l - w_ * (w_ - 1) / 2)))
+            ctx.count("attn.causal_pairs", jnp.float32(b * l * (l + 1) / 2))
+        values = vh.reshape(b, l, kvh // 2, 2 * d)
+
+        def attend(q_, k_):
+            if flash:
+                from ..kernels import flash_attention
+                with jax.named_scope("attn.kernels"):
+                    o = flash_attention(
+                        *(jnp.swapaxes(x, 1, 2).astype(mdt)
+                          for x in (q_, k_, values)), causal=True,
+                        mesh=mesh, spec=spec,
+                        **({"window": window} if window else {}))
+                return jnp.swapaxes(o, 1, 2).astype(f32)
+            logits = jnp.einsum(
+                "bqhd,bkhd->bhqk", q_.astype(mdt),
+                self._expand_kv(k_, h // 2).astype(mdt),
+                preferred_element_type=f32) / math.sqrt(d)
+            qpos, kpos = jnp.arange(l)[:, None], jnp.arange(l)[None, :]
+            mask = kpos <= qpos
+            if window:
+                mask = jnp.logical_and(mask, kpos > qpos - window)
+            probs = jax.nn.softmax(jnp.where(mask, logits, f32(-1e9)), -1)
+            return jnp.einsum(
+                "bhqk,bkhd->bqhd", probs.astype(mdt),
+                self._expand_kv(values, h // 2).astype(mdt),
+                preferred_element_type=f32)
+
+        first = attend(qh[:, :, 0::2], kh[:, :, 0::2])
+        second = attend(qh[:, :, 1::2], kh[:, :, 1::2])
+        with jax.named_scope("attn.diff"):
+            lq1, lk1, lq2, lk2 = (weights[f"lambda_{k}"].astype(f32)
+                                  for k in ("q1", "k1", "q2", "k2"))
+            lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) \
+                + lam_init
+            pair = _rms(first - lam * second, weights["subln"],
+                        params.get("subln_eps", 1e-5)) * (1.0 - lam_init)
+            # a layer's lambda: lambda_init at untrained vectors, and a
+            # step that ran plain attention reads no ``attn.diff_layers``
+            ctx.count("attn.diff_lambda_mean", lam)
+            ctx.count("attn.diff_layers", f32(1.0))
+        out = self._project_out(pair.reshape(b, l, h, d), None, weights,
+                                ctx, mdt, cdt)
+        return out + ([kh.astype(cdt), vh.astype(cdt)]
+                      if params.get("kv_out") else [])
 
     @staticmethod
     def _project_out(ctxv, gate, weights, ctx, mdt, cdt):
@@ -1162,6 +1302,8 @@ class MultiHeadAttentionOp(OpDef):
         e = params["embed_dim"]
         h = params["num_heads"]
         kv_frac = (params.get("num_kv_heads", 0) or h) / h
+        if params.get("kv_projected"):
+            kv_frac = 0.0                     # another layer's products
         proj = (2.0 * b * lq * e * e                      # q proj
                 + 2.0 * b * 2 * lk * e * e * kv_frac     # k+v (GQA)
                 + 2.0 * b * lq * e * e)                  # out proj

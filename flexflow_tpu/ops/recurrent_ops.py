@@ -72,6 +72,20 @@ chunk and ``S_0`` the state at its start:
 Again every exponent is a difference that is <= 0. The decay is a
 head's, so ``L`` is one ``C x C`` matrix a head and ``C B^T`` one for all
 heads; nothing is halved or solved.
+
+The selective scan (Mamba-1): ``D`` channels, each a state of ``N``
+entries, under a decay that differs by channel AND by state entry,
+
+    h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] B_t[n] x_t[c]
+    y_t[c]    = sum_n C_t[n] h_t[n, c]
+
+so there is no ``C x C`` matrix of decays a head to multiply a product
+by: a pair of tokens has ``N x D`` of them. :func:`selective_scan` walks
+the TOKENS, in chunks that are rematerialised one at a time: a chunk's
+``exp(dt A)`` and ``dt B x`` (``C x N x D`` each, every exponent <= 0)
+are made at once, the state is carried through them step by step
+(:func:`_decayed_sums`, whose backward is one walk the other way), and
+the backward pass holds the state each chunk starts from.
 """
 from __future__ import annotations
 
@@ -423,6 +437,83 @@ def state_space_scan(x, dt, a, bm, cm, chunk: int, mdt=jnp.float32, *,
         y = inside + before                             # (B, M, C, H, P)
         y = y.reshape((y.shape[0], -1) + y.shape[3:])
     return y[:, :t], jax.lax.stop_gradient(jnp.min(big_g[:, :, -1]))
+
+
+@jax.custom_vjp
+def _decayed_sums(da, dbx, h0):
+    """``h_t = da_t * h_{t-1} + dbx_t`` from ``h0`` (B, N, D), token by
+    token over the leading axis of ``da``, ``dbx`` (C, B, N, D): every
+    ``h_t``, stacked (C, B, N, D). Elementwise, float32. Its backward is
+    one walk from the last token to the first carrying the state's
+    cotangent, ``G_t = g_t + da_{t+1} G_{t+1}``; the operands' follow
+    from the stacked ``G`` at once (``d dbx = G``, ``d da_t = G_t
+    h_{t-1}``): the residuals are ``da``, the states and ``h0``."""
+    def step(h, now):
+        h = now[0] * h + now[1]
+        return h, h
+
+    return jax.lax.scan(step, h0, (da, dbx))[1]
+
+
+def _decayed_sums_fwd(da, dbx, h0):
+    hs = _decayed_sums(da, dbx, h0)
+    return hs, (da, hs, h0)
+
+
+def _decayed_sums_bwd(res, g):
+    da, hs, h0 = res
+
+    def step(later, now):       # ``later``: da_{t+1} G_{t+1}
+        total = now[0] + later
+        return now[1] * total, total
+
+    d_h0, total = jax.lax.scan(step, jnp.zeros_like(h0), (g, da),
+                               reverse=True)
+    return total * jnp.concatenate([h0[None], hs[:-1]]), total, d_h0
+
+
+_decayed_sums.defvjp(_decayed_sums_fwd, _decayed_sums_bwd)
+
+
+def _selective_chunk(h0, x, dt, bm, cm, a):
+    """One chunk of :func:`selective_scan` from the state ``h0`` (B, N,
+    D): ``x``, ``dt`` (C, B, D), ``bm``, ``cm`` (C, B, N), ``a`` (N, D).
+    Returns the state it leaves and its outputs (C, B, D)."""
+    da = jnp.exp(dt[:, :, None, :] * a)                 # dt a <= 0
+    dbx = (dt * x)[:, :, None, :] * bm[..., None]
+    hs = _decayed_sums(da, dbx, h0)
+    return hs[-1], jnp.sum(hs * cm[..., None], axis=2)
+
+
+def selective_scan(x, dt, a, bm, cm, chunk: int, *, layer=None):
+    """The selective scan of the module's docstring from a zero state,
+    without the ``D`` skip: ``x``, ``dt`` (B, T, D) with ``dt`` > 0 the
+    step size a channel-token, ``a`` (N, D) < 0, ``bm``, ``cm`` (B, T,
+    N); everything float32 (no product here has a matrix unit's shape:
+    ``N`` is 16). Returns ``y`` (B, T, D) and the most negative ``dt a``
+    of any token, channel and state entry.
+
+    One ``lax.scan`` over the chunks carries the state (``N x D``
+    float32 a sequence); its body, rematerialised (site ``ssm1.chunk``),
+    is :func:`_selective_chunk`, so what the backward pass holds is the
+    state each chunk starts from and one chunk's ``(C, N, D)`` arrays at
+    a time, never ``(T, N, D)``. A sequence that is no whole number of
+    chunks is padded with positions that write nothing and decay
+    nothing (``dt`` 0); one shorter than a chunk is one chunk of its
+    own length."""
+    b, t, _ = x.shape
+    chunk = min(int(chunk), t)
+    m = -(-t // chunk)
+
+    one = checkpointed(_selective_chunk, site="ssm1.chunk", layer=layer)
+    _, y = jax.lax.scan(
+        lambda h, now: one(h, *now, a),
+        jnp.zeros((b,) + a.shape, jnp.float32),
+        # (B, T, ..) -> (M, C, B, ..): chunks and their tokens lead
+        tuple(jnp.moveaxis(_in_chunks(v, chunk, axis=1), 0, 2)
+              for v in (x, dt, bm, cm)))
+    y = jnp.moveaxis(y.reshape((m * chunk, b) + y.shape[3:]), 0, 1)
+    return y[:, :t], jax.lax.stop_gradient(jnp.min(dt * jnp.min(a, 0)))
 
 
 def _unit(x):
@@ -807,6 +898,127 @@ class StateSpaceMixerOp(OpDef):
         proj = e * (2 * inner + 2 * n + h) + inner * e
         return tokens * (2.0 * proj + (2 * k + 1) * (inner + 2 * n)
                          + 5.0 * inner * n + 2 * inner)
+
+    def backward_flops_factor(self):
+        return 2.0
+
+
+@register
+class SelectiveScanMixerOp(OpDef):
+    """A selective-scan (Mamba-1) mixer: ``D = inner`` channels, each a
+    state of ``N`` entries under :func:`selective_scan`.
+
+      [x | z] = u in_proj                      D | D, no bias
+      x  = silu(short_conv(x; conv_w) + conv_b)            K causal taps
+      [d | B | C] = x x_proj                   R | N | N, no bias
+      dt = softplus(d dt_proj + dt_bias)       the step size through a
+                                               LOW-RANK pair, R -> D
+      A  = -exp(A_log)                         (N, D): a decay a channel
+                                               AND a state entry
+      m  = selective_scan(x, dt, A, B, C) + D x
+      out = (m * silu(z)) out_proj             the gate, no norm
+
+    An op of its own and not a form of :class:`StateSpaceMixerOp`: no
+    weight, no projection's split and no line of the recurrence is
+    shared (the step size, B and C come from the CONVOLVED x, the decay
+    is no head's, nothing is normed), so a ``form`` would be two ops in
+    one ``emit``; what the two share they import (``short_conv``,
+    ``checkpointed``).
+
+    ``memory_out``: ``m`` (the scan's output with the skip, BEFORE the
+    gate) is a second output, which a later layer reads
+    (:meth:`hands_on`: a rematerialised block hands it on beside the
+    residual stream). The four projections are at the compute dtype with
+    float32 accumulation; the taps, softplus, ``dt A`` and its
+    exponential, the state and the products with B and C are float32.
+    The recurrence runs under the name scope ``ssm1.scan``, plain JAX;
+    the layer is not rematerialised whole (a block around it is), the
+    scan's chunks are. Training and evaluation only: there is no decode
+    path that carries the state from call to call."""
+    op_type = OperatorType.OP_SELECTIVE_SCAN_MIXER
+
+    def hands_on(self, params):
+        return (1,) if params.get("memory_out") else ()
+
+    def infer(self, params, in_shapes, in_dtypes):
+        outs = [(in_shapes[0], in_dtypes[0])]
+        if params.get("memory_out"):
+            outs.append((tuple(in_shapes[0][:-1]) + (params["inner"],),
+                         in_dtypes[0]))
+        return outs
+
+    def weights(self, params, in_shapes, in_dtypes):
+        e, dt = in_shapes[0][-1], in_dtypes[0]
+        d, n, r, k = (params["inner"], params["state"], params["dt_rank"],
+                      params["taps"])
+        return [
+            WeightSpec("in_proj", (e, 2 * d), dt),
+            WeightSpec("conv_w", (d, k), dt, init_args={"fans": (k, k)}),
+            WeightSpec("conv_b", (d,), dt, InitializerType.ZERO),
+            WeightSpec("x_proj", (d, r + 2 * n), dt),
+            WeightSpec("dt_proj", (r, d), dt),
+            # softplus(dt_bias) log-uniform in (1e-3, 1e-1); A = -(1..N)
+            # in every channel (S4D-real), state entries down the rows
+            WeightSpec("dt_bias", (d,), dt, InitializerType.UNIFORM,
+                       {"min": math.log(1e-3), "max": math.log(1e-1),
+                        "map": "inverse_softplus_of_exp"}),
+            WeightSpec("A_log", (n, d), dt, InitializerType.CONSTANT,
+                       {"rows": "log_count"}),
+            WeightSpec("D", (d,), dt, InitializerType.ONE),
+            WeightSpec("out_proj", (d, e), dt)]
+
+    def emit(self, params, inputs, weights, ctx, name):
+        (u,) = inputs
+        if getattr(ctx, "kv_mode", None) is not None:
+            raise NotImplementedError(
+                f"{name}: the selective-scan mixer has no decode path "
+                f"that carries its state beside a KV cache")
+        mdt = compute_dtype(ctx, u.dtype)
+        f32 = jnp.float32
+        d, n, r = params["inner"], params["state"], params["dt_rank"]
+        chunk = int(params["chunk"])
+        b, t = u.shape[:2]
+        memory_out = bool(params.get("memory_out"))
+        if events.enabled():
+            events.instant("ssm1.scan", layer=name, channels=d, state=n,
+                           dt_rank=r, taps=params["taps"], tokens=b * t,
+                           chunk=min(chunk, t), chunks=-(-t // min(chunk, t)),
+                           state_bytes=b * n * d * 4,
+                           memory_out=memory_out, impl="plain")
+
+        def mm(pattern, x, w):
+            return jnp.einsum(pattern, x.astype(mdt), w.astype(mdt),
+                              preferred_element_type=f32)
+
+        w = weights
+        x, z = jnp.split(mm("bte,ec->btc", u, w["in_proj"]), [d], -1)
+        x = jax.nn.silu(short_conv(x, w["conv_w"].astype(f32))
+                        + w["conv_b"].astype(f32))
+        low, bm, cm = jnp.split(mm("btc,cr->btr", x, w["x_proj"]),
+                                [r, r + n], -1)
+        dt = jax.nn.softplus(mm("btr,rc->btc", low, w["dt_proj"])
+                             + w["dt_bias"].astype(f32))
+        with jax.named_scope("ssm1.scan"):
+            y, least = selective_scan(x, dt, -jnp.exp(w["A_log"].astype(f32)),
+                                      bm, cm, chunk, layer=name)
+        memory = y + w["D"].astype(f32) * x
+        out = mm("btc,ce->bte", memory * jax.nn.silu(z), w["out_proj"])
+        # counters add over layers and steps: the sum of each layer's
+        # most negative ``dt A``, beside the number of scans
+        ctx.count("ssm1.log_decay_min", least)
+        ctx.count("ssm1.scans", jnp.float32(1.0))
+        return [out.astype(u.dtype)] \
+            + ([memory.astype(u.dtype)] if memory_out else [])
+
+    def flops(self, params, in_shapes, out_shapes):
+        """By the recurrent form: a channel-token decays its state (N),
+        writes it (2 N) and reads it (2 N), and adds the skip."""
+        tokens = float(np.prod(in_shapes[0][:-1]))
+        e = in_shapes[0][-1]
+        d, n, r, k = (params["inner"], params["state"], params["dt_rank"],
+                      params["taps"])
+        proj = e * 2 * d + d * (r + 2 * n) + r * d + d * e
+        return tokens * (2.0 * proj + (2 * k + 1) * d + 5.0 * d * n + 2 * d)
 
     def backward_flops_factor(self):
         return 2.0

@@ -150,8 +150,8 @@ def checkpointed(fn, *, site: str, policy=None, layer=None, block=None,
     """``jax.checkpoint(fn, policy=policy)``: the ONE place where the
     package rematerialises. ``site`` names who asks (``block``,
     ``kda.layer``, ``kda.branch``, ``kda.terms``, ``kda.step``,
-    ``gdn.terms``, ``ssm.layer``, ``ssm.chunk``, ``mhc.maps``, ``mhc.plain``,
-    ``dsa.chunk``); what is wrapped, and
+    ``gdn.terms``, ``ssm.layer``, ``ssm.chunk``, ``ssm1.chunk``, ``mhc.maps``,
+    ``mhc.plain``, ``dsa.chunk``); what is wrapped, and
     what is kept, is the caller's decision and stays with it.
 
     On the device: the call runs under ``jax.named_scope("remat.<site>")``,
@@ -230,6 +230,16 @@ class OpDef:
         (``MultiHeadAttentionOp`` with an indexer). Such an op may mark
         further values of its own ``KEPT_BY_BLOCK``."""
         return self.keeps_output_for_block
+
+    def hands_on(self, params: Dict[str, Any]) -> Tuple[int, ...]:
+        """The outputs of THIS layer, by index, that a layer further on
+        reads beside the residual stream (a scan's output a gated memory
+        unit reads, keys and values another attention layer attends
+        over). A run of rematerialised blocks hands them from the block
+        that makes them to the blocks that read them
+        (``executor.py::_find_remat_blocks``); they are held, not made
+        again."""
+        return ()
 
     # ---- graph level ----
     def infer(self, params: Dict[str, Any],

@@ -55,6 +55,17 @@ def _diagonal(args, shape, xp):
     return args["diagonal"] * xp.eye(*shape)
 
 
+def _constant(args, shape, xp):
+    """``CONSTANT``: ``init_args["value"]`` everywhere, or with
+    ``init_args["rows"] == "log_count"`` the log of a row's number from 1
+    (S4D-real: a state entry ``n`` of every channel starts at ``A = -n``)."""
+    if args.get("rows") == "log_count":
+        rows = xp.log(xp.arange(1, shape[0] + 1, dtype=xp.float32))
+        return xp.broadcast_to(rows.reshape((-1,) + (1,) * (len(shape) - 1)),
+                               shape)
+    return xp.full(shape, args.get("value", 0.0))
+
+
 def initialize_host(spec, key_ints, np_dtype):
     """Host-side twin of :func:`initialize`: numpy Philox keyed by the
     integer path ``key_ints`` (deterministic across runs/platforms).
@@ -76,7 +87,7 @@ def initialize_host(spec, key_ints, np_dtype):
     if kind == InitializerType.ONE:
         return np.ones(shape, np_dtype)
     if kind == InitializerType.CONSTANT:
-        return np.full(shape, args.get("value", 0.0), np_dtype)
+        return np.asarray(_constant(args, shape, np), np_dtype)
     # Philox keys are 2x uint64: word 0 = seed mixed with the path tag,
     # word 1 = the (sub-path, index) pair — all path components are
     # < 2^32 in practice, so the packing is collision-free
@@ -110,7 +121,7 @@ def initialize(spec, rng, jnp_dtype):
     if kind == InitializerType.ONE:
         return jnp.ones(shape, jnp_dtype)
     if kind == InitializerType.CONSTANT:
-        return jnp.full(shape, args.get("value", 0.0), jnp_dtype)
+        return jnp.asarray(_constant(args, shape, jnp), jnp_dtype)
     if kind == InitializerType.UNIFORM:
         lo, hi = args.get("min", -0.05), args.get("max", 0.05)
         return _mapped(args.get("map"),

@@ -61,6 +61,13 @@ def options_for(layer: Layer) -> List[ShardOption]:
     elif t == OperatorType.OP_EMBEDDING:
         sample()
         opts.append(ShardOption("parameter", r - 1, (("kernel", 1),)))
+    elif t == OperatorType.OP_MULTIHEAD_ATTENTION \
+            and (getattr(layer, "params", None) or {}).get("differential"):
+        # differential attention pairs ADJACENT heads and may hand its
+        # keys and values to another layer in heads: a shard of the
+        # heads' axis would have to keep the pairs whole on both layers,
+        # which is not built (the plan verifier refuses it); batch only
+        sample()
     elif t == OperatorType.OP_MULTIHEAD_ATTENTION:
         sample()
         # head-parallel: wq/wk/wv head dim, wo input-head dim; output stays
@@ -113,6 +120,15 @@ def options_for(layer: Layer) -> List[ShardOption]:
         # verifier refuses it)
         opts.append(ShardOption("parameter", -1, (
             ("norm", 0), ("out_proj", 0))))
+    elif t == OperatorType.OP_SELECTIVE_SCAN_MIXER:
+        # batch only. The channels are independent recurrences, but a
+        # shard of them has run under no mesh (the input projection's
+        # columns are [x | z] and the step size's low-rank pair
+        # contracts over all channels: the seams are the partitioner's
+        # to place), so it is not offered. Nor is the sequence: a shard
+        # would need a halo of taps - 1 positions AND the state its
+        # neighbour leaves (the plan verifier refuses it)
+        sample()
     elif t == OperatorType.OP_HYPER_CONNECTION:
         sample()
         # per token: the maps of a position read that position's streams

@@ -19,6 +19,7 @@ from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
                                      GraniteHybridRankConfig,
                                      HybridConvMoEConfig, KeyeRankConfig,
                                      KimiLinearRankConfig, LatentMoEConfig,
+                                     Phi4FlashRankConfig,
                                      Qwen3NextRankConfig, TrinityRankConfig,
                                      XingRankConfig)
 
@@ -247,6 +248,24 @@ def test_leg_j_gdn_gated_moe_tiny_on_the_cpu_mesh(capsys):
     assert f"python3 {chip_smoke.VALIDATION_GDN}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_GDN))
+
+
+def test_leg_k_sambay_tiny_on_the_cpu_mesh(capsys):
+    """32 positions on the 8-device mesh: the six layers of six kinds
+    are six rematerialised blocks of their own lengths, the scan's
+    output and the keys and values handed across their edges, every
+    scan and differential layer announced."""
+    chip_smoke.leg_sambay(Phi4FlashRankConfig.tiny(), seq=32,
+                          per_chip_batch=1, label="K/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    assert ("rematerialised run (1, (11, 11, 11, 11, 15, 11), 6) hands on "
+            "2 layers' outputs") in out
+    assert ("ssm1.scan ['ssm_0', 'ssm_2']; attn.diff ['attn_1', 'attn_3', "
+            "'attn_5']; resolved ['xla'] in 3 layers") in out
+    assert "a scan's most negative dt A -" in out and "lambda 0.7" in out
+    assert f"python3 {chip_smoke.VALIDATION_SAMBAY}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_SAMBAY))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

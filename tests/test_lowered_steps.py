@@ -1,7 +1,7 @@
 """Every rank configuration's train step lowers to the text it lowered
 to at the parent commit.
 
-The ten classes are the eight that a cell's config file names and the
+The eleven classes are the nine that a cell's config file names and the
 two base classes they extend; each is built at its ``tiny()`` size by
 its builder (``rank_family.lowered_step``: the default ``FFConfig`` but
 no search, 2 x 32 ids drawn from seed 0, cold caches), with and without
@@ -43,6 +43,7 @@ BUILDER = {          # class name: its builder and the cell that names it
     "TrinityRankConfig": nlp.build_hybrid_conv_moe,     # cell 8
     "GraniteHybridRankConfig": nlp.build_hybrid_conv_moe,   # cell 9
     "Qwen3NextRankConfig": nlp.build_hybrid_conv_moe,   # cell 10
+    "Phi4FlashRankConfig": nlp.build_hybrid_conv_moe,   # cell 11
 }
 
 LOWERED = {
@@ -80,6 +81,10 @@ LOWERED = {
         "1ea7f47cd760026f9b0bf390fc61c4f929c761cb23873c57e4fd383c92e9ab38",
     ("LatentMoEConfig", "blocks"):
         "ebe5b056861101bf03d389f0a0a87b9ba94ff3ab8c13135eb2be6bb91922e3d1",
+    ("Phi4FlashRankConfig", "none"):
+        "8a6c7e2c5faf315891702659868813abcc0088093e0307ad59c12f4275cf8f65",
+    ("Phi4FlashRankConfig", "blocks"):
+        "f399127f3e6867ae5b2e09f0aa78d7b74ce9c6cbf323391b3d2725a239bb0452",
     ("Qwen3NextRankConfig", "none"):
         "6ae1f7bc1a602372feec80a028ef319c4313df79ef0dc76c116b5e2ddbeba336",
     ("Qwen3NextRankConfig", "blocks"):

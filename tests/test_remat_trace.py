@@ -35,7 +35,8 @@ SITES = {"block": "executor.py", "kda.layer": "ops/recurrent_ops.py",
          "kda.step": "ops/recurrent_ops.py",
          "gdn.terms": "ops/recurrent_ops.py",
          "ssm.layer": "ops/recurrent_ops.py",
-         "ssm.chunk": "ops/recurrent_ops.py", "mhc.maps": "ops/hyper_ops.py",
+         "ssm.chunk": "ops/recurrent_ops.py",
+         "ssm1.chunk": "ops/recurrent_ops.py", "mhc.maps": "ops/hyper_ops.py",
          "mhc.plain": "ops/hyper_ops.py",
          "dsa.chunk": "ops/sparse_attention.py"}
 

@@ -1402,3 +1402,73 @@ def test_the_channel_decay_delta_rule_compiles_at_the_published_width(
                   r"= f32\[([0-9,]*)\]", l).group(1).split(",")])
               >= 32 * 4096 * 128]
     assert not turned, turned
+
+
+# ----------------------------------------------------------------------
+# the SambaY cell (PR 61): flash at 64 / 128 on paired heads, the
+# selective scan at the published width
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("window", [0, 512], ids=["whole", "window_512"])
+def test_the_grouped_kernels_compile_at_cell_11s_shapes(v5e_devices,
+                                                        chip_locations,
+                                                        window):
+    """``phi4_mini_flash_reasoning.train.1chip``: one of a differential
+    layer's two calls, 20 query pairs on 10 key pairs over 8,192
+    positions, q.k over 64 and p.v over 128 (no cell before it ran 64 /
+    128), bf16, causal, whole and in the 512 band: the three kernels
+    compile and read K and V at their own 10 heads."""
+    b, h, kvh, s, d, dv = 1, 20, 10, 8192, 64, 128
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((b, kvh, s, d), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((b, kvh, s, dv), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, interpret=False,
+                            window=window)
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert _kernel_names(txt) == FLASH_NAMES
+    for narrow in (f"bf16[{b * kvh},{s},{d}]", f"bf16[{b * kvh},{s},{dv}]"):
+        assert all(narrow in l.split(" custom-call(")[1]
+                   for l in txt.splitlines() if MOSAIC_CALL in l)
+
+
+def test_the_selective_scan_mixer_compiles_at_the_published_width(
+        v5e_devices):
+    """One mixer's forward and backward at 2560 -> 5120 channels of 16
+    state entries over 8,192 positions in 128 chunks of 64, bf16
+    operands, compiled for a described v5e: plain XLA (no Mosaic call),
+    the recurrence under ``ssm1.scan`` in rematerialised chunks, and NO
+    array of all tokens' states: ``(8192, 16, 5120)`` float32 would be
+    2.5 GiB and the layer's temporaries read 1.58 (its interior held: it
+    is not rematerialised whole, a block around it is)."""
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.ops.recurrent_ops import SelectiveScanMixerOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    params = {"inner": 5120, "state": 16, "dt_rank": 160, "taps": 4,
+              "chunk": 64, "memory_out": True}
+    op = SelectiveScanMixerOp()
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    x = jax.ShapeDtypeStruct((1, 8192, 2560), jnp.float32, sharding=one)
+    w = {s.name: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=one)
+         for s in op.weights(params, [(1, 8192, 2560)],
+                             [DataType.DT_FLOAT])}
+    assert sum(int(np.prod(v.shape)) for v in w.values()) == 41241600
+
+    def loss(x, w):
+        y, m = op.emit(params, [x], w,
+                       EmitCtx(training=True, config=FFConfig()), "ssm_0")
+        return jnp.sum(y) + jnp.sum(m)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
+    txt = compiled.as_text()
+    assert MOSAIC_CALL not in txt
+    assert "ssm1.scan" in txt and "remat.ssm1.chunk" in txt \
+        and " while(" in txt
+    # a chunk's states, never the sequence's
+    assert re.search(r"f32\[64,1,16,5120\]", txt)
+    assert not re.search(r"f32\[(8192|128,64),1,16,5120\]", txt)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0 * 2 ** 30

@@ -1335,6 +1335,26 @@ def leg_sambay(model_cfg, seq: int, per_chip_batch: int, label: str,
             for i, k in enumerate(kinds) if k.startswith("diff_")],
         f"{label}: what the layers announced is not layer_types {kinds}: "
         f"{said}")
+    from flexflow_tpu.kernels.selective_scan import takes_kernel
+    scan = said["ssm1.scan"][0][1]
+    took = {a["impl"] for _, a in said["ssm1.scan"]}
+    kernels = {}
+    for e in events.events():
+        if e["name"] == "ssm1.kernel":
+            kernels.setdefault(e["attrs"]["kernel"], e["attrs"])
+    # (on one device: under a mesh of several the plain path)
+    want = "kernel" if takes_kernel(scan["chunk"], scan["channels"],
+                                    scan["state"]) else "plain"
+    say(f"{label}: the scans by {sorted(took)} (the shapes say {want})"
+        + "".join(f"; {k}: {a['grid_steps']} grid steps of {a['blocks']} "
+                  f"blocks of {a['block_channels']} channels"
+                  for k, a in sorted(kernels.items())))
+    check((took == {want} or took == {"plain"} and len(jax.devices()) > 1)
+          and sorted(kernels) == (["bwd", "fwd"] if took == {"kernel"}
+                                  else []),
+          f"{label}: the scans announced "
+          f"{ {n: a['impl'] for n, a in said['ssm1.scan']} } and the "
+          f"kernels {sorted(kernels)} where the shapes say {want}")
     ctr = events.counters()
     scans = ctr.get("ssm1.scans", 0)
     least = ctr.get("ssm1.log_decay_min", 0) / max(1.0, scans)

@@ -9,6 +9,7 @@ tensors or ``build_hybrid_conv_moe``'s six SambaY kinds.
 
     python3 examples/tpu_validate_sambay.py [--seeds 1 2 3]
         [--seq 8192] [--grad-seq 1024] [--skip-forward] [--skip-gradients]
+        [--time-kernels]
 
 The model is ``benchmarks/configs/phi4_mini_flash_reasoning.json``
 through the normal path (``FFModel`` -> ``build_hybrid_conv_moe`` ->
@@ -42,11 +43,22 @@ prints PASS/FAIL, exit code 1 on any failure):
      SwiGLU, the embedding and the head, against ``jax.grad`` of the
      reference's loss, each held to twice what the reference itself
      reads with bf16 operands. ``correct`` sees no gradient.
+  5. with ``--time-kernels``, the recurrence ALONE at the published
+     shape (1 x ``--seq`` x 5,120 channels, a state of 16, chunks of 64):
+     ``selective_scan`` down ``kernels/selective_scan.py`` and down the
+     plain path, the forward and the pair under ``jax.grad``, sixteen
+     calls in one jit each (a call's ``bm`` waits on the one before),
+     the device's clock a call and by op; then ``y`` and the five
+     gradients of the two paths against each other. The go / no-go
+     reading of ISSUE 62: the kernel's forward under 4 ms, its pair
+     under two thirds of the plain pair's.
 """
 import argparse
 import json
 import os
 import sys
+import tempfile
+import time
 
 import jax
 import jax.numpy as jnp
@@ -138,9 +150,9 @@ def forward_checks(conf, ref, seq, seeds):
             check("each layer ran what its kind says", {
                 n: (a.get("window"), a.get("kv_source"), a.get("impl"),
                     a.get("memory_out")) for n, a in said.items()} == {
-                "ssm_0": (None, None, "plain", False),
+                "ssm_0": (None, None, "kernel", False),
                 "attn_1": (window, "own", "flash", None),
-                "ssm_2": (None, None, "plain", True),
+                "ssm_2": (None, None, "kernel", True),
                 "attn_3": (0, "own", "flash", None),
                 "attn_5": (0, "attn_3", "flash", None)}, "")
         READINGS[f"seed {seed}"] = errs
@@ -218,6 +230,104 @@ def gradient_checks(conf, ref, seed, seq):
               f"{eb:.3e}, and the program against THAT {own:.3e}")
 
 
+def time_kernels(seq, calls=16, channels=5120, state=16, chunk=64):
+    """Check 5 of the module's docstring."""
+    import numpy as np
+    from benchmarks.harness import trace_reduce
+    from flexflow_tpu.kernels import selective_scan as kernels
+    from flexflow_tpu.ops.recurrent_ops import selective_scan
+    f32 = jnp.float32
+    on_chip = jax.devices()[0].platform == "tpu"
+    rng = np.random.default_rng(62)
+    # the cell's seeds: softplus(dt_bias) log-uniform in (1e-3, 1e-1),
+    # A = -(1 .. N) in every channel
+    x, w = (jnp.asarray(rng.standard_normal((1, seq, channels)), f32)
+            for _ in range(2))
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                                        (1, seq, channels))), f32)
+    a = -jnp.broadcast_to(jnp.arange(1.0, state + 1, dtype=f32)[:, None],
+                          (state, channels))
+    bm, cm = (jnp.asarray(rng.standard_normal((1, seq, state)), f32)
+              for _ in range(2))
+    check("the published shape takes the kernels",
+          kernels.takes_kernel(chunk, channels, state),
+          f"chunk {chunk}, {channels} channels, a state of {state}")
+
+    def scan(impl):
+        return lambda *v: selective_scan(*v, chunk,
+                                         kernels=impl == "kernel")[0]
+
+    def forward(impl):
+        def f(x, dt, a, bm, cm):
+            for _ in range(calls):      # the next call waits on this one
+                y = scan(impl)(x, dt, a, bm, cm)
+                bm = bm + 0.0 * y[:, :, :state]
+            return y
+        return f
+
+    def pair(impl):
+        def f(x, dt, a, bm, cm):
+            for _ in range(calls):
+                y, pull = jax.vjp(scan(impl), x, dt, a, bm, cm)
+                grads = pull(w)         # (y read too, or XLA drops its work)
+                bm = bm + 0.0 * (grads[0] + y)[:, :, :state]
+            return (y,) + grads
+        return f
+
+    def timed(fn):
+        """(result, host ms a call, device ms a call, ms a call by op)."""
+        fn = jax.jit(fn)
+        out = jax.block_until_ready(fn(x, dt, a, bm, cm))      # compiles
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(x, dt, a, bm, cm))
+        host = (time.perf_counter() - t0) / calls * 1e3
+        if not on_chip:
+            return out, host, None, {}
+        with tempfile.TemporaryDirectory() as tmp:
+            jax.profiler.start_trace(tmp)
+            try:
+                jax.block_until_ready(fn(x, dt, a, bm, cm))
+            finally:
+                jax.profiler.stop_trace()
+            ev = trace_reduce.extract(trace_reduce.find_xplane(tmp))
+        by_name = {}        # self times: a ``while`` holds its body's ops
+        for ops in ev["devices"].values():
+            for name, ns in trace_reduce.self_times(ops).items():
+                name = trace_reduce.op_name(name).rsplit(".", 1)[0]
+                by_name[name] = by_name.get(name, 0.0) + ns / calls / 1e6
+        top = dict(sorted(by_name.items(), key=lambda kv: -kv[1])[:8])
+        return out, host, sum(by_name.values()), {
+            k: round(v, 4) for k, v in top.items()}
+
+    got, ms = {}, {}
+    for impl in ("plain", "kernel"):
+        for what, fn in (("forward", forward), ("pair", pair)):
+            jax.clear_caches()
+            out, host, device, by_name = timed(fn(impl))
+            got[impl, what] = out
+            ms[impl, what] = device if device is not None else host
+            print(json.dumps(dict(
+                check="time_kernels", impl=impl, what=what, tokens=seq,
+                channels=channels, state=state, chunk=chunk, calls=calls,
+                device=jax.devices()[0].device_kind, host_ms_a_call=host,
+                device_ms_a_call=device, by_name=by_name)), flush=True)
+    READINGS["time_kernels"] = {f"{i}.{w}": v for (i, w), v in ms.items()}
+    far = {}
+    for name, u, v in zip(("y", "d_x", "d_dt", "d_a", "d_B", "d_C"),
+                          got["plain", "pair"], got["kernel", "pair"]):
+        far[name] = l2(v, u)
+    READINGS["time_kernels"]["kernel_against_plain"] = far
+    check("the kernels against the plain path", max(far.values()) <= 1e-4,
+          ", ".join(f"{k} {v:.2e}" for k, v in far.items()))
+    check("the kernel's forward alone", ms["kernel", "forward"] < 4.0
+          or not on_chip, f"{ms['kernel', 'forward']:.3f} ms a call, the "
+          f"plain path's {ms['plain', 'forward']:.3f} (go under 4)")
+    check("the kernels' pair alone",
+          3 * ms["kernel", "pair"] < 2 * ms["plain", "pair"] or not on_chip,
+          f"{ms['kernel', 'pair']:.3f} ms a call, the plain pair's "
+          f"{ms['plain', 'pair']:.3f} (go under two thirds)")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seeds", type=int, nargs="+", default=[6100201])
@@ -225,6 +335,7 @@ def main():
     ap.add_argument("--grad-seq", type=int, default=1024)
     ap.add_argument("--skip-forward", action="store_true")
     ap.add_argument("--skip-gradients", action="store_true")
+    ap.add_argument("--time-kernels", action="store_true")
     ap.add_argument("--config", default=os.path.join(
         BENCH, "configs", "phi4_mini_flash_reasoning.json"))
     args = ap.parse_args()
@@ -236,6 +347,9 @@ def main():
     with open(args.config) as f:
         conf = json.load(f)
     ref = cells.load_module(BENCH, "reference", "sambay_ref")
+    if args.time_kernels:
+        time_kernels(args.seq)
+        jax.clear_caches()
     if not args.skip_forward:
         forward_checks(conf, ref, args.seq, args.seeds)
         jax.clear_caches()

@@ -97,6 +97,7 @@ import numpy as np
 
 from ..core.tensor import WeightSpec
 from ..ffconst import InitializerType, OperatorType
+from ..kernels import selective_scan as ssm1_kernels
 from ..kernels import state_space as ssm_kernels
 from ..kernels.gated_delta_rule import (SUB, chunk_terms, head_chunk_terms,
                                         scan_chunks, takes_head_kernel,
@@ -485,7 +486,8 @@ def _selective_chunk(h0, x, dt, bm, cm, a):
     return hs[-1], jnp.sum(hs * cm[..., None], axis=2)
 
 
-def selective_scan(x, dt, a, bm, cm, chunk: int, *, layer=None):
+def selective_scan(x, dt, a, bm, cm, chunk: int, *, layer=None,
+                   kernels=True):
     """The selective scan of the module's docstring from a zero state,
     without the ``D`` skip: ``x``, ``dt`` (B, T, D) with ``dt`` > 0 the
     step size a channel-token, ``a`` (N, D) < 0, ``bm``, ``cm`` (B, T,
@@ -493,9 +495,19 @@ def selective_scan(x, dt, a, bm, cm, chunk: int, *, layer=None):
     ``N`` is 16). Returns ``y`` (B, T, D) and the most negative ``dt a``
     of any token, channel and state entry.
 
-    One ``lax.scan`` over the chunks carries the state (``N x D``
-    float32 a sequence); its body, rematerialised (site ``ssm1.chunk``),
-    is :func:`_selective_chunk`, so what the backward pass holds is the
+    Where the shapes take them (``ssm1_kernels.takes_kernel``: the
+    channels in whole blocks of 1,024, a state of whole eights, a chunk
+    of whole sublane tiles) the walk runs in the Pallas kernels of
+    ``kernels/selective_scan.py``, forward and backward under one
+    ``custom_vjp`` that keeps its five inputs and the chunk-boundary
+    states: the state rides in VMEM from token to token and chunk to
+    chunk, and nothing of shape ``(.., N, D)`` but those boundary states
+    reaches HBM (``layer`` names the caller in their ``ssm1.kernel``
+    instants). Every other shape, and a caller that says
+    ``kernels=False`` (a mesh of several devices), takes plain JAX: one
+    ``lax.scan`` over the chunks carries the state (``N x D`` float32 a
+    sequence); its body, rematerialised (site ``ssm1.chunk``), is
+    :func:`_selective_chunk`, so what the backward pass holds is the
     state each chunk starts from and one chunk's ``(C, N, D)`` arrays at
     a time, never ``(T, N, D)``. A sequence that is no whole number of
     chunks is padded with positions that write nothing and decay
@@ -505,6 +517,18 @@ def selective_scan(x, dt, a, bm, cm, chunk: int, *, layer=None):
     chunk = min(int(chunk), t)
     m = -(-t // chunk)
 
+    def least():
+        return jax.lax.stop_gradient(jnp.min(dt * jnp.min(a, 0)))
+
+    if kernels and ssm1_kernels.takes_kernel(chunk, *a.shape[::-1]):
+        def padded(v):          # (B, T, ..) -> (B, M C, ..)
+            return _in_chunks(v, chunk, axis=1).reshape((b, m * chunk, -1))
+
+        y = ssm1_kernels.scan_chunks(
+            padded(x), padded(dt), a.astype(jnp.float32), padded(bm),
+            padded(cm), chunk, layer=layer)
+        return y[:, :t], least()
+
     one = checkpointed(_selective_chunk, site="ssm1.chunk", layer=layer)
     _, y = jax.lax.scan(
         lambda h, now: one(h, *now, a),
@@ -513,7 +537,7 @@ def selective_scan(x, dt, a, bm, cm, chunk: int, *, layer=None):
         tuple(jnp.moveaxis(_in_chunks(v, chunk, axis=1), 0, 2)
               for v in (x, dt, bm, cm)))
     y = jnp.moveaxis(y.reshape((m * chunk, b) + y.shape[3:]), 0, 1)
-    return y[:, :t], jax.lax.stop_gradient(jnp.min(dt * jnp.min(a, 0)))
+    return y[:, :t], least()
 
 
 def _unit(x):
@@ -931,8 +955,12 @@ class SelectiveScanMixerOp(OpDef):
     residual stream). The four projections are at the compute dtype with
     float32 accumulation; the taps, softplus, ``dt A`` and its
     exponential, the state and the products with B and C are float32.
-    The recurrence runs under the name scope ``ssm1.scan``, plain JAX;
-    the layer is not rematerialised whole (a block around it is), the
+    The recurrence runs under the name scope ``ssm1.scan``: by the
+    kernels of ``kernels/selective_scan.py`` on one device at the
+    channels in whole blocks of 1,024, a state of whole eights and a
+    chunk of whole sublane tiles, which the published 5,120, 16 and 64
+    are, their backward too; plain JAX elsewhere. The layer is not
+    rematerialised whole (a block around it is); on the plain path the
     scan's chunks are. Training and evaluation only: there is no decode
     path that carries the state from call to call."""
     op_type = OperatorType.OP_SELECTIVE_SCAN_MIXER
@@ -979,12 +1007,20 @@ class SelectiveScanMixerOp(OpDef):
         chunk = int(params["chunk"])
         b, t = u.shape[:2]
         memory_out = bool(params.get("memory_out"))
+        # the walk by the kernels where the shapes take them, on one
+        # device (under a mesh the plain path, which GSPMD partitions
+        # like any other XLA op)
+        mesh = getattr(ctx, "mesh", None)
+        kernels = mesh is None or mesh.size == 1
         if events.enabled():
             events.instant("ssm1.scan", layer=name, channels=d, state=n,
                            dt_rank=r, taps=params["taps"], tokens=b * t,
                            chunk=min(chunk, t), chunks=-(-t // min(chunk, t)),
                            state_bytes=b * n * d * 4,
-                           memory_out=memory_out, impl="plain")
+                           memory_out=memory_out,
+                           impl="kernel" if kernels
+                           and ssm1_kernels.takes_kernel(min(chunk, t), d, n)
+                           else "plain")
 
         def mm(pattern, x, w):
             return jnp.einsum(pattern, x.astype(mdt), w.astype(mdt),
@@ -1000,7 +1036,8 @@ class SelectiveScanMixerOp(OpDef):
                              + w["dt_bias"].astype(f32))
         with jax.named_scope("ssm1.scan"):
             y, least = selective_scan(x, dt, -jnp.exp(w["A_log"].astype(f32)),
-                                      bm, cm, chunk, layer=name)
+                                      bm, cm, chunk, layer=name,
+                                      kernels=kernels)
         memory = y + w["D"].astype(f32) * x
         out = mm("btc,ce->bte", memory * jax.nn.silu(z), w["out_proj"])
         # counters add over layers and steps: the sum of each layer's

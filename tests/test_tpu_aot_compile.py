@@ -1436,18 +1436,33 @@ def test_the_grouped_kernels_compile_at_cell_11s_shapes(v5e_devices,
 
 
 def test_the_selective_scan_mixer_compiles_at_the_published_width(
-        v5e_devices):
+        v5e_devices, monkeypatch):
     """One mixer's forward and backward at 2560 -> 5120 channels of 16
     state entries over 8,192 positions in 128 chunks of 64, bf16
-    operands, compiled for a described v5e: plain XLA (no Mosaic call),
-    the recurrence under ``ssm1.scan`` in rematerialised chunks, and NO
-    array of all tokens' states: ``(8192, 16, 5120)`` float32 would be
-    2.5 GiB and the layer's temporaries read 1.58 (its interior held: it
-    is not rematerialised whole, a block around it is)."""
+    operands, compiled for a described v5e: the recurrence is two Mosaic
+    calls (``kernels/selective_scan.py``: the forward that keeps the
+    chunks' starting states, the backward), both under ``ssm1.scan``,
+    each with a cost estimate; no ``while`` walks the chunks and no
+    ``remat.ssm1.chunk`` is left; NO array of a chunk's states, let
+    alone the sequence's (``(64, 16, 5120)`` float32 was 20 MiB a chunk
+    on the plain path, ``(8192, 16, 5120)`` would be 2.5 GiB): what
+    carries ``(.., 16, ..)`` registers is the 128 chunks' starting
+    states, 40 MiB. ``x``, ``dt`` and ``y`` reach the calls as ``(1024,
+    320, 128)``, the bytes of the tiled ``(8192, 5120)`` arrays as they
+    lie: nothing that size is copied, turned or reshaped beside the
+    calls (the views are bitcasts inside the neighbouring fusions). The
+    layer's temporaries read 1.72 GiB here where the plain path's read
+    1.58 (its interior held: it is not rematerialised whole, a block
+    around it is): the calls' ``dx`` and ``d dt`` are buffers of their
+    own, 160 MiB each, where XLA fused the plain path's into their
+    consumers; the whole step's ``step_hbm_gib`` is LOWER (12.077 for
+    12.124: PERF.md section 6, PR 62)."""
     from flexflow_tpu import FFConfig
     from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.kernels import selective_scan
     from flexflow_tpu.ops.recurrent_ops import SelectiveScanMixerOp
     from flexflow_tpu.ops.registry import EmitCtx
+    monkeypatch.setattr(selective_scan, "pallas_interpret", lambda: False)
     params = {"inner": 5120, "state": 16, "dt_rank": 160, "taps": 4,
               "chunk": 64, "memory_out": True}
     op = SelectiveScanMixerOp()
@@ -1463,12 +1478,26 @@ def test_the_selective_scan_mixer_compiles_at_the_published_width(
                        EmitCtx(training=True, config=FFConfig()), "ssm_0")
         return jnp.sum(y) + jnp.sum(m)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w)
+    assert lowered.as_text().count("cost_estimate") == 2
+    compiled = lowered.compile()
     txt = compiled.as_text()
-    assert MOSAIC_CALL not in txt
-    assert "ssm1.scan" in txt and "remat.ssm1.chunk" in txt \
-        and " while(" in txt
-    # a chunk's states, never the sequence's
-    assert re.search(r"f32\[64,1,16,5120\]", txt)
-    assert not re.search(r"f32\[(8192|128,64),1,16,5120\]", txt)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.0 * 2 ** 30
+    calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+    assert _kernel_names(txt) == ["selective_scan_bwd", "selective_scan_fwd"]
+    assert all("ssm1.scan" in l for l in calls)
+    # in the text's order: the forward first
+    assert ["selective_scan_fwd" in l.split(" = ")[0] for l in calls] \
+        == [True, False]
+    assert "remat.ssm1.chunk" not in txt and " while(" not in txt
+    # a chunk's states or the sequence's, in any view
+    assert not re.search(r"f32\[(8192|128,64|64),1,16,5120\]", txt)
+    assert re.search(r"f32\[1,128,5,16,8,128\]", txt)     # the 128 starts
+    # nothing the size of x beside the calls but fusions' own results
+    whole = 8192 * 5120
+    moved = [l for l in txt.splitlines()
+             for m in [re.search(
+                 r"= f32\[([0-9,]+)\]\S* (copy|transpose|reshape)\(", l)]
+             if m and np.prod([int(v) for v in m.group(1).split(",")])
+             >= whole]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.75 * 2 ** 30

@@ -40,8 +40,9 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          benchmark cell is. It checks that the ``kda.scan`` and
          ``attn.latent`` instants are the configuration's two lists,
          that the layers took the path their shapes give (at published
-         widths the kernels: the chunks' terms and the scan, each
-         forward and backward, four kinds of ``kda.kernel`` instant) and
+         widths the kernels: the chunks' terms, the scan and q, k and
+         v's way from the projections, each forward and backward, six
+         kinds of ``kda.kernel`` instant) and
          prints the ``kda.*`` counters; it looks at no gradient:
          ``examples/tpu_validate_linear_latent_moe.py`` does.
   Leg F  four residual streams under manifold-constrained
@@ -96,8 +97,10 @@ SEARCH_BUDGET = 8
 TRAIN_STEPS = 5           # after the step that compiles
 #: the ``kernel=`` of a delta-rule layer's ``kda.kernel`` / ``gdn.kernel``
 #: instants on the kernel path: the chunks' terms and the scan, each
-#: forward and backward
+#: forward and backward; and q, k and v's way from the projections
+#: (``kernels/delta_mix.py``), which has a predicate of its own
 DELTA_RULE_KERNELS = ["bwd", "fwd", "scan_bwd", "scan_fwd"]
+DELTA_MIX_KERNELS = ["mix_bwd", "mix_fwd"]
 PROMPT_LEN, NEW_TOKENS = 128, 16
 # Per-chip batches for f32 weights, gradients and Adam moments, no
 # rematerialization. BERT-large: XLA's memory analysis of the compiled
@@ -678,6 +681,12 @@ def _say_delta_rule_kernels(label, name, kernels, of_terms):
     grid, and what a step holds (``of_terms`` words the terms' kernels,
     the scan's say how many heads' states ride in VMEM)."""
     for kind, a in sorted(kernels.items()):
+        if kind.startswith("mix"):
+            say(f"{label}: {name} {kind}: {a['grid_steps']} grid steps of "
+                f"{a['tile']} tokens of {a['heads_per_step']} of "
+                f"{a['heads']} heads ({a['part']}), "
+                f"{a['vmem_bytes'] / 2 ** 20:.1f} MiB of VMEM a step")
+            continue
         held = (f"{a['heads_per_step']} heads' states in VMEM"
                 if kind.startswith("scan") else of_terms(a))
         say(f"{label}: {name} {kind}: {a['grid_steps']} grid steps of "
@@ -741,11 +750,15 @@ def leg_linear_latent_moe(model_cfg, seq: int, per_chip_batch: int,
         f"{want})")
     _say_delta_rule_kernels(label, "kda.kernel", kernels,
                             lambda a: f"sub-blocks of {a['sub']}")
-    check(took == {want} and sorted(kernels) == (
-        DELTA_RULE_KERNELS if want == "kernel" else []),
+    mixed = {a["mix"] for a in seen["kda.scan"].values()}
+    say(f"{label}: q, k and v from the projections by {sorted(mixed)}")
+    check(took == {want} and len(mixed) == 1 and sorted(kernels) == sorted(
+        (DELTA_RULE_KERNELS if want == "kernel" else [])
+        + (DELTA_MIX_KERNELS if mixed == {"kernel"} else [])),
           f"{label}: the linear-attention layers announced "
-          f"{ {n: a['impl'] for n, a in seen['kda.scan'].items()} } and "
-          f"the kernels {sorted(kernels)} where the shapes say {want}")
+          f"{ {n: a['impl'] for n, a in seen['kda.scan'].items()} }, mix "
+          f"{sorted(mixed)} and the kernels {sorted(kernels)} where the "
+          f"shapes say {want}")
     check(all(a["q_rank"] is None and a["rope"] is False
               for a in seen["attn.latent"].values()),
           f"{label}: latent attention built as {seen['attn.latent']}")
@@ -1249,12 +1262,16 @@ def leg_gdn_gated_moe(model_cfg, seq: int, per_chip_batch: int, label: str,
     _say_delta_rule_kernels(
         label, "gdn.kernel", kernels,
         lambda a: f"{a['group']} value heads a q/k head")
+    mixed = {a["mix"] for _, a in said["gdn.scan"]}
+    say(f"{label}: q, k and v from the projections by {sorted(mixed)}")
     check(len(took) == 1 and (took == {want} or took == {"plain"})
-          and sorted(kernels) == (DELTA_RULE_KERNELS
-                                  if took == {"kernel"} else []),
+          and len(mixed) == 1 and sorted(kernels) == sorted(
+              (DELTA_RULE_KERNELS if took == {"kernel"} else [])
+              + (DELTA_MIX_KERNELS if mixed == {"kernel"} else [])),
           f"{label}: the linear layers announced "
-          f"{ {n: a['impl'] for n, a in said['gdn.scan']} } and the "
-          f"kernels {sorted(kernels)} where the shapes say {want}")
+          f"{ {n: a['impl'] for n, a in said['gdn.scan']} }, mix "
+          f"{sorted(mixed)} and the kernels {sorted(kernels)} where the "
+          f"shapes say {want}")
     ctr = events.counters()
     scans = ctr.get("gdn.scans", 0)
     least = ctr.get("gdn.log_decay_min", 0) / max(1.0, scans)

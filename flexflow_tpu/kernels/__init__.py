@@ -13,6 +13,12 @@ with:
   - ``gated_delta_rule``: the in-chunk terms of linear attention's chunked
     recurrence (fwd+bwd), taken by ``ops/recurrent_ops.py`` where the
     shapes allow; not a registry entry.
+  - ``delta_mix``: a delta-rule layer's q, k and v from the projections'
+    float32 output to the recurrence's operand in one pass (the causal
+    taps, the SiLU, the unit length with its scale, the turn to
+    heads-first; fwd+bwd), taken by
+    ``ops/recurrent_ops.py::GatedDeltaRuleOp.projections`` where a head
+    is whole lanes; not a registry entry.
   - ``hyper_connection``: the residual streams' mixes (fwd+bwd), a tile of
     tokens' whole ``n x C`` entries in VMEM, taken by ``ops/hyper_ops.py``
     where the channels are whole lanes; not a registry entry.

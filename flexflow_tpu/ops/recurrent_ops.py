@@ -97,6 +97,7 @@ import numpy as np
 
 from ..core.tensor import WeightSpec
 from ..ffconst import InitializerType, OperatorType
+from ..kernels import delta_mix as mix_kernels
 from ..kernels import selective_scan as ssm1_kernels
 from ..kernels import state_space as ssm_kernels
 from ..kernels.gated_delta_rule import (SUB, chunk_terms, head_chunk_terms,
@@ -254,6 +255,18 @@ def _in_chunks(x, chunk, axis=2):
     return x.reshape(x.shape[:axis] + (-1, chunk) + x.shape[axis + 1:])
 
 
+def _holds_whole_heads(heads, mesh, spec) -> bool:
+    """Whether every device of ``mesh`` holds whole heads of an operand
+    of ``heads`` heads sharded by the head entry of ``spec``."""
+    if mesh is None or mesh.size == 1:
+        return True
+    entry = (tuple(spec or ()) + (None, None))[1]
+    degree = 1
+    for axis in (entry if isinstance(entry, tuple) else (entry,)):
+        degree *= mesh.shape[axis] if axis is not None else 1
+    return heads % degree == 0
+
+
 def head_decay_impl(chunk, q_heads, heads, dk, dv, mesh=None, spec=None):
     """``"kernel"`` or ``"plain"`` for a decay a head, from what the
     call can observe: the shapes (:func:`takes_head_kernel`) and, under a
@@ -262,12 +275,20 @@ def head_decay_impl(chunk, q_heads, heads, dk, dv, mesh=None, spec=None):
     if heads % q_heads or not takes_head_kernel(chunk, dk, dv,
                                                 heads // q_heads):
         return "plain"
-    if mesh is not None and mesh.size > 1:
-        entry = (tuple(spec or ()) + (None, None))[1]
-        degree = 1
-        for axis in (entry if isinstance(entry, tuple) else (entry,)):
-            degree *= mesh.shape[axis] if axis is not None else 1
-        if q_heads % degree:
+    return "kernel" if _holds_whole_heads(q_heads, mesh, spec) else "plain"
+
+
+def mix_impl(weights, tokens, mesh=None, spec=None):
+    """``"kernel"`` or ``"plain"`` for a layer's q, k and v chains (the
+    taps, the SiLU and the unit length between a projection and the
+    recurrence), from what the call can observe: the shapes
+    (``kernels/delta_mix.py::takes_kernel``, every branch's) and, under
+    a mesh of several devices, whether every device holds whole heads
+    of q and k as of v."""
+    for n in "qkv":
+        heads, d, taps = weights["conv_" + n].shape
+        if not (mix_kernels.takes_kernel(d, taps, tokens, jnp.float32)
+                and _holds_whole_heads(heads, mesh, spec)):
             return "plain"
     return "kernel"
 
@@ -581,7 +602,12 @@ class GatedDeltaRuleOp(OpDef):
     recurrence (the chunks' terms and the scan over the chunk states,
     both by the kernels of ``kernels/gated_delta_rule.py`` at head sizes
     in whole lanes; not the projections) runs under the name scope
-    ``kda.scan``. Training and evaluation only: there is no
+    ``kda.scan``. At head sizes in whole lanes q, k and v go from the
+    projections' products to the recurrence through the two kernels of
+    ``kernels/delta_mix.py`` (:func:`mix_impl`; taps, SiLU, unit length
+    and the turn to heads-first in one pass each way) under ``kda.mix``
+    / ``gdn.mix``; the layer's ``kda.scan`` / ``gdn.scan`` instant says
+    so in ``mix``. Training and evaluation only: there is no
     decode path that carries the state from call to call."""
     op_type = OperatorType.OP_GATED_DELTA_RULE
     keeps_output_for_block = True   # ``emit``: the layer is one checkpoint
@@ -636,19 +662,39 @@ class GatedDeltaRuleOp(OpDef):
             WeightSpec("wo", (h, d, e), dt, init_args=fans(h * d, e))]
 
     @staticmethod
-    def projections(x, weights, mdt, *, layer=None, specs=None, mesh=None):
+    def projections(x, weights, mdt, *, layer=None, specs=None, mesh=None,
+                    shard=(None, None)):
         """``q, k, v, g, beta`` as the recurrence takes them and the
         output gate, all float32 and heads leading: (B, H, T, d) but
         ``beta`` (B, H, T). ``layer`` names the caller in its branches'
         ``remat.wrap`` instants, which count one device's bytes by
-        ``specs`` (``x``'s, the weights' by name) over ``mesh``."""
+        ``specs`` (``x``'s, the weights' by name) over ``mesh``.
+
+        Where :func:`mix_impl` says ``"kernel"`` (``shard``: the
+        kernels' ``(mesh, spec)``, ``_kernel_shard_spec``'s) q, k and v
+        go from the projection's product, left tokens-first as the
+        product writes it, to heads-first through
+        ``kernels/delta_mix.py`` under the name scope ``kda.mix`` /
+        ``gdn.mix``, q's ``d ** -0.5`` inside; otherwise through
+        ``short_conv``, ``silu`` and ``_unit``, which is also the
+        kernels' oracle."""
         f32 = jnp.float32
+        scope = "gdn" if "wa" in weights else "kda"
+        by_kernel = mix_impl(weights, x.shape[1], *shard) == "kernel"
 
         def mm(pattern, a, w):
             return jnp.einsum(pattern, a.astype(mdt), w.astype(mdt),
                               preferred_element_type=f32)
 
-        def mixed(x, w, taps, unit):    # a head's channels: (B, T, d)
+        def mixed(x, w, taps, unit, scale=1.0, part=None):
+            if by_kernel:
+                p = mm("bte,ehd->bthd", x, w)
+                with jax.named_scope(scope + ".mix"):
+                    return mix_kernels.delta_mix(
+                        p, taps, unit=unit, scale=scale, eps=NORM_EPS,
+                        scope=scope, layer=layer, part=part, mesh=shard[0],
+                        spec=shard[1])
+            # a head's channels: (B, T, d)
             z = jax.nn.silu(jax.vmap(short_conv, (1, 0), 1)(
                 mm("bte,ehd->bhtd", x, w), taps.astype(f32)))
             return _unit(z) if unit else z
@@ -682,9 +728,14 @@ class GatedDeltaRuleOp(OpDef):
             return jax.nn.silu(mm("bte,ehd->bhtd", x, w))
 
         d = weights["wq"].shape[-1]
-        q = branch(mixed, "wq", "conv_q", unit=True) * d ** -0.5
-        k = branch(mixed, "wk", "conv_k", unit=True)
-        v = branch(mixed, "wv", "conv_v", unit=False)
+        if by_kernel:
+            q, k, v = (branch(mixed, "w" + n, "conv_" + n, unit=n != "v",
+                              scale=d ** -0.5 if n == "q" else 1.0,
+                              part="w" + n) for n in "qkv")
+        else:
+            q = branch(mixed, "wq", "conv_q", unit=True) * d ** -0.5
+            k = branch(mixed, "wk", "conv_k", unit=True)
+            v = branch(mixed, "wv", "conv_v", unit=False)
         if "wa" in weights:
             g = branch(head_decay, "wa", "A_log", "dt_bias")
         else:
@@ -712,6 +763,7 @@ class GatedDeltaRuleOp(OpDef):
         # a compiled kernel inside a multi-device jit runs on each
         # device's (batch, head) shard, as the attention kernels do
         mesh, spec = MultiHeadAttentionOp._kernel_shard_spec(ctx, b, h)
+        mix = mix_impl(weights, t, mesh, spec) if events.enabled() else None
         if events.enabled() and by_head:
             chunks = -(-t // chunk)
             hk, dk = weights["wk"].shape[1:]
@@ -723,7 +775,7 @@ class GatedDeltaRuleOp(OpDef):
                            taps=weights["conv_q"].shape[-1],
                            tokens=b * t, chunk=chunk, chunks=chunks,
                            state_bytes=4 * b * chunks * h * d * d,
-                           impl=impl, scan=impl)
+                           impl=impl, scan=impl, mix=mix)
         elif events.enabled():
             chunks = -(-t // chunk)
             impl = "kernel" if takes_kernel(chunk, d, d) else "plain"
@@ -731,7 +783,7 @@ class GatedDeltaRuleOp(OpDef):
                            taps=weights["conv_q"].shape[-1],
                            tokens=b * t, chunk=chunk, chunks=chunks,
                            state_bytes=4 * b * chunks * h * d * d,
-                           impl=impl, scan=impl)
+                           impl=impl, scan=impl, mix=mix)
 
         # The layer is rematerialised whole, and inside it each branch
         # of the projections once more: what it keeps for the backward
@@ -750,7 +802,8 @@ class GatedDeltaRuleOp(OpDef):
 
         def layer(x, weights):
             q, k, v, g, beta, gate = self.projections(
-                x, weights, mdt, layer=name, specs=specs, mesh=wrap_mesh)
+                x, weights, mdt, layer=name, specs=specs, mesh=wrap_mesh,
+                shard=(mesh, spec))
             with jax.named_scope(scope + ".scan"):
                 o, least = gated_delta_rule(q, k, v, g, beta, chunk, mdt,
                                             layer=name, mesh=mesh,

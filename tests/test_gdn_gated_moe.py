@@ -236,7 +236,7 @@ def test_the_layers_span_and_counters():
         "layer": "linear_attn", "key_heads": HK, "value_heads": HV,
         "key_head_dim": D, "head_dim": D, "taps": TAPS, "tokens": B * S,
         "chunk": 16, "chunks": 3, "state_bytes": 4 * B * 3 * HV * D * D,
-        "impl": "plain", "scan": "plain"}
+        "impl": "plain", "scan": "plain", "mix": "plain"}
     assert float(counters["gdn.scans"]) == 1.0
     assert float(counters["gdn.log_decay_min"]) < 0.0
     assert not [k for k in counters if k.startswith("kda.")]

@@ -313,7 +313,14 @@ def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
     assert scan["impl"] == impl and scan["layer"] == "gdn_7"
     assert scan["scan"] == impl
     assert not [e for e in seen if e["name"].startswith("kda.")]
+    # (q, k and v reach the recurrence through ``kernels/delta_mix.py``
+    # wherever a head is whole lanes, whatever the chunk:
+    # ``tests/test_delta_mix_kernel.py`` reads those instants)
+    assert scan["mix"] == ("kernel" if d == 128 else "plain")
     kernels = [e["attrs"] for e in seen if e["name"] == "gdn.kernel"]
+    assert any(k["kernel"].startswith("mix_") for k in kernels) \
+        is (d == 128)
+    kernels = [k for k in kernels if not k["kernel"].startswith("mix_")]
     wraps = [e["attrs"]["site"] for e in seen if e["name"] == "remat.wrap"]
     assert ("gdn.terms" in wraps) is (impl == "plain")
     # (the layer is rematerialised whole: jax.checkpoint traces its

@@ -247,7 +247,14 @@ def test_the_layer_announces_the_path_it_took(d, chunk, impl, calls):
     (scan,) = [e["attrs"] for e in seen if e["name"] == "kda.scan"]
     assert scan["impl"] == impl and scan["layer"] == "kda_7"
     assert scan["scan"] == impl
+    # (q, k and v reach the recurrence through ``kernels/delta_mix.py``
+    # wherever a head is whole lanes, whatever the chunk:
+    # ``tests/test_delta_mix_kernel.py`` reads those instants)
+    assert scan["mix"] == ("kernel" if d == 128 else "plain")
     kernels = [e["attrs"] for e in seen if e["name"] == "kda.kernel"]
+    assert any(k["kernel"].startswith("mix_") for k in kernels) \
+        is (d == 128)
+    kernels = [k for k in kernels if not k["kernel"].startswith("mix_")]
     # (the layer is rematerialised whole: jax.checkpoint traces its
     # forward once more before the rules run)
     assert sorted({k["kernel"] for k in kernels}) == sorted(calls)

@@ -352,9 +352,9 @@ KDA_NAMES = ["gated_delta_rule_bwd", "gated_delta_rule_fwd",
 def compiled_kda(monkeypatch):
     """The op asks the platform whether to interpret, and the platform
     here is the CPU: answer for the described chip."""
-    monkeypatch.setattr(
-        "flexflow_tpu.kernels.gated_delta_rule.pallas_interpret",
-        lambda: False)
+    for module in ("gated_delta_rule", "delta_mix"):
+        monkeypatch.setattr(
+            f"flexflow_tpu.kernels.{module}.pallas_interpret", lambda: False)
 
 
 def _kda_loss(mesh, spec, q, k, v, g, beta, chunk=64):
@@ -493,6 +493,26 @@ def test_a_rematerialised_block_calls_the_forward_kernel_twice_a_layer(
                                   ("scan_bwd", 1))}
     assert not [l for l in txt.splitlines()
                 if " while(" in l and "kda.scan" in l]
+    # q, k and v through ``kernels/delta_mix.py`` (PR 63), every call
+    # under ``kda.mix`` and none under ``kda.scan``: a branch's forward
+    # runs with the step's forward and the layer's second run; its third
+    # run (the ``kda.branch`` checkpoint's) needs the product alone, so
+    # the forward call is dropped there: 2 x 3 forward calls a layer and
+    # one backward a branch (3 x 3 and 3 if it were kept)
+    mixes = {}
+    for line in txt.splitlines():
+        if MOSAIC_CALL in line and "delta_mix" in line:
+            op_name = line.split('op_name="')[1].split('"')[0]
+            assert "kda.mix" in op_name and "kda.scan" not in op_name
+            (layer,) = {p for p in op_name.split("/")
+                        if p.startswith("kda_")}
+            (kernel,) = re.findall(r"delta_mix_(\w+)/", op_name)
+            mixes[layer, kernel] = mixes.get((layer, kernel), 0) + 1
+    found = {n for (_, k), n in mixes.items() if k == "fwd"}
+    assert found in ({6}, {9}), mixes
+    print(f"delta_mix_fwd calls a layer: {found}")
+    assert mixes == {(f"kda_{i}", k): n for i in (0, 1, 2, 4)
+                     for k, n in (("fwd", 6), ("bwd", 3))}
 
 
 # the residual streams' mixes (kernels/hyper_connection.py)
@@ -1204,6 +1224,16 @@ def test_the_grouped_kernels_compile_at_cell_10s_shapes(v5e_devices,
                for l in txt.splitlines() if MOSAIC_CALL in l)
 
 
+def _turned(txt, size):
+    """The float32 ``copy`` / ``transpose`` instructions of a compiled
+    layer whose result has ``size`` entries or more: an operand of the
+    delta rule's kernels turned or copied on its way."""
+    return [l for l in txt.splitlines()
+            if re.search(r"= f32\[[0-9,]*\]\S* (copy|transpose)\(", l)
+            and np.prod([int(n) for n in re.search(
+                r"= f32\[([0-9,]*)\]", l).group(1).split(",")]) >= size]
+
+
 def test_the_head_decay_delta_rule_compiles_at_the_published_width(
         v5e_devices, compiled_kda):
     """One linear layer's forward and backward at 2048 -> 16 q/k heads
@@ -1240,11 +1270,19 @@ def test_the_head_decay_delta_rule_compiles_at_the_published_width(
                        ).lower(x, w).compile()
     txt = compiled.as_text()
     calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
-    assert _kernel_names(txt) == ["gated_delta_rule_head_bwd"] \
+    assert _kernel_names(txt) == ["delta_mix_bwd"] * 3 \
+        + ["delta_mix_fwd"] * 6 + ["gated_delta_rule_head_bwd"] \
         + ["gated_delta_rule_head_fwd"] * 2 \
         + ["gated_delta_rule_scan_bwd"] \
         + ["gated_delta_rule_scan_fwd"] * 2
-    assert all("gdn.scan" in l for l in calls)
+    assert all(("gdn.mix" if "delta_mix" in l else "gdn.scan") in l
+               for l in calls)
+    # q's and k's projections reach the mix kernels tokens-first at
+    # their own 16 heads, v's at 32, and leave heads-first
+    assert sorted(re.search(r"= \(?(f32\[[0-9,]*\])", l).group(1)
+                  for l in calls if "delta_mix_fwd" in l) \
+        == ["f32[1,16,8192,128]"] * 4 + ["f32[1,32,8192,128]"] * 2
+    assert not _turned(txt, 16 * 8192 * 128)
     assert all("f32[16,8192,128]" in l for l in calls if "_head_" in l)
     # the scan's rows leave as (B H, T, dv) and its states stay (N, B H,
     # dv, dk): no turn of the stacked outputs after a loop
@@ -1391,17 +1429,142 @@ def test_the_channel_decay_delta_rule_compiles_at_the_published_width(
     txt = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))
                   ).lower(x, w).compile().as_text()
     calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
-    assert _kernel_names(txt) == ["gated_delta_rule_bwd"] \
+    assert _kernel_names(txt) == ["delta_mix_bwd"] * 3 \
+        + ["delta_mix_fwd"] * 6 + ["gated_delta_rule_bwd"] \
         + ["gated_delta_rule_fwd"] * 2 + ["gated_delta_rule_scan_bwd"] \
         + ["gated_delta_rule_scan_fwd"] * 2
-    assert all("kda.scan" in l for l in calls)
+    assert all(("kda.mix" if "delta_mix" in l else "kda.scan") in l
+               for l in calls)
     assert " while(" not in txt and "triangular" not in txt
-    turned = [l for l in txt.splitlines()
-              if re.search(r"= f32\[[0-9,]*\]\S* (copy|transpose)\(", l)
-              and "kda.scan" in l and np.prod([int(n) for n in re.search(
-                  r"= f32\[([0-9,]*)\]", l).group(1).split(",")])
-              >= 32 * 4096 * 128]
-    assert not turned, turned
+    # nor around the mix kernels': the projection's product is written
+    # tokens-first as they read it, ``dp`` goes to the products as it is
+    assert not _turned(txt, 32 * 4096 * 128)
+
+
+# ----------------------------------------------------------------------
+# q, k and v from the projection's product to the recurrence's operand
+# (kernels/delta_mix.py, PR 63)
+# ----------------------------------------------------------------------
+MIX_NAMES = ["delta_mix_bwd", "delta_mix_fwd"]
+# (batch, tokens, heads, unit): cell 5's q, k and v, cell 10's q and k,
+# cell 10's v
+MIX_SHAPES = {"cell5_q": (1, 4096, 32, True), "cell5_v": (1, 4096, 32, False),
+              "cell10_q": (1, 8192, 16, True),
+              "cell10_v": (1, 8192, 32, False)}
+
+
+def _mix_both(mesh, spec, unit, p, taps, ct):
+    from flexflow_tpu.kernels.delta_mix import delta_mix
+    b, t, width = p.shape
+    with jax.named_scope("ff.forward"), jax.named_scope("kda_2"), \
+            jax.named_scope("kda.mix"):
+        y, pull = jax.vjp(lambda p, taps: delta_mix(
+            p.reshape(b, t, -1, 128), taps, unit=unit, scale=128 ** -0.5,
+            eps=1e-6, interpret=False, mesh=mesh, spec=spec), p, taps)
+    return y, pull(ct)
+
+
+def _mix_operands(mesh, spec, b, t, heads):
+    bi, hi = (tuple(spec) + (None, None))[:2]
+
+    def arr(shape, *entries):
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=NamedSharding(mesh, P(*entries)))
+    return (arr((b, t, heads * 128), bi, None, hi),
+            arr((heads, 128, 4), hi), arr((b, heads, t, 128), bi, hi))
+
+
+@pytest.mark.parametrize("case", sorted(MIX_SHAPES))
+def test_the_mix_kernels_compile_at_the_cells_shapes(v5e_devices,
+                                                     chip_locations, case):
+    """Forward and backward at the derived tiles for a described v5e:
+    the projection's ``(b, t, heads * d)`` float32 in, heads-first out,
+    ``dp`` the projection's own bytes, and nothing of an operand's size
+    copied or turned beside the two calls."""
+    from flexflow_tpu.kernels import delta_mix as dmk
+    b, t, heads, unit = MIX_SHAPES[case]
+    assert dmk.takes_kernel(128, 4, t, jnp.float32)
+    mesh = Mesh(np.array(v5e_devices[:1]), ("x0",))
+    txt = _compile_text(functools.partial(_mix_both, None, None, unit),
+                        *_mix_operands(mesh, (), b, t, heads))
+    assert _kernel_names(txt) == MIX_NAMES
+    bwd, fwd = (l.split(" custom-call(") for l in sorted(
+        (l for l in txt.splitlines() if MOSAIC_CALL in l),
+        key=lambda l: "delta_mix_fwd" in l.split(" = ")[0]))
+    wide, first = f"f32[{b},{t},{heads * 128}]", f"f32[{b},{heads},{t},128]"
+    # (the operands' shapes stand in the call's layout constraints)
+    assert first in fwd[0] and fwd[1].count(wide) == 2
+    assert wide in bwd[0] and bwd[1].count(wide) == 3 \
+        and bwd[1].count(first) == 2
+    # both carry the scope the benchmark's layer shares find them by
+    assert "ff.forward/kda_2/kda.mix/" in fwd[1] \
+        and "transpose(ff.forward)/kda_2/kda.mix/" in bwd[1]
+    assert not _turned(txt, b * t * heads * 128)
+
+
+@pytest.mark.parametrize("spec", [("x0", None), (None, "x0")],
+                         ids=["batch", "heads"])
+def test_the_mix_kernels_compile_under_a_mesh(v5e_devices, spec):
+    """Four chips by batch or by heads: each runs the kernels on its own
+    rows and whole heads under ``shard_map``; by batch the taps'
+    gradient is summed over the chips."""
+    mesh = Mesh(np.array(v5e_devices), ("x0",))
+    txt = _compile_text(functools.partial(_mix_both, mesh, P(*spec), True),
+                        *_mix_operands(mesh, spec, 4, 512, 8))
+    assert _kernel_names(txt) == MIX_NAMES
+    assert ("all-reduce" in txt) is (spec[0] is not None)
+
+
+# sha256 of one linear layer's lowered text (value and gradients of a
+# sum, float32 operands, for a described v5e) taken from the parent of
+# PR 63 (``git archive b5347bf``) by these same lines: where ``mix_impl``
+# says no, q, k and v are ``short_conv``, ``silu`` and ``_unit`` line for
+# line. A PR that means to change those lines replaces the hashes.
+LINEAR_LAYERS = {
+    "channel_decay_heads_of_64": (
+        {"num_heads": 4, "head_dim": 64, "taps": 4, "eps": 1e-5}, False,
+        "50d6cd5a597398ec3434102b4633b5ac6e91484d873efc588b99e83a467a9469"),
+    "channel_decay_stubbed": (
+        {"num_heads": 2, "head_dim": 128, "taps": 4, "eps": 1e-5}, True,
+        "aabae65529ac31637d7cf6967177bb52dfcb16c826fd87cf81fec27663b04ae9"),
+    "head_decay_stubbed": (
+        {"num_heads": 4, "num_key_heads": 2, "head_dim": 128, "taps": 4,
+         "eps": 1e-6, "decay": "head"}, True,
+        "f398e961dba98198c1c72d0ef42ecd80bc14205e93dd25eda8fd00d4deaaaec9"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LINEAR_LAYERS))
+def test_where_the_mix_predicate_says_no_the_layer_lowers_to_the_parents_text(
+        v5e_devices, monkeypatch, case):
+    """Heads of 64 (the shapes say no) and both forms of the decay at
+    heads of 128 with the predicate stubbed to no: the layer's lowered
+    text is the parent's, and names no mix kernel."""
+    import hashlib
+
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.ops.recurrent_ops import GatedDeltaRuleOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    params, stubbed, sha = LINEAR_LAYERS[case]
+    if stubbed:
+        monkeypatch.setattr("flexflow_tpu.kernels.delta_mix.takes_kernel",
+                            lambda *a: False)
+    op = GatedDeltaRuleOp()
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    shape = (1, 512, 256)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+    w = {s.name: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=one)
+         for s in op.weights(params, [shape], [DataType.DT_FLOAT])}
+
+    def loss(x, w):
+        (y,) = op.emit(params, [x], w,
+                       EmitCtx(training=True, config=FFConfig()), "linear_0")
+        return jnp.sum(y * y)
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).as_text()
+    assert "delta_mix" not in lowered
+    assert hashlib.sha256(lowered.encode()).hexdigest() == sha
 
 
 # ----------------------------------------------------------------------

@@ -1390,6 +1390,80 @@ def leg_sambay(model_cfg, seq: int, per_chip_batch: int, label: str,
     _compiled_step_size(ff, x, y, label)
 
 
+# ----------------------------------------------------------------------
+# Leg L — a block-diffusion training step
+# ----------------------------------------------------------------------
+VALIDATION_BLOCK_DIFFUSION = "examples/tpu_validate_block_diffusion.py"
+
+
+def leg_block_diffusion(model_cfg, seq: int, per_chip_batch: int,
+                        label: str, alpha: float = 1e-5) -> None:
+    """``build_hybrid_conv_moe`` with ``"block_diffusion_attention"``
+    layers through compile and fit with ``remat = "blocks"``: the
+    eval-mode loss (one mask, the configuration's) falls, the noising op
+    announced a draw from the step's key and masked about half the
+    tokens, every attention layer announced the mask and who draws it
+    (the flash kernels on a chip, which then skip the dead quadrant and,
+    from two tiles a half on, visit under half of the square), the loss
+    its weights, nothing was dropped, and the step
+    fits the chip. ``VALIDATION_BLOCK_DIFFUSION`` holds the mask, the
+    draw and the gradients to the reference, and this leg names it."""
+    import jax
+
+    from flexflow_tpu.models.nlp import build_hybrid_conv_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label, dropout=True)     # a new mask every step
+    _check_kept_outputs(label, ff)
+    layers = len(model_cfg.layer_types)
+    noted = {name: [e["attrs"] for e in events.events()
+                    if e["name"] == name]
+             for name in ("diffusion.noise", "attn.block_diffusion",
+                          "loss.weighted")}
+    drew = sorted({a["key"] for a in noted["diffusion.noise"]})
+    masks = {a["layer"]: a["impl"] for a in noted["attn.block_diffusion"]}
+    impls = ff.executor.resolved_attention_impls
+    say(f"{label}: the noising op drew from {drew}; the mask in "
+        f"{len(masks)} layers by {sorted(set(masks.values()))}; the loss "
+        f"weighted over {sorted({a['rows'] for a in noted['loss.weighted']})}"
+        f" rows; resolved {sorted(set(impls.values()))}")
+    check(drew == ["eval", "step"] and len(masks) == layers == len(impls)
+          and noted["loss.weighted"]
+          and all(a["block_length"] == model_cfg.block_length
+                  for a in noted["attn.block_diffusion"]),
+          f"{label}: what announced itself: {noted}")
+    ctr = events.counters()
+    masked = ctr.get("diffusion.masked_tokens", 0) / max(
+        1.0, ctr.get("diffusion.tokens", 0))
+    weight = ctr.get("diffusion.weight_sum", 0) / max(
+        1.0, ctr.get("diffusion.tokens", 0))
+    kept = ctr.get("attn.bd_visited_pairs", 0) / max(
+        1.0, ctr.get("attn.bd_pairs", 0))
+    live = 0.25 + model_cfg.block_length / (4.0 * seq)
+    say(f"{label}: {masked:.4f} of the tokens masked, weights {weight:.4f} "
+        f"a token on average; the kernels' grids visit {kept:.4f} of the "
+        f"square, the mask attends {live:.4f}")
+    check(0.3 < masked < 0.7 and 0.5 < weight < 2.0,
+          f"{label}: masked share {masked}, mean weight {weight}")
+    if chip:
+        # one tile a half skips the dead quadrant alone (0.75); from
+        # two tiles a half on, the triangle and the off-diagonal too
+        check(set(impls.values()) == {"flash"} == set(masks.values())
+              and live <= kept <= (0.75 if seq < 2048 else 0.5),
+              f"{label}: attention resolved to {impls}, the mask by "
+              f"{masks}, {kept} of the square visited at seq {seq}")
+    else:
+        check(kept == 1.0, f"{label}: off the kernels every pair is "
+                           f"computed, not {kept}")
+    _check_experts_counters(label)
+    _check_flash_grids(label, want=chip)
+    say(f"{label}: not checked here: the mask, the draw and the gradients "
+        f"against the reference: python3 {VALIDATION_BLOCK_DIFFUSION}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def main() -> int:
     import jax
     devs = jax.devices()
@@ -1408,6 +1482,7 @@ def main() -> int:
                                          LatentMoEConfig, LFM2RankConfig,
                                          Phi4FlashRankConfig,
                                          Qwen3NextRankConfig,
+                                         SDARRankConfig,
                                          TrinityRankConfig, XingRankConfig)
     from flexflow_tpu.utils.compilation_cache import (
         cache_entries, enable_compilation_cache)
@@ -1462,6 +1537,10 @@ def main() -> int:
             Phi4FlashRankConfig.tiny(), sliding_window=256), 1024, 1,
             "K/small", alpha=1e-3)
         leg_sambay(Phi4FlashRankConfig(), 8192, 1, "K/phi4flash")
+        # 512 tokens as 1,024 positions, then the cell's shapes
+        leg_block_diffusion(SDARRankConfig.tiny(), 512, 1, "L/small",
+                            alpha=1e-3)
+        leg_block_diffusion(SDARRankConfig(), 4096, 1, "L/sdar")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

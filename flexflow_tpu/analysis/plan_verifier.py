@@ -373,6 +373,7 @@ def _check_op_shards(report, strategy, by_name, axis_sizes,
             _check_spec(report, axis_sizes, name, f"output[{i}]", sp,
                         shape)
             _check_conv_sequence(report, axis_sizes, layer, sp)
+            _check_block_diffusion_sequence(report, axis_sizes, layer, sp)
             _check_stream_axes(report, axis_sizes, layer, sp)
         for wname, sp in (getattr(os_, "weights", {}) or {}).items():
             if sp is None:
@@ -456,6 +457,31 @@ def _check_conv_sequence(report, axis_sizes, layer, spec) -> None:
                    f"output spec {spec} shards the sequence of "
                    f"{needs.format(taps=taps)} a halo of {taps - 1} "
                    f"positions from its neighbour, which is not built")
+
+
+def _check_block_diffusion_sequence(report, axis_sizes, layer, spec) -> None:
+    """The 2 L positions of a block-diffusion step are the halves of ONE
+    sequence: the noising op joins them, and an attention layer under the
+    mask reads the clean half from the noised one's rows. Batch- and
+    head-sharded layouts are local; a sequence-sharded one would need
+    the other half's keys from another shard, which nothing emits (ring
+    attention has no such mask, ``kernels/registry.py``;
+    ``search/opshard.py`` offers none)."""
+    from ..ffconst import OperatorType
+    kind = getattr(layer, "op_type", None)
+    params = getattr(layer, "params", None) or {}
+    if kind != OperatorType.OP_BLOCK_DIFFUSION_NOISE and not (
+            kind == OperatorType.OP_MULTIHEAD_ATTENTION
+            and params.get("block_diffusion_block")):
+        return
+    entries = _spec_entries(spec)
+    if len(entries) > 1 and any(axis_sizes.get(a, 1) > 1
+                                for a in entries[1]):
+        report.add("op-shard", "error", layer.name,
+                   f"output spec {spec} shards the sequence of a "
+                   f"block-diffusion step: the noised and the clean half "
+                   f"are one sequence under the mask, and a shard of it "
+                   f"is not built")
 
 
 # -- check 2: layout seams --------------------------------------------------

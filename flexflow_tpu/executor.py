@@ -146,7 +146,8 @@ def device_bytes(tree) -> int:
 
 
 def _needs_rng(layer: Layer) -> bool:
-    if layer.op_type == OperatorType.OP_DROPOUT:
+    if layer.op_type in (OperatorType.OP_DROPOUT,
+                         OperatorType.OP_BLOCK_DIFFUSION_NOISE):
         return True
     if layer.op_type == OperatorType.OP_MULTIHEAD_ATTENTION:
         return layer.params.get("dropout", 0.0) > 0.0
@@ -544,7 +545,7 @@ class Executor:
     def __init__(self, program: GraphProgram, config, dmesh: DeviceMesh,
                  strategy: ShardingStrategy, optimizer: Optimizer,
                  loss_type: LossType, metrics: Sequence[MetricsType],
-                 seed: int = 0):
+                 seed: int = 0, loss_weights: Optional[Tensor] = None):
         self.program = program
         self.config = config
         self.dmesh = dmesh
@@ -553,6 +554,8 @@ class Executor:
         self.loss_type = LossType(loss_type)
         self.metrics = list(metrics)
         self.seed = seed
+        # the tensor that weighs the loss's rows (FFModel.set_loss_weights)
+        self._loss_weights_tensor = loss_weights
         self._train_step = None
         self._eval_step = None
         # ZeRO-1 (runtime/zero.py): NamedSharding pytree for the updated
@@ -1348,12 +1351,19 @@ class Executor:
 
     def _loss_and_metrics(self, outs, capture, label, aux_losses):
         pred = outs[0]
+        # a graph that names a weights tensor: its rows weigh the loss's
+        weighted = {} if self._loss_weights_tensor is None else {
+            "weights": capture[self._loss_weights_tensor.guid]}
+        if weighted:
+            obs_events.instant("loss.weighted", weighted=True,
+                               rows=weighted["weights"].size)
         if self._logits_tensor is not None:
             logits = capture[self._logits_tensor.guid]
             loss = losses_mod.compute_loss(self.loss_type, logits, label,
-                                           logits=True)
+                                           logits=True, **weighted)
         else:
-            loss = losses_mod.compute_loss(self.loss_type, pred, label)
+            loss = losses_mod.compute_loss(self.loss_type, pred, label,
+                                           **weighted)
         for al in aux_losses:
             loss = loss + al
         bm = metrics_mod.compute_batch_metrics(self.metrics, pred, label,

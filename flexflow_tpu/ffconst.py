@@ -242,6 +242,10 @@ class OperatorType(enum.IntEnum):
     # state entry (token steps inside checkpointed chunks), the scan's
     # output optionally handed on to a later layer
     OP_SELECTIVE_SCAN_MIXER = enum.auto()
+    # block-diffusion noising: from a batch's ids the decoder's 2 L ids
+    # (a copy with tokens replaced by the mask id, then the clean one)
+    # and each position's loss weight, drawn from the step's key
+    OP_BLOCK_DIFFUSION_NOISE = enum.auto()
 
 
 # Ops that are pure elementwise-unary (single input, same shape out).

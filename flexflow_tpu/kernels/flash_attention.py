@@ -26,6 +26,14 @@ either side of the band skipped, their index maps naming the band's
 nearest block so that nothing is copied for them. It is never an operand;
 with ``window=0`` every kernel is the text it was (docs/kernels.md).
 
+A block-diffusion mask (``bd=(L, B)``, not causal, over ``2 L`` positions
+``[noised | clean]`` in blocks of ``B``: a noised query sees the noised
+keys of its own block and the clean keys of earlier blocks, a clean
+query the clean keys of its own and earlier blocks) is a third static
+form of the same kind: drawn from the tile's positions, its dead tiles
+skipped and named as their live neighbours. With ``bd=()`` every kernel
+is the text it was (docs/kernels.md).
+
 Grouped-query attention (k and v at fewer heads than q) is index
 arithmetic too: the kernels read the key/value heads in place, a query
 head naming its group's row, and ``bwd_dkv`` walks the key/value heads
@@ -83,10 +91,14 @@ def _tile_positions(iq, ik, block_q, block_k, keys_major):
 
 
 def _key_mask(iq, ik, block_q, block_k, kv_len, causal, keys_major=False,
-              window=0):
-    """Validity mask for one (q block, k block) tile; kv_len and window
-    are static. ``window``: a query sees the ``window`` keys that end
-    with its own, ``k_pos > q_pos - window`` beside the causal edge."""
+              window=0, bd=()):
+    """Validity mask for one (q block, k block) tile; kv_len, window and
+    bd are static. ``window``: a query sees the ``window`` keys that end
+    with its own, ``k_pos > q_pos - window`` beside the causal edge.
+    ``bd``: the block-diffusion mask alone (:func:`_bd_mask`; such a call
+    has no padded key)."""
+    if bd:
+        return _bd_mask(iq, ik, block_q, block_k, keys_major, bd)
     q_pos, k_pos = _tile_positions(iq, ik, block_q, block_k, keys_major)
     mask = k_pos < kv_len
     if causal:
@@ -94,6 +106,56 @@ def _key_mask(iq, ik, block_q, block_k, kv_len, causal, keys_major=False,
     if window:
         mask = jnp.logical_and(mask, k_pos > q_pos - window)
     return mask
+
+
+def _bd_mask(iq, ik, block_q, block_k, keys_major, bd):
+    """The block-diffusion mask of one tile, ``bd = (L, B)``: positions
+    ``[0, L)`` are the noised copy, ``[L, 2 L)`` the clean one, and
+    ``b(i) = (i mod L) // B``. A tile lies in one quadrant (``L`` is a
+    multiple of every block), which two scalars say; with ``e`` = the
+    key's place in its half less the first place of the query's block,
+
+      noised q, noised k   ``b(k) == b(q)``   ``0 <= e <= B - 1``
+      noised q, clean k    ``b(k) <  b(q)``   ``e <= -1``
+      clean q, clean k     ``b(k) <= b(q)``   ``e <= B - 1``
+      clean q, noised k    never
+
+    so the mask is two comparisons of ``e`` with the quadrant's scalars."""
+    length, block = bd
+    shape, q_axis = (((block_k, block_q), 1) if keys_major
+                     else ((block_q, block_k), 0))
+    q0, k0 = iq * block_q, ik * block_k
+    q_rel = q0 % length + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_rel = k0 % length + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                   1 - q_axis)
+    q_start = (q_rel & -block) if block & (block - 1) == 0 \
+        else q_rel - jax.lax.rem(q_rel, jnp.int32(block))
+    e = k_rel - q_start
+    q_clean, k_clean = q0 >= length, k0 >= length
+    lo = jnp.where(k_clean, -length, 0)
+    hi = jnp.where(k_clean, jnp.where(q_clean, block - 1, -1),
+                   jnp.where(q_clean, -1, block - 1))
+    return jnp.logical_and(e >= lo, e <= hi)
+
+
+def _bd_live(iq, ik, block_q, block_k, bd):
+    """Whether any pair of q block ``iq`` and k block ``ik`` is attended
+    under the block-diffusion mask ``bd`` (ints, or traced program ids):
+    noised x noised where the blocks' ranges of the rows and the columns
+    meet, noised x clean where the tile's first key lies before the last
+    query's block, clean x clean as the causal edge moved to the block's
+    end, clean x noised never."""
+    length, block = bd
+    q0, k0 = iq * block_q, ik * block_k
+    qb0 = (q0 % length) // block
+    qb1 = (q0 % length + block_q - 1) // block
+    kb0 = (k0 % length) // block
+    kb1 = (k0 % length + block_k - 1) // block
+    q_noised, q_clean = q0 < length, q0 >= length
+    k_noised, k_clean = k0 < length, k0 >= length
+    return ((q_noised & k_noised & (kb1 >= qb0) & (kb0 <= qb1))
+            | (q_noised & k_clean & (kb0 < qb1))
+            | (q_clean & k_clean & (kb0 <= qb1)))
 
 
 def _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k, rate,
@@ -157,12 +219,15 @@ def _fwd_piece(block_k):
     return FWD_PIECE if block_k % FWD_PIECE == 0 else block_k
 
 
-def _piece_live(iq, piece, block_q, piece_k, window=0):
+def _piece_live(iq, piece, block_q, piece_k, window=0, bd=()):
     """Causal: whether any pair of q block ``iq`` with the keys
     ``[piece * piece_k, (piece + 1) * piece_k)`` lies on or under the
     diagonal and, with a ``window``, inside the band: the piece's last
     key within the window of the block's first query (ints, or traced
-    program ids). A dead piece is skipped."""
+    program ids). ``bd``: under that mask instead (:func:`_bd_live`). A
+    dead piece is skipped."""
+    if bd:
+        return _bd_live(iq, piece, block_q, piece_k, bd)
     live = piece * piece_k <= (iq + 1) * block_q - 1
     if window:
         live = live & ((piece + 1) * piece_k - 1 > iq * block_q - window)
@@ -191,8 +256,12 @@ def _attended(mask_ref, keys=slice(None)):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
                 kv_len, block_q, block_k, dropout_rate, masked=False,
-                window=0):
-    """Online softmax over the k blocks of one q block. ``window``: the
+                window=0, bd=()):
+    """Online softmax over the k blocks of one q block. ``bd``: the
+    block-diffusion mask in place of the causal one, its dead pieces
+    skipped as the causal ones are (every row has a live key, and a
+    piece that holds none of ITS keys adds nothing that stays, as under
+    a window). ``window``: the
     band's other edge, index arithmetic like the causal one (a row's
     first live pieces may lie wholly left of ITS window: what they add
     at the stand-in maximum is scaled to 0 by the first real score).
@@ -236,7 +305,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         valid = _key_mask(iq, piece, block_q, piece_k, kv_len, causal,
-                          window=window)
+                          window=window, bd=bd)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref, keys))
         s = jnp.where(valid, s, NEG_INF)
@@ -275,10 +344,10 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
     # the same at cell 3's); without it, where a condition that always
     # holds would be folded away, the scope is a loop's body (0-9% slower
     # than inline where inline compiled; PERF.md section 6, PR 32).
-    if causal:
+    if causal or bd:
         for c in range(pieces):
             pl.when(_piece_live(iq, ik * pieces + c, block_q, piece_k,
-                                window))(functools.partial(_piece, c))
+                                window, bd))(functools.partial(_piece, c))
     elif pieces == 1:
         _piece(0)
     else:
@@ -326,7 +395,7 @@ def _bwd_ds(p, do, v, delta, keep, rate, sm_scale, keys_major):
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    *refs, sm_scale, causal, kv_len, block_q, block_k,
-                   dropout_rate, masked=False, window=0):
+                   dropout_rate, masked=False, window=0, bd=()):
     """A q block stays while k blocks stream, so the tile is held
     queries-major, (block_q, block_k), and the q block's two statistics
     are made lane-replicated (block_q, 128) tiles ONCE, into scratch
@@ -350,7 +419,8 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for row_ref, sc in ((lse_ref, lse_sc), (delta_ref, delta_sc)):
             sc[:] = jnp.broadcast_to(row_ref[0], (LANES, block_q)).T
 
-    live = _piece_live(iq, ik, block_q, block_k, window) if causal else True
+    live = _piece_live(iq, ik, block_q, block_k, window, bd) \
+        if causal or bd else True
 
     @pl.when(live)
     def _compute():
@@ -359,7 +429,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                dropout_rate) if dropout_rate > 0.0 else None
         q, lse = q_ref[0], _lanes(lse_sc[:], block_k)
         valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal,
-                          window=window)
+                          window=window, bd=bd)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref))
         p = _bwd_p(q, k, lse, valid, sm_scale, keys_major=False)
@@ -377,7 +447,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, *refs, sm_scale, causal, kv_len, block_q,
                     block_k, dropout_rate, masked=False, window=0, group=1,
-                    q_blocks=0):
+                    q_blocks=0, bd=()):
     """A k block stays while q blocks stream, and the tile is held
     KEYS-MAJOR, (block_k, block_q): ``s^T = k q^T`` and ``dp^T = v do^T``
     contract the last dimension of both operands as ``s`` always did,
@@ -414,6 +484,8 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     live = ((iq + 1) * block_q - 1 >= ik * block_k) if causal else True
     if window:      # and its first query still sees the block's last key
         live = live & (iq * block_q < (ik + 1) * block_k - 1 + window)
+    if bd:
+        live = _bd_live(iq, ik, block_q, block_k, bd)
 
     @pl.when(live)
     def _compute():
@@ -424,7 +496,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             keys_major=True) if dropout_rate > 0.0 else None
         k, lse = k_ref[0], lse_ref[0]
         valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal,
-                          keys_major=True, window=window)
+                          keys_major=True, window=window, bd=bd)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref))
         p = _bwd_p(q, k, lse, valid, sm_scale, keys_major=True)
@@ -477,12 +549,74 @@ def _q_spec(block_q, d):
     return pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
 
 
-def _live_k(block_q, block_k, causal, window):
+def _where(cond, a, b):
+    """``jnp.where`` that stays Python's on Python values: the index
+    maps below are also walked with ints (:func:`grid_steps`, which an
+    op may call inside a trace, where a ``jnp`` function of ints is a
+    tracer)."""
+    return (a if cond else b) if isinstance(cond, bool) \
+        else jnp.where(cond, a, b)
+
+
+def _clamp(x, lo, hi):
+    """``min(max(x, lo), hi)``, Python's on ints."""
+    if all(isinstance(n, int) for n in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+def _bd_live_k(block_q, block_k, bd):
+    """:func:`_live_k` under the block-diffusion mask ``bd = (L, B)``:
+    a noised q block's live k blocks are the noised ones its blocks of
+    ``B`` meet, ``[n0, n1]``, and the clean ones up to ``c1``, the last
+    with a key before its last query's block; a clean q block's the
+    clean ones up to its last query's block. A dead step names the
+    nearest live block of its half of the keys (a clean q block's
+    noised steps the first clean block), so nothing is copied for it."""
+    length, block = bd
+    nqh, nkh = length // block_q, length // block_k
+
+    def ik(i, j):
+        q0 = (i % nqh) * block_q
+        qb0, qb1 = q0 // block, (q0 + block_q - 1) // block
+        noised = i < nqh
+        n0 = _where(noised, qb0 * block // block_k, nkh)
+        n1 = _where(noised, (qb1 * block + block - 1) // block_k, nkh)
+        c1 = nkh + (qb1 * block + _where(noised, -1, block - 1)) // block_k
+        return _where(j < nkh, _clamp(j, n0, n1), _clamp(j, 0, c1))
+    return ik
+
+
+def _bd_live_q(block_q, block_k, bd):
+    """:func:`_dkv_live_q` under the block-diffusion mask: a noised k
+    block is read by the noised q blocks its blocks of ``B`` meet; a
+    clean one by the noised q blocks from the first whose last query's
+    block lies after its first key's, and by the clean ones from the
+    block that holds its first key's block."""
+    length, block = bd
+    nqh, nkh = length // block_q, length // block_k
+
+    def iq(j, i):
+        k0 = (j % nkh) * block_k
+        kb0, kb1 = k0 // block, (k0 + block_k - 1) // block
+        a0 = kb0 * block // block_q
+        a1 = (kb1 * block + block - 1) // block_q
+        last = 2 * nqh - 1
+        clean = _where(
+            i < nqh, _clamp(i, (kb0 + 1) * block // block_q, last),
+            _clamp(i, nqh + kb0 * block // block_q, last))
+        return _where(j < nkh, _clamp(i, a0, a1), clean)
+    return iq
+
+
+def _live_k(block_q, block_k, causal, window, bd=()):
     """``ik(i, j)``: the k block step ``j`` of q block ``i``'s row of the
     (bh, nq, nk) grids names. A step above the causal diagonal, whose
     arithmetic ``pl.when(live)`` skips, names the row's last live block
     again, and a step left of the window's band its first, so the
     pipeline issues no copy for either."""
+    if bd:
+        return _bd_live_k(block_q, block_k, bd)
     if not causal:
         return lambda i, j: j
     if not window:
@@ -499,10 +633,10 @@ def _kv_row(group):
     return (lambda b: b) if group == 1 else (lambda b: b // group)
 
 
-def _k_spec(block_q, block_k, d, causal, window=0, group=1):
+def _k_spec(block_q, block_k, d, causal, window=0, group=1, bd=()):
     """k/v blocks of the (bh, nq, nk) grids (see :func:`_live_k`);
     ``group`` query heads read one k/v head (:func:`_kv_row`)."""
-    ik, row = _live_k(block_q, block_k, causal, window), _kv_row(group)
+    ik, row = _live_k(block_q, block_k, causal, window, bd), _kv_row(group)
     return pl.BlockSpec((1, block_k, d),
                         lambda b, i, j: (row(b), ik(i, j), 0))
 
@@ -524,11 +658,13 @@ def _mask_spec(block_q, block_k, causal, heads):
                         lambda b, i, j: (b // heads, i, live_k(i, j)))
 
 
-def _dkv_live_q(block_q, block_k, causal, window=0):
+def _dkv_live_q(block_q, block_k, causal, window=0, bd=()):
     """``iq(j, i)``: the q block step ``i`` of k block ``j``'s row of the
     (bh, nk, nq) dkv grid names: a dead causal step names the column's
     first live q block, a step under the window's band its last (see
     _live_k)."""
+    if bd:
+        return _bd_live_q(block_q, block_k, bd)
     if causal and window:
         return lambda j, i: jnp.minimum(
             jnp.maximum(i, _first_live_q(j, block_q, block_k)),
@@ -561,12 +697,13 @@ def _dkv_mask_spec(block_q, block_k, causal, heads, group=1, nq=0):
                         lambda b, j, i: (b // kv_heads, j, iq(j, block(i))))
 
 
-def _dkv_specs(block_q, block_k, d, causal, window=0, group=1, nq=0):
+def _dkv_specs(block_q, block_k, d, causal, window=0, group=1, nq=0,
+               bd=()):
     """(q/do, k/v, row statistics) specs of the (b * kv heads, nk,
     group * nq) dkv grid: the index maps swap the roles of grid axes 1
     and 2, and a dead step names its OWN member's nearest live block
     (:func:`_dkv_member`, :func:`_dkv_live_q`)."""
-    iq = _dkv_live_q(block_q, block_k, causal, window)
+    iq = _dkv_live_q(block_q, block_k, causal, window, bd)
     head, block = _dkv_member(group, nq)
     return (pl.BlockSpec((1, block_q, d),
                          lambda b, j, i: (head(b, i), iq(j, block(i)), 0)),
@@ -576,7 +713,7 @@ def _dkv_specs(block_q, block_k, d, causal, window=0, group=1, nq=0):
 
 
 def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0,
-               kv_group=1):
+               kv_group=1, bd=()):
     """What the grid of one call costs (``window``: under the causal
     band of that many keys a query, both of whose edges are skipped;
     ``kv_group``: the query heads that read one k/v head, which changes
@@ -590,10 +727,22 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0,
     first step counts as a fetch (Mosaic skips that one too where the
     row before ended on the same block). The forward walks a step's k
     block in pieces of ``piece_k`` keys (:func:`_fwd_piece`) and skips
-    the dead ones: ``live_pieces`` are those it computes."""
+    the dead ones: ``live_pieces`` are those it computes. ``bd``: under
+    the block-diffusion mask, whose live steps :func:`_bd_live` says
+    and whose index maps are walked as they stand; ``visited_pairs`` are
+    then the pairs of the tiles (the forward's pieces) the call
+    computes, of which the mask attends ``bh (L L + L B)``."""
     nq, nk = sq // block_q, sk // block_k
     live = fetched = 0
-    if kernel == "bwd_dkv":            # rows are k blocks, q blocks stream
+    if bd:
+        dkv = kernel == "bwd_dkv"
+        name = (_bd_live_q if dkv else _bd_live_k)(block_q, block_k, bd)
+        for row in range(nk if dkv else nq):
+            along = range(nq if dkv else nk)
+            live += sum(bool(_bd_live(*((t, row) if dkv else (row, t)),
+                                      block_q, block_k, bd)) for t in along)
+            fetched += len({name(row, t) for t in along})
+    elif kernel == "bwd_dkv":            # rows are k blocks, q blocks stream
         for j in range(nk):
             first = _first_live_q(j, block_q, block_k) if causal else 0
             last = min(nq - 1, _last_live_q(j, block_q, block_k, window)) \
@@ -614,11 +763,17 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0,
         out["window"] = window
     if kv_group > 1:
         out["kv_group"] = kv_group
+    if bd:
+        out.update(block_diffusion="%dx%d" % bd,
+                   visited_pairs=bh * live * block_q * block_k)
     if kernel == "fwd":
         piece_k = _fwd_piece(block_k)
         out.update(piece_k=piece_k, live_pieces=bh * sum(
-            not causal or bool(_piece_live(i, p, block_q, piece_k, window))
+            not (causal or bd)
+            or bool(_piece_live(i, p, block_q, piece_k, window, bd))
             for i in range(nq) for p in range(sk // piece_k)))
+        if bd:
+            out["visited_pairs"] = out["live_pieces"] * block_q * piece_k
     else:       # the row statistics the call is handed, and its tile's form
         out.update(stat_bytes=2 * bh * sq * 4,
                    tile="keys_major" if kernel == "bwd_dkv"
@@ -627,14 +782,15 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0,
 
 
 def _note_grid(kernel, q, k, block_q, block_k, causal, masked=False,
-               window=0):
+               window=0, bd=()):
     """One ``flash.grid`` instant per emitted call (flat operands ``q``
     and ``k``), at trace time; a masked call's says so, a windowed
-    call's says ``window=``, a grouped one's ``kv_group=``."""
+    call's says ``window=``, a grouped one's ``kv_group=``, one under
+    the block-diffusion mask ``block_diffusion=``."""
     if events.enabled():
         events.instant("flash.grid", **grid_steps(
             kernel, q.shape[0], q.shape[1], k.shape[1], block_q, block_k,
-            causal, window, q.shape[0] // k.shape[0]),
+            causal, window, q.shape[0] // k.shape[0], bd),
             **({"masked": True} if masked else {}))
 
 
@@ -648,14 +804,17 @@ def _note_grid(kernel, q, k, block_q, block_k, causal, masked=False,
 # three kernels cost 85 ms a layer on the chip's host, 6 s of set-up for
 # BERT-large's 24 layers (PERF.md section 6, PR 30).
 _STATIC = ("kv_len", "sm_scale", "causal", "block_q", "block_k",
-           "dropout_rate", "interpret", "heads", "window")
+           "dropout_rate", "interpret", "heads", "window", "bd")
 
 
-def _masked(mask, spec, window=0):
-    """What a mask or a window adds to a call: ``(kernel options,
-    in_specs, operands)``, nothing where ``mask`` is None and the window
-    0. A window is an option alone, never an operand."""
+def _masked(mask, spec, window=0, bd=()):
+    """What a mask, a window or the block-diffusion form adds to a call:
+    ``(kernel options, in_specs, operands)``, nothing where ``mask`` is
+    None, the window 0 and ``bd`` empty. A window and ``bd`` are options
+    alone, never operands."""
     opts = {"window": window} if window else {}
+    if bd:
+        opts["bd"] = bd
     if mask is None:
         return opts, [], ()
     return dict(opts, masked=True), [spec()], (mask,)
@@ -663,7 +822,7 @@ def _masked(mask, spec, window=0):
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
-              dropout_rate, interpret, mask=None, heads=1, window=0):
+              dropout_rate, interpret, mask=None, heads=1, window=0, bd=()):
     """q (bh, sq, d); k and v (bh // group, sk, .): a query head reads
     its group's row (:func:`_kv_row`). ``mask``: None or (bh // heads,
     sq, sk) int8."""
@@ -671,7 +830,7 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
     sk, dv = k.shape[1], v.shape[2]      # q.k over d, p.v over dv
     group = bh // k.shape[0]
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
-        _mask_spec, block_q, block_k, causal, heads), window)
+        _mask_spec, block_q, block_k, causal, heads), window, bd)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -680,8 +839,8 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
         kernel,
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[_SEED_SPEC, _q_spec(block_q, d),
-                  _k_spec(block_q, block_k, d, causal, window, group),
-                  _k_spec(block_q, block_k, dv, causal, window, group)]
+                  _k_spec(block_q, block_k, d, causal, window, group, bd),
+                  _k_spec(block_q, block_k, dv, causal, window, group, bd)]
         + mask_specs,
         out_specs=[_q_spec(block_q, dv), _stat_spec(block_q)],
         out_shape=[
@@ -702,7 +861,7 @@ def _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
                  causal, block_q, block_k, dropout_rate, interpret,
-                 mask=None, heads=1, window=0):
+                 mask=None, heads=1, window=0, bd=()):
     """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row;
     k and v (bh // group, sk, .) as the forward's; ``mask``: None or
     (bh // heads, sq, sk) int8."""
@@ -710,12 +869,12 @@ def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
     sk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
-        _mask_spec, block_q, block_k, causal, heads), window)
+        _mask_spec, block_q, block_k, causal, heads), window, bd)
     stat = _stat_spec(block_q)
     qs = _q_spec(block_q, d)
-    ks = _k_spec(block_q, block_k, d, causal, window, group)
+    ks = _k_spec(block_q, block_k, d, causal, window, group, bd)
     dos = _q_spec(block_q, dv)
-    vs = _k_spec(block_q, block_k, dv, causal, window, group)
+    vs = _k_spec(block_q, block_k, dv, causal, window, group, bd)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -737,7 +896,7 @@ def _bwd_dq_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
                   causal, block_q, block_k, dropout_rate, interpret,
-                  mask=None, heads=1, window=0):
+                  mask=None, heads=1, window=0, bd=()):
     """``lse`` and ``delta`` are (bh, 1, sq) float32: one value a row;
     k and v (bh // group, sk, .), and ``dk``, ``dv`` in their shapes:
     the grid walks the k/v heads, and a k block stays while its group's
@@ -747,13 +906,14 @@ def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
     sk, dv = k.shape[1], v.shape[2]
     group, nq = bh // k.shape[0], sq // block_q
     opts, mask_specs, mask_args = _masked(mask, functools.partial(
-        _dkv_mask_spec, block_q, block_k, causal, heads, group, nq), window)
+        _dkv_mask_spec, block_q, block_k, causal, heads, group, nq), window,
+        bd)
     if group > 1:
         opts = dict(opts, group=group, q_blocks=nq)
     qs2, ks2, stat2 = _dkv_specs(block_q, block_k, d, causal, window, group,
-                                 nq)
+                                 nq, bd)
     dos2, vs2, _ = _dkv_specs(block_q, block_k, dv, causal, window, group,
-                              nq)
+                              nq, bd)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
@@ -776,36 +936,37 @@ def _bwd_dkv_call(seed, q, k, v, do, lse, delta, kv_len, sm_scale,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
 def _flash(q, k, v, seed, kv_len, sm_scale, causal, block_q, block_k,
-           dq_blocks, dkv_blocks, dropout_rate, interpret, window):
+           dq_blocks, dkv_blocks, dropout_rate, interpret, window, bd):
     o, _ = _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
-                           block_k, dropout_rate, interpret, window=window)
+                           block_k, dropout_rate, interpret, window=window,
+                           bd=bd)
     return o
 
 
 def _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
                     block_k, dropout_rate, interpret, mask=None, heads=1,
-                    window=0):
+                    window=0, bd=()):
     _note_grid("fwd", q, k, block_q, block_k, causal, mask is not None,
-               window)
+               window, bd)
     return _fwd_call(q, k, v, seed, kv_len, sm_scale, causal, block_q,
                      block_k, dropout_rate, interpret, mask=mask,
-                     heads=heads, window=window)
+                     heads=heads, window=window, bd=bd)
 
 
 def _flash_fwd_rule(q, k, v, seed, kv_len, sm_scale, causal, block_q,
                     block_k, dq_blocks, dkv_blocks, dropout_rate,
-                    interpret, window):
+                    interpret, window, bd):
     o, lse = _noted_fwd_call(q, k, v, seed, kv_len, sm_scale, causal,
                              block_q, block_k, dropout_rate, interpret,
-                             window=window)
+                             window=window, bd=bd)
     return o, (q, k, v, seed, o, lse)
 
 
 def _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
                     dropout_rate, interpret, q, k, v, seed, o, lse, do,
-                    mask=None, heads=1, window=0):
+                    mask=None, heads=1, window=0, bd=()):
     """``(dq, dk, dv)`` from the forward's operands, output and
     log-sum-exp; ``mask``: the forward's, which the dkv call is handed
     transposed (an (sk, sq) int8 copy a batch row, made here)."""
@@ -814,14 +975,14 @@ def _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
     # one float32 a row, the rows along the lanes: nothing is replicated
     operands = (seed, q, k, v, do, lse[:, None, :], delta[:, None, :],
                 kv_len, sm_scale, causal)
-    _note_grid("bwd_dq", q, k, *dq_blocks, causal, masked, window)
+    _note_grid("bwd_dq", q, k, *dq_blocks, causal, masked, window, bd)
     dq = _bwd_dq_call(*operands, *dq_blocks, dropout_rate, interpret,
-                      mask=mask, heads=heads, window=window)
-    _note_grid("bwd_dkv", q, k, *dkv_blocks, causal, masked, window)
+                      mask=mask, heads=heads, window=window, bd=bd)
+    _note_grid("bwd_dkv", q, k, *dkv_blocks, causal, masked, window, bd)
     dk, dv = _bwd_dkv_call(
         *operands, *dkv_blocks, dropout_rate, interpret,
         mask=jnp.swapaxes(mask, 1, 2) if masked else None, heads=heads,
-        window=window)
+        window=window, bd=bd)
     return dq, dk, dv
 
 
@@ -831,11 +992,11 @@ def _no_cotangent(x):
 
 def _flash_bwd_rule(kv_len, sm_scale, causal, fwd_block_q, fwd_block_k,
                     dq_blocks, dkv_blocks, dropout_rate, interpret, window,
-                    res, do):
+                    bd, res, do):
     q, k, v, seed, o, lse = res
     return _backward_calls(kv_len, sm_scale, causal, dq_blocks, dkv_blocks,
                            dropout_rate, interpret, q, k, v, seed, o, lse,
-                           do, window=window) + (_no_cotangent(seed),)
+                           do, window=window, bd=bd) + (_no_cotangent(seed),)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1056,7 +1217,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     bwd_block_q: Optional[int] = None,
                     bwd_block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    mesh=None, spec=None, mask=None, window: int = 0):
+                    mesh=None, spec=None, mask=None, window: int = 0,
+                    block_diffusion: tuple = ()):
     """Tiled flash attention. q: (b, h, sq, d); k: (b, kvh, sk, d); v:
     (b, kvh, sk, dv), and the output (b, h, sq, dv). ``dv`` may differ
     from ``d`` (latent attention: q.k over 192, p.v over 128); the score
@@ -1088,6 +1250,21 @@ def flash_attention(q, k, v, *, causal: bool = False,
     and its index map names the band's nearest block, so nothing is
     copied for it (docs/kernels.md). A window of at least the keys is
     the causal call. The tiles are the causal call's.
+
+    ``block_diffusion``: ``()``, or ``(L, B)`` for self-attention over
+    ``2 L`` positions, a noised copy of ``L`` tokens and then the clean
+    one, in blocks of ``B`` tokens (``B`` divides ``L``): with ``b(i) =
+    (i mod L) // B`` a noised query sees the noised keys of its own
+    block and the clean keys of earlier blocks, a clean query the clean
+    keys of its own and earlier blocks, and no clean query a noised key:
+    ``L L + L B`` of the ``4 L L`` pairs. Not ``causal``, no window, mask
+    or dropout beside it. Index arithmetic like the window: the mask
+    from the tile's positions (:func:`_bd_mask`), the dead tiles (the
+    clean x noised quadrant, the clean x clean upper triangle, all but
+    the diagonal tiles of noised x noised, the noised x clean tiles of
+    later blocks) skipped and named as live neighbours. The tiles are
+    derived for ``L`` positions, so that none straddles the halves; ``L``
+    has to be a multiple of 128 (docs/kernels.md).
 
     Pads the key length to a multiple of 128 and the query length to a
     multiple of 8 (of 128 from 512 positions on), tiles them by divisors,
@@ -1133,6 +1310,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise ValueError(f"window={window} wants causal=True and >= 0")
     if window >= k.shape[2]:           # the band is the whole triangle
         window = 0
+    bd = tuple(int(n) for n in block_diffusion)
+    if bd:
+        length, block = bd
+        if causal or window or mask is not None or dropout_rate > 0.0:
+            raise NotImplementedError(
+                "the block-diffusion mask is built with no causal edge, "
+                "window, mask operand or dropout beside it")
+        if q.shape[2] != 2 * length or k.shape[2] != 2 * length \
+                or length % 128 or block <= 0 or length % block:
+            raise NotImplementedError(
+                f"block_diffusion={bd} wants {2 * length} queries and keys"
+                f" (got {q.shape[2]} and {k.shape[2]}), a length that is "
+                f"a multiple of 128 and of the block")
     if mask is not None:
         if window:
             raise NotImplementedError(
@@ -1156,13 +1346,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
             q, k, v, mesh, spec, causal=causal, sm_scale=sm_scale,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
             block_q=block_q, block_k=block_k, bwd_block_q=bwd_block_q,
-            bwd_block_k=bwd_block_k, interpret=interpret, window=window)
+            bwd_block_k=bwd_block_k, interpret=interpret, window=window,
+            block_diffusion=bd)
     (qp, kp, vp), seed, cut, plan = _prepare(
         q, k, v, False, causal, sm_scale, dropout_rate, dropout_seed,
-        block_q, block_k, bwd_block_q, bwd_block_k)
+        block_q, block_k, bwd_block_q, bwd_block_k,
+        tiled=bd[0] if bd else None)
+    if bd and any(bd[0] % n for blocks in plan[3:] for n in blocks):
+        raise NotImplementedError(
+            f"blocks {plan[3:]} do not tile the {bd[0]} positions of a "
+            f"half of block_diffusion={bd}")
     o = _flash(qp, kp, vp, seed, plan.kv_len, plan.sm_scale, causal,
                *plan.fwd_blocks, plan.dq_blocks, plan.dkv_blocks,
-               float(dropout_rate), interpret, window)
+               float(dropout_rate), interpret, window, bd)
     return cut(o)
 
 
@@ -1178,13 +1374,14 @@ class _Plan(NamedTuple):
 
 
 def _prepare(q, k, v, masked, causal, sm_scale, dropout_rate, dropout_seed,
-             block_q, block_k, bwd_block_q, bwd_block_k):
+             block_q, block_k, bwd_block_q, bwd_block_k, tiled=None):
     """``((qp, kp, vp), seed, cut, plan)``: the operands padded and flat,
     q (batch * heads, sq, d), k and v (batch * kv heads, sk, .) at their
     own head count (the calls take the group from the two); the dropout
     seed as the kernels read it; ``cut``, which gives a flat padded
     output its (b, h, sq, dv) form back; and the :class:`_Plan`, its
-    blocks derived for a ``masked`` call or an unmasked one."""
+    blocks derived for a ``masked`` call or an unmasked one, as divisors
+    of the padded lengths or, ``tiled`` given, of that many positions."""
     b, h, sq, d = q.shape
     kvh, sk, dv = k.shape[1], k.shape[2], v.shape[3]
     if h % kvh or v.shape[1] != kvh:
@@ -1211,11 +1408,12 @@ def _prepare(q, k, v, masked, causal, sm_scale, dropout_rate, dropout_seed,
     sq_p, d_p = qp.shape[2], qp.shape[3]
     sk_p, dv_p = kp.shape[2], vp.shape[3]
     # forward blocks from the shapes; an explicit one wins
-    derived = fwd_tiles(sq_p, sk_p, d_p, q.dtype, dropout_rate > 0.0, dv_p,
-                        masked)
+    derived = fwd_tiles(tiled or sq_p, tiled or sk_p, d_p, q.dtype,
+                        dropout_rate > 0.0, dv_p, masked)
     block_q, block_k = sq_to or derived[0], sk_to or derived[1]
-    dq_blocks, dkv_blocks = bwd_tiles(sq_p, sk_p, d_p, q.dtype,
-                                      dropout_rate > 0.0, dv_p, masked)
+    dq_blocks, dkv_blocks = bwd_tiles(tiled or sq_p, tiled or sk_p, d_p,
+                                      q.dtype, dropout_rate > 0.0, dv_p,
+                                      masked)
     # an explicit backward block wins, for both kernels
     if bwd_block_q is not None:
         bq = _explicit_block(bwd_block_q, block_q)
@@ -1384,7 +1582,7 @@ def head_mean_tiles(sq, sk, d, dtype):
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=tuple(
-    n for n in _STATIC if n not in ("dropout_rate", "window")))
+    n for n in _STATIC if n not in ("dropout_rate", "window", "bd")))
 def _head_mean_call(q, k, lse, mask_t, kv_len, sm_scale, causal, block_q,
                     block_k, interpret, heads):
     """q (b * heads, s, d), k (b * kv heads, s, d); lse (b * heads, 1,
@@ -1490,6 +1688,35 @@ def _flash_sharded(q, k, v, mesh, spec, *, dropout_rate, dropout_seed,
     # check_vma off: pallas_call outputs carry no varying-axes info
     return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 3 + (P(),),
                          out_specs=spec, check_vma=False)(q, k, v, seed)
+
+
+def block_diffusion_mask(length: int, block: int) -> np.ndarray:
+    """The (2 L, 2 L) boolean table of the block-diffusion mask, queries
+    by keys, written out from the halves and the blocks: what the kernels
+    draw a tile at a time (:func:`flash_attention`'s
+    ``block_diffusion``), for the paths that build a mask and for tests."""
+    i = np.arange(2 * length)
+    clean, blk = i >= length, (i % length) // block
+    q_clean, k_clean = clean[:, None], clean[None, :]
+    qb, kb = blk[:, None], blk[None, :]
+    return np.where(q_clean, k_clean & (kb <= qb),
+                    np.where(k_clean, kb < qb, kb == qb))
+
+
+def block_diffusion_visited(bh: int, length: int, block: int, d: int,
+                            dtype, kv_group: int = 1) -> dict:
+    """``{"fwd" | "bwd_dq" | "bwd_dkv": pairs}``: the pairs of the tiles
+    (the forward's pieces) each kernel's grid computes for a call of
+    ``bh`` (batch x query head)s under ``block_diffusion=(length,
+    block)`` at head size ``d``, at the tiles the call derives from its
+    shapes; the mask attends ``bh (L L + L B)`` of them."""
+    d = -(-d // 64) * 64
+    tiles = dict(zip(("bwd_dq", "bwd_dkv"),
+                     bwd_tiles(length, length, d, dtype, False)),
+                 fwd=fwd_tiles(length, length, d, dtype, False))
+    return {k: grid_steps(k, bh, 2 * length, 2 * length, *tiles[k], False,
+                          0, kv_group, (length, block))["visited_pairs"]
+            for k in ("fwd", "bwd_dq", "bwd_dkv")}
 
 
 def mha_reference(q, k, v, *, causal: bool = False,

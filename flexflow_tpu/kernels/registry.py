@@ -41,6 +41,9 @@ def _attn_flash(ctx: Dict[str, Any]) -> Optional[str]:
             ctx.get("q_len", 0) != ctx.get("kv_len", 0):
         return "flash kernel does not mask causal cross-attention " \
                "(q_len != kv_len)"
+    if ctx.get("block_diffusion_block", 0) and ctx.get("q_len", 0) % 256:
+        return "the flash kernels draw the block-diffusion mask in tiles " \
+               "of 128 that divide a half of the sequence"
     return None      # a sliding window is the kernels' own band arithmetic
 
 
@@ -64,6 +67,8 @@ def _attn_ring(ctx: Dict[str, Any]) -> Optional[str]:
                f"seq-axis degree {deg}"
     if ctx.get("sliding_window", 0):
         return "ring attention has no sliding-window mask support"
+    if ctx.get("block_diffusion_block", 0):
+        return "ring attention has no block-diffusion mask support"
     if ctx.get("dropout", 0.0):
         return "ring attention has no in-kernel dropout"
     return None
@@ -118,6 +123,8 @@ def attention_ctx(params: Dict[str, Any], q_len: int, kv_len: int,
         "seq_degree": int(seq_degree),
         "latent": bool(latent),
         "indexer": bool(params.get("indexer_heads")),
+        "block_diffusion_block": int(
+            params.get("block_diffusion_block", 0) or 0),
     }
 
 

@@ -237,6 +237,7 @@ class FFModel:
                             differential: Optional[dict] = None,
                             kv_out: bool = False,
                             kv_projected: bool = False,
+                            block_diffusion_block: int = 0,
                             name: Optional[str] = None
                             ) -> Union[Tensor, Tuple[Tensor, ...]]:
         """Multi-head attention (``ops.nn_ops.MultiHeadAttentionOp``):
@@ -278,7 +279,20 @@ class FFModel:
         (``kv_out``: the result is ``(output, k, v)``, k and v (batch,
         seq, kv heads, head size) after the bias) and may take another
         layer's in their place (``kv_projected``: ``key`` and ``value``
-        are such tensors, and the layer has no ``wk``, ``wv``)."""
+        are such tensors, and the layer has no ``wk``, ``wv``).
+        ``block_diffusion_block`` (``B`` > 0): self-attention over ``2
+        L`` positions, a noised copy of ``L`` tokens and then the clean
+        one, under the block-diffusion mask (a noised query sees the
+        noised keys of its own block of ``B`` and the clean keys of
+        earlier blocks, a clean query the clean keys of its own and
+        earlier blocks; ``kernels.flash_attention``'s
+        ``block_diffusion``, drawn inside the flash kernels where ``L``
+        is a multiple of 128, an explicit mask elsewhere). Not
+        ``causal``; ``rope`` is allowed, and ``positions`` may then be
+        (batch, L): both halves turn by them, so a noised token and its
+        clean copy turn alike. Not built beside a window, an indexer,
+        differential attention, an output gate, dropout, the ring path
+        or a key/value cache."""
         params = {"embed_dim": embed_dim, "num_heads": num_heads,
                   "kdim": kdim, "vdim": vdim, "dropout": dropout,
                   "bias": bias, "add_bias_kv": add_bias_kv,
@@ -379,6 +393,21 @@ class FFModel:
             params["kv_source"] = getattr(key.owner_layer, "name", "input")
         if kv_out:
             params["kv_out"] = True
+        if block_diffusion_block:
+            unbuilt = [k for k, v in (
+                ("causal", causal), ("sliding_window", sliding_window),
+                ("indexer", indexer), ("differential", differential),
+                ("output_gate", output_gate), ("dropout", dropout)) if v]
+            length, odd = divmod(query.shape[1], 2)
+            if unbuilt or block_diffusion_block < 0 or odd \
+                    or length % block_diffusion_block \
+                    or key is not query or value is not query:
+                raise ValueError(
+                    f"block_diffusion_block {block_diffusion_block}: "
+                    f"self-attention over 2 L positions, L a multiple of "
+                    f"the block (got {query.shape[1]}); not built beside "
+                    f"{unbuilt}")
+            params["block_diffusion_block"] = int(block_diffusion_block)
         inputs = [query, key, value]
         if positions is not None:
             # (batch, seq) int32: what the rotary embedding turns by
@@ -571,6 +600,7 @@ class FFModel:
                        scoring: str = "sigmoid",
                        shared_gate: bool = False,
                        choice_bias: bool = True,
+                       router_repeats: int = 1,
                        name: Optional[str] = None) -> Tensor:
         """One sparse, dropless mixture-of-experts feed-forward layer
         (``ops.moe_ops.RoutedExpertsOp``): ``scoring`` (``"sigmoid"``
@@ -585,7 +615,12 @@ class FFModel:
         rows_multiplied``). ``shared_gate``: the shared expert's output
         times ``sigmoid(x . w_s)``, one scalar a token from a weight of
         its own. ``choice_bias`` false: the op has no ``bias`` weight
-        (softmax scores only, whose choice reads none)."""
+        (softmax scores only, whose choice reads none).
+        ``router_repeats`` r: the router is INITIALISED with its first
+        ``num_experts / r`` columns drawn and repeated r times, so that
+        at those weights every token scores alike in each of r shares
+        of the experts (its top ``top_k`` lie evenly over the shares
+        where r divides ``top_k``); the op's mathematics is unchanged."""
         held = num_experts if experts_held is None else experts_held
         if not 0 <= first_held <= first_held + held <= num_experts:
             raise ValueError(
@@ -611,6 +646,11 @@ class FFModel:
                 raise ValueError(f"scores by {scoring!r} read a choice "
                                  f"bias")
             more["choice_bias"] = False
+        if router_repeats != 1:
+            if router_repeats < 1 or num_experts % router_repeats:
+                raise ValueError(f"router_repeats {router_repeats} of "
+                                 f"{num_experts} experts")
+            more["router_repeats"] = int(router_repeats)
         return self._unary(OperatorType.OP_ROUTED_EXPERTS, input, name,
                            num_experts=num_experts, top_k=top_k,
                            expert_dim=expert_dim, shared_dim=shared_dim,
@@ -628,6 +668,34 @@ class FFModel:
                                [logits, ids],
                                {"offset": int(offset),
                                 "weight": float(weight)}, name).outputs[0]
+
+    def block_diffusion_noise(self, ids: Tensor, block_length: int,
+                              mask_token_id: int, t_min: float = 1e-3,
+                              eval_noise_seed: int = 0,
+                              name: Optional[str] = None
+                              ) -> Tuple[Tensor, Tensor]:
+        """``(z_ids, weights)`` of a block-diffusion training step
+        (``ops.nn_ops.BlockDiffusionNoiseOp``): (batch, 2 L) ids, a copy
+        of ``ids`` with tokens replaced by ``mask_token_id`` (each with
+        its block's probability ``t``) and then ``ids`` as they are, and
+        each token's loss weight ``masked / t``, (batch, L). The draw is
+        the step's in training and ``eval_noise_seed``'s otherwise."""
+        if block_length < 1 or not 0.0 < t_min <= 1.0:
+            raise ValueError(f"blocks of {block_length} tokens, t_min "
+                             f"{t_min}")
+        return tuple(self._add_layer(
+            OperatorType.OP_BLOCK_DIFFUSION_NOISE, [ids],
+            {"block_length": int(block_length),
+             "mask_token_id": int(mask_token_id), "t_min": float(t_min),
+             "eval_noise_seed": int(eval_noise_seed)}, name).outputs)
+
+    def set_loss_weights(self, weights: Tensor) -> None:
+        """Name the tensor whose entries weigh the rows of the training
+        loss: with it the sparse cross-entropy is ``sum(w * nll) /
+        rows`` in place of the mean (``runtime.losses.compute_loss``);
+        metrics that count rows weigh nothing. ``weights`` has the
+        output's shape less its last axis. Call before ``compile``."""
+        self._loss_weights_tensor = weights
 
     def batch_norm(self, input: Tensor, relu: bool = True,
                    eps: float = 1e-5, momentum: float = 0.1,
@@ -1140,10 +1208,11 @@ class FFModel:
                 program = GraphProgram(exec_layers,
                                        self.graph_inputs + self.const_inputs,
                                        exec_outputs)
-                self.executor = Executor(program, self.config, self.dmesh,
-                                         self.strategy, self.optimizer,
-                                         self.loss_type, self.metrics,
-                                         seed=self.config.seed)
+                self.executor = Executor(
+                    program, self.config, self.dmesh, self.strategy,
+                    self.optimizer, self.loss_type, self.metrics,
+                    seed=self.config.seed,
+                    loss_weights=getattr(self, "_loss_weights_tensor", None))
             # searched data movement: one reshard planner per strategy plans
             # every layout transition (bank boundaries, pipeline-region
             # entry/exit, layout-op output constraints) with scored explicit
@@ -1786,6 +1855,7 @@ class FFModel:
                 raise ValueError(
                     f"prompt_len {prompt_len} + max_new_tokens "
                     f"{max_new_tokens} exceeds the sequence length {L}")
+        self._refuse_block_diffusion_decode()
         names = {t.name for t in self.graph_inputs}
         fixed = {k: jnp.asarray(v)
                  for k, v in (extra_inputs or {}).items()}
@@ -1857,6 +1927,19 @@ class FFModel:
         return bool(mha) and all(l.params.get("causal", False)
                                  and not l.params.get("differential")
                                  for l in mha)
+
+    def _refuse_block_diffusion_decode(self) -> None:
+        """A model trained under the block-diffusion mask yields a BLOCK
+        a decoding step and commits its cache a block at a time; neither
+        ``generate`` path does that, and next-token sampling from such a
+        model would be another model's text."""
+        if any(l.params.get("block_diffusion_block")
+               or l.op_type == OperatorType.OP_BLOCK_DIFFUSION_NOISE
+               for l in self.executor.program.layers):
+            raise NotImplementedError(
+                "generate() cannot decode a block-diffusion model yet: a "
+                "step has to yield a block and the key/value cache commit "
+                "a block at a time (ROADMAP.md, reach queue)")
 
     def _generate_kv(self, ids0, prompt_len, max_new_tokens, temperature,
                      seed, eos_token_id, top_k=0, top_p=1.0):

@@ -1444,6 +1444,89 @@ class Qwen3NextRankConfig(HybridConvMoEConfig):
 
 
 @dataclasses.dataclass
+class SDARRankConfig(HybridConvMoEConfig):
+    """What ONE chip holds of SDAR-30B-A3B-Chat (``model_type:
+    sdar_moe``, 30B parameters, 3B a token) where 8 chips share each
+    layer (the benchmark's ``sdar_30b_a3b``), on the TRAINING path of a
+    block-diffusion model (SDAR, arXiv:2510.06303): the decoder is the
+    Qwen3-MoE one (grouped-query attention, 32 query heads on 4 key/value
+    heads of 128, q/k norms before the rotary embedding; 128
+    softmax-routed experts of 768, 8 a token, gates normalised, no
+    shared expert, no choice bias, every layer an expert layer), and a
+    step runs it over ``2 L`` positions: the batch's ``L`` tokens with
+    some replaced by ``mask_token_id`` (a block of ``block_length``
+    tokens draws ``t = t_min + (1 - t_min) u`` and masks each of its
+    tokens with probability ``t``), then the clean tokens, both halves
+    at positions ``0 .. L - 1``, under the block-diffusion mask
+    (``FFModel.multihead_attention``'s ``block_diffusion_block``). The
+    head reads the noised half and the loss is ``(1 / L) sum_i w_i
+    nll_i`` with ``w_i = masked_i / t``. Here: experts 0 to 15, one of
+    eight slices of the vocabulary and published layers 0 to 5 (the rest
+    lie on further chips as pipeline stages); every width as published.
+
+    The fields after the parent's carry ``config.json``'s keys by their
+    names (``rms_norm_eps`` and ``rope_theta`` are copied over the
+    parent's names for them); the last four are the training recipe's,
+    which ``config.json`` has no key for."""
+    vocab_size: int = 18992
+    num_hidden_layers: int = 6
+    layer_types: list | None = None      # every layer the one kind
+    num_dense_layers: int = 0
+    num_key_value_heads: int = 4
+    head_dim: int | None = 128
+    intermediate_size: int = 6144        # published; no dense layer reads it
+    moe_intermediate_size: int = 768
+    num_experts: int = 16
+    num_experts_published: int | None = 128
+    num_experts_per_tok: int = 8
+    use_expert_bias: bool = False
+    # the keys the parent class does not have
+    decoder_sparse_step: int = 1
+    mlp_only_layers: list = dataclasses.field(default_factory=list)
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    scoring_func: str = "softmax"        # not in config.json: the family's
+    # not in config.json: the block-diffusion training recipe
+    block_length: int = 4
+    mask_token_id: int = 18991           # the slice's last id
+    t_min: float = 1e-3
+    eval_noise_seed: int = 23            # sum(w) / L = 0.947 at 1 x 4096
+    # how the routers are DRAWN: the columns of one share of the experts,
+    # repeated for each share (``FFModel.routed_experts``), so that the
+    # rows this share is sent do not hang on the seed's draw of which
+    # experts the mask id's ~L/2 alike positions choose; 1: a plain draw
+    router_repeats: int = 8
+
+    def __post_init__(self):
+        if self.decoder_sparse_step != 1 or self.mlp_only_layers:
+            raise ValueError("a layer without experts is not built for "
+                             "this family")
+        kinds = ["block_diffusion_attention"] * self.num_hidden_layers
+        if self.layer_types is None:
+            self.layer_types = kinds
+        elif list(self.layer_types) != kinds:
+            raise ValueError(f"layer_types {self.layer_types}: every "
+                             f"layer is 'block_diffusion_attention'")
+        if not 0 <= self.mask_token_id < self.vocab_size:
+            raise ValueError(f"mask_token_id {self.mask_token_id} is no id "
+                             f"of the {self.vocab_size} held")
+        self.norm_eps = self.rms_norm_eps
+        self.rope_parameters = {"rope_theta": self.rope_theta,
+                                "rope_type": "default"}
+
+    @classmethod
+    def tiny(cls):
+        """3 equal layers, 4 heads on 2 kv heads of 16, 16 experts top-4
+        of width 32, all held, blocks of 4 tokens: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_hidden_layers=3,
+                   num_attention_heads=4, num_key_value_heads=2,
+                   head_dim=16, rope_theta=10000.0,
+                   moe_intermediate_size=32, num_experts=16,
+                   num_experts_published=None, num_experts_per_tok=4,
+                   mask_token_id=95, router_repeats=1)
+
+
+@dataclasses.dataclass
 class Phi4FlashRankConfig(HybridConvMoEConfig):
     """What ONE chip holds of Phi-4-mini-flash-reasoning (``model_type:
     phi4flash``, 3.8B parameters, dense; the SambaY decoder-hybrid-decoder
@@ -1556,6 +1639,13 @@ class Phi4FlashRankConfig(HybridConvMoEConfig):
                    mamba_chunk_size=16)
 
 
+def _rolled_by_one(ff: FFModel, x, rows: int, name: str):
+    """Rows ``1 .. rows - 1`` and then row 0 of ``x``'s first ``rows``
+    along axis 1: ``out[i] = x[(i + 1) % rows]``."""
+    return ff.concat([ff.slice_tensor(x, [1], [rows], [1]),
+                      ff.slice_tensor(x, [0], [1], [1])], axis=1, name=name)
+
+
 def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                           cfg: HybridConvMoEConfig | None = None):
     """Causal LM of :class:`HybridConvMoEConfig`: inputs ``[ids, pos]``,
@@ -1585,7 +1675,12 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     ``"gated_memory"``, ``"diff_cross_attention"``) are a selective-scan
     mixer, differential attention and a gated memory unit of plain ops;
     two of them read what an earlier layer handed on, and that class's
-    norms are LayerNorms with a bias. ``pos`` is what the
+    norms are LayerNorms with a bias. A ``"block_diffusion_attention"``
+    layer (:class:`SDARRankConfig`) attends under the block-diffusion
+    mask; that class's graph opens with the noising op, runs every layer
+    over ``2 seq_len`` positions (the noised copy, then the clean one),
+    reads the head off the noised half and weighs the loss's rows.
+    ``pos`` is what the
     attention layers' rotary embedding turns by (a layout in which no
     layer turns by it still declares it, and ``fit`` drops its array).
 
@@ -1597,12 +1692,14 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     if len(kinds) != cfg.num_hidden_layers \
             or set(kinds) - {"conv", "full_attention", "sparse_attention",
                              "sliding_attention", "mamba", "attention",
-                             "linear_attention"} - _SAMBAY_KINDS:
+                             "linear_attention",
+                             "block_diffusion_attention"} - _SAMBAY_KINDS:
         raise ValueError(
             f"layer_types must name {cfg.num_hidden_layers} layers, each "
             f"'conv', 'full_attention', 'sparse_attention', "
             f"'sliding_attention', 'mamba', 'attention', "
-            f"'linear_attention' or one of {sorted(_SAMBAY_KINDS)}; got "
+            f"'linear_attention', 'block_diffusion_attention' or one of "
+            f"{sorted(_SAMBAY_KINDS)}; got "
             f"{len(kinds)}: {sorted(set(kinds))}")
     if cfg.conv_bias or not cfg.norm_topk_prob:
         raise ValueError("conv_bias and gates that are not normalised "
@@ -1664,12 +1761,31 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     # the scalars of a subclass (``GraniteHybridRankConfig``): absent,
     # the graph has no node for them
     residual_scale = getattr(cfg, "residual_multiplier", None)
+    diffusion = "block_diffusion_attention" in kinds
+    if diffusion and (set(kinds) != {"block_diffusion_attention"}
+                      or not hasattr(cfg, "block_length")):
+        raise ValueError("'block_diffusion_attention' layers need the "
+                         "configuration's block_length, mask_token_id, "
+                         "t_min and eval_noise_seed, and stand alone")
+    if diffusion:           # softmax scores read no choice bias: no weight
+        experts.update(choice_bias=cfg.use_expert_bias,
+                       router_repeats=cfg.router_repeats)
     ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
     pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids",
                            may_be_unread=not set(kinds) - {"mamba",
                                                            "attention"}
                            - _SAMBAY_KINDS)
-    h = ff.embedding(ids, cfg.vocab_size, hid, name="embed_tokens")
+    read_ids = ids
+    if diffusion:
+        # the decoder reads [noised ; clean], 2 s positions; the runner's
+        # labels are the NEXT token's, so the loss is handed the head's
+        # rows and their weights rolled by one: out[i] = P[(i + 1) % s]
+        read_ids, loss_weights = ff.block_diffusion_noise(
+            ids, cfg.block_length, cfg.mask_token_id, cfg.t_min,
+            cfg.eval_noise_seed, name="noise")
+        ff.set_loss_weights(_rolled_by_one(ff, loss_weights, s,
+                                           "loss_weights"))
+    h = ff.embedding(read_ids, cfg.vocab_size, hid, name="embed_tokens")
     if getattr(cfg, "mup_enabled", False):
         h = ff.scalar_multiply(h, math.sqrt(hid), name="embed_scale")
     if hasattr(cfg, "embedding_multiplier"):
@@ -1743,6 +1859,15 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                 cfg.linear_conv_kernel_dim, eps=cfg.norm_eps,
                 num_key_heads=cfg.linear_num_key_heads, decay="head",
                 name=f"linear_attn_{i}")
+        elif kind == "block_diffusion_attention":
+            # both halves turn by the one ``pos``; not causal
+            op = ff.multihead_attention(
+                x, x, x, hid, heads, kdim=heads * head_dim,
+                vdim=heads * head_dim, bias=False, rope=True,
+                rope_theta=cfg.rope_parameters["rope_theta"],
+                num_kv_heads=cfg.num_key_value_heads, qk_norm=True,
+                qk_norm_eps=cfg.norm_eps, positions=pos,
+                block_diffusion_block=cfg.block_length, name=f"attn_{i}")
         elif kind == "attention":
             # no rotary embedding, no q/k norm, the model's own scale
             op = ff.multihead_attention(
@@ -1790,6 +1915,9 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
         if sandwich:
             y = norm(y, f"post_ffn_norm_{i}")
         h = ff.add(h, scaled(y, f"ffn_scale_{i}"), name=f"ffn_res_{i}")
+    if diffusion:
+        # the head reads the noised half, rolled as the weights are
+        h = _rolled_by_one(ff, h, s, "noised_rows")
     logits = ff.dense(norm(h, "final_norm"), cfg.vocab_size,
                       use_bias=False, name="lm_head")
     if hasattr(cfg, "logits_scaling"):

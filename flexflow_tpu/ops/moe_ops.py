@@ -454,7 +454,12 @@ class RoutedExpertsOp(OpDef):
         n, held = params["num_experts"], params["experts_held"]
         f, fs = params["expert_dim"], params["shared_dim"]
         up, down = {"fans": (e, f)}, {"fans": (f, e)}   # fans per expert
-        ws = [WeightSpec("wg", (e, n), dt)]
+        # ``router_repeats`` r: the router's first n / r columns are drawn
+        # and repeated r times, so a token's scores are alike in every
+        # share of n / r experts
+        r = params.get("router_repeats", 1)
+        ws = [WeightSpec("wg", (e, n), dt,
+                         init_args={"column_repeats": r} if r > 1 else {})]
         if params.get("choice_bias", True):
             # drawn once; corrects the choice, never trained
             ws.append(WeightSpec("bias", (n,), dt, InitializerType.NORMAL,
@@ -510,7 +515,9 @@ class RoutedExpertsOp(OpDef):
                            rows_multiplied=budget,
                            token_sum="kernel" if kernel else "plain",
                            **({"shared_gate": True}
-                              if "ws_scalar" in weights else {}))
+                              if "ws_scalar" in weights else {}),
+                           **({"router_repeats": params["router_repeats"]}
+                              if "router_repeats" in params else {}))
             if kernel:
                 # ``_chunk`` is traced once a shape, so its calls are
                 # noted here, where the layer has a name: the forward's

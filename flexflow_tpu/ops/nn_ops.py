@@ -621,6 +621,12 @@ class MultiHeadAttentionOp(OpDef):
             q_len, kv_len, head_dim, v_dim, dropout)
 
     @staticmethod
+    def _bd_kernels_tile(params, seq: int) -> bool:
+        """No block-diffusion mask, or one the flash kernels draw: their
+        tiles are multiples of 128 that divide a half of the sequence."""
+        return not params.get("block_diffusion_block") or seq % 256 == 0
+
+    @staticmethod
     def _note_impl(ctx, name: str, impl: str) -> None:
         """Record which implementation this trace emitted for the full
         (non-KV) forward: ``Executor.resolved_attention_impls`` is what
@@ -676,7 +682,8 @@ class MultiHeadAttentionOp(OpDef):
         if not (params.get("qk_norm", False) and params.get("rope", False)) \
                 or params.get("rotary_dim") is not None \
                 or getattr(ctx, "kv_mode", None) is not None \
-                or not params.get("causal", False) \
+                or not (params.get("causal", False)
+                        or params.get("block_diffusion_block")) \
                 or qh.shape[1] != kh.shape[1] or qh.shape[2] % kh.shape[2]:
             return False
         mesh = getattr(ctx, "mesh", None)
@@ -691,7 +698,8 @@ class MultiHeadAttentionOp(OpDef):
                 and nrk.takes_kernel(s, kv, d, 1, mdt)
                 and self._flash_enabled(
                     impl, s, s, d, vh.shape[-1], rate, causal=True,
-                    window=params.get("sliding_window", 0)))
+                    window=params.get("sliding_window", 0))
+                and self._bd_kernels_tile(params, s))
 
     def emit(self, params, inputs, weights, ctx, name):
         # an optional fourth input: (B, L) int32 positions that the
@@ -723,6 +731,20 @@ class MultiHeadAttentionOp(OpDef):
             gate = proj(q, weights["wg"], None) \
                 if params.get("output_gate", False) else None
         window = params.get("sliding_window", 0)
+        bd_block = params.get("block_diffusion_block", 0)
+        if bd_block:
+            unbuilt = [k for k in ("causal", "sliding_window",
+                                   "indexer_heads", "differential",
+                                   "output_gate", "dropout", "kv_out",
+                                   "kv_projected") if params.get(k)]
+            if unbuilt or self._impl_for(ctx, name) == "ring" \
+                    or getattr(ctx, "kv_mode", None) is not None \
+                    or qh.shape[1] != kh.shape[1] or qh.shape[1] % 2:
+                raise ValueError(
+                    f"{name}: the block-diffusion mask is self-attention "
+                    f"over 2 L positions on the flash and XLA paths of the "
+                    f"training and eval forward; not built beside "
+                    f"{unbuilt or 'the ring path or a key/value cache'}")
         if params.get("differential"):
             return self._emit_differential(params, weights, ctx, name, qh,
                                            kh, vh, mdt, cdt)
@@ -789,7 +811,7 @@ class MultiHeadAttentionOp(OpDef):
                 # half-split rotate) — positions are absolute indices, so
                 # the single decode token rotates at kv_index and the cache
                 # stores already-rotated keys
-                if not causal:
+                if not (causal or bd_block):
                     raise ValueError(
                         "rope is only supported for causal attention")
                 if qh.shape[1] != kh.shape[1]:
@@ -804,6 +826,9 @@ class MultiHeadAttentionOp(OpDef):
                     pos = kvi[:, None] if kvi.ndim else kvi[None]
                 elif positions:
                     pos = positions[0]
+                    if bd_block and pos.shape[1] * 2 == qh.shape[1]:
+                        # the noised half and the clean one turn alike
+                        pos = jnp.concatenate([pos, pos], axis=1)
                 else:
                     pos = jnp.arange(qh.shape[1], dtype=jnp.int32)
                 if fused:
@@ -874,7 +899,8 @@ class MultiHeadAttentionOp(OpDef):
         flash = not ring and (fused or (
             (not window or kv_mode is None) and self._flash_enabled(
                 impl, qh.shape[1], kh.shape[1], qh.shape[-1], vh.shape[-1],
-                rate, causal=causal, window=window)))
+                rate, causal=causal, window=window)
+            and self._bd_kernels_tile(params, qh.shape[1])))
         mesh, spec = self._kernel_shard_spec(ctx, qh.shape[0], heads) \
             if flash else (None, None)
         # GQA: the flash kernels read the kv heads in place (a query
@@ -905,6 +931,10 @@ class MultiHeadAttentionOp(OpDef):
             ctx.count("attn.window_pairs",
                       jnp.float32(b_ * (w_ * s_ - w_ * (w_ - 1) / 2)))
             ctx.count("attn.causal_pairs", jnp.float32(causal_pairs))
+        if bd_block:
+            self._note_block_diffusion(ctx, name, q.shape[0],
+                                       q.shape[1] // 2, bd_block, heads,
+                                       vh.shape[2], qh.shape[-1], flash, mdt)
         if flash:
             # Pallas flash kernel ((b,h,s,d) layout); dropout on the
             # probabilities is counter-based and in-kernel, compiled on
@@ -931,6 +961,8 @@ class MultiHeadAttentionOp(OpDef):
                     dropout_rate=rate, dropout_seed=seed,
                     mesh=mesh, spec=spec,
                     **({"window": window} if window else {}),
+                    **({"block_diffusion": (q.shape[1] // 2, bd_block)}
+                       if bd_block else {}),
                     **({} if sm_scale is None else {"sm_scale": sm_scale}))
             ctxv = jnp.swapaxes(o, 1, 2).astype(jnp.float32)
             return self._project_out(ctxv, gate, weights, ctx, mdt, cdt)
@@ -952,6 +984,11 @@ class MultiHeadAttentionOp(OpDef):
                 # last `window` positions only
                 mask = jnp.logical_and(mask, kpos > qpos - window)
             logits = jnp.where(mask, logits, jnp.float32(-1e9))
+        if bd_block:
+            from ..kernels.flash_attention import block_diffusion_mask
+            logits = jnp.where(
+                block_diffusion_mask(logits.shape[-1] // 2, bd_block),
+                logits, jnp.float32(-1e9))
         probs = jax.nn.softmax(logits, axis=-1)
         rate = params.get("dropout", 0.0)
         if ctx.training and rate > 0.0:
@@ -963,6 +1000,37 @@ class MultiHeadAttentionOp(OpDef):
                           vh.astype(mdt),
                           preferred_element_type=jnp.float32)
         return self._project_out(ctxv, gate, weights, ctx, mdt, cdt)
+
+    @staticmethod
+    def _note_block_diffusion(ctx, name, batch, length, block, heads,
+                              kv_heads, head_dim, flash, mdt) -> None:
+        """What a layer under the block-diffusion mask announces: the
+        instant ``attn.block_diffusion`` (the mask's sizes and live
+        pairs, which path draws it and, on the flash path, the tiles'
+        pairs each kernel's grid visits) and the counters
+        ``attn.bd_pairs`` (the 4 L L pairs a kernel, three kernels a
+        layer) and ``attn.bd_visited_pairs`` (those the three grids
+        compute; off the kernels every pair is), whose quotient is the
+        share of the square the layer pays for: 0.25 + B / 4 L is the
+        mask's own."""
+        from ..kernels.flash_attention import block_diffusion_visited
+        pairs = 4 * length * length
+        visited = block_diffusion_visited(
+            batch * heads, length, block, head_dim, mdt,
+            heads // kv_heads) if flash else {
+            k: batch * heads * pairs for k in ("fwd", "bwd_dq", "bwd_dkv")}
+        ctx.count("attn.bd_pairs", jnp.float32(3 * batch * heads * pairs))
+        ctx.count("attn.bd_visited_pairs",
+                  jnp.float32(sum(visited.values())))
+        if events.enabled():
+            events.instant(
+                "attn.block_diffusion", layer=name, tokens=length,
+                block_length=block, heads=heads, kv_heads=kv_heads,
+                live_pairs=length * length + length * block, pairs=pairs,
+                impl="flash" if flash else "xla",
+                flash_calls=3 if flash else 0,
+                **{f"visited_pairs_{k}": v // (batch * heads)
+                   for k, v in visited.items()})
 
     def _emit_differential(self, params, weights, ctx, name, qh, kh, vh,
                            mdt, cdt):
@@ -1564,6 +1632,74 @@ class NextTokenLossOp(OpDef):
         loss = self.mean_nll(logits, ids, int(params["offset"]))
         ctx.aux_losses.append(params["weight"] * loss)
         return [loss.reshape(1)]
+
+
+@register
+class BlockDiffusionNoiseOp(OpDef):
+    """The noising of a block-diffusion training step (SDAR,
+    arXiv:2510.06303; BD3-LMs' vectorised form, arXiv:2503.09573): from
+    ids ``x`` (batch, L) in blocks of ``block_length``,
+
+        u_b ~ U[0, 1) a block,   t_b = t_min + (1 - t_min) u_b
+        m_i = [v_i < t_b(i)],    v_i ~ U[0, 1) a token
+        z = [where(m, mask_token_id, x) ; x]        (batch, 2 L) ids
+        w_i = m_i / t_b(i)                          (batch, L) float32
+
+    ``u`` and ``v`` are ``jax.random.uniform`` on the two halves of
+    ``jax.random.split(key)``, shapes (batch, L / block_length) and
+    (batch, L). The key is the step's in training (``ctx.rng_for``: a
+    new mask every step index, one mask at one index) and
+    ``jax.random.key(eval_noise_seed)`` otherwise, so that an eval pass
+    and a reference see one mask. Whether a token is masked is ``m``,
+    never a comparison of ids. No gradient flows in or out.
+
+    It announces itself: the instant ``diffusion.noise`` and the
+    counters ``diffusion.tokens``, ``diffusion.masked_tokens`` and
+    ``diffusion.weight_sum`` (over tokens: near 1, and far from it when
+    a block drew a tiny ``t``)."""
+    op_type = OperatorType.OP_BLOCK_DIFFUSION_NOISE
+
+    def infer(self, params, in_shapes, in_dtypes):
+        (b, l), = in_shapes
+        if l % params["block_length"]:
+            raise ValueError(f"{l} tokens are not whole blocks of "
+                             f"{params['block_length']}")
+        return [((b, 2 * l), in_dtypes[0]), ((b, l), DataType.DT_FLOAT)]
+
+    @staticmethod
+    def draw(key, batch: int, length: int, block: int, t_min: float):
+        """``(masked, t)``, (batch, L) bool and float32, from ``key``."""
+        k_t, k_v = jax.random.split(key)
+        u = jax.random.uniform(k_t, (batch, length // block), jnp.float32)
+        v = jax.random.uniform(k_v, (batch, length), jnp.float32)
+        t = jnp.repeat(t_min + (1.0 - t_min) * u, block, axis=1)
+        return v < t, t
+
+    def emit(self, params, inputs, weights, ctx, name):
+        (ids,) = inputs
+        b, l = ids.shape
+        key = ctx.rng_for(name) if ctx.training else None
+        source = "eval" if key is None else "step"
+        if key is None:
+            key = jax.random.key(params["eval_noise_seed"])
+        masked, t = self.draw(key, b, l, params["block_length"],
+                              params["t_min"])
+        noised = jnp.where(masked, jnp.asarray(params["mask_token_id"],
+                                               ids.dtype), ids)
+        hit = masked.astype(jnp.float32)
+        w = hit / t
+        ctx.count("diffusion.tokens", jnp.float32(b * l))
+        ctx.count("diffusion.masked_tokens", jnp.sum(hit))
+        ctx.count("diffusion.weight_sum", jnp.sum(w))
+        if events.enabled():
+            events.instant(
+                "diffusion.noise", layer=name, tokens=b * l,
+                block_length=params["block_length"],
+                blocks=b * l // params["block_length"],
+                mask_token_id=params["mask_token_id"],
+                t_min=params["t_min"], key=source)
+        return [jnp.concatenate([noised, ids], axis=1),
+                jax.lax.stop_gradient(w)]
 
 
 # ---------------------------------------------------------------------------

@@ -55,6 +55,19 @@ def _diagonal(args, shape, xp):
     return args["diagonal"] * xp.eye(*shape)
 
 
+def _drawn_columns(args, shape):
+    """``init_args["column_repeats"] = r``: a GLOROT_UNIFORM weight draws
+    its first ``shape[-1] / r`` columns (limits from the whole shape's
+    fans) and repeats them ``r`` times along the last axis, column ``j``
+    again at ``j + shape[-1] / r`` and so on. Returns the shape to draw
+    and ``np.tile``'s repeats; absent, the whole shape once."""
+    r = int(args.get("column_repeats", 1))
+    if r < 1 or shape[-1] % r:
+        raise ValueError(f"column_repeats {r} of {shape[-1]} columns")
+    return (tuple(shape[:-1]) + (shape[-1] // r,),
+            (1,) * (len(shape) - 1) + (r,))
+
+
 def _constant(args, shape, xp):
     """``CONSTANT``: ``init_args["value"]`` everywhere, or with
     ``init_args["rows"] == "log_count"`` the log of a row's number from 1
@@ -107,7 +120,9 @@ def initialize_host(spec, key_ints, np_dtype):
     if kind == InitializerType.GLOROT_UNIFORM:
         fan_in, fan_out = args.get("fans") or _fan_in_out(shape)
         limit = math.sqrt(6.0 / (fan_in + fan_out))
-        return gen.uniform(-limit, limit, shape).astype(np_dtype)
+        drawn, r = _drawn_columns(args, shape)
+        w = gen.uniform(-limit, limit, drawn).astype(np_dtype)
+        return w if drawn == shape else np.tile(w, r)
     raise ValueError(kind)
 
 
@@ -134,5 +149,7 @@ def initialize(spec, rng, jnp_dtype):
     if kind == InitializerType.GLOROT_UNIFORM:
         fan_in, fan_out = args.get("fans") or _fan_in_out(shape)
         limit = math.sqrt(6.0 / (fan_in + fan_out))
-        return jax.random.uniform(rng, shape, jnp_dtype, -limit, limit)
+        drawn, r = _drawn_columns(args, shape)
+        w = jax.random.uniform(rng, drawn, jnp_dtype, -limit, limit)
+        return w if drawn == tuple(shape) else jnp.tile(w, r)
     raise ValueError(kind)

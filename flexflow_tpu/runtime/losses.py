@@ -16,9 +16,13 @@ import jax.numpy as jnp
 from ..ffconst import LossType
 
 
-def compute_loss(loss_type: LossType, pred, label, *, logits: bool = False):
+def compute_loss(loss_type: LossType, pred, label, *, logits: bool = False,
+                 weights=None):
     """Mean-reduced scalar loss. `pred` is the final op output (or pre-
-    softmax logits when logits=True and the loss is a cross-entropy)."""
+    softmax logits when logits=True and the loss is a cross-entropy).
+    ``weights`` (the sparse cross-entropy alone): one weight a row, and
+    the loss is ``sum(w * nll) / rows``, the rows all counted whatever
+    their weight (a masked-diffusion loss: ``w = masked / t``)."""
     loss_type = LossType(loss_type)
     pred = pred.astype(jnp.float32)
 
@@ -29,7 +33,15 @@ def compute_loss(loss_type: LossType, pred, label, *, logits: bool = False):
         else:
             logp = jnp.log(jnp.clip(pred, 1e-10, 1.0))
         nll = -jnp.take_along_axis(logp, label[..., None], axis=-1)[..., 0]
+        if weights is not None:
+            w = jax.lax.stop_gradient(weights.astype(jnp.float32))
+            return jnp.sum(w.reshape(nll.shape) * nll) / nll.size
         return jnp.mean(nll)
+
+    if weights is not None:
+        raise NotImplementedError(
+            f"{loss_type.name} takes no weights: rows are weighed in the "
+            f"sparse categorical cross-entropy alone")
 
     if loss_type == LossType.LOSS_CATEGORICAL_CROSSENTROPY:
         label = label.astype(jnp.float32)

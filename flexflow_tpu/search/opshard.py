@@ -120,6 +120,10 @@ def options_for(layer: Layer) -> List[ShardOption]:
         # verifier refuses it)
         opts.append(ShardOption("parameter", -1, (
             ("norm", 0), ("out_proj", 0))))
+    elif t == OperatorType.OP_BLOCK_DIFFUSION_NOISE:
+        # batch only: a row's draw is its own, and the 2 L ids are the
+        # halves of ONE sequence (a shard of them would cut a block)
+        sample()
     elif t == OperatorType.OP_SELECTIVE_SCAN_MIXER:
         # batch only. The channels are independent recurrences, but a
         # shard of them has run under no mesh (the input projection's

@@ -452,7 +452,8 @@ class _GuardRun:
             outputs)
         self.executor = Executor(
             program, cfg, dmesh, strategy, ff.optimizer, ff.loss_type,
-            getattr(ff, "metrics", []), seed=cfg.seed)
+            getattr(ff, "metrics", []), seed=cfg.seed,
+            loss_weights=getattr(ff, "_loss_weights_tensor", None))
         self._optimizer = ff.optimizer
         # fed exactly as fit() will feed it: the adopted side's compiled
         # step is then the one training runs, not a near-copy that

@@ -1,0 +1,568 @@
+"""``sdar_30b_a3b.train.1chip`` (PR 64): the files resolve, the manifest's
+new entries are there BY NAME (nothing here pins a list's tail: a later
+PR appends after them), the configuration's file holds the catalog row
+except for the cut, the parameter and operation counts are the model's
+(645,623,296 here, 30,532,122,624 at full depth with all 128 experts and
+the whole vocabulary), and the ten readers read a hand-made trace, the
+recorded test traces and a parent's program (nothing, without an error).
+"""
+import dataclasses
+import json
+import math
+import os
+import types
+
+import pytest
+
+from benchmarks.harness import (cells, peaks, scope_reduce, span_reduce,
+                                trace_reduce)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = os.path.join(ROOT, "benchmarks")
+CONFIG = "sdar_30b_a3b"
+CELL = "sdar_30b_a3b.train.1chip"
+OLDER_CELLS = {
+    "bert_large.train.1chip", "gpt2_124m.train.1chip",
+    "joyai_llm_flash.train.1chip", "lfm2_24b_a2b.train.1chip",
+    "kimi_linear_48b_a3b.train.1chip", "xing4_29b_a4b.train.1chip",
+    "keye_vl2_30b_a3b.train.1chip", "trinity_mini.train.1chip",
+    "granite_4_0_h_micro.train.1chip", "qwen3_next_80b_a3b.train.1chip",
+    "phi4_mini_flash_reasoning.train.1chip"}
+US = 1000
+PR64 = {        # name -> (unit, better, source, layer)
+    "sdar_attn_time_share.train": ("%", "lower", "device_trace",
+                                   "attention"),
+    "sdar_moe_time_share.train": ("%", "lower", "device_trace", "experts"),
+    "sdar_noise_loss_time_share.train": ("%", "lower", "device_trace",
+                                         "executor"),
+    "sdar_flash_fwd_roofline": ("%", "higher", "device_trace", "kernels"),
+    "sdar_flash_bwd_dq_roofline": ("%", "higher", "device_trace",
+                                   "kernels"),
+    "sdar_flash_bwd_dkv_roofline": ("%", "higher", "device_trace",
+                                    "kernels"),
+    "sdar_bd_kept_share": ("ratio", "lower", "program_counter",
+                           "attention"),
+    "sdar_masked_share": ("ratio", "higher", "program_counter",
+                          "executor"),
+    "sdar_moe_dropped_assignments": ("count", "lower", "program_counter",
+                                     "experts"),
+    "sdar_moe_overflow_layer_steps": ("count", "lower", "program_counter",
+                                      "experts"),
+}
+SHARED = {"compile_s", "step_ms.train", "mfu.train", "in_window_compiles",
+          "mosaic_calls_per_step", "kernel_time_share.train",
+          "device_idle_share.train", "recompute_time_share.train",
+          "recompute_kernel_time_share.train",
+          "recompute_again_time_share.train", "remat_held_gib",
+          "weights_and_optimizer_gib"}
+L, BLOCK = 4096, 4
+LIVE = 16793600                 # L L + L B of the 67,108,864
+
+
+def _read(metric, ctx):
+    return cells.load_module(BENCH, "layer_metrics",
+                             cells.metric_file(metric)).read(ctx)
+
+
+@pytest.fixture(scope="module")
+def manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def cell():
+    return cells.resolve_cell(ROOT, CELL)
+
+
+# ----------------------------------------------------------------------
+# the manifest, by name
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("name", sorted(PR64))
+def test_each_new_metric_lists_the_cell_and_has_a_reader(manifest, name):
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert len(by_name) == len(manifest["per_layer"])
+    unit, better, source, layer = PR64[name]
+    assert by_name[name] == {
+        "name": name, "unit": unit, "better": better, "source": source,
+        "layer": layer, "moves": "train_tokens_per_s",
+        "workloads": [CELL] + by_name[name]["workloads"][1:]}
+    assert callable(cells.load_module(
+        BENCH, "layer_metrics", cells.metric_file(name)).read)
+
+
+def test_the_new_entries_come_after_every_entry_the_parent_had(manifest):
+    """After the eleven cells and configurations and the 117 metrics of
+    the parent; what comes after this PR's is not this test's to say."""
+    order = [m["name"] for m in manifest["per_layer"]]
+    first = min(order.index(n) for n in PR64)
+    assert order.index("phi4flash_ssm_min_step_log_decay") < first >= 117
+    names = [w["name"] for w in manifest["workloads"]]
+    assert all(names.index(w) < names.index(CELL) for w in OLDER_CELLS)
+    configs = [c["name"] for c in manifest["configs"]]
+    assert configs.index("phi4_mini_flash_reasoning") < configs.index(CONFIG)
+    assert len(set(names)) == len(names) and len(set(configs)) == len(configs)
+    assert all(len(e["why"]) <= 200 for e in
+               manifest["configs"] + manifest["workloads"])
+    assert all(w["chips"] == 1 for w in manifest["workloads"]
+               if w["name"] in OLDER_CELLS or w["name"] == CELL)
+    assert manifest["run_seconds"] == 20
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        perf = f.read()
+    assert CELL in perf and "sdar_bd_kept_share" in perf
+
+
+@pytest.mark.parametrize("older", sorted(OLDER_CELLS))
+def test_every_older_cell_is_unmoved(manifest, older):
+    entry = next(w for w in manifest["workloads"] if w["name"] == older)
+    assert entry["chips"] == 1 and entry["config"] != CONFIG
+    reported = {m["name"] for m in cells.resolve_cell(ROOT, older).per_layer}
+    assert not reported & set(PR64)
+
+
+def test_the_cell_reports_the_shared_metrics_and_its_own(cell):
+    assert {m["name"] for m in cell.end_to_end} == {
+        "train_tokens_per_s", "step_hbm_gib", "setup_s"}
+    assert {m["name"] for m in cell.per_layer} >= SHARED | set(PR64)
+    assert not [m["name"] for m in cell.per_layer
+                if m["name"].startswith("sdar_") and m["name"] not in PR64]
+    assert cell.chips == 1
+    # the file that was there, as it is: one sequence of 4096 tokens,
+    # which the decoder runs as 8192 positions
+    assert cell.traffic_name == "train_b1_s4096"
+    assert cell.traffic["seq"] == L and L % cell.config["block_length"] == 0
+    assert cell.traffic["ffconfig"] == {"remat": "blocks"}
+    assert cell.config["reference_sequences"] == \
+        cell.traffic["per_chip_batch"] == 1
+
+
+# ----------------------------------------------------------------------
+# the configuration's file
+# ----------------------------------------------------------------------
+CATALOG = {        # the catalog row's ``config``, architectures.jsonl
+    "attention_bias": False, "decoder_sparse_step": 1, "head_dim": 128,
+    "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 6144,
+    "max_position_embeddings": 32768, "max_window_layers": 48,
+    "mlp_only_layers": [], "model_type": "sdar_moe",
+    "moe_intermediate_size": 768, "norm_topk_prob": True,
+    "num_attention_heads": 32, "num_experts": 128,
+    "num_experts_per_tok": 8, "num_hidden_layers": 48,
+    "num_key_value_heads": 4, "rms_norm_eps": 1e-06, "rope_scaling": None,
+    "rope_theta": 1000000, "sliding_window": None,
+    "tie_word_embeddings": False, "use_sliding_window": False,
+    "vocab_size": 151936}
+CUT = {"num_hidden_layers": 6, "num_experts": 16, "vocab_size": 18992}
+
+
+def test_every_published_key_is_in_the_file_and_only_the_cut_differs(
+        manifest, cell):
+    conf = cell.config
+    entry = next(c for c in manifest["configs"] if c["name"] == CONFIG)
+    assert entry["source"] == conf["source"] \
+        == "https://huggingface.co/JetLM/SDAR-30B-A3B-Chat/blob/main/" \
+           "config.json"
+    assert entry["file"] == f"benchmarks/configs/{CONFIG}.json"
+    differs = [k for k, v in CATALOG.items() if conf[k] != v]
+    assert sorted(differs) == sorted(CUT) == sorted(entry["reduced"]) \
+        == sorted(conf["reduced"]) == sorted(conf["reduced_why"])
+    for key, value in CUT.items():
+        assert conf[key] == value
+    # the published values of the cut keys are in the file too
+    assert conf["num_hidden_layers_published"] == 48
+    assert conf["num_experts_published"] == 128 == 8 * conf["num_experts"]
+    assert conf["vocab_size_published"] == 151936 == 8 * conf["vocab_size"]
+    assert conf["first_held_expert"] == 0
+    assert conf["layer_types"] == ["block_diffusion_attention"] * 6
+    # no width is cut, and none may be
+    widths = ("hidden_size", "intermediate_size", "moe_intermediate_size",
+              "num_attention_heads", "num_key_value_heads", "head_dim",
+              "num_experts_per_tok")
+    assert not set(widths) & set(conf["reduced"])
+    assert set(conf["departures"]) == {"rolled_rows"}
+    for form in ("block_length", "schedule", "loss_form", "mask_token_id",
+                 "qk_norm", "scoring_func", "positions", "eval_noise_seed",
+                 "dropout", "initialisation"):
+        assert form in conf["assumed"], form
+    assert (conf["block_length"], conf["t_min"]) == (4, 1e-3)
+    assert conf["mask_token_id"] == conf["vocab_size"] - 1
+    for said in ("8 chips share each layer", "16 here, experts 0 to 15",
+                 "18992 of 151936", "6 of 48 layers"):
+        assert said in conf["deployment"], said
+    assert conf["reference"] \
+        == "block_diffusion_moe_ref:block_diffusion_moe_decoder"
+    # c (ln 18992 + half the logits' variance) with c = sum(w) / L of the
+    # eval draw: an UNWEIGHTED loss (ln 18992 and more) is over the band
+    lo, hi = conf["initial_loss_band"]
+    assert lo < hi < math.log(conf["vocab_size"])
+    assert 0 < conf["reference_rel_tol"] < 0.1
+    assert "TO BE SET" not in json.dumps(conf) \
+        and "PLACEHOLDER" not in json.dumps(conf)
+
+
+def test_the_eval_draws_weight_sum_is_the_files(cell):
+    """``c = sum(w) / L`` of the eval draw at the cell's 1 x 4096 tokens:
+    a constant of ``eval_noise_seed``, which the file states."""
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    import jax.numpy as jnp
+    ref = cells.load_module(BENCH, "reference", "block_diffusion_moe_ref")
+    ids = jnp.zeros((1, L), jnp.int32)
+    w = ref.weights(cell.config, ids)
+    masked, t = ref.noise(cell.config, ids)
+    assert float(w.mean()) == pytest.approx(0.94692, abs=1e-5)
+    assert "0.94692" in cell.config["assumed"]["eval_noise_seed"]
+    assert int(masked.sum()) == 1992 and float(t.min()) > 1e-3
+
+
+def test_the_files_fields_are_the_classs(cell):
+    """Every field of the builder's class is in the file at the class's
+    own value for this rank: the file IS the configuration that runs."""
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    conf = cell.config
+    cls = cells.load_attr(conf["config_class"])
+    default = cls()
+    for f in dataclasses.fields(cls):
+        assert f.name in conf, f.name
+        assert conf[f.name] == getattr(default, f.name), f.name
+
+
+def _weight_counts(mc, builder):
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.ops.registry import get_op_def
+    ff = FFModel(FFConfig())
+    builder(ff, 1, 256, mc)
+    counts = {}
+    for layer in ff.layers:
+        specs = get_op_def(layer.op_type).weights(
+            layer.params, [t.shape for t in layer.inputs],
+            [t.dtype for t in layer.inputs])
+        counts[layer.name] = sum(math.prod(s.shape) for s in specs)
+    return counts
+
+
+def test_the_parameter_count_is_the_built_models(cell):
+    """``parameters_here`` against the ops' own weight lists at the
+    published widths (shapes alone: nothing is allocated), against ISSUE
+    64's table, and the full depth with all 128 experts and the whole
+    vocabulary against the published 30B."""
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    conf = cell.config
+    cls = cells.load_attr(conf["config_class"])
+    mc = cls(**{f.name: conf[f.name] for f in dataclasses.fields(cls)
+                if f.name in conf})
+    builder = cells.load_attr(conf["builder"])
+    counts = _weight_counts(mc, builder)
+    here = conf["parameters_here"]
+    assert counts["noise"] == 0
+    assert counts["attn_0"] == counts["attn_5"] \
+        == here["attention_per_layer"] == 18874624 \
+        == 2 * 2048 * 32 * 128 + 2 * 2048 * 4 * 128 + 2 * 128
+    one_expert = 3 * 2048 * 768
+    assert counts["experts_0"] \
+        == here["router_per_layer"] + here["experts_held_per_layer"] \
+        == 2048 * 128 + 16 * one_expert
+    assert here["experts_held_per_layer"] == 75497472
+    norms = counts["operator_norm_0"] + counts["ffn_norm_0"]
+    assert norms == here["two_norms_per_layer"] == 4096
+    assert here["layer"] == 94638336 \
+        == counts["attn_0"] + counts["experts_0"] + norms
+    assert here["layers"] == 567830016 == 6 * here["layer"]
+    assert counts["embed_tokens"] == counts["lm_head"] == 38895616
+    assert here["embedding_and_head"] == 2 * 38895616
+    assert counts["final_norm"] == here["final_norm"] == 2048
+    assert sum(counts.values()) == here["total"] == 645623296
+    # 9.62 GiB at 16 bytes, 7.22 GiB of it arguments of the step
+    assert 16 * here["total"] / 2 ** 30 == pytest.approx(9.62, abs=5e-3)
+    assert 12 * here["total"] / 2 ** 30 == pytest.approx(7.22, abs=5e-3)
+    # the full depth, every expert, the whole vocabulary: "30B", and
+    # "A3B" a token
+    full = _weight_counts(dataclasses.replace(
+        mc, num_hidden_layers=48, layer_types=None, num_experts=128,
+        vocab_size=151936, mask_token_id=151935), builder)
+    assert sum(full.values()) == here["published_total"] == 30532122624
+    a_token = 48 * (18874624 + 4096 + 262144 + 8 * one_expert) \
+        + 2 * 311164928 + 2048
+    assert 3.3e9 < a_token < 3.4e9
+
+
+def test_every_seed_sends_this_share_the_same_rows(cell):
+    """The six routers are drawn as this share's 16 columns repeated for
+    each of the 8 shares (``router_repeats`` = published / held), so at
+    any seed's weights every position's 8 chosen experts are one in each
+    share: 8,192 rows a layer for the 16 experts held, however many
+    positions hold one id (the mask's). With a plain draw the count
+    hangs on the seed (PERF.md section 6, PR 64)."""
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    import numpy as np
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.ops.registry import get_op_def
+    from flexflow_tpu.runtime.initializers import initialize_host
+    conf = cell.config
+    assert conf["router_repeats"] * conf["num_experts"] \
+        == conf["num_experts_published"] == 128
+    assert conf["num_experts_per_tok"] % conf["router_repeats"] == 0
+    assert "router_repeats" in conf["assumed"]["initialisation"]
+    cls = cells.load_attr(conf["config_class"])
+    mc = cls(**{f.name: conf[f.name] for f in dataclasses.fields(cls)
+                if f.name in conf})
+    ff = FFModel(FFConfig())
+    cells.load_attr(conf["builder"])(ff, 1, 256, mc)
+    routers = [la for la in ff.layers if la.name.startswith("experts_")]
+    assert len(routers) == 6
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(512, 2048)).astype(np.float32)
+    x[100:300] = x[100]                     # 200 positions hold one id
+    held = {}
+    for li, layer in enumerate(routers):
+        assert layer.params["router_repeats"] == 8
+        (wg,) = [w for w in get_op_def(layer.op_type).weights(
+            layer.params, [t.shape for t in layer.inputs],
+            [t.dtype for t in layer.inputs]) if w.name == "wg"]
+        for r in (8, 1):
+            wg.init_args = {"column_repeats": r}
+            w = initialize_host(wg, (2147486411, 1, li, 0), np.float32)
+            assert w.shape == (2048, 128)
+            top = np.argsort(-(x @ w), axis=1, kind="stable")[:, :8]
+            held[li, r] = (top < 16).sum(axis=1)
+        assert np.array_equal(w[:, :16] != w[:, 16:32],
+                              np.ones((2048, 16), bool))    # r == 1
+        assert (held[li, 8] == 1).all()
+    assert {int(held[li, 1].sum()) for li in range(6)} != {512}
+
+
+def test_model_flops_against_a_hand_count(cell):
+    flops = cells.load_module(BENCH, "flops", CONFIG)
+    h, heads, kv, d = 2048, 32, 4, 128
+    proj = 2 * h * heads * d + 2 * h * kv * d            # 18.9M a position
+    experts = h * 128 + 3 * h * 768 * (8 * 16 / 128)     # one expert met
+    keys = LIVE / (2 * L)                # keys a position, on average
+    position = 2 * proj + 2 * keys * heads * 2 * d + 2 * experts
+    want = 2 * 6 * position + 2 * h * 18992      # 2 positions a token
+    assert flops.live_pairs(L, BLOCK) == LIVE == L * L + L * BLOCK
+    assert LIVE / (2 * L) ** 2 == pytest.approx(0.25024, abs=1e-5)
+    assert flops.forward_flops_per_token(cell.config, L) \
+        == pytest.approx(want)
+    assert flops.train_flops_per_token(cell.config, L) \
+        == pytest.approx(3 * want)
+    # ISSUE 64's round numbers: 16,384 operations a pair a layer, 0.275
+    # TFLOP of attention a layer, 0.32 of the head; a layer's products
+    # are 0.39 TFLOP and not the issue's 0.93, which counted the eight
+    # experts a position is ROUTED to where one of them is held here
+    assert heads * 2 * 2 * d == 16384
+    assert 0.27e12 < 16384 * LIVE < 0.28e12
+    assert 0.31e12 < 2 * h * 18992 * L < 0.33e12
+    assert 0.38e12 < 2 * 2 * L * (proj + experts) < 0.40e12
+    assert 4.2e12 < want * L < 4.4e12
+
+
+@pytest.mark.parametrize("kernel,products", [
+    ("flash_attention_fwd", 2), ("flash_attention_bwd_dq", 3),
+    ("flash_attention_bwd_dkv", 4)])
+def test_a_flash_calls_operations_and_bytes_by_hand(kernel, products):
+    """A layer's call at the cell's shapes: 32 query heads reading 4
+    key/value heads in place over 8,192 positions, the LIVE pairs."""
+    flops = cells.load_module(BENCH, "flops", CONFIG)
+    s = 2 * L
+    q, k = ("bf16", [32, s, 128]), ("bf16", [4, s, 128])
+    operands = [("s32", [1, 1]), q, k, k]
+    assert flops.flash_operations(kernel, operands, BLOCK) \
+        == products * 2 * 32 * LIVE * 128
+    # a quarter of what a count over the square would say
+    assert flops.flash_operations(kernel, operands, BLOCK) \
+        / (products * 2 * 32 * s * s * 128) == pytest.approx(0.25024,
+                                                             abs=1e-5)
+    results = [("bf16", [32, s, 128])]
+    assert flops.bytes_moved("flash_attention_fwd", operands, results) \
+        == 4 + 2 * s * 128 * (32 + 4 + 4 + 32)
+    peak = peaks.lookup("TPU v5 lite")
+    seconds, bound = flops.flash_roofline_s(kernel, operands, results,
+                                            BLOCK, peak)
+    assert bound == "operations" and seconds == pytest.approx(
+        products * 2 * 32 * LIVE * 128 / peak["bf16_flops_per_s"])
+    with pytest.raises(ValueError, match="2 L"):
+        flops.flash_operations(kernel, [operands[0], q,
+                                        ("bf16", [4, L, 128])], BLOCK)
+
+
+# ----------------------------------------------------------------------
+# the readers, on a hand-made trace
+# ----------------------------------------------------------------------
+TOP = "jit(step_fn)/jit(main)/"
+FWD = TOP + "jvp(ff.forward)/remat.block/checkpoint/"
+BWD = TOP + "transpose(jvp(ff.forward))/jvp(ff.forward)/remat.block/" \
+    "checkpoint/rematted_computation/"
+OPS = [          # name, start us, duration us, op_name
+    ("fusion.1", 1000, 20, TOP + "jvp(ff.forward)/noise/threefry2x32"),
+    ("fusion.2", 1020, 10, TOP + "jvp(ff.forward)/loss_weights/concatenate"),
+    ("fusion.3", 1030, 70, FWD + "attn_0/attn.proj/dot_general"),
+    ("flash_attention_fwd.1", 1100, 100,
+     FWD + "attn_0/attn.kernels/flash_attention_fwd/pallas_call"),
+    ("flash_attention_fwd.2", 1200, 100,
+     BWD + "attn_1/attn.kernels/flash_attention_fwd/pallas_call"),
+    ("fusion.4", 1300, 150, FWD + "experts_0/moe.experts/dot_general"),
+    ("ragged-dot-none.1", 1450, 50, ""),
+    ("fusion.5", 1500, 30, TOP + "jvp(ff.forward)/noised_rows/concatenate"),
+    ("fusion.6", 1530, 70, TOP + "jvp(ff.forward)/lm_head/dot_general"),
+    ("fusion.7", 1600, 60, TOP + "jvp(ff.loss)/reduce_sum"),
+    ("fusion.8", 1660, 40, TOP + "transpose(jvp(ff.loss))/mul"),
+    ("fusion.9", 1700, 100, TOP + "ff.optimizer/mul"),
+]
+BD = {"num_heads": 32, "num_kv_heads": 4, "causal": False,
+      "block_diffusion_block": BLOCK}
+LAYERS = [("noise", "OP_BLOCK_DIFFUSION_NOISE", {"block_length": BLOCK}),
+          ("op_slice_1", "OP_SLICE", {}), ("op_slice_2", "OP_SLICE", {}),
+          ("loss_weights", "OP_CONCAT", {}),
+          ("embed_tokens", "OP_EMBEDDING", {}),
+          ("operator_norm_0", "OP_RMS_NORM", {}),
+          ("attn_0", "OP_MULTIHEAD_ATTENTION", BD),
+          ("operator_res_0", "OP_EW_ADD", {}),
+          ("ffn_norm_0", "OP_RMS_NORM", {}),
+          ("experts_0", "OP_ROUTED_EXPERTS", {}),
+          ("ffn_res_0", "OP_EW_ADD", {}),
+          ("attn_1", "OP_MULTIHEAD_ATTENTION", BD),
+          ("experts_1", "OP_ROUTED_EXPERTS", {}),
+          ("ffn_res_1", "OP_EW_ADD", {}),
+          ("op_slice_3", "OP_SLICE", {}), ("noised_rows", "OP_CONCAT", {}),
+          ("final_norm", "OP_RMS_NORM", {}), ("lm_head", "OP_LINEAR", {}),
+          ("op_softmax_9", "OP_SOFTMAX", {})]
+CALL = [("s32", [1, 1]), ("bf16", [32, 2 * L, 128]),
+        ("bf16", [4, 2 * L, 128]), ("bf16", [4, 2 * L, 128])]
+RESULTS = [("bf16", [32, 2 * L, 128])]
+
+
+def _model(layers):
+    return types.SimpleNamespace(layers=[
+        types.SimpleNamespace(name=n, params=p,
+                              op_type=types.SimpleNamespace(name=k))
+        for n, k, p in layers])
+
+
+def _hand_ctx(ops=OPS, layers=LAYERS):
+    events = {"devices": {"/device:TPU:0": [[n, s * US, d * US]
+                                            for n, s, d, _ in ops]},
+              "marks": [["bench.group", 1000 * US, 1000 * US]], "spans": []}
+    instr = {n: {"op_name": op, "mosaic": n.startswith("flash"),
+                 "operands": CALL if n.startswith("flash") else [],
+                 "results": RESULTS if n.startswith("flash") else []}
+             for n, _, _, op in ops}
+    names = {n for n, _, _ in layers}
+    by_op = scope_reduce.op_self_ns(events, instr, names)
+    return types.SimpleNamespace(
+        span_reduced=span_reduce.reduce_spans(events, instr),
+        span_instructions=instr, model=_model(layers),
+        scope_layer_ns=scope_reduce.layer_self_ns(events, instr, names),
+        name_by_op=by_op, peak=peaks.lookup("TPU v5 lite"), counters={},
+        cell=types.SimpleNamespace(bench_dir=BENCH))
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("sdar_attn_time_share.train", 100.0 * 270 / 800),
+    ("sdar_moe_time_share.train", 100.0 * 200 / 800),
+    ("sdar_noise_loss_time_share.train", 100.0 * 230 / 800)])
+def test_time_shares_by_hand(metric, want):
+    """The attention layers' projection and two kernel calls, one of
+    them recomputed (270 of 800 us busy); the experts' product and the
+    unnamed grouped product after it (200); the noising op, the two
+    rolls, the head and the loss forward and backward (20 + 10 + 30 + 70
+    + 60 + 40 = 230), and not the optimizer."""
+    ctx = _hand_ctx()
+    assert ctx.span_reduced["busy_ns"] == 800 * US
+    assert _read(metric, ctx) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+def test_a_roofline_counts_each_call_by_the_live_pairs(kernel):
+    name = f"flash_attention_{kernel}"
+    ops = [(n.replace("fwd", kernel), s, d, op.replace("fwd", kernel))
+           for n, s, d, op in OPS]
+    ctx = _hand_ctx(ops)
+    flops = cells.load_module(BENCH, "flops", CONFIG)
+    least = 2 * flops.flash_roofline_s(name, CALL, RESULTS, BLOCK,
+                                       ctx.peak)[0]
+    got = _read(f"sdar_flash_{kernel}_roofline", ctx)
+    assert got == pytest.approx(100.0 * least / 200e-6)
+    # a call of a layer under another mask shares the kernel's time:
+    # nothing can be said
+    plain = [(n, k, {"num_heads": 32, "causal": True} if n == "attn_1"
+              else p) for n, k, p in LAYERS]
+    assert _read(f"sdar_flash_{kernel}_roofline",
+                 _hand_ctx(ops, plain)) is None
+
+
+def test_the_counters_quotients_by_hand():
+    ctx = _hand_ctx()
+    visited = 3 * 32 * 0.375 * (2 * L) ** 2        # what the grids visit
+    ctx.counters = {"attn.bd_visited_pairs": 6 * 50 * visited,
+                    "attn.bd_pairs": 6 * 50 * 3 * 32 * (2.0 * L) ** 2,
+                    "diffusion.masked_tokens": 50 * 2011.0,
+                    "diffusion.tokens": 50 * 4096.0,
+                    "moe.dropped": 0.0, "moe.overflow": 3.0}
+    assert _read("sdar_bd_kept_share", ctx) == pytest.approx(0.375)
+    assert _read("sdar_masked_share", ctx) == pytest.approx(2011 / 4096)
+    assert _read("sdar_moe_dropped_assignments", ctx) == 0.0
+    assert _read("sdar_moe_overflow_layer_steps", ctx) == 3.0
+    ctx.counters = {"diffusion.tokens": 0.0}
+    assert _read("sdar_masked_share", ctx) is None
+    assert _read("sdar_bd_kept_share", ctx) is None
+
+
+@pytest.mark.parametrize("metric", sorted(PR64))
+def test_every_new_reader_reads_nothing_from_the_parent(metric):
+    """The parent of PR 64 has no noising op, no attention layer under
+    the block-diffusion mask and no ``diffusion.*`` or ``attn.bd_*``
+    counter, and a run without ``--trace 1`` has no trace and no
+    counters: nothing to read, and no error. A model of the parent's
+    (cell 7's causal attention beside routed experts) is not read as
+    this model's."""
+    keye = [("embed_tokens", "OP_EMBEDDING", {}),
+            ("attn_0", "OP_MULTIHEAD_ATTENTION",
+             {"num_heads": 32, "num_kv_heads": 4, "causal": True}),
+            ("experts_0", "OP_ROUTED_EXPERTS", {}),
+            ("ffn_res_0", "OP_EW_ADD", {}), ("lm_head", "OP_LINEAR", {})]
+    ops = [("flash_attention_fwd.1", 1000, 100,
+            FWD + "attn_0/attn.kernels/flash_attention_fwd/pallas_call"),
+           ("fusion.1", 1100, 200, FWD + "experts_0/dot_general"),
+           ("fusion.2", 1300, 100, TOP + "jvp(ff.forward)/lm_head/dot")]
+    ctx = _hand_ctx(ops, keye)
+    assert _read(metric, ctx) is None
+    bare = types.SimpleNamespace(
+        trace=None, step_text="", peak=None, counters={},
+        model=_model(LAYERS),
+        cell=types.SimpleNamespace(root="/nonexistent", name="x.train",
+                                   bench_dir=BENCH))
+    assert _read(metric, bare) is None
+
+
+@pytest.mark.parametrize("metric", sorted(PR64))
+def test_every_new_reader_reads_the_recorded_testdata_without_error(
+        metric):
+    """``benchmarks/testdata/``'s recorded traces are of a model with
+    none of the layers these readers pick: each returns None or a
+    number, and raises nothing."""
+    with open(os.path.join(BENCH, "testdata", "trace_events.json")) as f:
+        recorded = json.load(f)
+    ctx = types.SimpleNamespace(
+        trace=trace_reduce.reduce_trace(recorded["events"], []),
+        cell=types.SimpleNamespace(root="/nonexistent", name="x.train",
+                                   bench_dir=BENCH),
+        step_text="", peak=peaks.lookup("TPU v5 lite"), counters={},
+        model=_model(LAYERS))
+    got = _read(metric, ctx)
+    assert got is None or isinstance(got, float)
+
+
+def test_the_reference_imports_nothing_of_the_program():
+    with open(os.path.join(BENCH, "reference",
+                           "block_diffusion_moe_ref.py")) as f:
+        text = f.read()
+    assert "flexflow_tpu" not in text and "import flexflow" not in text
+    assert 'default_matmul_precision("highest")' in text
+    code = text.split('"""', 2)[2]
+    assert "tile" not in code and "pallas" not in code     # no tiles
+    assert "jax.random.split(key)" in code and code.count(
+        "jax.random.uniform(") == 2         # the two documented draws
+    mod = cells.load_module(BENCH, "reference", "block_diffusion_moe_ref")
+    assert callable(mod.block_diffusion_moe_decoder) and callable(mod.loss)
+    assert set(mod.PERTURBATIONS) == {
+        "causal_clean_only", "own_block_clean_keys",
+        "consecutive_positions", "unit_weights"}

@@ -20,8 +20,8 @@ from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
                                      HybridConvMoEConfig, KeyeRankConfig,
                                      KimiLinearRankConfig, LatentMoEConfig,
                                      Phi4FlashRankConfig,
-                                     Qwen3NextRankConfig, TrinityRankConfig,
-                                     XingRankConfig)
+                                     Qwen3NextRankConfig, SDARRankConfig,
+                                     TrinityRankConfig, XingRankConfig)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -266,6 +266,25 @@ def test_leg_k_sambay_tiny_on_the_cpu_mesh(capsys):
     assert f"python3 {chip_smoke.VALIDATION_SAMBAY}" in out
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__), chip_smoke.VALIDATION_SAMBAY))
+
+
+def test_leg_l_block_diffusion_tiny_on_the_cpu_mesh(capsys):
+    """32 tokens (64 positions) on the 8-device mesh: three
+    rematerialised blocks, the noising op drawing from the step's key in
+    training and the configuration's in eval, the mask in every layer
+    (off the kernels here: every pair computed), the loss weighted."""
+    chip_smoke.leg_block_diffusion(SDARRankConfig.tiny(), seq=32,
+                                   per_chip_batch=1, label="L/small",
+                                   alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "rematerialised run (5, 6, 3)" in out
+    assert ("the noising op drew from ['eval', 'step']; the mask in 3 "
+            "layers by ['xla']; the loss weighted over [256] rows") in out
+    assert "the kernels' grids visit 1.0000 of the square" in out
+    assert f"python3 {chip_smoke.VALIDATION_BLOCK_DIFFUSION}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__),
+        chip_smoke.VALIDATION_BLOCK_DIFFUSION))
 
 
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):

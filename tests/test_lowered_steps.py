@@ -1,7 +1,7 @@
 """Every rank configuration's train step lowers to the text it lowered
 to at the parent commit.
 
-The eleven classes are the nine that a cell's config file names and the
+The twelve classes are the ten that a cell's config file names and the
 two base classes they extend; each is built at its ``tiny()`` size by
 its builder (``rank_family.lowered_step``: the default ``FFConfig`` but
 no search, 2 x 32 ids drawn from seed 0, cold caches), with and without
@@ -44,6 +44,7 @@ BUILDER = {          # class name: its builder and the cell that names it
     "GraniteHybridRankConfig": nlp.build_hybrid_conv_moe,   # cell 9
     "Qwen3NextRankConfig": nlp.build_hybrid_conv_moe,   # cell 10
     "Phi4FlashRankConfig": nlp.build_hybrid_conv_moe,   # cell 11
+    "SDARRankConfig": nlp.build_hybrid_conv_moe,        # cell 12
 }
 
 LOWERED = {
@@ -89,6 +90,10 @@ LOWERED = {
         "6ae1f7bc1a602372feec80a028ef319c4313df79ef0dc76c116b5e2ddbeba336",
     ("Qwen3NextRankConfig", "blocks"):
         "75213f2e760c85ba401dc4d7b94af0ca803596dd8603717014c37d19e5087721",
+    ("SDARRankConfig", "none"):
+        "8a4e467e6eb009c46b51c360a745350850688561966f6b2c1e9bd944ae881047",
+    ("SDARRankConfig", "blocks"):
+        "559ef78378bc594fc9af5b72d7df47717e2ce99d0e2b44fe3e14440581731f1b",
     ("TrinityRankConfig", "none"):
         "708cf492ac0352eff099c0761b8f98dcc72db3ac033f5d898799227c50ac6696",
     ("TrinityRankConfig", "blocks"):

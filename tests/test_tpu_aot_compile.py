@@ -1664,3 +1664,42 @@ def test_the_selective_scan_mixer_compiles_at_the_published_width(
              >= whole]
     assert not moved, moved
     assert compiled.memory_analysis().temp_size_in_bytes < 1.75 * 2 ** 30
+
+
+# ----------------------------------------------------------------------
+# the block-diffusion mask inside the flash kernels (PR 64)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("length,block,dtype", [
+    (4096, 4, "bfloat16"), (1536, 24, "float32")],
+    ids=["cell_12s", "a_block_of_24_float32"])
+def test_the_block_diffusion_kernels_compile(v5e_devices, chip_locations,
+                                             length, block, dtype):
+    """``sdar_30b_a3b.train.1chip``: 32 query heads on 4 key/value heads
+    of 128 over 2 x 4,096 positions, bf16, blocks of 4 (and a block that
+    is no power of two, whose first place is a remainder): the three
+    kernels with the mask's index maps and liveness tests compile under
+    Mosaic, and the call has no operand beyond the plain call's (the
+    mask is never one)."""
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    q = jax.ShapeDtypeStruct((1, 32, 2 * length, 128), jnp.dtype(dtype),
+                             sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 4, 2 * length, 128), jnp.dtype(dtype),
+                              sharding=one)
+
+    def loss(q, k, v, bd):
+        o = flash_attention(q, k, v, interpret=False, block_diffusion=bd)
+        return jnp.sum(o.astype(jnp.float32))
+
+    def text(bd):
+        return _compile_text(jax.grad(functools.partial(loss, bd=bd),
+                                      argnums=(0, 1, 2)), q, kv, kv)
+
+    masked, plain = text((length, block)), text(())
+    assert _kernel_names(masked) == FLASH_NAMES
+
+    def operands(txt):
+        return sorted(l.split(" custom-call(")[1].count("%")
+                      for l in txt.splitlines() if MOSAIC_CALL in l)
+
+    assert operands(masked) == operands(plain)
+    assert "s8[" not in masked

@@ -1396,6 +1396,20 @@ def leg_sambay(model_cfg, seq: int, per_chip_batch: int, label: str,
 VALIDATION_BLOCK_DIFFUSION = "examples/tpu_validate_block_diffusion.py"
 
 
+def _bd_walked_share(seq: int) -> float:
+    """What the flash kernels' grids compute of the ``(2 seq)^2`` square
+    under the block-diffusion mask at tiles of ``min(seq, 1024)`` a
+    side, ``h`` of them a half: the ``h (h + 1)`` live tiles of the
+    noised x clean and clean x clean triangles whole, the ``h`` diagonal
+    tiles of noised x noised as their ``BD_SUB``-wide diagonal
+    sub-blocks (one tile a half: 0.5625 at 512 positions, 0.75 where
+    the tile is one sub-block; the cell's four: 0.3203)."""
+    from flexflow_tpu.kernels.flash_attention import BD_SUB
+    side = min(seq, 1024)
+    h = seq // side
+    return (BD_SUB / side + h + 1) / (4 * h)
+
+
 def leg_block_diffusion(model_cfg, seq: int, per_chip_batch: int,
                         label: str, alpha: float = 1e-5) -> None:
     """``build_hybrid_conv_moe`` with ``"block_diffusion_attention"``
@@ -1403,8 +1417,9 @@ def leg_block_diffusion(model_cfg, seq: int, per_chip_batch: int,
     eval-mode loss (one mask, the configuration's) falls, the noising op
     announced a draw from the step's key and masked about half the
     tokens, every attention layer announced the mask and who draws it
-    (the flash kernels on a chip, which then skip the dead quadrant and,
-    from two tiles a half on, visit under half of the square), the loss
+    (the flash kernels on a chip, which then skip the dead quadrant and
+    the dead triangles' tiles and walk the noised x noised diagonal
+    tiles as sub-blocks: :func:`_bd_walked_share`), the loss
     its weights, nothing was dropped, and the step
     fits the chip. ``VALIDATION_BLOCK_DIFFUSION`` holds the mask, the
     draw and the gradients to the reference, and this leg names it."""
@@ -1448,12 +1463,13 @@ def leg_block_diffusion(model_cfg, seq: int, per_chip_batch: int,
     check(0.3 < masked < 0.7 and 0.5 < weight < 2.0,
           f"{label}: masked share {masked}, mean weight {weight}")
     if chip:
-        # one tile a half skips the dead quadrant alone (0.75); from
-        # two tiles a half on, the triangle and the off-diagonal too
+        # smaller tiles than the widest would visit less, never more
+        most = _bd_walked_share(seq)
         check(set(impls.values()) == {"flash"} == set(masks.values())
-              and live <= kept <= (0.75 if seq < 2048 else 0.5),
+              and live <= kept <= most + 1e-6,
               f"{label}: attention resolved to {impls}, the mask by "
-              f"{masks}, {kept} of the square visited at seq {seq}")
+              f"{masks}, {kept} of the square visited at seq {seq} "
+              f"(at most {most:.4f})")
     else:
         check(kept == 1.0, f"{label}: off the kernels every pair is "
                            f"computed, not {kept}")

@@ -8,6 +8,19 @@ noising op, the weighted loss or ``build_hybrid_conv_moe``'s
     python3 examples/tpu_validate_block_diffusion.py [--seeds 1 2 3]
         [--seq 4096] [--skip-kernels] [--skip-forward] [--skip-gradients]
         [--gradient-variants default xla float32] [--load-seeds 1 2 ...]
+    python3 examples/tpu_validate_block_diffusion.py --time-kernels
+        [--sub 128 256 512] [--tree _parent]
+
+``--time-kernels`` does one thing and stops: the three flash kernels
+ALONE at the cell's shapes (32 query heads on 4 key/value heads of 128,
+bf16, 2 x ``--seq`` positions), sixteen forward + backward pairs in one
+jit, ms a call on the device's clock and the share of the square each
+grid computes, one JSON line a sub-block side of ``--sub`` (the noised
+x noised diagonal tiles' walk, ``kernels/flash_attention.py::BD_SUB``;
+default: the tree's own); ``--tree DIR`` times another checkout's
+package (a ``git archive`` of the parent: every tile whole). It is the
+go / no-go of a change to those kernels and how ``BD_SUB`` was chosen
+(1.5 min a tree).
 
 The model is ``benchmarks/configs/sdar_30b_a3b.json`` through the normal
 path (``FFModel`` -> ``build_hybrid_conv_moe`` -> ``compile``), the
@@ -48,10 +61,13 @@ reference ``benchmarks/reference/block_diffusion_moe_ref.py`` (float32,
      operands. ``correct`` sees no gradient.
 """
 import argparse
+import importlib
 import json
 import math
 import os
 import sys
+import tempfile
+import time
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +76,9 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+if "--tree" in sys.argv:        # another checkout's package, ahead of ours
+    sys.path.insert(0, os.path.abspath(
+        sys.argv[sys.argv.index("--tree") + 1]))
 
 from benchmarks.harness import cells  # noqa: E402
 from examples.tpu_validate_latent_moe import (  # noqa: E402
@@ -129,6 +148,85 @@ def kernels(length, block):
               live <= g["visited_pairs"] < square / 2,
               f"visits {share:.4f} of the square, the mask attends "
               f"{live / square:.4f}")
+
+
+def time_kernels(length, block, subs, calls=16):
+    """The three kernels ALONE at the cell's shapes (32 query heads on 4
+    key/value heads of 128, bf16, 2 L positions): ``calls`` forward +
+    backward pairs in one jit, each waiting on the one before, and per
+    kernel the device's own ms a call (a profiler trace, by op name) and
+    the share of the square its grid visits (the ``flash.grid``
+    instants); the jit's trace, lowering and compile seconds beside them
+    (an unrolled kernel body is paid there, and so in ``setup_s``). One
+    JSON line a sub-block side of ``subs`` (None: the tree's own; a tree
+    without ``BD_SUB``, the parent of PR 65, scores every tile whole)."""
+    from benchmarks.harness import trace_reduce
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    ks = jax.random.split(jax.random.key(65), 4)
+    q, k, v, w = (jax.random.normal(ks[i], (1, n, 2 * length, 128),
+                                    jnp.bfloat16)
+                  for i, n in enumerate((32, 4, 4, 32)))
+    square = 32 * (2 * length) ** 2
+
+    def pairs(q, k, v):
+        for _ in range(calls):          # the next pair waits on this one
+            o, pull = jax.vjp(lambda *x: fa.flash_attention(
+                *x, block_diffusion=(length, block)), q, k, v)
+            dq, dk, dv = pull(w)
+            q, k, v = q + 0 * (dq + o), k + 0 * dk, v + 0 * dv
+        return o, dq, dk, dv
+
+    first = None
+    for sub in subs or [None]:
+        if sub is not None:
+            fa.BD_SUB = sub
+        jax.clear_caches()
+        events.enable()
+        events.clear()
+        t0 = time.perf_counter()
+        traced = jax.jit(pairs).trace(q, k, v)
+        t1 = time.perf_counter()
+        lowered = traced.lower()
+        t2 = time.perf_counter()
+        fn = lowered.compile()
+        t3 = time.perf_counter()
+        grids = {e["attrs"]["kernel"]: e["attrs"] for e in events.events()
+                 if e["name"] == "flash.grid"}
+        events.clear()
+        events.disable()
+        out = jax.block_until_ready(fn(q, k, v))
+        with tempfile.TemporaryDirectory() as tmp:
+            jax.profiler.start_trace(tmp)
+            try:
+                jax.block_until_ready(fn(q, k, v))
+            finally:
+                jax.profiler.stop_trace()
+            ev = trace_reduce.extract(trace_reduce.find_xplane(tmp))
+        ms = {}         # by kernel where the op's name holds one's
+        for ops in ev["devices"].values():
+            for name, ns in trace_reduce.self_times(ops).items():
+                name = trace_reduce.op_name(name).rsplit(".", 1)[0]
+                name = next((n for n in grids if n in name), name)
+                ms[name] = ms.get(name, 0.0) + ns / calls / 1e6
+        first = first or out
+        far = max(float(rel(a, b)) for a, b in zip(out, first))
+        line = dict(
+            check="time_kernels", tree=os.path.abspath(fa.__file__).rsplit(
+                os.sep, 3)[0],
+            bd_sub=getattr(fa, "BD_SUB", 0), length=length,
+            block=block, calls=calls, device=jax.devices()[0].device_kind,
+            ms_a_call={n: round(ms.get(n, 0.0), 4) for n in grids},
+            ms_of_the_three=round(sum(ms.get(n, 0.0) for n in grids), 4),
+            other_ms_a_pair=round(sum(t for n, t in ms.items()
+                                      if n not in grids), 4),
+            visited_share={n: g["visited_pairs"] / square
+                           for n, g in grids.items()},
+            trace_s=round(t1 - t0, 3), lower_s=round(t2 - t1, 3),
+            compile_s=round(t3 - t2, 3), against_the_first=far)
+        READINGS[f"time_kernels sub {line['bd_sub']}"] = line
+        print(json.dumps(line), flush=True)
+        check(f"sub-blocks of {line['bd_sub']}: the outputs are the first "
+              f"variant's", far < 1e-2, f"rel {far:.3e}")
 
 
 def forward_checks(conf, ref, seq, seeds):
@@ -350,6 +448,15 @@ def main():
     ap.add_argument("--seq", type=int, default=4096)
     ap.add_argument("--load-seeds", type=int, nargs="*", default=[])
     ap.add_argument("--skip-kernels", action="store_true")
+    ap.add_argument("--time-kernels", action="store_true",
+                    help="the three kernels alone at the cell's shapes, "
+                    "then stop")
+    ap.add_argument("--sub", type=int, nargs="*", default=[],
+                    help="--time-kernels: sub-block sides to time in turn "
+                    "(default: the tree's own BD_SUB)")
+    ap.add_argument("--tree", default=ROOT,
+                    help="time or validate another checkout's package "
+                    "(a git archive of the parent in _parent/)")
     ap.add_argument("--skip-forward", action="store_true")
     ap.add_argument("--skip-gradients", action="store_true")
     ap.add_argument("--gradient-variants", nargs="+", default=["default"],
@@ -363,6 +470,10 @@ def main():
     with open(os.path.join(BENCH, "configs", "sdar_30b_a3b.json")) as f:
         conf = json.load(f)
     ref = cells.load_module(BENCH, "reference", "block_diffusion_moe_ref")
+    if args.time_kernels:
+        time_kernels(args.seq, conf["block_length"], args.sub)
+        print("READINGS " + json.dumps(READINGS), flush=True)
+        return 1 if FAILED else 0
     if not args.skip_kernels:
         kernels(args.seq, conf["block_length"])
     if not args.skip_forward:

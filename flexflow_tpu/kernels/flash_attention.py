@@ -31,8 +31,10 @@ A block-diffusion mask (``bd=(L, B)``, not causal, over ``2 L`` positions
 keys of its own block and the clean keys of earlier blocks, a clean
 query the clean keys of its own and earlier blocks) is a third static
 form of the same kind: drawn from the tile's positions, its dead tiles
-skipped and named as their live neighbours. With ``bd=()`` every kernel
-is the text it was (docs/kernels.md).
+skipped and named as their live neighbours, and a live noised x noised
+tile, where the mask is block-diagonal, scored as the sub-blocks of its
+diagonal alone (:func:`_bd_sub`). With ``bd=()`` every kernel is the
+text it was (docs/kernels.md).
 
 Grouped-query attention (k and v at fewer heads than q) is index
 arithmetic too: the kernels read the key/value heads in place, a query
@@ -91,14 +93,21 @@ def _tile_positions(iq, ik, block_q, block_k, keys_major):
 
 
 def _key_mask(iq, ik, block_q, block_k, kv_len, causal, keys_major=False,
-              window=0, bd=()):
+              window=0, bd=(), part=None):
     """Validity mask for one (q block, k block) tile; kv_len, window and
     bd are static. ``window``: a query sees the ``window`` keys that end
     with its own, ``k_pos > q_pos - window`` beside the causal edge.
     ``bd``: the block-diffusion mask alone (:func:`_bd_mask`; such a call
-    has no padded key)."""
+    has no padded key), and under it ``part = (q_off, k_off, side)``: the
+    mask of the ``side x side`` sub-block at those places of the tile
+    alone (:func:`_bd_diagonal`)."""
     if bd:
-        return _bd_mask(iq, ik, block_q, block_k, keys_major, bd)
+        if part is None:
+            return _bd_mask(iq * block_q, ik * block_k, block_q, block_k,
+                            keys_major, bd)
+        q_off, k_off, side = part
+        return _bd_mask(iq * block_q + q_off, ik * block_k + k_off, side,
+                        side, keys_major, bd)
     q_pos, k_pos = _tile_positions(iq, ik, block_q, block_k, keys_major)
     mask = k_pos < kv_len
     if causal:
@@ -108,8 +117,10 @@ def _key_mask(iq, ik, block_q, block_k, kv_len, causal, keys_major=False,
     return mask
 
 
-def _bd_mask(iq, ik, block_q, block_k, keys_major, bd):
-    """The block-diffusion mask of one tile, ``bd = (L, B)``: positions
+def _bd_mask(q0, k0, block_q, block_k, keys_major, bd):
+    """The block-diffusion mask of the ``block_q x block_k`` pairs from
+    query ``q0`` and key ``k0`` on (a tile, or a sub-block of one), ``bd
+    = (L, B)``: positions
     ``[0, L)`` are the noised copy, ``[L, 2 L)`` the clean one, and
     ``b(i) = (i mod L) // B``. A tile lies in one quadrant (``L`` is a
     multiple of every block), which two scalars say; with ``e`` = the
@@ -124,7 +135,6 @@ def _bd_mask(iq, ik, block_q, block_k, keys_major, bd):
     length, block = bd
     shape, q_axis = (((block_k, block_q), 1) if keys_major
                      else ((block_q, block_k), 0))
-    q0, k0 = iq * block_q, ik * block_k
     q_rel = q0 % length + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_rel = k0 % length + jax.lax.broadcasted_iota(jnp.int32, shape,
                                                    1 - q_axis)
@@ -156,6 +166,94 @@ def _bd_live(iq, ik, block_q, block_k, bd):
     return ((q_noised & k_noised & (kb1 >= qb0) & (kb0 <= qb1))
             | (q_noised & k_clean & (kb0 < qb1))
             | (q_clean & k_clean & (kb0 <= qb1)))
+
+
+#: side of the sub-blocks a live noised x noised tile is walked in
+#: (:func:`_bd_diagonal`). Timed alone on a v5e at cell 12's shapes (bh
+#: 32 on 4 k/v heads, 8,192 positions, d 128, bf16; ms a call, forward /
+#: ``bwd_dq`` / ``bwd_dkv``): the tiles whole 2.754 / 4.019 / 4.910; in
+#: sub-blocks of 512 2.622 / 3.758 / 4.570, of 256 2.617 / 3.644 / 4.417,
+#: of 128 2.580 / 3.609 / 4.334. The matrix unit still fills on 128 x 128
+#: x 128 products, but a sub-block is a chain of two or five dependent
+#: ones with the row reductions between them, so 7/8 of a tile's pairs
+#: gone is 6-12% of a call and not 14.6 (PERF.md section 6, PR 65).
+BD_SUB = 128
+
+
+def _bd_sub(block_q, block_k, bd):
+    """Side of the diagonal sub-blocks that a live noised x noised tile
+    (the forward: piece) of ``block_q x block_k`` pairs is walked in
+    under ``bd = (L, B)``, or 0: the tile is scored whole. In that
+    quadrant the mask is block-diagonal in blocks of ``B``, so where
+    ``B`` divides ``BD_SUB`` no pair off the tile's ``BD_SUB``-wide
+    diagonal blocks is attended. The walk wants ``BD_SUB`` to divide
+    both sides and the narrower side the wider (aligned tiles then nest,
+    so that a live tile holds its narrower side's whole diagonal), and a
+    tile wider than one sub-block."""
+    if not bd:
+        return 0
+    small, large = sorted((block_q, block_k))
+    walked = (BD_SUB % bd[1] == 0 and small % BD_SUB == 0
+              and large % small == 0 and BD_SUB < large)
+    return BD_SUB if walked else 0
+
+
+def _bd_noised(iq, ik, block_q, block_k, bd):
+    """Whether tile (``iq``, ``ik``) lies in the noised x noised
+    quadrant (ints, or traced program ids)."""
+    return (iq * block_q < bd[0]) & (ik * block_k < bd[0])
+
+
+def _bd_diagonal(iq, ik, block_q, block_k, bd, sub, body):
+    """``body((q_off, k_off, sub))`` for each ``sub x sub`` block on the
+    diagonal of the live noised x noised tile (``iq``, ``ik``), by its
+    first row's and first key's place in the tile. Along the narrower
+    side those are the multiples of ``sub``; the wider side holds the
+    narrower one (aligned tiles nest) from the remainder of its first
+    position by the wider side on: a static place where ``iq`` / ``ik``
+    are ints, which the forward, whose k block is whole q blocks, gets by
+    handing its piece's place in the k block for ``ik``. Unrolled, so
+    that the sub-blocks' slices of the scratch are static and Mosaic may
+    overlap one sub-block's chain of products with the next's: as a
+    ``fori_loop`` over ``pl.multiple_of`` offsets the three calls of
+    cell 12 took 11.12 ms where unrolled they take 10.55 (PERF.md
+    section 6, PR 65)."""
+    q_in = (ik * block_k) % block_q if block_q > block_k else 0
+    k_in = (iq * block_q) % block_k if block_k > block_q else 0
+
+    def place(start, r):
+        at = start + r * sub
+        return at if isinstance(at, int) else pl.multiple_of(at, sub)
+
+    for r in range(min(block_q, block_k) // sub):
+        body((place(q_in, r), place(k_in, r), sub))
+
+
+def _part_slices(part):
+    """``(rows, keys)``: what a tile's body reads of its q-side and its
+    k-side blocks and scratch: all of them, or the sub-block ``part =
+    (q_off, k_off, side)``'s."""
+    if part is None:
+        return slice(None), slice(None)
+    q_off, k_off, side = part
+    return pl.ds(q_off, side), pl.ds(k_off, side)
+
+
+def _bd_walked(live, iq, ik, block_q, block_k, bd, compute, place=None):
+    """Tile (``iq``, ``ik``) of a kernel (the forward: a piece) where it
+    is ``live``: ``compute()``, the tile whole, but where the
+    block-diffusion mask's shapes allow (:func:`_bd_sub`) a tile of the
+    noised x noised quadrant as ``compute(part)`` over its diagonal's
+    sub-blocks. ``place``: what :func:`_bd_diagonal` is handed for
+    ``ik`` (the forward's piece in its k block), ``ik`` itself if None."""
+    sub = _bd_sub(block_q, block_k, bd)
+    if sub:
+        noised = _bd_noised(iq, ik, block_q, block_k, bd)
+        pl.when(live & noised)(functools.partial(
+            _bd_diagonal, iq, ik if place is None else place, block_q,
+            block_k, bd, sub, compute))
+        live = live & ~noised
+    pl.when(live)(compute)
 
 
 def _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k, rate,
@@ -294,36 +392,44 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
         l_sc[:] = jnp.zeros_like(l_sc)
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    def _piece(c):
+    def _piece(c, part=None):
+        """Piece ``c`` of the k block, whole, or its sub-block ``part``
+        (:func:`_bd_diagonal`): the same update on those rows' running
+        statistics and accumulator alone."""
         piece = ik * pieces + c              # among all pieces of the row
         keys = slice(None) if pieces == 1 else pl.ds(
             pl.multiple_of(c * piece_k, piece_k), piece_k)
-        q = q_ref[0]                         # (block_q, d)
+        rows, width = slice(None), piece_k
+        if part is not None:
+            q_off, k_off, width = part
+            rows, keys = pl.ds(q_off, width), pl.ds(c * piece_k + k_off,
+                                                    width)
+        q = q_ref[0, rows, :]                # (block_q, d)
         k = k_ref[0, keys, :]                # (piece_k, d)
         v = v_ref[0, keys, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         valid = _key_mask(iq, piece, block_q, piece_k, kv_len, causal,
-                          window=window, bd=bd)
+                          window=window, bd=bd, part=part)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref, keys))
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_sc[:]                                 # (block_q, 128)
+        m_prev = m_sc[rows, :]                           # (block_q, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - _lanes(m_new, piece_k))          # (block_q, piece_k)
+        p = jnp.exp(s - _lanes(m_new, width))            # (block_q, piece_k)
         # softmax denominator uses UNdropped p; dropout only scales the
         # numerator (matches dropout-on-probs semantics)
-        if piece_k % LANES == 0:
+        if width % LANES == 0:
             l_part = p[:, :LANES]
-            for g in range(1, piece_k // LANES):
+            for g in range(1, width // LANES):
                 l_part = l_part + p[:, g * LANES:(g + 1) * LANES]
         else:     # an explicit block Mosaic would not take: sum in lane 0
             lane = jax.lax.broadcasted_iota(jnp.int32, l_sc.shape, 1)
             l_part = jnp.where(lane == 0,
                                jnp.sum(p, axis=1, keepdims=True), 0.0)
-        l_sc[:] = l_sc[:] * alpha + l_part
+        l_sc[rows, :] = l_sc[rows, :] * alpha + l_part
         if dropout_rate > 0.0:
             keep = _tile_keep_mask(seed_ref, b, iq, piece,
                                    block_q, piece_k, dropout_rate)
@@ -331,8 +437,9 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_sc[:] = acc_sc[:] * _lanes(alpha, acc_sc.shape[1]) + pv
-        m_sc[:] = m_new
+        acc_sc[rows, :] = acc_sc[rows, :] * _lanes(alpha,
+                                                   acc_sc.shape[1]) + pv
+        m_sc[rows, :] = m_new
 
     # One piece's intermediates at a time: each piece runs in a scope of
     # its own. Unrolled inline, Mosaic kept several pieces' float32 and
@@ -344,10 +451,17 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs, sm_scale, causal,
     # the same at cell 3's); without it, where a condition that always
     # holds would be folded away, the scope is a loop's body (0-9% slower
     # than inline where inline compiled; PERF.md section 6, PR 32).
+    # A live piece of the noised x noised quadrant is walked in the
+    # sub-blocks of its diagonal, where the shapes allow (:func:`_bd_sub`);
+    # under a k block of whole q blocks the piece's place in it says
+    # where in the q block its keys' rows lie, statically.
     if causal or bd:
         for c in range(pieces):
-            pl.when(_piece_live(iq, ik * pieces + c, block_q, piece_k,
-                                window, bd))(functools.partial(_piece, c))
+            piece = ik * pieces + c
+            _bd_walked(_piece_live(iq, piece, block_q, piece_k, window, bd),
+                       iq, piece, block_q, piece_k, bd,
+                       functools.partial(_piece, c),
+                       place=piece if block_k % block_q else c)
     elif pieces == 1:
         _piece(0)
     else:
@@ -422,22 +536,28 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     live = _piece_live(iq, ik, block_q, block_k, window, bd) \
         if causal or bd else True
 
-    @pl.when(live)
-    def _compute():
-        k = k_ref[0]
+    def _compute(part=None):
+        """The tile, whole, or its sub-block ``part``
+        (:func:`_bd_diagonal`): those rows of ``dq`` from those keys."""
+        rows, keys = _part_slices(part)
+        width = block_k if part is None else part[2]
+        k = k_ref[0, keys, :]
         keep = _tile_keep_mask(seed_ref, b, iq, ik, block_q, block_k,
                                dropout_rate) if dropout_rate > 0.0 else None
-        q, lse = q_ref[0], _lanes(lse_sc[:], block_k)
+        q, lse = q_ref[0, rows, :], _lanes(lse_sc[rows, :], width)
         valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal,
-                          window=window, bd=bd)
+                          window=window, bd=bd, part=part)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref))
         p = _bwd_p(q, k, lse, valid, sm_scale, keys_major=False)
-        ds = _bwd_ds(p, do_ref[0], v_ref[0], _lanes(delta_sc[:], block_k),
+        ds = _bwd_ds(p, do_ref[0, rows, :], v_ref[0, keys, :],
+                     _lanes(delta_sc[rows, :], width),
                      keep, dropout_rate, sm_scale, keys_major=False)
-        dq_sc[:] += jax.lax.dot_general(
+        dq_sc[rows, :] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _bd_walked(live, iq, ik, block_q, block_k, bd, _compute)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -487,28 +607,33 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     if bd:
         live = _bd_live(iq, ik, block_q, block_k, bd)
 
-    @pl.when(live)
-    def _compute():
-        q, do = q_ref[0], do_ref[0]
+    def _compute(part=None):
+        """The tile, whole, or its sub-block ``part``
+        (:func:`_bd_diagonal`): those keys' ``dk`` and ``dv`` from those
+        rows."""
+        rows, keys = _part_slices(part)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
         # the forward's (seed, b, absolute positions) -> the forward's mask
         keep = _tile_keep_mask(
             seed_ref, b, iq, ik, block_q, block_k, dropout_rate,
             keys_major=True) if dropout_rate > 0.0 else None
-        k, lse = k_ref[0], lse_ref[0]
+        k, lse = k_ref[0, keys, :], lse_ref[0, :, rows]
         valid = _key_mask(iq, ik, block_q, block_k, kv_len, causal,
-                          keys_major=True, window=window, bd=bd)
+                          keys_major=True, window=window, bd=bd, part=part)
         if masked:
             valid = jnp.logical_and(valid, _attended(mask_ref))
         p = _bwd_p(q, k, lse, valid, sm_scale, keys_major=True)
         over_q = (((1,), (0,)), ((), ()))
-        dv_sc[:] += jax.lax.dot_general(
+        dv_sc[keys, :] += jax.lax.dot_general(
             _dropped(p, keep, dropout_rate).astype(do.dtype), do, over_q,
             preferred_element_type=jnp.float32)
-        ds = _bwd_ds(p, do, v_ref[0], delta_ref[0], keep, dropout_rate,
-                     sm_scale, keys_major=True)
-        dk_sc[:] += jax.lax.dot_general(
+        ds = _bwd_ds(p, do, v_ref[0, keys, :], delta_ref[0, :, rows], keep,
+                     dropout_rate, sm_scale, keys_major=True)
+        dk_sc[keys, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, over_q,
             preferred_element_type=jnp.float32)
+
+    _bd_walked(live, iq, ik, block_q, block_k, bd, _compute)
 
     @pl.when(step == steps - 1)
     def _finish():
@@ -730,8 +855,10 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0,
     the dead ones: ``live_pieces`` are those it computes. ``bd``: under
     the block-diffusion mask, whose live steps :func:`_bd_live` says
     and whose index maps are walked as they stand; ``visited_pairs`` are
-    then the pairs of the tiles (the forward's pieces) the call
-    computes, of which the mask attends ``bh (L L + L B)``."""
+    then the pairs the kernel's body is entered with: a live tile's (the
+    forward: a live piece's), but of one whose diagonal is walked
+    (``bd_sub``, :func:`_bd_sub`) its sub-blocks', of which the mask
+    attends ``bh (L L + L B)``."""
     nq, nk = sq // block_q, sk // block_k
     live = fetched = 0
     if bd:
@@ -742,6 +869,15 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0,
             live += sum(bool(_bd_live(*((t, row) if dkv else (row, t)),
                                       block_q, block_k, bd)) for t in along)
             fetched += len({name(row, t) for t in along})
+        # the forward scores a piece at a time, the others a tile
+        wide = _fwd_piece(block_k) if kernel == "fwd" else block_k
+        sub = _bd_sub(block_q, wide, bd)
+        visited = sum(
+            min(block_q, wide) * sub
+            if sub and _bd_noised(i, j, block_q, wide, bd)
+            else block_q * wide
+            for i in range(nq) for j in range(sk // wide)
+            if _bd_live(i, j, block_q, wide, bd))
     elif kernel == "bwd_dkv":            # rows are k blocks, q blocks stream
         for j in range(nk):
             first = _first_live_q(j, block_q, block_k) if causal else 0
@@ -764,16 +900,14 @@ def grid_steps(kernel, bh, sq, sk, block_q, block_k, causal, window=0,
     if kv_group > 1:
         out["kv_group"] = kv_group
     if bd:
-        out.update(block_diffusion="%dx%d" % bd,
-                   visited_pairs=bh * live * block_q * block_k)
+        out.update(block_diffusion="%dx%d" % bd, bd_sub=sub,
+                   visited_pairs=bh * visited)
     if kernel == "fwd":
         piece_k = _fwd_piece(block_k)
         out.update(piece_k=piece_k, live_pieces=bh * sum(
             not (causal or bd)
             or bool(_piece_live(i, p, block_q, piece_k, window, bd))
             for i in range(nq) for p in range(sk // piece_k)))
-        if bd:
-            out["visited_pairs"] = out["live_pieces"] * block_q * piece_k
     else:       # the row statistics the call is handed, and its tile's form
         out.update(stat_bytes=2 * bh * sq * 4,
                    tile="keys_major" if kernel == "bwd_dkv"
@@ -786,7 +920,9 @@ def _note_grid(kernel, q, k, block_q, block_k, causal, masked=False,
     """One ``flash.grid`` instant per emitted call (flat operands ``q``
     and ``k``), at trace time; a masked call's says so, a windowed
     call's says ``window=``, a grouped one's ``kv_group=``, one under
-    the block-diffusion mask ``block_diffusion=``."""
+    the block-diffusion mask ``block_diffusion=`` and ``bd_sub=``, the
+    side of the sub-blocks its noised x noised tiles are walked in (0:
+    they are scored whole)."""
     if events.enabled():
         events.instant("flash.grid", **grid_steps(
             kernel, q.shape[0], q.shape[1], k.shape[1], block_q, block_k,
@@ -1262,8 +1398,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     from the tile's positions (:func:`_bd_mask`), the dead tiles (the
     clean x noised quadrant, the clean x clean upper triangle, all but
     the diagonal tiles of noised x noised, the noised x clean tiles of
-    later blocks) skipped and named as live neighbours. The tiles are
-    derived for ``L`` positions, so that none straddles the halves; ``L``
+    later blocks) skipped and named as live neighbours, and a diagonal
+    tile of noised x noised walked as the ``BD_SUB``-wide sub-blocks of
+    its diagonal where ``B`` divides that side (:func:`_bd_sub`). The
+    tiles are derived for ``L`` positions, so that none straddles the halves; ``L``
     has to be a multiple of 128 (docs/kernels.md).
 
     Pads the key length to a multiple of 128 and the query length to a
@@ -1706,7 +1844,8 @@ def block_diffusion_mask(length: int, block: int) -> np.ndarray:
 def block_diffusion_visited(bh: int, length: int, block: int, d: int,
                             dtype, kv_group: int = 1) -> dict:
     """``{"fwd" | "bwd_dq" | "bwd_dkv": pairs}``: the pairs of the tiles
-    (the forward's pieces) each kernel's grid computes for a call of
+    (the forward's pieces; of a walked diagonal tile, :func:`_bd_sub`,
+    its sub-blocks alone) each kernel's grid computes for a call of
     ``bh`` (batch x query head)s under ``block_diffusion=(length,
     block)`` at head size ``d``, at the tiles the call derives from its
     shapes; the mask attends ``bh (L L + L B)`` of them."""

@@ -1010,7 +1010,9 @@ class MultiHeadAttentionOp(OpDef):
         pairs each kernel's grid visits) and the counters
         ``attn.bd_pairs`` (the 4 L L pairs a kernel, three kernels a
         layer) and ``attn.bd_visited_pairs`` (those the three grids
-        compute; off the kernels every pair is), whose quotient is the
+        compute, a noised x noised diagonal tile's sub-blocks where it
+        is walked in them; off the kernels every pair is), whose
+        quotient is the
         share of the square the layer pays for: 0.25 + B / 4 L is the
         mask's own."""
         from ..kernels.flash_attention import block_diffusion_visited

@@ -20,9 +20,11 @@ import numpy as np
 import pytest
 
 import rank_family as rf
-from flexflow_tpu.kernels.flash_attention import (_bd_live, _bd_live_k,
-                                                  _bd_live_q,
+from flexflow_tpu.kernels.flash_attention import (BD_SUB, _bd_live,
+                                                  _bd_live_k, _bd_live_q,
+                                                  _bd_sub, _fwd_piece,
                                                   block_diffusion_mask,
+                                                  block_diffusion_visited,
                                                   flash_attention,
                                                   grid_steps)
 from flexflow_tpu.models.nlp import SDARRankConfig, build_hybrid_conv_moe
@@ -111,14 +113,39 @@ def masked_softmax(q, k, v, mask):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-SQUARE = dict(block_q=128, block_k=128, bwd_block_q=128, bwd_block_k=128)
+def blocks_of(q, k, bwd_q=None, bwd_k=None):
+    return dict(block_q=q, block_k=k, bwd_block_q=bwd_q or q,
+                bwd_block_k=bwd_k or k)
+
+
+SQUARE = blocks_of(128, 128)
+WIDE = 2 * BD_SUB          # a tile of two sub-blocks a side
 KERNEL_CASES = {       # (L, B, blocks): a block that does not divide a tile,
     "one_tile_a_half": (128, 4, {}),          # tiles of several shapes
     "two_tiles_a_half": (256, 4, SQUARE),
     "block_24_in_tiles_of_128": (384, 24, SQUARE),
     "unequal_tiles": (256, 32, dict(block_q=64, block_k=256,
                                     bwd_block_q=64, bwd_block_k=128)),
+    # the noised x noised tiles walked along their diagonal (_bd_sub) ...
+    "walked_one_tile_a_half": (WIDE, 4, blocks_of(WIDE, WIDE)),
+    "walked_two_tiles_a_half": (2 * WIDE, 4, blocks_of(WIDE, WIDE)),
+    "walked_a_block_of_the_sub_blocks_side": (WIDE, BD_SUB,
+                                              blocks_of(WIDE, WIDE)),
+    "walked_a_wider_k_side": (2 * WIDE, 4, blocks_of(BD_SUB, 2 * WIDE,
+                                                 BD_SUB, WIDE)),
+    "walked_a_wider_q_side": (2 * WIDE, 4, blocks_of(WIDE, BD_SUB)),
+    # the cell's forward: pieces of 512 keys under a q block of 1,024 in
+    # a k block of whole q blocks, the rows' places static
+    "walked_pieces_of_a_k_block_of_whole_q_blocks": (
+        2048, 4, blocks_of(1024, 2048, 512, 1024)),
+    # ... and scored whole: a block that does not divide the sub-block
+    "whole_a_block_of_6": (3 * BD_SUB, 6, blocks_of(3 * BD_SUB, 3 * BD_SUB)),
+    "whole_a_block_wider_than_the_sub_block": (
+        2 * WIDE, WIDE, blocks_of(WIDE, WIDE)),
+    "whole_a_block_wider_at_one_tile_a_half": (
+        WIDE, WIDE, blocks_of(WIDE, WIDE)),
 }
+WALKED = {c for c in KERNEL_CASES if c.startswith("walked")}
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
@@ -138,20 +165,54 @@ def test_the_kernels_draw_the_mask(case):
             lambda q, k, v: jnp.sum(fn(q, k, v) * do), (0, 1, 2)))
 
     close(jax.jit(flash)(q, k, v), masked_softmax(q, k, v, mask), 1e-5)
-    (_, got), (_, want) = graded(flash)(q, k, v), graded(
-        functools.partial(masked_softmax, mask=mask))(q, k, v)
+    events.enable()
+    events.clear()
+    try:
+        (_, got) = graded(flash)(q, k, v)
+        subs = {e["attrs"]["kernel"][len("flash_attention_"):]:
+                e["attrs"]["bd_sub"] for e in events.events()
+                if e["name"] == "flash.grid"}
+    finally:
+        events.disable()
+        events.clear()
+    (_, want) = graded(functools.partial(masked_softmax, mask=mask))(q, k, v)
     for g, w in zip(got, want):
         close(g, w, 1e-5)
+    # each call says whether its diagonal tiles were walked
+    assert subs == dict.fromkeys(("fwd", "bwd_dq", "bwd_dkv"),
+                                 BD_SUB if case in WALKED else 0), subs
+
+
+def entered_pairs(mask, length, block_q, wide, sub):
+    """The pairs a kernel's body is entered with over one head, counted
+    from the table: a live tile's ``block_q x wide``, but, ``sub`` not 0,
+    of a live tile of the noised x noised quadrant those of its ``sub x
+    sub`` blocks that hold an attended pair."""
+    n = 2 * length
+    live = mask.reshape(n // block_q, block_q, n // wide, wide).any((1, 3))
+    pairs = int(live.sum()) * block_q * wide
+    if sub:
+        noised = mask[:length, :length]
+        tiles = noised.reshape(length // block_q, block_q, length // wide,
+                               wide).any((1, 3))
+        blocks = noised.reshape(length // sub, sub, length // sub,
+                                sub).any((1, 3))
+        pairs += int(blocks.sum()) * sub * sub \
+            - int(tiles.sum()) * block_q * wide
+    return pairs
 
 
 @pytest.mark.parametrize("length,block,block_q,block_k", [
     (4096, 4, 1024, 512), (4096, 4, 1024, 1024), (1536, 24, 128, 256),
-    (512, 4, 256, 128), (256, 128, 128, 128)])
+    (512, 4, 256, 128), (256, 128, 128, 128), (4096, 4, 1024, 4096),
+    (1024, 128, 256, 512), (1024, 256, 512, 512), (768, 6, 384, 384),
+    (2048, 4, 512, 1024), (1536, 4, 384, 256)])
 def test_the_grids_visit_the_live_tiles_and_name_them(length, block,
                                                       block_q, block_k):
     """A tile is live where the mask attends any of its pairs; a live
     step names its own block in both index maps; a dead one names a
-    block in range."""
+    block in range; ``visited_pairs`` are the pairs the kernel's body is
+    entered with, a walked diagonal tile's sub-blocks alone."""
     mask = block_diffusion_mask(length, block)
     nq, nk = 2 * length // block_q, 2 * length // block_k
     tiles = mask.reshape(nq, block_q, nk, block_k).any(axis=(1, 3))
@@ -171,12 +232,38 @@ def test_the_grids_visit_the_live_tiles_and_name_them(length, block,
         # nothing is copied for a dead step (but where a block of tokens
         # is a whole tile and a row's half of the keys has no live tile)
         assert block >= block_q or g["fetched_steps"] == g["live_steps"]
-        assert g["visited_pairs"] == 2 * tiles.sum() * block_q * block_k
+        assert g["bd_sub"] == _bd_sub(block_q, block_k, (length, block))
+        assert g["visited_pairs"] == 2 * entered_pairs(
+            mask, length, block_q, block_k, g["bd_sub"])
+    piece = _fwd_piece(block_k)
+    g = grid_steps("fwd", 2, 2 * length, 2 * length, block_q, block_k, False,
+                   0, 1, (length, block))
+    assert g["bd_sub"] == _bd_sub(block_q, piece, (length, block))
+    assert g["visited_pairs"] == 2 * entered_pairs(mask, length, block_q,
+                                                   piece, g["bd_sub"])
+    assert g["live_pieces"] == 2 * mask.reshape(
+        nq, block_q, 2 * length // piece, piece).any(axis=(1, 3)).sum()
+
+
+@pytest.mark.parametrize("block_q,block_k,block,sub", [
+    (1024, 1024, 4, BD_SUB), (1024, 512, 4, BD_SUB), (512, 1024, 4, BD_SUB),
+    (2 * BD_SUB, BD_SUB, 4, BD_SUB), (BD_SUB, 2 * BD_SUB, BD_SUB, BD_SUB),
+    (BD_SUB, BD_SUB, 4, 0),               # the tile is one sub-block
+    (1024, 1024, 6, 0), (1024, 1024, 2 * BD_SUB, 0),      # B and the side
+    (3 * BD_SUB, 2 * BD_SUB, 4, 0),       # tiles that do not nest
+    (BD_SUB + BD_SUB // 2, 3 * BD_SUB, 4, 0)])    # a side it does not divide
+def test_which_tiles_are_walked_along_their_diagonal(block_q, block_k,
+                                                     block, sub):
+    assert _bd_sub(block_q, block_k, (12 * 1024, block)) == sub
+    assert _bd_sub(block_q, block_k, ()) == 0
 
 
 def test_the_cells_grids_skip_the_dead_quadrant_and_triangle():
     """At the cell's shapes (L 4,096, B 4): 48 of 128 pieces, 24 of 64
-    tiles: 0.375 of the square for the mask's 0.2502."""
+    tiles, the four on the noised x noised diagonal as their sub-blocks:
+    (20 + 4 sub / 1,024) / 64 of the square for the mask's 0.2502."""
+    share = (20 + 4 * BD_SUB / 1024) / 64
+    assert share == {128: 0.3203125, 256: 0.328125, 512: 0.34375}[BD_SUB]
     fwd = grid_steps("fwd", 32, 8192, 8192, 1024, 4096, False, 0, 8,
                      (4096, 4))
     assert (fwd["live_pieces"], fwd["piece_k"]) == (32 * 48, 512)
@@ -184,8 +271,13 @@ def test_the_cells_grids_skip_the_dead_quadrant_and_triangle():
         g = grid_steps(kernel, 32, 8192, 8192, 1024, 1024, False, 0, 8,
                        (4096, 4))
         assert g["live_steps"] == 32 * 24 and g["steps"] == 32 * 64
+        assert g["bd_sub"] == fwd["bd_sub"] == BD_SUB
         assert g["visited_pairs"] == fwd["visited_pairs"] \
-            == 32 * 0.375 * 8192 ** 2
+            == 32 * share * 8192 ** 2
+    # the tiles the call derives from the cell's shapes, and the quotient
+    # of the program's counters attn.bd_visited_pairs / attn.bd_pairs
+    visited = block_diffusion_visited(32, 4096, 4, 128, jnp.bfloat16, 8)
+    assert sum(visited.values()) / (3 * 32 * 8192 ** 2) == share
 
 
 @pytest.mark.parametrize("what,kw", [

@@ -287,6 +287,23 @@ def test_leg_l_block_diffusion_tiny_on_the_cpu_mesh(capsys):
         chip_smoke.VALIDATION_BLOCK_DIFFUSION))
 
 
+@pytest.mark.parametrize("seq", [128, 512, 1024, 2048, 4096])
+def test_leg_l_bound_on_the_visited_share_is_the_grids_count(seq):
+    """What leg L allows the kernels' grids on a chip is what
+    ``grid_steps`` counts at the tiles the shapes derive (bf16, heads of
+    128): the diagonal tiles as their sub-blocks."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.flash_attention import (
+        BD_SUB, block_diffusion_visited)
+    visited = block_diffusion_visited(8, seq, 4, 128, jnp.bfloat16, 2)
+    want = sum(visited.values()) / (3 * 8 * (2 * seq) ** 2)
+    assert chip_smoke._bd_walked_share(seq) == want
+    assert want <= 0.75 and (seq > BD_SUB) == (want < 0.75)
+    if seq == 4096:             # the cell's
+        assert want == (20 + 4 * BD_SUB / 1024) / 64
+
+
 def test_a_loss_that_does_not_fall_fails_the_smoke(monkeypatch):
     class Stuck:
         def fit(self, **kw):

@@ -1703,3 +1703,41 @@ def test_the_block_diffusion_kernels_compile(v5e_devices, chip_locations,
 
     assert operands(masked) == operands(plain)
     assert "s8[" not in masked
+
+
+@pytest.mark.parametrize("blocks", [
+    dict(block_q=512, block_k=2048, bwd_block_q=512, bwd_block_k=1024),
+    dict(block_q=1024, block_k=512, bwd_block_q=1024, bwd_block_k=512),
+    dict(block_q=256, block_k=1024, bwd_block_q=256, bwd_block_k=256)],
+    ids=["a_wider_k_side", "a_wider_q_side", "pieces_wider_than_the_q_block"])
+def test_the_walked_diagonal_compiles_where_a_side_is_wider(
+        v5e_devices, chip_locations, blocks):
+    """PR 65: a live noised x noised tile is walked in the sub-blocks of
+    its diagonal. At cell 12's tiles every place is static; where one
+    side of a tile is wider, the narrower side's place in it comes from
+    the program ids: dynamic sublane offsets into the k-side blocks and
+    scratch, and in ``bwd_dkv`` under a wider q side a dynamic LANE
+    offset into the row statistics. Mosaic takes each."""
+    from flexflow_tpu.kernels.flash_attention import BD_SUB
+    from flexflow_tpu.obs import events
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    q = jax.ShapeDtypeStruct((1, 8, 4096, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 2, 4096, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, interpret=False,
+                            block_diffusion=(2048, 4), **blocks)
+        return jnp.sum(o.astype(jnp.float32))
+
+    events.enable()
+    events.clear()
+    try:
+        txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+        subs = [e["attrs"]["bd_sub"] for e in events.events()
+                if e["name"] == "flash.grid"]
+    finally:
+        events.disable()
+        events.clear()
+    assert _kernel_names(txt) == FLASH_NAMES
+    assert subs == [BD_SUB] * 3
+

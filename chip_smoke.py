@@ -75,6 +75,16 @@ whatever ``jax.devices()`` returns: one TPU chip or a four-chip host.
          kernels ran in; it looks at no selection and no gradient:
          ``examples/tpu_validate_sparse_index_moe.py`` does.
 
+  Leg M  blocks of ONE sub-layer (``build_hybrid_conv_moe`` with
+         ``"moe"``, ``"mamba"`` and ``"attention"`` layers): a small
+         model, then one chip's share of Nemotron 3 Super 120B-A12B at
+         published widths, 1 x 4096 tokens. It checks the [moe, mamba]
+         blocks, that every mixer announced its groups of B and C and
+         every expert layer its latent and ReLU-squared activation, the
+         experts' counters, and on the chip that the grouped scan and
+         the latent-wide token sum go by their kernels; it looks at no
+         gradient: ``examples/tpu_validate_nemotron_h.py`` does.
+
 It claims no speed. The times it prints are set-up facts of one run.
 It exits non-zero, before building anything, unless JAX reports a TPU;
 there is no option that lets it pass without one. Every later PR is
@@ -1480,6 +1490,80 @@ def leg_block_diffusion(model_cfg, seq: int, per_chip_batch: int,
     _compiled_step_size(ff, x, y, label)
 
 
+# ----------------------------------------------------------------------
+# Leg M — blocks of one sub-layer: grouped mixers, experts in a latent
+# ----------------------------------------------------------------------
+VALIDATION_NEMOTRON_H = "examples/tpu_validate_nemotron_h.py"
+
+
+def leg_latent_experts_hybrid(model_cfg, seq: int, per_chip_batch: int,
+                              label: str, alpha: float = 1e-5) -> None:
+    """``build_hybrid_conv_moe`` with blocks of ONE sub-layer (``"moe"``,
+    ``"mamba"``, ``"attention"``) through compile and fit with ``remat =
+    "blocks"``: the loss falls, the rematerialised run is the [moe,
+    mamba] pairs (the attention layer after them held whole) and keeps
+    every mixer's output, each mixer announced its groups and the heads
+    of the whole mixer, each expert layer its latent and its activation,
+    nothing is dropped and no layer leaves its row budget, and the step
+    fits the chip. On the chip the grouped scan and the way back to the
+    tokens over rows as wide as the latent go by their kernels.
+    ``VALIDATION_NEMOTRON_H`` holds the gradients to the reference, and
+    this leg names it."""
+    import jax
+
+    from flexflow_tpu.kernels import state_space
+    from flexflow_tpu.models.nlp import build_hybrid_conv_moe
+    from flexflow_tpu.obs import events
+    chip = jax.devices()[0].platform != "cpu"
+    ff, x, y = _lm_leg_setup(build_hybrid_conv_moe, model_cfg, seq,
+                             per_chip_batch, label, alpha)
+    _fit(ff, x, y, label)
+    _check_kept_outputs(label, ff)
+    kinds = list(model_cfg.layer_types)
+    pairs = sum(a == "moe" and b == "mamba"
+                for a, b in zip(kinds[::2], kinds[1::2]))
+    check(ff.executor._remat[1:3] == (6, pairs),
+          f"{label}: blocks {ff.executor._remat[:3]} for {pairs} pairs of "
+          f"{kinds}")
+    said = {name: sorted({e["attrs"]["layer"]: e["attrs"]
+                          for e in events.events()
+                          if e["name"] == name}.items())
+            for name in ("ssm.layer", "moe.route")}
+    say(f"{label}: instants " + "; ".join(
+        f"{n} {[k for k, _ in v]}" for n, v in said.items())
+        + "; scan " + str(sorted({a["impl"] for _, a in said["ssm.layer"]}))
+        + ", token sum "
+        + str(sorted({a["token_sum"] for _, a in said["moe.route"]})))
+    check([k for k, _ in said["ssm.layer"]] == sorted(
+        f"mamba_{i}" for i, k in enumerate(kinds) if k == "mamba")
+        and [k for k, _ in said["moe.route"]] == sorted(
+            f"experts_{i}" for i, k in enumerate(kinds) if k == "moe"),
+        f"{label}: the layers that announced themselves are not "
+        f"layer_types {kinds}: {said}")
+    check(all(a["groups"] == model_cfg.n_groups
+              and a["heads"] == model_cfg.mamba_num_heads
+              and a["chunk"] == model_cfg.chunk_size
+              for _, a in said["ssm.layer"])
+          and all(a["latent"] == model_cfg.moe_latent_size
+                  and a["activation"] == model_cfg.mlp_hidden_act
+                  and a["top_k"] == model_cfg.num_experts_per_tok
+                  and a["bias_step"] == model_cfg.router_bias_update_rate
+                  for _, a in said["moe.route"]),
+          f"{label}: sizes {said}")
+    if chip and state_space.takes_kernel(
+            model_cfg.chunk_size, model_cfg.mamba_num_heads,
+            model_cfg.mamba_head_dim, model_cfg.ssm_state_size,
+            model_cfg.n_groups):        # the cell's shapes, not tiny()'s
+        check({a["impl"] for _, a in said["ssm.layer"]} == {"kernel"}
+              and {a["token_sum"] for _, a in said["moe.route"]}
+              == {"kernel"},
+              f"{label}: off the kernels at seq {seq}: {said}")
+    _check_experts_counters(label)
+    say(f"{label}: not checked here: the gradients against the "
+        f"reference: python3 {VALIDATION_NEMOTRON_H}")
+    _compiled_step_size(ff, x, y, label)
+
+
 def main() -> int:
     import jax
     devs = jax.devices()
@@ -1496,6 +1580,7 @@ def main() -> int:
                                          KeyeRankConfig,
                                          KimiLinearRankConfig,
                                          LatentMoEConfig, LFM2RankConfig,
+                                         NemotronHRankConfig,
                                          Phi4FlashRankConfig,
                                          Qwen3NextRankConfig,
                                          SDARRankConfig,
@@ -1557,6 +1642,12 @@ def main() -> int:
         leg_block_diffusion(SDARRankConfig.tiny(), 512, 1, "L/small",
                             alpha=1e-3)
         leg_block_diffusion(SDARRankConfig(), 4096, 1, "L/sdar")
+        # a small model (plain scan, plain token sum), then the cell's
+        # shapes: the grouped scan at chunks of 128, experts in a latent
+        leg_latent_experts_hybrid(NemotronHRankConfig.tiny(), 1024, 1,
+                                  "M/small", alpha=1e-3)
+        leg_latent_experts_hybrid(NemotronHRankConfig(), 4096, 1,
+                                  "M/nemotron")
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

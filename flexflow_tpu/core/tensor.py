@@ -84,20 +84,28 @@ class WeightSpec:
 
     Analog of the reference's weight ``Tensor`` created by each layer
     (e.g. Linear kernel/bias) with an attached ``Initializer``.
+
+    ``sign_step`` > 0: the train step moves this weight by ``-sign_step
+    * sign(gradient)`` in place of the optimizer's update
+    (``Executor._apply_update``); the op that owns it says, through a
+    term of the loss whose value is zero, what that gradient is.
     """
 
-    __slots__ = ("name", "shape", "dtype", "initializer", "init_args", "create_grad")
+    __slots__ = ("name", "shape", "dtype", "initializer", "init_args",
+                 "create_grad", "sign_step")
 
     def __init__(self, name: str, shape: Sequence[int],
                  dtype: DataType = DataType.DT_FLOAT,
                  initializer: InitializerType = InitializerType.GLOROT_UNIFORM,
-                 init_args: Optional[dict] = None, create_grad: bool = True):
+                 init_args: Optional[dict] = None, create_grad: bool = True,
+                 sign_step: float = 0.0):
         self.name = name
         self.shape = tuple(int(s) for s in shape)
         self.dtype = DataType(dtype)
         self.initializer = initializer
         self.init_args = init_args or {}
         self.create_grad = create_grad
+        self.sign_step = float(sign_step)
 
     def __repr__(self):
         return f"WeightSpec({self.name}, {self.shape}, {self.initializer.value})"

@@ -556,6 +556,12 @@ class Executor:
         self.seed = seed
         # the tensor that weighs the loss's rows (FFModel.set_loss_weights)
         self._loss_weights_tensor = loss_weights
+        # (layer, weight) -> step, of the weights that move by the sign
+        # of their gradient and not by the optimizer (``WeightSpec.
+        # sign_step``: a router's choice bias under its balancing rule)
+        self._sign_steps = {(l.name, w.name): w.sign_step
+                            for l in program.layers for w in l.weights
+                            if w.sign_step}
         self._train_step = None
         self._eval_step = None
         # ZeRO-1 (runtime/zero.py): NamedSharding pytree for the updated
@@ -1398,6 +1404,10 @@ class Executor:
                 new_opt_state = jax.tree.map(
                     jax.lax.with_sharding_constraint,
                     new_opt_state, self.opt_state_constraints)
+        for (layer, w), rate in self._sign_steps.items():
+            old = params[layer][w]
+            new_params[layer] = {**new_params[layer], w: old - (
+                rate * jnp.sign(grads[layer][w])).astype(old.dtype)}
         return new_params, new_opt_state
 
     # ------------------------------------------------------------------

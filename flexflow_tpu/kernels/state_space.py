@@ -39,6 +39,16 @@ out the block's share of ``dB`` and ``dC``, summed over the blocks
 outside. ``G`` comes in twice, along the lanes and down the rows (it is
 1 MB), so ``G_i - G_j`` is a row minus a column.
 
+With several groups of ``B`` and ``C`` (head ``h`` reads group ``h // (H
+/ groups)``) a block of heads lies inside ONE group (``takes_kernel``
+asks that a group is whole blocks of eight heads), so the only thing
+that changes is which ``N`` columns a grid step reads: ``B`` and ``C``
+come in as ``(B, T, groups N)``, the groups side by side, and the block
+specs' index maps name the step's group (``block // blocks a group``);
+the backward's shares of ``dB`` and ``dC`` are summed over each group's
+blocks and laid side by side again. One group is the case of one: every
+block's group is 0 and the sum runs over all blocks.
+
 Every exponent taken is a difference of running log-decays that is <= 0
 (masked to 0 under the diagonal before the exponential, to nothing after
 it) or ``G`` itself, as in the plain code and for its reason.
@@ -89,26 +99,31 @@ F32 = jnp.float32
 _HIGHEST, _DEFAULT = jax.lax.Precision.HIGHEST, jax.lax.Precision.DEFAULT
 
 
-def takes_kernel(chunk: int, heads: int, head_dim: int, state: int) -> bool:
+def takes_kernel(chunk: int, heads: int, head_dim: int, state: int,
+                 groups: int = 1) -> bool:
     """Whether these shapes run the kernels: a chunk in whole tiles (one
     or two: ``MAX_CHUNK``), the state in whole lanes, a head's channels
     whole sublane tiles of bf16 (16 rows), and the heads in blocks of
     eight (``G``'s rows a step: a sublane tile) of no more than
-    ``BLOCK_ROWS`` channels, or all in one such block."""
+    ``BLOCK_ROWS`` channels, or all in one such block. With several
+    ``groups`` of B and C a block lies inside ONE group (whole eights of
+    a group's heads: the step reads that group's B and C)."""
     return (chunk % LANES == 0 and 0 < chunk <= MAX_CHUNK
             and state % LANES == 0 and state > 0
             and head_dim % 16 == 0 and head_dim > 0
-            and 0 < heads_per_block(heads, head_dim) * head_dim
+            and groups > 0 and heads % groups == 0
+            and 0 < heads_per_block(heads, head_dim, groups) * head_dim
             <= BLOCK_ROWS)
 
 
-def heads_per_block(heads: int, head_dim: int) -> int:
-    """Heads a grid step takes: the most eights of them that divide
-    ``heads`` within ``BLOCK_ROWS`` channels, or all of them."""
+def heads_per_block(heads: int, head_dim: int, groups: int = 1) -> int:
+    """Heads a grid step takes: the most eights of them that divide a
+    group's ``heads / groups`` within ``BLOCK_ROWS`` channels, or, with
+    one group, all of them (with several: 0, no block there is)."""
     n = BLOCK_ROWS // head_dim // 8 * 8
-    while n and heads % n:
+    while n and heads // groups % n:
         n -= 8
-    return n or heads
+    return n or (heads if groups == 1 else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -290,25 +305,33 @@ def _bwd_kernel(x_ref, dt_ref, gr_ref, gc_ref, bm_ref, bmt_ref, cm_ref,
 # ---------------------------------------------------------------------------
 # the calls
 # ---------------------------------------------------------------------------
-def _layout(x, c, p, backward):
+def _layout(x, c, p, backward, groups=1):
     """Grid, heads a step and the block specs by kind, for ``x`` (B, H
     P, T) in chunks of ``c``; the backward walks the chunks last to
-    first."""
+    first. With several ``groups`` the B and C operands hold the groups'
+    ``N`` columns (rows, transposed) side by side, and a block of heads
+    reads its own group's by the block index."""
     b, hp, t = x.shape
     m = t // c
-    n_heads = heads_per_block(hp // p, p)
+    n_heads = heads_per_block(hp // p, p, groups)
+    per_group = hp // p // groups // n_heads    # blocks of heads a group
 
     def chunk(j):
         return m - 1 - j if backward else j
+
+    def group(k):
+        return k // per_group
 
     def rows_by_tokens(rows):       # (B, blocks x rows, T): x, dt, G
         return pl.BlockSpec((None, rows, c), lambda i, k, j: (i, k, chunk(j)))
 
     def tokens_by(cols):            # (B, T, cols): B, C
-        return pl.BlockSpec((None, c, cols), lambda i, k, j: (i, chunk(j), 0))
+        return pl.BlockSpec((None, c, cols),
+                            lambda i, k, j: (i, chunk(j), group(k)))
 
     def by_tokens(rows):            # (B, rows, T): B^T, C^T
-        return pl.BlockSpec((None, rows, c), lambda i, k, j: (i, 0, chunk(j)))
+        return pl.BlockSpec((None, rows, c),
+                            lambda i, k, j: (i, group(k), chunk(j)))
 
     def of_block(rows, cols):       # (B, M, blocks, rows, cols)
         return pl.BlockSpec((None, None, None, rows, cols),
@@ -318,13 +341,13 @@ def _layout(x, c, p, backward):
             by_tokens, of_block)
 
 
-def _cost(kernel, x, c, n, p):
+def _cost(kernel, x, c, n, p, groups=1):
     """What a call computes and moves, for XLA's scheduler: the products
     of the upper tiles and of the states, one exponential an entry of
     ``L``, the slabs in and out."""
     b, hp, t = x.shape
     tiles = c // LANES
-    blocks = hp // (heads_per_block(hp // p, p) * p)
+    blocks = hp // (heads_per_block(hp // p, p, groups) * p)
     entries = b * (t // c) * (hp // p) * (tiles * (tiles + 1) // 2) \
         * LANES * LANES
     runs = 1 if kernel == "fwd" else 3      # x M^T | and dY M, x^T dY
@@ -338,7 +361,7 @@ def _cost(kernel, x, c, n, p):
 
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
-_STATIC = ("c", "p", "mdt", "interpret")
+_STATIC = ("c", "p", "mdt", "interpret", "groups")
 
 
 def _down_rows(gr, c, n_heads):
@@ -350,11 +373,11 @@ def _down_rows(gr, c, n_heads):
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _fwd_call(x, dt, gr, bm, cm, c, p, mdt, interpret):
+def _fwd_call(x, dt, gr, bm, cm, c, p, mdt, interpret, groups=1):
     b, hp, t = x.shape
-    n = bm.shape[-1]
+    n = bm.shape[-1] // groups
     grid, n_heads, rows_by_tokens, tokens_by, by_tokens, of_block = _layout(
-        x, c, p, False)
+        x, c, p, False, groups)
     rows = n_heads * p
     return pl.pallas_call(
         functools.partial(_fwd_kernel, mdt=mdt, p=p),
@@ -367,16 +390,18 @@ def _fwd_call(x, dt, gr, bm, cm, c, p, mdt, interpret):
                    jax.ShapeDtypeStruct((b, t // c, grid[1], rows, n), F32)],
         scratch_shapes=[pltpu.VMEM((c, c), F32), pltpu.VMEM((rows, n), F32)],
         compiler_params=_PARAMS, interpret=interpret,
-        cost_estimate=_cost("fwd", x, c, n, p), name="state_space_fwd",
+        cost_estimate=_cost("fwd", x, c, n, p, groups),
+        name="state_space_fwd",
     )(x, dt, gr, _down_rows(gr, c, n_heads), bm, jnp.swapaxes(cm, 1, 2))
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _bwd_call(x, dt, gr, bm, cm, starts, dy, c, p, mdt, interpret):
+def _bwd_call(x, dt, gr, bm, cm, starts, dy, c, p, mdt, interpret,
+              groups=1):
     b, hp, t = x.shape
-    n = bm.shape[-1]
+    n = bm.shape[-1] // groups
     grid, n_heads, rows_by_tokens, tokens_by, by_tokens, of_block = _layout(
-        x, c, p, True)
+        x, c, p, True, groups)
     rows, m = n_heads * p, t // c
     a_part = pl.BlockSpec((None, None, n, c),
                           lambda i, k, j: (k, i, 0, m - 1 - j))
@@ -398,30 +423,38 @@ def _bwd_call(x, dt, gr, bm, cm, starts, dy, c, p, mdt, interpret):
                         pltpu.VMEM((rows, c), F32),
                         pltpu.VMEM((rows, c), F32)],
         compiler_params=_PARAMS, interpret=interpret,
-        cost_estimate=_cost("bwd", x, c, n, p), name="state_space_bwd",
+        cost_estimate=_cost("bwd", x, c, n, p, groups),
+        name="state_space_bwd",
     )(x, dt, gr, _down_rows(gr, c, n_heads), bm, jnp.swapaxes(bm, 1, 2), cm,
       jnp.swapaxes(cm, 1, 2), starts, dy)
-    # a block of heads' share of dB and dC a chunk: summed here
-    return (dx, ddt, dg, jnp.swapaxes(jnp.sum(dbmt, 0), 1, 2),
-            jnp.swapaxes(jnp.sum(dcmt, 0), 1, 2))
+    # a block of heads' share of dB and dC a chunk: summed here, over
+    # each group's own blocks
+    def by_group(part):         # (blocks, B, N, T) -> (B, T, groups N)
+        part = jnp.sum(part.reshape((groups, -1) + part.shape[1:]), 1)
+        return jnp.transpose(part, (1, 3, 0, 2)).reshape(b, t, groups * n)
+
+    return dx, ddt, dg, by_group(dbmt), by_group(dcmt)
 
 
-def _note(kernel, layer, x, c, p):
+def _note(kernel, layer, x, c, p, groups):
     """One ``ssm.kernel`` instant per emitted call, at trace time."""
     if events.enabled():
         b, hp, t = x.shape
-        n_heads = heads_per_block(hp // p, p)
+        n_heads = heads_per_block(hp // p, p, groups)
         events.instant("ssm.kernel", kernel=kernel, layer=layer, chunk=c,
                        chunks=b * t // c, heads_per_step=n_heads,
+                       groups=groups,
                        grid_steps=b * (t // c) * (hp // p) // n_heads)
 
 
-def _noted_fwd_call(x, dt, gr, bm, cm, c, p, mdt, layer, interpret):
-    _note("fwd", layer, x, c, p)
-    return tuple(_fwd_call(x, dt, gr, bm, cm, c, p, mdt, interpret))
+def _noted_fwd_call(x, dt, gr, bm, cm, c, p, mdt, layer, interpret,
+                    groups):
+    _note("fwd", layer, x, c, p, groups)
+    return tuple(_fwd_call(x, dt, gr, bm, cm, c, p, mdt, interpret, groups))
 
 
-_scan = jax.custom_vjp(_noted_fwd_call, nondiff_argnums=(5, 6, 7, 8, 9))
+_scan = jax.custom_vjp(_noted_fwd_call,
+                       nondiff_argnums=(5, 6, 7, 8, 9, 10))
 
 
 def _scan_fwd(x, dt, gr, bm, cm, *static):
@@ -429,25 +462,27 @@ def _scan_fwd(x, dt, gr, bm, cm, *static):
     return (y, starts), (x, dt, gr, bm, cm, starts)
 
 
-def _scan_bwd(c, p, mdt, layer, interpret, res, cts):
-    _note("bwd", layer, res[0], c, p)
+def _scan_bwd(c, p, mdt, layer, interpret, groups, res, cts):
+    _note("bwd", layer, res[0], c, p, groups)
     # a custom_vjp's backward is traced outside the forward's scopes:
     # the recurrence's share of a step has to hold this call too. (The
     # states are handed out for the tests to read, not to be pulled
     # back through.)
     with jax.named_scope("ssm.scan"):
-        return _bwd_call(*res, cts[0], c, p, mdt, interpret)
+        return _bwd_call(*res, cts[0], c, p, mdt, interpret, groups)
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
 def scan_chunks(x, dt, big_g, bm, cm, chunk, mdt, *, layer=None,
-                interpret=None):
+                interpret=None, groups=1):
     """The recurrence over whole chunks from a zero state, by the
     kernels, channels first: ``x`` (B, H, P, T), ``dt`` the step sizes
     and ``big_g`` the running log-decay inside each chunk (B, H, T),
-    ``bm``, ``cm`` (B, T, N); float32, ``T`` whole chunks. Returns ``y``
+    ``bm``, ``cm`` (B, T, groups N), a group's ``N`` columns after the
+    one before (head ``h`` reads group ``h // (H / groups)``); float32,
+    ``T`` whole chunks. Returns ``y``
     (B, H, P, T) float32 and the state each chunk starts from, (B, M, H,
     P, N) (what the backward keeps; no cotangent is taken for it).
     ``layer`` names the caller in the ``ssm.kernel`` instants."""
@@ -455,5 +490,5 @@ def scan_chunks(x, dt, big_g, bm, cm, chunk, mdt, *, layer=None,
         interpret = pallas_interpret()
     b, h, p, t = x.shape
     y, starts = _scan(x.reshape(b, h * p, t), dt, big_g, bm, cm, chunk, p,
-                      jnp.dtype(mdt), layer, bool(interpret))
+                      jnp.dtype(mdt), layer, bool(interpret), int(groups))
     return y.reshape(x.shape), starts.reshape(b, t // chunk, h, p, -1)

@@ -476,22 +476,28 @@ class FFModel:
         (``ops.recurrent_ops.StateSpaceMixerOp``): ``num_heads`` heads of
         ``head_dim`` channels, each a ``head_dim x state`` state under a
         scalar decay a token; x, B and C through one causal depthwise
-        convolution of ``taps`` positions with a bias, B and C shared by
-        all heads (``groups`` 1: several groups are not built), computed
-        in chunks of ``chunk`` positions, a gated RMSNorm (``eps``) over
-        all channels before the output projection."""
-        if min(taps, num_heads, head_dim, state, chunk) < 1:
+        convolution of ``taps`` positions with a bias; ``groups`` of B
+        and C, head ``h`` reading group ``h // (num_heads / groups)``;
+        computed in chunks of ``chunk`` positions, a gated RMSNorm
+        (``eps``) whose mean square runs over each group's channels
+        before the output projection. A share of a mixer's heads is a
+        mixer of whole groups (it needs nothing of its neighbours'
+        before the output projection, whose part it gives)."""
+        if min(taps, num_heads, head_dim, state, chunk, groups) < 1:
             raise ValueError(
-                f"{num_heads} heads of {head_dim} x {state} in chunks of "
-                f"{chunk} behind a convolution of {taps} taps")
-        if groups != 1:
-            raise ValueError(f"{groups} groups of B and C: a state-space "
-                             f"mixer is built with one")
+                f"{num_heads} heads of {head_dim} x {state} in {groups} "
+                f"groups, chunks of {chunk}, behind a convolution of "
+                f"{taps} taps")
+        if num_heads % groups:
+            raise ValueError(f"{num_heads} heads in {groups} groups of B "
+                             f"and C: a group is whole heads")
+        # a graph of one group and all its heads is the one it was
+        more = {} if groups == 1 else {"groups": int(groups)}
         return self._unary(OperatorType.OP_STATE_SPACE_MIXER, input, name,
                            num_heads=int(num_heads),
                            head_dim=int(head_dim), state=int(state),
                            taps=int(taps), chunk=int(chunk),
-                           eps=float(eps))
+                           eps=float(eps), **more)
 
     def selective_scan_mixer(self, input: Tensor, inner: int, state: int,
                              dt_rank: int, taps: int, chunk: int,
@@ -601,12 +607,22 @@ class FFModel:
                        shared_gate: bool = False,
                        choice_bias: bool = True,
                        router_repeats: int = 1,
+                       latent: int = 0,
+                       activation: str = "swiglu",
+                       bias_step: float = 0.0,
                        name: Optional[str] = None) -> Tensor:
         """One sparse, dropless mixture-of-experts feed-forward layer
         (``ops.moe_ops.RoutedExpertsOp``): ``scoring`` (``"sigmoid"``
         with a bias-corrected choice, or ``"softmax"`` with none) over
-        ``num_experts``, the top ``top_k``, SwiGLU experts of
-        width ``expert_dim`` and a shared one of ``shared_dim`` (0: none).
+        ``num_experts``, the top ``top_k``, experts of width
+        ``expert_dim`` and a shared one of ``shared_dim`` (0: none).
+        ``activation``: ``"swiglu"``, three matrices an expert
+        (``w_down(silu(w_gate x) * w_up x)``), or ``"relu2"``, two
+        (``w_down relu(w_up x)^2``, no gate matrix), in the routed
+        experts and the shared one alike. ``latent`` l > 0: the routed
+        experts read ``x w_latent_in`` (l wide) and their weighted sum
+        goes back through ``w_latent_out`` (l to hidden), both weights
+        the op's; the router and the shared expert read ``x``.
         ``experts_held`` (default: all) and ``first_held`` say which
         experts' weights live here: the layer routes over all of them
         and computes the part of the result that its own give.
@@ -620,7 +636,10 @@ class FFModel:
         ``num_experts / r`` columns drawn and repeated r times, so that
         at those weights every token scores alike in each of r shares
         of the experts (its top ``top_k`` lie evenly over the shares
-        where r divides ``top_k``); the op's mathematics is unchanged."""
+        where r divides ``top_k``); the op's mathematics is unchanged.
+        ``bias_step`` u > 0: the choice's bias follows the balancing
+        rule, ``bias_i -= u * sign(load_i - mean load)`` after every
+        training step, over all ``num_experts``; 0: it stays as drawn."""
         held = num_experts if experts_held is None else experts_held
         if not 0 <= first_held <= first_held + held <= num_experts:
             raise ValueError(
@@ -634,6 +653,9 @@ class FFModel:
                 or (scoring == "softmax" and bias_std):
             raise ValueError(f"scores by {scoring!r} with a choice bias "
                              f"of spread {bias_std}")
+        if activation not in ("swiglu", "relu2") or latent < 0:
+            raise ValueError(f"experts of activation {activation!r} in a "
+                             f"latent of {latent}")
         more = {} if rows_factor == 2 else {"rows_factor": int(rows_factor)}
         if scoring != "sigmoid":
             more["scoring"] = scoring
@@ -651,6 +673,15 @@ class FFModel:
                 raise ValueError(f"router_repeats {router_repeats} of "
                                  f"{num_experts} experts")
             more["router_repeats"] = int(router_repeats)
+        if latent:
+            more["latent"] = int(latent)
+        if activation != "swiglu":
+            more["activation"] = activation
+        if bias_step:
+            if bias_step < 0 or scoring != "sigmoid":
+                raise ValueError(f"a bias step of {bias_step} under "
+                                 f"scores by {scoring!r}")
+            more["bias_step"] = float(bias_step)
         return self._unary(OperatorType.OP_ROUTED_EXPERTS, input, name,
                            num_experts=num_experts, top_k=top_k,
                            expert_dim=expert_dim, shared_dim=shared_dim,

@@ -1639,6 +1639,178 @@ class Phi4FlashRankConfig(HybridConvMoEConfig):
                    mamba_chunk_size=16)
 
 
+_NEMOTRON_KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
+
+
+@dataclasses.dataclass
+class NemotronHRankConfig(HybridConvMoEConfig):
+    """What ONE chip holds of NVIDIA-Nemotron-3-Super-120B-A12B
+    (``model_type: nemotron_h``, 120B parameters, 12B a token) as rank 0
+    of a 4-chip tensor-parallel group, 16 such groups sharing each
+    layer's experts (the benchmark's ``nemotron3_super_120b_a12b``). A
+    block of this family is ONE sub-layer, ``h += Mix(norm(h))``, its
+    kind a letter of ``hybrid_override_pattern``: ``M`` a Mamba-2 mixer
+    (``mamba_num_heads`` heads of ``mamba_head_dim`` with a state of
+    ``ssm_state_size`` in ``n_groups`` groups of B and C, ``conv_kernel``
+    taps with a bias, chunks of ``chunk_size``, the gated norm's mean
+    square over a group's channels); ``*`` grouped-query attention with
+    NO positional embedding, no q/k norm, scores times ``head_dim **
+    -0.5``; ``E`` a LatentMoE feed-forward: a sigmoid router over
+    ``n_routed_experts`` published experts (the top
+    ``num_experts_per_tok`` of score + bias, gates normalised and times
+    ``routed_scaling_factor``) and a shared expert of
+    ``moe_shared_expert_intermediate_size`` both read the stream, the
+    routed experts work in a latent of ``moe_latent_size`` between two
+    projections, and every expert is ``W2 relu(W1 .)^2``
+    (``mlp_hidden_act: relu2``: no gate matrix). Here: published layers
+    26 to 36 (``EMEMEMEMEM*``, the first whole period of 11), Mamba heads
+    0 to 31 of 128 with groups 0 and 1 of 8, query heads 0 to 7 of 32 on
+    key/value head 0 of 2, experts 0 to 7 of 512, one of eight slices of
+    the vocabulary; every width as published. The head shares' part of
+    each output projection goes on as it is: the group's all-reduce is
+    not run.
+
+    The fields after the parent's carry ``config.json``'s keys by their
+    names; ``n_routed_experts`` counts the experts HELD (it is copied
+    over the parent's ``num_experts``) and ``*_published`` give the
+    whole model's counts. The properties at the end hand the builder the
+    sizes under the names :class:`GraniteHybridRankConfig` gave them.
+    The multi-token-prediction module (``mtp_hybrid_override_pattern``)
+    is not built."""
+    vocab_size: int = 16384
+    hidden_size: int = 4096
+    num_hidden_layers: int = 11
+    layer_types: list | None = None      # from hybrid_override_pattern
+    num_dense_layers: int = 0
+    num_attention_heads: int = 8
+    num_key_value_heads: int = 1
+    head_dim: int | None = 128
+    intermediate_size: int = 2688        # published; no dense layer reads it
+    moe_intermediate_size: int = 2688
+    num_experts: int = 8
+    num_experts_published: int | None = 512
+    num_experts_per_tok: int = 22
+    routed_scaling_factor: float = 5.0
+    # the choice's bias starts where the published modelling code starts
+    # it, at zero (``e_score_correction_bias``, a buffer of zeros), and
+    # follows the family's balancing rule from there
+    # (``router_bias_update_rate``, below)
+    router_bias_std: float = 0.0
+    # the keys the parent class does not have
+    hybrid_override_pattern: str = "EMEMEMEMEM*"
+    mamba_num_heads: int = 32
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 2
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    expand: int = 2
+    use_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    mamba_hidden_act: str = "silu"
+    attention_bias: bool = False
+    mlp_bias: bool = False
+    use_bias: bool = False
+    mlp_hidden_act: str = "relu2"
+    n_routed_experts: int = 8
+    n_shared_experts: int = 1
+    moe_latent_size: int = 1024
+    moe_shared_expert_intermediate_size: int = 5376
+    n_group: int = 1                     # of experts: no group limit
+    topk_group: int = 1
+    layer_norm_epsilon: float = 1e-5
+    tie_word_embeddings: bool = False
+    # not in config.json: the whole model's counts beside the share's
+    mamba_num_heads_published: int = 128
+    n_groups_published: int = 8
+    num_attention_heads_published: int = 32
+    num_key_value_heads_published: int = 2
+    # not in config.json: the row budget of an expert layer, in uniform
+    # shares of the held experts (``FFModel.routed_experts``)
+    expert_rows_factor: int = 8
+    # not in config.json, the training recipe's: what the balancing rule
+    # moves an expert's choice bias by a step, down where the step sent
+    # it more than the uniform share of the assignments and up where
+    # less (``FFModel.routed_experts``'s ``bias_step``; DeepSeek-V3's
+    # published 0.001)
+    router_bias_update_rate: float = 1e-3
+    #: ``build_hybrid_conv_moe``: a layer is its one sub-layer
+    sublayers_per_block = 1
+
+    def __post_init__(self):
+        if self.use_bias or self.mlp_bias or self.attention_bias \
+                or self.mamba_proj_bias or not self.use_conv_bias \
+                or self.mlp_hidden_act != "relu2" \
+                or self.mamba_hidden_act != "silu" \
+                or self.n_group != 1 or self.topk_group != 1 \
+                or self.tie_word_embeddings \
+                or self.layer_norm_epsilon != self.norm_eps:
+            raise ValueError(
+                "a bias in a projection, a convolution without one, "
+                "experts that are not ReLU-squared, a group-limited "
+                "choice of experts, a tied head and two norm epsilons "
+                "are not built for this family")
+        if set(self.hybrid_override_pattern) - set(_NEMOTRON_KINDS):
+            raise ValueError(
+                f"hybrid_override_pattern {self.hybrid_override_pattern!r}"
+                f": its letters are {sorted(_NEMOTRON_KINDS)} (a dense "
+                f"MLP block, '-', is not built)")
+        kinds = [_NEMOTRON_KINDS[c] for c in self.hybrid_override_pattern]
+        if self.layer_types is None:
+            self.layer_types = kinds
+        elif list(self.layer_types) != kinds:
+            raise ValueError(f"layer_types {self.layer_types} is not "
+                             f"{self.hybrid_override_pattern!r}")
+        # query heads a key/value head: here, and in the whole model
+        reads, reads_published = (
+            self.num_attention_heads // self.num_key_value_heads,
+            self.num_attention_heads_published
+            // self.num_key_value_heads_published)
+        if self.mamba_num_heads_published * self.mamba_head_dim \
+                != self.expand * self.hidden_size \
+                or self.mamba_num_heads * self.n_groups_published \
+                != self.n_groups * self.mamba_num_heads_published \
+                or reads_published % reads \
+                or (self.num_key_value_heads > 1
+                    and reads != reads_published):
+            raise ValueError(
+                "the whole mixer's heads x mamba_head_dim is expand x "
+                "hidden_size, and a head share holds whole groups of B "
+                "and C, and whole key/value heads with the query heads "
+                "that read them or some of ONE key/value head's")
+        self.num_experts = self.n_routed_experts
+        self.num_dense_layers = 0
+
+    # the builder's names for the mixer's and the attention layer's sizes
+    mamba_n_heads = property(lambda self: self.mamba_num_heads)
+    mamba_d_head = property(lambda self: self.mamba_head_dim)
+    mamba_d_state = property(lambda self: self.ssm_state_size)
+    mamba_n_groups = property(lambda self: self.n_groups)
+    mamba_d_conv = property(lambda self: self.conv_kernel)
+    mamba_chunk_size = property(lambda self: self.chunk_size)
+    attention_multiplier = property(lambda self: self.head_dim ** -0.5)
+
+    @classmethod
+    def tiny(cls):
+        """``EMEM*``: 4 state-space heads of 16 in 2 groups with a state
+        of 8 in chunks of 16, 4 attention heads on 2 kv heads of 16, 16
+        ReLU-squared experts of 48 top-3 in a latent of 32 under a
+        stream of 64, all held, a shared expert of 96: tests."""
+        return cls(vocab_size=96, hidden_size=64, num_hidden_layers=5,
+                   hybrid_override_pattern="EMEM*",
+                   num_attention_heads=4, num_key_value_heads=2,
+                   head_dim=16, num_attention_heads_published=4,
+                   num_key_value_heads_published=2,
+                   mamba_num_heads=8, mamba_head_dim=16, ssm_state_size=8,
+                   n_groups=2, mamba_num_heads_published=8,
+                   n_groups_published=2, chunk_size=16,
+                   moe_intermediate_size=48, moe_latent_size=32,
+                   moe_shared_expert_intermediate_size=96,
+                   n_routed_experts=16, num_experts_published=None,
+                   num_experts_per_tok=3, router_bias_std=0.05,
+                   expert_rows_factor=2)
+
+
 def _rolled_by_one(ff: FFModel, x, rows: int, name: str):
     """Rows ``1 .. rows - 1`` and then row 0 of ``x``'s first ``rows``
     along axis 1: ``out[i] = x[(i + 1) % rows]``."""
@@ -1679,7 +1851,14 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     layer (:class:`SDARRankConfig`) attends under the block-diffusion
     mask; that class's graph opens with the noising op, runs every layer
     over ``2 seq_len`` positions (the noised copy, then the clean one),
-    reads the head off the noised half and weighs the loss's rows.
+    reads the head off the noised half and weighs the loss's rows. A
+    class whose ``sublayers_per_block`` is 1
+    (:class:`NemotronHRankConfig`) lays every layer out as ONE
+    sub-layer, ``h += Op(norm(h))`` OR ``h += FF(norm(h))``: its
+    ``"mamba"`` and ``"attention"`` layers end at the operator's add, a
+    ``"moe"`` layer is the feed-forward alone, and that class's mixers
+    read B and C in groups, its experts are ReLU-squared and routed in a
+    latent between two projections of the layer's own.
     ``pos`` is what the
     attention layers' rotary embedding turns by (a layout in which no
     layer turns by it still declares it, and ``fit`` drops its array).
@@ -1693,14 +1872,22 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
             or set(kinds) - {"conv", "full_attention", "sparse_attention",
                              "sliding_attention", "mamba", "attention",
                              "linear_attention",
-                             "block_diffusion_attention"} - _SAMBAY_KINDS:
+                             "block_diffusion_attention", "moe"} \
+            - _SAMBAY_KINDS:
         raise ValueError(
             f"layer_types must name {cfg.num_hidden_layers} layers, each "
             f"'conv', 'full_attention', 'sparse_attention', "
             f"'sliding_attention', 'mamba', 'attention', "
-            f"'linear_attention', 'block_diffusion_attention' or one of "
-            f"{sorted(_SAMBAY_KINDS)}; got "
+            f"'linear_attention', 'block_diffusion_attention', 'moe' or "
+            f"one of {sorted(_SAMBAY_KINDS)}; got "
             f"{len(kinds)}: {sorted(set(kinds))}")
+    # blocks of ONE sub-layer (``NemotronHRankConfig``): a layer is its
+    # operator or, where its kind is "moe", its feed-forward
+    single = getattr(cfg, "sublayers_per_block", 2) == 1
+    if ("moe" in kinds) != single or (single and cfg.num_dense_layers):
+        raise ValueError("a 'moe' layer is a block of one sub-layer: it "
+                         "needs a configuration whose sublayers_per_block "
+                         "is 1, which has no dense feed-forward")
     if cfg.conv_bias or not cfg.norm_topk_prob:
         raise ValueError("conv_bias and gates that are not normalised "
                          "over the chosen experts are not built")
@@ -1748,6 +1935,13 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
         experts.update(shared_gate=cfg.shared_expert_gate,
                        choice_bias=cfg.use_expert_bias,
                        rows_factor=cfg.expert_rows_factor)
+    if hasattr(cfg, "moe_latent_size"):
+        shared_dim = cfg.moe_shared_expert_intermediate_size \
+            * cfg.n_shared_experts
+        experts.update(latent=cfg.moe_latent_size,
+                       activation=cfg.mlp_hidden_act,
+                       rows_factor=cfg.expert_rows_factor,
+                       bias_step=cfg.router_bias_update_rate)
     if _SAMBAY_KINDS & set(kinds) and not hasattr(cfg, "mamba_dt_rank"):
         raise ValueError("a selective-scan, differential or gated-memory "
                          "layer needs the configuration's mamba_* sizes "
@@ -1773,7 +1967,8 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
     ids = ff.create_tensor((b, s), DataType.DT_INT32, name="input_ids")
     pos = ff.create_tensor((b, s), DataType.DT_INT32, name="position_ids",
                            may_be_unread=not set(kinds) - {"mamba",
-                                                           "attention"}
+                                                           "attention",
+                                                           "moe"}
                            - _SAMBAY_KINDS)
     read_ids = ids
     if diffusion:
@@ -1815,8 +2010,10 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
             else ff.scalar_multiply(x, residual_scale, name=name)
 
     for i, kind in enumerate(kinds):
-        x = norm(h, f"operator_norm_{i}")
-        if kind == "conv":
+        x = None if kind == "moe" else norm(h, f"operator_norm_{i}")
+        if kind == "moe":
+            op = None                   # the block is its feed-forward
+        elif kind == "conv":
             op = ff.gated_short_conv(x, cfg.conv_L_cache, name=f"conv_{i}")
         elif kind in ("mamba1", "mamba1_memory"):
             op = ff.selective_scan_mixer(
@@ -1890,10 +2087,13 @@ def build_hybrid_conv_moe(ff: FFModel, batch_size: int, seq_len: int,
                 **(indexer if kind == "sparse_attention" else {}),
                 **({"sliding_window": window}
                    if kind == "sliding_attention" else {}), **gated)
-        if sandwich:
-            op = norm(op, f"post_operator_norm_{i}")
-        h = ff.add(h, scaled(op, f"operator_scale_{i}"),
-                   name=f"operator_res_{i}")
+        if op is not None:
+            if sandwich:
+                op = norm(op, f"post_operator_norm_{i}")
+            h = ff.add(h, scaled(op, f"operator_scale_{i}"),
+                       name=f"operator_res_{i}")
+            if single:
+                continue
         x = norm(h, f"ffn_norm_{i}")
         if i < cfg.num_dense_layers:
             gate = ff.dense(x, cfg.intermediate_size, use_bias=False,

@@ -258,16 +258,18 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 @functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
 def _chunk(plan, mdt, c, floats, ints):
     """The held experts' part of the output from rows ``c * budget``
-    onwards of the sort, ``budget`` of them: the row gather, the three
-    grouped products and the activation over those rows, then each
-    token's sum of what they hold for it. ``plan`` is ``(budget,
+    onwards of the sort, ``budget`` of them: the row gather, the
+    grouped products (three of a SwiGLU, ``floats`` ending in ``w_gate,
+    w_up, w_down``; two of a ReLU-squared expert, which has no gate
+    matrix: ``w_up, w_down``) and the activation over those rows, then
+    each token's sum of what they hold for it. ``plan`` is ``(budget,
     kernel)``: with ``kernel`` that sum and the row gather's transpose
     are ``kernels/moe_token_sum.py``'s. Jitted and inlined for the
     trace cache alone: a model's layers and a layer's loops trace and
     differentiate this body once a shape, not once a use (a step's trace
     is set-up time, twice in a benchmark run)."""
     budget, kernel = plan
-    xm, gates, w_gate, w_up, w_down = floats
+    xm, gates, *ws = floats
     order, inverse, sizes = ints
     lo, ends = c * budget, jnp.cumsum(sizes)
     # each group's rows among these
@@ -296,8 +298,11 @@ def _chunk(plan, mdt, c, floats, ints):
     # gathered at the products' operand width: half the bytes of the
     # op's largest buffer, in both directions
     xs = _rows_for(xm, mine, at, held)                      # (budget, e)
-    act = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
-    return _combine(grouped(act, w_down), gates, mine, at, held)
+    if len(ws) == 3:
+        act = jax.nn.silu(grouped(xs, ws[0])) * grouped(xs, ws[1])
+    else:
+        act = jnp.square(jax.nn.relu(grouped(xs, ws[0])))
+    return _combine(grouped(act, ws[-1]), gates, mine, at, held)
 
 
 def _further_chunks(plan, sizes, body, start):
@@ -351,7 +356,7 @@ def _further_cotangents(plan, mdt, floats, ints, g, first):
     """The first chunk's cotangents ``first``, each the start of a loop
     that adds the further chunks' in place. Two loops, one for the
     activations' (input rows and gates, wanted by the layer before) and
-    one for the three weights', which only the optimizer wants: XLA
+    one for the experts' weights', which only the optimizer wants: XLA
     sinks the weights' products to their update at the end of the step,
     and with them a loop of their own; through one loop with the
     activations' they are written here and lie about until then, 150
@@ -366,13 +371,14 @@ def _further_cotangents(plan, mdt, floats, ints, g, first):
                                 jax.vjp(chunk, *floats[part])[1](g))
         return _further_chunks(plan, ints[2], more, first[part])
 
-    return further(slice(0, 2)) + further(slice(2, 5))
+    return further(slice(0, 2)) + further(slice(2, len(floats)))
 
 
 _sorted_domain.defvjp(_sorted_domain_fwd, _sorted_domain_bwd)
 
 
-def route(logits, bias, top_k: int, scale: float, scoring: str = "sigmoid"):
+def route(logits, bias, top_k: int, scale: float, scoring: str = "sigmoid",
+          loads: bool = False):
     """``(idx, gates)``, both (tokens, top_k), from the router's
     ``logits`` (tokens, experts) float32, by one of two score functions:
 
@@ -385,15 +391,20 @@ def route(logits, bias, top_k: int, scale: float, scoring: str = "sigmoid"):
                    ``bias`` is not read.
 
     Either way the gates are normalised over all ``top_k`` chosen and
-    scaled."""
+    scaled. With ``loads`` a third result, (experts,): the tokens that
+    chose each expert, counted as those whose biased score reaches
+    their token's ``top_k``-th (one compare over (tokens, experts); two
+    experts level at the cut both count)."""
     if scoring == "softmax":
-        scores = jax.nn.softmax(logits, axis=-1)
-        _, idx = jax.lax.top_k(scores, top_k)
+        biased = scores = jax.nn.softmax(logits, axis=-1)
     else:
         scores = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+        biased = scores + jax.lax.stop_gradient(bias)
+    least, idx = jax.lax.top_k(biased, top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     gates = scale * chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
+    if loads:
+        return idx, gates, jnp.sum(biased >= least[:, -1:], axis=0)
     return idx, gates
 
 
@@ -420,13 +431,35 @@ class RoutedExpertsOp(OpDef):
     family's, has no choice bias: the weight ``bias`` is still in the
     op's list, all zeros (``bias_std`` 0) and never read.
 
-    Every expert is a SwiGLU, ``w_down(silu(w_gate x) * w_up x)``. The
+    Every expert is a SwiGLU, ``w_down(silu(w_gate x) * w_up x)``, or,
+    with ``activation`` ``"relu2"`` in the parameters, ``w_down relu(w_up
+    x)^2`` (two matrices: no ``w_gate`` and no ``ws_gate`` in the op's
+    list). With ``latent`` l in the parameters the routed experts work
+    in a latent: they read ``u = x w_latent_in`` (l wide, their matrices
+    l x f and f x l) and their weighted sum goes back through
+    ``w_latent_out`` (l x hidden),
+
+      y = (sum_{i in S, i held} g_i E_i(x w_latent_in)) w_latent_out
+          +  E_shared(x)
+
+    while the router's scores and the shared expert read ``x`` itself;
+    the dispatch, its budget and ``kernels/moe_token_sum.py`` then move
+    rows l wide. The
     router, the choice and the gates' normalisation run over the
     published expert count whatever is held: what the absent experts
     would have added is left out, as on one rank of an expert-parallel
     layer before its exchange. ``bias`` corrects the choice only and
-    gets no gradient (its balancing rule is a training recipe's, not
-    this op's).
+    the loss gives it no gradient. With ``bias_step`` u > 0 in the
+    parameters it follows the family's balancing rule (Wang et al.,
+    "Auxiliary-loss-free load balancing", DeepSeek-V3's): after each
+    training step ``bias_i -= u * sign(c_i - mean(c))``, ``c_i`` the
+    assignments the step's choice gave expert i of ALL the published
+    ones. The op hands ``c - mean(c)`` over as the bias's gradient, by a
+    term of the loss whose value is zero, and the weight's
+    ``sign_step`` has the train step move it by the sign
+    (``Executor._apply_update``): so the rule crosses rematerialised
+    blocks and sums over micro-batches and data-parallel shards as a
+    gradient does.
 
     Dispatch: the ``tokens x top_k`` assignments are sorted by expert,
     those bound for absent experts in a trailing group that no product
@@ -453,7 +486,10 @@ class RoutedExpertsOp(OpDef):
         e, dt = in_shapes[0][-1], in_dtypes[0]
         n, held = params["num_experts"], params["experts_held"]
         f, fs = params["expert_dim"], params["shared_dim"]
-        up, down = {"fans": (e, f)}, {"fans": (f, e)}   # fans per expert
+        gated = params.get("activation", "swiglu") == "swiglu"
+        latent = params.get("latent", 0)
+        e_in = latent or e                  # what a routed expert reads
+        up, down = {"fans": (e_in, f)}, {"fans": (f, e_in)}     # an expert's
         # ``router_repeats`` r: the router's first n / r columns are drawn
         # and repeated r times, so a token's scores are alike in every
         # share of n / r experts
@@ -461,16 +497,24 @@ class RoutedExpertsOp(OpDef):
         ws = [WeightSpec("wg", (e, n), dt,
                          init_args={"column_repeats": r} if r > 1 else {})]
         if params.get("choice_bias", True):
-            # drawn once; corrects the choice, never trained
+            # drawn once; corrects the choice; moved by the balancing
+            # rule where the layer has a ``bias_step``, else never
             ws.append(WeightSpec("bias", (n,), dt, InitializerType.NORMAL,
                                  {"stddev": params.get("bias_std", 0.0)},
-                                 create_grad=False))
-        ws += [WeightSpec("w_gate", (held, e, f), dt, init_args=up),
-               WeightSpec("w_up", (held, e, f), dt, init_args=up),
-               WeightSpec("w_down", (held, f, e), dt, init_args=down)]
+                                 create_grad=False,
+                                 sign_step=params.get("bias_step", 0.0)))
+        if latent:
+            ws += [WeightSpec("w_latent_in", (e, latent), dt),
+                   WeightSpec("w_latent_out", (latent, e), dt)]
+        if gated:
+            ws.append(WeightSpec("w_gate", (held, e_in, f), dt,
+                                 init_args=up))
+        ws += [WeightSpec("w_up", (held, e_in, f), dt, init_args=up),
+               WeightSpec("w_down", (held, f, e_in), dt, init_args=down)]
         if fs:
-            ws += [WeightSpec("ws_gate", (e, fs), dt),
-                   WeightSpec("ws_up", (e, fs), dt),
+            if gated:
+                ws.append(WeightSpec("ws_gate", (e, fs), dt))
+            ws += [WeightSpec("ws_up", (e, fs), dt),
                    WeightSpec("ws_down", (fs, e), dt)]
             if params.get("shared_gate"):
                 ws.append(WeightSpec("ws_scalar", (e, 1), dt))
@@ -501,12 +545,16 @@ class RoutedExpertsOp(OpDef):
         xt = x.reshape(-1, x.shape[-1])
         t = xt.shape[0]
         rows, budget = t * k, self.rows_multiplied(t, params)
+        latent = params.get("latent", 0)
+        width = latent or x.shape[-1]       # of the rows the dispatch moves
+        expert_weights = [w for w in ("w_gate", "w_up", "w_down")
+                          if w in weights]
         # the way back to the tokens: the kernel where the shapes take
         # it, on one device (under a mesh the sort is over the global
         # batch, which no kernel call of a shard's own could walk)
         mesh = getattr(ctx, "mesh", None)
         kernel = ((mesh is None or mesh.size == 1) and all(
-            mts.takes_kernel(t, x.shape[-1], k, budget, held, dt)
+            mts.takes_kernel(t, width, k, budget, held, dt)
             for dt in (jnp.float32, mdt)))
         if events.enabled():
             events.instant("moe.route", layer=name, experts_published=n,
@@ -514,66 +562,96 @@ class RoutedExpertsOp(OpDef):
                            tokens=t, rows_budget=budget,
                            rows_multiplied=budget,
                            token_sum="kernel" if kernel else "plain",
+                           latent=latent,
+                           activation=params.get("activation", "swiglu"),
                            **({"shared_gate": True}
                               if "ws_scalar" in weights else {}),
-                           **({"router_repeats": params["router_repeats"]}
-                              if "router_repeats" in params else {}))
+                           **{key: params[key]
+                              for key in ("router_repeats", "bias_step")
+                              if key in params})
             if kernel:
                 # ``_chunk`` is traced once a shape, so its calls are
                 # noted here, where the layer has a name: the forward's
                 # sum and the row gather's transpose
                 for use, dt in (("combine", jnp.float32),
                                 ("rows_for_bwd", mdt)):
-                    tile = mts.tile_tokens(t, x.shape[-1], dt)
+                    tile = mts.tile_tokens(t, width, dt)
                     events.instant(
                         "moe.kernel", use=use, layer=name, tile=tile,
                         rows=budget, vmem_bytes=mts.vmem_bytes(
-                            tile, x.shape[-1], dt))
+                            tile, width, dt))
 
         # the router in float32, as published: a bf16 pass moves scores
         # by 1e-2 and with them the choice of experts
-        logits = jnp.dot(
-            xt.astype(jnp.float32), weights["wg"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST)
-        bias = weights["bias"].astype(jnp.float32) \
-            if "bias" in weights else None      # softmax scores read none
-        idx, gates = route(logits, bias, k,
-                           float(params.get("scale", 1.0)),
-                           params.get("scoring", "sigmoid"))
+        with jax.named_scope("moe.route"):
+            logits = jnp.dot(
+                xt.astype(jnp.float32), weights["wg"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+            bias = weights["bias"].astype(jnp.float32) \
+                if "bias" in weights else None  # softmax scores read none
+            balanced = bool(params.get("bias_step")) and ctx.training
+            idx, gates, *loads = route(logits, bias, k,
+                                       float(params.get("scale", 1.0)),
+                                       params.get("scoring", "sigmoid"),
+                                       loads=balanced)
+            if balanced:
+                # the balancing rule's gradient: every published
+                # expert's assignments over the uniform share, on a term
+                # that is zero whatever the bias
+                ctx.aux_losses.append(jnp.sum(
+                    (bias - jax.lax.stop_gradient(bias))
+                    * (loads[0] - rows / n)))
 
-        # sort the assignments by held expert; absent ones trail
-        local = idx.reshape(-1) - first
-        group = jnp.where((local >= 0) & (local < held), local, held)
-        order = jnp.argsort(group, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
-        sizes = jnp.bincount(group, length=held + 1)[:held].astype(
-            jnp.int32)
+            # sort the assignments by held expert; absent ones trail
+            local = idx.reshape(-1) - first
+            group = jnp.where((local >= 0) & (local < held), local, held)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+                jnp.int32)
 
-        floats = (xt.astype(mdt), gates) + tuple(
-            weights[w].astype(mdt) for w in ("w_gate", "w_up", "w_down"))
-        if budget == rows:
-            y = _chunk((rows, kernel), mdt, jnp.int32(0), floats,
-                       (order, inverse, sizes))
-            chunks = None
-        else:
+        def routed(xr):
+            """The held experts' weighted sum over rows as wide as
+            ``xr``'s, and how many chunks of the budget ran."""
+            floats = (xr.astype(mdt), gates) + tuple(
+                weights[w].astype(mdt) for w in expert_weights)
+            if budget == rows:
+                return _chunk((rows, kernel), mdt, jnp.int32(0), floats,
+                              (order, inverse, sizes)), None
             # whole chunks to slice: the padding sorts last, is never
             # live and reads token 0
-            order = jnp.pad(order, (0, -rows % budget))
-            y, chunks = _sorted_domain((budget, kernel), mdt, floats,
-                                       (order, inverse, sizes))
-        if "ws_gate" in weights:
-            g = matmul(xt, weights["ws_gate"], ctx=ctx)
-            u = matmul(xt, weights["ws_up"], ctx=ctx)
-            shared = matmul(jax.nn.silu(g) * u, weights["ws_down"], ctx=ctx)
-            if "ws_scalar" in weights:
-                # one scalar a token, float32 as the router's scores are
-                opened = jax.nn.sigmoid(jnp.dot(
-                    xt.astype(jnp.float32),
-                    weights["ws_scalar"].astype(jnp.float32),
-                    precision=jax.lax.Precision.HIGHEST))
-                shared = shared * opened
-                ctx.count("moe.shared_gate_mean", jnp.mean(opened))
-            y = y + shared
+            return _sorted_domain(
+                (budget, kernel), mdt, floats,
+                (jnp.pad(order, (0, -rows % budget)), inverse, sizes))
+
+        if latent:
+            # down to the latent, the experts there, and back: the
+            # router above and the shared expert below read x itself
+            with jax.named_scope("moe.latent"):
+                y, chunks = routed(matmul(xt, weights["w_latent_in"],
+                                          ctx=ctx))
+                y = matmul(y, weights["w_latent_out"], ctx=ctx)
+        else:
+            y, chunks = routed(xt)
+        if "ws_up" in weights:
+            with jax.named_scope("moe.shared"):
+                if "ws_gate" in weights:
+                    g = matmul(xt, weights["ws_gate"], ctx=ctx)
+                    u = matmul(xt, weights["ws_up"], ctx=ctx)
+                    act = jax.nn.silu(g) * u
+                else:
+                    act = jnp.square(jax.nn.relu(
+                        matmul(xt, weights["ws_up"], ctx=ctx)))
+                shared = matmul(act, weights["ws_down"], ctx=ctx)
+                if "ws_scalar" in weights:
+                    # one scalar a token, float32 as the router's scores
+                    opened = jax.nn.sigmoid(jnp.dot(
+                        xt.astype(jnp.float32),
+                        weights["ws_scalar"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST))
+                    shared = shared * opened
+                    ctx.count("moe.shared_gate_mean", jnp.mean(opened))
+                y = y + shared
 
         # what the router bound for this share, read from its choices,
         # against what the grouped products reached: an assignment is
@@ -602,9 +680,14 @@ class RoutedExpertsOp(OpDef):
         tokens = float(np.prod(in_shapes[0][:-1]))
         e = in_shapes[0][-1]
         share = params["experts_held"] / params["num_experts"]
-        routed = 3 * e * params["expert_dim"] * params["top_k"] * share
+        latent = params.get("latent", 0)
+        # matrices an expert: a SwiGLU's three, a ReLU-squared one's two
+        mats = 3 if params.get("activation", "swiglu") == "swiglu" else 2
+        routed = mats * (latent or e) * params["expert_dim"] \
+            * params["top_k"] * share
         return 2.0 * tokens * (e * params["num_experts"] + routed
-                               + 3 * e * params["shared_dim"])
+                               + 2 * e * latent
+                               + mats * e * params["shared_dim"])
 
     def backward_flops_factor(self):
         return 2.0

@@ -396,7 +396,8 @@ def state_space_scan(x, dt, a, bm, cm, chunk: int, mdt=jnp.float32, *,
     """The state-space recurrence of the module's docstring from a zero
     state, in chunks, without the ``D`` skip: ``x`` (B, T, H, P), ``dt``
     (B, T, H) > 0 the step size, ``a`` (H,) < 0, ``bm``, ``cm`` (B, T, N)
-    (one group: every head reads the same). ``mdt``: the type the
+    (one group: every head reads the same) or (B, T, groups, N) (head
+    ``h`` reads group ``h // (H / groups)``). ``mdt``: the type the
     products' operands are rounded to (sums, log-decays, their
     exponentials and the states are float32). Returns ``y`` (B, T, H, P)
     float32 and the most negative log-decay summed over one chunk.
@@ -419,26 +420,43 @@ def state_space_scan(x, dt, a, bm, cm, chunk: int, mdt=jnp.float32, *,
     what the scan stacks is ``P x N`` a head a chunk, not a chunk's
     outputs); those states are read by one product for all chunks. A
     sequence that is no whole number of chunks is padded with positions
-    that write nothing and decay nothing (``dt`` 0)."""
+    that write nothing and decay nothing (``dt`` 0). Several groups on
+    the plain path are that path once a group, over the group's heads."""
     t = x.shape[1]
     f32 = jnp.float32
+    groups = bm.shape[2] if bm.ndim == 4 else 1
+    by_kernels = kernels and ssm_kernels.takes_kernel(
+        chunk, *x.shape[2:], bm.shape[-1], groups)
+    if groups > 1 and not by_kernels:
+        per = x.shape[2] // groups
+        ys, leasts = zip(*(
+            state_space_scan(x[:, :, sl], dt[:, :, sl], a[sl], bm[:, :, g],
+                             cm[:, :, g], chunk, mdt, layer=layer,
+                             kernels=False)
+            for g, sl in ((g, slice(g * per, (g + 1) * per))
+                          for g in range(groups))))
+        return jnp.concatenate(ys, axis=2), jnp.min(jnp.stack(leasts))
 
     def in_chunks(v):           # (B, T, ..) -> (B, M, C, ..)
         return _in_chunks(v, chunk, axis=1)
 
     dt_c, cm_c = in_chunks(dt), in_chunks(cm)
     big_g = jnp.cumsum(dt_c * a.astype(f32), axis=2)    # (B, M, C, H)
-    if kernels and ssm_kernels.takes_kernel(chunk, *x.shape[2:],
-                                            bm.shape[-1]):
+    if by_kernels:
         def whole(v):           # (B, M, C, ..) -> (B, M C, ..)
             return v.reshape((v.shape[0], -1) + v.shape[3:])
 
         def last(v):            # .. -> (B, .., M C): tokens along lanes
             return jnp.moveaxis(whole(v), 1, -1)
 
+        def side_by_side(v):    # the groups' N columns, one after another
+            v = whole(v)
+            return v.reshape(v.shape[:2] + (-1,))
+
         y, _ = ssm_kernels.scan_chunks(
             last(in_chunks(x)), last(dt_c), last(big_g),
-            whole(in_chunks(bm)), whole(cm_c), chunk, mdt, layer=layer)
+            side_by_side(in_chunks(bm)), side_by_side(cm_c), chunk, mdt,
+            layer=layer, groups=groups)
         y = jnp.moveaxis(y, -1, 1)                      # (B, M C, H, P)
     else:
         inside, added = checkpointed(
@@ -844,17 +862,19 @@ class GatedDeltaRuleOp(OpDef):
 
 @register
 class StateSpaceMixerOp(OpDef):
-    """A state-space mixer (Mamba-2's form, one group of B/C): ``H``
-    heads of ``P`` channels, each the recurrence of
-    :func:`state_space_scan` over a state of ``P x N``.
+    """A state-space mixer (Mamba-2's form, ``G`` groups of B/C: 1 where
+    the parameters name none): ``H`` heads of ``P`` channels, each the
+    recurrence of :func:`state_space_scan` over a state of ``P x N``;
+    head ``h`` reads the B and C of group ``h // (H / G)``.
 
-      [z | xBC | dt] = x in_proj           H P | H P + 2 N | H
+      [z | xBC | dt] = x in_proj           H P | H P + 2 G N | H
       xBC = silu(short_conv(xBC; conv_w) + conv_b)     K causal taps
-      [x | B | C] = xBC                    x: (T, H, P); B, C: (T, N)
+      [x | B | C] = xBC                    x: (T, H, P); B, C: (T, G, N)
       dt = softplus(dt + dt_bias);  A = -exp(A_log)            a head
       y = state_space_scan(x, dt, A, B, C) + D x               D a head
       y = RMSNorm(y * silu(z); norm)       the gate BEFORE the norm,
-                                           the mean over all H P channels
+                                           the mean over each group's
+                                           H P / G channels
       out = y out_proj
 
     No bias but the convolution's. The two projections and the
@@ -880,6 +900,7 @@ class StateSpaceMixerOp(OpDef):
         h, p, n, k = (params["num_heads"], params["head_dim"],
                       params["state"], params["taps"])
         inner, uniform = h * p, InitializerType.UNIFORM
+        n *= params.get("groups", 1)        # B and C of every group
         return [
             WeightSpec("in_proj", (e, 2 * inner + 2 * n + h), dt),
             # one filter a channel: K taps in, K positions reached
@@ -908,6 +929,7 @@ class StateSpaceMixerOp(OpDef):
         f32 = jnp.float32
         h, p, n = params["num_heads"], params["head_dim"], params["state"]
         chunk, inner = int(params["chunk"]), h * p
+        groups = params.get("groups", 1)
         b, t = u.shape[:2]
         # the chunks' terms by the kernels where the shapes take them,
         # on one device (under a mesh the plain path, which GSPMD
@@ -916,11 +938,12 @@ class StateSpaceMixerOp(OpDef):
         kernels = mesh is None or mesh.size == 1
         if events.enabled():
             events.instant("ssm.layer", layer=name, heads=h, head_dim=p,
-                           state=n, groups=1, taps=params["taps"],
+                           state=n, groups=groups, taps=params["taps"],
                            tokens=b * t, chunk=chunk,
                            chunks=-(-t // chunk),
                            impl="kernel" if kernels
-                           and ssm_kernels.takes_kernel(chunk, h, p, n)
+                           and ssm_kernels.takes_kernel(chunk, h, p, n,
+                                                        groups)
                            else "plain")
 
         # The layer is rematerialised whole, as the gated delta rule is
@@ -933,10 +956,13 @@ class StateSpaceMixerOp(OpDef):
             zxbcdt = jnp.einsum("bte,ec->btc", u.astype(mdt),
                                 w["in_proj"].astype(mdt),
                                 preferred_element_type=f32)
-            z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], -1)
+            z, xbc, dt = jnp.split(
+                zxbcdt, [inner, 2 * inner + 2 * groups * n], -1)
             xbc = jax.nn.silu(short_conv(xbc, w["conv_w"].astype(f32))
                               + w["conv_b"].astype(f32))
-            x, bm, cm = jnp.split(xbc, [inner, inner + n], -1)
+            x, bm, cm = jnp.split(xbc, [inner, inner + groups * n], -1)
+            if groups > 1:
+                bm, cm = (v.reshape(b, t, groups, n) for v in (bm, cm))
             dt = jax.nn.softplus(dt + w["dt_bias"].astype(f32))
             with jax.named_scope("ssm.scan"):
                 y, least = state_space_scan(
@@ -948,7 +974,11 @@ class StateSpaceMixerOp(OpDef):
             # and the kernels is a copy of either on the chip (P is 64,
             # half a vector's lanes)
             y = y.reshape(b, t, inner) + jnp.repeat(w["D"].astype(f32), p) * x
-            y = _rms(y * jax.nn.silu(z), w["norm"], params["eps"])
+            y = y * jax.nn.silu(z)
+            # the mean square over a group's channels
+            y = _rms(y.reshape(b, t, groups, -1),
+                     w["norm"].reshape(groups, -1), params["eps"]
+                     ).reshape(b, t, inner)
             return jnp.einsum("btc,ce->bte", y.astype(mdt),
                               w["out_proj"].astype(mdt),
                               preferred_element_type=f32), least
@@ -972,8 +1002,9 @@ class StateSpaceMixerOp(OpDef):
         h, p, n, k = (params["num_heads"], params["head_dim"],
                       params["state"], params["taps"])
         inner = h * p
-        proj = e * (2 * inner + 2 * n + h) + inner * e
-        return tokens * (2.0 * proj + (2 * k + 1) * (inner + 2 * n)
+        bc = 2 * n * params.get("groups", 1)
+        proj = e * (2 * inner + bc + h) + inner * e
+        return tokens * (2.0 * proj + (2 * k + 1) * (inner + bc)
                          + 5.0 * inner * n + 2 * inner)
 
     def backward_flops_factor(self):

@@ -19,6 +19,7 @@ from flexflow_tpu.models.nlp import (BertConfig, GPTConfig,
                                      GraniteHybridRankConfig,
                                      HybridConvMoEConfig, KeyeRankConfig,
                                      KimiLinearRankConfig, LatentMoEConfig,
+                                     NemotronHRankConfig,
                                      Phi4FlashRankConfig,
                                      Qwen3NextRankConfig, SDARRankConfig,
                                      TrinityRankConfig, XingRankConfig)
@@ -285,6 +286,25 @@ def test_leg_l_block_diffusion_tiny_on_the_cpu_mesh(capsys):
     assert os.path.isfile(os.path.join(
         os.path.dirname(chip_smoke.__file__),
         chip_smoke.VALIDATION_BLOCK_DIFFUSION))
+
+
+def test_leg_m_latent_experts_hybrid_tiny_on_the_cpu_mesh(capsys):
+    """32 positions on the 8-device mesh: ``EMEM*`` is two
+    rematerialised [moe, mamba] blocks (the attention layer held whole),
+    both mixers' outputs kept, every mixer and every expert layer
+    announced, nothing dropped."""
+    chip_smoke.leg_latent_experts_hybrid(
+        NemotronHRankConfig.tiny(), seq=32, per_chip_batch=1,
+        label="M/small", alpha=1e-3)
+    out = capsys.readouterr().out
+    assert "rematerialised run (1, 6, 2) keeps 2 outputs" in out
+    assert ("ssm.layer ['mamba_1', 'mamba_3']; moe.route ['experts_0', "
+            "'experts_2']; scan ['plain'], token sum ['plain']") in out
+    assert "moe.dropped 0.0, moe.overflow 0.0" in out
+    assert f"python3 {chip_smoke.VALIDATION_NEMOTRON_H}" in out
+    assert os.path.isfile(os.path.join(
+        os.path.dirname(chip_smoke.__file__),
+        chip_smoke.VALIDATION_NEMOTRON_H))
 
 
 @pytest.mark.parametrize("seq", [128, 512, 1024, 2048, 4096])

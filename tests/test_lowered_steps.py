@@ -1,8 +1,8 @@
 """Every rank configuration's train step lowers to the text it lowered
 to at the parent commit.
 
-The twelve classes are the ten that a cell's config file names and the
-two base classes they extend; each is built at its ``tiny()`` size by
+The thirteen classes are the eleven that a cell's config file names and
+the two base classes they extend; each is built at its ``tiny()`` size by
 its builder (``rank_family.lowered_step``: the default ``FFConfig`` but
 no search, 2 x 32 ids drawn from seed 0, cold caches), with and without
 rematerialised blocks, and the sha256 of the lowered StableHLO is held
@@ -45,6 +45,7 @@ BUILDER = {          # class name: its builder and the cell that names it
     "Qwen3NextRankConfig": nlp.build_hybrid_conv_moe,   # cell 10
     "Phi4FlashRankConfig": nlp.build_hybrid_conv_moe,   # cell 11
     "SDARRankConfig": nlp.build_hybrid_conv_moe,        # cell 12
+    "NemotronHRankConfig": nlp.build_hybrid_conv_moe,   # cell 13
 }
 
 LOWERED = {
@@ -55,9 +56,9 @@ LOWERED = {
     ("attention layer", "xla"):
         "42694f5f039ec7b5be8f3bf3fb0ac4be16fa42807be7a53dd667a65aa9b407bb",
     ("GraniteHybridRankConfig", "none"):
-        "0eb7747046ee933c72a187ee32ee86caee2ef8e836c46fc9b6586ca4cebec775",
+        "d80aee0caff6db589728d4799a3ac93558c95a5ac19df9ddae0da01133a6d091",
     ("GraniteHybridRankConfig", "blocks"):
-        "59694966627e224ba92557c1aeaacec08bc1699e2a84d3c3d715a94e132a8b64",
+        "9dbe347ac12162413603559c0ec60f99a0aa4a3e54d05fe0ff80a8c96b1e96e9",
     ("HybridConvMoEConfig", "none"):
         "dfa0f7e24297d78957d8644dc8903deed572524d5efa93c3fb55d3801480e9e2",
     ("HybridConvMoEConfig", "blocks"):
@@ -82,6 +83,10 @@ LOWERED = {
         "1ea7f47cd760026f9b0bf390fc61c4f929c761cb23873c57e4fd383c92e9ab38",
     ("LatentMoEConfig", "blocks"):
         "ebe5b056861101bf03d389f0a0a87b9ba94ff3ab8c13135eb2be6bb91922e3d1",
+    ("NemotronHRankConfig", "none"):
+        "1049d9d563572cad10ee7aec0362a6e53b00f49cc31749e94109d4495d1f21cc",
+    ("NemotronHRankConfig", "blocks"):
+        "3cde0d0463900cf91b1a28a52ff3966a4637200659746d0e4eb79b66817cd35f",
     ("Phi4FlashRankConfig", "none"):
         "8a6c7e2c5faf315891702659868813abcc0088093e0307ad59c12f4275cf8f65",
     ("Phi4FlashRankConfig", "blocks"):

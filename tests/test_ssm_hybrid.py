@@ -297,7 +297,7 @@ def test_what_the_front_refuses():
     ff = FFModel(FFConfig())
     x = ff.create_tensor((B, S, E), name="x")
     with pytest.raises(ValueError, match="groups"):
-        ff.state_space_mixer(x, HM, P, N, TAPS, 16, groups=2)
+        ff.state_space_mixer(x, HM, P, N, TAPS, 16, groups=3)
     with pytest.raises(ValueError, match="taps"):
         ff.state_space_mixer(x, HM, P, N, 0, 16)
     with pytest.raises(ValueError, match="sm_scale"):

@@ -292,3 +292,85 @@ def test_the_predicate_reads_the_shapes(shape, takes):
 def test_a_step_takes_eights_of_heads_or_all(heads, p, want):
     n = ssk.heads_per_block(heads, p)
     assert n == want and heads % n == 0 and (n % 8 == 0 or n == heads)
+
+
+# ----------------------------------------------------------------------
+# several groups of B and C (PR 66): a block of heads lies in ONE group
+# ----------------------------------------------------------------------
+#: (positions, chunk, heads, head size, groups), operand type: two
+#: groups of two blocks of eight heads (``block // 2`` picks B and C,
+#: ``dB`` and ``dC`` are summed over a group's two blocks; the cell's
+#: block, over a ragged length, in both operand types); four groups of
+#: one block
+GROUPED = [((216, 128, 32, 64, 2), jnp.float32),
+           ((216, 128, 32, 64, 2), jnp.bfloat16),
+           ((128, 128, 32, 16, 4), jnp.float32)]
+
+
+@pytest.mark.parametrize("shape,mdt", GROUPED,
+                         ids=lambda v: getattr(v, "__name__", str(v)))
+def test_the_grouped_scan_and_every_pull_back_are_the_plain_paths(
+        shape, mdt):
+    """Output and the five cotangents on the kernel path, where head
+    ``h`` reads group ``h // (heads / groups)`` by the block index,
+    against the plain path (one ungrouped scan a group) and, in float32,
+    against the recurrence token by token a group."""
+    (seq, chunk, heads, p, groups), n = shape, 128
+    x, dt, a_log, _, _ = scan_inputs(seq, heads, p, n, batch=1)
+    rng = np.random.default_rng(5)
+    bm, cm = (jnp.asarray(rng.normal(size=(1, seq, groups, n)), jnp.float32)
+              for _ in range(2))
+    ct = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    assert ssk.takes_kernel(chunk, heads, p, n, groups)
+    assert ssk.heads_per_block(heads, p, groups) == 8
+
+    def run(kernels):
+        def scan(*a):
+            return state_space_scan(a[0], a[1], -jnp.exp(a[2]), a[3], a[4],
+                                    chunk, mdt, kernels=kernels)[0]
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(lambda *a: (
+                scan(*a), jax.vjp(scan, *a)[1](ct)))(x, dt, a_log, bm, cm)
+    (y1, g1), (y2, g2) = run(True), run(False)
+    close(y1, y2, 1e-6)
+    tol = 5e-5 if mdt == jnp.float32 else 2e-2
+    for a, b_, t in zip(g1, g2, (tol, tol, max(tol, 5e-3), tol, tol)):
+        assert a.shape == b_.shape
+        close(a, b_, t)
+    if mdt == jnp.float32:
+        per = heads // groups
+        with jax.default_matmul_precision("highest"):
+            want = jnp.concatenate([ref.recurrence(
+                x[:, :, g * per:(g + 1) * per], dt[:, :, g * per:(g + 1) * per],
+                a_log[g * per:(g + 1) * per], bm[:, :, g], cm[:, :, g],
+                jnp.zeros(per)) for g in range(groups)], axis=2)
+        close(y1, want, 2e-4)
+
+
+def test_a_wrong_group_is_apart():
+    """The same heads reading ONE group's B and C are another function:
+    the index map's ``block // blocks a group`` is read."""
+    x, dt, a_log, _, _ = scan_inputs(128, 32, 16, 128, batch=1)
+    rng = np.random.default_rng(5)
+    bm, cm = (jnp.asarray(rng.normal(size=(1, 128, 2, 128)), jnp.float32)
+              for _ in range(2))
+
+    def scan(bm, cm):
+        return jax.jit(lambda *a: state_space_scan(
+            a[0], a[1], -jnp.exp(a[2]), a[3], a[4], 128)[0])(
+            x, dt, a_log, bm, cm)
+    grouped, one = scan(bm, cm), scan(bm[:, :, 0], cm[:, :, 0])
+    close(grouped[:, :, :16], one[:, :, :16], 1e-6)
+    assert float(jnp.max(jnp.abs(grouped[:, :, 16:] - one[:, :, 16:]))) > 1.0
+
+
+@pytest.mark.parametrize("shape,takes", [
+    ((128, 32, 64, 128, 2), True),    # nemotron3_super_120b_a12b: 2 x 16
+    ((128, 128, 64, 128, 8), True),   # the whole mixer
+    ((128, 16, 64, 128, 8), False),   # two heads a group: no eights
+    ((128, 32, 64, 128, 3), False),   # heads in no whole groups
+    ((128, 48, 64, 128, 2), True),    # 24 a group: three blocks of eight
+    ((256, 64, 64, 128, 1), True),    # granite_4_0_h_micro, as it was
+])
+def test_the_predicate_reads_the_groups(shape, takes):
+    assert ssk.takes_kernel(*shape) is takes

@@ -1741,3 +1741,85 @@ def test_the_walked_diagonal_compiles_where_a_side_is_wider(
     assert _kernel_names(txt) == FLASH_NAMES
     assert subs == [BD_SUB] * 3
 
+
+
+# ----------------------------------------------------------------------
+# grouped state-space mixers, experts in a latent (PR 66)
+# ----------------------------------------------------------------------
+def test_the_grouped_mixer_compiles_at_cell_13s_shape(v5e_devices,
+                                                      monkeypatch):
+    """``nemotron3_super_120b_a12b.train.1chip``: one mixer's forward
+    and backward at 4096 -> 32 heads of 64 x 128 in 2 groups of B and C
+    over 4,096 positions in 32 chunks of 128, bf16 operands, compiled
+    for a described v5e: the recurrence is the two Mosaic calls, both
+    under ``ssm.scan``, reading B and C as ``(1, 4096, 256)`` (two
+    groups' columns side by side, picked by the block index: no copy a
+    group), and no ``while`` carries the chunk states."""
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.kernels import state_space
+    from flexflow_tpu.ops.recurrent_ops import StateSpaceMixerOp
+    from flexflow_tpu.ops.registry import EmitCtx
+    monkeypatch.setattr(state_space, "pallas_interpret", lambda: False)
+    params = {"num_heads": 32, "head_dim": 64, "state": 128, "taps": 4,
+              "chunk": 128, "eps": 1e-5, "groups": 2}
+    assert state_space.takes_kernel(128, 32, 64, 128, 2) \
+        and state_space.heads_per_block(32, 64, 2) == 8
+    op = StateSpaceMixerOp()
+    one = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    x = jax.ShapeDtypeStruct((1, 4096, 4096), jnp.float32, sharding=one)
+    w = {s.name: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=one)
+         for s in op.weights(params, [(1, 4096, 4096)],
+                             [DataType.DT_FLOAT])}
+    assert w["in_proj"].shape == (4096, 4640) \
+        and w["conv_w"].shape == (2560, 4)
+
+    def loss(x, w):
+        (y,) = op.emit(params, [x], w,
+                       EmitCtx(training=True, config=FFConfig()), "mamba_1")
+        return jnp.sum(y)
+
+    txt = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile() \
+        .as_text()
+    calls = [l for l in txt.splitlines() if MOSAIC_CALL in l]
+    assert _kernel_names(txt) == ["state_space_bwd", "state_space_fwd"]
+    assert all("ssm.scan" in l and "f32[1,4096,256]" in l for l in calls)
+    assert " while(" not in txt
+
+
+def test_the_latent_experts_compile_through_the_token_sum_kernel(
+        v5e_devices, compiled_token_sum, chip_locations):
+    """One LatentMoE layer's forward and backward at cell 13's shape
+    (4,096 tokens of 4,096, 22 of 512 sigmoid-routed experts with 8
+    held, ReLU-squared experts of 2,688 in a latent of 1,024, a shared
+    expert of 5,376 on the stream, eight shares of rows) for a described
+    v5e: the way back to the tokens is the kernel over rows as wide as
+    the LATENT, two grouped products a pass (no gate matrix), and the
+    three scopes are on the compiled ops, the BACKWARD's kernel calls
+    among them: ``_rows_for``'s and ``_combine``'s transposes are
+    ``custom_vjp`` backwards and come out under the scope their forward
+    call was made in, which is where
+    ``nemotron_moe_latent_time_share.train`` looks for them."""
+    from flexflow_tpu.kernels import moe_token_sum as mts
+    from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
+    params = dict(num_experts=512, top_k=22, expert_dim=2688,
+                  shared_dim=5376, experts_held=8, first_held=0, scale=5.0,
+                  bias_std=0.02, rows_factor=8, latent=1024,
+                  activation="relu2")
+    budget = RoutedExpertsOp.rows_multiplied(4096, params)
+    assert budget == 11264
+    assert mts.takes_kernel(4096, 1024, 22, budget, 8, jnp.bfloat16)
+    x, w = _experts_operands(v5e_devices[0], 4096, 4096, params)
+    assert sorted(w) == ["bias", "w_down", "w_latent_in", "w_latent_out",
+                         "w_up", "wg", "ws_down", "ws_up"]
+    txt = _compile_text(
+        jax.grad(functools.partial(_experts_loss, params), argnums=(0, 1)),
+        x, w)
+    mine = [l for l in txt.splitlines()
+            if MOSAIC_CALL in l and "moe_token_sum" in l.split(" = ")[0]]
+    assert len(mine) == 4 and all("moe.latent" in l for l in mine)
+    assert sum("transpose(" in l for l in mine) >= 2
+    assert all(f"[{budget},1024]" in l for l in mine)
+    for scope in ("experts_1/moe.route", "experts_1/moe.latent",
+                  "experts_1/moe.shared"):
+        assert scope in txt, scope

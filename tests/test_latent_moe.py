@@ -24,6 +24,7 @@ import rank_family as rf
 from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.models.nlp import LatentMoEConfig, build_latent_moe
 from flexflow_tpu.obs import events
+from flexflow_tpu.ops import moe_ops
 from flexflow_tpu.ops.moe_ops import RoutedExpertsOp
 from flexflow_tpu.runtime.metrics import COUNTER_PREFIX, is_count
 from rank_family import B, close, f32_ctx
@@ -203,9 +204,9 @@ def test_a_miscounted_group_shows_as_dropped(tiny, monkeypatch, lost):
     _, sound = _experts_layer(mc, x, ff.params["experts_2"], 0, 4)
     assert float(sound["moe.dropped"]) == 0
     assert float(sound["moe.load_max"]) > lost
-    bincount = jnp.bincount
-    monkeypatch.setattr(jnp, "bincount", lambda g, length: bincount(
-        g, length=length).at[1].add(-lost))
+    sizes = moe_ops.group_sizes
+    monkeypatch.setattr(moe_ops, "group_sizes", lambda g, held: sizes(
+        g, held).at[1].add(-lost))
     _, short = _experts_layer(mc, x, ff.params["experts_2"], 0, 4)
     assert float(short["moe.local_assignments"]) == \
         float(sound["moe.local_assignments"])

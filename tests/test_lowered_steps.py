@@ -21,7 +21,9 @@ The hashes were written at commit bc99f21 (PR 59's parent; PR 59
 changes no program file, so parent and change agree). A PR that MEANS
 to change a step replaces the hash it changes and says in ``CHANGES.md``
 which cell's step that is; a failure prints the class, the mode and the
-new hash. This file's cases clear JAX's caches, so they stay a file of
+new hash (PR 67 replaced the twenty-two with a routed-experts layer:
+``route()``'s gather and the sizes' scatter-add became a compare and a
+sum). This file's cases clear JAX's caches, so they stay a file of
 their own: ``--dist loadfile`` gives them a worker's turn and nobody
 else's warm cache is emptied.
 """
@@ -60,53 +62,53 @@ LOWERED = {
     ("GraniteHybridRankConfig", "blocks"):
         "9dbe347ac12162413603559c0ec60f99a0aa4a3e54d05fe0ff80a8c96b1e96e9",
     ("HybridConvMoEConfig", "none"):
-        "dfa0f7e24297d78957d8644dc8903deed572524d5efa93c3fb55d3801480e9e2",
+        "a9ad53f00df6a1a7741a400ff45a941468977dff354632fd96975c1be5200416",
     ("HybridConvMoEConfig", "blocks"):
-        "1a59f59459b09a86bf6a264c81d21c9e94dbb10dc30121ee98ade9478e4d5412",
+        "7a60dea107852e01d172fb40934f17424639e1c35f29d5d649cfb2e7c77540e9",
     ("JoyAIFlashRankConfig", "none"):
-        "cdb4f55ef86e6572cc9f607644a0e6ca659664c999bb223ba5717ff0473404c7",
+        "e9ecbfdaf13f9f8ca25f16947ea0728c8fda6250c6b28ddf3d67b4f7c62c433f",
     ("JoyAIFlashRankConfig", "blocks"):
-        "b60cef900927287072a1daacb2c7b189d6816245e4ceaf133ac55cf47e341049",
+        "fd6048f80b86f8aa15244a00b40f7562a888480f0618a2d99f383a322baf2855",
     ("KeyeRankConfig", "none"):
-        "44a48df71f1629c5f5151615ce7cec20dc2b887d471db0501ed2daead1839016",
+        "f66b1a7bd8f40e244e98696c7b6a9fbc9a6856e000ebc256602926162c9abb80",
     ("KeyeRankConfig", "blocks"):
-        "0d20f419bb84cdfb8951da276ea2479f1c690fabe605a2d669523c9d7fc86e65",
+        "d4fafc03534ebba625538b3ac782f6337230c48a5ba7044b9d94b2605905ba45",
     ("KimiLinearRankConfig", "none"):
-        "6dbfe7a9c289c7a9fb9be4120772e88d2073527aa1d0e64ae350d844e6968bb1",
+        "7fa66593f9ac8500b9eaf6c65a858025e2f4e602e5de9304f94a74eace1ca9fa",
     ("KimiLinearRankConfig", "blocks"):
-        "cba9000de8746dfce1a2cca52294f8b4f08c9417ac725d4ba2c6d8f6dd608d03",
+        "9342c3407dc551152fdcf8a403c40b39076242336c33634615456368c4ead52c",
     ("LFM2RankConfig", "none"):
-        "8f8128eed419125269ed485da3d88775a0ccb11bd8354bd7f5239e17fbd5c65a",
+        "f9fbadd1d2b91c3433b1e031a231f7220377192ddae0a209ef1a3eab4d4e7c6e",
     ("LFM2RankConfig", "blocks"):
-        "e39329f73030ad79e44711e72529ab8beb79297855b13b4672e1d90e91bc44cc",
+        "4603f5e794dc85199e86c65229f0fef0fdc00c5d9dc7f9c6706d92c89cfadc51",
     ("LatentMoEConfig", "none"):
-        "1ea7f47cd760026f9b0bf390fc61c4f929c761cb23873c57e4fd383c92e9ab38",
+        "aea1c063b9beb3ed6c9766a441337d4f85bfa01a83209d945baebbc070c64438",
     ("LatentMoEConfig", "blocks"):
-        "ebe5b056861101bf03d389f0a0a87b9ba94ff3ab8c13135eb2be6bb91922e3d1",
+        "6c8d1eb9c627d9bf8ae109596db830c4ea512c4653bbcae86270e895a071c851",
     ("NemotronHRankConfig", "none"):
-        "1049d9d563572cad10ee7aec0362a6e53b00f49cc31749e94109d4495d1f21cc",
+        "beb1c044b840ffd2965c0a31ebbccd75129747b8e18a043f9ae7a84fd08d6b13",
     ("NemotronHRankConfig", "blocks"):
-        "3cde0d0463900cf91b1a28a52ff3966a4637200659746d0e4eb79b66817cd35f",
+        "6ff6ac38c000ab4f79dd11f08901954c84101c397ca1918b4c01bb41342a9fa0",
     ("Phi4FlashRankConfig", "none"):
         "8a6c7e2c5faf315891702659868813abcc0088093e0307ad59c12f4275cf8f65",
     ("Phi4FlashRankConfig", "blocks"):
         "f399127f3e6867ae5b2e09f0aa78d7b74ce9c6cbf323391b3d2725a239bb0452",
     ("Qwen3NextRankConfig", "none"):
-        "6ae1f7bc1a602372feec80a028ef319c4313df79ef0dc76c116b5e2ddbeba336",
+        "9ddcbfa8406ec65170b673e5561a8c6b31cbc0f2950217c8a847ca2881e23309",
     ("Qwen3NextRankConfig", "blocks"):
-        "75213f2e760c85ba401dc4d7b94af0ca803596dd8603717014c37d19e5087721",
+        "74e14437d90e1ad2cfeaaa8984083118678b12839e703bd39ee8276566735958",
     ("SDARRankConfig", "none"):
-        "8a4e467e6eb009c46b51c360a745350850688561966f6b2c1e9bd944ae881047",
+        "9c0d79516b760d797ee8442e8948e8142ff7f4049964883dc403e15f6e7a4b28",
     ("SDARRankConfig", "blocks"):
-        "559ef78378bc594fc9af5b72d7df47717e2ce99d0e2b44fe3e14440581731f1b",
+        "6854672cea79c054f82f1b5cd64d9c90611651c2dd967bd374cc654ba2fc4621",
     ("TrinityRankConfig", "none"):
-        "708cf492ac0352eff099c0761b8f98dcc72db3ac033f5d898799227c50ac6696",
+        "b25c75a7714285166e64a4be21fa874136bbe71b2cc43ac71bbd366d7d5c4d57",
     ("TrinityRankConfig", "blocks"):
-        "5a64243ea76f334805bbe7f542884c20a3b5d4465e302e224d2f2934d7f96b58",
+        "dcf665e7a77a4c22337fe6255a70bb368e62841d4952edefefa9aaa201866cdf",
     ("XingRankConfig", "none"):
-        "5bcfba02c974b900704518f59fbe730bf5f3598ccd3de49a98e798df659d8488",
+        "8f36ad5009291ff5ba542c9ec68b0a2861a055e40a9baadcd631d7705bdcf9be",
     ("XingRankConfig", "blocks"):
-        "c0feb6265391f7b9f074e1784e0224456d34f4cef09cdab6c300c76bc0f0ea7a",
+        "6a70761031559ddb0a99ce9c3ef8740c4bfca6a437701eb4f8796f692a4abefa",
 }
 
 

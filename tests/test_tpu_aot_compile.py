@@ -1823,3 +1823,62 @@ def test_the_latent_experts_compile_through_the_token_sum_kernel(
     for scope in ("experts_1/moe.route", "experts_1/moe.latent",
                   "experts_1/moe.shared"):
         assert scope in txt, scope
+
+
+# ----------------------------------------------------------------------
+# the router: XLA's float32 product, no gather and no scatter (PR 67)
+# ----------------------------------------------------------------------
+#: (tokens, hidden, published experts) and the layer's parameters
+ROUTER_CELLS = {
+    "cell13_nemotron": ((4096, 4096, 512), dict(
+        num_experts=512, top_k=22, expert_dim=2688, shared_dim=5376,
+        experts_held=8, first_held=0, scale=5.0, bias_std=0.02,
+        rows_factor=8, latent=1024, activation="relu2")),
+    "cell10_qwen3next": ((8192, 2048, 512), dict(
+        num_experts=512, top_k=10, expert_dim=512, shared_dim=512,
+        experts_held=32, first_held=0, scoring="softmax",
+        choice_bias=False, shared_gate=True, rows_factor=6)),
+}
+
+
+def _highest_products(txt):
+    """The result shapes (leading ones dropped) of the compiled text's
+    matrix products whose operands are both at the highest precision:
+    six bf16 passes each."""
+    found = set()
+    for l in txt.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* convolution\(", l)
+        if m and "operand_precision={highest,highest}" in l:
+            dims = [int(d) for d in m.group(1).split(",")]
+            found.add(tuple(d for d in dims if d != 1))
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTER_CELLS))
+def test_the_router_keeps_xlas_product_and_walks_no_single_numbers(
+        v5e_devices, compiled_token_sum, chip_locations, cell):
+    """One routed-experts layer's forward and backward at the cell's
+    shape for a described v5e. The router's products are XLA's own at
+    the highest precision, forward and cotangent (on the chip they run
+    at the matrix unit's rate: ``examples/tpu_time_router_product.py``;
+    all three are held in the jaxpr by ``tests/test_router_product.py``:
+    here ``x^T dlogits`` has ``logits``' shape wherever tokens equal
+    hidden). Under ``moe.route`` nothing is left that the chip walks a
+    number at a time: no gather (the chosen experts' own scores were one
+    of 90,112 single numbers a layer in cell 13) and no scatter (the
+    groups' sizes), forward or backward; what stays are the product, the
+    top-k's sort, the two sorts of the assignments and elementwise
+    passes."""
+    (t, e, n), params = ROUTER_CELLS[cell]
+    x, w = _experts_operands(v5e_devices[0], t, e, params)
+    txt = _compile_text(
+        jax.grad(functools.partial(_experts_loss, params), argnums=(0, 1)),
+        x, w)
+    assert {(t, n), (t, e)} <= _highest_products(txt)
+    mine = [l for l in txt.splitlines()
+            if "experts_1/moe.route" in l and " = " in l]
+    assert len([l for l in mine if " sort(" in l]) == 3
+    walked = [l.split(" = ")[0].strip() for l in mine
+              if re.search(r" (gather|scatter)\(", l)
+              or "kind=kCustom" in l or "GatherScatter" in l]
+    assert not walked

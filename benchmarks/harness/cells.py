@@ -10,7 +10,14 @@ a cell by adding files and entries and edits nothing that is there.
   <root>/<paths[0]>/runners/<kind>.py            ``run(cell, ...)``
   <root>/<paths[0]>/flops/<config>.py            operations per token
   <root>/<paths[0]>/reference/<module>.py        the plain reference
-  <root>/<paths[0]>/layer_metrics/<metric>.py    ``read(ctx)``
+  <root>/<paths[0]>/layer_metrics/<metric>.py    ``read(ctx)``, or
+  <root>/<paths[0]>/layer_metrics/<metric>.json  ``{"reader": "<metric>"}``
+
+The second form is how a new cell reports a reading whose reader is
+there already under an entry that lists other cells (an accepted entry
+is not edited): the PR that brings the cell appends an entry of its own
+name that lists the cell, and a file that names the accepted entry. It
+copies no code.
 """
 from __future__ import annotations
 
@@ -94,6 +101,17 @@ def load_module(bench_dir: str, sub: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(bench_dir: str, metric: str):
+    """The per-layer metric's reader: the module of its own file, or of
+    the file of the entry that its ``.json`` names (one step, no chain);
+    None if it has neither."""
+    name = metric_file(metric)
+    alias = os.path.join(bench_dir, "layer_metrics", name + ".json")
+    if os.path.isfile(alias):
+        name = metric_file(_load_json(alias)["reader"])
+    return load_module(bench_dir, "layer_metrics", name)
 
 
 def load_attr(dotted: str):

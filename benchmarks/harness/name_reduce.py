@@ -15,9 +15,7 @@ PR that brought this file) makes every function here return ``None``.
 """
 from __future__ import annotations
 
-import os
-
-from benchmarks.harness import scope_reduce, span_reduce, trace_reduce
+from benchmarks.harness import scope_reduce, span_reduce
 
 
 def by_op(ctx):
@@ -29,11 +27,9 @@ def by_op(ctx):
     ctx.name_by_op = None
     if not span_reduce.reduced(ctx):
         return None
-    path = trace_reduce.find_xplane(
-        os.path.join(ctx.cell.root, ".bench_trace", ctx.cell.name))
     names = {l.name for l in ctx.model.layers}
     ctx.name_by_op = scope_reduce.op_self_ns(
-        span_reduce.extract(path), ctx.span_instructions, names) or None
+        ctx.span_events, ctx.span_instructions, names) or None
     return ctx.name_by_op
 
 

@@ -51,10 +51,7 @@ no checkpoint at all: that reads 0.0, whoever compiled it.
 """
 from __future__ import annotations
 
-import os
-
 from benchmarks.harness import name_reduce, scope_reduce, span_reduce
-from benchmarks.harness import trace_reduce
 
 RECOMPUTED = "rematted_computation"
 SCOPE_PREFIX = "remat."
@@ -280,10 +277,7 @@ def reduced(ctx):
     by_op = name_reduce.by_op(ctx) if r else None
     if not by_op:
         return None
-    path = trace_reduce.find_xplane(
-        os.path.join(ctx.cell.root, ".bench_trace", ctx.cell.name))
-    events = span_reduce.extract(path)
-    instr = ctx.span_instructions
+    events, instr = ctx.span_events, ctx.span_instructions
     calls = {name: n for table in r["kernel_calls"].values()
              for name, n in table.items()}
     ctx.remat_reduced = reduce_remat(

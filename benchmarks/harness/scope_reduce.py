@@ -23,8 +23,6 @@ PR that brought this file) makes every function here return ``None``.
 """
 from __future__ import annotations
 
-import os
-
 from benchmarks.harness import cells, span_reduce, trace_reduce
 
 UNNAMED_PREFIXES = ("ragged-dot",)
@@ -112,11 +110,8 @@ def by_layer(ctx):
     ctx.scope_layer_ns = None
     if not span_reduce.reduced(ctx):
         return None
-    path = trace_reduce.find_xplane(
-        os.path.join(ctx.cell.root, ".bench_trace", ctx.cell.name))
     names = {l.name for l in ctx.model.layers}
-    by_op = op_self_ns(span_reduce.extract(path), ctx.span_instructions,
-                       names)
+    by_op = op_self_ns(ctx.span_events, ctx.span_instructions, names)
     ctx.scope_layer_ns = _fold_layers(by_op) or None
     for line in report(by_op, ctx.model) if by_op else ():
         print(f"[bench] {line}", flush=True)

@@ -38,7 +38,6 @@ the most ``ff:`` spans (``fit``'s).
 """
 from __future__ import annotations
 
-import os
 import re
 
 from benchmarks.harness import cells, trace_reduce
@@ -53,6 +52,9 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _SHAPE = re.compile(r"\b([a-z]+\d+[a-z0-9]*|pred)\[([\d,]*)\]")
 _OPERANDS = re.compile(r"operand_layout_constraints=\{(.*?)\}, \w+=")
 _BACKWARD = re.compile(r"transpose\([^/]*ff\.(?:forward|loss)")
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")          # jvp(..), transpose(..)
+_DOTTED = re.compile(r"^[A-Za-z_]\w*(?:\.\w+)+$")  # moe.shared, ff.loss
+UNSCOPED = "unscoped"
 MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
 
 
@@ -63,32 +65,11 @@ def extract(xplane_path: str, mark_prefix: str = "bench.") -> dict:
     """``{"devices": {plane: [[name, start_ns, dur_ns], ...]},
     "marks": [[name, start_ns, dur_ns], ...],
     "spans": [[name, thread, start_ns, dur_ns], ...]}`` — devices and
-    marks as ``trace_reduce.extract`` gives them, spans the host events
-    named ``ff:<name>`` (the prefix cut off) with the line (thread) they
-    are on."""
-    from jax.profiler import ProfileData
-    data = ProfileData.from_file(xplane_path)
-    devices, marks, spans = {}, [], []
-    for plane in data.planes:
-        if plane.name.startswith(trace_reduce.DEVICE_PLANE_PREFIX):
-            for line in plane.lines:
-                if line.name == trace_reduce.OPS_LINE:
-                    devices[plane.name] = [
-                        [trace_reduce.op_name(ev.name), int(ev.start_ns),
-                         int(ev.duration_ns)] for ev in line.events]
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith(mark_prefix):
-                        marks.append([ev.name, int(ev.start_ns),
-                                      int(ev.duration_ns)])
-                    elif ev.name.startswith(SPAN_PREFIX):
-                        spans.append([ev.name[len(SPAN_PREFIX):], line.name,
-                                      int(ev.start_ns),
-                                      int(ev.duration_ns)])
-    marks.sort(key=lambda m: m[1])
-    spans.sort(key=lambda s: s[2])
-    return {"devices": devices, "marks": marks, "spans": spans}
+    marks as ``trace_reduce.extract`` gives them (the same parse, which
+    the runner makes once and keeps as ``ctx.span_events``), spans the
+    host events named ``ff:<name>`` (the prefix cut off) with the line
+    (thread) they are on."""
+    return trace_reduce.extract(xplane_path, mark_prefix, SPAN_PREFIX)
 
 
 # ----------------------------------------------------------------------
@@ -145,6 +126,30 @@ def layer_of(op_name: str) -> str:
     return ""
 
 
+def innermost_scope(op_name: str, layer_names=()) -> str:
+    """The innermost part of ``op_name`` that the PROGRAM opened: a
+    layer's name, or a dotted scope (``moe.shared``, ``ssm.scan``,
+    ``remat.block``, ``ff.optimizer``; the program's scopes carry a dot
+    and JAX's own, ``jvp(..)``, ``checkpoint``, ``while``, ``body``,
+    ``pallas_call``, do not), read through JAX's wrappers
+    (``transpose(jvp(ff.forward))`` is ``ff.forward``). The last part
+    is the primitive and is no scope. ``"unscoped"`` where there is
+    none."""
+    for part in reversed(op_name.split("/")[:-1]):
+        while (m := _WRAPPED.match(part)):
+            part = m.group(1)
+        if part in layer_names or _DOTTED.match(part):
+            return part
+    return UNSCOPED
+
+
+def scoped_names(instr: dict, layer_names=()) -> dict:
+    """HLO instruction name -> ``<innermost scope>/<instruction>``: the
+    names a traced run's ``breakdown`` prints."""
+    return {name: innermost_scope(entry["op_name"], layer_names)
+            + "/" + name for name, entry in instr.items()}
+
+
 def kernel_of(instruction: str, entry: dict) -> str:
     """A Mosaic call's kernel name: the scope its ``pallas_call`` sits
     in, else its instruction's name without the counter."""
@@ -190,6 +195,29 @@ def fit_thread(spans):
     return max(sorted(count), key=count.get) if count else None
 
 
+def window_spans(events: dict, lo: int, hi: int) -> list:
+    """The ``[name, start, dur]`` spans of ``fit``'s thread, clipped to
+    the window ``[lo, hi)``."""
+    thread = fit_thread(events["spans"])
+    return [[n, max(s, lo), min(s + d, hi) - max(s, lo)]
+            for n, t, s, d in events["spans"]
+            if t == thread and s < hi and s + d > lo]
+
+
+def host_segments(events: dict) -> list:
+    """``innermost_segments`` of the window's spans on ``fit``'s
+    thread, ``fit.epoch`` left out (it covers everything and so names
+    nothing): what the host was doing, for ``trace_reduce``'s idle
+    gaps. ``[]`` without marks."""
+    marks = events["marks"]
+    if not marks:
+        return []
+    lo = marks[0][1]
+    hi = max(s + d for _, s, d in marks)
+    return innermost_segments(
+        [sp for sp in window_spans(events, lo, hi) if sp[0] != EPOCH_SPAN])
+
+
 def reduce_spans(events: dict, instr: dict) -> dict:
     """See the module's docstring. Times in nanoseconds, summed over the
     devices of the trace; ``{}`` without marks or devices."""
@@ -198,10 +226,7 @@ def reduce_spans(events: dict, instr: dict) -> dict:
         return {}
     lo = marks[0][1]
     hi = max(s + d for _, s, d in marks)
-    thread = fit_thread(events["spans"])
-    mine = [[n, max(s, lo), min(s + d, hi) - max(s, lo)]
-            for n, t, s, d in events["spans"]
-            if t == thread and s < hi and s + d > lo]
+    mine = window_spans(events, lo, hi)
     segments = innermost_segments(mine)
     busy_ns = idle_ns = 0
     phase_ns = dict.fromkeys(PHASES, 0)
@@ -237,17 +262,9 @@ def reduce_spans(events: dict, instr: dict) -> dict:
             if b <= a:
                 continue
             idle_ns += b - a
-            left = b - a
-            for s, e, name in segments:
-                if e <= a:
-                    continue
-                if s >= b:
-                    break
-                ns = min(e, b) - max(s, a)
+            for name, ns in trace_reduce.gap_pieces(a, b, segments):
+                name = name or NO_SPAN
                 idle_by_span[name] = idle_by_span.get(name, 0) + ns
-                left -= ns
-            if left:
-                idle_by_span[NO_SPAN] = idle_by_span.get(NO_SPAN, 0) + left
     span_ns: dict = {}                  # name -> [count, summed ns]
     for name, _, d in mine:
         got = span_ns.setdefault(name, [0, 0])
@@ -265,23 +282,16 @@ def reduce_spans(events: dict, instr: dict) -> dict:
 # what the readers in layer_metrics/ call
 # ----------------------------------------------------------------------
 def reduced(ctx):
-    """The reduction of the traced run behind ``ctx``, made once and
-    kept on it; ``None`` where the run left no trace (no ``--trace 1``,
-    the CPU tests' stubbed profiler)."""
+    """The reduction of the traced run behind ``ctx``
+    (``ctx.span_events``, ``ctx.span_instructions``: the runner's one
+    parse of the trace and of the step's text), made once and kept on
+    it; ``None`` where the run left no trace."""
     if hasattr(ctx, "span_reduced"):
         return ctx.span_reduced
     ctx.span_reduced = None
-    if not ctx.trace:
+    if not ctx.trace or not ctx.span_events:
         return None
-    trace_dir = os.path.join(ctx.cell.root, ".bench_trace", ctx.cell.name)
-    try:
-        path = trace_reduce.find_xplane(trace_dir)
-    except FileNotFoundError:
-        return None
-    if not os.path.isfile(path):      # (the CPU tests stub the finder)
-        return None
-    ctx.span_instructions = instructions(ctx.step_text)
-    r = reduce_spans(extract(path), ctx.span_instructions)
+    r = reduce_spans(ctx.span_events, ctx.span_instructions)
     ctx.span_reduced = r or None
     if r:
         for line in report(r):

@@ -13,7 +13,8 @@ trace (``benchmarks/testdata``) without a chip:
       ran) and of its "Async XLA Ops" line (copies and collectives in
       flight beside it), and the host's ``TraceAnnotation`` spans whose
       name starts with ``mark_prefix``.
-  ``reduce_trace(events, kernel_names)``  does the arithmetic.
+  ``reduce_trace(events, kernel_names, names, host)``  does the
+      arithmetic.
 
 What is counted: *busy* is the union of the intervals in which an XLA
 op ran on a device's core, clipped to the window (an async copy or
@@ -22,6 +23,13 @@ does count towards the collective share); the *window* runs from the
 start of the first mark to the end of the last (the benchmark marks each
 group of steps); idle is what is left. A device's numbers are its own;
 the reported ones are the mean over the devices in the trace.
+
+The ten heaviest ops and the ten longest gaps are the run's
+``breakdown``, the only trace the writer of the next issue sees. The
+caller hands in the program's names for both (``span_reduce``): an op
+prints as ``<innermost scope>/<instruction>`` and a gap as ``<host
+span>/after:<op>``, since XLA's ``multiply_reduce_fusion.22`` names no
+layer and ``between-groups`` no cause.
 """
 from __future__ import annotations
 
@@ -34,6 +42,7 @@ OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"     # copies and collectives in flight
 COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
                        "collective-permute", "all-to-all")
+NAME_CUT = 80                    # characters of a printed op's name
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -44,10 +53,14 @@ def find_xplane(trace_dir: str) -> str:
     return found[-1]
 
 
-def extract(xplane_path: str, mark_prefix: str = "bench.") -> dict:
+def extract(xplane_path: str, mark_prefix: str = "bench.",
+            span_prefix: str = "") -> dict:
+    """The one parse of a traced run's file. With a ``span_prefix``,
+    also ``"spans"``: the host events so named (the prefix cut off) as
+    ``[name, thread, start_ns, dur_ns]``, for ``span_reduce``."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(xplane_path)
-    devices, in_flight, marks = {}, {}, []
+    devices, in_flight, marks, spans = {}, {}, [], []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE_PREFIX):
             for line in plane.lines:
@@ -58,11 +71,18 @@ def extract(xplane_path: str, mark_prefix: str = "bench.") -> dict:
                          int(ev.duration_ns)] for ev in line.events]
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                marks += [[ev.name, int(ev.start_ns), int(ev.duration_ns)]
-                          for ev in line.events
-                          if ev.name.startswith(mark_prefix)]
+                for ev in line.events:
+                    if ev.name.startswith(mark_prefix):
+                        marks.append([ev.name, int(ev.start_ns),
+                                      int(ev.duration_ns)])
+                    elif span_prefix and ev.name.startswith(span_prefix):
+                        spans.append([ev.name[len(span_prefix):],
+                                      line.name, int(ev.start_ns),
+                                      int(ev.duration_ns)])
     marks.sort(key=lambda m: m[1])
-    return {"devices": devices, "async": in_flight, "marks": marks}
+    spans.sort(key=lambda s: s[2])
+    return {"devices": devices, "async": in_flight, "marks": marks,
+            "spans": spans}
 
 
 def op_name(event_name: str) -> str:
@@ -123,9 +143,39 @@ def is_collective(name: str) -> bool:
     return name.lstrip("%").startswith(COLLECTIVE_PREFIXES)
 
 
-def reduce_trace(events: dict, kernel_names=()) -> dict:
+def gap_pieces(a: int, b: int, host) -> list:
+    """``[(host span | None, ns)]``: the idle interval ``[a, b)`` split
+    over the sorted, disjoint ``[start, end, name]`` pieces of ``host``
+    that cover it, and what none covers."""
+    out, left = [], b - a
+    for s, e, name in host:
+        if e <= a:
+            continue
+        if s >= b:
+            break
+        ns = min(e, b) - max(s, a)
+        out.append((name, ns))
+        left -= ns
+    if left:
+        out.append((None, left))
+    return out
+
+
+def reduce_trace(events: dict, kernel_names, names: dict,
+                 host) -> dict:
     """See the module's docstring. ``kernel_names``: the names of the
-    compiled step's Mosaic custom calls, as its HLO text gives them."""
+    compiled step's Mosaic custom calls, as its HLO text gives them.
+    ``names``: HLO instruction -> the name to print for it in
+    ``device_ops`` and behind a gap's ``after:``
+    (``span_reduce.scoped_names``: the program's innermost scope in
+    front of XLA's name; an instruction it does not hold prints as XLA
+    names it). ``host``: what the host was doing
+    (``span_reduce.host_segments``); an idle gap's time goes to the
+    host span that covers it, and only what none covers is named by
+    where it falls (``between-groups`` | ``inside-group``)."""
+    def shown(name: str) -> str:
+        return names.get(name, name)[:NAME_CUT]
+
     marks = events["marks"]
     if not marks or not events["devices"]:
         return {}
@@ -150,8 +200,8 @@ def reduce_trace(events: dict, kernel_names=()) -> dict:
         inside = [o for o in ops if o[1] < hi and o[1] + o[2] > lo]
         for name, ns in self_times(inside).items():
             op_self[name] = op_self.get(name, 0) + ns
-        # idle gaps of this device, named by where they fall and by the
-        # op that ran last before them
+        # idle gaps of this device, named by the host span over them
+        # (else by where they fall) and by the op that ran last before
         ends = sorted((s + d, n) for n, s, d in inside)
         end_times = [e for e, _ in ends]
         edges = [lo] + [x for iv in busy for x in iv] + [hi]
@@ -161,10 +211,11 @@ def reduce_trace(events: dict, kernel_names=()) -> dict:
             crosses = any(a <= m <= b for m in bounds[1:-1]) \
                 or a == lo or b == hi
             i = bisect.bisect_right(end_times, a)
-            last = ends[i - 1] if i else (0, "start")
-            name = ("between-groups" if crosses else "inside-group") \
-                + "/after:" + last[1][:80]
-            gaps[name] = gaps.get(name, 0) + b - a
+            after = "/after:" + (shown(ends[i - 1][1]) if i else "start")
+            where = "between-groups" if crosses else "inside-group"
+            for span, ns in gap_pieces(a, b, host):
+                name = (span or where) + after
+                gaps[name] = gaps.get(name, 0) + ns
     n = len(busy_ns)
     window = hi - lo
     busy_mean = sum(busy_ns) / n
@@ -177,7 +228,7 @@ def reduce_trace(events: dict, kernel_names=()) -> dict:
                               if sum(busy_ns) else 0.0),
         "collective_time_share": sum(coll_ns) / n / window,
         "n_marks": len(marks),
-        "device_ops": [[k, v / n / 1e9] for k, v in sorted(
+        "device_ops": [[shown(k), v / n / 1e9] for k, v in sorted(
             op_self.items(), key=lambda kv: -kv[1])[:10]],
         "idle_gaps": [[k, v / n / 1e9] for k, v in sorted(
             gaps.items(), key=lambda kv: -kv[1])[:10]],

@@ -1,4 +1,4 @@
-"""``trinity_moe_overflow_layer_steps``: the program's counter
+"""``moe_overflow_layer_steps``: the program's counter
 ``moe.overflow`` over the run: how many times an expert layer's router
 sent this share more rows than the layer's budget, so that the step ran
 the sorted domain's body again over the further chunks (1 a layer a

@@ -1,4 +1,4 @@
-"""``granite_attn_time_share.train``: device self time of the ops of the
+"""``scaled_attn_time_share.train``: device self time of the ops of the
 attention layers that carry a softmax scale of the model's own
 (``OP_MULTIHEAD_ATTENTION`` with ``sm_scale``: the projections, the
 three flash kernels reading the K/V heads in place, the output

@@ -1,4 +1,4 @@
-"""``granite_ssm_min_chunk_log_decay``: how much of the state a chunk
+"""``ssm_min_chunk_log_decay``: how much of the state a chunk
 starts from is still there at its end. A state-space mixer adds to the
 program's counter ``ssm.min_chunk_log_decay`` the most negative
 log-decay summed over one chunk (``G_C``, over its heads and chunks) and

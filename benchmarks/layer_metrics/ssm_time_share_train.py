@@ -1,4 +1,4 @@
-"""``granite_ssm_time_share.train``: device self time of the ops of the
+"""``ssm_time_share.train``: device self time of the ops of the
 state-space mixers (``OP_STATE_SPACE_MIXER``: the fused input
 projection, the convolution, the step sizes and log-decays, the chunked
 scan, the skip, the gated norm, the output projection), forward,

@@ -54,8 +54,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     metrics = {}
     if trace:
         for m in cell.per_layer:
-            reader = cells.load_module(cell.bench_dir, "layer_metrics",
-                                       cells.metric_file(m["name"]))
+            reader = cells.load_reader(cell.bench_dir, m["name"])
             value = None if reader is None else reader.read(res.ctx)
             if value is None:
                 say(f"per-layer metric {m['name']}: nothing to read")

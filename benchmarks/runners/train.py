@@ -59,7 +59,7 @@ import types
 
 import numpy as np
 
-from benchmarks.harness import cells, peaks, trace_reduce
+from benchmarks.harness import cells, peaks, span_reduce, trace_reduce
 
 TRACED_GROUPS = 2
 PROGRAM_SEED = 0
@@ -383,16 +383,25 @@ def run(cell, seed: int, seconds: float, trace: bool, say, t_start: float):
         f" (median group {statistics.median(clock.groups):.4f}s)"
         if clock.groups else "window: no group completed")
 
-    reduced = None
+    reduced = events = instr = None
     dev = _device_info()
     if trace and clock.traced == TRACED_GROUPS:
         t0 = time.perf_counter()
         kernel_names = [line.split("=")[0].strip().split()[-1]
                         for line in step_text.splitlines()
                         if 'custom_call_target="tpu_custom_call"' in line]
-        events = trace_reduce.extract(trace_reduce.find_xplane(trace_dir),
-                                      mark_prefix=MARK)
-        reduced = trace_reduce.reduce_trace(events, kernel_names)
+        # the one parse of the trace and of the step's text, kept for
+        # the per-layer readers; the breakdown goes under the program's
+        # names: each op behind its innermost scope, each idle gap under
+        # the host span over it
+        events = span_reduce.extract(trace_reduce.find_xplane(trace_dir),
+                                     mark_prefix=MARK)
+        instr = span_reduce.instructions(step_text)
+        reduced = trace_reduce.reduce_trace(
+            events, kernel_names,
+            names=span_reduce.scoped_names(instr,
+                                           {l.name for l in ff.layers}),
+            host=span_reduce.host_segments(events))
         scalars = {k: v for k, v in reduced.items()
                    if not isinstance(v, list)}
         say(f"trace reduced in {time.perf_counter() - t0:.1f}s: {scalars}")
@@ -406,7 +415,8 @@ def run(cell, seed: int, seconds: float, trace: bool, say, t_start: float):
             spans[ev["name"]] = spans.get(ev["name"], 0.0) + ev["dur"]
     ctx = types.SimpleNamespace(
         cell=cell, model=ff, spans=spans, counters=dict(obs.counters()),
-        step_text=step_text, trace=reduced, groups=list(clock.groups),
+        step_text=step_text, trace=reduced, span_events=events,
+        span_instructions=instr, groups=list(clock.groups),
         steps_per_group=spg, tokens_per_step=tokens_per_step,
         tokens_per_s=tokens_per_s, chips=n_dev, peak=peak,
         train_flops_per_token=flops.train_flops_per_token(conf, seq),

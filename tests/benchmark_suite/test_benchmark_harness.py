@@ -120,8 +120,8 @@ def stub_profiler(monkeypatch):
                         lambda *a, **k: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: d)
-    monkeypatch.setattr(trace_reduce, "extract",
-                        lambda path, mark_prefix="": recorded["events"])
+    monkeypatch.setattr(trace_reduce, "extract", lambda *a: dict(
+        recorded["events"], spans=[]))
     return recorded
 
 
@@ -195,7 +195,23 @@ def test_a_later_pr_adds_a_cell_with_files_only(tiny_root, tmp_path):
         "source": "program_counter", "layer": "executor",
         "moves": "train_tokens_per_s",
         "workloads": ["gpt2_wider.train"]})
+    # the new cell opts into a reading whose entry lists other cells:
+    # an entry of its own that names the accepted entry's reader
+    accepted = next(m for m in man["per_layer"]
+                    if m["name"] == "flash_fwd_roofline")
+    assert "gpt2_wider.train" not in accepted["workloads"]
+    man["per_layer"].append(dict(accepted, name="wider_flash_fwd_roofline",
+                                 workloads=["gpt2_wider.train"]))
+    _dump({"reader": "flash_fwd_roofline"}, os.path.join(
+        root, "benchmarks", "layer_metrics",
+        "wider_flash_fwd_roofline.json"))
     _dump(man, os.path.join(root, "BENCHMARK.json"))
+    bench = os.path.join(root, "benchmarks")
+    assert cells.load_reader(bench, "wider_flash_fwd_roofline").read.__code__ \
+        == cells.load_reader(bench, "flash_fwd_roofline").read.__code__
+    assert "wider_flash_fwd_roofline" in {
+        m["name"] for m in
+        cells.resolve_cell(root, "gpt2_wider.train").per_layer}
     res, said = _run(root, "gpt2_wider.train", True)
     assert res["metrics"]["loss_drop.train"] == {"value": 8.0,
                                                  "unit": "count"}
@@ -205,7 +221,8 @@ def test_a_later_pr_adds_a_cell_with_files_only(tiny_root, tmp_path):
     assert {k: after[k] for k in before} == before
     assert set(after) - set(before) == {
         "configs/gpt2_wider.json", "traffic/train_tiny_s32.json",
-        "layer_metrics/loss_drop_train.py"}
+        "layer_metrics/loss_drop_train.py",
+        "layer_metrics/wider_flash_fwd_roofline.json"}
     # and the cell that was there does not report the new metric
     assert "loss_drop.train" not in {
         m["name"] for m in
@@ -345,8 +362,7 @@ def test_every_workload_resolves_to_files_that_exist(workload):
     mod, _, fn = cell.config["reference"].partition(":")
     assert callable(getattr(cells.load_module(BENCH, "reference", mod), fn))
     for m in cell.per_layer:
-        reader = cells.load_module(BENCH, "layer_metrics",
-                                   cells.metric_file(m["name"]))
+        reader = cells.load_reader(BENCH, m["name"])
         assert reader is not None and callable(reader.read), m["name"]
     # the configuration is the program's own class at published widths
     cls = cells.load_attr(cell.config["config_class"])

@@ -69,11 +69,11 @@ PR48 = {     # name -> (unit, source, layer)
     "keye_dsa_select_time_share.train": ("%", "device_trace",
                                          "sparse_attention"),
     "keye_attn_time_share.train": ("%", "device_trace", "attention"),
-    "keye_moe_time_share.train": ("%", "device_trace", "experts"),
+    "moe_time_share.train": ("%", "device_trace", "experts"),
     "keye_dsa_kept_share": ("ratio", "program_counter", "sparse_attention"),
     "keye_dsa_index_kl": ("nats", "program_counter", "sparse_attention"),
-    "keye_moe_dropped_assignments": ("count", "program_counter", "experts"),
-    "keye_moe_overflow_layer_steps": ("count", "program_counter",
+    "moe_dropped_assignments": ("count", "program_counter", "experts"),
+    "moe_overflow_layer_steps": ("count", "program_counter",
                                       "experts")}
 SHARED = {"compile_s", "step_ms.train", "mfu.train", "in_window_compiles",
           "mosaic_calls_per_step", "kernel_time_share.train",
@@ -81,9 +81,19 @@ SHARED = {"compile_s", "step_ms.train", "mfu.train", "in_window_compiles",
 PARENT_COMMIT = "59797a2853318f7b05b28c9ed00a53dcaa962783"
 
 
+OWN = {n for n in PR48 if n.startswith("keye_")}     # no other cell's
+
+
 def _read(metric, ctx):
     return cells.load_module(BENCH, "layer_metrics",
                              cells.metric_file(metric)).read(ctx)
+
+
+def _holding(cell, listed):
+    """``listed``, which has to hold ``cell``: an entry lists every cell
+    whose run gives its reader a reading, this one among them."""
+    assert cell in listed
+    return listed
 
 
 @pytest.fixture(scope="module")
@@ -108,20 +118,21 @@ def test_each_new_metric_lists_the_cell_and_has_a_reader(manifest, name):
     assert by_name[name] == {
         "name": name, "unit": unit, "better": "lower", "source": source,
         "layer": layer, "moves": "train_tokens_per_s",
-        "workloads": [CELL] + by_name[name]["workloads"][1:]}
+        "workloads": _holding(CELL, by_name[name]["workloads"])}
     assert callable(cells.load_module(
         BENCH, "layer_metrics", cells.metric_file(name)).read)
 
 
 def test_the_new_entries_come_after_every_entry_the_parent_had(manifest):
-    """After the six cells and configurations and the 70 metrics of the
-    parent, whose names ``test_benchmark_xing.py`` holds; what comes
-    after this PR's is not this test's to say."""
+    """After the six cells and configurations of the parent and the
+    set-up's metrics, whose names ``test_benchmark_xing.py`` holds (PR
+    68 folded the copies among the 70 there were, so no count is held);
+    what comes after this PR's is not this test's to say."""
     order = [m["name"] for m in manifest["per_layer"]]
-    assert order.index("xing_setup_attributed_share") \
-        < min(order.index(n) for n in PR48)
-    assert sum(1 for n in order if order.index(n)
-               < min(order.index(m) for m in PR48)) >= 70
+    assert order.index("setup_attributed_share") \
+        < min(order.index(n) for n in OWN)
+    assert [n for n in order if n in OWN] \
+        == [n for n in PR48 if n in OWN]
     configs = [c["name"] for c in manifest["configs"]]
     assert all(configs.index(c) < configs.index(CONFIG)
                for c, _ in OLDER_CELLS.values())
@@ -146,13 +157,16 @@ def test_every_older_cell_is_unmoved(manifest, older):
     assert entry == dict(entry, config=config, traffic=traffic, chips=1)
     assert sum(c["name"] == config for c in manifest["configs"]) == 1
     reported = {m["name"] for m in cells.resolve_cell(ROOT, older).per_layer}
-    assert SHARED <= reported and not reported & set(PR48)
+    assert SHARED <= reported and not reported & OWN
 
 
 def test_the_manifest_is_the_parents_plus_this_prs_entries(manifest):
     """Against ``git show <parent>:BENCHMARK.json`` where the checkout
     has its history (the driver's copy of the committed files has not):
-    every older entry equal, key for key, in its old place."""
+    every older configuration and cell in its old place with its old
+    files, every older metric still reported under its name or under
+    the one ``testdata/folded_names.json`` gives for it, with
+    its unit, source, layer and what it moves."""
     import subprocess
     shown = subprocess.run(
         ["git", "show", f"{PARENT_COMMIT}:BENCHMARK.json"], cwd=ROOT,
@@ -160,14 +174,23 @@ def test_the_manifest_is_the_parents_plus_this_prs_entries(manifest):
     if shown.returncode != 0:
         pytest.skip("no git history here")
     parent = json.loads(shown.stdout)
+    with open(os.path.join(BENCH, "testdata", "folded_names.json")) as f:
+        renamed = json.load(f)["renamed"]
     for key in ("command", "paths", "run_seconds", "end_to_end"):
         assert manifest[key] == parent[key]
-    for key in ("configs", "workloads", "per_layer"):
-        assert manifest[key][:len(parent[key])] == parent[key]
-        mine = {"configs": [CONFIG], "workloads": [CELL],
-                "per_layer": list(PR48)}[key]
+    for key in ("configs", "workloads"):
+        for was, now in zip(parent[key], manifest[key]):
+            assert dict(was, why="") == dict(now, why="")
+        mine = {"configs": [CONFIG], "workloads": [CELL]}[key]
         added = [e["name"] for e in manifest[key][len(parent[key]):]]
         assert added[:len(mine)] == mine
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    for was in parent["per_layer"]:
+        now = by_name[renamed.get(was["name"], was["name"])]
+        for field in ("unit", "better", "source", "layer", "moves"):
+            assert now[field] == was[field], was["name"]
+        for cell_ in was.get("workloads", ()):
+            assert "workloads" not in now or cell_ in now["workloads"]
 
 
 def test_the_cell_reports_the_shared_metrics_and_its_own(cell):
@@ -373,22 +396,18 @@ def _model(layers):
         for n, k, p in layers])
 
 
-def _hand_ctx(tmp_path, monkeypatch, ops=OPS, layers=LAYERS):
+def _hand_ctx(tmp_path, ops=OPS, layers=LAYERS):
     """A context whose trace is the hand-made one: the reductions that
-    keep their result on it are given it, the one reader that opens the
-    trace itself is handed the same events."""
+    keep their result on it are given it, and the events beside them."""
     events = {"devices": {"/device:TPU:0": [[n, s * US, d * US]
                                             for n, s, d, _ in ops]},
               "marks": [["bench.group", 1000 * US, 1000 * US]], "spans": []}
     instr = {n: {"op_name": op, "mosaic": n.startswith("ragged"),
                  "operands": [], "results": []} for n, _, _, op in ops}
     names = {n for n, _, _ in layers}
-    monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: d)
-    monkeypatch.setattr(span_reduce, "extract",
-                        lambda path, mark_prefix="": events)
     return types.SimpleNamespace(
         span_reduced=span_reduce.reduce_spans(events, instr),
-        span_instructions=instr, model=_model(layers),
+        span_events=events, span_instructions=instr, model=_model(layers),
         scope_layer_ns=scope_reduce.layer_self_ns(events, instr, names),
         peak=peaks.lookup("TPU v5 lite"), counters={},
         cell=types.SimpleNamespace(bench_dir=BENCH, root=str(tmp_path),
@@ -399,26 +418,26 @@ def _hand_ctx(tmp_path, monkeypatch, ops=OPS, layers=LAYERS):
     ("keye_dsa_time_share.train", 100.0 * 240 / 760),
     ("keye_dsa_select_time_share.train", 100.0 * 100 / 760),
     ("keye_attn_time_share.train", 100.0 * 360 / 760),
-    ("keye_moe_time_share.train", 100.0 * 100 / 760)])
+    ("moe_time_share.train", 100.0 * 100 / 760)])
 def test_time_shares_by_hand_with_the_threshold_searchs_loop(
-        tmp_path, monkeypatch, metric, want):
+        tmp_path, metric, want):
     """The ``while`` event counts for what its body's ops leave of it,
     beside them; a chunk's own second run and the transposes count under
     the scope they carry; the indexer's share and the rest of the layer
     add up to the layer's 600 of 760."""
-    ctx = _hand_ctx(tmp_path, monkeypatch)
+    ctx = _hand_ctx(tmp_path)
     assert ctx.span_reduced["busy_ns"] == 760 * US
     assert _read(metric, ctx) == pytest.approx(want)
 
 
-def test_the_counters_by_hand(tmp_path, monkeypatch):
-    ctx = _hand_ctx(tmp_path, monkeypatch)
+def test_the_counters_by_hand(tmp_path):
+    ctx = _hand_ctx(tmp_path)
     ctx.counters = {"moe.dropped": 0.0, "moe.overflow": 3.0,
                     "dsa.kept_pairs": 4 * 14681088.0,
                     "dsa.causal_pairs": 4 * 33558528.0,
                     "dsa.index_kl": 0.5, "dsa.layers": 8.0}
-    assert _read("keye_moe_dropped_assignments", ctx) == 0.0
-    assert _read("keye_moe_overflow_layer_steps", ctx) == 3.0
+    assert _read("moe_dropped_assignments", ctx) == 0.0
+    assert _read("moe_overflow_layer_steps", ctx) == 3.0
     assert _read("keye_dsa_kept_share", ctx) == pytest.approx(
         14681088 / 33558528)
     # 6144 queries keep 2048 keys and the first 2048 keep all theirs
@@ -429,7 +448,7 @@ def test_the_counters_by_hand(tmp_path, monkeypatch):
 
 @pytest.mark.parametrize("metric", sorted(PR48))
 def test_every_new_reader_reads_nothing_from_the_parent(
-        tmp_path, monkeypatch, metric):
+        tmp_path, metric):
     """The parent of PR 48 names no layer with an indexer, opens no
     ``dsa.*`` scope and counts no ``dsa.*``; a model of the parent's
     (LFM2's grouped-query attention) has no such layer; and a run
@@ -442,7 +461,7 @@ def test_every_new_reader_reads_nothing_from_the_parent(
     ops = [("fusion.1", 1000, 100, FWD + "attn_1/mul"),
            ("flash_attention_fwd.1", 1100, 200,
             FWD + "attn_1/flash_attention_fwd/pallas_call")]
-    assert _read(metric, _hand_ctx(tmp_path, monkeypatch, ops, lfm2)) is None
+    assert _read(metric, _hand_ctx(tmp_path, ops, lfm2)) is None
     cell = types.SimpleNamespace(root=str(tmp_path), name="x.train",
                                  bench_dir=BENCH)
     bare = types.SimpleNamespace(
@@ -463,7 +482,9 @@ def test_every_new_reader_reads_the_recorded_testdata_without_error(
         spans = json.load(f)
     assert recorded and spans
     ctx = types.SimpleNamespace(
-        trace=trace_reduce.reduce_trace(recorded["events"], []),
+        trace=trace_reduce.reduce_trace(recorded["events"], [], {}, []),
+        span_events=dict(recorded["events"], spans=[]),
+        span_instructions={},
         cell=types.SimpleNamespace(root="/nonexistent", name="x.train",
                                    bench_dir=BENCH),
         step_text="", peak=peaks.lookup("TPU v5 lite"), counters={},
@@ -473,14 +494,14 @@ def test_every_new_reader_reads_the_recorded_testdata_without_error(
 
 
 def test_a_layer_with_no_op_under_the_scopes_reads_the_layer_alone(
-        tmp_path, monkeypatch):
+        tmp_path):
     """A program whose attention layers open no ``dsa.*`` scope: the
     experts' share reads, the indexer's and the split do not."""
     ops = [(n, s, d, "/".join(p for p in op.split("/")
                               if not p.startswith("dsa.")))
            for n, s, d, op in OPS]
-    ctx = _hand_ctx(tmp_path, monkeypatch, ops)
-    assert _read("keye_moe_time_share.train", ctx) == pytest.approx(
+    ctx = _hand_ctx(tmp_path, ops)
+    assert _read("moe_time_share.train", ctx) == pytest.approx(
         100.0 * 100 / 760)
     for metric in ("keye_dsa_time_share.train",
                    "keye_dsa_select_time_share.train",
@@ -546,8 +567,8 @@ def no_profiler(monkeypatch):
     monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: d)
-    monkeypatch.setattr(trace_reduce, "extract",
-                        lambda path, mark_prefix="": recorded["events"])
+    monkeypatch.setattr(trace_reduce, "extract", lambda *a: dict(
+        recorded["events"], spans=[]))
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -570,8 +591,8 @@ def test_the_cell_runs_through_the_train_runner(tiny_root, no_profiler,
     assert res["failed"] == 0 and res["attempted"] >= 1
     if trace:
         metrics = res["metrics"]
-        assert metrics["keye_moe_dropped_assignments"]["value"] == 0
-        assert metrics["keye_moe_overflow_layer_steps"]["value"] == 0
+        assert metrics["moe_dropped_assignments"]["value"] == 0
+        assert metrics["moe_overflow_layer_steps"]["value"] == 0
         kept = sum(min(t + 1, 24) for t in range(40)) / (40 * 41 / 2)
         assert metrics["keye_dsa_kept_share"]["value"] == pytest.approx(
             kept, rel=1e-6)

@@ -68,52 +68,63 @@ def cell():
 # ----------------------------------------------------------------------
 # the manifest and the configuration's file
 # ----------------------------------------------------------------------
-def test_the_manifest_gains_pr29s_eight_at_its_end_and_moves_no_entry():
-    """PR 29's eight entries are the last of the list, after PR 24's
-    seven (no ``workloads`` list) and PR 25's nine: the benchmark's
-    contract takes new entries at the end only."""
+def test_the_manifest_holds_pr29s_eight_by_name_and_moves_no_entry():
+    """By name: PR 29's eight entries after PR 24's seven (no
+    ``workloads`` list) and PR 25's nine, each listing this cell, with
+    its reader."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         man = json.load(f)
     names = [m["name"] for m in man["per_layer"]]
-    assert names[7:16] == PR25 and names[16:] == PR29
+    by_name = {m["name"]: m for m in man["per_layer"]}
+    assert len(by_name) == len(names)
     assert not any("workloads" in m for m in man["per_layer"][:7])
-    for m in man["per_layer"][16:]:
-        assert m["workloads"] == [CELL]
+    assert [n for n in names if n in PR25 + PR29] == PR25 + PR29
+    for name in PR29:
+        m = by_name[name]
+        assert CELL in m["workloads"]
         assert m["moves"] == "train_tokens_per_s"
         assert callable(cells.load_module(
-            BENCH, "layer_metrics", cells.metric_file(m["name"])).read)
-    assert [c["name"] for c in man["configs"]][-1] == "joyai_llm_flash"
-    assert [w["name"] for w in man["workloads"]][-1] == CELL
-    assert man["workloads"][-1]["chips"] == 1
+            BENCH, "layer_metrics", cells.metric_file(name)).read)
+    configs = [c["name"] for c in man["configs"]]
+    assert configs.index("gpt2_124m") < configs.index("joyai_llm_flash")
+    cell_names = [w["name"] for w in man["workloads"]]
+    assert cell_names.index("gpt2_124m.train.1chip") \
+        < cell_names.index(CELL)
+    assert man["workloads"][cell_names.index(CELL)]["chips"] == 1
 
 
 def test_pr25s_nine_metrics_stand_as_they_were():
-    """What ``test_benchmark_span_reduce.py``'s manifest test asserts of
-    ``per_layer[-9:]``, asserted of the same nine entries where they
-    stay (``conftest.py`` says why that test is deselected)."""
+    """PR 25's nine entries BY NAME, in the order they came in, before
+    this cell's: what ``test_benchmark_span_reduce.py``'s manifest test
+    holds, seen from the cell that was added after them."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         man = json.load(f)
-    nine = man["per_layer"][7:16]
-    assert [m["name"] for m in nine] == PR25
+    by_name = {m["name"]: m for m in man["per_layer"]}
+    names = [m["name"] for m in man["per_layer"]]
+    assert [n for n in names if n in PR25] == PR25
+    assert max(names.index(n) for n in PR25) \
+        < min(names.index(n) for n in PR29)
     both = ["bert_large.train.1chip", "gpt2_124m.train.1chip"]
-    for m in nine:
+    for name in PR25:
+        m = by_name[name]
         assert m["moves"] == "train_tokens_per_s"
-        assert m["workloads"] == (both[1:] if "roofline" in m["name"]
-                                  else both)
-        assert m["source"] == ("program_span" if m["name"] in PR25[6:]
+        for cell in both[1:] if "roofline" in name else both:
+            assert "workloads" not in m or cell in m["workloads"], name
+        assert m["source"] == ("program_span" if name in PR25[6:]
                                else "device_trace")
         assert callable(cells.load_module(
-            BENCH, "layer_metrics", cells.metric_file(m["name"])).read)
-    assert {m["layer"] for m in nine} == {
+            BENCH, "layer_metrics", cells.metric_file(name)).read)
+    assert {by_name[n]["layer"] for n in PR25} == {
         "executor", "kernels", "device", "loader"}
 
 
-def test_the_cell_reports_the_shared_metrics_and_its_own(cell):
+def test_the_cell_reports_the_shared_metrics_and_its_own_by_name(cell):
+    """By name, and open to what later PRs list the cell under."""
     assert {m["name"] for m in cell.end_to_end} == {
         "train_tokens_per_s", "step_hbm_gib", "setup_s"}
     mine = [m["name"] for m in cell.per_layer]
-    assert mine[-8:] == PR29
-    assert set(mine[:-8]) == {
+    assert [n for n in mine if n in PR29] == PR29
+    assert set(mine) >= {
         "compile_s", "step_ms.train", "mfu.train", "in_window_compiles",
         "mosaic_calls_per_step", "kernel_time_share.train",
         "device_idle_share.train"}
@@ -324,7 +335,7 @@ def _hand_ctx(ops=OPS, layers=LAYERS):
     names = {n for n, _ in layers}
     return types.SimpleNamespace(
         span_reduced=span_reduce.reduce_spans(events, instr),
-        span_instructions=instr, model=model,
+        span_events=events, span_instructions=instr, model=model,
         scope_layer_ns=scope_reduce.layer_self_ns(events, instr, names),
         peak=peaks.lookup("TPU v5 lite"), counters={},
         cell=types.SimpleNamespace(bench_dir=BENCH))
@@ -452,8 +463,8 @@ def tiny_root(tmp_path_factory):
                              "traffic": "train_tiny_remat", "chips": 8,
                              "why": "test"})
     for m in man["per_layer"]:
-        if m.get("workloads") == [CELL]:
-            m["workloads"] = [CELL, "joyai_tiny.train"]
+        if CELL in m.get("workloads", ()):
+            m["workloads"] = m["workloads"] + ["joyai_tiny.train"]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(man, f)
     return root
@@ -467,8 +478,8 @@ def no_profiler(monkeypatch):
     monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: d)
-    monkeypatch.setattr(trace_reduce, "extract",
-                        lambda path, mark_prefix="": recorded["events"])
+    monkeypatch.setattr(trace_reduce, "extract", lambda *a: dict(
+        recorded["events"], spans=[]))
 
 
 @pytest.mark.parametrize("trace", [0, 1])

@@ -8,12 +8,12 @@ readers the cell reports through read a hand-made trace, the recorded
 test traces and a parent's program (nothing, without an error).
 
 The driver's contract for ``BENCHMARK.json`` lets ``per_layer`` hold 128
-entries and the list held 127: the cell brings ONE metric of its own
-(``nemotron_moe_latent_time_share.train``) and edits no older entry. The
+entries and the list held 127: the cell brought ONE metric of its own
+(``nemotron_moe_latent_time_share.train``) and edited no older entry. The
 state-space, attention, expert and flash readers of cells 7, 8 and 9
 read this cell's spans and counters too (held below on a hand-made
-trace): a ``benchmark`` PR that folds the list's doubles can list the
-cell under them (ROADMAP B3).
+trace), and their entries list the cell, as do the two readers of
+``moe.route`` and ``moe.shared``.
 """
 import dataclasses
 import json
@@ -71,7 +71,7 @@ def test_the_new_metric_lists_the_cell(manifest):
     assert callable(cells.load_module(
         BENCH, "layer_metrics", cells.metric_file(NEW)).read)
     order = [m["name"] for m in manifest["per_layer"]]
-    assert order.index("sdar_moe_overflow_layer_steps") < order.index(NEW)
+    assert order.index("moe_overflow_layer_steps") < order.index(NEW)
 
 
 def test_the_new_entries_come_after_every_entry_the_parent_had(manifest):
@@ -331,7 +331,7 @@ def _hand_ctx(ops=OPS, layers=LAYERS):
     names = {n for n, _, _ in layers}
     return types.SimpleNamespace(
         span_reduced=span_reduce.reduce_spans(events, instr),
-        span_instructions=instr, model=_model(layers),
+        span_events=events, span_instructions=instr, model=_model(layers),
         scope_layer_ns=scope_reduce.layer_self_ns(events, instr, names),
         name_by_op=scope_reduce.op_self_ns(events, instr, names),
         peak=peaks.lookup("TPU v5 lite"), counters={},
@@ -340,10 +340,12 @@ def _hand_ctx(ops=OPS, layers=LAYERS):
 
 @pytest.mark.parametrize("metric,want", [
     (NEW, 100.0 * 140 / 800),
-    ("keye_moe_time_share.train", 100.0 * 300 / 800),
-    ("granite_ssm_time_share.train", 100.0 * 250 / 800),
-    ("granite_ssm_scan_time_share.train", 100.0 * 150 / 800),
-    ("granite_attn_time_share.train", 100.0 * 150 / 800)])
+    ("moe_time_share.train", 100.0 * 300 / 800),
+    ("moe_route_time_share.train", 100.0 * 40 / 800),
+    ("moe_shared_time_share.train", 100.0 * 120 / 800),
+    ("ssm_time_share.train", 100.0 * 250 / 800),
+    ("ssm_scan_time_share.train", 100.0 * 150 / 800),
+    ("scaled_attn_time_share.train", 100.0 * 150 / 800)])
 def test_time_shares_by_hand(metric, want):
     """The latent's projection, the unnamed grouped product after it and
     the token sum's backward call (60 + 50 + 30 = 140 of 800 us busy),
@@ -356,6 +358,26 @@ def test_time_shares_by_hand(metric, want):
     assert _read(metric, ctx) == pytest.approx(want)
 
 
+def test_the_scopes_of_an_expert_layer_sum_to_no_more_than_the_layer():
+    """``moe.route`` (40), ``moe.latent`` with the unnamed grouped
+    product (140) and ``moe.shared`` (120) are parts of the expert
+    layers' 300 us; a layer that opens neither scope (every program
+    before PR 66) reads nothing for the two, and no error."""
+    ctx = _hand_ctx()
+    parts = [_read(m, ctx) for m in (
+        "moe_route_time_share.train", NEW, "moe_shared_time_share.train")]
+    assert sum(parts) == pytest.approx(_read("moe_time_share.train", ctx))
+    older = [("experts_0", "OP_ROUTED_EXPERTS",
+              {"num_experts": 128, "top_k": 8, "experts_held": 16}),
+             ("lm_head", "OP_LINEAR", {})]
+    ops = [("fusion.1", 1000, 200, FWD + "experts_0/dot_general"),
+           ("fusion.2", 1200, 100, TOP + "jvp(ff.forward)/lm_head/dot")]
+    for metric in ("moe_route_time_share.train",
+                   "moe_shared_time_share.train"):
+        assert _read(metric, _hand_ctx(ops, older)) is None
+        assert _read(metric, _hand_ctx(ops, older[1:])) is None
+
+
 def test_the_flash_roofline_counts_the_triangle_at_the_kv_heads_own():
     """8 query heads reading ONE key/value head in place: the causal
     triangle's pairs a query head, K and V moved once at their own
@@ -366,7 +388,7 @@ def test_the_flash_roofline_counts_the_triangle_at_the_kv_heads_own():
                                      0, ctx.peak)
     assert bound == "operations" and seconds == pytest.approx(
         2 * 2 * 8 * (L * (L + 1) // 2) * 128 / ctx.peak["bf16_flops_per_s"])
-    assert _read("trinity_flash_fwd_roofline", ctx) \
+    assert _read("window_flash_fwd_roofline", ctx) \
         == pytest.approx(100.0 * seconds / 100e-6)
 
 
@@ -375,9 +397,9 @@ def test_the_counters_by_hand():
     ctx.counters = {"moe.dropped": 0.0, "moe.overflow": 2.0,
                     "ssm.min_chunk_log_decay": -5.0 * 40 * 30.0,
                     "ssm.layers": 5.0 * 40}
-    assert _read("keye_moe_dropped_assignments", ctx) == 0.0
-    assert _read("keye_moe_overflow_layer_steps", ctx) == 2.0
-    assert _read("granite_ssm_min_chunk_log_decay", ctx) \
+    assert _read("moe_dropped_assignments", ctx) == 0.0
+    assert _read("moe_overflow_layer_steps", ctx) == 2.0
+    assert _read("ssm_min_chunk_log_decay", ctx) \
         == pytest.approx(-30.0)
 
 

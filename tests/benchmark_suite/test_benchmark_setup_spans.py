@@ -66,38 +66,10 @@ NEW = {     # name: (unit, better, source, layer, moves), in the entries' order
     "retraces_after_warmup": ("count", "lower", "program_span", "executor",
                               "train_tokens_per_s"),
 }
-# every entry the parent had, by name, with the cells it lists (None:
-# no list, every cell reports it)
+# the seven entries without a list that the manifest had before these
 SHARED = ["compile_s", "step_ms.train", "mfu.train", "in_window_compiles",
           "mosaic_calls_per_step", "kernel_time_share.train",
           "device_idle_share.train"]
-ACCEPTED = dict(
-    {name: None for name in SHARED},
-    **{name: CELLS_1_2 for name in (
-        "fwd_time_share.train", "bwd_time_share.train",
-        "opt_time_share.train")},
-    **{name: CELLS_1_2[1:] for name in (
-        "flash_fwd_roofline", "flash_bwd_dq_roofline",
-        "flash_bwd_dkv_roofline")},
-    **{name: CELLS_1_2 for name in (
-        "idle_attributed_share.train", "dispatch_ms_per_step.train",
-        "loader_wait_ms_per_step.train")},
-    **{name: CELLS_3_5[:1] for name in (
-        "mla_time_share.train", "moe_time_share.train",
-        "mtp_time_share.train", "mla_flash_fwd_roofline",
-        "mla_flash_bwd_dq_roofline", "mla_flash_bwd_dkv_roofline",
-        "moe_dropped_assignments", "moe_load_max_over_mean")},
-    **{name: CELLS_3_5[1:2] for name in (
-        "short_conv_time_share.train", "gqa_time_share.train",
-        "lfm2_moe_time_share.train", "gqa_flash_fwd_roofline",
-        "gqa_flash_bwd_dq_roofline", "gqa_flash_bwd_dkv_roofline",
-        "lfm2_moe_dropped_assignments")},
-    **{name: CELLS_3_5[2:] for name in (
-        "kda_time_share.train", "kda_scan_time_share.train",
-        "kimi_mla_time_share.train", "kimi_moe_time_share.train",
-        "kimi_mla_flash_fwd_roofline", "kimi_mla_flash_bwd_dq_roofline",
-        "kimi_mla_flash_bwd_dkv_roofline",
-        "kimi_moe_dropped_assignments")})
 
 
 def _span(name, start, end, **attrs):
@@ -283,33 +255,40 @@ def manifest():
         return json.load(f)
 
 
+def _reports(entry, workload) -> bool:
+    return "workloads" not in entry or workload in entry["workloads"]
+
+
 @pytest.mark.parametrize("name", list(NEW))
 def test_every_new_metric_has_its_entry_and_its_reader(manifest, name):
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     unit, better, source, layer, moves = NEW[name]
-    assert by_name[name] == {
+    entry = dict(by_name[name])
+    entry.pop("workloads", None)
+    assert entry == {
         "name": name, "unit": unit, "better": better, "source": source,
-        "layer": layer, "moves": moves, "workloads": CELLS_1_2}
+        "layer": layer, "moves": moves}
+    assert all(_reports(by_name[name], w) for w in CELLS_1_2)
     assert callable(cells.load_module(
         BENCH, "layer_metrics", cells.metric_file(name)).read)
 
 
-def test_the_accepted_entries_stand_and_the_new_ones_come_after(manifest):
+def test_the_seven_and_the_older_shared_entries_stand_by_name(manifest):
+    """By name, and open to later entries and cells: every name once,
+    the seven shared entries with no list, the seven of the set-up
+    after them, ``compile_s`` as it was, the five cells in their
+    order."""
     order = [m["name"] for m in manifest["per_layer"]]
     assert len(set(order)) == len(order)
-    assert set(order) == set(ACCEPTED) | set(NEW)
-    for m in manifest["per_layer"]:
-        if m["name"] in ACCEPTED:
-            assert m.get("workloads") == ACCEPTED[m["name"]], m["name"]
-            assert order.index(m["name"]) < order.index("host_init_s")
-    # the accepted ones in the order they had: the seven without a list
-    # first, then each PR's in turn (the dict above is written in it)
-    assert [n for n in order if n in ACCEPTED] == list(ACCEPTED)
-    at = [order.index(n) for n in NEW]
-    assert at == list(range(at[0], at[0] + 7))
-    assert [w["name"] for w in manifest["workloads"]] \
-        == CELLS_1_2 + CELLS_3_5
     by_name = {m["name"]: m for m in manifest["per_layer"]}
+    for name in SHARED:
+        assert "workloads" not in by_name[name], name
+        assert order.index(name) < order.index("host_init_s")
+    assert [n for n in order if n in SHARED] == SHARED
+    assert [n for n in order if n in NEW] == list(NEW)
+    cells_ = [w["name"] for w in manifest["workloads"]]
+    assert [c for c in cells_ if c in CELLS_1_2 + CELLS_3_5] \
+        == CELLS_1_2 + CELLS_3_5
     assert by_name["compile_s"] == {
         "name": "compile_s", "unit": "s", "better": "lower",
         "source": "host_clock", "layer": "entry", "moves": "setup_s"}
@@ -317,12 +296,11 @@ def test_the_accepted_entries_stand_and_the_new_ones_come_after(manifest):
 
 
 @pytest.mark.parametrize("workload", CELLS_1_2 + CELLS_3_5)
-def test_cells_one_and_two_report_the_seven_and_the_others_do_not(workload):
+def test_every_training_cell_reports_the_seven(workload):
+    """The readers find the same spans in every training cell's ring,
+    so the entries list no cells."""
     mine = {m["name"] for m in cells.resolve_cell(ROOT, workload).per_layer}
-    if workload in CELLS_1_2:
-        assert set(NEW) <= mine
-    else:
-        assert not set(NEW) & mine
+    assert set(NEW) <= mine
 
 
 # ----------------------------------------------------------------------
@@ -343,7 +321,7 @@ def tiny_root(tmp_path_factory):
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         man = json.load(f)
     for m in man["per_layer"]:
-        if m["name"] in NEW:
+        if m["name"] in NEW and "workloads" in m:
             m["workloads"] = m["workloads"] + [
                 f"{name}.train" for name in harness.TINY]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:

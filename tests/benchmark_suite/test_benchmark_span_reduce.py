@@ -84,7 +84,7 @@ def test_busy_and_idle_are_trace_reduces(hand, reduced):
     assert reduced["idle_ns"] == (80 + 430 + 60) * US
     kernels = [n for n, e in sr.instructions(hand["step_text"]).items()
                if e["mosaic"]]
-    old = trace_reduce.reduce_trace(hand["events"], kernels)
+    old = trace_reduce.reduce_trace(hand["events"], kernels, {}, [])
     assert old["busy_s"] == pytest.approx(reduced["busy_ns"] / 1e9)
     assert old["idle_share"] == pytest.approx(570 / 2400)
     # the three kernels' device times are kernel_time_share x busy
@@ -258,13 +258,14 @@ def test_flash_roofline_shares_against_a_hand_count(hand, reduced):
 
 @pytest.mark.parametrize("metric", NEW_METRICS)
 def test_every_new_reader_returns_none_without_a_trace(metric, tmp_path):
-    """No ``--trace 1`` (``ctx.trace`` is None), and a traced run whose
-    profiler was stubbed (the CPU tests): nothing to read, no error."""
+    """No ``--trace 1`` (``ctx.trace`` is None), and a traced run that
+    left no events to parse: nothing to read, no error."""
     cell = types.SimpleNamespace(root=str(tmp_path), name="x.train",
                                  bench_dir=BENCH)
     for trace in (None, {"idle_share": 0.1}):
         ctx = types.SimpleNamespace(trace=trace, cell=cell, step_text="",
-                                    peak=None)
+                                    span_events=None,
+                                    span_instructions=None, peak=None)
         assert _read(metric, ctx) is None
 
 
@@ -288,19 +289,103 @@ def test_every_new_reader_returns_none_on_a_program_that_names_nothing(
     assert _read(metric, ctx) is None
 
 
-def test_the_manifest_appends_nine_metrics_each_with_its_reader():
+def test_the_manifest_holds_pr25s_nine_metrics_each_with_its_reader():
+    """By name: the nine entries in their order, what each moves, where
+    it comes from, its layer, its reader, and that cells 1 and 2 report
+    them."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         man = json.load(f)
+    by_name = {m["name"]: m for m in man["per_layer"]}
+    assert len(by_name) == len(man["per_layer"])
     names = [m["name"] for m in man["per_layer"]]
-    assert names[-9:] == NEW_METRICS
+    assert [n for n in names if n in NEW_METRICS] == NEW_METRICS
     both = ["bert_large.train.1chip", "gpt2_124m.train.1chip"]
-    for m in man["per_layer"][-9:]:
+    for name in NEW_METRICS:
+        m = by_name[name]
         assert m["moves"] == "train_tokens_per_s"
-        assert m["workloads"] == (both[1:] if "roofline" in m["name"]
-                                  else both)
-        assert m["source"] == ("program_span" if m["name"] in NEW_METRICS[6:]
+        for cell in both[1:] if "roofline" in name else both:
+            assert "workloads" not in m or cell in m["workloads"], name
+        assert m["source"] == ("program_span" if name in NEW_METRICS[6:]
                                else "device_trace")
         assert callable(cells.load_module(
-            BENCH, "layer_metrics", cells.metric_file(m["name"])).read)
-    assert {m["layer"] for m in man["per_layer"][-9:]} == {
+            BENCH, "layer_metrics", cells.metric_file(name)).read)
+    assert {by_name[n]["layer"] for n in NEW_METRICS} == {
         "executor", "kernels", "device", "loader"}
+
+
+# ----------------------------------------------------------------------
+# the breakdown under the program's names
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(step_fn)/jvp(ff.forward)/dense_1/dot_general", "dense_1"),
+    ("jit(step_fn)/transpose(jvp(ff.forward))/jvp(ff.forward)/remat.block/"
+     "checkpoint/rematted_computation/experts_0/moe.shared/dot_general",
+     "moe.shared"),
+    ("jit(step_fn)/jvp(ff.forward)/attn_2/attn.kernels/flash_attention_fwd/"
+     "pallas_call", "attn.kernels"),
+    # a layer the model does not name is JAX's word for all we know
+    ("jit(step_fn)/jvp(ff.forward)/mlp_9/dot_general", "ff.forward"),
+    ("jit(step_fn)/transpose(jvp(ff.loss))/sub", "ff.loss"),
+    ("jit(step_fn)/ff.optimizer/sub", "ff.optimizer"),
+    ("jit(step_fn)/while/body/closed_call/dot_general", "unscoped"),
+    ("jit(step_fn)/while", "unscoped"), ("", "unscoped")])
+def test_the_innermost_scope_the_program_opened(op_name, scope):
+    assert sr.innermost_scope(
+        op_name, {"dense_1", "attn_2", "experts_0"}) == scope
+
+
+def test_the_breakdown_names_ops_by_scope_and_gaps_by_host_span(hand):
+    """``device_ops``: each of the nine instructions behind its layer or
+    phase scope, ``unscoped/`` where its ``op_name`` has none (the
+    ``while`` and the copy). ``idle_gaps``, by the drawing above: the 80
+    us before the first op lie under ``loader_next`` (50) and
+    ``train_step`` (30); the 430 us after ``copy.1`` under nothing
+    (2100-2150 and 2250-2400, across the groups' edge: 200),
+    ``fit.callbacks`` (100), ``loader_next`` (100) and ``train_step``
+    (30); the 60 us after the last op under the flush (10) and nothing
+    (50). ``fit.epoch`` covers everything and names nothing."""
+    instr = sr.instructions(hand["step_text"])
+    names = sr.scoped_names(instr, set(hand["layers"]))
+    host = sr.host_segments(hand["events"])
+    assert all(n != sr.EPOCH_SPAN for _, _, n in host)
+    events = dict(hand["events"], **{"async": {}})
+    r = trace_reduce.reduce_trace(events, [], names, host)
+    assert [[n, round(s * 1e6)] for n, s in r["device_ops"]] == [
+        ["attn_2/flash_attention_bwd_dkv.1", 500],
+        ["attn_2/flash_attention_fwd.1", 400],
+        ["dense_1/fusion.1", 200],
+        ["attn_2/flash_attention_bwd_dq.1", 200],
+        ["ff.optimizer/divide_subtract_fusion.1", 200],
+        ["unscoped/while.1", 120],
+        ["dense_1/fusion.2", 100],
+        ["ff.loss/fusion.3", 100],
+        ["unscoped/copy.1", 10]]
+    assert {n: round(s * 1e6) for n, s in r["idle_gaps"]} == {
+        "between-groups/after:unscoped/copy.1": 200,
+        "fit.callbacks/after:unscoped/copy.1": 100,
+        "fit.loader_next/after:unscoped/copy.1": 100,
+        "fit.loader_next/after:start": 50,
+        "between-groups/after:ff.optimizer/divide_subtract_fusion.1": 50,
+        "executor.train_step/after:start": 30,
+        "executor.train_step/after:unscoped/copy.1": 30,
+        "metrics_buffer.flush/after:ff.optimizer/divide_subtract_fusion.1":
+        10}
+    # the gaps are what ``idle_attributed_share.train`` attributes
+    attributed = sum(s for n, s in r["idle_gaps"]
+                     if not n.startswith(("between-groups", "inside-group")))
+    total = sum(s for _, s in r["idle_gaps"])
+    whole = sr.reduce_spans(hand["events"], instr)
+    assert total * 1e9 == pytest.approx(whole["idle_ns"])
+    assert attributed * 1e9 == pytest.approx(sum(
+        ns for n, ns in whole["idle_by_span"].items()
+        if n not in (sr.EPOCH_SPAN, sr.NO_SPAN)))
+
+
+def test_a_printed_name_is_cut_at_eighty_characters(hand):
+    long = "experts_0/" + "x" * 100
+    events = dict(hand["events"], **{"async": {}})
+    r = trace_reduce.reduce_trace(events, [], {"copy.1": long}, [])
+    shown = [n for n, _ in r["device_ops"] if n.startswith("experts_0/")]
+    assert shown == [long[:trace_reduce.NAME_CUT]]
+    assert len(shown[0]) == 80
+    assert "between-groups/after:" + long[:80] in dict(r["idle_gaps"])

@@ -38,7 +38,7 @@ HAND = {
 
 
 def test_hand_counted_busy_idle_kernel_and_collective_shares():
-    r = tr.reduce_trace(HAND, ["%custom-call.1"])
+    r = tr.reduce_trace(HAND, ["%custom-call.1"], {}, [])
     assert r["n_devices"] == 1 and r["n_marks"] == 2
     assert r["window_s"] == pytest.approx(710e-9)      # 90 .. 800
     assert r["busy_s"] == pytest.approx(450e-9)        # 50 + 300 + 100
@@ -48,7 +48,7 @@ def test_hand_counted_busy_idle_kernel_and_collective_shares():
 
 
 def test_hand_counted_self_times_and_gaps():
-    r = tr.reduce_trace(HAND, ["%custom-call.1"])
+    r = tr.reduce_trace(HAND, ["%custom-call.1"], {}, [])
     ops = dict(r["device_ops"])
     # the while's own time is what its body does not cover: 300 - 200
     assert ops["while"] == pytest.approx(100e-9)
@@ -69,7 +69,7 @@ def test_two_devices_are_averaged_and_ops_outside_the_window_clipped():
         "/device:TPU:0": [["x", 0, 100], ["y", 150, 100]],    # 50 + 50 in
         "/device:TPU:1": [["all-gather.1", 50, 150]]},        # all in
         "marks": [["bench.group", 50, 150]]}
-    r = tr.reduce_trace(ev)
+    r = tr.reduce_trace(ev, [], {}, [])
     assert r["n_devices"] == 2
     assert r["window_s"] == pytest.approx(150e-9)
     assert r["busy_s"] == pytest.approx((100 + 150) / 2 * 1e-9)
@@ -78,8 +78,10 @@ def test_two_devices_are_averaged_and_ops_outside_the_window_clipped():
 
 
 def test_a_trace_with_no_marks_or_no_device_gives_nothing():
-    assert tr.reduce_trace({"devices": {}, "marks": HAND["marks"]}) == {}
-    assert tr.reduce_trace({"devices": HAND["devices"], "marks": []}) == {}
+    assert tr.reduce_trace({"devices": {}, "marks": HAND["marks"]},
+                           [], {}, []) == {}
+    assert tr.reduce_trace({"devices": HAND["devices"], "marks": []},
+                           [], {}, []) == {}
 
 
 def test_interval_helpers():
@@ -118,7 +120,7 @@ def test_recorded_chip_trace_against_a_raster_count():
     with open(RECORDED) as f:
         rec = json.load(f)
     assert os.path.getsize(RECORDED) < 1_000_000
-    r = tr.reduce_trace(rec["events"], rec["kernel_names"])
+    r = tr.reduce_trace(rec["events"], rec["kernel_names"], {}, [])
     (busy_share, kernel_share), = _raster(rec["events"],
                                           rec["kernel_names"], 1000)
     assert 1 - r["idle_share"] == pytest.approx(busy_share, abs=2e-3)

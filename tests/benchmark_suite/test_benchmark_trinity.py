@@ -32,19 +32,19 @@ PR51 = {        # name -> (unit, better, source, layer)
                                      "attention"),
     "trinity_full_attn_time_share.train": ("%", "lower", "device_trace",
                                            "attention"),
-    "trinity_moe_time_share.train": ("%", "lower", "device_trace",
+    "moe_time_share.train": ("%", "lower", "device_trace",
                                      "experts"),
-    "trinity_flash_fwd_roofline": ("%", "higher", "device_trace",
+    "window_flash_fwd_roofline": ("%", "higher", "device_trace",
                                    "kernels"),
-    "trinity_flash_bwd_dq_roofline": ("%", "higher", "device_trace",
+    "window_flash_bwd_dq_roofline": ("%", "higher", "device_trace",
                                       "kernels"),
-    "trinity_flash_bwd_dkv_roofline": ("%", "higher", "device_trace",
+    "window_flash_bwd_dkv_roofline": ("%", "higher", "device_trace",
                                        "kernels"),
-    "trinity_swa_kept_share": ("ratio", "lower", "program_counter",
+    "swa_kept_share": ("ratio", "lower", "program_counter",
                                "attention"),
-    "trinity_moe_dropped_assignments": ("count", "lower",
+    "moe_dropped_assignments": ("count", "lower",
                                         "program_counter", "experts"),
-    "trinity_moe_overflow_layer_steps": ("count", "lower",
+    "moe_overflow_layer_steps": ("count", "lower",
                                          "program_counter", "experts"),
 }
 SHARED = {"compile_s", "step_ms.train", "mfu.train", "in_window_compiles",
@@ -52,9 +52,19 @@ SHARED = {"compile_s", "step_ms.train", "mfu.train", "in_window_compiles",
           "device_idle_share.train"}
 
 
+OWN = {n for n in PR51 if n.startswith("trinity_")}   # no other cell's
+
+
 def _read(metric, ctx):
     return cells.load_module(BENCH, "layer_metrics",
                              cells.metric_file(metric)).read(ctx)
+
+
+def _holding(cell, listed):
+    """``listed``, which has to hold ``cell``: an entry lists every cell
+    whose run gives its reader a reading, this one among them."""
+    assert cell in listed
+    return listed
 
 
 @pytest.fixture(scope="module")
@@ -79,17 +89,20 @@ def test_each_new_metric_lists_the_cell_and_has_a_reader(manifest, name):
     assert by_name[name] == {
         "name": name, "unit": unit, "better": better, "source": source,
         "layer": layer, "moves": "train_tokens_per_s",
-        "workloads": [CELL] + by_name[name]["workloads"][1:]}
+        "workloads": _holding(CELL, by_name[name]["workloads"])}
     assert callable(cells.load_module(
         BENCH, "layer_metrics", cells.metric_file(name)).read)
 
 
 def test_the_new_entries_come_after_every_entry_the_parent_had(manifest):
-    """After the seven cells and configurations and the 78 metrics of
-    the parent; what comes after this PR's is not this test's to say."""
+    """After the seven cells and configurations of the parent and the
+    sparse-attention cell's metrics (no count is held); what comes
+    after this PR's is not this test's to say."""
     order = [m["name"] for m in manifest["per_layer"]]
-    first = min(order.index(n) for n in PR51)
-    assert order.index("keye_moe_overflow_layer_steps") < first >= 78
+    assert order.index("keye_dsa_index_kl") \
+        < min(order.index(n) for n in OWN)
+    assert [n for n in order if n in OWN] \
+        == [n for n in PR51 if n in OWN]
     names = [w["name"] for w in manifest["workloads"]]
     assert all(names.index(w) < names.index(CELL) for w in OLDER_CELLS)
     configs = [c["name"] for c in manifest["configs"]]
@@ -101,7 +114,7 @@ def test_the_new_entries_come_after_every_entry_the_parent_had(manifest):
                if w["name"] in OLDER_CELLS or w["name"] == CELL)
     with open(os.path.join(ROOT, "PERF.md")) as f:
         perf = f.read()
-    assert CELL in perf and "trinity_swa_kept_share" in perf
+    assert CELL in perf and "swa_kept_share" in perf
 
 
 @pytest.mark.parametrize("older", sorted(OLDER_CELLS))
@@ -109,7 +122,7 @@ def test_every_older_cell_is_unmoved(manifest, older):
     entry = next(w for w in manifest["workloads"] if w["name"] == older)
     assert entry["chips"] == 1 and entry["config"] != CONFIG
     reported = {m["name"] for m in cells.resolve_cell(ROOT, older).per_layer}
-    assert SHARED <= reported and not reported & set(PR51)
+    assert SHARED <= reported and not reported & OWN
 
 
 def test_the_cell_reports_the_shared_metrics_and_its_own(cell):
@@ -340,7 +353,7 @@ def _hand_ctx(ops=OPS, layers=LAYERS):
     names = {n for n, _, _ in layers}
     return types.SimpleNamespace(
         span_reduced=span_reduce.reduce_spans(events, instr),
-        span_instructions=instr, model=_model(layers),
+        span_events=events, span_instructions=instr, model=_model(layers),
         scope_layer_ns=scope_reduce.layer_self_ns(events, instr, names),
         peak=peaks.lookup("TPU v5 lite"), counters={},
         cell=types.SimpleNamespace(bench_dir=BENCH))
@@ -349,7 +362,7 @@ def _hand_ctx(ops=OPS, layers=LAYERS):
 @pytest.mark.parametrize("metric,want", [
     ("trinity_swa_time_share.train", 100.0 * 310 / 700),
     ("trinity_full_attn_time_share.train", 100.0 * 240 / 700),
-    ("trinity_moe_time_share.train", 100.0 * 100 / 700)])
+    ("moe_time_share.train", 100.0 * 100 / 700)])
 def test_time_shares_by_hand(metric, want):
     """The window layer's ops (its second forward under the block's
     rematerialisation among them) and the full layer's add up to the
@@ -365,17 +378,17 @@ def test_rooflines_count_each_call_by_its_own_layers_mask():
     peak = 197e12
     # forward: the window layer's call over the band, the full layer's
     # over the triangle, summed over the 300 us the two calls took
-    fwd = _read("trinity_flash_fwd_roofline", ctx)
+    fwd = _read("window_flash_fwd_roofline", ctx)
     assert fwd == pytest.approx(
         100.0 * (2 * 2 * 32 * (BAND + TRIANGLE) * 128 / peak) / 300e-6)
     # the backward call sits under the rematerialised block's scope and
     # is the window layer's: the band alone
-    dq = _read("trinity_flash_bwd_dq_roofline", ctx)
+    dq = _read("window_flash_bwd_dq_roofline", ctx)
     assert dq == pytest.approx(
         100.0 * (3 * 2 * 32 * BAND * 128 / peak) / 150e-6)
     # counted over the causal triangle it would read 2.29 times too high
     assert TRIANGLE / BAND == pytest.approx(2.2858, abs=1e-3)
-    assert _read("trinity_flash_bwd_dkv_roofline", ctx) is None   # no call
+    assert _read("window_flash_bwd_dkv_roofline", ctx) is None   # no call
 
 
 def test_rooflines_read_nothing_where_another_layer_shares_the_kernel():
@@ -388,8 +401,8 @@ def test_rooflines_read_nothing_where_another_layer_shares_the_kernel():
             "cross_attn", "OP_MULTIHEAD_ATTENTION", {"num_heads": 12})])
     finally:
         del KERNEL_SHAPES["flash_attention_fwd.3"]
-    assert _read("trinity_flash_fwd_roofline", ctx) is None
-    assert _read("trinity_flash_bwd_dq_roofline", ctx) is not None
+    assert _read("window_flash_fwd_roofline", ctx) is None
+    assert _read("window_flash_bwd_dq_roofline", ctx) is not None
 
 
 def test_the_counters_by_hand():
@@ -397,9 +410,9 @@ def test_the_counters_by_hand():
     ctx.counters = {"moe.dropped": 0.0, "moe.overflow": 2.0,
                     "attn.window_pairs": 4 * 14681088.0,
                     "attn.causal_pairs": 4 * 33558528.0}
-    assert _read("trinity_moe_dropped_assignments", ctx) == 0.0
-    assert _read("trinity_moe_overflow_layer_steps", ctx) == 2.0
-    assert _read("trinity_swa_kept_share", ctx) == pytest.approx(0.43748,
+    assert _read("moe_dropped_assignments", ctx) == 0.0
+    assert _read("moe_overflow_layer_steps", ctx) == 2.0
+    assert _read("swa_kept_share", ctx) == pytest.approx(0.43748,
                                                                  abs=1e-5)
 
 
@@ -419,7 +432,7 @@ def test_every_new_reader_reads_nothing_from_the_parent(metric):
             FWD + "attn_1/flash_attention_fwd/pallas_call")]
     got = _read(metric, _hand_ctx(ops, lfm2))
     if metric in ("trinity_full_attn_time_share.train",
-                  "trinity_flash_fwd_roofline"):
+                  "window_flash_fwd_roofline"):
         assert isinstance(got, float)
     else:
         assert got is None
@@ -443,7 +456,9 @@ def test_every_new_reader_reads_the_recorded_testdata_without_error(
         spans = json.load(f)
     assert recorded and spans
     ctx = types.SimpleNamespace(
-        trace=trace_reduce.reduce_trace(recorded["events"], []),
+        trace=trace_reduce.reduce_trace(recorded["events"], [], {}, []),
+        span_events=dict(recorded["events"], spans=[]),
+        span_instructions={},
         cell=types.SimpleNamespace(root="/nonexistent", name="x.train",
                                    bench_dir=BENCH),
         step_text="", peak=peaks.lookup("TPU v5 lite"), counters={},

@@ -65,29 +65,29 @@ OLDER_CELLS = {          # name -> (config, traffic)
                                         "train_b1_s4096")}
 US = 1000
 PR40 = ["xing_mhc_time_share.train", "xing_mhc_sinkhorn_time_share.train",
-        "xing_mla_time_share.train", "xing_moe_time_share.train",
-        "xing_mtp_time_share.train", "xing_mla_flash_fwd_roofline",
-        "xing_mla_flash_bwd_dq_roofline", "xing_mla_flash_bwd_dkv_roofline",
-        "xing_moe_dropped_assignments", "xing_mhc_sum_err",
+        "mla_time_share.train", "moe_time_share.train",
+        "mtp_time_share.train", "mla_flash_fwd_roofline",
+        "mla_flash_bwd_dq_roofline", "mla_flash_bwd_dkv_roofline",
+        "moe_dropped_assignments", "xing_mhc_sum_err",
         "xing_mhc_clamped"]
 LAYER_OF = dict(zip(PR40, [
     "residual", "residual", "attention", "experts", "mtp", "kernels",
     "kernels", "kernels", "experts", "residual", "residual"]))
 # the accepted readings of the executor, the loader, the device and the
-# entry, whose own ``workloads`` lists hold cells 1 and 2 alone: the
-# accepted metric of each name without its ``xing_``, entry for entry
+# entry, which list no cells and so report this one
 PR40_SHARED_LAYERS = [
-    "xing_fwd_time_share.train", "xing_bwd_time_share.train",
-    "xing_opt_time_share.train", "xing_dispatch_ms_per_step.train",
-    "xing_loader_wait_ms_per_step.train",
-    "xing_idle_attributed_share.train", "xing_retraces_after_warmup",
-    "xing_host_init_s", "xing_step_trace_s", "xing_step_backend_compile_s",
-    "xing_xla_cache_load_s", "xing_xla_cache_misses",
-    "xing_setup_attributed_share"]
+    "fwd_time_share.train", "bwd_time_share.train",
+    "opt_time_share.train", "dispatch_ms_per_step.train",
+    "loader_wait_ms_per_step.train",
+    "idle_attributed_share.train", "retraces_after_warmup",
+    "host_init_s", "step_trace_s", "step_backend_compile_s",
+    "xla_cache_load_s", "xla_cache_misses",
+    "setup_attributed_share"]
 MINE = PR40 + PR40_SHARED_LAYERS
 SHARED = {"compile_s", "step_ms.train", "mfu.train", "in_window_compiles",
           "mosaic_calls_per_step", "kernel_time_share.train",
           "device_idle_share.train"}
+OWN = [n for n in PR40 if n.startswith("xing_")]      # no other cell's
 PARENT_COMMIT = "8147231e14972d534d19685abf4dc3380dc528d0"
 
 
@@ -110,13 +110,17 @@ def cell():
 # ----------------------------------------------------------------------
 # the manifest, by name
 # ----------------------------------------------------------------------
+def _reports(entry, workload) -> bool:
+    return "workloads" not in entry or workload in entry["workloads"]
+
+
 @pytest.mark.parametrize("name", PR40)
-def test_each_new_metric_lists_the_cell_alone_and_has_a_reader(manifest,
-                                                               name):
+def test_each_new_metric_lists_the_cell_and_has_a_reader(manifest, name):
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     assert len(by_name) == len(manifest["per_layer"])
     m = by_name[name]
-    assert m["workloads"][:1] == [CELL]
+    assert CELL in m["workloads"]
+    assert m["workloads"] == [CELL] or name not in OWN
     assert m["moves"] == "train_tokens_per_s"
     assert m["layer"] == LAYER_OF[name]
     assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
@@ -133,12 +137,13 @@ def test_each_new_metric_lists_the_cell_alone_and_has_a_reader(manifest,
 @pytest.mark.parametrize("name", PR40_SHARED_LAYERS)
 def test_each_shared_layers_metric_is_the_accepted_one_for_this_cell(
         manifest, name):
+    """One entry and one file a reader: the accepted entry reports this
+    cell, and the copy this cell had is gone with its file."""
     by_name = {m["name"]: m for m in manifest["per_layer"]}
-    accepted = by_name[name[len("xing_"):]]
-    assert CELL not in accepted["workloads"]
-    assert by_name[name] == dict(
-        accepted, name=name,
-        workloads=[CELL] + by_name[name]["workloads"][1:])
+    assert _reports(by_name[name], CELL)
+    assert "xing_" + name not in by_name
+    assert cells.load_module(BENCH, "layer_metrics",
+                             cells.metric_file("xing_" + name)) is None
     assert callable(cells.load_module(
         BENCH, "layer_metrics", cells.metric_file(name)).read)
 
@@ -154,16 +159,15 @@ def _pr37_tables():
 
 
 def test_the_new_entries_come_after_every_entry_of_the_parents(manifest):
-    """In one run, the eleven and then the thirteen, after every name
-    the parent's manifest had (PR 37's tables list them). What comes
-    after them is not this test's to say."""
+    """This cell's own entries (the streams' four) after the set-up's
+    seven, which were the parent's last (PR 37's tables list them); the
+    ones it shares with other cells stand where the first of them
+    stood. What comes after them is not this test's to say."""
     pr37 = _pr37_tables()
     order = [m["name"] for m in manifest["per_layer"]]
-    at = [order.index(n) for n in MINE]
-    assert at == list(range(at[0], at[0] + len(MINE)))
-    parents = list(pr37.ACCEPTED) + list(pr37.NEW)
-    assert len(parents) == 46
-    assert max(order.index(n) for n in parents) < at[0]
+    assert [n for n in order if n in OWN] == OWN
+    assert max(order.index(n) for n in list(pr37.SHARED) + list(pr37.NEW)) \
+        < min(order.index(n) for n in OWN)
     configs = [c["name"] for c in manifest["configs"]]
     assert all(configs.index(c) < configs.index("xing4_29b_a4b")
                for c, _ in OLDER_CELLS.values())
@@ -178,25 +182,18 @@ def test_the_new_entries_come_after_every_entry_of_the_parents(manifest):
 
 
 def test_pr37s_closed_set_test_by_name(manifest):
-    """``test_benchmark_setup_spans.py::test_the_accepted_entries_stand_
-    and_the_new_ones_come_after`` holds ``per_layer`` to a closed set of
-    names and ``workloads`` to five cells, so it fails on any new entry
-    and ``tests/conftest.py`` deselects it. Everything else it asserted,
-    from its own tables: the accepted entries' lists and order, its seven
-    in one run after them, the five cells first and in order.
-    The set is held from below: what that file knew is all there, and
-    more may be."""
+    """What ``test_benchmark_setup_spans.py``'s closed-set test held,
+    from that file's own tables and from below: its seven shared
+    entries without a list and its seven of the set-up are all there,
+    in their order, the five cells first and in order; more may be."""
     pr37 = _pr37_tables()
     order = [m["name"] for m in manifest["per_layer"]]
     assert len(set(order)) == len(order)
-    assert set(order) >= set(pr37.ACCEPTED) | set(pr37.NEW) | set(MINE)
-    for m in manifest["per_layer"]:
-        if m["name"] in pr37.ACCEPTED:
-            assert m.get("workloads") == pr37.ACCEPTED[m["name"]], m["name"]
-            assert order.index(m["name"]) < order.index("host_init_s")
-    assert [n for n in order if n in pr37.ACCEPTED] == list(pr37.ACCEPTED)
-    at = [order.index(n) for n in pr37.NEW]
-    assert at == list(range(at[0], at[0] + 7))
+    assert set(order) >= set(pr37.SHARED) | set(pr37.NEW) | set(MINE)
+    assert [n for n in order if n in pr37.SHARED] == list(pr37.SHARED)
+    assert [n for n in order if n in pr37.NEW] == list(pr37.NEW)
+    assert max(order.index(n) for n in pr37.SHARED) \
+        < order.index("host_init_s")
     assert [w["name"] for w in manifest["workloads"]][:5] \
         == pr37.CELLS_1_2 + pr37.CELLS_3_5 == list(OLDER_CELLS)
     by_name = {m["name"]: m for m in manifest["per_layer"]}
@@ -209,13 +206,13 @@ def test_pr37s_closed_set_test_by_name(manifest):
 @pytest.mark.parametrize("older", sorted(OLDER_CELLS))
 def test_every_older_cell_is_unmoved(manifest, older):
     """Its entry, its configuration's entry, the metrics it reports: what
-    they were before this PR, none of this PR's among them."""
+    they were before this PR, none of this cell's own among them."""
     config, traffic = OLDER_CELLS[older]
     entry = next(w for w in manifest["workloads"] if w["name"] == older)
     assert entry == dict(entry, config=config, traffic=traffic, chips=1)
     assert sum(c["name"] == config for c in manifest["configs"]) == 1
     reported = {m["name"] for m in cells.resolve_cell(ROOT, older).per_layer}
-    assert SHARED <= reported and not reported & set(MINE)
+    assert SHARED <= reported and not reported & set(OWN)
     assert SHARED <= {m["name"] for m in manifest["per_layer"]
                       if "workloads" not in m}
 
@@ -223,7 +220,10 @@ def test_every_older_cell_is_unmoved(manifest, older):
 def test_the_manifest_is_the_parents_plus_this_prs_entries(manifest):
     """Against ``git show <parent>:BENCHMARK.json`` where the checkout
     has its history (the driver's copy of the committed files has not):
-    every older entry equal, key for key, in its old place."""
+    every older configuration and cell in its old place with its old
+    files, every older metric still reported under its name or under
+    the one ``testdata/folded_names.json`` gives for it, with
+    its unit, source, layer and what it moves."""
     import subprocess
     shown = subprocess.run(
         ["git", "show", f"{PARENT_COMMIT}:BENCHMARK.json"], cwd=ROOT,
@@ -231,14 +231,22 @@ def test_the_manifest_is_the_parents_plus_this_prs_entries(manifest):
     if shown.returncode != 0:
         pytest.skip("no git history here")
     parent = json.loads(shown.stdout)
+    with open(os.path.join(BENCH, "testdata", "folded_names.json")) as f:
+        renamed = json.load(f)["renamed"]
     for key in ("command", "paths", "run_seconds", "end_to_end"):
         assert manifest[key] == parent[key]
-    for key in ("configs", "workloads", "per_layer"):
-        assert manifest[key][:len(parent[key])] == parent[key]
-        mine = {"configs": ["xing4_29b_a4b"], "workloads": [CELL],
-                "per_layer": MINE}[key]
+    for key in ("configs", "workloads"):
+        for was, now in zip(parent[key], manifest[key]):
+            assert dict(was, why="") == dict(now, why="")
+        mine = {"configs": ["xing4_29b_a4b"], "workloads": [CELL]}[key]
         added = [e["name"] for e in manifest[key][len(parent[key]):]]
         assert added[:len(mine)] == mine
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    for was in parent["per_layer"]:
+        now = by_name[renamed.get(was["name"], was["name"])]
+        for field in ("unit", "better", "source", "layer", "moves"):
+            assert now[field] == was[field], was["name"]
+        assert all(_reports(now, c) for c in was.get("workloads", ()))
 
 
 def test_the_cell_reports_the_shared_metrics_and_its_own(cell):
@@ -452,10 +460,9 @@ def _model(layers):
         for n, k, p in layers])
 
 
-def _hand_ctx(tmp_path, monkeypatch, ops=OPS, layers=LAYERS):
+def _hand_ctx(tmp_path, ops=OPS, layers=LAYERS):
     """A context whose trace is the hand-made one: the reductions that
-    keep their result on it are given it, the one reader that opens the
-    trace itself is handed the same events."""
+    keep their result on it are given it, and the events beside them."""
     events = {"devices": {"/device:TPU:0": [[n, s * US, d * US]
                                             for n, s, d, _ in ops]},
               "marks": [["bench.group", 1000 * US, 1000 * US]], "spans": []}
@@ -465,12 +472,9 @@ def _hand_ctx(tmp_path, monkeypatch, ops=OPS, layers=LAYERS):
                  "results": KERNEL_SHAPES.get(n, ([], []))[1]}
              for n, _, _, op in ops}
     names = {n for n, _, _ in layers}
-    monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: d)
-    monkeypatch.setattr(span_reduce, "extract",
-                        lambda path, mark_prefix="": events)
     return types.SimpleNamespace(
         span_reduced=span_reduce.reduce_spans(events, instr),
-        span_instructions=instr, model=_model(layers),
+        span_events=events, span_instructions=instr, model=_model(layers),
         scope_layer_ns=scope_reduce.layer_self_ns(events, instr, names),
         peak=peaks.lookup("TPU v5 lite"), counters={},
         cell=types.SimpleNamespace(bench_dir=BENCH, root=str(tmp_path),
@@ -480,40 +484,40 @@ def _hand_ctx(tmp_path, monkeypatch, ops=OPS, layers=LAYERS):
 @pytest.mark.parametrize("metric,want", [
     ("xing_mhc_time_share.train", 100.0 * 460 / 760),
     ("xing_mhc_sinkhorn_time_share.train", 100.0 * 150 / 760),
-    ("xing_mla_time_share.train", 100.0 * 140 / 760),
-    ("xing_moe_time_share.train", 100.0 * 100 / 760),
-    ("xing_mtp_time_share.train", 100.0 * 50 / 760)])
+    ("mla_time_share.train", 100.0 * 140 / 760),
+    ("moe_time_share.train", 100.0 * 100 / 760),
+    ("mtp_time_share.train", 100.0 * 50 / 760)])
 def test_time_shares_by_hand_with_a_loop_of_iterations(
-        tmp_path, monkeypatch, metric, want):
+        tmp_path, metric, want):
     """The ``while`` event counts for what its body's ops leave of it,
     beside them; a ``post`` node's pass and a rematerialised one count
     for the mechanism, the module's nodes for the module too."""
-    ctx = _hand_ctx(tmp_path, monkeypatch)
+    ctx = _hand_ctx(tmp_path)
     assert ctx.span_reduced["busy_ns"] == 760 * US
     assert _read(metric, ctx) == pytest.approx(want)
 
 
-def test_the_flash_roofline_at_four_heads(tmp_path, monkeypatch):
-    ctx = _hand_ctx(tmp_path, monkeypatch)
-    fwd = _read("xing_mla_flash_fwd_roofline", ctx)
+def test_the_flash_roofline_at_four_heads(tmp_path):
+    ctx = _hand_ctx(tmp_path)
+    fwd = _read("mla_flash_fwd_roofline", ctx)
     assert fwd == pytest.approx(
         100.0 * (2 * 4 * PAIRS * (192 + 128) / 197e12) / 100e-6)
-    assert _read("xing_mla_flash_bwd_dq_roofline", ctx) is None  # no call
-    assert _read("xing_mla_flash_bwd_dkv_roofline", ctx) is None
+    assert _read("mla_flash_bwd_dq_roofline", ctx) is None  # no call
+    assert _read("mla_flash_bwd_dkv_roofline", ctx) is None
 
 
-def test_the_counters_by_hand(tmp_path, monkeypatch):
-    ctx = _hand_ctx(tmp_path, monkeypatch)
+def test_the_counters_by_hand(tmp_path):
+    ctx = _hand_ctx(tmp_path)
     ctx.counters = {"moe.dropped": 0.0, "mhc.sublayers": 96.0,
                     "mhc.sum_err": 0.48, "mhc.clamped": 3.0}
-    assert _read("xing_moe_dropped_assignments", ctx) == 0.0
+    assert _read("moe_dropped_assignments", ctx) == 0.0
     assert _read("xing_mhc_sum_err", ctx) == pytest.approx(0.005)
     assert _read("xing_mhc_clamped", ctx) == 3.0
 
 
 @pytest.mark.parametrize("metric", PR40)
 def test_every_new_reader_reads_nothing_from_the_parent(
-        tmp_path, monkeypatch, metric):
+        tmp_path, metric):
     """The parent of PR 40 names no hyper-connection node and counts no
     ``mhc.*``; a model of the parent's (GPT-2) has no latent attention,
     no expert layer, no module; and a run without ``--trace 1`` has no
@@ -524,7 +528,7 @@ def test_every_new_reader_reads_nothing_from_the_parent(
     ops = [("fusion.1", 1000, 100, FWD + "attn_1/mul"),
            ("flash_attention_fwd.1", 1100, 200,
             FWD + "attn_1/flash_attention_fwd/pallas_call")]
-    assert _read(metric, _hand_ctx(tmp_path, monkeypatch, ops, gpt2)) is None
+    assert _read(metric, _hand_ctx(tmp_path, ops, gpt2)) is None
     cell = types.SimpleNamespace(root=str(tmp_path), name="x.train",
                                  bench_dir=BENCH)
     bare = types.SimpleNamespace(
@@ -545,7 +549,9 @@ def test_every_new_reader_reads_the_recorded_testdata_without_error(
         spans = json.load(f)
     assert recorded and spans
     ctx = types.SimpleNamespace(
-        trace=trace_reduce.reduce_trace(recorded["events"], []),
+        trace=trace_reduce.reduce_trace(recorded["events"], [], {}, []),
+        span_events=dict(recorded["events"], spans=[]),
+        span_instructions={},
         cell=types.SimpleNamespace(root="/nonexistent", name="x.train",
                                    bench_dir=BENCH),
         step_text="", peak=peaks.lookup("TPU v5 lite"), counters={},
@@ -574,25 +580,28 @@ def recorded_ctx(monkeypatch):
 
 
 @pytest.mark.parametrize("metric", PR40_SHARED_LAYERS)
-def test_each_shared_layers_reader_reads_what_the_accepted_one_reads(
-        recorded_ctx, metric):
+def test_each_shared_layers_reader_reads_the_recorded_run(recorded_ctx,
+                                                          metric):
+    """The accepted reader on this cell's recorded run, held to the
+    run's hand count where PR 37's tables have one."""
     ctx, by_hand = recorded_ctx
-    accepted = metric[len("xing_"):]
     got = _read(metric, ctx)
-    assert got is not None and got == _read(accepted, ctx)
-    if accepted in by_hand:
-        assert got == pytest.approx(by_hand[accepted])
+    assert got is not None
+    if metric in by_hand:
+        assert got == pytest.approx(by_hand[metric])
+    elif "share" in metric:
+        assert 0.0 <= got <= 100.0
 
 
 @pytest.mark.parametrize("metric,want", [
-    ("xing_fwd_time_share.train", 100.0 * 550 / 760),
-    ("xing_bwd_time_share.train", 100.0 * 100 / 760),
-    ("xing_opt_time_share.train", 100.0 * 60 / 760)])
-def test_phase_shares_by_hand(tmp_path, monkeypatch, metric, want):
+    ("fwd_time_share.train", 100.0 * 550 / 760),
+    ("bwd_time_share.train", 100.0 * 100 / 760),
+    ("opt_time_share.train", 100.0 * 60 / 760)])
+def test_phase_shares_by_hand(tmp_path, metric, want):
     """Everything before ``fusion.8`` but the expert product with no
     ``op_name`` is the forward pass (550 of 760); the rematerialised mix
     lies inside a ``transpose(`` and counts as backward."""
-    assert _read(metric, _hand_ctx(tmp_path, monkeypatch)) == \
+    assert _read(metric, _hand_ctx(tmp_path)) == \
         pytest.approx(want)
 
 
@@ -615,7 +624,7 @@ def test_a_node_with_no_op_under_the_scope_reads_nothing(tmp_path,
     """A program whose nodes open no ``mhc.sinkhorn`` scope: the nodes'
     share reads, the iterations' does not."""
     ops = [(n, s, d, op.replace("/mhc.sinkhorn", "")) for n, s, d, op in OPS]
-    ctx = _hand_ctx(tmp_path, monkeypatch, ops)
+    ctx = _hand_ctx(tmp_path, ops)
     assert _read("xing_mhc_time_share.train", ctx) == pytest.approx(
         100.0 * 460 / 760)
     assert _read("xing_mhc_sinkhorn_time_share.train", ctx) is None
@@ -680,8 +689,8 @@ def no_profiler(monkeypatch):
     monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: d)
-    monkeypatch.setattr(trace_reduce, "extract",
-                        lambda path, mark_prefix="": recorded["events"])
+    monkeypatch.setattr(trace_reduce, "extract", lambda *a: dict(
+        recorded["events"], spans=[]))
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -704,16 +713,16 @@ def test_the_cell_runs_through_the_train_runner(tiny_root, no_profiler,
     assert res["failed"] == 0 and res["attempted"] >= 1
     if trace:
         metrics = res["metrics"]
-        assert metrics["xing_moe_dropped_assignments"]["value"] == 0
+        assert metrics["moe_dropped_assignments"]["value"] == 0
         assert metrics["xing_mhc_clamped"]["value"] == 0
         assert 0 < metrics["xing_mhc_sum_err"]["value"] < 0.2
         assert metrics["in_window_compiles"]["value"] == 0
         # the program's own ring reaches the entry's and the executor's
         # readers (the device's side is stubbed here: no phase share)
-        assert metrics["xing_retraces_after_warmup"]["value"] == 0
-        assert metrics["xing_host_init_s"]["value"] > 0
-        assert metrics["xing_step_trace_s"]["value"] > 0
-        assert 0 < metrics["xing_setup_attributed_share"]["value"] <= 100
+        assert metrics["retraces_after_warmup"]["value"] == 0
+        assert metrics["host_init_s"]["value"] > 0
+        assert metrics["step_trace_s"]["value"] > 0
+        assert 0 < metrics["setup_attributed_share"]["value"] <= 100
         assert metrics["step_ms.train"]["value"] > 0
     else:
         assert res["metrics"]["train_tokens_per_s"]["value"] > 0
